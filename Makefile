@@ -10,16 +10,15 @@ SINCE ?= HEAD
 
 # Speculative-decoding bench only (docs/performance.md "Speculative
 # decoding"): the three-arm vanilla / n-gram / draft-model A/B at the
-# 64-slot config. On CPU this smokes structure; the headline
-# accepted-tokens/s ratios are judged on chip (BENCH_SECTIONS gates the
-# other sections off, including the primary SFT probe).
+# 64-slot config. Needs the chip: bench.py exits non-zero without a TPU
+# (BENCH_SECTIONS gates the other sections off, including the primary
+# SFT probe).
 bench-spec:
 	BENCH_SECTIONS=gen_spec $(PYTHON) bench.py
 
 # Fused sampling-epilogue bench only (docs/performance.md "Fused sampling
 # epilogue"): materialized-logits vs streamed-head A/B at the 64-slot
-# config. On CPU this smokes structure + the exactness probe; the
-# headline tokens/s ratio is judged on chip.
+# config. Needs the chip: bench.py exits non-zero without a TPU.
 bench-fused:
 	BENCH_SECTIONS=gen_sample_fused $(PYTHON) bench.py
 

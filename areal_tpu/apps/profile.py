@@ -25,9 +25,11 @@ def run_profile(
     n_steps: int = 8,
     n_warmup: int = 2,  # >= 1: the first step compiles
     n_mbs: int = 1,
-    peak_flops: float = 197e12,
     seed: int = 0,
 ) -> dict:
+    """``mfu`` is reported only on a device with a row in
+    ``flops.DEVICE_PEAKS`` (the CLI insists on one; a library caller on
+    any other device gets step time and TFLOP/s, and no utilization)."""
     import numpy as np
 
     import jax
@@ -75,12 +77,21 @@ def run_profile(
         dt = (time.perf_counter() - t0) / n_steps
 
     fl = flops_mod.train_flops(cfg, T, seqlens=seqlens)
+    dev = jax.devices()[0]
+    peaks = flops_mod.DEVICE_PEAKS.get(dev.device_kind)
     return {
         "metric": "profile_step",
+        "device": {
+            "platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices()),
+        },
         "step_time_s": round(dt, 5),
         "tokens_per_s": round(T / dt, 1),
         "tflops_per_s": round(fl / dt / 1e12, 2),
-        "mfu": round(fl / dt / peak_flops, 4),
+        **(
+            {"mfu": round(fl / dt / peaks.bf16_flops / len(jax.devices()), 4)}
+            if peaks else {}
+        ),
         "n_params": int(flops_mod.param_count(cfg)),
         "seqlens": list(seqlens),
         "n_steps": n_steps,
@@ -95,13 +106,20 @@ def main(argv=None):
                     help="'LENxN' or comma list, e.g. 512x8 or 8192")
     ap.add_argument("--n-steps", type=int, default=8)
     ap.add_argument("--n-mbs", type=int, default=1)
-    ap.add_argument("--peak-flops", type=float, default=197e12)
     ap.add_argument("overrides", nargs="*")
     args = ap.parse_args(argv)
 
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+
+    from areal_tpu.base import flops as flops_mod
     from areal_tpu.experiments.config import ModelSpec
     from areal_tpu.experiments import load_config
 
+    # utilization needs a published peak: fail before the timed steps
+    flops_mod.device_peaks(jax.devices()[0].device_kind)
     spec = load_config(ModelSpec, args.config, args.overrides)
     if "x" in args.seqlens:
         ln, n = args.seqlens.split("x")
@@ -110,7 +128,6 @@ def main(argv=None):
         seqlens = [int(x) for x in args.seqlens.split(",")]
     out = run_profile(
         spec, seqlens, n_steps=args.n_steps, n_mbs=args.n_mbs,
-        peak_flops=args.peak_flops,
     )
     print(json.dumps(out))
     return 0

@@ -1,0 +1,41 @@
+"""The persistent XLA compile cache: ONE site configures it.
+
+Every process entry calls :func:`configure` before its first compile
+(``apps/launcher._setup_worker_env``, ``gateway/__main__``,
+``apps/profile``, ``bench.py``, ``chip_smoke.py``'s children). Where
+``JAX_COMPILATION_CACHE_DIR`` is set from outside, JAX reads it itself and
+this function changes nothing. Where it is not, the cache goes to the one
+fixed path ``constants.compile_cache_dir()`` names — by exporting that
+same variable, so spawned children and a later ``import jax`` agree on it,
+and by updating the config of a JAX that is already imported. Never a
+path built from ``tempfile``, a pid or the time: the directory is part of
+the cache key, and a cache that moves never hits.
+
+A run held to the CPU (``JAX_PLATFORMS=cpu``: the tests, CPU-designated
+workers) caches nothing unless the variable asks for it: its programs are
+tiny, and XLA:CPU ties a cached executable to the host's CPU features.
+
+Importing this module does not import JAX, and :func:`configure` never
+initialises a backend — the launcher's JAX-free parent calls it too.
+"""
+
+import os
+import sys
+from typing import Optional
+
+from areal_tpu.base import constants
+
+
+def configure() -> Optional[str]:
+    """Returns the directory the cache lives in, or None when this run
+    caches nothing (held to the CPU, variable unset)."""
+    if constants.env_str(constants.COMPILE_CACHE_ENV) is not None:
+        return constants.compile_cache_dir()    # JAX reads it itself
+    if constants.jax_platforms().startswith("cpu"):
+        return None
+    path = constants.compile_cache_dir()
+    os.environ[constants.COMPILE_CACHE_ENV] = path
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -358,6 +358,39 @@ def gateway_deadline_s() -> float:
     return env_float(GATEWAY_DEADLINE_S_ENV, 0.0)
 
 
+def jax_platforms() -> str:
+    """``JAX_PLATFORMS`` (JAX's own variable): "cpu" holds every process to
+    the CPU — the test harness and CPU-designated workers set it."""
+    return (env_str("JAX_PLATFORMS") or "").strip().lower()
+
+
+def tpu_visible_devices() -> Optional[list]:
+    """``TPU_VISIBLE_DEVICES`` (libtpu's own variable): the chip ids this
+    process may open, or None when unrestricted. The launcher sets it per
+    chip-owning child (``apps/launcher.plan_chips``)."""
+    raw = env_str("TPU_VISIBLE_DEVICES")
+    if not raw:
+        return None
+    return [int(x) for x in raw.split(",") if x.strip()]
+
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"  # JAX's own variable
+# the one fixed fallback: inside the checkout, git-ignored (the directory
+# is part of the cache key, so a path that moves never hits)
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compile_cache",
+)
+
+
+def compile_cache_dir() -> str:
+    """``JAX_COMPILATION_CACHE_DIR``: where the persistent XLA compile
+    cache lives. Set from outside, JAX reads it itself and the program
+    sets no other; unset, every process uses ``<checkout>/
+    .jax_compile_cache`` (see ``base/compile_cache.py``)."""
+    return env_str(COMPILE_CACHE_ENV) or _DEFAULT_COMPILE_CACHE_DIR
+
+
 def native_disabled() -> bool:
     """``AREAL_DISABLE_NATIVE``: skip building/loading the C packer
     extension (pure-python fallback)."""
@@ -651,6 +684,7 @@ def get_env_vars(**extra) -> dict:
         "JAX_PLATFORMS",
         "XLA_FLAGS",
         "TPU_VISIBLE_DEVICES",
+        COMPILE_CACHE_ENV,
     ]
     out = {k: os.environ[k] for k in keys if k in os.environ}
     out.update({k: str(v) for k, v in extra.items()})

@@ -9,9 +9,41 @@ The attention term uses true per-sequence lengths (packed varlen batches:
 cost scales with sum of len² within segments, not T²).
 """
 
+import dataclasses
 from typing import Optional, Sequence
 
 from areal_tpu.models.config import ModelConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float        # FLOP/s, one chip
+    hbm_bytes_per_s: float   # bytes/s, one chip
+    source: str
+
+
+# The ONE table of published peaks, keyed by ``jax.devices()[0].device_kind``.
+# MFU and roofline shares are only defined against a row of this table: a
+# device that is not here is an error, never a default.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12,
+        hbm_bytes_per_s=819e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+        "16 GB HBM2e at 819 GB/s per chip",
+    ),
+}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)} — add a sourced row to "
+            "areal_tpu/base/flops.py:DEVICE_PEAKS"
+        ) from None
 
 
 def param_count(cfg: ModelConfig, activated: bool = False) -> int:

@@ -129,6 +129,9 @@ def main(argv=None) -> int:
     p.add_argument("--brownout", action="store_true",
                    help="enable the brownout degradation ladder")
     args = p.parse_args(argv)
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
     try:
         return asyncio.run(_amain(args))
     except KeyboardInterrupt:

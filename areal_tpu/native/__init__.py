@@ -6,13 +6,17 @@ buffer fill kernels behind ``train/batching.pack_sequences``.
 
 The shared object compiles lazily with g++ into the package directory the
 first time it is needed (no pybind11/setuptools dance; plain C ABI +
-ctypes). Everything degrades gracefully: if no compiler is available or the
+ctypes). Its file name carries a hash of ``packer.cpp``, so a build of any
+other source is never loaded — whatever a copy of the tree did to mtimes,
+and whichever git-ignored ``.so`` it brought along. Everything degrades
+gracefully: if no compiler is available or the
 build fails, callers fall back to the pure-numpy implementations —
 ``available()`` says which path is live. Set ``AREAL_DISABLE_NATIVE=1`` to
 force the fallback (parity tests exercise both).
 """
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,24 +28,30 @@ logger = logging.getLogger("areal_tpu.native")
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "packer.cpp")
-_SO = os.path.join(_DIR, "_packer.so")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_packer.{digest}.so")
+
 
 _lib = None
 _tried = False
 _lock = threading.Lock()
 
 
-def _build() -> bool:
+def _build(so: str) -> bool:
     # per-process temp name: concurrent first-use builds (trainer +
     # evaluator child, multiple Slurm tasks on one FS) must not interleave
     # writes into one .tmp; os.replace is atomic, last writer wins
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, _SRC]
     try:
         subprocess.run(
             cmd, check=True, capture_output=True, text=True, timeout=120
         )
-        os.replace(tmp, _SO)
+        os.replace(tmp, so)
         return True
     except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
             FileNotFoundError, OSError) as e:
@@ -66,22 +76,21 @@ def _load():
         if constants.native_disabled():
             return None
         try:
-            stale = not os.path.exists(_SO) or (
-                os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            )
-        except OSError:
-            stale = True  # source missing/unreadable: try a build, then fail soft
-        if stale and not _build():
+            so = _so_path()
+        except OSError as e:
+            logger.warning("native packer source unreadable (%s)", e)
+            return None
+        if not os.path.exists(so) and not _build(so):
             return None
         try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
         except OSError:
-            # a stale/corrupt .so (e.g. from an interrupted build on a
-            # previous run): rebuild once before giving up
-            if not _build():
+            # a corrupt .so (e.g. built for another machine): rebuild once
+            # before giving up
+            if not _build(so):
                 return None
             try:
-                lib = ctypes.CDLL(_SO)
+                lib = ctypes.CDLL(so)
             except OSError as e:
                 logger.warning("native packer load failed (%s)", e)
                 return None

@@ -18,11 +18,13 @@ All shapes static: ``q,k,v`` are ``[T, H, D]`` / ``[T, Hkv, D]`` where T is
 the padded packed-token budget, so one compiled program serves every batch.
 """
 
+import contextlib
 import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -2.3819763e38  # ~ -float32 max; matches common flash-attn masks
 
@@ -122,11 +124,8 @@ def packed_attention(
         bsk = flash_block_size_k or bs
         while T % bsk:
             bsk //= 2
-        return _fa.packed_flash_attention(
-            q,
-            k,
-            v,
-            segment_ids,
+        flash = functools.partial(
+            _fa.packed_flash_attention,
             softmax_scale=softmax_scale,
             soft_cap=soft_cap,
             sliding_window=sliding_window,
@@ -134,9 +133,58 @@ def packed_attention(
             block_size_k=bsk,
             max_seqlen=max_seqlen,
         )
+        if _FLASH_MESH is not None:
+            # GSPMD cannot partition a Mosaic kernel ("Mosaic kernels
+            # cannot be automatically partitioned"): under a multi-device
+            # mesh the call is a shard_map. Attention is per-head
+            # independent, and contiguous q-head chunks of H/tp cover whole
+            # GQA groups, so each model shard runs the kernel on its own
+            # heads; a caller's vmap over batch rows (spmd_axis_name =
+            # the data axes) shards the rows the same way.
+            tp = _FLASH_MESH.shape.get("model", 1)
+            if k.shape[1] % tp:
+                raise ValueError(
+                    f"flash attention under a {tp}-way model axis needs "
+                    f"n_kv_heads ({k.shape[1]}) divisible by it"
+                )
+            heads = P(None, "model", None)
+            flash = jax.shard_map(
+                flash, mesh=_FLASH_MESH,
+                in_specs=(heads, heads, heads, P(None)), out_specs=heads,
+                check_vma=False,
+            )
+        return flash(q, k, v, segment_ids)
     return _attention_xla(
         q, k, v, segment_ids, softmax_scale, soft_cap, sliding_window
     )
+
+
+# The mesh a multi-device engine is tracing its programs under, or None.
+# Trace-time state, set while an engine's jitted functions are traced
+# (`trace_on_mesh`): what the flash dispatch above needs to know to wrap
+# the kernel.
+_FLASH_MESH = None
+
+
+@contextlib.contextmanager
+def flash_mesh(mesh):
+    global _FLASH_MESH
+    prev = _FLASH_MESH
+    _FLASH_MESH = mesh if mesh is not None and mesh.size > 1 else None
+    try:
+        yield
+    finally:
+        _FLASH_MESH = prev
+
+
+def trace_on_mesh(mesh, f):
+    """``f``, to be jitted by an engine that owns ``mesh``: whenever it is
+    traced, the flash dispatch knows the mesh."""
+    @functools.wraps(f)
+    def traced(*args):
+        with flash_mesh(mesh):
+            return f(*args)
+    return traced
 
 
 # Context-parallel override: when set, packed training attention rings the

@@ -124,13 +124,14 @@ def paged_decode_attention(
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     if use_pallas is None:
         # the kernel's in-VMEM reshapes need a full-lane head_dim; smaller
-        # heads (and sub-tile pages) take the XLA gather path. int8 pages
-        # need a (32, 128)-tileable stripe — page % 32 instead of % 8
-        page_mult = 32 if pages.dtype == jnp.int8 else 8
+        # heads (and sub-tile pages — 128 for an int8 pool, see
+        # page_multiple) take the XLA gather path
+        from areal_tpu.ops.pallas.paged_attention import page_multiple
+
         use_pallas = (
             jax.devices()[0].platform == "tpu"
             and q.shape[-1] % 128 == 0
-            and pages.shape[4] % page_mult == 0
+            and pages.shape[4] % page_multiple(pages.dtype) == 0
             and Hkv % tp == 0
         )
     elif use_pallas and tp > 1 and Hkv % tp != 0:
@@ -164,8 +165,6 @@ def paged_decode_attention(
 
             # contiguous q-head chunks of H/tp cover whole GQA groups
             # (H/tp = n_rep * Hkv/tp), so per-shard n_rep is unchanged
-            from areal_tpu.ops.pallas.compat import shard_map
-
             in_specs = (
                 P(None, "model", None),                    # q
                 P(None, "model", None),                    # k_self
@@ -178,7 +177,7 @@ def paged_decode_attention(
             if scales is not None:
                 # the scales pytree rides the pool's kv-head sharding
                 in_specs += (P(None, None, None, "model", None),)
-            return shard_map(
+            return jax.shard_map(
                 _kernel, mesh=mesh,
                 in_specs=in_specs,
                 out_specs=P(None, "model", None),

@@ -61,7 +61,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.ops.pallas.compat import compiler_params as _compiler_params
 
 NEG_INF = -2.3819763e38
 LANES = 128
@@ -75,6 +74,49 @@ SPECIALIZE_MIN_T = 8192
 # rest of the kernel needs ~30 MB at block 1024). Above this the backward
 # falls back to separate dq/dkv sweeps.
 FUSED_BWD_MAX_DQ_BYTES = 48 * 2**20
+
+
+# Mosaic's scoped-vmem budget: the default a kernel gets when it asks for
+# nothing (v5e), and the most it may ask for (v5e VMEM is 128 MiB).
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+MAX_SCOPED_VMEM = 114 * 2**20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer as Mosaic lays it out: the minor dim pads
+    to 128 lanes and the second-minor to the dtype's sublane tile (8 rows
+    of 32 bits) — a ``[..., block_q, 1]`` f32 column costs 128x its
+    logical size."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = (1,) + tuple(shape)
+    sub = 8 * max(1, 4 // item)
+    n = -(-rows // sub) * sub * -(-cols // LANES) * LANES * item
+    for d in lead:
+        n *= d
+    return n
+
+
+def _vmem_limit(blocks, scratch, temps) -> Optional[int]:
+    """Scoped-vmem budget from the kernel's real footprint: pipelined
+    in/out ``blocks`` are double-buffered, ``scratch`` is resident, and
+    ``temps`` are the in-kernel score/probability tiles. None (the
+    compiler's default) while the estimate sits clearly inside the default
+    budget; otherwise the estimate plus half again for what the estimate
+    cannot see (spills, relayout copies)."""
+    est = (
+        2 * sum(_vmem_bytes(*b) for b in blocks)
+        + sum(_vmem_bytes(*b) for b in scratch)
+        + temps
+    )
+    if est <= DEFAULT_SCOPED_VMEM * 3 // 4:
+        return None
+    return min(est * 3 // 2, MAX_SCOPED_VMEM)
+
+
+def _params(limit, **kwargs):
+    if limit is not None:
+        kwargs["vmem_limit_bytes"] = limit
+    return pltpu.CompilerParams(**kwargs)
 
 
 def _bwd_pipeline() -> bool:
@@ -465,16 +507,18 @@ def _flash_forward(
         jax.ShapeDtypeStruct((H, T, D), q.dtype),
         jax.ShapeDtypeStruct((H, T // block_q, block_q, 1), jnp.float32),
     ]
-    # big score tiles ([n_rep*block_q, block_k] f32) can exceed the default
-    # scoped-vmem budget; raise it (v5e VMEM is 128 MB)
-    tile_bytes = (
-        2 * n_rep * block_q * block_k * 4
-        + sum(4 * s.shape[0] * s.shape[1] for s in scratch_shapes)
-    )
-    compiler_params = _compiler_params(
-        **({"vmem_limit_bytes": min(tile_bytes + 48 * 2**20, 114 * 2**20)}
-           if tile_bytes > 24 * 2**20 or block_q >= 2048 else {})
-    )
+    compiler_params = _params(_vmem_limit(
+        blocks=[
+            ((1, block_q), jnp.int32), ((1, block_k), jnp.int32),
+            ((n_rep, block_q, D), q.dtype),            # q
+            ((block_k, D), k.dtype), ((block_k, D), v.dtype),
+            ((n_rep, block_q, D), q.dtype),            # out
+            ((n_rep, block_q, 1), jnp.float32),        # lse column
+        ],
+        scratch=[(s.shape, s.dtype) for s in scratch_shapes],
+        # s2 and p: [n_rep*block_q, block_k] f32 each
+        temps=2 * n_rep * block_q * block_k * 4,
+    ))
 
     if max_seqlen is None:
         # no static band: enumerate the causal triangle's block pairs
@@ -971,24 +1015,8 @@ def _flash_backward(
     # itself won't fit VMEM (extreme context lengths).
     dkv_scr_bytes = 2 * T * D * 4
     if dkv_scr_bytes <= FUSED_BWD_MAX_DQ_BYTES:
-        # estimated scoped need: whole-T dk/dv scratch + the rep-folded
-        # f32 score/ds tiles (x4: s2, p, ds + slack). Leave the compiler's
-        # default budget alone for small shapes (raising it measurably
-        # hurt short-context throughput).
-        # raise only when the default 16 MB budget cannot fit (raising it
-        # when unnecessary measurably hurt short-context throughput —
-        # ~7% on the 1B/512-packed shape, chip-measured r3+r4)
         pipeline = _bwd_pipeline()
         rows = n_rep * block_q
-        est = dkv_scr_bytes + 4 * n_rep * block_q * block_k * 4
-        if pipeline:  # parked p/ds tiles + k block copy
-            est += rows * block_k * (
-                do.dtype.itemsize + q.dtype.itemsize
-            ) + block_k * D * k.dtype.itemsize
-        limit = (
-            min(est + 40 * 2**20, 114 * 2**20)  # 114 MB = max scoped limit
-            if est > 14 * 2**20 else None
-        )
         out_shapes = [
             jax.ShapeDtypeStruct((Hkv, T, D), k.dtype),
             jax.ShapeDtypeStruct((Hkv, T, D), v.dtype),
@@ -1006,6 +1034,24 @@ def _flash_backward(
                 pltpu.VMEM((block_k, D), k.dtype),       # parked k block
                 pltpu.SMEM((2,), jnp.int32),             # [col, valid]
             ]
+        limit = _vmem_limit(
+            blocks=[
+                ((1, block_q), jnp.int32), ((1, block_k), jnp.int32),
+                ((n_rep, block_q, 1), jnp.float32),    # lse column
+                ((n_rep, block_q, 1), jnp.float32),    # delta column
+                ((n_rep, block_q, D), q.dtype),        # q
+                ((block_k, D), k.dtype), ((block_k, D), v.dtype),
+                ((n_rep, block_q, D), do.dtype),       # do
+                ((T, D), k.dtype), ((T, D), v.dtype),  # whole-T dk, dv
+                ((n_rep, block_q, D), q.dtype),        # dq
+            ],
+            scratch=[
+                (s.shape, s.dtype) for s in scratch_shapes
+                if s.memory_space == pltpu.MemorySpace.VMEM
+            ],
+            # s2, p, ds + slack: [n_rep*block_q, block_k] f32 each
+            temps=4 * rows * block_k * 4,
+        )
         kv_whole = pl.BlockSpec(
             (1, T, D), lambda *idx: (idx[0], 0, 0)
         )
@@ -1059,9 +1105,8 @@ def _flash_backward(
                     scratch_shapes=scratch_shapes,
                 ),
                 out_shape=out_shapes,
-                compiler_params=_compiler_params(
-                    dimension_semantics=("parallel", "arbitrary"),
-                    **({"vmem_limit_bytes": limit} if limit else {}),
+                compiler_params=_params(
+                    limit, dimension_semantics=("parallel", "arbitrary"),
                 ),
                 interpret=_interpret(),
             )(
@@ -1120,9 +1165,9 @@ def _flash_backward(
                 scratch_shapes=scratch_shapes,
             ),
             out_shape=out_shapes,
-            compiler_params=_compiler_params(
+            compiler_params=_params(
+                limit,
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-                **({"vmem_limit_bytes": limit} if limit else {}),
             ),
             interpret=_interpret(),
         )(kstart, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
@@ -1134,6 +1179,17 @@ def _flash_backward(
             jnp.minimum(ks[i] + j, _last_k(i, block_q, block_k)),
             0,
         )
+
+    # split sweeps (whole-T dk/dv scratch does not fit): both kernels
+    # stream the same q-side blocks and hold the same p/ds tiles
+    q_side_blocks = [
+        ((1, block_q), jnp.int32), ((1, block_k), jnp.int32),
+        ((n_rep, block_q, 1), jnp.float32),    # lse column
+        ((n_rep, block_q, 1), jnp.float32),    # delta column
+        ((n_rep, block_q, D), q.dtype),        # q
+        ((n_rep, block_q, D), do.dtype),       # do
+    ]
+    split_temps = 4 * n_rep * block_q * block_k * 4
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **common, n_rep=n_rep),
@@ -1175,12 +1231,14 @@ def _flash_backward(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((H, T, D), q.dtype),
-        # split-backward p/ds tiles need the same scoped-vmem raise as the
-        # forward at big (rep-folded) blocks
-        compiler_params=_compiler_params(
-            **({"vmem_limit_bytes": 100 * 2**20}
-               if n_rep * block_q >= 2048 else {})
-        ),
+        compiler_params=_params(_vmem_limit(
+            blocks=q_side_blocks + [
+                ((block_k, D), k.dtype), ((block_k, D), v.dtype),
+                ((n_rep, block_q, D), q.dtype),        # dq
+            ],
+            scratch=[((n_rep * block_q, D), jnp.float32)],
+            temps=split_temps,
+        )),
         interpret=_interpret(),
     )(kstart, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
 
@@ -1231,10 +1289,14 @@ def _flash_backward(
             jax.ShapeDtypeStruct((Hkv, T, D), k.dtype),
             jax.ShapeDtypeStruct((Hkv, T, D), v.dtype),
         ],
-        compiler_params=_compiler_params(
-            **({"vmem_limit_bytes": 100 * 2**20}
-               if block_k >= 2048 or n_rep * block_q >= 2048 else {})
-        ),
+        compiler_params=_params(_vmem_limit(
+            blocks=q_side_blocks + [
+                ((block_k, D), k.dtype), ((block_k, D), v.dtype),
+                ((block_k, D), k.dtype), ((block_k, D), v.dtype),  # dk, dv
+            ],
+            scratch=[((block_k, D), jnp.float32)] * 2,
+            temps=split_temps,
+        )),
         interpret=_interpret(),
     )(qlast, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
     return dq, dk, dv

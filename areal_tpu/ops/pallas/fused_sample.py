@@ -33,8 +33,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.ops.pallas import compat
-from areal_tpu.ops.pallas.compat import compiler_params as _compiler_params
 
 NEG_INF = -2.3819763e38
 LANES = 128
@@ -122,7 +120,11 @@ def _kernel(
     h = h ^ (h >> 13)
     h = h * np.uint32(0xC2B2AE35)
     h = h ^ (h >> 16)
-    u = ((h >> 8).astype(jnp.float32) + 0.5) * (1.0 / (1 << 24))
+    # the TPU lowering has no uint32 -> float32 cast; the top 24 bits fit
+    # an int32 exactly
+    u = ((h >> 8).astype(jnp.int32).astype(jnp.float32) + 0.5) * (
+        1.0 / (1 << 24)
+    )
     pert = warped - jnp.log(-jnp.log(u))
     pert = jnp.where(cols == excl_ref[:, :1], NEG_INF, pert)
     pbv, pbi = _first_max_idx(pert, cols, valid)
@@ -175,12 +177,6 @@ def fused_sample_pallas(
     ``ops/fused_sample.py`` (minus top-k, which the dispatch never routes
     here). The PRNG seed derives from ``rng`` on device — no host
     round-trip rides the dispatch."""
-    if not compat.compiler_params_available():
-        raise RuntimeError(
-            "pallas fused sample unavailable: the installed jax lacks "
-            "CompilerParams/TPUCompilerParams — use the XLA epilogue "
-            "(use_pallas=False)"
-        )
     R, E = x.shape
     V = w.shape[1]
     block_v = max(LANES, min(block_v, -(-V // LANES) * LANES))
@@ -239,7 +235,7 @@ def fused_sample_pallas(
             jax.ShapeDtypeStruct((R, LANES), jnp.float32),  # gathered_lp
             jax.ShapeDtypeStruct((R, LANES), jnp.float32),  # norm
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=(
                 # resident x + one head block (double-buffered) + row state

@@ -33,16 +33,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.ops.pallas import compat
-from areal_tpu.ops.pallas.compat import ANY_MEMORY_SPACE as _ANY_MEMORY_SPACE
-from areal_tpu.ops.pallas.compat import compiler_params as _compiler_params
-
 NEG_INF = -2.3819763e38
 LANES = 128
 
 
 def _interpret() -> bool:
     return jax.devices()[0].platform != "tpu"
+
+
+def page_multiple(pool_dtype) -> int:
+    """What the page size must be a multiple of for the compiled kernel —
+    the ONE rule both this module's check and the auto-dispatch gate
+    (``ops/paged_attention.py``) apply. A bf16/f32 page is a sublane dim
+    (tile of 8). An int8 pool also DMAs a ``[..., Hkv, page]`` f32 scale
+    stripe whose LAST dim is the page, and Mosaic wants a slice along the
+    lane dim aligned to 128 ("Slice shape along dimension 4 must be
+    aligned to tiling (128)") — so int8 pools with smaller pages take the
+    XLA gather path."""
+    return LANES if jnp.dtype(pool_dtype) == jnp.int8 else 8
 
 
 def _decode_kernel(
@@ -313,23 +321,12 @@ def decode(
     scratch and dequant fuses into the dots — the HBM read stays int8
     (half the KV bytes of bf16 + a 1/D scale overhead), values widen only
     in-register."""
-    if _ANY_MEMORY_SPACE is None or not compat.compiler_params_available():
-        # fail loudly at the boundary, not deep inside the kernel build:
-        # the pool ref must stay in ANY/HBM, and the double-buffered page
-        # scratch NEEDS the vmem_limit_bytes raise (silently dropping it
-        # would die in the XLA compile with a scoped-vmem error)
-        raise RuntimeError(
-            "pallas paged decode unavailable: the installed jax lacks "
-            "pltpu MemorySpace/TPUMemorySpace or CompilerParams/"
-            "TPUCompilerParams — use the XLA gather path "
-            "(use_pallas=False)"
-        )
     B, Hq, D = q.shape
     L, P, _, Hkv, page, _ = pages.shape
     M = table.shape[1]
     n_rep = Hq // Hkv
     quantized = scales is not None
-    page_mult = 32 if quantized else 8  # int8 sublane tile is 32
+    page_mult = page_multiple(pages.dtype)
     if not _interpret() and (D % 128 != 0 or page % page_mult != 0):
         raise ValueError(
             f"paged kernel needs head_dim%128==0 and page%{page_mult}==0 "
@@ -371,7 +368,7 @@ def decode(
         pl.BlockSpec((sb, Hq, D), lambda b, j, ly, t, l: (b, 0, 0)),
         pl.BlockSpec((sb, Hkv, D), lambda b, j, ly, t, l: (b, 0, 0)),
         pl.BlockSpec((sb, Hkv, D), lambda b, j, ly, t, l: (b, 0, 0)),
-        pl.BlockSpec(memory_space=_ANY_MEMORY_SPACE),
+        pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     scratch_shapes = [
         pltpu.VMEM((2, sb, 2, Hkv, kp * page, D), pages.dtype),
@@ -388,7 +385,7 @@ def decode(
     if quantized:
         # scales ride whole in ANY/HBM like the pool; their scratch and
         # semaphores slot in right after their KV twins (kernel ref order)
-        in_specs.append(pl.BlockSpec(memory_space=_ANY_MEMORY_SPACE))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY))
         scratch_shapes.insert(
             1, pltpu.VMEM((2, sb, 2, Hkv, kp * page), jnp.float32)
         )
@@ -409,7 +406,7 @@ def decode(
         # the double-buffered page scratch alone can exceed the 16 MB
         # default scoped-vmem budget; size the limit from the actual
         # scratch + generous op margin (v5e VMEM is 128 MB)
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_scratch_bytes(sb) + 32 * 2**20,
         ),
         interpret=_interpret(),

@@ -147,7 +147,6 @@ def ring_attention(
     shard_map re-partitions over ``axis_name``. Differentiable end-to-end
     (the backward ring is autodiff through ppermute).
     """
-    from jax.experimental.shard_map import shard_map
 
     T, H, Dh = q.shape
     cp = mesh.shape[axis_name]
@@ -180,10 +179,10 @@ def ring_attention(
     head_ax = "model" if (m > 1 and H % m == 0 and k.shape[1] % m == 0) else None
     spec_t = P(axis_name)
     spec_qkv = P(axis_name, head_ax, None)
-    return shard_map(
+    return jax.shard_map(
         ring_body,
         mesh=mesh,
         in_specs=(spec_qkv, spec_qkv, spec_qkv, spec_t),
         out_specs=spec_qkv,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, segment_ids)

@@ -63,10 +63,9 @@ from areal_tpu.parallel import multihost
 
 logger = logging.getLogger("areal_tpu.elastic")
 
-# Effectively-disabled heartbeat cadence for the coordination service and
+# Effectively-disabled heartbeat timeout for the coordination service and
 # clients (fact 1 above): failure detection is ours, not theirs.
-_HEARTBEAT_INTERVAL_S = 3600
-_MAX_MISSING_HEARTBEATS = 100000
+_HEARTBEAT_TIMEOUT_S = 10 * 365 * 24 * 3600
 
 # Strong references to previous epochs' distributed-runtime objects
 # (fact 2 above). Never cleared during the process lifetime.
@@ -104,7 +103,7 @@ _LOCAL_ERROR_MARKERS = ("RESOURCE_EXHAUSTED", "INVALID_ARGUMENT")
 def as_world_failure(err: BaseException) -> Optional[WorldFailureError]:
     """Classify an exception as a world failure, or None.
 
-    ``WorldFailureError`` passes through; an ``XlaRuntimeError`` (the gloo
+    ``WorldFailureError`` passes through; a ``JaxRuntimeError`` (the gloo
     transport erroring the instant a dead peer's sockets reset — the FAST
     detection path — or a device collective failing mid-step) and plain
     ``ConnectionError``s wrap into :class:`CollectiveFailedError` —
@@ -113,7 +112,9 @@ def as_world_failure(err: BaseException) -> Optional[WorldFailureError]:
     bug), return None and must propagate unchanged."""
     if isinstance(err, WorldFailureError):
         return err
-    if "XlaRuntimeError" in type(err).__name__:
+    import jax  # deferred: elastic is importable without a backend
+
+    if isinstance(err, jax.errors.JaxRuntimeError):
         msg = str(err)
         if any(m in msg for m in _LOCAL_ERROR_MARKERS):
             return None
@@ -571,16 +572,18 @@ class WorldEpochManager:
         import jax  # deferred: elastic is importable without a backend
 
         from jax._src import distributed as jdist
-        from jax._src.lib import xla_extension as xe
+        from jax._src.lib import _jax as xe
 
         st = jdist.global_state
         client = xe.get_distributed_runtime_client(
             ws.coordinator, self.cfg.process_id,
             init_timeout=int(self.cfg.init_timeout_s),
-            heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-            max_missing_heartbeats=_MAX_MISSING_HEARTBEATS,
+            heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
             shutdown_on_destruction=False,
             use_compression=True,
+            # a rank that dies must not take the survivors with it: the
+            # service does not propagate a recoverable task's failure
+            recoverable=True,
         )
         client.connect()
         st.client = client
@@ -667,12 +670,11 @@ def host_service(port: int, num_processes: int):
     ``LOG(FATAL)`` cascade of module-docstring fact 2). Old epochs'
     services stay parked next to the clients; ports leak one per
     reformation, bounded by the reform budget."""
-    from jax._src.lib import xla_extension as xe
+    from jax._src.lib import _jax as xe
 
     service = xe.get_distributed_runtime_service(
         f"[::]:{port}", num_processes,
-        heartbeat_interval=_HEARTBEAT_INTERVAL_S,
-        max_missing_heartbeats=_MAX_MISSING_HEARTBEATS,
+        heartbeat_timeout=_HEARTBEAT_TIMEOUT_S,
         shutdown_timeout=5,
     )
     _parked.append(service)

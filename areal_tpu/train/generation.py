@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from areal_tpu.api.model import GenerationHyperparameters
 from areal_tpu.gen.sampling import SamplingParams, sample_tokens
 from areal_tpu.models import transformer as tfm
+from areal_tpu.ops import attention as attn_ops
 
 
 def _next_pow2(n: int, lo: int = 64) -> int:
@@ -115,7 +116,9 @@ class SyncGenerator:
             return out_t, out_lp, n_gen, ~stopped  # never hit EOS => truncated
 
         jitted = jax.jit(
-            gen,
+            # the prefill's flash kernel needs shard_map under a
+            # multi-device mesh
+            attn_ops.trace_on_mesh(self.engine.mesh, gen),
             in_shardings=(
                 self.engine._param_shardings,
                 self._batch_sharding,   # input_ids

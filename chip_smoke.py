@@ -1,0 +1,1016 @@
+#!/usr/bin/env python3
+"""Chip smoke: the trainer, the serving stack and the RL loop on one TPU chip
+at Qwen2.5-1.5B widths, through the entry points a user would call.
+
+    python chip_smoke.py            # one chip: device, train, serve, rl
+    python chip_smoke.py --chips 4  # four chips: sharded sft + async-ppo world
+
+The quickest proof that the system still starts on the chip. It claims no
+speed: every time it prints is a smoke observation, not a benchmark result.
+
+Contract (the builder's instructions, "The chip check"):
+
+- the parent never imports JAX; each phase that needs the chip is ONE child
+  process, run in turn (a chip belongs to one process at a time). All of
+  them share one persistent compile cache, placed by the program's own
+  ``areal_tpu.base.compile_cache.configure()``;
+- weights and data come from ``--seed``; nothing needs the network;
+- every phase prints one JSON object; the LAST line of stdout is
+  ``{"ok": ..., "device": {"platform", "kind", "count"}}`` and nothing more;
+- no CPU fallback: anything but a TPU fails the first phase, and any failed
+  phase makes the exit code non-zero with ``"ok": false``;
+- kernels are checked from OUTSIDE the program: the children run under JAX's
+  own ``JAX_DUMP_IR_TO`` (the lowered program of every jit, written whether
+  or not the compile cache hits) and ``JAX_LOG_COMPILES`` (compile seconds).
+  A train step or decode chunk whose program has no ``tpu_custom_call``
+  carrying the kernel's name ran the XLA path or the interpreter, and fails.
+
+``--rehearse`` runs the same control flow at a toy size on whatever device
+JAX finds (the CPU in the sandbox), skips the device and kernel checks, and
+ALWAYS exits non-zero with ``"ok": false``: it debugs this script, it proves
+nothing about the chip.
+"""
+
+import argparse
+import concurrent.futures
+import glob
+import json
+import math
+import os
+import re
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import time
+import urllib.request
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+WORK = os.path.join(ROOT, ".smoke_work")  # git-ignored scratch of this script
+
+# R1-Distill-Qwen-1.5B / Qwen2.5-1.5B widths (bench.py `_gen_model_cfg`)
+ARCH_1P5B = dict(
+    n_layers=28, n_q_heads=12, n_kv_heads=2, head_dim=128, hidden_dim=1536,
+    intermediate_dim=8960, vocab_size=151936, use_attention_bias=True,
+    dtype="bfloat16",
+)
+ARCH_TOY = dict(
+    n_layers=2, n_q_heads=4, n_kv_heads=2, head_dim=8, hidden_dim=32,
+    intermediate_dim=64, vocab_size=256, use_attention_bias=True,
+    dtype="float32",
+)
+# Training memory on a 16 GB chip: bf16 params + bf16 Adam moments are
+# 9.9 GiB at full depth. With remat_policy="dots_attn" (bench.py's setting)
+# the step's compiler-reported need is 18.6 GiB at 28 layers (refused) and
+# fits only from 20 layers down; whole-layer remat ("full") peaks at
+# 15.0 GiB of 15.75 (AOT compile for a described v5e, PR 21). Depth is kept,
+# the remat policy gives way.
+TRAIN_OVERRIDES = dict(
+    remat_policy="full", loss_chunk_size=2048, attn_max_seqlen=512
+)
+# Served logprobs (bf16, paged, incremental) are held to a float32 dense
+# recompute within twice what bf16 alone costs the dense forward on the
+# same sequences, plus this floor (nats). A fixed 0.05 was the first guess;
+# the chip showed 0.09 between two correct bf16 paths at 28 layers.
+LOGPROB_FLOOR = 0.02
+
+
+class PhaseFailed(Exception):
+    def __init__(self, msg, device=None):
+        super().__init__(msg)
+        self.device = device  # what JAX found, when the device phase failed
+
+
+def emit(obj):
+    print(json.dumps(obj), flush=True)
+
+
+def require(cond, msg, device=None):
+    if not cond:
+        raise PhaseFailed(msg, device)
+
+
+def free_port():
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def child_env(phase_dir, extra=None):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_DUMP_IR_TO"] = os.path.join(phase_dir, "ir")
+    env["JAX_LOG_COMPILES"] = "1"
+    env["TPU_STDERR_LOG_LEVEL"] = env.get("TPU_STDERR_LOG_LEVEL", "2")
+    env.update(extra or {})
+    return env
+
+
+def run_child(phase_dir, name, argv, timeout, extra_env=None):
+    """Run one child to its end; stdout/stderr go to files of the phase."""
+    os.makedirs(phase_dir, exist_ok=True)
+    out_p = os.path.join(phase_dir, f"{name}.out")
+    err_p = os.path.join(phase_dir, f"{name}.err")
+    t0 = time.time()
+    with open(out_p, "w") as out, open(err_p, "w") as err:
+        try:
+            rc = subprocess.run(
+                argv, cwd=ROOT, env=child_env(phase_dir, extra_env),
+                stdout=out, stderr=err, timeout=timeout,
+            ).returncode
+        except subprocess.TimeoutExpired:
+            rc = 124
+    return rc, out_p, err_p, time.time() - t0
+
+
+def tail(path, n=25):
+    try:
+        with open(path, errors="replace") as f:
+            return "".join(f.readlines()[-n:])
+    except OSError:
+        return ""
+
+
+def last_json_line(path):
+    with open(path) as f:
+        lines = [l for l in f.read().splitlines() if l.startswith("{")]
+    require(lines, f"no JSON line in {path}")
+    return json.loads(lines[-1])
+
+
+def helper(phase_dir, name, payload, timeout=900):
+    """Run one of this file's own `--child` bodies in a fresh process."""
+    os.makedirs(phase_dir, exist_ok=True)
+    arg = os.path.join(phase_dir, f"{name}.in.json")
+    with open(arg, "w") as f:
+        json.dump(payload, f)
+    rc, out_p, err_p, secs = run_child(
+        phase_dir, name,
+        [sys.executable, os.path.abspath(__file__), "--child", name, arg],
+        timeout,
+    )
+    require(rc == 0, f"{name} child rc={rc}: {tail(err_p)}")
+    return last_json_line(out_p), secs
+
+
+_COMPILE_RE = re.compile(
+    r"Finished XLA compilation of (jit\([^)]*\)) in ([0-9.eE+-]+) sec"
+)
+
+
+def compile_seconds(*err_paths):
+    """Sum of JAX's own per-program compile (or cache-load) seconds."""
+    seen = set()
+    for p in err_paths:
+        try:
+            with open(p, errors="replace") as f:
+                seen.update(_COMPILE_RE.findall(f.read()))
+        except OSError:
+            pass
+    return round(sum(float(s) for _, s in seen), 2), len(seen)
+
+
+def ir_programs(phase_dir, jit_name):
+    return sorted(glob.glob(
+        os.path.join(phase_dir, "ir", f"*_jit_{jit_name}_compile.mlir")
+    ))
+
+
+def kernels_in(paths):
+    """Pallas kernels lowered for the TPU compiler in these programs: an
+    interpreted kernel or an XLA-path dispatch leaves none."""
+    names = set()
+    for p in paths:
+        with open(p, errors="replace") as f:
+            text = f.read()
+        if "tpu_custom_call" in text:
+            names.update(re.findall(r'kernel_name = "([^"]+)"', text))
+    return sorted(names)
+
+
+def read_metrics(fileroot, exp, trial="t0"):
+    p = os.path.join(fileroot, "logs", exp, trial, "metrics.jsonl")
+    require(os.path.exists(p), f"no metrics at {p}")
+    with open(p) as f:
+        return [json.loads(l) for l in f if l.strip()]
+
+
+def step_seconds(lines):
+    ts = [l["time"] for l in lines]
+    return [round(b - a, 3) for a, b in zip(ts, ts[1:])]
+
+
+def save_failure_logs():
+    """The tails of every child's stdout/stderr, where the chip tool brings
+    them back from (``chiprun_out/`` is all that survives its machine)."""
+    out = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out, exist_ok=True)
+    with open(os.path.join(out, "chip_smoke_failure.txt"), "w") as f:
+        for p in sorted(glob.glob(os.path.join(WORK, "**", "*.err"), recursive=True)
+                        + glob.glob(os.path.join(WORK, "**", "*.out"), recursive=True)):
+            f.write(f"\n===== {os.path.relpath(p, WORK)} =====\n{tail(p, 150)}")
+
+
+def overrides_json(d):
+    return json.dumps(d, separators=(",", ":"))
+
+
+# --------------------------------------------------------------------------- #
+# sizes
+# --------------------------------------------------------------------------- #
+
+
+def sizes(rehearse):
+    if rehearse:
+        return dict(
+            arch=ARCH_TOY, param_dtype="float32", seq=32, n_seq=8,
+            prompt=8, train_steps=5, lr=1e-3,
+            train_overrides=dict(remat_policy="full", loss_chunk_size=16),
+            slots=8, serve_seqlen=128, n_requests=8, serve_prompt=8,
+            new_tokens=16,
+            rl_prompts=2, rl_group=2, rl_prompt=8, rl_new=8, rl_layers=2,
+            world_layers=2,
+        )
+    return dict(
+        arch=ARCH_1P5B, param_dtype="bfloat16", seq=512, n_seq=8,
+        prompt=128, train_steps=5, lr=1e-4,
+        train_overrides=TRAIN_OVERRIDES,
+        slots=16, serve_seqlen=512, n_requests=16, serve_prompt=32,
+        new_tokens=64,
+        rl_prompts=4, rl_group=4, rl_prompt=64, rl_new=64, rl_layers=28,
+        world_layers=8,
+    )
+
+
+def rng_ids(seed, n, vocab):
+    """Seeded token ids without numpy's import cost mattering: a small LCG
+    is enough for synthetic prompts (ids in [1, vocab))."""
+    x = (seed * 6364136223846793005 + 1442695040888963407) % (1 << 64)
+    out = []
+    for _ in range(n):
+        x = (x * 6364136223846793005 + 1442695040888963407) % (1 << 64)
+        out.append(1 + (x >> 33) % (vocab - 1))
+    return out
+
+
+# --------------------------------------------------------------------------- #
+# phases
+# --------------------------------------------------------------------------- #
+
+
+def phase_device(sz, args, want_chips):
+    d = os.path.join(WORK, "device")
+    info, secs = helper(d, "device", {"rehearse": args.rehearse})
+    dev = {k: info[k] for k in ("platform", "kind", "count")}
+    emit({"phase": "device", **info, "seconds": round(secs, 1)})
+    if not args.rehearse:
+        require(dev["platform"] == "tpu",
+                f"JAX found {dev['platform']!r}, not a TPU", dev)
+        require(dev["count"] >= want_chips,
+                f"need {want_chips} chip(s), JAX sees {dev['count']}")
+        require(info["memory_stats"],
+                "device.memory_stats() reported nothing on the chip")
+    require(info["packer"] == "native", "native packer did not build/load")
+    return dev
+
+
+def write_sft_data(path, sz, seed):
+    vocab = sz["arch"]["vocab_size"]
+    with open(path, "w") as f:
+        for i in range(sz["n_seq"]):
+            ids = rng_ids(seed * 1000 + i, sz["seq"], vocab)
+            f.write(json.dumps({
+                "qid": f"s{i}",
+                "prompt_ids": ids[: sz["prompt"]],
+                "answer_ids": ids[sz["prompt"]:],
+            }) + "\n")
+
+
+def sft_argv(sz, fileroot, data, exp, parallel="d1m1", arch=None):
+    return [
+        sys.executable, "-m", "areal_tpu.apps.main", "sft",
+        f"experiment_name={exp}", "trial_name=t0", f"fileroot={fileroot}",
+        f"dataset.path={data}", "dataset.name=prompt_answer",
+        f"batch_size={sz['n_seq']}",
+        f"max_tokens_per_mb={sz['n_seq'] * sz['seq']}",
+        f"control.total_train_steps={sz['train_steps']}",
+        f"model.arch={overrides_json(arch or sz['arch'])}",
+        f"model.overrides={overrides_json(sz['train_overrides'])}",
+        f"model.parallel={parallel}",
+        f"model.param_dtype={sz['param_dtype']}",
+        f"model.optimizer.lr={sz['lr']}",
+    ]
+
+
+def check_train_run(sz, args, d, fileroot, exp, err_p, jit_name="train_step"):
+    """Shared by the one-chip train phase and the four-chip comparison."""
+    lines = read_metrics(fileroot, exp)
+    require(len(lines) == sz["train_steps"],
+            f"{len(lines)} steps logged, wanted {sz['train_steps']}")
+    losses = [l["sft/loss"] for l in lines]
+    require(all(math.isfinite(x) for x in losses), f"loss not finite: {losses}")
+    # step 1 runs at lr 0 (warm-up), so steps 1 and 2 see the same weights;
+    # every step sees the same 8 sequences, so the loss has to fall
+    require(lines[0]["sft/lr"] == 0.0, "first step was not the lr-0 no-op")
+    require(losses[-1] < losses[0] - 1e-3,
+            f"loss did not move after step 1: {losses}")
+    programs = ir_programs(d, jit_name)
+    require(len(programs) == 1,
+            f"train step was lowered {len(programs)} times (recompile)")
+    kernels = kernels_in(programs)
+    if not args.rehearse:
+        require(any("fwd_kernel" in k for k in kernels)
+                and any("bwd_kernel" in k for k in kernels),
+                f"compiled train step has no flash kernel: {kernels}")
+        require("sft/hbm_peak_bytes_in_use" in lines[-1],
+                "trainer logged no memory_stats gauges")
+    err = tail(err_p, 400)
+    require("native packer" not in err, "packer fell back to numpy")
+    csec, n_prog = compile_seconds(err_p)
+    return {
+        "losses": [round(x, 4) for x in losses],
+        "lr": [l["sft/lr"] for l in lines],
+        "step_seconds": step_seconds(lines),
+        "tflops_per_sec_logged": [
+            round(l["sft/tflops_per_sec"], 2) for l in lines
+        ],
+        "compile_seconds": csec,
+        "programs_compiled": n_prog,
+        "train_step_lowerings": len(programs),
+        "kernels": kernels,
+        "packer": "native",
+        "peak_hbm_gib": round(
+            lines[-1].get("sft/hbm_peak_bytes_in_use", 0) / 2**30, 2
+        ),
+        "hbm_in_use_gib": round(
+            lines[-1].get("sft/hbm_bytes_in_use", 0) / 2**30, 2
+        ),
+    }
+
+
+def phase_train(sz, args):
+    d = os.path.join(WORK, "train")
+    os.makedirs(d, exist_ok=True)
+    data = os.path.join(d, "sft.jsonl")
+    write_sft_data(data, sz, args.seed)
+    fileroot = os.path.join(d, "root")
+    rc, _, err_p, secs = run_child(
+        d, "sft", sft_argv(sz, fileroot, data, "smoke-sft"), timeout=1000
+    )
+    require(rc == 0, f"sft rc={rc}: {tail(err_p)}")
+    out = check_train_run(sz, args, d, fileroot, "smoke-sft", err_p)
+    emit({
+        "phase": "train", "entry": "python -m areal_tpu.apps.main sft",
+        "arch": sz["arch"], "overrides": sz["train_overrides"],
+        "param_dtype": sz["param_dtype"],
+        "tokens_per_step": sz["n_seq"] * sz["seq"],
+        "depth_cut": None, **out, "seconds": round(secs, 1),
+    })
+
+
+def http_json(url, body=None, timeout=600):
+    req = urllib.request.Request(
+        url, data=None if body is None else json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.status, json.loads(r.read())
+
+
+def completion(base, prompt, max_tokens, stream):
+    """One /v1/completions call; returns (status, text, usage|None)."""
+    body = {"prompt": prompt, "max_tokens": max_tokens, "temperature": 0.0,
+            "stream": stream}
+    req = urllib.request.Request(
+        base + "/v1/completions", data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=600) as r:
+        if not stream:
+            d = json.loads(r.read())
+            return r.status, d["choices"][0]["text"], d["usage"]
+        text, done = "", False
+        for raw in r:
+            line = raw.decode().strip()
+            if not line.startswith("data: "):
+                continue
+            if line == "data: [DONE]":
+                done = True
+                break
+            frame = json.loads(line[6:])
+            require("error" not in frame, f"SSE error frame: {frame}")
+            text += frame["choices"][0].get("text", "")
+        require(done, "SSE stream ended without [DONE]")
+        return r.status, text, None
+
+
+def ids_of(text):
+    # the seeded tokenizer spells token i as "t<i>"
+    return [int(w[1:]) for w in text.split()]
+
+
+def wait_for_line(proc, path, needle, timeout):
+    t0 = time.time()
+    while time.time() - t0 < timeout:
+        with open(path, errors="replace") as f:
+            for line in f:
+                if needle in line:
+                    return line
+        require(proc.poll() is None,
+                f"server exited rc={proc.returncode} before {needle!r}")
+        time.sleep(0.5)
+    raise PhaseFailed(f"no {needle!r} within {timeout}s")
+
+
+def stop(proc, grace=60):
+    if proc.poll() is None:
+        proc.send_signal(signal.SIGINT)
+        try:
+            proc.wait(grace)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+            proc.wait(30)
+
+
+def phase_serve(sz, args):
+    d = os.path.join(WORK, "serve")
+    os.makedirs(d, exist_ok=True)
+    ckpt = os.path.join(d, "ckpt")
+    t0 = time.time()
+    made, ckpt_secs = helper(
+        d, "ckpt", {"arch": sz["arch"], "seed": args.seed, "path": ckpt}
+    )
+    port = free_port()
+    out_p, err_p = os.path.join(d, "gateway.out"), os.path.join(d, "gateway.err")
+    vocab = sz["arch"]["vocab_size"]
+    n_pairs = sz["n_requests"] // 2
+    prompts = [
+        rng_ids(args.seed * 77 + i, sz["serve_prompt"], vocab)
+        for i in range(n_pairs)
+    ]
+    with open(out_p, "w") as out, open(err_p, "w") as err:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "areal_tpu.gateway", "--model-path", ckpt,
+             "--port", str(port), "--slots", str(sz["slots"]),
+             "--max-seqlen", str(sz["serve_seqlen"])],
+            cwd=ROOT, env=child_env(d), stdout=out, stderr=err,
+        )
+    try:
+        line = wait_for_line(proc, out_p, "gateway listening on", 900)
+        ready_secs = time.time() - t0 - ckpt_secs
+        backend = re.search(r"\(backend (http://[^)]+)\)", line).group(1)
+        base = f"http://127.0.0.1:{port}"
+        def wave():
+            """Every prompt twice at once: buffered and SSE, all greedy."""
+            t = time.time()
+            with concurrent.futures.ThreadPoolExecutor(sz["n_requests"]) as ex:
+                futs = [
+                    ex.submit(completion, base, p, sz["new_tokens"], stream)
+                    for p in prompts for stream in (False, True)
+                ]
+                return [f.result() for f in futs], time.time() - t
+
+        results, first_wave = wave()     # carries the compiles
+        again, second_wave = wave()
+        require(all(r[0] == 200 for r in results + again), "a request != 200")
+        outs = [ids_of(r[1]) for r in results]
+        require(all(len(o) == sz["new_tokens"] for o in outs),
+                f"token counts {[len(o) for o in outs]} != {sz['new_tokens']}")
+        for r in results[0::2]:
+            require(r[2]["completion_tokens"] == sz["new_tokens"]
+                    and r[2]["prompt_tokens"] == sz["serve_prompt"],
+                    f"usage wrong: {r[2]}")
+        # Token-exact agreement between two greedy runs of one prompt is an
+        # observation, not a check: at bf16 a random-weight model's top
+        # logits are near-ties, and the two runs differ in admit bucket,
+        # slot and prefix-cache state. What is CHECKED, below, is that every
+        # greedy token is the dense reference's argmax to within tolerance.
+        pairs_equal = sum(outs[2 * i] == outs[2 * i + 1] for i in range(n_pairs))
+        waves_equal = sum(ids_of(r[1]) == o for r, o in zip(again, outs))
+        # the gateway's OpenAI surface returns no logprobs ("logprobs":
+        # null); the gen server's own /generate — what RL rollout workers
+        # call — does. One sampled request (greedy logprobs are those of a
+        # temperature-0 distribution, i.e. ~0) for the recompute below.
+        st, sampled = http_json(backend + "/generate", {
+            "rid": "smoke-lp", "input_ids": prompts[0],
+            "sampling_params": {"max_new_tokens": sz["new_tokens"],
+                                "temperature": 1.0},
+        })
+        require(st == 200 and len(sampled["output_ids"]) == sz["new_tokens"],
+                f"/generate: {st} {len(sampled.get('output_ids', []))} tokens")
+        _, metrics = http_json(backend + "/metrics_json")
+    finally:
+        stop(proc)
+    require(proc.returncode == 0, f"gateway exit rc={proc.returncode}")
+    chunks = ir_programs(d, "chunk")
+    kernels = kernels_in(chunks)
+    if not args.rehearse:
+        require("_decode_kernel" in kernels,
+                f"decode chunk has no Pallas paged kernel: {kernels}")
+        require("hbm_peak_bytes_in_use" in metrics,
+                "gen server reported no memory_stats gauges")
+    # the server has given the chip back: dense recompute in its own child
+    ref, ref_secs = helper(d, "recompute", {
+        "ckpt": ckpt,
+        "seqs": [{"tokens": prompts[0] + sampled["output_ids"]}] + [
+            {"tokens": prompts[i // 2] + o} for i, o in enumerate(outs)
+        ],
+        "n_prompt": sz["serve_prompt"],
+    })
+    # yardstick: how far the served dtype alone moves a logprob of the
+    # DENSE forward against float32, over the same sequences
+    yard = max(
+        abs(a - b) for s in ref["seqs"]
+        for a, b in zip(s["lp_served_dtype"], s["lp"])
+    )
+    tol = 2 * yard + LOGPROB_FLOOR
+    diffs = [
+        abs(a - b)
+        for a, b in zip(sampled["output_logprobs"], ref["seqs"][0]["lp"])
+    ]
+    lp_diff = max(diffs)
+    greedy_gap = max(
+        m - l for s in ref["seqs"][1:] for m, l in zip(s["lp_max"], s["lp"])
+    )
+    require(lp_diff <= tol,
+            f"served logprobs differ from the float32 dense recompute by "
+            f"{lp_diff} (mean {sum(diffs) / len(diffs)}); tolerance {tol} "
+            f"= 2 x dense {sz['arch']['dtype']} error {yard} + {LOGPROB_FLOOR}")
+    require(greedy_gap <= tol,
+            f"a greedy token is {greedy_gap} nats below the float32 dense "
+            f"argmax; tolerance {tol}")
+    csec, n_prog = compile_seconds(err_p)
+    emit({
+        "phase": "serve", "entry": "python -m areal_tpu.gateway --model-path",
+        "arch": sz["arch"], "depth_cut": None,
+        "requests": 2 * sz["n_requests"] + 1,
+        "concurrent": sz["n_requests"], "new_tokens": sz["new_tokens"],
+        "buffered_eq_sse_pairs": f"{pairs_equal} of {n_pairs}",
+        "second_wave_eq_first": f"{waves_equal} of {len(outs)}",
+        "first_wave_seconds": round(first_wave, 2),
+        "second_wave_seconds": round(second_wave, 2),
+        "logprob_max_abs_diff_vs_dense_f32": round(lp_diff, 5),
+        "logprob_mean_abs_diff_vs_dense_f32": round(
+            sum(diffs) / len(diffs), 5),
+        "greedy_max_gap_vs_dense_f32_argmax": round(greedy_gap, 5),
+        "dense_served_dtype_vs_f32_max_abs": round(yard, 5),
+        "logprob_tolerance": round(tol, 5),
+        "logprob_tolerance_rule": "2 x (dense forward in the served dtype "
+        f"vs float32, max abs) + {LOGPROB_FLOOR} nats",
+        "decode_chunk_lowerings": len(chunks), "kernels": kernels,
+        "kv_dtype": metrics.get("kv_dtype"),
+        "fused_sample": metrics.get("fused_sample"),
+        "peak_hbm_gib": round(
+            metrics.get("hbm_peak_bytes_in_use", 0) / 2**30, 2),
+        "checkpoint_gib": round(made["bytes"] / 2**30, 2),
+        "checkpoint_seconds": round(ckpt_secs, 1),
+        "server_ready_seconds": round(ready_secs, 1),
+        "compile_seconds": csec, "programs_compiled": n_prog,
+        "recompute_seconds": round(ref_secs, 1),
+        "seconds": round(time.time() - t0, 1),
+    })
+
+
+def write_prompt_data(path, n, plen, vocab, seed):
+    with open(path, "w") as f:
+        for i in range(n):
+            f.write(json.dumps({
+                "query_id": f"q{i}",
+                "prompt_ids": rng_ids(seed * 31 + i, plen, vocab),
+                "task": "math", "solutions": ["\\boxed{7}"],
+            }) + "\n")
+
+
+def ppo_overrides(sz):
+    return [
+        f'gconfig={{"n": {sz["rl_group"]}, "max_new_tokens": {sz["rl_new"]}}}',
+        'ppo={"ppo_n_minibatches": 1, "disable_value": true,'
+        ' "group_adv_norm": true, "adv_norm": false, "kl_ctl": 0.0,'
+        ' "use_decoupled_loss": true, "recompute_logprob": true,'
+        f' "group_size": {sz["rl_group"]}}}',
+        "use_ref_model=false",
+    ]
+
+
+def phase_rl(sz, args):
+    d = os.path.join(WORK, "rl")
+    os.makedirs(d, exist_ok=True)
+    arch = dict(sz["arch"], n_layers=sz["rl_layers"])
+    data = os.path.join(d, "prompts.jsonl")
+    write_prompt_data(data, sz["rl_prompts"], sz["rl_prompt"],
+                      arch["vocab_size"], args.seed)
+    fileroot = os.path.join(d, "root")
+    tok_dir = os.path.join(d, "tokenizer")
+    helper(d, "tokenizer", {"path": tok_dir, "vocab_size": arch["vocab_size"]})
+    rounds = 2
+    overrides = {k: v for k, v in sz["train_overrides"].items()
+                 if k != "attn_max_seqlen"}
+    rc, _, err_p, secs = run_child(d, "sync_ppo", [
+        sys.executable, "-m", "areal_tpu.apps.main", "sync-ppo",
+        "experiment_name=smoke-rl", "trial_name=t0", f"fileroot={fileroot}",
+        f"dataset.path={data}", f"batch_size={sz['rl_prompts']}",
+        f"tokenizer_path={tok_dir}",
+        "max_tokens_per_mb=4096", f"control.total_train_steps={rounds}",
+        f"actor.arch={overrides_json(arch)}",
+        f"actor.overrides={overrides_json(overrides)}",
+        f"actor.param_dtype={sz['param_dtype']}",
+        f"actor.optimizer.lr={sz['lr']}",
+        *ppo_overrides(sz),
+    ], timeout=1000)
+    require(rc == 0, f"sync-ppo rc={rc}: {tail(err_p)}")
+    lines = read_metrics(fileroot, "smoke-rl")
+    require(len(lines) == rounds, f"{len(lines)} rounds logged")
+    n_seqs = sz["rl_prompts"] * sz["rl_group"]
+    for l in lines:
+        require(math.isfinite(l["sync_ppo/actor_loss"]),
+                f"actor loss not finite: {l}")
+        require(l["sync_ppo/n_seqs_consumed"] == n_seqs,
+                f"consumed {l['sync_ppo/n_seqs_consumed']} != {n_seqs}")
+    # the verifier really graded: some rollouts right, some wrong, so the
+    # group-normalised advantages are not all zero and the step has a
+    # gradient
+    require(any(-1.0 < l["sync_ppo/reward_mean"] < 1.0 for l in lines),
+            f"rewards degenerate: {[l['sync_ppo/reward_mean'] for l in lines]}")
+    require(any(l["sync_ppo/grad_norm"] > 0 for l in lines),
+            "no PPO step had a gradient")
+    steps = ir_programs(d, "train_step")
+    require(len(steps) == 1, f"PPO step lowered {len(steps)} times")
+    kernels = kernels_in(glob.glob(os.path.join(d, "ir", "*.mlir")))
+    if not args.rehearse:
+        require(any("fwd_kernel" in k for k in kernels_in(steps)),
+                f"PPO train step has no flash kernel: {kernels_in(steps)}")
+    csec, n_prog = compile_seconds(err_p)
+    emit({
+        "phase": "rl", "entry": "python -m areal_tpu.apps.main sync-ppo",
+        "arch": arch,
+        "depth_cut": (None if arch["n_layers"] == sz["arch"]["n_layers"]
+                      else f"{arch['n_layers']} of {sz['arch']['n_layers']}"),
+        "rounds": rounds, "seqs_per_round": n_seqs,
+        "new_tokens": sz["rl_new"],
+        "actor_loss": [l["sync_ppo/actor_loss"] for l in lines],
+        "reward_mean": [l["sync_ppo/reward_mean"] for l in lines],
+        "grad_norm": [round(l["sync_ppo/grad_norm"], 4) for l in lines],
+        "gen_seconds": [round(l["sync_ppo/timeperf/gen"], 2) for l in lines],
+        "round_seconds": [round(l["sync_ppo/timeperf/e2e"], 2) for l in lines],
+        "train_step_lowerings": len(steps), "kernels": kernels,
+        "compile_seconds": csec, "programs_compiled": n_prog,
+        "seconds": round(secs, 1),
+    })
+
+
+# --------------------------------------------------------------------------- #
+# --chips 4
+# --------------------------------------------------------------------------- #
+
+# sharded vs one-chip per-step loss (bf16, another reduction order)
+LOSS_TOL_REL = 0.02
+
+
+def hlo_entry_param_bytes(dump_dir, module_re):
+    """Per-device bytes of the compiled program's entry parameters, read
+    from XLA's after-optimizations text dump; plus its collectives."""
+    item = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1, "f16": 2,
+            "s8": 1, "u8": 1, "s64": 8, "u64": 8}
+    best = None
+    for p in glob.glob(os.path.join(dump_dir, "*after_optimizations.txt")):
+        if re.search(module_re, os.path.basename(p)):
+            if best is None or os.path.getsize(p) > os.path.getsize(best):
+                best = p
+    require(best, f"no XLA dump matching {module_re} in {dump_dir}")
+    with open(best, errors="replace") as f:
+        text = f.read()
+    entry = text[text.index("ENTRY"):]
+    total = 0
+    for dt, dims in re.findall(
+        r"= (\w+)\[([\d,]*)\][^ ]* parameter\(\d+\)", entry
+    ):
+        n = 1
+        for x in filter(None, dims.split(",")):
+            n *= int(x)
+        total += n * item.get(dt, 4)
+    coll = {
+        k: len(re.findall(rf"\b{k}(?:-start)?\(", text))
+        for k in ("all-reduce", "all-gather", "reduce-scatter",
+                  "collective-permute", "all-to-all")
+    }
+    return total, coll
+
+
+def phase_sharded_sft(sz, args):
+    d = os.path.join(WORK, "sharded")
+    os.makedirs(d, exist_ok=True)
+    data = os.path.join(d, "sft.jsonl")
+    write_sft_data(data, sz, args.seed)
+    runs = {}
+    for tag, par in (("four_chips", "d1f2m2"), ("one_chip", "d1m1")):
+        rd = os.path.join(d, tag)
+        fileroot = os.path.join(rd, "root")
+        dump = os.path.join(rd, "xla")
+        # the compile cache is off here so that XLA really compiles and its
+        # text dump (the only place collectives show) exists
+        rc, _, err_p, secs = run_child(
+            rd, "sft", sft_argv(sz, fileroot, data, f"smoke-{tag}", par),
+            timeout=1200,
+            extra_env={
+                "JAX_ENABLE_COMPILATION_CACHE": "false",
+                "XLA_FLAGS": (
+                    os.environ.get("XLA_FLAGS", "")
+                    + f" --xla_dump_to={dump} --xla_dump_hlo_as_text"
+                    " --xla_dump_hlo_module_re=.*train_step.*"
+                ).strip(),
+            },
+        )
+        require(rc == 0, f"sft {par} rc={rc}: {tail(err_p)}")
+        out = check_train_run(sz, args, rd, fileroot, f"smoke-{tag}", err_p)
+        out["param_bytes_per_device"], out["collectives"] = (
+            hlo_entry_param_bytes(dump, "train_step")
+        )
+        out["seconds"] = round(secs, 1)
+        runs[tag] = out
+    four, one = runs["four_chips"], runs["one_chip"]
+    rel = max(
+        abs(a - b) / max(abs(b), 1e-9)
+        for a, b in zip(four["losses"], one["losses"])
+    )
+    require(rel <= LOSS_TOL_REL,
+            f"sharded vs one-chip losses differ by {rel}: "
+            f"{four['losses']} vs {one['losses']}")
+    require(sum(four["collectives"].values()) > 0,
+            "four-chip step has no collectives")
+    require(sum(one["collectives"].values()) == 0,
+            "one-chip step has collectives")
+    ratio = four["param_bytes_per_device"] / one["param_bytes_per_device"]
+    require(ratio < 0.5, f"per-device argument bytes ratio {ratio}: "
+            "sharded leaves are not sharded")
+    if not args.rehearse:
+        require(four["hbm_in_use_gib"] < 0.5 * one["hbm_in_use_gib"],
+                "device 0 holds as much on four chips as on one")
+    emit({
+        "phase": "sharded_sft", "entry": "python -m areal_tpu.apps.main sft",
+        "arch": sz["arch"], "depth_cut": None, "parallel": "d1f2m2 vs d1m1",
+        "loss_max_rel_diff": round(rel, 5), "loss_tolerance_rel": LOSS_TOL_REL,
+        "per_device_param_bytes_ratio": round(ratio, 3),
+        "four_chips": four, "one_chip": one,
+    })
+
+
+def phase_async_world(sz, args):
+    d = os.path.join(WORK, "world")
+    os.makedirs(d, exist_ok=True)
+    arch = dict(sz["arch"], n_layers=sz["world_layers"])
+    data = os.path.join(d, "prompts.jsonl")
+    n_prompts = 8
+    write_prompt_data(data, n_prompts, sz["rl_prompt"], arch["vocab_size"],
+                      args.seed)
+    fileroot = os.path.join(d, "root")
+    steps = 3
+    overrides = {k: v for k, v in sz["train_overrides"].items()
+                 if k != "attn_max_seqlen"}
+    cpu = ["gen.device=cpu", "trainer_device=cpu"] if args.rehearse else []
+    rc, out_p, err_p, secs = run_child(d, "async_ppo", [
+        sys.executable, "-m", "areal_tpu.apps.main", "async-ppo",
+        "experiment_name=smoke-world", "trial_name=t0",
+        f"fileroot={fileroot}", f"dataset.path={data}",
+        f"train_batch_size={sz['rl_prompts']}", "max_tokens_per_mb=4096",
+        f"control.total_train_steps={steps}",
+        "control.ckpt_freq_steps=null", "control.ckpt_freq_secs=null",
+        f"actor.arch={overrides_json(arch)}",
+        f"actor.overrides={overrides_json(overrides)}",
+        "actor.parallel=d1f2m1", f"actor.param_dtype={sz['param_dtype']}",
+        f"actor.optimizer.lr={sz['lr']}",
+        "gen.n_servers=1", "gen.tp_size=2", f"gen.max_slots={sz['slots']}",
+        f"gen.max_seqlen={sz['serve_seqlen']}",
+        f"gen.max_new_tokens_cap={sz['rl_new']}",
+        "rollout.n_workers=1",
+        f"rollout.max_concurrent_tasks={sz['slots']}",
+        f"rollout.new_tokens_per_chunk={sz['rl_new']}",
+        "manager.max_head_offpolicyness=4", "recover_mode=disabled",
+        *ppo_overrides(sz), *cpu,
+    ], timeout=1500)
+    require(rc == 0, f"async-ppo rc={rc}: {tail(err_p)}\n{tail(out_p)}")
+    lines = read_metrics(fileroot, "smoke-world")
+    require(len(lines) == steps, f"{len(lines)} train steps logged")
+    require(all(math.isfinite(l["ppo/actor_loss"]) for l in lines),
+            "actor loss not finite")
+    owners = []
+    with open(out_p, errors="replace") as f:
+        for line in f:
+            if line.startswith('{"areal_devices"'):
+                owners.append(json.loads(line)["areal_devices"])
+    require({o["role"] for o in owners} == {"gen_server/0", "trainer"},
+            f"chip owners announced: {[o['role'] for o in owners]}")
+    if not args.rehearse:
+        sets = [set(o["visible_chips"] or ()) for o in owners]
+        require(all(len(s) == 2 for s in sets) and not (sets[0] & sets[1]),
+                f"chip sets not disjoint pairs: {owners}")
+        require(all(o["platform"] == "tpu" and len(o["ids"]) == 2
+                    for o in owners), f"owners do not see 2 TPU chips: {owners}")
+    gs = os.path.join(fileroot, "logs", "smoke-world", "t0",
+                      "gen_server_0.json")
+    require(os.path.exists(gs), "gen server dumped no metrics")
+    with open(gs) as f:
+        g = json.load(f)
+    require(g["version"] > 1,
+            f"gen server weight version stayed at {g['version']}")
+    emit({
+        "phase": "async_ppo_world",
+        "entry": "python -m areal_tpu.apps.main async-ppo",
+        "arch": arch,
+        "depth_cut": f"{arch['n_layers']} of {sz['arch']['n_layers']} layers",
+        "layout": "gen server tp=2 on 2 chips, trainer d1f2m1 on 2 chips, "
+                  "manager + rollout worker on the CPU",
+        "owners": owners, "train_steps": steps,
+        "actor_loss": [l["ppo/actor_loss"] for l in lines],
+        "gen_server_version": g["version"],
+        "n_weight_updates": g.get("n_weight_updates"),
+        "gen_tokens": g.get("gen_tokens"),
+        "seconds": round(secs, 1),
+    })
+
+
+# --------------------------------------------------------------------------- #
+# children (fresh processes; the only code here that imports JAX)
+# --------------------------------------------------------------------------- #
+
+
+def child_device(arg):
+    from areal_tpu.base import compile_cache
+
+    cache_dir = compile_cache.configure()
+    import jax
+
+    devs = jax.devices()
+    info = {
+        "platform": devs[0].platform, "kind": devs[0].device_kind,
+        "count": len(devs), "memory_stats": None, "packer": None,
+        "jax": jax.__version__, "compile_cache_dir": cache_dir,
+    }
+    if devs[0].platform == "tpu" or arg["rehearse"]:
+        from areal_tpu import native
+        from areal_tpu.base import hbm
+
+        # the packer is built from packer.cpp here, never loaded from a
+        # file git would not commit
+        for so in glob.glob(
+            os.path.join(ROOT, "areal_tpu", "native", "_packer*.so")
+        ):
+            os.unlink(so)
+        info["packer"] = "native" if native.available() else "numpy"
+        info["memory_stats"] = hbm.device_memory_stats(devs[0])
+    emit(info)
+
+
+def write_tokenizer(path, vocab_size, spell):
+    """A seeded word-level tokenizer (no download): token i is spelled
+    ``spell(i)``, and decoding joins spellings with spaces."""
+    from tokenizers import Tokenizer, models, pre_tokenizers
+    from transformers import PreTrainedTokenizerFast
+
+    tok = Tokenizer(models.WordLevel(
+        {spell(i): i for i in range(vocab_size)}, unk_token=spell(0)
+    ))
+    tok.pre_tokenizer = pre_tokenizers.WhitespaceSplit()
+    PreTrainedTokenizerFast(tokenizer_object=tok).save_pretrained(path)
+
+
+def child_tokenizer(arg):
+    # For the RL loop's verifier: every token spells a boxed answer, 7 for
+    # even ids and 8 for odd ones, so a rollout is "correct" (the dataset's
+    # solution is \\boxed{7}) iff its LAST token is even — about half of
+    # them, which gives GRPO groups rewards that differ.
+    os.makedirs(arg["path"], exist_ok=True)
+    write_tokenizer(arg["path"], arg["vocab_size"],
+                    lambda i: f"\\boxed{{{7 + i % 2}}}#{i}")
+    emit({"path": arg["path"]})
+
+
+def child_ckpt(arg):
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+
+    from areal_tpu.models import hf as hf_conv
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(**arg["arch"])
+    params = tfm.init_params(cfg, jax.random.key(arg["seed"]), dtype=cfg.dtype)
+    shutil.rmtree(arg["path"], ignore_errors=True)
+    hf_conv.save_hf_checkpoint(params, cfg, "qwen2", arg["path"])
+    # so the text surface of /v1/completions carries exact token ids:
+    # token i is spelled "t<i>"
+    write_tokenizer(arg["path"], cfg.vocab_size, lambda i: f"t{i}")
+    emit({"bytes": os.path.getsize(
+        os.path.join(arg["path"], "model.safetensors"))})
+
+
+def child_recompute(arg):
+    """The plain reference: XLA dense attention over the whole sequence,
+    once in the served dtype and once in float32 at full matmul precision.
+    The second is the truth; the first says how far bf16 alone moves a
+    logprob at this depth — the yardstick the served values are held to."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.models import hf as hf_conv
+    from areal_tpu.models import transformer as tfm
+
+    cfg, host = hf_conv.load_hf_checkpoint(arg["ckpt"])
+    cfg = dataclasses.replace(cfg, use_flash_attention=False)
+    n_prompt = arg["n_prompt"]
+
+    def run(dtype):
+        c = dataclasses.replace(cfg, dtype=dtype)
+        params = jax.tree.map(lambda x: jnp.asarray(x, dtype), host)
+
+        @jax.jit
+        def logprobs(params, ids):  # params as an ARGUMENT: closed over,
+            T = ids.shape[0]        # the weights become program constants
+            logits = tfm.forward_packed(
+                params, c, ids, jnp.ones((T,), jnp.int32),
+                jnp.arange(T, dtype=jnp.int32), remat=False,
+            )
+            lp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            nxt = jnp.take_along_axis(lp[:-1], ids[1:, None], axis=-1)[:, 0]
+            return nxt, jnp.max(lp[:-1], axis=-1)
+
+        out = []
+        with jax.default_matmul_precision("float32"):
+            for s in arg["seqs"]:
+                lp, lp_max = logprobs(
+                    params, jnp.asarray(s["tokens"], jnp.int32)
+                )
+                # position t predicts token t+1: generated tokens start at
+                # n_prompt
+                out.append((
+                    [float(x) for x in lp[n_prompt - 1:]],
+                    [float(x) for x in lp_max[n_prompt - 1:]],
+                ))
+        return out
+
+    served_dtype = run(cfg.dtype)
+    f32 = run("float32")
+    emit({"seqs": [
+        {"lp_served_dtype": a[0], "lp": b[0], "lp_max": b[1]}
+        for a, b in zip(served_dtype, f32)
+    ]})
+
+
+CHILDREN = {
+    "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
+    "tokenizer": child_tokenizer,
+}
+
+
+# --------------------------------------------------------------------------- #
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="toy sizes on any device; always exits non-zero")
+    ap.add_argument("--child", nargs=2, metavar=("NAME", "ARGFILE"),
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.child:
+        with open(args.child[1]) as f:
+            CHILDREN[args.child[0]](json.load(f))
+        return 0
+
+    shutil.rmtree(WORK, ignore_errors=True)
+    os.makedirs(WORK)
+    sz = sizes(args.rehearse)
+    phases = (
+        [phase_train, phase_serve, phase_rl] if args.chips == 1
+        else [phase_sharded_sft, phase_async_world]
+    )
+    device = {"platform": None, "kind": None, "count": 0}
+    ok = True
+    try:
+        device = phase_device(sz, args, args.chips)
+        for phase in phases:
+            phase(sz, args)
+    except Exception as e:  # any failed phase fails the smoke, loudly
+        ok = False
+        device = getattr(e, "device", None) or device
+        save_failure_logs()
+        emit({"phase": "failed", "error": f"{type(e).__name__}: {e}"[:4000]})
+    if args.rehearse:
+        emit({"ok": False, "rehearsal": True, "phases_passed": ok,
+              "device": device})
+        return 3
+    emit({"ok": ok, "device": device})
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -187,8 +187,7 @@ def test_as_world_failure_classification():
     original = elastic.CollectiveTimeoutError("t")
     assert elastic.as_world_failure(original) is original
 
-    class XlaRuntimeError(RuntimeError):  # matched by name, not import
-        pass
+    from jax.errors import JaxRuntimeError as XlaRuntimeError
 
     assert isinstance(
         elastic.as_world_failure(XlaRuntimeError("gloo died")),
@@ -303,8 +302,12 @@ def _stub_world(tmp_path, modes, **cfg_kw):
             ],
             poll_s=0.05,
             exit_grace_s=0.1,
-            collective_timeout_s=cfg_kw.pop("collective_timeout_s", 0.5),
-            report_grace_s=cfg_kw.pop("report_grace_s", 0.5),
+            # the wedge window (timeout + grace after the FIRST report)
+            # must outlast the spread in stub start-up under a loaded,
+            # six-worker test run: at 0.5 + 0.5 s a slow-starting survivor
+            # was SIGKILLed as wedged (rank_restarts == 2)
+            collective_timeout_s=cfg_kw.pop("collective_timeout_s", 2.0),
+            report_grace_s=cfg_kw.pop("report_grace_s", 2.0),
             reform_timeout_s=20.0,
             **cfg_kw,
         )

@@ -11,16 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from areal_tpu.ops.attention import _attention_xla
-from areal_tpu.ops.pallas import compat
 from areal_tpu.ops.pallas.flash_attention import packed_flash_attention
-
-# graceful degradation on jax API drift (docs/static_analysis.md PR 6):
-# skip — not fail deep inside a kernel build — when the installed jax
-# has neither CompilerParams spelling
-pytestmark = pytest.mark.skipif(
-    not compat.compiler_params_available(),
-    reason="installed jax lacks pltpu CompilerParams/TPUCompilerParams",
-)
 
 # These kernels run in interpret mode on CPU, which costs minutes for the
 # full parity sweep. Tier-1 keeps one representative per kernel feature
@@ -343,3 +334,63 @@ def test_flash_gradients_match_pipelined(rng, monkeypatch, gqa, banded):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4
         )
+
+
+def test_flash_under_a_mesh_runs_as_shard_map():
+    """GSPMD cannot partition a Mosaic kernel, so under a multi-device mesh
+    the flash dispatch wraps the kernel in shard_map over the head axis
+    (``ops/attention.flash_mesh``; first seen as "Mosaic kernels cannot be
+    automatically partitioned" from the d1f2m2 trainer on four chips).
+    Forward and gradients must match the XLA path, with batch rows vmapped
+    over the data axes the way the train engine does it."""
+    import functools
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from areal_tpu.ops import attention as attn_ops
+
+    mesh = Mesh(
+        np.array(jax.devices()[:4]).reshape(1, 2, 1, 2),
+        ("data", "fsdp", "ctx", "model"),
+    )
+    rows, T, H, Hkv, D = 2, 128, 4, 2, 16
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(rows, T, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(rows, T, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(rows, T, Hkv, D)), jnp.float32)
+    seg = jnp.asarray(
+        np.tile(np.r_[np.ones(70), 2 * np.ones(40), np.zeros(18)], (rows, 1)),
+        jnp.int32,
+    )
+    row_sh = NamedSharding(mesh, P(("data", "fsdp"), None, "model", None))
+    q, k, v = (jax.device_put(x, row_sh) for x in (q, k, v))
+    seg = jax.device_put(seg, NamedSharding(mesh, P(("data", "fsdp"), None)))
+
+    def loss(use_flash, q, k, v):
+        attn = functools.partial(
+            attn_ops.packed_attention, use_flash=use_flash,
+            flash_block_size=64, max_seqlen=128,
+        )
+        out = jax.vmap(attn, spmd_axis_name=("data", "fsdp"))(q, k, v, seg)
+        live = (seg > 0)[..., None, None]
+        return jnp.sum(jnp.where(live, out, 0.0) ** 2), out
+
+    def run(use_flash):
+        def f(q, k, v):
+            with attn_ops.flash_mesh(mesh):
+                return jax.value_and_grad(
+                    functools.partial(loss, use_flash), argnums=(0, 1, 2),
+                    has_aux=True,
+                )(q, k, v)
+        return jax.jit(f)(q, k, v)
+
+    (l_f, out_f), g_f = run(True)
+    (l_x, out_x), g_x = run(False)
+    live = np.asarray(seg > 0)[..., None, None]
+    np.testing.assert_allclose(
+        np.where(live, out_f, 0), np.where(live, out_x, 0), atol=2e-5
+    )
+    np.testing.assert_allclose(l_f, l_x, rtol=1e-5)
+    for a, b in zip(g_f, g_x):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)

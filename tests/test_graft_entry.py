@@ -1,7 +1,7 @@
 """Regression tests for the driver entry points (``__graft_entry__``).
 
 Round-1 threw away a whole round of multi-chip signal because
-``dryrun_multichip`` never forced the virtual CPU platform (VERDICT.md
+``dryrun_multichip`` never forced the virtual CPU platform (VERDICT r1
 "Next round" #1). These tests pin both entry points so they can't silently
 regress. Mirrors the reference's CPU-testability doctrine
 (``realhf/base/testing.py:48,137``).

@@ -32,7 +32,6 @@ from areal_tpu.gen.engine import GenerationEngine, GenRequest
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import paged_attention as xla_paged
-from areal_tpu.ops.pallas import compat
 
 CFG = ModelConfig(
     n_layers=2, n_q_heads=4, n_kv_heads=2, head_dim=8, hidden_dim=32,
@@ -166,11 +165,6 @@ class TestXLAPathParity:
             )
 
 
-@pytest.mark.skipif(
-    not (compat.compiler_params_available()
-         and compat.memory_space_available()),
-    reason="installed jax lacks pltpu CompilerParams or MemorySpace",
-)
 class TestPallasInt8Decode:
     """The kernel's in-register dequant (int8 page DMA + scale-stripe DMA,
     scales folded into the score/probability dots) vs the XLA int8 path.

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from areal_tpu.gen.engine import GenerationEngine, GenRequest
 from areal_tpu.gen.pages import OutOfPagesError, PagePool, PrefixRegistry
-from areal_tpu.ops.pallas import compat
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 
@@ -304,12 +303,6 @@ class TestThreadSafety:
         assert eng.pool.n_free == eng.n_pages
 
 
-@pytest.mark.skipif(
-    not (compat.compiler_params_available()
-         and compat.memory_space_available()),
-    reason="installed jax lacks pltpu CompilerParams or MemorySpace "
-    "under either spelling",
-)
 class TestPallasPagedDecode:
     """Pallas paged-decode kernel parity vs the XLA gather path (interpret
     mode on CPU; the same kernel runs compiled on TPU). Both paths take the

@@ -717,6 +717,9 @@ class TestChunkBoundarySync:
         ))
         eng.step(decode_steps=4)    # admit + first dispatch
         eng.step(decode_steps=4)    # warm both pipeline stages
+        # pace the warm-up's in-flight chunk too: the window's first
+        # resolve is of THAT chunk (CPU dispatch is asynchronous)
+        jax.block_until_ready((eng.state.lens, eng._prev_flags))
         metrics_mod.counters.clear(metrics_mod.GEN_CHUNK_FLAG_FETCHES)
         metrics_mod.counters.clear(metrics_mod.GEN_CHUNK_FLAG_BLOCKED)
         calls = []
@@ -729,8 +732,11 @@ class TestChunkBoundarySync:
         for _ in range(n_chunks):
             eng.step(decode_steps=4)
             # harness pacing only: wait out the in-flight chunk so the
-            # next resolve measures the protocol, not CPU scheduling
-            jax.block_until_ready(eng.state.lens)
+            # next resolve measures the protocol, not CPU scheduling.
+            # ALL of its outputs: on jax 0.9's CPU client the outputs of
+            # one execution turn ready one by one, so the state being
+            # ready does not make the flag tuple ready in the same instant
+            jax.block_until_ready((eng.state.lens, eng._prev_flags))
         assert calls == []          # the trace assertion: zero device_get
         assert metrics_mod.counters.get(
             metrics_mod.GEN_CHUNK_FLAG_FETCHES
@@ -757,10 +763,11 @@ class TestChunkBoundarySync:
         ))
         eng.step(decode_steps=2)
         eng.step(decode_steps=2)
+        jax.block_until_ready((eng.state.lens, eng._prev_flags))
         metrics_mod.counters.clear(metrics_mod.GEN_CHUNK_FLAG_BLOCKED)
         for _ in range(5):
             eng.step(decode_steps=2)
-            jax.block_until_ready(eng.state.lens)
+            jax.block_until_ready((eng.state.lens, eng._prev_flags))
         assert metrics_mod.counters.get(
             metrics_mod.GEN_CHUNK_FLAG_BLOCKED
         ) == 0

@@ -158,16 +158,8 @@ def test_no_recompile_across_rounds(rng, par):
             "program(s) at identical shapes"
         )
     finally:
-        # the public unregister name moved across jax versions; fall back to
-        # the by-callback private API so the listener never leaks into
-        # subsequent tests
-        unreg = getattr(
-            monitoring, "unregister_event_duration_listener",
-            getattr(
-                monitoring, "_unregister_event_duration_listener_by_callback",
-            ),
-        )
-        unreg(on_dur)
+        # never leak the listener into subsequent tests
+        monitoring.unregister_event_duration_listener(on_dur)
 
 
 def test_forward_unpacks_per_sequence(engine, rng):
