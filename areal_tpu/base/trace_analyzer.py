@@ -10,8 +10,13 @@ reader — no tensorflow/tensorboard dependency.
 Classification prefers the ``hlo_category`` stat the TPU op profiler
 attaches to each XLA-op event (e.g. "convolution", "all-reduce fusion",
 "copy"); name heuristics cover events without it (CPU traces, custom
-pallas calls). Idle = line span minus busy time on the op line — the
-device waiting on the host or on collectives-in-flight.
+pallas calls). Op events nest on a line (a ``while`` covers its body, a
+fusion its parts), so each event is charged its SELF time — its duration
+minus what its children cover — and busy time is the union of the
+intervals: the buckets add up to busy, never to more. Idle = line span
+minus busy — the device waiting on the host or on collectives-in-flight.
+Planes without a single op event (libtpu's empty "Megascale Trace"
+plane in a CPU trace) are skipped.
 
 CLI::
 
@@ -163,66 +168,95 @@ def analyze_xspace(path: str) -> List[TraceSummary]:
     return analyze_profile_data(_profile_data().from_file(path))
 
 
+def self_times_ns(events: List[Tuple[float, float]]) -> List[float]:
+    """Self time of each ``(start_ns, duration_ns)`` event of ONE line, in
+    the order given: its duration minus what the events lying inside it
+    cover (a child lies wholly inside its parent; a partial overlap is
+    clipped to the parent's end, so the self times of a line always add
+    up to the union of its intervals)."""
+    order = sorted(range(len(events)), key=lambda i: (events[i][0], -events[i][1]))
+    out = [0.0] * len(events)
+    stack: List[List[float]] = []   # [index, end]
+    for i in order:
+        start, dur = events[i]
+        while stack and stack[-1][1] <= start:
+            stack.pop()
+        end = start + dur
+        if stack:
+            end = min(end, stack[-1][1])
+            out[int(stack[-1][0])] -= end - start
+        out[i] += end - start
+        stack.append([i, end])
+    return out
+
+
+def _summarize_plane(plane, is_device: bool) -> TraceSummary:
+    buckets = {k: 0.0 for k in BUCKETS}
+    per_op: Dict[str, List] = {}
+    n_events = 0
+    span_lo, span_hi, busy = None, None, 0.0
+    for line in _op_lines(plane):
+        kept = []   # (name, bucket, start_ns, duration_ns)
+        for ev in line.events:
+            dur_ns = float(ev.duration_ns or 0.0)
+            name = ev.name
+            if dur_ns <= 0.0 or name.startswith(("end:", "$")):
+                continue
+            try:
+                stats = dict(ev.stats)
+            except Exception:
+                stats = {}
+            # device planes (TPU): every timed event is device work.
+            # CPU-fallback plane: the client threads mix compiler and
+            # dispatcher spans with op execution — only events stamped
+            # with an hlo_op stat are actual op work
+            if not is_device and "hlo_op" not in stats:
+                continue
+            cat = stats.get("hlo_category")
+            bucket = classify(name, cat if isinstance(cat, str) else None)
+            kept.append((name, bucket, float(ev.start_ns or 0.0), dur_ns))
+        selfs = self_times_ns([(t0, d) for _, _, t0, d in kept])
+        for (name, bucket, t0, dur_ns), self_ns in zip(kept, selfs):
+            buckets[bucket] += self_ns / 1e9
+            busy += self_ns / 1e9
+            n_events += 1
+            span_lo = t0 if span_lo is None else min(span_lo, t0)
+            span_hi = (
+                t0 + dur_ns if span_hi is None else max(span_hi, t0 + dur_ns)
+            )
+            rec = per_op.setdefault(name, [0.0, 0, bucket])
+            rec[0] += self_ns / 1e9
+            rec[1] += 1
+    if span_lo is not None:
+        buckets[IDLE] = max((span_hi - span_lo) / 1e9 - busy, 0.0)
+    top = sorted(
+        ((n, s, c, b) for n, (s, c, b) in per_op.items()),
+        key=lambda t: -t[1],
+    )[:50]
+    return TraceSummary(
+        device_total_s=busy + buckets[IDLE],
+        buckets_s=buckets,
+        top_ops=top,
+        n_events=n_events,
+        plane=plane.name,
+    )
+
+
 def analyze_profile_data(pd) -> List[TraceSummary]:
     planes = list(pd.planes)
-    device_planes = [p for p in planes if _is_device_plane(p.name)]
-    if not device_planes:
-        # CPU fallback: the XLA client threadpool plane holds the op events
-        device_planes = [
-            p for p in planes
-            if any("pjrtcpuclient" in ln.name.lower() for ln in p.lines)
-        ]
-    out = []
-    for plane in device_planes:
-        is_device = _is_device_plane(plane.name)
-        buckets = {k: 0.0 for k in BUCKETS}
-        per_op: Dict[str, List] = {}
-        n_events = 0
-        span_lo, span_hi, busy = None, None, 0.0
-        for line in _op_lines(plane):
-            for ev in line.events:
-                dur = (ev.duration_ns or 0.0) / 1e9
-                name = ev.name
-                if dur <= 0.0 or name.startswith(("end:", "$")):
-                    continue
-                try:
-                    stats = dict(ev.stats)
-                except Exception:
-                    stats = {}
-                # device planes (TPU): every timed event is device work.
-                # CPU-fallback plane: the client threads mix compiler and
-                # dispatcher spans with op execution — only events stamped
-                # with an hlo_op stat are actual op work
-                if not is_device and "hlo_op" not in stats:
-                    continue
-                cat = stats.get("hlo_category")
-                bucket = classify(name, cat if isinstance(cat, str) else None)
-                buckets[bucket] += dur
-                busy += dur
-                n_events += 1
-                t0 = float(ev.start_ns or 0.0)
-                span_lo = t0 if span_lo is None else min(span_lo, t0)
-                span_hi = (
-                    t0 + dur * 1e9 if span_hi is None
-                    else max(span_hi, t0 + dur * 1e9)
-                )
-                rec = per_op.setdefault(name, [0.0, 0, bucket])
-                rec[0] += dur
-                rec[1] += 1
-        if span_lo is not None:
-            buckets[IDLE] = max((span_hi - span_lo) / 1e9 - busy, 0.0)
-        top = sorted(
-            ((n, s, c, b) for n, (s, c, b) in per_op.items()),
-            key=lambda t: -t[1],
-        )[:50]
-        out.append(TraceSummary(
-            device_total_s=busy + buckets[IDLE],
-            buckets_s=buckets,
-            top_ops=top,
-            n_events=n_events,
-            plane=plane.name,
-        ))
-    return out
+
+    def with_ops(chosen, is_device: bool) -> List[TraceSummary]:
+        summaries = (_summarize_plane(p, is_device) for p in chosen)
+        return [s for s in summaries if s.n_events]
+
+    # CPU fallback: the XLA client threadpool plane holds the op events
+    return with_ops(
+        [p for p in planes if _is_device_plane(p.name)], True
+    ) or with_ops(
+        [p for p in planes
+         if any("pjrtcpuclient" in ln.name.lower() for ln in p.lines)],
+        False,
+    )
 
 
 def find_xplane_files(root: str) -> List[str]:

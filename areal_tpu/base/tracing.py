@@ -11,7 +11,12 @@ reference's ``REAL_DUMP_TRACE`` torch-profiler gating
 (``realhf/system/model_worker.py:79-94,828-909``).
 
 **Span plane** (always on unless ``AREAL_TRACE_SPANS=0``): every
-:func:`span` carries a W3C-traceparent-style identity —
+:func:`span` is also a ``jax.profiler.TraceAnnotation`` named
+``PROFILER_PREFIX + name`` (``areal/gen_engine/admit``), so in ANY
+profiler session — ``maybe_trace``, the benchmark's, an operator's
+``jax.profiler.start_trace`` — the program's spans sit on the host plane
+of the xplane, on the same clock as the device's ops. Every span carries
+a W3C-traceparent-style identity —
 
     ``00-<32-hex trace id>-<16-hex span id>-01``
 
@@ -36,6 +41,7 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -71,6 +77,11 @@ _qid: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 _flush_lock = threading.Lock()
+
+# What a span is called in a profiler trace: this prefix + its name. One
+# constant, so a reader picks the program's events out of the host plane
+# by prefix (benchmark/program_spans.py) and nothing else there changes.
+PROFILER_PREFIX = "areal/"
 
 
 def live_spans() -> List[Dict[str, object]]:
@@ -116,17 +127,16 @@ def trace_step() -> int:
     return constants.trace_step()
 
 
-@contextlib.contextmanager
 def annotate(name: str):
-    """Named region inside an active trace (per-MFC attribution in the
-    executor; free when no trace is being collected)."""
-    if not trace_enabled():
-        yield
-        return
-    import jax
-
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    """Named region in whatever profiler session is running (per-MFC
+    attribution in the executor; every :func:`span`). Outside a session a
+    ``TraceAnnotation`` is a flag check. A process that never imported
+    jax has no profiler to annotate for, and a span must not be what
+    imports it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 # --------------------------------------------------------------------- #
@@ -258,6 +268,9 @@ def _record_end(
         "span_id": rec["span_id"],
         "parent_id": rec["parent_id"],
         "start": wall_end - dur,
+        # the same instant on time.perf_counter(): what in-process readers
+        # (spans_since) window by; wall-clock `start` is for tracejoin
+        "t0": rec["t0"],
         "dur_s": dur,
         "thread": rec["thread"],
         "pid": os.getpid(),
@@ -284,24 +297,21 @@ def _record_end(
 def span(name: str, **attrs):
     """Data-plane span: always accumulates host wall time into
     ``metrics.counters`` under ``<name>_s`` (plus a ``<name>_n`` call
-    count), and additionally shows up as a named region when a profiler
-    trace is active (a ``time.perf_counter`` pair is ~100 ns — free
-    against any stage it wraps).
+    count).
 
-    With the span plane on (default), the span also joins the active
-    distributed trace — child of the context's current span, or the root
-    of a fresh trace — and its completion is recorded into the bounded
-    ring *including exception exits*: a span whose body raises is
-    stamped ``error=True`` with the exception type, never lost. Keyword
-    ``attrs`` (plus any riding qid) land in the record for tracejoin /
-    obs ``--trace`` to render. Yields the mutable attrs dict so a body
-    can add attributes discovered mid-span."""
-    enabled = spans_enabled()
-    if not enabled and not trace_enabled():
-        # counters-only fast path (AREAL_TRACE_SPANS=0, no profiler trace
-        # active): a clock read and two counter adds — no live-span
-        # registration, no ring record. The bench ``tracing`` section
-        # holds this path to vs_baseline ≈ 1.0 on the serving loop.
+    With the span plane on (default), the span is also a profiler
+    annotation named ``PROFILER_PREFIX + name`` (see :func:`annotate`),
+    and it joins the active distributed trace — child of the context's
+    current span, or the root of a fresh trace — and its completion is
+    recorded into the bounded ring *including exception exits*: a span
+    whose body raises is stamped ``error=True`` with the exception type,
+    never lost. Keyword ``attrs`` (plus any riding qid) land in the
+    record for tracejoin / obs ``--trace`` to render. Yields the mutable
+    attrs dict so a body can add attributes discovered mid-span."""
+    if not spans_enabled():
+        # counters-only path (AREAL_TRACE_SPANS=0): a clock read and two
+        # counter adds — no annotation, no live-span registration, no
+        # ring record.
         t0 = time.perf_counter()
         try:
             yield attrs
@@ -311,24 +321,22 @@ def span(name: str, **attrs):
             metrics_mod.counters.add(f"{name}_n", 1.0)
         return
     t0 = time.perf_counter()
+    c = _ctx.get()
     rec = {
         "name": name, "t0": t0, "thread": threading.current_thread().name,
+        "trace_id": c[0] if c else new_trace_id(),
+        "parent_id": (c[1] or None) if c else None,
+        "span_id": new_span_id(),
     }
-    ctx_tok = None
-    if enabled:
-        c = _ctx.get()
-        rec["trace_id"] = c[0] if c else new_trace_id()
-        rec["parent_id"] = (c[1] or None) if c else None
-        rec["span_id"] = new_span_id()
-        ctx_tok = _ctx.set((rec["trace_id"], rec["span_id"]))
-        q = _qid.get()
-        if q is not None:
-            attrs.setdefault("qid", q)
+    ctx_tok = _ctx.set((rec["trace_id"], rec["span_id"]))
+    q = _qid.get()
+    if q is not None:
+        attrs.setdefault("qid", q)
     with _live_lock:
         _live.append(rec)
     exc: Optional[BaseException] = None
     try:
-        with annotate(name):
+        with annotate(PROFILER_PREFIX + name):
             yield attrs
     except BaseException as e:  # noqa: BLE001 — stamped + re-raised
         exc = e
@@ -339,13 +347,11 @@ def span(name: str, **attrs):
                 _live.remove(rec)
             except ValueError:
                 pass
-        if ctx_tok is not None:
-            _ctx.reset(ctx_tok)
+        _ctx.reset(ctx_tok)
         dt = time.perf_counter() - t0
         metrics_mod.counters.add(f"{name}_s", dt)
         metrics_mod.counters.add(f"{name}_n", 1.0)
-        if enabled:
-            _record_end(rec, time.time(), dt, exc, attrs)
+        _record_end(rec, time.time(), dt, exc, attrs)
 
 
 # --------------------------------------------------------------------- #
@@ -359,6 +365,20 @@ def drain() -> List[dict]:
         out = list(_ring)
         _ring.clear()
     return out
+
+
+def spans_since(t0: float, t1: Optional[float] = None) -> List[dict]:
+    """The completed spans still in the ring whose monotonic start
+    (``rec["t0"]``, ``time.perf_counter`` seconds) lies in ``[t0, t1]``,
+    oldest first, WITHOUT taking them out: the flusher's next
+    :func:`drain` finds what it would have found. For in-process readers
+    of a window (the benchmark's per-layer metrics); what a flush already
+    wrote out, or the ring overwrote (``trace/dropped``), is not here."""
+    with _ring_lock:
+        return [
+            s for s in _ring
+            if s["t0"] >= t0 and (t1 is None or s["t0"] <= t1)
+        ]
 
 
 def recent_spans(n: int = _RECENT_CAP) -> List[dict]:

@@ -129,15 +129,36 @@ class GenRequest:
     top_k: int = 1 << 30
     greedy: bool = False
     stop_token_ids: List[int] = dataclasses.field(default_factory=list)
+    # stamped by ``GenerationEngine.submit`` (host ``perf_counter``
+    # seconds); rides the request to its slot and onto the ``GenOutput``
+    t_submit: Optional[float] = None
 
 
 @dataclasses.dataclass
 class GenOutput:
+    """One finished (or ``pause()``-interrupted) request.
+
+    The four timestamps are host ``time.perf_counter`` seconds, taken by
+    the engine with no device work: ``t_submit`` in ``submit``,
+    ``t_admit`` when the request got its slot and pages, ``t_first`` at
+    the resolve of the first chunk that ran the slot, ``t_done`` at its
+    harvest. ``t_first`` is the moment a caller could first see a token
+    (``partial_outputs`` after that chunk), so it is an upper bound on
+    time to first token at chunk granularity, not the device's time of
+    the first decode step; an interrupted request whose first chunk was
+    never resolved gets ``t_done`` there if it has tokens, else ``None``.
+    ``t_admit - t_submit`` is queue wait, ``t_first - t_submit`` time to
+    first token, ``t_done - t_first`` decoding."""
+
     rid: str
     output_ids: List[int]
     output_logprobs: List[float]
     finish_reason: str            # "stop" | "length" | "interrupted"
     version: int = 0
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
 
 
 def _finish_reason(n_gen, max_gen) -> str:
@@ -170,6 +191,9 @@ class _SlotInfo:
     rid: str
     pages: List[int]          # owned pages (refcount held by this slot)
     borrowed: List[int]       # shared prefix pages (one ref held)
+    t_submit: Optional[float] = None    # the GenOutput's timestamps
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 class GenerationEngine:
@@ -563,6 +587,7 @@ class GenerationEngine:
                     f"prompt {len(req.input_ids)} + max_new "
                     f"{req.max_new_tokens} exceeds per-slot capacity {self.S}"
                 )
+            req.t_submit = time.perf_counter()
             with self._pending_lock:
                 self._pending.append(req)
                 self._req_meta[req.rid] = req
@@ -698,13 +723,16 @@ class GenerationEngine:
             params = jax.device_put(params, self._param_sh)
         if draft_params is not None:
             draft_params = self.prepare_draft_params(draft_params)
-        with self._lock:
+        # the span covers the swap itself (what a chunk boundary pays),
+        # not the wait for the running chunk to release the lock
+        with self._lock, tracing.span("gen_engine/weight_swap") as attrs:
             self.params = params
             if draft_params is not None:
                 self.draft_params = draft_params
                 self.draft_version += 1
             self.version = version if version is not None else self.version + 1
             self.prefix.clear()
+            attrs["version"] = self.version
 
     def update_draft_params(self, draft_params):
         """Swap ONLY the draft model's weights between chunks. Does NOT
@@ -1008,6 +1036,23 @@ class GenerationEngine:
                     jnp.asarray(n_new),
                 )
 
+    def _admit(self):
+        """``_admit_pending`` under its span: the host's share of a chunk
+        boundary that the device most often waits out (page allocation,
+        prefix lookup, building and dispatching the prefill programs)."""
+        with tracing.span("gen_engine/admit") as attrs:
+            st = self.stats
+            before = (
+                st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"]
+            )
+            self._admit_pending()
+            attrs.update(
+                admitted=st["admitted"] - before[0],
+                prefill_tokens=st["prefill_tokens"] - before[1],
+                prefix_hit_tokens=st["prefix_hit_tokens"] - before[2],
+                pending_left=self.n_pending(),
+            )
+
     def _admit_pending(self):
         if not self.accepting:
             return
@@ -1049,7 +1094,10 @@ class GenerationEngine:
             table_row = np.zeros((self.M,), np.int32)
             table_row[: len(shared) + len(owned)] = shared + owned
             self._table_host[slot] = table_row
-            self._slots[slot] = _SlotInfo(rid=r.rid, pages=owned, borrowed=shared)
+            self._slots[slot] = _SlotInfo(
+                rid=r.rid, pages=owned, borrowed=shared,
+                t_submit=r.t_submit, t_admit=time.perf_counter(),
+            )
             covered = len(shared) * self.page
             row = {
                 "tokens": ids[covered:plen_eff],
@@ -1737,10 +1785,12 @@ class GenerationEngine:
         every resolve that still had to wait (the event-log proof the
         zero-blocking-sync test pins at 0)."""
         metrics_mod.counters.add(metrics_mod.GEN_CHUNK_FLAG_FETCHES)
-        if not all(f.is_ready() for f in flags):
+        blocked = not all(f.is_ready() for f in flags)
+        if blocked:
             metrics_mod.counters.add(metrics_mod.GEN_CHUNK_FLAG_BLOCKED)
-        # arealint: ok(resolving the dispatch-ahead flag copy, not a pull)
-        return tuple(np.asarray(f) for f in flags)
+        with tracing.span("gen_engine/flag_wait", blocked=blocked):
+            # arealint: ok(resolving the dispatch-ahead flag copy, not a pull)
+            return tuple(np.asarray(f) for f in flags)
 
     def _pull_outputs(self) -> dict:
         """ONE device pull of every slot's accumulated outputs + flags."""
@@ -1780,13 +1830,83 @@ class GenerationEngine:
         self._fused_topk_host[b] = False
         with self._pending_lock:
             self._req_meta.pop(info.rid, None)
+        t_done = time.perf_counter()
+        t_first = info.t_first
+        if t_first is None and n > 0:
+            # pause() between a chunk's dispatch and its resolve
+            t_first = t_done
         return GenOutput(
             rid=info.rid,
             output_ids=toks,
             output_logprobs=lps,
             finish_reason=reason,
             version=self.version,
+            t_submit=info.t_submit,
+            t_admit=info.t_admit,
+            t_first=t_first,
+            t_done=t_done,
         )
+
+    def _dispatch(self, decode_steps: int, running: List[int],
+                  ahead: int, chunk_attrs: dict) -> Tuple[tuple, int]:
+        """Pick the chunk program for the running slots and dispatch it,
+        under its span. ``ahead``: tokens already dispatched but not yet
+        in ``_lens_host`` (pipelined mode). Returns the flag handles and
+        the tokens a slot can advance in this chunk."""
+        with tracing.span("gen_engine/dispatch") as attrs:
+            make, tok_bound, wb, warp_idx = self._decode_chunk_fn(
+                decode_steps, running
+            )
+            lens = self._lens_host[running]
+            # width-limit the chunk to the pages this chunk can touch
+            W = self._table_width(int(lens.max()) + ahead + tok_bound)
+            attrs["table_width"] = W
+            chunk_attrs["slots"] = len(running)
+            # KV positions the decode kernel reads at the chunk's first
+            # step: exact on the host (prompt - 1 + generated per slot; in
+            # pipelined mode less the chunk still in flight)
+            chunk_attrs["resident_tokens"] = int(lens.sum())
+            self._observe_occupancy()
+            chunk = make(decode_steps, W, wb)
+            return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
+
+    def _mark_first(self, slots) -> None:
+        """``t_first`` for the slots whose first chunk just resolved."""
+        now = time.perf_counter()
+        for b in slots:
+            info = self._slots[b]
+            if info is not None and info.t_first is None:
+                info.t_first = now
+
+    def _harvest_finished(self, finished: List[int], n_gen, max_gen,
+                          chunk_attrs: dict) -> List[GenOutput]:
+        """One output pull for every finished slot, then their release,
+        under its span."""
+        chunk_attrs["finished"] = len(finished)
+        if not finished:
+            return []
+        with tracing.span(
+            "gen_engine/harvest", finished=len(finished)
+        ) as attrs:
+            # the chunk already deactivated them on device, so no scatter
+            # back
+            host_state = self._pull_outputs()
+            outs = [
+                self._harvest(
+                    b, _finish_reason(n_gen[b], max_gen[b]),
+                    host_state=host_state,
+                )
+                for b in finished
+            ]
+            # the finished requests' stamps as on their GenOutput, so a
+            # reader of the span ring has queue wait and time to first
+            # token without a span per request: a few floats a chunk
+            attrs["stamps"] = [
+                [round(t, 6) for t in
+                 (o.t_submit, o.t_admit, o.t_first, o.t_done)]
+                for o in outs
+            ]
+            return outs
 
     def step(self, decode_steps: int = 16) -> List[GenOutput]:
         """Admit pending requests, run one decode chunk, harvest finished.
@@ -1810,73 +1930,43 @@ class GenerationEngine:
                 "gen_engine/chunk", steps=decode_steps
             ) as span_attrs:
                 if self._pipeline:
-                    return self._step_pipelined(decode_steps)
-                self._admit_pending()
-                if self.n_running() == 0:
-                    return []
-                # width-limit the chunk to the pages this chunk can touch
+                    return self._step_pipelined(decode_steps, span_attrs)
+                self._admit()
                 running = [
                     b for b, s in enumerate(self._slots) if s is not None
                 ]
-                span_attrs["slots"] = len(running)
-                make, tok_bound, wb, warp_idx = self._decode_chunk_fn(
-                    decode_steps, running
-                )
-                W = self._table_width(
-                    int(self._lens_host[running].max()) + tok_bound
-                )
-                self._observe_occupancy()
-                chunk = make(decode_steps, W, wb)
+                if not running:
+                    return []
+                flags, _ = self._dispatch(decode_steps, running, 0, span_attrs)
                 # one host sync per chunk; the flag copy was enqueued at
                 # dispatch, so the resolve costs no extra round trip
-                flags = self._resolve_flags(
-                    self._dispatch_chunk(chunk, W, warp_idx)
-                )
+                flags = self._resolve_flags(flags)
                 active, n_gen, max_gen, lens = flags[:4]
                 if len(flags) > 4:
                     self._fold_spec_stats(flags[4:])
                 self._lens_host[:] = lens
-                finished = [
-                    b for b, info in enumerate(self._slots)
-                    if info is not None and not active[b]
-                ]
-                span_attrs["finished"] = len(finished)
-                if not finished:
-                    return []
-                # one more pull serves EVERY finished slot's outputs; the
-                # chunk already deactivated them on device, so no scatter
-                # back
-                host_state = self._pull_outputs()
-                outs = []
-                for b in finished:
-                    outs.append(self._harvest(
-                        b, _finish_reason(n_gen[b], max_gen[b]),
-                        host_state=host_state,
-                    ))
-                return outs
+                self._mark_first(running)
+                finished = [b for b in running if not active[b]]
+                return self._harvest_finished(
+                    finished, n_gen, max_gen, span_attrs
+                )
 
-    def _step_pipelined(self, decode_steps: int) -> List[GenOutput]:
-        self._admit_pending()
+    def _step_pipelined(
+        self, decode_steps: int, span_attrs: dict
+    ) -> List[GenOutput]:
+        self._admit()
         new_flags, new_running, new_ahead = None, (), 0
-        if self.n_running():
-            running = [b for b, s in enumerate(self._slots) if s is not None]
-            make, tok_bound, wb, warp_idx = self._decode_chunk_fn(
-                decode_steps, running
-            )
+        running = [b for b, s in enumerate(self._slots) if s is not None]
+        if running:
             # _lens_host can be one in-flight chunk stale for continuing
             # slots: widen the bound by the TOKENS already dispatched
             # (a spec chunk advances up to decode_steps * (K+1) of them)
-            W = self._table_width(
-                int(self._lens_host[running].max())
-                + self._steps_ahead + tok_bound
+            new_flags, new_ahead = self._dispatch(
+                decode_steps, running, self._steps_ahead, span_attrs
             )
-            self._observe_occupancy()
-            chunk = make(decode_steps, W, wb)
-            new_flags = self._dispatch_chunk(chunk, W, warp_idx)
             new_running = tuple(
                 (b, int(self._slot_epoch[b])) for b in running
             )
-            new_ahead = tok_bound
         prev_flags, prev_running = self._prev_flags, self._prev_running
         self._prev_flags, self._prev_running = new_flags, new_running
         self._steps_ahead = new_ahead
@@ -1897,21 +1987,13 @@ class GenerationEngine:
         ]
         for b in same:  # NOT fresh admissions (their lens is live)
             self._lens_host[b] = lens[b]
+        self._mark_first(same)
         finished = [b for b in same if not active[b]]
-        if not finished:
-            return []
         # output pull rides the CURRENT state: waits out the in-flight
         # chunk (same cost the unpipelined path pays every chunk). The
         # finished slots were inactive through chunk k+1, so their
         # outputs are final.
-        host_state = self._pull_outputs()
-        outs = []
-        for b in finished:
-            outs.append(self._harvest(
-                b, _finish_reason(n_gen[b], max_gen[b]),
-                host_state=host_state,
-            ))
-        return outs
+        return self._harvest_finished(finished, n_gen, max_gen, span_attrs)
 
     @property
     def has_inflight(self) -> bool:

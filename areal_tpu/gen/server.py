@@ -652,7 +652,6 @@ class GenerationHTTPServer:
             "pending": len(self.engine._pending),
             "served": self._served,
             "gen_tokens": self._gen_tokens,
-            "gen_throughput": self._gen_tokens / max(time.time() - self._start, 1e-6),
             "version": self.engine.version,
             "max_slots": self.engine.B,
             # per-slot token capacity: the gateway's prompt-size bound

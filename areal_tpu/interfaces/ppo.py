@@ -22,6 +22,7 @@ import numpy as np
 
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import ModelInterface, PPOHyperparameters
+from areal_tpu.base import tracing
 from areal_tpu.ops import ppo as ppo_ops
 from areal_tpu.parallel import multihost
 from areal_tpu.train import batching
@@ -115,7 +116,8 @@ class PPOActorInterface(ModelInterface):
     def inference(
         self, engine, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> SequenceSample:
-        outs = engine.forward(sample, mb_spec, logprob_output_fn)
+        with tracing.span("ppo/inference", n_mbs=mb_spec.n_mbs):
+            outs = engine.forward(sample, mb_spec, logprob_output_fn)
         main = sample.main_key()
         res = SequenceSample(
             keys={"prox_logp"},
@@ -132,7 +134,18 @@ class PPOActorInterface(ModelInterface):
     def _prepare(self, sample: SequenceSample) -> SequenceSample:
         """Compute advantages/returns on the whole batch (flat packed layout)
         and attach them as new keys — the analogue of the reference's
-        pre-minibatch GAE + normalization block (``ppo_interface.py:527-647``)."""
+        pre-minibatch GAE + normalization block (``ppo_interface.py:527-647``).
+        One ``ppo/prepare`` span per call: the ops below run eagerly, one
+        small program each, and the device waits for the host between
+        them."""
+        main = sample.main_key()
+        with tracing.span(
+            "ppo/prepare", n_seqs=sample.bs,
+            n_tokens=sum(sum(l) for l in sample.seqlens[main]),
+        ):
+            return self._advantages(sample)
+
+    def _advantages(self, sample: SequenceSample) -> SequenceSample:
         hp = self.hp
         pb = batching.pack_sequences(sample, n_rows=1, pad_multiple=128)
         a = {k: jnp.asarray(v[0]) for k, v in pb.arrays.items()}
@@ -227,41 +240,43 @@ class PPOActorInterface(ModelInterface):
         self, engine, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict[str, float]:
         hp = self.hp
-        sample = self._prepare(sample)
-        # engine.train_batch is collective: the minibatch COUNT must agree
-        # across hosts even when per-host batch sizes differ (a starved host
-        # with a partial batch must not run fewer collective calls)
-        n_mb = int(
-            multihost.allreduce_min(np.int64(min(hp.ppo_n_minibatches, sample.bs)))
-        )
-        mbs = sample.split(max(n_mb, 1))
-        # pipelined minibatch loop: pack+put of minibatch n+1 overlaps the
-        # in-flight jitted step for minibatch n (serial loop when
-        # AREAL_TRAIN_PREFETCH is off). No host collectives may run between
-        # these dispatches — ours (the kl_ctl allreduce) sit after the loop.
-        all_stats = engine.train_batches_pipelined(
-            mbs, mb_spec, self._actor_loss_fn, fetch_stats=False
-        )
-        engine.version += 1
-        # minibatch-mean WITHOUT a device pull (deferred-stats path: the
-        # trainer fetches once per logging interval, not per step)
-        out = engine_mod.mean_stats_dicts(all_stats)
-        # Adaptive KL control tracks policy-vs-reference divergence (the
-        # signed masked mean over action tokens), like the reference
-        # (ppo_interface.py:973-978) — NOT the PPO update KL. The update is
-        # fed the GLOBAL mean so per-host controllers never drift apart.
-        tot = multihost.allreduce_sum(
-            np.asarray([self._last_ref_kl * sample.bs, sample.bs], np.float64)
-        )
-        ref_kl_global = float(tot[0] / max(tot[1], 1))
-        self.kl_ctl.update(ref_kl_global, int(tot[1]))
-        out["kl_ctl"] = self.kl_ctl.value
-        out["ref_kl"] = ref_kl_global
-        out["n_seqs"] = sample.bs
-        if not engine_mod.train_prefetch_enabled():
-            # legacy per-step blocking behavior for callers that asked for it
-            out = engine_mod.fetch_stats_dict(out)
-        return out
+        with tracing.span("ppo/train_step") as span_attrs:
+            sample = self._prepare(sample)
+            # engine.train_batch is collective: the minibatch COUNT must agree
+            # across hosts even when per-host batch sizes differ (a starved host
+            # with a partial batch must not run fewer collective calls)
+            n_mb = int(
+                multihost.allreduce_min(np.int64(min(hp.ppo_n_minibatches, sample.bs)))
+            )
+            mbs = sample.split(max(n_mb, 1))
+            span_attrs["n_mbs"] = len(mbs)   # PPO minibatches: optimizer steps
+            # pipelined minibatch loop: pack+put of minibatch n+1 overlaps the
+            # in-flight jitted step for minibatch n (serial loop when
+            # AREAL_TRAIN_PREFETCH is off). No host collectives may run between
+            # these dispatches — ours (the kl_ctl allreduce) sit after the loop.
+            all_stats = engine.train_batches_pipelined(
+                mbs, mb_spec, self._actor_loss_fn, fetch_stats=False
+            )
+            engine.version += 1
+            # minibatch-mean WITHOUT a device pull (deferred-stats path: the
+            # trainer fetches once per logging interval, not per step)
+            out = engine_mod.mean_stats_dicts(all_stats)
+            # Adaptive KL control tracks policy-vs-reference divergence (the
+            # signed masked mean over action tokens), like the reference
+            # (ppo_interface.py:973-978) — NOT the PPO update KL. The update is
+            # fed the GLOBAL mean so per-host controllers never drift apart.
+            tot = multihost.allreduce_sum(
+                np.asarray([self._last_ref_kl * sample.bs, sample.bs], np.float64)
+            )
+            ref_kl_global = float(tot[0] / max(tot[1], 1))
+            self.kl_ctl.update(ref_kl_global, int(tot[1]))
+            out["kl_ctl"] = self.kl_ctl.value
+            out["ref_kl"] = ref_kl_global
+            out["n_seqs"] = sample.bs
+            if not engine_mod.train_prefetch_enabled():
+                # legacy per-step blocking behavior for callers that asked for it
+                out = engine_mod.fetch_stats_dict(out)
+            return out
 
 
 @dataclasses.dataclass
@@ -317,7 +332,8 @@ class PPOCriticInterface(ModelInterface):
     def inference(
         self, engine, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> SequenceSample:
-        outs = engine.forward(sample, mb_spec, value_output_fn)
+        with tracing.span("ppo/inference", n_mbs=mb_spec.n_mbs):
+            outs = engine.forward(sample, mb_spec, value_output_fn)
         main = sample.main_key()
         return SequenceSample(
             keys={"values"},
@@ -330,16 +346,18 @@ class PPOCriticInterface(ModelInterface):
         self, engine, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict[str, float]:
         hp = self.hp
-        sample = self._actor_helper._prepare(sample)
-        n_mb = int(
-            multihost.allreduce_min(np.int64(min(hp.ppo_n_minibatches, sample.bs)))
-        )
-        mbs = sample.split(max(n_mb, 1))
-        all_stats = engine.train_batches_pipelined(
-            mbs, mb_spec, self._critic_loss_fn, fetch_stats=False
-        )
-        engine.version += 1
-        out = engine_mod.mean_stats_dicts(all_stats)
-        if not engine_mod.train_prefetch_enabled():
-            out = engine_mod.fetch_stats_dict(out)
-        return out
+        with tracing.span("ppo/train_step") as span_attrs:
+            sample = self._actor_helper._prepare(sample)
+            n_mb = int(
+                multihost.allreduce_min(np.int64(min(hp.ppo_n_minibatches, sample.bs)))
+            )
+            mbs = sample.split(max(n_mb, 1))
+            span_attrs["n_mbs"] = len(mbs)   # PPO minibatches: optimizer steps
+            all_stats = engine.train_batches_pipelined(
+                mbs, mb_spec, self._critic_loss_fn, fetch_stats=False
+            )
+            engine.version += 1
+            out = engine_mod.mean_stats_dicts(all_stats)
+            if not engine_mod.train_prefetch_enabled():
+                out = engine_mod.fetch_stats_dict(out)
+            return out

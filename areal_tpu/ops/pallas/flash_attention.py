@@ -564,6 +564,7 @@ def _flash_forward(
             out_shape=out_shape,
             compiler_params=compiler_params,
             interpret=_interpret(),
+            name="flash_fwd_tri",
         )(
             kstart, needs, jnp.asarray(iq_t), jnp.asarray(ik_t),
             jnp.asarray(first_t), jnp.asarray(last_t), seg2d, seg2d, q, k, v,
@@ -613,6 +614,7 @@ def _flash_forward(
         out_shape=out_shape,
         compiler_params=compiler_params,
         interpret=_interpret(),
+        name="flash_fwd",
     )(kstart, needs, seg2d, seg2d, q, k, v)
     return out, lse4.reshape(H, T)
 
@@ -1109,6 +1111,7 @@ def _flash_backward(
                     limit, dimension_semantics=("parallel", "arbitrary"),
                 ),
                 interpret=_interpret(),
+                name="flash_bwd_fused_tri",
             )(
                 kstart, needs, jnp.asarray(iq_t), jnp.asarray(ik_t),
                 jnp.asarray(first_t), jnp.asarray(last_t),
@@ -1170,6 +1173,7 @@ def _flash_backward(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             ),
             interpret=_interpret(),
+            name="flash_bwd_fused",
         )(kstart, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
         return dq, dk, dv
 
@@ -1240,6 +1244,7 @@ def _flash_backward(
             temps=split_temps,
         )),
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(kstart, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
 
     def dkv_qi(ql, j, i):
@@ -1298,6 +1303,7 @@ def _flash_backward(
             temps=split_temps,
         )),
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(qlast, needs, seg2d, seg2d, lse4, delta4, q, k, v, do)
     return dq, dk, dv
 

@@ -244,6 +244,7 @@ def fused_sample_pallas(
             ),
         ),
         interpret=_interpret() if interpret is None else interpret,
+        name="fused_sample",
     )(*operands)
     tok, lp, am, gat, norm = (o[:, 0] for o in outs)
     out = {

@@ -410,5 +410,7 @@ def decode(
             vmem_limit_bytes=_scratch_bytes(sb) + 32 * 2**20,
         ),
         interpret=_interpret(),
+        # the kernel's name in the compiled program and the device trace
+        name="paged_decode_int8" if quantized else "paged_decode",
     )(*operands)
 

@@ -955,7 +955,8 @@ class TrainEngine:
         sequence and ``metrics.counters`` the realized depth, so tests and
         the bench can PROVE overlap rather than infer it."""
         depth = fwd_pipeline_depth() if pipeline_depth is None else pipeline_depth
-        mbs, packed, _ = self._make_micro_batches(sample, mb_spec)
+        with tracing.span("fwd_pipe/pack"):
+            mbs, packed, _ = self._make_micro_batches(sample, mb_spec)
         fwd = self._get_jitted("forward", output_fn)
         by_key: Dict[Any, np.ndarray] = {}
         events: List[Tuple[str, int]] = []

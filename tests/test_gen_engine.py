@@ -500,3 +500,136 @@ class TestPipelinedChunks:
         assert harvested["short"].finish_reason == "length"
         assert len(harvested["short"].output_ids) == 2
         assert harvested["long"].finish_reason == "interrupted"
+
+
+# --------------------------------------------------------------------------- #
+# Spans where the chip waits, and request timestamps (docs/observability.md
+# "Engine and trainer spans")
+# --------------------------------------------------------------------------- #
+
+
+def _chunks_with_children(spans):
+    """[(chunk record, {child name: record})] for chunks that dispatched."""
+    by_parent = {}
+    for s in spans:
+        by_parent.setdefault(s["parent_id"], {})[s["name"]] = s
+    return [
+        (s, by_parent.get(s["span_id"], {}))
+        for s in spans
+        if s["name"] == "gen_engine/chunk" and "slots" in s.get("attrs", {})
+    ]
+
+
+class TestEngineSpans:
+    @pytest.mark.parametrize("pipelined", [False, True])
+    def test_step_records_child_spans(self, params, rng, pipelined):
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(
+            CFG, params, max_slots=4, max_seqlen=128,
+            pipeline_chunks=pipelined,
+        )
+        prompts = [
+            [int(x) for x in rng.integers(1, 128, size=n)] for n in (5, 9, 3)
+        ]
+        tracing.drain()
+        for i, p in enumerate(prompts):
+            eng.submit(GenRequest(
+                rid=f"r{i}", input_ids=p, max_new_tokens=6, greedy=True))
+        outs = eng.run_until_done(decode_steps=4)
+        assert len(outs) == 3
+        chunks = _chunks_with_children(tracing.drain())
+        assert len(chunks) >= 2
+        first, kids = chunks[0]
+        assert {"gen_engine/admit", "gen_engine/dispatch"} <= set(kids)
+        assert kids["gen_engine/admit"]["attrs"] == {
+            "admitted": 3, "prefill_tokens": sum(len(p) - 1 for p in prompts),
+            "prefix_hit_tokens": 0, "pending_left": 0,
+        }
+        assert kids["gen_engine/dispatch"]["attrs"]["table_width"] >= 1
+        assert first["attrs"]["steps"] == 4 and first["attrs"]["slots"] == 3
+        # every flag wait says whether it blocked; every harvest how many
+        # requests it finished, with their four stamps
+        waits = [k["gen_engine/flag_wait"] for _, k in chunks
+                 if "gen_engine/flag_wait" in k]
+        assert waits and all(
+            isinstance(w["attrs"]["blocked"], bool) for w in waits)
+        harvests = [k["gen_engine/harvest"] for _, k in chunks
+                    if "gen_engine/harvest" in k]
+        assert sum(h["attrs"]["finished"] for h in harvests) == 3
+        for h in harvests:
+            a = h["attrs"]
+            assert len(a["stamps"]) == a["finished"]
+            for stamps in a["stamps"]:     # submit, admit, first, done
+                assert stamps == sorted(stamps) and len(stamps) == 4
+        assert sorted(round(o.t_done, 6) for o in outs) == sorted(
+            st[3] for h in harvests for st in h["attrs"]["stamps"])
+        # (a pipelined chunk with no earlier chunk to resolve counts none)
+        assert sum(c["attrs"].get("finished", 0) for c, _ in chunks) == 3
+        if not pipelined:
+            # unpipelined: the four children tile the chunk, in this order
+            order = ["gen_engine/admit", "gen_engine/dispatch",
+                     "gen_engine/flag_wait"]
+            starts = [kids[n]["t0"] for n in order]
+            assert starts == sorted(starts)
+            assert sum(k["dur_s"] for k in kids.values()) <= first["dur_s"]
+
+    def test_resident_tokens_is_the_running_slots_kv(self, params, rng):
+        """``resident_tokens`` at a chunk's dispatch = the KV positions
+        its first decode step reads: prompt - 1 + generated, per slot."""
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(CFG, params, max_slots=4, max_seqlen=128)
+        plens = (5, 9, 3)
+        for i, n in enumerate(plens):
+            eng.submit(GenRequest(
+                rid=f"r{i}", input_ids=[int(x) for x in rng.integers(1, 128, size=n)],
+                max_new_tokens=20, greedy=True))
+        tracing.drain()
+        for _ in range(3):
+            eng.step(4)
+        res = [c["attrs"]["resident_tokens"]
+               for c, _ in _chunks_with_children(tracing.drain())]
+        assert res == [sum(plens) - 3 + 3 * 4 * k for k in range(3)]
+
+    @pytest.mark.parametrize("pipelined", [False, True])
+    @pytest.mark.parametrize("how", ["finished", "interrupted"])
+    def test_genoutput_timestamps(self, params, rng, pipelined, how):
+        import time
+
+        eng = GenerationEngine(
+            CFG, params, max_slots=2, max_seqlen=128,
+            pipeline_chunks=pipelined,
+        )
+        t_before = time.perf_counter()
+        for i in range(3):      # the third waits for a slot
+            eng.submit(GenRequest(
+                rid=f"r{i}", input_ids=[int(x) for x in rng.integers(1, 128, size=4)],
+                max_new_tokens=6 if how == "finished" else 64, greedy=True))
+        if how == "finished":
+            outs = eng.run_until_done(decode_steps=4)
+            assert {o.finish_reason for o in outs} == {"length"}
+        else:
+            for _ in range(3):
+                eng.step(4)
+            outs = eng.pause()
+            assert {o.finish_reason for o in outs} == {"interrupted"}
+            assert len(outs) == 2 and all(o.output_ids for o in outs)
+        t_after = time.perf_counter()
+        for o in outs:
+            assert t_before <= o.t_submit <= o.t_admit <= o.t_first <= o.t_done <= t_after, o
+        if how == "finished":
+            late = next(o for o in outs if o.rid == "r2")
+            # it queued until a slot was harvested: admitted after the
+            # first two had their first tokens
+            assert late.t_admit >= max(o.t_first for o in outs if o.rid != "r2")
+
+    def test_weight_swap_span_carries_the_version(self, params):
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(CFG, params, max_slots=2, max_seqlen=64)
+        tracing.drain()
+        eng.update_params(params, version=7)
+        (rec,) = [s for s in tracing.drain()
+                  if s["name"] == "gen_engine/weight_swap"]
+        assert rec["attrs"] == {"version": 7}

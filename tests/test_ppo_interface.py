@@ -158,3 +158,40 @@ def test_advantages_match_manual_gae(engines, rng):
         gl = hp.discount * hp.gae_lambda
         expected = r * gl ** (acts[-1] - acts)
         np.testing.assert_allclose(a[acts], expected, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("role", ["actor", "critic"])
+def test_one_prepare_span_per_train_step(engines, rng, role):
+    """The advantage pre-pass runs under ``ppo/prepare``, once per
+    ``train_step``, inside the interface's own ``ppo/train_step`` span
+    (the critic reaches it through the actor helper)."""
+    from areal_tpu.base import tracing
+
+    eng = engines[0 if role == "actor" else 1]
+    hp = PPOHyperparameters(
+        ppo_n_minibatches=2, use_decoupled_loss=False, recompute_logprob=False)
+    iface = make_interface(f"ppo_{role}", hp=hp)
+    sample = _rollout_sample(rng, n_items=4)
+    spec = MicroBatchSpec(max_tokens_per_mb=128)
+    tracing.drain()
+    if role == "critic":
+        sample.update_(iface.inference(eng, sample, spec))
+        (inf,) = [s for s in tracing.drain() if s["name"] == "ppo/inference"]
+        assert inf["attrs"] == {"n_mbs": spec.n_mbs}
+    iface.train_step(eng, sample, spec)
+    spans = tracing.drain()
+    (step,) = [s for s in spans if s["name"] == "ppo/train_step"]
+    (prep,) = [s for s in spans if s["name"] == "ppo/prepare"]
+    assert prep["parent_id"] == step["span_id"]
+    assert prep["attrs"] == {
+        "n_seqs": 4,
+        "n_tokens": sum(sum(l) for l in sample.seqlens["packed_input_ids"]),
+    }
+    assert step["attrs"]["n_mbs"] == 2
+    # the packer and the step's dispatch lie inside the train step's span
+    # (on the packer thread when the prefetcher runs: then not as children)
+    inside = [s for s in spans if s["name"] in (
+        "train_pipe/pack", "train_pipe/put", "train_pipe/dispatch")]
+    assert len(inside) == 3 * 2
+    assert all(step["t0"] <= s["t0"] <= step["t0"] + step["dur_s"]
+               for s in inside)

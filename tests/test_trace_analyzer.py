@@ -11,6 +11,7 @@ import pytest
 from areal_tpu.base.trace_analyzer import (
     BUCKETS,
     TraceAnalyzerUnavailable,
+    analyze_profile_data,
     analyze_xspace,
     classify,
     find_xplane_files,
@@ -36,6 +37,48 @@ def test_classify_tables():
     assert classify("copy.3") == "memoryIO"
     assert classify("dynamic-update-slice.9") == "memoryIO"
     assert classify("custom-call.pallas") == "compute"
+
+
+class _Ev:
+    def __init__(self, name, start_ns, duration_ns, **stats):
+        self.name, self.start_ns, self.duration_ns = name, start_ns, duration_ns
+        self.stats = list(stats.items())
+
+
+class _Named:
+    def __init__(self, name, **kw):
+        self.name = name
+        self.__dict__.update(kw)
+
+
+def test_nested_events_count_self_time_and_empty_planes_are_skipped():
+    """A ``while`` covers its body on the op line: each op is charged its
+    self time, busy is the union, idle the rest of the line's span. The
+    op-less plane libtpu adds to a CPU trace is not a device."""
+    ops = [
+        _Ev("while.1", 0, 100),            # covers the next two
+        _Ev("fusion.2", 10, 30),
+        _Ev("copy.3", 50, 20),
+        _Ev("fusion.4", 150, 20),
+    ]
+    pd = _Named("xspace", planes=[
+        _Named("/device:CUSTOM:Megascale Trace", lines=[]),
+        _Named("/device:TPU:0", lines=[
+            _Named("XLA Ops", events=ops),
+            _Named("XLA Modules", events=[_Ev("jit_f", 0, 170)]),
+        ]),
+    ])
+    (s,) = analyze_profile_data(pd)
+    assert s.plane == "/device:TPU:0" and s.n_events == 4
+    per_op = {n: sec for n, sec, _, _ in s.top_ops}
+    assert per_op == pytest.approx({
+        "while.1": 50e-9, "fusion.2": 30e-9, "copy.3": 20e-9,
+        "fusion.4": 20e-9})
+    busy = sum(v for k, v in s.buckets_s.items() if k != "idle")
+    assert busy == pytest.approx(120e-9)           # the union, not 170
+    assert s.buckets_s["idle"] == pytest.approx(50e-9)
+    assert s.buckets_s["memoryIO"] == pytest.approx(20e-9)
+    assert s.device_total_s == pytest.approx(170e-9)
 
 
 @pytest.fixture(scope="module")

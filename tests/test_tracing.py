@@ -14,15 +14,54 @@ import pytest
 
 from areal_tpu.base import constants, tracing
 from areal_tpu.base import metrics as metrics_mod
+from areal_tpu.base.trace_analyzer import profile_data_available
 
 
 def test_disabled_is_free(monkeypatch):
+    """Without AREAL_DUMP_TRACE ``maybe_trace`` starts no profiler; an
+    annotation needs no variable and no session."""
     monkeypatch.delenv(constants.TRACE_ENV, raising=False)
     assert not tracing.trace_enabled()
     with tracing.maybe_trace("noop"):
         pass
     with tracing.annotate("noop"):
         pass
+
+
+def _host_event_names(trace_dir):
+    import jax
+
+    from areal_tpu.base.trace_analyzer import find_xplane_files
+
+    names = set()
+    for f in find_xplane_files(str(trace_dir)):
+        for plane in jax.profiler.ProfileData.from_file(f).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    names.update(ev.name for ev in line.events)
+    return names
+
+
+@pytest.mark.skipif(
+    not profile_data_available(),
+    reason="jax.profiler.ProfileData not available in this jax build",
+)
+@pytest.mark.parametrize("spans_on", [True, False])
+def test_span_is_on_the_profiler_clock(monkeypatch, tmp_path, spans_on):
+    """One clock: in ANY profiler session (AREAL_DUMP_TRACE unset) a span
+    is a host-plane event named PROFILER_PREFIX + its name, beside the
+    device's ops. AREAL_TRACE_SPANS=0 stays counters only."""
+    import jax
+
+    monkeypatch.delenv(constants.TRACE_ENV, raising=False)
+    monkeypatch.setenv("AREAL_TRACE_SPANS", "1" if spans_on else "0")
+    with jax.profiler.trace(str(tmp_path)):
+        with tracing.span("unit/one_clock", k=1):
+            jnp.ones((8, 8)).sum().block_until_ready()
+    want = tracing.PROFILER_PREFIX + "unit/one_clock"
+    assert tracing.PROFILER_PREFIX == "areal/"
+    assert (want in _host_event_names(tmp_path)) == spans_on
+    assert metrics_mod.counters.get("unit/one_clock_n") >= 1
 
 
 def test_trace_dumps_profile(monkeypatch, tmp_path):
@@ -169,6 +208,36 @@ class TestSpanRecords:
         assert any(
             s["name"] == "t/recent" for s in tracing.recent_spans(50)
         )
+
+    @pytest.mark.parametrize("then", ["drain", "flush"])
+    def test_spans_since_reads_without_draining(self, tmp_path, then):
+        """The in-process read of a window leaves the ring as the flusher
+        would have found it, and windows by monotonic start."""
+        import time
+
+        with tracing.span("t/before"):
+            pass
+        t_mid = time.perf_counter()
+        for i in range(3):
+            with tracing.span("t/after", i=i):
+                pass
+        t_end = time.perf_counter()
+        with tracing.span("t/late"):
+            pass
+        got = tracing.spans_since(t_mid, t_end)
+        assert [s["name"] for s in got] == ["t/after"] * 3
+        assert [s["attrs"]["i"] for s in got] == [0, 1, 2]
+        assert all(t_mid <= s["t0"] <= t_end for s in got)
+        assert [s["name"] for s in tracing.spans_since(t_mid)][-1] == "t/late"
+        assert tracing.spans_since(t_mid) == tracing.spans_since(t_mid)
+        if then == "drain":
+            assert [s["name"] for s in tracing.drain()] == (
+                ["t/before"] + ["t/after"] * 3 + ["t/late"])
+        else:
+            assert tracing.flush("w/0", root=str(tmp_path)) == 5
+            lines = (tmp_path / "w_0.jsonl").read_text().splitlines()
+            assert [json.loads(l)["name"] for l in lines][1:4] == ["t/after"] * 3
+        assert tracing.spans_since(0.0) == []
 
 
 class TestFlush:
