@@ -68,6 +68,7 @@ from areal_tpu.gen.sampling import (
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import fused_sample as fused_ops
+from areal_tpu.ops import paged_attention as paged_ops
 
 logger = logging.getLogger("areal_tpu.gen.engine")
 
@@ -568,6 +569,10 @@ class GenerationEngine:
             "admitted": 0,
             "spec_draft_tokens": 0,     # draft tokens proposed (spec decode)
             "spec_accepted_tokens": 0,  # draft tokens accepted & emitted
+            # per kernel-run chunk, at its first step: KV positions the
+            # paged-decode kernel computes over / KV tokens resident
+            "kernel_positions": 0,
+            "resident_tokens": 0,
         }
 
     # ------------------------------------------------------------------ #
@@ -1865,10 +1870,43 @@ class GenerationEngine:
             # KV positions the decode kernel reads at the chunk's first
             # step: exact on the host (prompt - 1 + generated per slot; in
             # pipelined mode less the chunk still in flight)
-            chunk_attrs["resident_tokens"] = int(lens.sum())
+            resident = int(lens.sum())
+            chunk_attrs["resident_tokens"] = resident
+            positions = self._kernel_positions(W)
+            if positions is not None:
+                chunk_attrs["kernel_positions"] = positions
+                self.stats["kernel_positions"] += positions
+                self.stats["resident_tokens"] += resident
             self._observe_occupancy()
             chunk = make(decode_steps, W, wb)
             return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
+
+    def _kernel_positions(self, W: int) -> Optional[int]:
+        """KV positions the paged-decode kernel's body runs over at the
+        first step of a vanilla chunk of table width ``W``: the kernel's
+        own block plan over the host's lengths, sorted as
+        ``decode_step_paged`` sorts its rows. Over ``resident_tokens`` it
+        is how many times the resident KV the kernel computes. Free slots
+        count as empty (on the device a finished slot keeps its length
+        until it is refilled). ``None`` where the chunk runs no such
+        kernel: the XLA gather path, a speculative chunk."""
+        cfg = self.cfg
+        tp = self.mesh.shape["model"] if self.mesh is not None else 1
+        pool_dtype = self.state.cache.pages.dtype
+        if self.spec or not paged_ops.decode_kernel_applies(
+            self._decode_use_pallas, cfg.head_dim, cfg.n_kv_heads,
+            self.page, pool_dtype, tp,
+        ):
+            return None
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        sb, kp = pl_paged.block_plan(
+            self.B, cfg.n_kv_heads // tp, cfg.head_dim, self.page, W,
+            pool_dtype,
+        )
+        return pl_paged.kernel_positions(
+            np.sort(self._lens_host), sb, kp * self.page
+        )
 
     def _mark_first(self, slots) -> None:
         """``t_first`` for the slots whose first chunk just resolved."""

@@ -901,6 +901,14 @@ def verify_step_paged(
     return _head(cfg, params, x), cache
 
 
+def _length_order(lens: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(order, inverse)``: the batch's rows by ascending resident length
+    (stable: equal lengths keep slot order, so a batch of one length is
+    left as it is) and the permutation that puts them back."""
+    order = jnp.argsort(lens)
+    return order, jnp.argsort(order)
+
+
 def decode_step_paged(
     params: Params,
     cfg: ModelConfig,
@@ -918,6 +926,14 @@ def decode_step_paged(
     cache, new lens — incremented where active). The pool is read-only in
     the layer scan; each layer's fresh K/V merges into attention as the
     self token and lands in the pool via one post-scan scatter.
+
+    The layer scan runs on the rows ORDERED BY ``lens`` (one sort a step,
+    hoisted out of the scan): the paged kernel works through every block
+    of consecutive rows as far as its longest row reaches
+    (``ops/pallas/paged_attention.py``), so rows of like length share a
+    block. A row's result does not depend on its neighbours; the hidden
+    states and the fresh K/V go back to slot order after the scan, and
+    everything the caller sees is in slot order.
 
     ``use_pallas`` threads through to the attention dispatch. ``mesh``
     (TP serving) routes the kernel through ``shard_map`` over the kv-head
@@ -937,13 +953,15 @@ def decode_step_paged(
     ``[B, V]`` logits never materialize."""
     from areal_tpu.ops import paged_attention as paged_ops
 
-    positions = lens
-    x = _embed(cfg, params, tokens, positions)        # [B, E]
+    new_lens = jnp.where(active, lens + 1, lens)
+    # the scan's rows, by length (``_o``); slot order again after it
+    order, inverse = _length_order(lens)
+    table_o, lens_o = table[order], lens[order]
+    x = _embed(cfg, params, tokens[order], lens_o)    # [B, E]
     if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
+        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), lens_o, jnp.float32)
     else:
         cos = sin = None
-    new_lens = jnp.where(active, lens + 1, lens)
 
     def layer(carry, lp):
         x, li = carry                                 # pool NOT in the scan
@@ -954,7 +972,7 @@ def decode_step_paged(
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
         ctx = paged_ops.paged_decode_attention(
-            q, k, v, cache.pages, li, table, lens,
+            q, k, v, cache.pages, li, table_o, lens_o,
             softmax_scale=cfg.softmax_scale,
             soft_cap=cfg.attn_logits_soft_cap,
             sliding_window=cfg.sliding_window,
@@ -970,9 +988,10 @@ def decode_step_paged(
     (x, _), (ks, vs) = jax.lax.scan(
         layer, (x, jnp.int32(0)), params["layers"]
     )
+    x, ks, vs = x[inverse], ks[:, inverse], vs[:, inverse]
     cache = _scatter_chunk_kv(
         cache, ks[:, :, None], vs[:, :, None], table,
-        positions[:, None], active[:, None],
+        lens[:, None], active[:, None],
     )
     if not with_head:
         return None, cache, new_lens

@@ -76,6 +76,28 @@ def gather_dequant_pages(
     return k, v
 
 
+def decode_kernel_applies(
+    use_pallas: Optional[bool], head_dim: int, n_kv_heads: int, page: int,
+    pool_dtype, tp: int = 1,
+) -> bool:
+    """Whether :func:`paged_decode_attention` runs the Pallas kernel:
+    ``use_pallas`` as given, or, left to the auto-dispatch (``None``), on a
+    TPU where the kernel's in-VMEM reshapes get a full-lane head_dim, the
+    page is a whole tile (128 for an int8 pool, see ``page_multiple``) and
+    the mesh's model axis splits whole kv heads. Everything else takes the
+    XLA gather path."""
+    if use_pallas is not None:
+        return use_pallas
+    from areal_tpu.ops.pallas.paged_attention import page_multiple
+
+    return (
+        jax.devices()[0].platform == "tpu"
+        and head_dim % 128 == 0
+        and page % page_multiple(pool_dtype) == 0
+        and n_kv_heads % tp == 0
+    )
+
+
 def paged_decode_attention(
     q: jnp.ndarray,          # [B, H, D] one new token per slot
     k_self: jnp.ndarray,     # [B, Hkv, D] the new token's K (not in pool)
@@ -122,19 +144,7 @@ def paged_decode_attention(
     if softmax_scale is None:
         softmax_scale = D ** -0.5
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
-    if use_pallas is None:
-        # the kernel's in-VMEM reshapes need a full-lane head_dim; smaller
-        # heads (and sub-tile pages — 128 for an int8 pool, see
-        # page_multiple) take the XLA gather path
-        from areal_tpu.ops.pallas.paged_attention import page_multiple
-
-        use_pallas = (
-            jax.devices()[0].platform == "tpu"
-            and q.shape[-1] % 128 == 0
-            and pages.shape[4] % page_multiple(pages.dtype) == 0
-            and Hkv % tp == 0
-        )
-    elif use_pallas and tp > 1 and Hkv % tp != 0:
+    if use_pallas and tp > 1 and Hkv % tp != 0:
         # explicit use_pallas=True with an incompatible mesh: the shard_map
         # below splits the kv-head axis over the model axis and cannot
         # split a head — fail here with the real constraint instead of an
@@ -146,7 +156,9 @@ def paged_decode_attention(
             "divides n_kv_heads, or pass use_pallas=False for the XLA "
             "gather path."
         )
-    if use_pallas:
+    if decode_kernel_applies(
+        use_pallas, D, Hkv, pages.shape[4], pages.dtype, tp
+    ):
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
         def _kernel(q_, k_, v_, pages_, layer_, table_, lens_, *scales_):

@@ -9,14 +9,39 @@ no flat reshape, no per-layer slice, no in-VMEM transpose). TPU
 counterpart of vLLM/SGLang's paged-attention CUDA kernels, which the
 reference inherits (SURVEY §2.1).
 
-Grid ``(ceil(B/SB), ceil(M/KP))``: SB slots x KP pages per step. Grid-step
-LATENCY (DMA round trips + fixed step cost, ~5.7 µs) — not bandwidth or
-FLOPs — dominates decode at serving batch sizes, and it pays per step per
-layer; batching SB slots per step amortizes it 8x (measured: one-page
-one-slot steps cost 14 ms per 1.5B/64-slot decode step; 368 µs per
-64-slot kernel call before slot batching). Every slot's page DMAs for a
-step start together and overlap; out-of-range pages skip the DMA and
-zero-fill (masked probabilities multiply NaN otherwise). GQA runs without
+Grid ``(B/SB, ceil(M/KP))``: SB slots x KP pages (``S = KP * page``
+positions) per step, ``(SB, KP)`` from :func:`block_plan`. What a call
+costs has three parts (measured alone on a TPU v5e at 128 slots x 12q/2kv
+x 128, page 128, table widths 32-64; PERF.md, PR 25):
+
+- every grid step walks its ``SB * KP`` table entries on the scalar core
+  (one issue, one zero and one wait branch each), page or no page, work
+  or no work in the step: about 36 ns an entry, so ``B * M`` entries cost
+  0.19 ms a call at a table of 40 pages with NO token resident. Block
+  shape does not change it (the entries are the same); a narrower table
+  does;
+- page DMAs are issued per slot, only for pages the slot holds, so the
+  bytes read from HBM are the resident KV and no more; they stream at
+  about 950 GB/s and overlap the dots;
+- the body (QK dot, softmax, PV dot, batched over ``[SB*Hkv, S, D]``)
+  runs for the WHOLE block of SB rows at every page block up to
+  ``ceil(max_len / S)`` of its LONGEST row, and ``_zero`` stores a page of
+  zeros (``2*Hkv*page*D`` elements) for every page a shorter row does not
+  hold up to that same maximum (masked probabilities are 0, but ``0 * NaN
+  = NaN`` in the PV dot). :func:`kernel_positions` counts that:
+  ``SB * S * ceil(max_len / S)`` summed over blocks. Rows of mixed length
+  in one block are work over positions that hold no KV (2.4 x the
+  resident KV with rows in random order, 1.5 x sorted; 0.06 ms of a 0.44
+  ms call).
+
+THE CALLER ORDERS ROWS BY LENGTH (``decode_step_paged`` sorts the batch
+once per step, before its layer scan), so a block's rows are of
+neighbouring length and its maximum is close to every member's; empty
+rows (``lens`` 0) gather in the first blocks, whose body is skipped
+outright. Rows of a block are independent in the batched dots and a page
+block that is all masked for a row leaves its ``m``/``l``/``acc`` as they
+were, so a row's result does not depend on which rows share its block.
+Every slot's page DMAs for a step start together. GQA runs without
 materializing the K/V head repeat: scores are batched ``dot_general``
 over the kv-head axis.
 
@@ -26,10 +51,11 @@ caller's layer scan; the model scatters all layers' new KV afterwards).
 """
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,6 +77,56 @@ def page_multiple(pool_dtype) -> int:
     aligned to tiling (128)") — so int8 pools with smaller pages take the
     XLA gather path."""
     return LANES if jnp.dtype(pool_dtype) == jnp.int8 else 8
+
+
+def _scratch_bytes(sb, kp, page, n_kv, head_dim, pool_dtype) -> int:
+    """Double-buffered KV page scratch of one grid step, plus an int8
+    pool's f32 scale stripes."""
+    dt = jnp.dtype(pool_dtype)
+    b = 2 * 2 * sb * kp * page * n_kv * head_dim * dt.itemsize
+    if dt == jnp.int8:
+        b += 2 * 2 * sb * kp * page * n_kv * 4
+    return b
+
+
+def block_plan(
+    batch: int,
+    n_kv_heads: int,
+    head_dim: int,
+    page: int,
+    table_width: int,
+    pool_dtype,
+    pages_per_step: int = 8,
+    slots_per_step: int = 8,
+) -> Tuple[int, int]:
+    """``(sb, kp)``: the slots and pages of one grid step that
+    :func:`decode` runs a batch with. ``kp`` is the table width capped at
+    ``pages_per_step``; ``sb`` is ``slots_per_step`` halved until it
+    divides the batch and the KV scratch is NOT OVER 16 MiB (a scratch of
+    exactly 16 MiB stays: 12q/2kv x 128 at page 128 runs 8 slots a step,
+    28q/4kv x 128 runs 4). Pure, so the engine counts
+    :func:`kernel_positions` with the plan the kernel uses."""
+    kp = min(pages_per_step, table_width)
+    sb = slots_per_step
+    while batch % sb:
+        sb //= 2
+    while sb > 1 and _scratch_bytes(
+        sb, kp, page, n_kv_heads, head_dim, pool_dtype
+    ) > 16 * 1024 * 1024:
+        sb //= 2
+    return sb, kp
+
+
+def kernel_positions(lens, sb: int, span: int) -> int:
+    """KV positions the kernel's body runs over for rows of resident
+    lengths ``lens`` IN THE ORDER THE KERNEL GETS THEM (host integers;
+    ``decode_step_paged`` hands them sorted, so its callers pass
+    ``np.sort(lens)``): every block of ``sb`` consecutive rows computes
+    ``sb * span`` positions for each of the ``ceil(max_len / span)`` page
+    blocks its longest row reaches (``span = kp * page``). Over the sum of
+    ``lens`` it is how many times the resident KV the kernel computes."""
+    longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
+    return int(sb * span * (-(-longest // span)).sum())
 
 
 def _decode_kernel(
@@ -335,22 +411,10 @@ def decode(
     if softmax_scale is None:
         softmax_scale = D ** -0.5
     hq_pad = max(8, Hq)
-    kp = min(pages_per_step, M)
+    sb, kp = block_plan(
+        B, Hkv, D, page, M, pages.dtype, pages_per_step, slots_per_step
+    )
     nblk = -(-M // kp)
-    sb = slots_per_step
-    while B % sb:
-        sb //= 2
-
-    def _scratch_bytes(sb_):
-        # double-buffered KV pages + (quantized) their f32 scale stripes
-        b = 2 * 2 * sb_ * kp * page * Hkv * D * pages.dtype.itemsize
-        if quantized:
-            b += 2 * 2 * sb_ * kp * page * Hkv * 4
-        return b
-
-    # VMEM budget: keep the (double-buffered) KV scratch under ~16 MB
-    while sb > 1 and _scratch_bytes(sb) > 16 * 1024 * 1024:
-        sb //= 2
 
     kernel = functools.partial(
         _decode_kernel,
@@ -407,7 +471,9 @@ def decode(
         # default scoped-vmem budget; size the limit from the actual
         # scratch + generous op margin (v5e VMEM is 128 MB)
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_scratch_bytes(sb) + 32 * 2**20,
+            vmem_limit_bytes=_scratch_bytes(
+                sb, kp, page, Hkv, D, pages.dtype
+            ) + 32 * 2**20,
         ),
         interpret=_interpret(),
         # the kernel's name in the compiled program and the device trace

@@ -592,6 +592,56 @@ class TestEngineSpans:
                for c, _ in _chunks_with_children(tracing.drain())]
         assert res == [sum(plens) - 3 + 3 * 4 * k for k in range(3)]
 
+    @pytest.mark.parametrize("use_pallas", [True, None])
+    def test_kernel_positions_follow_the_kernels_block_plan(
+            self, params, rng, use_pallas):
+        """``kernel_positions`` at a chunk's dispatch = what the paged
+        kernel's body runs over at its first step: per block of ``sb``
+        rows of the batch SORTED by length (as ``decode_step_paged`` hands
+        them over), ``sb`` x the page blocks of its longest row. Only on
+        chunks that run the kernel (forced on here, interpret mode; on the
+        CPU's own XLA gather path there is nothing to count), and the
+        tokens are those of the gather path either way."""
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(
+            CFG, params, max_slots=12, max_seqlen=256, page_size=8)
+        eng._decode_use_pallas = use_pallas
+        plens = (5, 150, 9, 70, 3, 130, 64, 20, 200)    # 3 slots stay free
+        prompts = [[int(x) for x in rng.integers(1, 128, size=n)]
+                   for n in plens]
+        for i, p in enumerate(prompts):
+            eng.submit(GenRequest(
+                rid=f"r{i}", input_ids=p, max_new_tokens=8, greedy=True))
+        tracing.drain()
+        outs = {o.rid: o.output_ids for o in eng.run_until_done(4)}
+        attrs = [c["attrs"] for c, _ in _chunks_with_children(tracing.drain())]
+        assert len(attrs) == 2
+        if use_pallas is None:
+            assert all("kernel_positions" not in a for a in attrs)
+            assert eng.stats["kernel_positions"] == 0
+            return
+        sb, span = 4, 8 * 8       # 12 slots: blocks of 4; 8 pages of 8
+        want = []
+        for k in range(2):
+            lens = np.sort([0] * 3 + [n - 1 + 4 * k for n in plens])
+            want.append(sum(
+                sb * span * -(-int(lens[b:b + sb].max()) // span)
+                for b in range(0, 12, sb)))
+        assert [a["kernel_positions"] for a in attrs] == want
+        # slot order (5, 150, 9, 70 | 3, 130, 64, 20 | 200, -, -, -) would
+        # take every block as far as a long row: 3 + 3 + 4 page blocks
+        assert want[0] == sb * span * (1 + 1 + 4) < sb * span * 10
+        assert eng.stats["kernel_positions"] == sum(want)
+        assert eng.stats["resident_tokens"] == sum(
+            a["resident_tokens"] for a in attrs)
+        ref = GenerationEngine(
+            CFG, params, max_slots=12, max_seqlen=256, page_size=8)
+        for i, p in enumerate(prompts):
+            ref.submit(GenRequest(
+                rid=f"r{i}", input_ids=p, max_new_tokens=8, greedy=True))
+        assert outs == {o.rid: o.output_ids for o in ref.run_until_done(4)}
+
     @pytest.mark.parametrize("pipelined", [False, True])
     @pytest.mark.parametrize("how", ["finished", "interrupted"])
     def test_genoutput_timestamps(self, params, rng, pipelined, how):

@@ -383,6 +383,161 @@ class TestPallasPagedDecode:
                 )
 
 
+def _heavy_tailed_lens(rng, n, cap):
+    """Resident lengths in random slot order: lognormal, a few empty, one
+    at the cap (the decode kernel's worst block mate)."""
+    lens = np.minimum(rng.lognormal(np.log(cap / 4), 0.9, size=n), cap)
+    lens = lens.astype(np.int32)
+    lens[rng.choice(n, 3, replace=False)] = 0
+    lens[rng.integers(n)] = cap
+    return lens
+
+
+class TestLengthOrderedDecode:
+    """``decode_step_paged`` runs its layer scan on the rows sorted by
+    resident length (the Pallas kernel's blocks then hold rows of like
+    length) and hands everything back in slot order: to the bit what the
+    same step gives with the rows left in slot order."""
+
+    # 12 rows: the kernel's plan is blocks of 4 (8 does not divide 12),
+    # three of them, at half the interpreter's trace time of 8-row blocks
+    B, PAGE = 12, 8
+
+    def _step(self, cfg, params, cache, lens, active, width, **kw):
+        rng = np.random.default_rng(7)
+        table = rng.permutation(self.B * width).reshape(
+            self.B, width).astype(np.int32)
+        tokens = rng.integers(1, cfg.vocab_size, size=self.B).astype(np.int32)
+        # one program, as the engine's chunk runs it (and half the time
+        # of the op-by-op dispatch around the scan)
+        return jax.jit(
+            lambda p, c, *a: tfm.decode_step_paged(
+                p, cfg, c, *a, use_pallas=True, **kw)
+        )(params, cache, tokens, table, lens, active)
+
+    @pytest.mark.parametrize(
+        "variant,width",
+        [("plain", 32), ("plain", 64), ("int8", 64), ("window", 32),
+         ("no_head", 32), ("hidden", 64)],
+    )
+    def test_bit_equal_to_slot_order(self, params, monkeypatch, variant,
+                                     width):
+        import dataclasses
+
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        cfg = CFG
+        if variant == "window":
+            cfg = dataclasses.replace(CFG, sliding_window=40)
+        kw = {"no_head": {"with_head": False},
+              "hidden": {"return_hidden": True}}.get(variant, {})
+        rng = np.random.default_rng(width)
+        cache = tfm.PagedKVCache.empty(
+            cfg, self.B * width, self.PAGE,
+            kv_dtype="int8" if variant == "int8" else None,
+        )
+        if variant == "int8":
+            cache = tfm.PagedKVCache(
+                pages=jnp.asarray(rng.integers(
+                    -127, 128, size=cache.pages.shape), jnp.int8),
+                scales=jnp.asarray(rng.uniform(
+                    0.001, 0.02, size=cache.scales.shape), jnp.float32),
+            )
+        else:
+            cache = tfm.PagedKVCache(pages=jnp.asarray(
+                rng.normal(size=cache.pages.shape), jnp.float32))
+        lens = _heavy_tailed_lens(rng, self.B, width * self.PAGE - 1)
+        active = rng.random(self.B) < 0.8
+        active[np.flatnonzero(lens == 0)[0]] = False    # empty AND inactive
+        active[np.argmax(lens)] = True
+
+        # the sort does something here: the kernel's plan computes over
+        # fewer positions on the sorted rows
+        sb, kp = pl_paged.block_plan(
+            self.B, cfg.n_kv_heads, cfg.head_dim, self.PAGE, width,
+            cache.pages.dtype,
+        )
+        span = kp * self.PAGE
+        assert pl_paged.kernel_positions(np.sort(lens), sb, span) < (
+            pl_paged.kernel_positions(lens, sb, span))
+
+        got = self._step(cfg, params, cache, lens, active, width, **kw)
+        monkeypatch.setattr(
+            tfm, "_length_order",
+            lambda lens: (jnp.arange(lens.shape[0]),) * 2,
+        )
+        want = self._step(cfg, params, cache, lens, active, width, **kw)
+        if variant == "no_head":
+            assert got[0] is None and want[0] is None
+        else:
+            np.testing.assert_array_equal(
+                np.asarray(got[0]), np.asarray(want[0]))
+        for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(got[2]), np.where(active, lens + 1, lens))
+        np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(want[2]))
+
+    def test_equal_lengths_keep_slot_order(self):
+        order, inverse = tfm._length_order(jnp.full((8,), 5, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(order), np.arange(8))
+        np.testing.assert_array_equal(np.asarray(inverse), np.arange(8))
+        order, inverse = tfm._length_order(
+            jnp.asarray([7, 0, 3, 0, 7], jnp.int32))
+        np.testing.assert_array_equal(np.asarray(order), [1, 3, 2, 0, 4])
+        np.testing.assert_array_equal(
+            np.asarray(order)[np.asarray(inverse)], np.arange(5))
+
+
+class TestKernelPositions:
+    """The block plan and the count of positions the kernel computes over
+    (``kernel_positions`` on the engine's chunk span)."""
+
+    @pytest.mark.parametrize(
+        "batch,n_kv,dtype,want",
+        [
+            # R1-Distill-Qwen-1.5B, 12q/2kv x 128: the scratch is exactly
+            # 16 MiB at 8 slots, and exactly 16 MiB is not over it
+            (128, 2, "bfloat16", (8, 8)),
+            # 7B widths, 28q/4kv x 128: 32 MiB at 8 slots, halved
+            (64, 4, "bfloat16", (4, 8)),
+            # an int8 pool: half the page bytes, but at 4 kv heads its
+            # scale stripes take 8 slots over 16 MiB again
+            (128, 2, "int8", (8, 8)),
+            (64, 4, "int8", (4, 8)),
+            # a batch that 8 does not divide; a table narrower than 8 pages
+            (4, 2, "bfloat16", (4, 8)),
+        ],
+    )
+    def test_block_plan(self, batch, n_kv, dtype, want):
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        assert pl_paged.block_plan(batch, n_kv, 128, 128, 32, dtype) == want
+        assert pl_paged.block_plan(batch, n_kv, 128, 128, 4, dtype)[1] == 4
+
+    @pytest.mark.parametrize("sb,span", [(8, 1024), (4, 1024), (2, 64)])
+    def test_matches_brute_force(self, sb, span):
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        rng = np.random.default_rng(sb)
+        lens = _heavy_tailed_lens(rng, 64, 5000)
+        for rows in (lens, np.sort(lens)):
+            brute = 0
+            for b0 in range(0, len(rows), sb):
+                for j in range(-(-5000 // span)):
+                    # the kernel's gate on the body of grid step (b0, j)
+                    if j * span < rows[b0:b0 + sb].max():
+                        brute += sb * span
+            assert pl_paged.kernel_positions(rows, sb, span) == brute
+
+    def test_equal_lengths_round_up_to_the_span(self):
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        lens = np.full(32, 1500)
+        assert pl_paged.kernel_positions(lens, 8, 1024) == 32 * 2048
+        assert pl_paged.kernel_positions(np.zeros(32, int), 8, 1024) == 0
+
+
 class TestRadixPartialPrefix:
     def test_sibling_prompts_share_preamble_pages(self, params):
         """Two prompts with a common 2-page system preamble but different
