@@ -117,6 +117,9 @@ class GenState:
     # one headless draft decode step) — mixed spec/vanilla traffic stays
     # correct on one state pytree.
     draft_cache: Optional[tfm.PagedKVCache] = None
+    # MoE routing record (``record_routing``; None otherwise): the experts
+    # the decode step that produced out_tokens[b, i] chose in every layer
+    out_routing: Optional[jnp.ndarray] = None   # [B, G, L, top_k] i32
 
 
 @dataclasses.dataclass
@@ -160,6 +163,12 @@ class GenOutput:
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
+    # engines built with ``record_routing`` (MoE models): int32
+    # ``[len(output_ids), n_layers, top_k]``, the experts chosen by the
+    # decode step that produced each output token, i.e. the routing of the
+    # INPUT token at position ``prompt_len - 1 + i`` of the sequence (the
+    # trainer's ``routed_experts`` key wants it at that position)
+    output_routing: Optional[np.ndarray] = None
 
 
 def _finish_reason(n_gen, max_gen) -> str:
@@ -229,9 +238,16 @@ class GenerationEngine:
         drafter: Optional[Drafter] = None,
         fused_sample: Optional[bool] = None,
         spec_k_adapt: Optional[bool] = None,
+        record_routing: bool = False,
     ):
         self.cfg = cfg
         self.mesh = mesh
+        # MoE models: every vanilla chunk returns a routing census with its
+        # harvest flags (``_fold_chunk_aux``); ``record_routing`` also keeps
+        # each output token's chosen experts for ``GenOutput``
+        self._moe = cfg.mlp_type == "moe"
+        if record_routing and not self._moe:
+            raise ValueError("record_routing: the model has no router")
         self._decode_use_pallas: Optional[bool] = None
         # KV-pool storage dtype (docs/performance.md "KV quantization"):
         # explicit argument > cfg.kv_dtype > AREAL_KV_DTYPE > serving dtype
@@ -446,6 +462,14 @@ class GenerationEngine:
                     if self._draft is not None
                     else None
                 ),
+                out_routing=(
+                    jnp.zeros(
+                        (self.B, self.G, cfg.n_layers, cfg.moe.top_k),
+                        jnp.int32,
+                    )
+                    if record_routing
+                    else None
+                ),
             )
 
         if mesh is None:
@@ -514,6 +538,11 @@ class GenerationEngine:
         # exactly distribution-preserving, so togglable between chunks
         # (``spec`` is read once per step() under the engine lock)
         self.spec = spec_on
+        if record_routing and spec_on:
+            raise ValueError(
+                "record_routing covers vanilla chunks only: the verify "
+                "pass of a speculative chunk records nothing"
+            )
         self.spec_k = max(
             1, spec_k if spec_k is not None else constants.spec_k()
         )
@@ -573,6 +602,14 @@ class GenerationEngine:
             # paged-decode kernel computes over / KV tokens resident
             "kernel_positions": 0,
             "resident_tokens": 0,
+            # MoE models, per vanilla chunk (every row of the batch routes,
+            # free slots too: the expert matmuls read what they route to):
+            # distinct experts with a token, summed over layers and steps /
+            # layers x steps x experts / the most tokens one expert got in
+            # one layer-step (max, not summed)
+            "moe_experts_hit": 0,
+            "moe_expert_slots": 0,
+            "moe_load_max": 0,
         }
 
     # ------------------------------------------------------------------ #
@@ -831,7 +868,9 @@ class GenerationEngine:
                 return []
             # ONE device pull for every slot (a per-slot fetch is one
             # blocking device->host sync each)
-            host_state = self._pull_outputs()
+            host_state = self._pull_outputs(
+                [b for b, s in enumerate(self._slots) if s is not None]
+            )
             outs = []
             for b, s in enumerate(self._slots):
                 if s is not None:
@@ -1232,12 +1271,13 @@ class GenerationEngine:
         cfg = self.cfg
 
         def one_step(state: GenState, params, draft_params, table, warp_rows):
-            head_out, cache, new_lens = tfm.decode_step_paged(
+            head_out, cache, new_lens, *routing = tfm.decode_step_paged(
                 params, cfg, state.cache, state.last_tokens, table,
                 state.lens, state.active,
                 use_pallas=self._decode_use_pallas,
                 mesh=self.mesh,
                 return_hidden=fused,
+                with_routing=self._moe,
             )
             if self._draft is not None:
                 # keep the draft pool current: one HEADLESS draft decode
@@ -1330,6 +1370,21 @@ class GenerationEngine:
             ctx_tokens = state.ctx_tokens.at[
                 rows, jnp.where(state.active, new_lens, self.S)
             ].set(tokens, mode="drop")
+            census = None
+            out_routing = state.out_routing
+            if self._moe:
+                (routing,) = routing                      # [L, B, top_k]
+                # tokens per (layer, expert) of this step, every row counted
+                load = jax.nn.one_hot(
+                    routing, cfg.moe.num_experts, dtype=jnp.int32
+                ).sum(axis=(1, 2))
+                census = jnp.stack([(load > 0).sum(), load.max()])
+                if out_routing is not None:
+                    keep = state.active[:, None, None]
+                    out_routing = out_routing.at[rows, idx].set(jnp.where(
+                        keep, routing.transpose(1, 0, 2),
+                        out_routing[rows, idx],
+                    ))
             return dataclasses.replace(
                 state,
                 cache=cache,
@@ -1342,20 +1397,33 @@ class GenerationEngine:
                 out_logprobs=out_logprobs,
                 ctx_tokens=ctx_tokens,
                 rng=rng,
-            )
+                out_routing=out_routing,
+            ), census
+
+        def flags_of(state: GenState, census):
+            # harvest flags ride as UNDONATED aux outputs: the pipelined
+            # step pulls them AFTER dispatching the next chunk (whose
+            # donation consumes the state buffers). An MoE model adds its
+            # routing census [experts hit, expert slots, largest load]:
+            # three integers reduced from what the steps already computed
+            flags = (state.active, state.n_gen, state.max_gen, state.lens)
+            if census is not None:
+                slots = n_steps * cfg.n_layers * cfg.moe.num_experts
+                flags += (jnp.stack([
+                    census[:, 0].sum(), jnp.int32(slots), census[:, 1].max()
+                ]).astype(jnp.int32),)
+            return flags
 
         if self._draft is None:
 
             def chunk(params, state, table, warp_rows):
                 def body(s, _):
-                    return one_step(s, params, None, table, warp_rows), None
+                    return one_step(s, params, None, table, warp_rows)
 
-                state, _ = jax.lax.scan(body, state, None, length=n_steps)
-                # harvest flags ride as UNDONATED aux outputs: the
-                # pipelined step pulls them AFTER dispatching the next
-                # chunk (whose donation consumes the state buffers)
-                return state, (state.active, state.n_gen, state.max_gen,
-                               state.lens)
+                state, census = jax.lax.scan(
+                    body, state, None, length=n_steps
+                )
+                return state, flags_of(state, census)
 
         else:
 
@@ -1363,11 +1431,12 @@ class GenerationEngine:
                 def body(s, _):
                     return one_step(
                         s, params, draft_params, table, warp_rows
-                    ), None
+                    )
 
-                state, _ = jax.lax.scan(body, state, None, length=n_steps)
-                return state, (state.active, state.n_gen, state.max_gen,
-                               state.lens)
+                state, census = jax.lax.scan(
+                    body, state, None, length=n_steps
+                )
+                return state, flags_of(state, census)
 
         sharding_kw = self._jit_sharding(2)
         if sharding_kw:
@@ -1376,7 +1445,8 @@ class GenerationEngine:
             # structure mismatch on meshed engines
             sharding_kw = dict(sharding_kw)
             sharding_kw["out_shardings"] = (
-                sharding_kw["out_shardings"], (self._repl,) * 4
+                sharding_kw["out_shardings"],
+                (self._repl,) * (5 if self._moe else 4),
             )
         jitted = jax.jit(
             chunk, donate_argnums=(self._state_argnum,), **sharding_kw
@@ -1612,6 +1682,23 @@ class GenerationEngine:
         self._jit_spec[key] = jitted
         return jitted
 
+    def _fold_chunk_aux(self, aux: tuple, chunk_attrs: dict):
+        """What a resolved chunk carries after its four harvest flags: a
+        speculative chunk's stat grids (two or three of them), or a
+        vanilla chunk's MoE routing census (one ``[3]`` vector)."""
+        if len(aux) == 1:
+            hit, slots, load_max = (int(v) for v in aux[0])
+            chunk_attrs["moe_experts_hit"] = hit
+            chunk_attrs["moe_expert_slots"] = slots
+            chunk_attrs["moe_load_max"] = load_max
+            self.stats["moe_experts_hit"] += hit
+            self.stats["moe_expert_slots"] += slots
+            self.stats["moe_load_max"] = max(
+                self.stats["moe_load_max"], load_max
+            )
+        elif aux:
+            self._fold_spec_stats(aux)
+
     def _fold_spec_stats(self, aux):
         """Fold one spec chunk's ``[n_steps, B]`` aux grids — drafted and
         accepted counts, plus (for sampled/general-q drafters) the mean
@@ -1797,16 +1884,23 @@ class GenerationEngine:
             # arealint: ok(resolving the dispatch-ahead flag copy, not a pull)
             return tuple(np.asarray(f) for f in flags)
 
-    def _pull_outputs(self) -> dict:
-        """ONE device pull of every slot's accumulated outputs + flags."""
-        n_gen, out_tokens, out_logprobs, active, max_gen = jax.device_get(
-            (self.state.n_gen, self.state.out_tokens,
-             self.state.out_logprobs, self.state.active, self.state.max_gen)
+    def _pull_outputs(self, slots: Sequence[int] = ()) -> dict:
+        """ONE device pull of every slot's accumulated outputs + flags
+        (and, on a ``record_routing`` engine, of the routing record of
+        ``slots``: the rows about to be harvested, not all ``B``)."""
+        st = self.state
+        want = (st.n_gen, st.out_tokens, st.out_logprobs, st.active,
+                st.max_gen)
+        if st.out_routing is not None and len(slots):
+            want += (st.out_routing[np.asarray(slots)],)
+        n_gen, out_tokens, out_logprobs, active, max_gen, *routing = (
+            jax.device_get(want)
         )
         return {
             "n_gen": n_gen, "out_tokens": out_tokens,
             "out_logprobs": out_logprobs, "active": active,
             "max_gen": max_gen,
+            "out_routing": dict(zip(slots, routing[0])) if routing else {},
         }
 
     def _harvest(self, b: int, reason: str, host_state: dict) -> GenOutput:
@@ -1823,6 +1917,7 @@ class GenerationEngine:
         n = int(host_state["n_gen"][b])
         toks = host_state["out_tokens"][b, :n].tolist()
         lps = host_state["out_logprobs"][b, :n].tolist()
+        routing = host_state["out_routing"].get(b)
         info = self._slots[b]
         self._slots[b] = None
         self.pool.release(info.pages)
@@ -1850,6 +1945,7 @@ class GenerationEngine:
             t_admit=info.t_admit,
             t_first=t_first,
             t_done=t_done,
+            output_routing=None if routing is None else routing[:n],
         )
 
     def _dispatch(self, decode_steps: int, running: List[int],
@@ -1928,7 +2024,7 @@ class GenerationEngine:
         ) as attrs:
             # the chunk already deactivated them on device, so no scatter
             # back
-            host_state = self._pull_outputs()
+            host_state = self._pull_outputs(finished)
             outs = [
                 self._harvest(
                     b, _finish_reason(n_gen[b], max_gen[b]),
@@ -1980,8 +2076,7 @@ class GenerationEngine:
                 # dispatch, so the resolve costs no extra round trip
                 flags = self._resolve_flags(flags)
                 active, n_gen, max_gen, lens = flags[:4]
-                if len(flags) > 4:
-                    self._fold_spec_stats(flags[4:])
+                self._fold_chunk_aux(flags[4:], span_attrs)
                 self._lens_host[:] = lens
                 self._mark_first(running)
                 finished = [b for b in running if not active[b]]
@@ -2015,8 +2110,8 @@ class GenerationEngine:
         # steady state — zero blocking syncs at the chunk boundary
         prev_flags = self._resolve_flags(prev_flags)
         active, n_gen, max_gen, lens = prev_flags[:4]
-        if len(prev_flags) > 4:
-            self._fold_spec_stats(prev_flags[4:])
+        # (pipelined: the census of the chunk before this span's own)
+        self._fold_chunk_aux(prev_flags[4:], span_attrs)
         # epoch check: a slot that turned over since chunk k's dispatch now
         # holds a DIFFERENT request — k's stale flags must not touch it
         same = [

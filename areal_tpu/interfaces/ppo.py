@@ -47,6 +47,28 @@ def logprob_output_fn(params, cfg, arrays):
     return vmapped_next_token_logprobs(params, cfg, arrays)
 
 
+def logprob_router_output_fn(params, cfg, arrays):
+    """``logprob_output_fn`` for an MoE model whose caller passed the
+    generation engine's routing (``routed_experts`` ``[D, T, L, top_k]``,
+    -1 where the engine recorded nothing: ``GenOutput.output_routing``):
+    ``[D, T, 3]`` float32 = the logprob, the layers of that token whose
+    chosen expert SET in this recompute equals the engine's, and the
+    layers compared. Top-k of a softmax is discontinuous, so a rounding
+    difference between the two programs can pick another expert: this is
+    the count of how often it did."""
+    lp, routing = vmapped_next_token_logprobs(
+        params, cfg, arrays, with_routing=True
+    )
+    mine = jnp.sort(routing.transpose(0, 2, 1, 3), axis=-1)  # [D, T, L, K]
+    theirs = arrays["routed_experts"]
+    known = (theirs >= 0).all(axis=-1) & (arrays["segment_ids"] > 0)[..., None]
+    agree = known & (mine == jnp.sort(theirs, axis=-1)).all(axis=-1)
+    return jnp.stack(
+        [lp, agree.sum(-1).astype(lp.dtype), known.sum(-1).astype(lp.dtype)],
+        axis=-1,
+    )
+
+
 def value_output_fn(params, cfg, arrays):
     """Per-token critic values [D, T] (zero on padding)."""
     values = vmapped_forward(params, cfg, arrays)[..., 0]
@@ -116,8 +138,19 @@ class PPOActorInterface(ModelInterface):
     def inference(
         self, engine, sample: SequenceSample, mb_spec: MicroBatchSpec
     ) -> SequenceSample:
-        with tracing.span("ppo/inference", n_mbs=mb_spec.n_mbs):
-            outs = engine.forward(sample, mb_spec, logprob_output_fn)
+        routed = (
+            "routed_experts" in sample.keys and engine.cfg.mlp_type == "moe"
+        )
+        with tracing.span("ppo/inference", n_mbs=mb_spec.n_mbs) as attrs:
+            outs = engine.forward(
+                sample, mb_spec,
+                logprob_router_output_fn if routed else logprob_output_fn,
+            )
+            if routed:
+                # (token, layer) pairs of this host's sequences
+                attrs["router_agree"] = int(sum(o[:, 1].sum() for o in outs))
+                attrs["router_total"] = int(sum(o[:, 2].sum() for o in outs))
+                outs = [o[:, 0] for o in outs]
         main = sample.main_key()
         res = SequenceSample(
             keys={"prox_logp"},

@@ -2,8 +2,8 @@
 
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
-family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral) via feature
-switches, exactly like the reference's single in-house architecture.
+family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe) via
+feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -21,14 +21,6 @@ class MoEConfig:
     z_loss_coeff: float = 0.0
     input_jitter_eps: Optional[float] = None
     norm_topk_prob: bool = True
-    # "dense": every expert for every token (XLA-fused; correct under any
-    # sharding of the expert axis). "ragged": sort-by-expert grouped GEMM via
-    # ``lax.ragged_dot`` (megablox-style) — the TPU fast path when experts are
-    # replicated or fit per-device; GSPMD may all-gather expert weights if the
-    # expert axis is sharded. With nonzero aux coefficients the two modes
-    # optimize slightly different load-balance estimators under the packed
-    # training path (per-row mean vs whole-batch; see ``ops/moe.py``).
-    dispatch: str = "dense"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +41,12 @@ class ModelConfig:
     # Attention
     use_attention_bias: bool = False       # qkv projection bias (qwen2, gpt2)
     use_attn_proj_bias: bool = False       # output projection bias (gpt2)
-    qk_layernorm: bool = False             # per-head q/k RMSNorm (qwen3)
+    qk_layernorm: bool = False             # q/k RMSNorm before rotary
+    # What one q/k norm spans; the HF family sets it, a user never does.
+    # "head": each head's ``head_dim`` after the split into heads, gains
+    # ``[L, D]`` (qwen3). "full": the whole projected vector BEFORE the
+    # split, gains ``[L, Hq*D]`` / ``[L, Hkv*D]`` (olmoe).
+    qk_norm_over: str = "head"
     sliding_window: Optional[int] = None
     attn_logits_soft_cap: Optional[float] = None
     softmax_scale: Optional[float] = None  # default head_dim ** -0.5
@@ -157,11 +154,21 @@ class ModelConfig:
         return self.n_q_heads // self.n_kv_heads
 
     @property
+    def qk_norm_full(self) -> bool:
+        """The q/k norm spans the whole projection (olmoe), not one head."""
+        return self.qk_layernorm and self.qk_norm_over == "full"
+
+    @property
     def rot_dim(self) -> int:
         return self.rotary_dim if self.rotary_dim is not None else self.head_dim
 
     def __post_init__(self):
         if self.n_q_heads % self.n_kv_heads != 0:
             raise ValueError("n_q_heads must be divisible by n_kv_heads")
+        if self.qk_norm_over not in ("head", "full"):
+            raise ValueError(
+                f"qk_norm_over must be 'head' or 'full', got "
+                f"{self.qk_norm_over!r}"
+            )
         if self.mlp_type == "moe" and self.moe is None:
             object.__setattr__(self, "moe", MoEConfig())

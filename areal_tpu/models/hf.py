@@ -1,7 +1,8 @@
 """HF ↔ areal_tpu checkpoint converters for all supported model families.
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
-(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC) consumed by
+(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe is added
+here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -140,7 +141,13 @@ _ARCH_NAMES = {
     "gemma": "GemmaForCausalLM",
     "gpt2": "GPT2LMHeadModel",
     "mixtral": "MixtralForCausalLM",
+    "olmoe": "OlmoeForCausalLM",
 }
+
+# How a family names its expert block in a checkpoint:
+# (block, router, gate, up, down) under ``model.layers.{i}.``
+_MIXTRAL_MOE = ("block_sparse_moe", "gate", "w1", "w3", "w2")
+_OLMOE_MOE = ("mlp", "gate", "gate_proj", "up_proj", "down_proj")
 
 
 def _stack(sd: HFState, pattern: str, n_layers: int, transpose: bool = False):
@@ -151,7 +158,9 @@ def _stack(sd: HFState, pattern: str, n_layers: int, transpose: bool = False):
     return np.stack(mats)
 
 
-def _llama_like_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+def _llama_like_params_from_hf(
+    sd: HFState, cfg: ModelConfig, moe_names=_MIXTRAL_MOE
+) -> Dict[str, Any]:
     L = cfg.n_layers
     p = "model.layers.{i}."
     attn: Dict[str, Any] = {
@@ -168,48 +177,22 @@ def _llama_like_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
         attn["q_norm"] = _stack(sd, p + "self_attn.q_norm.weight", L)
         attn["k_norm"] = _stack(sd, p + "self_attn.k_norm.weight", L)
     if cfg.mlp_type == "moe":
-        X = cfg.moe.num_experts
+        block, router, *experts = moe_names
+
+        def stack_experts(name):    # [L, X, in, out]
+            return np.stack([
+                np.stack([
+                    np.asarray(
+                        sd[f"model.layers.{i}.{block}.experts.{j}.{name}.weight"]
+                    ).T
+                    for j in range(cfg.moe.num_experts)
+                ])
+                for i in range(L)
+            ])
+
         mlp = {
-            "router": _stack(sd, p + "block_sparse_moe.gate.weight", L, True),
-            "w_gate": np.stack(
-                [
-                    np.stack(
-                        [
-                            np.asarray(
-                                sd[f"model.layers.{i}.block_sparse_moe.experts.{j}.w1.weight"]
-                            ).T
-                            for j in range(X)
-                        ]
-                    )
-                    for i in range(L)
-                ]
-            ),
-            "w_down": np.stack(
-                [
-                    np.stack(
-                        [
-                            np.asarray(
-                                sd[f"model.layers.{i}.block_sparse_moe.experts.{j}.w2.weight"]
-                            ).T
-                            for j in range(X)
-                        ]
-                    )
-                    for i in range(L)
-                ]
-            ),
-            "w_up": np.stack(
-                [
-                    np.stack(
-                        [
-                            np.asarray(
-                                sd[f"model.layers.{i}.block_sparse_moe.experts.{j}.w3.weight"]
-                            ).T
-                            for j in range(X)
-                        ]
-                    )
-                    for i in range(L)
-                ]
-            ),
+            "router": _stack(sd, p + f"{block}.{router}.weight", L, True),
+            **dict(zip(("w_gate", "w_up", "w_down"), map(stack_experts, experts))),
         }
     else:
         mlp = {
@@ -234,7 +217,9 @@ def _llama_like_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
     return params
 
 
-def _llama_like_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+def _llama_like_params_to_hf(
+    params: Dict[str, Any], cfg: ModelConfig, moe_names=_MIXTRAL_MOE
+) -> HFState:
     sd: HFState = {"model.embed_tokens.weight": np.asarray(params["embed"]["weight"])}
     lp = params["layers"]
     for i in range(cfg.n_layers):
@@ -255,12 +240,13 @@ def _llama_like_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFStat
             sd[p + "self_attn.k_norm.weight"] = np.asarray(a["k_norm"][i])
         m = lp["mlp"]
         if cfg.mlp_type == "moe":
-            sd[p + "block_sparse_moe.gate.weight"] = np.asarray(m["router"][i]).T
+            block, router, gate, up, down = moe_names
+            sd[p + f"{block}.{router}.weight"] = np.asarray(m["router"][i]).T
             for j in range(cfg.moe.num_experts):
-                e = p + f"block_sparse_moe.experts.{j}."
-                sd[e + "w1.weight"] = np.asarray(m["w_gate"][i, j]).T
-                sd[e + "w2.weight"] = np.asarray(m["w_down"][i, j]).T
-                sd[e + "w3.weight"] = np.asarray(m["w_up"][i, j]).T
+                e = p + f"{block}.experts.{j}."
+                sd[e + f"{gate}.weight"] = np.asarray(m["w_gate"][i, j]).T
+                sd[e + f"{down}.weight"] = np.asarray(m["w_down"][i, j]).T
+                sd[e + f"{up}.weight"] = np.asarray(m["w_up"][i, j]).T
         else:
             sd[p + "mlp.gate_proj.weight"] = np.asarray(m["w_gate"][i]).T
             sd[p + "mlp.up_proj.weight"] = np.asarray(m["w_up"][i]).T
@@ -329,6 +315,62 @@ register_hf_family(
         config_to_hf=_mixtral_config_to_hf,
         params_from_hf=_llama_like_params_from_hf,
         params_to_hf=_llama_like_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# OLMoE (llama-like + MoE without renormalised top-k + full-width q/k norm)
+# --------------------------------------------------------------------------- #
+
+
+def _olmoe_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """``intermediate_size`` is the width of ONE expert; ``q_norm`` /
+    ``k_norm`` are RMSNorms over the whole projection (``qk_norm_over=
+    "full"``); the top-k router weights are used as the softmax gives them
+    unless ``norm_topk_prob``; ``clip_qkv`` (null in the released configs)
+    is refused rather than dropped."""
+    if hf.get("clip_qkv") is not None:
+        raise ValueError("olmoe: clip_qkv is not supported")
+    base = _llama_like_config_from_hf(hf, qk_layernorm=True)
+    return dataclasses.replace(
+        base,
+        qk_norm_over="full",
+        mlp_type="moe",
+        moe=MoEConfig(
+            num_experts=hf["num_experts"],
+            top_k=hf["num_experts_per_tok"],
+            aux_loss_coeff=hf.get("router_aux_loss_coef", 0.0),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        ),
+    )
+
+
+def _olmoe_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    hf = _llama_like_config_to_hf(cfg, "olmoe")
+    del hf["head_dim"]      # not a key of OlmoeConfig: hidden / heads
+    hf.update(
+        num_experts=cfg.moe.num_experts,
+        num_experts_per_tok=cfg.moe.top_k,
+        norm_topk_prob=cfg.moe.norm_topk_prob,
+        router_aux_loss_coef=cfg.moe.aux_loss_coeff,
+        clip_qkv=None,
+    )
+    return hf
+
+
+register_hf_family(
+    HFFamily(
+        name="olmoe",
+        hf_model_type="olmoe",
+        config_from_hf=_olmoe_config_from_hf,
+        config_to_hf=_olmoe_config_to_hf,
+        params_from_hf=lambda sd, cfg: _llama_like_params_from_hf(
+            sd, cfg, _OLMOE_MOE
+        ),
+        params_to_hf=lambda params, cfg: _llama_like_params_to_hf(
+            params, cfg, _OLMOE_MOE
+        ),
     )
 )
 

@@ -86,8 +86,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.use_attn_proj_bias:
         attn["bo"] = jnp.zeros((L, E), dtype)
     if cfg.qk_layernorm:
-        attn["q_norm"] = jnp.ones((L, D), dtype)
-        attn["k_norm"] = jnp.ones((L, D), dtype)
+        # per head [L, D] (qwen3) or over the whole projection (olmoe)
+        full = cfg.qk_norm_full
+        attn["q_norm"] = jnp.ones((L, Hq * D if full else D), dtype)
+        attn["k_norm"] = jnp.ones((L, Hkv * D if full else D), dtype)
 
     if cfg.mlp_type == "gated":
         mlp: Dict[str, Any] = {
@@ -158,8 +160,10 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if cfg.use_attn_proj_bias:
         attn["bo"] = ("layer", "embed")
     if cfg.qk_layernorm:
-        attn["q_norm"] = ("layer", None)
-        attn["k_norm"] = ("layer", None)
+        # a full-width gain is laid out like the projection it scales
+        width = "heads" if cfg.qk_norm_full else None
+        attn["q_norm"] = ("layer", width)
+        attn["k_norm"] = ("layer", width)
 
     if cfg.mlp_type == "gated":
         mlp: Dict[str, Any] = {
@@ -175,9 +179,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     else:  # moe
         # Expert parallelism: the expert dim takes the `model` mesh axis, so
         # the per-expert F dim must stay unsharded (one mesh axis can map to
-        # at most one dim of a param). Dense dispatch contracts over the
-        # sharded expert dim (one psum); ragged dispatch runs with experts
-        # gathered per device — see ``ops/moe.py``.
+        # at most one dim of a param). How each dispatch behaves under a
+        # sharded expert dim is in ``ops/moe.py``.
         mlp = {
             "router": ("layer", "embed", None),
             "w_gate": ("layer", "expert", "embed", None),
@@ -221,21 +224,32 @@ def _cast(cfg: ModelConfig, p):
 
 
 def _qkv(cfg: ModelConfig, p, x):
-    """x: [..., E] -> q [..., Hq, D], k/v [..., Hkv, D] (rope NOT yet applied)."""
-    D = cfg.head_dim
+    """x: [..., E] -> q [..., Hq, D], k/v [..., Hkv, D] (rope NOT yet applied).
 
-    def proj(w, b, h):
+    The q/k norm is one of two kinds (``cfg.qk_norm_over``): over each
+    head's ``D`` after the split into heads (qwen3), or over the WHOLE
+    projected vector before the split (olmoe). Under tensor parallelism
+    the projection's last axis is sharded over heads, and the full-width
+    norm's mean of squares is a reduction over that axis: GSPMD completes
+    it with one all-reduce of a scalar a token (tested on the CPU mesh)."""
+    D = cfg.head_dim
+    eps = cfg.layer_norm_epsilon
+    full = cfg.qk_norm_full
+
+    def proj(w, b, h, full_gain=None):
         y = x @ w
         if b is not None:
             y = y + b
+        if full_gain is not None:
+            y = norms.rms_norm(y, full_gain, eps)
         return y.reshape(*x.shape[:-1], h, D)
 
-    q = proj(p["wq"], p.get("bq"), cfg.n_q_heads)
-    k = proj(p["wk"], p.get("bk"), cfg.n_kv_heads)
+    q = proj(p["wq"], p.get("bq"), cfg.n_q_heads, p["q_norm"] if full else None)
+    k = proj(p["wk"], p.get("bk"), cfg.n_kv_heads, p["k_norm"] if full else None)
     v = proj(p["wv"], p.get("bv"), cfg.n_kv_heads)
-    if cfg.qk_layernorm:
-        q = norms.rms_norm(q, p["q_norm"], cfg.layer_norm_epsilon)
-        k = norms.rms_norm(k, p["k_norm"], cfg.layer_norm_epsilon)
+    if cfg.qk_layernorm and not full:
+        q = norms.rms_norm(q, p["q_norm"], eps)
+        k = norms.rms_norm(k, p["k_norm"], eps)
     return q, k, v
 
 
@@ -253,11 +267,13 @@ def _rotary_cfg(cfg: ModelConfig) -> RotaryConfig:
 
 
 def _mlp(cfg: ModelConfig, p, x):
-    """Returns (out, aux_loss) — aux is the MoE load-balancing/z loss
-    (``jnp`` scalar, 0 for dense MLPs)."""
+    """Returns (out, aux_loss, routing) — aux is the MoE load-balancing/z
+    loss (``jnp`` scalar, 0 for dense MLPs); routing the experts each token
+    chose, ``[..., top_k]`` int32 (``None`` for dense MLPs)."""
     act = ACT2FN[cfg.activation_function]
     if cfg.mlp_type == "gated":
-        return (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"], jnp.float32(0.0)
+        out = (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
+        return out, jnp.float32(0.0), None
     if cfg.mlp_type == "fc":
         h = x @ p["w_fc"]
         if "b_fc" in p:
@@ -266,7 +282,7 @@ def _mlp(cfg: ModelConfig, p, x):
         h = h @ p["w_proj"]
         if "b_proj" in p:
             h = h + p["b_proj"]
-        return h, jnp.float32(0.0)
+        return h, jnp.float32(0.0), None
     # moe
     from areal_tpu.ops.moe import moe_mlp
 
@@ -333,10 +349,13 @@ def forward_packed(
     remat: bool = True,
     with_aux: bool = False,
     with_head: bool = True,
+    with_routing: bool = False,
 ) -> jnp.ndarray:
     """Full forward over a packed token axis. Returns ``[T, vocab]`` logits
     (fp32) or ``[T, 1]`` values for critics; with ``with_aux`` returns
     ``(out, aux_loss)`` where aux is the summed MoE router loss over layers.
+    ``with_routing`` (MoE models) appends the experts every token chose in
+    every layer, int32 ``[L, T, top_k]``.
     ``with_head=False`` returns the final-norm HIDDEN states ``[T, E]``
     instead — the chunked-loss path applies the head per token block so the
     ``[T, vocab]`` logits (4 GB f32 at 32k x 32k) never materialize.
@@ -373,8 +392,8 @@ def forward_packed(
     def _post(x, ctx, lp):
         x = x + _attn_out(lp["attn"], ctx)
         h = _norm(cfg, lp["ln2"], x)
-        m, aux = _mlp(cfg, lp["mlp"], h)
-        return x + m, aux
+        m, aux, routing = _mlp(cfg, lp["mlp"], h)
+        return x + m, (aux, routing)
 
     policy = cfg.remat_policy if remat else "none"
     dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -417,14 +436,19 @@ def forward_packed(
             layer = jax.checkpoint(layer, policy=dots, prevent_cse=False)
         elif policy != "none":
             raise ValueError(f"unknown remat_policy {policy!r}")
-    x, auxes = jax.lax.scan(
+    x, (auxes, routing) = jax.lax.scan(
         layer, x, params["layers"], unroll=cfg.layer_scan_unroll or 1
     )
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     out = _head(cfg, params, x) if with_head else x
+    res = (out,)
     if with_aux:
-        return out, jnp.sum(auxes)
-    return out
+        res += (jnp.sum(auxes),)
+    if with_routing:
+        if routing is None:
+            raise ValueError("with_routing: the model has no router")
+        res += (routing,)
+    return res if len(res) > 1 else out
 
 
 def chunked_next_token_logprobs(
@@ -921,6 +945,7 @@ def decode_step_paged(
     mesh=None,
     with_head: bool = True,
     return_hidden: bool = False,
+    with_routing: bool = False,
 ) -> Tuple[Optional[jnp.ndarray], PagedKVCache, jnp.ndarray]:
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
     cache, new lens — incremented where active). The pool is read-only in
@@ -950,7 +975,12 @@ def decode_step_paged(
     ``return_hidden=True`` (STATIC) returns the final-norm HIDDEN states
     ``[B, E]`` in place of logits for the fused sampling epilogue
     (``ops/fused_sample.py``), which streams the head itself — the
-    ``[B, V]`` logits never materialize."""
+    ``[B, V]`` logits never materialize.
+
+    ``with_routing=True`` (STATIC, MoE models) appends a fourth result:
+    the experts every slot's token chose in every layer, int32
+    ``[L, B, top_k]`` in slot order, free and finished slots included
+    (they run through the experts like any other row)."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     new_lens = jnp.where(active, lens + 1, lens)
@@ -982,10 +1012,10 @@ def decode_step_paged(
         )
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
         h = _norm(cfg, lp["ln2"], x)
-        x = x + _mlp(cfg, lp["mlp"], h)[0]
-        return (x, li + 1), (k, v)
+        m, _, routing = _mlp(cfg, lp["mlp"], h)
+        return (x + m, li + 1), (k, v, routing if with_routing else None)
 
-    (x, _), (ks, vs) = jax.lax.scan(
+    (x, _), (ks, vs, routing) = jax.lax.scan(
         layer, (x, jnp.int32(0)), params["layers"]
     )
     x, ks, vs = x[inverse], ks[:, inverse], vs[:, inverse]
@@ -993,9 +1023,15 @@ def decode_step_paged(
         cache, ks[:, :, None], vs[:, :, None], table,
         lens[:, None], active[:, None],
     )
+    if with_routing:
+        if routing is None:
+            raise ValueError("with_routing: the model has no router")
+        extra = (routing[:, inverse],)
+    else:
+        extra = ()
     if not with_head:
-        return None, cache, new_lens
+        return (None, cache, new_lens) + extra
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     if return_hidden:
-        return x, cache, new_lens
-    return _head(cfg, params, x), cache, new_lens
+        return (x, cache, new_lens) + extra
+    return (_head(cfg, params, x), cache, new_lens) + extra

@@ -1,37 +1,60 @@
 """Mixture-of-experts MLP (top-k router + experts).
 
 TPU-native counterpart of ``realhf/impl/model/modules/moe/`` (router.py,
-experts.py, token_dispatcher.py, layer.py — ~700 LoC). Two dispatch modes,
-selected by ``MoEConfig.dispatch``:
+experts.py, token_dispatcher.py, layer.py — ~700 LoC). ONE dispatch:
+every expert is computed for every token and the router's combine weights
+(zero where an expert was not chosen) are folded into the activations
+before the down projection, so the result is one contraction over
+(expert, width) and no ``[T, X, E]`` tensor is ever formed. It is correct
+under any sharding of the expert axis (the contraction runs over the
+sharded expert dim: expert parallelism by one psum), differentiates
+without special cases, and XLA fuses each layer's slice of the stacked
+weights straight into the matmuls.
 
-- ``"dense"``: every expert computed for every token, combined with the
-  routing weights. The correctness-first XLA path; also the right path when
-  the expert axis is sharded (the combine einsum contracts over the sharded
-  expert dim, giving expert parallelism via one psum).
-- ``"ragged"``: the reference's permute-tokens-per-expert grouped-GEMM scheme
-  (``token_dispatcher.py``), TPU-native: sort token copies by expert id and
-  run ``lax.ragged_dot`` (megablox-style) over contiguous expert groups.
-  O(T·K) expert FLOPs instead of O(T·X) — the fast path when experts are
-  replicated per device.
+Why not a grouped matmul. Until PR 26 a second path sorted the token
+copies by expert and ran ``lax.ragged_dot`` (the reference's
+permute-tokens-per-expert scheme, O(T·K) FLOPs instead of O(T·X)). Timed
+on a TPU v5e at OLMoE-1B-7B widths (64 experts of 2048 x 1024, 8 a token,
+bf16, experts alone, ms a layer; chip runs of PR 26, PERF.md §6):
+
+    tokens T            64     1024     4096   | 1024 fwd+bwd  4096 fwd+bwd
+    this path          1.14    4.40    17.4    |    22.6          77.2
+    ``ragged_dot``     5.01    6.39    12.1    |    31.3          56.2
+
+XLA lowers ``ragged_dot`` to a Mosaic kernel of its own, but each of its
+three calls a layer takes a materialised copy of that layer's slice of
+the stacked weights (3 x the expert bytes moved), and the kernel reaches
+about a quarter of the MXU's peak. This path runs at 96 % of the peak at
+T = 4096 and at the weights' HBM time at T = 64, where every expert is
+hit anyway (decode at 64 slots: P(an expert gets no token) = 0.03 %). So
+``ragged_dot`` loses 4.4 x in decode and 1.4 x in a 1024-token admission
+wave, and wins 1.4 x only at the trainer's 4096 tokens, where it brought
+a ``custom_vmap`` rule that could not be differentiated outside ``vmap``
+and, under a sharded expert axis, an all-gather of every expert's
+weights that the code cannot see coming. One path was kept. The 8 x
+wasted FLOPs at large T are what a real grouped-matmul kernel would win
+back (ROADMAP); ``ragged_dot`` as this JAX lowers it wins a sixth of them.
 
 Router runs in fp32 (matches the reference's fp32 router,
 ``moe/router.py``).
 """
-
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
 from areal_tpu.ops.activations import ACT2FN
 
+# the expert matmuls run under this scope: their HLO ``op_name`` carries it
+# (dumps, profilers that keep metadata). A v5e's xplane keeps none, so the
+# benchmark's ``moe.*`` readers find these ops by what they stream
+# (``benchmark/moe_flops.py``)
+EXPERTS_SCOPE = "moe_experts"
+
 
 def _route(cfg, router_w, x):
-    """fp32 router shared by both dispatch paths.
-
-    Returns (top_vals [T, K] — normalized+scaled combine weights,
-    top_idx [T, K], probs [T, X], logits [T, X]).
-    """
+    """fp32 router. Returns (top_vals [T, K] — the combine weights, scaled
+    and, if the family asks, renormalised —, top_idx [T, K], probs [T, X],
+    logits [T, X])."""
     moe = cfg.moe
     logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -41,137 +64,47 @@ def _route(cfg, router_w, x):
     return top_vals * moe.routed_scaling_factor, top_idx, probs, logits
 
 
-def router_probs(cfg, p, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Returns (combine_weights [T, X], router_logits [T, X])."""
-    top_vals, top_idx, probs, logits = _route(cfg, p["router"], x)
-    combine = jnp.zeros_like(probs)
-    combine = jnp.put_along_axis(  # scatter top-k weights back to [T, X]
-        combine, top_idx, top_vals, axis=-1, inplace=False
-    )
-    return combine, logits
-
-
-def _aux_tail(cfg, frac_tokens, probs, logits):
-    """Switch-style load-balance + z loss from precomputed routing stats."""
+def _aux_loss(cfg, chosen, probs, logits):
+    """Switch-style load-balance + z loss (≈ ``moe/router.py``) in fp32.
+    ``chosen`` [T, X] is 1 where the token chose the expert."""
     moe = cfg.moe
+    frac_tokens = jnp.mean(chosen, axis=0)
     frac_probs = jnp.mean(probs, axis=0)
     aux = moe.num_experts * jnp.sum(frac_tokens * frac_probs)
     z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
     return moe.aux_loss_coeff * aux + moe.z_loss_coeff * z
 
 
-def load_balancing_aux_loss(cfg, combine: jnp.ndarray, logits: jnp.ndarray):
-    """Switch-style aux loss (≈ ``moe/router.py`` aux loss) in fp32."""
-    probs = jax.nn.softmax(logits, axis=-1)
-    frac_tokens = jnp.mean((combine > 0).astype(jnp.float32), axis=0)
-    return _aux_tail(cfg, frac_tokens, probs, logits)
-
-
 def moe_mlp(cfg, p, x):
-    """x: [..., E] -> ([..., E], aux_loss). Dispatch per ``cfg.moe.dispatch``.
+    """x: [..., E] -> (out [..., E], aux_loss, top_idx [..., K]).
 
-    The aux loss includes padding tokens (the layer has no mask); with packed
-    batches the padding fraction is small and its router logits are the
-    uniform x=0 output, so the bias is negligible.
+    ``top_idx`` are the experts each token chose (largest weight first):
+    the generation engine counts them (``moe_experts_hit``) and can hand
+    them to the trainer, whose recompute counts how often it agrees
+    (``ppo/inference``'s ``router_agree``).
+
+    The aux loss includes padding tokens (the layer has no mask); with
+    packed batches the padding fraction is small and its router logits are
+    the uniform x=0 output, so the bias is negligible. Under the train
+    engine's ``vmap`` over packed rows it is a per-row loss, and the engine
+    takes the mean over rows.
     """
-    if cfg.moe.dispatch == "ragged":
-        return _moe_mlp_ragged(cfg, p, x)
-    if cfg.moe.dispatch != "dense":
-        raise ValueError(
-            f"MoEConfig.dispatch must be 'dense' or 'ragged', "
-            f"got {cfg.moe.dispatch!r}"
-        )
-    return _moe_mlp_dense(cfg, p, x)
-
-
-def _moe_mlp_dense(cfg, p, x):
     act = ACT2FN[cfg.activation_function]
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
-    combine, logits = router_probs(cfg, p, xt)
-    h = act(jnp.einsum("te,xef->txf", xt, p["w_gate"])) * jnp.einsum(
-        "te,xef->txf", xt, p["w_up"]
-    )
-    y = jnp.einsum("txf,xfe->txe", h, p["w_down"])
-    out = jnp.einsum("txe,tx->te", y, combine.astype(y.dtype))
-    aux = load_balancing_aux_loss(cfg, combine, logits)
-    return out.reshape(*lead, -1), aux
-
-
-def _moe_mlp_ragged(cfg, p, x):
-    """Grouped-GEMM dispatch: sort the T·K (token, expert) copies by expert id
-    so each expert's tokens are a contiguous row block, then three
-    ``lax.ragged_dot`` calls (gate/up/down) run one GEMM per expert without
-    materializing the [T, X, F] dense activations.
-
-    ``lax.ragged_dot`` has no batching rule, so under ``vmap`` over packed
-    rows (the train engine's ``vmapped_forward``) a ``custom_vmap`` rule
-    folds the row dim into the token dim — expert grouping is row-agnostic —
-    and broadcasts the globally-computed aux loss back to the rows (the
-    engine means it, recovering the global value).
-
-    Known corner: reverse-mode AD of an *un-vmapped* ragged call is
-    unsupported (``custom_vmap``'s unbatched application does not linearize
-    in current JAX). Every framework training path differentiates under
-    ``vmap`` (``vmapped_forward``), where the rule expands away before AD;
-    un-vmapped *forward* calls (generation) also work.
-    """
-    lead = x.shape[:-1]
-    xt = x.reshape(-1, x.shape[-1])
-    out, aux = _ragged_dispatch(
-        cfg, xt, p["router"], p["w_gate"], p["w_up"], p["w_down"]
-    )
-    return out.reshape(*lead, -1), aux
-
-
-def _ragged_dispatch(cfg, xt, router, w_gate, w_up, w_down):
-    @jax.custom_batching.custom_vmap
-    def core(xt, router, w_gate, w_up, w_down):
-        return _ragged_core(cfg, xt, router, w_gate, w_up, w_down)
-
-    @core.def_vmap
-    def core_vmap(axis_size, in_batched, xt, router, w_gate, w_up, w_down):
-        if any(in_batched[1:]):
-            raise NotImplementedError(
-                "ragged MoE dispatch: only activations may carry a vmap axis"
-            )
-        B, T, E = xt.shape
-        # Bottom out in the plain core: leaving a custom_vmap call in the
-        # expanded jaxpr breaks linearization. One vmap level is folded per
-        # rule application; a second enclosing vmap is unsupported.
-        out, aux = _ragged_core(
-            cfg, xt.reshape(B * T, E), router, w_gate, w_up, w_down
+    top_vals, top_idx, probs, logits = _route(cfg, p["router"], xt)
+    onehot = jax.nn.one_hot(top_idx, cfg.moe.num_experts, dtype=jnp.float32)
+    chosen = onehot.sum(axis=1)                                  # [T, X]
+    combine = (top_vals[:, :, None] * onehot).sum(axis=1)        # [T, X]
+    with jax.named_scope(EXPERTS_SCOPE):
+        h = act(jnp.einsum("te,xef->txf", xt, p["w_gate"])) * jnp.einsum(
+            "te,xef->txf", xt, p["w_up"]
         )
-        return (out.reshape(B, T, E), jnp.broadcast_to(aux, (B,))), (True, True)
-
-    return core(xt, router, w_gate, w_up, w_down)
-
-
-def _ragged_core(cfg, xt, router, w_gate, w_up, w_down):
-    """xt: [T, E] -> (out [T, E], aux scalar). Static shapes throughout
-    (argsort + bincount, no dynamic slicing), so the whole thing jits once
-    regardless of the routing realized at runtime."""
-    moe = cfg.moe
-    act = ACT2FN[cfg.activation_function]
-    T, K, X = xt.shape[0], moe.top_k, moe.num_experts
-
-    top_vals, top_idx, probs, logits = _route(cfg, router, xt)
-    flat_expert = top_idx.reshape(-1)  # [T*K]
-    order = jnp.argsort(flat_expert, stable=True)
-    tok_sorted = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)[order]
-    group_sizes = jnp.bincount(flat_expert, length=X).astype(jnp.int32)
-
-    xs = xt[tok_sorted]  # [T*K, E], expert-contiguous
-    dot = lambda a, w: jax.lax.ragged_dot(
-        a, w, group_sizes, preferred_element_type=jnp.float32
-    ).astype(xt.dtype)
-    h = act(dot(xs, w_gate)) * dot(xs, w_up)
-    y = dot(h, w_down)  # [T*K, E]
-    w = top_vals.reshape(-1)[order].astype(y.dtype)
-    out = jax.ops.segment_sum(y * w[:, None], tok_sorted, num_segments=T)
-
-    # Aux loss from the same quantities the dense path derives from `combine`:
-    # frac_tokens[x] = fraction of (token, slot) selections hitting expert x.
-    frac_tokens = group_sizes.astype(jnp.float32) / float(T)
-    aux = _aux_tail(cfg, frac_tokens, probs, logits)
-    return out.astype(xt.dtype), aux
+        h = h * combine.astype(h.dtype)[:, :, None]
+        out = jnp.einsum("txf,xfe->te", h, p["w_down"])
+    aux = _aux_loss(cfg, chosen, probs, logits)
+    return (
+        out.reshape(*lead, -1),
+        aux,
+        top_idx.reshape(*lead, cfg.moe.top_k),
+    )

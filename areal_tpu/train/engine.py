@@ -143,13 +143,13 @@ class OptimizerConfig:
 def vmapped_forward(
     params, cfg: ModelConfig, arrays: Dict[str, jnp.ndarray],
     with_aux: bool = False, with_head: bool = True,
+    with_routing: bool = False,
 ):
     """Model forward over ``[D, T]`` packed buffers -> ``[D, T, vocab|1]``.
     With ``with_aux``, returns ``(out, aux)`` where aux is the MoE router
-    loss (0 for non-MoE models). Estimator depends on the dispatch mode:
-    dense computes per-row losses and this returns their mean; ragged
-    computes one whole-batch loss over all rows' tokens (see ``ops/moe.py``)
-    — numerically different objectives for nonzero aux coefficients.
+    loss (0 for non-MoE models): each row's own loss (``ops/moe.py``), and
+    this returns their mean. ``with_routing`` (MoE models) appends the
+    experts every token chose, ``[D, L, T, top_k]``.
 
     ``spmd_axis_name`` tells any shard_map inside (the context-parallel
     attention ring) that the vmapped row axis lives on the data axes —
@@ -157,42 +157,45 @@ def vmapped_forward(
     out = jax.vmap(
         lambda ids, seg, pos: tfm.forward_packed(
             params, cfg, ids, seg, pos, with_aux=with_aux,
-            with_head=with_head,
+            with_head=with_head, with_routing=with_routing,
         ),
         spmd_axis_name=("data", "fsdp"),
     )(arrays["input_ids"], arrays["segment_ids"], arrays["positions"])
     if with_aux:
-        logits, aux = out
-        return logits, jnp.mean(aux)
+        logits, aux, *rest = out
+        return (logits, jnp.mean(aux), *rest)
     return out
 
 
-def vmapped_next_token_logprobs(params, cfg, arrays, with_aux: bool = False):
+def vmapped_next_token_logprobs(
+    params, cfg, arrays, with_aux: bool = False, with_routing: bool = False
+):
     """Token-aligned next-token logprobs over ``[D, T]`` packed buffers —
     the shared primitive behind the SFT loss, the PPO logprob-recompute
     MFC, and the PPO actor loss. Honors ``cfg.loss_chunk_size``: the LM
     head + softmax + gather run per token block under remat so the
     ``[T, vocab]`` logits (4 GB f32 at the 32k protocol shape) never
-    materialize on ANY of those paths."""
+    materialize on ANY of those paths. Returns ``lp``, or ``(lp, aux)`` /
+    ``(lp, routing)`` / ``(lp, aux, routing)`` as asked."""
     from areal_tpu.ops import ppo as ppo_ops
 
-    if cfg.loss_chunk_size:
-        out = vmapped_forward(
-            params, cfg, arrays, with_aux=with_aux, with_head=False
-        )
-        hidden, aux = out if with_aux else (out, None)
+    chunked = bool(cfg.loss_chunk_size)
+    out = vmapped_forward(
+        params, cfg, arrays, with_aux=with_aux, with_head=not chunked,
+        with_routing=with_routing,
+    )
+    head_in, *extra = out if (with_aux or with_routing) else (out,)
+    if chunked:
         lp = jax.vmap(
             lambda h, ids, seg: tfm.chunked_next_token_logprobs(
                 params, cfg, h, ids, seg, chunk=cfg.loss_chunk_size
             )
-        )(hidden, arrays["input_ids"], arrays["segment_ids"])
+        )(head_in, arrays["input_ids"], arrays["segment_ids"])
     else:
-        out = vmapped_forward(params, cfg, arrays, with_aux=with_aux)
-        logits, aux = out if with_aux else (out, None)
         lp = jax.vmap(ppo_ops.gather_packed_shifted_log_probs)(
-            logits, arrays["input_ids"], arrays["segment_ids"]
+            head_in, arrays["input_ids"], arrays["segment_ids"]
         )
-    return (lp, aux) if with_aux else lp
+    return (lp, *extra) if extra else lp
 
 
 class TrainEngine:

@@ -29,6 +29,9 @@ from jax.sharding import SingleDeviceSharding
 # the 1.5B profile (bench.py `_gen_model_cfg`) and the 125M preset
 QWEN_1P5B = dict(hq=12, hkv=2, d=128)
 PRESET_125M = dict(hq=12, hkv=4, d=64)
+# OLMoE-1B-7B: multi-head (n_rep 1); 16 kv heads x 128 at page 128 leave
+# room for ONE slot x 8 pages in the paged kernel's 16 MiB of scratch
+OLMOE = dict(hq=16, hkv=16, d=128)
 T_TRAIN = 4096          # 8 x 512 packed tokens: the default train step
 
 
@@ -142,8 +145,9 @@ def test_flash_long_context_block_compiles(compiled_kernels, one_chip):
     )
 
 
-def _paged_specs(one_chip, *, page, int8, L=28, B=64, P=256, M=16):
-    hq, hkv, d = QWEN_1P5B["hq"], QWEN_1P5B["hkv"], QWEN_1P5B["d"]
+def _paged_specs(one_chip, *, page, int8, L=28, B=64, P=256, M=16,
+                 layout=QWEN_1P5B):
+    hq, hkv, d = layout["hq"], layout["hkv"], layout["d"]
     specs = [
         _spec((B, hq, d), jnp.bfloat16, one_chip),
         _spec((B, hkv, d), jnp.bfloat16, one_chip),
@@ -179,6 +183,21 @@ def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8):
         )
 
     _compile(f, *_paged_specs(one_chip, page=page, int8=int8))
+
+
+def test_paged_decode_compiles_at_16_kv_heads(compiled_kernels, one_chip):
+    """The OLMoE cell's shape: 64 slots, 16q/16kv x 128 (n_rep 1), page
+    128, a table of 32 pages. The block plan leaves one slot a grid step
+    (8 pages x 16 heads x 128 x 128 x 2 B x K and V, double-buffered, is
+    exactly the 16 MiB the plan allows), and Mosaic takes it."""
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    assert pl_paged.block_plan(64, 16, 128, 128, 32, jnp.bfloat16) == (1, 8)
+    _compile(
+        pl_paged.decode,
+        *_paged_specs(one_chip, page=128, int8=False, L=8, P=715, M=32,
+                      layout=OLMOE),
+    )
 
 
 def test_int8_page64_turned_away_by_the_gate(compiled_kernels):
