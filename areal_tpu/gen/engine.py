@@ -602,6 +602,9 @@ class GenerationEngine:
             # paged-decode kernel computes over / KV tokens resident
             "kernel_positions": 0,
             "resident_tokens": 0,
+            # ... and its grid steps that reach a page / all its grid steps
+            "kernel_steps_active": 0,
+            "kernel_steps": 0,
             # MoE models, per vanilla chunk (every row of the batch routes,
             # free slots too: the expert matmuls read what they route to):
             # distinct experts with a token, summed over layers and steps /
@@ -1968,21 +1971,24 @@ class GenerationEngine:
             # pipelined mode less the chunk still in flight)
             resident = int(lens.sum())
             chunk_attrs["resident_tokens"] = resident
-            positions = self._kernel_positions(W)
-            if positions is not None:
-                chunk_attrs["kernel_positions"] = positions
-                self.stats["kernel_positions"] += positions
+            counts = self._kernel_counts(W)
+            if counts is not None:
+                chunk_attrs.update(counts)
+                for name, n in counts.items():
+                    self.stats[name] += n
                 self.stats["resident_tokens"] += resident
             self._observe_occupancy()
             chunk = make(decode_steps, W, wb)
             return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
 
-    def _kernel_positions(self, W: int) -> Optional[int]:
-        """KV positions the paged-decode kernel's body runs over at the
-        first step of a vanilla chunk of table width ``W``: the kernel's
-        own block plan over the host's lengths, sorted as
-        ``decode_step_paged`` sorts its rows. Over ``resident_tokens`` it
-        is how many times the resident KV the kernel computes. Free slots
+    def _kernel_counts(self, W: int) -> Optional[Dict[str, int]]:
+        """What the paged-decode kernel does at the first step of a vanilla
+        chunk of table width ``W``, from the kernel's own block plan over
+        the host's lengths, sorted as ``decode_step_paged`` sorts its rows.
+        ``kernel_positions``: KV positions its body runs over (over
+        ``resident_tokens``, how many times the resident KV it computes);
+        ``kernel_steps_active`` of ``kernel_steps`` grid steps reach a page
+        and walk their table entries (the rest cost one test). Free slots
         count as empty (on the device a finished slot keeps its length
         until it is refilled). ``None`` where the chunk runs no such
         kernel: the XLA gather path, a speculative chunk."""
@@ -2000,9 +2006,13 @@ class GenerationEngine:
             self.B, cfg.n_kv_heads // tp, cfg.head_dim, self.page, W,
             pool_dtype,
         )
-        return pl_paged.kernel_positions(
-            np.sort(self._lens_host), sb, kp * self.page
-        )
+        lens, span = np.sort(self._lens_host), kp * self.page
+        active, total = pl_paged.kernel_steps(lens, sb, span, -(-W // kp))
+        return {
+            "kernel_positions": pl_paged.kernel_positions(lens, sb, span),
+            "kernel_steps_active": active,
+            "kernel_steps": total,
+        }
 
     def _mark_first(self, slots) -> None:
         """``t_first`` for the slots whose first chunk just resolved."""

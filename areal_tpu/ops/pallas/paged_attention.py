@@ -11,15 +11,24 @@ reference inherits (SURVEY §2.1).
 
 Grid ``(B/SB, ceil(M/KP))``: SB slots x KP pages (``S = KP * page``
 positions) per step, ``(SB, KP)`` from :func:`block_plan`. What a call
-costs has three parts (measured alone on a TPU v5e at 128 slots x 12q/2kv
-x 128, page 128, table widths 32-64; PERF.md, PR 25):
+costs has four parts (measured alone on a TPU v5e at page 128, bf16, at
+128 slots x 12q/2kv x 128 with a table of 40 pages unless said; PERF.md,
+PRs 25 and 27):
 
-- every grid step walks its ``SB * KP`` table entries on the scalar core
-  (one issue, one zero and one wait branch each), page or no page, work
-  or no work in the step: about 36 ns an entry, so ``B * M`` entries cost
-  0.19 ms a call at a table of 40 pages with NO token resident. Block
-  shape does not change it (the entries are the same); a narrower table
-  does;
+- a grid step costs 0.3-0.45 us whatever it holds (the pipeline's block
+  copies and ONE test of the block's longest row against the step's first
+  position): 80 steps 0.026 ms, 256 steps (64 slots, 16 kv heads, SB 1)
+  0.11 ms, with NO token resident. A step that row does not reach has no
+  page to fetch and nothing the body would read, and does nothing else:
+  no copy started, no page zeroed, no wait, no body.
+  :func:`kernel_steps` counts the steps that are reached (a third of them
+  on heavy-tailed rollout traffic, once rows are sorted);
+- a step that IS reached walks its ``SB * KP`` table entries on the scalar
+  core (a start and a zero branch for the next step, a wait branch for
+  this one), page or no page: about 36 ns an entry (all ``B * M`` of them
+  would be 0.19 ms a call). Bounding the walk by each row's own page
+  count (dynamic loops in place of the unrolled branches) measured
+  0.1-0.2 % of a call and is not taken;
 - page DMAs are issued per slot, only for pages the slot holds, so the
   bytes read from HBM are the resident KV and no more; they stream at
   about 950 GB/s and overlap the dots;
@@ -31,13 +40,12 @@ x 128, page 128, table widths 32-64; PERF.md, PR 25):
   = NaN`` in the PV dot). :func:`kernel_positions` counts that:
   ``SB * S * ceil(max_len / S)`` summed over blocks. Rows of mixed length
   in one block are work over positions that hold no KV (2.4 x the
-  resident KV with rows in random order, 1.5 x sorted; 0.06 ms of a 0.44
-  ms call).
+  resident KV with rows in random order, 1.5 x sorted).
 
 THE CALLER ORDERS ROWS BY LENGTH (``decode_step_paged`` sorts the batch
 once per step, before its layer scan), so a block's rows are of
 neighbouring length and its maximum is close to every member's; empty
-rows (``lens`` 0) gather in the first blocks, whose body is skipped
+and short rows gather in the first blocks, whose later steps are skipped
 outright. Rows of a block are independent in the batched dots and a page
 block that is all masked for a row leaves its ``m``/``l``/``acc`` as they
 were, so a row's result does not depend on which rows share its block.
@@ -117,6 +125,13 @@ def block_plan(
     return sb, kp
 
 
+def _steps_reached(lens, sb: int, span: int) -> int:
+    """Grid steps whose block of ``sb`` consecutive rows of ``lens`` reaches
+    the step's first position: ``ceil(longest / span)`` a block."""
+    longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
+    return int((-(-longest // span)).sum())
+
+
 def kernel_positions(lens, sb: int, span: int) -> int:
     """KV positions the kernel's body runs over for rows of resident
     lengths ``lens`` IN THE ORDER THE KERNEL GETS THEM (host integers;
@@ -125,8 +140,16 @@ def kernel_positions(lens, sb: int, span: int) -> int:
     ``sb * span`` positions for each of the ``ceil(max_len / span)`` page
     blocks its longest row reaches (``span = kp * page``). Over the sum of
     ``lens`` it is how many times the resident KV the kernel computes."""
-    longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
-    return int(sb * span * (-(-longest // span)).sum())
+    return sb * span * _steps_reached(lens, sb, span)
+
+
+def kernel_steps(lens, sb: int, span: int, nblk: int) -> Tuple[int, int]:
+    """``(active, total)`` grid steps of a call over rows ``lens`` (in the
+    kernel's order, as for :func:`kernel_positions`) with ``nblk`` page
+    blocks a row: a block of ``sb`` rows is active in the steps its longest
+    row reaches. Only those walk their table entries, wait and run the
+    body; the others cost one test each."""
+    return _steps_reached(lens, sb, span), len(lens) // sb * nblk
 
 
 def _decode_kernel(
@@ -176,6 +199,7 @@ def _decode_kernel(
     g = bb * nblk + j         # linearized grid step
     Hq = q_ref.shape[1]
     D = q_ref.shape[2]
+    S = kp * page             # positions of one grid step
     layer = layer_ref[0]
 
     @pl.when(j == 0)
@@ -196,44 +220,48 @@ def _decode_kernel(
         max_lens_t = functools.reduce(
             jnp.maximum, [lens_ref[bb_t * sb + s] for s in range(sb)]
         )
-        for s in range(sb):
-            slot = bb_t * sb + s
-            n_used = pl.cdiv(lens_ref[slot], page)
-            for i in range(kp):
-                @pl.when(j_t * kp + i < n_used)
-                def _start(s=s, i=i, slot=slot, j_t=j_t):
-                    pidx = table_ref[slot, j_t * kp + i]
-                    # K and V are interleaved per page: ONE DMA per page,
-                    # landing in the [2, Hkv, i*page:(i+1)*page, D] stripe
-                    # of the compute-layout scratch
-                    pltpu.make_async_copy(
-                        kv_hbm.at[layer, pidx],
-                        kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
-                        sems.at[buf, s, i],
-                    ).start()
-                    if quantized:
-                        # the page's scale stripe rides a second (tiny —
-                        # 1/D of the page bytes) DMA into the parallel
-                        # scale scratch; dequant happens in-register at
-                        # the dots, never as a widened pool copy
-                        pltpu.make_async_copy(
-                            sc_hbm.at[layer, pidx],
-                            sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
-                            sc_sems.at[buf, s, i],
-                        ).start()
 
-                @pl.when(
-                    (j_t * kp + i >= n_used)
-                    & (j_t * kp * page < max_lens_t)
-                )
-                def _zero(s=s, i=i, j_t=j_t):
-                    kv_scr[buf, s, :, :, pl.ds(i * page, page), :] = (
-                        jnp.zeros((2, n_kv, page, D), kv_scr.dtype)
-                    )
-                    if quantized:
-                        sc_scr[buf, s, :, :, pl.ds(i * page, page)] = (
-                            jnp.zeros((2, n_kv, page), sc_scr.dtype)
+        # a step its block's longest row does not reach has no page to
+        # fetch and nothing the body will read: ONE test skips its SB * KP
+        # entries (most steps, once the caller has sorted rows by length)
+        @pl.when(j_t * S < max_lens_t)
+        def _reached():
+            for s in range(sb):
+                slot = bb_t * sb + s
+                n_used = pl.cdiv(lens_ref[slot], page)
+                for i in range(kp):
+                    @pl.when(j_t * kp + i < n_used)
+                    def _start(s=s, i=i, slot=slot):
+                        pidx = table_ref[slot, j_t * kp + i]
+                        # K and V are interleaved per page: ONE DMA per
+                        # page, landing in the [2, Hkv, i*page:(i+1)*page,
+                        # D] stripe of the compute-layout scratch
+                        pltpu.make_async_copy(
+                            kv_hbm.at[layer, pidx],
+                            kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
+                            sems.at[buf, s, i],
+                        ).start()
+                        if quantized:
+                            # the page's scale stripe rides a second (tiny
+                            # — 1/D of the page bytes) DMA into the
+                            # parallel scale scratch; dequant happens
+                            # in-register at the dots, never as a widened
+                            # pool copy
+                            pltpu.make_async_copy(
+                                sc_hbm.at[layer, pidx],
+                                sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
+                                sc_sems.at[buf, s, i],
+                            ).start()
+
+                    @pl.when(j_t * kp + i >= n_used)
+                    def _zero(s=s, i=i):
+                        kv_scr[buf, s, :, :, pl.ds(i * page, page), :] = (
+                            jnp.zeros((2, n_kv, page, D), kv_scr.dtype)
                         )
+                        if quantized:
+                            sc_scr[buf, s, :, :, pl.ds(i * page, page)] = (
+                                jnp.zeros((2, n_kv, page), sc_scr.dtype)
+                            )
 
     # Software pipeline over the (sequential) linearized grid: step g's
     # pages were prefetched at step g-1; here we kick off g+1's DMAs BEFORE
@@ -251,26 +279,35 @@ def _decode_kernel(
     def _prefetch():
         _issue(g + 1, jax.lax.rem(g + 1, 2))
 
-    for s in range(sb):
-        slot = bb * sb + s
-        n_used = pl.cdiv(lens_ref[slot], page)
-        for i in range(kp):
-            @pl.when(j * kp + i < n_used)
-            def _wait(s=s, i=i, slot=slot):
-                pidx = table_ref[slot, j * kp + i]
-                pltpu.make_async_copy(
-                    kv_hbm.at[layer, pidx],
-                    kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
-                    sems.at[buf, s, i],
-                ).wait()
-                if quantized:
-                    pltpu.make_async_copy(
-                        sc_hbm.at[layer, pidx],
-                        sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
-                        sc_sems.at[buf, s, i],
-                    ).wait()
+    max_lens = functools.reduce(
+        jnp.maximum, [lens_ref[bb * sb + s] for s in range(sb)]
+    )
+    # the predicate _issue started this step's copies under, over the same
+    # scalars: every started copy is waited for, and a step that started
+    # none tests nothing
+    reached = j * S < max_lens
 
-    S = kp * page
+    @pl.when(reached)
+    def _arrived():
+        for s in range(sb):
+            slot = bb * sb + s
+            n_used = pl.cdiv(lens_ref[slot], page)
+            for i in range(kp):
+                @pl.when(j * kp + i < n_used)
+                def _wait(s=s, i=i, slot=slot):
+                    pidx = table_ref[slot, j * kp + i]
+                    pltpu.make_async_copy(
+                        kv_hbm.at[layer, pidx],
+                        kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
+                        sems.at[buf, s, i],
+                    ).wait()
+                    if quantized:
+                        pltpu.make_async_copy(
+                            sc_hbm.at[layer, pidx],
+                            sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
+                            sc_sems.at[buf, s, i],
+                        ).wait()
+
     # per-slot resident lengths as an [SB, 1, S] operand built from stacked
     # scalar SPLATS (Mosaic rejects 1D->3D vector reshapes); the whole
     # block body is BATCHED over slots — one slot-folded-batch dot pair
@@ -281,11 +318,8 @@ def _decode_kernel(
         [jnp.full((1, S), lens_ref[bb * sb + s], jnp.int32)
          for s in range(sb)]
     )                                                          # [SB, 1, S]
-    max_lens = functools.reduce(
-        jnp.maximum, [lens_ref[bb * sb + s] for s in range(sb)]
-    )
 
-    @pl.when((j * S < max_lens) & (max_lens > 0))
+    @pl.when(reached)
     def _body():
         # (SB, Hkv) folds into ONE batch dim (Mosaic's tpu.matmul supports
         # a single batch dim); the reshape is layout-free

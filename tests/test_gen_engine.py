@@ -592,21 +592,25 @@ class TestEngineSpans:
                for c, _ in _chunks_with_children(tracing.drain())]
         assert res == [sum(plens) - 3 + 3 * 4 * k for k in range(3)]
 
-    @pytest.mark.parametrize("use_pallas", [True, None])
-    def test_kernel_positions_follow_the_kernels_block_plan(
-            self, params, rng, use_pallas):
+    @pytest.mark.parametrize("path", ["kernel", "xla", "spec"])
+    def test_kernel_counts_follow_the_kernels_block_plan(
+            self, params, rng, path):
         """``kernel_positions`` at a chunk's dispatch = what the paged
         kernel's body runs over at its first step: per block of ``sb``
         rows of the batch SORTED by length (as ``decode_step_paged`` hands
-        them over), ``sb`` x the page blocks of its longest row. Only on
-        chunks that run the kernel (forced on here, interpret mode; on the
-        CPU's own XLA gather path there is nothing to count), and the
+        them over), ``sb`` x the page blocks of its longest row; those page
+        blocks are ``kernel_steps_active`` of the ``kernel_steps`` grid
+        steps (blocks x page blocks of the table), the steps that still
+        walk their table entries. Only on chunks that run the kernel
+        (forced on here, interpret mode; on the CPU's own XLA gather path
+        and in a speculative chunk there is nothing to count), and the
         tokens are those of the gather path either way."""
         from areal_tpu.base import tracing
 
         eng = GenerationEngine(
-            CFG, params, max_slots=12, max_seqlen=256, page_size=8)
-        eng._decode_use_pallas = use_pallas
+            CFG, params, max_slots=12, max_seqlen=256, page_size=8,
+            spec_decode=path == "spec")
+        eng._decode_use_pallas = None if path == "xla" else True
         plens = (5, 150, 9, 70, 3, 130, 64, 20, 200)    # 3 slots stay free
         prompts = [[int(x) for x in rng.integers(1, 128, size=n)]
                    for n in plens]
@@ -615,26 +619,38 @@ class TestEngineSpans:
                 rid=f"r{i}", input_ids=p, max_new_tokens=8, greedy=True))
         tracing.drain()
         outs = {o.rid: o.output_ids for o in eng.run_until_done(4)}
-        attrs = [c["attrs"] for c, _ in _chunks_with_children(tracing.drain())]
-        assert len(attrs) == 2
-        if use_pallas is None:
-            assert all("kernel_positions" not in a for a in attrs)
-            assert eng.stats["kernel_positions"] == 0
+        chunks = _chunks_with_children(tracing.drain())
+        attrs = [c["attrs"] for c, _ in chunks]
+        names = ("kernel_positions", "kernel_steps_active", "kernel_steps")
+        if path != "kernel":
+            assert attrs
+            assert not any(n in a for a in attrs for n in names)
+            assert all(eng.stats[n] == 0 for n in names)
             return
+        assert len(attrs) == 2
         sb, span = 4, 8 * 8       # 12 slots: blocks of 4; 8 pages of 8
         want = []
         for k in range(2):
             lens = np.sort([0] * 3 + [n - 1 + 4 * k for n in plens])
             want.append(sum(
-                sb * span * -(-int(lens[b:b + sb].max()) // span)
+                -(-int(lens[b:b + sb].max()) // span)
                 for b in range(0, 12, sb)))
-        assert [a["kernel_positions"] for a in attrs] == want
+        assert [a["kernel_steps_active"] for a in attrs] == want
+        assert [a["kernel_positions"] for a in attrs] == [
+            sb * span * w for w in want]
         # slot order (5, 150, 9, 70 | 3, 130, 64, 20 | 200, -, -, -) would
         # take every block as far as a long row: 3 + 3 + 4 page blocks
-        assert want[0] == sb * span * (1 + 1 + 4) < sb * span * 10
-        assert eng.stats["kernel_positions"] == sum(want)
-        assert eng.stats["resident_tokens"] == sum(
-            a["resident_tokens"] for a in attrs)
+        assert want[0] == 1 + 1 + 4
+        # 3 blocks x the page blocks of the chunk's table: the block of
+        # short rows and the one with the free slots reach only the first
+        widths = [c["gen_engine/dispatch"]["attrs"]["table_width"]
+                  for _, c in chunks]
+        assert [a["kernel_steps"] for a in attrs] == [
+            3 * -(-w // 8) for w in widths]
+        assert all(a["kernel_steps_active"] < a["kernel_steps"]
+                   for a in attrs)
+        for n in names + ("resident_tokens",):
+            assert eng.stats[n] == sum(a[n] for a in attrs)
         ref = GenerationEngine(
             CFG, params, max_slots=12, max_seqlen=256, page_size=8)
         for i, p in enumerate(prompts):

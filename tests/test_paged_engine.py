@@ -489,6 +489,79 @@ class TestLengthOrderedDecode:
             np.asarray(order)[np.asarray(inverse)], np.arange(5))
 
 
+class TestPagedDecodeStepGate:
+    """The kernel against the XLA gather path at the shapes its step gate
+    decides on: a grid step whose slot block reaches no page starts no copy,
+    zeroes nothing, waits for nothing and runs no body, while the prefetch
+    of step g+1 keeps crossing between such steps and steps with work.
+    Blocks of 2 slots x 2 pages of 8 (a span of 16 positions) unless the
+    case says otherwise: the interpreter is slow."""
+
+    PAGE = 8
+
+    @pytest.mark.parametrize(
+        "lens,width,kp,sb,variant",
+        [
+            # empty block | block with work | empty block: the prefetch
+            # crosses from a skipped step into an active one and back
+            pytest.param([0, 0, 5, 20, 0, 0], 4, 2, 2, "plain",
+                         id="empty-work-empty"),
+            # one long row among empty ones: its block is active to the
+            # end, every page of the other row zeroed
+            pytest.param([0, 44, 0, 0], 6, 2, 2, "plain", id="one-long"),
+            # exactly at the span, one over, one under; a table whose last
+            # page block is partial (5 pages in blocks of 2)
+            pytest.param([16, 16, 17, 1, 15, 0, 32, 33], 5, 2, 2, "plain",
+                         id="span-edges"),
+            # the cells' table: 40 pages in 5 blocks of 8, rows sorted as
+            # ``decode_step_paged`` hands them over
+            pytest.param([0, 0, 3, 60, 64, 65, 130, 319], 40, 8, 2, "plain",
+                         id="table40-kp8"),
+            # one slot a step (the OLMoE cell's plan)
+            pytest.param([0, 20, 0, 33], 5, 2, 1, "plain", id="sb1"),
+            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "int8", id="int8"),
+            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "window",
+                         id="window"),
+        ],
+    )
+    def test_parity_vs_xla(self, lens, width, kp, sb, variant):
+        from areal_tpu.ops import paged_attention as xla_paged
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        rng = np.random.default_rng(len(lens) + width)
+        lens = np.asarray(lens, np.int32)
+        B, Hq, Hkv, D, L, page = len(lens), 2, 1, 16, 2, self.PAGE
+        P = B * width
+        assert pl_paged.block_plan(
+            B, Hkv, D, page, width, jnp.float32, kp, sb) == (sb, kp)
+        active, total = pl_paged.kernel_steps(
+            lens, sb, kp * page, -(-width // kp))
+        assert 0 < active < total      # the gate has steps to skip
+        q = rng.normal(size=(B, Hq, D)).astype(np.float32)
+        k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        v_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        pool = rng.normal(size=(L, P, 2, Hkv, page, D)).astype(np.float32)
+        table = rng.permutation(P).reshape(B, width).astype(np.int32)
+        kw = {}
+        if variant == "int8":
+            pool = rng.integers(-127, 128, size=pool.shape).astype(np.int8)
+            kw["scales"] = jnp.asarray(rng.uniform(
+                0.001, 0.02, size=pool.shape[:-1]), jnp.float32)
+        if variant == "window":
+            kw["sliding_window"] = 6
+        got = pl_paged.decode(
+            q, k_self, v_self, pool, jnp.int32(1), table, lens,
+            pages_per_step=kp, slots_per_step=sb, **kw,
+        )
+        want = xla_paged.paged_decode_attention(
+            q, k_self, v_self, pool, jnp.int32(1), table, lens,
+            use_pallas=False, **kw,
+        )
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=2e-5
+        )
+
+
 class TestKernelPositions:
     """The block plan and the count of positions the kernel computes over
     (``kernel_positions`` on the engine's chunk span)."""
@@ -529,6 +602,34 @@ class TestKernelPositions:
                     if j * span < rows[b0:b0 + sb].max():
                         brute += sb * span
             assert pl_paged.kernel_positions(rows, sb, span) == brute
+
+    @pytest.mark.parametrize(
+        "sb,span,nblk", [(8, 1024, 5), (4, 1024, 5), (1, 1024, 4), (2, 64, 79)]
+    )
+    def test_steps_match_brute_force(self, sb, span, nblk):
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        rng = np.random.default_rng(sb)
+        lens = _heavy_tailed_lens(rng, 64, min(5000, nblk * span))
+        for rows in (lens, np.sort(lens)):
+            # the kernel's gate on grid step (b0, j): issue, wait and body
+            brute = sum(
+                j * span < rows[b0:b0 + sb].max()
+                for b0 in range(0, len(rows), sb) for j in range(nblk)
+            )
+            assert pl_paged.kernel_steps(rows, sb, span, nblk) == (
+                brute, 64 // sb * nblk)
+            assert pl_paged.kernel_positions(rows, sb, span) == (
+                sb * span * brute)
+
+    @pytest.mark.parametrize(
+        "length,want", [(0, 0), (1, 4), (1024, 4), (1025, 8), (5120, 20)]
+    )
+    def test_steps_of_equal_lengths(self, length, want):
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        lens = np.full(32, length)
+        assert pl_paged.kernel_steps(lens, 8, 1024, 5) == (want, 20)
 
     def test_equal_lengths_round_up_to_the_span(self):
         from areal_tpu.ops.pallas import paged_attention as pl_paged

@@ -32,6 +32,8 @@ PRESET_125M = dict(hq=12, hkv=4, d=64)
 # OLMoE-1B-7B: multi-head (n_rep 1); 16 kv heads x 128 at page 128 leave
 # room for ONE slot x 8 pages in the paged kernel's 16 MiB of scratch
 OLMOE = dict(hq=16, hkv=16, d=128)
+# R1-Distill-Qwen-7B: 4 kv heads leave room for 4 slots a grid step
+QWEN_7B = dict(hq=28, hkv=4, d=128)
 T_TRAIN = 4096          # 8 x 512 packed tokens: the default train step
 
 
@@ -166,14 +168,21 @@ def _paged_specs(one_chip, *, page, int8, L=28, B=64, P=256, M=16,
 
 
 @pytest.mark.parametrize(
-    "page,int8",
+    "page,int8,shape",
     [
-        pytest.param(128, False, id="bf16-page128"),
-        pytest.param(64, False, id="bf16-page64"),
-        pytest.param(128, True, id="int8-page128"),
+        pytest.param(128, False, {}, id="bf16-page128"),
+        pytest.param(64, False, {}, id="bf16-page64"),
+        pytest.param(128, True, {}, id="int8-page128"),
+        # the rollout cells' calls, a table of 40 pages in 5 blocks of 8
+        # (the OLMoE cell's is the case below)
+        pytest.param(128, False, dict(B=128, M=40, P=2588),
+                     id="cell1-128x12q2kv-table40"),
+        pytest.param(128, False,
+                     dict(B=64, M=40, P=1311, L=16, layout=QWEN_7B),
+                     id="cell3-64x28q4kv-table40"),
     ],
 )
-def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8):
+def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8, shape):
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
     def f(q, ks, vs, pages, layer, table, lens, *scales):
@@ -182,7 +191,7 @@ def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8):
             scales=scales[0] if scales else None,
         )
 
-    _compile(f, *_paged_specs(one_chip, page=page, int8=int8))
+    _compile(f, *_paged_specs(one_chip, page=page, int8=int8, **shape))
 
 
 def test_paged_decode_compiles_at_16_kv_heads(compiled_kernels, one_chip):
