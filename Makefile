@@ -6,21 +6,7 @@ PYTHON ?= python
 # Diff base for lint-fast: any git ref (branch, SHA, HEAD~1, ...).
 SINCE ?= HEAD
 
-.PHONY: lint lint-fast lint-rules serve chaos chaos-serve bench-spec bench-fused
-
-# Speculative-decoding bench only (docs/performance.md "Speculative
-# decoding"): the three-arm vanilla / n-gram / draft-model A/B at the
-# 64-slot config. Needs the chip: bench.py exits non-zero without a TPU
-# (BENCH_SECTIONS gates the other sections off, including the primary
-# SFT probe).
-bench-spec:
-	BENCH_SECTIONS=gen_spec $(PYTHON) bench.py
-
-# Fused sampling-epilogue bench only (docs/performance.md "Fused sampling
-# epilogue"): materialized-logits vs streamed-head A/B at the 64-slot
-# config. Needs the chip: bench.py exits non-zero without a TPU.
-bench-fused:
-	BENCH_SECTIONS=gen_sample_fused $(PYTHON) bench.py
+.PHONY: lint lint-fast lint-rules serve chaos chaos-serve
 
 # Chaos soak, short seeded schedule (CI-sized): drive the 4-process
 # elastic CPU fault world through one seeded kill/hang + the serving-side

@@ -2,7 +2,7 @@
 
 Every process entry calls :func:`configure` before its first compile
 (``apps/launcher._setup_worker_env``, ``gateway/__main__``,
-``apps/profile``, ``bench.py``, ``chip_smoke.py``'s children). Where
+``apps/profile``, ``benchmark/run.py``, ``chip_smoke.py``'s children). Where
 ``JAX_COMPILATION_CACHE_DIR`` is set from outside, JAX reads it itself and
 this function changes nothing. Where it is not, the cache goes to the one
 fixed path ``constants.compile_cache_dir()`` names — by exporting that

@@ -18,10 +18,8 @@ _trial_name: Optional[str] = None
 
 # Env-var knobs (AREAL_* ≈ the reference's REAL_*).
 TRACE_ENV = "AREAL_DUMP_TRACE"          # jax.profiler traces per MFC
-RECORD_PERF_ENV = "AREAL_RECORD_PERFORMANCE"
 MEMORY_KILL_ENV = "AREAL_HBM_KILL_THRESHOLD"
 MEMORY_WARN_ENV = "AREAL_HBM_WARN_THRESHOLD"
-WEIGHT_SYNC_IMPL_ENV = "AREAL_WEIGHT_SYNC_IMPL"  # DISK (default) | DCN
 # Host↔device data-plane pipelining (docs/pipelined_data_plane.md). Both
 # default ON; "0"/"false"/"off" disables, an integer sets the depth.
 FWD_PIPELINE_ENV = "AREAL_FWD_PIPELINE"       # dispatch-ahead forward()
@@ -210,8 +208,8 @@ def decode_pipeline_enabled() -> bool:
 def spec_decode_enabled() -> bool:
     """``AREAL_SPEC_DECODE`` (default off): generation engines decode with
     speculative draft-and-verify chunks (self-drafting n-gram baseline;
-    exactly distribution-preserving, so PPO-safe). Default off until
-    chip-measured — see the ``gen_spec`` bench section."""
+    exactly distribution-preserving, so PPO-safe). Default off and
+    unjudged: no benchmark cell has run it (ROADMAP D1)."""
     return env_flag(SPEC_DECODE_ENV, False)
 
 
@@ -269,8 +267,8 @@ def fused_sample_enabled() -> bool:
     (top-p rows keep the sorted reference path via the warp-row bucket
     machinery). Token-exact for greedy slots, distribution-exact for
     sampled slots (docs/performance.md "Fused sampling epilogue").
-    Default off until chip-measured — see the ``gen_sample_fused`` bench
-    section."""
+    Default off and unjudged: no benchmark cell has run it (ROADMAP
+    D1)."""
     return env_flag(FUSED_SAMPLE_ENV, False)
 
 
@@ -279,8 +277,8 @@ def spec_k_adapt_enabled() -> bool:
     ``spec_k`` between chunks from the live ``gen/spec_accept_len``
     window (mean accept length with hysteresis, over a small fixed K
     choice set so chunk compile keys stay bounded). The live value is
-    exported as the ``gen/spec_k_current`` gauge. Default off until
-    chip-measured alongside the spec bench."""
+    exported as the ``gen/spec_k_current`` gauge. Default off and
+    unjudged, with ``AREAL_SPEC_DECODE`` (ROADMAP D1)."""
     return env_flag(SPEC_K_ADAPT_ENV, False)
 
 
@@ -290,7 +288,8 @@ def kv_dtype() -> Optional[str]:
     stores quantized pages with per-(page-slot, kv-head) scales — half the
     decode HBM KV traffic, 2x resident pages at fixed pool HBM
     (docs/performance.md "KV quantization"). Default stays the serving
-    dtype until chip-verified (``gen_kvq`` bench section). Unknown values
+    dtype: the int8 pool is unjudged, no benchmark cell has run it
+    (ROADMAP D1). Unknown values
     fall back to unset (logged), not crash — same contract as the other
     tolerant knobs. An explicit ``cfg.kv_dtype`` / engine argument
     overrides this knob."""
@@ -424,8 +423,8 @@ def trace_spans_enabled() -> bool:
     with W3C-style trace/span IDs, record its completion into the bounded
     per-process ring, and propagate trace context over the HTTP/SSE plane
     (docs/observability.md "Distributed tracing"). "0"/"off" reverts
-    spans to bare counter accumulation — the bench ``tracing`` section
-    proves that disabled path is free (``vs_baseline ≈ 1.0``)."""
+    spans to bare counter accumulation; what a span costs on and off
+    is in ``PERF.md`` §6 (PR 24)."""
     return env_flag(TRACE_SPANS_ENV, True)
 
 
@@ -656,9 +655,7 @@ def get_env_vars(**extra) -> dict:
         "AREAL_FUNCTIONCALL_CONCURRENCY",
         "AREAL_FUNCTIONCALL_DP",
         TRACE_ENV,
-        RECORD_PERF_ENV,
         MEMORY_KILL_ENV,
-        WEIGHT_SYNC_IMPL_ENV,
         FWD_PIPELINE_ENV,
         TRAIN_PREFETCH_ENV,
         TRAIN_GUARD_ENV,

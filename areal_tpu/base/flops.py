@@ -2,8 +2,9 @@
 
 TPU-native counterpart of the reference's per-MFC FLOPs counter
 (``realhf/system/flops_counter.py:15``, formulas in
-``realhf/base/monitor.py:288-350``): the trainer multiplies these by wall
-time to log TFLOP/s per step, the bench uses them for MFU.
+``realhf/base/monitor.py:288-350``): the trainer divides these by wall
+time to log TFLOP/s per step. The benchmark's ``train.mfu`` does not read
+this module: ``benchmark/flops.py`` keeps arithmetic of its own.
 
 The attention term uses true per-sequence lengths (packed varlen batches:
 cost scales with sum of len² within segments, not T²).
@@ -67,23 +68,26 @@ def param_count(cfg: ModelConfig, activated: bool = False) -> int:
     return V * E + L * per_layer + head
 
 
-def train_flops(
-    cfg: ModelConfig,
-    n_tokens: int,
-    seqlens: Optional[Sequence[int]] = None,
+def matmul_param_count(cfg: ModelConfig, activated: bool = False) -> int:
+    """The parameters a token is multiplied with: :func:`param_count` less
+    the ``V x E`` embedding table, whose lookup is a gather. A tied table
+    is the head's matmul and stays counted once; an untied head or a
+    critic's scalar head leaves the table out."""
+    n = param_count(cfg, activated)
+    if cfg.tied_embedding and not cfg.is_critic:
+        return n
+    return n - cfg.vocab_size * cfg.hidden_dim
+
+
+def _attention_forward_flops(
+    cfg: ModelConfig, seqlens: Optional[Sequence[int]]
 ) -> float:
-    """Total FLOPs for ONE forward+backward over ``n_tokens`` packed tokens
-    (backward ≈ 2x forward for matmuls; attention backward ≈ 2.5x its
-    forward). ``seqlens`` sharpens the attention term; without it the
-    attention cost is omitted (matmul-dominated models)."""
-    fwd = 2 * param_count(cfg, activated=True) * n_tokens
-    attn_fwd = 0.0
-    if seqlens:
-        D = cfg.head_dim
-        H = cfg.n_q_heads
-        # 2 matmuls x 2 FLOP/MAC x causal half
-        attn_fwd = sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.n_layers
-    return 3 * fwd + 3.5 * attn_fwd
+    """Causal attention forward: 2 matmuls x 2 FLOP/MAC x causal half.
+    Without ``seqlens`` the term is omitted (matmul-dominated models)."""
+    if not seqlens:
+        return 0.0
+    D, H = cfg.head_dim, cfg.n_q_heads
+    return sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.n_layers
 
 
 def forward_flops(
@@ -91,9 +95,17 @@ def forward_flops(
     n_tokens: int,
     seqlens: Optional[Sequence[int]] = None,
 ) -> float:
-    fwd = 2 * param_count(cfg, activated=True) * n_tokens
-    attn_fwd = 0.0
-    if seqlens:
-        D, H = cfg.head_dim, cfg.n_q_heads
-        attn_fwd = sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.n_layers
-    return fwd + attn_fwd
+    fwd = 2 * matmul_param_count(cfg, activated=True) * n_tokens
+    return fwd + _attention_forward_flops(cfg, seqlens)
+
+
+def train_flops(
+    cfg: ModelConfig,
+    n_tokens: int,
+    seqlens: Optional[Sequence[int]] = None,
+) -> float:
+    """Total FLOPs for ONE forward+backward over ``n_tokens`` packed tokens
+    (backward ≈ 2x forward for matmuls; attention backward ≈ 2.5x its
+    forward). ``seqlens`` sharpens the attention term."""
+    fwd = 2 * matmul_param_count(cfg, activated=True) * n_tokens
+    return 3 * fwd + 3.5 * _attention_forward_flops(cfg, seqlens)

@@ -1,7 +1,7 @@
 """Jax jit-cache introspection (one guarded home for a private API).
 
 ``PjitFunction._cache_size`` counts jax-level specializations — the signal
-bench warm-up uses to detect that another timed round would eat a compile
+the benchmark's drivers use to see that a window held no compile
 (re-specializations from sharding/layout drift that python-level compile
 counters cannot see). It is private to jax, so both engines go through this
 helper: an upgrade that removes it degrades the gate to 0 instead of

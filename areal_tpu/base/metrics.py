@@ -3,12 +3,12 @@ the reference, ``realhf/base/logging.py``) plus process-global pipeline
 counters.
 
 Sinks: stdout (always), tensorboardX (if importable), jsonl file (always —
-the judge/bench harness reads it). wandb/swanlab are not available in this
+``apps/obs`` and post-mortems read it). wandb/swanlab are not available in this
 image; the API accepts and ignores their configs.
 
 ``counters`` instruments the host↔device data plane (dispatch-ahead
 forward, prefetched train minibatches, deferred stats fetches): cheap
-monotonic host counters the bench/tests read to PROVE overlap happened
+monotonic host counters the tests read to PROVE overlap happened
 (e.g. ``fwd_pipe/max_in_flight`` ≥ 2) instead of inferring it from wall
 time alone.
 """
@@ -414,7 +414,7 @@ TRAIN_STEPS = "train/steps"                # optimizer steps taken
 # Speculative decoding (docs/performance.md "Speculative decoding"):
 # drafted vs accepted draft tokens (sums; their ratio is the accept rate)
 # plus an accept-length distribution per (slot, spec step) — the drafter
-# quality signal the bench and the ops CLI read.
+# quality signal the ops CLI reads.
 GEN_SPEC_DRAFT_TOKENS = "gen/spec_draft_tokens"
 GEN_SPEC_ACCEPTED_TOKENS = "gen/spec_accepted_tokens"
 GEN_SPEC_ACCEPT_LEN = "gen/spec_accept_len"

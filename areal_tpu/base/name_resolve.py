@@ -498,8 +498,8 @@ def default_repository() -> NameRecordRepository:
 
 def set_repository(repo: NameRecordRepository):
     """Install an already-built repository as the module default — the
-    save/restore counterpart of :func:`reconfigure` for benches and tests
-    that temporarily swap backends."""
+    save/restore counterpart of :func:`reconfigure` for tests that
+    temporarily swap backends."""
     global _DEFAULT
     _DEFAULT = repo
 

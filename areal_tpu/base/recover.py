@@ -14,7 +14,7 @@ renamed over ``<path>``. A preemption at ANY instant leaves either the old
 committed checkpoint or the new one — never a half-written dir that a
 restarted trainer would try to restore. ``shutil.rmtree`` on a path that can
 hold a live checkpoint is only legal inside this module (enforced by
-``tools/check_async_hygiene.py``).
+``tools.arealint``'s ``live-checkpoint-rmtree`` rule).
 """
 
 import dataclasses

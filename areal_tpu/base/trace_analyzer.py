@@ -21,10 +21,6 @@ plane in a CPU trace) are skipped.
 CLI::
 
     python -m areal_tpu.apps.trace_analyze /tmp/areal_trace [--top 20]
-
-and ``summarize_latest(dir)`` is wired into ``bench.py``: every traced
-bench section can print where its device time went without the by-hand
-breakdowns rounds 3-4 used.
 """
 
 import dataclasses
@@ -36,8 +32,8 @@ from typing import Dict, List, Optional, Tuple
 class TraceAnalyzerUnavailable(RuntimeError):
     """The installed jax/jaxlib does not bundle the ``ProfileData`` XSpace
     reader (``jax.profiler.ProfileData`` appeared in jaxlib 0.4.x and has
-    moved between releases). Callers that can degrade (bench sections, the
-    CLI, pytest) catch/skip on this instead of crashing on AttributeError
+    moved between releases). Callers that can degrade (the CLI, pytest)
+    catch/skip on this instead of crashing on AttributeError
     deep inside an analysis pass."""
 
 
@@ -269,21 +265,3 @@ def find_xplane_files(root: str) -> List[str]:
     # jax writes plugins/profile/<timestamp>/<host>.xplane.pb
     newest_dir = max(os.path.dirname(f) for f in files)
     return sorted(f for f in files if os.path.dirname(f) == newest_dir)
-
-
-def summarize_latest(root: str) -> Optional[dict]:
-    """Analyze the newest trace under ``root``; None when there is none
-    (or when this jax build cannot read xplane files — a bench section's
-    trace breakdown degrades to absent, it must not fail the run)."""
-    files = find_xplane_files(root)
-    if not files:
-        return None
-    summaries = []
-    try:
-        for f in files:
-            summaries.extend(s.as_dict() for s in analyze_xspace(f))
-    except TraceAnalyzerUnavailable:
-        return None
-    if not summaries:
-        return None
-    return {"files": files, "planes": summaries}

@@ -157,13 +157,12 @@ class TransformerDrafter(Drafter):
     @classmethod
     def shared_prefix(cls, cfg, params, n_layers: int,
                       kv_dtype: Optional[str] = None):
-        """Smoke/bench constructor: the draft is the first ``n_layers``
-        of the target's stacked params (shared embeddings + head). A
-        stand-in for a distilled draft when no checkpoint exists —
-        predictive only when the target's later layers refine rather
-        than overturn the early layers' logits (true of trained models;
-        the random-init bench constructs its target that way). Real
-        deployments point ``AREAL_SPEC_DRAFT_MODEL`` at a distilled
+        """Test constructor: the draft is the first ``n_layers`` of the
+        target's stacked params (shared embeddings + head). A stand-in
+        for a distilled draft when no checkpoint exists: predictive only
+        when the target's later layers refine rather than overturn the
+        early layers' logits (true of trained models; for seeded weights
+        see the damping recipe in ROADMAP D1). Real deployments point ``AREAL_SPEC_DRAFT_MODEL`` at a distilled
         checkpoint instead."""
         if not 0 < n_layers <= cfg.n_layers:
             raise ValueError(

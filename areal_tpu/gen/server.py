@@ -189,7 +189,7 @@ class GenerationHTTPServer:
 
     def _dump_metrics(self):
         """Phase accounting survives the process (the in-memory
-        /metrics_json gauges die with it) — how a bench or postmortem
+        /metrics_json gauges die with it) — how a postmortem
         attributes where the serving side's wall time went."""
         try:
             with open(self.metrics_dump_path, "w") as f:

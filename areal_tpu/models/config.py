@@ -85,8 +85,8 @@ class ModelConfig:
     dtype: str = "bfloat16"
 
     # Paged-KV pool storage dtype for generation engines (docs/performance.md
-    # "KV quantization"): None = serving ``dtype`` (raw bf16 pages — the
-    # chip-verified default until the gen_kvq bench proves int8 on hardware);
+    # "KV quantization"): None = serving ``dtype`` (raw bf16 pages, the
+    # default: the int8 pool has run in no benchmark cell, ROADMAP D1);
     # "int8" stores quantized pages with per-(page-slot, kv-head) scales in a
     # parallel scales array, halving decode's HBM KV traffic and doubling
     # resident pages at fixed pool HBM. The AREAL_KV_DTYPE env knob

@@ -123,8 +123,8 @@ def _bwd_pipeline() -> bool:
     # cross-block software pipelining in the fused backward (VERDICT r4
     # #4): park (p, ds) one step and issue their gradient dots alongside
     # the next block's VPU work. Numerics identical (parking dtype = the
-    # dots' operand dtype). Default OFF until chip-measured — the bench
-    # A/Bs both settings and the winner becomes the default.
+    # dots' operand dtype). Default off and unjudged: neither side has
+    # been timed in the train cell (ROADMAP D1, S4).
     from areal_tpu.base import constants
 
     return constants.flash_bwd_pipeline_enabled()

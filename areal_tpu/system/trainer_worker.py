@@ -633,7 +633,7 @@ class AsyncPPOTrainerWorker:
                 watchdog.stop()
             self._watchdog = None
             # trailing deferred stats must land in the jsonl before exit
-            # (the bench/judge reads it) — best-effort: after a device-side
+            # (``apps/obs`` and post-mortems read it) — best-effort: after a device-side
             # crash the pending device_get raises again, and that secondary
             # failure must not mask the original exception from run_step.
             # Then the final version must land before exit — and a crashed

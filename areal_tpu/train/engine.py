@@ -66,7 +66,7 @@ def train_guard_enabled() -> bool:
     on): a non-finite loss or grad norm makes the step SELECT the old
     params/opt state instead of applying the poisoned update, and report
     ``guard/step_ok`` in the stats the trainer already fetches — no extra
-    host round trip (bench.py ``guard`` section proves ~0 overhead). Read
+    host round trip (its cost on the chip: not measured, ROADMAP S6). Read
     at jit-build time; toggling requires a fresh engine."""
     return constants.env_knob(constants.TRAIN_GUARD_ENV, 1) > 0
 
@@ -587,12 +587,13 @@ class TrainEngine:
             # inputs, and any drift between GSPMD's inferred output
             # shardings and the init-time ones forces a silent full
             # recompile of the step on round 2 (the single-device variant
-            # of this — optax count scalars — cost 64.7 s at bench shape;
-            # the multi-device variant shows up under dp/fsdp meshes).
+            # of this is optax's count scalars; the multi-device variant
+            # shows up under dp/fsdp meshes; neither cost is measured in
+            # this round's record).
             # The scalar-stats output stays UNSPECIFIED on purpose: pinning
-            # it replicated measurably cost ~35% of primary-bench step time
-            # (0.458 -> 0.329 MFU, chip-measured r4), and stats never feed
-            # back as inputs, so they cannot cause recompiles.
+            # it replicated slowed the step in an earlier round (capture
+            # deleted in PR 21: not measured), and stats never feed back
+            # as inputs, so they cannot cause recompiles.
             opt_sh = jax.tree.map(lambda x: x.sharding, self.opt_state)
             jitted = jax.jit(
                 on_mesh(train_step),
@@ -618,9 +619,8 @@ class TrainEngine:
 
     def n_jit_entries(self) -> int:
         """Total jax-level specializations across this engine's jitted
-        programs. Stable across identical-shape rounds once warm — bench
-        warm-up loops until this stops growing (a growing count means the
-        next timed round would eat a compile)."""
+        programs. Stable across identical-shape rounds once warm: a
+        growing count means the next round would eat a compile."""
         from areal_tpu.base import jitcache
 
         return jitcache.total_cache_size(j for (_, j) in self._jit_cache.values())
@@ -955,8 +955,8 @@ class TrainEngine:
         unpacks rows. Results are byte-identical to the serial path (same
         jitted program, same inputs, only the host-side fetch order moves);
         ``self._last_forward_events`` records the (dispatch|fetch, mb)
-        sequence and ``metrics.counters`` the realized depth, so tests and
-        the bench can PROVE overlap rather than infer it."""
+        sequence and ``metrics.counters`` the realized depth, so tests
+        can PROVE overlap rather than infer it."""
         depth = fwd_pipeline_depth() if pipeline_depth is None else pipeline_depth
         with tracing.span("fwd_pipe/pack"):
             mbs, packed, _ = self._make_micro_batches(sample, mb_spec)

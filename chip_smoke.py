@@ -49,7 +49,8 @@ import urllib.request
 ROOT = os.path.dirname(os.path.abspath(__file__))
 WORK = os.path.join(ROOT, ".smoke_work")  # git-ignored scratch of this script
 
-# R1-Distill-Qwen-1.5B / Qwen2.5-1.5B widths (bench.py `_gen_model_cfg`)
+# R1-Distill-Qwen-1.5B / Qwen2.5-1.5B widths, as the benchmark's cells
+# have them (benchmark/configs/r1d-qwen-1p5b.json)
 ARCH_1P5B = dict(
     n_layers=28, n_q_heads=12, n_kv_heads=2, head_dim=128, hidden_dim=1536,
     intermediate_dim=8960, vocab_size=151936, use_attention_bias=True,
@@ -61,11 +62,11 @@ ARCH_TOY = dict(
     dtype="float32",
 )
 # Training memory on a 16 GB chip: bf16 params + bf16 Adam moments are
-# 9.9 GiB at full depth. With remat_policy="dots_attn" (bench.py's setting)
-# the step's compiler-reported need is 18.6 GiB at 28 layers (refused) and
-# fits only from 20 layers down; whole-layer remat ("full") peaks at
-# 15.0 GiB of 15.75 (AOT compile for a described v5e, PR 21). Depth is kept,
-# the remat policy gives way.
+# 9.9 GiB at full depth. With remat_policy="dots_attn" the step's
+# compiler-reported need is 18.6 GiB at 28 layers (refused) and fits only
+# from 20 layers down; whole-layer remat ("full") peaks at 15.0 GiB of
+# 15.75 (AOT compile for a described v5e, PR 21). Depth is kept, the remat
+# policy gives way.
 TRAIN_OVERRIDES = dict(
     remat_policy="full", loss_chunk_size=2048, attn_max_seqlen=512
 )
@@ -178,8 +179,10 @@ def ir_programs(phase_dir, jit_name):
 
 
 def kernels_in(paths):
-    """Pallas kernels lowered for the TPU compiler in these programs: an
-    interpreted kernel or an XLA-path dispatch leaves none."""
+    """Pallas kernels lowered for the TPU compiler in these programs, by
+    the ``name=`` of their ``pallas_call`` site (``flash_fwd*``,
+    ``flash_bwd*``, ``paged_decode*``): an interpreted kernel or an
+    XLA-path dispatch leaves none."""
     names = set()
     for p in paths:
         with open(p, errors="replace") as f:
@@ -320,8 +323,8 @@ def check_train_run(sz, args, d, fileroot, exp, err_p, jit_name="train_step"):
             f"train step was lowered {len(programs)} times (recompile)")
     kernels = kernels_in(programs)
     if not args.rehearse:
-        require(any("fwd_kernel" in k for k in kernels)
-                and any("bwd_kernel" in k for k in kernels),
+        require(any(k.startswith("flash_fwd") for k in kernels)
+                and any(k.startswith("flash_bwd") for k in kernels),
                 f"compiled train step has no flash kernel: {kernels}")
         require("sft/hbm_peak_bytes_in_use" in lines[-1],
                 "trainer logged no memory_stats gauges")
@@ -506,7 +509,7 @@ def phase_serve(sz, args):
     chunks = ir_programs(d, "chunk")
     kernels = kernels_in(chunks)
     if not args.rehearse:
-        require("_decode_kernel" in kernels,
+        require(any(k.startswith("paged_decode") for k in kernels),
                 f"decode chunk has no Pallas paged kernel: {kernels}")
         require("hbm_peak_bytes_in_use" in metrics,
                 "gen server reported no memory_stats gauges")
@@ -638,7 +641,7 @@ def phase_rl(sz, args):
     require(len(steps) == 1, f"PPO step lowered {len(steps)} times")
     kernels = kernels_in(glob.glob(os.path.join(d, "ir", "*.mlir")))
     if not args.rehearse:
-        require(any("fwd_kernel" in k for k in kernels_in(steps)),
+        require(any(k.startswith("flash_fwd") for k in kernels_in(steps)),
                 f"PPO train step has no flash kernel: {kernels_in(steps)}")
     csec, n_prog = compile_seconds(err_p)
     emit({

@@ -20,8 +20,14 @@ os.environ.setdefault("AREAL_FILEROOT", "/tmp/areal_tpu_test")
 # dispatch-ahead depth and the background packer thread only oversubscribe
 # the cores the multi-process e2e worlds already share (~35% wall-time
 # regression measured on test_experiment_e2e). Production (TPU) keeps the
-# ON defaults; tests/test_data_pipeline.py turns the knobs on explicitly
-# to exercise both paths.
+# ON defaults, so every other tier-1 test runs the SERIAL side of both
+# mechanisms (ROADMAP D14). The tests that turn the default side back on,
+# all in tests/test_data_pipeline.py:
+# test_forward_pipeline_identical_and_overlapped (AREAL_FWD_PIPELINE=2
+# against 0, both meshes), test_forward_explicit_depth_overrides_env,
+# test_train_batches_pipelined_matches_serial (AREAL_TRAIN_PREFETCH 1
+# against 0), test_trainer_worker_defers_stats_fetch and
+# test_env_knob_parsing (the unset defaults).
 os.environ.setdefault("AREAL_FWD_PIPELINE", "0")
 os.environ.setdefault("AREAL_TRAIN_PREFETCH", "0")
 

@@ -73,7 +73,6 @@ def main():
     import numpy as np
 
     from areal_tpu.api.data import MicroBatchSpec, SequenceSample
-    from areal_tpu.base import stats_tracker
     from areal_tpu.models.config import ModelConfig
     from areal_tpu.ops import ppo as ppo_ops
     from areal_tpu.parallel.mesh import ParallelConfig
@@ -151,16 +150,18 @@ def main():
     if args.num_processes > 1:
         assert max(rounds_per_step) <= 2, rounds_per_step
 
-    # host-local stats -> cross-host reduction (each host records its rank)
-    stats_tracker.DEFAULT.scalar(rank_sum=float(args.process_id))
-    reduced = stats_tracker.DEFAULT.export(cross_host=args.num_processes > 1)
+    # host-local scalar -> cross-host reduction (each host gives its rank;
+    # the mean over hosts is (P - 1) / 2)
+    rank_mean = float(
+        multihost.allgather_rows(np.float64(args.process_id)).mean()
+    )
 
     if args.out and (multihost.is_main() or args.out_all_ranks):
         with open(args.out, "w") as f:
             json.dump(
                 {
                     "losses": losses,
-                    "rank_sum": reduced["rank_sum"],
+                    "rank_mean": rank_mean,
                     "process_count": jax.process_count(),
                     "device_count": jax.device_count(),
                     "n_local_items": len(mine),

@@ -1,4 +1,5 @@
-"""Tier-1 static async-hygiene pass (tools/check_async_hygiene.py).
+"""Tier-1 static async-hygiene pass (``tools.arealint``'s four legacy
+async rules, ``LEGACY_ASYNC_RULES``).
 
 Keeps ``areal_tpu/system/`` and ``areal_tpu/train/`` free of the bug
 classes the fault-tolerance subsystems fixed: bare ``asyncio.gather(``
@@ -9,26 +10,24 @@ the commit helper (a crash mid-save destroys the only restore point), and
 ``time.sleep`` inside ``async def`` (blocks the event loop).
 """
 
-import importlib.util
+import functools
 import os
 import textwrap
 
+from tools import arealint
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def _checker():
-    spec = importlib.util.spec_from_file_location(
-        "check_async_hygiene",
-        os.path.join(REPO, "tools", "check_async_hygiene.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+scan_source = functools.partial(
+    arealint.scan_source, rules=arealint.LEGACY_ASYNC_RULES
+)
+scan_paths = functools.partial(
+    arealint.scan_paths, rules=arealint.LEGACY_ASYNC_RULES
+)
 
 
 def test_system_layer_is_clean():
-    mod = _checker()
-    findings = mod.scan_paths([
+    findings = scan_paths([
         os.path.join(REPO, "areal_tpu", "system"),
         os.path.join(REPO, "areal_tpu", "train"),
     ])
@@ -36,7 +35,6 @@ def test_system_layer_is_clean():
 
 
 def test_checker_flags_bare_gather_and_discarded_task():
-    mod = _checker()
     src = textwrap.dedent(
         """
         import asyncio
@@ -51,12 +49,11 @@ def test_checker_flags_bare_gather_and_discarded_task():
             await t
         """
     )
-    rules = sorted(f.rule for f in mod.scan_source(src))
+    rules = sorted(f.rule for f in scan_source(src))
     assert rules == ["bare-gather", "discarded-task"]
 
 
 def test_checker_suppression_and_non_asyncio_gather():
-    mod = _checker()
     src = textwrap.dedent(
         """
         import asyncio
@@ -68,11 +65,10 @@ def test_checker_suppression_and_non_asyncio_gather():
             return SequenceSample.gather(batch)  # not asyncio: ignored
         """
     )
-    assert mod.scan_source(src) == []
+    assert scan_source(src) == []
 
 
 def test_checker_flags_live_checkpoint_rmtree():
-    mod = _checker()
     src = textwrap.dedent(
         """
         import shutil
@@ -84,14 +80,13 @@ def test_checker_flags_live_checkpoint_rmtree():
             shutil.rmtree(path)  # async-hygiene: ok
         """
     )
-    rules = [f.rule for f in mod.scan_source(src, "areal_tpu/train/x.py")]
+    rules = [f.rule for f in scan_source(src, "areal_tpu/train/x.py")]
     assert rules == ["live-checkpoint-rmtree", "live-checkpoint-rmtree"]
     # the commit helper itself is the one sanctioned deletion site
-    assert mod.scan_source(src, "areal_tpu/base/recover.py") == []
+    assert scan_source(src, "areal_tpu/base/recover.py") == []
 
 
 def test_checker_flags_time_sleep_in_async():
-    mod = _checker()
     src = textwrap.dedent(
         """
         import asyncio
@@ -121,24 +116,6 @@ def test_checker_flags_time_sleep_in_async():
             time.sleep(1.0)
         """
     )
-    findings = [f for f in mod.scan_source(src) if f.rule == "sleep-in-async"]
+    findings = [f for f in scan_source(src) if f.rule == "sleep-in-async"]
     assert len(findings) == 3
     assert all("blocks the event loop" in f.message for f in findings)
-
-
-def test_stub_is_deprecated_but_forwards():
-    """The retired entry point still works (forwards to arealint's four
-    migrated rules) and says so: a deprecation notice on stderr, findings
-    + exit codes unchanged. Deleted one release after arealint v2."""
-    import subprocess
-    import sys
-
-    clean = os.path.join(REPO, "areal_tpu", "base", "faults.py")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "check_async_hygiene.py"),
-         clean],
-        cwd=REPO, capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "deprecated" in r.stderr
-    assert "python -m tools.arealint" in r.stderr

@@ -266,22 +266,39 @@ async def test_weight_sync_sharded_trainer_to_tp_gen_server(tmp_path, rng):
             output_ids=d["output_ids"], version=d["version"]
         )
 
-    def trainer_greedy(n=6):
-        """Teacher-forced argmax chain on the trainer's CURRENT params."""
+    # the TP-sharded engine and the dense forward sum in different orders:
+    # below this top-2 gap (nats; logits here are O(1) float32 sums of
+    # 32-64 terms) either token is a correct greedy choice
+    TIE_TOL = 1e-3
+
+    def assert_greedy_under_trainer(out, n=6):
+        """The engine's greedy chain, teacher-forced through a dense
+        forward on the trainer's CURRENT params: every token is the dense
+        argmax, or ties with it (top-2 log-prob gap under ``TIE_TOL``: a
+        near-tie may flip with reduction order). Forcing the engine's own
+        chain keeps the positions after a flipped tie comparable: each is
+        judged by the dense log-probs under the prefix the engine had. (A
+        greedy request reports log-prob 0.0, so the engine's own
+        log-probs say nothing here.)"""
         host = jax.tree.map(np.asarray, multihost_gather(teng))
-        ids = [3, 14, 15, 9, 2]
-        for _ in range(n):
-            T = len(ids)
-            pad = ((T + 127) // 128) * 128
-            logits = tfm.forward_packed(
-                jax.tree.map(jnp_asarray, host), cfg,
-                _arr(np.r_[ids, np.zeros(pad - T)], np.int32),
-                _arr(np.r_[np.ones(T), np.zeros(pad - T)], np.int32),
-                _arr(np.r_[np.arange(T), np.zeros(pad - T)], np.int32),
-                remat=False,
+        prompt = [3, 14, 15, 9, 2]
+        assert len(out.output_ids) == n
+        ids = prompt + list(out.output_ids)
+        T, pad = len(ids), 128
+        logits = tfm.forward_packed(
+            jax.tree.map(jnp_asarray, host), cfg,
+            _arr(np.r_[ids, np.zeros(pad - T)], np.int32),
+            _arr(np.r_[np.ones(T), np.zeros(pad - T)], np.int32),
+            _arr(np.r_[np.arange(T), np.zeros(pad - T)], np.int32),
+            remat=False,
+        )
+        logp = np.asarray(jax.nn.log_softmax(logits.astype(_jnp.float32)))
+        for i, tok in enumerate(out.output_ids):
+            row = logp[len(prompt) - 1 + i]
+            assert row.max() - row[tok] < TIE_TOL, (
+                f"token {i}: engine chose {tok} ({row[tok]:.6f}), dense "
+                f"argmax {int(row.argmax())} ({row.max():.6f})"
             )
-            ids.append(int(np.argmax(np.asarray(logits)[T - 1])))
-        return ids[5:]
 
     import jax.numpy as _jnp
 
@@ -327,7 +344,7 @@ async def test_weight_sync_sharded_trainer_to_tp_gen_server(tmp_path, rng):
         assert geng.params["layers"]["attn"]["wq"].sharding.spec[-1] == "model"
         out1 = await greedy_via_server()
         assert out1.version == 1
-        assert out1.output_ids == trainer_greedy()
+        assert_greedy_under_trainer(out1)
 
         # round trip 2 (lr is large so params demonstrably moved)
         train_one_step()
@@ -341,7 +358,7 @@ async def test_weight_sync_sharded_trainer_to_tp_gen_server(tmp_path, rng):
         assert path == ckpt2 and manager.version == 2 and geng.version == 2
         out2 = await greedy_via_server()
         assert out2.version == 2
-        assert out2.output_ids == trainer_greedy()
+        assert_greedy_under_trainer(out2)
 
         # staleness gate reflects the synced version: with version=2 and
         # max_head_offpolicyness=1, intake stays open until training_samples

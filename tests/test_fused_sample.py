@@ -514,54 +514,6 @@ class TestAdaptiveSpecK:
         assert snap.get(metrics_mod.GEN_SPEC_K_CURRENT) == 2.0
 
 
-def _run_fused_stanza(B=8):
-    """Shared ``gen_sample_fused`` bench run for the tier-1 smoke and the
-    slow throughput-ordering pin: a tiny 2-layer model with a LARGE-ish
-    vocab (8192) so the ``[B, V]`` materialization the fused path removes
-    is actually visible on the CPU harness."""
-    import dataclasses
-
-    import bench as bench_mod
-
-    cfg = dataclasses.replace(CFG, vocab_size=8192)
-    return bench_mod._bench_gen_sample_fused(
-        819e9, 197e12, cfg=cfg, B=B, PLEN=64, D_STEPS=8, N_CHUNKS=3,
-    )
-
-
-def test_bench_gen_sample_fused_stanza_end_to_end():
-    """The ``gen_sample_fused`` A/B bench runs end-to-end on the CPU
-    harness: both arms decode, the sampled-logprob probe is exact (greedy
-    fused logprobs are token-exact, so the delta is float-associativity
-    noise), and throughput is floored against pathology only — the strict
-    >= 1.0 ordering is pinned by the slow variant below (CPU wall clock
-    on a loaded CI box must not flake tier-1); absolute ratios are judged
-    on chip (HBM-roofline economics)."""
-    out = _run_fused_stanza()
-    assert set(out) >= {
-        "tokens_per_s", "baseline_tokens_per_s", "vs_baseline",
-        "max_logprob_delta",
-    }
-    assert out["tokens_per_s"] > 0
-    assert out["baseline_tokens_per_s"] > 0
-    # pathology floor only: the 8-slot timed window is a few hundred ms,
-    # so scheduler noise on a busy CI box swings the ratio well below the
-    # real ~1.25x (measured cold); the ordering bar lives in the slow pin
-    assert out["vs_baseline"] > 0.5
-    assert out["max_logprob_delta"] < 1e-4
-
-
-@pytest.mark.slow
-def test_bench_gen_sample_fused_beats_baseline():
-    """The strict CPU-smoke speed ordering (the ISSUE 16 acceptance bar):
-    at the 64-slot smoke shape the fused epilogue beats the materialized
-    baseline (measured 1.59x on the CPU harness). Wall-clock comparison —
-    slow-marked so a loaded tier-1 CI box can't flake it."""
-    out = _run_fused_stanza(B=64)
-    assert out["vs_baseline"] >= 1.0
-    assert out["max_logprob_delta"] < 1e-4
-
-
 class TestGaugeKind:
     def test_gauge_last_value_wins_and_delta_reports_as_is(self):
         name = "test/fused_gauge"

@@ -461,23 +461,3 @@ class TestKnobResolution:
         assert m["kv_pool_bytes"] == eng.kv_pool_bytes() > 0
         assert m["n_pages_free"] == eng.pool.n_free
         assert 0.0 <= m["kv_pool_occupancy"] <= 1.0
-
-
-@pytest.mark.slow
-class TestBenchStanza:
-    def test_gen_kvq_smoke(self):
-        """The ``gen_kvq`` bench stanza end-to-end on CPU at a tiny shape:
-        all arms run and report tokens/s, vs_baseline, and a finite max
-        logit delta (the acceptance bar for the CPU leg; chip numbers ride
-        the ROADMAP item 3 capture)."""
-        import bench
-
-        out = bench._bench_gen_kvq(
-            819e9, 197e12, cfg=CFG, B=2, PLEN=32, D_STEPS=4, N_CHUNKS=2,
-        )
-        assert out["bf16_tokens_per_s"] > 0
-        assert out["int8_tokens_per_s"] > 0
-        assert out["int8_2x_slots_tokens_per_s"] > 0
-        assert out["vs_baseline"] > 0
-        assert np.isfinite(out["max_logit_delta"])
-        assert out["slots_2x"] == 2 * out["slots"]

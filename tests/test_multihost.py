@@ -119,8 +119,8 @@ def test_two_process_world_matches_single_process(tmp_path):
         assert a == pytest.approx(b, rel=2e-4)
     assert single["losses"][-1] < single["losses"][0]
     # cross-host scalar reduction: mean of per-rank values (0+1)/2
-    assert dist["rank_sum"] == pytest.approx(0.5)
-    assert single["rank_sum"] == pytest.approx(0.0)
+    assert dist["rank_mean"] == pytest.approx(0.5)
+    assert single["rank_mean"] == pytest.approx(0.0)
 
 
 @pytest.mark.slow
@@ -172,4 +172,4 @@ def test_four_process_uneven_hosts_with_straggler(tmp_path):
     )
     assert diverged  # the predicate really did differ across ranks
     # cross-host stats reduction over 4 ranks: mean(0,1,2,3)
-    assert dist["rank_sum"] == pytest.approx(1.5)
+    assert dist["rank_mean"] == pytest.approx(1.5)

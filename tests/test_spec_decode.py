@@ -842,61 +842,6 @@ class TestServingSurface:
             await client.close()
 
 
-def _run_gen_spec_stanza():
-    """Shared three-arm ``gen_spec`` run for the tier-1 smoke and the
-    slow throughput-ordering pin: an 8-layer micro target (so the
-    2-layer shared-prefix draft is meaningfully cheaper) at a shape
-    whose slots stay live through every measured chunk."""
-    import bench as bench_mod
-
-    cfg8 = dataclasses.replace(
-        CFG, n_layers=8, dtype="float32",
-    )
-    return bench_mod._bench_gen_spec(
-        819e9, 197e12, cfg=cfg8, B=8, PLEN=128, D_STEPS=4, N_CHUNKS=3,
-        motif_len=8,
-    )
-
-
-def test_bench_gen_spec_stanza_end_to_end():
-    """The three-arm ``gen_spec`` bench (vanilla / n-gram / draft-model)
-    runs end-to-end on the CPU harness and the DETERMINISTIC draft-arm
-    acceptance bars hold: its accept rate beats the n-gram drafter's
-    (including the chip-measured 0.29). Accept rates are seeded greedy
-    token counts, so they are exact; the wall-clock throughput ORDERING
-    (draft_vs_baseline > vs_baseline) is real but CI-load-sensitive, so
-    tier-1 only floors it against pathology and the strict ordering is
-    pinned by the slow variant below (run unmarked locally + on chip).
-    Absolute ratios are judged on chip (HBM-roofline economics)."""
-    out = _run_gen_spec_stanza()
-    assert set(out) >= {
-        "vanilla_tokens_per_s", "accepted_tokens_per_s", "accept_rate",
-        "vs_baseline", "spec_k", "draft_tokens_per_s", "draft_accept_rate",
-        "draft_vs_baseline", "draft_layers",
-    }
-    assert out["accepted_tokens_per_s"] > 0
-    assert 0.0 < out["accept_rate"] <= 1.0
-    assert out["vs_baseline"] > 0.8
-    # the draft-model acceptance bar (ISSUE 14): beat the n-gram's
-    # accept rate and its chip-measured 0.29 — deterministic, so strict
-    assert out["draft_accept_rate"] > max(0.29, out["accept_rate"])
-    # throughput sanity floor only (see docstring): CPU-timer noise on a
-    # loaded CI box must not flake tier-1
-    assert out["draft_vs_baseline"] > 0.75 * out["vs_baseline"]
-
-
-@pytest.mark.slow
-def test_bench_gen_spec_draft_beats_ngram_throughput():
-    """The strict CPU-smoke speed ordering (ISSUE 14 acceptance): the
-    draft arm's accepted-tokens/s vs_baseline beats the n-gram arm at
-    the same settings. Wall-clock comparison — slow-marked so a loaded
-    tier-1 CI box can't flake it; verified per-PR by the spec verify
-    driver and on every local/chip bench run."""
-    out = _run_gen_spec_stanza()
-    assert out["draft_accept_rate"] > max(0.29, out["accept_rate"])
-    assert out["draft_vs_baseline"] > out["vs_baseline"]
-
-
 # --------------------------------------------------------------------- #
 # Exhaustive spec-vs-vanilla parity sweep. Tier-1 keeps ONE representative
 # configuration (matching the round-6 kernel-test policy); the rest run

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-# the 1.5B profile (bench.py `_gen_model_cfg`) and the 125M preset
+# the 1.5B cell's widths (benchmark/configs/r1d-qwen-1p5b.json) and the
+# 125M preset
 QWEN_1P5B = dict(hq=12, hkv=2, d=128)
 PRESET_125M = dict(hq=12, hkv=4, d=64)
 # OLMoE-1B-7B: multi-head (n_rep 1); 16 kv heads x 128 at page 128 leave
