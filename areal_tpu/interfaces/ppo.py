@@ -92,6 +92,17 @@ class PPOActorInterface(ModelInterface):
         self._last_ref_kl = 0.0
         # Built once so the engine's jit cache hits across train_step calls.
         self._actor_loss_fn = self._build_actor_loss()
+        # Likewise the advantage pre-pass: its cache lives with the interface.
+        self._prepass = jax.jit(self._build_prepass())
+        # what it reads of a packed batch; no other array is sent to the device
+        self._prepass_keys = {
+            "segment_ids", "prompt_mask", "packed_logprobs", "rewards",
+            "packed_ref_logprobs", "seq_no_eos_mask",
+        }
+        if not self.hp.disable_value:
+            self._prepass_keys.add("values")
+        if self.hp.group_adv_norm:
+            self._prepass_keys.add("item_ids")
 
     def _build_actor_loss(self):
         hp = self.hp
@@ -164,85 +175,96 @@ class PPOActorInterface(ModelInterface):
     # advantage computation over the full batch
     # -------------------------------------------------------------- #
 
+    def _build_prepass(self):
+        """The advantage pre-pass as ONE function of the packed ``[T]``
+        arrays it reads and the KL coefficient: reward shaping, GAE and the
+        normalisation. ``jax.jit`` specialises it on ``T``, on which optional
+        keys (``values``, ``packed_ref_logprobs``, ``seq_no_eos_mask``) the
+        batch carries and on the constants of ``self.hp``. The coefficient
+        is a traced operand: under ``use_adaptive_kl`` it changes every
+        step."""
+        hp = self.hp
+
+        def prepass(a, kl_coef):
+            seg = a["segment_ids"]
+            mask = _action_mask({k: v[None] for k, v in a.items()})[0]
+
+            behav_lp = a["packed_logprobs"].astype(jnp.float32)
+            ref_lp = a.get("packed_ref_logprobs", behav_lp)  # absent: zero KL penalty
+            raw_v = a.get("values", jnp.zeros_like(behav_lp)).astype(jnp.float32)
+            values = raw_v * mask
+
+            reward_score = (
+                a["rewards"].astype(jnp.float32) * hp.reward_output_scaling
+                + hp.reward_output_bias
+            )
+            no_eos = a.get(
+                "seq_no_eos_mask", jnp.zeros_like(reward_score, dtype=bool)
+            ).astype(bool)
+
+            # KL-penalized dense rewards + task reward at the *last action* token
+            ref_kl = behav_lp - ref_lp.astype(jnp.float32)
+            ref_kl_mean = jnp.sum(jnp.where(mask, ref_kl, 0.0)) / jnp.maximum(
+                mask.sum(), 1
+            )
+            kl_rw = jnp.where(mask, -kl_coef * ref_kl, 0.0)
+            nxt_mask = jnp.concatenate([mask[1:], jnp.zeros((1,), bool)])
+            last_action = mask & ~nxt_mask
+            score = jnp.clip(reward_score, -hp.max_reward_clip, hp.max_reward_clip)
+            if hp.mask_no_eos_with_zero:
+                score = jnp.where(no_eos, 0.0, score)
+            rewards = kl_rw + jnp.where(last_action, score, 0.0)
+
+            # next values: within the action span values[t+1]; at the last
+            # action, bootstrap with the next token's value iff the sequence
+            # was truncated (≈ cugae's seq_no_eos bootstrap).
+            zero = jnp.zeros((1,), jnp.float32)
+            next_values = jnp.where(
+                nxt_mask,
+                jnp.concatenate([values[1:], zero]),
+                jnp.where(no_eos, jnp.concatenate([raw_v[1:], zero]), 0.0),
+            )
+
+            adv, ret = ppo_ops.segment_gae(
+                rewards, values, next_values, seg, hp.discount, hp.gae_lambda,
+                mask=mask, not_end=nxt_mask,
+            )
+            if hp.group_adv_norm:
+                # every item holds a token, so ``T`` bounds the group count
+                # and the program never specialises on the batch size; the
+                # groups past the last item are empty (their count clamps
+                # to 1) and nothing gathers them
+                adv = ppo_ops.group_normalization(
+                    adv, mask, a["item_ids"], num_groups=seg.shape[0]
+                )
+            elif hp.adv_norm:
+                adv = ppo_ops.masked_normalization(adv, mask)
+            return adv, ret, kl_rw, ref_kl_mean
+
+        return prepass
+
     def _prepare(self, sample: SequenceSample) -> SequenceSample:
         """Compute advantages/returns on the whole batch (flat packed layout)
         and attach them as new keys — the analogue of the reference's
         pre-minibatch GAE + normalization block (``ppo_interface.py:527-647``).
-        One ``ppo/prepare`` span per call: the ops below run eagerly, one
-        small program each, and the device waits for the host between
-        them."""
+        One ``ppo/prepare`` span per call, around the host's packing, one
+        transfer in, ONE compiled program (``_build_prepass``) and one
+        transfer out; its ``compiled`` is 1 when this call traced a new
+        program (a padded length or a set of keys not met before), else 0."""
         main = sample.main_key()
         with tracing.span(
             "ppo/prepare", n_seqs=sample.bs,
             n_tokens=sum(sum(l) for l in sample.seqlens[main]),
-        ):
-            return self._advantages(sample)
-
-    def _advantages(self, sample: SequenceSample) -> SequenceSample:
-        hp = self.hp
-        pb = batching.pack_sequences(sample, n_rows=1, pad_multiple=128)
-        a = {k: jnp.asarray(v[0]) for k, v in pb.arrays.items()}
-        seg = a["segment_ids"]
-        mask = _action_mask({k: v[None] for k, v in a.items()})[0]
-
-        behav_lp = a["packed_logprobs"].astype(jnp.float32)
-        ref_lp = a.get("packed_ref_logprobs")
-        if ref_lp is None:
-            ref_lp = behav_lp  # zero KL penalty
-        values = a.get("values")
-        if values is None or hp.disable_value:
-            values = jnp.zeros_like(behav_lp)
-        values = values.astype(jnp.float32) * mask
-
-        reward_score = (
-            a["rewards"].astype(jnp.float32) * hp.reward_output_scaling
-            + hp.reward_output_bias
-        )
-        no_eos = a.get("seq_no_eos_mask")
-        if no_eos is None:
-            no_eos = jnp.zeros_like(reward_score, dtype=bool)
-        no_eos = no_eos.astype(bool)
-
-        # KL-penalized dense rewards + task reward at the *last action* token
-        ref_kl = behav_lp - ref_lp.astype(jnp.float32)
-        ref_kl_mean = jnp.sum(jnp.where(mask, ref_kl, 0.0)) / jnp.maximum(
-            mask.sum(), 1
-        )
-        kl_rw = jnp.where(mask, -self.kl_ctl.value * ref_kl, 0.0)
-        nxt_mask = jnp.concatenate([mask[1:], jnp.zeros((1,), bool)])
-        last_action = mask & ~nxt_mask
-        score = jnp.clip(reward_score, -hp.max_reward_clip, hp.max_reward_clip)
-        if hp.mask_no_eos_with_zero:
-            score = jnp.where(no_eos, 0.0, score)
-        rewards = kl_rw + jnp.where(last_action, score, 0.0)
-
-        # next values: within the action span values[t+1]; at the last action,
-        # bootstrap with the next token's value iff the sequence was truncated
-        # (≈ cugae's seq_no_eos bootstrap).
-        shifted_v = jnp.concatenate([values[1:], jnp.zeros((1,), values.dtype)])
-        raw_v = a.get("values")
-        if raw_v is not None and not hp.disable_value:
-            shifted_raw = jnp.concatenate(
-                [raw_v.astype(jnp.float32)[1:], jnp.zeros((1,), jnp.float32)]
-            )
-        else:
-            shifted_raw = jnp.zeros_like(values)
-        next_values = jnp.where(
-            nxt_mask, shifted_v, jnp.where(no_eos, shifted_raw, 0.0)
-        )
-
-        adv, ret = ppo_ops.segment_gae(
-            rewards, values, next_values, seg, hp.discount, hp.gae_lambda,
-            mask=mask, not_end=nxt_mask,
-        )
-        if hp.group_adv_norm:
-            adv = ppo_ops.group_normalization(
-                adv, mask, a["item_ids"], num_groups=sample.bs
-            )
-        elif hp.adv_norm:
-            adv = ppo_ops.masked_normalization(adv, mask)
-
-        return self._attach(sample, pb, adv, ret, kl_rw, ref_kl_mean)
+        ) as attrs:
+            pb = batching.pack_sequences(sample, n_rows=1, pad_multiple=128)
+            n_programs = self._prepass._cache_size()
+            out = self._prepass(*jax.device_put((
+                {k: v[0] for k, v in pb.arrays.items()
+                 if k in self._prepass_keys},
+                np.float32(self.kl_ctl.value),
+            )))
+            attrs["compiled"] = self._prepass._cache_size() - n_programs
+            return self._attach(sample, pb, *out)
 
     def _attach(self, sample, pb, adv, ret, kl_rw, ref_kl_mean):
         # ONE device->host transfer for everything the host needs

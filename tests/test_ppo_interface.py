@@ -9,6 +9,7 @@ import pytest
 
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import PPOHyperparameters, make_interface
+from areal_tpu.base import tracing
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.parallel.mesh import ParallelConfig
 from areal_tpu.train.engine import OptimizerConfig, TrainEngine
@@ -160,13 +161,156 @@ def test_advantages_match_manual_gae(engines, rng):
         np.testing.assert_allclose(a[acts], expected, rtol=1e-4, atol=1e-5)
 
 
+def _reference_prepass(sample, hp, kl_coef):
+    """The advantage pre-pass in plain NumPy, a sequence at a time: KL-shaped
+    rewards, the score at the last action, GAE as a reverse loop with the
+    truncation bootstrap, then the normalisation. Arrays in the sample's
+    flat order, and the masked mean of the reference KL."""
+    d = sample.data
+    lens = [n for inner in sample.seqlens["packed_input_ids"] for n in inner]
+    item_of_seq = [
+        i for i, inner in enumerate(sample.seqlens["packed_input_ids"])
+        for _ in inner
+    ]
+    offs = np.cumsum([0] + lens)
+    total = int(offs[-1])
+    use_values = "values" in d and not hp.disable_value
+    behav = d["packed_logprobs"].astype(np.float32)
+    ref = d.get("packed_ref_logprobs", behav).astype(np.float32)
+    adv = np.zeros(total, np.float32)
+    ret = np.zeros(total, np.float32)
+    kl_rw = np.zeros(total, np.float32)
+    mask = np.zeros(total, bool)
+    for s, n in enumerate(lens):
+        o = int(offs[s])
+        plen = int(d["prompt_mask"][o:o + n].sum())
+        acts = np.arange(o + plen - 1, o + n - 1)
+        mask[acts] = True
+        v = (d["values"][o:o + n].astype(np.float32) if use_values
+             else np.zeros(n, np.float32))
+        score = np.float32(d["rewards"][s]) * hp.reward_output_scaling
+        score = np.clip(
+            score + hp.reward_output_bias,
+            -hp.max_reward_clip, hp.max_reward_clip)
+        no_eos = bool(d["seq_no_eos_mask"][s]) if "seq_no_eos_mask" in d else False
+        if hp.mask_no_eos_with_zero and no_eos:
+            score = 0.0
+        kl_rw[acts] = -np.float32(kl_coef) * (behav[acts] - ref[acts])
+        last = np.float32(0.0)
+        for t in acts[::-1]:
+            r = kl_rw[t] + (score if t == acts[-1] else 0.0)
+            if t < acts[-1]:
+                nv = v[t + 1 - o]
+            else:   # a truncated sequence bootstraps from the next value
+                nv = v[t + 1 - o] if no_eos else 0.0
+            delta = r + hp.discount * nv - v[t - o]
+            last = np.float32(delta + hp.discount * hp.gae_lambda * last)
+            adv[t] = last
+            ret[t] = last + v[t - o]
+    ref_kl_mean = float((behav - ref)[mask].mean())
+    if hp.group_adv_norm:
+        item = np.repeat(item_of_seq, lens)
+        for g in set(item_of_seq):
+            sel = mask & (item == g)
+            c = adv[sel] - adv[sel].mean()
+            adv[sel] = c / np.sqrt((c ** 2).mean() + 1e-5)
+    elif hp.adv_norm:
+        c = adv[mask] - adv[mask].mean()
+        adv[mask] = c / np.sqrt((c ** 2).mean() + 1e-5)
+    return adv, ret, kl_rw, ref_kl_mean
+
+
+PREPASS_CASES = {
+    "values": dict(
+        hp=dict(max_reward_clip=0.5, reward_output_scaling=2.0,
+                reward_output_bias=0.1),
+        values=True),
+    "disable_value": dict(hp=dict(disable_value=True), values=True),
+    "adv_norm": dict(hp=dict(adv_norm=True), values=True),
+    "group_adv_norm": dict(
+        hp=dict(disable_value=True, group_adv_norm=True, group_size=2),
+        n_items=2, group=2),
+    "truncated_bootstrap": dict(
+        hp=dict(mask_no_eos_with_zero=True), values=True, truncate=1),
+    "kl_rewards": dict(hp=dict(kl_ctl=0.1), values=True, ref_scale=0.7),
+}
+
+
+@pytest.mark.parametrize("case", PREPASS_CASES)
+def test_prepass_equals_numpy_reference(rng, case):
+    """The compiled pre-pass against a per-sequence NumPy loop written here:
+    the three attached keys and the reference KL the controller is fed."""
+    c = PREPASS_CASES[case]
+    hp = PPOHyperparameters(**{
+        "adv_norm": False, "discount": 0.95, "gae_lambda": 0.9, "kl_ctl": 0.0,
+        **c["hp"]})
+    actor = make_interface("ppo_actor", hp=hp)
+    sample = _rollout_sample(
+        rng, n_items=c.get("n_items", 4), group=c.get("group", 1))
+    n_tok = sample.data["packed_input_ids"].shape[0]
+    if "ref_scale" in c:
+        sample.data["packed_ref_logprobs"] = (
+            sample.data["packed_logprobs"] * c["ref_scale"]
+            + rng.normal(size=n_tok).astype(np.float32) * 0.05)
+    if "truncate" in c:
+        sample.data["seq_no_eos_mask"][c["truncate"]] = True
+    if c.get("values"):
+        sample.update_(SequenceSample(
+            keys={"values"}, ids=list(sample.ids),
+            seqlens={"values": sample.seqlens["packed_input_ids"]},
+            data={"values": rng.normal(size=n_tok).astype(np.float32)}))
+    want = _reference_prepass(sample, hp, actor.kl_ctl.value)
+    actor._prepare(sample)
+    for key, ref in zip(("advantages", "returns", "kl_rewards"), want):
+        assert sample.data[key].dtype == np.float32
+        np.testing.assert_allclose(
+            sample.data[key], ref, rtol=1e-5, atol=1e-6, err_msg=key)
+    np.testing.assert_allclose(actor._last_ref_kl, want[3], rtol=1e-5, atol=1e-7)
+    if case == "truncated_bootstrap":
+        # the bootstrap is what moved: the same batch not truncated differs
+        sample.data["seq_no_eos_mask"][:] = False
+        assert not np.allclose(
+            _reference_prepass(sample, hp, 0.0)[0], want[0], rtol=1e-3)
+
+
+def _prepare_compiles():
+    return [s["attrs"]["compiled"] for s in tracing.drain()
+            if s["name"] == "ppo/prepare"]
+
+
+def test_prepass_compiles_once_per_shape_never_per_value(engines, rng):
+    """One program per padded length and set of keys: not one per value of
+    the adaptive KL coefficient, per batch content or per batch size."""
+    actor_eng, _ = engines
+    spec = MicroBatchSpec(max_tokens_per_mb=128)
+    actor = make_interface("ppo_actor", hp=PPOHyperparameters(
+        ppo_n_minibatches=1, use_adaptive_kl=True, kl_ctl=0.1,
+        use_decoupled_loss=False, recompute_logprob=False))
+    _prepare_compiles()
+    coefs = []
+    for _ in range(3):   # equal padded T (128), other contents, other kl_ctl
+        coefs.append(actor.kl_ctl.value)
+        actor.train_step(actor_eng, _rollout_sample(rng, n_items=4), spec)
+    assert len(set(coefs)) == 3
+    assert _prepare_compiles() == [1, 0, 0]
+    longer = _rollout_sample(rng, n_items=40)   # 200..400 tokens: another T
+    assert longer.data["packed_input_ids"].shape[0] > 128
+    actor._prepare(longer)
+    actor._prepare(_rollout_sample(rng, n_items=4))
+    assert _prepare_compiles() == [1, 0]
+
+    grpo = make_interface("ppo_actor", hp=PPOHyperparameters(
+        disable_value=True, group_adv_norm=True, adv_norm=False, group_size=2))
+    for n_items in (3, 5):   # batch sizes 3 and 5, both padded to T = 128
+        grpo._prepare(_rollout_sample(rng, n_items=n_items, group=2))
+    assert _prepare_compiles() == [1, 0]
+
+
 @pytest.mark.parametrize("role", ["actor", "critic"])
 def test_one_prepare_span_per_train_step(engines, rng, role):
     """The advantage pre-pass runs under ``ppo/prepare``, once per
     ``train_step``, inside the interface's own ``ppo/train_step`` span
     (the critic reaches it through the actor helper)."""
-    from areal_tpu.base import tracing
-
     eng = engines[0 if role == "actor" else 1]
     hp = PPOHyperparameters(
         ppo_n_minibatches=2, use_decoupled_loss=False, recompute_logprob=False)
@@ -186,6 +330,7 @@ def test_one_prepare_span_per_train_step(engines, rng, role):
     assert prep["attrs"] == {
         "n_seqs": 4,
         "n_tokens": sum(sum(l) for l in sample.seqlens["packed_input_ids"]),
+        "compiled": 1,   # a new interface's first batch traces its pre-pass
     }
     assert step["attrs"]["n_mbs"] == 2
     # the packer and the step's dispatch lie inside the train step's span
