@@ -53,19 +53,32 @@ def param_count(cfg: ModelConfig, activated: bool = False) -> int:
     through — the per-token FLOP proxy (total ≠ activated for MoE)."""
     E, D = cfg.hidden_dim, cfg.head_dim
     L, V, F = cfg.n_layers, cfg.vocab_size, cfg.intermediate_dim
-    attn = E * (cfg.n_q_heads * D) + 2 * E * (cfg.n_kv_heads * D) + (
-        cfg.n_q_heads * D
-    ) * E
+    H = cfg.n_q_heads
+    if cfg.mla is not None:
+        # latent attention: q through its latent, one kv latent + rotary
+        # key, the up-projection to per-head nope keys and values, o_proj
+        m = cfg.mla
+        attn = (
+            E * m.q_lora_rank + m.q_lora_rank * H * D + E * m.latent_dim
+            + m.kv_lora_rank * H * (m.qk_nope_head_dim + m.v_head_dim)
+            + H * m.v_head_dim * E
+        )
+    else:
+        attn = E * (H * D) + 2 * E * (cfg.n_kv_heads * D) + (H * D) * E
     if cfg.mlp_type == "gated":
         mlp = 3 * E * F
     elif cfg.mlp_type == "moe":
         n_active = cfg.moe.top_k if activated else cfg.moe.num_experts
-        mlp = n_active * 3 * E * F + E * cfg.moe.num_experts
+        n_active += cfg.moe.n_shared_experts    # every token takes these
+        mlp = n_active * 3 * E * cfg.expert_dim + E * cfg.moe.num_experts
     else:
         mlp = 2 * E * F
-    per_layer = attn + mlp
+    # an expert model's leading dense layers are SwiGLUs of F
+    layers = cfg.n_dense_layers * (attn + 3 * E * F) + (
+        L - cfg.n_dense_layers
+    ) * (attn + mlp)
     head = E if cfg.is_critic else (0 if cfg.tied_embedding else E * V)
-    return V * E + L * per_layer + head
+    return V * E + layers + head
 
 
 def matmul_param_count(cfg: ModelConfig, activated: bool = False) -> int:

@@ -256,6 +256,18 @@ class GenerationEngine:
         )
         self.kv_dtype = _resolve_kv_dtype(kd, cfg.dtype)
         self.kv_quantized = self.kv_dtype == "int8"
+        if cfg.mla is not None:
+            # what the latent pool does not do is refused, not approximated
+            if self.kv_quantized:
+                raise NotImplementedError(
+                    "latent attention: the page pool holds latents in the "
+                    "serving dtype; an int8 pool is not supported"
+                )
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise NotImplementedError(
+                    "latent attention: a latent page has no head axis to "
+                    "shard; tensor-parallel serving is not supported"
+                )
         # Drafter resolution happens BEFORE device-state construction: a
         # TransformerDrafter adds a draft param tree and a draft KV pool
         # to everything below (shardings, state pytree, jitted programs).
@@ -369,7 +381,8 @@ class GenerationEngine:
             if self.draft_cfg is not None:
                 check_tp_divisibility(self.draft_cfg, tp, role="draft model")
             self._repl = NamedSharding(mesh, P())
-            # pool [L, P, 2, Hkv, page, D]: shard the kv-head dim; the
+            # pool [L, P, 2, Hkv, page, D]: shard the kv-head dim (a latent
+            # pool's is 1 and the mesh's model axis too, checked above); the
             # int8 pool's scales [L, P, 2, Hkv, page] extend the same
             # Hkv-axis TP split (scales are per kv head, so each model
             # shard holds exactly its local heads' scales)
@@ -464,7 +477,7 @@ class GenerationEngine:
                 ),
                 out_routing=(
                     jnp.zeros(
-                        (self.B, self.G, cfg.n_layers, cfg.moe.top_k),
+                        (self.B, self.G, cfg.n_moe_layers, cfg.moe.top_k),
                         jnp.int32,
                     )
                     if record_routing
@@ -668,6 +681,28 @@ class GenerationEngine:
             for j in d.values()
         )
 
+    def program_sizes(self) -> Dict[str, int]:
+        """``n_jit_entries`` by program: the jax-level specializations of
+        each admission, commit and chunk program, under the key the engine
+        files it by (``"extend(2, 64, False)"``). Two readings around a
+        window name the program that was specialised inside it."""
+        from areal_tpu.base import jitcache
+
+        return {
+            f"{name}{key}": jitcache.cache_size(fn)
+            for name, d in (("extend", self._jit_extend),
+                            ("commit", self._jit_commit),
+                            ("chunk", self._jit_chunk))
+            for key, fn in d.items()
+        }
+
+    def table_widths(self) -> List[int]:
+        """The page-table widths (pages) the admission and chunk programs
+        come in, narrowest first: a warm-up has to reach each."""
+        return sorted({
+            self._table_width(p * self.page) for p in range(1, self.M + 1)
+        })
+
     def kv_pool_bytes(self) -> int:
         """Configured KV-pool HBM footprint (pages + quant scales),
         computed from shapes — no device pull. The serving gauge the
@@ -675,12 +710,20 @@ class GenerationEngine:
         return self._pool_bytes_for(self.cfg, self.kv_quantized)
 
     def _pool_bytes_for(self, cfg: ModelConfig, quantized: bool) -> int:
-        elems = cfg.n_layers * self.n_pages * 2 * cfg.n_kv_heads * self.page
+        # what the MODEL says a token holds (K and V heads, or one padded
+        # latent row), not 2 * Hkv * D
+        streams, heads, width = tfm.kv_page_geometry(cfg)
+        elems = cfg.n_layers * self.n_pages * streams * heads * self.page
         item = 1 if quantized else jnp.dtype(cfg.dtype).itemsize
-        total = elems * cfg.head_dim * item
+        total = elems * width * item
         if quantized:
             total += elems * 4  # one f32 scale per (token slot, head, K|V)
         return total
+
+    def cache_bytes_per_token(self) -> int:
+        """What one resident token really takes in the pool, every layer,
+        padding and an int8 pool's scales included."""
+        return self.kv_pool_bytes() // (self.n_pages * self.page)
 
     def draft_kv_pool_bytes(self) -> int:
         """Configured HBM footprint of the draft model's KV pool (0 when
@@ -1411,7 +1454,7 @@ class GenerationEngine:
             # three integers reduced from what the steps already computed
             flags = (state.active, state.n_gen, state.max_gen, state.lens)
             if census is not None:
-                slots = n_steps * cfg.n_layers * cfg.moe.num_experts
+                slots = n_steps * cfg.n_moe_layers * cfg.moe.num_experts
                 flags += (jnp.stack([
                     census[:, 0].sum(), jnp.int32(slots), census[:, 1].max()
                 ]).astype(jnp.int32),)
@@ -1971,6 +2014,7 @@ class GenerationEngine:
             # pipelined mode less the chunk still in flight)
             resident = int(lens.sum())
             chunk_attrs["resident_tokens"] = resident
+            chunk_attrs["cache_bytes_per_token"] = self.cache_bytes_per_token()
             counts = self._kernel_counts(W)
             if counts is not None:
                 chunk_attrs.update(counts)
@@ -1995,16 +2039,23 @@ class GenerationEngine:
         cfg = self.cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
         pool_dtype = self.state.cache.pages.dtype
-        if self.spec or not paged_ops.decode_kernel_applies(
-            self._decode_use_pallas, cfg.head_dim, cfg.n_kv_heads,
-            self.page, pool_dtype, tp,
-        ):
+        streams, heads, width = tfm.kv_page_geometry(cfg)
+        if cfg.mla is not None:
+            applies = paged_ops.latent_kernel_applies(
+                self._decode_use_pallas, cfg.mla.kv_lora_rank, self.page
+            )
+        else:
+            applies = paged_ops.decode_kernel_applies(
+                self._decode_use_pallas, width, heads, self.page,
+                pool_dtype, tp,
+            )
+        if self.spec or not applies:
             return None
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
         sb, kp = pl_paged.block_plan(
-            self.B, cfg.n_kv_heads // tp, cfg.head_dim, self.page, W,
-            pool_dtype,
+            self.B, heads // tp, width, self.page, W, pool_dtype,
+            streams=streams,
         )
         lens, span = np.sort(self._lens_host), kp * self.page
         active, total = pl_paged.kernel_steps(lens, sb, span, -(-W // kp))

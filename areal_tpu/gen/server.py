@@ -670,6 +670,9 @@ class GenerationHTTPServer:
             "n_pages_free": self.engine.pool.n_free,
             "kv_dtype": self.engine.kv_dtype,
             "kv_pool_bytes": self.engine.kv_pool_bytes(),
+            # what one resident token takes of it (the model says: K and V
+            # heads, or one padded latent row a layer)
+            "cache_bytes_per_token": self.engine.cache_bytes_per_token(),
             "kv_pool_occupancy": round(self.engine.kv_pool_occupancy(), 4),
             # admission signal: excludes instantly-evictable cache-only
             # pages (the gateway gates dispatch on THIS, not the raw

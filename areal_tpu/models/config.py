@@ -2,8 +2,8 @@
 
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
-family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe) via
-feature switches, exactly like the reference's single in-house architecture.
+family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
+joyai_llm_flash) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -21,6 +21,40 @@ class MoEConfig:
     z_loss_coeff: float = 0.0
     input_jitter_eps: Optional[float] = None
     norm_topk_prob: bool = True
+    # What the HF family sets and a user never does (``ops/moe.py``):
+    # width of ONE expert (None = the model's ``intermediate_dim``);
+    # shared experts, applied to every token and added to the routed sum,
+    # as one SwiGLU of ``n_shared_experts * expert_dim``; the router's
+    # scores, "softmax" over all logits or "sigmoid" of each; and whether
+    # the router carries a correction bias that is added to the scores for
+    # the CHOICE of experts only, never to the combine weights.
+    expert_dim: Optional[int] = None
+    n_shared_experts: int = 0
+    scoring: str = "softmax"
+    selection_bias: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (the ``deepseek_v3`` equations; family
+    ``joyai_llm_flash``). Queries come through a latent of ``q_lora_rank``;
+    keys and values are up-projections of ONE latent of ``kv_lora_rank`` a
+    token, beside one rotary key of ``qk_rope_head_dim`` shared by every
+    head. A query/key head is ``[nope ; rope]`` (the model's ``head_dim``
+    is their sum), a value head ``v_head_dim``. The rotary pairs are
+    ``(2i, 2i+1)`` (``rope_interleave``; the family refuses the other
+    pairing, which no published model of it uses)."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        """What the cache must hold of a token in a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -50,6 +84,9 @@ class ModelConfig:
     sliding_window: Optional[int] = None
     attn_logits_soft_cap: Optional[float] = None
     softmax_scale: Optional[float] = None  # default head_dim ** -0.5
+    # Latent attention in place of the q/k/v projections (None = those).
+    # ``head_dim`` is then nope + rope and ``n_kv_heads == n_q_heads``.
+    mla: Optional[MLAConfig] = None
 
     # Rotary (apply_rotary False => learned absolute positions, gpt2)
     apply_rotary: bool = True
@@ -66,6 +103,16 @@ class ModelConfig:
     mlp_type: str = "gated"                # "gated" (swiglu) | "fc" (gpt2) | "moe"
     use_mlp_bias: bool = False             # gpt2
     moe: Optional[MoEConfig] = None
+    # An expert model's leading layers that are dense SwiGLUs of
+    # ``intermediate_dim`` instead (``first_k_dense_replace``): the stack is
+    # then two runs of identical layers, ``params["dense_layers"]`` before
+    # ``params["layers"]``.
+    n_dense_layers: int = 0
+    # Multi-token-prediction modules after the stack (``params["mtp"]``,
+    # ``models/transformer.mtp_logits``): each is a projection of
+    # [norm(embedding of the next token) ; norm(hidden)] and one block as
+    # the stack's last kind. No generation or training path runs them.
+    n_mtp_layers: int = 0
 
     # Embeddings / head
     tied_embedding: bool = False
@@ -160,7 +207,20 @@ class ModelConfig:
 
     @property
     def rot_dim(self) -> int:
+        if self.mla is not None:
+            return self.mla.qk_rope_head_dim
         return self.rotary_dim if self.rotary_dim is not None else self.head_dim
+
+    @property
+    def n_moe_layers(self) -> int:
+        """Layers with a router (0 for a dense model)."""
+        return self.n_layers - self.n_dense_layers if self.mlp_type == "moe" else 0
+
+    @property
+    def expert_dim(self) -> int:
+        if self.moe is not None and self.moe.expert_dim is not None:
+            return self.moe.expert_dim
+        return self.intermediate_dim
 
     def __post_init__(self):
         if self.n_q_heads % self.n_kv_heads != 0:
@@ -172,3 +232,18 @@ class ModelConfig:
             )
         if self.mlp_type == "moe" and self.moe is None:
             object.__setattr__(self, "moe", MoEConfig())
+        if self.n_dense_layers and not (
+            self.mlp_type == "moe" and self.n_dense_layers < self.n_layers
+        ):
+            raise ValueError(
+                "n_dense_layers: leading dense layers of an expert model, "
+                "fewer than n_layers"
+            )
+        if self.mla is not None:
+            m = self.mla
+            if self.head_dim != m.qk_nope_head_dim + m.qk_rope_head_dim:
+                raise ValueError("mla: head_dim must be nope + rope")
+            if self.n_kv_heads != self.n_q_heads or m.v_head_dim > self.head_dim:
+                raise ValueError(
+                    "mla: n_kv_heads == n_q_heads and v_head_dim <= head_dim"
+                )

@@ -1,8 +1,8 @@
 """HF ↔ areal_tpu checkpoint converters for all supported model families.
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
-(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe is added
-here) consumed by
+(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe and
+joyai_llm_flash are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -22,7 +22,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from areal_tpu.models.config import ModelConfig, MoEConfig
+from areal_tpu.models.config import MLAConfig, ModelConfig, MoEConfig
 
 HFState = Dict[str, np.ndarray]
 
@@ -142,6 +142,7 @@ _ARCH_NAMES = {
     "gpt2": "GPT2LMHeadModel",
     "mixtral": "MixtralForCausalLM",
     "olmoe": "OlmoeForCausalLM",
+    "joyai_llm_flash": "DeepseekV3ForCausalLM",
 }
 
 # How a family names its expert block in a checkpoint:
@@ -371,6 +372,284 @@ register_hf_family(
         params_to_hf=lambda params, cfg: _llama_like_params_to_hf(
             params, cfg, _OLMOE_MOE
         ),
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# JoyAI-LLM-Flash (the ``deepseek_v3`` equations: latent attention, sigmoid
+# router with a correction bias, a shared expert, leading dense layers, a
+# multi-token-prediction module)
+# --------------------------------------------------------------------------- #
+
+def _joyai_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Key for key a ``deepseek_v3`` config. What the program does not
+    implement is refused, not guessed: a group-limited router (``n_group``
+    / ``topk_group`` other than 1), any ``rope_scaling``, rotary pairs other
+    than ``(2i, 2i+1)`` (``rope_interleave`` false), a router other
+    than sigmoid ``noaux_tc``, experts on other than every layer after the
+    dense ones, attention bias, queries without a latent. Keys that shape
+    nothing here: ``ep_size`` (how a deployment spreads the experts),
+    ``qk_head_dim`` and ``head_dim`` (nope + rope and HF's name for the
+    rotary width: checked against the keys they repeat),
+    ``num_key_value_heads`` (latent attention has no key heads of its own:
+    every query head has its key, up-projected from the one latent)."""
+    def must(key, ok, default=None):
+        v = hf.get(key, default)
+        if v not in ok:
+            raise ValueError(
+                f"joyai_llm_flash: {key}={v!r} is not supported "
+                f"(implemented: {ok})"
+            )
+
+    must("n_group", (1,), 1)
+    must("topk_group", (1,), 1)
+    must("rope_scaling", (None,))
+    must("rope_interleave", (True,), True)
+    must("scoring_func", ("sigmoid",))
+    must("topk_method", ("noaux_tc",))
+    must("moe_layer_freq", (1,), 1)
+    must("attention_bias", (False,), False)
+    must("hidden_act", ("silu",), "silu")
+    n_q = hf["num_attention_heads"]
+    if not hf.get("q_lora_rank"):
+        raise ValueError("joyai_llm_flash: q_lora_rank is required")
+    nope, rope = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+    must("qk_head_dim", (nope + rope, None))
+    must("head_dim", (rope, None))
+    n_dense = hf.get("first_k_dense_replace", 0)
+    if not 0 <= n_dense < hf["num_hidden_layers"]:
+        raise ValueError(
+            "joyai_llm_flash: first_k_dense_replace must leave an expert layer"
+        )
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        n_q_heads=n_q,
+        n_kv_heads=n_q,
+        head_dim=nope + rope,
+        hidden_dim=hf["hidden_size"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 131072),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-6),
+        rotary_base=hf.get("rope_theta", 10000.0),
+        tied_embedding=bool(hf.get("tie_word_embeddings", False)),
+        mla=MLAConfig(
+            q_lora_rank=hf["q_lora_rank"],
+            kv_lora_rank=hf["kv_lora_rank"],
+            qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope,
+            v_head_dim=hf["v_head_dim"],
+        ),
+        mlp_type="moe",
+        n_dense_layers=n_dense,
+        n_mtp_layers=hf.get("num_nextn_predict_layers", 0),
+        moe=MoEConfig(
+            num_experts=hf["n_routed_experts"],
+            top_k=hf["num_experts_per_tok"],
+            routed_scaling_factor=hf.get("routed_scaling_factor", 1.0),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            expert_dim=hf["moe_intermediate_size"],
+            n_shared_experts=hf.get("n_shared_experts") or 0,
+            scoring="sigmoid",
+            selection_bias=True,
+        ),
+    )
+
+
+def _joyai_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    m, moe = cfg.mla, cfg.moe
+    return {
+        "model_type": "joyai_llm_flash",
+        "architectures": [_ARCH_NAMES["joyai_llm_flash"]],
+        "attention_bias": False,
+        "ep_size": 1,
+        "first_k_dense_replace": cfg.n_dense_layers,
+        "head_dim": m.qk_rope_head_dim,
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "kv_lora_rank": m.kv_lora_rank,
+        "max_position_embeddings": cfg.n_positions,
+        "moe_intermediate_size": cfg.expert_dim,
+        "moe_layer_freq": 1,
+        "n_group": 1,
+        "n_routed_experts": moe.num_experts,
+        "n_shared_experts": moe.n_shared_experts,
+        "norm_topk_prob": moe.norm_topk_prob,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_experts_per_tok": moe.top_k,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "num_nextn_predict_layers": cfg.n_mtp_layers,
+        "q_lora_rank": m.q_lora_rank,
+        "qk_head_dim": cfg.head_dim,
+        "qk_nope_head_dim": m.qk_nope_head_dim,
+        "qk_rope_head_dim": m.qk_rope_head_dim,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_interleave": True,
+        "rope_scaling": None,
+        "rope_theta": cfg.rotary_base,
+        "routed_scaling_factor": moe.routed_scaling_factor,
+        "scoring_func": "sigmoid",
+        "tie_word_embeddings": cfg.tied_embedding,
+        "topk_group": 1,
+        "topk_method": "noaux_tc",
+        "v_head_dim": m.v_head_dim,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+# our leaf -> the checkpoint's name under ``model.layers.{i}.`` (matrices
+# are transposed on the way, gains and the bias are not)
+_JOYAI_ATTN = {
+    "wq_a": "self_attn.q_a_proj.weight",
+    "q_a_norm": "self_attn.q_a_layernorm.weight",
+    "wq_b": "self_attn.q_b_proj.weight",
+    "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+    "kv_a_norm": "self_attn.kv_a_layernorm.weight",
+    "wkv_b": "self_attn.kv_b_proj.weight",
+    "wo": "self_attn.o_proj.weight",
+}
+_JOYAI_MLP = {
+    "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+    "w_down": "mlp.down_proj.weight",
+    "router": "mlp.gate.weight",
+    "b_router": "mlp.gate.e_score_correction_bias",
+    "shared_gate": "mlp.shared_experts.gate_proj.weight",
+    "shared_up": "mlp.shared_experts.up_proj.weight",
+    "shared_down": "mlp.shared_experts.down_proj.weight",
+}
+_JOYAI_EXPERT = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+
+
+def _is_vector(leaf: str) -> bool:
+    return leaf.endswith("_norm") or leaf.startswith("b")
+
+
+def _joyai_stack_from_hf(sd: HFState, ids: List[int], moe: Optional[MoEConfig]):
+    """The layers ``ids`` of the checkpoint as one stack; ``moe`` None for
+    dense layers."""
+    def stack(name, leaf):
+        return np.stack([
+            np.asarray(sd[f"model.layers.{i}.{name}"]).T if not _is_vector(leaf)
+            else np.asarray(sd[f"model.layers.{i}.{name}"])
+            for i in ids
+        ])
+
+    def gain(name):
+        return {"weight": np.stack(
+            [np.asarray(sd[f"model.layers.{i}.{name}.weight"]) for i in ids]
+        )}
+
+    attn = {leaf: stack(name, leaf) for leaf, name in _JOYAI_ATTN.items()}
+    if moe is None:
+        mlp = {leaf: stack(_JOYAI_MLP[leaf], leaf)
+               for leaf in ("w_gate", "w_up", "w_down")}
+    else:
+        mlp = {"router": stack(_JOYAI_MLP["router"], "router"),
+               "b_router": stack(_JOYAI_MLP["b_router"], "b_router")}
+        for leaf, name in _JOYAI_EXPERT.items():
+            mlp[leaf] = np.stack([
+                np.stack([
+                    np.asarray(
+                        sd[f"model.layers.{i}.mlp.experts.{j}.{name}.weight"]
+                    ).T
+                    for j in range(moe.num_experts)
+                ])
+                for i in ids
+            ])
+        if moe.n_shared_experts:
+            for leaf in ("shared_gate", "shared_up", "shared_down"):
+                mlp[leaf] = stack(_JOYAI_MLP[leaf], leaf)
+    return {"ln1": gain("input_layernorm"), "attn": attn,
+            "ln2": gain("post_attention_layernorm"), "mlp": mlp}
+
+
+def _joyai_stack_to_hf(sd: HFState, stack, ids: List[int]):
+    for n, i in enumerate(ids):
+        p = f"model.layers.{i}."
+        sd[p + "input_layernorm.weight"] = np.asarray(stack["ln1"]["weight"][n])
+        sd[p + "post_attention_layernorm.weight"] = np.asarray(
+            stack["ln2"]["weight"][n])
+        for leaf, name in _JOYAI_ATTN.items():
+            a = np.asarray(stack["attn"][leaf][n])
+            sd[p + name] = a if _is_vector(leaf) else a.T
+        mlp = stack["mlp"]
+        for leaf, a in mlp.items():
+            if "router" in mlp and leaf in _JOYAI_EXPERT:
+                for j in range(a.shape[1]):
+                    sd[p + f"mlp.experts.{j}.{_JOYAI_EXPERT[leaf]}.weight"] = (
+                        np.asarray(a[n, j]).T)
+            else:
+                a = np.asarray(a[n])
+                sd[p + _JOYAI_MLP[leaf]] = a if _is_vector(leaf) else a.T
+
+
+def _joyai_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    L, nd = cfg.n_layers, cfg.n_dense_layers
+    params: Dict[str, Any] = {
+        "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
+    }
+    if nd:
+        params["dense_layers"] = _joyai_stack_from_hf(sd, list(range(nd)), None)
+    params["layers"] = _joyai_stack_from_hf(sd, list(range(nd, L)), cfg.moe)
+    params["final_ln"] = {"weight": np.asarray(sd["model.norm.weight"])}
+    if cfg.n_mtp_layers:
+        # the modules follow the stack as layers L, L+1, ...; their copies
+        # of the embedding, the final norm and the head (``embed_tokens``,
+        # ``shared_head.*``) are the model's own and are not read
+        ids = list(range(L, L + cfg.n_mtp_layers))
+        params["mtp"] = {
+            "e_norm": {"weight": np.stack(
+                [np.asarray(sd[f"model.layers.{i}.enorm.weight"]) for i in ids])},
+            "h_norm": {"weight": np.stack(
+                [np.asarray(sd[f"model.layers.{i}.hnorm.weight"]) for i in ids])},
+            "eh_proj": np.stack(
+                [np.asarray(sd[f"model.layers.{i}.eh_proj.weight"]).T
+                 for i in ids]),
+            "block": _joyai_stack_from_hf(sd, ids, cfg.moe),
+        }
+    if not cfg.is_critic and not cfg.tied_embedding:
+        params["head"] = {"weight": np.asarray(sd["lm_head.weight"]).T}
+    return params
+
+
+def _joyai_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    L, nd = cfg.n_layers, cfg.n_dense_layers
+    sd: HFState = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
+        "model.norm.weight": np.asarray(params["final_ln"]["weight"]),
+    }
+    if nd:
+        _joyai_stack_to_hf(sd, params["dense_layers"], list(range(nd)))
+    _joyai_stack_to_hf(sd, params["layers"], list(range(nd, L)))
+    if not cfg.is_critic and not cfg.tied_embedding:
+        sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
+    if cfg.n_mtp_layers:
+        mtp = params["mtp"]
+        ids = list(range(L, L + cfg.n_mtp_layers))
+        _joyai_stack_to_hf(sd, mtp["block"], ids)
+        for n, i in enumerate(ids):
+            p = f"model.layers.{i}."
+            sd[p + "enorm.weight"] = np.asarray(mtp["e_norm"]["weight"][n])
+            sd[p + "hnorm.weight"] = np.asarray(mtp["h_norm"]["weight"][n])
+            sd[p + "eh_proj.weight"] = np.asarray(mtp["eh_proj"][n]).T
+            sd[p + "embed_tokens.weight"] = sd["model.embed_tokens.weight"]
+            sd[p + "shared_head.norm.weight"] = sd["model.norm.weight"]
+            if "lm_head.weight" in sd:
+                sd[p + "shared_head.head.weight"] = sd["lm_head.weight"]
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="joyai_llm_flash",
+        hf_model_type="joyai_llm_flash",
+        config_from_hf=_joyai_config_from_hf,
+        config_to_hf=_joyai_config_to_hf,
+        params_from_hf=_joyai_params_from_hf,
+        params_to_hf=_joyai_params_to_hf,
     )
 )
 

@@ -73,6 +73,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         return p
 
     has_ln_bias = cfg.layer_norm_type == "layer"
+    if cfg.mla is not None:
+        return _init_latent(cfg, rng, dtype)
     attn: Dict[str, Any] = {
         "wq": w((L, E, Hq * D)),
         "wk": w((L, E, Hkv * D)),
@@ -103,13 +105,20 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             mlp["b_fc"] = jnp.zeros((L, F), dtype)
             mlp["b_proj"] = jnp.zeros((L, E), dtype)
     elif cfg.mlp_type == "moe":
-        X = cfg.moe.num_experts
+        X, F = cfg.moe.num_experts, cfg.expert_dim
         mlp = {
             "router": w((L, E, X)),
             "w_gate": w((L, X, E, F)),
             "w_up": w((L, X, E, F)),
             "w_down": w((L, X, F, E)),
         }
+        if cfg.moe.selection_bias:
+            mlp["b_router"] = jnp.zeros((L, X), dtype)
+        if cfg.moe.n_shared_experts:
+            Fs = cfg.moe.n_shared_experts * F
+            mlp["shared_gate"] = w((L, E, Fs))
+            mlp["shared_up"] = w((L, E, Fs))
+            mlp["shared_down"] = w((L, Fs, E))
     else:
         raise ValueError(cfg.mlp_type)
 
@@ -135,10 +144,108 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     return params
 
 
+def _latent_spec(cfg: ModelConfig) -> Params:
+    """The parameter tree of a latent-attention model (``cfg.mla``) as
+    ``(shape, logical axes)`` leaves: ONE description that both
+    :func:`init_params` and :func:`param_logical_axes` read. Stacks, in
+    the order they run: ``dense_layers`` (``cfg.n_dense_layers`` leading
+    layers whose MLP is a SwiGLU of ``intermediate_dim``), ``layers`` (the
+    rest: experts if the model has them), and ``mtp`` (the multi-token-
+    prediction modules: each one more block of the last kind behind
+    ``eh_proj`` over [``e_norm`` (embedding) ; ``h_norm`` (hidden)])."""
+    E, H, V = cfg.hidden_dim, cfg.n_q_heads, cfg.vocab_size
+    m = cfg.mla
+    qk, kv_up = cfg.head_dim, m.qk_nope_head_dim + m.v_head_dim
+
+    def stack(n, mlp_type):
+        gain = ((n, E), ("layer", "embed"))
+        attn = {
+            "wq_a": ((n, E, m.q_lora_rank), ("layer", "embed", None)),
+            "q_a_norm": ((n, m.q_lora_rank), ("layer", None)),
+            "wq_b": ((n, m.q_lora_rank, H * qk), ("layer", None, "heads")),
+            "wkv_a": ((n, E, m.latent_dim), ("layer", "embed", None)),
+            "kv_a_norm": ((n, m.kv_lora_rank), ("layer", None)),
+            "wkv_b": ((n, m.kv_lora_rank, H * kv_up), ("layer", None, "heads")),
+            "wo": ((n, H * m.v_head_dim, E), ("layer", "heads", "embed")),
+        }
+        if mlp_type == "moe":
+            X, F = cfg.moe.num_experts, cfg.expert_dim
+            mlp = {
+                "router": ((n, E, X), ("layer", "embed", None)),
+                "w_gate": ((n, X, E, F), ("layer", "expert", "embed", None)),
+                "w_up": ((n, X, E, F), ("layer", "expert", "embed", None)),
+                "w_down": ((n, X, F, E), ("layer", "expert", None, "embed")),
+            }
+            if cfg.moe.selection_bias:
+                mlp["b_router"] = ((n, X), ("layer", None))
+            Fs = cfg.moe.n_shared_experts * F
+            if Fs:
+                mlp["shared_gate"] = ((n, E, Fs), ("layer", "embed", "mlp"))
+                mlp["shared_up"] = ((n, E, Fs), ("layer", "embed", "mlp"))
+                mlp["shared_down"] = ((n, Fs, E), ("layer", "mlp", "embed"))
+        else:
+            F = cfg.intermediate_dim
+            mlp = {
+                "w_gate": ((n, E, F), ("layer", "embed", "mlp")),
+                "w_up": ((n, E, F), ("layer", "embed", "mlp")),
+                "w_down": ((n, F, E), ("layer", "mlp", "embed")),
+            }
+        return {"ln1": {"weight": gain}, "attn": attn,
+                "ln2": {"weight": gain}, "mlp": mlp}
+
+    spec: Params = {"embed": {"weight": ((V, E), ("vocab", "embed"))}}
+    if cfg.n_dense_layers:
+        spec["dense_layers"] = stack(cfg.n_dense_layers, "gated")
+    spec["layers"] = stack(cfg.n_layers - cfg.n_dense_layers, cfg.mlp_type)
+    spec["final_ln"] = {"weight": ((E,), ("embed",))}
+    if cfg.n_mtp_layers:
+        n = cfg.n_mtp_layers
+        spec["mtp"] = {
+            "e_norm": {"weight": ((n, E), ("layer", "embed"))},
+            "h_norm": {"weight": ((n, E), ("layer", "embed"))},
+            "eh_proj": ((n, 2 * E, E), ("layer", None, "embed")),
+            "block": stack(n, cfg.mlp_type),
+        }
+    if cfg.is_critic:
+        spec["head"] = {"weight": ((E, 1), ("embed", None))}
+    elif not cfg.tied_embedding:
+        spec["head"] = {"weight": ((E, V), ("embed", "vocab"))}
+    return spec
+
+
+def _is_spec_leaf(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+
+
+def _init_latent(cfg: ModelConfig, rng: jax.Array, dtype) -> Params:
+    """:func:`init_params` of a latent-attention model: matrices
+    normal(0.02), gains one, the router's correction bias zero."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        _latent_spec(cfg), is_leaf=_is_spec_leaf
+    )
+    out = []
+    for key, (path, (shape, _)) in zip(_split(rng, len(leaves)), leaves):
+        names = [k.key for k in path]
+        if any(n.startswith("ln") or n.endswith("_ln") or n.endswith("_norm")
+               for n in names):
+            out.append(jnp.ones(shape, dtype))
+        elif names[-1].startswith("b"):
+            out.append(jnp.zeros(shape, dtype))
+        else:
+            out.append(
+                (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+            )
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
 def param_logical_axes(cfg: ModelConfig) -> Params:
     """Logical sharding axes per parameter leaf (same tree structure as
     ``init_params``). ``None`` entries are replicated. ``areal_tpu.parallel``
     maps logical names → mesh axes (e.g. ``embed→fsdp``, ``heads/mlp/vocab→model``)."""
+    if cfg.mla is not None:
+        return jax.tree.map(
+            lambda leaf: leaf[1], _latent_spec(cfg), is_leaf=_is_spec_leaf
+        )
     has_ln_bias = cfg.layer_norm_type == "layer"
 
     def ln():
@@ -187,6 +294,12 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             "w_up": ("layer", "expert", "embed", None),
             "w_down": ("layer", "expert", None, "embed"),
         }
+        if cfg.moe.selection_bias:
+            mlp["b_router"] = ("layer", None)
+        if cfg.moe.n_shared_experts:
+            mlp["shared_gate"] = ("layer", "embed", "mlp")
+            mlp["shared_up"] = ("layer", "embed", "mlp")
+            mlp["shared_down"] = ("layer", "mlp", "embed")
 
     axes: Params = {
         "embed": {"weight": ("vocab", "embed")},
@@ -266,12 +379,144 @@ def _rotary_cfg(cfg: ModelConfig) -> RotaryConfig:
     )
 
 
+def _qkv_roped(cfg: ModelConfig, p, x, cos, sin):
+    """:func:`_qkv` with the positions applied: what attention takes."""
+    if cfg.mla is not None:
+        return _mla_expanded(cfg, p, x, cos, sin)
+    q, k, v = _qkv(cfg, p, x)
+    if cfg.apply_rotary:
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
+    return q, k, v
+
+
+# --------------------------------------------------------------------------- #
+# Latent attention (``cfg.mla``; the ``deepseek_v3`` equations)
+# --------------------------------------------------------------------------- #
+
+
+def latent_pool_width(cfg: ModelConfig) -> int:
+    """Width of a token's row in the latent page pool: ``kv_lora_rank +
+    qk_rope_head_dim`` values (576 as published), padded with zeros to a
+    whole number of 128-lane tiles (640). The chip stores a minor dimension
+    in whole tiles whatever the array says (Mosaic: "Slice shape along
+    dimension 5 must be aligned to tiling (128), but is 576", against a
+    memref already laid out 640 wide), so the padding costs the bytes
+    either way; declaring it keeps the page one DMA and the reported pool
+    size true."""
+    return -(-cfg.mla.latent_dim // 128) * 128
+
+
+def _rope_pairs(x, cos, sin):
+    """Rotary on ``x [..., heads, rope_dim]``. The published pairs are
+    ``(2i, 2i+1)`` (``rope_interleave``): the vector is first put in
+    evens-then-odds order and then rotated half-split, which is that
+    rotation followed by one fixed permutation. Queries and keys get the
+    same permutation, so every score is the published one."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rotary(x, cos, sin)
+
+
+def _mla_project(cfg: ModelConfig, p, x, cos, sin):
+    """x ``[..., E]`` -> ``q_nope [..., H, nope]``, ``q_rope [..., H,
+    rope]`` (rotated), ``c_kv [..., rank]`` (normed) and ``k_rope [...,
+    rope]`` (rotated; ONE for all heads)."""
+    m, eps = cfg.mla, cfg.layer_norm_epsilon
+    with jax.named_scope("mla_q_latent"):
+        c_q = norms.rms_norm(x @ p["wq_a"], p["q_a_norm"], eps)
+        q = (c_q @ p["wq_b"]).reshape(*x.shape[:-1], cfg.n_q_heads, cfg.head_dim)
+    with jax.named_scope("mla_kv_latent"):
+        kv_a = x @ p["wkv_a"]
+        c_kv = norms.rms_norm(kv_a[..., : m.kv_lora_rank], p["kv_a_norm"], eps)
+        k_rope = kv_a[..., None, m.kv_lora_rank :]
+    q_rope = _rope_pairs(q[..., m.qk_nope_head_dim :], cos, sin)
+    k_rope = _rope_pairs(k_rope, cos, sin)[..., 0, :]
+    return q[..., : m.qk_nope_head_dim], q_rope, c_kv, k_rope
+
+
+def _mla_expanded(cfg: ModelConfig, p, x, cos, sin):
+    """The EXPANDED form: per-head keys and values up-projected from the
+    latent, for the paths that attend over the tokens at hand (trainer,
+    dense-cache prefill and decode). ``q, k [..., H, nope + rope]``;
+    ``v`` is padded with zeros from ``v_head_dim`` to the key's width,
+    because the attention kernels take one head width (:func:`_attn_out`
+    drops the padding's share again)."""
+    m = cfg.mla
+    q_nope, q_rope, c_kv, k_rope = _mla_project(cfg, p, x, cos, sin)
+    H = cfg.n_q_heads
+    with jax.named_scope("mla_kv_up"):
+        kv = (c_kv @ p["wkv_b"]).reshape(
+            *x.shape[:-1], H, m.qk_nope_head_dim + m.v_head_dim
+        )
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [
+            kv[..., : m.qk_nope_head_dim],
+            jnp.broadcast_to(
+                k_rope[..., None, :], (*x.shape[:-1], H, m.qk_rope_head_dim)
+            ),
+        ],
+        axis=-1,
+    )
+    v = kv[..., m.qk_nope_head_dim :]
+    pad = cfg.head_dim - m.v_head_dim
+    if pad:
+        v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
+    return q, k, v
+
+
+def _mla_absorbed(cfg: ModelConfig, p, x, cos, sin):
+    """The ABSORBED form, for attention over the latent page pool:
+    ``q [..., H, W]`` and the token's own ``latent [..., W]`` with ``W =``
+    :func:`latent_pool_width`. The key up-projection ``W_uk`` is folded
+    into the query (``q_lat = q_nope W_uk^T``), so a head's score against
+    any cached token is ``q . latent`` with ``q = [q_lat ; q_rope ; 0]``
+    and ``latent = [c_kv ; k_rope ; 0]``: multi-query attention over ONE
+    stream that is key and, in its first ``kv_lora_rank`` values, value."""
+    m = cfg.mla
+    q_nope, q_rope, c_kv, k_rope = _mla_project(cfg, p, x, cos, sin)
+    w_uk = p["wkv_b"].reshape(m.kv_lora_rank, cfg.n_q_heads, -1)[
+        ..., : m.qk_nope_head_dim
+    ]
+    with jax.named_scope("mla_absorb_q"):
+        q_lat = jnp.einsum("...hd,rhd->...hr", q_nope, w_uk)
+    pad = latent_pool_width(cfg) - m.latent_dim
+    q = jnp.concatenate(
+        [q_lat, q_rope, jnp.zeros((*q_rope.shape[:-1], pad), q_rope.dtype)],
+        axis=-1,
+    )
+    latent = jnp.concatenate(
+        [c_kv, k_rope, jnp.zeros((*k_rope.shape[:-1], pad), k_rope.dtype)],
+        axis=-1,
+    )
+    return q, latent
+
+
+def _mla_absorbed_out(cfg: ModelConfig, p, ctx):
+    """``ctx [..., H, rank]`` (probabilities over the latents) -> the
+    heads' values ``[..., H, v_head_dim]`` through ``W_uv``."""
+    m = cfg.mla
+    w_uv = p["wkv_b"].reshape(m.kv_lora_rank, cfg.n_q_heads, -1)[
+        ..., m.qk_nope_head_dim :
+    ]
+    with jax.named_scope("mla_absorb_out"):
+        return jnp.einsum("...hr,rhd->...hd", ctx, w_uv)
+
+
+def _attn_scale(cfg: ModelConfig) -> float:
+    return cfg.softmax_scale or cfg.head_dim ** -0.5
+
+
 def _mlp(cfg: ModelConfig, p, x):
     """Returns (out, aux_loss, routing) — aux is the MoE load-balancing/z
     loss (``jnp`` scalar, 0 for dense MLPs); routing the experts each token
     chose, ``[..., top_k]`` int32 (``None`` for dense MLPs)."""
     act = ACT2FN[cfg.activation_function]
-    if cfg.mlp_type == "gated":
+    # a leading dense layer of an expert model is told by its tree: it has
+    # no router
+    if cfg.mlp_type == "gated" or (
+        cfg.mlp_type == "moe" and "router" not in p
+    ):
         out = (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
         return out, jnp.float32(0.0), None
     if cfg.mlp_type == "fc":
@@ -290,11 +535,52 @@ def _mlp(cfg: ModelConfig, p, x):
 
 
 def _attn_out(p, ctx):
-    """ctx: [..., H, D] -> [..., E]."""
+    """ctx: [..., H, D] -> [..., E]. Where a value head is narrower than a
+    key head (latent attention in its expanded form pads ``v`` with zeros
+    up to the key's width for the one-width kernels), the padding's share
+    of ``ctx`` is dropped here."""
+    dv = p["wo"].shape[-2] // ctx.shape[-2]
+    if dv != ctx.shape[-1]:
+        ctx = ctx[..., :dv]
     y = ctx.reshape(*ctx.shape[:-2], -1) @ p["wo"]
     if "bo" in p:
         y = y + p["bo"]
     return y
+
+
+def _layer_stacks(params: Params):
+    """The model's runs of identical layers, in the order they run: the
+    leading dense layers of an expert model, if it has them, then
+    ``layers``."""
+    if "dense_layers" in params:
+        return [params["dense_layers"], params["layers"]]
+    return [params["layers"]]
+
+
+def _scan_layers(layer, carry, params: Params, xs=(), unroll=1):
+    """``lax.scan`` of ``layer(carry, (lp, *xs_l))`` over every stack of
+    :func:`_layer_stacks`, one scan a stack (a model of one stack is ONE
+    scan, as ever). ``xs``: arrays with a leading axis over ALL layers,
+    cut to each stack's run. The stacked results (a tuple) are joined on
+    the layer axis; a member that a stack gives as ``None`` (a dense
+    layer's routing) is left out of the join."""
+    stacks = _layer_stacks(params)
+    outs, at = [], 0
+    for st in stacks:
+        n = jax.tree.leaves(st)[0].shape[0]
+        sl = tuple(x[at : at + n] for x in xs) if len(stacks) > 1 else xs
+        carry, ys = jax.lax.scan(
+            layer, carry, (st, *sl) if xs else st, unroll=unroll
+        )
+        outs.append(ys)
+        at += n
+    if len(outs) == 1:
+        return carry, outs[0]
+    joined = []
+    for parts in zip(*outs):
+        parts = [y for y in parts if y is not None]
+        joined.append(jnp.concatenate(parts, axis=0) if parts else None)
+    return carry, tuple(joined)
 
 
 # --------------------------------------------------------------------------- #
@@ -350,6 +636,7 @@ def forward_packed(
     with_aux: bool = False,
     with_head: bool = True,
     with_routing: bool = False,
+    with_mtp: bool = False,
 ) -> jnp.ndarray:
     """Full forward over a packed token axis. Returns ``[T, vocab]`` logits
     (fp32) or ``[T, 1]`` values for critics; with ``with_aux`` returns
@@ -359,6 +646,10 @@ def forward_packed(
     ``with_head=False`` returns the final-norm HIDDEN states ``[T, E]``
     instead — the chunked-loss path applies the head per token block so the
     ``[T, vocab]`` logits (4 GB f32 at 32k x 32k) never materialize.
+    ``with_mtp`` (``cfg.n_mtp_layers`` > 0) appends the multi-token-
+    prediction modules' logits, fp32 ``[n_mtp, T, vocab]``: row ``k`` at
+    position ``i`` is the distribution of token ``i + k + 2`` (the last
+    ``k + 1`` positions of a segment have no such input and are garbage).
     Padding rows are garbage — mask downstream with ``segment_ids > 0``."""
     x = _embed(cfg, params, input_ids, positions)
     if cfg.apply_rotary:
@@ -383,11 +674,7 @@ def forward_packed(
 
     def _pre(x, lp):
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv(cfg, lp["attn"], h)
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
-        return q, k, v
+        return _qkv_roped(cfg, lp["attn"], h, cos, sin)
 
     def _post(x, ctx, lp):
         x = x + _attn_out(lp["attn"], ctx)
@@ -436,9 +723,10 @@ def forward_packed(
             layer = jax.checkpoint(layer, policy=dots, prevent_cse=False)
         elif policy != "none":
             raise ValueError(f"unknown remat_policy {policy!r}")
-    x, (auxes, routing) = jax.lax.scan(
-        layer, x, params["layers"], unroll=cfg.layer_scan_unroll or 1
+    x, (auxes, routing) = _scan_layers(
+        layer, x, params, unroll=cfg.layer_scan_unroll or 1
     )
+    stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     out = _head(cfg, params, x) if with_head else x
     res = (out,)
@@ -448,7 +736,40 @@ def forward_packed(
         if routing is None:
             raise ValueError("with_routing: the model has no router")
         res += (routing,)
+    if with_mtp:
+        if not cfg.n_mtp_layers:
+            raise ValueError("with_mtp: the model has no such module")
+        res += (_mtp_logits(
+            params, cfg, stack_out, input_ids, positions, layer
+        ),)
     return res if len(res) > 1 else out
+
+
+def _mtp_logits(params, cfg, h, input_ids, positions, layer):
+    """The multi-token-prediction modules in sequence (the ``deepseek_v3``
+    description). Module ``k`` takes the previous depth's hidden states
+    ``h [T, E]`` (depth 0: the stack's output BEFORE the final norm) and
+    the embedding of the token ``k + 1`` places on: ``h' = [e_norm(Emb(t_
+    {i+k+1})) ; h_norm(h_i)] @ eh_proj``, then ONE block of the stack's
+    last kind at the same positions, then the model's own final norm and
+    head. The modules own ``e_norm``, ``h_norm``, ``eh_proj`` and the
+    block; the embedding, the final norm and the head are shared."""
+    mtp = params["mtp"]
+    out = []
+    for k in range(cfg.n_mtp_layers):
+        mp = _cast(cfg, jax.tree.map(lambda a: a[k], mtp))
+        nxt = jnp.roll(input_ids, -(k + 1))
+        e = _embed(cfg, params, nxt, positions)
+        with jax.named_scope("mtp_eh_proj"):
+            h = jnp.concatenate(
+                [_norm(cfg, mp["e_norm"], e), _norm(cfg, mp["h_norm"], h)],
+                axis=-1,
+            ) @ mp["eh_proj"]
+        h, _ = layer(h, jax.tree.map(lambda a: a[k], mtp["block"]))
+        out.append(
+            _head(cfg, params, _norm(cfg, _cast(cfg, params["final_ln"]), h))
+        )
+    return jnp.stack(out)
 
 
 def chunked_next_token_logprobs(
@@ -565,10 +886,7 @@ def prefill(
     def layer(x, lp):
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv(cfg, lp["attn"], h)  # [B, S, H, D]
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)  # [B, S, H, D]
         if use_flash:
             H, D = q.shape[-2:]
             ctx = attn_ops.packed_attention(
@@ -597,7 +915,7 @@ def prefill(
         x = x + _mlp(cfg, lp["mlp"], h)[0]
         return x, (k, v)
 
-    x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
+    x, (ks, vs) = _scan_layers(layer, x, params)
     cap = cache.k.shape[2]
     pad = cap - S
     if pad < 0:
@@ -642,10 +960,8 @@ def decode_step(
         lp, kc, vc = inputs
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv(cfg, lp["attn"], h)  # q: [B, Hq, D]; k/v: [B, Hkv, D]
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+        # q: [B, Hq, D]; k/v: [B, Hkv, D]
+        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)
         # write new K/V at write_at (only for active slots)
         slot = jnp.arange(kc.shape[1])[None, :, None, None]  # [1, S, 1, 1]
         put = (slot == write_at[:, None, None, None]) & active[:, None, None, None]
@@ -665,7 +981,7 @@ def decode_step(
         x = x + _mlp(cfg, lp["mlp"], h)[0]
         return x, (kc, vc)
 
-    x, (ks, vs) = jax.lax.scan(layer, x, (params["layers"], cache.k, cache.v))
+    x, (ks, vs) = _scan_layers(layer, x, params, xs=(cache.k, cache.v))
     cache = KVCache(k=ks, v=vs, lens=new_lens)
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     return _head(cfg, params, x), cache
@@ -700,7 +1016,14 @@ class PagedKVCache:
     post-scan scatter (:func:`_scatter_chunk_kv`); dequant is fused into
     every paged-attention entry point so int8 pages are read straight from
     HBM and widened in-register — a bf16 copy of the pool never exists.
-    ``scales is None`` = raw serving-dtype pages (the default)."""
+    ``scales is None`` = raw serving-dtype pages (the default).
+
+    A LATENT pool (``cfg.mla``, :func:`kv_page_geometry`): ``pages [L, P,
+    1, 1, page, W]``, ONE stream and no head axis (the two unit axes keep
+    the page walk, the scatter and the kernel's copies those of the K/V
+    pool). A token's row in a layer is ``[c_kv ; k_rope ; 0]``, ``W =``
+    :func:`latent_pool_width`: key for all query heads and, in its first
+    ``kv_lora_rank`` values, their value. Always in the serving dtype."""
 
     pages: jnp.ndarray
     scales: Optional[jnp.ndarray] = None
@@ -720,15 +1043,27 @@ class PagedKVCache:
         """``kv_dtype``: normalized pool storage dtype — ``"int8"`` builds
         the quantized pool + scales pair, anything else (None) stores raw
         ``cfg.dtype`` pages."""
-        shape = (
-            cfg.n_layers, n_pages, 2, cfg.n_kv_heads, page_size, cfg.head_dim
-        )
+        streams, heads, width = kv_page_geometry(cfg)
+        shape = (cfg.n_layers, n_pages, streams, heads, page_size, width)
         if kv_dtype == "int8":
+            if cfg.mla is not None:
+                raise ValueError("a latent page pool cannot be int8")
             return cls(
                 pages=jnp.zeros(shape, jnp.int8),
                 scales=jnp.zeros(shape[:-1], jnp.float32),
             )
         return cls(pages=jnp.zeros(shape, jnp.dtype(cfg.dtype)))
+
+
+def kv_page_geometry(cfg: ModelConfig) -> Tuple[int, int, int]:
+    """``(streams, heads, width)`` of what the page pool holds of one token
+    in one layer, as the model declares it: a K and a V of ``n_kv_heads x
+    head_dim``, or one latent row (:func:`latent_pool_width`). Pool bytes,
+    page counts and the kernel's block plan follow this, not ``2 * Hkv *
+    D``."""
+    if cfg.mla is not None:
+        return 1, 1, latent_pool_width(cfg)
+    return 2, cfg.n_kv_heads, cfg.head_dim
 
 
 def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, positions, valid):
@@ -752,9 +1087,12 @@ def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, positions, valid):
     SAME flat row indices — one extra [rows] scatter of scalars, no
     second index computation. Per-row scales make incremental page fills
     exact: a new token never forces requantizing its page's earlier
-    residents."""
+    residents.
+
+    A latent pool has one stream: ``ks`` is the latents ``[L, B, C, 1, W]``
+    and ``vs`` is ``None``."""
     L, B, C, Hkv, D = ks.shape
-    P, _, _, page = cache.pages.shape[1:5]
+    P, S_, _, page = cache.pages.shape[1:5]
     M = table.shape[1]
     page_idx = jnp.take_along_axis(
         table, jnp.clip(positions // page, 0, M - 1), axis=1
@@ -777,13 +1115,15 @@ def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, positions, valid):
         ).astype(jnp.int8)                              # [L, B, C, 2, Hkv, D]
     else:
         scale = None
-        kv = jnp.stack([ks, vs], axis=3).astype(dt)     # [L, B, C, 2, Hkv, D]
+        kv = jnp.stack(
+            [ks] if vs is None else [ks, vs], axis=3
+        ).astype(dt)                                    # [L, B, C, 2, Hkv, D]
     # flat row = (((l*P + p)*2 + kv)*Hkv + h)*page + off
-    n_rows = L * P * 2 * Hkv * page
+    n_rows = L * P * S_ * Hkv * page
     base = page_idx[None] + P * jnp.arange(L)[:, None, None]     # [L, B, C]
-    kvi = jnp.arange(2)[None, None, None, :, None]
+    kvi = jnp.arange(S_)[None, None, None, :, None]
     hi = jnp.arange(Hkv)[None, None, None, None, :]
-    rows = ((base[..., None, None] * 2 + kvi) * Hkv + hi) * page \
+    rows = ((base[..., None, None] * S_ + kvi) * Hkv + hi) * page \
         + off[None, :, :, None, None]                   # [L, B, C, 2, Hkv]
     rows = jnp.where(valid[None, :, :, None, None], rows, n_rows)  # => drop
     flat = cache.pages.reshape(n_rows, D)
@@ -827,7 +1167,7 @@ def _extend_layers(
 
     def _attend(q, k, v, li):
         kw = dict(
-            softmax_scale=cfg.softmax_scale,
+            softmax_scale=_attn_scale(cfg),
             soft_cap=cfg.attn_logits_soft_cap,
             sliding_window=cfg.sliding_window,
             scales=cache.scales,
@@ -845,19 +1185,24 @@ def _extend_layers(
         x, li = carry                                 # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv(cfg, lp["attn"], h)            # [B, C, H(kv), D]
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
-        ctx = _attend(q, k, v, li)
+        if cfg.mla is not None:
+            # absorbed form, chunk and pool alike: multi-query attention
+            # of [B, C, H, W] queries over the latents, whose head is the
+            # value (``vs`` stays None: the pool has one stream)
+            q, latent = _mla_absorbed(cfg, lp["attn"], h, cos, sin)
+            k, v = latent[..., None, :], None
+            ctx = _attend(q, k, k[..., : cfg.mla.kv_lora_rank], li)
+            ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
+        else:
+            # [B, C, H(kv), D]
+            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)
+            ctx = _attend(q, k, v, li)
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
         h = _norm(cfg, lp["ln2"], x)
         x = x + _mlp(cfg, lp["mlp"], h)[0]
         return (x, li + 1), (k, v)
 
-    (x, _), (ks, vs) = jax.lax.scan(
-        layer, (x, jnp.int32(0)), params["layers"]
-    )
+    (x, _), (ks, vs) = _scan_layers(layer, (x, jnp.int32(0)), params)
     return x, ks, vs, positions, valid
 
 
@@ -978,7 +1323,8 @@ def decode_step_paged(
     ``[B, V]`` logits never materialize.
 
     ``with_routing=True`` (STATIC, MoE models) appends a fourth result:
-    the experts every slot's token chose in every layer, int32
+    the experts every slot's token chose in every layer that has a router
+    (``cfg.n_moe_layers``: leading dense layers have none), int32
     ``[L, B, top_k]`` in slot order, free and finished slots included
     (they run through the experts like any other row)."""
     from areal_tpu.ops import paged_attention as paged_ops
@@ -997,31 +1343,42 @@ def decode_step_paged(
         x, li = carry                                 # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv(cfg, lp["attn"], h)            # q [B, H, D]
-        if cfg.apply_rotary:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
-        ctx = paged_ops.paged_decode_attention(
-            q, k, v, cache.pages, li, table_o, lens_o,
-            softmax_scale=cfg.softmax_scale,
+        kw = dict(
+            softmax_scale=_attn_scale(cfg),
             soft_cap=cfg.attn_logits_soft_cap,
             sliding_window=cfg.sliding_window,
             use_pallas=use_pallas,
             mesh=mesh,
             scales=cache.scales,
         )
+        if cfg.mla is not None:
+            # absorbed form straight from the latent pages: q [B, H, W]
+            # against ONE stream that is key and value (``mla_decode``)
+            q, latent = _mla_absorbed(cfg, lp["attn"], h, cos, sin)
+            k, v = latent[:, None], None
+            ctx = paged_ops.paged_decode_attention(
+                q, k, None, cache.pages, li, table_o, lens_o,
+                value_width=cfg.mla.kv_lora_rank, **kw,
+            )
+            ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
+        else:
+            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)  # q [B, H, D]
+            ctx = paged_ops.paged_decode_attention(
+                q, k, v, cache.pages, li, table_o, lens_o, **kw
+            )
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
         h = _norm(cfg, lp["ln2"], x)
         m, _, routing = _mlp(cfg, lp["mlp"], h)
         return (x + m, li + 1), (k, v, routing if with_routing else None)
 
-    (x, _), (ks, vs, routing) = jax.lax.scan(
-        layer, (x, jnp.int32(0)), params["layers"]
+    (x, _), (ks, vs, routing) = _scan_layers(
+        layer, (x, jnp.int32(0)), params
     )
-    x, ks, vs = x[inverse], ks[:, inverse], vs[:, inverse]
+    x, ks = x[inverse], ks[:, inverse]
     cache = _scatter_chunk_kv(
-        cache, ks[:, :, None], vs[:, :, None], table,
-        lens[:, None], active[:, None],
+        cache, ks[:, :, None],
+        None if vs is None else vs[:, inverse][:, :, None],
+        table, lens[:, None], active[:, None],
     )
     if with_routing:
         if routing is None:

@@ -35,8 +35,21 @@ weights that the code cannot see coming. One path was kept. The 8 x
 wasted FLOPs at large T are what a real grouped-matmul kernel would win
 back (ROADMAP); ``ragged_dot`` as this JAX lowers it wins a sixth of them.
 
+At 256 experts and 8 a token (``joyai_llm_flash``) the same path computes
+32 x the needed FLOPs. From shapes alone, at 2048 x 768 experts in bf16 on
+a v5e (197 TFLOP/s, 819 GB/s): a layer's routed weights are 2.42 GB,
+2.95 ms to stream; its dispatch is 6 * 2048 * 768 * 256 = 2.4 GFLOP a
+row, so 128 rows cost 1.6 ms of MXU under the weights' 2.95 ms (free),
+256 rows 3.1 ms (level with them) and a 1024-token admission wave 12.6 ms
+(4.3 x the bytes). PERF.md has what the chip says. Still one path.
+
 Router runs in fp32 (matches the reference's fp32 router,
-``moe/router.py``).
+``moe/router.py``). Two kinds of score (``MoEConfig.scoring``): a softmax
+over all logits (mixtral, olmoe), or a sigmoid of each (``deepseek_v3``'s
+``noaux_tc``), where the experts are chosen by score PLUS a learned
+correction bias ``b_router`` and weighted by the score alone. Shared
+experts (``n_shared_experts``) are one more SwiGLU applied to every token
+and added to the routed sum.
 """
 
 import jax
@@ -49,14 +62,29 @@ from areal_tpu.ops.activations import ACT2FN
 # benchmark's ``moe.*`` readers find these ops by what they stream
 # (``benchmark/moe_flops.py``)
 EXPERTS_SCOPE = "moe_experts"
+SHARED_SCOPE = "moe_shared_expert"
 
 
-def _route(cfg, router_w, x):
+def _route(cfg, router_w, x, bias=None):
     """fp32 router. Returns (top_vals [T, K] — the combine weights, scaled
     and, if the family asks, renormalised —, top_idx [T, K], probs [T, X],
-    logits [T, X])."""
+    logits [T, X]). ``bias`` [X] moves the choice and not the weights."""
     moe = cfg.moe
     logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_idx = jax.lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32),
+            moe.top_k,
+        )
+        top_vals = jnp.take_along_axis(scores, top_idx, axis=-1)
+        # the load-balance loss wants a distribution over experts
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        if moe.norm_topk_prob:
+            top_vals = top_vals / (
+                jnp.sum(top_vals, axis=-1, keepdims=True) + 1e-20
+            )
+        return top_vals * moe.routed_scaling_factor, top_idx, probs, logits
     probs = jax.nn.softmax(logits, axis=-1)
     top_vals, top_idx = jax.lax.top_k(probs, moe.top_k)
     if moe.norm_topk_prob:
@@ -92,7 +120,9 @@ def moe_mlp(cfg, p, x):
     act = ACT2FN[cfg.activation_function]
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
-    top_vals, top_idx, probs, logits = _route(cfg, p["router"], xt)
+    top_vals, top_idx, probs, logits = _route(
+        cfg, p["router"], xt, p.get("b_router")
+    )
     onehot = jax.nn.one_hot(top_idx, cfg.moe.num_experts, dtype=jnp.float32)
     chosen = onehot.sum(axis=1)                                  # [T, X]
     combine = (top_vals[:, :, None] * onehot).sum(axis=1)        # [T, X]
@@ -102,6 +132,11 @@ def moe_mlp(cfg, p, x):
         )
         h = h * combine.astype(h.dtype)[:, :, None]
         out = jnp.einsum("txf,xfe->te", h, p["w_down"])
+    if "shared_gate" in p:
+        with jax.named_scope(SHARED_SCOPE):
+            out = out + (
+                act(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
+            ) @ p["shared_down"]
     aux = _aux_loss(cfg, chosen, probs, logits)
     return (
         out.reshape(*lead, -1),

@@ -2,6 +2,8 @@
 
 TPU-native counterpart of the paged attention the reference inherits from
 SGLang/vLLM CUDA kernels. KV lives in a pool ``[L, P, 2, Hkv, page, D]``
+(or, for latent attention, ``[L, P, 1, 1, page, W]``: one stream that is
+key and, in its leading values, value; ``models/transformer.py``)
 (K and V interleaved per page — one page, one contiguous block, one DMA,
 heads before tokens so the decode kernel needs no in-VMEM transpose);
 each slot owns a page TABLE ``[M]`` instead of a dense slab, so HBM scales
@@ -46,8 +48,18 @@ def gather_pages(
     Hkv, page, D = g.shape[3:]
     g = jnp.swapaxes(g, 3, 4)              # [B, M, 2, page, Hkv, D]
     k = g[:, :, 0].reshape(B, M * page, Hkv, D)
+    if g.shape[2] == 1:
+        # a latent pool: one stream, whose head is the value (the caller
+        # knows how much of it: :func:`_latent_values`)
+        return k, None
     v = g[:, :, 1].reshape(B, M * page, Hkv, D)
     return k, v
+
+
+def _latent_values(k, v, width: int):
+    """The values of a gathered view: ``v``, or for a latent pool (``v is
+    None``) the first ``width`` of every key."""
+    return k[..., :width] if v is None else v
 
 
 def gather_dequant_pages(
@@ -98,6 +110,22 @@ def decode_kernel_applies(
     )
 
 
+def latent_kernel_applies(
+    use_pallas: Optional[bool], value_width: int, page: int
+) -> bool:
+    """:func:`decode_kernel_applies` for a latent pool: ``use_pallas`` as
+    given, or, left to the auto-dispatch, on a TPU where the value is a
+    whole number of lane tiles of its key (the row itself is padded to
+    one by the model)."""
+    if use_pallas is not None:
+        return use_pallas
+    return (
+        jax.devices()[0].platform == "tpu"
+        and value_width % 128 == 0
+        and page % 8 == 0
+    )
+
+
 def paged_decode_attention(
     q: jnp.ndarray,          # [B, H, D] one new token per slot
     k_self: jnp.ndarray,     # [B, Hkv, D] the new token's K (not in pool)
@@ -113,6 +141,7 @@ def paged_decode_attention(
     use_pallas: Optional[bool] = None,
     mesh=None,
     scales: Optional[jnp.ndarray] = None,  # [L, P, 2, Hkv, page] int8 pools
+    value_width: Optional[int] = None,
 ) -> jnp.ndarray:
     """Single-token attention against paged KV plus the token itself.
     The pool holds positions ``[0, lens)``; the query sits at position
@@ -137,13 +166,37 @@ def paged_decode_attention(
     This is the attention half of the decode-step roofline; the OTHER
     half — the LM head + sampling epilogue — streams through
     ``ops/fused_sample.py`` under ``AREAL_FUSED_SAMPLE`` (same
-    auto-detect-then-fallback dispatch shape as ``use_pallas`` here)."""
+    auto-detect-then-fallback dispatch shape as ``use_pallas`` here).
+
+    ``value_width`` marks a LATENT pool ``[L, P, 1, 1, page, D]`` (absorbed
+    latent attention, ``models/transformer.py``): ``q [B, H, D]`` carries
+    the key up-projection, ``k_self [B, 1, D]`` is the token's latent,
+    ``v_self`` is not read, a position's value is the first
+    ``value_width`` of its key, and the result is ``[B, H, value_width]``.
+    The Pallas kernel is the same one, named ``mla_decode`` (one stream
+    DMA'd once, 32 query rows on it); it is not sharded over a mesh."""
     B, H, D = q.shape
     Hkv = pages.shape[3]
     n_rep = H // Hkv
     if softmax_scale is None:
         softmax_scale = D ** -0.5
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    if value_width is not None:
+        if tp > 1:
+            raise NotImplementedError(
+                "a latent page pool has no head axis to shard: tensor-"
+                "parallel serving of latent attention is not supported"
+            )
+        v_self = k_self[..., :value_width]
+        if latent_kernel_applies(use_pallas, value_width, pages.shape[4]):
+            from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+            return pl_paged.decode(
+                q, k_self, None, pages, layer, table, lens,
+                softmax_scale=softmax_scale, soft_cap=soft_cap,
+                sliding_window=sliding_window, value_width=value_width,
+            )
+        use_pallas = False
     if use_pallas and tp > 1 and Hkv % tp != 0:
         # explicit use_pallas=True with an incompatible mesh: the shard_map
         # below splits the kv-head axis over the model axis and cannot
@@ -197,6 +250,7 @@ def paged_decode_attention(
             )(*operands)
         return _kernel(*operands)
     k, v = gather_dequant_pages(pages, table, layer, scales)  # [B, S, Hkv, D]
+    v = _latent_values(k, v, v_self.shape[-1])
     S = k.shape[1]
     qg = q.reshape(B, Hkv, n_rep, D)
     s_pool = jnp.einsum(
@@ -226,7 +280,7 @@ def paged_decode_attention(
         preferred_element_type=jnp.float32,
     ) + p_self[..., None] * v_self[:, :, None].astype(jnp.float32)
     out = acc / denom[..., None]
-    return out.reshape(B, H, D).astype(q.dtype)
+    return out.reshape(B, H, v.shape[-1]).astype(q.dtype)
 
 
 def paged_verify_attention(
@@ -338,9 +392,10 @@ def paged_extend_attention(
         preferred_element_type=jnp.float32,
     )
 
+    Dv = v_chunk.shape[-1]      # narrower than D over a latent pool
     if skip_pool:
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        out = jnp.moveaxis(out, 3, 1).reshape(B, C, H, D)
+        out = jnp.moveaxis(out, 3, 1).reshape(B, C, H, Dv)
         return jnp.where(
             valid_q[:, :, None, None], out, 0.0
         ).astype(q.dtype)
@@ -350,11 +405,12 @@ def paged_extend_attention(
     # never the pool; the intra-chunk part above is untouched: the chunk's
     # own K/V ride as full-precision operands)
     k, v = gather_dequant_pages(pages, table, layer, scales)  # [B, S, Hkv, D]
+    v = _latent_values(k, v, Dv)
     S = k.shape[1]
     Sb = kv_block if S % kv_block == 0 else S
     nb = S // Sb
     kb = jnp.moveaxis(k.reshape(B, nb, Sb, Hkv, D), 1, 0)
-    vb = jnp.moveaxis(v.reshape(B, nb, Sb, Hkv, D), 1, 0)
+    vb = jnp.moveaxis(v.reshape(B, nb, Sb, Hkv, Dv), 1, 0)
     offs = jnp.arange(nb) * Sb
     qpos = start[:, None] + qpos_in_chunk[None, :]           # [B, C]
 
@@ -390,7 +446,7 @@ def paged_extend_attention(
 
     (m, l, acc), _ = jax.lax.scan(body, (m, l, acc), (kb, vb, offs))
     out = acc / jnp.maximum(l, 1e-30)[..., None]             # [B,g,r,C,D]
-    out = jnp.moveaxis(out, 3, 1).reshape(B, C, H, D)
+    out = jnp.moveaxis(out, 3, 1).reshape(B, C, H, Dv)
     # fully-masked (invalid) rows carry garbage; zero them
     out = jnp.where(valid_q[:, :, None, None], out, 0.0)
     return out.astype(q.dtype)

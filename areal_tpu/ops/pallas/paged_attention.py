@@ -87,13 +87,14 @@ def page_multiple(pool_dtype) -> int:
     return LANES if jnp.dtype(pool_dtype) == jnp.int8 else 8
 
 
-def _scratch_bytes(sb, kp, page, n_kv, head_dim, pool_dtype) -> int:
+def _scratch_bytes(sb, kp, page, n_kv, head_dim, pool_dtype, streams=2) -> int:
     """Double-buffered KV page scratch of one grid step, plus an int8
-    pool's f32 scale stripes."""
+    pool's f32 scale stripes. ``streams``: 2 for a K and a V half, 1 for
+    a latent pool whose one stream is both."""
     dt = jnp.dtype(pool_dtype)
-    b = 2 * 2 * sb * kp * page * n_kv * head_dim * dt.itemsize
+    b = 2 * streams * sb * kp * page * n_kv * head_dim * dt.itemsize
     if dt == jnp.int8:
-        b += 2 * 2 * sb * kp * page * n_kv * 4
+        b += 2 * streams * sb * kp * page * n_kv * 4
     return b
 
 
@@ -106,20 +107,21 @@ def block_plan(
     pool_dtype,
     pages_per_step: int = 8,
     slots_per_step: int = 8,
+    streams: int = 2,
 ) -> Tuple[int, int]:
     """``(sb, kp)``: the slots and pages of one grid step that
     :func:`decode` runs a batch with. ``kp`` is the table width capped at
     ``pages_per_step``; ``sb`` is ``slots_per_step`` halved until it
     divides the batch and the KV scratch is NOT OVER 16 MiB (a scratch of
     exactly 16 MiB stays: 12q/2kv x 128 at page 128 runs 8 slots a step,
-    28q/4kv x 128 runs 4). Pure, so the engine counts
+    28q/4kv x 128 runs 4; one latent stream of 576 runs 4). Pure, so the engine counts
     :func:`kernel_positions` with the plan the kernel uses."""
     kp = min(pages_per_step, table_width)
     sb = slots_per_step
     while batch % sb:
         sb //= 2
     while sb > 1 and _scratch_bytes(
-        sb, kp, page, n_kv_heads, head_dim, pool_dtype
+        sb, kp, page, n_kv_heads, head_dim, pool_dtype, streams
     ) > 16 * 1024 * 1024:
         sb //= 2
     return sb, kp
@@ -163,7 +165,14 @@ def _decode_kernel(
     soft_cap: Optional[float],
     sliding_window: Optional[int],
     quantized: bool,
+    dv: Optional[int] = None,
 ):
+    # ``dv`` set: a LATENT pool ``[L, P, 1, 1, page, D]`` (absorbed MLA,
+    # ``models/transformer.py``). Its one stream is key and value at once
+    # for every query head: ``n_kv`` is 1, the value of a position is the
+    # first ``dv`` of its ``D`` key values, there is no ``vs_ref`` (the
+    # current token's value is the head of its key) and the output is
+    # ``[SB, Hq, dv]``. Grid, page walk, copies and softmax are the same.
     # Ref order (inputs, outputs, scratch); the int8 pool adds a scales
     # input + a scales scratch/semaphore pair right after their KV twins:
     #   layer_ref  [1] int32 scalar-prefetch: which layer of the pool
@@ -188,10 +197,16 @@ def _decode_kernel(
         (layer_ref, table_ref, lens_ref, q_ref, ks_ref, vs_ref, kv_hbm,
          sc_hbm, o_ref, kv_scr, sc_scr, m_scr, l_scr, acc_scr, sems,
          sc_sems) = refs
+    elif dv is not None:
+        (layer_ref, table_ref, lens_ref, q_ref, ks_ref, kv_hbm,
+         o_ref, kv_scr, m_scr, l_scr, acc_scr, sems) = refs
+        vs_ref = sc_hbm = sc_scr = sc_sems = None
     else:
         (layer_ref, table_ref, lens_ref, q_ref, ks_ref, vs_ref, kv_hbm,
          o_ref, kv_scr, m_scr, l_scr, acc_scr, sems) = refs
         sc_hbm = sc_scr = sc_sems = None
+    latent = dv is not None
+    n_str = 1 if latent else 2
     bb = pl.program_id(0)
     j = pl.program_id(1)
     nblk = pl.num_programs(1)
@@ -199,6 +214,7 @@ def _decode_kernel(
     g = bb * nblk + j         # linearized grid step
     Hq = q_ref.shape[1]
     D = q_ref.shape[2]
+    Dv = dv if latent else D  # width of a value
     S = kp * page             # positions of one grid step
     layer = layer_ref[0]
 
@@ -256,7 +272,7 @@ def _decode_kernel(
                     @pl.when(j_t * kp + i >= n_used)
                     def _zero(s=s, i=i):
                         kv_scr[buf, s, :, :, pl.ds(i * page, page), :] = (
-                            jnp.zeros((2, n_kv, page, D), kv_scr.dtype)
+                            jnp.zeros((n_str, n_kv, page, D), kv_scr.dtype)
                         )
                         if quantized:
                             sc_scr[buf, s, :, :, pl.ds(i * page, page)] = (
@@ -325,7 +341,10 @@ def _decode_kernel(
         # a single batch dim); the reshape is layout-free
         q = q_ref[...].reshape(sb * n_kv, n_rep, D)
         k = kv_scr[buf, :, 0].reshape(sb * n_kv, S, D)
-        v = kv_scr[buf, :, 1].reshape(sb * n_kv, S, D)
+        if latent:
+            v = kv_scr[buf, :, 0, :, :, :Dv].reshape(sb * n_kv, S, Dv)
+        else:
+            v = kv_scr[buf, :, 1].reshape(sb * n_kv, S, D)
         if quantized:
             # in-register widening: int8 in [-127, 127] is exact in bf16
             # (8 mantissa bits cover 256), so casting to q's dtype loses
@@ -373,8 +392,8 @@ def _decode_kernel(
             pq, v,
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ).reshape(sb, Hq, D)
-        acc_scr[:, :Hq, :D] = acc_scr[:, :Hq, :D] * corr + pv
+        ).reshape(sb, Hq, Dv)
+        acc_scr[:, :Hq, :Dv] = acc_scr[:, :Hq, :Dv] * corr + pv
         m_scr[:, :Hq] = jnp.broadcast_to(m_new, (sb, Hq, LANES))
         l_scr[:, :Hq] = jnp.broadcast_to(l_new, (sb, Hq, LANES))
 
@@ -384,7 +403,7 @@ def _decode_kernel(
         # KV is scattered into the pool by the caller AFTER the layer scan)
         q = q_ref[...].reshape(sb, n_kv, n_rep, D)
         ks = ks_ref[...]                                      # [SB,Hkv,D]
-        vs = vs_ref[...]
+        vs = ks[:, :, :Dv] if latent else vs_ref[...]
         s_self = jnp.sum(
             q.astype(jnp.float32) * ks[:, :, None].astype(jnp.float32),
             axis=3,
@@ -400,9 +419,9 @@ def _decode_kernel(
         p_self = jnp.exp(s_self - m_new)                      # [SB,Hq,1]
         l = corr * l_scr[:, :Hq, 0:1] + p_self
         v_rep = jnp.broadcast_to(
-            vs[:, :, None].astype(jnp.float32), (sb, n_kv, n_rep, D)
-        ).reshape(sb, Hq, D)
-        acc = acc_scr[:, :Hq, :D] * corr + p_self * v_rep
+            vs[:, :, None].astype(jnp.float32), (sb, n_kv, n_rep, Dv)
+        ).reshape(sb, Hq, Dv)
+        acc = acc_scr[:, :Hq, :Dv] * corr + p_self * v_rep
         o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
@@ -421,6 +440,7 @@ def decode(
     pages_per_step: int = 8,
     slots_per_step: int = 8,
     scales: Optional[jnp.ndarray] = None,  # [L, P, 2, Hkv, page] f32
+    value_width: Optional[int] = None,
 ) -> jnp.ndarray:
     """The pool rides in whole (ANY memory space); the kernel issues its own
     per-page DMAs keyed by the scalar-prefetched layer index and page table
@@ -430,14 +450,32 @@ def decode(
     each page's scale stripe DMAs alongside the page into a parallel
     scratch and dequant fuses into the dots — the HBM read stays int8
     (half the KV bytes of bf16 + a 1/D scale overhead), values widen only
-    in-register."""
+    in-register.
+
+    ``value_width`` marks a LATENT pool ``[L, P, 1, 1, page, D]`` (absorbed
+    MLA): ``q`` is ``[B, Hq, D]`` with ``D`` the latent's whole width,
+    ``k_self`` ``[B, 1, D]`` the current token's latent, ``v_self`` is not
+    read (pass ``None``), and the result is ``[B, Hq, value_width]``: the
+    probabilities over the first ``value_width`` values of every resident
+    latent. The kernel is then named ``mla_decode``."""
     B, Hq, D = q.shape
-    L, P, _, Hkv, page, _ = pages.shape
+    L, P, streams, Hkv, page, _ = pages.shape
     M = table.shape[1]
     n_rep = Hq // Hkv
     quantized = scales is not None
+    latent = value_width is not None
+    if latent and (streams != 1 or Hkv != 1 or quantized):
+        raise ValueError(
+            f"a latent pool is [L, P, 1, 1, page, D] in the serving dtype; "
+            f"got {pages.shape}, scales={quantized}"
+        )
+    Dv = value_width if latent else D
     page_mult = page_multiple(pages.dtype)
-    if not _interpret() and (D % 128 != 0 or page % page_mult != 0):
+    # a latent row is DMA'd and multiplied whole and sliced at ``Dv``: the
+    # slice, not the row, has to end on a lane tile
+    if not _interpret() and (
+        (Dv if latent else D) % 128 != 0 or page % page_mult != 0
+    ):
         raise ValueError(
             f"paged kernel needs head_dim%128==0 and page%{page_mult}==0 "
             f"on TPU; got D={D}, page={page} — use the XLA gather path"
@@ -446,7 +484,8 @@ def decode(
         softmax_scale = D ** -0.5
     hq_pad = max(8, Hq)
     sb, kp = block_plan(
-        B, Hkv, D, page, M, pages.dtype, pages_per_step, slots_per_step
+        B, Hkv, D, page, M, pages.dtype, pages_per_step, slots_per_step,
+        streams,
     )
     nblk = -(-M // kp)
 
@@ -461,24 +500,26 @@ def decode(
         soft_cap=soft_cap,
         sliding_window=sliding_window,
         quantized=quantized,
+        dv=value_width,
     )
+    row = lambda b, j, ly, t, l: (b, 0, 0)
     in_specs = [
-        pl.BlockSpec((sb, Hq, D), lambda b, j, ly, t, l: (b, 0, 0)),
-        pl.BlockSpec((sb, Hkv, D), lambda b, j, ly, t, l: (b, 0, 0)),
-        pl.BlockSpec((sb, Hkv, D), lambda b, j, ly, t, l: (b, 0, 0)),
+        pl.BlockSpec((sb, Hq, D), row),
+        pl.BlockSpec((sb, Hkv, D), row),
+        *([] if latent else [pl.BlockSpec((sb, Hkv, D), row)]),
         pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     scratch_shapes = [
-        pltpu.VMEM((2, sb, 2, Hkv, kp * page, D), pages.dtype),
+        pltpu.VMEM((2, sb, streams, Hkv, kp * page, D), pages.dtype),
         pltpu.VMEM((sb, hq_pad, LANES), jnp.float32),
         pltpu.VMEM((sb, hq_pad, LANES), jnp.float32),
-        # lanes padded to a full tile; the kernel uses [:, :D]
-        pltpu.VMEM((sb, hq_pad, max(D, LANES)), jnp.float32),
+        # lanes padded to a full tile; the kernel uses [:, :Dv]
+        pltpu.VMEM((sb, hq_pad, max(Dv, LANES)), jnp.float32),
         pltpu.SemaphoreType.DMA((2, sb, kp)),
     ]
     operands = [
         jnp.asarray(layer, jnp.int32).reshape(1), table, lens,
-        q, k_self, v_self, pages,
+        q, k_self, *([] if latent else [v_self]), pages,
     ]
     if quantized:
         # scales ride whole in ANY/HBM like the pool; their scratch and
@@ -495,22 +536,23 @@ def decode(
             num_scalar_prefetch=3,
             grid=(B // sb, nblk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (sb, Hq, D), lambda b, j, ly, t, l: (b, 0, 0)
-            ),
+            out_specs=pl.BlockSpec((sb, Hq, Dv), row),
             scratch_shapes=scratch_shapes,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype),
         # the double-buffered page scratch alone can exceed the 16 MB
         # default scoped-vmem budget; size the limit from the actual
         # scratch + generous op margin (v5e VMEM is 128 MB)
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_scratch_bytes(
-                sb, kp, page, Hkv, D, pages.dtype
+                sb, kp, page, Hkv, D, pages.dtype, streams
             ) + 32 * 2**20,
         ),
         interpret=_interpret(),
         # the kernel's name in the compiled program and the device trace
-        name="paged_decode_int8" if quantized else "paged_decode",
+        name=(
+            "mla_decode" if latent
+            else "paged_decode_int8" if quantized else "paged_decode"
+        ),
     )(*operands)
 

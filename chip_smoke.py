@@ -178,11 +178,16 @@ def ir_programs(phase_dir, jit_name):
     ))
 
 
+# the decode chunk's paged kernel by model: K and V pages (raw or int8), or
+# the latent pool of a latent-attention family (``joyai_llm_flash``)
+DECODE_KERNELS = ("paged_decode", "mla_decode")
+
+
 def kernels_in(paths):
     """Pallas kernels lowered for the TPU compiler in these programs, by
     the ``name=`` of their ``pallas_call`` site (``flash_fwd*``,
-    ``flash_bwd*``, ``paged_decode*``): an interpreted kernel or an
-    XLA-path dispatch leaves none."""
+    ``flash_bwd*``, ``paged_decode*``, ``mla_decode``): an interpreted
+    kernel or an XLA-path dispatch leaves none."""
     names = set()
     for p in paths:
         with open(p, errors="replace") as f:
@@ -509,7 +514,7 @@ def phase_serve(sz, args):
     chunks = ir_programs(d, "chunk")
     kernels = kernels_in(chunks)
     if not args.rehearse:
-        require(any(k.startswith("paged_decode") for k in kernels),
+        require(any(k.startswith(DECODE_KERNELS) for k in kernels),
                 f"decode chunk has no Pallas paged kernel: {kernels}")
         require("hbm_peak_bytes_in_use" in metrics,
                 "gen server reported no memory_stats gauges")

@@ -255,3 +255,114 @@ def test_fused_sample_compiles(compiled_kernels, one_chip, V, E):
         _spec((R,), jnp.float32, one_chip),
         _spec((R,), jnp.bool_, one_chip),
     )
+
+
+# ------------------------------------------------------------------ #
+# JoyAI-LLM-Flash cut to five layers (benchmark/configs/joyai-flash-l5):
+# the latent decode kernel alone, then the engine's two programs whole
+# ------------------------------------------------------------------ #
+
+# the cell joyai-flash-l5.rollout: 256 slots, a table of 72 pages of 128,
+# 5,968 pages of one 640-wide latent row a token a layer
+JOYAI_CELL = dict(B=256, M=72, P=5968, L=5, H=32, W=640, DV=512)
+
+
+def test_mla_decode_compiles(compiled_kernels, one_chip):
+    """The paged kernel over ONE stream that is key and value: 32 query
+    rows of 640 (576 + padding) on latent pages ``[128, 640]``, values the
+    first 512. The plan is 4 slots x 8 pages a step (9.4 MiB of scratch);
+    Mosaic takes the 640-wide row whole and refuses a 576-wide one
+    ("Slice shape along dimension 5 must be aligned to tiling (128)")."""
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    c = JOYAI_CELL
+    assert pl_paged.block_plan(
+        c["B"], 1, c["W"], 128, c["M"], jnp.bfloat16, streams=1) == (4, 8)
+
+    def f(q, lat, pages, layer, table, lens):
+        return pl_paged.decode(
+            q, lat, None, pages, layer, table, lens,
+            softmax_scale=192 ** -0.5, value_width=c["DV"])
+
+    compiled = _compile(
+        f,
+        _spec((c["B"], c["H"], c["W"]), jnp.bfloat16, one_chip),
+        _spec((c["B"], 1, c["W"]), jnp.bfloat16, one_chip),
+        _spec((c["L"], c["P"], 1, 1, 128, c["W"]), jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((c["B"], c["M"]), jnp.int32, one_chip),
+        _spec((c["B"],), jnp.int32, one_chip),
+    )
+    assert "mla_decode" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def joyai_engine():
+    """The engine of the cell at its real configuration, with placeholder
+    weights and a pool of a few pages: its programs are built from the
+    configuration alone and are lowered below on SHAPES of the real size
+    (11 GB of weights and a 4.9 GB pool are never allocated)."""
+    import json
+
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "joyai-flash-l5.json")) as f:
+        arch = json.load(f)
+    cfg = sut.model_config(arch, {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=JOYAI_CELL["B"], max_seqlen=9216, max_new_tokens_cap=8192,
+        page_size=128, n_pages=80, seed=0)
+    eng._decode_use_pallas = True
+    return eng, shapes
+
+
+def _joyai_program_specs(eng, shapes, one_chip):
+    import dataclasses
+
+    from areal_tpu.models import transformer as tfm
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    state = jax.tree.map(spec, eng.state)
+    pages = eng.state.cache.pages
+    state = dataclasses.replace(state, cache=tfm.PagedKVCache(pages=_spec(
+        (pages.shape[0], JOYAI_CELL["P"]) + pages.shape[2:], pages.dtype,
+        one_chip)))
+    return jax.tree.map(spec, shapes), state
+
+
+@pytest.mark.parametrize("program", ["jit_chunk", "jit_extend"])
+def test_joyai_engine_programs_compile(
+        compiled_kernels, one_chip, joyai_engine, program):
+    """``jit_chunk`` (16 decode steps over the latent pool at 256 slots and
+    the full table: two scans, ``mla_decode``, the 256-expert dispatch, the
+    129k-vocabulary head) and ``jit_extend`` (an admission wave of 8 x 128
+    tokens against the pool) for a described v5e, beside 11.1 GB of weights
+    and the cell's pool: arguments + temporaries under the chip's 16.9e9."""
+    eng, shapes = joyai_engine
+    params, state = _joyai_program_specs(eng, shapes, one_chip)
+    B, M = JOYAI_CELL["B"], JOYAI_CELL["M"]
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        args = (_spec((B, M), jnp.int32, one_chip),
+                _spec((0,), jnp.int32, one_chip))
+    else:
+        n, W, C = 8, 32, eng.admit_chunk
+        fn = eng._extend_fn(n, W, skip_pool=False)
+        args = (_spec((n, C), jnp.int32, one_chip),
+                _spec((n, W), jnp.int32, one_chip),
+                _spec((n,), jnp.int32, one_chip),
+                _spec((n,), jnp.int32, one_chip))
+    compiled = fn.lower(params, state, *args).compile()
+    text = compiled.as_text()
+    assert ("mla_decode" in text) == (program == "jit_chunk")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
