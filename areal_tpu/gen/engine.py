@@ -19,7 +19,11 @@ and SGLang's radix/paged KV memory. Redesigned for XLA:
   quantization").
 - Admission = CHUNKED PREFILL: prompts stream through a fixed
   ``[n_rows, page]`` extend program, so compile count is bounded by the
-  admit-row buckets alone — never by prompt length.
+  admit-row buckets alone — never by prompt length. A chunk is TWO
+  programs: ``jit_extend`` runs the layers over the pool and returns the
+  chunk's fresh K/V, ``jit_kv_write`` puts it into the pages (one program
+  for every bucket and table width, so the write kernel is traced and
+  lowered once a start).
 - Decode: a jitted ``lax.scan`` chunk of N steps; stop-token detection and
   per-slot caps run on device, so the host syncs once per chunk.
 - Interruption: the host stops issuing chunks and harvests partial outputs;
@@ -600,6 +604,7 @@ class GenerationEngine:
         self._lock = threading.RLock()
         self._pending_lock = threading.Lock()
         self._jit_extend: Dict[int, Any] = {}
+        self._jit_kv_write: Dict[int, Any] = {}
         self._jit_commit: Dict[int, Any] = {}
         self._jit_chunk: Dict[int, Any] = {}
         self._jit_spec: Dict[Any, Any] = {}
@@ -618,6 +623,10 @@ class GenerationEngine:
             # ... and its grid steps that reach a page / all its grid steps
             "kernel_steps_active": 0,
             "kernel_steps": 0,
+            # pool tiles the ``kv_page_write`` kernel writes: of a vanilla
+            # chunk as dispatched, of an admission wave's prefill; stays 0
+            # where the XLA scatter writes the pool (``_kv_write_rows``)
+            "kv_write_tiles": 0,
             # MoE models, per vanilla chunk (every row of the batch routes,
             # free slots too: the expert matmuls read what they route to):
             # distinct experts with a token, summed over layers and steps /
@@ -664,8 +673,9 @@ class GenerationEngine:
         """Total jitted specializations (stability tested: bounded by the
         admit buckets + decode/spec chunk sizes, NOT by prompt lengths)."""
         return (
-            len(self._jit_extend) + len(self._jit_commit)
-            + len(self._jit_chunk) + len(self._jit_spec)
+            len(self._jit_extend) + len(self._jit_kv_write)
+            + len(self._jit_commit) + len(self._jit_chunk)
+            + len(self._jit_spec)
         )
 
     def n_jit_entries(self) -> int:
@@ -676,21 +686,22 @@ class GenerationEngine:
 
         return jitcache.total_cache_size(
             j
-            for d in (self._jit_extend, self._jit_commit, self._jit_chunk,
-                      self._jit_spec)
+            for d in (self._jit_extend, self._jit_kv_write, self._jit_commit,
+                      self._jit_chunk, self._jit_spec)
             for j in d.values()
         )
 
     def program_sizes(self) -> Dict[str, int]:
         """``n_jit_entries`` by program: the jax-level specializations of
-        each admission, commit and chunk program, under the key the engine
-        files it by (``"extend(2, 64, False)"``). Two readings around a
+        each admission, write, commit and chunk program, under the key the
+        engine files it by (``"extend(2, 64, False)"``). Two readings around a
         window name the program that was specialised inside it."""
         from areal_tpu.base import jitcache
 
         return {
             f"{name}{key}": jitcache.cache_size(fn)
             for name, d in (("extend", self._jit_extend),
+                            ("kv_write", self._jit_kv_write),
                             ("commit", self._jit_commit),
                             ("chunk", self._jit_chunk))
             for key, fn in d.items()
@@ -965,48 +976,76 @@ class GenerationEngine:
         return min(w, self.M)
 
     def _extend_fn(self, n_rows: int, width: int, skip_pool: bool = False):
+        """The COMPUTING half of a prefill chunk: ``(*params, state, tokens,
+        table_rows, start, n_new)`` to the chunk's fresh K/V of every layer,
+        one ``(ks, vs)`` a pool (the target's, then the draft model's: the
+        prompt prefills BOTH pools from the same tokens, tables and waves,
+        which is what keeps them in lockstep through prefix sharing too).
+        The pool is read and NOT written here: that is ``_kv_write_fn``'s
+        program, which every bucket, table width and ``skip_pool`` share
+        (the write kernel is traced and lowered once a start, not once a
+        program: a dozen of them were 8 s of a 41 s set-up; PERF.md §6, PR
+        31), so the rows come out padded to ITS batch."""
         key = (n_rows, width, skip_pool)
         if key in self._jit_extend:
             return self._jit_extend[key]
-        cfg = self.cfg
-        dcfg = self.draft_cfg
+        cfgs = (self.cfg, self.draft_cfg)
+        pad = self._kv_write_batch(n_rows) - n_rows
 
-        if self._draft is None:
+        def extend(*args):
+            model, (state, *chunk) = args[:-5], args[-5:]
+            fresh = tuple(
+                tfm.extend_paged_kv(p, c, kv, *chunk, skip_pool=skip_pool)
+                for p, c, kv in zip(
+                    model, cfgs, (state.cache, state.draft_cache))
+            )
+            return jax.tree.map(
+                lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * 3),
+                fresh,
+            )
 
-            def extend(params, state: GenState, tokens, table_rows, start,
-                       n_new):
-                cache = tfm.extend_paged(
-                    params, cfg, state.cache, tokens, table_rows, start,
-                    n_new, skip_pool=skip_pool,
-                )
-                return dataclasses.replace(state, cache=cache)
-
-        else:
-            # draft-model serving: the prompt prefills BOTH pools in one
-            # program — the draft needs its own prompt KV before it can
-            # propose, and writing it here (same tokens, same tables,
-            # same waves) is what keeps the pools in lockstep through
-            # prefix sharing too (a borrowed page carries both models'
-            # KV, written once by the first prefill)
-            def extend(params, draft_params, state: GenState, tokens,
-                       table_rows, start, n_new):
-                cache = tfm.extend_paged(
-                    params, cfg, state.cache, tokens, table_rows, start,
-                    n_new, skip_pool=skip_pool,
-                )
-                dcache = tfm.extend_paged(
-                    draft_params, dcfg, state.draft_cache, tokens,
-                    table_rows, start, n_new, skip_pool=skip_pool,
-                )
-                return dataclasses.replace(
-                    state, cache=cache, draft_cache=dcache
-                )
-
-        jitted = jax.jit(
-            extend, donate_argnums=(self._state_argnum,),
-            **self._jit_sharding(4),
-        )
+        sharding_kw = self._jit_sharding(4)
+        sharding_kw.pop("out_shardings", None)
+        jitted = jax.jit(extend, **sharding_kw)
         self._jit_extend[key] = jitted
+        return jitted
+
+    def _kv_write_batch(self, n_rows: int) -> int:
+        """Rows of the write program that takes a wave of ``n_rows``: the
+        top bucket where the ``kv_page_write`` kernel writes (ONE program
+        holds it; a padding row, ``n_new`` 0, costs the kernel a skipped
+        loop), the wave's own where the XLA scatter does (it pays for
+        every row it is given, dropped or not)."""
+        return self.admit_buckets[-1] if self._kv_write_rows() else n_rows
+
+    def _kv_write_fn(self, n_rows: int):
+        """The WRITING half of a prefill chunk: ``(state, fresh, table_rows
+        [n_rows, M], start, n_new)`` to the state with every pool's fresh
+        K/V in its pages (``tfm._write_chunk_kv``: the path of the decode
+        step's write), the state donated."""
+        if n_rows in self._jit_kv_write:
+            return self._jit_kv_write[n_rows]
+        write_kw = dict(use_pallas=self._decode_use_pallas, mesh=self.mesh)
+
+        def kv_write(state: GenState, fresh, table_rows, start, n_new):
+            caches = [
+                tfm._write_chunk_kv(
+                    kv, ks, vs, table_rows, start, n_new, **write_kw)
+                for kv, (ks, vs) in zip(
+                    (state.cache, state.draft_cache), fresh)
+            ]
+            return dataclasses.replace(
+                state, **dict(zip(("cache", "draft_cache"), caches))
+            )
+
+        sharding_kw = {}
+        if self.mesh is not None:
+            sharding_kw = dict(
+                in_shardings=(self._state_sh, None) + (self._repl,) * 3,
+                out_shardings=self._state_sh,
+            )
+        jitted = jax.jit(kv_write, donate_argnums=(0,), **sharding_kw)
+        self._jit_kv_write[n_rows] = jitted
         return jitted
 
     def _jit_sharding(self, n_host_args: int, with_params: bool = True):
@@ -1096,10 +1135,12 @@ class GenerationEngine:
             i += len(chunk_rows)
             max_t = max(len(r["tokens"]) for r in chunk_rows)
             n_chunks = max(1, -(-max_t // C))
-            tables = np.zeros((n, self.M), np.int32)
-            starts0 = np.zeros((n,), np.int32)
+            # the write program's rows: the wave's, or padded to one batch
+            nw = self._kv_write_batch(n)
+            tables = np.zeros((nw, self.M), np.int32)
+            starts0 = np.zeros((nw,), np.int32)
             all_tokens = np.zeros((n, n_chunks * C), np.int32)
-            counts = np.zeros((n,), np.int32)
+            counts = np.zeros((nw,), np.int32)
             for j, r in enumerate(chunk_rows):
                 tables[j] = r["table_row"]
                 starts0[j] = r["start"]
@@ -1117,13 +1158,27 @@ class GenerationEngine:
                 # key includes it; at short-prompt admission the dead pool
                 # scan cost as much as the intra-chunk attention)
                 skip_pool = c == 0 and not starts0.any()
-                extend = self._extend_fn(n, W, skip_pool)
-                self.state = extend(
+                rows_per_tile = self._kv_write_rows()
+                if rows_per_tile:
+                    from areal_tpu.ops.pallas import kv_page_write
+
+                    self.stats["kv_write_tiles"] += self.cfg.n_layers * sum(
+                        kv_page_write.tiles_of_run(
+                            int(s0) + c * C, int(k), rows_per_tile
+                        )
+                        for s0, k in zip(starts0, n_new)
+                    )
+                start = starts0 + c * C
+                fresh = self._extend_fn(n, W, skip_pool)(
                     *self._model_args(), self.state,
                     jnp.asarray(all_tokens[:, c * C : (c + 1) * C]),
-                    jnp.asarray(tables[:, :W]),
-                    jnp.asarray(starts0 + c * C),
-                    jnp.asarray(n_new),
+                    jnp.asarray(tables[:n, :W]),
+                    jnp.asarray(start[:n]),
+                    jnp.asarray(n_new[:n]),
+                )
+                self.state = self._kv_write_fn(nw)(
+                    self.state, fresh, jnp.asarray(tables),
+                    jnp.asarray(start), jnp.asarray(n_new),
                 )
 
     def _admit(self):
@@ -1133,7 +1188,8 @@ class GenerationEngine:
         with tracing.span("gen_engine/admit") as attrs:
             st = self.stats
             before = (
-                st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"]
+                st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"],
+                st["kv_write_tiles"],
             )
             self._admit_pending()
             attrs.update(
@@ -1142,6 +1198,9 @@ class GenerationEngine:
                 prefix_hit_tokens=st["prefix_hit_tokens"] - before[2],
                 pending_left=self.n_pending(),
             )
+            if self._kv_write_rows():
+                # tiles the wave's prefill wrote through the kernel
+                attrs["kv_write_tiles"] = st["kv_write_tiles"] - before[3]
 
     def _admit_pending(self):
         if not self.accepting:
@@ -1537,10 +1596,13 @@ class GenerationEngine:
             # KV residency bound, acceptance-agnostic (see
             # ``verify_step_paged``): position i's KV can only ever be
             # read if emission n_gen+i stays below the cap — and writing
-            # past it could run off the slot's allocated pages
-            write_mask = state.active[:, None] & (
-                state.n_gen[:, None] + pos_i < state.max_gen[:, None]
-            )
+            # past it could run off the slot's allocated pages. A prefix
+            # of the chunk, so a count (the target's KV write takes it
+            # as one) and, for the draft model's steps, its mask
+            n_write = jnp.where(
+                state.active, jnp.clip(state.max_gen - state.n_gen, 0, C), 0
+            ).astype(jnp.int32)
+            write_mask = pos_i < n_write[:, None]
             if self._draft is not None:
                 # draft MODEL: K autoregressive small-model decode steps
                 # on the draft params + draft pool, sampling each token
@@ -1572,7 +1634,8 @@ class GenerationEngine:
             )                                             # [B, C]
             verify_out, cache = tfm.verify_step_paged(
                 params, cfg, state.cache, chunk_toks, table, state.lens,
-                n_new, write_mask, return_hidden=fused,
+                n_new, n_write, return_hidden=fused,
+                use_pallas=self._decode_use_pallas, mesh=self.mesh,
             )
             if self.mesh is not None:
                 # sampling runs replicated after one logits all-gather
@@ -2017,13 +2080,36 @@ class GenerationEngine:
             chunk_attrs["cache_bytes_per_token"] = self.cache_bytes_per_token()
             counts = self._kernel_counts(W)
             if counts is not None:
+                self.stats["resident_tokens"] += resident
+            if self._kv_write_rows() and not self.spec:
+                # one tile a (layer, running slot, step), as dispatched: a
+                # slot that finishes inside the chunk writes none after
+                counts = dict(counts or {}, kv_write_tiles=(
+                    self.cfg.n_layers * len(running) * decode_steps
+                ))
+            if counts is not None:
                 chunk_attrs.update(counts)
                 for name, n in counts.items():
                     self.stats[name] += n
-                self.stats["resident_tokens"] += resident
             self._observe_occupancy()
             chunk = make(decode_steps, W, wb)
             return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
+
+    def _kv_write_rows(self) -> int:
+        """Rows of the pool tile that the ``kv_page_write`` kernel copies,
+        where fresh K/V reaches this engine's pool through it; 0 where the
+        XLA scatter writes the pool (an int8 pool, a mesh, no TPU): the
+        predicate the model's write applies
+        (``ops/paged_attention.py:kv_write_kernel_applies``) over the same
+        pool."""
+        cache = self.state.cache
+        if not paged_ops.kv_write_kernel_applies(
+            self._decode_use_pallas, cache.pages, cache.quantized, self.mesh
+        ):
+            return 0
+        from areal_tpu.ops.pallas import kv_page_write
+
+        return kv_page_write.tile_rows(cache.pages.dtype)
 
     def _kernel_counts(self, W: int) -> Optional[Dict[str, int]]:
         """What the paged-decode kernel does at the first step of a vanilla

@@ -1007,13 +1007,28 @@ class PagedKVCache:
     share pages (counterpart of SGLang's radix-cache memory, SURVEY
     §2.1).
 
+    How fresh tokens get in (:func:`_write_chunk_kv`, once a step after
+    the layer scan): the head-before-token layout that spares the decode
+    kernel its transpose makes a token's rows STRIDED, ``S * Hkv`` rows of
+    ``D`` a layer, and on a TPU v5e XLA's row scatter pays 70 ns for each
+    of them whatever their number (1.0 ms of a 16.6 ms decode step at
+    1.5B widths, 3-6 % of every K/V rollout cell; ledger, PR 30). So on
+    the chip a raw-dtype pool on one device is written by the
+    ``kv_page_write`` kernel (``ops/pallas/kv_page_write.py``): the pool's
+    16-row tile of all streams and heads at once, read, merged and
+    written back IN PLACE, many in flight. The layout stays. An int8 pool
+    (a second array with another tile), a pool under a mesh and every
+    other platform keep the XLA scatter, :func:`_scatter_chunk_kv`, which
+    is also the kernel's plain reference.
+
     ``scales`` (int8 mode, docs/performance.md "KV quantization"): pages
     store int8 values and a parallel ``[L, P, 2, Hkv, page]`` f32 array
     carries one dequant scale per (page slot, kv head) — page-structured
     exactly like the pool, so page tables, TP's kv-head sharding, and
     radix prefix sharing address both arrays with the same indices and
     shared pages share their scales for free. Quantization happens at the
-    post-scan scatter (:func:`_scatter_chunk_kv`); dequant is fused into
+    post-scan scatter (:func:`_scatter_chunk_kv`, which an int8 pool
+    always takes); dequant is fused into
     every paged-attention entry point so int8 pages are read straight from
     HBM and widened in-register — a bf16 copy of the pool never exists.
     ``scales is None`` = raw serving-dtype pages (the default).
@@ -1066,13 +1081,58 @@ def kv_page_geometry(cfg: ModelConfig) -> Tuple[int, int, int]:
     return 2, cfg.n_kv_heads, cfg.head_dim
 
 
-def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, positions, valid):
-    """ONE scatter of every layer's fresh K/V into the pool.
+def _write_chunk_kv(
+    cache: PagedKVCache, ks, vs, table, start, count,
+    use_pallas: Optional[bool] = None, mesh=None,
+) -> PagedKVCache:
+    """Every layer's fresh K/V into the pool, ONCE, after the layer scan:
+    row ``b``'s tokens ``c < count[b]`` of the chunk land at positions
+    ``start[b] + c`` of its pages (``count`` 0: a free or finished slot, a
+    padding row; nothing of it is written). All three step functions end
+    here: one token a row (``decode_step_paged``), admission's chunks
+    (``extend_paged``), a speculative verify pass (``verify_step_paged``).
 
-    ks/vs ``[L, B, C, Hkv, D]``; positions/valid ``[B, C]``. Runs AFTER the
+    Which path runs where (``ops/paged_attention.py:
+    kv_write_kernel_applies``, one predicate over what is observable, no
+    flag): on a TPU a raw-dtype pool on one device takes the tile-copy
+    kernel ``kv_page_write`` (``ops/pallas/kv_page_write.py``), in place;
+    an int8 pool, a pool under a mesh, any other platform and the CPU
+    tests take :func:`_scatter_chunk_kv`, the kernel's plain reference,
+    which leaves the same bits."""
+    from areal_tpu.ops import paged_attention as paged_ops
+
+    pages = cache.pages
+    if not paged_ops.kv_write_kernel_applies(
+        use_pallas, pages, cache.quantized, mesh
+    ):
+        return _scatter_chunk_kv(cache, ks, vs, table, start, count)
+    from areal_tpu.ops.pallas import kv_page_write
+
+    fresh = jnp.stack([ks] if vs is None else [ks, vs], axis=3)
+    return PagedKVCache(
+        pages=kv_page_write.write(pages, fresh, table, start, count)
+    )
+
+
+def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, start, count):
+    """ONE XLA scatter of every layer's fresh K/V into the pool: the plain
+    reference of the ``kv_page_write`` kernel and the path of everything
+    that kernel does not take (:func:`_write_chunk_kv`).
+
+    ks/vs ``[L, B, C, Hkv, D]``; ``start``/``count`` ``[B]``: chunk token
+    ``c`` of row ``b`` is position ``start[b] + c`` and is written where
+    ``c < count[b]``. Runs AFTER the
     layer scan — the pool never rides the scan carry (which streamed the
     whole multi-GB pool through stacked scan outputs every step; measured
     ~30 ms/step at a 1.5B/64-slot decode, round-3 xprof).
+
+    What it costs on a TPU v5e: the scatter pays per ROW, serially, 70 ns
+    for every 256-byte row whatever the row count, and the bytes are
+    nothing (ledger, PR 30, one decode step: 28 x 128 x 2 x 2 = 14,336
+    rows in 1.00 ms at 1.5B; 16 x 64 x 2 x 4 = 8,192 in 0.58 ms at 7B-l16;
+    8 x 64 x 2 x 16 = 16,384 in 1.14 ms at OLMoE-l8: 70 / 70 / 69 ns a row
+    for 3.7 MB that the chip's HBM moves in 4.5 us). That is why the chip
+    does not run it where the kernel applies.
 
     The scatter runs on a FLAT ``[L*P*2*Hkv*page, D]`` row view: flattening
     every dim but the minor one is a layout-preserving bitcast, and a 2D
@@ -1094,6 +1154,8 @@ def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, positions, valid):
     L, B, C, Hkv, D = ks.shape
     P, S_, _, page = cache.pages.shape[1:5]
     M = table.shape[1]
+    positions = start[:, None] + jnp.arange(C)[None, :]
+    valid = jnp.arange(C)[None, :] < count[:, None]
     page_idx = jnp.take_along_axis(
         table, jnp.clip(positions // page, 0, M - 1), axis=1
     )                                                   # [B, C]
@@ -1148,17 +1210,15 @@ def _extend_layers(
     n_new: jnp.ndarray,      # [B]
     skip_pool: bool = False,
     verify: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
     """Shared multi-token layer scan over the page pool (chunked prefill
     AND the spec-decode verify pass — one implementation, two attention
-    entry points). Returns ``(x [B, C, E] pre-final-norm hidden, ks, vs,
-    positions, valid)``; the caller scatters KV and (for verify) applies
-    the head."""
+    entry points). Returns ``(x [B, C, E] pre-final-norm hidden, ks,
+    vs)``; the caller writes the KV and (for verify) applies the head."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     B, C = tokens.shape
     positions = start[:, None] + jnp.arange(C)[None, :]
-    valid = jnp.arange(C)[None, :] < n_new[:, None]
     x = _embed(cfg, params, tokens, positions)
     if cfg.apply_rotary:
         cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
@@ -1203,10 +1263,10 @@ def _extend_layers(
         return (x, li + 1), (k, v)
 
     (x, _), (ks, vs) = _scan_layers(layer, (x, jnp.int32(0)), params)
-    return x, ks, vs, positions, valid
+    return x, ks, vs
 
 
-def extend_paged(
+def extend_paged_kv(
     params: Params,
     cfg: ModelConfig,
     cache: PagedKVCache,
@@ -1215,17 +1275,44 @@ def extend_paged(
     start: jnp.ndarray,      # [B] tokens already resident per slot
     n_new: jnp.ndarray,      # [B] valid tokens in this chunk (<= C)
     skip_pool: bool = False,
-) -> PagedKVCache:
-    """Chunked prefill: attend the chunk causally over everything resident
-    (pool part + intra-chunk part, merged inside the op) and scatter the
-    chunk's KV into the pages once after the layer scan. Logits are not
-    computed — admission feeds the last prompt token to the first decode
+):
+    """Chunked prefill, the computing half: attend the chunk causally over
+    everything resident (pool part + intra-chunk part, merged inside the
+    op) and return every layer's fresh ``(ks, vs)`` ``[L, B, C, Hkv, D]``
+    (``vs`` None for a latent pool); the pool is read, not written
+    (:func:`_write_chunk_kv` is the other half, and the engine runs it as
+    a program of its own, ``gen/engine.py:_kv_write_fn``). Logits are not
+    computed: admission feeds the last prompt token to the first decode
     step instead. ``skip_pool`` (STATIC): every row starts at position 0,
     so the pool scan is dead weight (see ``paged_extend_attention``)."""
-    _, ks, vs, positions, valid = _extend_layers(
+    _, ks, vs = _extend_layers(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool
     )
-    return _scatter_chunk_kv(cache, ks, vs, table, positions, valid)
+    return ks, vs
+
+
+def extend_paged(
+    params: Params,
+    cfg: ModelConfig,
+    cache: PagedKVCache,
+    tokens: jnp.ndarray,
+    table: jnp.ndarray,
+    start: jnp.ndarray,
+    n_new: jnp.ndarray,
+    skip_pool: bool = False,
+    use_pallas: Optional[bool] = None,
+    mesh=None,
+) -> PagedKVCache:
+    """Both halves of chunked prefill in one call: :func:`extend_paged_kv`,
+    then the chunk's KV into the pages (:func:`_write_chunk_kv`, whose
+    path ``use_pallas`` / ``mesh`` choose; the chunk's attention is
+    XLA's)."""
+    ks, vs = extend_paged_kv(
+        params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool
+    )
+    return _write_chunk_kv(
+        cache, ks, vs, table, start, n_new, use_pallas, mesh
+    )
 
 
 def verify_step_paged(
@@ -1236,34 +1323,41 @@ def verify_step_paged(
     table: jnp.ndarray,        # [B, M]
     lens: jnp.ndarray,         # [B] resident tokens (chunk starts here)
     n_new: jnp.ndarray,        # [B] C where the slot is active, 0 otherwise
-    write_mask: jnp.ndarray,   # [B, C] which chunk positions' KV may land
+    n_write: jnp.ndarray,      # [B] how many chunk positions' KV may land
     return_hidden: bool = False,
+    use_pallas: Optional[bool] = None,
+    mesh=None,
 ) -> Tuple[jnp.ndarray, PagedKVCache]:
     """Speculative-decode VERIFY: ``decode_step_paged`` generalized to C =
     K+1 query tokens per slot in ONE pass — one params read and one pool
     sweep score the whole draft, where vanilla decode pays both per token.
     Returns fp32 logits ``[B, C, V]`` (position ``i`` is the distribution
     for the token following ``tokens[:, i]``) and the cache with the
-    chunk's KV scattered where ``write_mask`` allows.
+    chunk's KV written for the first ``n_write`` positions of each row.
 
     ``return_hidden=True`` (STATIC) returns the final-NORM hidden states
     ``[B, C, E]`` instead of logits: the fused sampling epilogue
     (``ops/fused_sample.py``) streams the head over vocab blocks itself,
     so the ``[B, C, V]`` logits never materialize.
 
-    ``write_mask`` is the acceptance-agnostic residency bound the engine
-    computes (``active & (n_gen + i < max_gen)``): rejected drafts' KV
+    ``n_write`` is the acceptance-agnostic residency bound the engine
+    computes (position ``i`` lands where the slot is active and ``n_gen +
+    i < max_gen``: a PREFIX of the chunk, so a count says it, as ``n_new``
+    does for admission): rejected drafts' KV
     lands in pool positions beyond the post-acceptance ``lens``, which
     attention never reads (``pos < lens``) and later steps overwrite
     before ``lens`` reaches them — so the scatter can run BEFORE the
     accept/reject decision, keeping the whole spec step inside one jitted
-    chunk with no host sync. The mask only exists to keep writes inside
+    chunk with no host sync. The bound only exists to keep writes inside
     the slot's allocated pages (a position past ``max_gen`` could fall off
-    the page table and alias page 0)."""
-    x, ks, vs, positions, _ = _extend_layers(
+    the page table and alias page 0). ``use_pallas`` / ``mesh`` choose the
+    KV write's path only (:func:`_write_chunk_kv`)."""
+    x, ks, vs = _extend_layers(
         params, cfg, cache, tokens, table, lens, n_new, verify=True
     )
-    cache = _scatter_chunk_kv(cache, ks, vs, table, positions, write_mask)
+    cache = _write_chunk_kv(
+        cache, ks, vs, table, lens, n_write, use_pallas, mesh
+    )
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     if return_hidden:
         return x, cache
@@ -1295,7 +1389,9 @@ def decode_step_paged(
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
     cache, new lens — incremented where active). The pool is read-only in
     the layer scan; each layer's fresh K/V merges into attention as the
-    self token and lands in the pool via one post-scan scatter.
+    self token and lands in the pool in one post-scan write
+    (:func:`_write_chunk_kv`: the tile-copy kernel on the chip, the XLA
+    scatter elsewhere).
 
     The layer scan runs on the rows ORDERED BY ``lens`` (one sort a step,
     hoisted out of the scan): the paged kernel works through every block
@@ -1375,10 +1471,10 @@ def decode_step_paged(
         layer, (x, jnp.int32(0)), params
     )
     x, ks = x[inverse], ks[:, inverse]
-    cache = _scatter_chunk_kv(
+    cache = _write_chunk_kv(
         cache, ks[:, :, None],
         None if vs is None else vs[:, inverse][:, :, None],
-        table, lens[:, None], active[:, None],
+        table, lens, active.astype(jnp.int32), use_pallas, mesh,
     )
     if with_routing:
         if routing is None:

@@ -126,6 +126,32 @@ def latent_kernel_applies(
     )
 
 
+def kv_write_kernel_applies(
+    use_pallas: Optional[bool], pages, quantized: bool = False, mesh=None,
+) -> bool:
+    """Whether the step's fresh K/V reaches the pool ``pages`` (``[L, P,
+    S, H, page, W]``; its shape and dtype are read) by the tile-copy
+    kernel (``ops/pallas/kv_page_write.py``) or by the XLA row scatter,
+    from what the caller can observe. The scatter keeps: an int8 pool (a
+    second array, the scales, with another tile); a pool under a mesh of
+    more than one device (``pallas_call`` has no partitioning rule); a
+    page that is not whole tiles. Otherwise ``use_pallas`` as given (the
+    CPU tests run the kernel in interpret mode), or, left to the
+    auto-dispatch, on a TPU where a row is whole lane tiles."""
+    from areal_tpu.ops.pallas.kv_page_write import tile_rows
+
+    page, width = pages.shape[4:]
+    if (
+        quantized
+        or (mesh is not None and mesh.size > 1)
+        or page % tile_rows(pages.dtype) != 0
+    ):
+        return False
+    if use_pallas is not None:
+        return use_pallas
+    return jax.devices()[0].platform == "tpu" and width % 128 == 0
+
+
 def paged_decode_attention(
     q: jnp.ndarray,          # [B, H, D] one new token per slot
     k_self: jnp.ndarray,     # [B, Hkv, D] the new token's K (not in pool)

@@ -181,6 +181,10 @@ def ir_programs(phase_dir, jit_name):
 # the decode chunk's paged kernel by model: K and V pages (raw or int8), or
 # the latent pool of a latent-attention family (``joyai_llm_flash``)
 DECODE_KERNELS = ("paged_decode", "mla_decode")
+# the kernel that puts the step's fresh K/V into the page pool
+# (ops/pallas/kv_page_write.py), in the decode chunk and in admission's
+# write program (``jit_kv_write``)
+KV_WRITE_KERNEL = "kv_page_write"
 
 
 def kernels_in(paths):
@@ -513,11 +517,30 @@ def phase_serve(sz, args):
     require(proc.returncode == 0, f"gateway exit rc={proc.returncode}")
     chunks = ir_programs(d, "chunk")
     kernels = kernels_in(chunks)
+    # admission's write program: ONE, whatever the buckets and table widths
+    kv_writes = ir_programs(d, "kv_write")
+    write_kernels = kernels_in(kv_writes)
     if not args.rehearse:
         require(any(k.startswith(DECODE_KERNELS) for k in kernels),
                 f"decode chunk has no Pallas paged kernel: {kernels}")
+        require(KV_WRITE_KERNEL in kernels and KV_WRITE_KERNEL in write_kernels,
+                f"fresh K/V reaches the pool by the XLA scatter: chunk "
+                f"{kernels}, admission's write {write_kernels}")
+        require(len(kv_writes) == 1,
+                f"admission lowered {len(kv_writes)} write programs, not one")
+        require(metrics.get("engine_kv_write_tiles", 0) > 0,
+                "engine_kv_write_tiles is 0 on /metrics_json")
         require("hbm_peak_bytes_in_use" in metrics,
                 "gen server reported no memory_stats gauges")
+    # the server has given the chip back: the KV write alone, kernel and
+    # scatter into two copies of a small real pool, compared bit for bit
+    kv_write, _ = helper(d, "kvwrite", {
+        "arch": sz["arch"], "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(all(c["bit_equal"] and c["rows_changed"] == c["rows_written"]
+                for c in kv_write["cases"]),
+            f"kv_page_write and the XLA scatter leave different pools: "
+            f"{kv_write['cases']}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -567,6 +590,9 @@ def phase_serve(sz, args):
         "logprob_tolerance_rule": "2 x (dense forward in the served dtype "
         f"vs float32, max abs) + {LOGPROB_FLOOR} nats",
         "decode_chunk_lowerings": len(chunks), "kernels": kernels,
+        "kv_write_lowerings": len(kv_writes), "kv_write_kernels": write_kernels,
+        "kv_write_tiles": metrics.get("engine_kv_write_tiles"),
+        "kv_write_vs_scatter": kv_write["cases"],
         "kv_dtype": metrics.get("kv_dtype"),
         "fused_sample": metrics.get("fused_sample"),
         "peak_hbm_gib": round(
@@ -971,9 +997,71 @@ def child_recompute(arg):
     ]})
 
 
+def child_kvwrite(arg):
+    """Fresh rows into two copies of a small pool of the model's K/V
+    geometry and of a latent-shaped one (one stream of 640: no latent
+    model is served here, so this is as far as the chip sees that shape),
+    one through ``kv_page_write`` and one through the XLA scatter that is
+    its reference: a decode step's single tokens at the edges of tiles
+    and pages, then an admission wave's runs and a verify pass's."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.ops.pallas.kv_page_write import tile_rows
+
+    arch = arg["arch"]
+    dtype = jnp.dtype(arch["dtype"])
+    R = tile_rows(dtype)
+    page = 8 * R
+    lat_w = 16 if arg["rehearse"] else 640
+    pools = {
+        "kv": (arch["n_layers"], 2, arch["n_kv_heads"], arch["head_dim"]),
+        "latent": (5, 1, 1, lat_w),
+    }
+    rng = np.random.default_rng(arg["seed"])
+    B, M = 8, 3
+    table = jnp.asarray(1 + np.arange(B * M).reshape(B, M), jnp.int32)
+    ends = [0, R - 1, R, page - 1, page, 2 * page + 5, 7, 3 * page - 1]
+    writes = [      # (chunk, start, count)
+        (1, ends, [1, 1, 1, 1, 1, 1, 0, 1]),
+        (8 * R, [0, page, R + 5, 3, 2 * page, page - R, 0, 40],
+         [8 * R, 8 * R, 8 * R, 6 * R + 4, 3 * R + 9, R, 0, 1]),
+        (5, [R - 2, page - 3, 0, 11, page, 2 * page - 1, 9, 30],
+         [5, 5, 3, 0, 1, 4, 5, 2]),
+    ]
+    kernel = jax.jit(
+        lambda c, *a: tfm._write_chunk_kv(c, *a, use_pallas=True))
+    scatter = jax.jit(tfm._scatter_chunk_kv)
+    cases = []
+    for kind, (L, S, H, W) in pools.items():
+        pool = jnp.asarray(
+            rng.standard_normal((L, 1 + B * M, S, H, page, W)), dtype)
+        for C, start, count in writes:
+            ks = jnp.asarray(rng.standard_normal((L, B, C, H, W)), dtype)
+            vs = None if S == 1 else jnp.asarray(
+                rng.standard_normal((L, B, C, H, W)), dtype)
+            args = (ks, vs, table, jnp.asarray(start, jnp.int32),
+                    jnp.asarray(count, jnp.int32))
+            got = kernel(tfm.PagedKVCache(pages=pool), *args).pages
+            want = scatter(tfm.PagedKVCache(pages=pool), *args).pages
+            cases.append({
+                "pool": kind, "shape": list(pool.shape), "chunk": C,
+                "bit_equal": bool(jnp.array_equal(got, want)),
+                "rows_changed": int(jnp.sum(jnp.any(got != pool, axis=-1))),
+                "rows_written": sum(count) * L * S * H,
+            })
+            pool = got
+    emit({"cases": cases})
+
+
 CHILDREN = {
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
-    "tokenizer": child_tokenizer,
+    "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
 }
 
 

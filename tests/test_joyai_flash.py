@@ -600,7 +600,7 @@ def test_extend_then_verify_matches_reference(params, rng):
         chunk = jnp.zeros((2, 4), jnp.int32).at[1].set(jnp.asarray(seq[20:]))
         logits, cache2 = tfm.verify_step_paged(
             params, CFG, cache, chunk, table, jnp.asarray([0, 20]),
-            jnp.asarray([0, 4]), jnp.asarray([[False] * 4, [True] * 4]))
+            jnp.asarray([0, 4]), jnp.asarray([0, 4]))
     lp = jax.nn.log_softmax(logits[1], axis=-1)
     got = np.asarray(lp[np.arange(3), np.asarray(seq[21:])])
     np.testing.assert_allclose(

@@ -74,7 +74,8 @@ class TestQuantScatter:
         valid = np.asarray([[True], [True], [False]])
         out = tfm._scatter_chunk_kv(
             cache, jnp.asarray(ks), jnp.asarray(vs), jnp.asarray(table),
-            jnp.asarray(positions), jnp.asarray(valid),
+            jnp.asarray(positions[:, 0]),
+            jnp.asarray(valid[:, 0].astype(np.int32)),
         )
         pages = np.asarray(out.pages)
         scales = np.asarray(out.scales)
@@ -103,8 +104,8 @@ class TestQuantScatter:
             cache,
             jnp.zeros((CFG.n_layers, 1, 1, CFG.n_kv_heads, CFG.head_dim)),
             jnp.zeros((CFG.n_layers, 1, 1, CFG.n_kv_heads, CFG.head_dim)),
-            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 1), jnp.int32),
-            jnp.ones((1, 1), bool),
+            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+            jnp.ones((1,), jnp.int32),
         )
         assert out.scales is None
 
@@ -263,7 +264,7 @@ class TestEngineParity:
                 eng.params, CFG, state.cache, chunk,
                 jnp.asarray(eng._table_host[:, :W]), state.lens,
                 jnp.where(state.active, 4, 0).astype(jnp.int32),
-                state.active[:, None] & jnp.ones((2, 4), bool),
+                jnp.where(state.active, 4, 0).astype(jnp.int32),
             )
             logits[kd] = np.asarray(lg)
         err = np.abs(logits["int8"] - logits[None]).max()

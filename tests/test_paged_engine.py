@@ -787,8 +787,8 @@ def test_pool_scatter_matches_reference():
     got = np.asarray(_scatter_chunk_kv(
         PagedKVCache(pages=jnp.asarray(pages)),
         jnp.asarray(ks[:, :, None]), jnp.asarray(vs[:, :, None]),
-        jnp.asarray(table), jnp.asarray(lens[:, None]),
-        jnp.asarray(active[:, None]),
+        jnp.asarray(table), jnp.asarray(lens),
+        jnp.asarray(active.astype(np.int32)),
     ).pages)
     want = pages.copy()
     for b in range(B):
@@ -799,3 +799,103 @@ def test_pool_scatter_matches_reference():
             want[l, p_, 0, :, o, :] = ks[l, b]
             want[l, p_, 1, :, o, :] = vs[l, b]
     np.testing.assert_array_equal(got, want)
+
+
+def test_kv_write_kernel_and_scatter_leave_the_same_engine(params, monkeypatch):
+    """The same requests through an engine whose KV writes take the
+    ``kv_page_write`` kernel (interpret mode) and one whose writes take
+    the XLA scatter: after several chunks, with admissions between them
+    (prefix hits among them), the pools are bit-equal and so are the
+    outputs. Attention is held to the XLA gather path in both, so the
+    write is the only difference; ``kv_write_tiles`` is on the chunk and
+    admit spans of the one and absent from the other's."""
+    from areal_tpu.base import tracing
+    from areal_tpu.ops import paged_attention as paged_ops
+
+    monkeypatch.setattr(
+        paged_ops, "decode_kernel_applies", lambda *a, **k: False
+    )
+    rng = np.random.default_rng(3)
+    shared = rng.integers(1, 128, size=20).tolist()
+    prompts = [
+        shared + rng.integers(1, 128, size=n).tolist()
+        for n in (3, 9, 14, 1)
+    ] + [rng.integers(1, 128, size=n).tolist() for n in (5, 27, 12)]
+    pools, outs, spans = {}, {}, {}
+    for use_pallas in (True, False):
+        eng = GenerationEngine(
+            CFG, params, max_slots=4, max_seqlen=64, max_new_tokens_cap=16,
+            page_size=8, n_pages=40, seed=0,
+        )
+        eng._decode_use_pallas = use_pallas
+        assert bool(eng._kv_write_rows()) == use_pallas
+        mark = time.perf_counter()
+        got = {}
+        for i, ids in enumerate(prompts):
+            eng.submit(GenRequest(
+                rid=f"r{i}", input_ids=ids, max_new_tokens=6 + i,
+                greedy=True,
+            ))
+        for _ in range(12):          # 4 slots, 7 requests: two waves
+            for o in eng.step(decode_steps=3):
+                got[o.rid] = (list(o.output_ids), list(o.output_logprobs))
+            if len(got) == len(prompts):
+                break
+        assert len(got) == len(prompts)
+        pools[use_pallas] = np.asarray(eng.state.cache.pages)
+        outs[use_pallas] = got
+        spans[use_pallas] = [
+            r for r in tracing.spans_since(mark)
+            if r["name"] in ("gen_engine/chunk", "gen_engine/admit")
+        ]
+        assert (eng.stats["kv_write_tiles"] > 0) == use_pallas
+    np.testing.assert_array_equal(pools[True], pools[False])
+    assert outs[True] == outs[False]
+    on = [r["attrs"] for r in spans[True]]
+    assert all(
+        "kv_write_tiles" in a for a in on if a.get("slots") or "admitted" in a
+    )
+    # a vanilla chunk: one tile a (layer, running slot, step)
+    chunk = next(a for a in on if a.get("slots"))
+    assert chunk["kv_write_tiles"] == CFG.n_layers * chunk["slots"] * 3
+    # an admission: the tiles of 8 rows its prefilled tokens fall into
+    admit = next(a for a in on if a.get("prefill_tokens"))
+    assert admit["kv_write_tiles"] >= CFG.n_layers * (
+        -(-admit["prefill_tokens"] // 8)
+    )
+    assert not any("kv_write_tiles" in r["attrs"] for r in spans[False])
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_admission_writes_through_one_program(params, use_pallas):
+    """Admission's chunk is two programs: ``jit_extend`` (a bucket, a
+    table width, ``skip_pool``) returns the fresh K/V and leaves the state
+    alone, ``jit_kv_write`` puts it into the pages. Where the kernel
+    writes, waves of every size share ONE write program (rows padded to
+    the top bucket, so the kernel is traced and lowered once a start);
+    where the scatter does, a wave's write has the wave's rows (a scatter
+    pays for every row it is given)."""
+    eng = GenerationEngine(
+        CFG, params, max_slots=8, max_seqlen=64, max_new_tokens_cap=8,
+        page_size=8, n_pages=60, seed=0,
+    )
+    eng._decode_use_pallas = use_pallas
+    rng = np.random.default_rng(5)
+    k = 0
+    for wave in (1, 3, 2):              # buckets 1, 4, 2
+        for _ in range(wave):
+            eng.submit(GenRequest(
+                rid=f"w{k}", input_ids=rng.integers(1, 128, size=11).tolist(),
+                max_new_tokens=2, greedy=True,
+            ))
+            k += 1
+        eng.run_until_done(decode_steps=2)
+    top = eng.admit_buckets[-1]
+    assert {key[0] for key in eng._jit_extend} == {1, 2, 4}
+    assert sorted(eng._jit_kv_write) == ([top] if use_pallas else [1, 2, 4])
+    sizes = eng.program_sizes()
+    assert all(sizes[f"kv_write{n}"] == 1 for n in eng._jit_kv_write)
+    assert eng.n_compiles() == (
+        len(eng._jit_extend) + len(eng._jit_kv_write)
+        + len(eng._jit_commit) + len(eng._jit_chunk)
+    )

@@ -124,9 +124,8 @@ class TestGreedyParity:
         chunk = jnp.concatenate([state.last_tokens[:, None], drafts], axis=1)
         C = int(chunk.shape[1])
         n_new = jnp.where(state.active, C, 0).astype(jnp.int32)
-        wmask = state.active[:, None] & jnp.ones((1, C), bool)
         v_logits, _ = tfm.verify_step_paged(
-            params, CFG, state.cache, chunk, table, state.lens, n_new, wmask,
+            params, CFG, state.cache, chunk, table, state.lens, n_new, n_new,
         )
         # sequential teacher-forced decode over the same tokens
         cache, lens = state.cache, state.lens

@@ -23,6 +23,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -73,9 +74,10 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the three kernel modules off interpret mode (on the CPU their
     `_interpret()` says True) for the duration of one test."""
     from areal_tpu.ops.pallas import flash_attention, fused_sample
+    from areal_tpu.ops.pallas import kv_page_write
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
-    for mod in (flash_attention, fused_sample, pl_paged):
+    for mod in (flash_attention, fused_sample, pl_paged, kv_page_write):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -296,6 +298,114 @@ def test_mla_decode_compiles(compiled_kernels, one_chip):
     assert "mla_decode" in compiled.as_text()
 
 
+# ------------------------------------------------------------------ #
+# kv_page_write: fresh K/V into the pool by tile copies, in place
+# ------------------------------------------------------------------ #
+
+# the rollout cells' pools [L, P, S, H, 128, W], slots and table widths
+KV_WRITE_CELLS = {
+    "cell1": dict(L=28, P=2588, S=2, H=2, W=128, B=128, M=40,
+                  config="r1d-qwen-1p5b", seqlen=5120, out=4096),
+    "cell3": dict(L=16, P=1311, S=2, H=4, W=128, B=64, M=40,
+                  config="r1d-qwen-7b-l16", seqlen=5120, out=4096),
+    "olmoe": dict(L=8, P=1072, S=2, H=16, W=128, B=64, M=32,
+                  config="olmoe-1b-7b-l8", seqlen=4096, out=3072),
+    "joyai": dict(L=5, P=5967, S=1, H=1, W=640, B=256, M=72),
+}
+
+
+@pytest.mark.parametrize(
+    "cell,rows,chunk",
+    [
+        # a decode step: one token a slot (one 16-row slab a layer and slot)
+        ("cell1", None, 1), ("cell3", None, 1), ("olmoe", None, 1),
+        ("joyai", None, 1),
+        # an admission wave of 8 x 128 tokens at the two ends: 128 KB
+        # slabs, and a latent row of five lane tiles (its window of fresh
+        # rows starts between tiles: Mosaic takes that one lane tile wide)
+        ("olmoe", 8, 128), ("joyai", 8, 128),
+    ],
+)
+def test_kv_page_write_compiles(compiled_kernels, one_chip, cell, rows, chunk):
+    """The write kernel alone at the cells' pools, the pool donated: the
+    result IS the argument (aliased), and the program holds no second
+    pool (temporaries: the admission wave's rows in f32, nothing else)."""
+    from areal_tpu.ops.pallas import kv_page_write
+
+    c = KV_WRITE_CELLS[cell]
+    B = rows or c["B"]
+    pool = (c["L"], c["P"], c["S"], c["H"], 128, c["W"])
+    compiled = jax.jit(kv_page_write.write, donate_argnums=(0,)).lower(
+        _spec(pool, jnp.bfloat16, one_chip),
+        _spec((c["L"], B, chunk, c["S"], c["H"], c["W"]), jnp.bfloat16,
+              one_chip),
+        _spec((B, c["M"]), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kv_page_write" in text
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 0.4e9 < pool_bytes
+
+
+@pytest.mark.parametrize("cell", ["cell1", "cell3", "olmoe"])
+def test_kv_cells_chunk_writes_the_pool_in_place(
+        compiled_kernels, one_chip, cell):
+    """``jit_chunk`` of the three K/V rollout cells (16 decode steps at
+    the cell's slots and table, the engine's own program with its state
+    donated): ``paged_decode`` and ``kv_page_write`` are both in it, no
+    scatter over the pool is, and arguments + temporaries leave no room
+    for a second pool (9.5 / 5.5 / 9.0 GB beside the weights)."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from benchmark import sut
+
+    c = KV_WRITE_CELLS[cell]
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", c["config"] + ".json")) as f:
+        cfg = sut.model_config(json.load(f), {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=c["B"], max_seqlen=c["seqlen"],
+        max_new_tokens_cap=c["out"], page_size=128, n_pages=80, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.M == c["M"] and eng._kv_write_rows() == 16
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    pages = eng.state.cache.pages
+    pool = (pages.shape[0], c["P"]) + pages.shape[2:]
+    assert pool == (c["L"], c["P"], c["S"], c["H"], 128, c["W"])
+    state = dataclasses.replace(
+        jax.tree.map(spec, eng.state),
+        cache=tfm.PagedKVCache(pages=_spec(pool, pages.dtype, one_chip)))
+    compiled = eng._chunk_fn(16, c["M"], 0, fused=False, with_topk=False).lower(
+        jax.tree.map(spec, shapes), state,
+        _spec((c["B"], c["M"]), jnp.int32, one_chip),
+        _spec((0,), jnp.int32, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_decode" in text and "kv_page_write" in text
+    n_rows = int(np.prod(pool[:5]))
+    assert f"bf16[{n_rows},128]" not in text     # the scatter's flat view
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * n_rows * 128
+    weight_bytes = sum(
+        2 * int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < weight_bytes + pool_bytes + 1.0e9)
+
+
 @pytest.fixture(scope="module")
 def joyai_engine():
     """The engine of the cell at its real configuration, with placeholder
@@ -339,30 +449,47 @@ def _joyai_program_specs(eng, shapes, one_chip):
     return jax.tree.map(spec, shapes), state
 
 
-@pytest.mark.parametrize("program", ["jit_chunk", "jit_extend"])
+@pytest.mark.parametrize("program", ["jit_chunk", "jit_extend", "jit_write"])
 def test_joyai_engine_programs_compile(
         compiled_kernels, one_chip, joyai_engine, program):
     """``jit_chunk`` (16 decode steps over the latent pool at 256 slots and
     the full table: two scans, ``mla_decode``, the 256-expert dispatch, the
-    129k-vocabulary head) and ``jit_extend`` (an admission wave of 8 x 128
-    tokens against the pool) for a described v5e, beside 11.1 GB of weights
-    and the cell's pool: arguments + temporaries under the chip's 16.9e9."""
+    129k-vocabulary head), ``jit_extend`` (an admission wave of 8 x 128
+    tokens against the pool, which it reads and does not write) and
+    ``jit_write`` (the wave's fresh latents into the pool, the one program
+    every admission bucket and table width shares) for a described v5e,
+    beside 11.1 GB of weights and the cell's pool: arguments + temporaries
+    under the chip's 16.9e9."""
     eng, shapes = joyai_engine
     params, state = _joyai_program_specs(eng, shapes, one_chip)
     B, M = JOYAI_CELL["B"], JOYAI_CELL["M"]
+    n, W, C = 8, 32, eng.admit_chunk
+    extend = eng._extend_fn(n, W, skip_pool=False)
+    extend_args = (params, state,
+                   _spec((n, C), jnp.int32, one_chip),
+                   _spec((n, W), jnp.int32, one_chip),
+                   _spec((n,), jnp.int32, one_chip),
+                   _spec((n,), jnp.int32, one_chip))
     if program == "jit_chunk":
         fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
-        args = (_spec((B, M), jnp.int32, one_chip),
+        args = (params, state, _spec((B, M), jnp.int32, one_chip),
                 _spec((0,), jnp.int32, one_chip))
+    elif program == "jit_extend":
+        fn, args = extend, extend_args
     else:
-        n, W, C = 8, 32, eng.admit_chunk
-        fn = eng._extend_fn(n, W, skip_pool=False)
-        args = (_spec((n, C), jnp.int32, one_chip),
-                _spec((n, W), jnp.int32, one_chip),
+        assert eng._kv_write_batch(1) == eng._kv_write_batch(n) == n
+        fresh = jax.tree.map(
+            lambda a: _spec(a.shape, a.dtype, one_chip),
+            jax.eval_shape(extend, *extend_args))
+        fn = eng._kv_write_fn(n)
+        args = (state, fresh, _spec((n, M), jnp.int32, one_chip),
                 _spec((n,), jnp.int32, one_chip),
                 _spec((n,), jnp.int32, one_chip))
-    compiled = fn.lower(params, state, *args).compile()
+    compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     assert ("mla_decode" in text) == (program == "jit_chunk")
+    # fresh latents reach the pool by the tile-copy kernel, in the chunk
+    # and in admission's write program; admission's layers hold no write
+    assert ("kv_page_write" in text) == (program != "jit_extend")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
