@@ -17,6 +17,18 @@ and SGLang's radix/paged KV memory. Redesigned for XLA:
   dequant fuses into every paged-attention path — half the decode KV bytes,
   itemsize-ratio x pages at the same pool HBM (docs/performance.md "KV
   quantization").
+- LAYER KINDS (``cfg.layer_pattern``: window and full layers in one stack):
+  still ONE pool and ONE free list, pages of one byte size (a page holds
+  ``page`` tokens of one position of the period in every period), and a
+  slot has one table a position of the period. A full position's pages are
+  all taken at admission, as ever. A window position takes the prompt's
+  pages at admission and RESERVES what it will need later: at most the
+  window, a page and the look-ahead of the chunks in flight. Before every
+  chunk (and every chunk of admission) the pages wholly behind ``len -
+  window`` are released, WHILE the request runs: to the free list, unless
+  the prefix registry or a sibling still holds them, and the pages the
+  chunk will write are taken from the reservation. A model of one kind is
+  the same code with one position a period: no second allocator.
 - Admission = CHUNKED PREFILL: prompts stream through a fixed
   ``[n_rows, page]`` extend program, so compile count is bounded by the
   admit-row buckets alone — never by prompt length. A chunk is TWO
@@ -63,7 +75,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from areal_tpu.base import constants, tracing
 from areal_tpu.base import metrics as metrics_mod
 from areal_tpu.gen.drafter import Drafter, NGramDrafter, TransformerDrafter
-from areal_tpu.gen.pages import OutOfPagesError, PagePool, PrefixRegistry
+from areal_tpu.gen.pages import PagePool, PrefixRegistry
+from areal_tpu.gen.pages import _ids as _held_ids
 from areal_tpu.gen.sampling import (
     SamplingParams,
     sample_tokens,
@@ -175,6 +188,15 @@ class GenOutput:
     output_routing: Optional[np.ndarray] = None
 
 
+def _page_ids(page, kind: Optional[int] = None):
+    """Of one entry of a prefix hit (a page id, or with layer kinds the
+    page of every kind, -1 where a window kind's is gone): the pool pages
+    it holds, or the page of ``kind`` (-1: none)."""
+    if kind is None:
+        return _held_ids(page)
+    return int(page) if isinstance(page, (int, np.integer)) else int(page[kind])
+
+
 def _finish_reason(n_gen, max_gen) -> str:
     """length-vs-stop classification, shared by every harvest site."""
     return "length" if n_gen >= max_gen else "stop"
@@ -202,9 +224,15 @@ def _resolve_kv_dtype(kv_dtype: Optional[str], serving_dtype: str) -> str:
 
 @dataclasses.dataclass
 class _SlotInfo:
+    """A running request's slot. Its pages are the entries of its tables
+    that ``GenerationEngine._held`` marks (one reference each, whether the
+    slot took the page fresh or borrowed it from the prefix registry)."""
+
     rid: str
-    pages: List[int]          # owned pages (refcount held by this slot)
-    borrowed: List[int]       # shared prefix pages (one ref held)
+    n_total: int              # pages of prompt + whole output, a kind
+    # a kind's pages promised to this slot and not yet taken (0 for a
+    # full kind, whose pages are all taken at admission)
+    reserved: List[int]
     t_submit: Optional[float] = None    # the GenOutput's timestamps
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
@@ -444,11 +472,40 @@ class GenerationEngine:
         # point: more resident slots/longer prefixes at fixed HBM), never
         # a smaller footprint by surprise. Pass n_pages to cap bytes.
         bytes_ratio = jnp.dtype(cfg.dtype).itemsize if self.kv_quantized else 1
+        # layer kinds: the sliding window of each position of the model's
+        # period (None: full attention); one page table a position
+        self._windows = [w for w, _ in cfg.layer_kinds]
+        self._windowed = any(w is not None for w in self._windows)
+        K = len(self._windows)
+        if self._draft is not None and (
+            K > 1 or self.draft_cfg.period > 1
+        ):
+            raise NotImplementedError(
+                "layer kinds: a draft model shares the target's page "
+                "tables, which a model with a period of kinds has several of"
+            )
         self.n_pages = (
-            n_pages if n_pages is not None else self.B * self.M * bytes_ratio
+            n_pages if n_pages is not None
+            else self.B * self.M * bytes_ratio * K
         )
         self.pool = PagePool(self.n_pages, page_size)
-        self.prefix = PrefixRegistry(self.pool)
+        self.prefix = PrefixRegistry(self.pool, self._windows)
+        # positions a dispatch may run ahead of the host's lengths: the
+        # chunk's tokens and, in pipelined mode, the chunk still in flight,
+        # at ``step``'s default of 16 decode steps (a longer chunk takes
+        # what it needs beyond that from the pool at large, or is refused:
+        # ``_roll_windows``). Admission needs none: a prompt's pages are
+        # all taken before its chunks run
+        self._lookahead = 2 * 16 * (
+            (spec_k or constants.spec_k()) + 1 if spec_on else 1)
+        # the most pages a slot holds in a window kind at once: positions
+        # ``[n + 1 - window, n + lookahead)`` wherever they lie in their
+        # pages
+        self._window_claim = [
+            None if w is None
+            else (w + self._lookahead - 2) // page_size + 2
+            for w in self._windows
+        ]
 
         def make_state() -> GenState:
             return GenState(
@@ -525,7 +582,20 @@ class GenerationEngine:
         self.accepting = True  # False = decode only, no new admissions
         self.paused = False
         self._slots: List[Optional[_SlotInfo]] = [None] * self.B
-        self._table_host = np.zeros((self.B, self.M), np.int32)
+        # one table a layer kind; ``_table_host`` is the first (the only
+        # one of a model of one kind), ``_held`` which entries a slot holds
+        # a reference through, ``_win_lo`` / ``_win_hi`` the pages of a
+        # window kind released so far / taken so far
+        self._tables_host = np.zeros((K, self.B, self.M), np.int32)
+        self._table_host = self._tables_host[0]
+        self._held = np.zeros((K, self.B, self.M), bool)
+        self._win_lo = np.zeros((K, self.B), np.int64)
+        self._win_hi = np.zeros((K, self.B), np.int64)
+        # window kinds' pages that more than one slot holds, counted once
+        # for every holder past the first: each is a page promised
+        # (``pool.reserved``) to whichever holder gives the shared page up
+        # while another still reads it, and then needs one of its own
+        self._deposits = 0
         # host mirror of per-slot resident lengths: admission knows them
         # exactly, each chunk's sync refreshes them — lets decode chunks
         # run width-limited (see _table_width) without extra device pulls
@@ -627,6 +697,12 @@ class GenerationEngine:
             # chunk as dispatched, of an admission wave's prefill; stays 0
             # where the XLA scatter writes the pool (``_kv_write_rows``)
             "kv_write_tiles": 0,
+            # layer kinds: pages of window kinds released behind the window
+            # while their request ran (at admission's chunks and before
+            # decode chunks), and the window kinds' share of the resident
+            # tokens (sum over running slots of min(len, window), a chunk)
+            "window_pages_released": 0,
+            "window_resident_tokens": 0,
             # MoE models, per vanilla chunk (every row of the batch routes,
             # free slots too: the expert matmuls read what they route to):
             # distinct experts with a token, summed over layers and steps /
@@ -724,7 +800,7 @@ class GenerationEngine:
         # what the MODEL says a token holds (K and V heads, or one padded
         # latent row), not 2 * Hkv * D
         streams, heads, width = tfm.kv_page_geometry(cfg)
-        elems = cfg.n_layers * self.n_pages * streams * heads * self.page
+        elems = cfg.n_periods * self.n_pages * streams * heads * self.page
         item = 1 if quantized else jnp.dtype(cfg.dtype).itemsize
         total = elems * width * item
         if quantized:
@@ -733,8 +809,20 @@ class GenerationEngine:
 
     def cache_bytes_per_token(self) -> int:
         """What one resident token really takes in the pool, every layer,
-        padding and an int8 pool's scales included."""
-        return self.kv_pool_bytes() // (self.n_pages * self.page)
+        padding and an int8 pool's scales included (with layer kinds: a
+        token that is resident in every kind)."""
+        return sum(self.cache_bytes_per_token_by_kind().values())
+
+    def cache_bytes_per_token_by_kind(self) -> Dict[str, int]:
+        """:meth:`cache_bytes_per_token` split by layer kind: ``full``
+        layers hold a token for as long as its request runs, ``window``
+        layers for ``sliding_window`` positions."""
+        one = self.kv_pool_bytes() // (self.n_pages * self.page)
+        n_window = sum(w is not None for w in self._windows)
+        out = {"full": one * (len(self._windows) - n_window)}
+        if n_window:
+            out["window"] = one * n_window
+        return out
 
     def draft_kv_pool_bytes(self) -> int:
         """Configured HBM footprint of the draft model's KV pool (0 when
@@ -747,8 +835,12 @@ class GenerationEngine:
         return self._pool_bytes_for(self.draft_cfg, self.draft_kv_quantized)
 
     def kv_pool_occupancy(self) -> float:
-        """Fraction of pool pages currently held (slots + prefix cache)."""
-        return 1.0 - self.pool.n_free / max(self.n_pages, 1)
+        """Fraction of pool pages currently held (slots + prefix cache) or
+        promised to a running slot (a window kind's later pages, counted
+        against the free ones; what the registry alone holds backs a
+        promise too and is held either way)."""
+        free = max(self.pool.n_free - self.pool.reserved, 0)
+        return 1.0 - free / max(self.n_pages, 1)
 
     def kv_pool_demand_occupancy(self) -> float:
         """Occupancy excluding prefix-cache-only pages (instantly
@@ -756,8 +848,7 @@ class GenerationEngine:
         (the serving gateway) should use: raw occupancy counts cache the
         next admission would evict, so a cache-warm idle server would
         read as permanently full."""
-        free_eq = self.pool.n_free + self.prefix.n_reclaimable()
-        return 1.0 - free_eq / max(self.n_pages, 1)
+        return 1.0 - max(self.pool.n_unpromised, 0) / max(self.n_pages, 1)
 
     def _observe_occupancy(self):
         """Fold the current pool occupancy into the telemetry histogram —
@@ -894,15 +985,7 @@ class GenerationEngine:
         with self._lock:
             for b, s in enumerate(self._slots):
                 if s is not None and s.rid == rid:
-                    self._slots[b] = None
-                    self.pool.release(s.pages)
-                    if s.borrowed:
-                        self.pool.release(s.borrowed)
-                    self._table_host[b] = 0
-                    self._lens_host[b] = 0
-                    self._warp_host[b] = False
-                    self._fused_warp_host[b] = False
-                    self._fused_topk_host[b] = False
+                    self._free_slot(b)
                     with self._pending_lock:
                         self._req_meta.pop(rid, None)
                     # deactivate on device so later chunks stop feeding the
@@ -956,6 +1039,138 @@ class GenerationEngine:
     def resume(self):
         with self._lock:
             self.paused = False
+
+    # ------------------------------------------------------------------ #
+    # A slot's pages, by layer kind
+    # ------------------------------------------------------------------ #
+
+    def _free_slot(self, b: int) -> _SlotInfo:
+        """Release slot ``b``: every page of every kind it holds a
+        reference through, and what it had reserved and not taken."""
+        info = self._slots[b]
+        self._slots[b] = None
+        held = self._held[:, b]
+        for j, w in enumerate(self._windows):
+            if w is not None:
+                self._give_up(j, self._tables_host[j, b][held[j]])
+        self.pool.release(self._tables_host[:, b][held].tolist())
+        self.pool.reserved -= sum(info.reserved)
+        held[:] = False
+        self._tables_host[:, b] = 0
+        self._lens_host[b] = 0
+        self._warp_host[b] = False
+        self._fused_warp_host[b] = False
+        self._fused_topk_host[b] = False
+        return info
+
+    def _give_up(self, j: int, pages) -> None:
+        """Before a slot releases ``pages`` of window kind ``j``: those
+        that another slot still holds were paid for by a deposit when the
+        second holder came (``_deposits``), and one is spent now."""
+        if len(pages):
+            n = int((self.pool.n_slot_holders(pages) > 1).sum())
+            self._deposits -= n
+            self.pool.reserved -= n
+
+    def _window_want(self, b: int, j: int) -> int:
+        """Pages of window kind ``j`` that slot ``b`` may still have to
+        take: its claim (the most it holds at once: the window, a page and
+        the look-ahead) less what it holds from the window's edge on, and
+        never more than the pages it has not reached yet. (A prompt
+        longer than that is held whole for the length of its admission;
+        it wants nothing until its chunks have given enough back.)"""
+        info = self._slots[b]
+        lo = int(self._win_lo[j, b])
+        want = min(self._window_claim[j], info.n_total - lo) - int(
+            self._held[j, b, lo:].sum())
+        return max(min(want, info.n_total - int(self._win_hi[j, b])), 0)
+
+    def _roll_windows(self, b: int, n_lo: int, n_hi: int) -> int:
+        """Slot ``b`` is about to run positions ``[n_lo, n_hi)`` (a decode
+        chunk, a chunk of admission): in every window kind, give up the
+        pages that lie wholly behind ``n_lo + 1 - window`` (no later query
+        sees them; to the free list, unless the registry or a sibling
+        still holds them) and take, from the slot's reservation, the pages
+        up to ``n_hi``. Returns the pages released.
+
+        Why taking never fails: ``pool.reserved`` (the slots' ``reserved``
+        and the deposits) is at all times backed by pages that are free or
+        held by the registry alone (``PagePool.n_unpromised >= 0``).
+        Admission checks it. A released page that nobody else holds goes
+        free (or stays with the registry alone), which backs the page the
+        window needs at its other end; one that a sibling still reads was
+        paid for by that sibling's deposit (``_give_up``)."""
+        info = self._slots[b]
+        page = self.page
+        released = 0
+        for j, w in enumerate(self._windows):
+            if w is None:
+                continue
+            lo = max(n_lo + 1 - w, 0) // page
+            if lo > self._win_lo[j, b]:
+                idx = np.arange(self._win_lo[j, b], lo)
+                idx = idx[self._held[j, b, idx]]
+                self._give_up(j, self._tables_host[j, b, idx])
+                self.pool.release(self._tables_host[j, b, idx].tolist())
+                if self.enable_prefix_cache:
+                    self.prefix.note_given_up(self._tables_host[j, b, idx])
+                self._held[j, b, idx] = False
+                self._tables_host[j, b, idx] = 0
+                self._win_lo[j, b] = lo
+                released += len(idx)
+                want = self._window_want(b, j)
+                self.pool.reserved += want - info.reserved[j]
+                info.reserved[j] = want
+            hi = min(-(-n_hi // page), info.n_total)
+            take = hi - int(self._win_hi[j, b])
+            if take > 0:
+                if take > info.reserved[j]:
+                    # a chunk longer than the look-ahead the slot reserved
+                    # for (``step(decode_steps)`` is the caller's): the
+                    # pool backs the difference, or the call is refused
+                    extra = take - info.reserved[j]
+                    if self.pool.n_unpromised < extra:
+                        raise RuntimeError(
+                            f"slot {b} runs {n_hi - n_lo} positions ahead, "
+                            f"past what a window kind reserves "
+                            f"({self._lookahead}), and the pool has no page "
+                            "to spare"
+                        )
+                    info.reserved[j] += extra
+                    self.pool.reserved += extra
+                if self.pool.n_free < take:
+                    # (a walk of the whole tree once the queue of given-up
+                    # pages is empty: ask for a batch, not a page)
+                    self.prefix.evict_lru(max(take, 64))
+                at = slice(int(self._win_hi[j, b]), hi)
+                self._tables_host[j, b, at] = self.pool.alloc(take)
+                self._held[j, b, at] = True
+                self._win_hi[j, b] = hi
+                info.reserved[j] -= take
+                self.pool.reserved -= take
+        self.stats["window_pages_released"] += released
+        return released
+
+    def _registry_pages(self, slot: int, n: int) -> List:
+        """The slot's first ``n`` table entries as the prefix registry
+        files them: page ids, or with layer kinds one list a page, the
+        page of every kind (-1 where a window kind's has been given up)."""
+        if len(self._windows) == 1:
+            return self._table_host[slot, :n].tolist()
+        return np.where(
+            self._held[:, slot, :n], self._tables_host[:, slot, :n], -1
+        ).T.tolist()
+
+    def _table_arg(self, rows, width: int):
+        """The page tables of ``rows`` (slots, or all with ``slice(None)``)
+        cut to ``width`` entries, as the jitted programs take them: ``[n,
+        width]``, or with layer kinds ``[kinds, n, width]``. Always a COPY:
+        on a CPU backend ``jnp.asarray`` may alias the host buffer, a
+        dispatched program reads it later, and the tables of running slots
+        change between dispatches (``_roll_windows``)."""
+        if len(self._windows) > 1:
+            return self._tables_host[:, rows, :width].copy()
+        return self._table_host[rows, :width].copy()
 
     # ------------------------------------------------------------------ #
     # Admission: chunked prefill through the page pool
@@ -1137,19 +1352,38 @@ class GenerationEngine:
             n_chunks = max(1, -(-max_t // C))
             # the write program's rows: the wave's, or padded to one batch
             nw = self._kv_write_batch(n)
-            tables = np.zeros((nw, self.M), np.int32)
+            slots = [r["slot"] for r in chunk_rows]
+
+            def wave_tables():
+                # the wave's rows of every kind's table, padded to the
+                # write program's batch: a fresh array a call (a dispatched
+                # program may still read the last one)
+                t = np.zeros(
+                    self._tables_host.shape[:1] + (nw, self.M), np.int32)
+                t[:, : len(slots)] = self._tables_host[:, slots]
+                return t if len(self._windows) > 1 else t[0]
+
             starts0 = np.zeros((nw,), np.int32)
             all_tokens = np.zeros((n, n_chunks * C), np.int32)
             counts = np.zeros((nw,), np.int32)
             for j, r in enumerate(chunk_rows):
-                tables[j] = r["table_row"]
                 starts0[j] = r["start"]
                 all_tokens[j, : len(r["tokens"])] = r["tokens"]
                 counts[j] = len(r["tokens"])
+            tables = wave_tables()
             for c in range(n_chunks):
                 n_new = np.clip(counts - c * C, 0, C)
                 if not n_new.any():
                     break
+                if self._windowed:
+                    # a prompt longer than a window goes through the same
+                    # path: its window kinds' pages go back as the chunks
+                    # pass, and the tables of the wave follow
+                    for j, b in enumerate(slots):
+                        if n_new[j]:
+                            at = int(starts0[j]) + c * C
+                            self._roll_windows(b, at, at + int(n_new[j]))
+                    tables = wave_tables()
                 max_pos = int(np.max(starts0 + np.minimum(counts, (c + 1) * C)))
                 W = self._table_width(max_pos)
                 # cold-prompt first waves start every row at position 0:
@@ -1172,7 +1406,7 @@ class GenerationEngine:
                 fresh = self._extend_fn(n, W, skip_pool)(
                     *self._model_args(), self.state,
                     jnp.asarray(all_tokens[:, c * C : (c + 1) * C]),
-                    jnp.asarray(tables[:n, :W]),
+                    jnp.asarray(tables[..., :n, :W]),
                     jnp.asarray(start[:n]),
                     jnp.asarray(n_new[:n]),
                 )
@@ -1189,7 +1423,7 @@ class GenerationEngine:
             st = self.stats
             before = (
                 st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"],
-                st["kv_write_tiles"],
+                st["kv_write_tiles"], st["window_pages_released"],
             )
             self._admit_pending()
             attrs.update(
@@ -1201,6 +1435,11 @@ class GenerationEngine:
             if self._kv_write_rows():
                 # tiles the wave's prefill wrote through the kernel
                 attrs["kv_write_tiles"] = st["kv_write_tiles"] - before[3]
+            if self._windowed:
+                # window kinds' pages that prompts longer than the window
+                # gave back as their chunks passed
+                attrs["window_pages_released"] = (
+                    st["window_pages_released"] - before[4])
 
     def _admit_pending(self):
         if not self.accepting:
@@ -1211,7 +1450,7 @@ class GenerationEngine:
         admitted: List[Tuple[GenRequest, int, dict]] = []
         misses: List[dict] = []
         hits: List[dict] = []
-        deferred_inserts: List[Tuple[List[int], List[int]]] = []
+        deferred_inserts: List[Tuple[List[int], int, int]] = []
         still_pending: List[GenRequest] = []
         with self._pending_lock:
             take = self._pending[: len(free) + 8]  # small lookahead
@@ -1223,35 +1462,81 @@ class GenerationEngine:
             max_gen = min(r.max_new_tokens, self.G)
             n_total = -(-(plen_eff + max_gen) // self.page)
             n_shared_full = plen_eff // self.page
-            shared: List[int] = []
+            shared: List = []
             if self.enable_prefix_cache and n_shared_full > 0:
                 shared = self.prefix.lookup(ids, n_shared_full) or []
-            n_owned = n_total - len(shared)
+            # a kind's pages taken now: all of a full kind's, as ever; a
+            # window kind's up to the end of the prompt or to its claim,
+            # whichever is less (the rest is reserved and taken as the
+            # prompt's chunks and then the output move on,
+            # ``_roll_windows``: a prompt longer than the window never
+            # holds more than the window's worth)
+            n_prompt = -(-plen_eff // self.page)
+            take_to = [
+                n_total if w is None
+                else min(n_total, max(min(n_prompt, claim), len(shared)))
+                for w, claim in zip(self._windows, self._window_claim)
+            ]
+            n_owned = sum(take_to) - len(take_to) * len(shared)
+            info = _SlotInfo(
+                rid=r.rid, n_total=n_total, reserved=[0] * len(take_to),
+                t_submit=r.t_submit,
+            )
             if self.pool.n_free < n_owned:
                 self.prefix.evict_lru(n_owned)
-            try:
-                owned = self.pool.alloc(n_owned)
-            except OutOfPagesError:
-                # pool pressure: resident slots / registry hold everything;
-                # retry on a later step
+            slot = free[0]
+            self._slots[slot] = info
+            self._win_lo[:, slot] = 0
+            self._win_hi[:, slot] = take_to
+            tables = self._tables_host[:, slot]
+            tables[:] = 0
+            self._held[:, slot] = False
+            n_deposit = 0
+            for j, to in enumerate(take_to):
+                got = np.asarray(
+                    [_page_ids(page, j) for page in shared], np.int64)
+                tables[j, : len(shared)] = np.maximum(got, 0)
+                self._held[j, slot, : len(shared)] = got >= 0
+                self._held[j, slot, len(shared):to] = True
+                if self._windows[j] is not None and len(got):
+                    # a window kind's page that another slot holds too
+                    n_deposit += int((
+                        self.pool.n_slot_holders(got[got >= 0]) > 1).sum())
+            want = [
+                0 if w is None else self._window_want(slot, j)
+                for j, w in enumerate(self._windows)
+            ]
+            if (
+                self.pool.n_free < n_owned
+                or self.pool.n_unpromised - n_owned < sum(want) + n_deposit
+            ):
+                # pool pressure: resident slots / registry hold everything
+                # (or it is promised to running slots); retry on a later
+                # step
+                self._slots[slot] = None
+                self._held[:, slot] = False
+                tables[:] = 0
                 if shared:
-                    self.pool.release(shared)
+                    self.pool.release(
+                        [p for page in shared for p in _page_ids(page)])
                 still_pending.append(r)
                 break
-            slot = free.pop(0)
+            owned = self.pool.alloc(n_owned)
+            free.pop(0)
             self._slot_epoch[slot] += 1
-            table_row = np.zeros((self.M,), np.int32)
-            table_row[: len(shared) + len(owned)] = shared + owned
-            self._table_host[slot] = table_row
-            self._slots[slot] = _SlotInfo(
-                rid=r.rid, pages=owned, borrowed=shared,
-                t_submit=r.t_submit, t_admit=time.perf_counter(),
-            )
+            info.reserved = want
+            info.t_admit = time.perf_counter()
+            self.pool.reserved += sum(want) + n_deposit
+            self._deposits += n_deposit
+            at = 0
+            for j, to in enumerate(take_to):
+                n_new_pages = to - len(shared)
+                tables[j, len(shared):to] = owned[at : at + n_new_pages]
+                at += n_new_pages
             covered = len(shared) * self.page
             row = {
                 "tokens": ids[covered:plen_eff],
                 "start": covered,
-                "table_row": table_row,
                 "slot": slot,
             }
             if shared:
@@ -1264,15 +1549,15 @@ class GenerationEngine:
                     # extend waves run. This slot's pages are written in wave
                     # 2; inserting now would let a same-cycle borrower (also
                     # wave 2) read them before they are written.
-                    n_new = n_shared_full - len(shared)
-                    deferred_inserts.append((ids, shared + owned[:n_new]))
+                    deferred_inserts.append((ids, slot, n_shared_full))
             else:
                 misses.append(row)
                 if self.enable_prefix_cache and n_shared_full > 0:
                     # cold prompt: register immediately — its pages are
                     # written in wave 1, so same-cycle group members can
                     # borrow them in wave 2
-                    self.prefix.insert(ids, list(owned[:n_shared_full]))
+                    self.prefix.insert(
+                        ids, self._registry_pages(slot, n_shared_full))
             self.stats["prefill_tokens"] += len(row["tokens"])
             self.stats["admitted"] += 1
             if self.kv_quantized and owned:
@@ -1292,8 +1577,8 @@ class GenerationEngine:
         # or by earlier admissions)
         self._run_extends(misses)
         self._run_extends(hits)
-        for ins_ids, ins_pages in deferred_inserts:
-            self.prefix.insert(ins_ids, ins_pages)
+        for ins_ids, slot, n_full in deferred_inserts:
+            self.prefix.insert(ins_ids, self._registry_pages(slot, n_full))
         # commit slot state in row buckets
         i = 0
         while i < len(admitted):
@@ -1973,7 +2258,8 @@ class GenerationEngine:
         consume it later."""
         self.state, flags = chunk(
             *self._model_args(), self.state,
-            jnp.asarray(self._table_host[:, :W]), jnp.asarray(warp_idx),
+            jnp.asarray(self._table_arg(slice(None), W)),
+            jnp.asarray(warp_idx),
         )
         for f in flags:
             f.copy_to_host_async()
@@ -2027,16 +2313,7 @@ class GenerationEngine:
         toks = host_state["out_tokens"][b, :n].tolist()
         lps = host_state["out_logprobs"][b, :n].tolist()
         routing = host_state["out_routing"].get(b)
-        info = self._slots[b]
-        self._slots[b] = None
-        self.pool.release(info.pages)
-        if info.borrowed:
-            self.pool.release(info.borrowed)
-        self._table_host[b] = 0
-        self._lens_host[b] = 0
-        self._warp_host[b] = False
-        self._fused_warp_host[b] = False
-        self._fused_topk_host[b] = False
+        info = self._free_slot(b)
         with self._pending_lock:
             self._req_meta.pop(info.rid, None)
         t_done = time.perf_counter()
@@ -2068,6 +2345,22 @@ class GenerationEngine:
                 decode_steps, running
             )
             lens = self._lens_host[running]
+            if self._windowed:
+                # window kinds: pages behind the window go back, the pages
+                # this chunk writes are taken (the host's lengths may lag
+                # one chunk behind: a lower bound, so nothing live goes)
+                chunk_attrs["window_pages_released"] = sum(
+                    self._roll_windows(
+                        b, int(n), int(n) + ahead + tok_bound)
+                    for b, n in zip(running, lens)
+                )
+                w = max(w for w in self._windows if w is not None)
+                chunk_attrs["window_resident_tokens"] = int(
+                    np.minimum(lens, w).sum())
+                self.stats["window_resident_tokens"] += chunk_attrs[
+                    "window_resident_tokens"]
+                for kind, n in self.cache_bytes_per_token_by_kind().items():
+                    chunk_attrs["cache_bytes_per_token_" + kind] = n
             # width-limit the chunk to the pages this chunk can touch
             W = self._table_width(int(lens.max()) + ahead + tok_bound)
             attrs["table_width"] = W
@@ -2090,7 +2383,7 @@ class GenerationEngine:
             if counts is not None:
                 chunk_attrs.update(counts)
                 for name, n in counts.items():
-                    self.stats[name] += n
+                    self.stats[name] = self.stats.get(name, 0) + n
             self._observe_occupancy()
             chunk = make(decode_steps, W, wb)
             return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
@@ -2144,12 +2437,29 @@ class GenerationEngine:
             streams=streams,
         )
         lens, span = np.sort(self._lens_host), kp * self.page
-        active, total = pl_paged.kernel_steps(lens, sb, span, -(-W // kp))
-        return {
-            "kernel_positions": pl_paged.kernel_positions(lens, sb, span),
+        by_kind = []
+        for w in self._windows:
+            first = None if w is None else pl_paged.first_visible(lens, w)
+            by_kind.append((
+                pl_paged.kernel_positions(lens, sb, span, first),
+                *pl_paged.kernel_steps(lens, sb, span, -(-W // kp), first),
+            ))
+        positions, active, total = (sum(x) for x in zip(*by_kind))
+        # a layer's call on average: a window layer computes over fewer
+        # positions than are resident, so with layer kinds this can read
+        # under the resident tokens
+        counts = {
+            "kernel_positions": positions // len(by_kind),
             "kernel_steps_active": active,
             "kernel_steps": total,
         }
+        if self._windowed:
+            for kind in ("full", "window"):
+                of = [c[0] for c, w in zip(by_kind, self._windows)
+                      if (w is None) == (kind == "full")]
+                if of:
+                    counts[f"kernel_positions_{kind}"] = of[0]
+        return counts
 
     def _mark_first(self, slots) -> None:
         """``t_first`` for the slots whose first chunk just resolved."""

@@ -15,10 +15,24 @@ BYTE-AGNOSTIC: a page index addresses whatever the pool stores (raw
 bf16 pages or int8 pages + their parallel scales array — docs/
 performance.md "KV quantization"), so prefix sharing shares quantized
 pages and their scales without this module knowing either exists.
+
+LAYER KINDS (a model whose stack mixes window and full layers,
+``models/transformer.PagedKVCache``): ONE pool and ONE free list, pages of
+one byte size, and a slot has one page table a kind. The registry then
+holds, for one page of prompt, the page of EVERY kind (a node's ``page``
+is a tuple), so a group still prefills its prompt once. A window kind's
+page that lies wholly behind a slot's window is released by the slot while
+it runs; one that the registry (or a sibling) still holds stays resident
+and is simply no longer that slot's. Pages that only the registry holds
+are the pool's reserve: ``PagePool.n_cached_only`` counts them, a slot's
+later needs are RESERVED against free + cached-only pages
+(``PagePool.reserved``), and under pressure the registry gives back first
+the window-kind pages of nodes whose other pages are still borrowed
+(a running slot has moved past them: ``evict_lru``).
 """
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -35,10 +49,33 @@ class PagePool:
         self.page_size = page_size
         self._free: List[int] = list(range(n_pages - 1, -1, -1))
         self._ref = np.zeros(n_pages, np.int32)
+        # pages the prefix registry holds a reference to, and how many of
+        # them NOTHING else holds: those the registry can give back at once
+        self._cached = np.zeros(n_pages, bool)
+        self.n_cached_only = 0
+        # pages promised to running slots and not yet taken (a window
+        # kind's later pages, ``gen/engine.py``): always backed by free or
+        # cached-only pages, so taking one never fails
+        self.reserved = 0
 
     @property
     def n_free(self) -> int:
         return len(self._free)
+
+    @property
+    def n_unpromised(self) -> int:
+        """Pages an admission may still take or reserve: free or held by
+        the registry alone, less those already promised."""
+        return len(self._free) + self.n_cached_only - self.reserved
+
+    def _only_cached(self, p: int) -> bool:
+        return bool(self._cached[p]) and self._ref[p] == 1
+
+    def set_cached(self, p: int, cached: bool):
+        """The registry took (or is about to drop) its reference to ``p``."""
+        self.n_cached_only -= self._only_cached(p)
+        self._cached[p] = cached
+        self.n_cached_only += self._only_cached(p)
 
     def alloc(self, n: int) -> List[int]:
         """n fresh pages (refcount 1 each); raises OutOfPagesError."""
@@ -55,26 +92,46 @@ class PagePool:
         for p in pages:
             if self._ref[p] <= 0:
                 raise ValueError(f"page {p} is free; cannot share")
+            self.n_cached_only -= self._only_cached(p)
             self._ref[p] += 1
 
     def refcount(self, page: int) -> int:
         return int(self._ref[page])
+
+    def n_slot_holders(self, pages) -> np.ndarray:
+        """References to each of ``pages`` that are not the registry's."""
+        pages = np.asarray(pages, np.int64)
+        return self._ref[pages] - self._cached[pages]
 
     def release(self, pages: Sequence[int]):
         """Drop one reference per page; refcount 0 returns it to the pool."""
         for p in pages:
             if self._ref[p] <= 0:
                 raise ValueError(f"double free of page {p}")
+            self.n_cached_only -= self._only_cached(p)
             self._ref[p] -= 1
             if self._ref[p] == 0:
+                self._cached[p] = False
                 self._free.append(p)
+            else:
+                self.n_cached_only += self._only_cached(p)
 
 
 @dataclasses.dataclass
 class _RadixNode:
-    page: int                                   # resident page (one ref held)
+    # resident page (one ref held); with layer kinds a LIST, the page of
+    # each kind, where -1 is a window kind's page that was given back
+    page: Any
     children: Dict[Tuple[int, ...], "_RadixNode"]
     last_used: int                              # LRU tick
+
+
+def _ids(page) -> List[int]:
+    """The pool pages of a node's (or a hit's) ``page``: itself, or the
+    kinds' pages that are still there."""
+    if isinstance(page, (int, np.integer)):
+        return [int(page)]
+    return [int(p) for p in page if p >= 0]
 
 
 class PrefixRegistry:
@@ -90,11 +147,74 @@ class PrefixRegistry:
     new-policy generations).
     """
 
-    def __init__(self, pool: PagePool):
+    def __init__(
+        self, pool: PagePool, windows: Sequence[Optional[int]] = (None,)
+    ):
+        """``windows``: the sliding window of each layer kind (``None``: a
+        full-attention kind). More than one kind: a node's ``page`` (and
+        every entry of a hit or an insert) is a list, one page a kind."""
         self.pool = pool
+        self.windows = tuple(windows)
         self._children: Dict[Tuple[int, ...], _RadixNode] = {}
         self._tick = 0
         self._n_nodes = 0
+        # layer kinds: where a window kind's page is filed (node, kind), and
+        # the pages a running slot has given up behind its window while
+        # the registry kept them: the first to go under pressure, found
+        # without a walk of the tree (``note_given_up``, ``evict_lru``)
+        self._where: Dict[int, Tuple[_RadixNode, int]] = {}
+        self._given_up: List[int] = []
+
+    def _hold_node(self, node: _RadixNode, kinds: Sequence[int]):
+        """Take the registry's reference to the node's pages of ``kinds``."""
+        pages = [int(node.page[j]) for j in kinds]
+        self._hold(pages)
+        for j, p in zip(kinds, pages):
+            if self.windows[j] is not None:
+                self._where[p] = (node, j)
+
+    def _give_back(self, node: _RadixNode, j: int):
+        """Drop the node's page of window kind ``j``: the node stays, a
+        hit that would need the page is cut short (``_usable``)."""
+        p = int(node.page[j])
+        self._where.pop(p, None)
+        self._drop([p])
+        node.page[j] = -1
+
+    def note_given_up(self, pages: Sequence[int]):
+        """A slot has released ``pages`` of a window kind behind its window.
+        Those the registry holds stay resident (a sibling admitted later
+        may borrow them) but are the first to go when pages are needed."""
+        self._given_up.extend(int(p) for p in pages if int(p) in self._where)
+
+    def _hold(self, pages: Sequence[int]):
+        self.pool.ref(pages)  # arealint: owns(gen.kv-pages, the registry's own reference: a node keeps it until _drop)
+        for p in pages:
+            self.pool.set_cached(p, True)
+
+    def _drop(self, pages: Sequence[int]):
+        for p in pages:
+            self.pool.set_cached(p, False)
+        self.pool.release(pages)
+
+    def _usable(self, pages: List, n: int) -> int:
+        """The longest prefix ``m <= n`` of a matched chain that a borrower
+        can use: its first new token sits at position ``m * page`` and a
+        window kind's layers then read back to ``m * page + 1 - window``,
+        so every page of that kind from there to ``m`` has to be there."""
+        ps = self.pool.page_size
+        for m in range(n, 0, -1):
+            ok = True
+            for j, w in enumerate(self.windows):
+                if w is None:
+                    continue
+                first = max(m * ps + 1 - w, 0) // ps
+                if any(pages[i][j] < 0 for i in range(first, m)):
+                    ok = False
+                    break
+            if ok:
+                return m
+        return 0
 
     def __len__(self) -> int:
         return self._n_nodes  # resident pages held by the tree
@@ -123,9 +243,11 @@ class PrefixRegistry:
             node.last_used = self._tick
             pages.append(node.page)
             children = node.children
+        if len(self.windows) > 1:
+            pages = pages[: self._usable(pages, len(pages))]
         if not pages:
             return None
-        self.pool.ref(pages)
+        self.pool.ref([p for page in pages for p in _ids(page)])
         return pages
 
     def insert(self, prompt_ids: Sequence[int], pages: List[int]):
@@ -138,12 +260,27 @@ class PrefixRegistry:
         for chunk, page in zip(self._chunks(prompt_ids, len(pages)), pages):
             node = children.get(chunk)
             if node is None:
-                self.pool.ref([page])
-                node = _RadixNode(page=page, children={}, last_used=self._tick)
+                if isinstance(page, (int, np.integer)):
+                    self._hold([int(page)])
+                    node = _RadixNode(
+                        page=page, children={}, last_used=self._tick)
+                else:
+                    node = _RadixNode(
+                        page=[int(p) for p in page], children={},
+                        last_used=self._tick)
+                    self._hold_node(
+                        node, [j for j, p in enumerate(page) if p >= 0])
                 children[chunk] = node
                 self._n_nodes += 1
             else:
                 node.last_used = self._tick
+                if len(self.windows) > 1:
+                    # a window kind's page that was given back comes home
+                    home = [j for j, p in enumerate(page)
+                            if node.page[j] < 0 and p >= 0]
+                    for j in home:
+                        node.page[j] = int(page[j])
+                    self._hold_node(node, home)
             children = node.children
 
     def n_reclaimable(self) -> int:
@@ -156,8 +293,7 @@ class PrefixRegistry:
         stack = list(self._children.values())
         while stack:
             n = stack.pop()
-            if self.pool.refcount(n.page) == 1:
-                out += 1
+            out += sum(self.pool.refcount(p) == 1 for p in _ids(n.page))
             stack.extend(n.children.values())
         return out
 
@@ -174,6 +310,18 @@ class PrefixRegistry:
             return 0
         import heapq
 
+        # first what running slots gave up behind their windows and only
+        # the registry still holds: no walk, newest last
+        evicted = 0
+        while self._given_up and self.pool.n_free < n_pages_needed:
+            p = self._given_up.pop()
+            at = self._where.get(p)
+            if at is not None and self.pool.refcount(p) == 1:
+                self._give_back(*at)
+                evicted += 1
+        if self.pool.n_free >= n_pages_needed:
+            return evicted
+
         # one DFS: entry = [parent_children, key, node, n_live_children, idx]
         entries: List[list] = []
         parent_idx: Dict[int, int] = {}
@@ -185,22 +333,38 @@ class PrefixRegistry:
             if pidx is not None:
                 parent_idx[i] = pidx
             stack.extend((n.children, ck, cn, i) for ck, cn in n.children.items())
+            if len(self.windows) > 1 and any(
+                self.pool.refcount(p) > 1 for p in _ids(n.page)
+            ):
+                # a node some slot still borrows from: its window kinds'
+                # pages that ONLY the registry holds lie behind that
+                # slot's window (it gave them up while running). They go
+                # first: a later hit that would need them is cut short
+                # (``_usable``), nothing else is lost
+                for j, w in enumerate(self.windows):
+                    p = n.page[j]
+                    if w is not None and p >= 0 and self.pool.refcount(p) == 1:
+                        self._give_back(n, j)
+                        evicted += 1
+        if self.pool.n_free >= n_pages_needed:
+            return evicted
         heap = [
             (e[2].last_used, i) for i, e in enumerate(entries) if e[3] == 0
         ]
         heapq.heapify(heap)
-        evicted = 0
         while heap and self.pool.n_free < n_pages_needed:
             _, i = heapq.heappop(heap)
             pc, k, n, _ = entries[i]
-            if self.pool.refcount(n.page) > 1:
+            if any(self.pool.refcount(p) > 1 for p in _ids(n.page)):
                 # borrowed by a resident slot: evicting frees nothing and
                 # loses the prefix; leave this subtree alone
                 continue
-            self.pool.release([n.page])
+            for p in _ids(n.page):
+                self._where.pop(p, None)
+            self._drop(_ids(n.page))
             del pc[k]
             self._n_nodes -= 1
-            evicted += 1
+            evicted += len(_ids(n.page))
             pi = parent_idx.get(i)
             if pi is not None:
                 entries[pi][3] -= 1
@@ -213,7 +377,9 @@ class PrefixRegistry:
         stack = list(self._children.values())
         while stack:
             n = stack.pop()
-            self.pool.release([n.page])
+            self._drop(_ids(n.page))
             stack.extend(n.children.values())
         self._children = {}
         self._n_nodes = 0
+        self._where.clear()
+        self._given_up.clear()

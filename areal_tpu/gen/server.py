@@ -673,6 +673,13 @@ class GenerationHTTPServer:
             # what one resident token takes of it (the model says: K and V
             # heads, or one padded latent row a layer)
             "cache_bytes_per_token": self.engine.cache_bytes_per_token(),
+            # ... by layer kind (window and full layers in one stack hold a
+            # token for different lengths of time), and the pages promised
+            # to running slots and not yet taken (a window kind's later
+            # pages; counted as held by ``kv_pool_occupancy``)
+            "cache_bytes_per_token_by_kind": (
+                self.engine.cache_bytes_per_token_by_kind()),
+            "pages_reserved": self.engine.pool.reserved,
             "kv_pool_occupancy": round(self.engine.kv_pool_occupancy(), 4),
             # admission signal: excludes instantly-evictable cache-only
             # pages (the gateway gates dispatch on THIS, not the raw

@@ -3,11 +3,11 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +32,11 @@ class MoEConfig:
     n_shared_experts: int = 0
     scoring: str = "softmax"
     selection_bias: bool = False
+    # The router reads the layer's normed INPUT (what attention reads),
+    # not the normed residual the experts read (``smallthinker``: "router
+    # placed before attention"; every forward hands ``moe_mlp`` that
+    # tensor beside the experts' own).
+    router_on_layer_input: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +87,15 @@ class ModelConfig:
     # split, gains ``[L, Hq*D]`` / ``[L, Hkv*D]`` (olmoe).
     qk_norm_over: str = "head"
     sliding_window: Optional[int] = None
+    # A PERIOD of layer kinds, where the layers of one stack differ in what
+    # is static about their attention (``smallthinker``): layer ``l`` is
+    # kind ``layer_pattern[l % period]``, each kind ``(sliding window or
+    # None, rotary or not)``. None = every layer alike (``sliding_window``,
+    # ``apply_rotary``). The weight tree is one stack either way (all
+    # layers have one shape); the page pool holds a page of one position of
+    # the period in every period and a slot has one table a position
+    # (``models/transformer.PagedKVCache``, ``gen/engine.py``).
+    layer_pattern: Optional[Tuple[Tuple[Optional[int], bool], ...]] = None
     attn_logits_soft_cap: Optional[float] = None
     softmax_scale: Optional[float] = None  # default head_dim ** -0.5
     # Latent attention in place of the q/k/v projections (None = those).
@@ -197,6 +211,24 @@ class ModelConfig:
         return self.use_flash_attention
 
     @property
+    def layer_kinds(self) -> Tuple[Tuple[Optional[int], bool], ...]:
+        """``(window, rotary)`` of each position of the period: one entry
+        for a model whose layers are alike."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern
+        return ((self.sliding_window, self.apply_rotary),)
+
+    @property
+    def period(self) -> int:
+        return len(self.layer_kinds)
+
+    @property
+    def n_periods(self) -> int:
+        """Leading axis of the page pool: a page holds one position of the
+        period in every period."""
+        return self.n_layers // self.period
+
+    @property
     def n_rep(self) -> int:
         return self.n_q_heads // self.n_kv_heads
 
@@ -239,6 +271,21 @@ class ModelConfig:
                 "n_dense_layers: leading dense layers of an expert model, "
                 "fewer than n_layers"
             )
+        if self.layer_pattern is not None:
+            object.__setattr__(
+                self, "layer_pattern",
+                tuple((w, bool(r)) for w, r in self.layer_pattern),
+            )
+            if (
+                not self.layer_pattern
+                or self.n_layers % len(self.layer_pattern)
+                or self.n_dense_layers or self.n_mtp_layers
+                or self.mla is not None or self.abs_position_embedding
+            ):
+                raise ValueError(
+                    "layer_pattern: a period that divides n_layers, in a "
+                    "model of one stack with rotary or no positions"
+                )
         if self.mla is not None:
             m = self.mla
             if self.head_dim != m.qk_nope_head_dim + m.qk_rope_head_dim:

@@ -1,8 +1,8 @@
 """HF ↔ areal_tpu checkpoint converters for all supported model families.
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
-(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe and
-joyai_llm_flash are added here) consumed by
+(llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
+joyai_llm_flash and smallthinker are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -18,7 +18,7 @@ transposed on the way through. GPT-2's ``Conv1D`` is already ``[in, out]``.
 import dataclasses
 import json
 import os
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -371,6 +371,143 @@ register_hf_family(
         ),
         params_to_hf=lambda params, cfg: _llama_like_params_to_hf(
             params, cfg, _OLMOE_MOE
+        ),
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# SmallThinker (window and full layers in one stack; the full layers carry
+# no positional encoding; a softmax router that reads the layer's normed
+# INPUT, before attention; ReGLU experts, no shared expert, no dense layer)
+# --------------------------------------------------------------------------- #
+
+_SMALLTHINKER_MOE = ("block_sparse_moe", "primary_router", "gate", "up", "down")
+
+
+def _layout_period(layout: List[Tuple[int, int]]) -> int:
+    """The shortest period of a per-layer layout that divides its length:
+    found from the layout, never assumed."""
+    n = len(layout)
+    return next(
+        p for p in range(1, n + 1)
+        if n % p == 0 and all(layout[i] == layout[i % p] for i in range(n))
+    )
+
+
+def _smallthinker_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read. Layer ``l`` is a window
+    layer of ``sliding_window_size`` where ``sliding_window_layout[l]`` is
+    1 and full where 0; it applies rotary where ``rope_layout[l]`` is 1 and
+    NO positional encoding where 0. The router takes the output of
+    ``input_layernorm`` (``router_on_layer_input``); its weights are the
+    softmax over the top ``moe_num_active_primary_experts`` logits, which
+    is the softmax over all of them, top-k, renormalised. What the family
+    does not do is refused, never guessed: a non-null ``rope_scaling``, a
+    layout shorter than the depth or with an entry that is not 0 / 1,
+    ``moe_primary_router_apply_softmax`` false (a sigmoid router) or
+    ``norm_topk_prob`` false."""
+    L = hf["num_hidden_layers"]
+    if hf.get("rope_scaling") is not None:
+        raise ValueError("smallthinker: rope_scaling is not supported")
+    if not hf.get("moe_primary_router_apply_softmax", False):
+        raise ValueError(
+            "smallthinker: moe_primary_router_apply_softmax false (a sigmoid "
+            "router) is not supported"
+        )
+    if not hf.get("norm_topk_prob", True):
+        raise ValueError("smallthinker: norm_topk_prob false is not supported")
+    layouts = {}
+    for key in ("sliding_window_layout", "rope_layout"):
+        layout = hf.get(key)
+        if layout is None:
+            layout = [0 if key == "sliding_window_layout" else 1] * L
+        layout = list(layout)
+        if len(layout) < L:
+            raise ValueError(
+                f"smallthinker: {key} has {len(layout)} entries for {L} layers"
+            )
+        if any(v not in (0, 1) for v in layout):
+            raise ValueError(f"smallthinker: {key} entries must be 0 or 1")
+        layouts[key] = layout[:L]
+    window = hf.get("sliding_window_size")
+    if any(layouts["sliding_window_layout"]) and not window:
+        raise ValueError("smallthinker: window layers need sliding_window_size")
+    per_layer = list(zip(layouts["sliding_window_layout"], layouts["rope_layout"]))
+    period = _layout_period(per_layer)
+    n_q = hf["num_attention_heads"]
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=n_q,
+        n_kv_heads=hf.get("num_key_value_heads") or n_q,
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // n_q,
+        hidden_dim=hf["hidden_size"],
+        # the model has no dense MLP; the key is the width of ONE expert
+        intermediate_dim=hf["moe_ffn_hidden_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 16384),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-6),
+        rotary_base=hf.get("rope_theta", 10000.0),
+        layer_pattern=tuple(
+            (window if w else None, bool(r)) for w, r in per_layer[:period]
+        ),
+        activation_function="relu",
+        mlp_type="moe",
+        moe=MoEConfig(
+            num_experts=hf["moe_num_primary_experts"],
+            top_k=hf["moe_num_active_primary_experts"],
+            norm_topk_prob=True,
+            expert_dim=hf["moe_ffn_hidden_size"],
+            router_on_layer_input=True,
+        ),
+        tied_embedding=bool(hf.get("tie_word_embeddings", False)),
+    )
+
+
+def _smallthinker_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys, key for key (``model_name`` is the checkpoint's
+    own label and not the model's to know: the 21B's is written)."""
+    kinds = [cfg.layer_kinds[l % cfg.period] for l in range(cfg.n_layers)]
+    windows = {w for w, _ in kinds if w is not None}
+    if len(windows) > 1:
+        raise ValueError("smallthinker: one sliding_window_size for all layers")
+    return {
+        "model_type": "smallthinker",
+        "architectures": ["SmallThinkerForCausalLM"],
+        "model_name": "smallthinker_21b_instruct",
+        "head_dim": cfg.head_dim,
+        "hidden_size": cfg.hidden_dim,
+        "max_position_embeddings": cfg.n_positions,
+        "moe_ffn_hidden_size": cfg.expert_dim,
+        "moe_num_active_primary_experts": cfg.moe.top_k,
+        "moe_num_primary_experts": cfg.moe.num_experts,
+        "moe_primary_router_apply_softmax": True,
+        "norm_topk_prob": cfg.moe.norm_topk_prob,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_layout": [int(r) for _, r in kinds],
+        "rope_scaling": None,
+        "rope_theta": cfg.rotary_base,
+        "sliding_window_layout": [int(w is not None) for w, _ in kinds],
+        "sliding_window_size": windows.pop() if windows else None,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+register_hf_family(
+    HFFamily(
+        name="smallthinker",
+        hf_model_type="smallthinker",
+        config_from_hf=_smallthinker_config_from_hf,
+        config_to_hf=_smallthinker_config_to_hf,
+        params_from_hf=lambda sd, cfg: _llama_like_params_from_hf(
+            sd, cfg, _SMALLTHINKER_MOE
+        ),
+        params_to_hf=lambda params, cfg: _llama_like_params_to_hf(
+            params, cfg, _SMALLTHINKER_MOE
         ),
     )
 )

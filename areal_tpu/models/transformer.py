@@ -379,12 +379,22 @@ def _rotary_cfg(cfg: ModelConfig) -> RotaryConfig:
     )
 
 
-def _qkv_roped(cfg: ModelConfig, p, x, cos, sin):
-    """:func:`_qkv` with the positions applied: what attention takes."""
+def _cos_sin(cfg: ModelConfig, positions):
+    """The rotary tables at ``positions``, or ``(None, None)`` for a model
+    none of whose layer kinds is rotary."""
+    if any(rotary for _, rotary in cfg.layer_kinds):
+        return rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
+    return None, None
+
+
+def _qkv_roped(cfg: ModelConfig, p, x, cos, sin, rotary=None):
+    """:func:`_qkv` with the positions applied: what attention takes.
+    ``rotary``: the layer kind's own (``cfg.layer_kinds``), where the
+    layers of the stack differ; else the model's ``apply_rotary``."""
     if cfg.mla is not None:
         return _mla_expanded(cfg, p, x, cos, sin)
     q, k, v = _qkv(cfg, p, x)
-    if cfg.apply_rotary:
+    if cfg.apply_rotary if rotary is None else rotary:
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
     return q, k, v
@@ -503,14 +513,23 @@ def _mla_absorbed_out(cfg: ModelConfig, p, ctx):
         return jnp.einsum("...hr,rhd->...hd", ctx, w_uv)
 
 
+def _attn_scope(window: Optional[int]) -> str:
+    """``jax.named_scope`` of a layer kind's attention, in a model whose
+    layers come in kinds (``cfg.layer_pattern``)."""
+    return "attn_full" if window is None else "attn_window"
+
+
 def _attn_scale(cfg: ModelConfig) -> float:
     return cfg.softmax_scale or cfg.head_dim ** -0.5
 
 
-def _mlp(cfg: ModelConfig, p, x):
+def _mlp(cfg: ModelConfig, p, x, layer_in=None):
     """Returns (out, aux_loss, routing) — aux is the MoE load-balancing/z
     loss (``jnp`` scalar, 0 for dense MLPs); routing the experts each token
-    chose, ``[..., top_k]`` int32 (``None`` for dense MLPs)."""
+    chose, ``[..., top_k]`` int32 (``None`` for dense MLPs). ``layer_in``:
+    the layer's normed INPUT (what its attention read), which every
+    forward hands over: a router that reads it instead of ``x``
+    (``MoEConfig.router_on_layer_input``) gets it from here."""
     act = ACT2FN[cfg.activation_function]
     # a leading dense layer of an expert model is told by its tree: it has
     # no router
@@ -531,7 +550,10 @@ def _mlp(cfg: ModelConfig, p, x):
     # moe
     from areal_tpu.ops.moe import moe_mlp
 
-    return moe_mlp(cfg, p, x)
+    return moe_mlp(
+        cfg, p, x,
+        router_input=layer_in if cfg.moe.router_on_layer_input else None,
+    )
 
 
 def _attn_out(p, ctx):
@@ -557,13 +579,57 @@ def _layer_stacks(params: Params):
     return [params["layers"]]
 
 
+def _scan_periods(layers, carry, stack, xs=(), unroll=1):
+    """:func:`_scan_layers` of a stack whose layers come in a PERIOD of
+    kinds (``cfg.layer_pattern``): ``layers[j]`` is the layer function of
+    position ``j``, with what is static about its kind (window, rotary,
+    which page table) closed over. ONE scan runs over the periods, its
+    body the ``p`` positions in order; results come back on the layer
+    axis.
+
+    Each layer's weights are cut out of the stack ``[L, ...]`` by its own
+    dynamic index ``period * p + j``, one layer at a time, as a plain scan
+    over the stack does: XLA fuses such a slice into the matmul that reads
+    it. (Handing the scan the stack viewed as ``[L / p, p, ...]`` makes
+    the compiler materialise a whole period's slice, whose positions have
+    several readers: 3 x 0.96 GB of copies a period at the 21B's widths,
+    and 1.9 GB over the chip's memory in the decode chunk.)"""
+    p = len(layers)
+    n_periods = jax.tree.leaves(stack)[0].shape[0] // p
+
+    def body(carry, period):
+        ys = []
+        for j, layer in enumerate(layers):
+            inp = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, period * p + j, 0, keepdims=False),
+                (stack, *xs) if xs else stack,
+            )
+            carry, y = layer(carry, inp)
+            ys.append(y)
+        return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+    carry, ys = jax.lax.scan(
+        body, carry, jnp.arange(n_periods, dtype=jnp.int32), unroll=unroll
+    )
+    return carry, jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * p, *a.shape[2:]), ys
+    )
+
+
 def _scan_layers(layer, carry, params: Params, xs=(), unroll=1):
     """``lax.scan`` of ``layer(carry, (lp, *xs_l))`` over every stack of
     :func:`_layer_stacks`, one scan a stack (a model of one stack is ONE
     scan, as ever). ``xs``: arrays with a leading axis over ALL layers,
     cut to each stack's run. The stacked results (a tuple) are joined on
     the layer axis; a member that a stack gives as ``None`` (a dense
-    layer's routing) is left out of the join."""
+    layer's routing) is left out of the join. ``layer`` is one function,
+    or a list of them, one a position of the model's period of layer
+    kinds (:func:`_scan_periods`; a list of one is that one)."""
+    if isinstance(layer, (list, tuple)):
+        if len(layer) > 1:
+            return _scan_periods(layer, carry, params["layers"], xs, unroll)
+        (layer,) = layer
     stacks = _layer_stacks(params)
     outs, at = [], 0
     for st in stacks:
@@ -652,12 +718,9 @@ def forward_packed(
     ``k + 1`` positions of a segment have no such input and are garbage).
     Padding rows are garbage — mask downstream with ``segment_ids > 0``."""
     x = _embed(cfg, params, input_ids, positions)
-    if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
-    else:
-        cos = sin = None
+    cos, sin = _cos_sin(cfg, positions)
 
-    def _attend(q, k, v):
+    def _attend(q, k, v, window):
         return attn_ops.packed_attention(
             q,
             k,
@@ -665,66 +728,87 @@ def forward_packed(
             segment_ids,
             softmax_scale=cfg.softmax_scale,
             soft_cap=cfg.attn_logits_soft_cap,
-            sliding_window=cfg.sliding_window,
+            sliding_window=window,
             use_flash=cfg.flash_enabled(),
             flash_block_size=cfg.flash_block_size,
             flash_block_size_k=cfg.flash_block_size_k,
             max_seqlen=cfg.attn_max_seqlen,
         )
 
-    def _pre(x, lp):
+    # a router that reads the layer's input needs it after attention: the
+    # norm is recomputed there (one RMSNorm) rather than carried across
+    # the attention kernel, which the split checkpointing below cuts at
+    def _pre(x, lp, rotary):
         h = _norm(cfg, lp["ln1"], x)
-        return _qkv_roped(cfg, lp["attn"], h, cos, sin)
+        return _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
 
     def _post(x, ctx, lp):
+        layer_in = (
+            _norm(cfg, lp["ln1"], x)
+            if cfg.moe is not None and cfg.moe.router_on_layer_input
+            else None
+        )
         x = x + _attn_out(lp["attn"], ctx)
         h = _norm(cfg, lp["ln2"], x)
-        m, aux, routing = _mlp(cfg, lp["mlp"], h)
+        m, aux, routing = _mlp(cfg, lp["mlp"], h, layer_in)
         return x + m, (aux, routing)
 
     policy = cfg.remat_policy if remat else "none"
     dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
 
-    if policy == "dots_attn":
-        # Split checkpointing that leaves the attention kernel OUTSIDE the
-        # remat region: jax.checkpoint cannot save a custom_vjp's residuals,
-        # so a whole-layer checkpoint re-runs the full flash forward inside
-        # the backward just to regenerate (out, lse) — ~25% of a long-context
-        # step. Here attention residuals (q, k, v, out, lse) are saved
-        # (~180 MB/layer at 32k for a 768-wide model) and only the cheap
-        # projection/MLP matmul inputs are recomputed. The bf16 param cast
-        # stays INSIDE each region — hoisting it would turn every layer's
-        # cast param tree into saved residuals.
-        pre = jax.checkpoint(
-            lambda x, lp: _pre(x, _cast(cfg, lp)),
-            policy=dots, prevent_cse=False,
-        )
-        post = jax.checkpoint(
-            lambda x, ctx, lp: _post(x, ctx, _cast(cfg, lp)),
-            policy=dots, prevent_cse=False,
-        )
+    if policy not in ("dots_attn", "full", "dots", "none"):
+        raise ValueError(f"unknown remat_policy {policy!r}")
 
-        def layer(x, lp):
-            q, k, v = pre(x, lp)
-            ctx = _attend(q, k, v)
-            return post(x, ctx, lp)
+    def make_layer(kind):
+        window, rotary = kind
 
-    else:
+        def attend(q, k, v):
+            if cfg.layer_pattern is None:
+                return _attend(q, k, v, window)
+            with jax.named_scope(_attn_scope(window)):
+                return _attend(q, k, v, window)
+
+        if policy == "dots_attn":
+            # Split checkpointing that leaves the attention kernel OUTSIDE
+            # the remat region: jax.checkpoint cannot save a custom_vjp's
+            # residuals, so a whole-layer checkpoint re-runs the full flash
+            # forward inside the backward just to regenerate (out, lse) —
+            # ~25% of a long-context step. Here attention residuals (q, k,
+            # v, out, lse) are saved (~180 MB/layer at 32k for a 768-wide
+            # model) and only the cheap projection/MLP matmul inputs are
+            # recomputed. The bf16 param cast stays INSIDE each region —
+            # hoisting it would turn every layer's cast param tree into
+            # saved residuals.
+            pre = jax.checkpoint(
+                lambda x, lp: _pre(x, _cast(cfg, lp), rotary),
+                policy=dots, prevent_cse=False,
+            )
+            post = jax.checkpoint(
+                lambda x, ctx, lp: _post(x, ctx, _cast(cfg, lp)),
+                policy=dots, prevent_cse=False,
+            )
+
+            def layer(x, lp):
+                q, k, v = pre(x, lp)
+                return post(x, attend(q, k, v), lp)
+
+            return layer
 
         def layer(x, lp):
             lp = _cast(cfg, lp)
-            q, k, v = _pre(x, lp)
-            ctx = _attend(q, k, v)
-            return _post(x, ctx, lp)
+            q, k, v = _pre(x, lp, rotary)
+            return _post(x, attend(q, k, v), lp)
 
         if policy == "full":
-            layer = jax.checkpoint(layer, prevent_cse=False)
-        elif policy == "dots":
-            layer = jax.checkpoint(layer, policy=dots, prevent_cse=False)
-        elif policy != "none":
-            raise ValueError(f"unknown remat_policy {policy!r}")
+            return jax.checkpoint(layer, prevent_cse=False)
+        if policy == "dots":
+            return jax.checkpoint(layer, policy=dots, prevent_cse=False)
+        return layer
+
+    layers = [make_layer(kind) for kind in cfg.layer_kinds]
+    layer = layers[-1]      # the block a multi-token-prediction module is
     x, (auxes, routing) = _scan_layers(
-        layer, x, params, unroll=cfg.layer_scan_unroll or 1
+        layers, x, params, unroll=cfg.layer_scan_unroll or 1
     )
     stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
@@ -861,10 +945,7 @@ def prefill(
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
     valid = positions < prompt_lens[:, None]
     x = _embed(cfg, params, input_ids, positions)
-    if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
-    else:
-        cos = sin = None
+    cos, sin = _cos_sin(cfg, positions)
     idx = jnp.arange(S)
     use_flash = cfg.flash_enabled()
     if use_flash:
@@ -879,14 +960,20 @@ def prefill(
     else:
         # causal & in-prompt mask, [B, S, S]
         mask = (idx[None, :, None] >= idx[None, None, :]) & valid[:, None, :]
-        if cfg.sliding_window is not None:
-            mask &= idx[None, :, None] - idx[None, None, :] < cfg.sliding_window
     scale = cfg.softmax_scale or cfg.head_dim**-0.5
 
-    def layer(x, lp):
+    def make_layer(kind):
+        window, rotary = kind
+        kind_mask = mask
+        if mask is not None and window is not None:
+            kind_mask = mask & (
+                idx[None, :, None] - idx[None, None, :] < window)
+        return functools.partial(layer, window, rotary, kind_mask)
+
+    def layer(window, rotary, mask, x, lp):
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)  # [B, S, H, D]
+        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)  # [B, S, H, D]
         if use_flash:
             H, D = q.shape[-2:]
             ctx = attn_ops.packed_attention(
@@ -896,7 +983,7 @@ def prefill(
                 flat_seg,
                 softmax_scale=scale,
                 soft_cap=cfg.attn_logits_soft_cap,
-                sliding_window=cfg.sliding_window,
+                sliding_window=window,
                 use_flash=True,
                 max_seqlen=S,
             ).reshape(B, S, H, D)
@@ -911,11 +998,12 @@ def prefill(
             probs = jax.nn.softmax(scores, axis=-1).astype(vv.dtype)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        h = _norm(cfg, lp["ln2"], x)
-        x = x + _mlp(cfg, lp["mlp"], h)[0]
+        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
         return x, (k, v)
 
-    x, (ks, vs) = _scan_layers(layer, x, params)
+    x, (ks, vs) = _scan_layers(
+        [make_layer(kind) for kind in cfg.layer_kinds], x, params
+    )
     cap = cache.k.shape[2]
     pad = cap - S
     if pad < 0:
@@ -949,19 +1037,17 @@ def decode_step(
         active = jnp.ones((B,), bool)
     positions = cache.lens  # position of the new token
     x = _embed(cfg, params, tokens, positions)  # [B, E]
-    if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
-    else:
-        cos = sin = None
+    cos, sin = _cos_sin(cfg, positions)
     write_at = cache.lens  # [B]
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
 
-    def layer(x, inputs):
+    def layer(kind, x, inputs):
+        window, rotary = kind
         lp, kc, vc = inputs
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         # q: [B, Hq, D]; k/v: [B, Hkv, D]
-        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)
+        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
         # write new K/V at write_at (only for active slots)
         slot = jnp.arange(kc.shape[1])[None, :, None, None]  # [1, S, 1, 1]
         put = (slot == write_at[:, None, None, None]) & active[:, None, None, None]
@@ -974,14 +1060,16 @@ def decode_step(
             new_lens,
             softmax_scale=cfg.softmax_scale,
             soft_cap=cfg.attn_logits_soft_cap,
-            sliding_window=cfg.sliding_window,
+            sliding_window=window,
         )
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        h = _norm(cfg, lp["ln2"], x)
-        x = x + _mlp(cfg, lp["mlp"], h)[0]
+        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
         return x, (kc, vc)
 
-    x, (ks, vs) = _scan_layers(layer, x, params, xs=(cache.k, cache.v))
+    x, (ks, vs) = _scan_layers(
+        [functools.partial(layer, kind) for kind in cfg.layer_kinds],
+        x, params, xs=(cache.k, cache.v),
+    )
     cache = KVCache(k=ks, v=vs, lens=new_lens)
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     return _head(cfg, params, x), cache
@@ -1038,7 +1126,22 @@ class PagedKVCache:
     the page walk, the scatter and the kernel's copies those of the K/V
     pool). A token's row in a layer is ``[c_kv ; k_rope ; 0]``, ``W =``
     :func:`latent_pool_width`: key for all query heads and, in its first
-    ``kv_lora_rank`` values, their value. Always in the serving dtype."""
+    ``kv_lora_rank`` values, their value. Always in the serving dtype.
+
+    LAYER KINDS (``cfg.layer_pattern``: window and full layers in one
+    stack): the leading axis is the model's PERIODS, not its layers. A
+    page holds ``page`` tokens of ONE position of the period in EVERY
+    period (``pages [L / p, P, 2, Hkv, page, D]``), so every page has one
+    byte size whatever kind of layer it serves, ONE free list feeds all
+    kinds, and how the pool's bytes divide between kinds is decided by the
+    traffic. A slot has one page table a position of the period (``table
+    [p, B, M]`` where a model of one kind has ``[B, M]``): layer ``l``
+    reads and writes ``pages[l // p]`` through ``table[l % p]``. What that
+    buys: a window position's page that lies wholly behind ``len -
+    window`` is of no further use to its slot and goes back to the free
+    list while the request still runs (``gen/engine.py``); nothing here
+    reads a table entry before a row's first visible position. A model of
+    one kind is the same layout with ``p = 1``."""
 
     pages: jnp.ndarray
     scales: Optional[jnp.ndarray] = None
@@ -1059,7 +1162,7 @@ class PagedKVCache:
         the quantized pool + scales pair, anything else (None) stores raw
         ``cfg.dtype`` pages."""
         streams, heads, width = kv_page_geometry(cfg)
-        shape = (cfg.n_layers, n_pages, streams, heads, page_size, width)
+        shape = (cfg.n_periods, n_pages, streams, heads, page_size, width)
         if kv_dtype == "int8":
             if cfg.mla is not None:
                 raise ValueError("a latent page pool cannot be int8")
@@ -1101,6 +1204,22 @@ def _write_chunk_kv(
     which leaves the same bits."""
     from areal_tpu.ops import paged_attention as paged_ops
 
+    if table.ndim == 3:
+        # a table a position of the period: the fresh K/V of the layers at
+        # position j of every period land in the pages of table j
+        p = table.shape[0]
+
+        def of_kind(a, j):
+            if a is None:
+                return None
+            return a.reshape(a.shape[0] // p, p, *a.shape[1:])[:, j]
+
+        for j in range(p):
+            cache = _write_chunk_kv(
+                cache, of_kind(ks, j), of_kind(vs, j), table[j], start,
+                count, use_pallas, mesh,
+            )
+        return cache
     pages = cache.pages
     if not paged_ops.kv_write_kernel_applies(
         use_pallas, pages, cache.quantized, mesh
@@ -1220,28 +1339,29 @@ def _extend_layers(
     B, C = tokens.shape
     positions = start[:, None] + jnp.arange(C)[None, :]
     x = _embed(cfg, params, tokens, positions)
-    if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), positions, jnp.float32)
-    else:
-        cos = sin = None
+    cos, sin = _cos_sin(cfg, positions)
+    kinds = cfg.layer_kinds
 
-    def _attend(q, k, v, li):
+    def _attend(q, k, v, li, j):
         kw = dict(
             softmax_scale=_attn_scale(cfg),
             soft_cap=cfg.attn_logits_soft_cap,
-            sliding_window=cfg.sliding_window,
+            sliding_window=kinds[j][0],
             scales=cache.scales,
         )
+        tbl = _kind_table(table, j)
         if verify:
             return paged_ops.paged_verify_attention(
-                q, k, v, cache.pages, li, table, start, n_new, **kw
+                q, k, v, cache.pages, li, tbl, start, n_new, **kw
             )
         return paged_ops.paged_extend_attention(
-            q, k, v, cache.pages, li, table, start, n_new,
+            q, k, v, cache.pages, li, tbl, start, n_new,
             skip_pool=skip_pool, **kw,
         )
 
-    def layer(carry, lp):
+    def layer(j, carry, lp):
+        # ``li``: which slice of the pool's leading axis the layer's pages
+        # are in: its layer, or (layer kinds) its period
         x, li = carry                                 # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
@@ -1251,19 +1371,27 @@ def _extend_layers(
             # value (``vs`` stays None: the pool has one stream)
             q, latent = _mla_absorbed(cfg, lp["attn"], h, cos, sin)
             k, v = latent[..., None, :], None
-            ctx = _attend(q, k, k[..., : cfg.mla.kv_lora_rank], li)
+            ctx = _attend(q, k, k[..., : cfg.mla.kv_lora_rank], li, j)
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
         else:
             # [B, C, H(kv), D]
-            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)
-            ctx = _attend(q, k, v, li)
+            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, kinds[j][1])
+            ctx = _attend(q, k, v, li, j)
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        h = _norm(cfg, lp["ln2"], x)
-        x = x + _mlp(cfg, lp["mlp"], h)[0]
-        return (x, li + 1), (k, v)
+        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
+        return (x, li + int(j == len(kinds) - 1)), (k, v)
 
-    (x, _), (ks, vs) = _scan_layers(layer, (x, jnp.int32(0)), params)
+    (x, _), (ks, vs) = _scan_layers(
+        [functools.partial(layer, j) for j in range(len(kinds))],
+        (x, jnp.int32(0)), params,
+    )
     return x, ks, vs
+
+
+def _kind_table(table, j: int):
+    """The page table of position ``j`` of the period: ``table [p, B, M]``
+    of a model with layer kinds, or the one table ``[B, M]``."""
+    return table[j] if table.ndim == 3 else table
 
 
 def extend_paged_kv(
@@ -1428,21 +1556,23 @@ def decode_step_paged(
     new_lens = jnp.where(active, lens + 1, lens)
     # the scan's rows, by length (``_o``); slot order again after it
     order, inverse = _length_order(lens)
-    table_o, lens_o = table[order], lens[order]
+    table_o = table[order] if table.ndim == 2 else table[:, order]
+    lens_o = lens[order]
     x = _embed(cfg, params, tokens[order], lens_o)    # [B, E]
-    if cfg.apply_rotary:
-        cos, sin = rotary_cos_sin(_rotary_cfg(cfg), lens_o, jnp.float32)
-    else:
-        cos = sin = None
+    cos, sin = _cos_sin(cfg, lens_o)
+    kinds = cfg.layer_kinds
 
-    def layer(carry, lp):
+    def layer(j, carry, lp):
+        # ``li``: the layer, or (layer kinds) the period: the slice of the
+        # pool's leading axis that holds the layer's pages
         x, li = carry                                 # pool NOT in the scan
+        window, rotary = kinds[j]
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         kw = dict(
             softmax_scale=_attn_scale(cfg),
             soft_cap=cfg.attn_logits_soft_cap,
-            sliding_window=cfg.sliding_window,
+            sliding_window=window,
             use_pallas=use_pallas,
             mesh=mesh,
             scales=cache.scales,
@@ -1457,18 +1587,28 @@ def decode_step_paged(
                 value_width=cfg.mla.kv_lora_rank, **kw,
             )
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
-        else:
+        elif cfg.layer_pattern is None:
             q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)  # q [B, H, D]
             ctx = paged_ops.paged_decode_attention(
                 q, k, v, cache.pages, li, table_o, lens_o, **kw
             )
+        else:
+            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
+            with jax.named_scope(_attn_scope(window)):
+                ctx = paged_ops.paged_decode_attention(
+                    q, k, v, cache.pages, li, _kind_table(table_o, j),
+                    lens_o, **kw
+                )
         x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        h = _norm(cfg, lp["ln2"], x)
-        m, _, routing = _mlp(cfg, lp["mlp"], h)
-        return (x + m, li + 1), (k, v, routing if with_routing else None)
+        m, _, routing = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)
+        return (
+            (x + m, li + int(j == len(kinds) - 1)),
+            (k, v, routing if with_routing else None),
+        )
 
     (x, _), (ks, vs, routing) = _scan_layers(
-        layer, (x, jnp.int32(0)), params
+        [functools.partial(layer, j) for j in range(len(kinds))],
+        (x, jnp.int32(0)), params,
     )
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(

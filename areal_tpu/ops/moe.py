@@ -49,7 +49,10 @@ over all logits (mixtral, olmoe), or a sigmoid of each (``deepseek_v3``'s
 ``noaux_tc``), where the experts are chosen by score PLUS a learned
 correction bias ``b_router`` and weighted by the score alone. Shared
 experts (``n_shared_experts``) are one more SwiGLU applied to every token
-and added to the routed sum.
+and added to the routed sum. Where the family says so
+(``MoEConfig.router_on_layer_input``: ``smallthinker``) the router reads
+ANOTHER tensor than the experts: the layer's normed input, computed a
+whole attention earlier; the caller hands it in as ``router_input``.
 """
 
 import jax
@@ -63,6 +66,7 @@ from areal_tpu.ops.activations import ACT2FN
 # (``benchmark/moe_flops.py``)
 EXPERTS_SCOPE = "moe_experts"
 SHARED_SCOPE = "moe_shared_expert"
+EARLY_ROUTER_SCOPE = "moe_router_early"
 
 
 def _route(cfg, router_w, x, bias=None):
@@ -103,8 +107,12 @@ def _aux_loss(cfg, chosen, probs, logits):
     return moe.aux_loss_coeff * aux + moe.z_loss_coeff * z
 
 
-def moe_mlp(cfg, p, x):
+def moe_mlp(cfg, p, x, router_input=None):
     """x: [..., E] -> (out [..., E], aux_loss, top_idx [..., K]).
+
+    ``router_input`` ``[..., E]``: what the router reads where that is not
+    ``x`` (``cfg.moe.router_on_layer_input``; required then, refused
+    otherwise, so that no path can feed the router the wrong tensor).
 
     ``top_idx`` are the experts each token chose (largest weight first):
     the generation engine counts them (``moe_experts_hit``) and can hand
@@ -120,9 +128,21 @@ def moe_mlp(cfg, p, x):
     act = ACT2FN[cfg.activation_function]
     lead = x.shape[:-1]
     xt = x.reshape(-1, x.shape[-1])
-    top_vals, top_idx, probs, logits = _route(
-        cfg, p["router"], xt, p.get("b_router")
-    )
+    if (router_input is not None) != cfg.moe.router_on_layer_input:
+        raise ValueError(
+            "moe_mlp: router_input goes with cfg.moe.router_on_layer_input"
+        )
+    if router_input is None:
+        top_vals, top_idx, probs, logits = _route(
+            cfg, p["router"], xt, p.get("b_router")
+        )
+    else:
+        with jax.named_scope(EARLY_ROUTER_SCOPE):
+            top_vals, top_idx, probs, logits = _route(
+                cfg, p["router"],
+                router_input.reshape(-1, router_input.shape[-1]),
+                p.get("b_router"),
+            )
     onehot = jax.nn.one_hot(top_idx, cfg.moe.num_experts, dtype=jnp.float32)
     chosen = onehot.sum(axis=1)                                  # [T, X]
     combine = (top_vals[:, :, None] * onehot).sum(axis=1)        # [T, X]

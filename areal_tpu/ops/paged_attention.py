@@ -88,6 +88,29 @@ def gather_dequant_pages(
     return k, v
 
 
+def window_view(
+    table: jnp.ndarray, first: jnp.ndarray, page: int, sliding_window: int,
+    block_pages: int = 8,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The part of each row's table that a window layer can see: ``(sub
+    [B, Mw], row0 [B])``, the ``Mw`` table entries from the page that the
+    row's ``first`` visible position falls in, and the position that page
+    starts at. ``Mw`` covers ``sliding_window - 1`` positions wherever they
+    lie in their pages (a whole number of ``block_pages`` where that is
+    under the table's width), so what the gather reads is O(window), not
+    O(length), and entries before the first page, which may be stale (the
+    engine has given those pages back), are never looked up. Entries past
+    the table's end repeat its last one; their positions lie past every
+    length and are masked like any other."""
+    M = table.shape[1]
+    mw = -(-(sliding_window - 1) // page) + 1
+    mw = min(-(-mw // block_pages) * block_pages, M)
+    p0 = first // page
+    idx = p0[:, None] + jnp.arange(mw)[None, :]
+    sub = jnp.take_along_axis(table, jnp.minimum(idx, M - 1), axis=1)
+    return sub, p0 * page
+
+
 def decode_kernel_applies(
     use_pallas: Optional[bool], head_dim: int, n_kv_heads: int, page: int,
     pool_dtype, tp: int = 1,
@@ -275,6 +298,14 @@ def paged_decode_attention(
                 check_vma=False,
             )(*operands)
         return _kernel(*operands)
+    row0 = None
+    if sliding_window is not None:
+        # the kernel's plain reference: pages from the first visible
+        # position on, the edge page masked inside
+        from areal_tpu.ops.pallas.paged_attention import first_visible
+
+        first = first_visible(lens, sliding_window)
+        table, row0 = window_view(table, first, pages.shape[4], sliding_window)
     k, v = gather_dequant_pages(pages, table, layer, scales)  # [B, S, Hkv, D]
     v = _latent_values(k, v, v_self.shape[-1])
     S = k.shape[1]
@@ -290,10 +321,12 @@ def paged_decode_attention(
         s_pool = soft_cap * jnp.tanh(s_pool / soft_cap)
         s_self = soft_cap * jnp.tanh(s_self / soft_cap)
     pos = jnp.arange(S)[None, :]
+    if row0 is not None:
+        pos = pos + row0[:, None]
     mask = pos < lens[:, None]              # [B, S]
     if sliding_window is not None:
         # the query sits at position lens
-        mask &= pos > lens[:, None] - sliding_window
+        mask &= pos >= first[:, None]
     s_pool = jnp.where(mask[:, None, None], s_pool, _NEG_INF)
     # online-softmax merge of pool part and the always-attended self token
     m = jnp.maximum(s_pool.max(-1), s_self)            # [B, Hkv, r]
@@ -430,6 +463,15 @@ def paged_extend_attention(
     # (int8 pools dequant behind the gather — the per-slot view widens,
     # never the pool; the intra-chunk part above is untouched: the chunk's
     # own K/V ride as full-precision operands)
+    row0 = None
+    if sliding_window is not None:
+        # pool pages from the first position the chunk's FIRST token sees
+        from areal_tpu.ops.pallas.paged_attention import first_visible
+
+        table, row0 = window_view(
+            table, first_visible(start, sliding_window), pages.shape[4],
+            sliding_window, block_pages=max(kv_block // pages.shape[4], 1),
+        )
     k, v = gather_dequant_pages(pages, table, layer, scales)  # [B, S, Hkv, D]
     v = _latent_values(k, v, Dv)
     S = k.shape[1]
@@ -449,13 +491,15 @@ def paged_extend_attention(
         ) * softmax_scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
-        kpos = off + jnp.arange(Sb)                          # [Sb]
+        kpos = (off + jnp.arange(Sb))[None, None, :]         # [1|B, 1, Sb]
+        if row0 is not None:
+            kpos = kpos + row0[:, None, None]
         # every pool position < start is causally visible to every chunk
         # token; the per-token bound only matters for the sliding window
-        mask = kpos[None, None, :] < start[:, None, None]    # [B, 1|C, Sb]
+        mask = kpos < start[:, None, None]                   # [B, 1|C, Sb]
         mask = jnp.broadcast_to(mask, (B, C, Sb))
         if sliding_window is not None:
-            mask &= kpos[None, None, :] > qpos[:, :, None] - sliding_window
+            mask &= kpos > qpos[:, :, None] - sliding_window
         s = jnp.where(mask[:, None, None], s, _NEG_INF)      # [B,g,r,C,Sb]
         m_new = jnp.maximum(m, s.max(-1))
         # m can be -inf while everything so far is masked; keep the

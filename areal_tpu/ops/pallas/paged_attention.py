@@ -56,6 +56,17 @@ over the kv-head axis.
 The CURRENT token's K/V ride as separate operands and fold into the
 online softmax at the last grid step (the pool is read-only during the
 caller's layer scan; the model scatters all layers' new KV afterwards).
+
+A WINDOW layer (``sliding_window``) is another program of the same kernel,
+``paged_decode_window``: each row also has a FIRST visible position
+(``max(len + 1 - window, 0)``, a fourth scalar-prefetch operand). Pages
+wholly before it are neither copied nor walked (their table entries may
+be stale: the engine gives such pages back to the free list while the
+request runs, ``gen/engine.py``), the page the edge falls in is masked
+inside, and a grid step that lies wholly before the first position of
+every row of its block costs the one test, as a step past the longest row
+does (``_steps_reached`` counts both ends). The full-attention program
+has none of this and is what it was.
 """
 
 import functools
@@ -127,14 +138,28 @@ def block_plan(
     return sb, kp
 
 
-def _steps_reached(lens, sb: int, span: int) -> int:
+def first_visible(lens, sliding_window: Optional[int]):
+    """First pool position the query at position ``lens`` sees (numpy or
+    jax integers): it sees itself and ``sliding_window - 1`` before it."""
+    if sliding_window is None:
+        return lens * 0
+    return (lens + 1 - sliding_window).clip(0)
+
+
+def _steps_reached(lens, sb: int, span: int, first=None) -> int:
     """Grid steps whose block of ``sb`` consecutive rows of ``lens`` reaches
-    the step's first position: ``ceil(longest / span)`` a block."""
+    the step's first position: ``ceil(longest / span)`` a block; with the
+    rows' ``first`` visible positions (a window layer), less the steps that
+    end before the block's least one."""
     longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
-    return int((-(-longest // span)).sum())
+    steps = -(-longest // span)
+    if first is not None:
+        least = np.asarray(first, np.int64).reshape(-1, sb).min(axis=1)
+        steps = np.maximum(steps - least // span, 0)
+    return int(steps.sum())
 
 
-def kernel_positions(lens, sb: int, span: int) -> int:
+def kernel_positions(lens, sb: int, span: int, first=None) -> int:
     """KV positions the kernel's body runs over for rows of resident
     lengths ``lens`` IN THE ORDER THE KERNEL GETS THEM (host integers;
     ``decode_step_paged`` hands them sorted, so its callers pass
@@ -142,16 +167,18 @@ def kernel_positions(lens, sb: int, span: int) -> int:
     ``sb * span`` positions for each of the ``ceil(max_len / span)`` page
     blocks its longest row reaches (``span = kp * page``). Over the sum of
     ``lens`` it is how many times the resident KV the kernel computes."""
-    return sb * span * _steps_reached(lens, sb, span)
+    return sb * span * _steps_reached(lens, sb, span, first)
 
 
-def kernel_steps(lens, sb: int, span: int, nblk: int) -> Tuple[int, int]:
+def kernel_steps(
+    lens, sb: int, span: int, nblk: int, first=None
+) -> Tuple[int, int]:
     """``(active, total)`` grid steps of a call over rows ``lens`` (in the
     kernel's order, as for :func:`kernel_positions`) with ``nblk`` page
     blocks a row: a block of ``sb`` rows is active in the steps its longest
     row reaches. Only those walk their table entries, wait and run the
     body; the others cost one test each."""
-    return _steps_reached(lens, sb, span), len(lens) // sb * nblk
+    return _steps_reached(lens, sb, span, first), len(lens) // sb * nblk
 
 
 def _decode_kernel(
@@ -163,7 +190,7 @@ def _decode_kernel(
     n_kv: int,
     n_rep: int,
     soft_cap: Optional[float],
-    sliding_window: Optional[int],
+    windowed: bool,
     quantized: bool,
     dv: Optional[int] = None,
 ):
@@ -178,6 +205,8 @@ def _decode_kernel(
     #   layer_ref  [1] int32 scalar-prefetch: which layer of the pool
     #   table_ref  [B, M] int32 scalar-prefetch
     #   lens_ref   [B] int32 scalar-prefetch (pool-resident, EXCL. self)
+    #   first_ref  [B] int32 scalar-prefetch: first visible position
+    #              (``windowed`` only: a window layer's program)
     #   q_ref      [SB, Hq, D]
     #   ks_ref     [SB, Hkv, D] the current tokens' K (not in the pool)
     #   vs_ref     [SB, Hkv, D]
@@ -193,6 +222,9 @@ def _decode_kernel(
     #   acc_scr    [SB, HqP, Dp] f32
     #   sems       DMA semaphores [2, SB, KP]
     #   sc_sems    DMA semaphores [2, SB, KP]                 (quantized)
+    first_ref = None
+    if windowed:
+        first_ref, refs = refs[3], refs[:3] + refs[4:]
     if quantized:
         (layer_ref, table_ref, lens_ref, q_ref, ks_ref, vs_ref, kv_hbm,
          sc_hbm, o_ref, kv_scr, sc_scr, m_scr, l_scr, acc_scr, sems,
@@ -224,6 +256,35 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    def _reaches(bb_t, j_t):
+        """Whether step ``j_t`` holds a visible position of any row of block
+        ``bb_t``: the predicate copies are started AND waited for under."""
+        rows = [bb_t * sb + s for s in range(sb)]
+        longest = functools.reduce(
+            jnp.maximum, [lens_ref[r] for r in rows])
+        reached = j_t * S < longest
+        if windowed:
+            least = functools.reduce(
+                jnp.minimum, [first_ref[r] for r in rows])
+            reached &= (j_t + 1) * S > least
+        return reached
+
+    def _page_tests(slot):
+        """``tests(pg) -> (held, not held)`` for the pages of the slot's
+        table: held where the page has a visible position (a page wholly
+        behind a window is not the slot's any more). The scalars a slot's
+        ``kp`` tests share are read once."""
+        n_used = pl.cdiv(lens_ref[slot], page)
+        if not windowed:
+            return lambda pg: (pg < n_used, pg >= n_used)
+        p0 = first_ref[slot] // page
+
+        def tests(pg):
+            held = (pg < n_used) & (pg >= p0)
+            return held, jnp.logical_not(held)
+
+        return tests
+
     def _issue(g_t, buf):
         """Start every resident-page DMA (and zero un-DMA'd tail blocks the
         body will read) for linear grid step ``g_t`` into buffer ``buf``."""
@@ -233,20 +294,20 @@ def _decode_kernel(
         # the block is active, so un-DMA'd pages of shorter slots must be
         # zeroed up to the block the longest slot reaches (masked
         # probabilities are 0, but 0 * NaN = NaN in the PV dot)
-        max_lens_t = functools.reduce(
-            jnp.maximum, [lens_ref[bb_t * sb + s] for s in range(sb)]
-        )
-
-        # a step its block's longest row does not reach has no page to
-        # fetch and nothing the body will read: ONE test skips its SB * KP
-        # entries (most steps, once the caller has sorted rows by length)
-        @pl.when(j_t * S < max_lens_t)
+        # a step its block's longest row does not reach (or, in a window
+        # layer, that ends before every row's first visible position) has
+        # no page to fetch and nothing the body will read: ONE test skips
+        # its SB * KP entries (most steps, once the caller has sorted rows
+        # by length)
+        @pl.when(_reaches(bb_t, j_t))
         def _reached():
             for s in range(sb):
                 slot = bb_t * sb + s
-                n_used = pl.cdiv(lens_ref[slot], page)
+                tests = _page_tests(slot)
                 for i in range(kp):
-                    @pl.when(j_t * kp + i < n_used)
+                    held, free = tests(j_t * kp + i)
+
+                    @pl.when(held)
                     def _start(s=s, i=i, slot=slot):
                         pidx = table_ref[slot, j_t * kp + i]
                         # K and V are interleaved per page: ONE DMA per
@@ -269,7 +330,7 @@ def _decode_kernel(
                                 sc_sems.at[buf, s, i],
                             ).start()
 
-                    @pl.when(j_t * kp + i >= n_used)
+                    @pl.when(free)
                     def _zero(s=s, i=i):
                         kv_scr[buf, s, :, :, pl.ds(i * page, page), :] = (
                             jnp.zeros((n_str, n_kv, page, D), kv_scr.dtype)
@@ -295,21 +356,18 @@ def _decode_kernel(
     def _prefetch():
         _issue(g + 1, jax.lax.rem(g + 1, 2))
 
-    max_lens = functools.reduce(
-        jnp.maximum, [lens_ref[bb * sb + s] for s in range(sb)]
-    )
     # the predicate _issue started this step's copies under, over the same
     # scalars: every started copy is waited for, and a step that started
     # none tests nothing
-    reached = j * S < max_lens
+    reached = _reaches(bb, j)
 
     @pl.when(reached)
     def _arrived():
         for s in range(sb):
             slot = bb * sb + s
-            n_used = pl.cdiv(lens_ref[slot], page)
+            tests = _page_tests(slot)
             for i in range(kp):
-                @pl.when(j * kp + i < n_used)
+                @pl.when(tests(j * kp + i)[0])
                 def _wait(s=s, i=i, slot=slot):
                     pidx = table_ref[slot, j * kp + i]
                     pltpu.make_async_copy(
@@ -330,10 +388,14 @@ def _decode_kernel(
     # instead of SB sequential small-dot bodies, which left the MXU idle
     # between per-slot dots and made the (now DMA-overlapped) kernel
     # compute-bound
-    lens_v = jnp.stack(
-        [jnp.full((1, S), lens_ref[bb * sb + s], jnp.int32)
-         for s in range(sb)]
-    )                                                          # [SB, 1, S]
+    def _splat(ref):
+        return jnp.stack(
+            [jnp.full((1, S), ref[bb * sb + s], jnp.int32)
+             for s in range(sb)]
+        )                                                      # [SB, 1, S]
+
+    lens_v = _splat(lens_ref)
+    first_v = _splat(first_ref) if windowed else None
 
     @pl.when(reached)
     def _body():
@@ -365,9 +427,10 @@ def _decode_kernel(
         sc = sc.reshape(sb, Hq, S)
         kpos = j * S + jax.lax.broadcasted_iota(jnp.int32, (sb, Hq, S), 2)
         mask = kpos < lens_v
-        if sliding_window is not None:
-            # each query sits at position lens of its slot
-            mask &= kpos > lens_v - sliding_window
+        if windowed:
+            # the edge page of a window: positions before the first
+            # visible one are resident in it and not the query's to see
+            mask &= kpos >= first_v
         sc = jnp.where(mask, sc, NEG_INF)
 
         m_prev = m_scr[:, :Hq, 0:1]                           # [SB,Hq,1]
@@ -457,7 +520,12 @@ def decode(
     ``k_self`` ``[B, 1, D]`` the current token's latent, ``v_self`` is not
     read (pass ``None``), and the result is ``[B, Hq, value_width]``: the
     probabilities over the first ``value_width`` values of every resident
-    latent. The kernel is then named ``mla_decode``."""
+    latent. The kernel is then named ``mla_decode``.
+
+    ``sliding_window``: the query (at position ``lens``) sees itself and
+    ``sliding_window - 1`` positions before it; the kernel is then the
+    ``_window`` program of its name and reads each row's pages from its
+    first visible position on (module docstring)."""
     B, Hq, D = q.shape
     L, P, streams, Hkv, page, _ = pages.shape
     M = table.shape[1]
@@ -483,6 +551,7 @@ def decode(
     if softmax_scale is None:
         softmax_scale = D ** -0.5
     hq_pad = max(8, Hq)
+    windowed = sliding_window is not None
     sb, kp = block_plan(
         B, Hkv, D, page, M, pages.dtype, pages_per_step, slots_per_step,
         streams,
@@ -498,11 +567,11 @@ def decode(
         n_kv=Hkv,
         n_rep=n_rep,
         soft_cap=soft_cap,
-        sliding_window=sliding_window,
+        windowed=windowed,
         quantized=quantized,
         dv=value_width,
     )
-    row = lambda b, j, ly, t, l: (b, 0, 0)
+    row = lambda b, j, *_: (b, 0, 0)
     in_specs = [
         pl.BlockSpec((sb, Hq, D), row),
         pl.BlockSpec((sb, Hkv, D), row),
@@ -519,6 +588,8 @@ def decode(
     ]
     operands = [
         jnp.asarray(layer, jnp.int32).reshape(1), table, lens,
+        *([first_visible(lens, sliding_window).astype(jnp.int32)]
+          if windowed else []),
         q, k_self, *([] if latent else [v_self]), pages,
     ]
     if quantized:
@@ -533,7 +604,7 @@ def decode(
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4 if windowed else 3,
             grid=(B // sb, nblk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((sb, Hq, Dv), row),
@@ -553,6 +624,6 @@ def decode(
         name=(
             "mla_decode" if latent
             else "paged_decode_int8" if quantized else "paged_decode"
-        ),
+        ) + ("_window" if windowed else ""),
     )(*operands)
 

@@ -178,8 +178,10 @@ def ir_programs(phase_dir, jit_name):
     ))
 
 
-# the decode chunk's paged kernel by model: K and V pages (raw or int8), or
-# the latent pool of a latent-attention family (``joyai_llm_flash``)
+# the decode chunk's paged kernel by model, by the start of its name: K and
+# V pages (raw or int8; a window layer's own program of it is
+# ``paged_decode_window``), or the latent pool of a latent-attention family
+# (``joyai_llm_flash``)
 DECODE_KERNELS = ("paged_decode", "mla_decode")
 # the kernel that puts the step's fresh K/V into the page pool
 # (ops/pallas/kv_page_write.py), in the decode chunk and in admission's

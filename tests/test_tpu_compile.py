@@ -493,3 +493,123 @@ def test_joyai_engine_programs_compile(
     assert ("kv_page_write" in text) == (program != "jit_extend")
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
+
+
+# ------------------------------------------------------------------ #
+# The smallthinker-l8.rollout_out15k cell (layer kinds: one page pool, a
+# page table a position of the period, the window layers' own program of
+# the paged kernel).
+# ------------------------------------------------------------------ #
+
+HYBRID_CELL = dict(B=112, M=128, P=14495, periods=2)
+
+
+def test_paged_decode_window_compiles_alone(compiled_kernels, one_chip):
+    """``paged_decode_window`` at the cell's shape: 112 slots, 28q/4kv x
+    128, page 128, a table of 128 pages, the pool's leading axis the two
+    periods; a fourth scalar-prefetch operand (each row's first visible
+    position) and the kernel's own name."""
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    def f(q, ks, vs, pages, layer, table, lens):
+        return pl_paged.decode(
+            q, ks, vs, pages, layer, table, lens, sliding_window=4096)
+
+    text = _compile(f, *_paged_specs(
+        one_chip, page=128, int8=False, L=HYBRID_CELL["periods"],
+        B=HYBRID_CELL["B"], P=HYBRID_CELL["P"], M=HYBRID_CELL["M"],
+        layout=QWEN_7B)).as_text()
+    assert "paged_decode_window" in text
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """The engine of the cell at its real configuration, with placeholder
+    weights and a pool of a few pages (7.9 GB of weights and a 7.6 GB pool
+    are never allocated)."""
+    import json
+
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "smallthinker-21b-l8.json")) as f:
+        arch = json.load(f)
+    cfg = sut.model_config(arch, {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=HYBRID_CELL["B"], max_seqlen=16384,
+        max_new_tokens_cap=15360, page_size=128, n_pages=80, seed=0)
+    eng._decode_use_pallas = True
+    return eng, shapes
+
+
+@pytest.mark.parametrize("program", ["jit_chunk", "jit_extend", "jit_write"])
+def test_hybrid_engine_programs_compile(
+        compiled_kernels, one_chip, hybrid_engine, program):
+    """``jit_chunk`` (16 decode steps at 112 slots and the full table of
+    128 pages in each of the four kinds: one scan over the two periods,
+    both programs of the paged kernel, the 64-expert dispatch, the
+    152k-vocabulary head), ``jit_extend`` (an admission wave of 8 x 128
+    tokens against the pool) and ``jit_write`` (the wave's fresh K/V into
+    every kind's pages) for a described v5e, beside 7.93 GB of weights and
+    the cell's pool of 14,495 pages (7.6 GB): arguments + temporaries
+    under 16.4e9."""
+    import dataclasses
+
+    from areal_tpu.models import transformer as tfm
+
+    eng, shapes = hybrid_engine
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    params = jax.tree.map(spec, shapes)
+    pages = eng.state.cache.pages
+    assert pages.shape[0] == HYBRID_CELL["periods"]
+    state = dataclasses.replace(
+        jax.tree.map(spec, eng.state),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (pages.shape[0], HYBRID_CELL["P"]) + pages.shape[2:],
+            pages.dtype, one_chip)))
+    B, M = HYBRID_CELL["B"], HYBRID_CELL["M"]
+    n, W, C = 8, 64, eng.admit_chunk
+    extend = eng._extend_fn(n, W, skip_pool=False)
+    extend_args = (params, state,
+                   _spec((n, C), jnp.int32, one_chip),
+                   _spec((4, n, W), jnp.int32, one_chip),
+                   _spec((n,), jnp.int32, one_chip),
+                   _spec((n,), jnp.int32, one_chip))
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        args = (params, state, _spec((4, B, M), jnp.int32, one_chip),
+                _spec((0,), jnp.int32, one_chip))
+    elif program == "jit_extend":
+        fn, args = extend, extend_args
+    else:
+        fresh = jax.tree.map(
+            lambda a: _spec(a.shape, a.dtype, one_chip),
+            jax.eval_shape(extend, *extend_args))
+        fn = eng._kv_write_fn(n)
+        args = (state, fresh, _spec((4, n, M), jnp.int32, one_chip),
+                _spec((n,), jnp.int32, one_chip),
+                _spec((n,), jnp.int32, one_chip))
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    # the full layers' program and the window layers', by the names of
+    # their instructions (a file name of an earlier compile can turn up in
+    # a module's table of source files)
+    import re
+
+    assert bool(re.search(r"%paged_decode_window(\.\d+)? = ", text)) == (
+        program == "jit_chunk")
+    assert bool(re.search(r"%paged_decode(\.\d+)? = ", text)) == (
+        program == "jit_chunk")
+    assert bool(re.search(r"%kv_page_write(\.\d+)? = ", text)) == (
+        program != "jit_extend")
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
