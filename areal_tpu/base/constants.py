@@ -43,8 +43,6 @@ SPEC_DECODE_ENV = "AREAL_SPEC_DECODE"   # draft-and-verify decode chunks
 SPEC_K_ENV = "AREAL_SPEC_K"             # draft tokens per slot per spec step
 SPEC_DRAFT_MODEL_ENV = "AREAL_SPEC_DRAFT_MODEL"      # HF dir of draft model
 SPEC_DRAFT_KV_DTYPE_ENV = "AREAL_SPEC_DRAFT_KV_DTYPE"  # draft KV pool dtype
-# Fused sampling epilogue (docs/performance.md "Fused sampling epilogue").
-FUSED_SAMPLE_ENV = "AREAL_FUSED_SAMPLE"  # streamed LM-head + sampling epilogue
 SPEC_K_ADAPT_ENV = "AREAL_SPEC_K_ADAPT"  # retune spec_k from live accept stats
 # KV-pool quantization (docs/performance.md "KV quantization").
 KV_DTYPE_ENV = "AREAL_KV_DTYPE"         # paged KV pool storage dtype
@@ -256,20 +254,6 @@ def spec_draft_kv_dtype() -> Optional[str]:
         SPEC_DRAFT_KV_DTYPE_ENV, raw,
     )
     return None
-
-
-def fused_sample_enabled() -> bool:
-    """``AREAL_FUSED_SAMPLE`` (default off): decode/verify chunks sample
-    through the fused LM-head + sampling epilogue — the head is streamed
-    over vocab blocks with online softmax/argmax/Gumbel state, so the full
-    ``[B, V]`` logits tensor is never materialized and the per-token
-    descending sort disappears for greedy/plain-temperature/top-k slots
-    (top-p rows keep the sorted reference path via the warp-row bucket
-    machinery). Token-exact for greedy slots, distribution-exact for
-    sampled slots (docs/performance.md "Fused sampling epilogue").
-    Default off and unjudged: no benchmark cell has run it (ROADMAP
-    D1)."""
-    return env_flag(FUSED_SAMPLE_ENV, False)
 
 
 def spec_k_adapt_enabled() -> bool:
@@ -646,7 +630,6 @@ def get_env_vars(**extra) -> dict:
         SPEC_K_ENV,
         SPEC_DRAFT_MODEL_ENV,
         SPEC_DRAFT_KV_DTYPE_ENV,
-        FUSED_SAMPLE_ENV,
         SPEC_K_ADAPT_ENV,
         KV_DTYPE_ENV,
         "AREAL_DISABLE_NATIVE",

@@ -639,10 +639,13 @@ class GenerationEngine:
         # [B, V] logits (and their sort) leave the per-token path. Exact
         # for greedy, distribution-exact otherwise; top-p (and top-k >
         # TOPK_MAX) slots keep the sorted path via the warp-row bucket.
+        # No flag: the rule reads what this engine can observe (one TPU
+        # device, an untied head in the serving dtype, a policy); the
+        # argument pins either side (tests, the CPU's streamed XLA pass).
         self.fused = (
             fused_sample
             if fused_sample is not None
-            else constants.fused_sample_enabled()
+            else fused_ops.fused_sample_applies(cfg, self.params, mesh)
         )
         # adaptive spec-K: retune the draft length from the live accept-len
         # histogram the engine already folds per chunk. K only moves within
@@ -711,6 +714,11 @@ class GenerationEngine:
             "moe_experts_hit": 0,
             "moe_expert_slots": 0,
             "moe_load_max": 0,
+            # fused chunks, rows x steps as dispatched: sampled by the
+            # fused head-and-sample pass / sent to the sorted path (top-p,
+            # top-k past the online buffer); both 0 on a materialised engine
+            "fused_rows": 0,
+            "sampler_fallback_rows": 0,
         }
 
     # ------------------------------------------------------------------ #
@@ -1647,7 +1655,8 @@ class GenerationEngine:
         whole batch through a ``[B, V]`` sort, and greedy-only traffic
         skips it entirely. Specializations stay bounded by log2 buckets.
 
-        ``fused`` (STATIC, AREAL_FUSED_SAMPLE): the decode step returns
+        ``fused`` (STATIC, the fused epilogue: ``self.fused``, by
+        ``ops/fused_sample.py:fused_sample_applies``): the step returns
         final-norm hidden states and ``ops/fused_sample.py`` streams the
         LM head over vocab blocks — the ``[B, V]`` logits never
         materialize. Under fused routing the warp bucket holds only the
@@ -2189,7 +2198,8 @@ class GenerationEngine:
             w *= 2
         return min(w, self.B)
 
-    def _decode_chunk_fn(self, decode_steps: int, running: List[int]):
+    def _decode_chunk_fn(self, decode_steps: int, running: List[int],
+                         chunk_attrs: dict):
         """Pick the chunk program (spec or vanilla) plus its table-width
         token bound and the per-slot warp operand for one dispatch.
         ``self.spec`` is read here, under the engine lock — flipping it
@@ -2203,9 +2213,9 @@ class GenerationEngine:
         ``[B, V]`` sort (the old static ``warp=True`` key did exactly
         that)."""
         tok_bound = decode_steps * ((self.spec_k + 1) if self.spec else 1)
-        # fused routing (AREAL_FUSED_SAMPLE): the vanilla chunk narrows
-        # the fallback bucket to the slots the online pass cannot serve
-        # (_fused_warp_host — top-p, top-k > TOPK_MAX); plain top-k slots
+        # fused routing (the fused epilogue, ``fused_sample_applies``): the
+        # vanilla chunk narrows the fallback bucket to the slots the online
+        # pass cannot serve (_fused_warp_host — top-p, top-k > TOPK_MAX); plain top-k slots
         # ride the online buffer instead of the sort. The spec chunk's
         # fused acceptance has no top-k buffer, so it keeps the full
         # _warp_host bucket; draft-model (general-q) spec stays on the
@@ -2239,11 +2249,17 @@ class GenerationEngine:
             metrics_mod.counters.add(
                 metrics_mod.GEN_FUSED_SAMPLE_STEPS, decode_steps
             )
-            if warp_slots:
+            # rows x steps as dispatched, by where they are sampled: the
+            # fused pass, or the sorted path over their own logits rows
+            fallback = len(warp_slots) * decode_steps
+            if fallback:
                 metrics_mod.counters.add(
-                    metrics_mod.GEN_SAMPLER_FALLBACK_ROWS,
-                    len(warp_slots) * decode_steps,
+                    metrics_mod.GEN_SAMPLER_FALLBACK_ROWS, fallback
                 )
+            chunk_attrs["fused_rows"] = len(running) * decode_steps - fallback
+            chunk_attrs["sampler_fallback_rows"] = fallback
+            self.stats["fused_rows"] += chunk_attrs["fused_rows"]
+            self.stats["sampler_fallback_rows"] += fallback
         return make, tok_bound, wb, warp_idx
 
     def _dispatch_chunk(self, chunk, W: int, warp_idx) -> tuple:
@@ -2342,7 +2358,7 @@ class GenerationEngine:
         the tokens a slot can advance in this chunk."""
         with tracing.span("gen_engine/dispatch") as attrs:
             make, tok_bound, wb, warp_idx = self._decode_chunk_fn(
-                decode_steps, running
+                decode_steps, running, chunk_attrs
             )
             lens = self._lens_host[running]
             if self._windowed:

@@ -665,10 +665,13 @@ def _embed(cfg: ModelConfig, params: Params, input_ids, positions):
 
 def head_weight(cfg: ModelConfig, params: Params):
     """The LM-head weight ``[E, V]`` in serving dtype (tied embeddings
-    transpose on the fly — a lazy view XLA fuses into the consumer). The
-    fused sampling epilogue streams this over vocab blocks instead of
-    calling :func:`_head`; the soft cap, when configured, must be applied
-    by the consumer (``ops/fused_sample.py`` takes it as an argument)."""
+    transpose on the fly — a lazy view XLA fuses into the consumer, but a
+    ``V x E`` copy every step as a kernel's operand: the fused epilogue's
+    rule, ``ops/fused_sample.py:fused_sample_applies``, keeps tied heads
+    on the materialised path). The fused epilogue streams this over vocab
+    blocks instead of calling :func:`_head`; the soft cap, when
+    configured, must be applied by the consumer (``ops/fused_sample.py``
+    takes it as an argument)."""
     if cfg.tied_embedding:
         return _cast(cfg, params["embed"]["weight"]).T
     return _cast(cfg, params["head"]["weight"])
@@ -1464,9 +1467,10 @@ def verify_step_paged(
     chunk's KV written for the first ``n_write`` positions of each row.
 
     ``return_hidden=True`` (STATIC) returns the final-NORM hidden states
-    ``[B, C, E]`` instead of logits: the fused sampling epilogue
-    (``ops/fused_sample.py``) streams the head over vocab blocks itself,
-    so the ``[B, C, V]`` logits never materialize.
+    ``[B, C, E]`` instead of logits: the fused epilogue
+    (``ops/fused_sample.py``; the engine asks for it where
+    ``fused_sample_applies`` says so) streams the head over vocab blocks
+    itself, so the ``[B, C, V]`` logits never materialize.
 
     ``n_write`` is the acceptance-agnostic residency bound the engine
     computes (position ``i`` lands where the slot is active and ``n_gen +
@@ -1542,8 +1546,9 @@ def decode_step_paged(
     model's step at a 152k vocab) would be dead weight.
 
     ``return_hidden=True`` (STATIC) returns the final-norm HIDDEN states
-    ``[B, E]`` in place of logits for the fused sampling epilogue
-    (``ops/fused_sample.py``), which streams the head itself — the
+    ``[B, E]`` in place of logits for the fused epilogue
+    (``ops/fused_sample.py``; the engine asks for it where
+    ``fused_sample_applies`` says so), which streams the head itself — the
     ``[B, V]`` logits never materialize.
 
     ``with_routing=True`` (STATIC, MoE models) appends a fourth result:

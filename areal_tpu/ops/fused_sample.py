@@ -40,7 +40,9 @@ same marginal, different RNG stream, so individual draws differ from
 reference keeps ties at the k-th value (a measure-zero difference for
 continuous logits).
 
-Dispatch mirrors ``ops/paged_attention.py``: ``use_pallas=None``
+Whether an engine's decode steps end in this pass at all is
+:func:`fused_sample_applies`, a rule over what the engine can observe (no
+flag). Dispatch mirrors ``ops/paged_attention.py``: ``use_pallas=None``
 auto-detects (TPU, no top-k buffer, no mesh); the XLA path is itself
 streamed (peak extra memory ``[R, block]``, not ``[R, V]``) and serves
 CPU/interpret parity, meshes (GSPMD partitions the block matmuls), and
@@ -62,6 +64,39 @@ _MASK = -2.3819763e38
 # Top-k buffer width: slots with top_k <= TOPK_MAX sample exactly from the
 # online buffer; larger top_k falls back to the sorted reference path.
 TOPK_MAX = 64
+
+
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+def fused_sample_applies(
+    cfg, params, mesh=None, platform: Optional[str] = None
+) -> bool:
+    """Whether a generation engine's decode steps end in the fused
+    head-and-sample kernel or in the materialised ``[B, V]`` logits and
+    ``gen/sampling.py``, from what the engine can observe (as
+    ``kv_write_kernel_applies`` and ``decode_kernel_applies`` say it of
+    their kernels). The kernel serves: ONE TPU device (``pallas_call`` has
+    no partitioning rule, so any mesh of several keeps the materialised
+    path, which GSPMD partitions); an UNTIED head stored in the serving
+    dtype (``head_weight`` of a tied embedding is a lazy transpose, and a
+    head kept in another dtype a lazy cast: as a ``pallas_call`` operand
+    either becomes a ``V x E`` copy every step); a policy, not a critic
+    (whose head is one column); a vocabulary of at least one lane tile.
+    Everything else runs exactly the programs it ran before the kernel
+    existed. ``params`` is the engine's tree as it serves it (arrays or
+    their shapes); ``platform`` defaults to the first device's."""
+    if platform is None:
+        platform = _platform()
+    return (
+        platform == "tpu"
+        and (mesh is None or mesh.size == 1)
+        and not cfg.tied_embedding
+        and not cfg.is_critic
+        and params["head"]["weight"].dtype == jnp.dtype(cfg.dtype)
+        and cfg.vocab_size >= 128
+    )
 
 
 def _update_block(
@@ -221,7 +256,7 @@ def fused_sample(
     topk: Optional[jnp.ndarray] = None,    # [R] i32; > TOPK_MAX => inactive
     exclude: Optional[jnp.ndarray] = None,  # [R] i32 token to mask (-1 none)
     gather_ids: Optional[jnp.ndarray] = None,  # [R] i32 token to score
-    block_size: int = 2048,
+    block_size: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     mesh=None,
     interpret: Optional[bool] = None,
@@ -242,15 +277,17 @@ def fused_sample(
     top-k buffer and no mesh; everywhere else the streamed XLA path runs
     (same math, same memory shape — peak ``[R, block]``). Explicit
     ``use_pallas=True`` raises when the kernel cannot serve the request.
+    ``block_size`` left ``None``: the kernel sizes its vocabulary block
+    from the shapes (``ops/pallas/fused_sample.py:block_columns``), the XLA
+    path takes 2048 columns.
     """
     R, E = x.shape
     V = w.shape[1]
     if w.shape[0] != E:
         raise ValueError(f"head weight {w.shape} does not match hidden {x.shape}")
-    platform = jax.devices()[0].platform
     if use_pallas is None:
         use_pallas = (
-            platform == "tpu"
+            _platform() == "tpu"
             and mesh is None
             and topk is None
             and V >= 128
@@ -277,7 +314,7 @@ def fused_sample(
         )
     return _fused_sample_xla(
         rng, x, w, temperature, greedy, soft_cap, topk, exclude,
-        gather_ids, block_size, TOPK_MAX,
+        gather_ids, 2048 if block_size is None else block_size, TOPK_MAX,
     )
 
 
@@ -289,7 +326,7 @@ def fused_spec_rejection(
     sp,                            # SamplingParams
     greedy: Optional[jnp.ndarray] = None,
     soft_cap: Optional[float] = None,
-    block_size: int = 2048,
+    block_size: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     mesh=None,
 ):

@@ -213,8 +213,9 @@ def paged_decode_attention(
     serving hot path. The scales array shards on the same kv-head axis.
 
     This is the attention half of the decode-step roofline; the OTHER
-    half — the LM head + sampling epilogue — streams through
-    ``ops/fused_sample.py`` under ``AREAL_FUSED_SAMPLE`` (same
+    half — the LM head + sampling epilogue — streams through the fused
+    epilogue of ``ops/fused_sample.py`` where its rule
+    (``fused_sample_applies``) says the engine can run it (same
     auto-detect-then-fallback dispatch shape as ``use_pallas`` here).
 
     ``value_width`` marks a LATENT pool ``[L, P, 1, 1, page, D]`` (absorbed

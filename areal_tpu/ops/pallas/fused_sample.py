@@ -3,20 +3,49 @@
 One grid step per vocab block: the head block ``W[:, j*BV:(j+1)*BV]``
 streams HBM -> VMEM through the pallas pipeline while the hidden states
 ``x [R, E]`` stay resident, the block's logits come off the MXU in f32,
-and the sampling state folds in online — running softmax normalizer
-(max + rescaled sum-of-exponentials, the same recurrence as the paged
-extend kernel), running raw argmax (greedy slots, token-exact), and a
-running Gumbel-top-1 argmax over the temperature-warped logits (the
-categorical sample; in-kernel PRNG via ``pltpu.prng_seed`` /
-``prng_random_bits``, reseeded per block from the scalar-prefetched seed
-so the stream is grid-order independent). The full ``[R, V]`` logits
-tensor never exists in HBM: HBM traffic is exactly one read of the head
-weight — the decode-epilogue roofline.
+and the sampling state folds in online. The full ``[R, V]`` logits tensor
+never exists in HBM: HBM traffic is exactly one read of the head weight —
+the decode-epilogue roofline.
 
-Per-row extras for the speculative verify path: an *excluded* token
-(masked out of the Gumbel argmax only — the rejection-sampling residual
-"p with the rejected token removed") and a *gathered* token whose warped
-logit is returned (the draft-token acceptance score).
+The fold is written for the vector unit, which at 256 rows x 129k columns
+bounds the call together with the MXU once the weight streams at the
+memory's rate. A block's logits are taken in pieces of ``[ROW_GROUP,
+128]`` (a few vector registers; a loop over row groups, and within one
+over lane tiles, ``TILE_UNROLL`` an iteration, so the program stays small
+to trace), and all running state is kept PER LANE, ``[R, 128]``: a
+piece folds into it with element-wise compares and selects only, and the
+cross-lane reductions (one max a block for the softmax reference, the
+arg-max's tie-break at the very end) touch ``[R, 128]``, never
+``[R, BV]``. Per piece:
+
+- running raw arg-max per lane (value, tile index): strict ``>`` keeps the
+  earliest tile, and the final min over lanes of ``tile * 128 + lane``
+  among the lanes at the row max IS ``jnp.argmax``'s tie order (greedy
+  slots, token-exact). Its row max is also the block's softmax reference
+  (``max(logits) / T == max(logits / T)``), so no second max is taken;
+- running sum of ``exp(warped - m)`` per lane (``m``: the row's running
+  max, rescaled once a block): the exact log-normaliser ``m + log l``;
+- running Gumbel-top-1 arg-max per lane over ``warped - log(-log u)``
+  (value, tile index, the warped logit there): the categorical sample and
+  its log-prob.
+
+The uniforms ``u`` come from the chip's PRNG (``pltpu.prng_seed`` /
+``prng_random_bits``: one instruction a register, reseeded per block from
+the scalar-prefetched seed and the block index, so the stream does not
+depend on grid order) where the kernel is compiled, and from a
+counter-based hash of (seed, row, column) (the murmur3 finaliser, about
+twelve integer ops an element) in interpret mode, which has no PRNG
+lowering. Either way the draw is an exact Gumbel-top-1 over every valid
+column, reproducible for one seed on one path.
+
+Built only when the caller passes them (the engine's vanilla chunk passes
+neither), per-row extras for the speculative verify path: an *excluded*
+token (masked out of the Gumbel argmax only — the rejection-sampling
+residual "p with the rejected token removed") and a *gathered* token
+whose warped logit is returned (the draft-token acceptance score).
+Columns past the vocabulary exist in the last block only, and how many of
+its columns are real is static: that block's program folds its whole
+tiles, masks the one partial tile and skips the rest.
 
 Top-k slots are NOT handled here (the online top-k buffer lives in the
 streamed XLA path of ``ops/fused_sample.py``; the engine routes top-k
@@ -36,6 +65,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
 LANES = 128
+# rows of one piece, at most: [32, 128] f32 is four vector registers, so a
+# piece's chain of element-wise ops and the seven per-lane state arrays of
+# its row group fit the register file together
+ROW_GROUP = 32
+# columns of one grid step, at most: a block of 1536+ rows x 2048 columns
+# is 6+ MB, far past where a DMA is efficient (1024 and 4096 measured the
+# same within 3 % at the five rollout cells' shapes, 512 up to 15 % slower)
+MAX_BLOCK_V = 2048
+# pieces of a block the compiler sees at once: a loop over a block's lane
+# tiles takes this many an iteration, so the scheduler can overlap one
+# piece's chain with the next. It matters only where the vector unit
+# bounds the call (256 rows x 129,280 columns, ms a call on a v5e at 1 / 2
+# / 4 / 8 / 16 an iteration: 1.13 / 1.02 / 0.96 / 0.94 / 0.91; nothing at 64-128
+# rows), and every piece is traced and lowered again by each of the dozen
+# programs of a rollout cell that hold this kernel, at every start (all
+# 16 unrolled: 0.4 s a program in the sandbox, 8: 0.25)
+TILE_UNROLL = 8
+# what the double-buffered weight block and the block's f32 logits may
+# take of a v5e's 128 MiB of VMEM
+_VMEM_BUDGET = 40 * 2 ** 20
 _BIG_I32 = 2 ** 30  # python literal: a jnp scalar would be a captured const
 
 
@@ -43,122 +92,211 @@ def _interpret() -> bool:
     return jax.devices()[0].platform != "tpu"
 
 
-def _first_max_idx(vals, cols, valid):
-    """(max value [R,1], first column index attaining it [R,1]) — the 2D
-    formulation of argmax (min column id among the maxima) so the kernel
-    never needs a 1-D iota, and tie order matches ``jnp.argmax``."""
-    mv = jnp.max(jnp.where(valid, vals, NEG_INF), axis=-1, keepdims=True)
-    at_max = valid & (vals == mv)
-    mi = jnp.min(jnp.where(at_max, cols, _BIG_I32), axis=-1, keepdims=True)
-    return mv, mi
+def block_columns(R: int, E: int, V: int, itemsize: int) -> int:
+    """Vocabulary columns of one grid step, from the shapes: the most (in
+    whole lane tiles, up to ``MAX_BLOCK_V``) whose double-buffered weight
+    block ``[E, BV]`` and f32 logits ``[R, BV]`` (with two temporaries of
+    that size) fit the VMEM budget. 3584 rows of bf16 leave 2048 columns
+    (14.7 MB a buffer)."""
+    per_col = 2 * E * itemsize + 3 * R * 4
+    fit = max(LANES, _VMEM_BUDGET // per_col // LANES * LANES)
+    return min(MAX_BLOCK_V, fit, -(-V // LANES) * LANES)
 
 
-def _kernel(
-    seed_ref, x_ref, w_ref, temp_ref, greedy_ref, excl_ref, gid_ref,
-    tok_ref, lp_ref, argmax_ref, gat_ref, norm_ref,
-    m_scr, l_scr, amv_scr, ami_scr, gp_scr, gw_scr, gi_scr, gat_scr,
-    *, nb: int, block_v: int, vocab: int, soft_cap: Optional[float],
-):
-    j = pl.program_id(0)
-    R = x_ref.shape[0]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        amv_scr[...] = jnp.full_like(amv_scr, NEG_INF)
-        ami_scr[...] = jnp.zeros_like(ami_scr)
-        gp_scr[...] = jnp.full_like(gp_scr, NEG_INF)
-        gw_scr[...] = jnp.zeros_like(gw_scr)
-        gi_scr[...] = jnp.zeros_like(gi_scr)
-        gat_scr[...] = jnp.full_like(gat_scr, NEG_INF)
-
-    logits = jnp.dot(
-        x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-    )
-    if soft_cap is not None and soft_cap > 0:
-        logits = jnp.tanh(logits / soft_cap) * soft_cap
-    cols = j * block_v + jax.lax.broadcasted_iota(
-        jnp.int32, (R, block_v), 1
-    )
-    valid = cols < vocab
-    t = jnp.maximum(temp_ref[:, :1], 1e-6)
-    warped = jnp.where(valid, logits, 0.0) / t
-
-    # online logsumexp of the warped logits
-    m_prev = m_scr[:, :1]
-    bm = jnp.max(jnp.where(valid, warped, NEG_INF), axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, bm)
-    l_new = l_scr[:, :1] * jnp.exp(m_prev - m_new) + jnp.sum(
-        jnp.where(valid, jnp.exp(warped - m_new), 0.0),
-        axis=-1, keepdims=True,
-    )
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    # running raw argmax: strict > keeps the earliest maximum across
-    # blocks, matching jnp.argmax tie order over the full vocab
-    bv, bi = _first_max_idx(logits, cols, valid)
-    upd = bv > amv_scr[:, :1]
-    amv_new = jnp.where(upd, bv, amv_scr[:, :1])
-    ami_new = jnp.where(upd, bi, ami_scr[:, :1])
-    amv_scr[...] = jnp.broadcast_to(amv_new, amv_scr.shape)
-    ami_scr[...] = jnp.broadcast_to(ami_new, ami_scr.shape)
-
-    # Gumbel-top-1 over warped (+ per-row exclusion): running argmax of
-    # warped + G across every block IS a categorical draw. Uniforms come
-    # from a counter-based hash of (seed, row, global column) — the
-    # murmur3 finalizer over a per-element counter — rather than the
-    # stateful pltpu PRNG: identical bits in compiled and interpret mode
-    # (the interpret path has no prng_seed lowering), and independent of
-    # grid-iteration order by construction.
-    rows_i = jax.lax.broadcasted_iota(jnp.int32, (R, block_v), 0)
-    h = (cols * -1640531527) ^ (rows_i * -2048144789) ^ seed_ref[0]
+def _hash_uniform_bits(row_term, lane_term, col0):
+    """uint32 bits of the counter hash at columns ``col0 + lane``:
+    ``row_term`` is ``row * c2 ^ seed`` and ``lane_term`` ``lane * c1``,
+    both ``[n, 128]`` and made once a grid step."""
+    h = (lane_term + col0 * -1640531527) ^ row_term
     h = jax.lax.bitcast_convert_type(h, jnp.uint32)
     h = h ^ (h >> 16)
     h = h * np.uint32(0x85EBCA6B)
     h = h ^ (h >> 13)
     h = h * np.uint32(0xC2B2AE35)
-    h = h ^ (h >> 16)
-    # the TPU lowering has no uint32 -> float32 cast; the top 24 bits fit
-    # an int32 exactly
-    u = ((h >> 8).astype(jnp.int32).astype(jnp.float32) + 0.5) * (
-        1.0 / (1 << 24)
-    )
-    pert = warped - jnp.log(-jnp.log(u))
-    pert = jnp.where(cols == excl_ref[:, :1], NEG_INF, pert)
-    pbv, pbi = _first_max_idx(pert, cols, valid)
-    pw = jnp.sum(
-        jnp.where(cols == pbi, warped, 0.0), axis=-1, keepdims=True
-    )
-    upd2 = pbv > gp_scr[:, :1]
-    gp_new = jnp.where(upd2, pbv, gp_scr[:, :1])
-    gw_new = jnp.where(upd2, pw, gw_scr[:, :1])
-    gi_new = jnp.where(upd2, pbi, gi_scr[:, :1])
-    gp_scr[...] = jnp.broadcast_to(gp_new, gp_scr.shape)
-    gw_scr[...] = jnp.broadcast_to(gw_new, gw_scr.shape)
-    gi_scr[...] = jnp.broadcast_to(gi_new, gi_scr.shape)
+    return h ^ (h >> 16)
 
-    # gathered warped logit (speculative draft score)
-    hit = valid & (cols == gid_ref[:, :1])
-    any_hit = jnp.max(
-        jnp.where(hit, 1.0, 0.0), axis=-1, keepdims=True
-    ) > 0.0
-    gval = jnp.sum(jnp.where(hit, warped, 0.0), axis=-1, keepdims=True)
-    gat_new = jnp.where(any_hit, gval, gat_scr[:, :1])
-    gat_scr[...] = jnp.broadcast_to(gat_new, gat_scr.shape)
+
+def _kernel(
+    seed_ref, x_ref, w_ref, temp_ref, greedy_ref, *refs,
+    nb: int, block_v: int, vocab: int, soft_cap: Optional[float],
+    with_exclude: bool, with_gather: bool, hw_prng: bool,
+):
+    refs = list(refs)
+    excl_ref = refs.pop(0) if with_exclude else None
+    gid_ref = refs.pop(0) if with_gather else None
+    tok_ref, lp_ref, argmax_ref, norm_ref = refs[:4]
+    refs = refs[4:]
+    gat_ref = refs.pop(0) if with_gather else None
+    lg_scr, m_scr, l_scr, amv_scr, ami_scr, gp_scr, gw_scr, gi_scr = refs[:8]
+    gat_scr = refs[8] if with_gather else None
+
+    j = pl.program_id(0)
+    R = x_ref.shape[0]
+    n_tiles = block_v // LANES
+    # rows of one piece (R is whole sublane tiles)
+    rg = next(n for n in (ROW_GROUP, 16, 8) if R % n == 0)
+    seed = seed_ref[0]
+
+    @pl.when(j == 0)
+    def _init():
+        for ref, fill in (
+            (m_scr, NEG_INF), (l_scr, 0.0), (amv_scr, NEG_INF),
+            (ami_scr, 0), (gp_scr, NEG_INF), (gw_scr, 0.0), (gi_scr, 0),
+        ) + (((gat_scr, NEG_INF),) if with_gather else ()):
+            ref[...] = jnp.full(ref.shape, fill, ref.dtype)
+
+    def fold(tiles: int, partial: int):
+        """One block's ``tiles`` whole lane tiles and, after them, the
+        first ``partial`` columns of one more."""
+        logits = jnp.dot(
+            x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        )
+        if soft_cap is not None and soft_cap > 0:
+            logits = jnp.tanh(logits / soft_cap) * soft_cap
+        lg_scr[...] = logits
+        if hw_prng:
+            pltpu.prng_seed(seed, j)
+
+        def group(g, carry):
+            rs = pl.ds(pl.multiple_of(g * rg, rg), rg)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rg, LANES), 1)
+            inv_t = 1.0 / jnp.maximum(temp_ref[rs, :], 1e-6)
+
+            def piece(c, columns):
+                lg = lg_scr[rs, pl.ds(pl.multiple_of(c * LANES, LANES), LANES)]
+                if columns < LANES:
+                    lg = jnp.where(lane < columns, lg, -jnp.inf)
+                return lg
+
+            def over_pieces(body, state):
+                # the whole tiles in a loop, TILE_UNROLL of them an
+                # iteration, then what is left of them and the partial one
+                def some(o, st):
+                    for i in range(TILE_UNROLL):
+                        st = body(o * TILE_UNROLL + i, LANES, st)
+                    return st
+
+                state = jax.lax.fori_loop(
+                    0, tiles // TILE_UNROLL, some, state)
+                for c in range(tiles // TILE_UNROLL * TILE_UNROLL, tiles):
+                    state = body(c, LANES, state)
+                if partial:
+                    state = body(tiles, partial, state)
+                return state
+
+            # pass A: the running raw arg-max per lane; its row max is the
+            # block's softmax reference
+            def arg_max(c, columns, state):
+                am_v, am_i = state
+                lg = piece(c, columns)
+                upd = lg > am_v
+                return (jnp.where(upd, lg, am_v),
+                        jnp.where(upd, j * n_tiles + c, am_i))
+
+            am_v, am_i = over_pieces(
+                arg_max, (amv_scr[rs, :], ami_scr[rs, :]))
+            amv_scr[rs, :] = am_v
+            ami_scr[rs, :] = am_i
+            m_prev = m_scr[rs, :]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(am_v, axis=-1, keepdims=True) * inv_t
+            )
+            m_scr[rs, :] = m_new
+
+            # pass B: the normaliser's sum and the Gumbel arg-max
+            if not hw_prng:
+                row_term = (
+                    (jax.lax.broadcasted_iota(jnp.int32, (rg, LANES), 0)
+                     + g * rg) * -2048144789
+                ) ^ seed
+                lane_term = lane * -1640531527
+            if with_exclude:
+                excl = excl_ref[rs, :]
+            if with_gather:
+                gid = gid_ref[rs, :]
+
+            def sample(c, columns, state):
+                l, gp, gw, gi, *gat = state
+                col0 = j * block_v + c * LANES
+                warped = piece(c, columns) * inv_t
+                l = l + jnp.exp(warped - m_new)
+                if hw_prng:
+                    bits = pltpu.bitcast(
+                        pltpu.prng_random_bits((rg, LANES)), jnp.uint32
+                    )
+                else:
+                    bits = _hash_uniform_bits(row_term, lane_term, col0)
+                # the TPU lowering has no uint32 -> float32 cast; the top
+                # 24 bits fit an int32 exactly
+                u = ((bits >> 8).astype(jnp.int32).astype(jnp.float32)
+                     + 0.5) * (1.0 / (1 << 24))
+                pert = warped - jnp.log(-jnp.log(u))
+                if with_exclude:
+                    pert = jnp.where(lane == excl - col0, NEG_INF, pert)
+                upd = pert > gp
+                if with_gather:
+                    gat = [jnp.where(lane == gid - col0, warped, gat[0])]
+                return (l, jnp.where(upd, pert, gp),
+                        jnp.where(upd, warped, gw),
+                        jnp.where(upd, j * n_tiles + c, gi), *gat)
+
+            l, gp, gw, gi, *gat = over_pieces(sample, (
+                l_scr[rs, :] * jnp.exp(m_prev - m_new),
+                gp_scr[rs, :], gw_scr[rs, :], gi_scr[rs, :],
+            ) + ((gat_scr[rs, :],) if with_gather else ()))
+            l_scr[rs, :] = l
+            gp_scr[rs, :] = gp
+            gw_scr[rs, :] = gw
+            gi_scr[rs, :] = gi
+            if with_gather:
+                gat_scr[rs, :] = gat[0]
+            return carry
+
+        jax.lax.fori_loop(0, R // rg, group, 0)
+
+    # columns past the vocabulary exist in the last block only, and how
+    # many of its columns are real is known here
+    tail = vocab - (nb - 1) * block_v
+    if tail == block_v:
+        fold(n_tiles, 0)
+    else:
+        pl.when(j < nb - 1)(functools.partial(fold, n_tiles, 0))
+        pl.when(j == nb - 1)(
+            functools.partial(fold, tail // LANES, tail % LANES))
 
     @pl.when(j == nb - 1)
     def _emit():
-        norm = m_new + jnp.log(l_new)
-        is_greedy = greedy_ref[:, :1] > 0
-        tok = jnp.where(is_greedy, ami_new, gi_new)
-        lp = jnp.where(is_greedy, amv_new / t - norm, gw_new - norm)
-        tok_ref[...] = jnp.broadcast_to(tok, tok_ref.shape)
-        lp_ref[...] = jnp.broadcast_to(lp, lp_ref.shape)
-        argmax_ref[...] = jnp.broadcast_to(ami_new, argmax_ref.shape)
-        gat_ref[...] = jnp.broadcast_to(gat_new - norm, gat_ref.shape)
-        norm_ref[...] = jnp.broadcast_to(norm, norm_ref.shape)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (R, LANES), 1)
+
+        def first_at_max(vals, tiles):
+            # the row max and the FIRST column that attains it: per lane
+            # the earliest tile was kept, so the min column among the lanes
+            # at the max is jnp.argmax's tie order
+            v = jnp.max(vals, axis=-1, keepdims=True)
+            cols = jnp.where(vals == v, tiles * LANES + lane, _BIG_I32)
+            return v, cols, jnp.min(cols, axis=-1, keepdims=True)
+
+        t = jnp.maximum(temp_ref[...], 1e-6)
+        am_v, _, am_i = first_at_max(amv_scr[...], ami_scr[...])
+        _, g_cols, g_i = first_at_max(gp_scr[...], gi_scr[...])
+        g_w = jnp.max(
+            jnp.where(g_cols == g_i, gw_scr[...], NEG_INF),
+            axis=-1, keepdims=True,
+        )
+        norm = m_scr[...] + jnp.log(
+            jnp.sum(l_scr[...], axis=-1, keepdims=True)
+        )
+        is_greedy = greedy_ref[...] > 0
+        tok_ref[...] = jnp.where(is_greedy, am_i, g_i)
+        # a greedy row's m IS am_v * (1 / t), the same product
+        lp_ref[...] = jnp.where(is_greedy, am_v * (1.0 / t), g_w) - norm
+        argmax_ref[...] = jnp.broadcast_to(am_i, argmax_ref.shape)
+        norm_ref[...] = norm
+        if with_gather:
+            # one lane of one tile ever took the gathered logit
+            gat_ref[...] = jnp.max(
+                gat_scr[...], axis=-1, keepdims=True
+            ) - norm
 
 
 def fused_sample_pallas(
@@ -170,41 +308,59 @@ def fused_sample_pallas(
     exclude: Optional[jnp.ndarray] = None,     # [R] i32, -1 = none
     gather_ids: Optional[jnp.ndarray] = None,  # [R] i32
     soft_cap: Optional[float] = None,
-    block_v: int = 2048,
+    block_v: Optional[int] = None,
     interpret: Optional[bool] = None,
 ):
     """Kernel wrapper; same result dict as the XLA path of
     ``ops/fused_sample.py`` (minus top-k, which the dispatch never routes
     here). The PRNG seed derives from ``rng`` on device — no host
-    round-trip rides the dispatch."""
-    R, E = x.shape
+    round-trip rides the dispatch. ``block_v`` (columns of one grid step)
+    follows from the shapes (:func:`block_columns`); a caller's value is
+    held to what fits."""
+    R0, E = x.shape
     V = w.shape[1]
-    block_v = max(LANES, min(block_v, -(-V // LANES) * LANES))
+    R = -(-R0 // 8) * 8  # whole sublane tiles; the padding rows are cut off
+    fit = block_columns(R, E, V, w.dtype.itemsize)
+    if block_v is None:
+        block_v = fit
+    else:
+        block_v = max(LANES, min(block_v // LANES * LANES, fit))
     nb = -(-V // block_v)
+    interpret = _interpret() if interpret is None else interpret
     seed = jax.random.randint(
         rng, (1,), jnp.iinfo(jnp.int32).min, jnp.iinfo(jnp.int32).max,
         dtype=jnp.int32,
     )
 
     def _rows(v, dtype, fill):
-        if v is None:
-            arr = jnp.full((R, 1), fill, dtype)
-        else:
-            arr = v.astype(dtype).reshape(R, 1)
-        return jnp.broadcast_to(arr, (R, LANES))
+        arr = jnp.pad(v.astype(dtype), (0, R - R0), constant_values=fill)
+        return jnp.broadcast_to(arr[:, None], (R, LANES))
 
+    x = jnp.pad(x, ((0, R - R0), (0, 0)))
     operands = [
-        seed,
-        x,
-        w,
+        seed, x, w,
         _rows(temperature, jnp.float32, 1.0),
-        _rows(greedy.astype(jnp.int32), jnp.int32, 0),
-        _rows(exclude, jnp.int32, -1),
-        _rows(gather_ids, jnp.int32, -1),
+        _rows(greedy, jnp.int32, 0),
     ]
+    out_dtypes = [jnp.int32, jnp.float32, jnp.int32, jnp.float32]
+    # per-lane running state: softmax max and sum, raw arg-max (value,
+    # tile), Gumbel arg-max (perturbed value, warped logit there, tile)
+    scratch = [
+        jnp.float32, jnp.float32, jnp.float32, jnp.int32,
+        jnp.float32, jnp.float32, jnp.int32,
+    ]
+    if exclude is not None:
+        operands.append(_rows(exclude, jnp.int32, -1))
+    if gather_ids is not None:
+        operands.append(_rows(gather_ids, jnp.int32, -1))
+        out_dtypes.append(jnp.float32)
+        scratch.append(jnp.float32)
     row_spec = pl.BlockSpec((R, LANES), lambda j, s: (0, 0))
     kernel = functools.partial(
         _kernel, nb=nb, block_v=block_v, vocab=V, soft_cap=soft_cap,
+        with_exclude=exclude is not None,
+        with_gather=gather_ids is not None,
+        hw_prng=not interpret,
     )
     outs = pl.pallas_call(
         kernel,
@@ -214,39 +370,30 @@ def fused_sample_pallas(
             in_specs=[
                 pl.BlockSpec((R, E), lambda j, s: (0, 0)),
                 pl.BlockSpec((E, block_v), lambda j, s: (0, j)),
-                row_spec, row_spec, row_spec, row_spec,
-            ],
-            out_specs=[row_spec] * 5,
-            scratch_shapes=[
-                pltpu.VMEM((R, LANES), jnp.float32),   # m
-                pltpu.VMEM((R, LANES), jnp.float32),   # l
-                pltpu.VMEM((R, LANES), jnp.float32),   # argmax value
-                pltpu.VMEM((R, LANES), jnp.int32),     # argmax index
-                pltpu.VMEM((R, LANES), jnp.float32),   # gumbel perturbed max
-                pltpu.VMEM((R, LANES), jnp.float32),   # warped @ gumbel idx
-                pltpu.VMEM((R, LANES), jnp.int32),     # gumbel index
-                pltpu.VMEM((R, LANES), jnp.float32),   # gathered warped
+            ] + [row_spec] * (len(operands) - 3),
+            out_specs=[row_spec] * len(out_dtypes),
+            scratch_shapes=[pltpu.VMEM((R, block_v), jnp.float32)] + [
+                pltpu.VMEM((R, LANES), dt) for dt in scratch
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((R, LANES), jnp.int32),    # tokens
-            jax.ShapeDtypeStruct((R, LANES), jnp.float32),  # logprobs
-            jax.ShapeDtypeStruct((R, LANES), jnp.int32),    # argmax
-            jax.ShapeDtypeStruct((R, LANES), jnp.float32),  # gathered_lp
-            jax.ShapeDtypeStruct((R, LANES), jnp.float32),  # norm
+            jax.ShapeDtypeStruct((R, LANES), dt) for dt in out_dtypes
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=(
-                # resident x + one head block (double-buffered) + row state
-                4 * R * E + 2 * 4 * E * block_v + 16 * R * LANES * 4
-                + 32 * 2 ** 20
+                # double-buffered head block and resident x, the block's
+                # logits with temporaries, the row state
+                2 * E * block_v * w.dtype.itemsize
+                + 2 * R * E * x.dtype.itemsize
+                + 6 * R * block_v * 4 + 32 * R * LANES * 4
+                + 16 * 2 ** 20
             ),
         ),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
         name="fused_sample",
     )(*operands)
-    tok, lp, am, gat, norm = (o[:, 0] for o in outs)
+    tok, lp, am, norm = (o[:R0, 0] for o in outs[:4])
     out = {
         "tokens": tok,
         "logprobs": lp,
@@ -254,5 +401,5 @@ def fused_sample_pallas(
         "norm": norm,
     }
     if gather_ids is not None:
-        out["gathered_lp"] = gat
+        out["gathered_lp"] = outs[4][:R0, 0]
     return out

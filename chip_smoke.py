@@ -187,6 +187,9 @@ DECODE_KERNELS = ("paged_decode", "mla_decode")
 # (ops/pallas/kv_page_write.py), in the decode chunk and in admission's
 # write program (``jit_kv_write``)
 KV_WRITE_KERNEL = "kv_page_write"
+# the kernel a decode step ends in (ops/pallas/fused_sample.py): the head
+# streamed over vocabulary blocks and sampled from inside the pass
+FUSED_SAMPLE_KERNEL = "fused_sample"
 
 
 def kernels_in(paths):
@@ -534,6 +537,17 @@ def phase_serve(sz, args):
                 "engine_kv_write_tiles is 0 on /metrics_json")
         require("hbm_peak_bytes_in_use" in metrics,
                 "gen server reported no memory_stats gauges")
+        # the step ends in the fused head-and-sample kernel, by the
+        # engine's own rule (one TPU device, an untied head)
+        require(FUSED_SAMPLE_KERNEL in kernels
+                and metrics.get("fused_sample") is True,
+                f"decode chunk samples from materialised logits: {kernels}, "
+                f"fused_sample {metrics.get('fused_sample')}")
+        require(metrics.get("engine_fused_rows", 0) > 0
+                and metrics.get("engine_sampler_fallback_rows") == 0,
+                "engine_fused_rows / engine_sampler_fallback_rows: "
+                f"{metrics.get('engine_fused_rows')} / "
+                f"{metrics.get('engine_sampler_fallback_rows')}")
     # the server has given the chip back: the KV write alone, kernel and
     # scatter into two copies of a small real pool, compared bit for bit
     kv_write, _ = helper(d, "kvwrite", {
@@ -543,6 +557,20 @@ def phase_serve(sz, args):
                 for c in kv_write["cases"]),
             f"kv_page_write and the XLA scatter leave different pools: "
             f"{kv_write['cases']}")
+    # ... and the decode epilogue alone: the fused kernel (compiled on the
+    # chip, interpreted off it) against the head on the same hidden states
+    fused, _ = helper(d, "fusedsample", {
+        "arch": sz["arch"], "seed": args.seed, "slots": sz["slots"],
+        "steps": 16, "draw_calls": 40 if args.rehearse else 200,
+    })
+    require(fused["logprob_max_abs_diff_vs_f32_head"] <= 1e-3
+            and fused["greedy_row_is_the_argmax"],
+            f"fused_sample disagrees with the head it streams: {fused}")
+    # the chi-square's quantile at p ~ 1e-6 (z = 4.75) for the degrees of
+    # freedom this run has (Wilson-Hilferty)
+    df, v = fused["chi2_df"], 2 / (9 * fused["chi2_df"])
+    require(fused["chi2"] <= df * (1 - v + 4.75 * v ** 0.5) ** 3,
+            f"fused_sample's draws are not its softmax's: {fused}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -597,6 +625,8 @@ def phase_serve(sz, args):
         "kv_write_vs_scatter": kv_write["cases"],
         "kv_dtype": metrics.get("kv_dtype"),
         "fused_sample": metrics.get("fused_sample"),
+        "fused_rows": metrics.get("engine_fused_rows"),
+        "fused_sample_vs_head": fused,
         "peak_hbm_gib": round(
             metrics.get("hbm_peak_bytes_in_use", 0) / 2**30, 2),
         "checkpoint_gib": round(made["bytes"] / 2**30, 2),
@@ -1061,9 +1091,92 @@ def child_kvwrite(arg):
     emit({"cases": cases})
 
 
+def child_fusedsample(arg):
+    """One chunk's steps of the decode epilogue alone, at the served
+    model's head: the fused head-and-sample kernel's log-probs of the
+    tokens it drew against the head applied to the same hidden states
+    (in float32: ``apply_head`` rounds the logits to the serving dtype
+    first, and how far that moves a log-prob is reported beside it), a
+    greedy row's token against the arg-max, and the chi-square of the
+    uniform source the kernel uses HERE (the chip's PRNG where it is
+    compiled): 256 rows that are one row are 256 draws a call."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.models.config import ModelConfig
+    from areal_tpu.ops import fused_sample as fs
+
+    cfg = ModelConfig(**arg["arch"])
+    dtype = jnp.dtype(cfg.dtype)
+    B, E, V = arg["slots"], cfg.hidden_dim, cfg.vocab_size
+    kx, kw, kr, kc = jax.random.split(jax.random.key(arg["seed"]), 4)
+    w = (jax.random.normal(kw, (E, V), jnp.float32) * 2 / E ** 0.5).astype(dtype)
+    params = {"head": {"weight": w}}
+    temp = jnp.where(jnp.arange(B) == 0, 0.0, 1.0).astype(jnp.float32)
+
+    @jax.jit
+    def step(key, x):
+        out = fs.fused_sample(
+            key, x, tfm.head_weight(cfg, params), temp, temp <= 0.0,
+            use_pallas=True)
+        exact = jax.nn.log_softmax(
+            jnp.dot(x, w, preferred_element_type=jnp.float32), axis=-1)
+        served = jax.nn.log_softmax(tfm.apply_head(cfg, params, x), axis=-1)
+        rows = jnp.arange(B)
+        tok = out["tokens"]
+        # a greedy row reports the log-prob of a temperature-0
+        # distribution (~0): compare the sampled rows
+        lp = jnp.where(temp > 0.0, out["logprobs"], exact[rows, tok])
+        return (tok, jnp.max(jnp.abs(lp - exact[rows, tok])),
+                jnp.max(jnp.abs(served[rows, tok] - exact[rows, tok])),
+                jnp.argmax(exact[0]))
+
+    diffs, rounding, greedy_ok, toks = [], [], True, set()
+    for i, key in enumerate(jax.random.split(kr, arg["steps"])):
+        x = jax.random.normal(
+            jax.random.fold_in(kx, i), (B, E), jnp.float32).astype(dtype)
+        tok, d, r, am = step(key, x)
+        diffs.append(float(d))
+        rounding.append(float(r))
+        greedy_ok &= int(tok[0]) == int(am)
+        toks.update(np.asarray(tok[1:]).tolist())
+    # the uniform source's marginal
+    R, Vs = 256, 1024
+    x1 = jax.random.normal(kc, (1, 256), jnp.float32).astype(dtype)
+    ws = (jax.random.normal(kw, (256, Vs), jnp.float32) * 3 / 16).astype(dtype)
+    p = np.asarray(jax.nn.softmax(
+        jnp.dot(x1, ws, preferred_element_type=jnp.float32)[0]), np.float64)
+    draw = jax.jit(lambda k: fs.fused_sample(
+        k, jnp.tile(x1, (R, 1)), ws, jnp.ones((R,)), jnp.zeros((R,), bool),
+        block_size=256, use_pallas=True)["tokens"])
+    counts = np.zeros(Vs)
+    for key in jax.random.split(kc, arg["draw_calls"]):
+        counts += np.bincount(np.asarray(draw(key)), minlength=Vs)
+    n = counts.sum()
+    big = np.flatnonzero(n * p >= 20)     # the rest pooled into one bin
+    obs = np.append(counts[big], n - counts[big].sum())
+    exp = np.append(n * p[big], n - (n * p[big]).sum())
+    emit({
+        "rows": B, "hidden": E, "vocab": V, "steps": arg["steps"],
+        "logprob_max_abs_diff_vs_f32_head": max(diffs),
+        "serving_dtype_head_moves_a_logprob_by": max(rounding),
+        "greedy_row_is_the_argmax": bool(greedy_ok),
+        "distinct_sampled_tokens": len(toks),
+        "chi2": float(((obs - exp) ** 2 / exp).sum()), "chi2_df": len(obs) - 1,
+        "draws": int(n),
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 CHILDREN = {
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
+    "fusedsample": child_fusedsample,
 }
 
 

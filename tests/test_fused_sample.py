@@ -198,6 +198,149 @@ class TestOpParity:
             )
 
 
+class TestFusedSampleApplies:
+    """``fused_sample_applies``: the engine's default, from what it can
+    observe (no flag)."""
+
+    @staticmethod
+    def _params(dtype):
+        return {"head": {"weight": jax.ShapeDtypeStruct((32, 128), dtype)}}
+
+    @pytest.mark.parametrize(
+        "case,want",
+        [("tpu_one_device", True), ("mesh_of_one", True), ("cpu", False),
+         ("mesh", False), ("tied_head", False), ("critic", False),
+         ("small_vocab", False), ("head_in_another_dtype", False)],
+    )
+    def test_rule(self, case, want):
+        import dataclasses
+
+        from jax.sharding import Mesh
+
+        cfg = dataclasses.replace(CFG, dtype="bfloat16")
+        head, mesh, platform = jnp.bfloat16, None, "tpu"
+        if case == "cpu":
+            platform = "cpu"
+        elif case == "mesh":
+            mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
+        elif case == "mesh_of_one":
+            mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
+        elif case == "tied_head":
+            cfg = dataclasses.replace(cfg, tied_embedding=True)
+        elif case == "critic":
+            cfg = dataclasses.replace(cfg, is_critic=True)
+        elif case == "small_vocab":
+            cfg = dataclasses.replace(cfg, vocab_size=64)
+        elif case == "head_in_another_dtype":
+            head = jnp.float32
+        assert fs.fused_sample_applies(
+            cfg, self._params(head), mesh=mesh, platform=platform) is want
+
+    def test_platform_defaults_to_the_first_device(self):
+        assert fs.fused_sample_applies(CFG, self._params(jnp.float32)) is False
+
+
+class TestKernelForms:
+    """The Pallas kernel (interpret mode) over row groups, vocabulary
+    blocks with a masked tail, and the two folds only a speculative
+    verify asks for."""
+
+    @pytest.mark.parametrize(
+        "R,V,block",
+        [pytest.param(72, 700, 256, id="nine_groups_of_8"),
+         pytest.param(64, 1024, 512, id="two_groups_of_32_no_tail"),
+         pytest.param(5, 130, None, id="padded_rows_block_from_shapes")],
+    )
+    def test_greedy_exact_and_lp_formula(self, R, V, block):
+        x, w, logits = _head_problem(R=R, E=32, V=V, seed=4)
+        temp = jnp.where(jnp.arange(R) % 3 == 0, 0.0, 0.8).astype(
+            jnp.float32)
+        greedy = temp <= 0.0
+        out = fs.fused_sample(
+            jax.random.key(9), x, w, temp, greedy, block_size=block,
+            use_pallas=True,
+        )
+        g = np.asarray(greedy)
+        ref = np.asarray(jnp.argmax(logits, -1))
+        tok = np.asarray(out["tokens"])
+        assert tok.shape == (R,) and (tok >= 0).all() and (tok < V).all()
+        assert np.array_equal(tok[g], ref[g])
+        assert np.array_equal(np.asarray(out["argmax"]), ref)
+        warped = np.asarray(logits) / np.maximum(
+            np.asarray(temp)[:, None], 1e-6)
+        lse = np.asarray(
+            jax.scipy.special.logsumexp(jnp.asarray(warped), axis=-1))
+        np.testing.assert_allclose(np.asarray(out["norm"]), lse, rtol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(out["logprobs"]), warped[np.arange(R), tok] - lse,
+            atol=1e-3,
+        )
+        # sampled rows are not all one token: the draws differ by row
+        assert len(set(tok[~g].tolist())) > 1
+
+    def test_folds_change_nothing_for_rows_that_use_neither(self):
+        """With the excluded / gathered folds built (every row passing
+        "none") the tokens and log-probs are those of the kernel built
+        without them; a row that does exclude its draw gets another."""
+        x, w, logits = _head_problem(R=16, E=32, V=500, seed=2)
+        R = x.shape[0]
+        temp = jnp.ones((R,), jnp.float32)
+        greedy = jnp.zeros((R,), bool)
+        args = (jax.random.key(5), x, w, temp, greedy)
+        kw = dict(block_size=128, use_pallas=True)
+        plain = fs.fused_sample(*args, **kw)
+        assert "gathered_lp" not in plain
+        gids = (jnp.arange(R) * 7) % 500
+        folded = fs.fused_sample(
+            *args, exclude=jnp.full((R,), -1, jnp.int32), gather_ids=gids,
+            **kw,
+        )
+        for k in ("tokens", "logprobs", "argmax", "norm"):
+            np.testing.assert_array_equal(
+                np.asarray(plain[k]), np.asarray(folded[k]), err_msg=k)
+        lp_all = np.asarray(jax.nn.log_softmax(logits, -1))
+        np.testing.assert_allclose(
+            np.asarray(folded["gathered_lp"]),
+            lp_all[np.arange(R), np.asarray(gids)], atol=1e-4,
+        )
+        excluded = fs.fused_sample(*args, exclude=plain["tokens"], **kw)
+        assert not np.any(
+            np.asarray(excluded["tokens"]) == np.asarray(plain["tokens"]))
+
+    def test_block_follows_from_the_shapes(self):
+        from areal_tpu.ops.pallas import fused_sample as fsk
+
+        # the five rollout cells: 2048 columns; a toy vocabulary: one
+        # lane tile; a head too wide for 2048 columns of it: fewer
+        for R, E, V in [(128, 1536, 151936), (64, 3584, 152064),
+                        (64, 2048, 50304), (256, 2048, 129280),
+                        (112, 2560, 151936)]:
+            assert fsk.block_columns(R, E, V, 2) == 2048
+        assert fsk.block_columns(8, 32, 100, 4) == 128
+        wide = fsk.block_columns(64, 16384, 152064, 2)
+        assert wide % 128 == 0 and 128 <= wide < 2048
+
+    def test_rows_are_independent_draws_of_the_marginal(self):
+        """256 rows that are ONE row: every call is 256 draws of one
+        distribution (the kernel's uniforms differ by row and column), so
+        a few calls give the chi-square of ``chip_smoke.py``'s check of the
+        chip's own uniform source."""
+        x1, w, logits = _head_problem(R=1, E=8, V=16, seed=1)
+        p = np.asarray(jax.nn.softmax(logits[0]))
+        R = 256
+        x = jnp.tile(x1, (R, 1))
+        f = jax.jit(lambda k: fs.fused_sample(
+            k, x, w, jnp.ones((R,)), jnp.zeros((R,), bool),
+            use_pallas=True,
+        )["tokens"])
+        counts = np.zeros(16)
+        for k in jax.random.split(jax.random.key(11), 40):
+            counts += np.bincount(np.asarray(f(k)), minlength=16)
+        n = counts.sum()
+        chi2 = float((((counts - n * p) ** 2) / (n * p)).sum())
+        assert chi2 < CHI2_CRIT, (chi2, counts)
+
+
 class TestDistribution:
     """Chi-square: the fused sampler's first-token marginal equals the
     softmax of the (warped / restricted) head output."""
@@ -293,7 +436,7 @@ class TestLogprobFastPath:
 
 class TestEngineFused:
     def test_greedy_fused_matches_reference(self, params, rng):
-        """The tentpole contract: AREAL_FUSED_SAMPLE greedy decode is
+        """The tentpole contract: the fused epilogue's greedy decode is
         token- and logprob-exact vs the materialized reference through
         the full engine."""
         prompts = _prompts(rng)
@@ -317,15 +460,18 @@ class TestEngineFused:
                 atol=1e-4,
             )
 
-    def test_env_knob_enables_fused(self, params, monkeypatch):
-        monkeypatch.setenv("AREAL_FUSED_SAMPLE", "1")
-        eng = _engine(params, max_slots=1)
-        assert eng.fused is True
+    @pytest.mark.parametrize("fused", [True, False])
+    def test_constructor_argument_overrides_the_rule(self, params, fused):
+        """On the CPU the rule says no; the argument pins either side."""
+        assert _engine(params, max_slots=1).fused is False
+        eng = _engine(params, fused=fused, max_slots=1)
+        assert eng.fused is fused
         eng.submit(GenRequest(
             rid="a", input_ids=[1, 2, 3], max_new_tokens=4, greedy=True,
         ))
         outs = eng.run_until_done(decode_steps=2)
         assert len(outs[0].output_ids) == 4
+        assert (eng.stats["fused_rows"] > 0) == fused
 
     def test_mixed_batch_fallback_rows_reproducible(self, params):
         """A top-p slot routes through the sorted fallback while greedy /

@@ -574,6 +574,43 @@ class TestEngineSpans:
             assert starts == sorted(starts)
             assert sum(k["dur_s"] for k in kids.values()) <= first["dur_s"]
 
+    @pytest.mark.parametrize("engine", ["fused", "fused_pipelined",
+                                        "materialised"])
+    def test_fused_rows_and_sampler_fallback_rows(self, params, rng, engine):
+        """A fused chunk says where its rows were sampled, rows x steps as
+        dispatched: ``fused_rows`` by the fused head-and-sample pass,
+        ``sampler_fallback_rows`` by the sorted path (a top-p row), on the
+        span and summed on ``engine.stats``; a materialised engine carries
+        neither attribute and counts 0."""
+        from areal_tpu.base import tracing
+
+        fused = engine != "materialised"
+        eng = GenerationEngine(
+            CFG, params, max_slots=4, max_seqlen=128, fused_sample=fused,
+            pipeline_chunks=engine == "fused_pipelined",
+        )
+        for i, kw in enumerate((
+                dict(greedy=True), dict(temperature=1.0),
+                dict(temperature=1.0, top_p=0.9))):
+            eng.submit(GenRequest(
+                rid=f"r{i}",
+                input_ids=[int(x) for x in rng.integers(1, 128, size=4 + i)],
+                max_new_tokens=20, **kw))
+        tracing.drain()
+        for _ in range(2):
+            eng.step(4)
+        attrs = [c["attrs"] for c, _ in _chunks_with_children(tracing.drain())]
+        assert len(attrs) == 2 and all(a["slots"] == 3 for a in attrs)
+        if fused:
+            assert [a["fused_rows"] for a in attrs] == [2 * 4] * 2
+            assert [a["sampler_fallback_rows"] for a in attrs] == [1 * 4] * 2
+        else:
+            assert not any(
+                "fused_rows" in a or "sampler_fallback_rows" in a
+                for a in attrs)
+        assert eng.stats["fused_rows"] == (16 if fused else 0)
+        assert eng.stats["sampler_fallback_rows"] == (8 if fused else 0)
+
     def test_resident_tokens_is_the_running_slots_kv(self, params, rng):
         """``resident_tokens`` at a chunk's dispatch = the KV positions
         its first decode step reads: prompt - 1 + generated, per slot."""

@@ -20,6 +20,7 @@ back and only produces warnings).
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,12 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
 
     for mod in (flash_attention, fused_sample, pl_paged, kv_page_write):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
+    # ... and the fused epilogue's dispatch (and the engine's rule) off the
+    # CPU they would see: a chunk built with ``fused=True`` ends in the
+    # kernel, as on the chip, not in the streamed XLA pass
+    from areal_tpu.ops import fused_sample as fused_ops
+
+    monkeypatch.setattr(fused_ops, "_platform", lambda: "tpu")
 
 
 def _spec(shape, dtype, sharding):
@@ -236,18 +243,59 @@ def test_int8_page64_turned_away_by_the_gate(compiled_kernels):
         )
 
 
-@pytest.mark.parametrize(
-    "V,E", [pytest.param(151936, 1536, id="1p5b"),
-            pytest.param(32768, 768, id="125m")],
-)
-def test_fused_sample_compiles(compiled_kernels, one_chip, V, E):
-    from areal_tpu.ops.pallas.fused_sample import fused_sample_pallas
+# the rollout cells' decode epilogue: slots x hidden x vocabulary
+FUSED_SAMPLE_CELLS = {
+    "cell1": (128, 1536, 151936),
+    "cell3": (64, 3584, 152064),
+    "olmoe": (64, 2048, 50304),
+    "joyai": (256, 2048, 129280),
+    "smallthinker": (112, 2560, 151936),
+}
 
-    R = 64
+
+@pytest.mark.parametrize(
+    "R,E,V",
+    [pytest.param(*shape, id=cell)
+     for cell, shape in FUSED_SAMPLE_CELLS.items()]
+    + [pytest.param(64, 768, 32768, id="125m")],
+)
+def test_fused_sample_compiles(compiled_kernels, one_chip, R, E, V):
+    """The head-and-sample kernel as the vanilla chunk calls it (no
+    excluded and no gathered token) at every rollout cell's ``(R, E, V)``:
+    a block of 2048 columns everywhere (3584 rows of bf16 are 14.7 MB a
+    buffer), the chip's PRNG for the uniforms."""
+    from areal_tpu.ops.pallas import fused_sample as fsk
+
+    assert fsk.block_columns(R, E, V, 2) == 2048
 
     def f(x, w, temperature, greedy):
-        return fused_sample_pallas(
+        return fsk.fused_sample_pallas(
             jax.random.key(0), x, w, temperature, greedy
+        )
+
+    text = _compile(
+        f,
+        _spec((R, E), jnp.bfloat16, one_chip),
+        _spec((E, V), jnp.bfloat16, one_chip),
+        _spec((R,), jnp.float32, one_chip),
+        _spec((R,), jnp.bool_, one_chip),
+    ).as_text()
+    assert "fused_sample" in text
+    assert f"[{R},{V}]" not in text
+
+
+def test_fused_sample_compiles_with_spec_folds(compiled_kernels, one_chip):
+    """The speculative verify's call: 64 slots x 5 positions, an excluded
+    and a gathered token a row (the two folds the vanilla chunk leaves
+    out)."""
+    from areal_tpu.ops.pallas.fused_sample import fused_sample_pallas
+
+    R, E, V = 320, 1536, 151936
+
+    def f(x, w, temperature, greedy, exclude, gather_ids):
+        return fused_sample_pallas(
+            jax.random.key(0), x, w, temperature, greedy,
+            exclude=exclude, gather_ids=gather_ids,
         )
 
     _compile(
@@ -256,7 +304,17 @@ def test_fused_sample_compiles(compiled_kernels, one_chip, V, E):
         _spec((E, V), jnp.bfloat16, one_chip),
         _spec((R,), jnp.float32, one_chip),
         _spec((R,), jnp.bool_, one_chip),
+        _spec((R,), jnp.int32, one_chip),
+        _spec((R,), jnp.int32, one_chip),
     )
+
+
+def _assert_fused_epilogue(text, B, V):
+    """A chunk program whose steps end in the fused kernel: the kernel is
+    in it, and nothing of the logits' shape is (no ``[B, V]`` buffer in
+    any dtype, so no head matmul for XLA to compute twice)."""
+    assert re.search(r"%fused_sample(\.\d+)? = ", text)
+    assert f"[{B},{V}]" not in text
 
 
 # ------------------------------------------------------------------ #
@@ -351,14 +409,22 @@ def test_kv_page_write_compiles(compiled_kernels, one_chip, cell, rows, chunk):
     assert mem.temp_size_in_bytes < 0.4e9 < pool_bytes
 
 
-@pytest.mark.parametrize("cell", ["cell1", "cell3", "olmoe"])
+@pytest.mark.parametrize(
+    "cell,fused",
+    [("cell1", True), ("cell3", True), ("olmoe", True),
+     # the materialised epilogue, which a mesh, a tied head and every
+     # platform but a TPU keep (``fused_sample_applies``)
+     ("cell3", False)],
+)
 def test_kv_cells_chunk_writes_the_pool_in_place(
-        compiled_kernels, one_chip, cell):
+        compiled_kernels, one_chip, cell, fused):
     """``jit_chunk`` of the three K/V rollout cells (16 decode steps at
     the cell's slots and table, the engine's own program with its state
     donated): ``paged_decode`` and ``kv_page_write`` are both in it, no
     scatter over the pool is, and arguments + temporaries leave no room
-    for a second pool (9.5 / 5.5 / 9.0 GB beside the weights)."""
+    for a second pool (9.5 / 5.5 / 9.0 GB beside the weights). As the cell
+    runs it the step ends in ``fused_sample`` and holds no ``[B, V]``
+    buffer."""
     import dataclasses
     import json
 
@@ -388,13 +454,19 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
     state = dataclasses.replace(
         jax.tree.map(spec, eng.state),
         cache=tfm.PagedKVCache(pages=_spec(pool, pages.dtype, one_chip)))
-    compiled = eng._chunk_fn(16, c["M"], 0, fused=False, with_topk=False).lower(
+    compiled = eng._chunk_fn(16, c["M"], 0, fused=fused, with_topk=False).lower(
         jax.tree.map(spec, shapes), state,
         _spec((c["B"], c["M"]), jnp.int32, one_chip),
         _spec((0,), jnp.int32, one_chip),
     ).compile()
     text = compiled.as_text()
     assert "paged_decode" in text and "kv_page_write" in text
+    assert eng.fused       # the rule, on what the fixture describes
+    if fused:
+        _assert_fused_epilogue(text, c["B"], cfg.vocab_size)
+    else:
+        assert not re.search(r"%fused_sample(\.\d+)? = ", text)
+        assert f"[{c['B']},{cfg.vocab_size}]" in text
     n_rows = int(np.prod(pool[:5]))
     assert f"bf16[{n_rows},128]" not in text     # the scatter's flat view
     mem = compiled.memory_analysis()
@@ -454,7 +526,7 @@ def test_joyai_engine_programs_compile(
         compiled_kernels, one_chip, joyai_engine, program):
     """``jit_chunk`` (16 decode steps over the latent pool at 256 slots and
     the full table: two scans, ``mla_decode``, the 256-expert dispatch, the
-    129k-vocabulary head), ``jit_extend`` (an admission wave of 8 x 128
+    129k-vocabulary head inside ``fused_sample``), ``jit_extend`` (an admission wave of 8 x 128
     tokens against the pool, which it reads and does not write) and
     ``jit_write`` (the wave's fresh latents into the pool, the one program
     every admission bucket and table width shares) for a described v5e,
@@ -471,7 +543,7 @@ def test_joyai_engine_programs_compile(
                    _spec((n,), jnp.int32, one_chip),
                    _spec((n,), jnp.int32, one_chip))
     if program == "jit_chunk":
-        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        fn = eng._chunk_fn(16, M, 0, fused=True, with_topk=False)
         args = (params, state, _spec((B, M), jnp.int32, one_chip),
                 _spec((0,), jnp.int32, one_chip))
     elif program == "jit_extend":
@@ -491,6 +563,10 @@ def test_joyai_engine_programs_compile(
     # fresh latents reach the pool by the tile-copy kernel, in the chunk
     # and in admission's write program; admission's layers hold no write
     assert ("kv_page_write" in text) == (program != "jit_extend")
+    if program == "jit_chunk":
+        # the step ends in the fused kernel: no [256, 129280] logits, so
+        # no head for XLA to rematerialise at the memory limit
+        _assert_fused_epilogue(text, B, eng.cfg.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
 
@@ -554,7 +630,7 @@ def test_hybrid_engine_programs_compile(
     """``jit_chunk`` (16 decode steps at 112 slots and the full table of
     128 pages in each of the four kinds: one scan over the two periods,
     both programs of the paged kernel, the 64-expert dispatch, the
-    152k-vocabulary head), ``jit_extend`` (an admission wave of 8 x 128
+    152k-vocabulary head inside ``fused_sample``), ``jit_extend`` (an admission wave of 8 x 128
     tokens against the pool) and ``jit_write`` (the wave's fresh K/V into
     every kind's pages) for a described v5e, beside 7.93 GB of weights and
     the cell's pool of 14,495 pages (7.6 GB): arguments + temporaries
@@ -585,7 +661,7 @@ def test_hybrid_engine_programs_compile(
                    _spec((n,), jnp.int32, one_chip),
                    _spec((n,), jnp.int32, one_chip))
     if program == "jit_chunk":
-        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        fn = eng._chunk_fn(16, M, 0, fused=True, with_topk=False)
         args = (params, state, _spec((4, B, M), jnp.int32, one_chip),
                 _spec((0,), jnp.int32, one_chip))
     elif program == "jit_extend":
@@ -603,13 +679,13 @@ def test_hybrid_engine_programs_compile(
     # the full layers' program and the window layers', by the names of
     # their instructions (a file name of an earlier compile can turn up in
     # a module's table of source files)
-    import re
-
     assert bool(re.search(r"%paged_decode_window(\.\d+)? = ", text)) == (
         program == "jit_chunk")
     assert bool(re.search(r"%paged_decode(\.\d+)? = ", text)) == (
         program == "jit_chunk")
     assert bool(re.search(r"%kv_page_write(\.\d+)? = ", text)) == (
         program != "jit_extend")
+    if program == "jit_chunk":
+        _assert_fused_epilogue(text, B, eng.cfg.vocab_size)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
