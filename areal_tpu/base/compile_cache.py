@@ -17,18 +17,23 @@ tiny, and XLA:CPU ties a cached executable to the host's CPU features.
 
 Importing this module does not import JAX, and :func:`configure` never
 initialises a backend — the launcher's JAX-free parent calls it too.
+Where JAX is already imported it also starts the process's compile
+listener (``tracing.listen_for_compiles``), so what the cache hit and
+missed is counted from the first program on; where it is not, the
+engines' constructors do.
 """
 
 import os
 import sys
 from typing import Optional
 
-from areal_tpu.base import constants
+from areal_tpu.base import constants, tracing
 
 
 def configure() -> Optional[str]:
     """Returns the directory the cache lives in, or None when this run
     caches nothing (held to the CPU, variable unset)."""
+    tracing.listen_for_compiles()   # nothing where JAX is not imported
     if constants.env_str(constants.COMPILE_CACHE_ENV) is not None:
         return constants.compile_cache_dir()    # JAX reads it itself
     if constants.jax_platforms().startswith("cpu"):

@@ -490,9 +490,8 @@ GW_BROWNOUT_TRANSITIONS = "gw/brownout_transitions"  # ladder level changes
 # --------------------------------------------------------------------- #
 # Distributed tracing namespace (``trace/``, docs/observability.md
 # "Distributed tracing"): the span ring / flush plane plus flight-
-# recorder dumps. ``trace/span_s`` is a histogram over every recorded
-# span's duration (one distribution across names — per-name wall time
-# already rides the ``<name>_s`` sums ``tracing.span`` has always kept).
+# recorder dumps. Per-name wall time rides the ``<name>_s`` / ``<name>_n``
+# sums ``tracing.span`` keeps.
 # --------------------------------------------------------------------- #
 
 TRACE_SPANS = "trace/spans"                 # spans recorded into the ring
@@ -501,7 +500,22 @@ TRACE_DROPPED = "trace/dropped"             # ring overwrote an unflushed span
 TRACE_FLUSHES = "trace/flushes"             # ring drains to the fileroot
 TRACE_FLUSHED_SPANS = "trace/flushed_spans" # spans written by those drains
 TRACE_FLIGHT_DUMPS = "trace/flight_dumps"   # flight-recorder dumps written
-TRACE_SPAN_S = "trace/span_s"               # histogram: recorded span durations
+
+# --------------------------------------------------------------------- #
+# Compile namespace (``compile/``, docs/observability.md "What a start
+# cost"): filled by ``tracing.listen_for_compiles``'s listeners, once for
+# every executable JAX builds or loads from its persistent cache. All
+# sums, so they ride the telemetry plane and ``/metrics_json``.
+# --------------------------------------------------------------------- #
+
+COMPILE_PROGRAMS = "compile/programs"         # executables built or loaded
+COMPILE_TRACE_S = "compile/trace_s"           # Python tracing, top level only
+COMPILE_LOWER_S = "compile/lower_s"           # jaxpr -> MLIR module
+COMPILE_BACKEND_S = "compile/backend_s"       # XLA compile, or the cache load
+COMPILE_CACHE_HITS = "compile/cache_hits"     # loaded from the persistent cache
+COMPILE_CACHE_MISSES = "compile/cache_misses" # compiled, then written to it
+COMPILE_CACHE_LOAD_S = "compile/cache_load_s" # reading + deserialising hits
+COMPILE_CACHE_SAVED_S = "compile/cache_saved_s"  # compile time the hits saved
 
 
 # Fraction edges for the pool-occupancy histogram: occupancy lives in
@@ -548,7 +562,6 @@ METRIC_KINDS: Dict[str, str] = {
     GW_QUEUE_WAIT_S: KIND_HISTOGRAM,
     GW_TTFT_S: KIND_HISTOGRAM,
     GW_BROWNOUT_LEVEL: KIND_GAUGE,
-    TRACE_SPAN_S: KIND_HISTOGRAM,
 }
 
 # Non-default bucket edges per histogram key (default: the log-spaced

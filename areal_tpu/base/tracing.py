@@ -30,6 +30,12 @@ every worker's flushes into one Chrome-``trace_event`` timeline and
 tree. The ring additionally feeds the crash flight recorder
 (``system/worker_base.FlightRecorder``) its recent-span evidence.
 
+**Compile listener** (:func:`listen_for_compiles`): ONE pair of
+``jax.monitoring`` listeners a process turns every executable JAX builds
+or loads from its cache into the ``compile/*`` counters and, with the
+span plane on, one ``compile/program`` record under the span that paid
+for it (docs/observability.md "What a start cost").
+
 Context flows through :mod:`contextvars`, so one event loop serving many
 concurrent requests keeps each request's trace identity isolated without
 any per-request plumbing beyond the ``with tracing.activate(...)`` at
@@ -53,7 +59,9 @@ from areal_tpu.base import metrics as metrics_mod
 # Live-span registry: every open tracing.span is visible here, so the hang
 # watchdog (system/worker_base.HangWatchdog) can report WHAT a wedged worker
 # was doing (e.g. "train_pipe/dispatch open for 1800s") alongside raw thread
-# stacks — without any profiler attached.
+# stacks — without any profiler attached. A record holds its span's attrs
+# dict too: the compile listener bumps ``compiled`` / ``compile_s`` on the
+# innermost span open where a program was built.
 _live_lock = threading.Lock()
 _live: List[dict] = []
 
@@ -258,9 +266,20 @@ def activate(
 # --------------------------------------------------------------------- #
 
 
+def _new_record(name: str, t0: float, attrs: Dict[str, object]) -> dict:
+    """A record that joins the active trace: child of the context's
+    current span, or the root of a fresh trace."""
+    c = _ctx.get()
+    return {
+        "name": name, "t0": t0, "thread": threading.current_thread().name,
+        "trace_id": c[0] if c else new_trace_id(),
+        "parent_id": (c[1] or None) if c else None,
+        "span_id": new_span_id(), "attrs": attrs,
+    }
+
+
 def _record_end(
     rec: dict, wall_end: float, dur: float, exc: Optional[BaseException],
-    attrs: Dict[str, object],
 ) -> None:
     out = {
         "name": rec["name"],
@@ -278,8 +297,8 @@ def _record_end(
     }
     if exc is not None:
         out["exc"] = type(exc).__name__
-    if attrs:
-        out["attrs"] = attrs
+    if rec["attrs"]:
+        out["attrs"] = rec["attrs"]
     cap = constants.trace_ring_size()
     with _ring_lock:
         while len(_ring) >= cap:
@@ -290,7 +309,6 @@ def _record_end(
     metrics_mod.counters.add(metrics_mod.TRACE_SPANS)
     if exc is not None:
         metrics_mod.counters.add(metrics_mod.TRACE_SPAN_ERRORS)
-    metrics_mod.counters.observe(metrics_mod.TRACE_SPAN_S, dur)
 
 
 @contextlib.contextmanager
@@ -321,13 +339,7 @@ def span(name: str, **attrs):
             metrics_mod.counters.add(f"{name}_n", 1.0)
         return
     t0 = time.perf_counter()
-    c = _ctx.get()
-    rec = {
-        "name": name, "t0": t0, "thread": threading.current_thread().name,
-        "trace_id": c[0] if c else new_trace_id(),
-        "parent_id": (c[1] or None) if c else None,
-        "span_id": new_span_id(),
-    }
+    rec = _new_record(name, t0, attrs)
     ctx_tok = _ctx.set((rec["trace_id"], rec["span_id"]))
     q = _qid.get()
     if q is not None:
@@ -351,7 +363,112 @@ def span(name: str, **attrs):
         dt = time.perf_counter() - t0
         metrics_mod.counters.add(f"{name}_s", dt)
         metrics_mod.counters.add(f"{name}_n", 1.0)
-        _record_end(rec, time.time(), dt, exc, attrs)
+        _record_end(rec, time.time(), dt, exc)
+
+
+# --------------------------------------------------------------------- #
+# Compile listener: what building programs cost, and which span paid
+# --------------------------------------------------------------------- #
+
+_JAX_COMPILE = "/jax/core/compile/"
+_JAX_CACHE = "/jax/compilation_cache/"
+COMPILE_RECORD = "compile/program"
+
+_listen_lock = threading.Lock()
+_listening = False
+# The program this thread is building: JAX reports its stages one event
+# each, in order, on the thread that compiles — ``traces`` {function name:
+# seconds}, ``lower`` (program name, seconds), ``cache_hit`` — and
+# ``backend_compile_duration`` closes it.
+_building = threading.local()
+
+
+def listen_for_compiles() -> bool:
+    """Register the process's ONE pair of ``jax.monitoring`` listeners
+    (idempotent: two engines in a process share it). Never what imports
+    JAX: before JAX is imported it does nothing and returns False, and
+    the next caller tries again. Called by both engines' constructors
+    before their first device work, by the workers at start, and by
+    ``compile_cache.configure`` where JAX is already there. The listeners
+    run only when JAX traces, lowers or compiles: a step that builds
+    nothing never reaches them."""
+    global _listening
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    with _listen_lock:
+        if not _listening:
+            import jax.monitoring
+
+            jax.monitoring.register_event_listener(_on_jax_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration
+            )
+            _listening = True
+    return True
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    if event == _JAX_CACHE + "cache_hits":
+        _building.cache_hit = True
+        metrics_mod.counters.add(metrics_mod.COMPILE_CACHE_HITS)
+    elif event == _JAX_CACHE + "cache_misses":
+        _building.cache_hit = False
+        metrics_mod.counters.add(metrics_mod.COMPILE_CACHE_MISSES)
+
+
+def _on_jax_duration(event: str, duration: float, fun_name: str = "",
+                     **_kw) -> None:
+    if event == _JAX_COMPILE + "jaxpr_trace_duration":
+        # a function that calls other jitted functions reports theirs
+        # first and its own, which contains them, last: kept by name, the
+        # program takes the one of its own name and never the sum
+        _building.__dict__.setdefault("traces", {})[fun_name] = duration
+    elif event == _JAX_COMPILE + "jaxpr_to_mlir_module_duration":
+        _building.lower = (fun_name, duration)
+    elif event == _JAX_CACHE + "cache_retrieval_time_sec":
+        metrics_mod.counters.add(metrics_mod.COMPILE_CACHE_LOAD_S, duration)
+    elif event == _JAX_CACHE + "compile_time_saved_sec":
+        metrics_mod.counters.add(metrics_mod.COMPILE_CACHE_SAVED_S, duration)
+    elif event == _JAX_COMPILE + "backend_compile_duration":
+        _program_built(fun_name, duration)
+
+
+def _program_built(fun_name: str, backend_s: float) -> None:
+    """One executable was built, or loaded from the persistent cache
+    (``fun_name`` is JAX's, ``jit(chunk)``): counters always; with the
+    span plane on, one ``compile/program`` record, child of the innermost
+    span open on this thread, whose ``compiled`` / ``compile_s`` it
+    bumps."""
+    built = _building.__dict__
+    traces = built.pop("traces", {})
+    lower_name, lower_s = built.pop("lower", ("", 0.0))
+    if lower_name != fun_name:      # lowered earlier, by other hands
+        lower_s = 0.0
+    # "jit(chunk)" was traced as "chunk"
+    trace_s = traces.get(fun_name[fun_name.find("(") + 1:-1], 0.0)
+    cache_hit = built.pop("cache_hit", None)    # None: no cache consulted
+    add = metrics_mod.counters.add
+    add(metrics_mod.COMPILE_PROGRAMS)
+    add(metrics_mod.COMPILE_TRACE_S, trace_s)
+    add(metrics_mod.COMPILE_LOWER_S, lower_s)
+    add(metrics_mod.COMPILE_BACKEND_S, backend_s)
+    if not spans_enabled():
+        return
+    dur = trace_s + lower_s + backend_s
+    rec = _new_record(COMPILE_RECORD, time.perf_counter() - dur, {
+        "fun_name": fun_name, "trace_s": trace_s, "lower_s": lower_s,
+        "backend_s": backend_s, "cache_hit": cache_hit,
+    })
+    if rec["parent_id"] is not None:
+        with _live_lock:
+            for r in reversed(_live):
+                if r["span_id"] == rec["parent_id"]:
+                    a = r["attrs"]
+                    a["compiled"] = a.get("compiled", 0) + 1
+                    a["compile_s"] = a.get("compile_s", 0.0) + dur
+                    break
+    _record_end(rec, time.time(), dur, None)
 
 
 # --------------------------------------------------------------------- #

@@ -272,454 +272,468 @@ class GenerationEngine:
         spec_k_adapt: Optional[bool] = None,
         record_routing: bool = False,
     ):
-        self.cfg = cfg
-        self.mesh = mesh
-        # MoE models: every vanilla chunk returns a routing census with its
-        # harvest flags (``_fold_chunk_aux``); ``record_routing`` also keeps
-        # each output token's chosen experts for ``GenOutput``
-        self._moe = cfg.mlp_type == "moe"
-        if record_routing and not self._moe:
-            raise ValueError("record_routing: the model has no router")
-        self._decode_use_pallas: Optional[bool] = None
-        # KV-pool storage dtype (docs/performance.md "KV quantization"):
-        # explicit argument > cfg.kv_dtype > AREAL_KV_DTYPE > serving dtype
-        kd = kv_dtype if kv_dtype is not None else (
-            cfg.kv_dtype if cfg.kv_dtype is not None else constants.kv_dtype()
-        )
-        self.kv_dtype = _resolve_kv_dtype(kd, cfg.dtype)
-        self.kv_quantized = self.kv_dtype == "int8"
-        if cfg.mla is not None:
-            # what the latent pool does not do is refused, not approximated
-            if self.kv_quantized:
+        # one listener a process, live before the first device work below
+        tracing.listen_for_compiles()
+        with tracing.span("gen_engine/start", max_slots=max_slots) as start:
+            self.cfg = cfg
+            self.mesh = mesh
+            # MoE models: every vanilla chunk returns a routing census with its
+            # harvest flags (``_fold_chunk_aux``); ``record_routing`` also keeps
+            # each output token's chosen experts for ``GenOutput``
+            self._moe = cfg.mlp_type == "moe"
+            if record_routing and not self._moe:
+                raise ValueError("record_routing: the model has no router")
+            self._decode_use_pallas: Optional[bool] = None
+            # KV-pool storage dtype (docs/performance.md "KV quantization"):
+            # explicit argument > cfg.kv_dtype > AREAL_KV_DTYPE > serving dtype
+            kd = kv_dtype if kv_dtype is not None else (
+                cfg.kv_dtype if cfg.kv_dtype is not None else constants.kv_dtype()
+            )
+            self.kv_dtype = _resolve_kv_dtype(kd, cfg.dtype)
+            self.kv_quantized = self.kv_dtype == "int8"
+            if cfg.mla is not None:
+                # what the latent pool does not do is refused, not approximated
+                if self.kv_quantized:
+                    raise NotImplementedError(
+                        "latent attention: the page pool holds latents in the "
+                        "serving dtype; an int8 pool is not supported"
+                    )
+                if mesh is not None and mesh.shape.get("model", 1) > 1:
+                    raise NotImplementedError(
+                        "latent attention: a latent page has no head axis to "
+                        "shard; tensor-parallel serving is not supported"
+                    )
+            # Drafter resolution happens BEFORE device-state construction: a
+            # TransformerDrafter adds a draft param tree and a draft KV pool
+            # to everything below (shardings, state pytree, jitted programs).
+            # Explicit argument > AREAL_SPEC_DRAFT_MODEL checkpoint > the
+            # free self-drafting n-gram baseline. The env-knob checkpoint is
+            # only loaded when spec decode is actually on: a draft model is
+            # real HBM (pool + params) and a per-vanilla-step maintenance
+            # sweep, which an engine that never speculates must not pay just
+            # because a fleet-wide env var is set. An EXPLICIT drafter
+            # argument is kept regardless — that caller may toggle spec on
+            # later, and the pool must exist in the state pytree from
+            # construction.
+            spec_on = (
+                spec_decode
+                if spec_decode is not None
+                else constants.spec_decode_enabled()
+            )
+            if drafter is None:
+                draft_path = constants.spec_draft_model()
+                if draft_path and spec_on:
+                    with tracing.span("gen_engine/start/draft_model"):
+                        drafter = TransformerDrafter.from_hf(
+                            draft_path,
+                            kv_dtype=constants.spec_draft_kv_dtype(),
+                        )
+                elif draft_path:
+                    logger.warning(
+                        "%s is set but spec decode is disabled on this engine; "
+                        "not loading the draft model (enable %s or pass "
+                        "spec_decode=True to serve it)",
+                        constants.SPEC_DRAFT_MODEL_ENV,
+                        constants.SPEC_DECODE_ENV,
+                    )
+            self.drafter: Drafter = (
+                drafter if drafter is not None else NGramDrafter()
+            )
+            if not getattr(self.drafter, "deterministic", True) and not getattr(
+                self.drafter, "provides_q_logprobs", False
+            ):
+                # sampled proposals without a proposal distribution cannot be
+                # rejection-sampled correctly — accepting them would silently
+                # bias generation toward the drafter (PPO corruption). Sampled
+                # drafters must declare provides_q_logprobs and return their
+                # q; the general-q branch of spec_rejection_sample handles
+                # the rest.
                 raise NotImplementedError(
-                    "latent attention: the page pool holds latents in the "
-                    "serving dtype; an int8 pool is not supported"
+                    "non-deterministic drafters need their proposal logprobs "
+                    "threaded into spec_rejection_sample (q_logprobs): set "
+                    "provides_q_logprobs = True and return them, or use a "
+                    "deterministic (one-hot) drafter"
                 )
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
+            self._draft: Optional[TransformerDrafter] = (
+                self.drafter if isinstance(self.drafter, TransformerDrafter)
+                else None
+            )
+            if (
+                not getattr(self.drafter, "deterministic", True)
+                and self._draft is None
+            ):
+                # the q_logprobs contract is wired through the model-drafter
+                # interface only: a sampled drafter outside it would take the
+                # one-hot propose() path and its q would silently never reach
+                # the rejection sampler
                 raise NotImplementedError(
-                    "latent attention: a latent page has no head axis to "
-                    "shard; tensor-parallel serving is not supported"
+                    "sampled drafters are wired through the TransformerDrafter "
+                    "propose_model interface (draft params + paged KV inside "
+                    "the jitted chunk); subclass TransformerDrafter to "
+                    "customize proposals"
                 )
-        # Drafter resolution happens BEFORE device-state construction: a
-        # TransformerDrafter adds a draft param tree and a draft KV pool
-        # to everything below (shardings, state pytree, jitted programs).
-        # Explicit argument > AREAL_SPEC_DRAFT_MODEL checkpoint > the
-        # free self-drafting n-gram baseline. The env-knob checkpoint is
-        # only loaded when spec decode is actually on: a draft model is
-        # real HBM (pool + params) and a per-vanilla-step maintenance
-        # sweep, which an engine that never speculates must not pay just
-        # because a fleet-wide env var is set. An EXPLICIT drafter
-        # argument is kept regardless — that caller may toggle spec on
-        # later, and the pool must exist in the state pytree from
-        # construction.
-        spec_on = (
-            spec_decode
-            if spec_decode is not None
-            else constants.spec_decode_enabled()
-        )
-        if drafter is None:
-            draft_path = constants.spec_draft_model()
-            if draft_path and spec_on:
-                drafter = TransformerDrafter.from_hf(
-                    draft_path, kv_dtype=constants.spec_draft_kv_dtype()
+            self.draft_cfg: Optional[ModelConfig] = None
+            self.draft_kv_dtype: Optional[str] = None
+            self.draft_kv_quantized = False
+            self.draft_version = 0
+            if self._draft is not None:
+                dcfg = self._draft.cfg
+                if dcfg.vocab_size != cfg.vocab_size:
+                    raise ValueError(
+                        f"draft model vocab ({dcfg.vocab_size}) must match the "
+                        f"serving model's ({cfg.vocab_size}) — proposed tokens "
+                        "are scored by the target verbatim"
+                    )
+                if dcfg.dtype != cfg.dtype:
+                    # serve the draft in the target's activation dtype (a
+                    # float32 CPU test config must not silently run a bf16
+                    # draft next to a float32 target)
+                    dcfg = dataclasses.replace(dcfg, dtype=cfg.dtype)
+                self.draft_cfg = dcfg
+                # write the coerced cfg back: propose_model's forward runs
+                # under the DRAFTER's cfg, and leaving the checkpoint dtype
+                # there would compute spec-chunk proposals in one dtype while
+                # the vanilla chunk's maintenance step (draft_cfg) writes KV
+                # in another — the silent mismatch the coercion exists to
+                # prevent
+                self._draft.cfg = dcfg
+                dkd = (
+                    self._draft.kv_dtype
+                    if self._draft.kv_dtype is not None
+                    else constants.spec_draft_kv_dtype()
                 )
-            elif draft_path:
-                logger.warning(
-                    "%s is set but spec decode is disabled on this engine; "
-                    "not loading the draft model (enable %s or pass "
-                    "spec_decode=True to serve it)",
-                    constants.SPEC_DRAFT_MODEL_ENV,
-                    constants.SPEC_DECODE_ENV,
-                )
-        self.drafter: Drafter = drafter if drafter is not None else NGramDrafter()
-        if not getattr(self.drafter, "deterministic", True) and not getattr(
-            self.drafter, "provides_q_logprobs", False
-        ):
-            # sampled proposals without a proposal distribution cannot be
-            # rejection-sampled correctly — accepting them would silently
-            # bias generation toward the drafter (PPO corruption). Sampled
-            # drafters must declare provides_q_logprobs and return their
-            # q; the general-q branch of spec_rejection_sample handles
-            # the rest.
-            raise NotImplementedError(
-                "non-deterministic drafters need their proposal logprobs "
-                "threaded into spec_rejection_sample (q_logprobs): set "
-                "provides_q_logprobs = True and return them, or use a "
-                "deterministic (one-hot) drafter"
-            )
-        self._draft: Optional[TransformerDrafter] = (
-            self.drafter if isinstance(self.drafter, TransformerDrafter)
-            else None
-        )
-        if (
-            not getattr(self.drafter, "deterministic", True)
-            and self._draft is None
-        ):
-            # the q_logprobs contract is wired through the model-drafter
-            # interface only: a sampled drafter outside it would take the
-            # one-hot propose() path and its q would silently never reach
-            # the rejection sampler
-            raise NotImplementedError(
-                "sampled drafters are wired through the TransformerDrafter "
-                "propose_model interface (draft params + paged KV inside "
-                "the jitted chunk); subclass TransformerDrafter to "
-                "customize proposals"
-            )
-        self.draft_cfg: Optional[ModelConfig] = None
-        self.draft_kv_dtype: Optional[str] = None
-        self.draft_kv_quantized = False
-        self.draft_version = 0
-        if self._draft is not None:
-            dcfg = self._draft.cfg
-            if dcfg.vocab_size != cfg.vocab_size:
-                raise ValueError(
-                    f"draft model vocab ({dcfg.vocab_size}) must match the "
-                    f"serving model's ({cfg.vocab_size}) — proposed tokens "
-                    "are scored by the target verbatim"
-                )
-            if dcfg.dtype != cfg.dtype:
-                # serve the draft in the target's activation dtype (a
-                # float32 CPU test config must not silently run a bf16
-                # draft next to a float32 target)
-                dcfg = dataclasses.replace(dcfg, dtype=cfg.dtype)
-            self.draft_cfg = dcfg
-            # write the coerced cfg back: propose_model's forward runs
-            # under the DRAFTER's cfg, and leaving the checkpoint dtype
-            # there would compute spec-chunk proposals in one dtype while
-            # the vanilla chunk's maintenance step (draft_cfg) writes KV
-            # in another — the silent mismatch the coercion exists to
-            # prevent
-            self._draft.cfg = dcfg
-            dkd = (
-                self._draft.kv_dtype
-                if self._draft.kv_dtype is not None
-                else constants.spec_draft_kv_dtype()
-            )
-            self.draft_kv_dtype = _resolve_kv_dtype(dkd, dcfg.dtype)
-            self.draft_kv_quantized = self.draft_kv_dtype == "int8"
-        if mesh is not None:
-            if "model" not in mesh.axis_names:
-                raise ValueError(
-                    f"generation mesh needs a 'model' axis, got {mesh.axis_names}"
-                )
-            tp = mesh.shape["model"]
-            # bare pallas_call has no GSPMD partitioning rule, so >1-way
-            # 'model' serving routes the decode kernel through shard_map
-            # over the kv-head axis (ops/paged_attention.py) — r5, replaces
-            # the r3 XLA-gather pin; _decode_use_pallas stays None (auto)
-            from areal_tpu.parallel.mesh import check_tp_divisibility
+                self.draft_kv_dtype = _resolve_kv_dtype(dkd, dcfg.dtype)
+                self.draft_kv_quantized = self.draft_kv_dtype == "int8"
+            if mesh is not None:
+                if "model" not in mesh.axis_names:
+                    raise ValueError(
+                        "generation mesh needs a 'model' axis, got "
+                        f"{mesh.axis_names}"
+                    )
+                tp = mesh.shape["model"]
+                # bare pallas_call has no GSPMD partitioning rule, so >1-way
+                # 'model' serving routes the decode kernel through shard_map
+                # over the kv-head axis (ops/paged_attention.py) — r5, replaces
+                # the r3 XLA-gather pin; _decode_use_pallas stays None (auto)
+                from areal_tpu.parallel.mesh import check_tp_divisibility
 
-            check_tp_divisibility(cfg, tp, role="generation")
-            if self.draft_cfg is not None:
-                check_tp_divisibility(self.draft_cfg, tp, role="draft model")
-            self._repl = NamedSharding(mesh, P())
-            # pool [L, P, 2, Hkv, page, D]: shard the kv-head dim (a latent
-            # pool's is 1 and the mesh's model axis too, checked above); the
-            # int8 pool's scales [L, P, 2, Hkv, page] extend the same
-            # Hkv-axis TP split (scales are per kv head, so each model
-            # shard holds exactly its local heads' scales)
-            self._pages_sh = NamedSharding(
-                mesh, P(None, None, None, "model", None, None)
-            )
-            self._scales_sh = NamedSharding(
-                mesh, P(None, None, None, "model", None)
-            )
-            from areal_tpu.parallel.mesh import param_shardings
-
-            self._param_sh = param_shardings(
-                mesh, tfm.param_logical_axes(cfg), GEN_RULES
-            )
-            if self.draft_cfg is not None:
-                # the draft shards through the SAME logical-axis rules:
-                # heads/mlp/vocab split on `model`, embed replicated —
-                # its psums ride the same ICI the target's do
-                self._draft_param_sh = param_shardings(
-                    mesh, tfm.param_logical_axes(self.draft_cfg), GEN_RULES
+                check_tp_divisibility(cfg, tp, role="generation")
+                if self.draft_cfg is not None:
+                    check_tp_divisibility(self.draft_cfg, tp, role="draft model")
+                self._repl = NamedSharding(mesh, P())
+                # pool [L, P, 2, Hkv, page, D]: shard the kv-head dim (a latent
+                # pool's is 1 and the mesh's model axis too, checked above); the
+                # int8 pool's scales [L, P, 2, Hkv, page] extend the same
+                # Hkv-axis TP split (scales are per kv head, so each model
+                # shard holds exactly its local heads' scales)
+                self._pages_sh = NamedSharding(
+                    mesh, P(None, None, None, "model", None, None)
                 )
-        self.params = self.prepare_params(params)
-        self.draft_params = (
-            self._prepare_params_for(
-                self._draft.params, self.draft_cfg.dtype,
-                self._draft_param_sh if mesh is not None else None,
-            )
-            if self._draft is not None
-            else None
-        )
-        self.B = max_slots
-        self.page = page_size
-        self.M = -(-max_seqlen // page_size)      # table width (pages/slot)
-        self.S = self.M * page_size
-        self.G = max_new_tokens_cap
-        self.version = 0
-        # prefill streams through [n_rows, admit_chunk] extend programs;
-        # bigger chunks amortize the per-chunk attention over resident KV
-        # (31.5k prompt at chunk 128 = 246 waves each re-reading the whole
-        # prefix; at 2048 = 16 waves) at the cost of padding short prompts
-        # up to one chunk. Default: one page (exact, best for short prompts).
-        if admit_chunk_tokens is None:
-            self.admit_chunk = page_size
-        else:
-            self.admit_chunk = max(
-                page_size, -(-admit_chunk_tokens // page_size) * page_size
-            )
-        self.admit_buckets = sorted(admit_buckets)
-        self.global_stop_ids = list(stop_token_ids)
-        self.max_stop_ids = 8
-        self.enable_prefix_cache = enable_prefix_cache
-        # dense-equivalent pool by default, sized at the SERVING-dtype HBM
-        # budget: a quantized pool's smaller elements buy more pages for
-        # the same bytes (int8 under bf16 serving = 2x n_pages — the whole
-        # point: more resident slots/longer prefixes at fixed HBM), never
-        # a smaller footprint by surprise. Pass n_pages to cap bytes.
-        bytes_ratio = jnp.dtype(cfg.dtype).itemsize if self.kv_quantized else 1
-        # layer kinds: the sliding window of each position of the model's
-        # period (None: full attention); one page table a position
-        self._windows = [w for w, _ in cfg.layer_kinds]
-        self._windowed = any(w is not None for w in self._windows)
-        K = len(self._windows)
-        if self._draft is not None and (
-            K > 1 or self.draft_cfg.period > 1
-        ):
-            raise NotImplementedError(
-                "layer kinds: a draft model shares the target's page "
-                "tables, which a model with a period of kinds has several of"
-            )
-        self.n_pages = (
-            n_pages if n_pages is not None
-            else self.B * self.M * bytes_ratio * K
-        )
-        self.pool = PagePool(self.n_pages, page_size)
-        self.prefix = PrefixRegistry(self.pool, self._windows)
-        # positions a dispatch may run ahead of the host's lengths: the
-        # chunk's tokens and, in pipelined mode, the chunk still in flight,
-        # at ``step``'s default of 16 decode steps (a longer chunk takes
-        # what it needs beyond that from the pool at large, or is refused:
-        # ``_roll_windows``). Admission needs none: a prompt's pages are
-        # all taken before its chunks run
-        self._lookahead = 2 * 16 * (
-            (spec_k or constants.spec_k()) + 1 if spec_on else 1)
-        # the most pages a slot holds in a window kind at once: positions
-        # ``[n + 1 - window, n + lookahead)`` wherever they lie in their
-        # pages
-        self._window_claim = [
-            None if w is None
-            else (w + self._lookahead - 2) // page_size + 2
-            for w in self._windows
-        ]
+                self._scales_sh = NamedSharding(
+                    mesh, P(None, None, None, "model", None)
+                )
+                from areal_tpu.parallel.mesh import param_shardings
 
-        def make_state() -> GenState:
-            return GenState(
-                cache=tfm.PagedKVCache.empty(
-                    cfg, self.n_pages, page_size,
-                    kv_dtype="int8" if self.kv_quantized else None,
-                ),
-                lens=jnp.zeros((self.B,), jnp.int32),
-                last_tokens=jnp.zeros((self.B,), jnp.int32),
-                active=jnp.zeros((self.B,), bool),
-                n_gen=jnp.zeros((self.B,), jnp.int32),
-                min_gen=jnp.zeros((self.B,), jnp.int32),
-                max_gen=jnp.zeros((self.B,), jnp.int32),
-                stop_ids=jnp.full((self.B, self.max_stop_ids), -1, jnp.int32),
-                out_tokens=jnp.zeros((self.B, self.G), jnp.int32),
-                out_logprobs=jnp.zeros((self.B, self.G), jnp.float32),
-                ctx_tokens=jnp.zeros((self.B, self.S), jnp.int32),
-                fallback_token=jnp.zeros((self.B,), jnp.int32),
-                sp=SamplingParams.filled(self.B),
-                rng=jax.random.key(seed),
-                # the draft pool mirrors the target pool's page count so
-                # one page index addresses both (lockstep alloc/free)
-                draft_cache=(
-                    tfm.PagedKVCache.empty(
-                        self.draft_cfg, self.n_pages, page_size,
-                        kv_dtype="int8" if self.draft_kv_quantized else None,
+                self._param_sh = param_shardings(
+                    mesh, tfm.param_logical_axes(cfg), GEN_RULES
+                )
+                if self.draft_cfg is not None:
+                    # the draft shards through the SAME logical-axis rules:
+                    # heads/mlp/vocab split on `model`, embed replicated —
+                    # its psums ride the same ICI the target's do
+                    self._draft_param_sh = param_shardings(
+                        mesh, tfm.param_logical_axes(self.draft_cfg), GEN_RULES
+                    )
+            with tracing.span("gen_engine/start/params"):
+                self.params = self.prepare_params(params)
+                self.draft_params = (
+                    self._prepare_params_for(
+                        self._draft.params, self.draft_cfg.dtype,
+                        self._draft_param_sh if mesh is not None else None,
                     )
                     if self._draft is not None
                     else None
-                ),
-                out_routing=(
-                    jnp.zeros(
-                        (self.B, self.G, cfg.n_moe_layers, cfg.moe.top_k),
-                        jnp.int32,
-                    )
-                    if record_routing
-                    else None
-                ),
+                )
+            self.B = max_slots
+            self.page = page_size
+            self.M = -(-max_seqlen // page_size)      # table width (pages/slot)
+            self.S = self.M * page_size
+            self.G = max_new_tokens_cap
+            self.version = 0
+            # prefill streams through [n_rows, admit_chunk] extend programs;
+            # bigger chunks amortize the per-chunk attention over resident KV
+            # (31.5k prompt at chunk 128 = 246 waves each re-reading the whole
+            # prefix; at 2048 = 16 waves) at the cost of padding short prompts
+            # up to one chunk. Default: one page (exact, best for short prompts).
+            if admit_chunk_tokens is None:
+                self.admit_chunk = page_size
+            else:
+                self.admit_chunk = max(
+                    page_size, -(-admit_chunk_tokens // page_size) * page_size
+                )
+            self.admit_buckets = sorted(admit_buckets)
+            self.global_stop_ids = list(stop_token_ids)
+            self.max_stop_ids = 8
+            self.enable_prefix_cache = enable_prefix_cache
+            # dense-equivalent pool by default, sized at the SERVING-dtype HBM
+            # budget: a quantized pool's smaller elements buy more pages for
+            # the same bytes (int8 under bf16 serving = 2x n_pages — the whole
+            # point: more resident slots/longer prefixes at fixed HBM), never
+            # a smaller footprint by surprise. Pass n_pages to cap bytes.
+            bytes_ratio = jnp.dtype(cfg.dtype).itemsize if self.kv_quantized else 1
+            # layer kinds: the sliding window of each position of the model's
+            # period (None: full attention); one page table a position
+            self._windows = [w for w, _ in cfg.layer_kinds]
+            self._windowed = any(w is not None for w in self._windows)
+            K = len(self._windows)
+            if self._draft is not None and (
+                K > 1 or self.draft_cfg.period > 1
+            ):
+                raise NotImplementedError(
+                    "layer kinds: a draft model shares the target's page "
+                    "tables, which a model with a period of kinds has several of"
+                )
+            self.n_pages = (
+                n_pages if n_pages is not None
+                else self.B * self.M * bytes_ratio * K
             )
+            self.pool = PagePool(self.n_pages, page_size)
+            self.prefix = PrefixRegistry(self.pool, self._windows)
+            # positions a dispatch may run ahead of the host's lengths: the
+            # chunk's tokens and, in pipelined mode, the chunk still in flight,
+            # at ``step``'s default of 16 decode steps (a longer chunk takes
+            # what it needs beyond that from the pool at large, or is refused:
+            # ``_roll_windows``). Admission needs none: a prompt's pages are
+            # all taken before its chunks run
+            self._lookahead = 2 * 16 * (
+                (spec_k or constants.spec_k()) + 1 if spec_on else 1)
+            # the most pages a slot holds in a window kind at once: positions
+            # ``[n + 1 - window, n + lookahead)`` wherever they lie in their
+            # pages
+            self._window_claim = [
+                None if w is None
+                else (w + self._lookahead - 2) // page_size + 2
+                for w in self._windows
+            ]
 
-        if mesh is None:
-            self._state_sh = None
-            self.state = make_state()
-        else:
-            # the KV pool shards on its Hkv axis; everything else replicates.
-            # Creating the state UNDER jit with out_shardings lands each pool
-            # shard directly on its device — no transient full-size buffer.
-            sh = jax.tree.map(
-                lambda _: self._repl, jax.eval_shape(make_state)
-            )
-            sh = dataclasses.replace(
-                sh,
-                cache=tfm.PagedKVCache(
-                    pages=self._pages_sh,
-                    scales=self._scales_sh if self.kv_quantized else None,
-                ),
-                # the draft pool has the same [L, P, 2, Hkv, page, D]
-                # layout, so it takes the same kv-head-axis TP split
-                draft_cache=(
-                    tfm.PagedKVCache(
-                        pages=self._pages_sh,
-                        scales=(
-                            self._scales_sh
-                            if self.draft_kv_quantized else None
+            def make_state() -> GenState:
+                return GenState(
+                    cache=tfm.PagedKVCache.empty(
+                        cfg, self.n_pages, page_size,
+                        kv_dtype="int8" if self.kv_quantized else None,
+                    ),
+                    lens=jnp.zeros((self.B,), jnp.int32),
+                    last_tokens=jnp.zeros((self.B,), jnp.int32),
+                    active=jnp.zeros((self.B,), bool),
+                    n_gen=jnp.zeros((self.B,), jnp.int32),
+                    min_gen=jnp.zeros((self.B,), jnp.int32),
+                    max_gen=jnp.zeros((self.B,), jnp.int32),
+                    stop_ids=jnp.full((self.B, self.max_stop_ids), -1, jnp.int32),
+                    out_tokens=jnp.zeros((self.B, self.G), jnp.int32),
+                    out_logprobs=jnp.zeros((self.B, self.G), jnp.float32),
+                    ctx_tokens=jnp.zeros((self.B, self.S), jnp.int32),
+                    fallback_token=jnp.zeros((self.B,), jnp.int32),
+                    sp=SamplingParams.filled(self.B),
+                    rng=jax.random.key(seed),
+                    # the draft pool mirrors the target pool's page count so
+                    # one page index addresses both (lockstep alloc/free)
+                    draft_cache=(
+                        tfm.PagedKVCache.empty(
+                            self.draft_cfg, self.n_pages, page_size,
+                            kv_dtype="int8" if self.draft_kv_quantized else None,
+                        )
+                        if self._draft is not None
+                        else None
+                    ),
+                    out_routing=(
+                        jnp.zeros(
+                            (self.B, self.G, cfg.n_moe_layers, cfg.moe.top_k),
+                            jnp.int32,
+                        )
+                        if record_routing
+                        else None
+                    ),
+                )
+
+            with tracing.span("gen_engine/start/state"):
+                if mesh is None:
+                    self._state_sh = None
+                    self.state = make_state()
+                else:
+                    # the KV pool shards on its Hkv axis; everything else
+                    # replicates. Creating the state UNDER jit with
+                    # out_shardings lands each pool shard directly on its
+                    # device — no transient full-size buffer.
+                    sh = jax.tree.map(
+                        lambda _: self._repl, jax.eval_shape(make_state)
+                    )
+                    sh = dataclasses.replace(
+                        sh,
+                        cache=tfm.PagedKVCache(
+                            pages=self._pages_sh,
+                            scales=(
+                                self._scales_sh if self.kv_quantized else None
+                            ),
+                        ),
+                        # the draft pool has the same [L, P, 2, Hkv, page, D]
+                        # layout, so it takes the same kv-head-axis TP split
+                        draft_cache=(
+                            tfm.PagedKVCache(
+                                pages=self._pages_sh,
+                                scales=(
+                                    self._scales_sh
+                                    if self.draft_kv_quantized else None
+                                ),
+                            )
+                            if self._draft is not None
+                            else None
                         ),
                     )
-                    if self._draft is not None
-                    else None
-                ),
+                    self._state_sh = sh
+                    # arealint: ok(one-time engine-state materialization at construction)
+                    self.state = jax.jit(make_state, out_shardings=sh)()
+            self.accepting = True  # False = decode only, no new admissions
+            self.paused = False
+            self._slots: List[Optional[_SlotInfo]] = [None] * self.B
+            # one table a layer kind; ``_table_host`` is the first (the only
+            # one of a model of one kind), ``_held`` which entries a slot holds
+            # a reference through, ``_win_lo`` / ``_win_hi`` the pages of a
+            # window kind released so far / taken so far
+            self._tables_host = np.zeros((K, self.B, self.M), np.int32)
+            self._table_host = self._tables_host[0]
+            self._held = np.zeros((K, self.B, self.M), bool)
+            self._win_lo = np.zeros((K, self.B), np.int64)
+            self._win_hi = np.zeros((K, self.B), np.int64)
+            # window kinds' pages that more than one slot holds, counted once
+            # for every holder past the first: each is a page promised
+            # (``pool.reserved``) to whichever holder gives the shared page up
+            # while another still reads it, and then needs one of its own
+            self._deposits = 0
+            # host mirror of per-slot resident lengths: admission knows them
+            # exactly, each chunk's sync refreshes them — lets decode chunks
+            # run width-limited (see _table_width) without extra device pulls
+            self._lens_host = np.zeros((self.B,), np.int64)
+            # host mirror of "does this slot warp" (top-p/top-k active): when
+            # no resident slot warps, the decode chunk skips the [B, V] sort —
+            # the most expensive op of a step at a 152k vocab
+            self._warp_host = np.zeros((self.B,), bool)
+            # fused-epilogue routing mirrors: under the fused sampler a slot
+            # only needs the sorted fallback for machinery the online pass
+            # lacks — top-p, or top-k wider than the online buffer
+            # (_fused_warp_host); plain top-k slots up to TOPK_MAX stay fused
+            # through the online top-k buffer (_fused_topk_host)
+            self._fused_warp_host = np.zeros((self.B,), bool)
+            self._fused_topk_host = np.zeros((self.B,), bool)
+            self._pending: List[GenRequest] = []
+            self._req_meta: Dict[str, GenRequest] = {}
+            # chunk pipelining (step() docstring): harvest one chunk late so
+            # the per-chunk host sync overlaps the next chunk's compute
+            self._pipeline = (
+                pipeline_chunks
+                if pipeline_chunks is not None
+                else constants.decode_pipeline_enabled()
             )
-            self._state_sh = sh
-            # arealint: ok(one-time engine-state materialization at construction)
-            self.state = jax.jit(make_state, out_shardings=sh)()
-        self.accepting = True  # False = decode only, no new admissions
-        self.paused = False
-        self._slots: List[Optional[_SlotInfo]] = [None] * self.B
-        # one table a layer kind; ``_table_host`` is the first (the only
-        # one of a model of one kind), ``_held`` which entries a slot holds
-        # a reference through, ``_win_lo`` / ``_win_hi`` the pages of a
-        # window kind released so far / taken so far
-        self._tables_host = np.zeros((K, self.B, self.M), np.int32)
-        self._table_host = self._tables_host[0]
-        self._held = np.zeros((K, self.B, self.M), bool)
-        self._win_lo = np.zeros((K, self.B), np.int64)
-        self._win_hi = np.zeros((K, self.B), np.int64)
-        # window kinds' pages that more than one slot holds, counted once
-        # for every holder past the first: each is a page promised
-        # (``pool.reserved``) to whichever holder gives the shared page up
-        # while another still reads it, and then needs one of its own
-        self._deposits = 0
-        # host mirror of per-slot resident lengths: admission knows them
-        # exactly, each chunk's sync refreshes them — lets decode chunks
-        # run width-limited (see _table_width) without extra device pulls
-        self._lens_host = np.zeros((self.B,), np.int64)
-        # host mirror of "does this slot warp" (top-p/top-k active): when
-        # no resident slot warps, the decode chunk skips the [B, V] sort —
-        # the most expensive op of a step at a 152k vocab
-        self._warp_host = np.zeros((self.B,), bool)
-        # fused-epilogue routing mirrors: under the fused sampler a slot
-        # only needs the sorted fallback for machinery the online pass
-        # lacks — top-p, or top-k wider than the online buffer
-        # (_fused_warp_host); plain top-k slots up to TOPK_MAX stay fused
-        # through the online top-k buffer (_fused_topk_host)
-        self._fused_warp_host = np.zeros((self.B,), bool)
-        self._fused_topk_host = np.zeros((self.B,), bool)
-        self._pending: List[GenRequest] = []
-        self._req_meta: Dict[str, GenRequest] = {}
-        # chunk pipelining (step() docstring): harvest one chunk late so
-        # the per-chunk host sync overlaps the next chunk's compute
-        self._pipeline = (
-            pipeline_chunks
-            if pipeline_chunks is not None
-            else constants.decode_pipeline_enabled()
-        )
-        # speculative decoding (docs/performance.md): draft-and-verify
-        # chunks amortize one params+pool sweep over K+1 candidate tokens;
-        # exactly distribution-preserving, so togglable between chunks
-        # (``spec`` is read once per step() under the engine lock)
-        self.spec = spec_on
-        if record_routing and spec_on:
-            raise ValueError(
-                "record_routing covers vanilla chunks only: the verify "
-                "pass of a speculative chunk records nothing"
+            # speculative decoding (docs/performance.md): draft-and-verify
+            # chunks amortize one params+pool sweep over K+1 candidate tokens;
+            # exactly distribution-preserving, so togglable between chunks
+            # (``spec`` is read once per step() under the engine lock)
+            self.spec = spec_on
+            if record_routing and spec_on:
+                raise ValueError(
+                    "record_routing covers vanilla chunks only: the verify "
+                    "pass of a speculative chunk records nothing"
+                )
+            self.spec_k = max(
+                1, spec_k if spec_k is not None else constants.spec_k()
             )
-        self.spec_k = max(
-            1, spec_k if spec_k is not None else constants.spec_k()
-        )
-        # fused sampling epilogue (docs/performance.md "Fused sampling
-        # epilogue"): decode/verify chunks return final-norm hidden states
-        # and the sampler streams the LM head over vocab blocks — the
-        # [B, V] logits (and their sort) leave the per-token path. Exact
-        # for greedy, distribution-exact otherwise; top-p (and top-k >
-        # TOPK_MAX) slots keep the sorted path via the warp-row bucket.
-        # No flag: the rule reads what this engine can observe (one TPU
-        # device, an untied head in the serving dtype, a policy); the
-        # argument pins either side (tests, the CPU's streamed XLA pass).
-        self.fused = (
-            fused_sample
-            if fused_sample is not None
-            else fused_ops.fused_sample_applies(cfg, self.params, mesh)
-        )
-        # adaptive spec-K: retune the draft length from the live accept-len
-        # histogram the engine already folds per chunk. K only moves within
-        # a small fixed choice set so jitted spec-chunk specializations
-        # stay bounded (one per (chunk key, K) pair, K in _spec_k_choices).
-        self.spec_k_adapt = (
-            spec_k_adapt
-            if spec_k_adapt is not None
-            else constants.spec_k_adapt_enabled()
-        )
-        self._spec_k_choices = sorted({1, 2, 4, 8} | {self.spec_k})
-        self._accept_window: List[float] = []
-        if self.spec:
-            metrics_mod.counters.gauge(
-                metrics_mod.GEN_SPEC_K_CURRENT, float(self.spec_k)
+            # fused sampling epilogue (docs/performance.md "Fused sampling
+            # epilogue"): decode/verify chunks return final-norm hidden states
+            # and the sampler streams the LM head over vocab blocks — the
+            # [B, V] logits (and their sort) leave the per-token path. Exact
+            # for greedy, distribution-exact otherwise; top-p (and top-k >
+            # TOPK_MAX) slots keep the sorted path via the warp-row bucket.
+            # No flag: the rule reads what this engine can observe (one TPU
+            # device, an untied head in the serving dtype, a policy); the
+            # argument pins either side (tests, the CPU's streamed XLA pass).
+            self.fused = (
+                fused_sample
+                if fused_sample is not None
+                else fused_ops.fused_sample_applies(cfg, self.params, mesh)
             )
-        self._prev_flags = None           # chunk k's undonated flag outputs
-        self._prev_running: tuple = ()    # (slot, epoch) pairs at k's dispatch
-        self._steps_ahead = 0   # token-advance bound of the in-flight chunk
-        # admission generation per slot: stale flags from a chunk dispatched
-        # before the slot turned over must never harvest its NEW occupant
-        self._slot_epoch = np.zeros((self.B,), np.int64)
-        # Two-tier locking: `_lock` guards device state / slots / pool and is
-        # held by step() for a whole decode chunk; `_pending_lock` guards
-        # ONLY the intake queue so submit() on the server's asyncio thread
-        # never blocks behind a running chunk. free_slots/n_running read the
-        # slot list without a lock (GIL-atomic snapshot; metrics may lag one
-        # chunk, which is fine).
-        self._lock = threading.RLock()
-        self._pending_lock = threading.Lock()
-        self._jit_extend: Dict[int, Any] = {}
-        self._jit_kv_write: Dict[int, Any] = {}
-        self._jit_commit: Dict[int, Any] = {}
-        self._jit_chunk: Dict[int, Any] = {}
-        self._jit_spec: Dict[Any, Any] = {}
-        # observability
-        self.stats = {
-            "prefill_tokens": 0,        # prompt tokens actually computed
-            "prefix_hit_tokens": 0,     # prompt tokens served from shared pages
-            "prefix_hits": 0,
-            "admitted": 0,
-            "spec_draft_tokens": 0,     # draft tokens proposed (spec decode)
-            "spec_accepted_tokens": 0,  # draft tokens accepted & emitted
-            # per kernel-run chunk, at its first step: KV positions the
-            # paged-decode kernel computes over / KV tokens resident
-            "kernel_positions": 0,
-            "resident_tokens": 0,
-            # ... and its grid steps that reach a page / all its grid steps
-            "kernel_steps_active": 0,
-            "kernel_steps": 0,
-            # pool tiles the ``kv_page_write`` kernel writes: of a vanilla
-            # chunk as dispatched, of an admission wave's prefill; stays 0
-            # where the XLA scatter writes the pool (``_kv_write_rows``)
-            "kv_write_tiles": 0,
-            # layer kinds: pages of window kinds released behind the window
-            # while their request ran (at admission's chunks and before
-            # decode chunks), and the window kinds' share of the resident
-            # tokens (sum over running slots of min(len, window), a chunk)
-            "window_pages_released": 0,
-            "window_resident_tokens": 0,
-            # MoE models, per vanilla chunk (every row of the batch routes,
-            # free slots too: the expert matmuls read what they route to):
-            # distinct experts with a token, summed over layers and steps /
-            # layers x steps x experts / the most tokens one expert got in
-            # one layer-step (max, not summed)
-            "moe_experts_hit": 0,
-            "moe_expert_slots": 0,
-            "moe_load_max": 0,
-            # fused chunks, rows x steps as dispatched: sampled by the
-            # fused head-and-sample pass / sent to the sorted path (top-p,
-            # top-k past the online buffer); both 0 on a materialised engine
-            "fused_rows": 0,
-            "sampler_fallback_rows": 0,
-        }
+            # adaptive spec-K: retune the draft length from the live accept-len
+            # histogram the engine already folds per chunk. K only moves within
+            # a small fixed choice set so jitted spec-chunk specializations
+            # stay bounded (one per (chunk key, K) pair, K in _spec_k_choices).
+            self.spec_k_adapt = (
+                spec_k_adapt
+                if spec_k_adapt is not None
+                else constants.spec_k_adapt_enabled()
+            )
+            self._spec_k_choices = sorted({1, 2, 4, 8} | {self.spec_k})
+            self._accept_window: List[float] = []
+            if self.spec:
+                metrics_mod.counters.gauge(
+                    metrics_mod.GEN_SPEC_K_CURRENT, float(self.spec_k)
+                )
+            self._prev_flags = None           # chunk k's undonated flag outputs
+            self._prev_running: tuple = ()    # (slot, epoch) pairs at k's dispatch
+            self._steps_ahead = 0   # token-advance bound of the in-flight chunk
+            # admission generation per slot: stale flags from a chunk dispatched
+            # before the slot turned over must never harvest its NEW occupant
+            self._slot_epoch = np.zeros((self.B,), np.int64)
+            # Two-tier locking: `_lock` guards device state / slots / pool and is
+            # held by step() for a whole decode chunk; `_pending_lock` guards
+            # ONLY the intake queue so submit() on the server's asyncio thread
+            # never blocks behind a running chunk. free_slots/n_running read the
+            # slot list without a lock (GIL-atomic snapshot; metrics may lag one
+            # chunk, which is fine).
+            self._lock = threading.RLock()
+            self._pending_lock = threading.Lock()
+            self._jit_extend: Dict[int, Any] = {}
+            self._jit_kv_write: Dict[int, Any] = {}
+            self._jit_commit: Dict[int, Any] = {}
+            self._jit_chunk: Dict[int, Any] = {}
+            self._jit_spec: Dict[Any, Any] = {}
+            # observability
+            self.stats = {
+                "prefill_tokens": 0,        # prompt tokens actually computed
+                "prefix_hit_tokens": 0,     # prompt tokens served from shared pages
+                "prefix_hits": 0,
+                "admitted": 0,
+                "spec_draft_tokens": 0,     # draft tokens proposed (spec decode)
+                "spec_accepted_tokens": 0,  # draft tokens accepted & emitted
+                # per kernel-run chunk, at its first step: KV positions the
+                # paged-decode kernel computes over / KV tokens resident
+                "kernel_positions": 0,
+                "resident_tokens": 0,
+                # ... and its grid steps that reach a page / all its grid steps
+                "kernel_steps_active": 0,
+                "kernel_steps": 0,
+                # pool tiles the ``kv_page_write`` kernel writes: of a vanilla
+                # chunk as dispatched, of an admission wave's prefill; stays 0
+                # where the XLA scatter writes the pool (``_kv_write_rows``)
+                "kv_write_tiles": 0,
+                # layer kinds: pages of window kinds released behind the window
+                # while their request ran (at admission's chunks and before
+                # decode chunks), and the window kinds' share of the resident
+                # tokens (sum over running slots of min(len, window), a chunk)
+                "window_pages_released": 0,
+                "window_resident_tokens": 0,
+                # MoE models, per vanilla chunk (every row of the batch routes,
+                # free slots too: the expert matmuls read what they route to):
+                # distinct experts with a token, summed over layers and steps /
+                # layers x steps x experts / the most tokens one expert got in
+                # one layer-step (max, not summed)
+                "moe_experts_hit": 0,
+                "moe_expert_slots": 0,
+                "moe_load_max": 0,
+                # fused chunks, rows x steps as dispatched: sampled by the
+                # fused head-and-sample pass / sent to the sorted path (top-p,
+                # top-k past the online buffer); both 0 on a materialised engine
+                "fused_rows": 0,
+                "sampler_fallback_rows": 0,
+            }
+            start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
     # ------------------------------------------------------------------ #
     # Client API

@@ -126,6 +126,8 @@ class GenerationHTTPServer:
         overlap_load: bool = True,
         stream_interval_s: float = 0.0,
     ):
+        # the engine's constructor started it; a stand-in engine did not
+        tracing.listen_for_compiles()
         self.engine = engine
         self.decode_steps = decode_steps
         self.metrics_dump_path = metrics_dump_path

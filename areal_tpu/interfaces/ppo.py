@@ -249,21 +249,21 @@ class PPOActorInterface(ModelInterface):
         pre-minibatch GAE + normalization block (``ppo_interface.py:527-647``).
         One ``ppo/prepare`` span per call, around the host's packing, one
         transfer in, ONE compiled program (``_build_prepass``) and one
-        transfer out; its ``compiled`` is 1 when this call traced a new
-        program (a padded length or a set of keys not met before), else 0."""
+        transfer out. ``tracing``'s compile listener stamps the span with
+        ``compiled`` = 1 (and ``compile_s``) when the call built a program
+        (a padded length or a set of keys not met before); a call that
+        built none carries neither."""
         main = sample.main_key()
         with tracing.span(
             "ppo/prepare", n_seqs=sample.bs,
             n_tokens=sum(sum(l) for l in sample.seqlens[main]),
-        ) as attrs:
+        ):
             pb = batching.pack_sequences(sample, n_rows=1, pad_multiple=128)
-            n_programs = self._prepass._cache_size()
             out = self._prepass(*jax.device_put((
                 {k: v[0] for k, v in pb.arrays.items()
                  if k in self._prepass_keys},
                 np.float32(self.kl_ctl.value),
             )))
-            attrs["compiled"] = self._prepass._cache_size() - n_programs
             return self._attach(sample, pb, *out)
 
     def _attach(self, sample, pb, adv, ret, kl_rw, ref_kl_mean):

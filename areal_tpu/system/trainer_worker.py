@@ -91,6 +91,8 @@ class AsyncPPOTrainerWorker:
         max_head_offpolicyness: Optional[int] = None,
         buffer_capacity: int = 16384,
     ):
+        # the engines' constructors started it; stand-in engines did not
+        tracing.listen_for_compiles()
         self.experiment_name = experiment_name
         self.trial_name = trial_name
         self.actor_engine = actor_engine
@@ -924,6 +926,7 @@ class SFTTrainerWorker:
         interface_name: str = "sft",
         interface_kwargs: Optional[Dict] = None,
     ):
+        tracing.listen_for_compiles()
         self.experiment_name = experiment_name
         self.trial_name = trial_name
         self.engine = engine

@@ -209,46 +209,49 @@ class TrainEngine:
         mesh=None,
         param_dtype: str = "float32",
     ):
-        self.cfg = model_cfg
-        self.parallel = parallel
-        # fp32 master params by default; "bfloat16" halves param+grad memory
-        # (fits ~1B-param models with Adam on one 16GB chip) at some
-        # optimizer-precision cost
-        self.param_dtype = jnp.dtype(param_dtype)
-        self.mesh = mesh if mesh is not None else make_mesh(parallel)
-        self.optimizer_cfg = optimizer
-        self.params = None
-        self.opt_state = None
-        self.tx = None
-        self.hf_family = None
-        self._step = 0
-        self.version = 0
-        self._jit_cache: Dict[Any, Callable] = {}
-        self._param_shardings = param_shardings(
-            self.mesh, tfm.param_logical_axes(model_cfg)
-        )
-        self._batch_sharding = NamedSharding(self.mesh, batch_pspec())
-        # stacked micro-batches [n_mbs, D, T, ...]: rows still spread over
-        # the data axes, tokens over ctx, the micro-batch axis unsharded
-        self._stacked_sharding = NamedSharding(
-            self.mesh, P(None, ("data", "fsdp"), "ctx")
-        )
-        from areal_tpu.ops import attention as attn_ops
-
-        if parallel.ctx > 1:
-            # context parallelism: packed attention rings the token axis
-            # over this mesh (process-global — every engine in a CP
-            # experiment must share the same mesh topology; conflicting
-            # shapes raise in set_context_parallel)
-            if parallel.ctx & (parallel.ctx - 1):
-                raise ValueError(f"ctx must be a power of two, got {parallel.ctx}")
-            attn_ops.set_context_parallel(self.mesh, "ctx")
-        elif attn_ops.get_context_parallel() is not None:
-            raise ValueError(
-                "a context-parallel engine is active in this process: every "
-                "train engine must use the same ctx topology (got ctx=1); "
-                "match the parallel specs or clear_context_parallel() first"
+        # one listener a process, live before the first device work below
+        tracing.listen_for_compiles()
+        with tracing.span("train_engine/start"):
+            self.cfg = model_cfg
+            self.parallel = parallel
+            # fp32 master params by default; "bfloat16" halves param+grad memory
+            # (fits ~1B-param models with Adam on one 16GB chip) at some
+            # optimizer-precision cost
+            self.param_dtype = jnp.dtype(param_dtype)
+            self.mesh = mesh if mesh is not None else make_mesh(parallel)
+            self.optimizer_cfg = optimizer
+            self.params = None
+            self.opt_state = None
+            self.tx = None
+            self.hf_family = None
+            self._step = 0
+            self.version = 0
+            self._jit_cache: Dict[Any, Callable] = {}
+            self._param_shardings = param_shardings(
+                self.mesh, tfm.param_logical_axes(model_cfg)
             )
+            self._batch_sharding = NamedSharding(self.mesh, batch_pspec())
+            # stacked micro-batches [n_mbs, D, T, ...]: rows still spread over
+            # the data axes, tokens over ctx, the micro-batch axis unsharded
+            self._stacked_sharding = NamedSharding(
+                self.mesh, P(None, ("data", "fsdp"), "ctx")
+            )
+            from areal_tpu.ops import attention as attn_ops
+
+            if parallel.ctx > 1:
+                # context parallelism: packed attention rings the token axis
+                # over this mesh (process-global — every engine in a CP
+                # experiment must share the same mesh topology; conflicting
+                # shapes raise in set_context_parallel)
+                if parallel.ctx & (parallel.ctx - 1):
+                    raise ValueError(f"ctx must be a power of two, got {parallel.ctx}")
+                attn_ops.set_context_parallel(self.mesh, "ctx")
+            elif attn_ops.get_context_parallel() is not None:
+                raise ValueError(
+                    "a context-parallel engine is active in this process: every "
+                    "train engine must use the same ctx topology (got ctx=1); "
+                    "match the parallel specs or clear_context_parallel() first"
+                )
 
     # ------------------------------------------------------------------ #
     # Initialization
@@ -272,11 +275,14 @@ class TrainEngine:
         return self.n_rows // nproc
 
     def init_random(self, seed: int = 0):
-        init = jax.jit(
-            functools.partial(tfm.init_params, self.cfg, dtype=self.param_dtype),
-            out_shardings=self._param_shardings,
-        )
-        self.params = init(jax.random.key(seed))
+        with tracing.span("train_engine/start/params"):
+            # a named function: the program is ``jit(init_params)`` in the
+            # compile records, a partial would be ``jit(<unknown>)``
+            def init_params(key):
+                return tfm.init_params(self.cfg, key, dtype=self.param_dtype)
+
+            init = jax.jit(init_params, out_shardings=self._param_shardings)
+            self.params = init(jax.random.key(seed))
         return self
 
     def load_hf(self, path: str, init_critic_head: bool = False):
@@ -294,7 +300,8 @@ class TrainEngine:
 
         from areal_tpu.models import hf as hf_conv
 
-        cfg, host_params = hf_conv.load_hf_checkpoint(path)
+        with tracing.span("train_engine/start/checkpoint_read"):
+            cfg, host_params = hf_conv.load_hf_checkpoint(path)
         with open(os.path.join(path, "config.json")) as f:
             model_type = json.load(f)["model_type"]
         self.hf_family = hf_conv.family_for_model_type(model_type).name
@@ -313,21 +320,24 @@ class TrainEngine:
         return self.load_params(host_params)
 
     def load_params(self, host_params):
-        host_params = jax.tree.map(
-            lambda x: np.asarray(x, self.param_dtype), host_params
-        )
-        if multihost.is_multihost():
-            # every process holds the full host copy (loaded from shared FS);
-            # each materializes only its addressable shards
-            self.params = jax.tree.map(
-                lambda x, s: jax.make_array_from_callback(
-                    x.shape, s, lambda idx: x[idx]
-                ),
-                host_params,
-                self._param_shardings,
+        with tracing.span("train_engine/start/params"):
+            host_params = jax.tree.map(
+                lambda x: np.asarray(x, self.param_dtype), host_params
             )
-        else:
-            self.params = jax.device_put(host_params, self._param_shardings)
+            if multihost.is_multihost():
+                # every process holds the full host copy (loaded from shared
+                # FS); each materializes only its addressable shards
+                self.params = jax.tree.map(
+                    lambda x, s: jax.make_array_from_callback(
+                        x.shape, s, lambda idx: x[idx]
+                    ),
+                    host_params,
+                    self._param_shardings,
+                )
+            else:
+                self.params = jax.device_put(
+                    host_params, self._param_shardings
+                )
         return self
 
     def save_hf(self, path: str, family: str, async_write: bool = False,
@@ -461,18 +471,19 @@ class TrainEngine:
         # chip (RESOURCE_EXHAUSTED in setup_optimizer, chip run, PR 21).
         # One jit with out_shardings also touches only local devices in a
         # multi-process world: no transfer, no collective.
-        repl = NamedSharding(self.mesh, P())
-        opt_shardings = optax.tree_map_params(
-            self.tx,
-            lambda _, sharding: sharding,
-            jax.eval_shape(self.tx.init, self.params),
-            self._param_shardings,
-            transform_non_params=lambda _: repl,
-        )
-        # arealint: ok(one-time optimizer-state init at setup, not a per-step rebuild)
-        self.opt_state = jax.jit(
-            self.tx.init, out_shardings=opt_shardings
-        )(self.params)
+        with tracing.span("train_engine/start/optimizer"):
+            repl = NamedSharding(self.mesh, P())
+            opt_shardings = optax.tree_map_params(
+                self.tx,
+                lambda _, sharding: sharding,
+                jax.eval_shape(self.tx.init, self.params),
+                self._param_shardings,
+                transform_non_params=lambda _: repl,
+            )
+            # arealint: ok(one-time optimizer-state init at setup, not a per-step rebuild)
+            self.opt_state = jax.jit(
+                self.tx.init, out_shardings=opt_shardings
+            )(self.params)
         return self
 
     # ------------------------------------------------------------------ #

@@ -542,7 +542,11 @@ class TestEngineSpans:
         assert len(chunks) >= 2
         first, kids = chunks[0]
         assert {"gen_engine/admit", "gen_engine/dispatch"} <= set(kids)
-        assert kids["gen_engine/admit"]["attrs"] == {
+        # the first wave at this shape built its programs under the span
+        admit = kids["gen_engine/admit"]
+        assert admit["attrs"].pop("compiled") >= 1
+        assert 0 < admit["attrs"].pop("compile_s") <= admit["dur_s"]
+        assert admit["attrs"] == {
             "admitted": 3, "prefill_tokens": sum(len(p) - 1 for p in prompts),
             "prefix_hit_tokens": 0, "pending_left": 0,
         }
@@ -726,6 +730,94 @@ class TestEngineSpans:
             # it queued until a slot was harvested: admitted after the
             # first two had their first tokens
             assert late.t_admit >= max(o.t_first for o in outs if o.rid != "r2")
+
+    def test_start_is_a_span_with_its_phases_as_children(self, params):
+        """``gen_engine/start`` around the constructor, a child a phase
+        that touches the device, and every program built in it a
+        ``compile/program`` record under the phase that built it."""
+        from areal_tpu.base import tracing
+
+        tracing.drain()
+        # sizes no other test of this process builds a state at: its
+        # eager programs are new here
+        eng = GenerationEngine(
+            CFG, params, max_slots=3, max_seqlen=320, max_new_tokens_cap=77)
+        spans = tracing.drain()
+        (start,) = [s for s in spans if s["name"] == "gen_engine/start"]
+        kids = [s for s in spans if s["parent_id"] == start["span_id"]]
+        assert [k["name"] for k in kids] == [
+            "gen_engine/start/params", "gen_engine/start/state"]
+        assert sum(k["dur_s"] for k in kids) <= start["dur_s"]
+        a = start["attrs"]
+        assert (a["max_slots"], a["n_pages"], a["pool_bytes"]) == (
+            3, eng.n_pages, eng.kv_pool_bytes())
+        built = [s for s in spans if s["name"] == "compile/program"]
+        by_id = {s["span_id"]: s for s in spans}
+        assert built and all(
+            by_id[b["parent_id"]]["name"].startswith("gen_engine/start/")
+            for b in built)
+        assert sum(k.get("attrs", {}).get("compiled", 0)
+                   for k in kids) == len(built)
+        assert "compiled" not in a      # the phases paid, not the start
+        assert not [s for s in tracing.live_spans()
+                    if s["name"].startswith("gen_engine/start")]
+
+    def test_first_step_at_a_shape_says_what_it_built(self, params, rng):
+        """``compiled`` on ``gen_engine/admit`` / ``gen_engine/dispatch``:
+        the first step at a shape builds its programs under them, each a
+        ``compile/program`` child naming the program; the second builds
+        nothing and carries no stamp."""
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(
+            CFG, params, max_slots=2, max_seqlen=128, pipeline_chunks=False)
+        eng.submit(GenRequest(
+            rid="a", input_ids=[int(x) for x in rng.integers(1, 128, size=5)],
+            max_new_tokens=40, greedy=True))
+        steps = []
+        for _ in range(2):
+            tracing.drain()
+            eng.step(4)
+            steps.append(tracing.drain())
+        names = ("gen_engine/admit", "gen_engine/dispatch")
+        first = {s["name"]: s for s in steps[0] if s["name"] in names}
+        assert first["gen_engine/admit"]["attrs"]["compiled"] >= 1
+        assert first["gen_engine/dispatch"]["attrs"]["compiled"] >= 1
+        programs = {
+            s["attrs"]["fun_name"] for s in steps[0]
+            if s["name"] == "compile/program"
+            and s["parent_id"] == first["gen_engine/dispatch"]["span_id"]}
+        assert "jit(chunk)" in programs
+        assert not [s for s in steps[1] if s["name"] == "compile/program"]
+        assert all("compiled" not in s.get("attrs", {}) for s in steps[1])
+
+    def test_a_table_width_nobody_warmed_is_one_record(self, params, rng):
+        """Serving builds a program when a slot grows into the next table
+        width: ONE ``compile/program`` record, child of the
+        ``gen_engine/dispatch`` that paid for it, naming the program."""
+        from areal_tpu.base import tracing
+
+        eng = GenerationEngine(
+            CFG, params, max_slots=2, max_seqlen=512, page_size=8,
+            max_new_tokens_cap=64, pipeline_chunks=False)
+        eng.submit(GenRequest(       # 240 of the 256 positions 32 pages hold
+            rid="a", input_ids=[int(x) for x in rng.integers(1, 128, size=240)],
+            max_new_tokens=40, greedy=True))
+        eng.step(4)
+        tracing.drain()
+        while not eng.step(4):
+            pass
+        spans = tracing.drain()
+        dispatches = [s for s in spans if s["name"] == "gen_engine/dispatch"]
+        assert {d["attrs"]["table_width"] for d in dispatches} == {32, 64}
+        (built,) = [s for s in spans if s["name"] == "compile/program"]
+        assert built["attrs"]["fun_name"] == "jit(chunk)"
+        (paid,) = [d for d in dispatches if d["span_id"] == built["parent_id"]]
+        assert paid["attrs"]["table_width"] == 64
+        assert paid["attrs"]["compiled"] == 1
+        assert paid["attrs"]["compile_s"] == built["dur_s"]
+        assert [d["attrs"].get("compiled", 0) for d in dispatches] == [
+            int(d is paid) for d in dispatches]
 
     def test_weight_swap_span_carries_the_version(self, params):
         from areal_tpu.base import tracing

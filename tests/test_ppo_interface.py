@@ -274,7 +274,9 @@ def test_prepass_equals_numpy_reference(rng, case):
 
 
 def _prepare_compiles():
-    return [s["attrs"]["compiled"] for s in tracing.drain()
+    """Programs built under each ``ppo/prepare`` span, as the compile
+    listener stamped them (no stamp: none built)."""
+    return [s["attrs"].get("compiled", 0) for s in tracing.drain()
             if s["name"] == "ppo/prepare"]
 
 
@@ -327,11 +329,16 @@ def test_one_prepare_span_per_train_step(engines, rng, role):
     (step,) = [s for s in spans if s["name"] == "ppo/train_step"]
     (prep,) = [s for s in spans if s["name"] == "ppo/prepare"]
     assert prep["parent_id"] == step["span_id"]
+    # a new interface's first batch builds its pre-pass: the listener's stamp
+    assert 0 < prep["attrs"].pop("compile_s") <= prep["dur_s"]
     assert prep["attrs"] == {
         "n_seqs": 4,
         "n_tokens": sum(sum(l) for l in sample.seqlens["packed_input_ids"]),
-        "compiled": 1,   # a new interface's first batch traces its pre-pass
+        "compiled": 1,
     }
+    (built,) = [s for s in spans if s["name"] == "compile/program"
+                and s["parent_id"] == prep["span_id"]]
+    assert built["attrs"]["fun_name"] == "jit(prepass)"
     assert step["attrs"]["n_mbs"] == 2
     # the packer and the step's dispatch lie inside the train step's span
     # (on the packer thread when the prefetcher runs: then not as children)

@@ -240,6 +240,159 @@ class TestSpanRecords:
         assert tracing.spans_since(0.0) == []
 
 
+class TestCompileListener:
+    """``tracing.listen_for_compiles``: every executable JAX builds is the
+    ``compile/*`` counters and one ``compile/program`` record under the
+    span that paid for it (docs/observability.md "What a start cost")."""
+
+    @staticmethod
+    def _fresh(scale):
+        import jax
+
+        def unit_program(x):
+            return x * scale + 1.0
+
+        return jax.jit(unit_program)    # a new function: never built before
+
+    @staticmethod
+    def _compile_counters():
+        return {k: v for k, v in metrics_mod.counters.snapshot().items()
+                if k.startswith("compile/")}
+
+    def test_program_built_under_a_span_is_its_child(self):
+        import numpy as np
+
+        assert tracing.listen_for_compiles()
+        fn, x = self._fresh(2.0), np.ones(4, np.float32)
+        with tracing.span("t/builds") as attrs:
+            fn(x)
+        spans = tracing.drain()
+        (built,) = [s for s in spans if s["name"] == "compile/program"]
+        (outer,) = [s for s in spans if s["name"] == "t/builds"]
+        assert built["parent_id"] == outer["span_id"]
+        assert built["trace_id"] == outer["trace_id"]
+        a = built["attrs"]
+        assert a["fun_name"] == "jit(unit_program)"
+        assert a["cache_hit"] is None       # the CPU tests cache nothing
+        stages = [a["trace_s"], a["lower_s"], a["backend_s"]]
+        assert all(t > 0 for t in stages)
+        assert built["dur_s"] == pytest.approx(sum(stages))
+        assert built["dur_s"] <= outer["dur_s"]
+        assert outer["t0"] <= built["t0"]
+        assert attrs == {"compiled": 1, "compile_s": built["dur_s"]}
+        # the second call builds nothing: no record, no stamp on its span
+        with tracing.span("t/builds") as attrs:
+            fn(x)
+        assert attrs == {}
+        assert [s["name"] for s in tracing.drain()] == ["t/builds"]
+
+    def test_stamp_lands_on_the_innermost_open_span(self):
+        import numpy as np
+
+        tracing.listen_for_compiles()
+        fn = self._fresh(3.0)
+        with tracing.span("t/outer") as outer:
+            with tracing.span("t/inner") as inner:
+                fn(np.ones(4, np.float32))
+        assert inner["compiled"] == 1 and "compiled" not in outer
+        recs = {s["name"]: s for s in tracing.drain()}
+        assert recs["compile/program"]["parent_id"] == recs["t/inner"]["span_id"]
+
+    def test_a_program_counts_its_top_level_trace_once(self):
+        """A jitted function that calls jitted functions fires a trace
+        event for each of them, then its own, which contains theirs: the
+        program's ``trace_s`` is its own, never the sum."""
+        import jax
+        import numpy as np
+        from jax._src import monitoring
+
+        tracing.listen_for_compiles()
+        inner = self._fresh(5.0)
+
+        @jax.jit
+        def calls_inner(x):
+            return inner(x) + inner(x + 1.0)
+
+        traces = []
+
+        def on_dur(key, dur, fun_name="", **kw):
+            if key.endswith("jaxpr_trace_duration"):
+                traces.append((fun_name, dur))
+
+        monitoring.register_event_duration_secs_listener(on_dur)
+        before = self._compile_counters()
+        try:
+            with tracing.span("t/nested"):
+                calls_inner(np.ones(4, np.float32))
+        finally:
+            monitoring.unregister_event_duration_listener(on_dur)
+        after = self._compile_counters()
+        (built,) = [s for s in tracing.drain() if s["name"] == "compile/program"]
+        assert built["attrs"]["fun_name"] == "jit(calls_inner)"
+        assert traces[-1][0] == "calls_inner"
+        assert "unit_program" in [n for n, _ in traces[:-1]]
+        assert built["attrs"]["trace_s"] == traces[-1][1]
+        assert sum(d for _, d in traces) > traces[-1][1]
+        assert after["compile/programs"] == before.get("compile/programs", 0) + 1
+        assert after["compile/trace_s"] - before.get("compile/trace_s", 0) \
+            == pytest.approx(traces[-1][1])
+
+    def test_one_listener_however_often_it_is_asked_for(self):
+        import numpy as np
+
+        for _ in range(3):      # two engines and a worker in one process
+            assert tracing.listen_for_compiles()
+        before = self._compile_counters().get("compile/programs", 0)
+        with tracing.span("t/once") as attrs:
+            self._fresh(7.0)(np.ones(4, np.float32))
+        assert attrs["compiled"] == 1
+        assert len([s for s in tracing.drain()
+                    if s["name"] == "compile/program"]) == 1
+        assert self._compile_counters()["compile/programs"] == before + 1
+
+    def test_spans_off_keeps_the_counters_and_no_record(self, monkeypatch):
+        import numpy as np
+
+        tracing.listen_for_compiles()
+        monkeypatch.setenv(constants.TRACE_SPANS_ENV, "0")
+        before = self._compile_counters()
+        with tracing.span("t/off_builds") as attrs:
+            self._fresh(11.0)(np.ones(4, np.float32))
+        after = self._compile_counters()
+        assert attrs == {} and tracing.drain() == []
+        assert after["compile/programs"] == before.get("compile/programs", 0) + 1
+        for k in ("compile/trace_s", "compile/lower_s", "compile/backend_s"):
+            assert after[k] > before.get(k, 0.0)
+
+
+    def test_configure_starts_it_only_where_jax_already_is(self):
+        """``compile_cache.configure`` is called by JAX-free parents too:
+        it must not import JAX, and where JAX is there it starts the
+        listener before the process's first program."""
+        import subprocess
+        import sys
+
+        code = (
+            "import sys\n"
+            "from areal_tpu.base import compile_cache, metrics, tracing\n"
+            "compile_cache.configure()\n"
+            "assert 'jax' not in sys.modules\n"
+            "assert tracing.listen_for_compiles() is False\n"
+            "import jax, numpy as np\n"
+            "compile_cache.configure()\n"
+            "jax.jit(lambda x: x + 1)(np.ones(3, np.float32))\n"
+            "assert metrics.counters.get('compile/programs') == 1\n"
+            "assert tracing.drain()[-1]['name'] == 'compile/program'\n"
+        )
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        p = subprocess.run(
+            [sys.executable, "-c", code], cwd=root, capture_output=True,
+            text=True, timeout=300,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert p.returncode == 0, p.stderr[-2000:]
+
+
 class TestFlush:
     def test_flush_appends_worker_stamped_jsonl(self, tmp_path):
         with tracing.span("t/flush", rid="r1"):

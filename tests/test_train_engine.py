@@ -161,6 +161,47 @@ def test_no_recompile_across_rounds(rng, par):
         monitoring.unregister_event_duration_listener(on_dur)
 
 
+def test_start_phases_are_spans_and_steps_say_what_they_built(rng):
+    """``train_engine/start`` around the constructor, then a span a phase
+    of the state's making as the caller asks for them; the first train
+    step builds its program under ``train_pipe/dispatch`` (``compiled``, a
+    ``compile/program`` child), the second builds nothing."""
+    from areal_tpu.base import tracing
+
+    tracing.drain()
+    eng = TrainEngine(TINY, optimizer=OptimizerConfig(lr=1e-3))
+    eng.init_random(0)
+    eng.setup_optimizer(total_train_steps=50)
+    spans = tracing.drain()
+    phases = [s for s in spans if s["name"].startswith("train_engine/start")]
+    assert [s["name"] for s in phases] == [
+        "train_engine/start", "train_engine/start/params",
+        "train_engine/start/optimizer"]
+    for name, program in (("params", "jit(init_params)"),
+                          ("optimizer", "jit(init_fn)")):    # optax's name
+        (phase,) = [s for s in phases if s["name"].endswith(name)]
+        built = [s["attrs"]["fun_name"] for s in spans
+                 if s["name"] == "compile/program"
+                 and s["parent_id"] == phase["span_id"]]
+        assert program in built and phase["attrs"]["compiled"] == len(built)
+        assert 0 < phase["attrs"]["compile_s"] <= phase["dur_s"]
+
+    sample = _make_sample(rng, n_items=8)
+    spec = MicroBatchSpec(n_mbs=1, max_tokens_per_mb=256)
+    rounds = []
+    for _ in range(2):
+        eng.train_batch(sample, spec, _sft_loss, fetch_stats=False)
+        rounds.append(tracing.drain())
+    (dispatch,) = [s for s in rounds[0] if s["name"] == "train_pipe/dispatch"]
+    assert dispatch["attrs"]["compiled"] >= 1
+    assert "jit(train_step)" in [
+        s["attrs"]["fun_name"] for s in rounds[0]
+        if s["name"] == "compile/program"
+        and s["parent_id"] == dispatch["span_id"]]
+    assert not [s for s in rounds[1] if s["name"] == "compile/program"]
+    assert all("compiled" not in s.get("attrs", {}) for s in rounds[1])
+
+
 def test_forward_unpacks_per_sequence(engine, rng):
     sample = _make_sample(rng, n_items=5)
 
