@@ -706,9 +706,12 @@ class GenerationEngine:
                 # paged-decode kernel computes over / KV tokens resident
                 "kernel_positions": 0,
                 "resident_tokens": 0,
-                # ... and its grid steps that reach a page / all its grid steps
+                # ... and its grid steps that reach a page / all its grid
+                # steps / the reached steps whose copies a reached step of
+                # another block starts (the prefetch chain's block crossings)
                 "kernel_steps_active": 0,
                 "kernel_steps": 0,
+                "kernel_steps_chained": 0,
                 # pool tiles the ``kv_page_write`` kernel writes: of a vanilla
                 # chunk as dispatched, of an admission wave's prefill; stays 0
                 # where the XLA scatter writes the pool (``_kv_write_rows``)
@@ -2441,7 +2444,10 @@ class GenerationEngine:
         ``kernel_positions``: KV positions its body runs over (over
         ``resident_tokens``, how many times the resident KV it computes);
         ``kernel_steps_active`` of ``kernel_steps`` grid steps reach a page
-        and walk their table entries (the rest cost one test). Free slots
+        and walk their table entries (the rest cost one test);
+        ``kernel_steps_chained`` of the reached ones are the first of a
+        block after the call's first, their copies started by the last
+        reached step of an earlier block. Free slots
         count as empty (on the device a finished slot keeps its length
         until it is refilled). ``None`` where the chunk runs no such
         kernel: the XLA gather path, a speculative chunk."""
@@ -2468,13 +2474,15 @@ class GenerationEngine:
         )
         lens, span = np.sort(self._lens_host), kp * self.page
         by_kind = []
+        nblk = -(-W // kp)
         for w in self._windows:
             first = None if w is None else pl_paged.first_visible(lens, w)
             by_kind.append((
                 pl_paged.kernel_positions(lens, sb, span, first),
-                *pl_paged.kernel_steps(lens, sb, span, -(-W // kp), first),
+                *pl_paged.kernel_steps(lens, sb, span, nblk, first),
+                pl_paged.kernel_steps_chained(lens, sb, span, nblk, first),
             ))
-        positions, active, total = (sum(x) for x in zip(*by_kind))
+        positions, active, total, chained = (sum(x) for x in zip(*by_kind))
         # a layer's call on average: a window layer computes over fewer
         # positions than are resident, so with layer kinds this can read
         # under the resident tokens
@@ -2482,6 +2490,7 @@ class GenerationEngine:
             "kernel_positions": positions // len(by_kind),
             "kernel_steps_active": active,
             "kernel_steps": total,
+            "kernel_steps_chained": chained,
         }
         if self._windowed:
             for kind in ("full", "window"):

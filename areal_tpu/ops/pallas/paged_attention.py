@@ -11,27 +11,46 @@ reference inherits (SURVEY §2.1).
 
 Grid ``(B/SB, ceil(M/KP))``: SB slots x KP pages (``S = KP * page``
 positions) per step, ``(SB, KP)`` from :func:`block_plan`. What a call
-costs has four parts (measured alone on a TPU v5e at page 128, bf16, at
-128 slots x 12q/2kv x 128 with a table of 40 pages unless said; PERF.md,
-PRs 25 and 27):
+costs, measured alone on a TPU v5e at page 128, bf16, at 128 slots x
+12q/2kv x 128 unless said (PERF.md §6, PRs 25, 27 and 36):
 
-- a grid step costs 0.3-0.45 us whatever it holds (the pipeline's block
-  copies and ONE test of the block's longest row against the step's first
-  position): 80 steps 0.026 ms, 256 steps (64 slots, 16 kv heads, SB 1)
-  0.11 ms, with NO token resident. A step that row does not reach has no
-  page to fetch and nothing the body would read, and does nothing else:
-  no copy started, no page zeroed, no wait, no body.
-  :func:`kernel_steps` counts the steps that are reached (a third of them
-  on heavy-tailed rollout traffic, once rows are sorted);
-- a step that IS reached walks its ``SB * KP`` table entries on the scalar
-  core (a start and a zero branch for the next step, a wait branch for
-  this one), page or no page: about 36 ns an entry (all ``B * M`` of them
-  would be 0.19 ms a call). Bounding the walk by each row's own page
-  count (dynamic loops in place of the unrolled branches) measured
-  0.1-0.2 % of a call and is not taken;
+- the call and its grid: 0.025 ms with NO token resident at 32 grid steps,
+  0.029 at 80, 0.11 at 256 (64 slots, 16 kv heads, SB 1). A step its block
+  does not reach does the ``j == 0`` init, the last step's fold of the
+  current token and ONE test of the block's rows against the step's
+  positions (``_block_span``), and nothing else: no copy started, no page
+  zeroed, no wait, no body, no look at the next step. That is 0.1 us a
+  step, 0.2 us where reached steps lie between them (48 unreached steps
+  among 32 reached ones add 0.011 ms). :func:`kernel_steps` counts the
+  steps that are reached (a third of them on heavy-tailed rollout traffic,
+  once rows are sorted);
 - page DMAs are issued per slot, only for pages the slot holds, so the
-  bytes read from HBM are the resident KV and no more; they stream at
-  about 950 GB/s and overlap the dots;
+  bytes read from HBM are the resident KV and no more. Where EVERY step is
+  reached the copies of step ``n + 1`` run under the dots of step ``n`` and
+  the call reads its bytes at 713 GB/s (all rows 2,048: 0.376 ms) to 734
+  GB/s (all rows 4,096: 0.731 ms), 87-90 % of the chip's 819: the steady
+  state. The scalar walk over a reached step's ``SB * KP`` table entries
+  (a start or a zero branch for the next step, a wait branch for this one)
+  hides under it: bounding the walk by each row's own page count measured
+  0.1-0.2 % of a call, folding start and zero into one branch 0.0-0.3 %
+  (PR 27). An earlier fit of "36 ns an entry, bytes at 950 GB/s" was the
+  next item read as a walk: no byte here moves faster than 819 GB/s. What
+  the entries DID cost was the host's time: written as Python loops each
+  was traced on its own by every chunk program at every start, so they
+  are ONE traced visit that the lowering unrolls (``_each_entry``);
+- what the steady state loses at a block's edge. The prefetch chain runs
+  over REACHED steps in grid order (``_next_reached``; its host twin is
+  :func:`reached_chain`): the last reached step of a block starts the first
+  reached step of the next block that reaches any, over whatever unreached
+  steps and empty blocks lie between. Until PR 36 the chain ran over grid
+  steps ``g -> g + 1``: at every block's last reached step it started
+  nothing, the step ended with the DMA queue empty and the next block's
+  first step waited for its copies in full, ~2.5 us a crossing alone (all
+  rows 2,048 in a table of 40 pages, 16 crossings: 0.427 ms, now 0.387;
+  with one reached step a block, all rows 1,024: 0.248 -> 0.207) and ~3 in
+  the 1.5B rollout cell (a call 0.330 -> 0.280 ms).
+  :func:`kernel_steps_chained` counts the crossings: ~15 a call in the
+  1.5B rollout cell, 63 at SB 1;
 - the body (QK dot, softmax, PV dot, batched over ``[SB*Hkv, S, D]``)
   runs for the WHOLE block of SB rows at every page block up to
   ``ceil(max_len / S)`` of its LONGEST row, and ``_zero`` stores a page of
@@ -40,7 +59,12 @@ PRs 25 and 27):
   = NaN`` in the PV dot). :func:`kernel_positions` counts that:
   ``SB * S * ceil(max_len / S)`` summed over blocks. Rows of mixed length
   in one block are work over positions that hold no KV (2.4 x the
-  resident KV with rows in random order, 1.5 x sorted).
+  resident KV with rows in random order, 1.5 x sorted). At SB 8 such
+  blocks also lose what the chain wins: on rows in SLOT order it measures
+  6-9 % SLOWER alone than the chain over grid steps did (at SB 4 1 % and
+  at SB 1 12 % faster; sorted 9-27 % faster at the five cells'
+  geometries). Why is not established (the zero stores of a mixed block
+  are the suspect); it is one more reason for the caller's sort.
 
 THE CALLER ORDERS ROWS BY LENGTH (``decode_step_paged`` sorts the batch
 once per step, before its layer scan), so a block's rows are of
@@ -65,8 +89,9 @@ be stale: the engine gives such pages back to the free list while the
 request runs, ``gen/engine.py``), the page the edge falls in is masked
 inside, and a grid step that lies wholly before the first position of
 every row of its block costs the one test, as a step past the longest row
-does (``_steps_reached`` counts both ends). The full-attention program
-has none of this and is what it was.
+does (``_reached_spans`` gives both ends; the chain enters a block at its
+FIRST reached step, not at step 0). The full-attention program has none
+of this and is what it was.
 """
 
 import functools
@@ -146,17 +171,30 @@ def first_visible(lens, sliding_window: Optional[int]):
     return (lens + 1 - sliding_window).clip(0)
 
 
+def _reached_spans(lens, sb: int, span: int, first=None, nblk=None):
+    """``(lo, hi)`` a block of ``sb`` consecutive rows of ``lens``: the grid
+    steps ``lo <= j < hi`` hold a visible position of some row of the block.
+    ``hi`` is ``ceil(longest / span)``, held to the table's ``nblk`` page
+    blocks where given; ``lo`` is 0, or with the rows' ``first`` visible
+    positions (a window layer) the step the block's least one falls in. A
+    block that reaches nothing has ``lo == hi``."""
+    longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
+    hi = -(-longest // span)
+    if nblk is not None:
+        hi = np.minimum(hi, nblk)
+    if first is None:
+        return np.zeros_like(hi), hi
+    least = np.asarray(first, np.int64).reshape(-1, sb).min(axis=1)
+    return np.minimum(least // span, hi), hi
+
+
 def _steps_reached(lens, sb: int, span: int, first=None) -> int:
     """Grid steps whose block of ``sb`` consecutive rows of ``lens`` reaches
     the step's first position: ``ceil(longest / span)`` a block; with the
     rows' ``first`` visible positions (a window layer), less the steps that
     end before the block's least one."""
-    longest = np.asarray(lens, np.int64).reshape(-1, sb).max(axis=1)
-    steps = -(-longest // span)
-    if first is not None:
-        least = np.asarray(first, np.int64).reshape(-1, sb).min(axis=1)
-        steps = np.maximum(steps - least // span, 0)
-    return int(steps.sum())
+    lo, hi = _reached_spans(lens, sb, span, first)
+    return int((hi - lo).sum())
 
 
 def kernel_positions(lens, sb: int, span: int, first=None) -> int:
@@ -177,8 +215,85 @@ def kernel_steps(
     kernel's order, as for :func:`kernel_positions`) with ``nblk`` page
     blocks a row: a block of ``sb`` rows is active in the steps its longest
     row reaches. Only those walk their table entries, wait and run the
-    body; the others cost one test each."""
+    body; the others cost one test."""
     return _steps_reached(lens, sb, span, first), len(lens) // sb * nblk
+
+
+def reached_chain(lens, sb: int, span: int, nblk: int, first=None):
+    """The kernel's prefetch chain over rows ``lens`` (any order), as four
+    vectors a block of ``sb`` rows: ``(lo, hi, nxt, before)``. The block's
+    reached steps are ``lo <= j < hi`` (:func:`_reached_spans`, ``hi`` held
+    to ``nblk``); ``nxt`` is the next block that reaches any step (the
+    number of blocks where none does); ``before`` counts the reached steps
+    of the blocks before it. So reached step ``(b, j)`` is the call's
+    ``before[b] + j - lo[b]``-th (its scratch buffer is that ordinal's
+    parity) and starts the copies of ``(b, j + 1)`` where ``j + 1 <
+    hi[b]``, else of ``(nxt[b], lo[nxt[b]])``, else of nothing."""
+    lo, hi = _reached_spans(lens, sb, span, first, nblk)
+    nb = len(lo)
+    nxt = np.full(nb, nb, np.int64)
+    after = nb
+    for b in range(nb - 1, -1, -1):
+        nxt[b] = after
+        if lo[b] < hi[b]:
+            after = b
+    before = np.concatenate([[0], np.cumsum(hi - lo)[:-1]])
+    return lo, hi, nxt, before
+
+
+def kernel_steps_chained(
+    lens, sb: int, span: int, nblk: int, first=None
+) -> int:
+    """Reached steps of a call (:func:`kernel_steps`) whose copies a reached
+    step of ANOTHER block starts: every block that reaches a step but the
+    call's first such. Before the chain ran over reached steps each of these
+    waited for its copies in full behind an empty DMA queue."""
+    lo, hi = _reached_spans(lens, sb, span, first, nblk)
+    return max(int((lo < hi).sum()) - 1, 0)
+
+
+def _block_span(lens_ref, first_ref, b, *, sb: int, S: int, nblk: int):
+    """``(lo, hi)`` of block ``b`` on the scalar core (:func:`_reached_spans`
+    held to ``nblk``; ``first_ref`` is ``None`` but in a window program):
+    step ``j`` is reached where ``lo <= j < hi``. The ONE predicate copies
+    are started, waited for and chained under. Refs or arrays."""
+    rows = [b * sb + s for s in range(sb)]
+    longest = functools.reduce(jnp.maximum, [lens_ref[r] for r in rows])
+    hi = jnp.minimum(pl.cdiv(longest, S), nblk)
+    if first_ref is None:
+        return jnp.zeros_like(hi), hi
+    least = functools.reduce(jnp.minimum, [first_ref[r] for r in rows])
+    return least // S, hi
+
+
+def _first_reached(lens_ref, first_ref, b0, *, nb: int, **plan):
+    """``(b, lo, found)``: the first block from ``b0`` on that reaches a
+    step, and that step; a bounded walk over blocks on the scalar core
+    (over a whole call every block is looked at once)."""
+    def span(b):
+        return _block_span(
+            lens_ref, first_ref, jnp.minimum(b, nb - 1), **plan)
+
+    b0 = jnp.asarray(b0, jnp.int32)
+    b, lo, _ = jax.lax.while_loop(
+        lambda c: (c[0] < nb) & (c[1] >= c[2]),
+        lambda c: (c[0] + 1, *span(c[0] + 1)),
+        (b0, *span(b0)),
+    )
+    return b, lo, b < nb
+
+
+def _next_reached(lens_ref, first_ref, bb, j, *, nb: int, **plan):
+    """``(b, j, found)``: the reached step after reached step ``(bb, j)`` in
+    grid order (:func:`reached_chain` is its host twin): the block's next
+    where it reaches one, else the first of the next block that reaches
+    any."""
+    _, hi = _block_span(lens_ref, first_ref, bb, **plan)
+    stay = j + 1 < hi
+    # staying, the walk starts past the last block and looks at none
+    b, lo, found = _first_reached(
+        lens_ref, first_ref, jnp.where(stay, nb, bb + 1), nb=nb, **plan)
+    return jnp.where(stay, bb, b), jnp.where(stay, j + 1, lo), stay | found
 
 
 def _decode_kernel(
@@ -187,6 +302,8 @@ def _decode_kernel(
     page: int,
     kp: int,
     sb: int,
+    nb: int,
+    nblk: int,
     n_kv: int,
     n_rep: int,
     soft_cap: Optional[float],
@@ -222,6 +339,9 @@ def _decode_kernel(
     #   acc_scr    [SB, HqP, Dp] f32
     #   sems       DMA semaphores [2, SB, KP]
     #   sc_sems    DMA semaphores [2, SB, KP]                 (quantized)
+    #   ord_scr    [1] int32 SMEM: which of the two buffers the NEXT reached
+    #              step's pages are in (the parity of its ordinal)
+    *refs, ord_scr = refs
     first_ref = None
     if windowed:
         first_ref, refs = refs[3], refs[:3] + refs[4:]
@@ -241,9 +361,6 @@ def _decode_kernel(
     n_str = 1 if latent else 2
     bb = pl.program_id(0)
     j = pl.program_id(1)
-    nblk = pl.num_programs(1)
-    total = pl.num_programs(0) * nblk
-    g = bb * nblk + j         # linearized grid step
     Hq = q_ref.shape[1]
     D = q_ref.shape[2]
     Dv = dv if latent else D  # width of a value
@@ -255,19 +372,6 @@ def _decode_kernel(
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def _reaches(bb_t, j_t):
-        """Whether step ``j_t`` holds a visible position of any row of block
-        ``bb_t``: the predicate copies are started AND waited for under."""
-        rows = [bb_t * sb + s for s in range(sb)]
-        longest = functools.reduce(
-            jnp.maximum, [lens_ref[r] for r in rows])
-        reached = j_t * S < longest
-        if windowed:
-            least = functools.reduce(
-                jnp.minimum, [first_ref[r] for r in rows])
-            reached &= (j_t + 1) * S > least
-        return reached
 
     def _page_tests(slot):
         """``tests(pg) -> (held, not held)`` for the pages of the slot's
@@ -285,102 +389,127 @@ def _decode_kernel(
 
         return tests
 
-    def _issue(g_t, buf):
+    def _each_entry(bb_t, visit):
+        """``visit(s, slot, i, at, tests)`` over the ``SB * KP`` table
+        entries of a step of block ``bb_t``: row ``s`` of the block is
+        ``slot`` with its page ``tests``, entry ``i`` of the step lands in
+        the stripe ``at`` of the row's scratch. The visit is traced ONCE and
+        unrolled where the kernel is lowered; as Python loops every entry
+        was a trace of its own, in every chunk program at every start
+        (PERF.md §6, PR 36)."""
+        def row(s, _):
+            slot = bb_t * sb + s
+            tests = _page_tests(slot)
+
+            def entry(i, _):
+                at = pl.ds(pl.multiple_of(i * page, page), page)
+                visit(s, slot, i, at, tests)
+
+            jax.lax.fori_loop(0, kp, entry, None, unroll=True)
+
+        jax.lax.fori_loop(0, sb, row, None, unroll=True)
+
+    def _issue(bb_t, j_t, buf):
         """Start every resident-page DMA (and zero un-DMA'd tail blocks the
-        body will read) for linear grid step ``g_t`` into buffer ``buf``."""
-        bb_t = g_t // nblk
-        j_t = g_t % nblk
+        body will read) for REACHED step ``j_t`` of block ``bb_t`` into
+        buffer ``buf``."""
         # the batched body reads EVERY slot's stripe whenever any slot of
         # the block is active, so un-DMA'd pages of shorter slots must be
         # zeroed up to the block the longest slot reaches (masked
         # probabilities are 0, but 0 * NaN = NaN in the PV dot)
-        # a step its block's longest row does not reach (or, in a window
-        # layer, that ends before every row's first visible position) has
-        # no page to fetch and nothing the body will read: ONE test skips
-        # its SB * KP entries (most steps, once the caller has sorted rows
-        # by length)
-        @pl.when(_reaches(bb_t, j_t))
-        def _reached():
-            for s in range(sb):
-                slot = bb_t * sb + s
-                tests = _page_tests(slot)
-                for i in range(kp):
-                    held, free = tests(j_t * kp + i)
+        def visit(s, slot, i, at, tests):
+            held, free = tests(j_t * kp + i)
 
-                    @pl.when(held)
-                    def _start(s=s, i=i, slot=slot):
-                        pidx = table_ref[slot, j_t * kp + i]
-                        # K and V are interleaved per page: ONE DMA per
-                        # page, landing in the [2, Hkv, i*page:(i+1)*page,
-                        # D] stripe of the compute-layout scratch
-                        pltpu.make_async_copy(
-                            kv_hbm.at[layer, pidx],
-                            kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
-                            sems.at[buf, s, i],
-                        ).start()
-                        if quantized:
-                            # the page's scale stripe rides a second (tiny
-                            # — 1/D of the page bytes) DMA into the
-                            # parallel scale scratch; dequant happens
-                            # in-register at the dots, never as a widened
-                            # pool copy
-                            pltpu.make_async_copy(
-                                sc_hbm.at[layer, pidx],
-                                sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
-                                sc_sems.at[buf, s, i],
-                            ).start()
+            @pl.when(held)
+            def _start():
+                pidx = table_ref[slot, j_t * kp + i]
+                # K and V are interleaved per page: ONE DMA per page,
+                # landing in the [2, Hkv, i*page:(i+1)*page, D] stripe of
+                # the compute-layout scratch
+                pltpu.make_async_copy(
+                    kv_hbm.at[layer, pidx],
+                    kv_scr.at[buf, s, :, :, at, :],
+                    sems.at[buf, s, i],
+                ).start()
+                if quantized:
+                    # the page's scale stripe rides a second (tiny — 1/D
+                    # of the page bytes) DMA into the parallel scale
+                    # scratch; dequant happens in-register at the dots,
+                    # never as a widened pool copy
+                    pltpu.make_async_copy(
+                        sc_hbm.at[layer, pidx],
+                        sc_scr.at[buf, s, :, :, at],
+                        sc_sems.at[buf, s, i],
+                    ).start()
 
-                    @pl.when(free)
-                    def _zero(s=s, i=i):
-                        kv_scr[buf, s, :, :, pl.ds(i * page, page), :] = (
-                            jnp.zeros((n_str, n_kv, page, D), kv_scr.dtype)
-                        )
-                        if quantized:
-                            sc_scr[buf, s, :, :, pl.ds(i * page, page)] = (
-                                jnp.zeros((2, n_kv, page), sc_scr.dtype)
-                            )
+            @pl.when(free)
+            def _zero():
+                kv_scr[buf, s, :, :, at, :] = (
+                    jnp.zeros((n_str, n_kv, page, D), kv_scr.dtype)
+                )
+                if quantized:
+                    sc_scr[buf, s, :, :, at] = (
+                        jnp.zeros((2, n_kv, page), sc_scr.dtype)
+                    )
 
-    # Software pipeline over the (sequential) linearized grid: step g's
-    # pages were prefetched at step g-1; here we kick off g+1's DMAs BEFORE
-    # consuming g's, so the HBM reads for the next block overlap this
-    # block's dots. Un-overlapped DMA cost drops from every grid step to
-    # one per kernel call (measured r4: the serial issue->wait->compute
-    # loop held the kernel at ~0.42 of HBM bandwidth).
-    buf = jax.lax.rem(g, 2)
+        _each_entry(bb_t, visit)
 
-    @pl.when(g == 0)
+    # Software pipeline over the REACHED steps of the (sequential) grid, in
+    # grid order: a reached step's pages were started by the reached step
+    # before it, in whatever block that lies, and it starts the next reached
+    # step's BEFORE it waits for its own, so the HBM reads of the next page
+    # block run under this one's dots across block boundaries too (a chain
+    # over grid steps ``g -> g + 1`` started nothing at the last reached step
+    # of every block: that step ended with the DMA queue empty and the next
+    # block's first step waited for its copies in full). The call's first
+    # reached step is started here, at grid step 0. A step that is not
+    # reached starts nothing, waits for nothing and looks for nothing. The
+    # two buffers alternate with the ORDINAL of the reached step, kept as a
+    # parity in SMEM across the grid: a block that reaches an odd number of
+    # steps hands the next block the other buffer.
+    plan = dict(sb=sb, S=S, nblk=nblk, nb=nb)
+
+    @pl.when((bb == 0) & (j == 0))
     def _prologue():
-        _issue(0, 0)
+        ord_scr[0] = 0
+        b_t, j_t, found = _first_reached(lens_ref, first_ref, 0, **plan)
 
-    @pl.when(g + 1 < total)
-    def _prefetch():
-        _issue(g + 1, jax.lax.rem(g + 1, 2))
+        @pl.when(found)
+        def _():
+            _issue(b_t, j_t, 0)
 
-    # the predicate _issue started this step's copies under, over the same
-    # scalars: every started copy is waited for, and a step that started
-    # none tests nothing
-    reached = _reaches(bb, j)
+    # the predicate this step's copies were started under, over the same
+    # scalars (``_block_span``): every started copy is waited for, and a
+    # step that started none tests nothing
+    lo, hi = _block_span(lens_ref, first_ref, bb, sb=sb, S=S, nblk=nblk)
+    reached = (lo <= j) & (j < hi)
+    buf = ord_scr[0]
 
     @pl.when(reached)
     def _arrived():
-        for s in range(sb):
-            slot = bb * sb + s
-            tests = _page_tests(slot)
-            for i in range(kp):
-                @pl.when(tests(j * kp + i)[0])
-                def _wait(s=s, i=i, slot=slot):
-                    pidx = table_ref[slot, j * kp + i]
+        b_t, j_t, found = _next_reached(lens_ref, first_ref, bb, j, **plan)
+
+        @pl.when(found)
+        def _prefetch():
+            _issue(b_t, j_t, 1 - buf)
+
+        def visit(s, slot, i, at, tests):
+            @pl.when(tests(j * kp + i)[0])
+            def _wait():
+                pidx = table_ref[slot, j * kp + i]
+                pltpu.make_async_copy(
+                    kv_hbm.at[layer, pidx],
+                    kv_scr.at[buf, s, :, :, at, :],
+                    sems.at[buf, s, i],
+                ).wait()
+                if quantized:
                     pltpu.make_async_copy(
-                        kv_hbm.at[layer, pidx],
-                        kv_scr.at[buf, s, :, :, pl.ds(i * page, page), :],
-                        sems.at[buf, s, i],
+                        sc_hbm.at[layer, pidx],
+                        sc_scr.at[buf, s, :, :, at],
+                        sc_sems.at[buf, s, i],
                     ).wait()
-                    if quantized:
-                        pltpu.make_async_copy(
-                            sc_hbm.at[layer, pidx],
-                            sc_scr.at[buf, s, :, :, pl.ds(i * page, page)],
-                            sc_sems.at[buf, s, i],
-                        ).wait()
+
+        _each_entry(bb, visit)
 
     # per-slot resident lengths as an [SB, 1, S] operand built from stacked
     # scalar SPLATS (Mosaic rejects 1D->3D vector reshapes); the whole
@@ -394,11 +523,11 @@ def _decode_kernel(
              for s in range(sb)]
         )                                                      # [SB, 1, S]
 
-    lens_v = _splat(lens_ref)
-    first_v = _splat(first_ref) if windowed else None
-
     @pl.when(reached)
     def _body():
+        lens_v = _splat(lens_ref)
+        first_v = _splat(first_ref) if windowed else None
+        ord_scr[0] = 1 - buf
         # (SB, Hkv) folds into ONE batch dim (Mosaic's tpu.matmul supports
         # a single batch dim); the reshape is layout-free
         q = q_ref[...].reshape(sb * n_kv, n_rep, D)
@@ -564,6 +693,8 @@ def decode(
         page=page,
         kp=kp,
         sb=sb,
+        nb=B // sb,
+        nblk=nblk,
         n_kv=Hkv,
         n_rep=n_rep,
         soft_cap=soft_cap,
@@ -601,6 +732,8 @@ def decode(
         )
         scratch_shapes.append(pltpu.SemaphoreType.DMA((2, sb, kp)))
         operands.append(scales)
+    # the parity of the next reached step's ordinal, last of the scratch
+    scratch_shapes.append(pltpu.SMEM((1,), jnp.int32))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

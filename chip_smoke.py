@@ -33,6 +33,7 @@ nothing about the chip.
 
 import argparse
 import concurrent.futures
+import functools
 import glob
 import json
 import math
@@ -557,6 +558,16 @@ def phase_serve(sz, args):
                 for c in kv_write["cases"]),
             f"kv_page_write and the XLA scatter leave different pools: "
             f"{kv_write['cases']}")
+    # ... the paged-decode kernel alone against the gather path, on rows
+    # whose prefetch chain crosses empty blocks, sorted and in slot order
+    paged, _ = helper(d, "pageddecode", {
+        "arch": sz["arch"], "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(all(c["max_abs_diff_vs_gather"] <= paged["tolerance"]
+                for c in paged["cases"])
+            and paged["sorted_rows_bit_equal_to_shuffled"],
+            f"paged_decode disagrees with the gather path, or with itself "
+            f"on the same rows in another order: {paged}")
     # ... and the decode epilogue alone: the fused kernel (compiled on the
     # chip, interpreted off it) against the head on the same hidden states
     fused, _ = helper(d, "fusedsample", {
@@ -623,6 +634,7 @@ def phase_serve(sz, args):
         "kv_write_lowerings": len(kv_writes), "kv_write_kernels": write_kernels,
         "kv_write_tiles": metrics.get("engine_kv_write_tiles"),
         "kv_write_vs_scatter": kv_write["cases"],
+        "paged_decode_vs_gather": paged,
         "kv_dtype": metrics.get("kv_dtype"),
         "fused_sample": metrics.get("fused_sample"),
         "fused_rows": metrics.get("engine_fused_rows"),
@@ -1091,6 +1103,80 @@ def child_kvwrite(arg):
     emit({"cases": cases})
 
 
+def child_pageddecode(arg):
+    """The paged-decode kernel alone (compiled on the chip, interpreted off
+    it) against the XLA gather path over one pool of the model's K/V
+    geometry: a heavy-tailed batch with whole blocks of empty rows in the
+    middle, handed over sorted by length (as ``decode_step_paged`` does)
+    and shuffled, so the kernel's prefetch chain over reached steps starts
+    past empty blocks, crosses them, and alternates its two buffers over
+    blocks that reach one step and blocks that reach every one. A row's
+    result may not depend on the order."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.ops import paged_attention as paged_ops
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    arch = arg["arch"]
+    dtype = jnp.dtype(arch["dtype"])
+    Hq, Hkv, D = arch["n_q_heads"], arch["n_kv_heads"], arch["head_dim"]
+    B, page, M = (48, 8, 24) if arg["rehearse"] else (64, 128, 40)
+    rng = np.random.default_rng(arg["seed"])
+    cap = M * page - 1
+    lens = np.minimum(rng.lognormal(np.log(cap / 6), 0.9, B), cap)
+    lens = lens.astype(np.int32)
+    lens[rng.integers(B)] = cap
+    sb, kp = pl_paged.block_plan(B, Hkv, D, page, M, dtype)
+    lens[2 * sb:4 * sb] = 0            # two whole blocks, in the middle
+    lens[-2 * sb:-sb] = 0
+    need = -(-lens // page)
+    table = np.zeros((B, M), np.int32)
+    pages = rng.permutation(int(need.sum())) + 1
+    for b, at in enumerate(np.cumsum(need) - need):
+        table[b, :need[b]] = pages[at:at + need[b]]
+    pool = jnp.asarray(rng.standard_normal(
+        (1, 1 + int(need.sum()), 2, Hkv, page, D)), dtype)
+    q, ks, vs = (
+        jnp.asarray(rng.standard_normal((B, h, D)), dtype)
+        for h in (Hq, Hkv, Hkv))
+
+    def attend(rows, use_pallas):
+        return np.asarray(jax.jit(functools.partial(
+            paged_ops.paged_decode_attention, use_pallas=use_pallas,
+        ))(q[rows], ks[rows], vs[rows], pool, jnp.int32(0),
+           jnp.asarray(table[rows]), jnp.asarray(lens[rows]),
+        ).astype(jnp.float32))
+
+    orders = {"shuffled": np.arange(B), "sorted": np.argsort(lens)}
+    span, nblk = kp * page, -(-M // kp)
+    got, cases = {}, []
+    for name, rows in orders.items():
+        got[name] = attend(rows, True)
+        active, total = pl_paged.kernel_steps(lens[rows], sb, span, nblk)
+        cases.append({
+            "rows": name, "steps_active": active, "steps": total,
+            "steps_chained": pl_paged.kernel_steps_chained(
+                lens[rows], sb, span, nblk),
+            "max_abs_diff_vs_gather": float(
+                np.abs(got[name] - attend(rows, False)).max()),
+        })
+    emit({
+        "slots": B, "heads": [Hq, Hkv, D], "page": page, "table": M,
+        "plan": [sb, kp], "resident_tokens": int(lens.sum()),
+        "cases": cases,
+        # both paths accumulate in float32 and round once to the dtype
+        "tolerance": 2e-5 if dtype == jnp.float32 else 2e-2,
+        "sorted_rows_bit_equal_to_shuffled": bool(np.array_equal(
+            got["sorted"], got["shuffled"][orders["sorted"]])),
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 def child_fusedsample(arg):
     """One chunk's steps of the decode epilogue alone, at the served
     model's head: the fused head-and-sample kernel's log-probs of the
@@ -1176,7 +1262,7 @@ def child_fusedsample(arg):
 CHILDREN = {
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
-    "fusedsample": child_fusedsample,
+    "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,
 }
 
 

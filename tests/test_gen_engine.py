@@ -642,7 +642,9 @@ class TestEngineSpans:
         them over), ``sb`` x the page blocks of its longest row; those page
         blocks are ``kernel_steps_active`` of the ``kernel_steps`` grid
         steps (blocks x page blocks of the table), the steps that still
-        walk their table entries. Only on chunks that run the kernel
+        walk their table entries, and ``kernel_steps_chained`` of those are
+        started by the last reached step of an earlier block (every block
+        that reaches a step but the first). Only on chunks that run the kernel
         (forced on here, interpret mode; on the CPU's own XLA gather path
         and in a speculative chunk there is nothing to count), and the
         tokens are those of the gather path either way."""
@@ -662,7 +664,8 @@ class TestEngineSpans:
         outs = {o.rid: o.output_ids for o in eng.run_until_done(4)}
         chunks = _chunks_with_children(tracing.drain())
         attrs = [c["attrs"] for c, _ in chunks]
-        names = ("kernel_positions", "kernel_steps_active", "kernel_steps")
+        names = ("kernel_positions", "kernel_steps_active", "kernel_steps",
+                 "kernel_steps_chained")
         if path != "kernel":
             assert attrs
             assert not any(n in a for a in attrs for n in names)
@@ -690,6 +693,9 @@ class TestEngineSpans:
             3 * -(-w // 8) for w in widths]
         assert all(a["kernel_steps_active"] < a["kernel_steps"]
                    for a in attrs)
+        # sorted, the three free slots share block 0 with a short row: all
+        # three blocks reach a step, two of them behind another block's
+        assert [a["kernel_steps_chained"] for a in attrs] == [2, 2]
         for n in names + ("resident_tokens",):
             assert eng.stats[n] == sum(a[n] for a in attrs)
         ref = GenerationEngine(

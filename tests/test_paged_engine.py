@@ -492,39 +492,74 @@ class TestLengthOrderedDecode:
 class TestPagedDecodeStepGate:
     """The kernel against the XLA gather path at the shapes its step gate
     decides on: a grid step whose slot block reaches no page starts no copy,
-    zeroes nothing, waits for nothing and runs no body, while the prefetch
-    of step g+1 keeps crossing between such steps and steps with work.
-    Blocks of 2 slots x 2 pages of 8 (a span of 16 positions) unless the
-    case says otherwise: the interpreter is slow."""
+    zeroes nothing, waits for nothing and runs no body, and the prefetch
+    chain runs from each REACHED step to the next one in grid order, over
+    whatever unreached steps and empty blocks lie between (``chained``: how
+    many times it crosses into another block). Rows in ANY order: each case
+    is also run with its rows sorted by length, to the bit the same result
+    a row. Blocks of 2 slots x 2 pages of 8 (a span of 16 positions) unless
+    the case says otherwise: the interpreter is slow."""
 
     PAGE = 8
 
     @pytest.mark.parametrize(
-        "lens,width,kp,sb,variant",
+        "lens,width,kp,sb,variant,chained",
         [
-            # empty block | block with work | empty block: the prefetch
-            # crosses from a skipped step into an active one and back
-            pytest.param([0, 0, 5, 20, 0, 0], 4, 2, 2, "plain",
+            # empty block | block with work | empty block: the prologue
+            # starts a step of block 1, whose last step starts nothing
+            pytest.param([0, 0, 5, 20, 0, 0], 4, 2, 2, "plain", 0,
                          id="empty-work-empty"),
             # one long row among empty ones: its block is active to the
             # end, every page of the other row zeroed
-            pytest.param([0, 44, 0, 0], 6, 2, 2, "plain", id="one-long"),
+            pytest.param([0, 44, 0, 0], 6, 2, 2, "plain", 0, id="one-long"),
             # exactly at the span, one over, one under; a table whose last
             # page block is partial (5 pages in blocks of 2)
-            pytest.param([16, 16, 17, 1, 15, 0, 32, 33], 5, 2, 2, "plain",
+            pytest.param([16, 16, 17, 1, 15, 0, 32, 33], 5, 2, 2, "plain", 3,
                          id="span-edges"),
             # the cells' table: 40 pages in 5 blocks of 8, rows sorted as
             # ``decode_step_paged`` hands them over
             pytest.param([0, 0, 3, 60, 64, 65, 130, 319], 40, 8, 2, "plain",
-                         id="table40-kp8"),
+                         2, id="table40-kp8"),
             # one slot a step (the OLMoE cell's plan)
-            pytest.param([0, 20, 0, 33], 5, 2, 1, "plain", id="sb1"),
-            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "int8", id="int8"),
-            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "window",
+            pytest.param([0, 20, 0, 33], 5, 2, 1, "plain", 1, id="sb1"),
+            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "int8", 1, id="int8"),
+            pytest.param([0, 0, 5, 20, 0, 36], 5, 2, 2, "window", 1,
                          id="window"),
+            # the chain over reached steps (PR 36). Rows in random order:
+            # every block reaches its own number of steps
+            pytest.param([37, 2, 0, 9, 48, 47, 1, 30, 16, 0, 5, 41], 6, 2, 2,
+                         "plain", 5, id="random-order"),
+            # empty blocks BETWEEN full ones: the chain jumps two blocks
+            pytest.param([20, 33, 0, 0, 0, 0, 40, 7, 0, 0, 12, 1], 6, 2, 2,
+                         "plain", 2, id="holes"),
+            # nothing resident: the prologue finds no step and starts none
+            pytest.param([0, 0, 0, 0], 4, 2, 2, "plain", 0, id="all-empty"),
+            # ONE reached step in the whole call, started at grid step 0
+            # from two blocks away
+            pytest.param([0, 0, 0, 0, 0, 3, 0, 0], 4, 2, 2, "plain", 0,
+                         id="one-step"),
+            # a block that reaches every step (three: an ODD number) then
+            # one that reaches one, then two: by the parity of the grid step
+            # the second block's step would share the first's last buffer
+            pytest.param([48, 40, 5, 2, 30, 16], 6, 2, 2, "plain", 2,
+                         id="buffer-ordinal"),
+            # a window program whose blocks START past step 0 (first
+            # visible positions 35.. and 65..: steps 2 and 4), an empty
+            # block between them and a short one after
+            pytest.param([40, 44, 0, 0, 70, 75, 3, 9], 10, 2, 2, "window", 2,
+                         id="window-late-start"),
+            # the latent program: one stream, the value the key's head
+            pytest.param([20, 33, 0, 0, 48, 7, 0, 0, 12, 1], 6, 2, 2,
+                         "latent", 2, id="latent"),
+            pytest.param([20, 33, 0, 0, 0, 0, 40, 7, 12, 1], 6, 2, 2, "int8",
+                         2, id="int8-holes"),
+            # one slot a step over a table of 32 pages in 4 blocks of 8
+            # (the OLMoE cell's plan): a drain a slot before the chain
+            pytest.param([0, 200, 70, 256, 0, 130], 32, 8, 1, "plain", 3,
+                         id="sb1-table32-kp8"),
         ],
     )
-    def test_parity_vs_xla(self, lens, width, kp, sb, variant):
+    def test_parity_vs_xla(self, lens, width, kp, sb, variant, chained):
         from areal_tpu.ops import paged_attention as xla_paged
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
@@ -532,34 +567,49 @@ class TestPagedDecodeStepGate:
         lens = np.asarray(lens, np.int32)
         B, Hq, Hkv, D, L, page = len(lens), 2, 1, 16, 2, self.PAGE
         P = B * width
+        latent = variant == "latent"
+        streams = 1 if latent else 2
         assert pl_paged.block_plan(
-            B, Hkv, D, page, width, jnp.float32, kp, sb) == (sb, kp)
-        active, total = pl_paged.kernel_steps(
-            lens, sb, kp * page, -(-width // kp))
-        assert 0 < active < total      # the gate has steps to skip
+            B, Hkv, D, page, width, jnp.float32, kp, sb, streams) == (sb, kp)
+        kw, first = {}, None
+        if variant == "window":
+            kw["sliding_window"] = 6
+            first = pl_paged.first_visible(lens, 6)
+        plan = (sb, kp * page, -(-width // kp), first)
+        active, total = pl_paged.kernel_steps(lens, *plan)
+        assert active < total          # the gate has steps to skip
+        assert pl_paged.kernel_steps_chained(lens, *plan) == chained
         q = rng.normal(size=(B, Hq, D)).astype(np.float32)
         k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
         v_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
-        pool = rng.normal(size=(L, P, 2, Hkv, page, D)).astype(np.float32)
+        pool = rng.normal(
+            size=(L, P, streams, Hkv, page, D)).astype(np.float32)
         table = rng.permutation(P).reshape(B, width).astype(np.int32)
-        kw = {}
         if variant == "int8":
             pool = rng.integers(-127, 128, size=pool.shape).astype(np.int8)
             kw["scales"] = jnp.asarray(rng.uniform(
                 0.001, 0.02, size=pool.shape[:-1]), jnp.float32)
-        if variant == "window":
-            kw["sliding_window"] = 6
-        got = pl_paged.decode(
-            q, k_self, v_self, pool, jnp.int32(1), table, lens,
-            pages_per_step=kp, slots_per_step=sb, **kw,
-        )
+        if latent:
+            v_self, kw["value_width"] = None, 8
+
+        def kernel(rows):
+            return np.asarray(pl_paged.decode(
+                q[rows], k_self[rows], None if latent else v_self[rows],
+                pool, jnp.int32(1), table[rows], lens[rows],
+                pages_per_step=kp, slots_per_step=sb, **kw,
+            ))
+
+        got = kernel(np.arange(B))
         want = xla_paged.paged_decode_attention(
             q, k_self, v_self, pool, jnp.int32(1), table, lens,
             use_pallas=False, **kw,
         )
-        np.testing.assert_allclose(
-            np.asarray(got), np.asarray(want), atol=2e-5
-        )
+        np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
+        # sorted as ``decode_step_paged`` hands the rows over: another
+        # chain, every row's result the same to the bit
+        order = np.argsort(lens, kind="stable")
+        if not np.array_equal(order, np.arange(B)):
+            np.testing.assert_array_equal(kernel(order), got[order])
 
 
 class TestKernelPositions:
@@ -621,6 +671,73 @@ class TestKernelPositions:
                 brute, 64 // sb * nblk)
             assert pl_paged.kernel_positions(rows, sb, span) == (
                 sb * span * brute)
+
+    @pytest.mark.parametrize(
+        "sb,span,nblk,window",
+        [(8, 1024, 5, None), (4, 1024, 5, None), (1, 1024, 4, None),
+         (2, 64, 79, None), (4, 1024, 16, 4096), (2, 64, 79, 200),
+         (1, 64, 20, 130)],
+    )
+    def test_chain_matches_brute_force(self, sb, span, nblk, window):
+        """The prefetch chain over reached steps: the host's four vectors
+        (``reached_chain``), the count on the span
+        (``kernel_steps_chained``) and the kernel's own walk on the scalar
+        core (``_first_reached`` / ``_next_reached``, here over arrays in
+        place of SMEM refs) against a Python loop over the grid, rows
+        sorted, in random order and with blocks emptied in the middle."""
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        rng = np.random.default_rng(sb + nblk)
+        lens = _heavy_tailed_lens(rng, 64, min(5000, nblk * span))
+        holes = lens.copy()
+        holes[rng.integers(0, 64 // sb, 5)[:, None] * sb + np.arange(sb)] = 0
+        plan = dict(sb=sb, S=span, nblk=nblk)
+        for rows in (lens, np.sort(lens), holes, np.zeros(64, np.int32)):
+            first = None
+            if window is not None:
+                first = pl_paged.first_visible(rows, window)
+            # the grid in order, each step under the kernel's gate
+            chain = []
+            for b in range(64 // sb):
+                blk = slice(b * sb, (b + 1) * sb)
+                for j in range(nblk):
+                    reached = j * span < rows[blk].max()
+                    if window is not None:
+                        reached &= (j + 1) * span > first[blk].min()
+                    if reached:
+                        chain.append((b, j))
+            assert pl_paged.kernel_steps(rows, sb, span, nblk, first) == (
+                len(chain), 64 // sb * nblk)
+            # a step of another block than the one before it in the chain
+            assert pl_paged.kernel_steps_chained(
+                rows, sb, span, nblk, first
+            ) == sum(a[0] != b[0] for a, b in zip(chain, chain[1:]))
+            lo, hi, nxt, before = pl_paged.reached_chain(
+                rows, sb, span, nblk, first)
+            nb = 64 // sb
+            for n, (b, j) in enumerate(chain):
+                assert lo[b] <= j < hi[b]
+                assert before[b] + j - lo[b] == n          # the ordinal
+                if j + 1 < hi[b]:
+                    after = (b, j + 1)
+                elif nxt[b] < nb:
+                    after = (nxt[b], lo[nxt[b]])
+                else:
+                    after = None
+                assert after == (chain[n + 1] if n + 1 < len(chain) else None)
+            # the kernel's walk, over the same scalars
+            refs = (jnp.asarray(rows, jnp.int32),
+                    None if first is None else jnp.asarray(first, jnp.int32))
+            b0, j0, found = pl_paged._first_reached(*refs, 0, nb=nb, **plan)
+            assert bool(found) == bool(chain)
+            if chain:
+                assert (int(b0), int(j0)) == chain[0]
+                step = jax.jit(jax.vmap(lambda b, j: pl_paged._next_reached(
+                    *refs, b, j, nb=nb, **plan)))
+                bs, js, ok = (np.asarray(x) for x in step(
+                    *jnp.asarray(chain, jnp.int32).T))
+                assert ok[:-1].all() and not ok[-1]
+                assert list(zip(bs[:-1], js[:-1])) == chain[1:]
 
     @pytest.mark.parametrize(
         "length,want", [(0, 0), (1, 4), (1024, 4), (1025, 8), (5120, 20)]

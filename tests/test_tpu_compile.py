@@ -18,6 +18,7 @@ is off around these compiles (a described-device entry cannot be read
 back and only produces warnings).
 """
 
+import collections
 import functools
 import os
 import re
@@ -596,6 +597,79 @@ def test_paged_decode_window_compiles_alone(compiled_kernels, one_chip):
         B=HYBRID_CELL["B"], P=HYBRID_CELL["P"], M=HYBRID_CELL["M"],
         layout=QWEN_7B)).as_text()
     assert "paged_decode_window" in text
+
+
+def _count_primitives(jaxpr, counts=None):
+    """Primitive names of ``jaxpr`` and every jaxpr nested in it."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        for value in eqn.params.values():
+            for x in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    _count_primitives(inner, counts)
+    return counts
+
+
+@pytest.mark.parametrize(
+    "program",
+    ["cell1-table32", "cell3-table32", "olmoe-table32", "window-table64",
+     "mla_decode-table64"],
+)
+def test_chained_decode_lowers_with_two_copies_of_issue(
+        compiled_kernels, one_chip, program):
+    """The kernel whose prefetch chain runs over REACHED steps (a walk over
+    blocks on the scalar core, the buffer's parity in SMEM) lowers for a
+    v5e in every program of it, at the NARROWER table width the cells'
+    chunk programs also come in; and it is traced with TWO copies of the
+    page copies' start (the prologue's and the chain's) and ONE of the
+    wait, each ONE entry of the table that the lowering unrolls over the
+    step's ``SB * KP``: what is traced is traced by every chunk program at
+    every start (PERF.md §6, PRs 31, 35 and 36)."""
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    kw, streams = {}, 2
+    if program.startswith("mla_decode"):
+        c = JOYAI_CELL
+        B, hkv, width, streams = c["B"], 1, c["W"], 1
+        specs = [
+            _spec((B, c["H"], width), jnp.bfloat16, one_chip),
+            _spec((B, 1, width), jnp.bfloat16, one_chip),
+            None,
+            _spec((c["L"], c["P"], 1, 1, 128, width), jnp.bfloat16, one_chip),
+            _spec((), jnp.int32, one_chip),
+            _spec((B, 64), jnp.int32, one_chip),
+            _spec((B,), jnp.int32, one_chip),
+        ]
+        kw = dict(softmax_scale=192 ** -0.5, value_width=c["DV"])
+    else:
+        shape = {
+            "cell1-table32": dict(B=128, M=32, P=2588),
+            "cell3-table32": dict(B=64, M=32, P=1311, L=16, layout=QWEN_7B),
+            "olmoe-table32": dict(B=64, M=32, P=715, L=8, layout=OLMOE),
+            "window-table64": dict(
+                B=HYBRID_CELL["B"], M=64, P=HYBRID_CELL["P"],
+                L=HYBRID_CELL["periods"], layout=QWEN_7B),
+        }[program]
+        if program.startswith("window"):
+            kw = dict(sliding_window=4096)
+        specs = _paged_specs(one_chip, page=128, int8=False, **shape)
+        B, hkv, width = specs[0].shape[0], specs[1].shape[1], 128
+    sb, kp = pl_paged.block_plan(
+        B, hkv, width, 128, specs[5].shape[1], jnp.bfloat16, streams=streams)
+
+    def f(q, ks, vs, pages, layer, table, lens):
+        return pl_paged.decode(q, ks, vs, pages, layer, table, lens, **kw)
+
+    counts = _count_primitives(jax.make_jaxpr(f)(*specs).jaxpr)
+    assert sb * kp > 1
+    assert counts["dma_start"] == 2
+    assert counts["dma_wait"] == 1
+    assert counts["while"] == 2
+    name = {"window": "paged_decode_window", "mla_decode": "mla_decode"}.get(
+        program.split("-")[0], "paged_decode")
+    assert re.search(rf"%{name}(\.\d+)? = ", _compile(f, *specs).as_text())
 
 
 @pytest.fixture(scope="module")
