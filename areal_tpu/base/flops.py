@@ -92,6 +92,20 @@ def matmul_param_count(cfg: ModelConfig, activated: bool = False) -> int:
     return n - cfg.vocab_size * cfg.hidden_dim
 
 
+def _matmul_uses(cfg: ModelConfig) -> int:
+    """Multiplications a token takes with a parameter, summed over the
+    parameters (routed experts: the ``top_k`` it takes): :func:`matmul_
+    param_count` for a model whose stack runs once. A looped stack
+    (``cfg.n_passes``) multiplies with every layer once a PASS and with the
+    head once, so the layers' share is counted ``n_passes`` times."""
+    n = matmul_param_count(cfg, activated=True)
+    if cfg.n_passes == 1:
+        return n
+    E, V = cfg.hidden_dim, cfg.vocab_size
+    head = E if cfg.is_critic else E * V
+    return cfg.n_passes * (n - head) + head
+
+
 def _attention_forward_flops(
     cfg: ModelConfig, seqlens: Optional[Sequence[int]]
 ) -> float:
@@ -100,7 +114,8 @@ def _attention_forward_flops(
     if not seqlens:
         return 0.0
     D, H = cfg.head_dim, cfg.n_q_heads
-    return sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.n_layers
+    # once a layer of CACHE: a looped stack attends once a pass
+    return sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.cache_layers
 
 
 def forward_flops(
@@ -108,7 +123,7 @@ def forward_flops(
     n_tokens: int,
     seqlens: Optional[Sequence[int]] = None,
 ) -> float:
-    fwd = 2 * matmul_param_count(cfg, activated=True) * n_tokens
+    fwd = 2 * _matmul_uses(cfg) * n_tokens
     return fwd + _attention_forward_flops(cfg, seqlens)
 
 
@@ -120,5 +135,5 @@ def train_flops(
     """Total FLOPs for ONE forward+backward over ``n_tokens`` packed tokens
     (backward ≈ 2x forward for matmuls; attention backward ≈ 2.5x its
     forward). ``seqlens`` sharpens the attention term."""
-    fwd = 2 * matmul_param_count(cfg, activated=True) * n_tokens
+    fwd = 2 * _matmul_uses(cfg) * n_tokens
     return 3 * fwd + 3.5 * _attention_forward_flops(cfg, seqlens)

@@ -164,6 +164,12 @@ class TransformerDrafter(Drafter):
         early layers' logits (true of trained models; for seeded weights
         see the damping recipe in ROADMAP D1). Real deployments point ``AREAL_SPEC_DRAFT_MODEL`` at a distilled
         checkpoint instead."""
+        if cfg.n_passes > 1:
+            raise ValueError(
+                f"shared-prefix draft: the target runs its {cfg.n_layers} "
+                f"layers {cfg.n_passes} times (n_passes), and 'the first "
+                "n_layers' of a looped stack is no model"
+            )
         if not 0 < n_layers <= cfg.n_layers:
             raise ValueError(
                 f"shared-prefix draft needs 0 < n_layers <= {cfg.n_layers}, "

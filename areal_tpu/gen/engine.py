@@ -493,6 +493,15 @@ class GenerationEngine:
                     "layer kinds: a draft model shares the target's page "
                     "tables, which a model with a period of kinds has several of"
                 )
+            if self._draft is not None and (
+                cfg.n_passes > 1 or self.draft_cfg.n_passes > 1
+            ):
+                raise NotImplementedError(
+                    f"a looped stack (n_passes={cfg.n_passes}, draft "
+                    f"{self.draft_cfg.n_passes}): a draft model beside it is "
+                    "not supported (its pool, maintenance step and acceptance "
+                    "rate have been run beside no such target)"
+                )
             self.n_pages = (
                 n_pages if n_pages is not None
                 else self.B * self.M * bytes_ratio * K
@@ -735,6 +744,11 @@ class GenerationEngine:
                 # top-k past the online buffer); both 0 on a materialised engine
                 "fused_rows": 0,
                 "sampler_fallback_rows": 0,
+                # steps x ``cfg.n_passes`` (runs of the whole stack) and
+                # steps x passes x layers (runs of one layer: each reads that
+                # layer's weights once) of the decode chunks as dispatched
+                "loop_passes": 0,
+                "layer_passes": 0,
             }
             start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
@@ -1421,7 +1435,7 @@ class GenerationEngine:
                 if rows_per_tile:
                     from areal_tpu.ops.pallas import kv_page_write
 
-                    self.stats["kv_write_tiles"] += self.cfg.n_layers * sum(
+                    self.stats["kv_write_tiles"] += self.cfg.cache_layers * sum(
                         kv_page_write.tiles_of_run(
                             int(s0) + c * C, int(k), rows_per_tile
                         )
@@ -2404,15 +2418,24 @@ class GenerationEngine:
             resident = int(lens.sum())
             chunk_attrs["resident_tokens"] = resident
             chunk_attrs["cache_bytes_per_token"] = self.cache_bytes_per_token()
+            # a looped stack: the passes a step makes over its weights, the
+            # layers of cache behind them, and the layer runs (each reads
+            # one layer's weights once) of the chunk as dispatched
+            cfg = self.cfg
+            layer_passes = decode_steps * cfg.cache_layers
+            chunk_attrs.update(
+                loop_passes=cfg.n_passes, cache_layers=cfg.cache_layers,
+                layer_passes=layer_passes)
+            self.stats["loop_passes"] += decode_steps * cfg.n_passes
+            self.stats["layer_passes"] += layer_passes
             counts = self._kernel_counts(W)
             if counts is not None:
                 self.stats["resident_tokens"] += resident
             if self._kv_write_rows() and not self.spec:
-                # one tile a (layer, running slot, step), as dispatched: a
+                # one tile a (cache layer, running slot, step), as dispatched: a
                 # slot that finishes inside the chunk writes none after
-                counts = dict(counts or {}, kv_write_tiles=(
-                    self.cfg.n_layers * len(running) * decode_steps
-                ))
+                counts = dict(
+                    counts or {}, kv_write_tiles=layer_passes * len(running))
             if counts is not None:
                 chunk_attrs.update(counts)
                 for name, n in counts.items():

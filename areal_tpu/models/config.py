@@ -3,7 +3,7 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash, smallthinker) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker, ouro) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -76,6 +76,23 @@ class ModelConfig:
     # Norms
     layer_norm_type: str = "rms"      # "rms" | "gemma" (=(1+w) rms) | "layer" (gpt2)
     layer_norm_epsilon: float = 1e-5
+    # A norm on each BRANCH's output before it is added to the residual
+    # (``ouro``: ``x + norm(attn(norm(x)))``, ``x + norm(mlp(norm(x)))``),
+    # four norms a layer: ``layers.attn_out_ln`` / ``layers.mlp_out_ln``
+    # beside ``ln1`` / ``ln2``. The HF family sets it, a user never does.
+    norm_branch_out: bool = False
+
+    # A LOOPED stack (``ouro``'s ``total_ut_steps``): the ``n_layers``
+    # layers run ``n_passes`` times over ONE set of weights, the model's
+    # final norm after EVERY pass, the head after the last. A token's key
+    # and value of a layer differ from pass to pass, so every cache holds
+    # ``cache_layers`` layers of them: pass ``t``, layer ``l`` reads and
+    # writes cache layer ``t * n_layers + l``. ``exit_gate``: the family's
+    # ``Linear(hidden, 1)`` on each pass's output is part of the weight
+    # tree (``params["exit_gate"]``), loaded, carried and written back; no
+    # forward reads it (every token takes every pass).
+    n_passes: int = 1
+    exit_gate: bool = False
 
     # Attention
     use_attention_bias: bool = False       # qkv projection bias (qwen2, gpt2)
@@ -223,10 +240,18 @@ class ModelConfig:
         return len(self.layer_kinds)
 
     @property
+    def cache_layers(self) -> int:
+        """Layers of K/V (or latents) a cache holds of one token: a looped
+        stack keeps them a PASS, ``n_passes`` behind every weight layer.
+        What the pools' leading axes, the engine's bytes and tiles and
+        the attention FLOPs (``base/flops.py``) read."""
+        return self.n_passes * self.n_layers
+
+    @property
     def n_periods(self) -> int:
         """Leading axis of the page pool: a page holds one position of the
-        period in every period."""
-        return self.n_layers // self.period
+        period in every period, of every pass."""
+        return self.cache_layers // self.period
 
     @property
     def n_rep(self) -> int:
@@ -270,6 +295,18 @@ class ModelConfig:
             raise ValueError(
                 "n_dense_layers: leading dense layers of an expert model, "
                 "fewer than n_layers"
+            )
+        if self.n_passes < 1:
+            raise ValueError("n_passes: the stack runs at least once")
+        if (self.n_passes > 1 or self.exit_gate) and (
+            self.mla is not None or self.n_dense_layers or self.n_mtp_layers
+            or self.layer_pattern is not None
+        ):
+            raise ValueError(
+                f"n_passes={self.n_passes}: a looped stack is one run of "
+                "alike layers; with latent attention, leading dense layers, "
+                "multi-token-prediction modules or a period of layer kinds "
+                "it is not supported (no published model has both)"
             )
         if self.layer_pattern is not None:
             object.__setattr__(

@@ -2,7 +2,7 @@
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
-joyai_llm_flash and smallthinker are added here) consumed by
+joyai_llm_flash, smallthinker and ouro are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -509,6 +509,123 @@ register_hf_family(
         params_to_hf=lambda params, cfg: _llama_like_params_to_hf(
             params, cfg, _SMALLTHINKER_MOE
         ),
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# Ouro (a LOOPED stack: the layers run ``total_ut_steps`` times over one set
+# of weights, the model's final norm after every pass; four norms a layer,
+# one on each branch's output; an exit gate that the published threshold
+# never lets fire)
+# --------------------------------------------------------------------------- #
+
+
+def _ouro_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read. ``total_ut_steps`` is the
+    number of passes; ``early_exit_threshold`` 1.0 (as published) means no
+    token leaves the loop before the last pass, which is the only thing
+    this family computes. What it does not do is refused, never guessed:
+    a threshold below 1 (rows of one batch would take different numbers of
+    passes), ``use_sliding_window`` true or a non-null ``sliding_window``,
+    a non-null ``rope_scaling``, a ``layer_types`` entry (of the first
+    ``num_hidden_layers``) other than ``full_attention``, or fewer entries
+    than layers. ``max_window_layers`` only says from which layer a window
+    would apply and shapes nothing while there is none."""
+    L = hf["num_hidden_layers"]
+    if float(hf.get("early_exit_threshold", 1.0)) < 1.0:
+        raise ValueError(
+            "ouro: early_exit_threshold below 1 (tokens leaving the loop at "
+            "different passes) is not supported"
+        )
+    if hf.get("use_sliding_window", False) or hf.get("sliding_window") is not None:
+        raise ValueError("ouro: a sliding window is not supported")
+    if hf.get("rope_scaling") is not None:
+        raise ValueError("ouro: rope_scaling is not supported")
+    layer_types = hf.get("layer_types")
+    if layer_types is not None:
+        if len(layer_types) < L:
+            raise ValueError(
+                f"ouro: layer_types has {len(layer_types)} entries for "
+                f"{L} layers"
+            )
+        if any(t != "full_attention" for t in layer_types[:L]):
+            raise ValueError(
+                "ouro: every layer_types entry must be 'full_attention'"
+            )
+    return dataclasses.replace(
+        _llama_like_config_from_hf(hf),
+        norm_branch_out=True,
+        n_passes=int(hf["total_ut_steps"]),
+        exit_gate=True,
+    )
+
+
+def _ouro_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys, key for key."""
+    return {
+        "model_type": "ouro",
+        "architectures": ["OuroForCausalLM"],
+        "head_dim": cfg.head_dim,
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "layer_types": ["full_attention"] * cfg.n_layers,
+        "max_position_embeddings": cfg.n_positions,
+        "max_window_layers": cfg.n_layers,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_scaling": None,
+        "rope_theta": cfg.rotary_base,
+        "sliding_window": None,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "total_ut_steps": cfg.n_passes,
+        "early_exit_threshold": 1.0,
+        "use_sliding_window": False,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+_OURO_BRANCH_NORMS = (
+    ("attn_out_ln", "input_layernorm_2"),
+    ("mlp_out_ln", "post_attention_layernorm_2"),
+)
+
+
+def _ouro_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    params = _llama_like_params_from_hf(sd, cfg)
+    for ours, theirs in _OURO_BRANCH_NORMS:
+        params["layers"][ours] = {"weight": _stack(
+            sd, "model.layers.{i}." + theirs + ".weight", cfg.n_layers)}
+    params["exit_gate"] = {
+        "weight": np.asarray(sd["model.early_exit_gate.weight"]).T,
+        "bias": np.asarray(sd["model.early_exit_gate.bias"]),
+    }
+    return params
+
+
+def _ouro_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    sd = _llama_like_params_to_hf(params, cfg)
+    for ours, theirs in _OURO_BRANCH_NORMS:
+        for i in range(cfg.n_layers):
+            sd[f"model.layers.{i}.{theirs}.weight"] = np.asarray(
+                params["layers"][ours]["weight"][i])
+    sd["model.early_exit_gate.weight"] = np.asarray(
+        params["exit_gate"]["weight"]).T
+    sd["model.early_exit_gate.bias"] = np.asarray(params["exit_gate"]["bias"])
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="ouro",
+        hf_model_type="ouro",
+        config_from_hf=_ouro_config_from_hf,
+        config_to_hf=_ouro_config_to_hf,
+        params_from_hf=_ouro_params_from_hf,
+        params_to_hf=_ouro_params_to_hf,
     )
 )
 

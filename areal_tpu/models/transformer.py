@@ -135,6 +135,13 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             **({"bias": jnp.zeros((E,), dtype)} if has_ln_bias else {}),
         },
     }
+    if cfg.norm_branch_out:
+        params["layers"]["attn_out_ln"] = ln(has_ln_bias)
+        params["layers"]["mlp_out_ln"] = ln(has_ln_bias)
+    if cfg.exit_gate:
+        # carried, never read by a forward (``ModelConfig.exit_gate``)
+        params["exit_gate"] = {
+            "weight": w((E, 1)), "bias": jnp.zeros((1,), dtype)}
     if cfg.abs_position_embedding:
         params["pos_embed"] = {"weight": w((cfg.n_positions, E))}
     if cfg.is_critic:
@@ -309,6 +316,11 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             **({"bias": ("embed",)} if has_ln_bias else {}),
         },
     }
+    if cfg.norm_branch_out:
+        axes["layers"]["attn_out_ln"] = ln()
+        axes["layers"]["mlp_out_ln"] = ln()
+    if cfg.exit_gate:
+        axes["exit_gate"] = {"weight": ("embed", None), "bias": (None,)}
     if cfg.abs_position_embedding:
         axes["pos_embed"] = {"weight": (None, "embed")}
     if cfg.is_critic:
@@ -570,6 +582,15 @@ def _attn_out(p, ctx):
     return y
 
 
+def _add_branch(cfg: ModelConfig, lp, name: str, x, branch):
+    """``x + branch``: a layer's attention or MLP output onto the residual,
+    through the branch's own norm ``lp[name]`` first where the model has
+    one (``cfg.norm_branch_out``)."""
+    if cfg.norm_branch_out:
+        branch = _norm(cfg, lp[name], branch)
+    return x + branch
+
+
 def _layer_stacks(params: Params):
     """The model's runs of identical layers, in the order they run: the
     leading dense layers of an expert model, if it has them, then
@@ -647,6 +668,50 @@ def _scan_layers(layer, carry, params: Params, xs=(), unroll=1):
         parts = [y for y in parts if y is not None]
         joined.append(jnp.concatenate(parts, axis=0) if parts else None)
     return carry, tuple(joined)
+
+
+def _scan_passes(cfg: ModelConfig, layer, carry, params: Params, xs=(),
+                 unroll=1):
+    """:func:`_scan_layers`, ``cfg.n_passes`` times over ONE set of
+    weights: what every forward runs its stack through. A model of one
+    pass gets :func:`_scan_layers` and nothing around it. A looped stack
+    gets an outer ``lax.scan`` over the passes whose body is that scan:
+    the weights are closed over (a loop invariant, read again each pass,
+    never stacked ``n_passes`` times, and autodiff sums a weight's
+    gradient over its uses), the carry runs on from pass to pass (``x``,
+    or ``(x, li)`` with ``li`` the cache layer ``t * L + l`` that the next
+    layer reads and writes), and pass ``t > 0`` starts from the model's
+    final norm of pass ``t - 1``'s output; the caller applies that norm
+    after the last pass, as for any model. ``xs`` and the stacked results
+    have a leading axis over the CACHE layers ``[T * L, ...]``, pass-major,
+    and are cut to a pass's ``L`` here."""
+    T = cfg.n_passes
+    if T == 1:
+        return _scan_layers(layer, carry, params, xs, unroll)
+    final_ln = _cast(cfg, params["final_ln"])
+
+    def between(t, x):
+        # (one norm of the residual computed and dropped at pass 0: a
+        # select is cheaper in a scan body than a conditional)
+        return jnp.where(t > 0, _norm(cfg, final_ln, x), x)
+
+    def one_pass(carry, inp):
+        t, xs_t = inp
+        with jax.named_scope("loop_pass"):
+            if isinstance(carry, tuple):
+                carry = (between(t, carry[0]), *carry[1:])
+            else:
+                carry = between(t, carry)
+            return _scan_layers(layer, carry, params, xs_t, unroll)
+
+    carry, ys = jax.lax.scan(
+        one_pass, carry,
+        (jnp.arange(T, dtype=jnp.int32),
+         tuple(x.reshape(T, x.shape[0] // T, *x.shape[1:]) for x in xs)),
+    )
+    return carry, jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys
+    )
 
 
 # --------------------------------------------------------------------------- #
@@ -751,10 +816,10 @@ def forward_packed(
             if cfg.moe is not None and cfg.moe.router_on_layer_input
             else None
         )
-        x = x + _attn_out(lp["attn"], ctx)
+        x = _add_branch(cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx))
         h = _norm(cfg, lp["ln2"], x)
         m, aux, routing = _mlp(cfg, lp["mlp"], h, layer_in)
-        return x + m, (aux, routing)
+        return _add_branch(cfg, lp, "mlp_out_ln", x, m), (aux, routing)
 
     policy = cfg.remat_policy if remat else "none"
     dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -810,8 +875,8 @@ def forward_packed(
 
     layers = [make_layer(kind) for kind in cfg.layer_kinds]
     layer = layers[-1]      # the block a multi-token-prediction module is
-    x, (auxes, routing) = _scan_layers(
-        layers, x, params, unroll=cfg.layer_scan_unroll or 1
+    x, (auxes, routing) = _scan_passes(
+        cfg, layers, x, params, unroll=cfg.layer_scan_unroll or 1
     )
     stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
@@ -911,7 +976,9 @@ def chunked_next_token_logprobs(
 @dataclasses.dataclass
 class KVCache:
     """Per-layer KV cache: ``k, v: [L, B, S, Hkv, D]``; ``lens: [B]`` counts
-    valid entries per slot (0 = free slot)."""
+    valid entries per slot (0 = free slot). ``L`` is ``cfg.cache_layers``:
+    a looped stack (``cfg.n_passes``) holds a token once a PASS, pass ``t``
+    of layer ``l`` at ``t * n_layers + l``."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -919,7 +986,8 @@ class KVCache:
 
     @classmethod
     def empty(cls, cfg: ModelConfig, batch: int, capacity: int) -> "KVCache":
-        shape = (cfg.n_layers, batch, capacity, cfg.n_kv_heads, cfg.head_dim)
+        shape = (
+            cfg.cache_layers, batch, capacity, cfg.n_kv_heads, cfg.head_dim)
         dt = jnp.dtype(cfg.dtype)
         return cls(
             k=jnp.zeros(shape, dt),
@@ -1000,12 +1068,16 @@ def prefill(
             scores = jnp.where(mask[:, None], scores, attn_ops._NEG_INF)
             probs = jax.nn.softmax(scores, axis=-1).astype(vv.dtype)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
-        x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
+        x = _add_branch(
+            cfg, lp, "attn_out_ln", x,
+            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+        x = _add_branch(
+            cfg, lp, "mlp_out_ln", x,
+            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
         return x, (k, v)
 
-    x, (ks, vs) = _scan_layers(
-        [make_layer(kind) for kind in cfg.layer_kinds], x, params
+    x, (ks, vs) = _scan_passes(
+        cfg, [make_layer(kind) for kind in cfg.layer_kinds], x, params
     )
     cap = cache.k.shape[2]
     pad = cap - S
@@ -1065,12 +1137,16 @@ def decode_step(
             soft_cap=cfg.attn_logits_soft_cap,
             sliding_window=window,
         )
-        x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
+        x = _add_branch(
+            cfg, lp, "attn_out_ln", x,
+            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+        x = _add_branch(
+            cfg, lp, "mlp_out_ln", x,
+            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
         return x, (kc, vc)
 
-    x, (ks, vs) = _scan_layers(
-        [functools.partial(layer, kind) for kind in cfg.layer_kinds],
+    x, (ks, vs) = _scan_passes(
+        cfg, [functools.partial(layer, kind) for kind in cfg.layer_kinds],
         x, params, xs=(cache.k, cache.v),
     )
     cache = KVCache(k=ks, v=vs, lens=new_lens)
@@ -1144,7 +1220,16 @@ class PagedKVCache:
     window`` is of no further use to its slot and goes back to the free
     list while the request still runs (``gen/engine.py``); nothing here
     reads a table entry before a row's first visible position. A model of
-    one kind is the same layout with ``p = 1``."""
+    one kind is the same layout with ``p = 1``.
+
+    A LOOPED stack (``cfg.n_passes``: the layers run several times over
+    one set of weights): a token's key and value of a layer differ from
+    pass to pass, so the leading axis is ``cfg.cache_layers = n_passes x
+    n_layers`` behind ``n_layers`` layers of weights, pass-major: pass
+    ``t``, layer ``l`` reads and writes ``pages[t * n_layers + l]``
+    (:func:`_scan_passes`' running ``li``). One page table a slot, one
+    page the same positions in every cache layer: the engine, the prefix
+    registry and both kernels take the axis as given."""
 
     pages: jnp.ndarray
     scales: Optional[jnp.ndarray] = None
@@ -1380,12 +1465,16 @@ def _extend_layers(
             # [B, C, H(kv), D]
             q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, kinds[j][1])
             ctx = _attend(q, k, v, li, j)
-        x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
-        x = x + _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0]
+        x = _add_branch(
+            cfg, lp, "attn_out_ln", x,
+            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+        x = _add_branch(
+            cfg, lp, "mlp_out_ln", x,
+            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
         return (x, li + int(j == len(kinds) - 1)), (k, v)
 
-    (x, _), (ks, vs) = _scan_layers(
-        [functools.partial(layer, j) for j in range(len(kinds))],
+    (x, _), (ks, vs) = _scan_passes(
+        cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, jnp.int32(0)), params,
     )
     return x, ks, vs
@@ -1604,15 +1693,18 @@ def decode_step_paged(
                     q, k, v, cache.pages, li, _kind_table(table_o, j),
                     lens_o, **kw
                 )
-        x = x + _attn_out(lp["attn"], ctx.astype(x.dtype))
+        x = _add_branch(
+            cfg, lp, "attn_out_ln", x,
+            _attn_out(lp["attn"], ctx.astype(x.dtype)))
         m, _, routing = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)
         return (
-            (x + m, li + int(j == len(kinds) - 1)),
+            (_add_branch(cfg, lp, "mlp_out_ln", x, m),
+             li + int(j == len(kinds) - 1)),
             (k, v, routing if with_routing else None),
         )
 
-    (x, _), (ks, vs, routing) = _scan_layers(
-        [functools.partial(layer, j) for j in range(len(kinds))],
+    (x, _), (ks, vs, routing) = _scan_passes(
+        cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, jnp.int32(0)), params,
     )
     x, ks = x[inverse], ks[:, inverse]

@@ -370,6 +370,10 @@ KV_WRITE_CELLS = {
     "olmoe": dict(L=8, P=1072, S=2, H=16, W=128, B=64, M=32,
                   config="olmoe-1b-7b-l8", seqlen=4096, out=3072),
     "joyai": dict(L=5, P=5967, S=1, H=1, W=640, B=256, M=72),
+    # Ouro-2.6B-l8: 4 passes x 8 layers = 32 CACHE layers behind 8 layers of
+    # weights, pages of 64 (33.5 MB a page of 128 wastes too much a slot)
+    "ouro": dict(L=32, P=858, S=2, H=16, W=128, B=72, M=40, page=64,
+                 config="ouro-2p6b-l8", seqlen=2560, out=2048),
 }
 
 
@@ -378,7 +382,7 @@ KV_WRITE_CELLS = {
     [
         # a decode step: one token a slot (one 16-row slab a layer and slot)
         ("cell1", None, 1), ("cell3", None, 1), ("olmoe", None, 1),
-        ("joyai", None, 1),
+        ("joyai", None, 1), ("ouro", None, 1),
         # an admission wave of 8 x 128 tokens at the two ends: 128 KB
         # slabs, and a latent row of five lane tiles (its window of fresh
         # rows starts between tiles: Mosaic takes that one lane tile wide)
@@ -393,7 +397,7 @@ def test_kv_page_write_compiles(compiled_kernels, one_chip, cell, rows, chunk):
 
     c = KV_WRITE_CELLS[cell]
     B = rows or c["B"]
-    pool = (c["L"], c["P"], c["S"], c["H"], 128, c["W"])
+    pool = (c["L"], c["P"], c["S"], c["H"], c.get("page", 128), c["W"])
     compiled = jax.jit(kv_page_write.write, donate_argnums=(0,)).lower(
         _spec(pool, jnp.bfloat16, one_chip),
         _spec((c["L"], B, chunk, c["S"], c["H"], c["W"]), jnp.bfloat16,
@@ -413,6 +417,9 @@ def test_kv_page_write_compiles(compiled_kernels, one_chip, cell, rows, chunk):
 @pytest.mark.parametrize(
     "cell,fused",
     [("cell1", True), ("cell3", True), ("olmoe", True),
+     # a looped stack: both scans, the pool's 32 cache layers behind 8
+     # layers of weights, the stack not copied for the inner scan
+     ("ouro", True),
      # the materialised epilogue, which a mesh, a tied head and every
      # platform but a TPU keep (``fused_sample_applies``)
      ("cell3", False)],
@@ -442,7 +449,8 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
     eng = GenerationEngine(
         cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
         max_slots=c["B"], max_seqlen=c["seqlen"],
-        max_new_tokens_cap=c["out"], page_size=128, n_pages=80, seed=0)
+        max_new_tokens_cap=c["out"], page_size=c.get("page", 128),
+        n_pages=80, seed=0)
     eng._decode_use_pallas = True
     assert eng.M == c["M"] and eng._kv_write_rows() == 16
 
@@ -451,7 +459,9 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
 
     pages = eng.state.cache.pages
     pool = (pages.shape[0], c["P"]) + pages.shape[2:]
-    assert pool == (c["L"], c["P"], c["S"], c["H"], 128, c["W"])
+    page = c.get("page", 128)
+    assert pool == (c["L"], c["P"], c["S"], c["H"], page, c["W"])
+    assert pages.shape[0] == cfg.cache_layers
     state = dataclasses.replace(
         jax.tree.map(spec, eng.state),
         cache=tfm.PagedKVCache(pages=_spec(pool, pages.dtype, one_chip)))
