@@ -85,6 +85,7 @@ from areal_tpu.gen.sampling import (
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import fused_sample as fused_ops
+from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import paged_attention as paged_ops
 
 logger = logging.getLogger("areal_tpu.gen.engine")
@@ -739,6 +740,13 @@ class GenerationEngine:
                 "moe_experts_hit": 0,
                 "moe_expert_slots": 0,
                 "moe_load_max": 0,
+                # rows x expert layers x steps (decode chunks) or x chunks
+                # (admission's prefill programs) as dispatched, by where the
+                # routed experts ran them: the grouped-matmul kernel, or the
+                # dense einsums (``_moe_grouped``: the predicate the program
+                # was built with); both 0 for a model without a router
+                "moe_grouped_rows": 0,
+                "moe_dense_rows": 0,
                 # fused chunks, rows x steps as dispatched: sampled by the
                 # fused head-and-sample pass / sent to the sorted path (top-p,
                 # top-k past the online buffer); both 0 on a materialised engine
@@ -1245,13 +1253,17 @@ class GenerationEngine:
             return self._jit_extend[key]
         cfgs = (self.cfg, self.draft_cfg)
         pad = self._kv_write_batch(n_rows) - n_rows
+        # the target's routed experts see every token of the wave's chunk
+        # (a draft model keeps the einsums)
+        grouped = (self._moe_grouped(n_rows * self.admit_chunk), False)
 
         def extend(*args):
             model, (state, *chunk) = args[:-5], args[-5:]
             fresh = tuple(
-                tfm.extend_paged_kv(p, c, kv, *chunk, skip_pool=skip_pool)
-                for p, c, kv in zip(
-                    model, cfgs, (state.cache, state.draft_cache))
+                tfm.extend_paged_kv(
+                    p, c, kv, *chunk, skip_pool=skip_pool, moe_grouped=g)
+                for p, c, kv, g in zip(
+                    model, cfgs, (state.cache, state.draft_cache), grouped)
             )
             return jax.tree.map(
                 lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * 3),
@@ -1263,6 +1275,35 @@ class GenerationEngine:
         jitted = jax.jit(extend, **sharding_kw)
         self._jit_extend[key] = jitted
         return jitted
+
+    def _moe_grouped(self, rows: int) -> bool:
+        """Whether a program that hands the target's routed experts ``rows``
+        rows a layer runs them as the ``moe_grouped`` kernel
+        (``ops/moe.py:moe_grouped_applies``, over this engine's model, tree
+        and mesh): what the program is built with AND what its dispatches
+        are counted by (``moe_grouped_rows`` / ``moe_dense_rows``)."""
+        return moe_ops.moe_grouped_applies(
+            self.cfg, self.params, self.mesh, rows)
+
+    def _chunk_moe_rows(self) -> int:
+        """Rows a decode chunk's step hands the routed experts: the batch,
+        times a speculative chunk's verified positions."""
+        return self.B * ((self.spec_k + 1) if self.spec else 1)
+
+    def _count_moe_rows(self, rows: int, runs: int) -> Dict[str, int]:
+        """``rows x expert layers x runs`` (``runs``: decode steps x passes,
+        or 1 for a prefill chunk) onto ``moe_grouped_rows`` or
+        ``moe_dense_rows``, by :meth:`_moe_grouped` of ``rows``. Returns
+        both counts of this dispatch (one is 0) for its span."""
+        n = rows * self.cfg.n_moe_layers * runs
+        grouped = self._moe_grouped(rows)
+        counts = {
+            "moe_grouped_rows": n if grouped else 0,
+            "moe_dense_rows": 0 if grouped else n,
+        }
+        for name, v in counts.items():
+            self.stats[name] += v
+        return counts
 
     def _kv_write_batch(self, n_rows: int) -> int:
         """Rows of the write program that takes a wave of ``n_rows``: the
@@ -1442,6 +1483,8 @@ class GenerationEngine:
                         for s0, k in zip(starts0, n_new)
                     )
                 start = starts0 + c * C
+                if self._moe:
+                    self._count_moe_rows(n * C, 1)
                 fresh = self._extend_fn(n, W, skip_pool)(
                     *self._model_args(), self.state,
                     jnp.asarray(all_tokens[:, c * C : (c + 1) * C]),
@@ -1463,6 +1506,7 @@ class GenerationEngine:
             before = (
                 st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"],
                 st["kv_write_tiles"], st["window_pages_released"],
+                st["moe_grouped_rows"], st["moe_dense_rows"],
             )
             self._admit_pending()
             attrs.update(
@@ -1479,6 +1523,11 @@ class GenerationEngine:
                 # gave back as their chunks passed
                 attrs["window_pages_released"] = (
                     st["window_pages_released"] - before[4])
+            if self._moe:
+                # tokens x expert layers of the wave's prefill chunks, by
+                # where their routed experts ran
+                attrs["moe_grouped_rows"] = st["moe_grouped_rows"] - before[5]
+                attrs["moe_dense_rows"] = st["moe_dense_rows"] - before[6]
 
     def _admit_pending(self):
         if not self.accepting:
@@ -1708,6 +1757,7 @@ class GenerationEngine:
                 mesh=self.mesh,
                 return_hidden=fused,
                 with_routing=self._moe,
+                moe_grouped=self._moe_grouped(self._chunk_moe_rows()),
             )
             if self._draft is not None:
                 # keep the draft pool current: one HEADLESS draft decode
@@ -1961,6 +2011,7 @@ class GenerationEngine:
                 params, cfg, state.cache, chunk_toks, table, state.lens,
                 n_new, n_write, return_hidden=fused,
                 use_pallas=self._decode_use_pallas, mesh=self.mesh,
+                moe_grouped=self._moe_grouped(self._chunk_moe_rows()),
             )
             if self.mesh is not None:
                 # sampling runs replicated after one logits all-gather
@@ -2428,6 +2479,10 @@ class GenerationEngine:
                 layer_passes=layer_passes)
             self.stats["loop_passes"] += decode_steps * cfg.n_passes
             self.stats["layer_passes"] += layer_passes
+            if self._moe:
+                # every row of the batch routes, free slots too
+                chunk_attrs.update(self._count_moe_rows(
+                    self._chunk_moe_rows(), decode_steps * cfg.n_passes))
             counts = self._kernel_counts(W)
             if counts is not None:
                 self.stats["resident_tokens"] += resident

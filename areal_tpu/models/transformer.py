@@ -535,13 +535,15 @@ def _attn_scale(cfg: ModelConfig) -> float:
     return cfg.softmax_scale or cfg.head_dim ** -0.5
 
 
-def _mlp(cfg: ModelConfig, p, x, layer_in=None):
+def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None):
     """Returns (out, aux_loss, routing) — aux is the MoE load-balancing/z
     loss (``jnp`` scalar, 0 for dense MLPs); routing the experts each token
     chose, ``[..., top_k]`` int32 (``None`` for dense MLPs). ``layer_in``:
     the layer's normed INPUT (what its attention read), which every
     forward hands over: a router that reads it instead of ``x``
-    (``MoEConfig.router_on_layer_input``) gets it from here."""
+    (``MoEConfig.router_on_layer_input``) gets it from here. ``routed``:
+    ``(stacks, index)`` where the routed matrices did not come with ``p``
+    (:func:`_hold_routed`); a layer without a router ignores it."""
     act = ACT2FN[cfg.activation_function]
     # a leading dense layer of an expert model is told by its tree: it has
     # no router
@@ -565,6 +567,7 @@ def _mlp(cfg: ModelConfig, p, x, layer_in=None):
     return moe_mlp(
         cfg, p, x,
         router_input=layer_in if cfg.moe.router_on_layer_input else None,
+        routed=routed,
     )
 
 
@@ -598,6 +601,39 @@ def _layer_stacks(params: Params):
     if "dense_layers" in params:
         return [params["dense_layers"], params["layers"]]
     return [params["layers"]]
+
+
+_ROUTED = ("w_gate", "w_up", "w_down")
+
+
+def _hold_routed(params: Params) -> Tuple[Params, Params]:
+    """``(params, stacks)``: the tree with the routed experts' matrices
+    ``[L, X, ...]`` taken OUT of the expert stack, and those matrices. The
+    layer scans then cut a layer's slice of everything else, and the
+    stacks reach the layer whole, beside its index in them
+    (:func:`_routed_at`), as ``cache.pages`` and ``li`` do: the
+    grouped-matmul kernel indexes them itself. (A slice of them handed to
+    a custom call is a COPY of ``X x E x F`` for each, every layer-step;
+    into an einsum XLA fuses it, which is why only a forward that runs the
+    kernel asks for this.)"""
+    layers = params["layers"]
+    mlp = layers["mlp"]
+    rest = {k: v for k, v in mlp.items() if k not in _ROUTED}
+    return (
+        {**params, "layers": {**layers, "mlp": rest}},
+        {k: mlp[k] for k in _ROUTED},
+    )
+
+
+def _routed_at(cfg: ModelConfig, routed: Optional[Params], li, j: int):
+    """:func:`_mlp`'s ``routed`` for the layer at position ``j`` of the
+    period, from the running ``li`` of the engine's forwards (the layer's
+    slice of the pool's leading axis: its cache layer, or its period):
+    the held stacks and the layer's index in the expert stack."""
+    if routed is None:
+        return None
+    layer = (li * len(cfg.layer_kinds) + j) % cfg.n_layers
+    return routed, layer - cfg.n_dense_layers
 
 
 def _scan_periods(layers, carry, stack, xs=(), unroll=1):
@@ -1417,12 +1453,20 @@ def _extend_layers(
     n_new: jnp.ndarray,      # [B]
     skip_pool: bool = False,
     verify: bool = False,
+    moe_grouped: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
     """Shared multi-token layer scan over the page pool (chunked prefill
     AND the spec-decode verify pass — one implementation, two attention
     entry points). Returns ``(x [B, C, E] pre-final-norm hidden, ks,
-    vs)``; the caller writes the KV and (for verify) applies the head."""
+    vs)``; the caller writes the KV and (for verify) applies the head.
+    ``moe_grouped`` (STATIC): the routed experts run as the grouped-matmul
+    kernel over the whole stack (``ops/moe.py``; the caller asks
+    ``moe_grouped_applies``)."""
     from areal_tpu.ops import paged_attention as paged_ops
+
+    routed = None
+    if moe_grouped:
+        params, routed = _hold_routed(params)
 
     B, C = tokens.shape
     positions = start[:, None] + jnp.arange(C)[None, :]
@@ -1470,7 +1514,8 @@ def _extend_layers(
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
         x = _add_branch(
             cfg, lp, "mlp_out_ln", x,
-            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
+            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
+                 _routed_at(cfg, routed, li, j))[0])
         return (x, li + int(j == len(kinds) - 1)), (k, v)
 
     (x, _), (ks, vs) = _scan_passes(
@@ -1495,6 +1540,7 @@ def extend_paged_kv(
     start: jnp.ndarray,      # [B] tokens already resident per slot
     n_new: jnp.ndarray,      # [B] valid tokens in this chunk (<= C)
     skip_pool: bool = False,
+    moe_grouped: bool = False,
 ):
     """Chunked prefill, the computing half: attend the chunk causally over
     everything resident (pool part + intra-chunk part, merged inside the
@@ -1504,9 +1550,11 @@ def extend_paged_kv(
     a program of its own, ``gen/engine.py:_kv_write_fn``). Logits are not
     computed: admission feeds the last prompt token to the first decode
     step instead. ``skip_pool`` (STATIC): every row starts at position 0,
-    so the pool scan is dead weight (see ``paged_extend_attention``)."""
+    so the pool scan is dead weight (see ``paged_extend_attention``).
+    ``moe_grouped`` (STATIC): see :func:`_extend_layers`."""
     _, ks, vs = _extend_layers(
-        params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool
+        params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
+        moe_grouped=moe_grouped,
     )
     return ks, vs
 
@@ -1547,6 +1595,7 @@ def verify_step_paged(
     return_hidden: bool = False,
     use_pallas: Optional[bool] = None,
     mesh=None,
+    moe_grouped: bool = False,
 ) -> Tuple[jnp.ndarray, PagedKVCache]:
     """Speculative-decode VERIFY: ``decode_step_paged`` generalized to C =
     K+1 query tokens per slot in ONE pass — one params read and one pool
@@ -1572,9 +1621,11 @@ def verify_step_paged(
     chunk with no host sync. The bound only exists to keep writes inside
     the slot's allocated pages (a position past ``max_gen`` could fall off
     the page table and alias page 0). ``use_pallas`` / ``mesh`` choose the
-    KV write's path only (:func:`_write_chunk_kv`)."""
+    KV write's path only (:func:`_write_chunk_kv`); ``moe_grouped``
+    (STATIC): see :func:`_extend_layers`."""
     x, ks, vs = _extend_layers(
-        params, cfg, cache, tokens, table, lens, n_new, verify=True
+        params, cfg, cache, tokens, table, lens, n_new, verify=True,
+        moe_grouped=moe_grouped,
     )
     cache = _write_chunk_kv(
         cache, ks, vs, table, lens, n_write, use_pallas, mesh
@@ -1606,6 +1657,7 @@ def decode_step_paged(
     with_head: bool = True,
     return_hidden: bool = False,
     with_routing: bool = False,
+    moe_grouped: bool = False,
 ) -> Tuple[Optional[jnp.ndarray], PagedKVCache, jnp.ndarray]:
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
     cache, new lens — incremented where active). The pool is read-only in
@@ -1644,9 +1696,16 @@ def decode_step_paged(
     the experts every slot's token chose in every layer that has a router
     (``cfg.n_moe_layers``: leading dense layers have none), int32
     ``[L, B, top_k]`` in slot order, free and finished slots included
-    (they run through the experts like any other row)."""
+    (they run through the experts like any other row).
+
+    ``moe_grouped`` (STATIC): the routed experts run as the grouped-matmul
+    kernel over the whole stack (``ops/moe.py``; the engine asks
+    ``moe_grouped_applies`` with the rows of this step)."""
     from areal_tpu.ops import paged_attention as paged_ops
 
+    routed = None
+    if moe_grouped:
+        params, routed = _hold_routed(params)
     new_lens = jnp.where(active, lens + 1, lens)
     # the scan's rows, by length (``_o``); slot order again after it
     order, inverse = _length_order(lens)
@@ -1696,7 +1755,9 @@ def decode_step_paged(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, routing = _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)
+        m, _, routing = _mlp(
+            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
+            _routed_at(cfg, routed, li, j))
         return (
             (_add_branch(cfg, lp, "mlp_out_ln", x, m),
              li + int(j == len(kinds) - 1)),

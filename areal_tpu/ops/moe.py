@@ -1,47 +1,62 @@
 """Mixture-of-experts MLP (top-k router + experts).
 
 TPU-native counterpart of ``realhf/impl/model/modules/moe/`` (router.py,
-experts.py, token_dispatcher.py, layer.py — ~700 LoC). ONE dispatch:
-every expert is computed for every token and the router's combine weights
-(zero where an expert was not chosen) are folded into the activations
-before the down projection, so the result is one contraction over
-(expert, width) and no ``[T, X, E]`` tensor is ever formed. It is correct
-under any sharding of the expert axis (the contraction runs over the
-sharded expert dim: expert parallelism by one psum), differentiates
-without special cases, and XLA fuses each layer's slice of the stacked
-weights straight into the matmuls.
+experts.py, token_dispatcher.py, layer.py — ~700 LoC). The router, the
+top-k, the aux loss and the shared expert are one piece of code; after the
+router's choice the routed experts run through one of TWO dispatches that
+share nothing, because what each needs excludes the other.
 
-Why not a grouped matmul. Until PR 26 a second path sorted the token
-copies by expert and ran ``lax.ragged_dot`` (the reference's
-permute-tokens-per-expert scheme, O(T·K) FLOPs instead of O(T·X)). Timed
-on a TPU v5e at OLMoE-1B-7B widths (64 experts of 2048 x 1024, 8 a token,
-bf16, experts alone, ms a layer; chip runs of PR 26, PERF.md §6):
+The dense dispatch (the trainer, ``ppo/inference``, a mesh, and every
+program of the generation engine that hands the experts few rows): every
+expert is computed for every token and the router's combine weights (zero
+where an expert was not chosen) are folded into the activations before the
+down projection, so the result is one contraction over (expert, width) and
+no ``[T, X, E]`` tensor is ever formed. It is correct under any sharding of
+the expert axis (the contraction runs over the sharded expert dim: expert
+parallelism by one psum), differentiates without special cases, and XLA
+fuses each layer's slice of the stacked weights straight into the matmuls.
+It computes ``X / k`` times the needed FLOPs (8 x at OLMoE's 64 experts,
+32 x at ``joyai_llm_flash``'s 256): its MXU time is ``T / 240`` of the time
+the layer's routed weights take from HBM on a v5e (197 TFLOP/s over
+819 GB/s), whatever ``X`` and ``k`` are, and does not hide under it.
 
-    tokens T            64     1024     4096   | 1024 fwd+bwd  4096 fwd+bwd
-    this path          1.14    4.40    17.4    |    22.6          77.2
-    ``ragged_dot``     5.01    6.39    12.1    |    31.3          56.2
+The grouped dispatch (``ops/pallas/moe_grouped.py``; the generation
+engine's ``decode_step_paged`` and ``_extend_layers``, where
+:func:`moe_grouped_applies`): the ``T k`` chosen (row, expert) pairs sorted
+by expert, every run padded to whole row tiles, one Pallas kernel over the
+WHOLE stacked weights and a layer index that reads each expert a row chose
+once and no other. The caller must hand it the stack (``routed``): a
+kernel handed a layer's slice costs a copy of ``X x E x F`` for each of
+the three matrices every layer-step, which is what ``lax.ragged_dot`` paid
+until PR 26 removed it (5.01 ms against 1.14 at OLMoE's 64 rows, 12.1
+against 17.4 at 4096; PERF.md §6). It has no gradient and no partitioning
+rule (ROADMAP C12).
 
-XLA lowers ``ragged_dot`` to a Mosaic kernel of its own, but each of its
-three calls a layer takes a materialised copy of that layer's slice of
-the stacked weights (3 x the expert bytes moved), and the kernel reaches
-about a quarter of the MXU's peak. This path runs at 96 % of the peak at
-T = 4096 and at the weights' HBM time at T = 64, where every expert is
-hit anyway (decode at 64 slots: P(an expert gets no token) = 0.03 %). So
-``ragged_dot`` loses 4.4 x in decode and 1.4 x in a 1024-token admission
-wave, and wins 1.4 x only at the trainer's 4096 tokens, where it brought
-a ``custom_vmap`` rule that could not be differentiated outside ``vmap``
-and, under a sharded expert axis, an all-gather of every expert's
-weights that the code cannot see coming. One path was kept. The 8 x
-wasted FLOPs at large T are what a real grouped-matmul kernel would win
-back (ROADMAP); ``ragged_dot`` as this JAX lowers it wins a sixth of them.
+Experts alone on a TPU v5e, ms a layer, one layer of a 4- or 8-layer stack,
+bf16 (chip runs of PR 40, PERF.md §6; ``bytes``: the layer's routed
+weights at 819 GB/s):
 
-At 256 experts and 8 a token (``joyai_llm_flash``) the same path computes
-32 x the needed FLOPs. From shapes alone, at 2048 x 768 experts in bf16 on
-a v5e (197 TFLOP/s, 819 GB/s): a layer's routed weights are 2.42 GB,
-2.95 ms to stream; its dispatch is 6 * 2048 * 768 * 256 = 2.4 GFLOP a
-row, so 128 rows cost 1.6 ms of MXU under the weights' 2.95 ms (free),
-256 rows 3.1 ms (level with them) and a 1024-token admission wave 12.6 ms
-(4.3 x the bytes). PERF.md has what the chip says. Still one path.
+    experts                      rows   hit    bytes   dense   grouped (tile)
+    256 of 2048 x 768, 8 a row      8    23 %   2.95    3.24    0.80 (16)
+    256 of 2048 x 768, 8 a row    128    97 %   2.95    3.26    3.30 (16)
+    256 of 2048 x 768, 8 a row    256   100 %   2.95    3.90    3.46 (16)
+    256 of 2048 x 768, 8 a row    512   100 %   2.95    6.53    3.82 (32)
+    256 of 2048 x 768, 8 a row   1024   100 %   2.95   12.93    4.43 (64)
+    64 of 2048 x 1024, 8 a row     64   100 %   0.98    1.14    1.18 (16)
+    64 of 2048 x 1024, 8 a row    128   100 %   0.98    1.14    1.22 (32)
+    64 of 2048 x 1024, 8 a row   1024   100 %   0.98    4.39    2.30 (128)
+    64 of 2560 x 768, 6 a row     112   100 %   0.92    1.04    1.16 (32)
+    64 of 2560 x 768, 6 a row    1024   100 %   0.92    4.05    2.08 (128)
+
+``grouped`` includes the sort, the gather and the weighted sum around the
+call. The dense dispatch sits on its bytes up to about half the ridge and
+climbs with the rows from there; the kernel climbs a quarter as fast (the
+padded rows it moves), so the two cross at 0.58 of the ridge at 256
+experts (139 rows) and at 0.67 at 64 (161), and below the crossing the
+einsums are ahead by 1-11 %. Where few rows leave most experts unread the
+kernel reads the hit ones only (8 rows of 256 experts: 4.1 x). That is
+the rule of :func:`moe_grouped_applies`: rows at or over ``GROUPED_FROM``
+of the ridge, or at most ``GROUPED_FROM`` of the experts hit.
 
 Router runs in fp32 (matches the reference's fp32 router,
 ``moe/router.py``). Two kinds of score (``MoEConfig.scoring``): a softmax
@@ -54,6 +69,8 @@ and added to the routed sum. Where the family says so
 ANOTHER tensor than the experts: the layer's normed input, computed a
 whole attention earlier; the caller hands it in as ``router_input``.
 """
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -107,12 +124,67 @@ def _aux_loss(cfg, chosen, probs, logits):
     return moe.aux_loss_coeff * aux + moe.z_loss_coeff * z
 
 
-def moe_mlp(cfg, p, x, router_input=None):
+# one TPU v5e: bf16 FLOP/s over HBM bytes/s (197e12 / 819e9), the rows at
+# which the dense dispatch's MXU time equals its weights' HBM time
+RIDGE_ROWS = 240
+# the dense dispatch keeps the rows under this share of the ridge (the two
+# cross at 0.58 of it at 256 experts and 0.67 at 64: the module's table),
+# and the programs that hit no more than this share of the experts
+GROUPED_FROM = 0.6
+
+
+def _platform() -> str:
+    return jax.devices()[0].platform
+
+
+def moe_grouped_applies(
+    cfg, params, mesh=None, rows: int = 0, platform: Optional[str] = None
+) -> bool:
+    """Whether a program of the generation engine that hands the routed
+    experts ``rows`` rows a layer runs them as the grouped-matmul kernel
+    (``ops/pallas/moe_grouped.py``) or as the dense einsums, from what the
+    engine can observe (as ``kv_write_kernel_applies`` and
+    ``fused_sample_applies`` say it of their kernels). The kernel serves:
+    a model with a router; ONE TPU device (``pallas_call`` has no
+    partitioning rule: under a mesh of several the einsums contract over
+    the sharded expert axis); routed stacks stored in the serving dtype
+    (a lazy cast of an operand of a custom call is a copy of the stack);
+    and ``rows`` at or over ``GROUPED_FROM`` of the chip's ridge. The
+    dense dispatch's MXU time is ``rows / RIDGE_ROWS`` of its weights' HBM
+    time whatever ``X`` and ``k`` are, and does not hide under it; the
+    kernel's time is the HBM time of the experts HIT, ``1 - (1 - k / X) **
+    rows`` of them, so the few rows of a small batch that leave most
+    experts unread go to the kernel as well. ``params`` is the engine's
+    tree as it serves it (arrays or their shapes); ``platform`` defaults
+    to the first device's."""
+    if cfg.mlp_type != "moe" or rows <= 0:
+        return False
+    if platform is None:
+        platform = _platform()
+    moe = cfg.moe
+    hit = 1.0 - (1.0 - moe.top_k / moe.num_experts) ** rows
+    return (
+        platform == "tpu"
+        and (mesh is None or mesh.size == 1)
+        and params["layers"]["mlp"]["w_gate"].dtype == jnp.dtype(cfg.dtype)
+        and (rows >= GROUPED_FROM * RIDGE_ROWS or hit <= GROUPED_FROM)
+    )
+
+
+def moe_mlp(cfg, p, x, router_input=None, routed=None):
     """x: [..., E] -> (out [..., E], aux_loss, top_idx [..., K]).
 
     ``router_input`` ``[..., E]``: what the router reads where that is not
     ``x`` (``cfg.moe.router_on_layer_input``; required then, refused
     otherwise, so that no path can feed the router the wrong tensor).
+
+    ``routed`` ``(stacks, index)``: the routed matrices as the WHOLE
+    stacks ``[L, X, ...]`` and this layer's index in them, where ``p``
+    came without them (the generation engine's forwards, where
+    :func:`moe_grouped_applies`): the chosen (row, expert) pairs run
+    through the grouped-matmul kernel. Without it ``p`` holds the layer's
+    own ``[X, ...]`` and every expert is computed for every row. The two
+    share everything up to the router's choice and nothing after it.
 
     ``top_idx`` are the experts each token chose (largest weight first):
     the generation engine counts them (``moe_experts_hit``) and can hand
@@ -145,13 +217,24 @@ def moe_mlp(cfg, p, x, router_input=None):
             )
     onehot = jax.nn.one_hot(top_idx, cfg.moe.num_experts, dtype=jnp.float32)
     chosen = onehot.sum(axis=1)                                  # [T, X]
-    combine = (top_vals[:, :, None] * onehot).sum(axis=1)        # [T, X]
-    with jax.named_scope(EXPERTS_SCOPE):
-        h = act(jnp.einsum("te,xef->txf", xt, p["w_gate"])) * jnp.einsum(
-            "te,xef->txf", xt, p["w_up"]
-        )
-        h = h * combine.astype(h.dtype)[:, :, None]
-        out = jnp.einsum("txf,xfe->te", h, p["w_down"])
+    if routed is not None:
+        from areal_tpu.ops.pallas.moe_grouped import moe_grouped
+
+        stacks, index = routed
+        with jax.named_scope(EXPERTS_SCOPE):
+            out = moe_grouped(
+                xt, top_idx, top_vals, chosen.sum(axis=0),
+                stacks["w_gate"], stacks["w_up"], stacks["w_down"], index,
+                activation=cfg.activation_function,
+            )
+    else:
+        combine = (top_vals[:, :, None] * onehot).sum(axis=1)    # [T, X]
+        with jax.named_scope(EXPERTS_SCOPE):
+            h = act(jnp.einsum("te,xef->txf", xt, p["w_gate"])) * jnp.einsum(
+                "te,xef->txf", xt, p["w_up"]
+            )
+            h = h * combine.astype(h.dtype)[:, :, None]
+            out = jnp.einsum("txf,xfe->te", h, p["w_down"])
     if "shared_gate" in p:
         with jax.named_scope(SHARED_SCOPE):
             out = out + (

@@ -191,6 +191,10 @@ KV_WRITE_KERNEL = "kv_page_write"
 # the kernel a decode step ends in (ops/pallas/fused_sample.py): the head
 # streamed over vocabulary blocks and sampled from inside the pass
 FUSED_SAMPLE_KERNEL = "fused_sample"
+# the routed experts as one grouped matmul over the stacked weights
+# (ops/pallas/moe_grouped.py); the smoke's model has no router, so the
+# kernel runs alone, beside the einsums (``child_moegrouped``)
+MOE_GROUPED_KERNEL = "moe_grouped"
 
 
 def kernels_in(paths):
@@ -582,6 +586,14 @@ def phase_serve(sz, args):
     df, v = fused["chi2_df"], 2 / (9 * fused["chi2_df"])
     require(fused["chi2"] <= df * (1 - v + 4.75 * v ** 0.5) ** 3,
             f"fused_sample's draws are not its softmax's: {fused}")
+    # ... and the routed experts alone: the grouped-matmul kernel over one
+    # layer of a stack against the einsums over that layer's slice
+    grouped, _ = helper(d, "moegrouped", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(grouped["max_abs_diff_vs_einsums"] <= grouped["tolerance"]
+            and (args.rehearse or grouped["kernel"] == MOE_GROUPED_KERNEL),
+            f"moe_grouped disagrees with the einsums: {grouped}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -639,6 +651,7 @@ def phase_serve(sz, args):
         "fused_sample": metrics.get("fused_sample"),
         "fused_rows": metrics.get("engine_fused_rows"),
         "fused_sample_vs_head": fused,
+        "moe_grouped_vs_einsums": grouped,
         "peak_hbm_gib": round(
             metrics.get("hbm_peak_bytes_in_use", 0) / 2**30, 2),
         "checkpoint_gib": round(made["bytes"] / 2**30, 2),
@@ -1259,10 +1272,82 @@ def child_fusedsample(arg):
     })
 
 
+def child_moegrouped(arg):
+    """The grouped-matmul kernel alone (compiled on the chip, interpreted
+    off it) beside the einsums it stands in for, at JoyAI-LLM-Flash's
+    routed experts (256 of 2048 x 768, 8 a token, sigmoid scores) on the
+    256 rows of its decode step: the kernel is handed a 4-layer STACK and
+    the index of one layer, the einsums that layer's slice."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops.activations import ACT2FN
+    from areal_tpu.ops.pallas import moe_grouped as mg
+
+    T, X, k, E, F, L = (
+        (24, 8, 2, 128, 128, 2) if arg["rehearse"]
+        else (256, 256, 8, 2048, 768, 4))
+    dtype, layer = jnp.bfloat16, L - 2
+    keys = jax.random.split(jax.random.key(arg["seed"]), 5)
+    one = lambda key, shape: jax.random.normal(key, shape, dtype) * 0.02
+    # one layer drawn and stacked: the draw's temporaries stay a layer's
+    w_gate, w_up, w_down = (
+        jnp.tile(one(key, shape)[None], (L, 1, 1, 1))
+        for key, shape in zip(keys, ((X, E, F), (X, E, F), (X, F, E))))
+    x = jax.random.normal(keys[3], (T, E), dtype)
+    scores = jax.nn.sigmoid(jax.random.normal(keys[4], (T, X)))
+    top_vals, top_idx = jax.lax.top_k(scores, k)
+    top_vals = 2.5 * top_vals / top_vals.sum(-1, keepdims=True)
+    onehot = jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
+    sizes = onehot.sum((0, 1))
+
+    def einsums(x, w_gate, w_up, w_down):
+        combine = (top_vals[:, :, None] * onehot).sum(1)
+        h = ACT2FN["silu"](jnp.einsum("te,xef->txf", x, w_gate)) * jnp.einsum(
+            "te,xef->txf", x, w_up)
+        return jnp.einsum(
+            "txf,xfe->te", h * combine.astype(h.dtype)[:, :, None], w_down)
+
+    grouped = jax.jit(lambda *a: mg.moe_grouped(*a, activation="silu"))
+    args = (x, top_idx, top_vals, sizes, w_gate, w_up, w_down,
+            jnp.int32(layer))
+    names = re.findall(
+        r'kernel_name = "([^"]+)"', grouped.lower(*args).as_text())
+    want = jax.jit(einsums)(x, w_gate[layer], w_up[layer], w_down[layer])
+    got = grouped(*args)
+
+    def ms(f, *a):
+        f(*a).block_until_ready()
+        t0 = time.time()
+        for _ in range(10):
+            y = f(*a)
+        y.block_until_ready()
+        return round((time.time() - t0) * 100, 3)
+
+    f32 = lambda a: a.astype(jnp.float32)
+    emit({
+        "rows": T, "experts": X, "a_token": k, "widths": [E, F],
+        "stack_layers": L, "layer": layer, "row_tile": mg.row_tile(T * k, X),
+        "experts_hit": int((sizes > 0).sum()),
+        "max_abs_diff_vs_einsums": float(jnp.abs(f32(got) - f32(want)).max()),
+        # a few roundings to bf16 apart, at the outputs' own size
+        "tolerance": float(jnp.abs(f32(want)).max()) * 2 ** -6,
+        "kernel": names[0] if names else None,
+        "ms_grouped": ms(grouped, *args),
+        "ms_einsums_on_the_slice": ms(
+            jax.jit(einsums), x, w_gate[layer], w_up[layer], w_down[layer]),
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 CHILDREN = {
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,
+    "moegrouped": child_moegrouped,
 }
 
 

@@ -93,3 +93,55 @@ def _seed():
 
     seeding.set_random_seed(1, "test")
     yield
+
+
+@pytest.fixture
+def check_moe_grouped_serves_the_same(monkeypatch):
+    """``check(make_engine, prompts)``: an expert model's engine at test
+    size serves ``prompts`` (greedy) once with the routed experts on the
+    einsums and once on the ``moe_grouped`` kernel (interpret mode), by
+    patching the rule the engine asks (``ops/moe.py:moe_grouped_applies``:
+    a test's patch, not a switch of the program). The tokens are the same,
+    and ``moe_grouped_rows`` / ``moe_dense_rows`` add up to rows x expert
+    layers x steps on the chunks' spans, tokens x expert layers of the
+    prefill chunks on admission's, and to their sum on ``engine.stats``."""
+    from areal_tpu.base import tracing
+    from areal_tpu.gen.engine import GenRequest
+    from areal_tpu.ops import moe as moe_ops
+
+    def check(make_engine, prompts, n_new=10, steps=4):
+        served = {}
+        for grouped in (False, True):
+            monkeypatch.setattr(
+                moe_ops, "moe_grouped_applies", lambda *a, **k: grouped)
+            eng = make_engine()
+            tracing.drain()
+            for i, p in enumerate(prompts):
+                eng.submit(GenRequest(
+                    rid=f"r{i}", input_ids=p, max_new_tokens=n_new,
+                    temperature=0.0))
+            outs = {o.rid: o.output_ids for o in eng.run_until_done(steps)}
+            spans = [s for s in tracing.drain() if s["name"] in (
+                "gen_engine/chunk", "gen_engine/admit")]
+            ran, idle = (
+                ("moe_grouped_rows", "moe_dense_rows") if grouped
+                else ("moe_dense_rows", "moe_grouped_rows"))
+            layers = eng.cfg.n_moe_layers
+            chunks = [s["attrs"] for s in spans if "slots" in s["attrs"]]
+            assert chunks and all(
+                c[ran] == eng.B * layers * c["steps"] and c[idle] == 0
+                for c in chunks)
+            admits = [s["attrs"] for s in spans
+                      if s["name"] == "gen_engine/admit"]
+            prefilled = sum(a[ran] for a in admits)
+            # whole prefill chunks of whole row buckets, every expert layer
+            assert prefilled >= layers * sum(len(p) - 1 for p in prompts)
+            assert prefilled % (layers * eng.admit_chunk) == 0
+            assert all(a[idle] == 0 for a in admits)
+            assert eng.stats[ran] == prefilled + sum(c[ran] for c in chunks)
+            assert eng.stats[idle] == 0
+            served[grouped] = outs
+        assert all(len(v) == n_new for v in served[True].values())
+        assert served[True] == served[False]
+
+    return check

@@ -723,3 +723,13 @@ def test_trainer_gradients_match_reference(params, ppo_case):
         ulp = 4 * np.finfo(np.float32).eps * float(np.abs(w).max()) / scale
         np.testing.assert_allclose(
             a / scale, b / scale, atol=max(1e-3, ulp), err_msg=name)
+
+
+def test_engine_serves_the_same_tokens_through_the_grouped_kernel(
+        params, rng, check_moe_grouped_serves_the_same):
+    """The routed experts on the einsums and on ``moe_grouped``
+    (sigmoid scores with a correction bias, a leading dense
+    layer, the latent pool): the same
+    greedy tokens, and the two counters add up (``conftest.py``)."""
+    prompts = [[int(x) for x in rng.integers(1, 128, n)] for n in (5, 19, 33)]
+    check_moe_grouped_serves_the_same(lambda: _engine(params), prompts)

@@ -178,3 +178,178 @@ def test_experts_run_under_a_named_scope():
     scoped = [ln for ln in hlo.splitlines() if moe_ops.EXPERTS_SCOPE in ln]
     assert sum("dot_general" in ln for ln in scoped) == 3
     assert not any("top_k" in ln or "softmax" in ln for ln in scoped)
+
+
+# ------------------------------------------------------------------ #
+# the grouped-matmul kernel (``ops/pallas/moe_grouped.py``, interpret
+# mode here) against the einsums
+# ------------------------------------------------------------------ #
+
+def _grouped_case(T=24, X=8, k=2, scoring="softmax", act="silu",
+                  early=False, E=16, F=32):
+    cfg = dataclasses.replace(
+        _cfg(top_k=k),
+        activation_function=act,
+        moe=MoEConfig(
+            num_experts=X, top_k=k, aux_loss_coeff=0.01, z_loss_coeff=0.001,
+            scoring=scoring, router_on_layer_input=early,
+            routed_scaling_factor=2.5 if scoring == "sigmoid" else 1.0,
+        ),
+    )
+    return cfg, T, E, F
+
+
+GROUPED_CASES = {
+    # name: (case keywords, how the router's logits are bent, layer)
+    "random_routing": (dict(), None, 1),
+    "an_expert_with_no_row": (dict(), "starve", 1),
+    "every_row_on_one_expert": (dict(k=1), "one", 1),
+    "pairs_not_a_multiple_of_the_tile": (dict(T=37, k=3), None, 1),
+    "one_row": (dict(T=1), None, 1),
+    "more_rows_than_a_tile_an_expert": (dict(T=300, X=4), "one_heavy", 1),
+    "sigmoid_scoring": (dict(scoring="sigmoid"), None, 1),
+    "reglu": (dict(act="relu"), None, 1),
+    "router_on_the_layer_input": (dict(early=True), None, 1),
+    "first_layer_of_the_stack": (dict(), None, 0),
+    "last_layer_of_the_stack": (dict(), None, 2),
+}
+
+
+@pytest.mark.parametrize("name", list(GROUPED_CASES))
+def test_grouped_kernel_matches_the_einsums(name):
+    """``moe_mlp`` with the routed matrices as (stack, index) — the
+    kernel — against ``moe_mlp`` with the layer's slice — the einsums:
+    outputs within rounding (float32 here: the order of the sums),
+    ``top_idx`` identical, the aux loss to the last bit or the one before
+    it."""
+    kw, bend, layer = GROUPED_CASES[name]
+    cfg, T, E, F = _grouped_case(**kw)
+    X, L = cfg.moe.num_experts, 3
+    ks = jax.random.split(jax.random.PRNGKey(7), 8)
+    w = lambda key, shape: jax.random.normal(key, shape, jnp.float32) * 0.1
+    stacks = {
+        "w_gate": w(ks[0], (L, X, E, F)),
+        "w_up": w(ks[1], (L, X, E, F)),
+        "w_down": w(ks[2], (L, X, F, E)),
+    }
+    router = w(ks[3], (E, X)) * 10
+    x = jax.random.normal(ks[4], (T, E), jnp.float32)
+    rest = {"router": router}
+    if cfg.moe.scoring == "sigmoid":
+        rest["b_router"] = w(ks[5], (X,))
+    if bend == "starve":        # expert 3 never wins
+        rest["router"] = router.at[:, 3].set(0.0)
+        rest["b_router"] = jnp.zeros((X,)).at[3].set(-1e9)
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, scoring="sigmoid"))
+    elif bend in ("one", "one_heavy"):   # expert 2 always wins
+        rest["router"] = jnp.zeros((E, X))
+        rest["b_router"] = jnp.zeros((X,)).at[2].set(
+            5.0 if bend == "one" else 0.5)
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, scoring="sigmoid"))
+    early = (
+        jax.random.normal(ks[6], (T, E), jnp.float32)
+        if cfg.moe.router_on_layer_input else None)
+    dense_p = dict(rest, **{k: v[layer] for k, v in stacks.items()})
+    want, aux_w, idx_w = jax.jit(
+        lambda p: moe_ops.moe_mlp(cfg, p, x, router_input=early))(dense_p)
+    got, aux_g, idx_g = jax.jit(
+        lambda s, i: moe_ops.moe_mlp(
+            cfg, rest, x, router_input=early, routed=(s, i))
+    )(stacks, jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(idx_g), np.asarray(idx_w))
+    # one formula over the same choice, compiled into two programs
+    np.testing.assert_allclose(
+        np.asarray(aux_g), np.asarray(aux_w), rtol=1e-6, atol=0)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5)
+    if bend == "starve":
+        assert not (np.asarray(idx_g) == 3).any()
+    if bend == "one":
+        assert (np.asarray(idx_g) == 2).all()
+
+
+def test_grouped_kernel_in_bfloat16_is_within_rounding_of_the_einsums():
+    cfg, T, E, F = _grouped_case(T=64, E=128, F=128)
+    X = cfg.moe.num_experts
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    w = lambda key, shape: (
+        jax.random.normal(key, shape, jnp.float32) * 0.1
+    ).astype(jnp.bfloat16)
+    stacks = {
+        "w_gate": w(ks[0], (2, X, E, F)), "w_up": w(ks[1], (2, X, E, F)),
+        "w_down": w(ks[2], (2, X, F, E)),
+    }
+    rest = {"router": w(ks[3], (E, X))}
+    x = jax.random.normal(ks[4], (T, E), jnp.bfloat16)
+    want, _, idx_w = moe_ops.moe_mlp(
+        cfg, dict(rest, **{k: v[1] for k, v in stacks.items()}), x)
+    got, _, idx_g = moe_ops.moe_mlp(
+        cfg, rest, x, routed=(stacks, jnp.int32(1)))
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(idx_g), np.asarray(idx_w))
+    scale = float(jnp.abs(want.astype(jnp.float32)).max())
+    # two or three roundings to 8 bits of mantissa apart
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=scale * 2 ** -6)
+
+
+def test_tile_plan_pads_every_run_to_whole_tiles():
+    """The kernel's walk over runs of 0, 5, 0, 40, 3 pairs in tiles of 16:
+    expert 1 takes tile 0, expert 3 tiles 1-3, expert 4 tile 4; an expert
+    with no pair takes none; the tiles past the fifth repeat it."""
+    from areal_tpu.ops.pallas import moe_grouped as mg
+
+    sizes = jnp.asarray([0, 5, 0, 40, 3])
+    expert, first_row, n_active = mg.tile_plan(sizes, 8, 16)
+    assert int(n_active[0]) == 5
+    assert expert.tolist() == [1, 3, 3, 3, 4, 4, 4, 4]
+    assert first_row.tolist() == [0, 0, 16, 16, 64]
+    assert [mg.row_tile(n, x) for n, x in (
+        (8, 256), (2048, 256), (8192, 256), (512, 64), (8192, 64),
+    )] == [16, 16, 64, 16, 128]
+
+
+class _Mesh:
+    def __init__(self, size):
+        self.size = size
+
+
+# JoyAI's, OLMoE's and SmallThinker's experts a token; the rows of their
+# decode steps and admission programs (ISSUE 40's expected verdicts)
+@pytest.mark.parametrize(
+    "platform,mesh,dtype,T,X,k,want",
+    [
+        ("tpu", None, "bfloat16", 256, 256, 8, True),    # JoyAI decode
+        ("tpu", None, "bfloat16", 1024, 256, 8, True),   # a full wave
+        ("tpu", None, "bfloat16", 1024, 64, 8, True),
+        ("tpu", None, "bfloat16", 1024, 64, 6, True),
+        ("tpu", None, "bfloat16", 128, 256, 8, False),   # one-row admission
+        ("tpu", None, "bfloat16", 128, 64, 8, False),
+        ("tpu", None, "bfloat16", 64, 64, 8, False),     # OLMoE decode
+        ("tpu", None, "bfloat16", 112, 64, 6, False),    # SmallThinker
+        ("tpu", None, "bfloat16", 8, 256, 8, True),      # 23 % of them hit
+        ("tpu", None, "bfloat16", 8, 64, 8, False),      # 66 % hit
+        ("tpu", _Mesh(1), "bfloat16", 256, 256, 8, True),
+        ("tpu", _Mesh(4), "bfloat16", 256, 256, 8, False),
+        ("tpu", None, "float32", 256, 256, 8, False),    # a lazy cast
+        ("cpu", None, "bfloat16", 256, 256, 8, False),
+        ("tpu", None, "bfloat16", 0, 256, 8, False),
+    ],
+)
+def test_moe_grouped_applies(platform, mesh, dtype, T, X, k, want):
+    cfg = dataclasses.replace(
+        _cfg(top_k=k), dtype="bfloat16",
+        moe=MoEConfig(num_experts=X, top_k=k))
+    params = {"layers": {"mlp": {
+        "w_gate": jax.ShapeDtypeStruct((2, X, 16, 32), jnp.dtype(dtype))}}}
+    assert moe_ops.moe_grouped_applies(
+        cfg, params, mesh, rows=T, platform=platform) is want
+
+
+def test_moe_grouped_applies_to_no_model_without_a_router():
+    cfg = dataclasses.replace(_cfg(), mlp_type="gated", moe=None)
+    assert not moe_ops.moe_grouped_applies(
+        cfg, {}, None, rows=1024, platform="tpu")

@@ -464,3 +464,12 @@ def test_router_agreement_counts(params, rng):
     plain = _ppo_sample(rng, seqs, [len(p) for p in prompts], behav)
     _, attrs = counts(plain)
     assert "router_total" not in attrs
+
+
+def test_engine_serves_the_same_tokens_through_the_grouped_kernel(
+        params, rng, check_moe_grouped_serves_the_same):
+    """The routed experts on the einsums and on ``moe_grouped``
+    (softmax scores over 8 of the experts, a K/V pool): the same
+    greedy tokens, and the two counters add up (``conftest.py``)."""
+    prompts = [[int(x) for x in rng.integers(1, 128, n)] for n in (5, 19, 33)]
+    check_moe_grouped_serves_the_same(lambda: _engine(params), prompts)

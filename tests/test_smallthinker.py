@@ -984,3 +984,13 @@ def test_benchmark_bytes_of_a_cache_with_layer_kinds():
     assert not rx.search("jit_chunk/%while.3 while (s32[]) <- bf16[8,64,768,2560]")
     assert not rx.search("jit_chunk/%fusion.1 fusion bf16[112,2560] "
                          "<- bf16[8,2560,3584]")
+
+
+def test_engine_serves_the_same_tokens_through_the_grouped_kernel(
+        params, rng, check_moe_grouped_serves_the_same):
+    """The routed experts on the einsums and on ``moe_grouped``
+    (a router on the layer's input, ReGLU experts, layer kinds:
+    the index in the stack comes from the period): the same
+    greedy tokens, and the two counters add up (``conftest.py``)."""
+    prompts = [[int(x) for x in rng.integers(1, 128, n)] for n in (5, 19, 33)]
+    check_moe_grouped_serves_the_same(lambda: _engine(params), prompts)

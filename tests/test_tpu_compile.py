@@ -76,17 +76,22 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the three kernel modules off interpret mode (on the CPU their
     `_interpret()` says True) for the duration of one test."""
     from areal_tpu.ops.pallas import flash_attention, fused_sample
-    from areal_tpu.ops.pallas import kv_page_write
+    from areal_tpu.ops.pallas import kv_page_write, moe_grouped
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
-    for mod in (flash_attention, fused_sample, pl_paged, kv_page_write):
+    for mod in (flash_attention, fused_sample, pl_paged, kv_page_write,
+                moe_grouped):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     # ... and the fused epilogue's dispatch (and the engine's rule) off the
     # CPU they would see: a chunk built with ``fused=True`` ends in the
     # kernel, as on the chip, not in the streamed XLA pass
     from areal_tpu.ops import fused_sample as fused_ops
+    from areal_tpu.ops import moe as moe_ops
 
     monkeypatch.setattr(fused_ops, "_platform", lambda: "tpu")
+    # ... and the routed experts' (``moe_grouped_applies``): a program that
+    # hands them enough rows holds the grouped-matmul kernel
+    monkeypatch.setattr(moe_ops, "_platform", lambda: "tpu")
 
 
 def _spec(shape, dtype, sharding):
@@ -473,6 +478,8 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
     text = compiled.as_text()
     assert "paged_decode" in text and "kv_page_write" in text
     assert eng.fused       # the rule, on what the fixture describes
+    # OLMoE's 64 rows a step are under the ridge; the others have no router
+    assert not re.search(r"%moe_grouped(\.\d+)? = ", text)
     if fused:
         _assert_fused_epilogue(text, c["B"], cfg.vocab_size)
     else:
@@ -487,6 +494,17 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
     assert mem.alias_size_in_bytes >= pool_bytes
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < weight_bytes + pool_bytes + 1.0e9)
+
+
+def _assert_no_slice_of_the_routed_stack(text, cfg):
+    """No instruction of the compiled program RESULTS in one layer's
+    ``[X, E, F]`` or ``[X, F, E]`` of the routed stacks (what XLA makes of
+    a dynamic slice it cannot fuse into its reader: a custom call's
+    operand)."""
+    X, E, F = cfg.moe.num_experts, cfg.hidden_dim, cfg.expert_dim
+    for shape in (f"bf16[{X},{E},{F}]", f"bf16[{X},{F},{E}]"):
+        assert not re.search(
+            r"= (\()?" + re.escape(shape) + r"\{", text), shape
 
 
 @pytest.fixture(scope="module")
@@ -573,11 +591,21 @@ def test_joyai_engine_programs_compile(
     assert ("mla_decode" in text) == (program == "jit_chunk")
     # fresh latents reach the pool by the tile-copy kernel, in the chunk
     # and in admission's write program; admission's layers hold no write
-    assert ("kv_page_write" in text) == (program != "jit_extend")
+    # (the instruction, not the word: a jnp helper first traced inside
+    # ``kv_page_write.py`` keeps that file in its ops' metadata wherever
+    # it is used next)
+    assert bool(re.search(r"%kv_page_write(\.\d+)? = ", text)) == (
+        program != "jit_extend")
     if program == "jit_chunk":
         # the step ends in the fused kernel: no [256, 129280] logits, so
         # no head for XLA to rematerialise at the memory limit
         _assert_fused_epilogue(text, B, eng.cfg.vocab_size)
+    # 256 rows a decode step and 1024 a wave: both over the ridge, so the
+    # routed experts are the grouped-matmul kernel, which is handed the
+    # STACK: no op's result is a layer's slice of it
+    assert bool(re.search(r"%moe_grouped(\.\d+)? = ", text)) == (
+        program != "jit_write")
+    _assert_no_slice_of_the_routed_stack(text, eng.cfg)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.6e9
 
@@ -771,5 +799,197 @@ def test_hybrid_engine_programs_compile(
         program != "jit_extend")
     if program == "jit_chunk":
         _assert_fused_epilogue(text, B, eng.cfg.vocab_size)
+    # 112 rows a decode step: under the ridge, the einsums (the program the
+    # engine ran before the kernel existed); the wave's 1024: the kernel
+    assert bool(re.search(r"%moe_grouped(\.\d+)? = ", text)) == (
+        program == "jit_extend")
+    if program == "jit_extend":
+        _assert_no_slice_of_the_routed_stack(text, eng.cfg)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+# ------------------------------------------------------------------ #
+# moe_grouped: the routed experts as one grouped matmul over the stack
+# ------------------------------------------------------------------ #
+
+# experts, experts a token, hidden, expert width, expert layers of the cell
+MOE_CELLS = {
+    "joyai": dict(X=256, k=8, E=2048, F=768, L=4, act="silu"),
+    "olmoe": dict(X=64, k=8, E=2048, F=1024, L=8, act="silu"),
+    "smallthinker": dict(X=64, k=6, E=2560, F=768, L=8, act="relu"),
+}
+
+
+@pytest.mark.parametrize(
+    "cell,T",
+    [("joyai", 256), ("joyai", 1024), ("olmoe", 1024),
+     ("smallthinker", 1024), ("joyai", 1)],
+)
+def test_moe_grouped_compiles(compiled_kernels, one_chip, cell, T):
+    """The kernel at the three expert cells' widths, for JoyAI's decode
+    step, a full admission wave of each, and one row: it lowers for the
+    described v5e with the stacks as they are (no ``[X, E, F]`` copy: the
+    layer is a scalar-prefetch index), inside the VMEM it asks for."""
+    from areal_tpu.ops.pallas import moe_grouped as mg
+
+    c = MOE_CELLS[cell]
+    X, k, E, F, L = c["X"], c["k"], c["E"], c["F"], c["L"]
+
+    def f(x, top_idx, top_vals, sizes, w_gate, w_up, w_down, layer):
+        return mg.moe_grouped(
+            x, top_idx, top_vals, sizes, w_gate, w_up, w_down, layer,
+            activation=c["act"])
+
+    compiled = _compile(
+        f,
+        _spec((T, E), jnp.bfloat16, one_chip),
+        _spec((T, k), jnp.int32, one_chip),
+        _spec((T, k), jnp.float32, one_chip),
+        _spec((X,), jnp.float32, one_chip),
+        _spec((L, X, E, F), jnp.bfloat16, one_chip),
+        _spec((L, X, E, F), jnp.bfloat16, one_chip),
+        _spec((L, X, F, E), jnp.bfloat16, one_chip),
+        _spec((), jnp.int32, one_chip),
+    )
+    text = compiled.as_text()
+    assert re.search(r"%moe_grouped(\.\d+)? = ", text)
+    assert f"bf16[{X},{E},{F}]" not in text
+    assert f"bf16[{X},{F},{E}]" not in text
+    # the padded sorted rows in (bf16) and out (f32), with room for one
+    # more of each: not the einsums' two [T, X, F]
+    rows = T * k + min(X, T * k) * mg.row_tile(T * k, X)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        2 * rows * E * 6 + 2 ** 20)
+
+
+def _benchmark_config(config: str):
+    """``(cfg, weight shapes)`` of ``benchmark/configs/<config>.json`` as
+    the benchmark runs it."""
+    import json
+
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", config + ".json")) as f:
+        cfg = sut.model_config(json.load(f), {})
+    return cfg, sut.weight_shapes(cfg, cfg.dtype)
+
+
+def test_olmoe_wave_holds_the_kernel_and_its_decode_step_does_not(
+        compiled_kernels, one_chip):
+    """The OLMoE cell's engine: a wave of 8 x 128 tokens (1024 rows, 4.3 x
+    the ridge) runs the routed experts through ``moe_grouped`` on the
+    whole stack; the rule keeps its 64-row decode step and its one-row
+    admission program on the einsums."""
+    from areal_tpu.gen.engine import GenerationEngine
+
+    c = KV_WRITE_CELLS["olmoe"]
+    cfg, shapes = _benchmark_config(c["config"])
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=c["B"], max_seqlen=c["seqlen"],
+        max_new_tokens_cap=c["out"], page_size=128, n_pages=80, seed=0)
+    C = eng.admit_chunk
+    assert [eng._moe_grouped(rows) for rows in (c["B"], C, 8 * C)] == [
+        False, False, True]
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    n, W = 8, 32
+    text = eng._extend_fn(n, W, skip_pool=False).lower(
+        jax.tree.map(spec, shapes), jax.tree.map(spec, eng.state),
+        _spec((n, C), jnp.int32, one_chip),
+        _spec((n, W), jnp.int32, one_chip),
+        _spec((n,), jnp.int32, one_chip),
+        _spec((n,), jnp.int32, one_chip),
+    ).compile().as_text()
+    assert re.search(r"%moe_grouped(\.\d+)? = ", text)
+    _assert_no_slice_of_the_routed_stack(text, cfg)
+
+
+# ------------------------------------------------------------------ #
+# a program the rule leaves to the einsums is the program it was
+# ------------------------------------------------------------------ #
+
+# sha256 (16 hex digits) of the StableHLO of decode_step_paged,
+# extend_paged_kv, forward_packed and its gradient at the benchmark's six
+# configurations (real widths, a tiny batch), taken at the commit before
+# the grouped kernel's (PR 37's). A forward that changes on purpose
+# changes its line here.
+FORWARD_HASHES = {
+    "r1d-qwen-1p5b": (
+        "7cd5a9d6e3b9b38e", "c486ef017c9542d2", "0028c23830abfc7a",
+        "3cf1838c96fd400a"),
+    "r1d-qwen-7b-l16": (
+        "fef06ad21a9a4993", "e165b92075f76cfb", "6c9ed9487d925f10",
+        "2b863e5eef51fbd1"),
+    "ouro-2p6b-l8": (
+        "5d68e08adaf3e168", "2c177b99a1758a49", "0b368df30a8875a6",
+        "40ce0428625e79f5"),
+    "olmoe-1b-7b-l8": (
+        "ae37e3d107b1d6f6", "b605946f099dbfa5", "0fbc94975171839f",
+        "e30805641f8f4350"),
+    "joyai-flash-l5": (
+        "c4379504a8fa255a", "ff2da943fb04abdb", "8cf30c2a352c52fa",
+        "72aa22489a7ab0a3"),
+    "smallthinker-21b-l8": (
+        "a0e19bf38715ae01", "a91439fc8081a034", "c881c069dfe706c9",
+        "a7f3ebf91e10838f"),
+}
+
+
+def forward_hashes(config: str):
+    """``(decode, extend, packed, packed's gradient)``: the hashes above,
+    of this tree."""
+    import hashlib
+
+    from areal_tpu.models import transformer as tfm
+
+    cfg, shapes = _benchmark_config(config)
+    B, M, T = 8, 4, 64
+    cache = jax.eval_shape(lambda: tfm.PagedKVCache.empty(cfg, 12, 16))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    kinds = len(cfg.layer_kinds)
+    table = i32(B, M) if kinds == 1 else i32(kinds, B, M)
+
+    def decode(p, c, t, tb, ln, a):
+        return tfm.decode_step_paged(
+            p, cfg, c, t, tb, ln, a, use_pallas=False)[:3]
+
+    def extend(p, c, t, tb, s, n):
+        return tfm.extend_paged_kv(p, cfg, c, t, tb, s, n)
+
+    def packed(p, i, s, q):
+        return tfm.forward_packed(p, cfg, i, s, q)
+
+    def loss(p, i, s, q):
+        return tfm.forward_packed(p, cfg, i, s, q, with_aux=True)[0].sum()
+
+    return tuple(
+        hashlib.sha256(
+            jax.jit(f).lower(*a).as_text().encode()).hexdigest()[:16]
+        for f, a in (
+            (decode, (shapes, cache, i32(B), table, i32(B),
+                      jax.ShapeDtypeStruct((B,), jnp.bool_))),
+            (extend, (shapes, cache, i32(B, 16), table, i32(B), i32(B))),
+            (packed, (shapes, i32(T), i32(T), i32(T))),
+            (jax.grad(loss), (shapes, i32(T), i32(T), i32(T))),
+        ))
+
+
+@pytest.mark.parametrize("config", list(FORWARD_HASHES))
+def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
+        config):
+    """The three forwards as every caller but an engine over the ridge
+    builds them (``moe_grouped`` left False: the trainer, ``ppo/inference``,
+    a mesh, the CPU, every model without a router, OLMoE's and
+    SmallThinker's decode steps) lower to the StableHLO they lowered to
+    before the kernel existed: the routed stacks leave the scanned tree
+    only in a program that runs the kernel."""
+    assert forward_hashes(config) == FORWARD_HASHES[config]
