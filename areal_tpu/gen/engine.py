@@ -87,6 +87,7 @@ from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import fused_sample as fused_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import paged_attention as paged_ops
+from areal_tpu.ops import ssm as ssm_ops
 
 logger = logging.getLogger("areal_tpu.gen.engine")
 
@@ -138,6 +139,18 @@ class GenState:
     # MoE routing record (``record_routing``; None otherwise): the experts
     # the decode step that produced out_tokens[b, i] chose in every layer
     out_routing: Optional[jnp.ndarray] = None   # [B, G, L, top_k] i32
+    # a model with state-space layers (``cfg.ssm``; None otherwise): what
+    # those layers keep of each SLOT in place of keys and values, ``ssm
+    # [Ls, B, H, P, N]`` float32 and ``conv [Ls, B, (K - 1) x C]``. Allocated
+    # by slot, not by page; zeroed or seeded from a snapshot at admission
+    # (never inherited from the slot's last tenant), carried between
+    # admission's chunks, updated in place by every decode step.
+    ssm: Optional[tfm.SSMState] = None
+    # the prefix cache's SNAPSHOTS of that state, ``[Ls, n_snapshots,
+    # ...]``: entry ``i`` is the state after exactly the tokens of the
+    # registry node that files it (``PrefixRegistry``), so a prompt that
+    # hits there is seeded by one copy instead of a prefill
+    snaps: Optional[tfm.SSMState] = None
 
 
 @dataclasses.dataclass
@@ -187,6 +200,9 @@ class GenOutput:
     # INPUT token at position ``prompt_len - 1 + i`` of the sequence (the
     # trainer's ``routed_experts`` key wants it at that position)
     output_routing: Optional[np.ndarray] = None
+    # prompt tokens this request did NOT prefill: served from shared pages
+    # (and, with recurrent state, a snapshot) of the prefix cache
+    prefix_hit_tokens: int = 0
 
 
 def _page_ids(page, kind: Optional[int] = None):
@@ -237,6 +253,7 @@ class _SlotInfo:
     t_submit: Optional[float] = None    # the GenOutput's timestamps
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
+    prefix_hit_tokens: int = 0
 
 
 class GenerationEngine:
@@ -272,6 +289,7 @@ class GenerationEngine:
         fused_sample: Optional[bool] = None,
         spec_k_adapt: Optional[bool] = None,
         record_routing: bool = False,
+        state_snapshots: int = 8,
     ):
         # one listener a process, live before the first device work below
         tracing.listen_for_compiles()
@@ -405,6 +423,26 @@ class GenerationEngine:
                 )
                 self.draft_kv_dtype = _resolve_kv_dtype(dkd, dcfg.dtype)
                 self.draft_kv_quantized = self.draft_kv_dtype == "int8"
+            self._stateful = cfg.ssm is not None
+            if self._stateful:
+                # what has no test beside per-slot recurrent state is
+                # refused, not approximated
+                if self._draft is not None or spec_on:
+                    raise NotImplementedError(
+                        "state-space layers: a draft model or speculative "
+                        "chunks beside recurrent state are not supported (a "
+                        "rejected draft would need the state rolled back)"
+                    )
+                if self.kv_quantized:
+                    raise NotImplementedError(
+                        "state-space layers: an int8 page pool beside "
+                        "recurrent state is not supported"
+                    )
+                if mesh is not None and mesh.size > 1:
+                    raise NotImplementedError(
+                        "state-space layers: the per-slot state has no "
+                        "sharding; a mesh of several devices is not supported"
+                    )
             if mesh is not None:
                 if "model" not in mesh.axis_names:
                     raise ValueError(
@@ -508,7 +546,13 @@ class GenerationEngine:
                 else self.B * self.M * bytes_ratio * K
             )
             self.pool = PagePool(self.n_pages, page_size)
-            self.prefix = PrefixRegistry(self.pool, self._windows)
+            self.n_snapshots = (
+                max(int(state_snapshots), 1)
+                if self._stateful and enable_prefix_cache else 0)
+            self.prefix = PrefixRegistry(
+                self.pool, self._windows,
+                n_snapshots=self.n_snapshots if self._stateful else None,
+            )
             # positions a dispatch may run ahead of the host's lengths: the
             # chunk's tokens and, in pipelined mode, the chunk still in flight,
             # at ``step``'s default of 16 decode steps (a longer chunk takes
@@ -562,6 +606,14 @@ class GenerationEngine:
                         )
                         if record_routing
                         else None
+                    ),
+                    ssm=(
+                        tfm.SSMState.empty(cfg, self.B)
+                        if self._stateful else None
+                    ),
+                    snaps=(
+                        tfm.SSMState.empty(cfg, self.n_snapshots)
+                        if self.n_snapshots else None
                     ),
                 )
 
@@ -702,6 +754,7 @@ class GenerationEngine:
             self._jit_extend: Dict[int, Any] = {}
             self._jit_kv_write: Dict[int, Any] = {}
             self._jit_commit: Dict[int, Any] = {}
+            self._jit_state: Dict[Any, Any] = {}
             self._jit_chunk: Dict[int, Any] = {}
             self._jit_spec: Dict[Any, Any] = {}
             # observability
@@ -757,6 +810,17 @@ class GenerationEngine:
                 # layer's weights once) of the decode chunks as dispatched
                 "loop_passes": 0,
                 "layer_passes": 0,
+                # a model with state-space layers: running slots x steps of
+                # the decode chunks as dispatched (each reads and writes one
+                # slot's state once a layer); snapshots of that state filed
+                # in the prefix cache, admissions seeded from one, the bytes
+                # copied in and out, and snapshots dropped to make room or
+                # with their node
+                "state_slots": 0,
+                "state_snapshots_taken": 0,
+                "state_snapshot_hits": 0,
+                "state_snapshot_bytes": 0,
+                "state_snapshot_evictions": 0,
             }
             start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
@@ -797,8 +861,8 @@ class GenerationEngine:
         admit buckets + decode/spec chunk sizes, NOT by prompt lengths)."""
         return (
             len(self._jit_extend) + len(self._jit_kv_write)
-            + len(self._jit_commit) + len(self._jit_chunk)
-            + len(self._jit_spec)
+            + len(self._jit_commit) + len(self._jit_state)
+            + len(self._jit_chunk) + len(self._jit_spec)
         )
 
     def n_jit_entries(self) -> int:
@@ -810,7 +874,7 @@ class GenerationEngine:
         return jitcache.total_cache_size(
             j
             for d in (self._jit_extend, self._jit_kv_write, self._jit_commit,
-                      self._jit_chunk, self._jit_spec)
+                      self._jit_state, self._jit_chunk, self._jit_spec)
             for j in d.values()
         )
 
@@ -826,6 +890,7 @@ class GenerationEngine:
             for name, d in (("extend", self._jit_extend),
                             ("kv_write", self._jit_kv_write),
                             ("commit", self._jit_commit),
+                            ("state", self._jit_state),
                             ("chunk", self._jit_chunk))
             for key, fn in d.items()
         }
@@ -1014,6 +1079,25 @@ class GenerationEngine:
                     host["out_logprobs"][b, :n].tolist(),
                 )
             return out
+
+    def recurrent_state(self, rid: str) -> Optional[Tuple[int, np.ndarray]]:
+        """What the state-space layers hold of the running request ``rid``:
+        ``(n, ssm)`` with ``ssm [Ls, H, P, N]`` float32 the recurrent state
+        after the prompt and all but the last of the ``n`` tokens generated
+        so far (the last is fed at the next step), both from ONE state
+        pytree. For a check from outside that the state is what the
+        recurrence says (the benchmark's, a test's); ``None`` for a request
+        that holds no slot or a model without such layers. The pull blocks
+        on any in-flight chunk, as ``partial_outputs``'s does."""
+        with self._lock:
+            st = self.state
+            if st.ssm is None:
+                return None
+            for b, s in enumerate(self._slots):
+                if s is not None and s.rid == rid:
+                    n, ssm = jax.device_get((st.n_gen[b], st.ssm.ssm[:, b]))
+                    return int(n), np.asarray(ssm)
+            return None
 
     def cancel(self, rid: str) -> bool:
         """Abort a request (client disconnected): drop it from the pending
@@ -1257,24 +1341,50 @@ class GenerationEngine:
         # (a draft model keeps the einsums)
         grouped = (self._moe_grouped(n_rows * self.admit_chunk), False)
 
-        def extend(*args):
-            model, (state, *chunk) = args[:-5], args[-5:]
-            fresh = tuple(
-                tfm.extend_paged_kv(
-                    p, c, kv, *chunk, skip_pool=skip_pool, moe_grouped=g)
-                for p, c, kv, g in zip(
-                    model, cfgs, (state.cache, state.draft_cache), grouped)
-            )
-            return jax.tree.map(
-                lambda x: jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * 3),
-                fresh,
-            )
+        def pad_rows(x):
+            return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
-        sharding_kw = self._jit_sharding(4)
+        if self._stateful:
+
+            def extend(params, state, tokens, table, start, n_new, slots):
+                # the rows continue their slots' state (read here, put
+                # back by the write program beside the fresh K/V)
+                ks, vs, rows = tfm.extend_paged_kv(
+                    params, self.cfg, state.cache, tokens, table, start,
+                    n_new, skip_pool=skip_pool, ssm=state.ssm, slots=slots)
+                return jax.tree.map(pad_rows, ((ks, vs),)), rows
+
+        else:
+
+            def extend(*args):
+                model, (state, *chunk) = args[:-5], args[-5:]
+                fresh = tuple(
+                    tfm.extend_paged_kv(
+                        p, c, kv, *chunk, skip_pool=skip_pool, moe_grouped=g)
+                    for p, c, kv, g in zip(
+                        model, cfgs, (state.cache, state.draft_cache),
+                        grouped)
+                )
+                return jax.tree.map(pad_rows, fresh)
+
+        sharding_kw = self._jit_sharding(5 if self._stateful else 4)
         sharding_kw.pop("out_shardings", None)
         jitted = jax.jit(extend, **sharding_kw)
         self._jit_extend[key] = jitted
         return jitted
+
+    def _ssm_update(self):
+        """What a decode step's state-space layers update the per-slot
+        state with: the ``ssm_decode`` kernel where it applies
+        (``ops/pallas/ssm_decode.py:ssm_decode_applies``: one TPU device,
+        one group of heads), which reads and writes the state once; else
+        ``None``, which is ``ops/ssm.py:step_update`` (XLA: two fusions a
+        layer that both read the state)."""
+        from areal_tpu.ops.pallas import ssm_decode
+
+        if ssm_decode.ssm_decode_applies(self.cfg, self.mesh):
+            return ssm_decode.ssm_decode
+        return None
 
     def _moe_grouped(self, rows: int) -> bool:
         """Whether a program that hands the target's routed experts ``rows``
@@ -1311,6 +1421,10 @@ class GenerationEngine:
         holds it; a padding row, ``n_new`` 0, costs the kernel a skipped
         loop), the wave's own where the XLA scatter does (it pays for
         every row it is given, dropped or not)."""
+        if self._stateful:
+            # the rows' recurrent state is put back by the same program,
+            # and a padding row of THAT is 76 MB at the published sizes
+            return n_rows
         return self.admit_buckets[-1] if self._kv_write_rows() else n_rows
 
     def _kv_write_fn(self, n_rows: int):
@@ -1322,21 +1436,28 @@ class GenerationEngine:
             return self._jit_kv_write[n_rows]
         write_kw = dict(use_pallas=self._decode_use_pallas, mesh=self.mesh)
 
-        def kv_write(state: GenState, fresh, table_rows, start, n_new):
+        def kv_write(state: GenState, fresh, table_rows, start, n_new,
+                     state_rows=None, slots=None):
             caches = [
                 tfm._write_chunk_kv(
                     kv, ks, vs, table_rows, start, n_new, **write_kw)
                 for kv, (ks, vs) in zip(
                     (state.cache, state.draft_cache), fresh)
             ]
-            return dataclasses.replace(
+            state = dataclasses.replace(
                 state, **dict(zip(("cache", "draft_cache"), caches))
             )
+            if state_rows is not None:
+                state = dataclasses.replace(
+                    state,
+                    ssm=tfm.put_ssm_rows(state.ssm, slots, state_rows))
+            return state
 
         sharding_kw = {}
         if self.mesh is not None:
             sharding_kw = dict(
-                in_shardings=(self._state_sh, None) + (self._repl,) * 3,
+                in_shardings=(self._state_sh, None) + (self._repl,) * 3
+                + ((None, self._repl) if self._stateful else ()),
                 out_shardings=self._state_sh,
             )
         jitted = jax.jit(kv_write, donate_argnums=(0,), **sharding_kw)
@@ -1485,17 +1606,127 @@ class GenerationEngine:
                 start = starts0 + c * C
                 if self._moe:
                     self._count_moe_rows(n * C, 1)
+                state_args = ()
+                if self._stateful:
+                    # each row's slot; a padding row's is past the last
+                    # (read clipped, its state dropped on the way back)
+                    slot_arr = np.full((n,), self.B, np.int32)
+                    slot_arr[: len(slots)] = slots
+                    state_args = (jnp.asarray(slot_arr),)
                 fresh = self._extend_fn(n, W, skip_pool)(
                     *self._model_args(), self.state,
                     jnp.asarray(all_tokens[:, c * C : (c + 1) * C]),
                     jnp.asarray(tables[..., :n, :W]),
                     jnp.asarray(start[:n]),
                     jnp.asarray(n_new[:n]),
+                    *state_args,
                 )
+                if self._stateful:
+                    fresh, state_rows = fresh
+                    state_args = (state_rows,) + state_args
                 self.state = self._kv_write_fn(nw)(
                     self.state, fresh, jnp.asarray(tables),
-                    jnp.asarray(start), jnp.asarray(n_new),
+                    jnp.asarray(start), jnp.asarray(n_new), *state_args,
                 )
+
+    # ------------------------------------------------------------------ #
+    # A slot's recurrent state (models with state-space layers)
+    # ------------------------------------------------------------------ #
+
+    def _state_copy_fn(self, n_rows: int, into_slots: bool):
+        """``(state, dst [n], src [n])`` -> state, donated. ``into_slots``:
+        slot ``dst[i]``'s state becomes snapshot ``src[i]``, or ZERO where
+        ``src[i] < 0`` (every admitted request goes through here before
+        its first chunk: a slot never starts from what its last tenant
+        left). Otherwise snapshot ``dst[i]`` becomes slot ``src[i]``'s
+        state. A ``dst`` past the end is a padding row."""
+        key = (n_rows, into_slots)
+        if key in self._jit_state:
+            return self._jit_state[key]
+
+        def copy(state: GenState, dst, src):
+            if into_slots:
+                if state.snaps is None:
+                    rows = jax.tree.map(
+                        lambda a: jnp.zeros(
+                            (a.shape[0], n_rows) + a.shape[2:], a.dtype),
+                        state.ssm)
+                else:
+                    keep = src >= 0
+                    rows = jax.tree.map(
+                        lambda a: jnp.where(
+                            keep.reshape((1, -1) + (1,) * (a.ndim - 2)),
+                            a[:, jnp.maximum(src, 0)], 0),
+                        state.snaps)
+                return dataclasses.replace(
+                    state, ssm=tfm.put_ssm_rows(
+                        state.ssm, dst, (rows.ssm, rows.conv)))
+            return dataclasses.replace(
+                state, snaps=tfm.put_ssm_rows(
+                    state.snaps, dst,
+                    (state.ssm.ssm[:, src], state.ssm.conv[:, src])))
+
+        jitted = jax.jit(
+            copy, donate_argnums=(0,),
+            **self._jit_sharding(2, with_params=False),
+        )
+        self._jit_state[key] = jitted
+        return jitted
+
+    def _copy_state(self, pairs: List[Tuple[int, int]], into_slots: bool):
+        """Run :meth:`_state_copy_fn` over ``(dst, src)`` pairs in row
+        buckets; counts the snapshot bytes moved."""
+        per = ssm_ops.state_bytes_per_slot(self.cfg)
+        i = 0
+        while i < len(pairs):
+            n = self._row_bucket(len(pairs) - i)
+            group = pairs[i : i + n]
+            i += len(group)
+            dst = np.full((n,), self.B + self.n_snapshots, np.int32)
+            src = np.full((n,), -1 if into_slots else 0, np.int32)
+            dst[: len(group)] = [d for d, _ in group]
+            src[: len(group)] = [s_ for _, s_ in group]
+            self.state = self._state_copy_fn(n, into_slots)(
+                self.state, jnp.asarray(dst), jnp.asarray(src))
+            self.stats["state_snapshot_bytes"] += per * sum(
+                1 for _, s_ in group if s_ >= 0 or not into_slots)
+
+    def _take_snapshots(self, rows: List[dict]):
+        """File the state of each row's slot, which stands at the row's
+        page-aligned boundary, in the snapshot entry reserved for it."""
+        pairs = [(r["snap_to"], r["slot"]) for r in rows
+                 if r.get("snap_to") is not None]
+        if pairs:
+            self._copy_state(pairs, into_slots=False)
+            self.stats["state_snapshots_taken"] += len(pairs)
+
+    def _run_state_waves(self, misses: List[dict], hits: List[dict]):
+        """Admission's waves for a model with per-slot recurrent state.
+        A row's prompt is ``[start, boundary)`` then ``[boundary, end)``:
+        ``boundary`` is where its page sharing ends (its longest page-
+        aligned prefix) if a snapshot is to be taken there (``snap_to``),
+        else ``start``. Order: cold prompts start from zero and run to
+        their boundary, their snapshots are taken; ONLY THEN are the
+        prefix hits seeded (a member of a group that arrived in one wave
+        with the group's first reads what that first has just written),
+        run to their own boundary (a partial hit) and snapshotted; the
+        tails of all rows run last."""
+
+        def part(rows, lo, hi):
+            return [
+                dict(r, tokens=r["ids"][r[lo]:r[hi]], start=r[lo])
+                for r in rows if r[hi] > r[lo]
+            ]
+
+        self._copy_state([(r["slot"], -1) for r in misses], into_slots=True)
+        self._run_extends(part(misses, "start", "boundary"))
+        self._take_snapshots(misses)
+        self._copy_state(
+            [(r["slot"], r["snap_from"]) for r in hits], into_slots=True)
+        self.stats["state_snapshot_hits"] += len(hits)
+        self._run_extends(part(hits, "start", "boundary"))
+        self._take_snapshots(hits)
+        self._run_extends(part(misses + hits, "boundary", "end"))
 
     def _admit(self):
         """``_admit_pending`` under its span: the host's share of a chunk
@@ -1507,6 +1738,8 @@ class GenerationEngine:
                 st["admitted"], st["prefill_tokens"], st["prefix_hit_tokens"],
                 st["kv_write_tiles"], st["window_pages_released"],
                 st["moe_grouped_rows"], st["moe_dense_rows"],
+                st["state_snapshots_taken"], st["state_snapshot_hits"],
+                st["state_snapshot_bytes"], st["state_snapshot_evictions"],
             )
             self._admit_pending()
             attrs.update(
@@ -1528,6 +1761,15 @@ class GenerationEngine:
                 # where their routed experts ran
                 attrs["moe_grouped_rows"] = st["moe_grouped_rows"] - before[5]
                 attrs["moe_dense_rows"] = st["moe_dense_rows"] - before[6]
+            if self._stateful:
+                # snapshots of the recurrent state filed by this wave,
+                # admissions it seeded from one, bytes copied in and out,
+                # snapshots dropped (for room, or with their node)
+                for i, name in enumerate((
+                    "state_snapshots_taken", "state_snapshot_hits",
+                    "state_snapshot_bytes", "state_snapshot_evictions",
+                ), start=7):
+                    attrs[name] = st[name] - before[i]
 
     def _admit_pending(self):
         if not self.accepting:
@@ -1535,10 +1777,11 @@ class GenerationEngine:
         free = [b for b, s in enumerate(self._slots) if s is None]
         if not free:
             return
+        self.prefix.pinned.clear()
         admitted: List[Tuple[GenRequest, int, dict]] = []
         misses: List[dict] = []
         hits: List[dict] = []
-        deferred_inserts: List[Tuple[List[int], int, int]] = []
+        deferred_inserts: List[Tuple[List[int], int, int, Optional[int]]] = []
         still_pending: List[GenRequest] = []
         with self._pending_lock:
             take = self._pending[: len(free) + 8]  # small lookahead
@@ -1553,6 +1796,8 @@ class GenerationEngine:
             shared: List = []
             if self.enable_prefix_cache and n_shared_full > 0:
                 shared = self.prefix.lookup(ids, n_shared_full) or []
+            # (recurrent state: the hit ends at a node with a snapshot)
+            snap_from = self.prefix.hit_snapshot if shared else None
             # a kind's pages taken now: all of a full kind's, as ever; a
             # window kind's up to the end of the prompt or to its claim,
             # whichever is less (the rest is reserved and taken as the
@@ -1622,11 +1867,29 @@ class GenerationEngine:
                 tables[j, len(shared):to] = owned[at : at + n_new_pages]
                 at += n_new_pages
             covered = len(shared) * self.page
+            info.prefix_hit_tokens = covered
             row = {
                 "tokens": ids[covered:plen_eff],
                 "start": covered,
                 "slot": slot,
             }
+            # recurrent state: a chain that grows gets a snapshot of the
+            # state at its new end, taken when the row's chunks stand
+            # exactly there (``_run_state_waves``)
+            snap_to = None
+            if (
+                self._stateful and self.enable_prefix_cache
+                and n_shared_full > len(shared)
+            ):
+                snap_to = self.prefix.alloc_snapshot()
+            if self._stateful:
+                row.update(
+                    ids=ids, end=plen_eff, snap_from=snap_from,
+                    snap_to=snap_to,
+                    boundary=(
+                        covered if snap_to is None
+                        else n_shared_full * self.page),
+                )
             if shared:
                 self.stats["prefix_hits"] += 1
                 self.stats["prefix_hit_tokens"] += covered
@@ -1637,7 +1900,8 @@ class GenerationEngine:
                     # extend waves run. This slot's pages are written in wave
                     # 2; inserting now would let a same-cycle borrower (also
                     # wave 2) read them before they are written.
-                    deferred_inserts.append((ids, slot, n_shared_full))
+                    deferred_inserts.append(
+                        (ids, slot, n_shared_full, snap_to))
             else:
                 misses.append(row)
                 if self.enable_prefix_cache and n_shared_full > 0:
@@ -1645,7 +1909,8 @@ class GenerationEngine:
                     # written in wave 1, so same-cycle group members can
                     # borrow them in wave 2
                     self.prefix.insert(
-                        ids, self._registry_pages(slot, n_shared_full))
+                        ids, self._registry_pages(slot, n_shared_full),
+                        snapshot=snap_to)
             self.stats["prefill_tokens"] += len(row["tokens"])
             self.stats["admitted"] += 1
             if self.kv_quantized and owned:
@@ -1663,10 +1928,16 @@ class GenerationEngine:
         # wave 1: unique prompts compute their KV; wave 2: prefix borrowers
         # extend only their tails (their shared pages were written by wave 1
         # or by earlier admissions)
-        self._run_extends(misses)
-        self._run_extends(hits)
-        for ins_ids, slot, n_full in deferred_inserts:
-            self.prefix.insert(ins_ids, self._registry_pages(slot, n_full))
+        if self._stateful:
+            self._run_state_waves(misses, hits)
+        else:
+            self._run_extends(misses)
+            self._run_extends(hits)
+        for ins_ids, slot, n_full, snap in deferred_inserts:
+            self.prefix.insert(
+                ins_ids, self._registry_pages(slot, n_full), snapshot=snap)
+        self.stats["state_snapshot_evictions"] = (
+            self.prefix.snapshot_evictions)
         # commit slot state in row buckets
         i = 0
         while i < len(admitted):
@@ -1758,7 +2029,10 @@ class GenerationEngine:
                 return_hidden=fused,
                 with_routing=self._moe,
                 moe_grouped=self._moe_grouped(self._chunk_moe_rows()),
+                ssm=state.ssm,
+                ssm_update=self._ssm_update(),
             )
+            ssm = routing.pop() if self._stateful else None
             if self._draft is not None:
                 # keep the draft pool current: one HEADLESS draft decode
                 # step writes the draft model's KV of the token the
@@ -1878,6 +2152,7 @@ class GenerationEngine:
                 ctx_tokens=ctx_tokens,
                 rng=rng,
                 out_routing=out_routing,
+                ssm=ssm,
             ), census
 
         def flags_of(state: GenState, census):
@@ -2430,6 +2705,7 @@ class GenerationEngine:
             t_first=t_first,
             t_done=t_done,
             output_routing=None if routing is None else routing[:n],
+            prefix_hit_tokens=info.prefix_hit_tokens,
         )
 
     def _dispatch(self, decode_steps: int, running: List[int],
@@ -2473,10 +2749,19 @@ class GenerationEngine:
             # layers of cache behind them, and the layer runs (each reads
             # one layer's weights once) of the chunk as dispatched
             cfg = self.cfg
-            layer_passes = decode_steps * cfg.cache_layers
+            layer_passes = decode_steps * cfg.n_passes * cfg.n_layers
             chunk_attrs.update(
                 loop_passes=cfg.n_passes, cache_layers=cfg.cache_layers,
                 layer_passes=layer_passes)
+            if self._stateful:
+                # each running slot's state is read and written once a
+                # state-space layer a step; as dispatched: a slot that ends
+                # inside the chunk is counted to the chunk's end
+                chunk_attrs.update(
+                    state_slots=len(running) * decode_steps,
+                    state_bytes_per_slot=ssm_ops.state_bytes_per_slot(cfg),
+                    state_layers=cfg.n_ssm_layers)
+                self.stats["state_slots"] += chunk_attrs["state_slots"]
             self.stats["loop_passes"] += decode_steps * cfg.n_passes
             self.stats["layer_passes"] += layer_passes
             if self._moe:
@@ -2490,7 +2775,9 @@ class GenerationEngine:
                 # one tile a (cache layer, running slot, step), as dispatched: a
                 # slot that finishes inside the chunk writes none after
                 counts = dict(
-                    counts or {}, kv_write_tiles=layer_passes * len(running))
+                    counts or {},
+                    kv_write_tiles=(
+                        decode_steps * cfg.cache_layers * len(running)))
             if counts is not None:
                 chunk_attrs.update(counts)
                 for name, n in counts.items():

@@ -124,6 +124,10 @@ class _RadixNode:
     page: Any
     children: Dict[Tuple[int, ...], "_RadixNode"]
     last_used: int                              # LRU tick
+    # entry of the engine's snapshot pool that holds the per-slot state
+    # (``tfm.SSMState``) after exactly the tokens up to and including this
+    # node's page; None: no snapshot was taken here, or it was evicted
+    snap: Optional[int] = None
 
 
 def _ids(page) -> List[int]:
@@ -148,13 +152,30 @@ class PrefixRegistry:
     """
 
     def __init__(
-        self, pool: PagePool, windows: Sequence[Optional[int]] = (None,)
+        self, pool: PagePool, windows: Sequence[Optional[int]] = (None,),
+        n_snapshots: Optional[int] = None,
     ):
         """``windows``: the sliding window of each layer kind (``None``: a
         full-attention kind). More than one kind: a node's ``page`` (and
-        every entry of a hit or an insert) is a list, one page a kind."""
+        every entry of a hit or an insert) is a list, one page a kind.
+
+        ``n_snapshots`` (a model with per-slot recurrent state; None: no
+        such model): entries of the engine's snapshot pool. Pages alone
+        are then no hit: a borrower also needs the state after exactly
+        the shared tokens, which exists only where a snapshot was filed
+        (``alloc_snapshot`` + ``insert(snapshot=)``). ``lookup`` returns
+        the chain down to the deepest node that HOLDS one. A snapshot
+        goes with its node (``evict_lru``, ``clear``), or alone when the
+        pool of them is full (least recently used first; ``pinned``
+        entries, those an admission wave still reads or writes, stay)."""
         self.pool = pool
         self.windows = tuple(windows)
+        self.stateful = n_snapshots is not None
+        self._free_snaps: List[int] = list(range(n_snapshots or 0))[::-1]
+        self._snap_nodes: Dict[int, _RadixNode] = {}
+        self.pinned: set = set()
+        self.snapshot_evictions = 0
+        self.hit_snapshot: Optional[int] = None
         self._children: Dict[Tuple[int, ...], _RadixNode] = {}
         self._tick = 0
         self._n_nodes = 0
@@ -230,12 +251,17 @@ class PrefixRegistry:
     ) -> Optional[List[int]]:
         """Pages covering the LONGEST cached page-aligned prefix of the
         first ``n_full_pages`` pages (possibly fewer than requested), with a
-        reference taken for the caller — or None on a cold miss."""
+        reference taken for the caller — or None on a cold miss. A
+        stateful registry: the longest such prefix that ends in a node
+        with a snapshot, whose entry is then ``hit_snapshot`` (pinned
+        until the engine has copied it)."""
+        self.hit_snapshot = None
         if n_full_pages <= 0:
             return None
         self._tick += 1
         pages: List[int] = []
         children = self._children
+        n_state = 0
         for chunk in self._chunks(prompt_ids, n_full_pages):
             node = children.get(chunk)
             if node is None:
@@ -243,20 +269,55 @@ class PrefixRegistry:
             node.last_used = self._tick
             pages.append(node.page)
             children = node.children
+            if node.snap is not None:
+                n_state, self.hit_snapshot = len(pages), node.snap
+        if self.stateful:
+            pages = pages[:n_state]
         if len(self.windows) > 1:
             pages = pages[: self._usable(pages, len(pages))]
         if not pages:
             return None
+        if self.hit_snapshot is not None:
+            self.pinned.add(self.hit_snapshot)
         self.pool.ref([p for page in pages for p in _ids(page)])
         return pages
 
-    def insert(self, prompt_ids: Sequence[int], pages: List[int]):
+    def alloc_snapshot(self) -> Optional[int]:
+        """An entry of the snapshot pool for a node about to be inserted,
+        pinned; the least recently used node's if none is free, None if
+        every entry is pinned."""
+        if not self._free_snaps:
+            loose = [
+                (n.last_used, i) for i, n in self._snap_nodes.items()
+                if i not in self.pinned
+            ]
+            if not loose:
+                return None
+            self._drop_snapshot(self._snap_nodes[min(loose)[1]])
+            self.snapshot_evictions += 1
+        idx = self._free_snaps.pop()
+        self.pinned.add(idx)
+        return idx
+
+    def _drop_snapshot(self, node: _RadixNode):
+        if node.snap is not None:
+            del self._snap_nodes[node.snap]
+            self._free_snaps.append(node.snap)
+            node.snap = None
+
+    def insert(self, prompt_ids: Sequence[int], pages: List[int],
+               snapshot: Optional[int] = None):
         """Register a freshly covered page chain (shared prefix + newly
         prefilled pages). Existing nodes are kept — a racing identical
         prefill's duplicate page stays owned by its slot and is freed when
-        that slot finishes; new nodes take their own reference."""
+        that slot finishes; new nodes take their own reference.
+        ``snapshot``: the entry (``alloc_snapshot``) that holds, or will
+        hold before anyone is seeded from it, the state after the chain's
+        last page; filed at that node unless it has one already (the
+        entry is then free again)."""
         self._tick += 1
         children = self._children
+        node = None
         for chunk, page in zip(self._chunks(prompt_ids, len(pages)), pages):
             node = children.get(chunk)
             if node is None:
@@ -282,6 +343,13 @@ class PrefixRegistry:
                         node.page[j] = int(page[j])
                     self._hold_node(node, home)
             children = node.children
+        if snapshot is not None:
+            if node is None or node.snap is not None:
+                self.pinned.discard(snapshot)
+                self._free_snaps.append(snapshot)
+            else:
+                node.snap = snapshot
+                self._snap_nodes[snapshot] = node
 
     def n_reclaimable(self) -> int:
         """Pages held ONLY by the registry (refcount 1) — instantly
@@ -362,6 +430,9 @@ class PrefixRegistry:
             for p in _ids(n.page):
                 self._where.pop(p, None)
             self._drop(_ids(n.page))
+            if n.snap is not None:
+                self._drop_snapshot(n)
+                self.snapshot_evictions += 1
             del pc[k]
             self._n_nodes -= 1
             evicted += len(_ids(n.page))
@@ -378,8 +449,10 @@ class PrefixRegistry:
         while stack:
             n = stack.pop()
             self._drop(_ids(n.page))
+            self._drop_snapshot(n)
             stack.extend(n.children.values())
         self._children = {}
         self._n_nodes = 0
         self._where.clear()
         self._given_up.clear()
+        self.pinned.clear()

@@ -3,7 +3,7 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash, smallthinker, ouro) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -63,6 +63,44 @@ class MLAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """State-space layers (the Mamba-2 recurrence as ``granitemoehybrid``
+    lays it out; ``ops/ssm.py`` has the equations). ``n_heads`` heads of
+    ``head_dim``, each with a recurrent state of ``[head_dim, d_state]``;
+    ``n_groups`` groups of heads share one ``B`` and ``C`` of ``d_state``;
+    a causal depthwise convolution of width ``d_conv`` over ``conv_dim``
+    channels. ``chunk_size`` is the program's own (any chunking computes
+    the same function). ``state_dtype``: what the recurrent state is kept
+    and accumulated in; only float32 is supported (a 16-bit state is
+    rounded once a token for thousands of tokens while the trainer's
+    chunked scan accumulates in float32: another configuration)."""
+
+    n_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    chunk_size: int = 256
+    conv_bias: bool = True
+    proj_bias: bool = False
+    state_dtype: str = "float32"
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: ``[x ; B ; C]``."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_dim(self) -> int:
+        """Width of the input projection: ``[z ; xBC ; dt]``."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     n_layers: int
     n_q_heads: int
@@ -113,6 +151,18 @@ class ModelConfig:
     # the period in every period and a slot has one table a position
     # (``models/transformer.PagedKVCache``, ``gen/engine.py``).
     layer_pattern: Optional[Tuple[Tuple[Optional[int], bool], ...]] = None
+    # State-space layers beside attention layers (``granitemoehybrid``):
+    # ``mixer_pattern`` is a PERIOD of mixer kinds, "ssm" or "attn"; layer
+    # ``l`` is kind ``mixer_pattern[l % len]``. The kinds differ in weight
+    # SHAPE, so the weight tree holds a stack a kind (``params["layers"]``
+    # the attention layers in the order they run, ``params["ssm_layers"]``
+    # the state-space layers) and one scan over the periods cuts each
+    # position's weights from the stack of its kind
+    # (``models/transformer._scan_mixers``). Only attention layers hold
+    # K/V: ``cache_layers`` counts them alone. A state-space layer's
+    # context is a per-SLOT state (``models/transformer.SSMState``).
+    ssm: Optional[SSMConfig] = None
+    mixer_pattern: Optional[Tuple[str, ...]] = None
     attn_logits_soft_cap: Optional[float] = None
     softmax_scale: Optional[float] = None  # default head_dim ** -0.5
     # Latent attention in place of the q/k/v projections (None = those).
@@ -148,6 +198,13 @@ class ModelConfig:
     # Embeddings / head
     tied_embedding: bool = False
     normalize_embed: bool = False          # gemma: scale embeds by sqrt(hidden)
+    # ``granitemoehybrid``'s multipliers: the embedding times
+    # ``embedding_multiplier``, every branch times ``residual_multiplier``
+    # before it joins the residual, the logits DIVIDED by ``logits_scaling``
+    # (its ``attention_multiplier`` is ``softmax_scale``)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     final_logits_soft_cap: Optional[float] = None
     abs_position_embedding: bool = False   # gpt2 learned positions
 
@@ -244,8 +301,38 @@ class ModelConfig:
         """Layers of K/V (or latents) a cache holds of one token: a looped
         stack keeps them a PASS, ``n_passes`` behind every weight layer.
         What the pools' leading axes, the engine's bytes and tiles and
-        the attention FLOPs (``base/flops.py``) read."""
-        return self.n_passes * self.n_layers
+        the attention FLOPs (``base/flops.py``) read. State-space layers
+        hold no K/V and are not among them."""
+        return self.n_passes * self.n_attn_layers
+
+    @property
+    def kv_heads_per_row(self) -> int:
+        """kv heads laid side by side in ONE row of every cache (the page
+        pool, the dense cache; ``models/transformer._pack_qkv``): two where
+        state-space layers stand beside attention heads of at most half a
+        128-lane tile (the published 64: the paged kernels take a
+        full-lane head only), else one. Every older family keeps the
+        layout it had."""
+        paired = (
+            self.ssm is not None and self.head_dim * 2 <= 128
+            and self.n_kv_heads % 2 == 0
+        )
+        return 2 if paired else 1
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """The mixer kind of every layer, in the order the layers run."""
+        if self.mixer_pattern is None:
+            return ("attn",) * self.n_layers
+        return self.mixer_pattern * (self.n_layers // len(self.mixer_pattern))
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(m == "ssm" for m in self.mixers)
+
+    @property
+    def n_attn_layers(self) -> int:
+        return self.n_layers - self.n_ssm_layers
 
     @property
     def n_periods(self) -> int:
@@ -298,6 +385,45 @@ class ModelConfig:
             )
         if self.n_passes < 1:
             raise ValueError("n_passes: the stack runs at least once")
+        if (self.ssm is None) != (self.mixer_pattern is None):
+            raise ValueError("ssm and mixer_pattern come together")
+        if self.mixer_pattern is not None:
+            object.__setattr__(
+                self, "mixer_pattern", tuple(self.mixer_pattern))
+            pat, s = self.mixer_pattern, self.ssm
+            if (
+                not pat or self.n_layers % len(pat)
+                or any(m not in ("ssm", "attn") for m in pat)
+                or "attn" not in pat or "ssm" not in pat
+            ):
+                raise ValueError(
+                    "mixer_pattern: a period of 'ssm' and 'attn' (both "
+                    f"present) that divides n_layers, got {pat!r}"
+                )
+            if (
+                self.n_passes > 1 or self.exit_gate or self.mla is not None
+                or self.n_dense_layers or self.n_mtp_layers
+                or self.layer_pattern is not None
+                or self.abs_position_embedding or self.mlp_type == "moe"
+                or self.norm_branch_out or self.is_critic
+            ):
+                raise ValueError(
+                    "state-space layers: a dense model of one pass whose "
+                    "attention layers are alike; with a looped stack, latent "
+                    "attention, a router, layer kinds, learned positions, "
+                    "branch norms or a value head it is not supported"
+                )
+            if s.n_heads % s.n_groups:
+                raise ValueError(
+                    f"ssm: n_groups={s.n_groups} does not divide "
+                    f"n_heads={s.n_heads}"
+                )
+            if s.state_dtype != "float32":
+                raise ValueError(
+                    f"ssm: state_dtype {s.state_dtype!r}: the recurrent "
+                    "state is float32 (a 16-bit state is another "
+                    "configuration, not supported)"
+                )
         if (self.n_passes > 1 or self.exit_gate) and (
             self.mla is not None or self.n_dense_layers or self.n_mtp_layers
             or self.layer_pattern is not None

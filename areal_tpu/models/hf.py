@@ -2,7 +2,7 @@
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
-joyai_llm_flash, smallthinker and ouro are added here) consumed by
+joyai_llm_flash, smallthinker, ouro and granitemoehybrid are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -22,7 +22,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from areal_tpu.models.config import MLAConfig, ModelConfig, MoEConfig
+from areal_tpu.models.config import (
+    MLAConfig, ModelConfig, MoEConfig, SSMConfig,
+)
 
 HFState = Dict[str, np.ndarray]
 
@@ -626,6 +628,296 @@ register_hf_family(
         config_to_hf=_ouro_config_to_hf,
         params_from_hf=_ouro_params_from_hf,
         params_to_hf=_ouro_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# granitemoehybrid (state-space layers beside attention layers without
+# positions; four multipliers; a dense MLP where ``num_local_experts`` is 0)
+# --------------------------------------------------------------------------- #
+
+_GRANITE_MIXERS = {"mamba": "ssm", "attention": "attn"}
+
+
+def _granite_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read. What this family does not
+    compute is refused, never guessed: routed experts (``num_local_experts``
+    > 0), positions other than ``"nope"``, a non-null ``rope_scaling``, a
+    norm other than ``rmsnorm``, a ``layer_types`` entry other than
+    ``mamba`` / ``attention`` (or a list that is not whole periods with both
+    kinds), ``mamba_n_groups`` that does not divide the heads, and
+    ``mamba_expand x hidden_size`` other than ``mamba_n_heads x
+    mamba_d_head``. ``rope_theta`` shapes nothing without positions and is
+    written back as published (10000)."""
+    if int(hf.get("num_local_experts", 0) or 0) > 0:
+        raise ValueError(
+            "granitemoehybrid: routed experts (num_local_experts > 0) are "
+            "not supported"
+        )
+    if hf.get("position_embedding_type", "nope") != "nope":
+        raise ValueError(
+            "granitemoehybrid: position_embedding_type "
+            f"{hf.get('position_embedding_type')!r} is not supported "
+            "(only 'nope')"
+        )
+    if hf.get("rope_scaling") is not None:
+        raise ValueError("granitemoehybrid: rope_scaling is not supported")
+    if hf.get("normalization_function", "rmsnorm") != "rmsnorm":
+        raise ValueError(
+            "granitemoehybrid: normalization_function "
+            f"{hf.get('normalization_function')!r} is not supported"
+        )
+    L = hf["num_hidden_layers"]
+    layer_types = list(hf["layer_types"])[:L]
+    if len(layer_types) < L or any(
+        t not in _GRANITE_MIXERS for t in layer_types
+    ):
+        raise ValueError(
+            "granitemoehybrid: layer_types must name every layer 'mamba' or "
+            f"'attention', got {hf['layer_types']!r}"
+        )
+    mixers = [_GRANITE_MIXERS[t] for t in layer_types]
+    period = next(
+        p for p in range(1, L + 1)
+        if L % p == 0 and mixers == mixers[:p] * (L // p)
+    )
+    n_heads, d_head = hf["mamba_n_heads"], hf["mamba_d_head"]
+    if n_heads % hf.get("mamba_n_groups", 1):
+        raise ValueError(
+            f"granitemoehybrid: mamba_n_groups={hf['mamba_n_groups']} does "
+            f"not divide mamba_n_heads={n_heads}"
+        )
+    if hf.get("mamba_expand", 2) * hf["hidden_size"] != n_heads * d_head:
+        raise ValueError(
+            "granitemoehybrid: mamba_expand x hidden_size must equal "
+            "mamba_n_heads x mamba_d_head"
+        )
+    head_dim = hf["hidden_size"] // hf["num_attention_heads"]
+    n_kv = hf["num_key_value_heads"]
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=n_kv,
+        head_dim=head_dim,
+        hidden_dim=hf["hidden_size"],
+        intermediate_dim=hf["shared_intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 131072),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5),
+        use_attention_bias=bool(hf.get("attention_bias", False)),
+        softmax_scale=float(hf["attention_multiplier"]),
+        apply_rotary=False,
+        rotary_base=float(hf.get("rope_theta", 10000)),
+        activation_function=hf.get("hidden_act", "silu"),
+        tied_embedding=bool(hf.get("tie_word_embeddings", True)),
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+        ssm=SSMConfig(
+            n_heads=n_heads,
+            head_dim=d_head,
+            d_state=hf["mamba_d_state"],
+            n_groups=hf.get("mamba_n_groups", 1),
+            d_conv=hf.get("mamba_d_conv", 4),
+            chunk_size=hf.get("mamba_chunk_size", 256),
+            conv_bias=bool(hf.get("mamba_conv_bias", True)),
+            proj_bias=bool(hf.get("mamba_proj_bias", False)),
+        ),
+        mixer_pattern=tuple(mixers[:period]),
+    )
+
+
+def _granite_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys, key for key."""
+    s = cfg.ssm
+    back = {v: k for k, v in _GRANITE_MIXERS.items()}
+
+    def whole(x: float):
+        return int(x) if float(x).is_integer() else x
+
+    return {
+        "model_type": "granitemoehybrid",
+        "architectures": ["GraniteMoeHybridForCausalLM"],
+        "attention_bias": cfg.use_attention_bias,
+        "attention_multiplier": cfg.softmax_scale,
+        "embedding_multiplier": whole(cfg.embedding_multiplier),
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "layer_types": [back[m] for m in cfg.mixers],
+        "logits_scaling": whole(cfg.logits_scaling),
+        "mamba_chunk_size": s.chunk_size,
+        "mamba_conv_bias": s.conv_bias,
+        "mamba_d_conv": s.d_conv,
+        "mamba_d_head": s.head_dim,
+        "mamba_d_state": s.d_state,
+        "mamba_expand": s.d_inner // cfg.hidden_dim,
+        "mamba_n_groups": s.n_groups,
+        "mamba_n_heads": s.n_heads,
+        "mamba_proj_bias": s.proj_bias,
+        "max_position_embeddings": cfg.n_positions,
+        "normalization_function": "rmsnorm",
+        "num_attention_heads": cfg.n_q_heads,
+        "num_experts_per_tok": 0,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "num_local_experts": 0,
+        "position_embedding_type": "nope",
+        "residual_multiplier": cfg.residual_multiplier,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_scaling": None,
+        "rope_theta": whole(cfg.rotary_base),
+        "shared_intermediate_size": cfg.intermediate_dim,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+# (ours, the published name under ``model.layers.{i}.mamba.``, transposed)
+_GRANITE_MIXER = (
+    ("b_in", "in_proj.bias", False),
+    ("conv_b", "conv1d.bias", False),
+    ("dt_bias", "dt_bias", False),
+    ("A_log", "A_log", False),
+    ("D", "D", False),
+    ("gate_norm", "norm.weight", False),
+    ("w_out", "out_proj.weight", True),
+    ("b_out", "out_proj.bias", False),
+)
+
+
+def _granite_layer_ids(cfg: ModelConfig) -> Dict[str, List[int]]:
+    return {
+        kind: [i for i, m in enumerate(cfg.mixers) if m == kind]
+        for kind in ("attn", "ssm")
+    }
+
+
+def _granite_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    """A stack a kind of layer, each in the order its layers run. The
+    published MLP holds gate and up in ONE matrix (``shared_mlp.
+    input_linear``, gate first); the convolution's weight is ``[channels,
+    1, taps]``, ours ``[taps, channels]``."""
+    F = cfg.intermediate_dim
+    ids = _granite_layer_ids(cfg)
+
+    def get(i, name, transpose=False):
+        m = np.asarray(sd[f"model.layers.{i}.{name}"])
+        return m.T if transpose else m
+
+    def stack(kind, name, transpose=False, fn=None):
+        return np.stack([
+            (fn or (lambda m: m))(get(i, name, transpose)) for i in ids[kind]
+        ])
+
+    def common(kind):
+        return {
+            "ln1": {"weight": stack(kind, "input_layernorm.weight")},
+            "ln2": {"weight": stack(kind, "post_attention_layernorm.weight")},
+            "mlp": {
+                "w_gate": stack(kind, "shared_mlp.input_linear.weight", True,
+                                lambda m: m[:, :F]),
+                "w_up": stack(kind, "shared_mlp.input_linear.weight", True,
+                              lambda m: m[:, F:]),
+                "w_down": stack(kind, "shared_mlp.output_linear.weight", True),
+            },
+        }
+
+    attn = {
+        ours: stack("attn", f"self_attn.{theirs}_proj.weight", True)
+        for ours, theirs in (("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o"))
+    }
+    if cfg.use_attention_bias:
+        for ours, theirs in (("bq", "q"), ("bk", "k"), ("bv", "v")):
+            attn[ours] = stack("attn", f"self_attn.{theirs}_proj.bias")
+    optional = {
+        "b_in": cfg.ssm.proj_bias, "b_out": cfg.ssm.proj_bias,
+        "conv_b": cfg.ssm.conv_bias,
+    }
+    mixer = {
+        ours: stack("ssm", "mamba." + theirs, t)
+        for ours, theirs, t in _GRANITE_MIXER
+        if optional.get(ours, True)
+    }
+    mixer["conv_w"] = stack(
+        "ssm", "mamba.conv1d.weight", fn=lambda m: m[:, 0, :].T)
+    # the published in_proj is [z ; xBC ; dt] in one matrix; ours keeps
+    # the three apart (``ops/ssm.py:_split_in``)
+    s_ = cfg.ssm
+    for name, lo, hi in (
+        ("w_z", 0, s_.d_inner),
+        ("w_xbc", s_.d_inner, s_.d_inner + s_.conv_dim),
+        ("w_dt", s_.d_inner + s_.conv_dim, s_.in_dim),
+    ):
+        mixer[name] = stack(
+            "ssm", "mamba.in_proj.weight", True,
+            lambda m, lo=lo, hi=hi: m[:, lo:hi])
+    params: Dict[str, Any] = {
+        "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
+        "layers": {**common("attn"), "attn": attn},
+        "ssm_layers": {**common("ssm"), "ssm": mixer},
+        "final_ln": {"weight": np.asarray(sd["model.norm.weight"])},
+    }
+    if not cfg.tied_embedding:
+        params["head"] = {"weight": np.asarray(sd["lm_head.weight"]).T}
+    return params
+
+
+def _granite_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    sd: HFState = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
+        "model.norm.weight": np.asarray(params["final_ln"]["weight"]),
+    }
+    if not cfg.tied_embedding:
+        sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
+    ids = _granite_layer_ids(cfg)
+    for kind, tree in (("attn", "layers"), ("ssm", "ssm_layers")):
+        lp = params[tree]
+        for at, i in enumerate(ids[kind]):
+            p = f"model.layers.{i}."
+            sd[p + "input_layernorm.weight"] = np.asarray(
+                lp["ln1"]["weight"][at])
+            sd[p + "post_attention_layernorm.weight"] = np.asarray(
+                lp["ln2"]["weight"][at])
+            m = lp["mlp"]
+            sd[p + "shared_mlp.input_linear.weight"] = np.concatenate(
+                [np.asarray(m["w_gate"][at]).T, np.asarray(m["w_up"][at]).T])
+            sd[p + "shared_mlp.output_linear.weight"] = np.asarray(
+                m["w_down"][at]).T
+            if kind == "attn":
+                a = lp["attn"]
+                for ours, theirs in (
+                    ("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o")
+                ):
+                    sd[p + f"self_attn.{theirs}_proj.weight"] = np.asarray(
+                        a[ours][at]).T
+                if cfg.use_attention_bias:
+                    for ours, theirs in (("bq", "q"), ("bk", "k"), ("bv", "v")):
+                        sd[p + f"self_attn.{theirs}_proj.bias"] = np.asarray(
+                            a[ours][at])
+                continue
+            x = lp["ssm"]
+            for ours, theirs, t in _GRANITE_MIXER:
+                if ours in x:
+                    w = np.asarray(x[ours][at])
+                    sd[p + "mamba." + theirs] = w.T if t else w
+            sd[p + "mamba.in_proj.weight"] = np.concatenate([
+                np.asarray(x[name][at]).T for name in ("w_z", "w_xbc", "w_dt")
+            ])
+            sd[p + "mamba.conv1d.weight"] = np.ascontiguousarray(
+                np.asarray(x["conv_w"][at]).T[:, None, :])
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="granitemoehybrid",
+        hf_model_type="granitemoehybrid",
+        config_from_hf=_granite_config_from_hf,
+        config_to_hf=_granite_config_to_hf,
+        params_from_hf=_granite_params_from_hf,
+        params_to_hf=_granite_params_to_hf,
     )
 )
 

@@ -24,6 +24,7 @@ bf16.
 
 import dataclasses
 import functools
+import itertools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -32,6 +33,7 @@ import jax.numpy as jnp
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import attention as attn_ops
 from areal_tpu.ops import norms
+from areal_tpu.ops import ssm as ssm_ops
 from areal_tpu.ops.activations import ACT2FN
 from areal_tpu.ops.rotary import RotaryConfig, apply_rotary, rotary_cos_sin
 
@@ -56,7 +58,7 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         cfg.n_kv_heads,
         cfg.intermediate_dim,
         cfg.vocab_size,
-        cfg.n_layers,
+        cfg.n_attn_layers,
     )
     std = 0.02
     rngs = iter(_split(rng, 64))
@@ -66,10 +68,10 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
 
     ln_gain = jnp.zeros if cfg.layer_norm_type == "gemma" else jnp.ones
 
-    def ln(extra_bias: bool):
-        p = {"weight": ln_gain((L, E), dtype)}
+    def ln(extra_bias: bool, n: int = L):
+        p = {"weight": ln_gain((n, E), dtype)}
         if extra_bias:
-            p["bias"] = jnp.zeros((L, E), dtype)
+            p["bias"] = jnp.zeros((n, E), dtype)
         return p
 
     has_ln_bias = cfg.layer_norm_type == "layer"
@@ -142,6 +144,44 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         # carried, never read by a forward (``ModelConfig.exit_gate``)
         params["exit_gate"] = {
             "weight": w((E, 1)), "bias": jnp.zeros((1,), dtype)}
+    if cfg.ssm is not None:
+        # the state-space layers, a stack of their own (another SHAPE than
+        # an attention layer); ``A``, ``dt`` and ``D`` start in the
+        # published initialisation's ranges, not at normal(0.02), which
+        # would make every head forget in two tokens
+        s, Ls = cfg.ssm, cfg.n_ssm_layers
+        u = jax.random.uniform
+        dt0 = jnp.exp(u(next(rngs), (Ls, s.n_heads), jnp.float32,
+                        jnp.log(1e-3), jnp.log(1e-1)))
+        mixer: Dict[str, Any] = {
+            # the input projection's three parts (``ops/ssm.py:_split_in``)
+            "w_z": w((Ls, E, s.d_inner)),
+            "w_xbc": w((Ls, E, s.conv_dim)),
+            "w_dt": w((Ls, E, s.n_heads)),
+            "conv_w": w((Ls, s.d_conv, s.conv_dim)),
+            "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+            "A_log": jnp.log(
+                u(next(rngs), (Ls, s.n_heads), jnp.float32, 1.0, 16.0)
+            ).astype(dtype),
+            "D": jnp.ones((Ls, s.n_heads), dtype),
+            "gate_norm": jnp.ones((Ls, s.d_inner), dtype),
+            "w_out": w((Ls, s.d_inner, E)),
+        }
+        if s.conv_bias:
+            mixer["conv_b"] = jnp.zeros((Ls, s.conv_dim), dtype)
+        if s.proj_bias:
+            mixer["b_in"] = jnp.zeros((Ls, s.in_dim), dtype)
+            mixer["b_out"] = jnp.zeros((Ls, E), dtype)
+        params["ssm_layers"] = {
+            "ln1": ln(has_ln_bias, Ls),
+            "ssm": mixer,
+            "ln2": ln(has_ln_bias, Ls),
+            "mlp": {
+                "w_gate": w((Ls, E, F)),
+                "w_up": w((Ls, E, F)),
+                "w_down": w((Ls, F, E)),
+            },
+        }
     if cfg.abs_position_embedding:
         params["pos_embed"] = {"weight": w((cfg.n_positions, E))}
     if cfg.is_critic:
@@ -321,6 +361,34 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         axes["layers"]["mlp_out_ln"] = ln()
     if cfg.exit_gate:
         axes["exit_gate"] = {"weight": ("embed", None), "bias": (None,)}
+    if cfg.ssm is not None:
+        # no tensor-parallel split of the mixer (its heads, the
+        # convolution's channels and the state would all have to follow
+        # one; the engine refuses a mesh for this family)
+        mixer = {
+            "w_z": ("layer", "embed", None),
+            "w_xbc": ("layer", "embed", None),
+            "w_dt": ("layer", "embed", None),
+            "conv_w": ("layer", None, None),
+            "dt_bias": ("layer", None),
+            "A_log": ("layer", None),
+            "D": ("layer", None),
+            "gate_norm": ("layer", None),
+            "w_out": ("layer", None, "embed"),
+        }
+        if cfg.ssm.conv_bias:
+            mixer["conv_b"] = ("layer", None)
+        if cfg.ssm.proj_bias:
+            mixer["b_in"] = ("layer", None)
+            mixer["b_out"] = ("layer", "embed")
+        axes["ssm_layers"] = {
+            "ln1": ln(), "ssm": mixer, "ln2": ln(),
+            "mlp": {
+                "w_gate": ("layer", "embed", "mlp"),
+                "w_up": ("layer", "embed", "mlp"),
+                "w_down": ("layer", "mlp", "embed"),
+            },
+        }
     if cfg.abs_position_embedding:
         axes["pos_embed"] = {"weight": (None, "embed")}
     if cfg.is_critic:
@@ -591,6 +659,8 @@ def _add_branch(cfg: ModelConfig, lp, name: str, x, branch):
     one (``cfg.norm_branch_out``)."""
     if cfg.norm_branch_out:
         branch = _norm(cfg, lp[name], branch)
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
     return x + branch
 
 
@@ -750,6 +820,134 @@ def _scan_passes(cfg: ModelConfig, layer, carry, params: Params, xs=(),
     )
 
 
+def _scan_mixers(cfg: ModelConfig, attn_layer, ssm_layer, carry,
+                 params: Params, attn_xs=(), ssm_xs=(), unroll=1):
+    """:func:`_scan_periods` of a model whose layers differ in weight
+    SHAPE (``cfg.mixer_pattern``: state-space and attention layers): a
+    stack a kind, ``params["layers"]`` the attention layers and
+    ``params["ssm_layers"]`` the state-space layers, each in the order its
+    layers run. ONE scan runs over the periods; its body runs the
+    period's positions in order and cuts position ``j``'s weights (and
+    its slice of ``attn_xs`` / ``ssm_xs``, arrays over the layers of that
+    kind) from the stack of its kind by its index IN that stack, one
+    layer at a time, as a plain scan over one stack does (no copy of a
+    period's weights: :func:`_scan_periods` says what that cost). The
+    period is taken as its RUNS of one kind (five state-space layers, the
+    attention layer, four more), each a scan unrolled in full: the layer
+    is traced once a run, not once a position (three traces for ten: 49 s
+    of 67 s of tracing and lowering at the start of a 40-layer model's
+    engine; PERF.md section 6, PR 41), and the compiler still gets the
+    period as straight-line code.
+    ``attn_layer`` is one function or a list of one. Returns ``(carry,
+    (ys_attn, ys_ssm))``, each stacked over the layers of its kind."""
+    if isinstance(attn_layer, (list, tuple)):
+        (attn_layer,) = attn_layer
+    pat = cfg.mixer_pattern
+    per = {"attn": pat.count("attn"), "ssm": pat.count("ssm")}
+    fns = {"attn": attn_layer, "ssm": ssm_layer}
+    stacks = {
+        "attn": (params["layers"], *attn_xs) if attn_xs else params["layers"],
+        "ssm": (
+            (params["ssm_layers"], *ssm_xs) if ssm_xs
+            else params["ssm_layers"]),
+    }
+
+    # the period as runs of one kind: ("ssm", 5), ("attn", 1), ("ssm", 4)
+    runs = [(k, len(list(g))) for k, g in itertools.groupby(pat)]
+
+    def body(carry, period):
+        ys = {"attn": [], "ssm": []}
+        done = {"attn": 0, "ssm": 0}
+        for kind, n in runs:
+            first = period * per[kind] + done[kind]
+            done[kind] += n
+
+            def layer(carry, k, kind=kind, first=first):
+                inp = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(
+                        a, first + k, 0, keepdims=False),
+                    stacks[kind],
+                )
+                return fns[kind](carry, inp)
+
+            carry, y = jax.lax.scan(
+                layer, carry, jnp.arange(n, dtype=jnp.int32), unroll=n)
+            ys[kind].append(y)
+        return carry, tuple(
+            jax.tree.map(lambda *a: jnp.concatenate(a), *ys[kind])
+            for kind in ("attn", "ssm"))
+
+    carry, ys = jax.lax.scan(
+        body, carry,
+        jnp.arange(cfg.n_layers // len(pat), dtype=jnp.int32), unroll=unroll,
+    )
+    return carry, jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys
+    )
+
+
+def _run_stack(cfg: ModelConfig, layer, carry, params: Params, xs=(),
+               unroll=1, ssm_layer=None, ssm_xs=()):
+    """What every forward runs its layers through: :func:`_scan_passes`,
+    or :func:`_scan_mixers` for a model with state-space layers. Returns
+    ``(carry, ys, ys_ssm)``: the attention layers' stacked results and the
+    state-space layers' (None for a model without them)."""
+    if cfg.ssm is None:
+        carry, ys = _scan_passes(cfg, layer, carry, params, xs, unroll)
+        return carry, ys, None
+    carry, (ys, ys_ssm) = _scan_mixers(
+        cfg, layer, ssm_layer, carry, params, xs, ssm_xs, unroll)
+    return carry, ys, ys_ssm
+
+
+def _ssm_block(cfg: ModelConfig, lp, x, mixer):
+    """One state-space layer: ``x + mixer(norm(x))``, then the MLP as in
+    an attention layer. ``mixer(p, h)`` returns ``(out, state)``."""
+    h = _norm(cfg, lp["ln1"], x)
+    out, st = mixer(lp["ssm"], h)
+    x = _add_branch(cfg, lp, "attn_out_ln", x, out.astype(x.dtype))
+    x = _add_branch(
+        cfg, lp, "mlp_out_ln", x,
+        _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
+    return x, st
+
+
+# ``cfg.kv_heads_per_row`` kv heads side by side in ONE row of a cache: the
+# cache, the paged kernel and the write kernel then see ``Hkv / r`` heads of
+# ``r * D`` (a head of 64 fills half a 128-lane tile; two fill it, and the
+# kernels take a full-lane head only). A query head carries its values in
+# the part of the row that is its own kv head's and zeros in the rest, so
+# its scores are the published ones; of the context it reads that part.
+
+
+def _row_part(cfg: ModelConfig, dtype):
+    """``[Hq, r]`` one-hot: which part of its kv row a query head reads."""
+    part = (jnp.arange(cfg.n_q_heads) // cfg.n_rep) % cfg.kv_heads_per_row
+    return jax.nn.one_hot(part, cfg.kv_heads_per_row, dtype=dtype)
+
+
+def _pack_qkv(cfg: ModelConfig, q, k, v):
+    r = cfg.kv_heads_per_row
+    if r == 1:
+        return q, k, v
+    sel = _row_part(cfg, q.dtype)
+    q = (q[..., None, :] * sel[:, :, None]).reshape(*q.shape[:-1], -1)
+
+    def rows(a):
+        return a.reshape(*a.shape[:-2], a.shape[-2] // r, -1)
+
+    return q, rows(k), rows(v)
+
+
+def _unpack_ctx(cfg: ModelConfig, ctx):
+    r = cfg.kv_heads_per_row
+    if r == 1:
+        return ctx
+    sel = _row_part(cfg, ctx.dtype)
+    ctx = ctx.reshape(*ctx.shape[:-1], r, -1)
+    return (ctx * sel[:, :, None]).sum(axis=-2)
+
+
 # --------------------------------------------------------------------------- #
 # Packed forward (training / logprob inference)
 # --------------------------------------------------------------------------- #
@@ -759,6 +957,8 @@ def _embed(cfg: ModelConfig, params: Params, input_ids, positions):
     x = _cast(cfg, params["embed"]["weight"])[input_ids]
     if cfg.normalize_embed:
         x = x * jnp.asarray(cfg.hidden_dim**0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if cfg.abs_position_embedding:
         x = x + _cast(cfg, params["pos_embed"]["weight"])[positions]
     return x
@@ -782,6 +982,8 @@ def _head(cfg: ModelConfig, params: Params, x):
     if cfg.is_critic:
         return (x @ _cast(cfg, params["head"]["weight"])).astype(jnp.float32)
     logits = (x @ head_weight(cfg, params)).astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.final_logits_soft_cap is not None:
         c = cfg.final_logits_soft_cap
         logits = c * jnp.tanh(logits / c)
@@ -909,10 +1111,22 @@ def forward_packed(
             return jax.checkpoint(layer, policy=dots, prevent_cse=False)
         return layer
 
+    def ssm_layer(x, lp):
+        # one row that holds every document: a token at position 0 of its
+        # own resets the state and the convolution
+        lp = _cast(cfg, lp)
+        x, _ = _ssm_block(
+            cfg, lp, x[None],
+            lambda p, h: ssm_ops.mixer_chunk(cfg, p, h, positions[None]))
+        return x[0], None
+
+    if policy != "none":
+        ssm_layer = jax.checkpoint(ssm_layer, prevent_cse=False)
     layers = [make_layer(kind) for kind in cfg.layer_kinds]
     layer = layers[-1]      # the block a multi-token-prediction module is
-    x, (auxes, routing) = _scan_passes(
-        cfg, layers, x, params, unroll=cfg.layer_scan_unroll or 1
+    x, (auxes, routing), _ = _run_stack(
+        cfg, layers, x, params, unroll=cfg.layer_scan_unroll or 1,
+        ssm_layer=ssm_layer,
     )
     stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
@@ -1019,6 +1233,8 @@ class KVCache:
     k: jnp.ndarray
     v: jnp.ndarray
     lens: jnp.ndarray
+    # the state-space layers' state of every row (``cfg.ssm``; else None)
+    ssm: Optional["SSMState"] = None
 
     @classmethod
     def empty(cls, cfg: ModelConfig, batch: int, capacity: int) -> "KVCache":
@@ -1029,7 +1245,34 @@ class KVCache:
             k=jnp.zeros(shape, dt),
             v=jnp.zeros(shape, dt),
             lens=jnp.zeros((batch,), jnp.int32),
+            ssm=SSMState.empty(cfg, batch) if cfg.ssm is not None else None,
         )
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class SSMState:
+    """What the state-space layers keep of a ROW (a slot of the generation
+    engine, a row of the dense cache) in place of keys and values: ``ssm
+    [Ls, B, H, P, N]`` the recurrent state of every head of every
+    state-space layer, float32 (``cfg.ssm.state_dtype``), and ``conv [Ls,
+    B, (d_conv - 1) x C]`` the convolution's last inputs in the serving
+    dtype, flat (``ops/ssm.py:state_shapes`` says why). It has one size however long the row's context is, so it is
+    allocated by row and not by page: a prefix cannot be shared by
+    pointing at it, only by copying a snapshot of it
+    (``gen/engine.py``)."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+
+    @classmethod
+    def empty(cls, cfg: ModelConfig, batch: int) -> "SSMState":
+        ssm, conv = ssm_ops.state_shapes(cfg, batch)
+        return cls(
+            ssm=jnp.zeros(ssm, jnp.dtype(cfg.ssm.state_dtype)),
+            conv=jnp.zeros(conv, jnp.dtype(cfg.dtype)),
+        )
+
 
 
 def prefill(
@@ -1112,8 +1355,15 @@ def prefill(
             _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
         return x, (k, v)
 
-    x, (ks, vs) = _scan_passes(
-        cfg, [make_layer(kind) for kind in cfg.layer_kinds], x, params
+    def ssm_layer(x, lp):
+        return _ssm_block(
+            cfg, _cast(cfg, lp), x,
+            lambda p, h: ssm_ops.mixer_chunk(
+                cfg, p, h, positions, n_valid=prompt_lens))
+
+    x, (ks, vs), ssm = _run_stack(
+        cfg, [make_layer(kind) for kind in cfg.layer_kinds], x, params,
+        ssm_layer=ssm_layer,
     )
     cap = cache.k.shape[2]
     pad = cap - S
@@ -1126,6 +1376,7 @@ def prefill(
         k=jnp.where(keep, ks.astype(cache.k.dtype), cache.k),
         v=jnp.where(keep, vs.astype(cache.v.dtype), cache.v),
         lens=prompt_lens.astype(jnp.int32),
+        ssm=None if ssm is None else SSMState(*ssm),
     )
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     last = jnp.take_along_axis(
@@ -1181,11 +1432,20 @@ def decode_step(
             _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
         return x, (kc, vc)
 
-    x, (ks, vs) = _scan_passes(
+    def ssm_layer(x, inputs):
+        lp, s, cv = inputs
+        return _ssm_block(
+            cfg, _cast(cfg, lp), x,
+            lambda p, h: ssm_ops.mixer_step(cfg, p, h, (s, cv), active))
+
+    x, (ks, vs), ssm = _run_stack(
         cfg, [functools.partial(layer, kind) for kind in cfg.layer_kinds],
-        x, params, xs=(cache.k, cache.v),
+        x, params, xs=(cache.k, cache.v), ssm_layer=ssm_layer,
+        ssm_xs=() if cache.ssm is None else (cache.ssm.ssm, cache.ssm.conv),
     )
-    cache = KVCache(k=ks, v=vs, lens=new_lens)
+    cache = KVCache(
+        k=ks, v=vs, lens=new_lens,
+        ssm=None if ssm is None else SSMState(*ssm))
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     return _head(cfg, params, x), cache
 
@@ -1305,7 +1565,8 @@ def kv_page_geometry(cfg: ModelConfig) -> Tuple[int, int, int]:
     D``."""
     if cfg.mla is not None:
         return 1, 1, latent_pool_width(cfg)
-    return 2, cfg.n_kv_heads, cfg.head_dim
+    r = cfg.kv_heads_per_row
+    return 2, cfg.n_kv_heads // r, cfg.head_dim * r
 
 
 def _write_chunk_kv(
@@ -1454,15 +1715,30 @@ def _extend_layers(
     skip_pool: bool = False,
     verify: bool = False,
     moe_grouped: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
+    ssm: Optional[SSMState] = None,
+    slots: Optional[jnp.ndarray] = None,
+):
     """Shared multi-token layer scan over the page pool (chunked prefill
     AND the spec-decode verify pass — one implementation, two attention
     entry points). Returns ``(x [B, C, E] pre-final-norm hidden, ks,
-    vs)``; the caller writes the KV and (for verify) applies the head.
-    ``moe_grouped`` (STATIC): the routed experts run as the grouped-matmul
-    kernel over the whole stack (``ops/moe.py``; the caller asks
-    ``moe_grouped_applies``)."""
+    vs, ssm_rows)``; the caller writes the KV and (for verify) applies the
+    head. ``moe_grouped`` (STATIC): the routed experts run as the
+    grouped-matmul kernel over the whole stack (``ops/moe.py``; the caller
+    asks ``moe_grouped_applies``).
+
+    State-space layers (``cfg.ssm``): row ``b`` continues the state of
+    slot ``slots[b]`` of ``ssm`` (the engine's per-slot state, read and
+    not written here) over its ``n_new[b]`` tokens; a token at position 0
+    starts from nothing whatever the slot held. ``ssm_rows``: ``(ssm [Ls,
+    B, H, P, N], conv [Ls, B, K - 1, C])`` after them, for the caller to
+    put back; None for a model without such layers."""
     from areal_tpu.ops import paged_attention as paged_ops
+
+    if cfg.ssm is not None and verify:
+        raise NotImplementedError(
+            "state-space layers: a verify pass would have to roll the "
+            "recurrent state back past a rejected draft"
+        )
 
     routed = None
     if moe_grouped:
@@ -1491,10 +1767,20 @@ def _extend_layers(
             skip_pool=skip_pool, **kw,
         )
 
+    def ssm_layer(carry, lp):
+        x, li, si = carry
+        x, st = _ssm_block(
+            cfg, _cast(cfg, lp), x,
+            lambda p, h: ssm_ops.mixer_chunk(
+                cfg, p, h, positions, (ssm.ssm[si, slots], ssm.conv[si, slots]),
+                n_valid=n_new))
+        return (x, li, si + 1), st
+
     def layer(j, carry, lp):
         # ``li``: which slice of the pool's leading axis the layer's pages
-        # are in: its layer, or (layer kinds) its period
-        x, li = carry                                 # pool NOT in the scan
+        # are in: its layer, or (layer kinds) its period (``rest``: the
+        # running index of the state-space layers, which have their own)
+        x, li, *rest = carry                          # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         if cfg.mla is not None:
@@ -1507,8 +1793,9 @@ def _extend_layers(
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
         else:
             # [B, C, H(kv), D]
-            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, kinds[j][1])
-            ctx = _attend(q, k, v, li, j)
+            q, k, v = _pack_qkv(cfg, *_qkv_roped(
+                cfg, lp["attn"], h, cos, sin, kinds[j][1]))
+            ctx = _unpack_ctx(cfg, _attend(q, k, v, li, j))
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
@@ -1516,13 +1803,15 @@ def _extend_layers(
             cfg, lp, "mlp_out_ln", x,
             _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
                  _routed_at(cfg, routed, li, j))[0])
-        return (x, li + int(j == len(kinds) - 1)), (k, v)
+        return (x, li + int(j == len(kinds) - 1), *rest), (k, v)
 
-    (x, _), (ks, vs) = _scan_passes(
+    zero = jnp.int32(0)
+    (x, *_), (ks, vs), ssm_rows = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, jnp.int32(0)), params,
+        (x, zero) if cfg.ssm is None else (x, zero, zero), params,
+        ssm_layer=ssm_layer,
     )
-    return x, ks, vs
+    return x, ks, vs, ssm_rows
 
 
 def _kind_table(table, j: int):
@@ -1541,6 +1830,8 @@ def extend_paged_kv(
     n_new: jnp.ndarray,      # [B] valid tokens in this chunk (<= C)
     skip_pool: bool = False,
     moe_grouped: bool = False,
+    ssm: Optional[SSMState] = None,
+    slots: Optional[jnp.ndarray] = None,
 ):
     """Chunked prefill, the computing half: attend the chunk causally over
     everything resident (pool part + intra-chunk part, merged inside the
@@ -1551,11 +1842,16 @@ def extend_paged_kv(
     computed: admission feeds the last prompt token to the first decode
     step instead. ``skip_pool`` (STATIC): every row starts at position 0,
     so the pool scan is dead weight (see ``paged_extend_attention``).
-    ``moe_grouped`` (STATIC): see :func:`_extend_layers`."""
-    _, ks, vs = _extend_layers(
+    ``moe_grouped`` (STATIC): see :func:`_extend_layers`. ``ssm``,
+    ``slots`` (a model with state-space layers): the per-slot state and
+    each row's slot; the result is then ``(ks, vs, ssm_rows)``, the rows'
+    state after the chunk (:func:`_extend_layers`)."""
+    _, ks, vs, ssm_rows = _extend_layers(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
-        moe_grouped=moe_grouped,
+        moe_grouped=moe_grouped, ssm=ssm, slots=slots,
     )
+    if cfg.ssm is not None:
+        return ks, vs, ssm_rows
     return ks, vs
 
 
@@ -1570,17 +1866,33 @@ def extend_paged(
     skip_pool: bool = False,
     use_pallas: Optional[bool] = None,
     mesh=None,
-) -> PagedKVCache:
+    ssm: Optional[SSMState] = None,
+    slots: Optional[jnp.ndarray] = None,
+):
     """Both halves of chunked prefill in one call: :func:`extend_paged_kv`,
     then the chunk's KV into the pages (:func:`_write_chunk_kv`, whose
     path ``use_pallas`` / ``mesh`` choose; the chunk's attention is
-    XLA's)."""
-    ks, vs = extend_paged_kv(
-        params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool
+    XLA's). With state-space layers: ``(cache, ssm)``, the rows' state
+    put back at ``slots``."""
+    ks, vs, *rows = extend_paged_kv(
+        params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
+        ssm=ssm, slots=slots,
     )
-    return _write_chunk_kv(
+    cache = _write_chunk_kv(
         cache, ks, vs, table, start, n_new, use_pallas, mesh
     )
+    if cfg.ssm is None:
+        return cache
+    return cache, put_ssm_rows(ssm, slots, *rows)
+
+
+def put_ssm_rows(ssm: SSMState, slots, rows) -> SSMState:
+    """``rows`` (``(ssm, conv)`` over ``[Ls, n, ...]``) into the per-slot
+    state at ``slots [n]``; a slot index past the last is dropped (a
+    padding row)."""
+    return SSMState(*(
+        a.at[:, slots].set(v.astype(a.dtype), mode="drop")
+        for a, v in zip((ssm.ssm, ssm.conv), rows)))
 
 
 def verify_step_paged(
@@ -1623,7 +1935,7 @@ def verify_step_paged(
     the page table and alias page 0). ``use_pallas`` / ``mesh`` choose the
     KV write's path only (:func:`_write_chunk_kv`); ``moe_grouped``
     (STATIC): see :func:`_extend_layers`."""
-    x, ks, vs = _extend_layers(
+    x, ks, vs, _ = _extend_layers(
         params, cfg, cache, tokens, table, lens, n_new, verify=True,
         moe_grouped=moe_grouped,
     )
@@ -1658,6 +1970,8 @@ def decode_step_paged(
     return_hidden: bool = False,
     with_routing: bool = False,
     moe_grouped: bool = False,
+    ssm: Optional[SSMState] = None,
+    ssm_update=None,
 ) -> Tuple[Optional[jnp.ndarray], PagedKVCache, jnp.ndarray]:
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
     cache, new lens — incremented where active). The pool is read-only in
@@ -1700,7 +2014,18 @@ def decode_step_paged(
 
     ``moe_grouped`` (STATIC): the routed experts run as the grouped-matmul
     kernel over the whole stack (``ops/moe.py``; the engine asks
-    ``moe_grouped_applies`` with the rows of this step)."""
+    ``moe_grouped_applies`` with the rows of this step).
+
+    ``ssm`` (a model with state-space layers): the per-slot state, row
+    ``b`` slot ``b``'s. It rides the layer scan's CARRY and every
+    state-space layer reads and writes its own slice of it in place (a
+    donated argument stays one buffer); rows that are not ``active`` keep
+    theirs. The step's rows stay in slot order for such a model (the state
+    is by slot; its attention layers are a twentieth of the step). The
+    new state is appended to the result. ``ssm_update``: what stands in
+    for ``ops/ssm.py:step_update`` on the state of ALL layers
+    (``update(ssm_all, layer, x, dt, a, b, c, d, active=) -> (y,
+    ssm_all)``: the ``ssm_decode`` kernel)."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     routed = None
@@ -1708,17 +2033,46 @@ def decode_step_paged(
         params, routed = _hold_routed(params)
     new_lens = jnp.where(active, lens + 1, lens)
     # the scan's rows, by length (``_o``); slot order again after it
-    order, inverse = _length_order(lens)
+    if cfg.ssm is None:
+        order, inverse = _length_order(lens)
+    else:
+        order = inverse = jnp.arange(lens.shape[0])
     table_o = table[order] if table.ndim == 2 else table[:, order]
     lens_o = lens[order]
     x = _embed(cfg, params, tokens[order], lens_o)    # [B, E]
     cos, sin = _cos_sin(cfg, lens_o)
     kinds = cfg.layer_kinds
 
+    def ssm_layer(carry, lp):
+        x, li, si, st = carry
+        conv_l = jax.lax.dynamic_index_in_dim(st.conv, si, 0, keepdims=False)
+        if ssm_update is None:
+            ssm_l = jax.lax.dynamic_index_in_dim(
+                st.ssm, si, 0, keepdims=False)
+            update = None
+        else:
+            # the kernel takes the state of all layers and the layer's
+            # index, and gives the whole back, updated in place
+            ssm_l = st.ssm
+
+            def update(whole, *args, **kw):
+                return ssm_update(whole, si, *args, **kw)
+
+        x, (ssm_l, conv_l) = _ssm_block(
+            cfg, _cast(cfg, lp), x,
+            lambda p, h: ssm_ops.mixer_step(
+                cfg, p, h, (ssm_l, conv_l), active, update=update))
+        if ssm_update is None:
+            ssm_l = jax.lax.dynamic_update_index_in_dim(st.ssm, ssm_l, si, 0)
+        st = SSMState(
+            ssm=ssm_l,
+            conv=jax.lax.dynamic_update_index_in_dim(st.conv, conv_l, si, 0))
+        return (x, li, si + 1, st), (None, None, None)
+
     def layer(j, carry, lp):
         # ``li``: the layer, or (layer kinds) the period: the slice of the
         # pool's leading axis that holds the layer's pages
-        x, li = carry                                 # pool NOT in the scan
+        x, li, *rest = carry                          # pool NOT in the scan
         window, rotary = kinds[j]
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
@@ -1741,10 +2095,11 @@ def decode_step_paged(
             )
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
         elif cfg.layer_pattern is None:
-            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin)  # q [B, H, D]
-            ctx = paged_ops.paged_decode_attention(
+            q, k, v = _pack_qkv(
+                cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin))  # q [B, H, D]
+            ctx = _unpack_ctx(cfg, paged_ops.paged_decode_attention(
                 q, k, v, cache.pages, li, table_o, lens_o, **kw
-            )
+            ))
         else:
             q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
             with jax.named_scope(_attn_scope(window)):
@@ -1760,13 +2115,15 @@ def decode_step_paged(
             _routed_at(cfg, routed, li, j))
         return (
             (_add_branch(cfg, lp, "mlp_out_ln", x, m),
-             li + int(j == len(kinds) - 1)),
+             li + int(j == len(kinds) - 1), *rest),
             (k, v, routing if with_routing else None),
         )
 
-    (x, _), (ks, vs, routing) = _scan_passes(
+    zero = jnp.int32(0)
+    (x, *rest), (ks, vs, routing), _ = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, jnp.int32(0)), params,
+        (x, zero) if cfg.ssm is None else (x, zero, zero, ssm), params,
+        ssm_layer=ssm_layer,
     )
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(
@@ -1780,6 +2137,8 @@ def decode_step_paged(
         extra = (routing[:, inverse],)
     else:
         extra = ()
+    if cfg.ssm is not None:
+        extra += (rest[-1],)
     if not with_head:
         return (None, cache, new_lens) + extra
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)

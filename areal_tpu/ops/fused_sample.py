@@ -82,7 +82,8 @@ def fused_sample_applies(
     path, which GSPMD partitions); an UNTIED head stored in the serving
     dtype (``head_weight`` of a tied embedding is a lazy transpose, and a
     head kept in another dtype a lazy cast: as a ``pallas_call`` operand
-    either becomes a ``V x E`` copy every step); a policy, not a critic
+    either becomes a ``V x E`` copy every step) whose logits are not scaled
+    (``cfg.logits_scaling``: the kernel has no such argument); a policy, not a critic
     (whose head is one column); a vocabulary of at least one lane tile.
     Everything else runs exactly the programs it ran before the kernel
     existed. ``params`` is the engine's tree as it serves it (arrays or
@@ -93,6 +94,7 @@ def fused_sample_applies(
         platform == "tpu"
         and (mesh is None or mesh.size == 1)
         and not cfg.tied_embedding
+        and cfg.logits_scaling == 1.0
         and not cfg.is_critic
         and params["head"]["weight"].dtype == jnp.dtype(cfg.dtype)
         and cfg.vocab_size >= 128

@@ -1,0 +1,301 @@
+"""The state-space mixer (the published Mamba-2 recurrence, as the
+``granitemoehybrid`` family lays it out) in plain ``jax.numpy``.
+
+One layer, for head ``n`` with a state ``S`` of ``[head_dim, d_state]``::
+
+    [z ; xBC ; dt] = W_in x
+    xBC  = silu(conv(xBC))          causal depthwise, width d_conv, with bias
+    [x ; B ; C] = xBC               B and C shared by the heads of a group
+    dt   = softplus(dt + dt_bias)   A = -exp(A_log)
+    S_t  = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T
+    y_t  = S_t C_t + D x_t
+    out  = W_out (rms(y * silu(z)) * w)
+
+Two forms of one function:
+
+- :func:`mixer_chunk`: many tokens a row, the CHUNKED scan. Inside a chunk
+  of ``chunk`` tokens the recurrence is two masked matmuls (the decay
+  between two tokens of a chunk is ``exp(cum_t - cum_s)``); between chunks
+  a short ``lax.scan`` carries the state. It takes and returns the
+  recurrent and the convolution state (admission hands it a prompt a piece
+  at a time), and a token at position 0 of its document RESETS both before
+  it is read, so packed documents do not see each other. Autodiff through
+  it is the trainer's backward pass. Any chunk length gives the same
+  function.
+- :func:`mixer_step`: one token a row, the decode step's update.
+
+The recurrent state and everything that accumulates into it are float32
+whatever the serving dtype (``cfg.ssm.state_dtype``): a rollout's state is
+updated in place once a token for thousands of tokens, and the trainer
+recomputes the same log-probabilities with this module's chunked form.
+The state-space einsums run at ``Precision.HIGHEST``: at the default a TPU
+rounds float32 operands to bfloat16, which the one-token form (elementwise)
+never does, and the two forms must agree.
+"""
+
+import math
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from areal_tpu.models.config import ModelConfig
+from areal_tpu.ops import norms
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def state_shapes(cfg: ModelConfig, batch: int):
+    """``(ssm, conv)`` shapes of ``batch`` rows' state in ALL state-space
+    layers: ``[Ls, B, H, P, N]`` and ``[Ls, B, (d_conv - 1) x channels]``.
+    The convolution's last inputs are kept FLAT: with the 3 taps as an
+    axis of their own the chip's compiler, gathering a few rows, re-laid
+    the whole array out with that axis on the lanes (3 padded to 128:
+    2.99 GB at the published sizes; PERF.md §6 PR 41)."""
+    s = cfg.ssm
+    return (
+        (cfg.n_ssm_layers, batch, s.n_heads, s.head_dim, s.d_state),
+        (cfg.n_ssm_layers, batch, (s.d_conv - 1) * s.conv_dim),
+    )
+
+
+def state_bytes_per_slot(cfg: ModelConfig) -> int:
+    """What one slot's recurrent and convolution state take, all layers."""
+    ssm, conv = state_shapes(cfg, 1)
+    return (
+        math.prod(ssm) * jnp.dtype(cfg.ssm.state_dtype).itemsize
+        + math.prod(conv) * jnp.dtype(cfg.dtype).itemsize
+    )
+
+
+def _split_in(cfg: ModelConfig, p, h):
+    """``h [..., E]`` -> ``z [..., d_inner]``, ``xBC [..., conv_dim]``,
+    ``dt [..., H]`` (raw). The published input projection is ONE matrix of
+    ``z + xBC + dt`` columns; the tree keeps its three parts apart
+    (``w_z``, ``w_xbc``, ``w_dt``): at the published sizes the whole is
+    8,512 wide, not whole lane tiles, and the chip's compiler then kept a
+    transposed COPY of all 36 layers of it beside the decode chunk (1.17
+    GB; PERF.md §6 PR 41)."""
+    s = cfg.ssm
+    with jax.named_scope("ssm_in_proj"):
+        z, xbc, dt = h @ p["w_z"], h @ p["w_xbc"], h @ p["w_dt"]
+        if "b_in" in p:
+            b = p["b_in"]
+            z = z + b[..., : s.d_inner]
+            xbc = xbc + b[..., s.d_inner : s.d_inner + s.conv_dim]
+            dt = dt + b[..., s.d_inner + s.conv_dim :]
+    return z, xbc, dt
+
+
+def _dt_a(p, dt):
+    """``(dt, A)`` in float32: ``softplus(dt + dt_bias)`` and ``-exp(A_log)``
+    a head."""
+    dt = jax.nn.softplus(
+        dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    return dt, -jnp.exp(p["A_log"].astype(jnp.float32))
+
+
+def _gated_out(cfg: ModelConfig, p, y, z):
+    """``W_out (rms(y * silu(z)) * w)``; the norm spans ``d_inner``."""
+    with jax.named_scope("ssm_gated_norm"):
+        g = y * jax.nn.silu(z.astype(jnp.float32))
+        g = norms.rms_norm(g, p["gate_norm"], cfg.layer_norm_epsilon)
+    out = g.astype(z.dtype) @ p["w_out"]
+    if "b_out" in p:
+        out = out + p["b_out"]
+    return out
+
+
+def _split_xbc(cfg: ModelConfig, xbc):
+    """``xBC [..., conv_dim]`` -> ``x [..., G, R, P]``, ``B, C [..., G, N]``
+    (``G`` groups of ``R`` heads each), float32."""
+    s = cfg.ssm
+    G, R = s.n_groups, s.n_heads // s.n_groups
+    xbc = xbc.astype(jnp.float32)
+    lead = xbc.shape[:-1]
+    x = xbc[..., : s.d_inner].reshape(*lead, G, R, s.head_dim)
+    b = xbc[..., s.d_inner : s.d_inner + G * s.d_state].reshape(
+        *lead, G, s.d_state)
+    c = xbc[..., s.d_inner + G * s.d_state :].reshape(*lead, G, s.d_state)
+    return x, b, c
+
+
+def conv_chunk(p, xbc, positions, conv_state, n_valid):
+    """The causal depthwise convolution over ``xbc [B, T, C]`` whose rows
+    continue ``conv_state [B, (K - 1) x C]`` (the row's last ``K - 1``
+    inputs, flat: :func:`state_shapes`).
+    A tap that would reach behind position 0 of the token's own document
+    reads nothing (``positions [B, T]``, restarting a document). Returns
+    the activated output and the state after each row's first ``n_valid
+    [B]`` tokens."""
+    w = p["conv_w"]                                       # [K, C]
+    K = w.shape[0]
+    T = xbc.shape[1]
+    full = jnp.concatenate([
+        conv_state.astype(xbc.dtype).reshape(xbc.shape[0], K - 1, -1), xbc
+    ], axis=1)
+    with jax.named_scope("ssm_conv"):
+        out = 0.0
+        for d in range(K):
+            # the tap ``d`` tokens back: weight K - 1 - d
+            tap = full[:, K - 1 - d : K - 1 - d + T]
+            ok = (positions >= d)[..., None]
+            out = out + jnp.where(ok, tap, 0).astype(jnp.float32) * w[
+                K - 1 - d].astype(jnp.float32)
+        if "conv_b" in p:
+            out = out + p["conv_b"].astype(jnp.float32)
+        out = jax.nn.silu(out).astype(xbc.dtype)
+    new_state = jax.vmap(
+        lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, K - 1, axis=0)
+    )(full, n_valid)
+    return out, new_state.astype(conv_state.dtype).reshape(conv_state.shape)
+
+
+def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):
+    """The recurrence over ``T`` tokens a row in chunks of ``chunk``.
+
+    ``x [B, T, G, R, P]``, ``dt [B, T, G, R]`` (0 where a token must leave
+    the state alone: padding), ``a_head [G, R]``, ``b, c [B, T, G, N]``,
+    ``reset [B, T]`` (the state is dropped before this token), ``init [B,
+    G, R, P, N]``; all float32. Returns ``y [B, T, G, R, P]`` (without the
+    ``D x`` term) and the state after the last token."""
+    Bt, T = x.shape[:2]
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        # trailing tokens with dt 0 and no reset: the state passes through
+        x, dt, b, c = (
+            jnp.pad(v, [(0, 0), (0, pad)] + [(0, 0)] * (v.ndim - 2))
+            for v in (x, dt, b, c))
+        reset = jnp.pad(reset, [(0, 0), (0, pad)])
+    nc = (T + pad) // Q
+
+    def chunks(v):
+        return v.reshape(Bt, nc, Q, *v.shape[2:])
+
+    x, dt, b, c, reset = map(chunks, (x, dt, b, c, reset))
+    with jax.named_scope("ssm_scan"):
+        cum = jnp.cumsum(dt * a_head, axis=2)             # [B, nc, Q, G, R]
+        seg = jnp.cumsum(reset.astype(jnp.int32), axis=2)  # [B, nc, Q]
+        t_idx = jnp.arange(Q)
+        # token s reaches token t: not later, and no reset in (s, t]
+        reach = (t_idx[:, None] >= t_idx[None, :]) & (
+            seg[..., :, None] == seg[..., None, :])       # [B, nc, Q, Q]
+        diff = cum[:, :, :, None] - cum[:, :, None, :]    # [B, nc, t, s, G, R]
+        decay = jnp.where(reach[..., None, None], jnp.exp(
+            jnp.where(reach[..., None, None], diff, 0.0)), 0.0)
+        cb = jnp.einsum("bctgn,bcsgn->bctsg", c, b, precision=_HI)
+        m = cb[..., None] * decay * dt[:, :, None]        # [B, nc, t, s, G, R]
+        y = jnp.einsum("bctsgr,bcsgrp->bctgrp", m, x, precision=_HI)
+        # what each chunk adds to the state at its end, and what it keeps
+        # of the state it was handed
+        last = seg[:, :, -1:]
+        to_end = jnp.where(
+            (seg == last)[..., None, None],
+            jnp.exp(cum[:, :, -1:] - cum), 0.0) * dt      # [B, nc, Q, G, R]
+        add = jnp.einsum(
+            "bcsgr,bcsgrp,bcsgn->bcgrpn", to_end, x, b, precision=_HI)
+        keep = jnp.where(
+            (last == 0)[..., None], jnp.exp(cum[:, :, -1]), 0.0)  # [B, nc, G, R]
+
+        def carry(s, inp):
+            k, a = inp
+            return s * k[..., None, None] + a, s
+
+        final, s_in = jax.lax.scan(
+            carry, init,
+            (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(add, 1, 0)))
+        s_in = jnp.moveaxis(s_in, 0, 1)                   # [B, nc, G, R, P, N]
+        from_init = jnp.where(
+            (seg == 0)[..., None, None], jnp.exp(cum), 0.0)  # [B, nc, Q, G, R]
+        y = y + jnp.einsum(
+            "bctgn,bcgrpn->bctgrp", c, s_in, precision=_HI
+        ) * from_init[..., None]
+    y = y.reshape(Bt, nc * Q, *y.shape[3:])
+    return (y[:, :T] if pad else y), final
+
+
+def mixer_chunk(
+    cfg: ModelConfig, p, h, positions, state: Optional[Tuple] = None,
+    n_valid=None, chunk: Optional[int] = None,
+):
+    """The state-space mixer over ``h [B, T, E]`` (normed layer input).
+    ``positions [B, T]``: each token's place in its own document: a token
+    at 0 starts from an empty state and convolution, whatever came before
+    it on the row. ``state``: ``(ssm [B, H, P, N], conv [B, K - 1, C])`` the
+    rows continue from (None: empty; ``conv`` flat, ``[B, (K - 1) x C]``).
+    ``n_valid [B]``: tokens of each row
+    that count (the rest is padding BEHIND them, which leaves the state
+    as it is). Returns ``(out [B, T, E], (ssm, conv))``."""
+    s = cfg.ssm
+    Bt, T = h.shape[:2]
+    G, R = s.n_groups, s.n_heads // s.n_groups
+    if state is None:
+        state = (
+            jnp.zeros((Bt, s.n_heads, s.head_dim, s.d_state),
+                      jnp.dtype(s.state_dtype)),
+            jnp.zeros((Bt, (s.d_conv - 1) * s.conv_dim), h.dtype),
+        )
+    if n_valid is None:
+        n_valid = jnp.full((Bt,), T, jnp.int32)
+    ssm0, conv0 = state
+    z, xbc, dt = _split_in(cfg, p, h)
+    xbc, conv1 = conv_chunk(p, xbc, positions, conv0, n_valid)
+    x, b, c = _split_xbc(cfg, xbc)
+    dt, a = _dt_a(p, dt)
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]
+    dt = jnp.where(valid[..., None], dt, 0.0).reshape(Bt, T, G, R)
+    y, ssm1 = scan_chunked(
+        x, dt, a.reshape(G, R), b, c, (positions == 0) & valid,
+        ssm0.astype(jnp.float32).reshape(Bt, G, R, s.head_dim, s.d_state),
+        chunk or s.chunk_size,
+    )
+    y = y + p["D"].astype(jnp.float32).reshape(G, R)[..., None] * x
+    out = _gated_out(cfg, p, y.reshape(Bt, T, s.d_inner), z)
+    return out, (ssm1.reshape(ssm0.shape).astype(ssm0.dtype), conv1)
+
+
+def step_update(ssm, x, dt, a, b, c, d_skip):
+    """One token of the recurrence, every row: ``ssm [B, G, R, P, N]``, ``x
+    [B, G, R, P]``, ``dt [B, G, R]`` (0: the row's state stays), ``a,
+    d_skip [G, R]``, ``b, c [B, G, N]``; float32. Returns ``(y [B, G, R,
+    P], ssm)``. The plain reference of the ``ssm_decode`` kernel."""
+    with jax.named_scope("ssm_step"):
+        ssm = ssm * jnp.exp(dt * a)[..., None, None] + (
+            (dt[..., None] * x)[..., None] * b[:, :, None, None, :])
+        y = jnp.sum(ssm * c[:, :, None, None, :], axis=-1)
+    return y + d_skip[..., None] * x, ssm
+
+
+def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
+    """The mixer over ONE token a row: ``h [B, E]``, ``state`` as
+    :func:`mixer_chunk` takes it. Rows where ``active [B]`` is false leave
+    their state as it was (their output is garbage nobody reads).
+    ``update``: what stands in for :func:`step_update` (the engine's
+    kernel, which works on the state of all layers in place): it is handed
+    ``state[0]`` AS GIVEN in place of the reshaped state, and ``active``
+    by name; what it returns as the state is returned as is. Returns ``(out [B, E], (ssm,
+    conv))``."""
+    s = cfg.ssm
+    Bt = h.shape[0]
+    G, R = s.n_groups, s.n_heads // s.n_groups
+    ssm0, conv0 = state
+    if active is None:
+        active = jnp.ones((Bt,), bool)
+    z, xbc, dt = _split_in(cfg, p, h)
+    xbc, conv1 = conv_chunk(
+        p, xbc[:, None], jnp.full((Bt, 1), s.d_conv, jnp.int32), conv0,
+        active.astype(jnp.int32))
+    x, b, c = _split_xbc(cfg, xbc[:, 0])
+    dt, a = _dt_a(p, dt)
+    dt = jnp.where(active[:, None], dt, 0.0).reshape(Bt, G, R)
+    args = (x, dt, a.reshape(G, R), b, c,
+            p["D"].astype(jnp.float32).reshape(G, R))
+    if update is None:
+        y, ssm1 = step_update(
+            ssm0.reshape(Bt, G, R, s.head_dim, s.d_state), *args)
+        ssm1 = ssm1.reshape(ssm0.shape)
+    else:
+        y, ssm1 = update(ssm0, *args, active=active)
+    out = _gated_out(cfg, p, y.reshape(Bt, s.d_inner), z)
+    return out, (ssm1, conv1)

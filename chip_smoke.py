@@ -195,6 +195,11 @@ FUSED_SAMPLE_KERNEL = "fused_sample"
 # (ops/pallas/moe_grouped.py); the smoke's model has no router, so the
 # kernel runs alone, beside the einsums (``child_moegrouped``)
 MOE_GROUPED_KERNEL = "moe_grouped"
+# the decode step's update of the per-slot recurrent state
+# (ops/pallas/ssm_decode.py); the smoke's own model has no state-space
+# layer, so a helper child drives a two-layer model of layer KINDS (one
+# state-space, one attention layer) through the engine (``child_statespace``)
+SSM_DECODE_KERNEL = "ssm_decode"
 
 
 def kernels_in(paths):
@@ -594,6 +599,26 @@ def phase_serve(sz, args):
     require(grouped["max_abs_diff_vs_einsums"] <= grouped["tolerance"]
             and (args.rehearse or grouped["kernel"] == MOE_GROUPED_KERNEL),
             f"moe_grouped disagrees with the einsums: {grouped}")
+    # ... and a model of layer KINDS through the engine: one state-space
+    # layer beside one attention layer (the served model above has one
+    # kind), its log-probs against the token-by-token reference, its decode
+    # chunk searched for the state update's kernel and the paged kernels
+    # at a head of 64
+    hybrid, _ = helper(d, "statespace", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(hybrid["logprobs"]["correct"]
+            and hybrid["state_snapshot_hits"] == 3
+            and hybrid["prefix_hit_tokens"][1:] == hybrid["prefix_hit_tokens"][1:2] * 3
+            and hybrid["prefix_hit_tokens"][1] > 0,
+            f"state-space layers beside attention: served log-probs or the "
+            f"snapshot path are off: {hybrid}")
+    require(args.rehearse or all(
+                any(k.startswith(want) for k in hybrid["kernels"])
+                for want in (SSM_DECODE_KERNEL, "paged_decode",
+                             KV_WRITE_KERNEL)),
+            f"the two-kind model's decode chunk lacks a kernel: "
+            f"{hybrid['kernels']}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -652,6 +677,7 @@ def phase_serve(sz, args):
         "fused_rows": metrics.get("engine_fused_rows"),
         "fused_sample_vs_head": fused,
         "moe_grouped_vs_einsums": grouped,
+        "state_space_model": hybrid,
         "peak_hbm_gib": round(
             metrics.get("hbm_peak_bytes_in_use", 0) / 2**30, 2),
         "checkpoint_gib": round(made["bytes"] / 2**30, 2),
@@ -1343,11 +1369,93 @@ def child_moegrouped(arg):
     })
 
 
+def child_statespace(arg):
+    """A model of two layer KINDS through the generation engine: one
+    state-space layer and one attention layer at granite-4.0-h-micro's
+    widths (toy widths under ``--rehearse``), seeded as the benchmark
+    seeds it. A group of four shares a prompt (the first prefills in two
+    chunks and files a snapshot of the recurrent state, the rest are
+    seeded from it), one request generates alone; the served log-probs
+    are held to the token-by-token float32 reference, and the decode
+    chunk's program is searched for the kernels it should hold."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine, GenRequest
+    from benchmark import correct, sut, weights
+    from benchmark.drivers import rollout_state_inproc as drv
+
+    with open(os.path.join(
+            ROOT, "benchmark/configs/granite-4.0-h-micro.json")) as f:
+        arch = json.load(f)
+    arch.update(num_hidden_layers=2, layer_types=["mamba", "attention"])
+    page, prompt_len, new = 128, 300, 48
+    if arg["rehearse"]:
+        arch.update(hidden_size=64, intermediate_size=128,
+                    num_attention_heads=4, num_key_value_heads=2,
+                    vocab_size=512, serving_dtype="float32")
+        arch = drv._rehearsal_arch(arch)
+        page, prompt_len, new = 16, 40, 12
+    cfg = sut.model_config(arch, {})
+    params = drv._state_space_init(
+        weights.make_weights(
+            sut.weight_shapes(cfg, cfg.dtype), arg["seed"],
+            jnp.dtype(cfg.dtype)),
+        arg["seed"])
+    eng = GenerationEngine(
+        cfg, params, max_slots=8, max_seqlen=4 * page + 64,
+        max_new_tokens_cap=64, page_size=page, state_snapshots=2,
+        seed=arg["seed"] % (2**31 - 1))
+    rng = np.random.default_rng(arg["seed"])
+    shared = rng.integers(1, cfg.vocab_size, prompt_len).tolist()
+    alone = rng.integers(1, cfg.vocab_size, prompt_len // 3).tolist()
+    prompts = {**{f"g{i}": shared for i in range(4)}, "alone": alone}
+    for rid, p in prompts.items():
+        eng.submit(GenRequest(
+            rid=rid, input_ids=p, max_new_tokens=new, temperature=1.0))
+    outs = {o.rid: o for o in eng.run_until_done(decode_steps=8)}
+    (key,) = [k for k in eng._jit_chunk]
+    chunk = eng._chunk_fn(*key)
+    names = sorted(set(re.findall(
+        r'kernel_name = "([^"]+)"',
+        chunk.lower(
+            *eng._model_args(), eng.state,
+            jnp.asarray(eng._table_arg(slice(None), key[1])),
+            jnp.zeros((key[2],), jnp.int32)).as_text())))
+    samples = [{
+        "tokens": prompts[rid] + list(o.output_ids),
+        "start": len(prompts[rid]), "logprobs": o.output_logprobs,
+    } for rid, o in sorted(outs.items())]
+    stats = dict(eng.stats)
+    eng.state = None
+    verdict = correct.check_logprobs(params, arch, cfg.dtype, samples)
+    emit({
+        "layer_types": arch["layer_types"], "mixers": list(cfg.mixers),
+        "widths": [cfg.hidden_dim, cfg.ssm.n_heads, cfg.ssm.head_dim,
+                   cfg.ssm.d_state, cfg.n_kv_heads, cfg.head_dim],
+        "kv_heads_per_row": cfg.kv_heads_per_row,
+        "kernels": names,
+        "state_snapshots_taken": stats["state_snapshots_taken"],
+        "state_snapshot_hits": stats["state_snapshot_hits"],
+        "prefix_hit_tokens": [outs[f"g{i}"].prefix_hit_tokens
+                              for i in range(4)],
+        "state_slots": stats["state_slots"],
+        "logprobs": {k: verdict.get(k) for k in (
+            "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
+            "mean_abs_diff_nats", "n_positions")},
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 CHILDREN = {
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,
-    "moegrouped": child_moegrouped,
+    "moegrouped": child_moegrouped, "statespace": child_statespace,
 }
 
 

@@ -38,6 +38,9 @@ PRESET_125M = dict(hq=12, hkv=4, d=64)
 OLMOE = dict(hq=16, hkv=16, d=128)
 # R1-Distill-Qwen-7B: 4 kv heads leave room for 4 slots a grid step
 QWEN_7B = dict(hq=28, hkv=4, d=128)
+# granite-4.0-h-micro's attention layers in the pool's geometry: a head of
+# 64 is half a lane tile, so two kv heads share a row (kv_heads_per_row)
+GRANITE_ROWS = dict(hq=32, hkv=4, d=128)
 T_TRAIN = 4096          # 8 x 512 packed tokens: the default train step
 
 
@@ -76,11 +79,11 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the three kernel modules off interpret mode (on the CPU their
     `_interpret()` says True) for the duration of one test."""
     from areal_tpu.ops.pallas import flash_attention, fused_sample
-    from areal_tpu.ops.pallas import kv_page_write, moe_grouped
+    from areal_tpu.ops.pallas import kv_page_write, moe_grouped, ssm_decode
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
     for mod in (flash_attention, fused_sample, pl_paged, kv_page_write,
-                moe_grouped):
+                moe_grouped, ssm_decode):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     # ... and the fused epilogue's dispatch (and the engine's rule) off the
     # CPU they would see: a chunk built with ``fused=True`` ends in the
@@ -196,6 +199,11 @@ def _paged_specs(one_chip, *, page, int8, L=28, B=64, P=256, M=16,
         pytest.param(128, False,
                      dict(B=64, M=40, P=1311, L=16, layout=QWEN_7B),
                      id="cell3-64x28q4kv-table40"),
+        # granite-4.0-h-micro: 32q / 8kv x 64 as the kernel sees them, two
+        # kv heads to a 128-lane row (4 x 128), its four attention layers
+        pytest.param(128, False,
+                     dict(B=80, M=40, P=1621, L=4, layout=GRANITE_ROWS),
+                     id="granite-80x32q4rows-table40"),
     ],
 )
 def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8, shape):
@@ -247,6 +255,30 @@ def test_int8_page64_turned_away_by_the_gate(compiled_kernels):
             jax.ShapeDtypeStruct((B,), jnp.int32),
             scales=jax.ShapeDtypeStruct((L, P, 2, hkv, page), jnp.float32),
         )
+
+
+@pytest.mark.parametrize("head_block", [None, 64])
+def test_ssm_decode_compiles(compiled_kernels, one_chip, head_block):
+    """The state update's kernel at granite-4.0-h-micro's sizes and the
+    cell's 80 slots, the state of all 36 layers donated: the result IS the
+    argument (aliased) and the program holds no second state."""
+    from areal_tpu.ops.pallas import ssm_decode
+
+    Ls, B, H, P, N = 36, 80, 64, 64, 128
+    f32 = lambda *shape: _spec(shape, jnp.float32, one_chip)
+    compiled = jax.jit(
+        functools.partial(ssm_decode.ssm_decode, head_block=head_block),
+        donate_argnums=(0,),
+    ).lower(
+        f32(Ls, B, H, P, N), _spec((), jnp.int32, one_chip), f32(B, 1, H, P),
+        f32(B, 1, H), f32(1, H), f32(B, 1, N), f32(B, 1, N), f32(1, H),
+        _spec((B,), jnp.bool_, one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_decode" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * Ls * B * H * P * N
+    assert mem.temp_size_in_bytes < 0.05e9
 
 
 # the rollout cells' decode epilogue: slots x hidden x vocabulary
@@ -379,6 +411,8 @@ KV_WRITE_CELLS = {
     # weights, pages of 64 (33.5 MB a page of 128 wastes too much a slot)
     "ouro": dict(L=32, P=858, S=2, H=16, W=128, B=72, M=40, page=64,
                  config="ouro-2p6b-l8", seqlen=2560, out=2048),
+    # granite-4.0-h-micro: 4 attention layers, 8 kv heads of 64 as 4 rows
+    "granite": dict(L=4, P=1621, S=2, H=4, W=128, B=80, M=40),
 }
 
 
@@ -387,7 +421,7 @@ KV_WRITE_CELLS = {
     [
         # a decode step: one token a slot (one 16-row slab a layer and slot)
         ("cell1", None, 1), ("cell3", None, 1), ("olmoe", None, 1),
-        ("joyai", None, 1), ("ouro", None, 1),
+        ("joyai", None, 1), ("ouro", None, 1), ("granite", None, 1),
         # an admission wave of 8 x 128 tokens at the two ends: 128 KB
         # slabs, and a latent row of five lane tiles (its window of fresh
         # rows starts between tiles: Mosaic takes that one lane tile wide)
