@@ -141,7 +141,7 @@ class GenState:
     out_routing: Optional[jnp.ndarray] = None   # [B, G, L, top_k] i32
     # a model with state-space layers (``cfg.ssm``; None otherwise): what
     # those layers keep of each SLOT in place of keys and values, ``ssm
-    # [Ls, B, H, P, N]`` float32 and ``conv [Ls, B, (K - 1) x C]``. Allocated
+    # [Ls, B, G, K, N, 128]`` float32 and ``conv [Ls, B, (d_conv - 1) x C]``. Allocated
     # by slot, not by page; zeroed or seeded from a snapshot at admission
     # (never inherited from the slot's last tenant), carried between
     # admission's chunks, updated in place by every decode step.
@@ -1086,9 +1086,12 @@ class GenerationEngine:
         after the prompt and all but the last of the ``n`` tokens generated
         so far (the last is fed at the next step), both from ONE state
         pytree. For a check from outside that the state is what the
-        recurrence says (the benchmark's, a test's); ``None`` for a request
-        that holds no slot or a model without such layers. The pull blocks
-        on any in-flight chunk, as ``partial_outputs``'s does."""
+        recurrence says (the benchmark's, a test's): head by head as the
+        equations write it, so the ONE pulled row is turned here from the
+        layout the slots keep (``[Ls, G, K, N, 128]``, ``ops/ssm.py``).
+        ``None`` for a request that holds no slot or a model without such
+        layers. The pull blocks on any in-flight chunk, as
+        ``partial_outputs``'s does."""
         with self._lock:
             st = self.state
             if st.ssm is None:
@@ -1096,7 +1099,10 @@ class GenerationEngine:
             for b, s in enumerate(self._slots):
                 if s is not None and s.rid == rid:
                     n, ssm = jax.device_get((st.n_gen[b], st.ssm.ssm[:, b]))
-                    return int(n), np.asarray(ssm)
+                    c = self.cfg.ssm
+                    ssm = np.asarray(ssm).transpose(0, 1, 2, 4, 3)
+                    return int(n), ssm.reshape(
+                        len(ssm), c.n_heads, c.head_dim, c.d_state)
             return None
 
     def cancel(self, rid: str) -> bool:

@@ -1254,13 +1254,14 @@ class KVCache:
 class SSMState:
     """What the state-space layers keep of a ROW (a slot of the generation
     engine, a row of the dense cache) in place of keys and values: ``ssm
-    [Ls, B, H, P, N]`` the recurrent state of every head of every
-    state-space layer, float32 (``cfg.ssm.state_dtype``), and ``conv [Ls,
-    B, (d_conv - 1) x C]`` the convolution's last inputs in the serving
-    dtype, flat (``ops/ssm.py:state_shapes`` says why). It has one size however long the row's context is, so it is
-    allocated by row and not by page: a prefix cannot be shared by
-    pointing at it, only by copying a snapshot of it
-    (``gen/engine.py``)."""
+    [Ls, B, G, K, N, 128]`` the recurrent state of every head of every
+    state-space layer, float32 (``cfg.ssm.state_dtype``), its channels
+    minor in ``K`` lane tiles, and ``conv [Ls, B, (d_conv - 1) x C]`` the
+    convolution's last inputs in the serving dtype, flat (``ops/ssm.py``
+    says why of both).
+    It has one size however long the row's context is, so it is allocated
+    by row and not by page: a prefix cannot be shared by pointing at it,
+    only by copying a snapshot of it (``gen/engine.py``)."""
 
     ssm: jnp.ndarray
     conv: jnp.ndarray
@@ -1730,8 +1731,8 @@ def _extend_layers(
     slot ``slots[b]`` of ``ssm`` (the engine's per-slot state, read and
     not written here) over its ``n_new[b]`` tokens; a token at position 0
     starts from nothing whatever the slot held. ``ssm_rows``: ``(ssm [Ls,
-    B, H, P, N], conv [Ls, B, K - 1, C])`` after them, for the caller to
-    put back; None for a model without such layers."""
+    B, G, K, N, 128], conv [Ls, B, (d_conv - 1) x C])`` after them, for the
+    caller to put back; None for a model without such layers."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     if cfg.ssm is not None and verify:
@@ -1769,10 +1770,15 @@ def _extend_layers(
 
     def ssm_layer(carry, lp):
         x, li, si = carry
+        # the barrier keeps the rows' gather a result of its own: the
+        # gather of ONE row is a slice to the chip's compiler, and the
+        # layout the scan's matmuls ask of that slice it then gave to the
+        # whole state, a copy of all 36 layers of it (PERF.md §6 PR 42)
+        rows = jax.lax.optimization_barrier(ssm.ssm[si, slots])
         x, st = _ssm_block(
             cfg, _cast(cfg, lp), x,
             lambda p, h: ssm_ops.mixer_chunk(
-                cfg, p, h, positions, (ssm.ssm[si, slots], ssm.conv[si, slots]),
+                cfg, p, h, positions, (rows, ssm.conv[si, slots]),
                 n_valid=n_new))
         return (x, li, si + 1), st
 

@@ -1,7 +1,7 @@
 """The state-space mixer (the published Mamba-2 recurrence, as the
 ``granitemoehybrid`` family lays it out) in plain ``jax.numpy``.
 
-One layer, for head ``n`` with a state ``S`` of ``[head_dim, d_state]``::
+One layer, for a head with a state ``S`` of ``head_dim x d_state``::
 
     [z ; xBC ; dt] = W_in x
     xBC  = silu(conv(xBC))          causal depthwise, width d_conv, with bias
@@ -24,6 +24,28 @@ Two forms of one function:
   function.
 - :func:`mixer_step`: one token a row, the decode step's update.
 
+The recurrent state is kept TRANSPOSED and in lane tiles, ``[G, K, N,
+128]`` a row a layer (``G`` groups of ``R`` heads of ``P`` channels, a state
+of ``N`` a channel; channel ``r P + p`` of its group is lane ``l`` of tile
+``k``, ``k 128 + l``): the channels lie on the minor axis, the chip's
+lanes, and ``N`` on the sublanes. What varies a HEAD or a channel (``dt
+x``, the decay ``exp(dt A)``, ``y``) is then a plain ``[R x P]`` row as the
+model already holds it, and what is the SAME for every head of a group
+(``B``, ``C``) is the one operand that has to be spread over the lanes,
+once a row; the sum for ``y`` runs down the sublanes. With ``N`` on the
+lanes (``[H, P, N]``, what this module kept before PR 42) it is the other
+way round: ``dt x`` becomes a column pushed across the lanes for every
+head, and ``y`` a lane reduction a head (``ops/pallas/ssm_decode.py`` has
+the table). The tiles are an axis of their own because a minor axis of
+all ``R x P`` = 4,096 channels is more than the chip's compiler gathers
+rows of: admission's gather of a few slots' state first cut the WHOLE
+array in two along its lanes, and a slice a row in its place made it
+re-lay the whole array out for the chunked scan's matmuls (2.8 and 5.6
+GB of temporaries beside a state of 6; PERF.md §6 PR 42). A row is the
+same 2 MiB in one piece either way. ONE layout wherever the state lives:
+:func:`state_shapes`, both forms below, the engine's slots and its
+snapshots.
+
 The recurrent state and everything that accumulates into it are float32
 whatever the serving dtype (``cfg.ssm.state_dtype``): a rollout's state is
 updated in place once a token for thousands of tokens, and the trainer
@@ -45,16 +67,33 @@ from areal_tpu.ops import norms
 _HI = jax.lax.Precision.HIGHEST
 
 
+LANES = 128     # the chip's: the minor axis of a register and of a tile
+
+
+def _lane_tiles(w: int):
+    """``(K, lanes)`` of ``w`` channels: whole lane tiles (one tile of ``w``
+    where it is not whole tiles: test sizes)."""
+    return (w // LANES, LANES) if w % LANES == 0 else (1, w)
+
+
+def _tiles(v):
+    """``[..., W]`` channels -> ``[..., K, lanes]``."""
+    return v.reshape(*v.shape[:-1], *_lane_tiles(v.shape[-1]))
+
+
 def state_shapes(cfg: ModelConfig, batch: int):
     """``(ssm, conv)`` shapes of ``batch`` rows' state in ALL state-space
-    layers: ``[Ls, B, H, P, N]`` and ``[Ls, B, (d_conv - 1) x channels]``.
+    layers: ``[Ls, B, G, K, N, lanes]`` (the module docstring says why the
+    channels are minor, and in tiles) and ``[Ls, B, (d_conv - 1) x
+    channels]``.
     The convolution's last inputs are kept FLAT: with the 3 taps as an
     axis of their own the chip's compiler, gathering a few rows, re-laid
     the whole array out with that axis on the lanes (3 padded to 128:
     2.99 GB at the published sizes; PERF.md §6 PR 41)."""
     s = cfg.ssm
+    k, lanes = _lane_tiles(s.n_heads // s.n_groups * s.head_dim)
     return (
-        (cfg.n_ssm_layers, batch, s.n_heads, s.head_dim, s.d_state),
+        (cfg.n_ssm_layers, batch, s.n_groups, k, s.d_state, lanes),
         (cfg.n_ssm_layers, batch, (s.d_conv - 1) * s.conv_dim),
     )
 
@@ -157,8 +196,10 @@ def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):
     ``x [B, T, G, R, P]``, ``dt [B, T, G, R]`` (0 where a token must leave
     the state alone: padding), ``a_head [G, R]``, ``b, c [B, T, G, N]``,
     ``reset [B, T]`` (the state is dropped before this token), ``init [B,
-    G, R, P, N]``; all float32. Returns ``y [B, T, G, R, P]`` (without the
-    ``D x`` term) and the state after the last token."""
+    G, K, N, lanes]`` (the stored layout); all float32. Returns ``y [B, T,
+    G, R, P]`` (without the ``D x`` term) and the state after the last
+    token, laid out as ``init``: every product with the state is a matmul
+    whose result has the lanes minor, as it is stored."""
     Bt, T = x.shape[:2]
     Q = min(chunk, T)
     pad = -T % Q
@@ -193,24 +234,28 @@ def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):
         to_end = jnp.where(
             (seg == last)[..., None, None],
             jnp.exp(cum[:, :, -1:] - cum), 0.0) * dt      # [B, nc, Q, G, R]
+        G, R, P = x.shape[-3:]
         add = jnp.einsum(
-            "bcsgr,bcsgrp,bcsgn->bcgrpn", to_end, x, b, precision=_HI)
+            "bcsgkl,bcsgn->bcgknl",
+            _tiles((to_end[..., None] * x).reshape(Bt, nc, Q, G, R * P)), b,
+            precision=_HI)
         keep = jnp.where(
             (last == 0)[..., None], jnp.exp(cum[:, :, -1]), 0.0)  # [B, nc, G, R]
 
         def carry(s, inp):
             k, a = inp
-            return s * k[..., None, None] + a, s
+            return s * k + a, s
 
         final, s_in = jax.lax.scan(
             carry, init,
-            (jnp.moveaxis(keep, 1, 0), jnp.moveaxis(add, 1, 0)))
-        s_in = jnp.moveaxis(s_in, 0, 1)                   # [B, nc, G, R, P, N]
+            (jnp.moveaxis(_tiles(jnp.repeat(keep, P, axis=-1)), 1, 0)[
+                ..., None, :], jnp.moveaxis(add, 1, 0)))
+        s_in = jnp.moveaxis(s_in, 0, 1)                # [B, nc, G, K, N, lanes]
         from_init = jnp.where(
             (seg == 0)[..., None, None], jnp.exp(cum), 0.0)  # [B, nc, Q, G, R]
         y = y + jnp.einsum(
-            "bctgn,bcgrpn->bctgrp", c, s_in, precision=_HI
-        ) * from_init[..., None]
+            "bctgn,bcgknl->bctgkl", c, s_in, precision=_HI
+        ).reshape(y.shape) * from_init[..., None]
     y = y.reshape(Bt, nc * Q, *y.shape[3:])
     return (y[:, :T] if pad else y), final
 
@@ -222,8 +267,9 @@ def mixer_chunk(
     """The state-space mixer over ``h [B, T, E]`` (normed layer input).
     ``positions [B, T]``: each token's place in its own document: a token
     at 0 starts from an empty state and convolution, whatever came before
-    it on the row. ``state``: ``(ssm [B, H, P, N], conv [B, K - 1, C])`` the
-    rows continue from (None: empty; ``conv`` flat, ``[B, (K - 1) x C]``).
+    it on the row. ``state``: ``(ssm [B, G, K, N, lanes], conv [B, (d_conv
+    - 1) x C])`` the rows continue from (None: empty;
+    :func:`state_shapes`).
     ``n_valid [B]``: tokens of each row
     that count (the rest is padding BEHIND them, which leaves the state
     as it is). Returns ``(out [B, T, E], (ssm, conv))``."""
@@ -231,10 +277,10 @@ def mixer_chunk(
     Bt, T = h.shape[:2]
     G, R = s.n_groups, s.n_heads // s.n_groups
     if state is None:
+        ssm_shape, conv_shape = state_shapes(cfg, Bt)
         state = (
-            jnp.zeros((Bt, s.n_heads, s.head_dim, s.d_state),
-                      jnp.dtype(s.state_dtype)),
-            jnp.zeros((Bt, (s.d_conv - 1) * s.conv_dim), h.dtype),
+            jnp.zeros(ssm_shape[1:], jnp.dtype(s.state_dtype)),
+            jnp.zeros(conv_shape[1:], h.dtype),
         )
     if n_valid is None:
         n_valid = jnp.full((Bt,), T, jnp.int32)
@@ -247,24 +293,42 @@ def mixer_chunk(
     dt = jnp.where(valid[..., None], dt, 0.0).reshape(Bt, T, G, R)
     y, ssm1 = scan_chunked(
         x, dt, a.reshape(G, R), b, c, (positions == 0) & valid,
-        ssm0.astype(jnp.float32).reshape(Bt, G, R, s.head_dim, s.d_state),
-        chunk or s.chunk_size,
+        ssm0.astype(jnp.float32), chunk or s.chunk_size,
     )
     y = y + p["D"].astype(jnp.float32).reshape(G, R)[..., None] * x
     out = _gated_out(cfg, p, y.reshape(Bt, T, s.d_inner), z)
-    return out, (ssm1.reshape(ssm0.shape).astype(ssm0.dtype), conv1)
+    return out, (ssm1.astype(ssm0.dtype), conv1)
+
+
+def step_rows(x, dt, a):
+    """What varies a channel in one token's update, as rows over the
+    state's minor axis: ``(exp(dt A), dt x)``, each ``[B, G, R x P]``, from
+    ``x [B, G, R, P]``, ``dt [B, G, R]`` and ``a [G, R]``. No transpose:
+    the channels are minor in ``x`` as in the state."""
+    Bt, G, R, P = x.shape
+    dt = jnp.repeat(dt, P, axis=-1)
+    return jnp.exp(dt * jnp.repeat(a, P, axis=-1)), dt * x.reshape(
+        Bt, G, R * P)
+
+
+def step_out(y, x, d_skip):
+    """``y [B, G, R x P]`` (the state's sum against ``C``) ``+ D x``, as ``x
+    [B, G, R, P]``."""
+    Bt, G, R, P = x.shape
+    y = y + jnp.repeat(d_skip, P, axis=-1) * x.reshape(Bt, G, R * P)
+    return y.reshape(x.shape)
 
 
 def step_update(ssm, x, dt, a, b, c, d_skip):
-    """One token of the recurrence, every row: ``ssm [B, G, R, P, N]``, ``x
-    [B, G, R, P]``, ``dt [B, G, R]`` (0: the row's state stays), ``a,
+    """One token of the recurrence, every row: ``ssm [B, G, K, N, lanes]``,
+    ``x [B, G, R, P]``, ``dt [B, G, R]`` (0: the row's state stays), ``a,
     d_skip [G, R]``, ``b, c [B, G, N]``; float32. Returns ``(y [B, G, R,
     P], ssm)``. The plain reference of the ``ssm_decode`` kernel."""
     with jax.named_scope("ssm_step"):
-        ssm = ssm * jnp.exp(dt * a)[..., None, None] + (
-            (dt[..., None] * x)[..., None] * b[:, :, None, None, :])
-        y = jnp.sum(ssm * c[:, :, None, None, :], axis=-1)
-    return y + d_skip[..., None] * x, ssm
+        decay, dtx = (_tiles(v)[..., None, :] for v in step_rows(x, dt, a))
+        ssm = ssm * decay + b[:, :, None, :, None] * dtx
+        y = jnp.sum(ssm * c[:, :, None, :, None], axis=3)
+    return step_out(y.reshape(*y.shape[:2], -1), x, d_skip), ssm
 
 
 def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
@@ -273,9 +337,9 @@ def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
     their state as it was (their output is garbage nobody reads).
     ``update``: what stands in for :func:`step_update` (the engine's
     kernel, which works on the state of all layers in place): it is handed
-    ``state[0]`` AS GIVEN in place of the reshaped state, and ``active``
-    by name; what it returns as the state is returned as is. Returns ``(out [B, E], (ssm,
-    conv))``."""
+    the state of ALL layers in place of this layer's, and ``active`` by
+    name; what it returns as the state is returned as is. Returns ``(out
+    [B, E], (ssm, conv))``."""
     s = cfg.ssm
     Bt = h.shape[0]
     G, R = s.n_groups, s.n_heads // s.n_groups
@@ -291,11 +355,8 @@ def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
     dt = jnp.where(active[:, None], dt, 0.0).reshape(Bt, G, R)
     args = (x, dt, a.reshape(G, R), b, c,
             p["D"].astype(jnp.float32).reshape(G, R))
-    if update is None:
-        y, ssm1 = step_update(
-            ssm0.reshape(Bt, G, R, s.head_dim, s.d_state), *args)
-        ssm1 = ssm1.reshape(ssm0.shape)
-    else:
-        y, ssm1 = update(ssm0, *args, active=active)
+    y, ssm1 = (
+        step_update(ssm0, *args) if update is None
+        else update(ssm0, *args, active=active))
     out = _gated_out(cfg, p, y.reshape(Bt, s.d_inner), z)
     return out, (ssm1, conv1)

@@ -193,20 +193,33 @@ def test_pause_resume_and_a_resubmitted_partial_rollout(
             o.output_logprobs, atol=TOL)
 
 
-def test_recurrent_state_of_a_running_request_is_the_recurrences(params):
-    """``recurrent_state``: after a prompt prefilled in chunks and ``n - 1``
+@pytest.mark.parametrize("seeded,update", [
+    (False, "xla"), (True, "xla"), (True, "kernel")],
+    ids=["prefilled-xla", "snapshot-xla", "snapshot-kernel"])
+def test_recurrent_state_of_a_running_request_is_the_recurrences(
+        params, seeded, update, monkeypatch):
+    """``recurrent_state``: after a prompt prefilled in chunks (or SEEDED
+    from a sibling's snapshot and prefilled from there) and ``n - 1``
     in-place updates the slot holds what the token-by-token recurrence
-    holds after the same tokens, to float32's rounding (1e-5 of a head's
-    norm: the chunked scan sums in another order); the reference with its
-    state rounded to bfloat16 after every token is a hundred times that
-    away, so this comparison tells a 16-bit state where the
-    log-probabilities do not."""
+    holds after the same tokens, head by head as ``[Ls, H, P, N]`` whatever
+    layout the slots keep, to float32's rounding (1e-5 of a head's norm:
+    the chunked scan sums in another order); the reference with its state
+    rounded to bfloat16 after every token is a hundred times that away, so
+    this comparison tells a 16-bit state where the log-probabilities do
+    not. Once with the kernel the engine picks on the chip (interpret
+    mode), which updates the stored layout in place."""
     prompt = _prompt(30, 45)
+    if update == "kernel":
+        monkeypatch.setattr(
+            ssm_decode, "ssm_decode_applies", lambda cfg, mesh=None: True)
     eng = _engine(params)
+    if seeded:
+        _run(eng, [prompt], max_new=4)
     eng.submit(GenRequest(
         rid="a", input_ids=prompt, max_new_tokens=40, temperature=1.0))
     for _ in range(4):
         eng.step(4)
+    assert eng.stats["state_snapshot_hits"] == int(seeded)
     n, got = eng.recurrent_state("a")
     toks = eng.partial_outputs()["a"][0]
     assert n == len(toks) and n >= 8

@@ -409,37 +409,114 @@ def test_admission_in_chunks_equals_one_prefill(params):
             jnp.zeros((1,), jnp.int32), jnp.full((1,), 4), jnp.full((1,), 4))
 
 
-def test_ssm_decode_kernel_equals_the_plain_update():
-    """The kernel in interpret mode against ``ops/ssm.py:step_update``,
-    with rows that are not active before, between and after active ones,
-    at two head-block sizes."""
+SSM_DECODE_SHAPES = [              # G, R, P, N
+    pytest.param(1, 4, 64, 128, id="published-head-64x128"),
+    pytest.param(1, 8, 32, 128, id="head-of-32"),
+    pytest.param(2, 2, 64, 128, id="two-groups"),
+    pytest.param(1, 8, 8, 16, id="test-model"),
+]
+SSM_DECODE_ROWS = {
+    # before, between and after active rows; less than a phase of copies
+    "edges": [False, True, False, False, True, True],
+    # three phases, the last one ragged
+    "a-tenth-off": ([True] * 3 + [False] + [True] * 6) * 2,
+    # a phase with nothing to copy between two that have
+    "a-phase-off": [True] * 8 + [False] * 8 + [True] * 8,
+    "none": [False] * 3,
+}
+
+
+@pytest.mark.parametrize("rows", list(SSM_DECODE_ROWS))
+@pytest.mark.parametrize("G,R,P,N", SSM_DECODE_SHAPES)
+def test_ssm_decode_kernel_equals_the_plain_update(G, R, P, N, rows):
+    """The kernel in interpret mode against ``ops/ssm.py:step_update`` on
+    the stored layout ``[Ls, B, G, K, N, lanes]``: the state AND ``y`` to
+    float32's rounding (both sum in float32, the kernel's ``y`` in another
+    order), the rows that are not active and the other layers bit for
+    bit; whole phases and a ragged last one."""
     from areal_tpu.ops.pallas import ssm_decode
 
-    Ls, B, H, P, N = 3, 6, 8, 8, 128
+    active = jnp.asarray(SSM_DECODE_ROWS[rows])
+    Ls, B = 3, active.shape[0]
     ks = jax.random.split(jax.random.key(2), 8)
-    whole = jax.random.normal(ks[0], (Ls, B, H, P, N))
-    x = jax.random.normal(ks[1], (B, 1, H, P))
-    active = jnp.asarray([False, True, False, False, True, True])
+    whole = ssm_ops._tiles(jax.random.normal(ks[0], (Ls, B, G, N, R * P)))
+    whole = jnp.moveaxis(whole, -2, -3)       # [Ls, B, G, K, N, lanes]
+    x = jax.random.normal(ks[1], (B, G, R, P))
     dt = jnp.where(active[:, None, None],
-                   jax.nn.softplus(jax.random.normal(ks[2], (B, 1, H))), 0.0)
-    a = -jnp.exp(jax.random.normal(ks[3], (1, H)))
-    b = jax.random.normal(ks[4], (B, 1, N))
-    c = jax.random.normal(ks[5], (B, 1, N))
-    d = jax.random.normal(ks[6], (1, H))
-    want_y, want_s = ssm_ops.step_update(
-        whole[1][:, None], x, dt, a, b, c, d)
-    for hb in (4, 8):
-        y, got = ssm_decode.ssm_decode(
-            whole, 1, x, dt, a, b, c, d, active, head_block=hb)
-        keep = np.asarray(active)
-        # (y's sum over the lanes keeps 16 bits of each product: 2^-17 of
-        # products that reach ~30 here; the STATE below is float32's own)
-        np.testing.assert_allclose(y[keep], want_y[keep], rtol=1e-4, atol=3e-4)
+                   jax.nn.softplus(jax.random.normal(ks[2], (B, G, R))), 0.0)
+    a = -jnp.exp(jax.random.normal(ks[3], (G, R)))
+    b = jax.random.normal(ks[4], (B, G, N))
+    c = jax.random.normal(ks[5], (B, G, N))
+    d = jax.random.normal(ks[6], (G, R))
+    want_y, want_s = ssm_ops.step_update(whole[1], x, dt, a, b, c, d)
+    y, got = ssm_decode.ssm_decode(whole, 1, x, dt, a, b, c, d, active)
+    keep = np.asarray(active)
+    assert y.shape == want_y.shape
+    np.testing.assert_allclose(y[keep], want_y[keep], rtol=1e-5, atol=3e-5)
+    np.testing.assert_allclose(
+        got[1][keep], want_s[keep], rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(got[1][~keep], whole[1][~keep])
+    np.testing.assert_array_equal(got[0], whole[0])
+    np.testing.assert_array_equal(got[2], whole[2])
+
+
+@pytest.mark.parametrize("over,platform,mesh_size,want", [
+    ({}, "tpu", 1, True),
+    ({"n_groups": 2}, "tpu", 1, True),        # 32 heads x 64 a group
+    ({"n_groups": 64}, "tpu", 1, False),      # a group of 64 lanes
+    ({"head_dim": 8, "n_heads": 8}, "tpu", 1, False),
+    ({"d_state": 16}, "tpu", 1, False),       # N does not turn in whole tiles
+    ({"state_dtype": "bfloat16"}, "tpu", 1, False),
+    ({"n_heads": 256}, "tpu", 1, False),      # a ring of 128 MiB
+    ({}, "tpu", 2, False),
+    ({}, "cpu", 1, False),
+], ids=["published", "two-groups", "group-under-a-lane-tile", "test-model",
+        "small-state", "16-bit-state", "ring-over-vmem", "mesh", "cpu"])
+def test_ssm_decode_applies_from_what_the_kernel_needs(
+        over, platform, mesh_size, want):
+    """The rule asks the shapes for whole lane tiles a GROUP (not for one
+    group) of which two phases fit VMEM, a float32 state, one TPU
+    device."""
+    import types
+
+    from areal_tpu.ops.pallas import ssm_decode
+
+    ssm = dataclasses.replace(
+        SSMConfig(n_heads=64, head_dim=64, d_state=128, n_groups=1), **over)
+    cfg = types.SimpleNamespace(ssm=ssm)
+    mesh = types.SimpleNamespace(size=mesh_size)
+    assert ssm_decode.ssm_decode_applies(cfg, mesh, platform) is want
+    assert not ssm_decode.ssm_decode_applies(
+        types.SimpleNamespace(ssm=None), None, "tpu")
+
+
+def test_stored_state_is_the_recurrences_channels_minor(params):
+    """What ``mixer_chunk`` leaves (a dense-cache prefill) and ``mixer_step``
+    then updates token by token IS the token-by-token recurrence's state
+    ``[H, P, N]`` with its last two axes exchanged and the heads' channels
+    run together in lane tiles: ``[G, K, N, lanes]``, one layout for both
+    forms."""
+    ids = _toks(12, 29)
+    cache = tfm.KVCache.empty(CFG, 1, 32)
+    s = CFG.ssm
+    assert cache.ssm.ssm.shape == ssm_ops.state_shapes(CFG, 1)[0] == (
+        CFG.n_ssm_layers, 1, s.n_groups, 1, s.d_state, s.n_heads * s.head_dim)
+    published = dataclasses.replace(
+        CFG, ssm=SSMConfig(n_heads=64, head_dim=64, d_state=128))
+    assert ssm_ops.state_shapes(published, 80)[0] == (
+        CFG.n_ssm_layers, 80, 1, 32, 128, 128)
+    _, cache = tfm.prefill(
+        params, CFG, cache, jnp.asarray(ids[None, :21]), jnp.asarray([21]))
+    step = jax.jit(tfm.decode_step, static_argnums=(1,))
+    for t in range(21, 29):
+        _, cache = step(params, CFG, cache, jnp.asarray(ids[t:t + 1]))
+        if t not in (21, 28):
+            continue
+        want = ref.recurrent_state(
+            params, ARCH, ids[:t + 1], "float32", t + 1)      # [Ls, H, P, N]
+        got = np.asarray(cache.ssm.ssm[:, 0]).transpose(0, 1, 2, 4, 3)
         np.testing.assert_allclose(
-            got[1][keep], want_s[keep][:, 0], rtol=1e-6, atol=1e-6)
-        np.testing.assert_array_equal(got[1][~keep], whole[1][~keep])
-        np.testing.assert_array_equal(got[0], whole[0])
-        np.testing.assert_array_equal(got[2], whole[2])
+            got.reshape(want.shape), want, rtol=1e-4, atol=1e-5)
 
 
 # ---- the paged kernels at a head of 64 -------------------------------- #

@@ -257,28 +257,78 @@ def test_int8_page64_turned_away_by_the_gate(compiled_kernels):
         )
 
 
-@pytest.mark.parametrize("head_block", [None, 64])
-def test_ssm_decode_compiles(compiled_kernels, one_chip, head_block):
+@pytest.mark.parametrize("B,G,R,P,N", [
+    pytest.param(80, 1, 64, 64, 128, id="granite-80-slots"),
+    # two groups, the pairs not whole phases of 8, a state of two tiles
+    pytest.param(5, 2, 8, 32, 256, id="two-groups-ragged-phase"),
+])
+def test_ssm_decode_compiles(compiled_kernels, one_chip, B, G, R, P, N):
     """The state update's kernel at granite-4.0-h-micro's sizes and the
-    cell's 80 slots, the state of all 36 layers donated: the result IS the
-    argument (aliased) and the program holds no second state."""
+    cell's 80 slots (phases of 8 rows of ``[32, 128, 128]``: a ring of 32
+    MiB), the state of all 36 layers donated: the result IS the argument
+    (aliased) and the program holds no second state."""
     from areal_tpu.ops.pallas import ssm_decode
 
-    Ls, B, H, P, N = 36, 80, 64, 64, 128
+    Ls, K = 36, R * P // 128
     f32 = lambda *shape: _spec(shape, jnp.float32, one_chip)
-    compiled = jax.jit(
-        functools.partial(ssm_decode.ssm_decode, head_block=head_block),
-        donate_argnums=(0,),
-    ).lower(
-        f32(Ls, B, H, P, N), _spec((), jnp.int32, one_chip), f32(B, 1, H, P),
-        f32(B, 1, H), f32(1, H), f32(B, 1, N), f32(B, 1, N), f32(1, H),
-        _spec((B,), jnp.bool_, one_chip),
+    compiled = jax.jit(ssm_decode.ssm_decode, donate_argnums=(0,)).lower(
+        f32(Ls, B, G, K, N, 128), _spec((), jnp.int32, one_chip),
+        f32(B, G, R, P), f32(B, G, R), f32(G, R), f32(B, G, N), f32(B, G, N),
+        f32(G, R), _spec((B,), jnp.bool_, one_chip),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_decode" in text
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 4 * Ls * B * H * P * N
+    assert mem.alias_size_in_bytes >= 4 * Ls * B * G * K * N * 128
     assert mem.temp_size_in_bytes < 0.05e9
+
+
+@pytest.mark.parametrize("n_rows,width", [(1, 40), (8, 16)])
+def test_granite_admission_re_lays_no_state(
+        compiled_kernels, one_chip, monkeypatch, n_rows, width):
+    """The granite cell's admission program (the chunked scan continuing
+    ``n_rows`` slots' state, all 36 + 4 layers) at 16 slots: it READS the
+    per-slot state, so nothing in it may result in an array of the
+    state's size. With all 4,096 channels as the minor axis the chip's
+    compiler cut the whole state in two to gather a few rows of it, and a
+    one-row gather, which it makes a slice, had it re-lay the whole array
+    out for the scan's matmuls (PERF.md §6 PR 42)."""
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.ops.pallas import ssm_decode
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+        cfg = sut.model_config(json.load(f), {})
+    assert ssm_decode.ssm_decode_applies(cfg, None, "tpu")
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B = 16
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=B, max_seqlen=5120, max_new_tokens_cap=4096,
+        page_size=128, n_pages=80, state_snapshots=2, seed=0)
+    whole = eng.state.ssm.ssm.shape
+    assert whole == (36, B, 1, 32, 128, 128)
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    compiled = eng._extend_fn(n_rows, width, skip_pool=False).lower(
+        jax.tree.map(spec, shapes), jax.tree.map(spec, eng.state),
+        i32(n_rows, eng.admit_chunk), i32(n_rows, width), i32(n_rows),
+        i32(n_rows), i32(n_rows),
+    ).compile()
+    sized = "f32[" + ",".join(map(str, whole)) + "]"
+    made = [ln.strip()[:120] for ln in compiled.as_text().split("\n")
+            if f"= {sized}" in ln and " parameter(" not in ln
+            and " get-tuple-element(" not in ln]
+    assert not made, made
+    # (8 rows' chunk of 128 tokens: 0.55 GB of float32 activations)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
 # the rollout cells' decode epilogue: slots x hidden x vocabulary
