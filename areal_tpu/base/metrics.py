@@ -455,6 +455,14 @@ GEN_CHUNK_FLAG_BLOCKED = "gen/chunk_flag_blocked"
 # fleet aggregator and the gen server's /metrics_json gauges expose.
 GEN_KVQ_PAGES_QUANTIZED = "gen/kvq_pages_quantized"
 GEN_KV_POOL_OCCUPANCY = "gen/kv_pool_occupancy"
+# The engine's page policy (``gen/engine.py``, class docstring): pages
+# slots took while running, slot-chunks the dry rule held out of a chunk,
+# requests it preempted, and the positions their re-admission prefilled
+# again (all sums; all but the first stay 0 while the pool is roomy).
+GEN_PAGES_TAKEN_GROWING = "gen/pages_taken_growing"
+GEN_SLOTS_HELD = "gen/slots_held"
+GEN_PREEMPTIONS = "gen/preemptions"
+GEN_PREEMPTED_TOKENS_RECOMPUTED = "gen/preempted_tokens_recomputed"
 
 # --------------------------------------------------------------------- #
 # Serving-gateway namespace (``gw/``, docs/serving.md): every admission /

@@ -20,15 +20,19 @@ and SGLang's radix/paged KV memory. Redesigned for XLA:
 - LAYER KINDS (``cfg.layer_pattern``: window and full layers in one stack):
   still ONE pool and ONE free list, pages of one byte size (a page holds
   ``page`` tokens of one position of the period in every period), and a
-  slot has one table a position of the period. A full position's pages are
-  all taken at admission, as ever. A window position takes the prompt's
-  pages at admission and RESERVES what it will need later: at most the
-  window, a page and the look-ahead of the chunks in flight. Before every
-  chunk (and every chunk of admission) the pages wholly behind ``len -
-  window`` are released, WHILE the request runs: to the free list, unless
-  the prefix registry or a sibling still holds them, and the pages the
-  chunk will write are taken from the reservation. A model of one kind is
-  the same code with one position a period: no second allocator.
+  slot has one table a position of the period. A model of one kind is the
+  same code with one position a period: no second allocator.
+- PAGES ARE TAKEN AS A SLOT GROWS, in every kind (``GenerationEngine``'s
+  docstring has the whole policy): admission takes the prompt's pages and
+  RESERVES one look-ahead; every chunk takes the pages it writes from the
+  slot's reservation and tops the reservation up for the chunk after; a
+  window kind also gives back, WHILE the request runs, the pages wholly
+  behind ``len - window``. ``PagePool.n_unpromised >= 0`` at all times, so
+  taking inside a dispatched chunk never fails. When the pool runs dry the
+  engine stops admitting, then HOLDS the youngest slots out of a chunk,
+  then PREEMPTS the smallest (its tokens kept, its pages filed in the
+  prefix cache, the request back at the head of the queue): a client sees
+  nothing of either but time.
 - Admission = CHUNKED PREFILL: prompts stream through a fixed
   ``[n_rows, page]`` extend program, so compile count is bounded by the
   admit-row buckets alone — never by prompt length. A chunk is TWO
@@ -243,13 +247,12 @@ def _resolve_kv_dtype(kv_dtype: Optional[str], serving_dtype: str) -> str:
 class _SlotInfo:
     """A running request's slot. Its pages are the entries of its tables
     that ``GenerationEngine._held`` marks (one reference each, whether the
-    slot took the page fresh or borrowed it from the prefix registry)."""
+    slot took the page fresh or borrowed it from the prefix registry); its
+    cap, its end, its place in the order of admission and what it has
+    reserved are the engine's ``_n_total``, ``_n_end``, ``_seq`` and
+    ``_reserved`` (arrays over the slots)."""
 
     rid: str
-    n_total: int              # pages of prompt + whole output, a kind
-    # a kind's pages promised to this slot and not yet taken (0 for a
-    # full kind, whose pages are all taken at admission)
-    reserved: List[int]
     t_submit: Optional[float] = None    # the GenOutput's timestamps
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
@@ -257,6 +260,62 @@ class _SlotInfo:
 
 
 class GenerationEngine:
+    """Continuous batching over a paged KV pool (the module's docstring).
+
+    THE PAGE POLICY, one for every layer kind and every model:
+
+    - *Admission takes the prompt, reserves one look-ahead.* A request
+      gets a slot when the pool can give it its prompt's pages (shared
+      prefix pages borrowed, the rest fresh) and promise it the pages its
+      next chunk can write (``_reserved`` / ``PagePool.reserved``;
+      a window kind: its whole claim, the window and a look-ahead, which
+      is all it ever holds), AND the pool still covers what the running
+      slots and the newcomer will hold at every point of the next
+      ``ADMIT_HORIZON`` positions, each slot growing a position a step up
+      to its ``max_new_tokens`` and freeing its pages there
+      (``_spare_pages``). ``n_total``, prompt + whole output, is a slot's
+      cap, never its holding.
+    - *A chunk takes what it writes* (``_seat``): before a decode chunk
+      every slot takes, from its reservation, the pages up to the last
+      position the chunk can write, and its reservation is topped up for
+      the chunk after. INVARIANT: ``pool.n_unpromised >= 0`` at all times
+      (what is promised is backed by pages that are free or held by the
+      prefix registry alone), so taking inside a dispatched chunk never
+      fails. The takes of one chunk boundary are summed and the registry
+      is asked once, for a batch.
+    - *The dry rule.* When a slot's reservation cannot be made to cover
+      its next chunk (nothing free, nothing held by the registry alone),
+      in this order: (a) nothing new is admitted (the queue waits);
+      (b) the slot is HELD out of the chunk, youngest first: it keeps its
+      pages and its device state and runs again when a finishing slot has
+      freed pages; (c) if every slot would be held, the slot with the
+      fewest positions is PREEMPTED: its tokens and log-probs are pulled
+      to the host, its full pages are filed in the prefix registry and
+      released, and the request goes back to the HEAD of the queue as
+      prompt + generated-so-far. Re-admitted it is an ordinary prefix hit
+      (a re-prefill where its pages were evicted meanwhile, or where the
+      model keeps per-slot recurrent state: no snapshot stands at its last
+      position), and its ``GenOutput`` carries ALL its tokens and
+      log-probs under the original's ``t_submit`` / ``t_admit`` /
+      ``t_first``. Some slot always runs, so every request ends.
+    - *What a client can observe*: nothing but time. No finish reason is
+      new, no output is shorter, ``partial_outputs`` and ``pause`` hand out
+      a held or preempted request's tokens like any other's. A request
+      that could not run even alone in the pool is refused at ``submit``.
+
+    Counters (``stats``, the chunk and admit spans, ``metrics``):
+    ``pages_taken_growing``, ``slots_held`` (slot-chunks held out),
+    ``preemptions``, ``preempted_tokens_recomputed``; ``slots_running`` on
+    every chunk span."""
+
+    # Positions ahead over which admission checks that the pool covers the
+    # running slots' growth (``_spare_pages``). Fitted on the Ouro cell's
+    # traffic at pools of 0.35-1 x its own (PERF.md section 6, PR 43: twice
+    # the shortest that held no slot): long enough that the dry rule stays
+    # rare, short enough that a request whose ``max_new_tokens`` is a
+    # far-off cap is not counted at its cap.
+    ADMIT_HORIZON = 512
+
     # Adaptive spec-K policy (AREAL_SPEC_K_ADAPT): retune after WINDOW
     # accept-length observations; step K up when the windowed mean accept
     # length clears UP * K (drafts are nearly free), down when it falls
@@ -524,6 +583,8 @@ class GenerationEngine:
             # period (None: full attention); one page table a position
             self._windows = [w for w, _ in cfg.layer_kinds]
             self._windowed = any(w is not None for w in self._windows)
+            self._full_kinds = np.array([w is None for w in self._windows])
+            self._n_full = int(self._full_kinds.sum())
             K = len(self._windows)
             if self._draft is not None and (
                 K > 1 or self.draft_cfg.period > 1
@@ -555,10 +616,10 @@ class GenerationEngine:
             )
             # positions a dispatch may run ahead of the host's lengths: the
             # chunk's tokens and, in pipelined mode, the chunk still in flight,
-            # at ``step``'s default of 16 decode steps (a longer chunk takes
-            # what it needs beyond that from the pool at large, or is refused:
-            # ``_roll_windows``). Admission needs none: a prompt's pages are
-            # all taken before its chunks run
+            # at ``step``'s default of 16 decode steps: what admission
+            # reserves for a slot's first chunk and a window kind's claim is
+            # sized by (a longer chunk takes what it needs beyond that from
+            # the pool at large, or its slot is held: ``_seat``)
             self._lookahead = 2 * 16 * (
                 (spec_k or constants.spec_k()) + 1 if spec_on else 1)
             # the most pages a slot holds in a window kind at once: positions
@@ -654,6 +715,10 @@ class GenerationEngine:
                     self._state_sh = sh
                     # arealint: ok(one-time engine-state materialization at construction)
                     self.state = jax.jit(make_state, out_shardings=sh)()
+                # the dry rule's one device program, built and run here (a
+                # no-op) so that holding a slot compiles nothing later
+                self._jit_activity = self._activity_fn()
+                self._set_activity(build=True)
             self.accepting = True  # False = decode only, no new admissions
             self.paused = False
             self._slots: List[Optional[_SlotInfo]] = [None] * self.B
@@ -666,6 +731,22 @@ class GenerationEngine:
             self._held = np.zeros((K, self.B, self.M), bool)
             self._win_lo = np.zeros((K, self.B), np.int64)
             self._win_hi = np.zeros((K, self.B), np.int64)
+            # of each slot: its CAP in pages a kind (prompt + whole output:
+            # never its holding), the positions it holds when its output is
+            # whole, and a kind's pages promised to it and not yet taken (a
+            # full kind's next chunk, a window kind's claim)
+            self._n_total = np.zeros((self.B,), np.int64)   # 0: a free slot
+            self._n_end = np.zeros((self.B,), np.int64)
+            self._seq = np.zeros((self.B,), np.int64)   # order of admission
+            self._reserved = np.zeros((K, self.B), np.int64)
+            # slots held out of the chunks by the dry rule (inactive on the
+            # device meanwhile), the admission count that orders the slots,
+            # and what a preempted request had generated before it lost its
+            # slot, by rid: prepended to what its later tenures generate
+            self._held_out: set = set()
+            self._admit_seq = 0
+            self._room: Optional[int] = None   # ``kv_pool_demand_occupancy``
+            self._carried: Dict[str, dict] = {}
             # window kinds' pages that more than one slot holds, counted once
             # for every holder past the first: each is a page promised
             # (``pool.reserved``) to whichever holder gives the shared page up
@@ -821,6 +902,14 @@ class GenerationEngine:
                 "state_snapshot_hits": 0,
                 "state_snapshot_bytes": 0,
                 "state_snapshot_evictions": 0,
+                # the page policy (class docstring): pages slots took while
+                # running (every kind, decode chunks and admission's), slot-
+                # chunks the dry rule held out, requests it preempted, and
+                # the positions their re-admission prefilled again
+                "pages_taken_growing": 0,
+                "slots_held": 0,
+                "preemptions": 0,
+                "preempted_tokens_recomputed": 0,
             }
             start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
@@ -841,6 +930,17 @@ class GenerationEngine:
                     f"prompt {len(req.input_ids)} + max_new "
                     f"{req.max_new_tokens} exceeds per-slot capacity {self.S}"
                 )
+            # the dry rule's last resort is one slot alone in the pool
+            n_total = -(-need // self.page)
+            alone = sum(
+                n_total if claim is None else min(n_total, claim)
+                for claim in self._window_claim)
+            if alone > self.n_pages:
+                raise ValueError(
+                    f"prompt {len(req.input_ids)} + max_new "
+                    f"{req.max_new_tokens} needs {alone} pages, the pool "
+                    f"has {self.n_pages}: it could not run even alone"
+                )
             req.t_submit = time.perf_counter()
             with self._pending_lock:
                 self._pending.append(req)
@@ -858,7 +958,9 @@ class GenerationEngine:
 
     def n_compiles(self) -> int:
         """Total jitted specializations (stability tested: bounded by the
-        admit buckets + decode/spec chunk sizes, NOT by prompt lengths)."""
+        admit buckets + decode/spec chunk sizes, NOT by prompt lengths;
+        the dry rule's one program, built with the engine, is not among
+        them)."""
         return (
             len(self._jit_extend) + len(self._jit_kv_write)
             + len(self._jit_commit) + len(self._jit_state)
@@ -874,7 +976,8 @@ class GenerationEngine:
         return jitcache.total_cache_size(
             j
             for d in (self._jit_extend, self._jit_kv_write, self._jit_commit,
-                      self._jit_state, self._jit_chunk, self._jit_spec)
+                      self._jit_state, self._jit_chunk, self._jit_spec,
+                      {(): self._jit_activity})
             for j in d.values()
         )
 
@@ -891,7 +994,8 @@ class GenerationEngine:
                             ("kv_write", self._jit_kv_write),
                             ("commit", self._jit_commit),
                             ("state", self._jit_state),
-                            ("chunk", self._jit_chunk))
+                            ("chunk", self._jit_chunk),
+                            ("activity", {(): self._jit_activity}))
             for key, fn in d.items()
         }
 
@@ -948,24 +1052,35 @@ class GenerationEngine:
 
     def kv_pool_occupancy(self) -> float:
         """Fraction of pool pages currently held (slots + prefix cache) or
-        promised to a running slot (a window kind's later pages, counted
-        against the free ones; what the registry alone holds backs a
-        promise too and is held either way)."""
+        promised to a running slot (its next chunk's pages, a window
+        kind's claim; counted against the free ones; what the registry
+        alone holds backs a promise too and is held either way). Pages
+        are taken as slots grow, so this is what the slots have WRITTEN
+        and a look-ahead, not what their outputs may come to."""
         free = max(self.pool.n_free - self.pool.reserved, 0)
         return 1.0 - free / max(self.n_pages, 1)
 
     def kv_pool_demand_occupancy(self) -> float:
-        """Occupancy excluding prefix-cache-only pages (instantly
-        evictable under pressure) — the ADMISSION signal external gates
-        (the serving gateway) should use: raw occupancy counts cache the
-        next admission would evict, so a cache-warm idle server would
-        read as permanently full."""
-        return 1.0 - max(self.pool.n_unpromised, 0) / max(self.n_pages, 1)
+        """One less the share of the pool that the NEXT ADMISSION can
+        have — the signal external gates (the serving gateway) should use.
+        Pages the prefix cache alone holds count as free (the next
+        admission would evict them: a cache-warm idle server must not read
+        as full); pages promised to running slots do not; and neither do
+        the pages the running slots will grow into over the next
+        ``ADMIT_HORIZON`` positions, less what those that end there give
+        back (``_spare_pages``, as of the last chunk boundary): the engine
+        itself admits against that, so a server whose slots are young and
+        growing does not read as empty."""
+        room = self.pool.n_unpromised
+        if self._room is not None and self.n_running():
+            room = min(room, self._room)
+        return 1.0 - max(room, 0) / max(self.n_pages, 1)
 
     def _observe_occupancy(self):
         """Fold the current pool occupancy into the telemetry histogram —
         host arithmetic riding a chunk dispatch the engine already pays."""
         occ = self.kv_pool_occupancy()
+        self._room = int(self._spare_pages().min())
         metrics_mod.counters.observe(metrics_mod.GEN_KV_POOL_OCCUPANCY, occ)
         if self._draft is not None:
             # lockstep pools: the draft pool's occupancy IS the target
@@ -1060,7 +1175,9 @@ class GenerationEngine:
 
         ONE device pull serves every requested slot (same batching rule as
         ``_harvest``). Callers off the event loop only: the pull blocks on
-        any in-flight chunk."""
+        any in-flight chunk. A request the dry rule preempted reads as it
+        would have: what it generated before is kept on the host, whether
+        it waits for a slot or runs in one again."""
         with self._lock:
             wanted = None if rids is None else set(rids)
             sel = [
@@ -1068,15 +1185,20 @@ class GenerationEngine:
                 for b, s in enumerate(self._slots)
                 if s is not None and (wanted is None or s.rid in wanted)
             ]
+            out: Dict[str, Tuple[List[int], List[float]]] = {
+                rid: (list(c["tokens"]), list(c["logprobs"]))
+                for rid, c in self._carried.items()
+                if wanted is None or rid in wanted
+            }
             if not sel:
-                return {}
+                return out
             host = self._pull_outputs()
-            out: Dict[str, Tuple[List[int], List[float]]] = {}
             for b, rid in sel:
                 n = int(host["n_gen"][b])
+                toks, lps = out.get(rid, ([], []))
                 out[rid] = (
-                    host["out_tokens"][b, :n].tolist(),
-                    host["out_logprobs"][b, :n].tolist(),
+                    toks + host["out_tokens"][b, :n].tolist(),
+                    lps + host["out_logprobs"][b, :n].tolist(),
                 )
             return out
 
@@ -1118,11 +1240,14 @@ class GenerationEngine:
                 if r.rid == rid:
                     del self._pending[i]
                     self._req_meta.pop(rid, None)
+                    # (a preempted request waits here with its tokens)
+                    self._carried.pop(rid, None)
                     return True
         with self._lock:
             for b, s in enumerate(self._slots):
                 if s is not None and s.rid == rid:
                     self._free_slot(b)
+                    self._carried.pop(rid, None)
                     with self._pending_lock:
                         self._req_meta.pop(rid, None)
                     # deactivate on device so later chunks stop feeding the
@@ -1136,26 +1261,31 @@ class GenerationEngine:
         return False
 
     def pause(self) -> List[GenOutput]:
-        """Stop generating and harvest all running slots as interrupted."""
+        """Stop generating and harvest all running slots as interrupted:
+        held slots among them, and the requests the dry rule preempted
+        that wait for a slot again (each with everything it generated;
+        they leave the queue, as a running request leaves its slot)."""
         with self._lock:
             self.paused = True
             self._prev_flags, self._prev_running = None, ()
             self._steps_ahead = 0
-            if not any(s is not None for s in self._slots):
-                return []
-            # ONE device pull for every slot (a per-slot fetch is one
-            # blocking device->host sync each)
-            host_state = self._pull_outputs(
-                [b for b, s in enumerate(self._slots) if s is not None]
-            )
             outs = []
-            for b, s in enumerate(self._slots):
-                if s is not None:
+            if any(s is not None for s in self._slots):
+                # ONE device pull for every slot (a per-slot fetch is one
+                # blocking device->host sync each)
+                host_state = self._pull_outputs(
+                    [b for b, s in enumerate(self._slots) if s is not None]
+                )
+                for b, s in enumerate(self._slots):
+                    if s is None:
+                        continue
                     # pipelined mode can hold finished-but-unharvested
                     # slots; they must NOT be reported interrupted (the
-                    # client would pointlessly resubmit a complete sample)
+                    # client would pointlessly resubmit a complete sample).
+                    # A held slot is inactive on the device and not done
                     reason = (
-                        "interrupted" if host_state["active"][b]
+                        "interrupted"
+                        if host_state["active"][b] or b in self._held_out
                         else _finish_reason(
                             host_state["n_gen"][b], host_state["max_gen"][b]
                         )
@@ -1163,14 +1293,36 @@ class GenerationEngine:
                     outs.append(
                         self._harvest(b, reason, host_state=host_state)
                     )
-            # ONE batched deactivation (the harvested slots were still
-            # active on device; a per-slot .at[b].set dispatch costs a
-            # round trip each)
-            self.state = dataclasses.replace(
-                self.state,
-                active=jnp.zeros_like(self.state.active),
-                lens=jnp.zeros_like(self.state.lens),
-            )
+                # ONE batched deactivation (the harvested slots were still
+                # active on device; a per-slot .at[b].set dispatch costs a
+                # round trip each)
+                self.state = dataclasses.replace(
+                    self.state,
+                    active=jnp.zeros_like(self.state.active),
+                    lens=jnp.zeros_like(self.state.lens),
+                )
+            if self._carried:
+                # what is left there now waits preempted in the queue
+                with self._pending_lock:
+                    waiting = [
+                        r for r in self._pending if r.rid in self._carried]
+                    self._pending[:] = [
+                        r for r in self._pending
+                        if r.rid not in self._carried]
+                    for r in waiting:
+                        self._req_meta.pop(r.rid, None)
+                now = time.perf_counter()
+                for r in waiting:
+                    c = self._carried.pop(r.rid)
+                    outs.append(GenOutput(
+                        rid=r.rid, output_ids=c["tokens"],
+                        output_logprobs=c["logprobs"],
+                        finish_reason="interrupted", version=self.version,
+                        t_submit=r.t_submit, t_admit=c["t_admit"],
+                        t_first=c["t_first"] or now, t_done=now,
+                        output_routing=c["routing"],
+                        prefix_hit_tokens=c["prefix_hit_tokens"],
+                    ))
             return outs
 
     def resume(self):
@@ -1186,12 +1338,15 @@ class GenerationEngine:
         reference through, and what it had reserved and not taken."""
         info = self._slots[b]
         self._slots[b] = None
+        self._held_out.discard(b)
         held = self._held[:, b]
         for j, w in enumerate(self._windows):
             if w is not None:
                 self._give_up(j, self._tables_host[j, b][held[j]])
         self.pool.release(self._tables_host[:, b][held].tolist())
-        self.pool.reserved -= sum(info.reserved)
+        self.pool.reserved -= int(self._reserved[:, b].sum())
+        self._reserved[:, b] = 0
+        self._n_total[b] = self._n_end[b] = 0
         held[:] = False
         self._tables_host[:, b] = 0
         self._lens_host[b] = 0
@@ -1216,77 +1371,330 @@ class GenerationEngine:
         never more than the pages it has not reached yet. (A prompt
         longer than that is held whole for the length of its admission;
         it wants nothing until its chunks have given enough back.)"""
-        info = self._slots[b]
+        n_total = int(self._n_total[b])
         lo = int(self._win_lo[j, b])
-        want = min(self._window_claim[j], info.n_total - lo) - int(
+        want = min(self._window_claim[j], n_total - lo) - int(
             self._held[j, b, lo:].sum())
-        return max(min(want, info.n_total - int(self._win_hi[j, b])), 0)
+        return max(min(want, n_total - int(self._win_hi[j, b])), 0)
 
-    def _roll_windows(self, b: int, n_lo: int, n_hi: int) -> int:
-        """Slot ``b`` is about to run positions ``[n_lo, n_hi)`` (a decode
-        chunk, a chunk of admission): in every window kind, give up the
-        pages that lie wholly behind ``n_lo + 1 - window`` (no later query
-        sees them; to the free list, unless the registry or a sibling
-        still holds them) and take, from the slot's reservation, the pages
-        up to ``n_hi``. Returns the pages released.
+    def _release_behind(self, b: int, n_lo: int) -> int:
+        """Slot ``b`` stands at position ``n_lo``: in every window kind,
+        give up the pages that lie wholly behind ``n_lo + 1 - window`` (no
+        later query sees them; to the free list, unless the registry or a
+        sibling still holds them) and re-size the kind's reservation to
+        what the slot may still have to take (``_window_want``). Returns
+        the pages released.
 
-        Why taking never fails: ``pool.reserved`` (the slots' ``reserved``
-        and the deposits) is at all times backed by pages that are free or
-        held by the registry alone (``PagePool.n_unpromised >= 0``).
-        Admission checks it. A released page that nobody else holds goes
-        free (or stays with the registry alone), which backs the page the
-        window needs at its other end; one that a sibling still reads was
-        paid for by that sibling's deposit (``_give_up``)."""
-        info = self._slots[b]
-        page = self.page
+        The invariant survives: a released page that nobody else holds
+        goes free (or stays with the registry alone), which backs the page
+        the window needs at its other end; one that a sibling still reads
+        was paid for by that sibling's deposit (``_give_up``)."""
         released = 0
         for j, w in enumerate(self._windows):
             if w is None:
                 continue
-            lo = max(n_lo + 1 - w, 0) // page
-            if lo > self._win_lo[j, b]:
-                idx = np.arange(self._win_lo[j, b], lo)
-                idx = idx[self._held[j, b, idx]]
-                self._give_up(j, self._tables_host[j, b, idx])
-                self.pool.release(self._tables_host[j, b, idx].tolist())
-                if self.enable_prefix_cache:
-                    self.prefix.note_given_up(self._tables_host[j, b, idx])
-                self._held[j, b, idx] = False
-                self._tables_host[j, b, idx] = 0
-                self._win_lo[j, b] = lo
-                released += len(idx)
-                want = self._window_want(b, j)
-                self.pool.reserved += want - info.reserved[j]
-                info.reserved[j] = want
-            hi = min(-(-n_hi // page), info.n_total)
-            take = hi - int(self._win_hi[j, b])
-            if take > 0:
-                if take > info.reserved[j]:
-                    # a chunk longer than the look-ahead the slot reserved
-                    # for (``step(decode_steps)`` is the caller's): the
-                    # pool backs the difference, or the call is refused
-                    extra = take - info.reserved[j]
-                    if self.pool.n_unpromised < extra:
-                        raise RuntimeError(
-                            f"slot {b} runs {n_hi - n_lo} positions ahead, "
-                            f"past what a window kind reserves "
-                            f"({self._lookahead}), and the pool has no page "
-                            "to spare"
-                        )
-                    info.reserved[j] += extra
-                    self.pool.reserved += extra
-                if self.pool.n_free < take:
-                    # (a walk of the whole tree once the queue of given-up
-                    # pages is empty: ask for a batch, not a page)
-                    self.prefix.evict_lru(max(take, 64))
-                at = slice(int(self._win_hi[j, b]), hi)
-                self._tables_host[j, b, at] = self.pool.alloc(take)
-                self._held[j, b, at] = True
-                self._win_hi[j, b] = hi
-                info.reserved[j] -= take
-                self.pool.reserved -= take
+            lo = max(n_lo + 1 - w, 0) // self.page
+            if lo <= self._win_lo[j, b]:
+                continue
+            idx = np.arange(self._win_lo[j, b], lo)
+            idx = idx[self._held[j, b, idx]]
+            self._give_up(j, self._tables_host[j, b, idx])
+            self.pool.release(self._tables_host[j, b, idx].tolist())
+            if self.enable_prefix_cache:
+                self.prefix.note_given_up(self._tables_host[j, b, idx])
+            self._held[j, b, idx] = False
+            self._tables_host[j, b, idx] = 0
+            self._win_lo[j, b] = lo
+            released += len(idx)
+            want = self._window_want(b, j)
+            self.pool.reserved += want - int(self._reserved[j, b])
+            self._reserved[j, b] = want
         self.stats["window_pages_released"] += released
         return released
+
+    def _pages_ahead(self, slots: Sequence[int], span: int) -> np.ndarray:
+        """``[kinds, len(slots)]``: the pages a kind that each of ``slots``
+        has yet to take before it can write ``span`` positions on from
+        where the host knows it to stand (never past its cap)."""
+        hi = np.minimum(
+            -(-(self._lens_host[slots] + span) // self.page),
+            self._n_total[slots])
+        return np.maximum(hi[None, :] - self._win_hi[:, slots], 0)
+
+    def _promise(self, slots: Sequence[int], pages: np.ndarray) -> List[int]:
+        """Raise the reservations of ``slots`` to cover ``pages [kinds,
+        len(slots)]``, the difference out of the unpromised pages, the
+        first of ``slots`` first. Returns the slots for which the pool has
+        not got it: theirs stay as they were."""
+        extra = np.maximum(pages - self._reserved[:, slots], 0)
+        each = extra.sum(axis=0)
+        refused = []
+        left = self.pool.n_unpromised
+        if each.sum() > left:
+            for i in np.nonzero(each)[0]:
+                if each[i] <= left:
+                    left -= each[i]
+                else:
+                    refused.append(int(slots[i]))
+                    extra[:, i] = 0
+        self._reserved[:, slots] += extra
+        self.pool.reserved += int(extra.sum())
+        return refused
+
+    def _take(self, slots: Sequence[int], pages: np.ndarray) -> int:
+        """Each of ``slots`` takes ``pages [kinds, len(slots)]`` out of its
+        reservation (``_promise`` has seen to it that they are there).
+        Never fails: what is reserved is backed by pages that are free or
+        held by the registry alone (``PagePool.n_unpromised >= 0``), and
+        the registry is asked for them here, once, for a batch (a walk of
+        the whole tree once the queue of given-up pages is empty). Returns
+        the pages taken."""
+        n_taken = int(pages.sum())
+        if n_taken == 0:
+            return 0
+        self._make_free(n_taken)
+        # every (kind, slot) that takes, once a page it takes: the entries
+        # of its table from where it stands
+        kinds, at = np.nonzero(pages)
+        n = pages[kinds, at]
+        first = np.repeat(np.cumsum(n) - n, n)
+        kinds, of = np.repeat(kinds, n), np.repeat(np.asarray(slots)[at], n)
+        entries = self._win_hi[kinds, of] + np.arange(n_taken) - first
+        self._tables_host[kinds, of, entries] = self.pool.alloc(n_taken)
+        self._held[kinds, of, entries] = True
+        self._win_hi[:, slots] += pages
+        self._reserved[:, slots] -= pages
+        self.pool.reserved -= n_taken
+        self.stats["pages_taken_growing"] += n_taken
+        metrics_mod.counters.add(metrics_mod.GEN_PAGES_TAKEN_GROWING, n_taken)
+        if self.kv_quantized:
+            # these pages' KV lands int8 at the post-scan scatter
+            metrics_mod.counters.add(
+                metrics_mod.GEN_KVQ_PAGES_QUANTIZED, n_taken)
+        return n_taken
+
+    def _make_free(self, n: int) -> None:
+        """See to it that ``n`` pages are on the free list, at the
+        registry's cost (a walk of the whole tree once the queue of
+        given-up pages is empty: ask for a batch, not a page)."""
+        if self.pool.n_free < n:
+            self.prefix.evict_lru(max(n, 64))
+
+    def _roll_windows(self, b: int, n_lo: int, n_hi: int) -> int:
+        """A chunk of slot ``b``'s ADMISSION is about to write positions
+        ``[n_lo, n_hi)``: window kinds give up what lies behind ``n_lo``
+        and take, from their reservation, the pages up to ``n_hi`` (a full
+        kind holds its prompt's pages since it was admitted). Returns the
+        pages released."""
+        released = self._release_behind(b, n_lo)
+        take = self._pages_ahead([b], n_hi - int(self._lens_host[b]))
+        if self._promise([b], take):
+            raise RuntimeError(
+                f"slot {b}: a chunk of admission runs {n_hi - n_lo} "
+                f"positions, past what a window kind reserves "
+                f"({self._lookahead}), and the pool has no page to spare")
+        self._take([b], take)
+        return released
+
+    # ------------------------------------------------------------------ #
+    # Who runs the next chunk: growth, and the dry rule
+    # ------------------------------------------------------------------ #
+
+    def _seat(self, span: int, chunk_attrs: dict) -> List[int]:
+        """The slots that run the next chunk, which can write ``span``
+        positions past the host's lengths, each with the pages it writes
+        TAKEN; the rest are held out of it (class docstring, the dry
+        rule). Oldest first: a slot whose reservation, raised out of the
+        unpromised pages if need be, covers its part of the chunk runs; a
+        slot for which the pool has not got that is held, the youngest
+        therefore first; when every slot would be held, the one with the
+        fewest positions is preempted and the rest try again. Then the
+        reservations of the running slots are topped up for the chunk
+        after, as far as the pool goes. One walk of the prefix tree at
+        most (the takes are summed), one device call at most (held, woken
+        and preempted slots change their ``active`` together)."""
+        occupied = np.nonzero(self._n_total)[0]
+        released = sum(
+            self._release_behind(b, int(self._lens_host[b]))
+            for b in occupied) if self._windowed else 0
+        occupied = occupied[np.argsort(self._seq[occupied])]
+        preempted: List[int] = []
+        while True:
+            take = self._pages_ahead(occupied, span)
+            held = self._promise(occupied, take)
+            if len(held) < len(occupied) or not held:
+                break
+            if len(held) == 1:
+                raise RuntimeError(
+                    f"slot {held[0]} alone cannot take the pages of a chunk "
+                    f"of {span} positions: the pool ({self.n_pages} pages) "
+                    "is too small for it")
+            # every slot would be held: the smallest gives way
+            b = min(held, key=lambda b: self._lens_host[b])
+            self._preempt(b)
+            occupied = occupied[occupied != b]
+            preempted.append(b)
+        if held:
+            runs = ~np.isin(occupied, held)
+            occupied, take = occupied[runs], take[:, runs]
+        running = occupied.tolist()
+        n_taken = self._take(running, take)
+        # the chunk after this one writes at most ``span`` further on (a
+        # slot for which the pool has not got that asks again, out of what
+        # is free then, when that chunk is seated)
+        self._promise(
+            running,
+            self._pages_ahead(running, 2 * span) * self._full_kinds[:, None])
+        was = self._held_out
+        self._held_out = set(held)
+        self._set_activity(
+            off=(self._held_out - was) | set(preempted),
+            on=was - self._held_out, drop=preempted)
+        if held:
+            self.stats["slots_held"] += len(held)
+            metrics_mod.counters.add(metrics_mod.GEN_SLOTS_HELD, len(held))
+        chunk_attrs.update(
+            slots_running=len(running), slots_held=len(held),
+            preemptions=len(preempted), pages_taken_growing=n_taken)
+        if self._windowed:
+            chunk_attrs["window_pages_released"] = released
+        return sorted(running)
+
+    def _preempt(self, b: int) -> None:
+        """Slot ``b`` gives way (the dry rule's last step): what it has
+        generated is pulled to the host and kept under its rid, its full
+        pages are filed in the prefix registry (so that its re-admission
+        is a prefix hit while they last; not for a model with per-slot
+        recurrent state, where no snapshot stands at its last position
+        and pages alone are no hit) and released, and the request goes
+        back to the HEAD of the queue as prompt + generated-so-far with
+        what is left of its ``max_new_tokens``. The caller deactivates the
+        slot on the device."""
+        info = self._slots[b]
+        host = self._pull_outputs([b])
+        n = int(host["n_gen"][b])
+        with self._pending_lock:
+            req = self._req_meta[info.rid]
+        toks = host["out_tokens"][b, :n].tolist()
+        ids = list(req.input_ids) + toks
+        c = self._carried.setdefault(info.rid, {
+            "tokens": [], "logprobs": [], "routing": None,
+            "t_admit": info.t_admit, "t_first": None,
+            "prefix_hit_tokens": info.prefix_hit_tokens,
+        })
+        c["tokens"] += toks
+        c["logprobs"] += host["out_logprobs"][b, :n].tolist()
+        c["t_first"] = c["t_first"] or info.t_first
+        routing = host["out_routing"].get(b)
+        if routing is not None:
+            c["routing"] = routing[:n] if c["routing"] is None else (
+                np.concatenate([c["routing"], routing[:n]]))
+        n_full = (len(ids) - 1) // self.page      # pages wholly written
+        if self.enable_prefix_cache and not self._stateful and n_full:
+            self.prefix.insert(ids, self._registry_pages(b, n_full))
+        self._free_slot(b)
+        again = dataclasses.replace(
+            req, input_ids=ids,
+            max_new_tokens=int(host["max_gen"][b]) - n,
+            min_new_tokens=max(req.min_new_tokens - n, 0),
+        )
+        with self._pending_lock:
+            self._pending.insert(0, again)
+            self._req_meta[info.rid] = again
+        self.stats["preemptions"] += 1
+        metrics_mod.counters.add(metrics_mod.GEN_PREEMPTIONS)
+
+    def _activity_fn(self):
+        """``(state, off [B], on [B], drop [B])`` -> state, donated: the
+        slots of ``off`` stop being fed by the chunks (held, preempted),
+        those of ``on`` are fed again (a held slot's ``active`` was true
+        when it was held and nothing ran it since), those of ``drop``
+        (preempted) also read as empty."""
+
+        def activity(state: GenState, off, on, drop):
+            return dataclasses.replace(
+                state,
+                active=(state.active & ~off) | on,
+                lens=jnp.where(drop, 0, state.lens),
+            )
+
+        return jax.jit(
+            activity, donate_argnums=(0,),
+            **self._jit_sharding(3, with_params=False),
+        )
+
+    def _set_activity(self, off=(), on=(), drop=(), build=False) -> None:
+        masks = np.zeros((3, self.B), bool)
+        for m, slots in zip(masks, (off, on, drop)):
+            m[list(slots)] = True
+        if masks.any() or build:
+            self.state = self._jit_activity(
+                self.state, *(jnp.asarray(m) for m in masks))
+
+    @property
+    def _horizon(self) -> np.ndarray:
+        """Admission's look into the pool's future, in positions from now."""
+        return np.arange(
+            0, self.ADMIT_HORIZON + 1, max(self.page // 4, 1), dtype=np.int64)
+
+    def _spare_pages(self) -> np.ndarray:
+        """Pages the pool has to spare at each point of admission's
+        horizon (0, a quarter page, ... ``ADMIT_HORIZON`` positions from
+        now) if nobody new is admitted: what is unpromised now, and what
+        the slots make of it (``_slots_over_horizon``)."""
+        return self.pool.n_unpromised + self._slots_over_horizon()
+
+    def _slots_over_horizon(self, unless_spare: Optional[int] = None):
+        """What the slots add to the pool's spare pages at each point of
+        the horizon (mostly negative): less what each will have taken by
+        then in its full kinds (a position a step and a look-ahead, up to
+        its cap; what it has reserved is part of that and unpromised no
+        more, so it is added back), plus the pages that the slots which
+        have ended by then hold alone. Window kinds hold their claims
+        throughout (they are promised already). A slot that ends early
+        only leaves more.
+
+        ``unless_spare`` (admission's short cut for a roomy pool): where
+        the unpromised pages cover that many beside the MOST the slots can
+        take over the horizon, a page a page of positions each, that bound
+        is returned in the exact sum's place."""
+        occupied = np.nonzero(self._n_total)[0]
+        if not len(occupied) or not self._n_full:
+            return np.zeros(self._horizon.shape, np.int64)
+        if unless_spare is not None:
+            most = len(occupied) * self._n_full * (
+                (self.ADMIT_HORIZON + self._lookahead) // self.page + 1)
+            if self.pool.n_unpromised - most >= unless_spare:
+                return np.full(self._horizon.shape, -most, np.int64)
+        full = int(np.argmax(self._full_kinds))
+        need, alive = self._growth(
+            self._lens_host[occupied] + self._steps_ahead,
+            self._n_end[occupied], self._n_total[occupied],
+            self._win_hi[full, occupied])
+        # pages each slot holds alone (the registry aside): free, or the
+        # registry's alone, once it has ended
+        alone = self.pool.n_slot_holders(self._tables_host[self._held]) == 1
+        slots = np.repeat(
+            np.tile(np.arange(self.B), len(self._windows)),
+            self._held.sum(axis=2).ravel())
+        alone = np.bincount(slots[alone], minlength=self.B)[occupied]
+        return (
+            int(self._reserved[full, occupied].sum()) * self._n_full
+            - need.sum(0) + (~alive * alone[:, None]).sum(0))
+
+    def _growth(self, n, n_end, n_total, hi):
+        """``[slots, horizon]``: the pages, over all full kinds, that
+        slots at positions ``n`` holding ``hi`` pages a kind (cap
+        ``n_total``, whole at ``n_end`` positions) have yet to take at
+        each point of the horizon, 0 from where they have ended (one
+        point of grace); and where they still run."""
+        grid = self._horizon
+        at = np.asarray(n)[:, None] + grid[None, :]
+        alive = at < np.asarray(n_end)[:, None] + grid[min(1, len(grid) - 1)]
+        cover = np.minimum(
+            -(-(at + self._lookahead) // self.page),
+            np.asarray(n_total)[:, None]) - np.asarray(hi)[:, None]
+        return np.where(alive, np.maximum(cover, 0), 0) * self._n_full, alive
 
     def _registry_pages(self, slot: int, n: int) -> List:
         """The slot's first ``n`` table entries as the prefix registry
@@ -1404,7 +1812,12 @@ class GenerationEngine:
     def _chunk_moe_rows(self) -> int:
         """Rows a decode chunk's step hands the routed experts: the batch,
         times a speculative chunk's verified positions."""
-        return self.B * ((self.spec_k + 1) if self.spec else 1)
+        return self.B * self._chunk_tokens(1)
+
+    def _chunk_tokens(self, decode_steps: int) -> int:
+        """Positions a slot can advance (and write) in a chunk of
+        ``decode_steps``: a speculative step verifies ``spec_k + 1``."""
+        return decode_steps * ((self.spec_k + 1) if self.spec else 1)
 
     def _count_moe_rows(self, rows: int, runs: int) -> Dict[str, int]:
         """``rows x expert layers x runs`` (``runs``: decode steps x passes,
@@ -1550,6 +1963,12 @@ class GenerationEngine:
         if not rows:
             return
         C = self.admit_chunk
+        # a wave runs as many chunks as its LONGEST row has: rows of like
+        # length share a wave (the rows of one call never read each other's
+        # pages, so their order is free; an opening population of contexts
+        # from 128 to 2,560 tokens ran 190 chunk programs in arrival order
+        # and runs 120 so: PERF.md section 6, PR 43)
+        rows = sorted(rows, key=lambda r: len(r["tokens"]))
         i = 0
         while i < len(rows):
             n = self._row_bucket(len(rows) - i)
@@ -1746,6 +2165,7 @@ class GenerationEngine:
                 st["moe_grouped_rows"], st["moe_dense_rows"],
                 st["state_snapshots_taken"], st["state_snapshot_hits"],
                 st["state_snapshot_bytes"], st["state_snapshot_evictions"],
+                st["preempted_tokens_recomputed"],
             )
             self._admit_pending()
             attrs.update(
@@ -1753,6 +2173,10 @@ class GenerationEngine:
                 prefill_tokens=st["prefill_tokens"] - before[1],
                 prefix_hit_tokens=st["prefix_hit_tokens"] - before[2],
                 pending_left=self.n_pending(),
+                # of the prefilled tokens, those a preempted request had
+                # computed once already
+                preempted_tokens_recomputed=(
+                    st["preempted_tokens_recomputed"] - before[11]),
             )
             if self._kv_write_rows():
                 # tiles the wave's prefill wrote through the kernel
@@ -1789,6 +2213,7 @@ class GenerationEngine:
         hits: List[dict] = []
         deferred_inserts: List[Tuple[List[int], int, int, Optional[int]]] = []
         still_pending: List[GenRequest] = []
+        over_horizon = None     # ``_slots_over_horizon``, kept current
         with self._pending_lock:
             take = self._pending[: len(free) + 8]  # small lookahead
             del self._pending[: len(take)]
@@ -1804,27 +2229,35 @@ class GenerationEngine:
                 shared = self.prefix.lookup(ids, n_shared_full) or []
             # (recurrent state: the hit ends at a node with a snapshot)
             snap_from = self.prefix.hit_snapshot if shared else None
-            # a kind's pages taken now: all of a full kind's, as ever; a
-            # window kind's up to the end of the prompt or to its claim,
-            # whichever is less (the rest is reserved and taken as the
-            # prompt's chunks and then the output move on,
-            # ``_roll_windows``: a prompt longer than the window never
-            # holds more than the window's worth)
+            # a kind's pages taken now: the prompt's, and of a window kind
+            # no more than its claim (a prompt longer than the window never
+            # holds more than the window's worth). The rest is taken as the
+            # prompt's chunks and then the output move on (``_roll_windows``,
+            # ``_seat``); ``n_total`` is the slot's cap, not its holding
             n_prompt = -(-plen_eff // self.page)
             take_to = [
-                n_total if w is None
-                else min(n_total, max(min(n_prompt, claim), len(shared)))
-                for w, claim in zip(self._windows, self._window_claim)
+                max(n_prompt if claim is None else min(n_prompt, claim),
+                    len(shared))
+                for claim in self._window_claim
             ]
             n_owned = sum(take_to) - len(take_to) * len(shared)
-            info = _SlotInfo(
-                rid=r.rid, n_total=n_total, reserved=[0] * len(take_to),
-                t_submit=r.t_submit,
-            )
-            if self.pool.n_free < n_owned:
-                self.prefix.evict_lru(n_owned)
+            # the pool's spare pages over admission's horizon, as the
+            # slots seated so far leave it (the hit's pages are borrowed)
+            if over_horizon is None:
+                over_horizon = self._slots_over_horizon(
+                    unless_spare=n_owned + sum(
+                        c or (self.ADMIT_HORIZON + self._lookahead)
+                        // self.page + 1 for c in self._window_claim))
+            spare = self.pool.n_unpromised + over_horizon
+            self._admit_seq += 1
+            info = _SlotInfo(rid=r.rid, t_submit=r.t_submit)
+            self._make_free(n_owned)
             slot = free[0]
             self._slots[slot] = info
+            self._lens_host[slot] = plen_eff
+            self._n_total[slot] = n_total
+            self._n_end[slot] = plen_eff + max_gen
+            self._seq[slot] = self._admit_seq
             self._win_lo[:, slot] = 0
             self._win_hi[:, slot] = take_to
             tables = self._tables_host[:, slot]
@@ -1841,18 +2274,32 @@ class GenerationEngine:
                     # a window kind's page that another slot holds too
                     n_deposit += int((
                         self.pool.n_slot_holders(got[got >= 0]) > 1).sum())
+            # what is promised with the slot: a full kind's first chunk, a
+            # window kind's claim
+            ahead = self._pages_ahead([slot], self._lookahead)[:, 0]
             want = [
-                0 if w is None else self._window_want(slot, j)
+                int(ahead[j]) if w is None else self._window_want(slot, j)
                 for j, w in enumerate(self._windows)
             ]
+            # ... and what the newcomer takes of the pool over the horizon:
+            # its pages now, a full kind's as it grows, none once it ended
+            grows, alive = self._growth(
+                [plen_eff], [plen_eff + max_gen], [n_total], [n_prompt])
+            want_full = int(ahead[self._full_kinds].sum())
+            takes = np.where(
+                alive[0],
+                grows[0] + n_owned + n_deposit + sum(want) - want_full, 0)
             if (
                 self.pool.n_free < n_owned
                 or self.pool.n_unpromised - n_owned < sum(want) + n_deposit
+                or (spare - takes).min() < 0
             ):
-                # pool pressure: resident slots / registry hold everything
-                # (or it is promised to running slots); retry on a later
-                # step
+                # pool pressure: resident slots / registry hold everything,
+                # or it is promised to running slots, or they will have
+                # grown into it; retry on a later step
                 self._slots[slot] = None
+                self._lens_host[slot] = 0
+                self._n_total[slot] = self._n_end[slot] = 0
                 self._held[:, slot] = False
                 tables[:] = 0
                 if shared:
@@ -1862,8 +2309,11 @@ class GenerationEngine:
                 break
             owned = self.pool.alloc(n_owned)
             free.pop(0)
+            # (the newcomer among the slots: ``_slots_over_horizon``'s term)
+            over_horizon = over_horizon + np.where(
+                alive[0], want_full - grows[0], n_owned)
             self._slot_epoch[slot] += 1
-            info.reserved = want
+            self._reserved[:, slot] = want
             info.t_admit = time.perf_counter()
             self.pool.reserved += sum(want) + n_deposit
             self._deposits += n_deposit
@@ -1919,6 +2369,13 @@ class GenerationEngine:
                         snapshot=snap_to)
             self.stats["prefill_tokens"] += len(row["tokens"])
             self.stats["admitted"] += 1
+            if r.rid in self._carried and row["tokens"]:
+                # a preempted request back in a slot: these positions were
+                # computed once before
+                self.stats["preempted_tokens_recomputed"] += len(row["tokens"])
+                metrics_mod.counters.add(
+                    metrics_mod.GEN_PREEMPTED_TOKENS_RECOMPUTED,
+                    len(row["tokens"]))
             if self.kv_quantized and owned:
                 # these pages' KV lands int8 at the post-scan scatter
                 metrics_mod.counters.add(
@@ -1967,7 +2424,6 @@ class GenerationEngine:
                 last_toks[j] = ids[-1]
                 lens[j] = len(ids) - 1
                 ctx_rows[j, : min(len(ids), self.S)] = ids[: self.S]
-                self._lens_host[slot] = len(ids) - 1
                 self._warp_host[slot] = (
                     r.top_p < 1.0 or r.top_k < self.cfg.vocab_size
                 ) and not r.greedy and r.temperature > 0.0
@@ -2563,8 +3019,8 @@ class GenerationEngine:
 
     def _decode_chunk_fn(self, decode_steps: int, running: List[int],
                          chunk_attrs: dict):
-        """Pick the chunk program (spec or vanilla) plus its table-width
-        token bound and the per-slot warp operand for one dispatch.
+        """Pick the chunk program (spec or vanilla) and the per-slot warp
+        operand for one dispatch.
         ``self.spec`` is read here, under the engine lock — flipping it
         between chunks is safe and takes effect on the next dispatch
         (both programs share one state pytree).
@@ -2575,7 +3031,6 @@ class GenerationEngine:
         instead of one top-p request forcing the whole batch through the
         ``[B, V]`` sort (the old static ``warp=True`` key did exactly
         that)."""
-        tok_bound = decode_steps * ((self.spec_k + 1) if self.spec else 1)
         # fused routing (the fused epilogue, ``fused_sample_applies``): the
         # vanilla chunk narrows the fallback bucket to the slots the online
         # pass cannot serve (_fused_warp_host — top-p, top-k > TOPK_MAX); plain top-k slots
@@ -2623,7 +3078,7 @@ class GenerationEngine:
             chunk_attrs["sampler_fallback_rows"] = fallback
             self.stats["fused_rows"] += chunk_attrs["fused_rows"]
             self.stats["sampler_fallback_rows"] += fallback
-        return make, tok_bound, wb, warp_idx
+        return make, wb, warp_idx
 
     def _dispatch_chunk(self, chunk, W: int, warp_idx) -> tuple:
         """Dispatch one decode chunk and START its harvest-flag D2H copy
@@ -2692,6 +3147,7 @@ class GenerationEngine:
         toks = host_state["out_tokens"][b, :n].tolist()
         lps = host_state["out_logprobs"][b, :n].tolist()
         routing = host_state["out_routing"].get(b)
+        routing = None if routing is None else routing[:n]
         info = self._free_slot(b)
         with self._pending_lock:
             self._req_meta.pop(info.rid, None)
@@ -2700,6 +3156,16 @@ class GenerationEngine:
         if t_first is None and n > 0:
             # pause() between a chunk's dispatch and its resolve
             t_first = t_done
+        t_admit, hit = info.t_admit, info.prefix_hit_tokens
+        c = self._carried.pop(info.rid, None)
+        if c is not None:
+            # the dry rule preempted it: what it generated in its earlier
+            # tenures comes first, under the original's stamps
+            toks, lps = c["tokens"] + toks, c["logprobs"] + lps
+            if c["routing"] is not None:
+                routing = np.concatenate([c["routing"], routing])
+            t_admit, hit = c["t_admit"], c["prefix_hit_tokens"]
+            t_first = c["t_first"] or t_first
         return GenOutput(
             rid=info.rid,
             output_ids=toks,
@@ -2707,33 +3173,33 @@ class GenerationEngine:
             finish_reason=reason,
             version=self.version,
             t_submit=info.t_submit,
-            t_admit=info.t_admit,
+            t_admit=t_admit,
             t_first=t_first,
             t_done=t_done,
-            output_routing=None if routing is None else routing[:n],
-            prefix_hit_tokens=info.prefix_hit_tokens,
+            output_routing=routing,
+            prefix_hit_tokens=hit,
         )
 
-    def _dispatch(self, decode_steps: int, running: List[int],
-                  ahead: int, chunk_attrs: dict) -> Tuple[tuple, int]:
-        """Pick the chunk program for the running slots and dispatch it,
-        under its span. ``ahead``: tokens already dispatched but not yet
-        in ``_lens_host`` (pipelined mode). Returns the flag handles and
-        the tokens a slot can advance in this chunk."""
+    def _dispatch(self, decode_steps: int, ahead: int,
+                  chunk_attrs: dict) -> Tuple[Optional[tuple], int, List[int]]:
+        """Seat the slots (``_seat``: who runs, with the pages the chunk
+        writes), pick the chunk program for them and dispatch it, under
+        its span. ``ahead``: tokens already dispatched but not yet in
+        ``_lens_host`` (pipelined mode). Returns the flag handles (``None``:
+        no slot to run), the tokens a slot can advance in this chunk, and
+        the slots that run it."""
         with tracing.span("gen_engine/dispatch") as attrs:
-            make, tok_bound, wb, warp_idx = self._decode_chunk_fn(
+            tok_bound = self._chunk_tokens(decode_steps)
+            # (the host's lengths may lag one chunk behind: a lower bound,
+            # so no live page of a window kind goes)
+            running = self._seat(ahead + tok_bound, chunk_attrs)
+            if not running:
+                return None, tok_bound, running
+            make, wb, warp_idx = self._decode_chunk_fn(
                 decode_steps, running, chunk_attrs
             )
             lens = self._lens_host[running]
             if self._windowed:
-                # window kinds: pages behind the window go back, the pages
-                # this chunk writes are taken (the host's lengths may lag
-                # one chunk behind: a lower bound, so nothing live goes)
-                chunk_attrs["window_pages_released"] = sum(
-                    self._roll_windows(
-                        b, int(n), int(n) + ahead + tok_bound)
-                    for b, n in zip(running, lens)
-                )
                 w = max(w for w in self._windows if w is not None)
                 chunk_attrs["window_resident_tokens"] = int(
                     np.minimum(lens, w).sum())
@@ -2790,7 +3256,7 @@ class GenerationEngine:
                     self.stats[name] = self.stats.get(name, 0) + n
             self._observe_occupancy()
             chunk = make(decode_steps, W, wb)
-            return self._dispatch_chunk(chunk, W, warp_idx), tok_bound
+            return self._dispatch_chunk(chunk, W, warp_idx), tok_bound, running
 
     def _kv_write_rows(self) -> int:
         """Rows of the pool tile that the ``kv_page_write`` kernel copies,
@@ -2933,17 +3399,16 @@ class GenerationEngine:
                 if self._pipeline:
                     return self._step_pipelined(decode_steps, span_attrs)
                 self._admit()
-                running = [
-                    b for b, s in enumerate(self._slots) if s is not None
-                ]
-                if not running:
+                flags, _, running = self._dispatch(
+                    decode_steps, 0, span_attrs)
+                if flags is None:
                     return []
-                flags, _ = self._dispatch(decode_steps, running, 0, span_attrs)
                 # one host sync per chunk; the flag copy was enqueued at
                 # dispatch, so the resolve costs no extra round trip
                 flags = self._resolve_flags(flags)
                 active, n_gen, max_gen, lens = flags[:4]
                 self._fold_chunk_aux(flags[4:], span_attrs)
+                # (a held slot's length stands still: the same number)
                 self._lens_host[:] = lens
                 self._mark_first(running)
                 finished = [b for b in running if not active[b]]
@@ -2955,23 +3420,47 @@ class GenerationEngine:
         self, decode_steps: int, span_attrs: dict
     ) -> List[GenOutput]:
         self._admit()
-        new_flags, new_running, new_ahead = None, (), 0
-        running = [b for b, s in enumerate(self._slots) if s is not None]
-        if running:
-            # _lens_host can be one in-flight chunk stale for continuing
-            # slots: widen the bound by the TOKENS already dispatched
-            # (a spec chunk advances up to decode_steps * (K+1) of them)
-            new_flags, new_ahead = self._dispatch(
-                decode_steps, running, self._steps_ahead, span_attrs
-            )
-            new_running = tuple(
-                (b, int(self._slot_epoch[b])) for b in running
-            )
-        prev_flags, prev_running = self._prev_flags, self._prev_running
-        self._prev_flags, self._prev_running = new_flags, new_running
-        self._steps_ahead = new_ahead
+        outs: List[GenOutput] = []
+        if self._prev_flags is not None and self._pool_is_short(decode_steps):
+            # the dry rule acts on what the device has done, not on what
+            # is still in flight: the chunk before is settled first (this
+            # boundary pays the sync the pipeline hides elsewhere)
+            outs = self._settle(span_attrs)
+        # _lens_host can be one in-flight chunk stale for continuing
+        # slots: widen the bound by the TOKENS already dispatched
+        # (a spec chunk advances up to decode_steps * (K+1) of them)
+        new_flags, new_ahead, running = self._dispatch(
+            decode_steps, self._steps_ahead, span_attrs
+        )
+        new_running = tuple((b, int(self._slot_epoch[b])) for b in running)
+        prev_flags = self._prev_flags
         if prev_flags is None:
-            return []
+            self._prev_flags, self._prev_running = new_flags, new_running
+            self._steps_ahead = new_ahead if running else 0
+            return outs
+        outs = self._settle(span_attrs)
+        self._prev_flags, self._prev_running = new_flags, new_running
+        self._steps_ahead = new_ahead if running else 0
+        return outs
+
+    def _pool_is_short(self, decode_steps: int) -> bool:
+        """Whether seating the next chunk would hold a slot out (or one
+        is held out now): the pool cannot raise every slot's reservation
+        to the pages that chunk writes."""
+        if self._held_out:
+            return True
+        span = self._steps_ahead + self._chunk_tokens(decode_steps)
+        occupied = np.nonzero(self._n_total)[0]
+        short = np.maximum(
+            self._pages_ahead(occupied, span) - self._reserved[:, occupied], 0)
+        return int(short.sum()) > self.pool.n_unpromised
+
+    def _settle(self, span_attrs: dict) -> List[GenOutput]:
+        """Pipelined mode: resolve the flags of the chunk dispatched one
+        step ago and harvest its finishes. Nothing is in flight after."""
+        prev_flags, prev_running = self._prev_flags, self._prev_running
+        self._prev_flags, self._prev_running = None, ()
+        self._steps_ahead = 0
         # chunk k's flags landed on host while k (and now k+1) computed:
         # the dispatch-ahead copy makes this resolve a buffer read in
         # steady state — zero blocking syncs at the chunk boundary

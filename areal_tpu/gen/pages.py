@@ -427,21 +427,61 @@ class PrefixRegistry:
                 # borrowed by a resident slot: evicting frees nothing and
                 # loses the prefix; leave this subtree alone
                 continue
-            for p in _ids(n.page):
-                self._where.pop(p, None)
-            self._drop(_ids(n.page))
-            if n.snap is not None:
-                self._drop_snapshot(n)
-                self.snapshot_evictions += 1
+            evicted += self._unfile(n)
             del pc[k]
-            self._n_nodes -= 1
-            evicted += len(_ids(n.page))
             pi = parent_idx.get(i)
             if pi is not None:
                 entries[pi][3] -= 1
                 if entries[pi][3] == 0:
                     heapq.heappush(heap, (entries[pi][2].last_used, pi))
+        if self.pool.n_free < n_pages_needed:
+            evicted += self._evict_stranded(n_pages_needed)
         return evicted
+
+    def _evict_stranded(self, n_pages_needed: int) -> int:
+        """The last resort of ``evict_lru``: a node that ONLY the registry
+        holds while a slot holds one below it (the slot prefilled that
+        stretch for itself, found it filed already, and filed its own
+        pages further down: ``insert`` keeps existing nodes) never becomes
+        a leaf while that slot runs. It goes with its subtree: the pages
+        the registry alone holds come free, the slot keeps its own (they
+        are merely no longer filed). This is what makes every page of
+        ``PagePool.n_cached_only`` one that the registry can give back. A
+        subtree with a snapshot that an admission wave still reads or
+        writes stays."""
+        evicted = 0
+        stack = [(self._children, k, n) for k, n in self._children.items()]
+        while stack and self.pool.n_free < n_pages_needed:
+            pc, k, n = stack.pop()
+            if any(self.pool.refcount(p) > 1 for p in _ids(n.page)):
+                stack.extend((n.children, ck, cn)
+                             for ck, cn in n.children.items())
+                continue
+            below, todo = [], [n]
+            while todo:
+                x = todo.pop()
+                below.append(x)
+                todo.extend(x.children.values())
+            if any(x.snap in self.pinned for x in below if x.snap is not None):
+                continue
+            evicted += sum(self._unfile(x) for x in below)
+            del pc[k]
+        return evicted
+
+    def _unfile(self, node: _RadixNode) -> int:
+        """Drop the registry's hold on an evicted node's pages and its
+        snapshot (the caller takes the node out of the tree). Returns the
+        pages that came free: those nobody else held."""
+        ids = _ids(node.page)
+        freed = sum(self.pool.refcount(p) == 1 for p in ids)
+        for p in ids:
+            self._where.pop(p, None)
+        self._drop(ids)
+        if node.snap is not None:
+            self._drop_snapshot(node)
+            self.snapshot_evictions += 1
+        self._n_nodes -= 1
+        return freed
 
     def clear(self):
         """Invalidate everything (weight update)."""

@@ -251,15 +251,23 @@ async def test_weight_sync_sharded_trainer_to_tp_gen_server(tmp_path, rng):
         """Probe through the HTTP endpoint — the engine is owned by the
         server's background loop; direct step() calls would race it."""
         async with aiohttp.ClientSession() as sess:
-            async with sess.post(
-                f"http://127.0.0.1:{gen_port}/generate",
-                json={
-                    "rid": f"probe{np.random.randint(1 << 30)}",
-                    "input_ids": [3, 14, 15, 9, 2],
-                    "sampling_params": {"max_new_tokens": n, "greedy": True},
-                },
-            ) as r:
-                d = await r.json()
+            for _ in range(4):
+                async with sess.post(
+                    f"http://127.0.0.1:{gen_port}/generate",
+                    json={
+                        "rid": f"probe{np.random.randint(1 << 30)}",
+                        "input_ids": [3, 14, 15, 9, 2],
+                        "sampling_params": {
+                            "max_new_tokens": n, "greedy": True},
+                    },
+                ) as r:
+                    d = await r.json()
+                # the server's contract: a request caught by a weight
+                # update's interrupt comes back partial and its client
+                # submits again (ROADMAP D20: about one run in ten under six
+                # workers a probe of 6 came back with one chunk of 4)
+                if d.get("finish_reason") != "interrupted":
+                    break
         import types
 
         return types.SimpleNamespace(

@@ -549,9 +549,15 @@ class TestEngineSpans:
         assert admit["attrs"] == {
             "admitted": 3, "prefill_tokens": sum(len(p) - 1 for p in prompts),
             "prefix_hit_tokens": 0, "pending_left": 0,
+            "preempted_tokens_recomputed": 0,
         }
         assert kids["gen_engine/dispatch"]["attrs"]["table_width"] >= 1
         assert first["attrs"]["steps"] == 4 and first["attrs"]["slots"] == 3
+        # the page policy's census rides every chunk (a roomy pool: every
+        # slot runs, nobody is held or preempted)
+        assert (first["attrs"]["slots_running"], first["attrs"]["slots_held"],
+                first["attrs"]["preemptions"]) == (3, 0, 0)
+        assert first["attrs"]["pages_taken_growing"] >= 0
         # every flag wait says whether it blocked; every harvest how many
         # requests it finished, with their four stamps
         waits = [k["gen_engine/flag_wait"] for _, k in chunks

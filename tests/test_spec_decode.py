@@ -110,12 +110,16 @@ class TestGreedyParity:
         """The multi-token verify forward must produce the same logits as
         running decode_step_paged sequentially (teacher-forced) — the
         numerical anchor under everything above."""
-        eng = _engine(params, False, max_slots=2, page_size=8)
+        # (a page of 16: the four positions verified below, 7..10, lie in
+        # the page the slot holds; a slot takes its pages as it grows, and
+        # this test writes past what the engine has dispatched)
+        eng = _engine(params, False, max_slots=2, page_size=16)
         prompt = [int(x) for x in rng.integers(1, 128, size=6)]
         eng.submit(GenRequest(
             rid="a", input_ids=prompt, max_new_tokens=8, greedy=True,
         ))
         eng.step(decode_steps=2)   # some resident context
+        assert eng._held[0, 0, 0] and eng._lens_host[0] + 4 <= 16
         state = eng.state
         table = jnp.asarray(eng._table_host)
         drafts = jnp.asarray(
