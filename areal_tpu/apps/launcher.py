@@ -146,34 +146,6 @@ def gen_server_main(cfg, server_idx: int):
         from areal_tpu.models import transformer as tfm
 
         host_params = tfm.init_params(mcfg, jax.random.key(0))
-    # draft MODEL for spec decode: config beats the env knob; None lets
-    # the engine fall through to AREAL_SPEC_DRAFT_MODEL (then the n-gram
-    # self-drafter). Same gate as the engine's env path: an explicit
-    # drafter is kept by the engine regardless of the spec flag, so
-    # loading one here for a spec-disabled fleet would make every engine
-    # pay draft-pool HBM + a per-vanilla-step maintenance sweep while
-    # never speculating.
-    drafter = None
-    draft_path = getattr(cfg.gen, "spec_draft_model", None)
-    spec_on = (
-        cfg.gen.spec_decode
-        if cfg.gen.spec_decode is not None
-        else constants.spec_decode_enabled()
-    )
-    if draft_path and spec_on:
-        from areal_tpu.gen.drafter import TransformerDrafter
-
-        drafter = TransformerDrafter.from_hf(
-            draft_path,
-            kv_dtype=getattr(cfg.gen, "spec_draft_kv_dtype", None),
-        )
-    elif draft_path:
-        logger.warning(
-            "gen.spec_draft_model is set but spec decode is disabled for "
-            "the gen fleet; not loading the draft model (set "
-            "gen.spec_decode=true or %s to serve it)",
-            constants.SPEC_DECODE_ENV,
-        )
     engine = GenerationEngine(
         mcfg,
         host_params,  # cast + TP-shard happen inside (prepare_params)
@@ -186,9 +158,6 @@ def gen_server_main(cfg, server_idx: int):
         n_pages=cfg.gen.n_pages,
         kv_dtype=cfg.gen.kv_dtype,
         mesh=mesh,
-        spec_decode=cfg.gen.spec_decode,
-        spec_k=cfg.gen.spec_k,
-        drafter=drafter,
     )
 
     async def main():
@@ -237,11 +206,6 @@ def gen_server_main(cfg, server_idx: int):
                     engine.kv_pool_demand_occupancy()
                 ),
                 "n_pages_free": float(engine.pool.n_free),
-                # draft-model spec decode: pool bytes (0 without a draft
-                # model; occupancy is shared with the target pool — the
-                # pages move in lockstep) and the draft weight generation
-                "draft_kv_pool_bytes": float(engine.draft_kv_pool_bytes()),
-                "draft_version": float(engine.draft_version),
             },
         ).maybe_start()
         while watch.alive():
@@ -518,7 +482,7 @@ def gateway_main(cfg):
                     clamp_max_tokens=gspec.brownout_clamp_max_tokens,
                     weight_floor=gspec.brownout_weight_floor,
                 ),
-                scheduler, gw.config, scheduler._client,
+                scheduler, gw.config,
             )
             brownout_task = asyncio.get_event_loop().create_task(
                 controller.run()

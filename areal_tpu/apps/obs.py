@@ -131,25 +131,6 @@ def render(agg: "telemetry.FleetAggregate", now: Optional[float] = None) -> str:
         lines.append("gen-server breakers:")
         for url, state in sorted(agg.server_states.items()):
             lines.append(f"  {url:<40} {state}")
-    drafted = agg.counters.get("gen/spec_draft_tokens", 0.0)
-    if drafted:
-        # speculative-decoding fleet summary: realized accept rate (the
-        # breakeven signal /spec_decode acts on) plus the draft-model
-        # pool bytes when a TransformerDrafter is serving
-        accepted = agg.counters.get("gen/spec_accepted_tokens", 0.0)
-        draft_bytes = sum(
-            (w.get("gauges") or {}).get("draft_kv_pool_bytes", 0.0)
-            for w in agg.workers
-        )
-        row = (
-            f"spec decode: drafted={_fmt(drafted)} "
-            f"accepted={_fmt(accepted)} "
-            f"accept_rate={accepted / max(drafted, 1.0):.3f}"
-        )
-        if draft_bytes:
-            row += f"  draft_kv_pool={draft_bytes / 2**20:.1f}MiB"
-        lines.append("")
-        lines.append(row)
     if agg.histograms:
         lines.append("")
         lines.append(

@@ -38,12 +38,6 @@ TRACE_SPANS_ENV = "AREAL_TRACE_SPANS"        # span ring + trace-id propagation
 TRACE_RING_ENV = "AREAL_TRACE_RING"          # completed-span ring capacity
 TRACE_FLUSH_ENV = "AREAL_TRACE_FLUSH_S"      # dedicated span-flush period
 TRACE_LOG_TAIL_ENV = "AREAL_TRACE_LOG_TAIL"  # flight-recorder log-tail lines
-# Speculative decoding (docs/performance.md "Speculative decoding").
-SPEC_DECODE_ENV = "AREAL_SPEC_DECODE"   # draft-and-verify decode chunks
-SPEC_K_ENV = "AREAL_SPEC_K"             # draft tokens per slot per spec step
-SPEC_DRAFT_MODEL_ENV = "AREAL_SPEC_DRAFT_MODEL"      # HF dir of draft model
-SPEC_DRAFT_KV_DTYPE_ENV = "AREAL_SPEC_DRAFT_KV_DTYPE"  # draft KV pool dtype
-SPEC_K_ADAPT_ENV = "AREAL_SPEC_K_ADAPT"  # retune spec_k from live accept stats
 # KV-pool quantization (docs/performance.md "KV quantization").
 KV_DTYPE_ENV = "AREAL_KV_DTYPE"         # paged KV pool storage dtype
 # Elastic multihost (docs/fault_tolerance.md "Elastic multihost").
@@ -201,69 +195,6 @@ def decode_pipeline_enabled() -> bool:
     """``AREAL_DECODE_PIPELINE`` (default off): harvest decode chunks one
     late so the per-chunk host sync overlaps the next chunk's compute."""
     return env_flag("AREAL_DECODE_PIPELINE", False)
-
-
-def spec_decode_enabled() -> bool:
-    """``AREAL_SPEC_DECODE`` (default off): generation engines decode with
-    speculative draft-and-verify chunks (self-drafting n-gram baseline;
-    exactly distribution-preserving, so PPO-safe). Default off and
-    unjudged: no benchmark cell has run it (ROADMAP D1)."""
-    return env_flag(SPEC_DECODE_ENV, False)
-
-
-def spec_k() -> int:
-    """``AREAL_SPEC_K`` (default 4): draft tokens proposed per slot per
-    speculative decode step; the verify pass scores K+1 positions in one
-    forward. Floored at 1 (K=0 would be vanilla decode with extra steps)."""
-    return max(1, env_int(SPEC_K_ENV, 4))
-
-
-def spec_draft_model() -> Optional[str]:
-    """``AREAL_SPEC_DRAFT_MODEL`` (default unset): HF checkpoint dir of a
-    small draft MODEL for speculative decoding. When set, generation
-    engines constructed without an explicit drafter AND with spec decode
-    enabled build a TP-sharded ``TransformerDrafter`` from it instead of
-    the self-drafting n-gram baseline (docs/performance.md "Speculative
-    decoding"); spec-disabled engines log and ignore it — a draft model
-    is real HBM and per-step work an engine that never speculates must
-    not pay for a fleet-wide env var. The draft's vocab must match the
-    serving model's. Empty/unset -> None."""
-    raw = env_str(SPEC_DRAFT_MODEL_ENV)
-    if raw is None or not raw.strip():
-        return None
-    return raw.strip()
-
-
-def spec_draft_kv_dtype() -> Optional[str]:
-    """``AREAL_SPEC_DRAFT_KV_DTYPE`` (default unset = the draft's serving
-    dtype): storage dtype of the draft model's paged KV pool — the same
-    contract as ``AREAL_KV_DTYPE`` for the target pool (``"int8"``
-    quantizes; unknown values fall back to unset, logged). The draft
-    pool shares the target pool's page indices, so this knob only sizes
-    the draft's parallel pages array."""
-    raw = env_str(SPEC_DRAFT_KV_DTYPE_ENV)
-    if raw is None or not raw.strip():
-        return None
-    v = raw.strip().lower()
-    if v == "int8":
-        return "int8"
-    if v in ("bf16", "bfloat16"):
-        return "bf16"
-    _logger.warning(
-        "ignoring unknown %s=%r (using the draft serving dtype)",
-        SPEC_DRAFT_KV_DTYPE_ENV, raw,
-    )
-    return None
-
-
-def spec_k_adapt_enabled() -> bool:
-    """``AREAL_SPEC_K_ADAPT`` (default off): speculative engines retune
-    ``spec_k`` between chunks from the live ``gen/spec_accept_len``
-    window (mean accept length with hysteresis, over a small fixed K
-    choice set so chunk compile keys stay bounded). The live value is
-    exported as the ``gen/spec_k_current`` gauge. Default off and
-    unjudged, with ``AREAL_SPEC_DECODE`` (ROADMAP D1)."""
-    return env_flag(SPEC_K_ADAPT_ENV, False)
 
 
 def kv_dtype() -> Optional[str]:
@@ -626,11 +557,6 @@ def get_env_vars(**extra) -> dict:
         "AREAL_DEBUG_CHECKS",
         "AREAL_FLASH_BWD_PIPELINE",
         "AREAL_DECODE_PIPELINE",
-        SPEC_DECODE_ENV,
-        SPEC_K_ENV,
-        SPEC_DRAFT_MODEL_ENV,
-        SPEC_DRAFT_KV_DTYPE_ENV,
-        SPEC_K_ADAPT_ENV,
         KV_DTYPE_ENV,
         "AREAL_DISABLE_NATIVE",
         "AREAL_ENABLE_FUNCTION_CALL",

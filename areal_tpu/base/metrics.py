@@ -87,14 +87,11 @@ class Histogram:
         self.min = float("inf")
         self.max = float("-inf")
 
-    def observe(self, value: float, n: int = 1) -> None:
-        """Record ``value`` ``n`` times (``n > 1`` lets batched producers —
-        e.g. the spec-decode chunk folding a whole ``[steps, slots]``
-        accept-length grid — record without a per-observation loop)."""
+    def observe(self, value: float) -> None:
         v = float(value)
-        self.counts[bisect.bisect_left(self.boundaries, v)] += n
-        self.sum += v * n
-        self.count += n
+        self.counts[bisect.bisect_left(self.boundaries, v)] += 1
+        self.sum += v
+        self.count += 1
         if v < self.min:
             self.min = v
         if v > self.max:
@@ -220,15 +217,15 @@ class CounterRegistry:
 
     def gauge(self, name: str, value: float) -> None:
         """Last-value-wins gauge (a live setting, not an accumulation —
-        e.g. the adaptive speculative-K currently in force). Reported
+        e.g. the brownout level currently in force). Reported
         as-is in ``delta`` views; the fleet aggregator takes the max
         across workers."""
         with self._lock:
             self._kinds.setdefault(name, KIND_GAUGE)
             self._vals[name] = float(value)
 
-    def observe(self, name: str, value: float, n: int = 1) -> None:
-        """Record ``n`` observations of ``value`` into the histogram
+    def observe(self, name: str, value: float) -> None:
+        """Record one observation of ``value`` into the histogram
         ``name`` (created on first use with the catalog's boundaries for
         that key)."""
         with self._lock:
@@ -237,7 +234,7 @@ class CounterRegistry:
                 h = Histogram(HISTOGRAM_BOUNDARIES.get(name))
                 self._hists[name] = h
                 self._kinds.setdefault(name, KIND_HISTOGRAM)
-            h.observe(value, n)
+            h.observe(value)
 
     def get(self, name: str, default: float = 0.0) -> float:
         with self._lock:
@@ -411,36 +408,15 @@ MANAGER_SCHEDULED = "manager/schedule_requests"
 MANAGER_ALLOCATED = "manager/allocated"    # rollouts admitted by the gate
 TRAIN_STEPS = "train/steps"                # optimizer steps taken
 
-# Speculative decoding (docs/performance.md "Speculative decoding"):
-# drafted vs accepted draft tokens (sums; their ratio is the accept rate)
-# plus an accept-length distribution per (slot, spec step) — the drafter
-# quality signal the ops CLI reads.
-GEN_SPEC_DRAFT_TOKENS = "gen/spec_draft_tokens"
-GEN_SPEC_ACCEPTED_TOKENS = "gen/spec_accepted_tokens"
-GEN_SPEC_ACCEPT_LEN = "gen/spec_accept_len"
-
-# Draft-MODEL speculative decoding: the per-position acceptance
-# probability min(1, p/q) the rejection sampler computes for sampled
-# (general-q) drafters — the draft-quality signal independent of where
-# the first rejection lands — plus the draft pool's occupancy histogram
-# (its pages move in lockstep with the target pool's, so this mirrors
-# gen/kv_pool_occupancy whenever a draft model is configured; bytes ride
-# the per-worker gauge channel and /metrics_json).
-GEN_SPEC_Q_ACCEPT_PROB = "gen/spec_q_accept_prob"
-GEN_DRAFT_KV_POOL_OCCUPANCY = "gen/draft_kv_pool_occupancy"
-
 # Fused sampling epilogue (docs/performance.md "Fused sampling
 # epilogue"): decode steps sampled through the streamed LM-head epilogue
 # vs rows that fell back to the sorted reference path (top-p / oversize
-# top-k slots) — their ratio is the fused coverage of live traffic —
-# plus the adaptive speculative-K currently in force (a gauge: last value
-# wins locally, fleet aggregation takes the max across workers).
+# top-k slots) — their ratio is the fused coverage of live traffic.
 GEN_FUSED_SAMPLE_STEPS = "gen/fused_sample_steps"
 GEN_SAMPLER_FALLBACK_ROWS = "gen/sampler_fallback_rows"
-GEN_SPEC_K_CURRENT = "gen/spec_k_current"
 
-# Chunk-boundary sync protocol (docs/performance.md "Speculative
-# decoding" / chunk pipelining): every decode chunk's harvest-flag fetch
+# Chunk-boundary sync protocol (docs/performance.md, chunk
+# pipelining): every decode chunk's harvest-flag fetch
 # is dispatch-ahead (the D2H copy is enqueued at dispatch, resolved one
 # chunk later under AREAL_DECODE_PIPELINE) — ``blocked`` counts resolves
 # that found the copy not yet landed (a fresh host<->device round trip,
@@ -534,21 +510,7 @@ POOL_OCCUPANCY_BOUNDARIES: List[float] = [
     0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 0.95, 0.99,
 ]
 
-# Small-integer edges for the accept-length histogram: accept lengths are
-# 0..K (K = AREAL_SPEC_K, typically <= 8) and the duration edges would
-# smear 0/1/2 — the values that decide whether spec decode pays — into
-# one bucket.
-SPEC_ACCEPT_LEN_BOUNDARIES: List[float] = [
-    0.5, 1.5, 2.5, 3.5, 4.5, 5.5, 6.5, 7.5, 8.5, 12.5, 16.5,
-]
 
-# Probability edges for the general-q acceptance-probability histogram:
-# values live in [0, 1]; finer edges toward 1.0 because that is where a
-# good draft model lives (0.9 vs 0.99 mean accept is the difference
-# between spec paying and not at large K).
-SPEC_Q_ACCEPT_PROB_BOUNDARIES: List[float] = [
-    0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 0.95, 0.99,
-]
 
 
 # Per-key metric kinds; unknown keys default to KIND_SUM. The arealint
@@ -561,11 +523,7 @@ METRIC_KINDS: Dict[str, str] = {
     E2E_LATENCY_S: KIND_HISTOGRAM,
     TTFC_S: KIND_HISTOGRAM,
     REWARD_LAG_S: KIND_HISTOGRAM,
-    GEN_SPEC_ACCEPT_LEN: KIND_HISTOGRAM,
-    GEN_SPEC_K_CURRENT: KIND_GAUGE,
-    GEN_SPEC_Q_ACCEPT_PROB: KIND_HISTOGRAM,
     GEN_KV_POOL_OCCUPANCY: KIND_HISTOGRAM,
-    GEN_DRAFT_KV_POOL_OCCUPANCY: KIND_HISTOGRAM,
     RECOVERY_TIME_S: KIND_HISTOGRAM,
     GW_QUEUE_WAIT_S: KIND_HISTOGRAM,
     GW_TTFT_S: KIND_HISTOGRAM,
@@ -576,10 +534,7 @@ METRIC_KINDS: Dict[str, str] = {
 # duration edges).
 HISTOGRAM_BOUNDARIES: Dict[str, List[float]] = {
     STALENESS_VERSIONS: VERSION_LAG_BOUNDARIES,
-    GEN_SPEC_ACCEPT_LEN: SPEC_ACCEPT_LEN_BOUNDARIES,
-    GEN_SPEC_Q_ACCEPT_PROB: SPEC_Q_ACCEPT_PROB_BOUNDARIES,
     GEN_KV_POOL_OCCUPANCY: POOL_OCCUPANCY_BOUNDARIES,
-    GEN_DRAFT_KV_POOL_OCCUPANCY: POOL_OCCUPANCY_BOUNDARIES,
 }
 
 

@@ -85,18 +85,6 @@ class GenFleetSpec:
     tp_size: int = 1
     page_size: int = 128
     n_pages: Optional[int] = None    # KV pool size; None = max_slots * tables
-    # speculative decoding (docs/performance.md "Speculative decoding"):
-    # None defers to the AREAL_SPEC_DECODE / AREAL_SPEC_K env knobs
-    spec_decode: Optional[bool] = None
-    spec_k: Optional[int] = None
-    # draft MODEL for spec decode: HF checkpoint dir of a small model
-    # (vocab must match the serving model); None defers to the
-    # AREAL_SPEC_DRAFT_MODEL env knob (itself unset = the free n-gram
-    # self-drafter). The draft serves TP-sharded on the same mesh with
-    # its own paged KV pool; spec_draft_kv_dtype optionally int8-
-    # quantizes that pool (None -> AREAL_SPEC_DRAFT_KV_DTYPE).
-    spec_draft_model: Optional[str] = None
-    spec_draft_kv_dtype: Optional[str] = None
     # KV-pool storage dtype (docs/performance.md "KV quantization"):
     # None defers to cfg.kv_dtype / the AREAL_KV_DTYPE env knob; "int8"
     # stores quantized pages + per-(page-slot, kv-head) scales

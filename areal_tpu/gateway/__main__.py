@@ -88,9 +88,7 @@ async def _amain(args) -> int:
     if args.brownout:
         from areal_tpu.gateway.brownout import BrownoutConfig, wire_brownout
 
-        controller = wire_brownout(
-            BrownoutConfig(), scheduler, gw.config, scheduler._client
-        )
+        controller = wire_brownout(BrownoutConfig(), scheduler, gw.config)
         brownout_task = asyncio.get_event_loop().create_task(
             controller.run()
         )

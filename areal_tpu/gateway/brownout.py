@@ -8,13 +8,10 @@ through an ordered ladder of reversible levels:
 
 1. **clamp** — cap ``max_tokens`` fleet-wide (shorter answers for
    everyone beats failures for some).
-2. **no speculation** — disable speculative decoding via each backend's
-   ``/spec_decode`` toggle: draft work competes with target-model decode
-   for the same chips, so under saturation speculation costs throughput.
-3. **shed best-effort** — 429 tenants whose weight is below the
+2. **shed best-effort** — 429 tenants whose weight is below the
    configured floor, with an honest ``Retry-After`` (the ladder's
    soonest possible de-escalation), keeping capacity for paying lanes.
-4. **admit nothing** — every new request answers 429; in-flight streams
+3. **admit nothing** — every new request answers 429; in-flight streams
    run to completion. The last rung before falling over.
 
 The split mirrors ``gateway/autoscaler.py``: :func:`decide` is a PURE
@@ -32,7 +29,7 @@ adjacent rungs on a noisy signal). Transitions are counted
 import asyncio
 import dataclasses
 import time
-from typing import Awaitable, Callable, Dict, List, Optional
+from typing import Callable, List, Optional
 
 from areal_tpu.base import logging
 from areal_tpu.base import metrics as metrics_mod
@@ -57,9 +54,8 @@ class BrownoutConfig:
     levels: List[LevelThresholds] = dataclasses.field(
         default_factory=lambda: [
             LevelThresholds(0.90, 5.0, 0.25),   # 1: clamp max_tokens
-            LevelThresholds(0.95, 15.0, 0.50),  # 2: disable spec decode
-            LevelThresholds(0.97, 30.0, 0.75),  # 3: shed light tenants
-            LevelThresholds(0.99, 60.0, 1.00),  # 4: admit nothing new
+            LevelThresholds(0.97, 30.0, 0.75),  # 2: shed light tenants
+            LevelThresholds(0.99, 60.0, 1.00),  # 3: admit nothing new
         ]
     )
     # de-escalate only when every signal < entry threshold * hysteresis
@@ -67,7 +63,7 @@ class BrownoutConfig:
     min_hold_s: float = 30.0   # dwell before any step DOWN
     interval_s: float = 5.0    # controller loop cadence
     clamp_max_tokens: int = 256   # the level-1 cap
-    weight_floor: float = 1.0     # level-3: shed tenants below this weight
+    weight_floor: float = 1.0     # level-2: shed tenants below this weight
 
 
 def decide(cfg: BrownoutConfig, sig: ScaleSignals, current: int) -> int:
@@ -111,12 +107,10 @@ class BrownoutController:
 
     - ``clamp_cb(max_tokens | None)`` — apply/remove the fleet-wide
       ``max_tokens`` cap (level >= 1).
-    - ``spec_cb(enabled)`` — async; toggle speculative decoding across
-      the fleet (disabled at level >= 2, restored below).
     - ``shed_cb(weight_floor, retry_after_s)`` — shed tenants below the
-      floor (level >= 3; floor 0 disables shedding).
+      floor (level >= 2; floor 0 disables shedding).
     - ``pause_cb(paused, retry_after_s)`` — stop admitting new requests
-      (level >= 4).
+      (level >= 3).
     """
 
     def __init__(
@@ -124,7 +118,6 @@ class BrownoutController:
         cfg: BrownoutConfig,
         fetch_signals: Callable[[], ScaleSignals],
         clamp_cb: Callable[[Optional[int]], None],
-        spec_cb: Callable[[bool], Awaitable[None]],
         shed_cb: Callable[[float, float], None],
         pause_cb: Callable[[bool, float], None],
         clock=time.monotonic,
@@ -132,7 +125,6 @@ class BrownoutController:
         self.cfg = cfg
         self.fetch_signals = fetch_signals
         self.clamp_cb = clamp_cb
-        self.spec_cb = spec_cb
         self.shed_cb = shed_cb
         self.pause_cb = pause_cb
         self._clock = clock
@@ -157,20 +149,18 @@ class BrownoutController:
         ):
             return self.level  # dwell; escalation is never delayed
         if target != self.level:
-            await self._apply(target, sig)
+            self._apply(target, sig)
         return self.level
 
-    async def _apply(self, target: int, sig: ScaleSignals) -> None:
+    def _apply(self, target: int, sig: ScaleSignals) -> None:
         prev, self.level = self.level, target
         self._last_transition_t = self._clock()
         retry_after = self.retry_after_s()
         self.clamp_cb(self.cfg.clamp_max_tokens if target >= 1 else None)
-        if (target >= 2) != (prev >= 2):
-            await self.spec_cb(target < 2)
         self.shed_cb(
-            self.cfg.weight_floor if target >= 3 else 0.0, retry_after
+            self.cfg.weight_floor if target >= 2 else 0.0, retry_after
         )
-        self.pause_cb(target >= 4, retry_after)
+        self.pause_cb(target >= 3, retry_after)
         metrics_mod.counters.gauge(
             metrics_mod.GW_BROWNOUT_LEVEL, float(target)
         )
@@ -197,18 +187,13 @@ def wire_brownout(
     cfg: BrownoutConfig,
     scheduler,
     gateway_config,
-    client,
     clock=time.monotonic,
 ) -> BrownoutController:
     """Build a controller actuating a :class:`ContinuousBatchScheduler` +
-    :class:`GatewayConfig` pair over a :class:`GenAPIClient`.
+    :class:`GatewayConfig` pair.
 
     Signals come from the scheduler's live capacity view (mean KV demand
-    occupancy + unhealthy count) and the ``gw/queue_wait_s`` histogram.
-    The spec-decode lever remembers which backends actually HAD
-    speculation on, so restoring the ladder does not switch it on where
-    an operator had it disabled."""
-    spec_prev: Dict[str, bool] = {}
+    occupancy + unhealthy count) and the ``gw/queue_wait_s`` histogram."""
 
     def fetch_signals() -> ScaleSignals:
         states = list(scheduler._servers.values())
@@ -234,30 +219,6 @@ def wire_brownout(
     def clamp_cb(max_tokens: Optional[int]) -> None:
         gateway_config.brownout_max_tokens = max_tokens
 
-    async def spec_cb(enabled: bool) -> None:
-        if not enabled:
-            for url in scheduler.server_urls():
-                try:
-                    m = await client.metrics(url)
-                    spec_prev[url] = bool(m.get("spec_decode", False))
-                    if spec_prev[url]:
-                        await client.set_spec_decode(url, False)
-                except Exception:
-                    logger.warning(
-                        "brownout: disabling spec decode on %s failed", url
-                    )
-            return
-        for url, was_on in spec_prev.items():
-            if not was_on:
-                continue
-            try:
-                await client.set_spec_decode(url, True)
-            except Exception:
-                logger.warning(
-                    "brownout: restoring spec decode on %s failed", url
-                )
-        spec_prev.clear()
-
     def shed_cb(weight_floor: float, retry_after_s: float) -> None:
         scheduler.shed_weight_floor = weight_floor
         scheduler.brownout_retry_after_s = retry_after_s
@@ -267,6 +228,6 @@ def wire_brownout(
         scheduler.brownout_retry_after_s = retry_after_s
 
     return BrownoutController(
-        cfg, fetch_signals, clamp_cb, spec_cb, shed_cb, pause_cb,
+        cfg, fetch_signals, clamp_cb, shed_cb, pause_cb,
         clock=clock,
     )

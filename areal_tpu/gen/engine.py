@@ -78,14 +78,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from areal_tpu.base import constants, tracing
 from areal_tpu.base import metrics as metrics_mod
-from areal_tpu.gen.drafter import Drafter, NGramDrafter, TransformerDrafter
 from areal_tpu.gen.pages import PagePool, PrefixRegistry
 from areal_tpu.gen.pages import _ids as _held_ids
-from areal_tpu.gen.sampling import (
-    SamplingParams,
-    sample_tokens,
-    spec_rejection_sample,
-)
+from areal_tpu.gen.sampling import SamplingParams, sample_tokens
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import fused_sample as fused_ops
@@ -121,25 +116,8 @@ class GenState:
     stop_ids: jnp.ndarray       # [B, K] i32 per-slot stop tokens (-1 = unused)
     out_tokens: jnp.ndarray     # [B, G] i32
     out_logprobs: jnp.ndarray   # [B, G] f32
-    # token-id mirror of the resident context for the self-drafter:
-    # ctx_tokens[b, i] is the token whose KV sits at pool position i, and
-    # ctx_tokens[b, lens[b]] = last_tokens[b] (pending, KV not yet written).
-    # Maintained by BOTH decode paths so spec/vanilla chunks can interleave
-    # freely on one state pytree (bounded jit specializations).
-    ctx_tokens: jnp.ndarray     # [B, S] i32
-    # drafter fallback when the n-gram lookup misses: the target argmax at
-    # the previous spec step's emission boundary (greedy-from-last-logits)
-    fallback_token: jnp.ndarray  # [B] i32
     sp: SamplingParams
     rng: jax.Array
-    # draft MODEL's own paged KV pool (None without a TransformerDrafter):
-    # addressed by the SAME page tables and lens as the target pool, so
-    # draft pages allocate/free/share in lockstep with target pages, and
-    # BOTH decode paths keep it current (the spec chunk through the
-    # drafter's autoregressive proposal steps, the vanilla chunk through
-    # one headless draft decode step) — mixed spec/vanilla traffic stays
-    # correct on one state pytree.
-    draft_cache: Optional[tfm.PagedKVCache] = None
     # MoE routing record (``record_routing``; None otherwise): the experts
     # the decode step that produced out_tokens[b, i] chose in every layer
     out_routing: Optional[jnp.ndarray] = None   # [B, G, L, top_k] i32
@@ -316,15 +294,6 @@ class GenerationEngine:
     # far-off cap is not counted at its cap.
     ADMIT_HORIZON = 512
 
-    # Adaptive spec-K policy (AREAL_SPEC_K_ADAPT): retune after WINDOW
-    # accept-length observations; step K up when the windowed mean accept
-    # length clears UP * K (drafts are nearly free), down when it falls
-    # under DOWN * K (verify sweeps are mostly wasted). The UP/DOWN gap is
-    # the hysteresis band that keeps K from oscillating at a boundary.
-    SPEC_K_ADAPT_WINDOW = 128
-    SPEC_K_ADAPT_UP = 0.75
-    SPEC_K_ADAPT_DOWN = 0.25
-
     def __init__(
         self,
         cfg: ModelConfig,
@@ -342,11 +311,7 @@ class GenerationEngine:
         mesh: Optional[Mesh] = None,
         admit_chunk_tokens: Optional[int] = None,
         pipeline_chunks: Optional[bool] = None,
-        spec_decode: Optional[bool] = None,
-        spec_k: Optional[int] = None,
-        drafter: Optional[Drafter] = None,
         fused_sample: Optional[bool] = None,
-        spec_k_adapt: Optional[bool] = None,
         record_routing: bool = False,
         state_snapshots: int = 8,
     ):
@@ -355,7 +320,7 @@ class GenerationEngine:
         with tracing.span("gen_engine/start", max_slots=max_slots) as start:
             self.cfg = cfg
             self.mesh = mesh
-            # MoE models: every vanilla chunk returns a routing census with its
+            # MoE models: every chunk returns a routing census with its
             # harvest flags (``_fold_chunk_aux``); ``record_routing`` also keeps
             # each output token's chosen experts for ``GenOutput``
             self._moe = cfg.mlp_type == "moe"
@@ -381,117 +346,10 @@ class GenerationEngine:
                         "latent attention: a latent page has no head axis to "
                         "shard; tensor-parallel serving is not supported"
                     )
-            # Drafter resolution happens BEFORE device-state construction: a
-            # TransformerDrafter adds a draft param tree and a draft KV pool
-            # to everything below (shardings, state pytree, jitted programs).
-            # Explicit argument > AREAL_SPEC_DRAFT_MODEL checkpoint > the
-            # free self-drafting n-gram baseline. The env-knob checkpoint is
-            # only loaded when spec decode is actually on: a draft model is
-            # real HBM (pool + params) and a per-vanilla-step maintenance
-            # sweep, which an engine that never speculates must not pay just
-            # because a fleet-wide env var is set. An EXPLICIT drafter
-            # argument is kept regardless — that caller may toggle spec on
-            # later, and the pool must exist in the state pytree from
-            # construction.
-            spec_on = (
-                spec_decode
-                if spec_decode is not None
-                else constants.spec_decode_enabled()
-            )
-            if drafter is None:
-                draft_path = constants.spec_draft_model()
-                if draft_path and spec_on:
-                    with tracing.span("gen_engine/start/draft_model"):
-                        drafter = TransformerDrafter.from_hf(
-                            draft_path,
-                            kv_dtype=constants.spec_draft_kv_dtype(),
-                        )
-                elif draft_path:
-                    logger.warning(
-                        "%s is set but spec decode is disabled on this engine; "
-                        "not loading the draft model (enable %s or pass "
-                        "spec_decode=True to serve it)",
-                        constants.SPEC_DRAFT_MODEL_ENV,
-                        constants.SPEC_DECODE_ENV,
-                    )
-            self.drafter: Drafter = (
-                drafter if drafter is not None else NGramDrafter()
-            )
-            if not getattr(self.drafter, "deterministic", True) and not getattr(
-                self.drafter, "provides_q_logprobs", False
-            ):
-                # sampled proposals without a proposal distribution cannot be
-                # rejection-sampled correctly — accepting them would silently
-                # bias generation toward the drafter (PPO corruption). Sampled
-                # drafters must declare provides_q_logprobs and return their
-                # q; the general-q branch of spec_rejection_sample handles
-                # the rest.
-                raise NotImplementedError(
-                    "non-deterministic drafters need their proposal logprobs "
-                    "threaded into spec_rejection_sample (q_logprobs): set "
-                    "provides_q_logprobs = True and return them, or use a "
-                    "deterministic (one-hot) drafter"
-                )
-            self._draft: Optional[TransformerDrafter] = (
-                self.drafter if isinstance(self.drafter, TransformerDrafter)
-                else None
-            )
-            if (
-                not getattr(self.drafter, "deterministic", True)
-                and self._draft is None
-            ):
-                # the q_logprobs contract is wired through the model-drafter
-                # interface only: a sampled drafter outside it would take the
-                # one-hot propose() path and its q would silently never reach
-                # the rejection sampler
-                raise NotImplementedError(
-                    "sampled drafters are wired through the TransformerDrafter "
-                    "propose_model interface (draft params + paged KV inside "
-                    "the jitted chunk); subclass TransformerDrafter to "
-                    "customize proposals"
-                )
-            self.draft_cfg: Optional[ModelConfig] = None
-            self.draft_kv_dtype: Optional[str] = None
-            self.draft_kv_quantized = False
-            self.draft_version = 0
-            if self._draft is not None:
-                dcfg = self._draft.cfg
-                if dcfg.vocab_size != cfg.vocab_size:
-                    raise ValueError(
-                        f"draft model vocab ({dcfg.vocab_size}) must match the "
-                        f"serving model's ({cfg.vocab_size}) — proposed tokens "
-                        "are scored by the target verbatim"
-                    )
-                if dcfg.dtype != cfg.dtype:
-                    # serve the draft in the target's activation dtype (a
-                    # float32 CPU test config must not silently run a bf16
-                    # draft next to a float32 target)
-                    dcfg = dataclasses.replace(dcfg, dtype=cfg.dtype)
-                self.draft_cfg = dcfg
-                # write the coerced cfg back: propose_model's forward runs
-                # under the DRAFTER's cfg, and leaving the checkpoint dtype
-                # there would compute spec-chunk proposals in one dtype while
-                # the vanilla chunk's maintenance step (draft_cfg) writes KV
-                # in another — the silent mismatch the coercion exists to
-                # prevent
-                self._draft.cfg = dcfg
-                dkd = (
-                    self._draft.kv_dtype
-                    if self._draft.kv_dtype is not None
-                    else constants.spec_draft_kv_dtype()
-                )
-                self.draft_kv_dtype = _resolve_kv_dtype(dkd, dcfg.dtype)
-                self.draft_kv_quantized = self.draft_kv_dtype == "int8"
             self._stateful = cfg.ssm is not None
             if self._stateful:
                 # what has no test beside per-slot recurrent state is
                 # refused, not approximated
-                if self._draft is not None or spec_on:
-                    raise NotImplementedError(
-                        "state-space layers: a draft model or speculative "
-                        "chunks beside recurrent state are not supported (a "
-                        "rejected draft would need the state rolled back)"
-                    )
                 if self.kv_quantized:
                     raise NotImplementedError(
                         "state-space layers: an int8 page pool beside "
@@ -516,8 +374,6 @@ class GenerationEngine:
                 from areal_tpu.parallel.mesh import check_tp_divisibility
 
                 check_tp_divisibility(cfg, tp, role="generation")
-                if self.draft_cfg is not None:
-                    check_tp_divisibility(self.draft_cfg, tp, role="draft model")
                 self._repl = NamedSharding(mesh, P())
                 # pool [L, P, 2, Hkv, page, D]: shard the kv-head dim (a latent
                 # pool's is 1 and the mesh's model axis too, checked above); the
@@ -535,23 +391,8 @@ class GenerationEngine:
                 self._param_sh = param_shardings(
                     mesh, tfm.param_logical_axes(cfg), GEN_RULES
                 )
-                if self.draft_cfg is not None:
-                    # the draft shards through the SAME logical-axis rules:
-                    # heads/mlp/vocab split on `model`, embed replicated —
-                    # its psums ride the same ICI the target's do
-                    self._draft_param_sh = param_shardings(
-                        mesh, tfm.param_logical_axes(self.draft_cfg), GEN_RULES
-                    )
             with tracing.span("gen_engine/start/params"):
                 self.params = self.prepare_params(params)
-                self.draft_params = (
-                    self._prepare_params_for(
-                        self._draft.params, self.draft_cfg.dtype,
-                        self._draft_param_sh if mesh is not None else None,
-                    )
-                    if self._draft is not None
-                    else None
-                )
             self.B = max_slots
             self.page = page_size
             self.M = -(-max_seqlen // page_size)      # table width (pages/slot)
@@ -586,22 +427,6 @@ class GenerationEngine:
             self._full_kinds = np.array([w is None for w in self._windows])
             self._n_full = int(self._full_kinds.sum())
             K = len(self._windows)
-            if self._draft is not None and (
-                K > 1 or self.draft_cfg.period > 1
-            ):
-                raise NotImplementedError(
-                    "layer kinds: a draft model shares the target's page "
-                    "tables, which a model with a period of kinds has several of"
-                )
-            if self._draft is not None and (
-                cfg.n_passes > 1 or self.draft_cfg.n_passes > 1
-            ):
-                raise NotImplementedError(
-                    f"a looped stack (n_passes={cfg.n_passes}, draft "
-                    f"{self.draft_cfg.n_passes}): a draft model beside it is "
-                    "not supported (its pool, maintenance step and acceptance "
-                    "rate have been run beside no such target)"
-                )
             self.n_pages = (
                 n_pages if n_pages is not None
                 else self.B * self.M * bytes_ratio * K
@@ -620,8 +445,7 @@ class GenerationEngine:
             # reserves for a slot's first chunk and a window kind's claim is
             # sized by (a longer chunk takes what it needs beyond that from
             # the pool at large, or its slot is held: ``_seat``)
-            self._lookahead = 2 * 16 * (
-                (spec_k or constants.spec_k()) + 1 if spec_on else 1)
+            self._lookahead = 2 * 16
             # the most pages a slot holds in a window kind at once: positions
             # ``[n + 1 - window, n + lookahead)`` wherever they lie in their
             # pages
@@ -646,20 +470,8 @@ class GenerationEngine:
                     stop_ids=jnp.full((self.B, self.max_stop_ids), -1, jnp.int32),
                     out_tokens=jnp.zeros((self.B, self.G), jnp.int32),
                     out_logprobs=jnp.zeros((self.B, self.G), jnp.float32),
-                    ctx_tokens=jnp.zeros((self.B, self.S), jnp.int32),
-                    fallback_token=jnp.zeros((self.B,), jnp.int32),
                     sp=SamplingParams.filled(self.B),
                     rng=jax.random.key(seed),
-                    # the draft pool mirrors the target pool's page count so
-                    # one page index addresses both (lockstep alloc/free)
-                    draft_cache=(
-                        tfm.PagedKVCache.empty(
-                            self.draft_cfg, self.n_pages, page_size,
-                            kv_dtype="int8" if self.draft_kv_quantized else None,
-                        )
-                        if self._draft is not None
-                        else None
-                    ),
                     out_routing=(
                         jnp.zeros(
                             (self.B, self.G, cfg.n_moe_layers, cfg.moe.top_k),
@@ -697,19 +509,6 @@ class GenerationEngine:
                             scales=(
                                 self._scales_sh if self.kv_quantized else None
                             ),
-                        ),
-                        # the draft pool has the same [L, P, 2, Hkv, page, D]
-                        # layout, so it takes the same kv-head-axis TP split
-                        draft_cache=(
-                            tfm.PagedKVCache(
-                                pages=self._pages_sh,
-                                scales=(
-                                    self._scales_sh
-                                    if self.draft_kv_quantized else None
-                                ),
-                            )
-                            if self._draft is not None
-                            else None
                         ),
                     )
                     self._state_sh = sh
@@ -776,21 +575,8 @@ class GenerationEngine:
                 if pipeline_chunks is not None
                 else constants.decode_pipeline_enabled()
             )
-            # speculative decoding (docs/performance.md): draft-and-verify
-            # chunks amortize one params+pool sweep over K+1 candidate tokens;
-            # exactly distribution-preserving, so togglable between chunks
-            # (``spec`` is read once per step() under the engine lock)
-            self.spec = spec_on
-            if record_routing and spec_on:
-                raise ValueError(
-                    "record_routing covers vanilla chunks only: the verify "
-                    "pass of a speculative chunk records nothing"
-                )
-            self.spec_k = max(
-                1, spec_k if spec_k is not None else constants.spec_k()
-            )
             # fused sampling epilogue (docs/performance.md "Fused sampling
-            # epilogue"): decode/verify chunks return final-norm hidden states
+            # epilogue"): decode chunks return final-norm hidden states
             # and the sampler streams the LM head over vocab blocks — the
             # [B, V] logits (and their sort) leave the per-token path. Exact
             # for greedy, distribution-exact otherwise; top-p (and top-k >
@@ -803,21 +589,6 @@ class GenerationEngine:
                 if fused_sample is not None
                 else fused_ops.fused_sample_applies(cfg, self.params, mesh)
             )
-            # adaptive spec-K: retune the draft length from the live accept-len
-            # histogram the engine already folds per chunk. K only moves within
-            # a small fixed choice set so jitted spec-chunk specializations
-            # stay bounded (one per (chunk key, K) pair, K in _spec_k_choices).
-            self.spec_k_adapt = (
-                spec_k_adapt
-                if spec_k_adapt is not None
-                else constants.spec_k_adapt_enabled()
-            )
-            self._spec_k_choices = sorted({1, 2, 4, 8} | {self.spec_k})
-            self._accept_window: List[float] = []
-            if self.spec:
-                metrics_mod.counters.gauge(
-                    metrics_mod.GEN_SPEC_K_CURRENT, float(self.spec_k)
-                )
             self._prev_flags = None           # chunk k's undonated flag outputs
             self._prev_running: tuple = ()    # (slot, epoch) pairs at k's dispatch
             self._steps_ahead = 0   # token-advance bound of the in-flight chunk
@@ -837,15 +608,12 @@ class GenerationEngine:
             self._jit_commit: Dict[int, Any] = {}
             self._jit_state: Dict[Any, Any] = {}
             self._jit_chunk: Dict[int, Any] = {}
-            self._jit_spec: Dict[Any, Any] = {}
             # observability
             self.stats = {
                 "prefill_tokens": 0,        # prompt tokens actually computed
                 "prefix_hit_tokens": 0,     # prompt tokens served from shared pages
                 "prefix_hits": 0,
                 "admitted": 0,
-                "spec_draft_tokens": 0,     # draft tokens proposed (spec decode)
-                "spec_accepted_tokens": 0,  # draft tokens accepted & emitted
                 # per kernel-run chunk, at its first step: KV positions the
                 # paged-decode kernel computes over / KV tokens resident
                 "kernel_positions": 0,
@@ -856,7 +624,7 @@ class GenerationEngine:
                 "kernel_steps_active": 0,
                 "kernel_steps": 0,
                 "kernel_steps_chained": 0,
-                # pool tiles the ``kv_page_write`` kernel writes: of a vanilla
+                # pool tiles the ``kv_page_write`` kernel writes: of a decode
                 # chunk as dispatched, of an admission wave's prefill; stays 0
                 # where the XLA scatter writes the pool (``_kv_write_rows``)
                 "kv_write_tiles": 0,
@@ -866,7 +634,7 @@ class GenerationEngine:
                 # tokens (sum over running slots of min(len, window), a chunk)
                 "window_pages_released": 0,
                 "window_resident_tokens": 0,
-                # MoE models, per vanilla chunk (every row of the batch routes,
+                # MoE models, per decode chunk (every row of the batch routes,
                 # free slots too: the expert matmuls read what they route to):
                 # distinct experts with a token, summed over layers and steps /
                 # layers x steps x experts / the most tokens one expert got in
@@ -958,13 +726,13 @@ class GenerationEngine:
 
     def n_compiles(self) -> int:
         """Total jitted specializations (stability tested: bounded by the
-        admit buckets + decode/spec chunk sizes, NOT by prompt lengths;
+        admit buckets + decode chunk sizes, NOT by prompt lengths;
         the dry rule's one program, built with the engine, is not among
         them)."""
         return (
             len(self._jit_extend) + len(self._jit_kv_write)
             + len(self._jit_commit) + len(self._jit_state)
-            + len(self._jit_chunk) + len(self._jit_spec)
+            + len(self._jit_chunk)
         )
 
     def n_jit_entries(self) -> int:
@@ -976,7 +744,7 @@ class GenerationEngine:
         return jitcache.total_cache_size(
             j
             for d in (self._jit_extend, self._jit_kv_write, self._jit_commit,
-                      self._jit_state, self._jit_chunk, self._jit_spec,
+                      self._jit_state, self._jit_chunk,
                       {(): self._jit_activity})
             for j in d.values()
         )
@@ -1010,9 +778,7 @@ class GenerationEngine:
         """Configured KV-pool HBM footprint (pages + quant scales),
         computed from shapes — no device pull. The serving gauge the
         fleet aggregator watches for HBM headroom."""
-        return self._pool_bytes_for(self.cfg, self.kv_quantized)
-
-    def _pool_bytes_for(self, cfg: ModelConfig, quantized: bool) -> int:
+        cfg, quantized = self.cfg, self.kv_quantized
         # what the MODEL says a token holds (K and V heads, or one padded
         # latent row), not 2 * Hkv * D
         streams, heads, width = tfm.kv_page_geometry(cfg)
@@ -1039,16 +805,6 @@ class GenerationEngine:
         if n_window:
             out["window"] = one * n_window
         return out
-
-    def draft_kv_pool_bytes(self) -> int:
-        """Configured HBM footprint of the draft model's KV pool (0 when
-        no draft model is configured): same page count as the target pool
-        — the pools share page indices — at the draft's layer/head shape
-        and its own (int8-quantizable) storage dtype. The sizing math the
-        freed int8 headroom argument rests on (docs/performance.md)."""
-        if self._draft is None:
-            return 0
-        return self._pool_bytes_for(self.draft_cfg, self.draft_kv_quantized)
 
     def kv_pool_occupancy(self) -> float:
         """Fraction of pool pages currently held (slots + prefix cache) or
@@ -1082,90 +838,33 @@ class GenerationEngine:
         occ = self.kv_pool_occupancy()
         self._room = int(self._spare_pages().min())
         metrics_mod.counters.observe(metrics_mod.GEN_KV_POOL_OCCUPANCY, occ)
-        if self._draft is not None:
-            # lockstep pools: the draft pool's occupancy IS the target
-            # pool's, but it gets its own histogram so a fleet scraper
-            # can see draft HBM pressure without knowing the linkage
-            metrics_mod.counters.observe(
-                metrics_mod.GEN_DRAFT_KV_POOL_OCCUPANCY, occ
-            )
 
-    def _prepare_params_for(self, params, dtype, shardings):
-        """Cast a (host or device) param pytree to ``dtype`` and, when
-        ``shardings`` is given (TP serving), place each leaf on its mesh
-        shard. Numpy leaves cast on host so no full-size unsharded buffer
-        ever lands on one device."""
-        dt = jnp.dtype(dtype)
+    def prepare_params(self, params):
+        """Cast a (host or device) param pytree to the serving dtype and,
+        under a mesh (TP serving), place each leaf on its mesh shard. Numpy
+        leaves cast on host so no full-size unsharded buffer ever lands on
+        one device."""
+        dt = jnp.dtype(self.cfg.dtype)
         params = jax.tree.map(
             lambda x: x if x.dtype == dt else x.astype(dt), params
         )
-        if shardings is not None:
-            return jax.device_put(params, shardings)
+        if self.mesh is not None:
+            return jax.device_put(params, self._param_sh)
         return jax.tree.map(jnp.asarray, params)
 
-    def prepare_params(self, params):
-        """Serving-dtype cast + (when TP-sharded) mesh placement for the
-        TARGET model's params."""
-        return self._prepare_params_for(
-            params, self.cfg.dtype,
-            self._param_sh if self.mesh is not None else None,
-        )
-
-    def prepare_draft_params(self, params):
-        """Same contract for the DRAFT model's params."""
-        if self._draft is None:
-            raise ValueError("engine has no draft model configured")
-        return self._prepare_params_for(
-            params, self.draft_cfg.dtype,
-            self._draft_param_sh if self.mesh is not None else None,
-        )
-
-    def update_params(
-        self,
-        params,
-        version: Optional[int] = None,
-        draft_params=None,
-    ):
+    def update_params(self, params, version: Optional[int] = None):
         """Hot weight swap between decode chunks (≈ interrupt + reload).
         Invalidates the prefix cache: prompt KV computed under old weights
-        must not seed new generations.
-
-        ``draft_params`` optionally rides along: the weight-fanout channel
-        pushes refreshed draft weights NEXT TO the policy weights so the
-        draft keeps tracking the policy during RL (a drifting draft only
-        costs accept rate, never correctness — but accept rate IS the
-        speedup). Both swaps land under one lock acquisition / one prefix
-        invalidation."""
+        must not seed new generations."""
         if self.mesh is not None:
             params = jax.device_put(params, self._param_sh)
-        if draft_params is not None:
-            draft_params = self.prepare_draft_params(draft_params)
         # the span covers the swap itself (what a chunk boundary pays),
         # not the wait for the running chunk to release the lock
         with self._lock, tracing.span("gen_engine/weight_swap") as attrs:
             self.params = params
-            if draft_params is not None:
-                self.draft_params = draft_params
-                self.draft_version += 1
             self.version = version if version is not None else self.version + 1
             self.prefix.clear()
             attrs["version"] = self.version
-
-    def update_draft_params(self, draft_params):
-        """Swap ONLY the draft model's weights between chunks. Does NOT
-        bump the policy ``version`` — spec decode is exactly distribution-
-        preserving, so outputs (and their staleness tags) are unaffected —
-        but bumps ``draft_version`` and clears the prefix cache: cached
-        pages hold draft KV computed under the old draft weights, and
-        while stale draft KV can only lower accept rate, a fresh draft
-        should not propose from it. In-flight slots keep their resident
-        draft context (the same partial-rollout staleness the target's
-        swap tolerates)."""
-        draft_params = self.prepare_draft_params(draft_params)
-        with self._lock:
-            self.draft_params = draft_params
-            self.draft_version += 1
-            self.prefix.clear()
 
     def partial_outputs(
         self, rids: Optional[Sequence[str]] = None
@@ -1736,24 +1435,19 @@ class GenerationEngine:
         return min(w, self.M)
 
     def _extend_fn(self, n_rows: int, width: int, skip_pool: bool = False):
-        """The COMPUTING half of a prefill chunk: ``(*params, state, tokens,
+        """The COMPUTING half of a prefill chunk: ``(params, state, tokens,
         table_rows, start, n_new)`` to the chunk's fresh K/V of every layer,
-        one ``(ks, vs)`` a pool (the target's, then the draft model's: the
-        prompt prefills BOTH pools from the same tokens, tables and waves,
-        which is what keeps them in lockstep through prefix sharing too).
-        The pool is read and NOT written here: that is ``_kv_write_fn``'s
-        program, which every bucket, table width and ``skip_pool`` share
-        (the write kernel is traced and lowered once a start, not once a
+        ``(ks, vs)``. The pool is read and NOT written here: that is
+        ``_kv_write_fn``'s program, which every bucket, table width and
+        ``skip_pool`` share (the write kernel is traced and lowered once a start, not once a
         program: a dozen of them were 8 s of a 41 s set-up; PERF.md §6, PR
         31), so the rows come out padded to ITS batch."""
         key = (n_rows, width, skip_pool)
         if key in self._jit_extend:
             return self._jit_extend[key]
-        cfgs = (self.cfg, self.draft_cfg)
         pad = self._kv_write_batch(n_rows) - n_rows
-        # the target's routed experts see every token of the wave's chunk
-        # (a draft model keeps the einsums)
-        grouped = (self._moe_grouped(n_rows * self.admit_chunk), False)
+        # the routed experts see every token of the wave's chunk
+        grouped = self._moe_grouped(n_rows * self.admit_chunk)
 
         def pad_rows(x):
             return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
@@ -1766,20 +1460,14 @@ class GenerationEngine:
                 ks, vs, rows = tfm.extend_paged_kv(
                     params, self.cfg, state.cache, tokens, table, start,
                     n_new, skip_pool=skip_pool, ssm=state.ssm, slots=slots)
-                return jax.tree.map(pad_rows, ((ks, vs),)), rows
+                return jax.tree.map(pad_rows, (ks, vs)), rows
 
         else:
 
-            def extend(*args):
-                model, (state, *chunk) = args[:-5], args[-5:]
-                fresh = tuple(
-                    tfm.extend_paged_kv(
-                        p, c, kv, *chunk, skip_pool=skip_pool, moe_grouped=g)
-                    for p, c, kv, g in zip(
-                        model, cfgs, (state.cache, state.draft_cache),
-                        grouped)
-                )
-                return jax.tree.map(pad_rows, fresh)
+            def extend(params, state, *chunk):
+                return jax.tree.map(pad_rows, tfm.extend_paged_kv(
+                    params, self.cfg, state.cache, *chunk,
+                    skip_pool=skip_pool, moe_grouped=grouped))
 
         sharding_kw = self._jit_sharding(5 if self._stateful else 4)
         sharding_kw.pop("out_shardings", None)
@@ -1801,23 +1489,13 @@ class GenerationEngine:
         return None
 
     def _moe_grouped(self, rows: int) -> bool:
-        """Whether a program that hands the target's routed experts ``rows``
+        """Whether a program that hands the routed experts ``rows``
         rows a layer runs them as the ``moe_grouped`` kernel
         (``ops/moe.py:moe_grouped_applies``, over this engine's model, tree
         and mesh): what the program is built with AND what its dispatches
         are counted by (``moe_grouped_rows`` / ``moe_dense_rows``)."""
         return moe_ops.moe_grouped_applies(
             self.cfg, self.params, self.mesh, rows)
-
-    def _chunk_moe_rows(self) -> int:
-        """Rows a decode chunk's step hands the routed experts: the batch,
-        times a speculative chunk's verified positions."""
-        return self.B * self._chunk_tokens(1)
-
-    def _chunk_tokens(self, decode_steps: int) -> int:
-        """Positions a slot can advance (and write) in a chunk of
-        ``decode_steps``: a speculative step verifies ``spec_k + 1``."""
-        return decode_steps * ((self.spec_k + 1) if self.spec else 1)
 
     def _count_moe_rows(self, rows: int, runs: int) -> Dict[str, int]:
         """``rows x expert layers x runs`` (``runs``: decode steps x passes,
@@ -1848,8 +1526,8 @@ class GenerationEngine:
 
     def _kv_write_fn(self, n_rows: int):
         """The WRITING half of a prefill chunk: ``(state, fresh, table_rows
-        [n_rows, M], start, n_new)`` to the state with every pool's fresh
-        K/V in its pages (``tfm._write_chunk_kv``: the path of the decode
+        [n_rows, M], start, n_new)`` to the state with the fresh K/V in
+        its pages (``tfm._write_chunk_kv``: the path of the decode
         step's write), the state donated."""
         if n_rows in self._jit_kv_write:
             return self._jit_kv_write[n_rows]
@@ -1857,15 +1535,10 @@ class GenerationEngine:
 
         def kv_write(state: GenState, fresh, table_rows, start, n_new,
                      state_rows=None, slots=None):
-            caches = [
-                tfm._write_chunk_kv(
-                    kv, ks, vs, table_rows, start, n_new, **write_kw)
-                for kv, (ks, vs) in zip(
-                    (state.cache, state.draft_cache), fresh)
-            ]
+            ks, vs = fresh
             state = dataclasses.replace(
-                state, **dict(zip(("cache", "draft_cache"), caches))
-            )
+                state, cache=tfm._write_chunk_kv(
+                    state.cache, ks, vs, table_rows, start, n_new, **write_kw))
             if state_rows is not None:
                 state = dataclasses.replace(
                     state,
@@ -1885,39 +1558,20 @@ class GenerationEngine:
 
     def _jit_sharding(self, n_host_args: int, with_params: bool = True):
         """in/out sharding kwargs for the engine's jitted programs (empty
-        without a mesh): params (target, then draft when a draft model is
-        configured) on their TP shards, state on its (pools sharded, rest
-        replicated) shardings, host-side arrays replicated."""
+        without a mesh): params on their TP shards, state on its (pool
+        sharded, rest replicated) shardings, host-side arrays replicated."""
         if self.mesh is None:
             return {}
-        ins = ()
-        if with_params:
-            ins += (self._param_sh,)
-            if self._draft is not None:
-                ins += (self._draft_param_sh,)
+        ins = (self._param_sh,) if with_params else ()
         ins += (self._state_sh,) + (self._repl,) * n_host_args
         return {"in_shardings": ins, "out_shardings": self._state_sh}
-
-    def _model_args(self) -> tuple:
-        """Leading params arguments of every params-taking jitted program:
-        ``(params,)`` or ``(params, draft_params)`` — read per dispatch
-        under the engine lock, so hot swaps of either take effect at the
-        next chunk."""
-        if self._draft is not None:
-            return (self.params, self.draft_params)
-        return (self.params,)
-
-    @property
-    def _state_argnum(self) -> int:
-        """Donated-state position in the params-taking jitted programs."""
-        return 2 if self._draft is not None else 1
 
     def _commit_fn(self, n_rows: int):
         if n_rows in self._jit_commit:
             return self._jit_commit[n_rows]
 
         def commit(state: GenState, slots, last_toks, lens, temp, top_p,
-                   top_k, min_gen, max_gen, stop_ids, ctx_rows):
+                   top_k, min_gen, max_gen, stop_ids):
             return dataclasses.replace(
                 state,
                 lens=state.lens.at[slots].set(lens, mode="drop"),
@@ -1929,12 +1583,6 @@ class GenerationEngine:
                 stop_ids=state.stop_ids.at[slots].set(stop_ids, mode="drop"),
                 out_tokens=state.out_tokens.at[slots].set(0, mode="drop"),
                 out_logprobs=state.out_logprobs.at[slots].set(0.0, mode="drop"),
-                # full prompt ids for the self-drafter (covers borrowed
-                # prefix pages too — the radix cache shares KV, not ids)
-                ctx_tokens=state.ctx_tokens.at[slots].set(ctx_rows, mode="drop"),
-                fallback_token=state.fallback_token.at[slots].set(
-                    last_toks, mode="drop"
-                ),
                 sp=SamplingParams(
                     temperature=state.sp.temperature.at[slots].set(temp, mode="drop"),
                     top_p=state.sp.top_p.at[slots].set(top_p, mode="drop"),
@@ -1944,7 +1592,7 @@ class GenerationEngine:
 
         jitted = jax.jit(
             commit, donate_argnums=(0,),
-            **self._jit_sharding(10, with_params=False),
+            **self._jit_sharding(9, with_params=False),
         )
         self._jit_commit[n_rows] = jitted
         return jitted
@@ -2039,7 +1687,7 @@ class GenerationEngine:
                     slot_arr[: len(slots)] = slots
                     state_args = (jnp.asarray(slot_arr),)
                 fresh = self._extend_fn(n, W, skip_pool)(
-                    *self._model_args(), self.state,
+                    self.params, self.state,
                     jnp.asarray(all_tokens[:, c * C : (c + 1) * C]),
                     jnp.asarray(tables[..., :n, :W]),
                     jnp.asarray(start[:n]),
@@ -2417,13 +2065,11 @@ class GenerationEngine:
             min_gen = np.zeros((n,), np.int32)
             max_gen = np.zeros((n,), np.int32)
             stop_ids = np.full((n, K), -1, np.int32)
-            ctx_rows = np.zeros((n, self.S), np.int32)
             for j, (r, slot, _) in enumerate(group):
                 ids = r.input_ids
                 slots[j] = slot
                 last_toks[j] = ids[-1]
                 lens[j] = len(ids) - 1
-                ctx_rows[j, : min(len(ids), self.S)] = ids[: self.S]
                 self._warp_host[slot] = (
                     r.top_p < 1.0 or r.top_k < self.cfg.vocab_size
                 ) and not r.greedy and r.temperature > 0.0
@@ -2451,7 +2097,7 @@ class GenerationEngine:
                 self.state, jnp.asarray(slots), jnp.asarray(last_toks),
                 jnp.asarray(lens), jnp.asarray(temp), jnp.asarray(top_p),
                 jnp.asarray(top_k), jnp.asarray(min_gen), jnp.asarray(max_gen),
-                jnp.asarray(stop_ids), jnp.asarray(ctx_rows),
+                jnp.asarray(stop_ids),
             )
 
     # ------------------------------------------------------------------ #
@@ -2482,7 +2128,7 @@ class GenerationEngine:
             return self._jit_chunk[key]
         cfg = self.cfg
 
-        def one_step(state: GenState, params, draft_params, table, warp_rows):
+        def one_step(state: GenState, params, table, warp_rows):
             head_out, cache, new_lens, *routing = tfm.decode_step_paged(
                 params, cfg, state.cache, state.last_tokens, table,
                 state.lens, state.active,
@@ -2490,28 +2136,11 @@ class GenerationEngine:
                 mesh=self.mesh,
                 return_hidden=fused,
                 with_routing=self._moe,
-                moe_grouped=self._moe_grouped(self._chunk_moe_rows()),
+                moe_grouped=self._moe_grouped(self.B),
                 ssm=state.ssm,
                 ssm_update=self._ssm_update(),
             )
             ssm = routing.pop() if self._stateful else None
-            if self._draft is not None:
-                # keep the draft pool current: one HEADLESS draft decode
-                # step writes the draft model's KV of the token the
-                # target just consumed, at the same position with the
-                # same mask — so a spec chunk can take over mid-stream
-                # with a complete draft context (the draft-model
-                # counterpart of the ctx_tokens mirror below). Costs one
-                # small-model sweep per vanilla step, only on engines
-                # that configured a draft model.
-                _, draft_cache, _ = tfm.decode_step_paged(
-                    draft_params, self.draft_cfg, state.draft_cache,
-                    state.last_tokens, table, state.lens, state.active,
-                    use_pallas=self._decode_use_pallas, mesh=self.mesh,
-                    with_head=False,
-                )
-            else:
-                draft_cache = state.draft_cache
             if self.mesh is not None:
                 # one explicit all-gather of the [B, V] logits (fused: the
                 # much smaller [B, E] hidden states): sampling (sort-based
@@ -2581,11 +2210,6 @@ class GenerationEngine:
                 tokens[:, None] == state.stop_ids, axis=1
             ) & (n_gen >= state.min_gen)
             active = state.active & ~hit_stop & (n_gen < state.max_gen)
-            # keep the drafter's token mirror current (ctx[new_lens] = the
-            # token just sampled) so spec chunks can take over mid-stream
-            ctx_tokens = state.ctx_tokens.at[
-                rows, jnp.where(state.active, new_lens, self.S)
-            ].set(tokens, mode="drop")
             census = None
             out_routing = state.out_routing
             if self._moe:
@@ -2604,14 +2228,12 @@ class GenerationEngine:
             return dataclasses.replace(
                 state,
                 cache=cache,
-                draft_cache=draft_cache,
                 lens=new_lens,
                 last_tokens=tokens,
                 active=active,
                 n_gen=n_gen,
                 out_tokens=out_tokens,
                 out_logprobs=out_logprobs,
-                ctx_tokens=ctx_tokens,
                 rng=rng,
                 out_routing=out_routing,
                 ssm=ssm,
@@ -2631,29 +2253,12 @@ class GenerationEngine:
                 ]).astype(jnp.int32),)
             return flags
 
-        if self._draft is None:
+        def chunk(params, state, table, warp_rows):
+            def body(s, _):
+                return one_step(s, params, table, warp_rows)
 
-            def chunk(params, state, table, warp_rows):
-                def body(s, _):
-                    return one_step(s, params, None, table, warp_rows)
-
-                state, census = jax.lax.scan(
-                    body, state, None, length=n_steps
-                )
-                return state, flags_of(state, census)
-
-        else:
-
-            def chunk(params, draft_params, state, table, warp_rows):
-                def body(s, _):
-                    return one_step(
-                        s, params, draft_params, table, warp_rows
-                    )
-
-                state, census = jax.lax.scan(
-                    body, state, None, length=n_steps
-                )
-                return state, flags_of(state, census)
+            state, census = jax.lax.scan(body, state, None, length=n_steps)
+            return state, flags_of(state, census)
 
         sharding_kw = self._jit_sharding(2)
         if sharding_kw:
@@ -2665,250 +2270,14 @@ class GenerationEngine:
                 sharding_kw["out_shardings"],
                 (self._repl,) * (5 if self._moe else 4),
             )
-        jitted = jax.jit(
-            chunk, donate_argnums=(self._state_argnum,), **sharding_kw
-        )
+        jitted = jax.jit(chunk, donate_argnums=(1,), **sharding_kw)
         self._jit_chunk[key] = jitted
         return jitted
 
-    # ------------------------------------------------------------------ #
-    # Speculative decode (docs/performance.md "Speculative decoding"):
-    # each scan step drafts K tokens per slot (self-drafting n-gram
-    # lookup), scores K+1 positions in ONE verify forward (one params +
-    # pool sweep where vanilla pays one per token), and accepts a prefix
-    # by rejection sampling — exactly distribution-preserving, entirely
-    # on device. Composes with everything the vanilla chunk guarantees:
-    # same GenState pytree (mixed spec/vanilla traffic adds no
-    # specializations beyond the chunk program itself), same flag-tuple
-    # harvest protocol (pipelining, pause, weight swap untouched).
-    # ------------------------------------------------------------------ #
-
-    def _spec_chunk_fn(self, n_steps: int, width: int, warp_bucket: int,
-                       fused: bool = False):
-        """``fused`` (STATIC): verify returns final-norm hidden states and
-        ``ops/fused_sample.fused_spec_rejection`` runs acceptance from the
-        streamed head — one-hot (deterministic) drafters only; the engine
-        routes draft-model (general-q) spec through the materialized
-        verify path regardless of the flag. Warp-bucket rows keep the
-        sorted reference rejection sampler over their own logits rows."""
-        key = (n_steps, width, warp_bucket, self.spec_k, fused)
-        if key in self._jit_spec:
-            return self._jit_spec[key]
-        cfg = self.cfg
-        K = self.spec_k
-        C = K + 1
-        B, G, S = self.B, self.G, self.S
-
-        has_q = getattr(self.drafter, "provides_q_logprobs", False)
-
-        def one_spec_step(state: GenState, params, draft_params, table,
-                          warp_rows):
-            pos_i = jnp.arange(C)[None, :]
-            n_new = jnp.where(state.active, C, 0).astype(jnp.int32)
-            # KV residency bound, acceptance-agnostic (see
-            # ``verify_step_paged``): position i's KV can only ever be
-            # read if emission n_gen+i stays below the cap — and writing
-            # past it could run off the slot's allocated pages. A prefix
-            # of the chunk, so a count (the target's KV write takes it
-            # as one) and, for the draft model's steps, its mask
-            n_write = jnp.where(
-                state.active, jnp.clip(state.max_gen - state.n_gen, 0, C), 0
-            ).astype(jnp.int32)
-            write_mask = pos_i < n_write[:, None]
-            if self._draft is not None:
-                # draft MODEL: K autoregressive small-model decode steps
-                # on the draft params + draft pool, sampling each token
-                # from its own (plain temperature-scaled) distribution
-                # and returning that distribution as q. The draft pool's
-                # writes take the same acceptance-agnostic bound as the
-                # verify scatter, over ALL C chunk positions — the final
-                # one is d_K's KV, which a fully-accepted step leaves
-                # resident (see propose_model's docstring).
-                rng0, r_draft = jax.random.split(state.rng)
-                draft, q_logprobs, draft_cache = self.drafter.propose_model(
-                    draft_params, state.draft_cache, state.last_tokens,
-                    table, state.lens, write_mask, state.sp,
-                    r_draft, K,
-                    use_pallas=self._decode_use_pallas, mesh=self.mesh,
-                    logits_sharding=(
-                        self._repl if self.mesh is not None else None
-                    ),
-                )
-            else:
-                rng0 = state.rng
-                draft = self.drafter.propose(
-                    state.ctx_tokens, state.lens, state.fallback_token, K
-                )                                         # [B, K]
-                q_logprobs = None
-                draft_cache = state.draft_cache
-            chunk_toks = jnp.concatenate(
-                [state.last_tokens[:, None], draft], axis=1
-            )                                             # [B, C]
-            verify_out, cache = tfm.verify_step_paged(
-                params, cfg, state.cache, chunk_toks, table, state.lens,
-                n_new, n_write, return_hidden=fused,
-                use_pallas=self._decode_use_pallas, mesh=self.mesh,
-                moe_grouped=self._moe_grouped(self._chunk_moe_rows()),
-            )
-            if self.mesh is not None:
-                # sampling runs replicated after one logits all-gather
-                # (fused: the [B, C, E] hidden states — same constraint
-                # as the vanilla chunk)
-                verify_out = jax.lax.with_sharding_constraint(
-                    verify_out, self._repl
-                )
-            rng, sub = jax.random.split(rng0)
-            if fused:
-                # one-hot drafter guaranteed by the dispatch routing:
-                # acceptance runs from the streamed head, [B, C, V] verify
-                # logits never materialize
-                sp = state.sp
-                a, cand, cand_lp, boundary_arg = (
-                    fused_ops.fused_spec_rejection(
-                        sub, verify_out, tfm.head_weight(cfg, params),
-                        draft, sp, soft_cap=cfg.final_logits_soft_cap,
-                        mesh=self.mesh,
-                    )
-                )
-                if warp_bucket > 0:
-                    # warping slots (top-p / top-k) keep the sorted
-                    # reference rejection sampler over their OWN
-                    # [W, C, V] logits rows; padding indices (== B) clip
-                    # on the gather and drop on the scatter
-                    rng, sub2 = jax.random.split(rng)
-                    safe = jnp.clip(warp_rows, 0, B - 1)
-                    row_logits = tfm.apply_head(
-                        cfg, params, verify_out[safe]
-                    )
-                    sub_sp = SamplingParams(
-                        temperature=sp.temperature[safe],
-                        top_p=sp.top_p[safe],
-                        top_k=sp.top_k[safe],
-                    )
-                    a_w, tok_w, lp_w, barg_w = spec_rejection_sample(
-                        sub2, row_logits, draft[safe], sub_sp, warp=True
-                    )
-                    a = a.at[warp_rows].set(a_w, mode="drop")
-                    cand = cand.at[warp_rows].set(tok_w, mode="drop")
-                    cand_lp = cand_lp.at[warp_rows].set(lp_w, mode="drop")
-                    boundary_arg = boundary_arg.at[warp_rows].set(
-                        barg_w, mode="drop"
-                    )
-                q_acc_row = None
-            else:
-                # same per-slot warp narrowing as the vanilla chunk: only
-                # the warping slots' K+1 verify rows pay the sort. Sampled
-                # drafters feed the general-q branch; their per-position
-                # accept probability rides out as the draft-quality
-                # signal.
-                rej = spec_rejection_sample(
-                    sub, verify_out, draft, state.sp,
-                    warp=warp_bucket > 0,
-                    warp_rows=warp_rows if warp_bucket > 0 else None,
-                    q_logprobs=q_logprobs, return_accept_prob=has_q,
-                )
-                a, cand, cand_lp, boundary_arg = rej[:4]
-                q_acc_row = rej[4].mean(axis=1) if has_q else None  # [B]
-            # masked variable-length advance: accepted drafts + one
-            # residual token, capped at the remaining budget, truncated at
-            # the first accepted stop token (stop included, like vanilla)
-            remaining = state.max_gen - state.n_gen
-            e0 = jnp.minimum(a + 1, remaining)
-            emit_no = state.n_gen[:, None] + pos_i + 1
-            is_stop = jnp.any(
-                cand[:, :, None] == state.stop_ids[:, None, :], axis=2
-            ) & (emit_no >= state.min_gen[:, None])
-            stop_hit = is_stop & (pos_i < e0[:, None])
-            any_stop = stop_hit.any(axis=1)
-            first_stop = jnp.argmax(stop_hit, axis=1)
-            e = jnp.where(any_stop, first_stop + 1, e0)
-            e = jnp.where(state.active, e, 0)             # emitted count
-            emitted = pos_i < e[:, None]
-            rows = jnp.arange(B)
-            out_idx = jnp.where(emitted, state.n_gen[:, None] + pos_i, G)
-            out_tokens = state.out_tokens.at[rows[:, None], out_idx].set(
-                cand, mode="drop"
-            )
-            out_logprobs = state.out_logprobs.at[
-                rows[:, None], out_idx
-            ].set(cand_lp, mode="drop")
-            n_gen = state.n_gen + e
-            # t0's KV plus the accepted drafts' became resident; rejected
-            # drafts' writes sit beyond new_lens, masked until overwritten
-            new_lens = state.lens + e
-            last_tokens = jnp.where(
-                e > 0,
-                jnp.take_along_axis(
-                    cand, jnp.maximum(e - 1, 0)[:, None], axis=1
-                )[:, 0],
-                state.last_tokens,
-            )
-            active = state.active & ~any_stop & (n_gen < state.max_gen)
-            ctx_idx = jnp.where(
-                emitted, state.lens[:, None] + 1 + pos_i, S
-            )
-            ctx_tokens = state.ctx_tokens.at[rows[:, None], ctx_idx].set(
-                cand, mode="drop"
-            )
-            fallback = jnp.where(
-                state.active, boundary_arg, state.fallback_token
-            )
-            drafted = jnp.where(state.active, K, 0).astype(jnp.int32)
-            accepted = jnp.minimum(a, e).astype(jnp.int32)
-            new_state = dataclasses.replace(
-                state, cache=cache, draft_cache=draft_cache, lens=new_lens,
-                last_tokens=last_tokens, active=active, n_gen=n_gen,
-                out_tokens=out_tokens, out_logprobs=out_logprobs,
-                ctx_tokens=ctx_tokens, fallback_token=fallback, rng=rng,
-            )
-            aux = (drafted, accepted)
-            if has_q:
-                aux += (jnp.where(state.active, q_acc_row, 0.0),)
-            return new_state, aux
-
-        n_aux = 7 if has_q else 6
-
-        def spec_body(params, draft_params, state, table, warp_rows):
-            def body(s, _):
-                return one_spec_step(s, params, draft_params, table,
-                                     warp_rows)
-
-            state, aux = jax.lax.scan(body, state, None, length=n_steps)
-            # same 4-flag harvest protocol as the vanilla chunk, plus the
-            # per-step [n_steps, B] draft/accept grids (and, for sampled
-            # drafters, the mean accept-probability grid) the host folds
-            # into telemetry on the sync it already pays
-            return state, (state.active, state.n_gen, state.max_gen,
-                           state.lens) + aux
-
-        if self._draft is None:
-
-            def spec_chunk(params, state, table, warp_rows):
-                return spec_body(params, None, state, table, warp_rows)
-
-        else:
-
-            def spec_chunk(params, draft_params, state, table, warp_rows):
-                return spec_body(params, draft_params, state, table,
-                                 warp_rows)
-
-        sharding_kw = self._jit_sharding(2)
-        if sharding_kw:
-            sharding_kw = dict(sharding_kw)
-            sharding_kw["out_shardings"] = (
-                sharding_kw["out_shardings"], (self._repl,) * n_aux
-            )
-        jitted = jax.jit(
-            spec_chunk, donate_argnums=(self._state_argnum,), **sharding_kw
-        )
-        self._jit_spec[key] = jitted
-        return jitted
-
     def _fold_chunk_aux(self, aux: tuple, chunk_attrs: dict):
-        """What a resolved chunk carries after its four harvest flags: a
-        speculative chunk's stat grids (two or three of them), or a
-        vanilla chunk's MoE routing census (one ``[3]`` vector)."""
-        if len(aux) == 1:
+        """What a resolved chunk carries after its four harvest flags: an
+        MoE model's routing census (one ``[3]`` vector), else nothing."""
+        if aux:
             hit, slots, load_max = (int(v) for v in aux[0])
             chunk_attrs["moe_experts_hit"] = hit
             chunk_attrs["moe_expert_slots"] = slots
@@ -2918,93 +2287,6 @@ class GenerationEngine:
             self.stats["moe_load_max"] = max(
                 self.stats["moe_load_max"], load_max
             )
-        elif aux:
-            self._fold_spec_stats(aux)
-
-    def _fold_spec_stats(self, aux):
-        """Fold one spec chunk's ``[n_steps, B]`` aux grids — drafted and
-        accepted counts, plus (for sampled/general-q drafters) the mean
-        per-position acceptance probability — into engine stats +
-        telemetry counters. Host bookkeeping riding the per-chunk sync
-        the engine already pays, no extra pulls."""
-        drafted = np.asarray(aux[0])
-        accepted = np.asarray(aux[1])
-        d = int(drafted.sum())
-        if d == 0:
-            return
-        acc = int(accepted.sum())
-        self.stats["spec_draft_tokens"] += d
-        self.stats["spec_accepted_tokens"] += acc
-        metrics_mod.counters.add(metrics_mod.GEN_SPEC_DRAFT_TOKENS, d)
-        metrics_mod.counters.add(metrics_mod.GEN_SPEC_ACCEPTED_TOKENS, acc)
-        vals, counts = np.unique(accepted[drafted > 0], return_counts=True)
-        for v, c in zip(vals, counts):
-            metrics_mod.counters.observe(
-                metrics_mod.GEN_SPEC_ACCEPT_LEN, float(v), n=int(c)
-            )
-        if self.spec_k_adapt:
-            # adaptive spec-K rides the same per-chunk fold: the window
-            # sees every (step, slot) accept length the histogram does
-            self._accept_window.extend(
-                accepted[drafted > 0].astype(np.float64).tolist()
-            )
-            self._maybe_adapt_spec_k()
-        if len(aux) > 2:
-            # general-q drafter: per-(step, slot) mean accept probability.
-            # The grid is CONTINUOUS floats (np.unique would give no
-            # compression, i.e. one lock-guarded observe per slot-step),
-            # so pre-bucket against the histogram's own edges and observe
-            # each occupied bucket once at its in-bucket mean — exact
-            # bucket placement (digitize right=True == the histogram's
-            # bisect_left) and exact total sum, <= n_edges+1 observes.
-            q_acc = np.asarray(aux[2])[drafted > 0]
-            idx = np.digitize(
-                q_acc, metrics_mod.SPEC_Q_ACCEPT_PROB_BOUNDARIES,
-                right=True,
-            )
-            for i in np.unique(idx):
-                sel = q_acc[idx == i]
-                metrics_mod.counters.observe(
-                    metrics_mod.GEN_SPEC_Q_ACCEPT_PROB,
-                    float(sel.mean()), n=int(sel.size),
-                )
-
-    def _maybe_adapt_spec_k(self):
-        """Retune ``spec_k`` from the windowed mean accept length (called
-        under the engine lock on the per-chunk stats fold, so the next
-        ``_decode_chunk_fn`` — same lock — sees the new K). K moves ONE
-        step within ``_spec_k_choices``, keeping jitted spec-chunk
-        specializations bounded by the fixed choice set; the UP/DOWN
-        hysteresis band (class constants) keeps a workload sitting at a
-        boundary from thrashing between two K programs. The window
-        resets on every retune so the new K is judged on its own
-        evidence, not the old K's accept lengths."""
-        if len(self._accept_window) < self.SPEC_K_ADAPT_WINDOW:
-            return
-        window = self._accept_window[-self.SPEC_K_ADAPT_WINDOW:]
-        mean_acc = sum(window) / len(window)
-        i = self._spec_k_choices.index(self.spec_k)
-        new_k = self.spec_k
-        if (
-            mean_acc >= self.SPEC_K_ADAPT_UP * self.spec_k
-            and i + 1 < len(self._spec_k_choices)
-        ):
-            new_k = self._spec_k_choices[i + 1]
-        elif mean_acc <= self.SPEC_K_ADAPT_DOWN * self.spec_k and i > 0:
-            new_k = self._spec_k_choices[i - 1]
-        if new_k != self.spec_k:
-            logger.info(
-                "adaptive spec-K: %d -> %d (windowed mean accept %.2f)",
-                self.spec_k, new_k, mean_acc,
-            )
-            self.spec_k = new_k
-            self._accept_window.clear()
-            metrics_mod.counters.gauge(
-                metrics_mod.GEN_SPEC_K_CURRENT, float(new_k)
-            )
-        else:
-            # bound the host-side window without numpy churn
-            del self._accept_window[: -self.SPEC_K_ADAPT_WINDOW]
 
     def _warp_bucket(self, n: int) -> int:
         """Power-of-two capacity bucket for the warping-slot index operand
@@ -3017,13 +2299,10 @@ class GenerationEngine:
             w *= 2
         return min(w, self.B)
 
-    def _decode_chunk_fn(self, decode_steps: int, running: List[int],
-                         chunk_attrs: dict):
-        """Pick the chunk program (spec or vanilla) and the per-slot warp
-        operand for one dispatch.
-        ``self.spec`` is read here, under the engine lock — flipping it
-        between chunks is safe and takes effect on the next dispatch
-        (both programs share one state pytree).
+    def _warp_operand(self, decode_steps: int, running: List[int],
+                      chunk_attrs: dict):
+        """Pick the per-slot warp operand of one dispatch, and whether its
+        chunk program carries the online top-k buffer.
 
         The host knows exactly which resident slots warp (``_warp_host``,
         set at admission), so the chunk receives their indices padded to a
@@ -3032,38 +2311,17 @@ class GenerationEngine:
         ``[B, V]`` sort (the old static ``warp=True`` key did exactly
         that)."""
         # fused routing (the fused epilogue, ``fused_sample_applies``): the
-        # vanilla chunk narrows the fallback bucket to the slots the online
-        # pass cannot serve (_fused_warp_host — top-p, top-k > TOPK_MAX); plain top-k slots
-        # ride the online buffer instead of the sort. The spec chunk's
-        # fused acceptance has no top-k buffer, so it keeps the full
-        # _warp_host bucket; draft-model (general-q) spec stays on the
-        # materialized verify path entirely.
-        fused_spec = self.fused and self._draft is None
-        fused_vanilla = self.fused
-        if not self.spec and fused_vanilla:
-            mirror = self._fused_warp_host
-        else:
-            mirror = self._warp_host
+        # chunk narrows the fallback bucket to the slots the online pass
+        # cannot serve (_fused_warp_host — top-p, top-k > TOPK_MAX); plain
+        # top-k slots ride the online buffer instead of the sort.
+        mirror = self._fused_warp_host if self.fused else self._warp_host
         warp_slots = [b for b in running if mirror[b]]
         wb = self._warp_bucket(len(warp_slots))
         warp_idx = np.full((wb,), self.B, np.int32)  # padding => scatter-drop
         warp_idx[: len(warp_slots)] = warp_slots
-        if self.spec:
-            fused_on = fused_spec
-
-            def make(n, w, b, _f=fused_spec):
-                return self._spec_chunk_fn(n, w, b, fused=_f)
-
-        else:
-            fused_on = fused_vanilla
-            tk = fused_vanilla and any(
-                self._fused_topk_host[b] for b in running
-            )
-
-            def make(n, w, b, _f=fused_vanilla, _tk=tk):
-                return self._chunk_fn(n, w, b, fused=_f, with_topk=_tk)
-
-        if fused_on:
+        with_topk = self.fused and any(
+            self._fused_topk_host[b] for b in running)
+        if self.fused:
             metrics_mod.counters.add(
                 metrics_mod.GEN_FUSED_SAMPLE_STEPS, decode_steps
             )
@@ -3078,7 +2336,7 @@ class GenerationEngine:
             chunk_attrs["sampler_fallback_rows"] = fallback
             self.stats["fused_rows"] += chunk_attrs["fused_rows"]
             self.stats["sampler_fallback_rows"] += fallback
-        return make, wb, warp_idx
+        return with_topk, wb, warp_idx
 
     def _dispatch_chunk(self, chunk, W: int, warp_idx) -> tuple:
         """Dispatch one decode chunk and START its harvest-flag D2H copy
@@ -3091,7 +2349,7 @@ class GenerationEngine:
         ``_steps_ahead`` output protocol: start the copy at dispatch,
         consume it later."""
         self.state, flags = chunk(
-            *self._model_args(), self.state,
+            self.params, self.state,
             jnp.asarray(self._table_arg(slice(None), W)),
             jnp.asarray(warp_idx),
         )
@@ -3181,21 +2439,19 @@ class GenerationEngine:
         )
 
     def _dispatch(self, decode_steps: int, ahead: int,
-                  chunk_attrs: dict) -> Tuple[Optional[tuple], int, List[int]]:
+                  chunk_attrs: dict) -> Tuple[Optional[tuple], List[int]]:
         """Seat the slots (``_seat``: who runs, with the pages the chunk
         writes), pick the chunk program for them and dispatch it, under
         its span. ``ahead``: tokens already dispatched but not yet in
         ``_lens_host`` (pipelined mode). Returns the flag handles (``None``:
-        no slot to run), the tokens a slot can advance in this chunk, and
-        the slots that run it."""
+        no slot to run) and the slots that run the chunk."""
         with tracing.span("gen_engine/dispatch") as attrs:
-            tok_bound = self._chunk_tokens(decode_steps)
             # (the host's lengths may lag one chunk behind: a lower bound,
             # so no live page of a window kind goes)
-            running = self._seat(ahead + tok_bound, chunk_attrs)
+            running = self._seat(ahead + decode_steps, chunk_attrs)
             if not running:
-                return None, tok_bound, running
-            make, wb, warp_idx = self._decode_chunk_fn(
+                return None, running
+            with_topk, wb, warp_idx = self._warp_operand(
                 decode_steps, running, chunk_attrs
             )
             lens = self._lens_host[running]
@@ -3208,7 +2464,7 @@ class GenerationEngine:
                 for kind, n in self.cache_bytes_per_token_by_kind().items():
                     chunk_attrs["cache_bytes_per_token_" + kind] = n
             # width-limit the chunk to the pages this chunk can touch
-            W = self._table_width(int(lens.max()) + ahead + tok_bound)
+            W = self._table_width(int(lens.max()) + ahead + decode_steps)
             attrs["table_width"] = W
             chunk_attrs["slots"] = len(running)
             # KV positions the decode kernel reads at the chunk's first
@@ -3239,11 +2495,11 @@ class GenerationEngine:
             if self._moe:
                 # every row of the batch routes, free slots too
                 chunk_attrs.update(self._count_moe_rows(
-                    self._chunk_moe_rows(), decode_steps * cfg.n_passes))
+                    self.B, decode_steps * cfg.n_passes))
             counts = self._kernel_counts(W)
             if counts is not None:
                 self.stats["resident_tokens"] += resident
-            if self._kv_write_rows() and not self.spec:
+            if self._kv_write_rows():
                 # one tile a (cache layer, running slot, step), as dispatched: a
                 # slot that finishes inside the chunk writes none after
                 counts = dict(
@@ -3255,8 +2511,9 @@ class GenerationEngine:
                 for name, n in counts.items():
                     self.stats[name] = self.stats.get(name, 0) + n
             self._observe_occupancy()
-            chunk = make(decode_steps, W, wb)
-            return self._dispatch_chunk(chunk, W, warp_idx), tok_bound, running
+            chunk = self._chunk_fn(
+                decode_steps, W, wb, fused=self.fused, with_topk=with_topk)
+            return self._dispatch_chunk(chunk, W, warp_idx), running
 
     def _kv_write_rows(self) -> int:
         """Rows of the pool tile that the ``kv_page_write`` kernel copies,
@@ -3275,8 +2532,8 @@ class GenerationEngine:
         return kv_page_write.tile_rows(cache.pages.dtype)
 
     def _kernel_counts(self, W: int) -> Optional[Dict[str, int]]:
-        """What the paged-decode kernel does at the first step of a vanilla
-        chunk of table width ``W``, from the kernel's own block plan over
+        """What the paged-decode kernel does at the first step of a chunk
+        of table width ``W``, from the kernel's own block plan over
         the host's lengths, sorted as ``decode_step_paged`` sorts its rows.
         ``kernel_positions``: KV positions its body runs over (over
         ``resident_tokens``, how many times the resident KV it computes);
@@ -3287,7 +2544,7 @@ class GenerationEngine:
         reached step of an earlier block. Free slots
         count as empty (on the device a finished slot keeps its length
         until it is refilled). ``None`` where the chunk runs no such
-        kernel: the XLA gather path, a speculative chunk."""
+        kernel: the XLA gather path."""
         cfg = self.cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
         pool_dtype = self.state.cache.pages.dtype
@@ -3301,7 +2558,7 @@ class GenerationEngine:
                 self._decode_use_pallas, width, heads, self.page,
                 pool_dtype, tp,
             )
-        if self.spec or not applies:
+        if not applies:
             return None
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
@@ -3399,7 +2656,7 @@ class GenerationEngine:
                 if self._pipeline:
                     return self._step_pipelined(decode_steps, span_attrs)
                 self._admit()
-                flags, _, running = self._dispatch(
+                flags, running = self._dispatch(
                     decode_steps, 0, span_attrs)
                 if flags is None:
                     return []
@@ -3428,19 +2685,18 @@ class GenerationEngine:
             outs = self._settle(span_attrs)
         # _lens_host can be one in-flight chunk stale for continuing
         # slots: widen the bound by the TOKENS already dispatched
-        # (a spec chunk advances up to decode_steps * (K+1) of them)
-        new_flags, new_ahead, running = self._dispatch(
+        new_flags, running = self._dispatch(
             decode_steps, self._steps_ahead, span_attrs
         )
         new_running = tuple((b, int(self._slot_epoch[b])) for b in running)
         prev_flags = self._prev_flags
         if prev_flags is None:
             self._prev_flags, self._prev_running = new_flags, new_running
-            self._steps_ahead = new_ahead if running else 0
+            self._steps_ahead = decode_steps if running else 0
             return outs
         outs = self._settle(span_attrs)
         self._prev_flags, self._prev_running = new_flags, new_running
-        self._steps_ahead = new_ahead if running else 0
+        self._steps_ahead = decode_steps if running else 0
         return outs
 
     def _pool_is_short(self, decode_steps: int) -> bool:
@@ -3449,7 +2705,7 @@ class GenerationEngine:
         to the pages that chunk writes."""
         if self._held_out:
             return True
-        span = self._steps_ahead + self._chunk_tokens(decode_steps)
+        span = self._steps_ahead + decode_steps
         occupied = np.nonzero(self._n_total)[0]
         short = np.maximum(
             self._pages_ahead(occupied, span) - self._reserved[:, occupied], 0)

@@ -4,14 +4,6 @@ Counterpart of ``realhf/impl/model/utils/logits_warper.py`` (225 LoC) and the
 sampling half of ``genstep`` (``real_llm_generate.py:30``): temperature,
 top-k, top-p, greedy — vectorized over a slot batch, jit-friendly (no
 data-dependent shapes; top-p uses sort + cumulative mass masking).
-
-``spec_rejection_sample`` is the speculative-decoding acceptance step
-(Leviathan et al. 2023): given target logits at K+1 positions and K draft
-tokens, accept the longest valid draft prefix and sample one residual
-token from the normalized difference distribution — all vectorized over
-the slot batch, no host sync. The emitted-token marginal equals the
-target distribution exactly (see docs/performance.md "Speculative
-decoding"), which is what makes spec decode PPO-safe.
 """
 
 import dataclasses
@@ -88,7 +80,7 @@ def warp_logits_rows(
     """Warp ONLY the slots named by ``rows`` (host-known warping-slot
     indices, padded with an out-of-range index): the sort — the dominant
     cost of a decode step at a 152k vocab — runs over ``[W, V]`` (or
-    ``[W*C, V]`` for the spec-verify ``[B, C, V]`` shape) where W is the
+    ``[W*C, V]`` for ``[B, C, V]`` logits) where W is the
     warping-slot bucket, never the whole batch; every other slot gets the
     plain temperature scaling of the ``warp=False`` path. Exactly
     equivalent per row to full-batch :func:`warp_logits` /
@@ -115,12 +107,9 @@ def warp_logits_rows(
 
 
 def warp_logits_multi(logits: jnp.ndarray, sp: SamplingParams) -> jnp.ndarray:
-    """Warp ``[B, C, V]`` logits (C query positions per slot, the spec-decode
-    verify shape) with per-SLOT sampling params. ONE ``[B*C, V]`` sort serves
-    every position of every slot — the per-position formulation paid the
-    dominant sort cost C times; callers that know no slot warps skip this
-    entirely (``spec_rejection_sample(warp=False)``, mirroring
-    ``sample_tokens``'s static ``warp`` contract)."""
+    """Warp ``[B, C, V]`` logits (C query positions per slot) with per-SLOT
+    sampling params. ONE ``[B*C, V]`` sort serves every position of every
+    slot."""
     B, C, V = logits.shape
     flat_sp = SamplingParams(
         temperature=jnp.repeat(sp.temperature, C),
@@ -128,134 +117,6 @@ def warp_logits_multi(logits: jnp.ndarray, sp: SamplingParams) -> jnp.ndarray:
         top_k=jnp.repeat(sp.top_k, C),
     )
     return warp_logits(logits.reshape(B * C, V), flat_sp).reshape(B, C, V)
-
-
-def spec_rejection_sample(
-    rng: jax.Array,
-    logits: jnp.ndarray,        # [B, C, V] target logits; C = K + 1
-    draft: jnp.ndarray,         # [B, K] proposed tokens
-    sp: SamplingParams,
-    warp: bool = True,
-    greedy: Optional[jnp.ndarray] = None,
-    q_logprobs: Optional[jnp.ndarray] = None,  # [B, K, V] proposal logprobs
-    warp_rows: Optional[jnp.ndarray] = None,   # [W] warping-slot indices
-    return_accept_prob: bool = False,
-) -> Tuple[jnp.ndarray, ...]:
-    """Speculative-decoding acceptance: accept a prefix of the draft, then
-    sample ONE residual token from the normalized difference distribution.
-
-    ``logits[:, i]`` is the target distribution for the token FOLLOWING
-    chunk position ``i`` (chunk = [last_token, d_1..d_K]), so ``logits[:, i]``
-    scores ``draft[:, i]`` and ``logits[:, K]`` is the bonus distribution
-    when every draft token is accepted.
-
-    ``q_logprobs`` is the proposal distribution per draft position; ``None``
-    means a DETERMINISTIC drafter (one-hot proposal — the self-drafting
-    n-gram baseline): accept probability reduces to ``p(d)`` and the
-    residual to ``p`` with the rejected token removed, renormalized. Both
-    forms are exactly distribution-preserving: the marginal of each emitted
-    token equals the (warped) target distribution.
-
-    Greedy slots (``sp.temperature <= 0`` or explicit ``greedy``) accept a
-    draft token iff it equals the raw-logits argmax and emit the argmax as
-    the residual — token-identical to vanilla greedy decode.
-
-    Returns ``(accept_len [B] i32 in [0, K], tokens [B, C] i32,
-    logprobs [B, C] f32, boundary_argmax [B] i32)``: positions
-    ``i < accept_len`` hold accepted draft tokens, position ``accept_len``
-    the residual/bonus token, later positions garbage (callers mask by
-    their emit length). ``logprobs`` are w.r.t. the *warped target*
-    distribution at each position — the same semantics vanilla
-    ``sample_tokens`` reports, so PPO consumes spec and vanilla
-    trajectories identically. ``boundary_argmax`` is the target argmax at
-    the emission boundary (the engine's drafter-fallback hint).
-
-    ``return_accept_prob`` (STATIC) appends ``accept_prob [B, K] f32`` —
-    the per-position acceptance probability ``min(1, p(d_i)/q(d_i))``
-    (the 0/1 accept indicator for greedy slots): the draft-model quality
-    signal the engine folds into the ``gen/spec_q_accept_prob``
-    histogram, independent of where the first rejection happened to
-    land this step.
-    """
-    B, C, V = logits.shape
-    K = C - 1
-    if not warp:
-        warped = _plain_temperature(logits, sp)
-    elif warp_rows is not None:
-        # host-known warping slots: only their rows pay the sort
-        warped = warp_logits_rows(logits, sp, warp_rows)
-    else:
-        warped = warp_logits_multi(logits, sp)
-    logp = jax.nn.log_softmax(warped, axis=-1)               # [B, C, V]
-    argmax = jnp.argmax(logits, axis=-1).astype(jnp.int32)   # [B, C]
-    if greedy is None:
-        greedy = sp.temperature <= 0.0
-    r_acc, r_res = jax.random.split(rng)
-
-    draft_lp = jnp.take_along_axis(
-        logp[:, :K], draft[..., None], axis=-1
-    )[..., 0]                                                # [B, K]
-    # accept d_i with prob min(1, p(d_i)/q(d_i)); deterministic drafts have
-    # q(d_i) = 1 so the threshold is p(d_i) itself
-    log_ratio = draft_lp
-    if q_logprobs is not None:
-        q_lp = jnp.take_along_axis(
-            q_logprobs, draft[..., None], axis=-1
-        )[..., 0]
-        log_ratio = draft_lp - q_lp
-    u = jax.random.uniform(r_acc, draft.shape, minval=1e-20)
-    accept = jnp.where(
-        greedy[:, None], draft == argmax[:, :K], jnp.log(u) < log_ratio
-    )
-    # longest accepted prefix (first rejection stops everything after it)
-    accept_len = jnp.cumprod(accept.astype(jnp.int32), axis=1).sum(axis=1)
-
-    # residual/bonus row at the emission boundary
-    a = accept_len
-    row_w = jnp.take_along_axis(warped, a[:, None, None], axis=1)[:, 0]
-    row_lp = jnp.take_along_axis(logp, a[:, None, None], axis=1)[:, 0]
-    boundary_argmax = jnp.take_along_axis(argmax, a[:, None], axis=1)[:, 0]
-    rejected = a < K                                         # else: bonus
-    rej_tok = jnp.take_along_axis(
-        draft, jnp.minimum(a, K - 1)[:, None], axis=1
-    )[:, 0]
-    if q_logprobs is None:
-        # one-hot proposal: residual ∝ max(p - onehot(d), 0) = p with the
-        # rejected token zeroed, renormalized
-        res_logits = jnp.where(
-            rejected[:, None]
-            & (jnp.arange(V)[None, :] == rej_tok[:, None]),
-            NEG_INF, row_w,
-        )
-        sampled = jax.random.categorical(r_res, res_logits, axis=-1)
-    else:
-        q_row = jnp.take_along_axis(
-            q_logprobs, jnp.minimum(a, K - 1)[:, None, None], axis=1
-        )[:, 0]                                              # [B, V]
-        resid = jnp.maximum(jnp.exp(row_lp) - jnp.exp(q_row), 0.0)
-        # bonus position (a == K) samples the plain target distribution
-        resid = jnp.where(rejected[:, None], resid, jnp.exp(row_lp))
-        sampled = jax.random.categorical(
-            r_res, jnp.log(jnp.maximum(resid, 1e-30)), axis=-1
-        )
-    res_tok = jnp.where(greedy, boundary_argmax, sampled).astype(jnp.int32)
-    res_lp = jnp.take_along_axis(row_lp, res_tok[:, None], axis=1)[:, 0]
-
-    pos = jnp.arange(C)[None, :]
-    draft_pad = jnp.concatenate([draft, draft[:, -1:]], axis=1)
-    dlp_pad = jnp.concatenate([draft_lp, draft_lp[:, -1:]], axis=1)
-    tokens = jnp.where(
-        pos < a[:, None], draft_pad, res_tok[:, None]
-    ).astype(jnp.int32)
-    lps = jnp.where(pos < a[:, None], dlp_pad, res_lp[:, None])
-    if return_accept_prob:
-        acc_p = jnp.where(
-            greedy[:, None],
-            accept.astype(jnp.float32),
-            jnp.minimum(jnp.exp(log_ratio), 1.0),
-        )
-        return a.astype(jnp.int32), tokens, lps, boundary_argmax, acc_p
-    return a.astype(jnp.int32), tokens, lps, boundary_argmax
 
 
 def sample_tokens(

@@ -15,11 +15,7 @@ surface the rollout side depends on —
   interrupted (clients re-submit, ≈ the SGLang ``InterruptAllReq`` patch) →
   reload params from an HF checkpoint dir → resume. Returns ``num_paused``.
 - ``POST /pause_generation`` / ``POST /continue_generation``.
-- ``POST /spec_decode``: toggle speculative decoding between chunks (the
-  manager's lever when a workload's accept rate collapses below breakeven —
-  spec decode is distribution-preserving, so flipping it mid-serve is safe).
-- ``GET /health``, ``GET /metrics_json`` (running/served counters, version,
-  spec-decode accept rate).
+- ``GET /health``, ``GET /metrics_json`` (running/served counters, version).
 
 The engine's jitted chunks execute in a thread-pool executor so the asyncio
 loop stays responsive; one background task drives admission/decode
@@ -178,7 +174,6 @@ class GenerationHTTPServer:
         )
         app.router.add_post("/pause_generation", self._pause)
         app.router.add_post("/continue_generation", self._continue)
-        app.router.add_post("/spec_decode", self._spec_decode)
         app.router.add_get("/health", self._health)
         app.router.add_get("/metrics_json", self._metrics)
 
@@ -496,23 +491,10 @@ class GenerationHTTPServer:
     async def _update_weights(self, request: web.Request) -> web.Response:
         d = await request.json()
         path = d["model_path"]
-        # draft ride-along (docs/performance.md "Speculative decoding"):
-        # the weight-fanout channel may push refreshed draft weights next
-        # to the policy weights so the draft model keeps tracking the
-        # policy during RL — both swap in the same pause window
-        draft_path = d.get("draft_model_path")
-        if draft_path and self.engine._draft is None:
-            return web.json_response({
-                "success": False,
-                "message": "draft_model_path given but the engine has no "
-                           "draft model configured",
-                "num_paused_requests": 0,
-            })
         allow_interrupt = bool(d.get("allow_interrupt", True))
         overlap_load = bool(d.get("overlap_load", self.overlap_load))
         loop = asyncio.get_event_loop()
         params = None
-        draft_host_params = None
         if overlap_load:
             # OVERLAPPED reload (r5, VERDICT r4 #3): read the checkpoint
             # and stage it on device while the engine keeps decoding — the
@@ -526,10 +508,6 @@ class GenerationHTTPServer:
                 params = await loop.run_in_executor(
                     None, self._load_params, path
                 )
-                if draft_path:
-                    draft_host_params = await loop.run_in_executor(
-                        None, self._load_draft_host_params, draft_path
-                    )
             except Exception as e:  # noqa: BLE001 - reported to the manager
                 logger.exception("weight load failed (engine untouched)")
                 return web.json_response({
@@ -566,14 +544,7 @@ class GenerationHTTPServer:
                     params = await loop.run_in_executor(
                         None, self._load_params, path
                     )
-                if draft_path and draft_host_params is None:
-                    draft_host_params = await loop.run_in_executor(
-                        None, self._load_draft_host_params, draft_path
-                    )
-                self.engine.update_params(
-                    params, version=d.get("version"),
-                    draft_params=draft_host_params,
-                )
+                self.engine.update_params(params, version=d.get("version"))
                 ok = True
                 msg = f"loaded weights from {path}"
             except Exception as e:  # noqa: BLE001 - reported to the manager
@@ -595,30 +566,6 @@ class GenerationHTTPServer:
         # cast + (when TP-sharded) mesh placement
         return self.engine.prepare_params(host_params)
 
-    def _load_draft_host_params(self, path: str):
-        """Read a refreshed draft checkpoint (host pytree; the engine's
-        update_params casts + TP-shards it under its own lock). The
-        checkpoint must match the SERVING draft's architecture exactly —
-        the engine's jitted programs and draft KV pool were built from
-        ``draft_cfg``, so a different shape would swap in cleanly
-        (device_put carries no shape contract) and only explode at the
-        next chunk's retrace, long after this endpoint reported success."""
-        from areal_tpu.models import hf as hf_conv
-
-        cfg, host_params = hf_conv.load_hf_checkpoint(path)
-        ecfg = self.engine.draft_cfg
-        for f in (
-            "vocab_size", "n_layers", "n_q_heads", "n_kv_heads",
-            "head_dim", "hidden_dim", "intermediate_dim",
-        ):
-            if getattr(cfg, f) != getattr(ecfg, f):
-                raise ValueError(
-                    f"draft checkpoint {f} ({getattr(cfg, f)}) != serving "
-                    f"draft's ({getattr(ecfg, f)}) — a draft refresh must "
-                    "keep the architecture the engine was built with"
-                )
-        return host_params
-
     async def _pause(self, request: web.Request) -> web.Response:
         async with self._lock:
             interrupted = self.engine.pause()
@@ -628,22 +575,6 @@ class GenerationHTTPServer:
     async def _continue(self, request: web.Request) -> web.Response:
         self.engine.resume()
         return web.json_response({"success": True})
-
-    async def _spec_decode(self, request: web.Request) -> web.Response:
-        """Toggle speculative decoding. Takes effect at the next chunk
-        dispatch (the engine reads the flag under its lock per step);
-        in-flight chunks finish under their dispatched program."""
-        try:
-            d = await request.json()
-            enabled = bool(d["enabled"])
-        except (KeyError, TypeError, ValueError) as e:
-            return web.json_response({"error": repr(e)}, status=400)
-        self.engine.spec = enabled
-        return web.json_response({
-            "success": True,
-            "spec_decode": self.engine.spec,
-            "spec_k": self.engine.spec_k,
-        })
 
     async def _health(self, request: web.Request) -> web.Response:
         return web.json_response({"status": "ok"})
@@ -697,30 +628,9 @@ class GenerationHTTPServer:
             "weight_load_overlapped_s": round(self._t_weight_load, 3),
             "n_weight_updates": self._n_weight_updates,
             "n_interrupted": self._n_interrupted,
-            # speculative decoding: config + realized accept rate (the
-            # breakeven signal a manager would act on via /spec_decode)
-            "spec_decode": self.engine.spec,
-            "spec_k": self.engine.spec_k,
-            # adaptive spec-K: whether retuning is on and the CURRENT K
-            # (spec_k_current == spec_k; kept as its own field so scrapers
-            # tracking the gen/spec_k_current gauge read one name)
-            "spec_k_adapt": self.engine.spec_k_adapt,
-            "spec_k_current": self.engine.spec_k,
             # fused sampling epilogue (docs/performance.md): streamed
             # LM-head sampling on the decode chunk
             "fused_sample": self.engine.fused,
-            "spec_accept_rate": round(
-                self.engine.stats["spec_accepted_tokens"]
-                / max(self.engine.stats["spec_draft_tokens"], 1), 4
-            ),
-            # draft-MODEL spec decode (docs/performance.md): whether a
-            # TransformerDrafter is configured, its weight generation,
-            # and the draft pool's HBM gauges (pages move in lockstep
-            # with the target pool, so occupancy is shared)
-            "spec_draft_model": self.engine._draft is not None,
-            "draft_version": self.engine.draft_version,
-            "draft_kv_dtype": self.engine.draft_kv_dtype,
-            "draft_kv_pool_bytes": self.engine.draft_kv_pool_bytes(),
             **{f"engine_{k}": v for k, v in self.engine.stats.items()},
         }
 

@@ -1577,9 +1577,9 @@ def _write_chunk_kv(
     """Every layer's fresh K/V into the pool, ONCE, after the layer scan:
     row ``b``'s tokens ``c < count[b]`` of the chunk land at positions
     ``start[b] + c`` of its pages (``count`` 0: a free or finished slot, a
-    padding row; nothing of it is written). All three step functions end
+    padding row; nothing of it is written). Both step functions end
     here: one token a row (``decode_step_paged``), admission's chunks
-    (``extend_paged``), a speculative verify pass (``verify_step_paged``).
+    (``extend_paged``).
 
     Which path runs where (``ops/paged_attention.py:
     kv_write_kernel_applies``, one predicate over what is observable, no
@@ -1714,16 +1714,13 @@ def _extend_layers(
     start: jnp.ndarray,      # [B]
     n_new: jnp.ndarray,      # [B]
     skip_pool: bool = False,
-    verify: bool = False,
     moe_grouped: bool = False,
     ssm: Optional[SSMState] = None,
     slots: Optional[jnp.ndarray] = None,
 ):
-    """Shared multi-token layer scan over the page pool (chunked prefill
-    AND the spec-decode verify pass — one implementation, two attention
-    entry points). Returns ``(x [B, C, E] pre-final-norm hidden, ks,
-    vs, ssm_rows)``; the caller writes the KV and (for verify) applies the
-    head. ``moe_grouped`` (STATIC): the routed experts run as the
+    """The multi-token layer scan over the page pool (chunked prefill).
+    Returns ``(ks, vs, ssm_rows)``; the caller writes the KV.
+    ``moe_grouped`` (STATIC): the routed experts run as the
     grouped-matmul kernel over the whole stack (``ops/moe.py``; the caller
     asks ``moe_grouped_applies``).
 
@@ -1734,12 +1731,6 @@ def _extend_layers(
     B, G, K, N, 128], conv [Ls, B, (d_conv - 1) x C])`` after them, for the
     caller to put back; None for a model without such layers."""
     from areal_tpu.ops import paged_attention as paged_ops
-
-    if cfg.ssm is not None and verify:
-        raise NotImplementedError(
-            "state-space layers: a verify pass would have to roll the "
-            "recurrent state back past a rejected draft"
-        )
 
     routed = None
     if moe_grouped:
@@ -1758,13 +1749,8 @@ def _extend_layers(
             sliding_window=kinds[j][0],
             scales=cache.scales,
         )
-        tbl = _kind_table(table, j)
-        if verify:
-            return paged_ops.paged_verify_attention(
-                q, k, v, cache.pages, li, tbl, start, n_new, **kw
-            )
         return paged_ops.paged_extend_attention(
-            q, k, v, cache.pages, li, tbl, start, n_new,
+            q, k, v, cache.pages, li, _kind_table(table, j), start, n_new,
             skip_pool=skip_pool, **kw,
         )
 
@@ -1812,12 +1798,12 @@ def _extend_layers(
         return (x, li + int(j == len(kinds) - 1), *rest), (k, v)
 
     zero = jnp.int32(0)
-    (x, *_), (ks, vs), ssm_rows = _run_stack(
+    _, (ks, vs), ssm_rows = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, zero) if cfg.ssm is None else (x, zero, zero), params,
         ssm_layer=ssm_layer,
     )
-    return x, ks, vs, ssm_rows
+    return ks, vs, ssm_rows
 
 
 def _kind_table(table, j: int):
@@ -1852,7 +1838,7 @@ def extend_paged_kv(
     ``slots`` (a model with state-space layers): the per-slot state and
     each row's slot; the result is then ``(ks, vs, ssm_rows)``, the rows'
     state after the chunk (:func:`_extend_layers`)."""
-    _, ks, vs, ssm_rows = _extend_layers(
+    ks, vs, ssm_rows = _extend_layers(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         moe_grouped=moe_grouped, ssm=ssm, slots=slots,
     )
@@ -1901,59 +1887,6 @@ def put_ssm_rows(ssm: SSMState, slots, rows) -> SSMState:
         for a, v in zip((ssm.ssm, ssm.conv), rows)))
 
 
-def verify_step_paged(
-    params: Params,
-    cfg: ModelConfig,
-    cache: PagedKVCache,
-    tokens: jnp.ndarray,       # [B, C] verify chunk: [last_token, d_1..d_K]
-    table: jnp.ndarray,        # [B, M]
-    lens: jnp.ndarray,         # [B] resident tokens (chunk starts here)
-    n_new: jnp.ndarray,        # [B] C where the slot is active, 0 otherwise
-    n_write: jnp.ndarray,      # [B] how many chunk positions' KV may land
-    return_hidden: bool = False,
-    use_pallas: Optional[bool] = None,
-    mesh=None,
-    moe_grouped: bool = False,
-) -> Tuple[jnp.ndarray, PagedKVCache]:
-    """Speculative-decode VERIFY: ``decode_step_paged`` generalized to C =
-    K+1 query tokens per slot in ONE pass — one params read and one pool
-    sweep score the whole draft, where vanilla decode pays both per token.
-    Returns fp32 logits ``[B, C, V]`` (position ``i`` is the distribution
-    for the token following ``tokens[:, i]``) and the cache with the
-    chunk's KV written for the first ``n_write`` positions of each row.
-
-    ``return_hidden=True`` (STATIC) returns the final-NORM hidden states
-    ``[B, C, E]`` instead of logits: the fused epilogue
-    (``ops/fused_sample.py``; the engine asks for it where
-    ``fused_sample_applies`` says so) streams the head over vocab blocks
-    itself, so the ``[B, C, V]`` logits never materialize.
-
-    ``n_write`` is the acceptance-agnostic residency bound the engine
-    computes (position ``i`` lands where the slot is active and ``n_gen +
-    i < max_gen``: a PREFIX of the chunk, so a count says it, as ``n_new``
-    does for admission): rejected drafts' KV
-    lands in pool positions beyond the post-acceptance ``lens``, which
-    attention never reads (``pos < lens``) and later steps overwrite
-    before ``lens`` reaches them — so the scatter can run BEFORE the
-    accept/reject decision, keeping the whole spec step inside one jitted
-    chunk with no host sync. The bound only exists to keep writes inside
-    the slot's allocated pages (a position past ``max_gen`` could fall off
-    the page table and alias page 0). ``use_pallas`` / ``mesh`` choose the
-    KV write's path only (:func:`_write_chunk_kv`); ``moe_grouped``
-    (STATIC): see :func:`_extend_layers`."""
-    x, ks, vs, _ = _extend_layers(
-        params, cfg, cache, tokens, table, lens, n_new, verify=True,
-        moe_grouped=moe_grouped,
-    )
-    cache = _write_chunk_kv(
-        cache, ks, vs, table, lens, n_write, use_pallas, mesh
-    )
-    x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
-    if return_hidden:
-        return x, cache
-    return _head(cfg, params, x), cache
-
-
 def _length_order(lens: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(order, inverse)``: the batch's rows by ascending resident length
     (stable: equal lengths keep slot order, so a batch of one length is
@@ -1972,13 +1905,12 @@ def decode_step_paged(
     active: jnp.ndarray,       # [B] bool
     use_pallas: Optional[bool] = None,
     mesh=None,
-    with_head: bool = True,
     return_hidden: bool = False,
     with_routing: bool = False,
     moe_grouped: bool = False,
     ssm: Optional[SSMState] = None,
     ssm_update=None,
-) -> Tuple[Optional[jnp.ndarray], PagedKVCache, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, PagedKVCache, jnp.ndarray]:
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
     cache, new lens — incremented where active). The pool is read-only in
     the layer scan; each layer's fresh K/V merges into attention as the
@@ -1999,12 +1931,6 @@ def decode_step_paged(
     axis — each model shard runs Pallas on its local pool slice —
     because bare ``pallas_call`` has no GSPMD partitioning rule and would
     otherwise force a full-pool all-gather.
-
-    ``with_head=False`` (STATIC) skips the final norm + LM head and
-    returns ``None`` logits: the cache-maintenance step the engine's
-    vanilla chunk runs for a configured draft model only needs the KV
-    writes — the head matmul (the biggest single matmul of a small
-    model's step at a 152k vocab) would be dead weight.
 
     ``return_hidden=True`` (STATIC) returns the final-norm HIDDEN states
     ``[B, E]`` in place of logits for the fused epilogue
@@ -2145,8 +2071,6 @@ def decode_step_paged(
         extra = ()
     if cfg.ssm is not None:
         extra += (rest[-1],)
-    if not with_head:
-        return (None, cache, new_lens) + extra
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     if return_hidden:
         return (x, cache, new_lens) + extra

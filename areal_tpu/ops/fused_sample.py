@@ -19,14 +19,11 @@ per-row state —
   updates keep the first maximum, matching ``jnp.argmax`` tie order);
 - a running **Gumbel-top-1** argmax over ``warped + G`` (``G`` iid Gumbel,
   derived per block from the PRNG key) IS a categorical sample from
-  ``softmax(warped)`` — distribution-exact, no ``[B, V]`` materialization,
-  with an optional per-row *excluded* token (the speculative residual
-  "p with the rejected token removed, renormalized");
+  ``softmax(warped)`` — distribution-exact, no ``[B, V]`` materialization;
 - an optional running top-``TOPK_MAX`` (value, index) buffer merged per
   block via ``lax.top_k`` serves top-k slots exactly (for ``k <=
   TOPK_MAX``): the final sample is a cheap ``[R, TOPK_MAX]`` categorical
-  over the masked buffer;
-- a per-row gathered warped logit (the speculative draft-token score).
+  over the masked buffer.
 
 Top-p slots are NOT handled here — they keep the sorted reference path
 via the engine's warp-row bucket machinery (PR 9), so only those rows pay
@@ -107,8 +104,6 @@ def _update_block(
     col0,                          # scalar (may be traced): first column id
     key_blk: jax.Array,
     t: jnp.ndarray,                # [R] f32 temperature (floored)
-    exclude: Optional[jnp.ndarray],
-    gather_ids: Optional[jnp.ndarray],
     kmax: int,
 ) -> Dict[str, jnp.ndarray]:
     """Fold one vocab block into the online per-row state."""
@@ -138,8 +133,6 @@ def _update_block(
         key_blk, warped.shape, minval=jnp.finfo(jnp.float32).tiny, maxval=1.0
     )
     pert = warped - jnp.log(-jnp.log(u))
-    if exclude is not None:
-        pert = jnp.where(cols[None, :] == exclude[:, None], _MASK, pert)
     pbi = jnp.argmax(pert, axis=-1)
     pbv = jnp.take_along_axis(pert, pbi[:, None], axis=-1)[:, 0]
     pwv = jnp.take_along_axis(warped, pbi[:, None], axis=-1)[:, 0]
@@ -149,14 +142,6 @@ def _update_block(
     out["g_i"] = jnp.where(
         upd2, (col0 + pbi).astype(jnp.int32), c["g_i"]
     ).astype(jnp.int32)
-
-    if gather_ids is not None:
-        hit = cols[None, :] == gather_ids[:, None]
-        out["gat"] = jnp.where(
-            hit.any(axis=-1),
-            jnp.sum(jnp.where(hit, warped, 0.0), axis=-1),
-            c["gat"],
-        )
 
     if "topv" in c:
         cat_v = jnp.concatenate([c["topv"], warped], axis=-1)
@@ -170,8 +155,7 @@ def _update_block(
 
 
 def _fused_sample_xla(
-    rng, x, w, temperature, greedy, soft_cap, topk, exclude, gather_ids,
-    block_size, kmax,
+    rng, x, w, temperature, greedy, soft_cap, topk, block_size, kmax,
 ) -> Dict[str, jnp.ndarray]:
     R, E = x.shape
     V = w.shape[1]
@@ -188,8 +172,6 @@ def _fused_sample_xla(
         "g_w": jnp.zeros((R,), jnp.float32),
         "g_i": jnp.zeros((R,), jnp.int32),
     }
-    if gather_ids is not None:
-        carry["gat"] = jnp.full((R,), _MASK, jnp.float32)
     if topk is not None:
         carry["topv"] = jnp.full((R, kmax), _MASK, jnp.float32)
         carry["topi"] = jnp.zeros((R, kmax), jnp.int32)
@@ -205,7 +187,7 @@ def _fused_sample_xla(
             w_blk = jax.lax.dynamic_slice(w, (0, j * block), (E, block))
             c = _update_block(
                 c, _logits(w_blk), j * block, jax.random.fold_in(rng, j),
-                t, exclude, gather_ids, kmax,
+                t, kmax,
             )
             return c, None
 
@@ -214,7 +196,7 @@ def _fused_sample_xla(
         w_blk = jax.lax.slice(w, (0, nbf * block), (E, V))
         carry = _update_block(
             carry, _logits(w_blk), nbf * block,
-            jax.random.fold_in(rng, nbf), t, exclude, gather_ids, kmax,
+            jax.random.fold_in(rng, nbf), t, kmax,
         )
 
     norm = carry["m"] + jnp.log(carry["l"])
@@ -237,15 +219,12 @@ def _fused_sample_xla(
         use_k = (topk <= kmax) & ~greedy
         tokens = jnp.where(use_k, tok_k, tokens)
         lp = jnp.where(use_k, lp_k, lp)
-    out = {
+    return {
         "tokens": tokens.astype(jnp.int32),
         "logprobs": lp.astype(jnp.float32),
         "argmax": carry["am_i"],
         "norm": norm,
     }
-    if gather_ids is not None:
-        out["gathered_lp"] = carry["gat"] - norm
-    return out
 
 
 def fused_sample(
@@ -256,8 +235,6 @@ def fused_sample(
     greedy: jnp.ndarray,           # [R] bool
     soft_cap: Optional[float] = None,
     topk: Optional[jnp.ndarray] = None,    # [R] i32; > TOPK_MAX => inactive
-    exclude: Optional[jnp.ndarray] = None,  # [R] i32 token to mask (-1 none)
-    gather_ids: Optional[jnp.ndarray] = None,  # [R] i32 token to score
     block_size: Optional[int] = None,
     use_pallas: Optional[bool] = None,
     mesh=None,
@@ -267,13 +244,10 @@ def fused_sample(
 
     Returns a dict: ``tokens`` [R] i32 (greedy rows: exact raw argmax;
     rows with active ``topk``: exact top-k sample; others: Gumbel-top-1
-    categorical over the temperature-warped head, minus the optional
-    ``exclude`` token), ``logprobs`` [R] f32 w.r.t. the warped (and, for
-    top-k rows, top-k-restricted) distribution — the same semantics
-    ``sample_tokens`` reports — plus ``argmax`` [R] i32 (raw argmax),
-    ``norm`` [R] f32 (warped log-normalizer) and, when ``gather_ids`` is
-    given, ``gathered_lp`` [R] f32 (warped logprob of the gathered token,
-    the speculative draft score).
+    categorical over the temperature-warped head), ``logprobs`` [R] f32
+    w.r.t. the warped (and, for top-k rows, top-k-restricted) distribution
+    — the same semantics ``sample_tokens`` reports — plus ``argmax`` [R]
+    i32 (raw argmax) and ``norm`` [R] f32 (warped log-normalizer).
 
     ``use_pallas=None`` auto-detects: the TPU kernel runs when there is no
     top-k buffer and no mesh; everywhere else the streamed XLA path runs
@@ -310,85 +284,11 @@ def fused_sample(
         from areal_tpu.ops.pallas import fused_sample as _pk
 
         return _pk.fused_sample_pallas(
-            rng, x, w, temperature, greedy,
-            exclude=exclude, gather_ids=gather_ids, soft_cap=soft_cap,
+            rng, x, w, temperature, greedy, soft_cap=soft_cap,
             block_v=block_size, interpret=interpret,
         )
     return _fused_sample_xla(
-        rng, x, w, temperature, greedy, soft_cap, topk, exclude,
-        gather_ids, 2048 if block_size is None else block_size, TOPK_MAX,
+        rng, x, w, temperature, greedy, soft_cap, topk,
+        2048 if block_size is None else block_size, TOPK_MAX,
     )
 
-
-def fused_spec_rejection(
-    rng: jax.Array,
-    hidden: jnp.ndarray,           # [B, C, E] final-norm verify hidden
-    w: jnp.ndarray,                # [E, V]
-    draft: jnp.ndarray,            # [B, K] proposed tokens
-    sp,                            # SamplingParams
-    greedy: Optional[jnp.ndarray] = None,
-    soft_cap: Optional[float] = None,
-    block_size: Optional[int] = None,
-    use_pallas: Optional[bool] = None,
-    mesh=None,
-):
-    """Speculative rejection sampling from the streamed head — the fused
-    counterpart of ``gen/sampling.py::spec_rejection_sample`` for
-    DETERMINISTIC (one-hot) drafters, fed final-norm verify hidden states
-    instead of materialized ``[B, C, V]`` logits.
-
-    One fused pass over the ``B * C`` rows yields, per position: the
-    draft token's warped target logprob (the acceptance threshold), the
-    raw argmax (greedy acceptance + residual), and a pre-sampled residual
-    candidate — Gumbel-top-1 with the position's draft token excluded
-    (positions ``< K``; exclusion only binds where a rejection can occur)
-    which IS a draw from "p with the rejected token removed, renormalized";
-    the bonus position ``K`` samples the plain warped target. Acceptance
-    then picks the boundary row. Returns exactly
-    ``(accept_len, tokens [B, C], logprobs [B, C], boundary_argmax)`` with
-    the reference's semantics: token-exact for greedy slots,
-    distribution-exact otherwise. Warping slots (top-p / top-k) are NOT
-    handled here — the engine routes them through the sorted reference
-    path via the warp-row bucket.
-    """
-    B, C, E = hidden.shape
-    K = C - 1
-    r_acc, r_res = jax.random.split(rng)
-    if greedy is None:
-        greedy = sp.temperature <= 0.0
-    flat = hidden.reshape(B * C, E)
-    temp = jnp.repeat(sp.temperature, C)
-    greedy_flat = jnp.repeat(greedy, C)
-    neg1 = jnp.full((B, 1), -1, jnp.int32)
-    excl = jnp.concatenate([draft.astype(jnp.int32), neg1], axis=1)
-    gids = jnp.concatenate(
-        [draft.astype(jnp.int32), jnp.zeros((B, 1), jnp.int32)], axis=1
-    )
-    res = fused_sample(
-        r_res, flat, w, temp, greedy_flat, soft_cap=soft_cap,
-        exclude=excl.reshape(-1), gather_ids=gids.reshape(-1),
-        block_size=block_size, use_pallas=use_pallas, mesh=mesh,
-    )
-    cand = res["tokens"].reshape(B, C)
-    cand_lp = res["logprobs"].reshape(B, C)
-    argmax = res["argmax"].reshape(B, C)
-    draft_lp = res["gathered_lp"].reshape(B, C)[:, :K]
-
-    u = jax.random.uniform(r_acc, draft.shape, minval=1e-20)
-    accept = jnp.where(
-        greedy[:, None], draft == argmax[:, :K], jnp.log(u) < draft_lp
-    )
-    a = jnp.cumprod(accept.astype(jnp.int32), axis=1).sum(axis=1)
-
-    res_tok = jnp.take_along_axis(cand, a[:, None], axis=1)[:, 0]
-    res_lp = jnp.take_along_axis(cand_lp, a[:, None], axis=1)[:, 0]
-    boundary_argmax = jnp.take_along_axis(argmax, a[:, None], axis=1)[:, 0]
-
-    pos = jnp.arange(C)[None, :]
-    draft_pad = jnp.concatenate([draft, draft[:, -1:]], axis=1)
-    dlp_pad = jnp.concatenate([draft_lp, draft_lp[:, -1:]], axis=1)
-    tokens = jnp.where(
-        pos < a[:, None], draft_pad, res_tok[:, None]
-    ).astype(jnp.int32)
-    lps = jnp.where(pos < a[:, None], dlp_pad, res_lp[:, None])
-    return a.astype(jnp.int32), tokens, lps, boundary_argmax.astype(jnp.int32)

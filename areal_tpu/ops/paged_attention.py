@@ -343,50 +343,6 @@ def paged_decode_attention(
     return out.reshape(B, H, v.shape[-1]).astype(q.dtype)
 
 
-def paged_verify_attention(
-    q: jnp.ndarray,          # [B, C, H, D] verify chunk (C = K+1, small)
-    k_chunk: jnp.ndarray,    # [B, C, Hkv, D]
-    v_chunk: jnp.ndarray,
-    pages: jnp.ndarray,      # [L, P, 2, Hkv, page, D]
-    layer: jnp.ndarray,
-    table: jnp.ndarray,      # [B, M]
-    lens: jnp.ndarray,       # [B] tokens resident in the pool
-    n_new: jnp.ndarray,      # [B] valid chunk tokens (C where active, 0 else)
-    *,
-    softmax_scale: Optional[float] = None,
-    soft_cap: Optional[float] = None,
-    sliding_window: Optional[int] = None,
-    scales: Optional[jnp.ndarray] = None,
-) -> jnp.ndarray:
-    """Multi-token VERIFY attention for speculative decoding: the chunk is
-    ``[last_token, d_1..d_K]`` sitting at positions ``[lens, lens+K]``;
-    token ``i`` attends every pool position ``< lens`` plus chunk tokens
-    ``<= i`` — exactly the chunked-prefill contract with ``start = lens``,
-    so this delegates to :func:`paged_extend_attention` (ONE pass scores
-    all K+1 positions; the per-position decode kernel would re-read params
-    and pool K+1 times, which is the cost spec decode exists to amortize).
-
-    A dedicated kernel entry point, not an engine-side call into the
-    extend op, for the same reason decode has XLA + Pallas variants behind
-    one name: a fused verify kernel (C is tiny and static — the pool scan
-    could prefetch across positions) can land here later without touching
-    the model or engine layers.
-
-    The chunk's K/V ride as operands; the CALLER scatters them into the
-    pool after its layer scan, masking positions that can never become
-    resident (rejected drafts are overwritten before ``lens`` reaches
-    them)."""
-    if q.ndim != 4 or q.shape[1] != k_chunk.shape[1]:
-        raise ValueError(
-            f"verify chunk shapes disagree: q {q.shape} vs k {k_chunk.shape}"
-        )
-    return paged_extend_attention(
-        q, k_chunk, v_chunk, pages, layer, table, lens, n_new,
-        softmax_scale=softmax_scale, soft_cap=soft_cap,
-        sliding_window=sliding_window, scales=scales,
-    )
-
-
 def paged_extend_attention(
     q: jnp.ndarray,          # [B, C, H, D] chunk of new tokens
     k_chunk: jnp.ndarray,    # [B, C, Hkv, D] the chunk's K (not in pool)

@@ -38,11 +38,6 @@ twelve integer ops an element) in interpret mode, which has no PRNG
 lowering. Either way the draw is an exact Gumbel-top-1 over every valid
 column, reproducible for one seed on one path.
 
-Built only when the caller passes them (the engine's vanilla chunk passes
-neither), per-row extras for the speculative verify path: an *excluded*
-token (masked out of the Gumbel argmax only — the rejection-sampling
-residual "p with the rejected token removed") and a *gathered* token
-whose warped logit is returned (the draft-token acceptance score).
 Columns past the vocabulary exist in the last block only, and how many of
 its columns are real is static: that block's program folds its whole
 tiles, masks the one partial tile and skips the rest.
@@ -117,18 +112,12 @@ def _hash_uniform_bits(row_term, lane_term, col0):
 
 
 def _kernel(
-    seed_ref, x_ref, w_ref, temp_ref, greedy_ref, *refs,
-    nb: int, block_v: int, vocab: int, soft_cap: Optional[float],
-    with_exclude: bool, with_gather: bool, hw_prng: bool,
+    seed_ref, x_ref, w_ref, temp_ref, greedy_ref,
+    tok_ref, lp_ref, argmax_ref, norm_ref,
+    lg_scr, m_scr, l_scr, amv_scr, ami_scr, gp_scr, gw_scr, gi_scr,
+    *, nb: int, block_v: int, vocab: int, soft_cap: Optional[float],
+    hw_prng: bool,
 ):
-    refs = list(refs)
-    excl_ref = refs.pop(0) if with_exclude else None
-    gid_ref = refs.pop(0) if with_gather else None
-    tok_ref, lp_ref, argmax_ref, norm_ref = refs[:4]
-    refs = refs[4:]
-    gat_ref = refs.pop(0) if with_gather else None
-    lg_scr, m_scr, l_scr, amv_scr, ami_scr, gp_scr, gw_scr, gi_scr = refs[:8]
-    gat_scr = refs[8] if with_gather else None
 
     j = pl.program_id(0)
     R = x_ref.shape[0]
@@ -142,7 +131,7 @@ def _kernel(
         for ref, fill in (
             (m_scr, NEG_INF), (l_scr, 0.0), (amv_scr, NEG_INF),
             (ami_scr, 0), (gp_scr, NEG_INF), (gw_scr, 0.0), (gi_scr, 0),
-        ) + (((gat_scr, NEG_INF),) if with_gather else ()):
+        ):
             ref[...] = jnp.full(ref.shape, fill, ref.dtype)
 
     def fold(tiles: int, partial: int):
@@ -210,13 +199,9 @@ def _kernel(
                      + g * rg) * -2048144789
                 ) ^ seed
                 lane_term = lane * -1640531527
-            if with_exclude:
-                excl = excl_ref[rs, :]
-            if with_gather:
-                gid = gid_ref[rs, :]
 
             def sample(c, columns, state):
-                l, gp, gw, gi, *gat = state
+                l, gp, gw, gi = state
                 col0 = j * block_v + c * LANES
                 warped = piece(c, columns) * inv_t
                 l = l + jnp.exp(warped - m_new)
@@ -231,25 +216,19 @@ def _kernel(
                 u = ((bits >> 8).astype(jnp.int32).astype(jnp.float32)
                      + 0.5) * (1.0 / (1 << 24))
                 pert = warped - jnp.log(-jnp.log(u))
-                if with_exclude:
-                    pert = jnp.where(lane == excl - col0, NEG_INF, pert)
                 upd = pert > gp
-                if with_gather:
-                    gat = [jnp.where(lane == gid - col0, warped, gat[0])]
                 return (l, jnp.where(upd, pert, gp),
                         jnp.where(upd, warped, gw),
-                        jnp.where(upd, j * n_tiles + c, gi), *gat)
+                        jnp.where(upd, j * n_tiles + c, gi))
 
-            l, gp, gw, gi, *gat = over_pieces(sample, (
+            l, gp, gw, gi = over_pieces(sample, (
                 l_scr[rs, :] * jnp.exp(m_prev - m_new),
                 gp_scr[rs, :], gw_scr[rs, :], gi_scr[rs, :],
-            ) + ((gat_scr[rs, :],) if with_gather else ()))
+            ))
             l_scr[rs, :] = l
             gp_scr[rs, :] = gp
             gw_scr[rs, :] = gw
             gi_scr[rs, :] = gi
-            if with_gather:
-                gat_scr[rs, :] = gat[0]
             return carry
 
         jax.lax.fori_loop(0, R // rg, group, 0)
@@ -292,11 +271,6 @@ def _kernel(
         lp_ref[...] = jnp.where(is_greedy, am_v * (1.0 / t), g_w) - norm
         argmax_ref[...] = jnp.broadcast_to(am_i, argmax_ref.shape)
         norm_ref[...] = norm
-        if with_gather:
-            # one lane of one tile ever took the gathered logit
-            gat_ref[...] = jnp.max(
-                gat_scr[...], axis=-1, keepdims=True
-            ) - norm
 
 
 def fused_sample_pallas(
@@ -305,8 +279,6 @@ def fused_sample_pallas(
     w: jnp.ndarray,               # [E, V]
     temperature: jnp.ndarray,     # [R] f32
     greedy: jnp.ndarray,          # [R] bool
-    exclude: Optional[jnp.ndarray] = None,     # [R] i32, -1 = none
-    gather_ids: Optional[jnp.ndarray] = None,  # [R] i32
     soft_cap: Optional[float] = None,
     block_v: Optional[int] = None,
     interpret: Optional[bool] = None,
@@ -349,17 +321,9 @@ def fused_sample_pallas(
         jnp.float32, jnp.float32, jnp.float32, jnp.int32,
         jnp.float32, jnp.float32, jnp.int32,
     ]
-    if exclude is not None:
-        operands.append(_rows(exclude, jnp.int32, -1))
-    if gather_ids is not None:
-        operands.append(_rows(gather_ids, jnp.int32, -1))
-        out_dtypes.append(jnp.float32)
-        scratch.append(jnp.float32)
     row_spec = pl.BlockSpec((R, LANES), lambda j, s: (0, 0))
     kernel = functools.partial(
         _kernel, nb=nb, block_v=block_v, vocab=V, soft_cap=soft_cap,
-        with_exclude=exclude is not None,
-        with_gather=gather_ids is not None,
         hw_prng=not interpret,
     )
     outs = pl.pallas_call(
@@ -393,13 +357,10 @@ def fused_sample_pallas(
         interpret=interpret,
         name="fused_sample",
     )(*operands)
-    tok, lp, am, norm = (o[:R0, 0] for o in outs[:4])
-    out = {
+    tok, lp, am, norm = (o[:R0, 0] for o in outs)
+    return {
         "tokens": tok,
         "logprobs": lp,
         "argmax": am,
         "norm": norm,
     }
-    if gather_ids is not None:
-        out["gathered_lp"] = outs[4][:R0, 0]
-    return out

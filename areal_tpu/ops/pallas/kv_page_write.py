@@ -23,8 +23,7 @@ a valid token the kernel reads the slab(s) its run touches into VMEM,
 puts the fresh rows in under an ``lo <= iota < hi`` mask, and writes the
 slab back; a slab that lies whole inside the run is written without the
 read. One token a row (decode) is one slab a (layer, row); a chunk of
-``C`` tokens (admission's 128, a speculative verify pass) touches at
-most ``(C + R - 2) // R + 1``.
+``C`` tokens (admission's 128) touches at most ``(C + R - 2) // R + 1``.
 
 The pool is in ``ANY`` (HBM) and aliased to the result: it is updated IN
 PLACE, nothing else in it is touched, and it is BIT-equal to what the

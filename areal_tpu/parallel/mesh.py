@@ -119,10 +119,7 @@ def make_mesh(
 
 def check_tp_divisibility(cfg, tp: int, role: str = "model"):
     """Validate that a ``ModelConfig``'s TP-sharded dims divide by the
-    model-axis size — raised at construction, not deep inside a trace.
-    Shared by the generation engine's target AND draft models (the draft
-    shards through the same logical-axis rules, so it has the same
-    divisibility contract)."""
+    model-axis size — raised at construction, not deep inside a trace."""
     for dim, name in (
         (cfg.n_kv_heads, "n_kv_heads"),
         (cfg.n_q_heads, "n_q_heads"),

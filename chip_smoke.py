@@ -1423,7 +1423,7 @@ def child_statespace(arg):
     names = sorted(set(re.findall(
         r'kernel_name = "([^"]+)"',
         chunk.lower(
-            *eng._model_args(), eng.state,
+            eng.params, eng.state,
             jnp.asarray(eng._table_arg(slice(None), key[1])),
             jnp.zeros((key[2],), jnp.int32)).as_text())))
     samples = [{

@@ -145,3 +145,27 @@ def check_moe_grouped_serves_the_same(monkeypatch):
         assert served[True] == served[False]
 
     return check
+
+
+@pytest.fixture
+def decode_tokens_paged():
+    """``decode(params, cfg, cache, tokens [B, C], table, lens, n_new,
+    **kw)``: the rows with ``n_new > 0`` teacher-forced through
+    ``decode_step_paged``, a position a step, on a pool and page table the
+    test laid out itself. Returns ``(logits [B, C, V], cache)``: ``logits[:,
+    i]`` is the distribution of the token after ``tokens[:, i]``."""
+    import jax.numpy as jnp
+
+    from areal_tpu.models import transformer as tfm
+
+    def decode(params, cfg, cache, tokens, table, lens, n_new, **kw):
+        active = jnp.asarray(n_new) > 0
+        lens = jnp.asarray(lens)
+        logits = []
+        for c in range(tokens.shape[1]):
+            step, cache, lens = tfm.decode_step_paged(
+                params, cfg, cache, tokens[:, c], table, lens, active, **kw)
+            logits.append(step)
+        return jnp.stack(logits, axis=1), cache
+
+    return decode

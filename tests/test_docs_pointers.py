@@ -147,3 +147,18 @@ def test_the_guard_sees_a_broken_pointer(tmp_path):
         "python -m areal_tpu.apps.nope",
         "python gone_script.py",
     ]
+
+
+def test_readme_lists_the_benchmark_s_cells():
+    """The "Benchmarks" table of ``README.md`` has a row for every entry
+    of ``BENCHMARK.json``'s ``workloads`` and for nothing else (the
+    sentences around it count nothing)."""
+    import json
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        cells = [w["name"] for w in json.load(f)["workloads"]]
+    text = open(os.path.join(REPO, "README.md")).read()
+    section = text[text.index("## Benchmarks"):]
+    section = section[: section.index("\n## ", 1)]
+    rows = re.findall(r"^\| `([\w.\-]+)` ", section, re.M)
+    assert rows == cells

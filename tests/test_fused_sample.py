@@ -5,13 +5,10 @@ The load-bearing contracts:
 - greedy slots are TOKEN-exact and logprob-exact (up to float
   associativity) vs the materialize-then-sample reference, at the op level
   across block sizes and through the full engine;
-- temperature / top-k / exclusion sampling is distribution-exact
-  (chi-square on a toy vocab) — same marginal, different RNG stream;
-- the fused spec acceptance (``fused_spec_rejection``) preserves the
-  reference rejection-sampling semantics: greedy spec-over-fused equals
-  vanilla decode token for token;
+- temperature / top-k sampling is distribution-exact (chi-square on a
+  toy vocab) — same marginal, different RNG stream;
 - composition: warp-bucket fallback rows (top-p), pause/resume, tp2
-  serving, bounded compiles, adaptive spec-K, telemetry counters;
+  serving, bounded compiles, telemetry counters;
 - the ``sample_tokens(warp=False)`` gather-then-normalize logprob fast
   path equals the full ``log_softmax`` formulation exactly.
 """
@@ -28,7 +25,6 @@ from areal_tpu.gen.sampling import (
     SamplingParams,
     _plain_temperature,
     sample_tokens,
-    spec_rejection_sample,
 )
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
@@ -49,11 +45,11 @@ def params():
     return tfm.init_params(CFG, jax.random.key(5))
 
 
-def _engine(params, spec=False, fused=None, **kw):
+def _engine(params, fused=None, **kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("max_seqlen", 128)
     return GenerationEngine(
-        CFG, params, spec_decode=spec, fused_sample=fused, **kw
+        CFG, params, fused_sample=fused, **kw
     )
 
 
@@ -132,24 +128,6 @@ class TestOpParity:
                 k = int(topk[r])
                 top_ids = np.argsort(-np.asarray(logits)[r])[:k]
                 assert tok[r] in top_ids
-
-    def test_gathered_lp_scores_requested_token(self):
-        x, w, logits = _head_problem()
-        R = x.shape[0]
-        temp = jnp.full((R,), 0.9, jnp.float32)
-        gids = jnp.arange(R, dtype=jnp.int32) * 3
-        out = fs.fused_sample(
-            jax.random.key(1), x, w, temp, jnp.zeros((R,), bool),
-            gather_ids=gids, block_size=33, use_pallas=False,
-        )
-        warped = np.asarray(logits) / 0.9
-        lse = np.asarray(
-            jax.scipy.special.logsumexp(jnp.asarray(warped), axis=-1)
-        )
-        np.testing.assert_allclose(
-            np.asarray(out["gathered_lp"]),
-            warped[np.arange(R), np.arange(R) * 3] - lse, atol=1e-4,
-        )
 
     def test_pallas_interpret_matches_xla(self):
         """The kernel (CPU interpret mode) agrees with the streamed XLA
@@ -241,9 +219,8 @@ class TestFusedSampleApplies:
 
 
 class TestKernelForms:
-    """The Pallas kernel (interpret mode) over row groups, vocabulary
-    blocks with a masked tail, and the two folds only a speculative
-    verify asks for."""
+    """The Pallas kernel (interpret mode) over row groups and vocabulary
+    blocks with a masked tail."""
 
     @pytest.mark.parametrize(
         "R,V,block",
@@ -278,34 +255,29 @@ class TestKernelForms:
         # sampled rows are not all one token: the draws differ by row
         assert len(set(tok[~g].tolist())) > 1
 
-    def test_folds_change_nothing_for_rows_that_use_neither(self):
-        """With the excluded / gathered folds built (every row passing
-        "none") the tokens and log-probs are those of the kernel built
-        without them; a row that does exclude its draw gets another."""
+    def test_draws_do_not_depend_on_the_block(self):
+        """The interpreted kernel draws from a hash of (seed, row, column):
+        the same tokens whatever the block, a partial last block (500
+        columns) included; the sums differ only in their order."""
         x, w, logits = _head_problem(R=16, E=32, V=500, seed=2)
         R = x.shape[0]
-        temp = jnp.ones((R,), jnp.float32)
-        greedy = jnp.zeros((R,), bool)
-        args = (jax.random.key(5), x, w, temp, greedy)
-        kw = dict(block_size=128, use_pallas=True)
-        plain = fs.fused_sample(*args, **kw)
-        assert "gathered_lp" not in plain
-        gids = (jnp.arange(R) * 7) % 500
-        folded = fs.fused_sample(
-            *args, exclude=jnp.full((R,), -1, jnp.int32), gather_ids=gids,
-            **kw,
-        )
-        for k in ("tokens", "logprobs", "argmax", "norm"):
+        args = (jax.random.key(5), x, w, jnp.ones((R,), jnp.float32),
+                jnp.zeros((R,), bool))
+        one, other = (
+            fs.fused_sample(*args, block_size=b, use_pallas=True)
+            for b in (128, 256))
+        assert set(one) == {"tokens", "logprobs", "argmax", "norm"}
+        for k in ("tokens", "argmax"):
             np.testing.assert_array_equal(
-                np.asarray(plain[k]), np.asarray(folded[k]), err_msg=k)
+                np.asarray(one[k]), np.asarray(other[k]), err_msg=k)
+        for k in ("logprobs", "norm"):
+            np.testing.assert_allclose(
+                np.asarray(one[k]), np.asarray(other[k]), atol=1e-5,
+                err_msg=k)
         lp_all = np.asarray(jax.nn.log_softmax(logits, -1))
         np.testing.assert_allclose(
-            np.asarray(folded["gathered_lp"]),
-            lp_all[np.arange(R), np.asarray(gids)], atol=1e-4,
-        )
-        excluded = fs.fused_sample(*args, exclude=plain["tokens"], **kw)
-        assert not np.any(
-            np.asarray(excluded["tokens"]) == np.asarray(plain["tokens"]))
+            np.asarray(one["logprobs"]),
+            lp_all[np.arange(R), np.asarray(one["tokens"])], atol=1e-4)
 
     def test_block_follows_from_the_shapes(self):
         from areal_tpu.ops.pallas import fused_sample as fsk
@@ -382,24 +354,6 @@ class TestDistribution:
         counts = self._marginal(f)
         assert counts[np.setdiff1d(np.arange(16), keep)].sum() == 0
         assert self._chi2(counts, p) < CHI2_CRIT
-
-    @pytest.mark.slow
-    def test_excluded_token_marginal(self):
-        """The spec-residual distribution: p with one token removed,
-        renormalized — the excluded token must never appear."""
-        x, w, logits = _head_problem(R=1, E=8, V=16, seed=1)
-        p = np.asarray(jax.nn.softmax(logits[0]))
-        ex = int(np.argmax(p))
-        f = jax.jit(lambda k: fs.fused_sample(
-            k, x, w, jnp.ones((1,)), jnp.zeros((1,), bool),
-            exclude=jnp.array([ex]), block_size=16, use_pallas=False,
-        )["tokens"][0])
-        counts = self._marginal(f)
-        assert counts[ex] == 0
-        p2 = p.copy()
-        p2[ex] = 0.0
-        p2 /= p2.sum()
-        assert self._chi2(counts.astype(float), p2) < CHI2_CRIT
 
     @pytest.mark.slow
     def test_pallas_temperature_marginal(self):
@@ -507,31 +461,6 @@ class TestEngineFused:
             assert len(o.output_ids) == 8
             assert all(np.isfinite(o.output_logprobs))
 
-    def test_spec_over_fused_greedy_matches_vanilla(self, params, rng):
-        """Fused spec acceptance (streamed verify head) == vanilla greedy
-        decode == reference spec decode, token for token."""
-        prompts = _prompts(rng)
-
-        def run(spec, fused):
-            eng = _engine(params, spec=spec, fused=fused, max_slots=4,
-                          spec_k=3)
-            for i, p in enumerate(prompts):
-                eng.submit(GenRequest(
-                    rid=f"r{i}", input_ids=p, max_new_tokens=10 + i,
-                    greedy=True,
-                ))
-            return {o.rid: o for o in eng.run_until_done(decode_steps=3)}
-
-        ref = run(False, False)
-        sf = run(True, True)
-        assert set(ref) == set(sf)
-        for rid in ref:
-            assert ref[rid].output_ids == sf[rid].output_ids, rid
-            np.testing.assert_allclose(
-                ref[rid].output_logprobs, sf[rid].output_logprobs,
-                atol=1e-4,
-            )
-
     def test_pause_resume_prefix_parity_fused(self, params, rng):
         """Interruption composes: a fused engine paused mid-generation
         yields a prefix of the uninterrupted chain and resubmission
@@ -619,47 +548,6 @@ class TestEngineFused:
         ) > 0
 
 
-class TestAdaptiveSpecK:
-    def test_retunes_up_under_predictable_traffic(self, params):
-        """A repetitive prompt (n-gram drafter accepts ~everything) must
-        drive K up through the choice set, update the gauge, and keep
-        compiles bounded by the visited-K set."""
-        eng = GenerationEngine(
-            CFG, params, max_slots=2, max_seqlen=512, spec_decode=True,
-            spec_k=1, spec_k_adapt=True,
-        )
-        assert eng.spec_k_adapt is True
-        pat = [7, 8, 9, 10] * 10
-        for rid in ("a", "b"):
-            eng.submit(GenRequest(
-                rid=rid, input_ids=pat, max_new_tokens=250, greedy=True,
-            ))
-        eng.run_until_done(decode_steps=4)
-        assert eng.spec_k > 1
-        assert eng.spec_k in eng._spec_k_choices
-        snap = metrics_mod.counters.snapshot()
-        assert snap.get(metrics_mod.GEN_SPEC_K_CURRENT) == float(eng.spec_k)
-        # one spec-chunk program per (chunk key, visited K): bounded
-        assert len(eng._jit_spec) <= 4 * len(eng._spec_k_choices)
-
-    def test_static_without_knob(self, params):
-        eng = _engine(params, spec=True, spec_k=3)
-        assert eng.spec_k_adapt is False
-        eng.submit(GenRequest(
-            rid="a", input_ids=[7, 8, 9] * 6, max_new_tokens=30,
-            greedy=True,
-        ))
-        eng.run_until_done(decode_steps=3)
-        assert eng.spec_k == 3
-
-    def test_env_knob_and_gauge_init(self, params, monkeypatch):
-        monkeypatch.setenv("AREAL_SPEC_K_ADAPT", "1")
-        eng = _engine(params, spec=True, spec_k=2)
-        assert eng.spec_k_adapt is True
-        snap = metrics_mod.counters.snapshot()
-        assert snap.get(metrics_mod.GEN_SPEC_K_CURRENT) == 2.0
-
-
 class TestGaugeKind:
     def test_gauge_last_value_wins_and_delta_reports_as_is(self):
         name = "test/fused_gauge"
@@ -680,10 +568,10 @@ class TestGaugeKind:
         for i, v in enumerate((2.0, 4.0, 1.0)):
             agg.merge_snapshot({
                 "worker": f"w{i}",
-                "counters": {metrics_mod.GEN_SPEC_K_CURRENT: v},
+                "counters": {metrics_mod.GW_BROWNOUT_LEVEL: v},
             })
         # gauges merge via fleet max (the conservative view when workers
-        # retune at different times), not via sum
-        assert agg.counters[metrics_mod.GEN_SPEC_K_CURRENT] == 4.0
-        assert agg.kinds[metrics_mod.GEN_SPEC_K_CURRENT] == \
+        # move at different times), not via sum
+        assert agg.counters[metrics_mod.GW_BROWNOUT_LEVEL] == 4.0
+        assert agg.kinds[metrics_mod.GW_BROWNOUT_LEVEL] == \
             metrics_mod.KIND_GAUGE

@@ -935,40 +935,42 @@ def test_brownout_decide_table():
     assert brownout_decide(cfg, sig(), 0) == 0
     # each signal kind can trip a rung on its own
     assert brownout_decide(cfg, sig(kv_occupancy=0.91), 0) == 1
-    assert brownout_decide(cfg, sig(queue_wait_p95_s=16.0), 0) == 2
-    assert brownout_decide(cfg, sig(breaker_open=3), 0) == 3
+    assert brownout_decide(cfg, sig(queue_wait_p95_s=16.0), 0) == 1
+    assert brownout_decide(cfg, sig(queue_wait_p95_s=31.0), 0) == 2
+    assert brownout_decide(cfg, sig(breaker_open=3), 0) == 2
+    # three rungs: clamp, shed, admit nothing, at their thresholds
+    assert [(lv.kv_occupancy, lv.queue_wait_p95_s, lv.breaker_open_frac)
+            for lv in cfg.levels] == [
+        (0.90, 5.0, 0.25), (0.97, 30.0, 0.75), (0.99, 60.0, 1.00)]
     # escalation jumps straight to the worst tripped rung
-    assert brownout_decide(cfg, sig(kv_occupancy=0.995), 0) == 4
-    assert brownout_decide(cfg, sig(kv_occupancy=0.995), 2) == 4
+    assert brownout_decide(cfg, sig(kv_occupancy=0.995), 0) == 3
+    assert brownout_decide(cfg, sig(kv_occupancy=0.995), 2) == 3
     # hysteresis: below the entry bound but above entry*h holds the rung
     assert brownout_decide(cfg, sig(kv_occupancy=0.80), 1) == 1
     assert brownout_decide(cfg, sig(kv_occupancy=0.50), 1) == 0
     # de-escalation is one rung at a time even from a silent fleet
-    assert brownout_decide(cfg, sig(), 4) == 3
+    assert brownout_decide(cfg, sig(), 3) == 2
 
 
 async def test_brownout_controller_dwell_and_levers():
-    calls = {"clamp": [], "spec": [], "shed": [], "pause": []}
+    calls = {"clamp": [], "shed": [], "pause": []}
     t = {"now": 0.0}
     sig = {"s": ScaleSignals(routed=2, healthy=2)}
-
-    async def spec_cb(enabled):
-        calls["spec"].append(enabled)
 
     cfg = BrownoutConfig(min_hold_s=10.0, interval_s=1.0)
     ctrl = BrownoutController(
         cfg,
         lambda: sig["s"],
         lambda v: calls["clamp"].append(v),
-        spec_cb,
         lambda floor, ra: calls["shed"].append(floor),
         lambda paused, ra: calls["pause"].append(paused),
         clock=lambda: t["now"],
     )
-    sig["s"] = ScaleSignals(routed=2, healthy=2, kv_occupancy=0.96)
+    sig["s"] = ScaleSignals(routed=2, healthy=2, kv_occupancy=0.975)
     assert await ctrl.step_once() == 2
     assert calls["clamp"][-1] == cfg.clamp_max_tokens
-    assert calls["spec"] == [False]
+    assert calls["shed"][-1] == cfg.weight_floor
+    assert calls["pause"][-1] is False
     # recovery is dwell-gated...
     sig["s"] = ScaleSignals(routed=2, healthy=2)
     t["now"] = 5.0
@@ -976,14 +978,14 @@ async def test_brownout_controller_dwell_and_levers():
     # ...and one rung per pass once the hold lapses
     t["now"] = 20.0
     assert await ctrl.step_once() == 1
-    assert calls["spec"] == [False, True]
+    assert calls["shed"][-1] == 0.0
     t["now"] = 40.0
     assert await ctrl.step_once() == 0
     assert calls["clamp"][-1] is None
     # escalation is NEVER dwell-gated
     sig["s"] = ScaleSignals(routed=2, healthy=2, kv_occupancy=0.995)
     t["now"] = 40.5
-    assert await ctrl.step_once() == 4
+    assert await ctrl.step_once() == 3
     assert calls["shed"][-1] == cfg.weight_floor
     assert calls["pause"][-1] is True
     # the Retry-After hint is at least one controller interval

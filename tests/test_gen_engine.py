@@ -6,6 +6,7 @@ Counterpart of the reference's generation tests (in-house engine +
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -13,6 +14,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import engine_contract
+from areal_tpu.base import metrics as metrics_mod
 from areal_tpu.gen.engine import GenerationEngine, GenRequest
 from areal_tpu.models import transformer as tfm
 from areal_tpu.models.config import ModelConfig
@@ -57,131 +60,22 @@ def test_greedy_matches_forward(params, rng):
     assert len(outs[0].output_logprobs) == 8
 
 
-def test_continuous_batching_slot_turnover(params, rng):
-    eng = GenerationEngine(CFG, params, max_slots=2, max_seqlen=128)
-    prompts = {
-        f"r{i}": [int(x) for x in rng.integers(1, 128, size=int(n))]
-        for i, n in enumerate(rng.integers(3, 9, size=5))
-    }
-    for rid, p in prompts.items():
-        eng.submit(GenRequest(rid=rid, input_ids=p, max_new_tokens=6, greedy=True))
-    outs = {o.rid: o for o in eng.run_until_done(decode_steps=4)}
-    assert set(outs) == set(prompts)
-    for rid, p in prompts.items():
-        assert outs[rid].output_ids == _greedy_reference(params, p, 6), rid
+def _engine(params, **kw):
+    return GenerationEngine(
+        CFG, params, max_slots=4, max_seqlen=128, page_size=8, **kw)
 
 
-def test_stop_tokens(params, rng):
-    prompt = [int(x) for x in rng.integers(1, 128, size=5)]
-    ref = _greedy_reference(params, prompt, 12)
-    stop = ref[3]  # force a stop at the 4th generated token
-    eng = GenerationEngine(
-        CFG, params, max_slots=2, max_seqlen=128, stop_token_ids=[stop]
-    )
-    eng.submit(GenRequest(rid="a", input_ids=prompt, max_new_tokens=12, greedy=True))
-    outs = eng.run_until_done(decode_steps=2)
-    assert outs[0].finish_reason == "stop"
-    assert outs[0].output_ids == ref[:4]  # stop token included
-
-
-def test_interrupt_and_resume_protocol(params, rng):
-    """Pause mid-generation, resubmit with accumulated tokens (the partial
-    rollout protocol): concatenated output must equal the uninterrupted run."""
-    prompt = [int(x) for x in rng.integers(1, 128, size=5)]
-    ref = _greedy_reference(params, prompt, 10)
-
-    eng = GenerationEngine(CFG, params, max_slots=2, max_seqlen=128)
-    eng.submit(GenRequest(rid="a", input_ids=prompt, max_new_tokens=10, greedy=True))
-    eng.step(decode_steps=4)   # partial progress
-    parts = eng.pause()
-    assert len(parts) == 1 and parts[0].finish_reason == "interrupted"
-    got = parts[0].output_ids
-    assert 0 < len(got) < 10
-
-    eng.resume()
-    eng.submit(
-        GenRequest(
-            rid="a2", input_ids=prompt + got,
-            max_new_tokens=10 - len(got), greedy=True,
-        )
-    )
-    outs = eng.run_until_done(decode_steps=4)
-    assert got + outs[0].output_ids == ref
-
-
-def test_per_request_stop_tokens(params, rng):
-    prompt = [int(x) for x in rng.integers(1, 128, size=5)]
-    ref = _greedy_reference(params, prompt, 12)
-    stop = ref[2]
-    eng = GenerationEngine(CFG, params, max_slots=2, max_seqlen=128)  # no global stop
-    eng.submit(GenRequest(
-        rid="a", input_ids=prompt, max_new_tokens=12, greedy=True,
-        stop_token_ids=[stop],
-    ))
-    eng.submit(GenRequest(rid="b", input_ids=prompt, max_new_tokens=12, greedy=True))
-    outs = {o.rid: o for o in eng.run_until_done(decode_steps=2)}
-    assert outs["a"].finish_reason == "stop" and outs["a"].output_ids == ref[:3]
-    assert outs["b"].finish_reason == "length" and outs["b"].output_ids == ref
-
-
-def test_update_params_tags_version(params):
-    eng = GenerationEngine(CFG, params, max_slots=1, max_seqlen=128)
-    eng.submit(GenRequest(rid="a", input_ids=[1, 2, 3], max_new_tokens=2, greedy=True))
-    outs = eng.run_until_done(decode_steps=2)
-    assert outs[0].version == 0
-    new_params = tfm.init_params(CFG, jax.random.key(9))
-    eng.update_params(new_params, version=3)
-    eng.submit(GenRequest(rid="b", input_ids=[1, 2, 3], max_new_tokens=2, greedy=True))
-    outs = eng.run_until_done(decode_steps=2)
-    assert outs[0].version == 3
-
-
-def test_sampling_reproducible_and_diverse(params):
-    eng = GenerationEngine(CFG, params, max_slots=4, max_seqlen=128, seed=0)
-    for i in range(4):
-        eng.submit(GenRequest(
-            rid=f"s{i}", input_ids=[5, 6, 7], max_new_tokens=8,
-            temperature=1.0, top_p=0.95,
-        ))
-    outs = {o.rid: o.output_ids for o in eng.run_until_done(decode_steps=4)}
-    assert len(set(map(tuple, outs.values()))) > 1  # samples differ across slots
-
-
-def test_step_harvest_batches_device_pulls(params, rng, monkeypatch):
-    """step() makes at most TWO device pulls per chunk — one sync of the
-    small per-slot scalars, one batched fetch of every finished slot's
-    outputs — no matter how many slots finish inside the chunk, and no
-    per-slot scatter back (VERDICT r3 weak #2: 32 finishing slots used to
-    cost ~64 blocking host<->device syncs)."""
-    eng = GenerationEngine(CFG, params, max_slots=4, max_seqlen=64)
-    for i, n_new in enumerate((3, 4, 9, 12)):  # staggered finishes
-        eng.submit(GenRequest(
-            rid=f"r{i}",
-            input_ids=[int(x) for x in rng.integers(1, 128, size=5)],
-            max_new_tokens=n_new, greedy=True,
-        ))
-    calls = []
-    real_get = jax.device_get
-    monkeypatch.setattr(jax, "device_get", lambda x: calls.append(1) or real_get(x))
-    outs = []
-    for _ in range(40):
-        calls.clear()
-        outs.extend(eng.step(decode_steps=4))
-        assert len(calls) <= 2, f"{len(calls)} device pulls in one step"
-        if eng.free_slots() == 4 and not eng._pending:
-            break
-    assert sorted(o.rid for o in outs) == ["r0", "r1", "r2", "r3"]
-    assert {o.rid: len(o.output_ids) for o in outs} == {
-        "r0": 3, "r1": 4, "r2": 9, "r3": 12,
-    }
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
 
 
 class TestWarpContract:
     """The sampling layer's static-``warp`` split: engines that know no
     slot warps (host-side ``_warp_host``) skip the ``[B, V]`` sort — the
     dominant cost of a decode step at a 152k vocab — and the result must
-    be EXACT either way. The spec-decode verify path leans on the same
-    contract plus a single flattened sort for all K+1 positions."""
+    be EXACT either way, as is a single flattened sort over several
+    positions a slot."""
 
     def test_warp_false_exactness(self, rng):
         from areal_tpu.gen.sampling import SamplingParams, sample_tokens
@@ -202,7 +96,7 @@ class TestWarpContract:
                                    atol=1e-6)
 
     def test_warp_multi_matches_per_position(self, rng):
-        """One flattened sort over [B*C, V] (the spec-verify warp) must
+        """One flattened sort over [B*C, V] must
         equal warping each position independently."""
         from areal_tpu.gen.sampling import (
             SamplingParams, warp_logits, warp_logits_multi,
@@ -248,7 +142,7 @@ class TestWarpContract:
                                    atol=1e-5)
 
     def test_warp_rows_multi_matches_full(self, rng):
-        """The spec-verify [B, C, V] shape through warp_logits_rows."""
+        """The [B, C, V] shape through warp_logits_rows."""
         from areal_tpu.gen.sampling import (
             SamplingParams, warp_logits_multi, warp_logits_rows,
         )
@@ -433,34 +327,6 @@ class TestTensorParallelEngine:
 
 
 class TestPipelinedChunks:
-    def test_pipelined_matches_unpipelined_greedy(self, params, rng):
-        prompts = [
-            [int(x) for x in rng.integers(1, 128, size=n)]
-            for n in (5, 9, 3, 7)
-        ]
-        outs = []
-        for pipelined in (False, True):
-            eng = GenerationEngine(
-                CFG, params, max_slots=4, max_seqlen=128,
-                pipeline_chunks=pipelined,
-            )
-            for i, p in enumerate(prompts):
-                eng.submit(GenRequest(
-                    rid=f"r{i}", input_ids=p, max_new_tokens=10 + i,
-                    greedy=True,
-                ))
-            outs.append({
-                o.rid: o for o in eng.run_until_done(decode_steps=4)
-            })
-        assert set(outs[0]) == set(outs[1])
-        for rid in outs[0]:
-            assert outs[0][rid].output_ids == outs[1][rid].output_ids, rid
-            assert outs[0][rid].finish_reason == outs[1][rid].finish_reason
-            np.testing.assert_allclose(
-                outs[0][rid].output_logprobs, outs[1][rid].output_logprobs,
-                atol=1e-5,
-            )
-
     def test_pipelined_staggered_admission(self, params, rng):
         """New requests admitted mid-flight (slots freed by late harvests)
         must complete correctly — the fresh slot's lens/harvest state must
@@ -478,28 +344,53 @@ class TestPipelinedChunks:
         assert set(outs) == {f"s{i}" for i in range(5)}
         assert all(len(o.output_ids) == 6 for o in outs.values())
 
-    def test_pause_classifies_unharvested_finishes(self, params, rng):
-        """A slot that FINISHED in the in-flight chunk must come out of
-        pause() as stop/length, not 'interrupted' (a client would
-        resubmit a complete sample)."""
+    def test_steady_state_zero_blocking_device_get(self, params, monkeypatch):
+        """The dispatch-ahead flag fetch: the harvest-flag D2H copy starts
+        at chunk dispatch and resolves one chunk later (pipelined mode), so
+        steady-state decode issues ZERO blocking device_get calls at chunk
+        boundaries — proven by trace (a counting device_get shim) plus the
+        engine's own blocked-resolve counter, the same event-log proof
+        style as the fwd_pipe overlap test."""
         eng = GenerationEngine(
-            CFG, params, max_slots=2, max_seqlen=64, pipeline_chunks=True,
+            CFG, params, max_slots=2, max_seqlen=512, pipeline_chunks=True,
         )
         eng.submit(GenRequest(
-            rid="short", input_ids=[3, 4, 5], max_new_tokens=2, greedy=True,
+            rid="a", input_ids=[1, 2, 3, 4, 5], max_new_tokens=400,
+            greedy=True,
         ))
-        eng.submit(GenRequest(
-            rid="long", input_ids=[6, 7, 8], max_new_tokens=40, greedy=True,
-        ))
-        # one step: dispatches a 4-step chunk; 'short' finishes ON DEVICE
-        # inside it but its harvest is deferred (pipelined)
-        outs = eng.step(decode_steps=4)
-        assert outs == []
-        assert eng.has_inflight
-        harvested = {o.rid: o for o in eng.pause()}
-        assert harvested["short"].finish_reason == "length"
-        assert len(harvested["short"].output_ids) == 2
-        assert harvested["long"].finish_reason == "interrupted"
+        eng.step(decode_steps=4)    # admit + first dispatch
+        eng.step(decode_steps=4)    # warm both pipeline stages
+        # pace the warm-up's in-flight chunk too: the window's first
+        # resolve is of THAT chunk (CPU dispatch is asynchronous)
+        jax.block_until_ready((eng.state.lens, eng._prev_flags))
+        metrics_mod.counters.clear(metrics_mod.GEN_CHUNK_FLAG_FETCHES)
+        metrics_mod.counters.clear(metrics_mod.GEN_CHUNK_FLAG_BLOCKED)
+        calls = []
+        orig = jax.device_get
+        monkeypatch.setattr(
+            jax, "device_get",
+            lambda *a, **kw: (calls.append(a), orig(*a, **kw))[1],
+        )
+        n_chunks = 10
+        for _ in range(n_chunks):
+            eng.step(decode_steps=4)
+            # harness pacing only: wait out the in-flight chunk so the
+            # next resolve measures the protocol, not CPU scheduling.
+            # ALL of its outputs: on jax 0.9's CPU client the outputs of
+            # one execution turn ready one by one, so the state being
+            # ready does not make the flag tuple ready in the same instant
+            jax.block_until_ready((eng.state.lens, eng._prev_flags))
+        assert calls == []          # the trace assertion: zero device_get
+        assert metrics_mod.counters.get(
+            metrics_mod.GEN_CHUNK_FLAG_FETCHES
+        ) == n_chunks
+        assert metrics_mod.counters.get(
+            metrics_mod.GEN_CHUNK_FLAG_BLOCKED
+        ) == 0
+        # the engine still harvests correctly after the window
+        monkeypatch.setattr(jax, "device_get", orig)
+        outs = eng.run_until_done(decode_steps=64)
+        assert outs and outs[0].finish_reason == "length"
 
 
 # --------------------------------------------------------------------------- #
@@ -639,7 +530,7 @@ class TestEngineSpans:
                for c, _ in _chunks_with_children(tracing.drain())]
         assert res == [sum(plens) - 3 + 3 * 4 * k for k in range(3)]
 
-    @pytest.mark.parametrize("path", ["kernel", "xla", "spec"])
+    @pytest.mark.parametrize("path", ["kernel", "xla"])
     def test_kernel_counts_follow_the_kernels_block_plan(
             self, params, rng, path):
         """``kernel_positions`` at a chunk's dispatch = what the paged
@@ -652,13 +543,11 @@ class TestEngineSpans:
         started by the last reached step of an earlier block (every block
         that reaches a step but the first). Only on chunks that run the kernel
         (forced on here, interpret mode; on the CPU's own XLA gather path
-        and in a speculative chunk there is nothing to count), and the
-        tokens are those of the gather path either way."""
+        there is nothing to count), and the tokens are those of the gather path either way."""
         from areal_tpu.base import tracing
 
         eng = GenerationEngine(
-            CFG, params, max_slots=12, max_seqlen=256, page_size=8,
-            spec_decode=path == "spec")
+            CFG, params, max_slots=12, max_seqlen=256, page_size=8)
         eng._decode_use_pallas = None if path == "xla" else True
         plens = (5, 150, 9, 70, 3, 130, 64, 20, 200)    # 3 slots stay free
         prompts = [[int(x) for x in rng.integers(1, 128, size=n)]

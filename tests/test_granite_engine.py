@@ -8,11 +8,14 @@ off. Requests sample at temperature 1 from one seed, so two engines that
 agree on the logits (and seat the requests alike) produce the same
 tokens."""
 
+import functools
+
 import numpy as np
 import pytest
 
 import jax
 
+import engine_contract
 from areal_tpu.base import tracing
 from areal_tpu.gen.engine import GenerationEngine, GenRequest
 from areal_tpu.gen.pages import PagePool, PrefixRegistry
@@ -32,6 +35,11 @@ def _engine(params, **kw):
     kw = {"max_slots": 4, "max_seqlen": 128, "max_new_tokens_cap": 48,
           "page_size": PAGE, "admit_buckets": (1, 2, 4), "seed": 3, **kw}
     return GenerationEngine(CFG, params, **kw)
+
+
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
 
 
 def _prompt(seed, n):
@@ -242,16 +250,12 @@ def test_recurrent_state_of_a_running_request_is_the_recurrences(
 
 
 @pytest.mark.parametrize("kw", [
-    {"spec_decode": True}, {"kv_dtype": "int8"}, {"mesh": "2"},
-    {"drafter": "model"},
+    {"kv_dtype": "int8"}, {"mesh": "2"},
 ], ids=lambda kw: next(iter(kw)))
 def test_engine_refuses_what_has_no_test_beside_recurrent_state(params, kw):
     if "mesh" in kw:
         from jax.sharding import Mesh
         kw = {"mesh": Mesh(np.asarray(jax.devices()[:2]), ("model",))}
-    if "drafter" in kw:
-        from areal_tpu.gen.drafter import TransformerDrafter
-        kw = {"drafter": TransformerDrafter(CFG, params)}
     with pytest.raises(NotImplementedError, match="state-space"):
         _engine(params, **kw)
 

@@ -402,11 +402,6 @@ def test_admission_in_chunks_equals_one_prefill(params):
     for a, b in zip(jax.tree.leaves(whole),
                     jax.tree.leaves(jax.tree.map(lambda a: a[:, 1], ssm2))):
         np.testing.assert_allclose(a, b, atol=1e-5)
-    # a verify pass beside recurrent state is refused
-    with pytest.raises(NotImplementedError):
-        tfm.verify_step_paged(
-            params, CFG, pool, jnp.zeros((1, 4), jnp.int32), table[:1],
-            jnp.zeros((1,), jnp.int32), jnp.full((1,), 4), jnp.full((1,), 4))
 
 
 SSM_DECODE_SHAPES = [              # G, R, P, N

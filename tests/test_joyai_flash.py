@@ -23,6 +23,7 @@ more than 1e-3 (last test of section ii).
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -32,6 +33,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import engine_contract
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import PPOHyperparameters
 from areal_tpu.base import tracing
@@ -391,6 +393,11 @@ def _engine(params, **kw):
         page_size=8, enable_prefix_cache=True, seed=3, **kw)
 
 
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
+
+
 def test_pool_holds_one_latent_stream(params):
     """No V half, no per-head copy: a token takes ``layers x width`` values
     of the pool, the width being the latent's padded to whole lane tiles
@@ -579,11 +586,12 @@ def test_engine_routing_record(params, rng):
         np.asarray(want)[:, len(prompt) - 1:].transpose(1, 0, 2))
 
 
-def test_extend_then_verify_matches_reference(params, rng):
+def test_extend_then_decode_matches_reference(
+        params, rng, decode_tokens_paged):
     """``extend_paged`` (two chunks: the first skips the empty pool, the
-    second reads it) then ``verify_step_paged`` (the speculative verify
-    pass) straight on a latent pool with a page table of their own: the
-    verify chunk's logits are the reference's at those positions."""
+    second reads it) then four decode steps straight on a latent pool with
+    a page table of their own: the steps' logits are the reference's at
+    those positions."""
     seq = [int(x) for x in rng.integers(1, 128, 24)]
     cache = tfm.PagedKVCache.empty(CFG, 12, 8)
     assert cache.pages.shape == (3, 12, 1, 1, 8, 128)
@@ -598,14 +606,13 @@ def test_extend_then_verify_matches_reference(params, rng):
                 jnp.asarray([0, c * 10]), jnp.asarray([0, 10]),
                 skip_pool=skip)
         chunk = jnp.zeros((2, 4), jnp.int32).at[1].set(jnp.asarray(seq[20:]))
-        logits, cache2 = tfm.verify_step_paged(
-            params, CFG, cache, chunk, table, jnp.asarray([0, 20]),
-            jnp.asarray([0, 4]), jnp.asarray([0, 4]))
+        logits, cache2 = decode_tokens_paged(
+            params, CFG, cache, chunk, table, [0, 20], [0, 4])
     lp = jax.nn.log_softmax(logits[1], axis=-1)
     got = np.asarray(lp[np.arange(3), np.asarray(seq[21:])])
     np.testing.assert_allclose(
         got, _ref_logprobs(params, seq)[20:], atol=TOL_NATS)
-    # the chunk's latents landed at positions 20..23 (page 9, offsets 4..7)
+    # the steps' latents landed at positions 20..23 (page 9, offsets 4..7)
     # and nowhere else; the rotary part follows the 32 latent values
     assert float(jnp.abs(cache2.pages[:, 9, 0, 0, 4:, :40]).min()) > 0
     assert float(jnp.abs(cache2.pages[:, :, 0, 0, :, 40:]).max()) == 0

@@ -3,13 +3,13 @@
 The load-bearing contracts:
 - the quantizing post-scan scatter roundtrips values within int8 precision
   and lands scales at the same flat rows as their pages;
-- all three paged-attention entry points (decode / extend / verify) with
-  an int8 pool + scales match the same attention over the explicitly
+- both paged-attention entry points (decode / extend) with an int8 pool
+  + scales match the same attention over the explicitly
   dequantized pool — dequant is FUSED, never a materialized pool copy;
 - the Pallas decode kernel's in-register dequant matches the XLA path;
 - engine-level: greedy decode over an int8 pool is token-identical to the
   raw-dtype pool for (nearly) every sequence of the parity corpus, the
-  verify path's logit error is bounded, prefix sharing reuses quantized
+  teacher-forced logit error is bounded, prefix sharing reuses quantized
   pages AND their scales, TP serving and pause/resume compose, and int8
   mode buys itemsize-ratio x pages (2x under bf16 serving) at the same
   configured pool HBM.
@@ -112,7 +112,7 @@ class TestQuantScatter:
 
 def _attend_all_paths(pool, scales, q3, k3, v3, table, lens, n_new,
                       soft_cap=None, window=None):
-    """(decode, extend, verify) outputs for one pool; q3/k3/v3 are the
+    """(decode, extend) outputs for one pool; q3/k3/v3 are the
     [B, C, H(kv), D] chunk operands, decode uses position 0."""
     kw = dict(soft_cap=soft_cap, sliding_window=window)
     dec = xla_paged.paged_decode_attention(
@@ -123,11 +123,7 @@ def _attend_all_paths(pool, scales, q3, k3, v3, table, lens, n_new,
         q3, k3, v3, pool, jnp.int32(1), table, lens, n_new,
         scales=scales, **kw,
     )
-    ver = xla_paged.paged_verify_attention(
-        q3, k3, v3, pool, jnp.int32(1), table, lens, n_new,
-        scales=scales, **kw,
-    )
-    return dec, ext, ver
+    return dec, ext
 
 
 class TestXLAPathParity:
@@ -160,7 +156,7 @@ class TestXLAPathParity:
             jnp.asarray(deq), None, q3, k3, v3, table, lens, n_new,
             soft_cap, window,
         )
-        for name, g, w in zip(("decode", "extend", "verify"), got, want):
+        for name, g, w in zip(("decode", "extend"), got, want):
             np.testing.assert_allclose(
                 np.asarray(g), np.asarray(w), atol=2e-5, err_msg=name
             )
@@ -237,10 +233,11 @@ class TestEngineParity:
         )
         assert same >= 0.95 * len(prompts), f"{same}/{len(prompts)} matched"
 
-    def test_verify_path_logit_error_bounded(self, params, rng):
-        """Per-position max-abs logit error of the verify forward over an
+    def test_teacher_forced_logit_error_bounded(
+            self, params, rng, decode_tokens_paged):
+        """Per-position max-abs logit error of the decode forward over an
         int8 pool vs the raw pool, teacher-forced on the same tokens —
-        the quantization-noise bound spec decode and PPO logprobs see."""
+        the quantization-noise bound PPO logprobs see."""
         prompt = [int(x) for x in rng.integers(1, 128, size=9)]
         engines = {}
         for kd in (None, "int8"):
@@ -260,30 +257,14 @@ class TestEngineParity:
         for kd, eng in engines.items():
             state = eng.state
             W = eng._table_width(int(np.asarray(state.lens).max()) + 8)
-            lg, _ = tfm.verify_step_paged(
+            lg, _ = decode_tokens_paged(
                 eng.params, CFG, state.cache, chunk,
                 jnp.asarray(eng._table_host[:, :W]), state.lens,
-                jnp.where(state.active, 4, 0).astype(jnp.int32),
-                jnp.where(state.active, 4, 0).astype(jnp.int32),
+                jnp.where(state.active, 4, 0),
             )
             logits[kd] = np.asarray(lg)
         err = np.abs(logits["int8"] - logits[None]).max()
-        assert err < 0.1, f"max verify logit delta {err}"
-
-    def test_spec_decode_over_int8_pool(self, params, rng):
-        """Spec decode composes: greedy spec over an int8 pool is
-        token-identical to vanilla decode over the SAME int8 pool."""
-        prompts = [[int(x) for x in rng.integers(1, 128, n)] for n in (5, 9)]
-        outs = {}
-        for spec in (False, True):
-            outs[spec] = {
-                r: o.output_ids
-                for r, o in _run_greedy(
-                    params, prompts, 10, "int8",
-                    spec_decode=spec, spec_k=3,
-                ).items()
-            }
-        assert outs[True] == outs[False]
+        assert err < 0.1, f"max logit delta {err}"
 
     def test_tp2_int8_matches_single_device(self, params, rng):
         """Int8 pool + scales sharded over a 2-way ``model`` mesh (both on

@@ -16,6 +16,7 @@ dropped expert or a wrong cache position moves a log-probability by
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -23,6 +24,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import engine_contract
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
 from areal_tpu.gen.engine import GenerationEngine, GenRequest
@@ -245,6 +247,11 @@ def _engine(params, **kw):
         page_size=8, enable_prefix_cache=True, seed=3, **kw)
 
 
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
+
+
 @pytest.mark.parametrize("use_pallas", [True, None],
                          ids=["interpret_kernel", "xla_gather"])
 def test_engine_logprobs_match_reference(params, rng, use_pallas):
@@ -272,7 +279,7 @@ def test_engine_logprobs_match_reference(params, rng, use_pallas):
         want = _ref_logprobs(params, p + o.output_ids)[len(p) - 1:]
         np.testing.assert_allclose(
             np.asarray(o.output_logprobs), want, atol=TOL_NATS)
-    # the census every vanilla chunk of an MoE model carries
+    # the census every chunk of an MoE model carries
     chunks = [s["attrs"] for s in tracing.drain()
               if s["name"] == "gen_engine/chunk" and "slots" in s["attrs"]]
     assert chunks and all(

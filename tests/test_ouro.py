@@ -25,6 +25,7 @@ that); the same path in bfloat16 is off by more than 1e-3.
 """
 
 import dataclasses
+import functools
 import json
 import os
 import subprocess
@@ -36,11 +37,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import engine_contract
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import PPOHyperparameters
 from areal_tpu.base import flops as flops_mod
 from areal_tpu.base import tracing
-from areal_tpu.gen.drafter import TransformerDrafter
 from areal_tpu.gen.engine import GenerationEngine, GenRequest
 from areal_tpu.interfaces.ppo import PPOActorInterface
 from areal_tpu.models import hf as hf_conv
@@ -434,12 +435,12 @@ def test_dense_cache_prefill_and_decode_match_reference(params, rng):
 
 @pytest.mark.parametrize("use_pallas", [True, False],
                          ids=["interpret_kernel", "xla_gather"])
-def test_paged_extend_decode_and_verify_match_reference(
+def test_paged_extend_and_decode_match_reference(
         params, rng, use_pallas):
     """Two rows of unequal length through ``extend_paged`` (two chunks, the
-    second reading the pool), then ``decode_step_paged`` and one
-    ``verify_step_paged`` of 3 tokens: every log-prob is the reference's
-    full forward, and each (pass, layer) wrote its own slice of the pool."""
+    second reading the pool), then nine steps of ``decode_step_paged``:
+    every log-prob is the reference's full forward, and each (pass, layer)
+    wrote its own slice of the pool."""
     seq = _toks(rng, 34)
     cache = tfm.PagedKVCache.empty(CFG, 24, PAGE)
     assert cache.pages.shape == (T * L, 24, 2, 4, PAGE, 16)
@@ -458,7 +459,7 @@ def test_paged_extend_decode_and_verify_match_reference(
                 skip_pool=c == 0, use_pallas=use_pallas)
         lens = jnp.asarray(n0)
         got = [[], []]
-        for step in range(6):
+        for step in range(9):
             cur = [seq[n0[0] + step], seq[n0[1] + step]]
             logits, cache, lens = tfm.decode_step_paged(
                 params, CFG, cache, jnp.asarray(cur), table, lens,
@@ -466,18 +467,9 @@ def test_paged_extend_decode_and_verify_match_reference(
             for b in range(2):
                 got[b].append(float(jax.nn.log_softmax(logits[b])[
                     seq[n0[b] + step + 1]]))
-        chunk = np.asarray([seq[26:29], seq[17:20]], np.int32)
-        vlogits, cache2 = tfm.verify_step_paged(
-            params, CFG, cache, jnp.asarray(chunk), table, lens,
-            jnp.asarray([3, 3]), jnp.asarray([3, 3]), use_pallas=use_pallas)
     want = _ref_logprobs(params, seq)
-    np.testing.assert_allclose(got[0], want[20:26], atol=TOL_NATS)
-    np.testing.assert_allclose(got[1], want[11:17], atol=TOL_NATS)
-    for b, at in ((0, 26), (1, 17)):
-        lp = jax.nn.log_softmax(vlogits[b], axis=-1)
-        np.testing.assert_allclose(
-            np.asarray(lp[np.arange(3), np.asarray(seq[at + 1: at + 4])]),
-            want[at: at + 3], atol=TOL_NATS)
+    np.testing.assert_allclose(got[0], want[20:29], atol=TOL_NATS)
+    np.testing.assert_allclose(got[1], want[11:20], atol=TOL_NATS)
     pages = np.asarray(cache.pages)
     first = pages[:, 1]         # row 0's first page, in every cache layer
     assert np.abs(first).min(axis=(1, 2, 3, 4)).min() > 0
@@ -497,6 +489,11 @@ def _engine(params, cfg=CFG, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("admit_buckets", (1, 2, 4))
     return GenerationEngine(cfg, params, **kw)
+
+
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
 
 
 def _check_outputs(params, prompts, outs, n_new):
@@ -595,37 +592,6 @@ def test_prefix_hit_and_resumed_request_give_the_cold_one_s_logits(
         list(part.output_logprobs) + list(rest.output_logprobs),
         whole.output_logprobs, atol=TOL_NATS)
     assert eng2.stats["prefix_hit_tokens"] == PAGE    # 13 prefilled: 1 page
-
-
-def test_speculative_decoding_loops_in_the_verify_pass(params, rng):
-    """The n-gram drafter over a looped target: every verify pass runs
-    all the passes, so greedy speculative chunks give the vanilla chain,
-    and sampled ones the reference's log-probs."""
-    prompt = _toks(rng, 6) * 3
-    chains = []
-    for spec in (False, True):
-        eng = _engine(params, n_pages=96, spec_decode=spec, spec_k=2)
-        eng.submit(GenRequest(rid="a", input_ids=prompt, max_new_tokens=10,
-                              greedy=True))
-        (o,) = eng.run_until_done(4)
-        chains.append(o)
-    assert chains[0].output_ids == chains[1].output_ids
-    assert eng.stats["spec_draft_tokens"] > 0
-    eng.submit(GenRequest(rid="s", input_ids=prompt, max_new_tokens=10,
-                          temperature=1.0))
-    (o,) = eng.run_until_done(4)
-    _check_outputs(params, {"s": prompt}, {"s": o}, 10)
-
-
-@pytest.mark.parametrize("how", ["draft_model", "shared_prefix"])
-def test_drafters_that_mean_nothing_across_passes_are_refused(params, how):
-    if how == "shared_prefix":
-        with pytest.raises(ValueError, match=f"{T} times"):
-            TransformerDrafter.shared_prefix(CFG, params, 1)
-        return
-    plain = dataclasses.replace(CFG, n_passes=1)
-    with pytest.raises(NotImplementedError, match=f"n_passes={T}"):
-        _engine(params, drafter=TransformerDrafter(plain, params))
 
 
 def test_tensor_parallel_engine_shards_the_pool_s_heads(params, rng):

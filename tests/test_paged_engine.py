@@ -418,7 +418,7 @@ class TestLengthOrderedDecode:
     @pytest.mark.parametrize(
         "variant,width",
         [("plain", 32), ("plain", 64), ("int8", 64), ("window", 32),
-         ("no_head", 32), ("hidden", 64)],
+         ("window", 64), ("hidden", 64)],
     )
     def test_bit_equal_to_slot_order(self, params, monkeypatch, variant,
                                      width):
@@ -429,8 +429,7 @@ class TestLengthOrderedDecode:
         cfg = CFG
         if variant == "window":
             cfg = dataclasses.replace(CFG, sliding_window=40)
-        kw = {"no_head": {"with_head": False},
-              "hidden": {"return_hidden": True}}.get(variant, {})
+        kw = {"return_hidden": True} if variant == "hidden" else {}
         rng = np.random.default_rng(width)
         cache = tfm.PagedKVCache.empty(
             cfg, self.B * width, self.PAGE,
@@ -467,11 +466,8 @@ class TestLengthOrderedDecode:
             lambda lens: (jnp.arange(lens.shape[0]),) * 2,
         )
         want = self._step(cfg, params, cache, lens, active, width, **kw)
-        if variant == "no_head":
-            assert got[0] is None and want[0] is None
-        else:
-            np.testing.assert_array_equal(
-                np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(
+            np.asarray(got[0]), np.asarray(want[0]))
         for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         np.testing.assert_array_equal(
@@ -972,7 +968,7 @@ def test_kv_write_kernel_and_scatter_leave_the_same_engine(params, monkeypatch):
     assert all(
         "kv_write_tiles" in a for a in on if a.get("slots") or "admitted" in a
     )
-    # a vanilla chunk: one tile a (layer, running slot, step)
+    # a chunk: one tile a (layer, running slot, step)
     chunk = next(a for a in on if a.get("slots"))
     assert chunk["kv_write_tiles"] == CFG.n_layers * chunk["slots"] * 3
     # an admission: the tiles of 8 rows its prefilled tokens fall into

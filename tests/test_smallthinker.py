@@ -26,6 +26,7 @@ wrong tensor or a wrong cache position moves a log-probability by 1e-2 to
 """
 
 import dataclasses
+import functools
 import json
 import os
 
@@ -35,6 +36,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import engine_contract
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import PPOHyperparameters
 from areal_tpu.base import flops as flops_mod
@@ -379,6 +381,11 @@ def _engine(params, cfg=CFG, **kw):
     return GenerationEngine(cfg, params, **kw)
 
 
+@pytest.mark.parametrize("check", engine_contract.CHECKS)
+def test_engine_contract(params, check):
+    engine_contract.run(check, functools.partial(_engine, params), CFG, params)
+
+
 def _check_outputs(params, prompts, outs, n_new):
     for rid, p in prompts.items():
         o = outs[rid]
@@ -681,13 +688,6 @@ def test_pipelined_chunks_release_nothing_that_is_live(params, rng):
     assert eng.stats["window_pages_released"] > 0
 
 
-def test_engine_refuses_a_draft_model_beside_layer_kinds(params):
-    from areal_tpu.gen.drafter import TransformerDrafter
-
-    with pytest.raises(NotImplementedError, match="layer kinds"):
-        _engine(params, drafter=TransformerDrafter(CFG, params))
-
-
 def test_one_kind_with_a_window_is_the_same_code(rng):
     """A model whose layers all have one window (a period of one): one
     table, and the same release behind the window while the request runs."""
@@ -769,12 +769,13 @@ def test_window_kernel_reads_from_the_first_visible_position(rng, lens):
         assert win == full - sb * span      # the longer block skips one
 
 
-def test_extend_and_verify_across_the_window_s_edge(params, rng):
+def test_extend_and_decode_across_the_window_s_edge(
+        params, rng, decode_tokens_paged):
     """``extend_paged`` in chunks of 6 (so that chunks straddle pages and
-    the window's edge), then ``verify_step_paged``, straight on a pool
-    with a table a kind, the window kinds' entries behind each chunk's
-    first visible position pointing at a page of NaNs as the chunks pass:
-    the verify chunk's logits are the reference's."""
+    the window's edge), then four decode steps, straight on a pool with a
+    table a kind, the window kinds' entries behind each chunk's first
+    visible position pointing at a page of NaNs as the chunks pass: the
+    steps' logits are the reference's."""
     seq = _toks(rng, 34)
     cache = tfm.PagedKVCache.empty(CFG, 60, PAGE)
     assert cache.pages.shape == (2, 60, 2, 2, PAGE, 16)
@@ -798,14 +799,13 @@ def test_extend_and_verify_across_the_window_s_edge(params, rng):
         table[:, 1] = own
         table[1:, 1, : (30 + 1 - WINDOW) // PAGE] = 0
         chunk = jnp.zeros((2, 4), jnp.int32).at[1].set(jnp.asarray(seq[30:]))
-        logits, cache2 = tfm.verify_step_paged(
-            params, CFG, cache, chunk, jnp.asarray(table),
-            jnp.asarray([0, 30]), jnp.asarray([0, 4]), jnp.asarray([0, 4]))
+        logits, cache2 = decode_tokens_paged(
+            params, CFG, cache, chunk, jnp.asarray(table), [0, 30], [0, 4])
     lp = jax.nn.log_softmax(logits[1], axis=-1)
     got = np.asarray(lp[np.arange(3), np.asarray(seq[31:])])
     np.testing.assert_allclose(
         got, _ref_logprobs(params, seq)[30:], atol=TOL_NATS)
-    # the chunk's K/V landed in every kind's own page of positions 30..33
+    # the steps' K/V landed in every kind's own page of positions 30..33
     # (pages 7 and 8 of the row) and in no other kind's
     for j in range(4):
         assert float(jnp.abs(cache2.pages[:, own[j, 8], :, :, :2]).min()) > 0

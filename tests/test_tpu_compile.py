@@ -348,8 +348,8 @@ FUSED_SAMPLE_CELLS = {
     + [pytest.param(64, 768, 32768, id="125m")],
 )
 def test_fused_sample_compiles(compiled_kernels, one_chip, R, E, V):
-    """The head-and-sample kernel as the vanilla chunk calls it (no
-    excluded and no gathered token) at every rollout cell's ``(R, E, V)``:
+    """The head-and-sample kernel as the decode chunk calls it at every
+    rollout cell's ``(R, E, V)``:
     a block of 2048 columns everywhere (3584 rows of bf16 are 14.7 MB a
     buffer), the chip's PRNG for the uniforms."""
     from areal_tpu.ops.pallas import fused_sample as fsk
@@ -370,31 +370,6 @@ def test_fused_sample_compiles(compiled_kernels, one_chip, R, E, V):
     ).as_text()
     assert "fused_sample" in text
     assert f"[{R},{V}]" not in text
-
-
-def test_fused_sample_compiles_with_spec_folds(compiled_kernels, one_chip):
-    """The speculative verify's call: 64 slots x 5 positions, an excluded
-    and a gathered token a row (the two folds the vanilla chunk leaves
-    out)."""
-    from areal_tpu.ops.pallas.fused_sample import fused_sample_pallas
-
-    R, E, V = 320, 1536, 151936
-
-    def f(x, w, temperature, greedy, exclude, gather_ids):
-        return fused_sample_pallas(
-            jax.random.key(0), x, w, temperature, greedy,
-            exclude=exclude, gather_ids=gather_ids,
-        )
-
-    _compile(
-        f,
-        _spec((R, E), jnp.bfloat16, one_chip),
-        _spec((E, V), jnp.bfloat16, one_chip),
-        _spec((R,), jnp.float32, one_chip),
-        _spec((R,), jnp.bool_, one_chip),
-        _spec((R,), jnp.int32, one_chip),
-        _spec((R,), jnp.int32, one_chip),
-    )
 
 
 def _assert_fused_epilogue(text, B, V):

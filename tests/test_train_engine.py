@@ -454,7 +454,7 @@ class TestAsyncSaveHF:
         path = str(tmp_path / "ckpt_async")
         flag = []
         t = engine.save_hf(
-            path, "qwen2", async_write=True,
+            path, "llama", async_write=True,
             post_write=lambda: flag.append(1),
         )
         assert t is not None
@@ -462,6 +462,17 @@ class TestAsyncSaveHF:
         assert t._areal_exc is None
         assert flag == [1]
         assert os.path.exists(os.path.join(path, "model.safetensors"))
+        # the export is COMMITTED (a manifest) and round-trips through disk
+        from areal_tpu.base import recover
+
+        assert recover.read_manifest(path) is not None
+        back = TrainEngine(TINY, ParallelConfig(), OptimizerConfig())
+        back.load_hf(path)
+        for a, b in zip(jax.tree.leaves(engine.params),
+                        jax.tree.leaves(back.params)):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                atol=1e-6)
 
     def test_async_write_failure_is_stored_not_swallowed(
         self, engine, monkeypatch

@@ -104,7 +104,6 @@ DEFAULT_WIRE_DEFS = WireDefs(
     client_modules=(
         "areal_tpu/gen/client.py",
         "areal_tpu/gateway/scheduler.py",
-        "areal_tpu/gateway/brownout.py",
         "areal_tpu/gateway/autoscaler.py",
         "areal_tpu/system/fleet.py",
         "areal_tpu/system/partial_rollout.py",

@@ -431,8 +431,7 @@ def run_serve_scenario(cfg: ServeChaosConfig) -> Dict:
        out against their deadlines and are shed IN QUEUE — zero engine
        admissions, full token-bucket refund, fair-clock restored.
     D. **brownout walk**: synthetic pressure drives the ladder up level
-       by level (clamp -> spec off -> shed light tenants -> admit
-       nothing) and hysteresis + dwell walk it back down, restoring
+       by level (clamp -> shed light tenants -> admit nothing) and hysteresis + dwell walk it back down, restoring
        every lever.
 
     End state must leak nothing: no running slots, no pending requests,
@@ -668,8 +667,6 @@ async def _serve_soak(cfg: ServeChaosConfig) -> Dict:
 
             # D: brownout walk — up the ladder level by level on synthetic
             # pressure, back down under hysteresis + dwell
-            for u in urls:
-                await cl.set_spec_decode(u, True)
             trans0 = counter(metrics_mod.GW_BROWNOUT_TRANSITIONS)
             bcfg = BrownoutConfig(min_hold_s=5.0, clamp_max_tokens=8)
             fake_t = [0.0]
@@ -679,7 +676,7 @@ async def _serve_soak(cfg: ServeChaosConfig) -> Dict:
 
             gw_cfg = _GwCfg()
             ctrl = wire_brownout(
-                bcfg, sched, gw_cfg, cl, clock=lambda: fake_t[0]
+                bcfg, sched, gw_cfg, clock=lambda: fake_t[0]
             )
             sig = [ScaleSignals(routed=2, healthy=2)]
             ctrl.fetch_signals = lambda: sig[0]
@@ -689,8 +686,8 @@ async def _serve_soak(cfg: ServeChaosConfig) -> Dict:
                 sig[0] = dataclasses.replace(sig[0], kv_occupancy=kv)
                 return await ctrl.step_once()
 
-            levels = [await walk(kv) for kv in (0.92, 0.96, 0.975)]
-            # level 3: a below-floor tenant is shed with an honest hint
+            levels = [await walk(kv) for kv in (0.92, 0.975)]
+            # level 2: a below-floor tenant is shed with an honest hint
             shed_ok = pause_ok = False
             try:
                 sched.submit(
@@ -699,24 +696,18 @@ async def _serve_soak(cfg: ServeChaosConfig) -> Dict:
             except RateLimited as e:
                 shed_ok = e.retry_after_s > 0
             levels.append(await walk(0.995))
-            spec_off = [
-                bool((await cl.metrics(u)).get("spec_decode")) for u in urls
-            ]
             clamp_at_top = gw_cfg.brownout_max_tokens
-            # level 4: nobody new gets in
+            # level 3: nobody new gets in
             try:
                 sched.submit(
                     GatewayRequest.build("anonymous", prompt, dict(sp))
                 )
             except RateLimited as e:
                 pause_ok = e.retry_after_s > 0
-            # hysteresis: barely below the level-4 entry is NOT enough to
+            # hysteresis: barely below the level-3 entry is NOT enough to
             # step down, even after the dwell
             held = await walk(0.985)
-            down = [await walk(0.10) for _ in range(4)]
-            spec_back = [
-                bool((await cl.metrics(u)).get("spec_decode")) for u in urls
-            ]
+            down = [await walk(0.10) for _ in range(3)]
             transitions = counter(
                 metrics_mod.GW_BROWNOUT_TRANSITIONS
             ) - trans0
@@ -724,41 +715,35 @@ async def _serve_soak(cfg: ServeChaosConfig) -> Dict:
                 "up": levels,
                 "held_at": held,
                 "down": down,
-                "spec_disabled_at_top": [not s for s in spec_off],
-                "spec_restored": spec_back,
                 "clamp_at_top": clamp_at_top,
                 "clamp_after": gw_cfg.brownout_max_tokens,
                 "shed_429": shed_ok,
                 "pause_429": pause_ok,
                 "transitions": transitions,
             }
-            if levels != [1, 2, 3, 4]:
+            if levels != [1, 2, 3]:
                 violations.append(f"brownout escalation walked {levels}")
-            if held != 4:
+            if held != 3:
                 violations.append(
                     f"hysteresis failed: stepped to {held} on a barely-"
                     "recovered signal"
                 )
-            if down != [3, 2, 1, 0]:
+            if down != [2, 1, 0]:
                 violations.append(f"brownout de-escalation walked {down}")
-            if any(spec_off):
-                violations.append("level 2 left spec decode enabled")
-            if not all(spec_back):
-                violations.append("recovery did not restore spec decode")
             if clamp_at_top != bcfg.clamp_max_tokens:
                 violations.append("level 1 did not clamp max_tokens")
             if gw_cfg.brownout_max_tokens is not None:
                 violations.append("recovery did not remove the clamp")
             if not shed_ok:
                 violations.append(
-                    "level 3 did not shed the below-floor tenant"
+                    "level 2 did not shed the below-floor tenant"
                 )
             if not pause_ok:
-                violations.append("level 4 admitted a new request")
-            if transitions != 8:
+                violations.append("level 3 admitted a new request")
+            if transitions != 6:
                 violations.append(
                     f"counted {transitions} brownout transitions, "
-                    "expected 8 (4 up + 4 down; the held step is free)"
+                    "expected 6 (3 up + 3 down; the held step is free)"
                 )
             if sched.admit_paused or sched.shed_weight_floor:
                 violations.append("brownout levers left engaged at level 0")
