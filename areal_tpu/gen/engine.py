@@ -86,7 +86,6 @@ from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import fused_sample as fused_ops
 from areal_tpu.ops import moe as moe_ops
 from areal_tpu.ops import paged_attention as paged_ops
-from areal_tpu.ops import ssm as ssm_ops
 
 logger = logging.getLogger("areal_tpu.gen.engine")
 
@@ -127,12 +126,15 @@ class GenState:
     # by slot, not by page; zeroed or seeded from a snapshot at admission
     # (never inherited from the slot's last tenant), carried between
     # admission's chunks, updated in place by every decode step.
-    ssm: Optional[tfm.SSMState] = None
+    # A model whose attention runs inside a convolved latent (``cfg.cca``)
+    # keeps its carry here the same way, BESIDE keys and values in every
+    # layer (``tfm.CCAState``: ``carry [L, B, W]``, a few KB a layer).
+    ssm: Optional[Any] = None
     # the prefix cache's SNAPSHOTS of that state, ``[Ls, n_snapshots,
     # ...]``: entry ``i`` is the state after exactly the tokens of the
     # registry node that files it (``PrefixRegistry``), so a prompt that
     # hits there is seeded by one copy instead of a prefill
-    snaps: Optional[tfm.SSMState] = None
+    snaps: Optional[Any] = None
 
 
 @dataclasses.dataclass
@@ -185,6 +187,11 @@ class GenOutput:
     # prompt tokens this request did NOT prefill: served from shared pages
     # (and, with recurrent state, a snapshot) of the prefix cache
     prefix_hit_tokens: int = 0
+
+
+# rows of the routing record (``record_routing``) that one pull of the
+# harvest takes
+_ROUTING_PULL = 8
 
 
 def _page_ids(page, kind: Optional[int] = None):
@@ -313,7 +320,7 @@ class GenerationEngine:
         pipeline_chunks: Optional[bool] = None,
         fused_sample: Optional[bool] = None,
         record_routing: bool = False,
-        state_snapshots: int = 8,
+        state_snapshots: Optional[int] = None,
     ):
         # one listener a process, live before the first device work below
         tracing.listen_for_compiles()
@@ -346,19 +353,25 @@ class GenerationEngine:
                         "latent attention: a latent page has no head axis to "
                         "shard; tensor-parallel serving is not supported"
                     )
-            self._stateful = cfg.ssm is not None
+            # per-slot state beside the page pool: the state-space layers'
+            # recurrent state, or the convolved latent's carry (the
+            # ``state_*`` counters are of either)
+            self._stateful = cfg.ssm is not None or cfg.cca is not None
             if self._stateful:
-                # what has no test beside per-slot recurrent state is
-                # refused, not approximated
+                # what has no test beside per-slot state is refused, not
+                # approximated
+                kind = (
+                    "state-space layers" if cfg.ssm is not None
+                    else "attention in a convolved latent")
                 if self.kv_quantized:
                     raise NotImplementedError(
-                        "state-space layers: an int8 page pool beside "
-                        "recurrent state is not supported"
+                        f"{kind}: an int8 page pool beside per-slot state "
+                        "is not supported"
                     )
                 if mesh is not None and mesh.size > 1:
                     raise NotImplementedError(
-                        "state-space layers: the per-slot state has no "
-                        "sharding; a mesh of several devices is not supported"
+                        f"{kind}: the per-slot state has no sharding; a "
+                        "mesh of several devices is not supported"
                     )
             if mesh is not None:
                 if "model" not in mesh.axis_names:
@@ -432,6 +445,15 @@ class GenerationEngine:
                 else self.B * self.M * bytes_ratio * K
             )
             self.pool = PagePool(self.n_pages, page_size)
+            # snapshots of the per-slot state in the prefix cache: 8 of a
+            # recurrent state (76 MB each at the published sizes); of the
+            # convolved latent's carry (86 KB) two a slot: an admission
+            # files at most one, so the runs the running slots filed keep
+            # theirs and as many runs again whose tenants have left (the
+            # least recently used goes first; a run is a page at least)
+            if state_snapshots is None:
+                state_snapshots = (
+                    8 if cfg.cca is None else min(2 * self.B, self.n_pages))
             self.n_snapshots = (
                 max(int(state_snapshots), 1)
                 if self._stateful and enable_prefix_cache else 0)
@@ -480,12 +502,9 @@ class GenerationEngine:
                         if record_routing
                         else None
                     ),
-                    ssm=(
-                        tfm.SSMState.empty(cfg, self.B)
-                        if self._stateful else None
-                    ),
+                    ssm=tfm.row_state_empty(cfg, self.B),
                     snaps=(
-                        tfm.SSMState.empty(cfg, self.n_snapshots)
+                        tfm.row_state_empty(cfg, self.n_snapshots)
                         if self.n_snapshots else None
                     ),
                 )
@@ -518,6 +537,12 @@ class GenerationEngine:
                 # no-op) so that holding a slot compiles nothing later
                 self._jit_activity = self._activity_fn()
                 self._set_activity(build=True)
+                if record_routing:
+                    # likewise the harvest's pull of the routing record
+                    self._jit_routing_rows = jax.jit(lambda rec, idx: rec[idx])
+                    self._jit_routing_rows(
+                        self.state.out_routing,
+                        jnp.zeros((_ROUTING_PULL,), jnp.int32))
             self.accepting = True  # False = decode only, no new admissions
             self.paused = False
             self._slots: List[Optional[_SlotInfo]] = [None] * self.B
@@ -679,6 +704,10 @@ class GenerationEngine:
                 "preemptions": 0,
                 "preempted_tokens_recomputed": 0,
             }
+            if self._moe and cfg.moe.skip_expert:
+                # of the decode chunks' routing (active rows x expert
+                # layers x steps): rows in all, and rows that took the skip
+                self.stats.update(moe_rows=0, moe_skip_rows=0)
             start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
     # ------------------------------------------------------------------ #
@@ -1459,7 +1488,8 @@ class GenerationEngine:
                 # back by the write program beside the fresh K/V)
                 ks, vs, rows = tfm.extend_paged_kv(
                     params, self.cfg, state.cache, tokens, table, start,
-                    n_new, skip_pool=skip_pool, ssm=state.ssm, slots=slots)
+                    n_new, skip_pool=skip_pool, ssm=state.ssm, slots=slots,
+                    moe_grouped=grouped)
                 return jax.tree.map(pad_rows, (ks, vs)), rows
 
         else:
@@ -1484,7 +1514,8 @@ class GenerationEngine:
         layer that both read the state)."""
         from areal_tpu.ops.pallas import ssm_decode
 
-        if ssm_decode.ssm_decode_applies(self.cfg, self.mesh):
+        if self.cfg.ssm is not None and ssm_decode.ssm_decode_applies(
+                self.cfg, self.mesh):
             return ssm_decode.ssm_decode
         return None
 
@@ -1733,11 +1764,11 @@ class GenerationEngine:
                         state.snaps)
                 return dataclasses.replace(
                     state, ssm=tfm.put_ssm_rows(
-                        state.ssm, dst, (rows.ssm, rows.conv)))
+                        state.ssm, dst, jax.tree.leaves(rows)))
             return dataclasses.replace(
                 state, snaps=tfm.put_ssm_rows(
                     state.snaps, dst,
-                    (state.ssm.ssm[:, src], state.ssm.conv[:, src])))
+                    [a[:, src] for a in jax.tree.leaves(state.ssm)]))
 
         jitted = jax.jit(
             copy, donate_argnums=(0,),
@@ -1749,7 +1780,7 @@ class GenerationEngine:
     def _copy_state(self, pairs: List[Tuple[int, int]], into_slots: bool):
         """Run :meth:`_state_copy_fn` over ``(dst, src)`` pairs in row
         buckets; counts the snapshot bytes moved."""
-        per = ssm_ops.state_bytes_per_slot(self.cfg)
+        per = tfm.row_state_bytes(self.cfg)
         i = 0
         while i < len(pairs):
             n = self._row_bucket(len(pairs) - i)
@@ -2219,6 +2250,16 @@ class GenerationEngine:
                     routing, cfg.moe.num_experts, dtype=jnp.int32
                 ).sum(axis=(1, 2))
                 census = jnp.stack([(load > 0).sum(), load.max()])
+                if cfg.moe.skip_expert:
+                    # (the one-hot above has no column for the skip, which
+                    # is no expert: it is in neither count.) Of the rows
+                    # that run: routed in all, and routed to the skip
+                    n_rows = state.active.sum() * routing.shape[0]
+                    skipped = (
+                        (routing == cfg.moe.num_experts).any(axis=-1)
+                        & state.active[None]).sum()
+                    census = jnp.concatenate(
+                        [census, jnp.stack([n_rows, skipped])])
                 if out_routing is not None:
                     keep = state.active[:, None, None]
                     out_routing = out_routing.at[rows, idx].set(jnp.where(
@@ -2249,7 +2290,8 @@ class GenerationEngine:
             if census is not None:
                 slots = n_steps * cfg.n_moe_layers * cfg.moe.num_experts
                 flags += (jnp.stack([
-                    census[:, 0].sum(), jnp.int32(slots), census[:, 1].max()
+                    census[:, 0].sum(), jnp.int32(slots), census[:, 1].max(),
+                    *(census[:, i].sum() for i in range(2, census.shape[1])),
                 ]).astype(jnp.int32),)
             return flags
 
@@ -2276,9 +2318,13 @@ class GenerationEngine:
 
     def _fold_chunk_aux(self, aux: tuple, chunk_attrs: dict):
         """What a resolved chunk carries after its four harvest flags: an
-        MoE model's routing census (one ``[3]`` vector), else nothing."""
+        MoE model's routing census (one ``[3]`` vector; with a skip output
+        two more: rows routed, rows that took the skip), else nothing."""
         if aux:
-            hit, slots, load_max = (int(v) for v in aux[0])
+            hit, slots, load_max, *skip = (int(v) for v in aux[0])
+            for name, v in zip(("moe_rows", "moe_skip_rows"), skip):
+                chunk_attrs[name] = v
+                self.stats[name] += v
             chunk_attrs["moe_experts_hit"] = hit
             chunk_attrs["moe_expert_slots"] = slots
             chunk_attrs["moe_load_max"] = load_max
@@ -2379,7 +2425,15 @@ class GenerationEngine:
         want = (st.n_gen, st.out_tokens, st.out_logprobs, st.active,
                 st.max_gen)
         if st.out_routing is not None and len(slots):
-            want += (st.out_routing[np.asarray(slots)],)
+            # in groups of ``_ROUTING_PULL`` rows, the last padded: ONE
+            # program whatever number of slots ends in a chunk (an index
+            # array of the slots' own number compiled one for each)
+            n = _ROUTING_PULL
+            slots = list(slots)
+            want += tuple(
+                self._jit_routing_rows(st.out_routing, jnp.asarray(
+                    (slots[i : i + n] + [slots[i]] * n)[:n], jnp.int32))
+                for i in range(0, len(slots), n))
         n_gen, out_tokens, out_logprobs, active, max_gen, *routing = (
             jax.device_get(want)
         )
@@ -2387,7 +2441,8 @@ class GenerationEngine:
             "n_gen": n_gen, "out_tokens": out_tokens,
             "out_logprobs": out_logprobs, "active": active,
             "max_gen": max_gen,
-            "out_routing": dict(zip(slots, routing[0])) if routing else {},
+            "out_routing": dict(
+                zip(slots, (row for rows in routing for row in rows))),
         }
 
     def _harvest(self, b: int, reason: str, host_state: dict) -> GenOutput:
@@ -2487,8 +2542,8 @@ class GenerationEngine:
                 # inside the chunk is counted to the chunk's end
                 chunk_attrs.update(
                     state_slots=len(running) * decode_steps,
-                    state_bytes_per_slot=ssm_ops.state_bytes_per_slot(cfg),
-                    state_layers=cfg.n_ssm_layers)
+                    state_bytes_per_slot=tfm.row_state_bytes(cfg),
+                    state_layers=cfg.n_ssm_layers or cfg.n_layers)
                 self.stats["state_slots"] += chunk_attrs["state_slots"]
             self.stats["loop_passes"] += decode_steps * cfg.n_passes
             self.stats["layer_passes"] += layer_passes

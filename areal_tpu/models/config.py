@@ -3,7 +3,7 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash, smallthinker, ouro, granitemoehybrid) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -37,6 +37,16 @@ class MoEConfig:
     # placed before attention"; every forward hands ``moe_mlp`` that
     # tensor beside the experts' own).
     router_on_layer_input: bool = False
+    # The router is an MLP with STATE (``zaya``; ``ops/moe.py:_route_mlp``):
+    # a down-projection of the normed residual to ``router_dim``, plus the
+    # previous layer's such vector times a learned gain, then an RMSNorm
+    # and three GELU layers to the logits. The vector rides every
+    # forward's layer scan beside the residual. None: one matmul.
+    router_dim: Optional[int] = None
+    # One more router output after the experts': a row that chooses it
+    # takes NO expert and passes through, scaled by its router weight.
+    # ``top_idx == num_experts`` marks it wherever routing is reported.
+    skip_expert: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +70,25 @@ class MLAConfig:
     def latent_dim(self) -> int:
         """What the cache must hold of a token in a layer."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class CCAConfig:
+    """Compressed convolutional attention (family ``zaya``; ``ops/cca.py``
+    has the equations). The query and key projections of a token, side by
+    side (``latent_dim`` channels, one ``head_dim`` a head), pass through
+    two causal convolutions over the sequence, a depthwise one of
+    ``time0`` taps and one of ``time1`` taps that mixes the channels of
+    each head, before a mean of the two projections is added, each head
+    is scaled to a fixed norm (the keys times a learned temperature a kv
+    head) and the rotary embedding is applied. The values of the second
+    half of the kv heads are the PREVIOUS token's. What a row carries from
+    token to token beside its keys and values is therefore the
+    convolutions' last inputs and that one value projection:
+    ``models/transformer.CCAState``."""
+
+    time0: int = 2
+    time1: int = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -168,6 +197,10 @@ class ModelConfig:
     # Latent attention in place of the q/k/v projections (None = those).
     # ``head_dim`` is then nope + rope and ``n_kv_heads == n_q_heads``.
     mla: Optional[MLAConfig] = None
+    # Attention inside a convolved latent (``CCAConfig``). Every layer is
+    # paged attention over the ONE page pool, and beside it a slot keeps a
+    # small carry (``models/transformer.CCAState``).
+    cca: Optional[CCAConfig] = None
 
     # Rotary (apply_rotary False => learned absolute positions, gpt2)
     apply_rotary: bool = True
@@ -205,6 +238,10 @@ class ModelConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # ``zaya``: a branch joins the residual as ``a_r * (x + b_r) + a_h *
+    # (branch + b_h)``, four learned vectors a sublayer
+    # (``layers.attn_res`` / ``layers.mlp_res``), in place of ``x + branch``
+    residual_scaling: bool = False
     final_logits_soft_cap: Optional[float] = None
     abs_position_embedding: bool = False   # gpt2 learned positions
 
@@ -356,6 +393,22 @@ class ModelConfig:
         return self.rotary_dim if self.rotary_dim is not None else self.head_dim
 
     @property
+    def cca_latent_dim(self) -> int:
+        """Channels the convolutions run over: ``[q ; k]`` of one token."""
+        return (self.n_q_heads + self.n_kv_heads) * self.head_dim
+
+    @property
+    def cca_carry_dim(self) -> int:
+        """What a row carries a layer: the last ``time0 - 1`` inputs of
+        the first convolution, the last ``time1 - 1`` of the second, and
+        the shifted half of the last token's value projection."""
+        c = self.cca
+        return (
+            (c.time0 + c.time1 - 2) * self.cca_latent_dim
+            + self.n_kv_heads // 2 * self.head_dim
+        )
+
+    @property
     def n_moe_layers(self) -> int:
         """Layers with a router (0 for a dense model)."""
         return self.n_layers - self.n_dense_layers if self.mlp_type == "moe" else 0
@@ -449,6 +502,32 @@ class ModelConfig:
                     "layer_pattern: a period that divides n_layers, in a "
                     "model of one stack with rotary or no positions"
                 )
+        if self.cca is not None:
+            if (
+                self.mla is not None or self.ssm is not None
+                or self.layer_pattern is not None or self.n_passes > 1
+                or self.sliding_window is not None or self.qk_layernorm
+                or self.use_attention_bias or self.abs_position_embedding
+                or self.n_kv_heads % 2 or self.n_mtp_layers
+                or min(self.cca.time0, self.cca.time1) < 1
+            ):
+                raise ValueError(
+                    "cca: one pass of alike full-attention layers with an "
+                    "even number of kv heads (half of them hold the previous "
+                    "token's values) and convolutions of at least one tap; "
+                    "with latent attention, state-space layers, layer kinds, "
+                    "a looped stack, a window, q/k norms or biases, learned "
+                    "positions or prediction modules it is not supported"
+                )
+        if self.moe is not None and self.moe.router_dim is not None and (
+            self.moe.scoring != "softmax" or self.moe.router_on_layer_input
+            or self.n_dense_layers or self.n_passes > 1 or self.n_mtp_layers
+        ):
+            raise ValueError(
+                "moe.router_dim: the stateful MLP router scores by softmax "
+                "over the residual it is given, in a stack of alike layers "
+                "run once"
+            )
         if self.mla is not None:
             m = self.mla
             if self.head_dim != m.qk_nope_head_dim + m.qk_rope_head_dim:

@@ -23,7 +23,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from areal_tpu.models.config import (
-    MLAConfig, ModelConfig, MoEConfig, SSMConfig,
+    CCAConfig, MLAConfig, ModelConfig, MoEConfig, SSMConfig,
 )
 
 HFState = Dict[str, np.ndarray]
@@ -1333,6 +1333,232 @@ register_hf_family(
         config_to_hf=_gpt2_config_to_hf,
         params_from_hf=_gpt2_params_from_hf,
         params_to_hf=_gpt2_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# zaya (attention inside a convolved latent, CCA; a top-1 expert layer behind
+# an MLP router with state and a skip; learned residual scaling; tied head)
+# --------------------------------------------------------------------------- #
+
+
+def _zaya_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read. Every layer is
+    ``hybrid``: an attention sublayer inside the convolved latent, then an
+    expert sublayer. What the family can say and this program does not do
+    is refused, never guessed: a ``layer_types`` entry other than
+    ``hybrid`` (``hybrid_sliding``: window layers of this attention kind),
+    a non-null ``sliding_window``, biases on the projections or the head,
+    another ``rope_type`` than ``default``, fewer entries than layers."""
+    L = hf["num_hidden_layers"]
+    types = hf.get("layer_types") or ["hybrid"] * L
+    if len(types) < L or any(t != "hybrid" for t in types[:L]):
+        raise ValueError(
+            "zaya: every layer_types entry (one a layer) must be 'hybrid'; "
+            "window layers of this attention kind are not supported")
+    if hf.get("sliding_window") is not None:
+        raise ValueError("zaya: a sliding window is not supported")
+    if hf.get("attention_bias", False) or hf.get("lm_head_bias", False):
+        raise ValueError("zaya: biases on the projections or the head")
+    rope = (hf.get("rope_parameters") or {}).get("hybrid") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError("zaya: rope_type other than 'default'")
+    factor = float(rope.get(
+        "partial_rotary_factor", hf.get("partial_rotary_factor", 1.0)))
+    head_dim = hf["head_dim"]
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=head_dim,
+        hidden_dim=hf["hidden_size"],
+        # (shapes nothing: every layer's MLP is the experts')
+        intermediate_dim=hf["moe_intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 32768),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5),
+        rotary_base=float(rope.get("rope_theta", 10000.0)),
+        rotary_dim=int(head_dim * factor),
+        activation_function=hf.get("hidden_act", "silu"),
+        tied_embedding=bool(hf.get("tie_word_embeddings", True)),
+        cca=CCAConfig(
+            time0=int(hf.get("cca_time0", 2)), time1=int(hf.get("cca_time1", 2))),
+        residual_scaling=True,
+        mlp_type="moe",
+        moe=MoEConfig(
+            num_experts=hf["num_experts"],
+            top_k=hf["num_experts_per_tok"],
+            norm_topk_prob=False,
+            expert_dim=hf["moe_intermediate_size"],
+            selection_bias=True,
+            router_dim=hf["router_hidden_size"],
+            skip_expert=True,
+        ),
+    )
+
+
+def _zaya_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys, key for key (``hybrid_sliding``'s rotary entry
+    is the family's constant: no layer of that type is supported, so
+    nothing of the model depends on it)."""
+    factor = cfg.rot_dim / cfg.head_dim
+
+    def rope(theta):
+        return {"partial_rotary_factor": factor, "rope_theta": theta,
+                "rope_type": "default"}
+
+    theta = cfg.rotary_base
+    return {
+        "model_type": "zaya",
+        "attention_bias": False,
+        "cca_time0": cfg.cca.time0,
+        "cca_time1": cfg.cca.time1,
+        "head_dim": cfg.head_dim,
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "layer_types": ["hybrid"] * cfg.n_layers,
+        "lm_head_bias": False,
+        "max_position_embeddings": cfg.n_positions,
+        "moe_intermediate_size": cfg.expert_dim,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_experts": cfg.moe.num_experts,
+        "num_experts_per_tok": cfg.moe.top_k,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "partial_rotary_factor": factor,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_parameters": {
+            "hybrid": rope(int(theta) if float(theta).is_integer() else theta),
+            "hybrid_sliding": rope(10000),
+            "rope_type": "default",
+        },
+        "router_hidden_size": cfg.moe.router_dim,
+        "sliding_window": None,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+# (ours, theirs, transposed): per-layer leaves that map one to one. The
+# names on the right were written from memory of the family's public
+# modelling file (``benchmark/configs/zaya1-8b-l16.json``,
+# ``assumed.from_memory.weight_names``)
+_ZAYA_ATTN = (
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("conv0_b", "self_attn.conv_qk.0.bias", False),
+    ("conv1_b", "self_attn.conv_qk.1.bias", False),
+    ("k_temp", "self_attn.temp", False),
+)
+_ZAYA_ROUTER = (
+    ("router_in", "mlp.router.down_proj.weight", True),
+    ("b_router_in", "mlp.router.down_proj.bias", False),
+    ("router_mix", "mlp.router.eda_scale", False),
+    ("router_norm", "mlp.router.norm.weight", False),
+    ("router_w1", "mlp.router.mlp.0.weight", True),
+    ("b_router1", "mlp.router.mlp.0.bias", False),
+    ("router_w2", "mlp.router.mlp.2.weight", True),
+    ("b_router2", "mlp.router.mlp.2.bias", False),
+    ("router", "mlp.router.mlp.4.weight", True),
+    ("b_router", "mlp.router.balancing_biases", False),
+)
+_ZAYA_RES = (
+    ("attn_res", "res_scale_attn"), ("mlp_res", "res_scale_mlp"))
+_ZAYA_RES_LEAVES = (
+    ("a_r", "residual_scale"), ("b_r", "residual_bias"),
+    ("a_h", "hidden_states_scale"), ("b_h", "hidden_states_bias"))
+_ZAYA_EXPERT = (
+    ("w_gate", "gate_proj"), ("w_up", "up_proj"), ("w_down", "down_proj"))
+
+
+def _zaya_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    """The two value projections (current token, previous token) side by
+    side in ``wv``; the depthwise convolution ``[C, 1, taps]`` as ``[taps,
+    C]``; the grouped one ``[C, D, taps]`` (a head's outputs by inputs) as
+    ``[taps, heads, D in, D out]``."""
+    L, D = cfg.n_layers, cfg.head_dim
+    H = cfg.n_q_heads + cfg.n_kv_heads
+    pre = "model.layers.{i}."
+
+    def stack(name, transpose=False, fn=None):
+        m = _stack(sd, pre + name, L, transpose)
+        return m if fn is None else np.stack([fn(x) for x in m])
+
+    attn = {o: stack(t, tr) for o, t, tr in _ZAYA_ATTN}
+    attn["wv"] = np.concatenate([
+        stack("self_attn.val_proj1.weight", True),
+        stack("self_attn.val_proj2.weight", True)], axis=-1)
+    attn["conv0_w"] = stack(
+        "self_attn.conv_qk.0.weight", fn=lambda w: w[:, 0, :].T)
+    attn["conv1_w"] = stack(
+        "self_attn.conv_qk.1.weight",
+        fn=lambda w: w.reshape(H, D, D, -1).transpose(3, 0, 2, 1))
+    mlp = {o: stack(t, tr) for o, t, tr in _ZAYA_ROUTER}
+    for ours, theirs in _ZAYA_EXPERT:
+        mlp[ours] = np.stack([
+            _stack(sd, pre + f"mlp.experts.{e}.{theirs}.weight", L, True)
+            for e in range(cfg.moe.num_experts)], axis=1)
+    layers = {
+        "ln1": {"weight": stack("input_layernorm.weight")},
+        "ln2": {"weight": stack("post_attention_layernorm.weight")},
+        "attn": attn, "mlp": mlp,
+    }
+    for ours, theirs in _ZAYA_RES:
+        layers[ours] = {
+            o: stack(f"{theirs}.{t}") for o, t in _ZAYA_RES_LEAVES}
+    return {
+        "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
+        "layers": layers,
+        "final_ln": {"weight": np.asarray(sd["model.norm.weight"])},
+    }
+
+
+def _zaya_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    L, D = cfg.n_layers, cfg.head_dim
+    half = cfg.n_kv_heads // 2 * D
+    lay = jax_to_numpy(params["layers"])
+    sd: HFState = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
+        "model.norm.weight": np.asarray(params["final_ln"]["weight"]),
+    }
+    for i in range(L):
+        pre = f"model.layers.{i}."
+
+        def put(name, m, transpose=False):
+            sd[pre + name] = np.ascontiguousarray(m.T if transpose else m)
+
+        a, m = lay["attn"], lay["mlp"]
+        put("input_layernorm.weight", lay["ln1"]["weight"][i])
+        put("post_attention_layernorm.weight", lay["ln2"]["weight"][i])
+        for ours, theirs, tr in _ZAYA_ATTN:
+            put(theirs, a[ours][i], tr)
+        put("self_attn.val_proj1.weight", a["wv"][i][:, :half], True)
+        put("self_attn.val_proj2.weight", a["wv"][i][:, half:], True)
+        put("self_attn.conv_qk.0.weight", a["conv0_w"][i].T[:, None, :])
+        w1 = a["conv1_w"][i]                        # [taps, H, in, out]
+        put("self_attn.conv_qk.1.weight",
+            w1.transpose(1, 3, 2, 0).reshape(-1, D, w1.shape[0]))
+        for ours, theirs, tr in _ZAYA_ROUTER:
+            put(theirs, m[ours][i], tr)
+        for ours, theirs in _ZAYA_EXPERT:
+            for e in range(cfg.moe.num_experts):
+                put(f"mlp.experts.{e}.{theirs}.weight", m[ours][i, e], True)
+        for ours, theirs in _ZAYA_RES:
+            for o, t in _ZAYA_RES_LEAVES:
+                put(f"{theirs}.{t}", lay[ours][o][i])
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="zaya",
+        hf_model_type="zaya",
+        config_from_hf=_zaya_config_from_hf,
+        config_to_hf=_zaya_config_to_hf,
+        params_from_hf=_zaya_params_from_hf,
+        params_to_hf=_zaya_params_to_hf,
     )
 )
 

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import attention as attn_ops
+from areal_tpu.ops import cca as cca_ops
 from areal_tpu.ops import norms
 from areal_tpu.ops import ssm as ssm_ops
 from areal_tpu.ops.activations import ACT2FN
@@ -94,6 +95,18 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         full = cfg.qk_norm_full
         attn["q_norm"] = jnp.ones((L, Hq * D if full else D), dtype)
         attn["k_norm"] = jnp.ones((L, Hkv * D if full else D), dtype)
+    if cfg.cca is not None:
+        # the two convolutions over [q ; k] (``ops/cca.py``): taps first,
+        # the newest last; the second is one D x D block a head, input-
+        # major. Fan-in scale, not normal(0.02), under which the
+        # convolved path would vanish beside the mean it is added to
+        c, C = cfg.cca, cfg.cca_latent_dim
+        attn["conv0_w"] = w((L, c.time0, C)) * (c.time0 ** -0.5 / std)
+        attn["conv0_b"] = jnp.zeros((L, C), dtype)
+        attn["conv1_w"] = w((L, c.time1, Hq + Hkv, D, D)) * (
+            (c.time1 * D) ** -0.5 / std)
+        attn["conv1_b"] = jnp.zeros((L, C), dtype)
+        attn["k_temp"] = jnp.ones((L, Hkv), dtype)
 
     if cfg.mlp_type == "gated":
         mlp: Dict[str, Any] = {
@@ -116,6 +129,19 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         }
         if cfg.moe.selection_bias:
             mlp["b_router"] = jnp.zeros((L, X), dtype)
+        if cfg.moe.router_dim is not None:
+            # the stateful MLP router (``ops/moe.py:_route_mlp``); its
+            # last layer keeps the name ``router``, one output more where
+            # the family has a skip
+            R, n_out = cfg.moe.router_dim, X + int(cfg.moe.skip_expert)
+            mlp.update(
+                router_in=w((L, E, R)), b_router_in=jnp.zeros((L, R), dtype),
+                router_mix=jnp.ones((L, R), dtype),
+                router_norm=jnp.ones((L, R), dtype),
+                router_w1=w((L, R, R)), b_router1=jnp.zeros((L, R), dtype),
+                router_w2=w((L, R, R)), b_router2=jnp.zeros((L, R), dtype),
+                router=w((L, R, n_out)), b_router=jnp.zeros((L, n_out), dtype),
+            )
         if cfg.moe.n_shared_experts:
             Fs = cfg.moe.n_shared_experts * F
             mlp["shared_gate"] = w((L, E, Fs))
@@ -140,6 +166,13 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     if cfg.norm_branch_out:
         params["layers"]["attn_out_ln"] = ln(has_ln_bias)
         params["layers"]["mlp_out_ln"] = ln(has_ln_bias)
+    if cfg.residual_scaling:
+        # how a branch joins the residual (:func:`_add_branch`)
+        for name in _RES_SCALE.values():
+            params["layers"][name] = {
+                "a_r": jnp.ones((L, E), dtype), "b_r": jnp.zeros((L, E), dtype),
+                "a_h": jnp.ones((L, E), dtype), "b_h": jnp.zeros((L, E), dtype),
+            }
     if cfg.exit_gate:
         # carried, never read by a forward (``ModelConfig.exit_gate``)
         params["exit_gate"] = {
@@ -318,6 +351,14 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         width = "heads" if cfg.qk_norm_full else None
         attn["q_norm"] = ("layer", width)
         attn["k_norm"] = ("layer", width)
+    if cfg.cca is not None:
+        # no tensor-parallel split of the convolved latent (the engine
+        # refuses a mesh for this family); the trainer replicates these
+        attn["conv0_w"] = ("layer", None, None)
+        attn["conv0_b"] = ("layer", None)
+        attn["conv1_w"] = ("layer", None, None, None, None)
+        attn["conv1_b"] = ("layer", None)
+        attn["k_temp"] = ("layer", None)
 
     if cfg.mlp_type == "gated":
         mlp: Dict[str, Any] = {
@@ -343,6 +384,14 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         }
         if cfg.moe.selection_bias:
             mlp["b_router"] = ("layer", None)
+        if cfg.moe.router_dim is not None:
+            mlp.update(
+                router_in=("layer", "embed", None), router=("layer", None, None),
+                router_w1=("layer", None, None), router_w2=("layer", None, None),
+                **{k: ("layer", None) for k in (
+                    "b_router_in", "router_mix", "router_norm", "b_router1",
+                    "b_router2", "b_router")},
+            )
         if cfg.moe.n_shared_experts:
             mlp["shared_gate"] = ("layer", "embed", "mlp")
             mlp["shared_up"] = ("layer", "embed", "mlp")
@@ -359,6 +408,10 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
     if cfg.norm_branch_out:
         axes["layers"]["attn_out_ln"] = ln()
         axes["layers"]["mlp_out_ln"] = ln()
+    if cfg.residual_scaling:
+        for name in _RES_SCALE.values():
+            axes["layers"][name] = {
+                k: ("layer", "embed") for k in ("a_r", "b_r", "a_h", "b_h")}
     if cfg.exit_gate:
         axes["exit_gate"] = {"weight": ("embed", None), "bias": (None,)}
     if cfg.ssm is not None:
@@ -478,6 +531,37 @@ def _qkv_roped(cfg: ModelConfig, p, x, cos, sin, rotary=None):
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
     return q, k, v
+
+
+def _cca_qkv_roped(cfg: ModelConfig, p, h, cos, sin, positions,
+                   carry=None, n_valid=None):
+    """:func:`_qkv_roped` of a model whose attention runs inside a
+    convolved latent (``cfg.cca``; ``ops/cca.py``), over ``h [B, T, E]``:
+    ``(q, k, v, carry)``. The rows continue ``carry [B, W]`` (None:
+    nothing) and hand back theirs after their first ``n_valid [B]``
+    tokens. The rotary embedding comes LAST, after the convolutions, the
+    mean and the norm: what a cache holds is ``k`` after all of it."""
+    q, k, v, carry = cca_ops.qkv(cfg, p, h, positions, carry, n_valid)
+    return apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v, carry
+
+
+def _cca_qkv_step(cfg: ModelConfig, p, h, cos, sin, positions, carry, active):
+    """:func:`_cca_qkv_roped` over ONE token a row (``h [B, E]``, a decode
+    step): rows where ``active`` is false keep their carry."""
+    q, k, v, carry = _cca_qkv_roped(
+        cfg, p, h[:, None], cos[:, None], sin[:, None], positions[:, None],
+        carry, active.astype(jnp.int32))
+    return q[:, 0], k[:, 0], v[:, 0], carry
+
+
+def _router_state0(cfg: ModelConfig, x):
+    """What a stateful router (``cfg.moe.router_dim``) reads as the state
+    before the first layer: zeros ``[..., router_dim]``, fp32, one a row
+    of ``x``. It rides every forward's layer scan beside ``x``. None for
+    every other model."""
+    if cfg.moe is None or cfg.moe.router_dim is None:
+        return None
+    return jnp.zeros((*x.shape[:-1], cfg.moe.router_dim), jnp.float32)
 
 
 # --------------------------------------------------------------------------- #
@@ -603,10 +687,14 @@ def _attn_scale(cfg: ModelConfig) -> float:
     return cfg.softmax_scale or cfg.head_dim ** -0.5
 
 
-def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None):
-    """Returns (out, aux_loss, routing) — aux is the MoE load-balancing/z
-    loss (``jnp`` scalar, 0 for dense MLPs); routing the experts each token
-    chose, ``[..., top_k]`` int32 (``None`` for dense MLPs). ``layer_in``:
+def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None,
+         router_state=None):
+    """Returns (out, aux_loss, routing, router_state) — aux is the MoE
+    load-balancing/z loss (``jnp`` scalar, 0 for dense MLPs); routing the
+    experts each token chose, ``[..., top_k]`` int32 (``None`` for dense
+    MLPs); ``router_state`` in and out: a stateful router's vector of the
+    previous and of this layer (:func:`_router_state0`; None for every
+    other model). ``layer_in``:
     the layer's normed INPUT (what its attention read), which every
     forward hands over: a router that reads it instead of ``x``
     (``MoEConfig.router_on_layer_input``) gets it from here. ``routed``:
@@ -619,7 +707,7 @@ def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None):
         cfg.mlp_type == "moe" and "router" not in p
     ):
         out = (act(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
-        return out, jnp.float32(0.0), None
+        return out, jnp.float32(0.0), None, None
     if cfg.mlp_type == "fc":
         h = x @ p["w_fc"]
         if "b_fc" in p:
@@ -628,15 +716,16 @@ def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None):
         h = h @ p["w_proj"]
         if "b_proj" in p:
             h = h + p["b_proj"]
-        return h, jnp.float32(0.0), None
+        return h, jnp.float32(0.0), None, None
     # moe
     from areal_tpu.ops.moe import moe_mlp
 
-    return moe_mlp(
+    res = moe_mlp(
         cfg, p, x,
         router_input=layer_in if cfg.moe.router_on_layer_input else None,
-        routed=routed,
+        routed=routed, router_state=router_state,
     )
+    return res if len(res) == 4 else (*res, None)
 
 
 def _attn_out(p, ctx):
@@ -653,14 +742,25 @@ def _attn_out(p, ctx):
     return y
 
 
+# the learned residual scaling of each branch (``cfg.residual_scaling``), by
+# the name :func:`_add_branch` is handed
+_RES_SCALE = {"attn_out_ln": "attn_res", "mlp_out_ln": "mlp_res"}
+
+
 def _add_branch(cfg: ModelConfig, lp, name: str, x, branch):
     """``x + branch``: a layer's attention or MLP output onto the residual,
     through the branch's own norm ``lp[name]`` first where the model has
-    one (``cfg.norm_branch_out``)."""
+    one (``cfg.norm_branch_out``). With ``cfg.residual_scaling`` the sum
+    is ``a_r * (x + b_r) + a_h * (branch + b_h)``, four learned vectors a
+    branch: the published form defers it to the next sublayer's entry
+    (and the final norm's), which is the same function."""
     if cfg.norm_branch_out:
         branch = _norm(cfg, lp[name], branch)
     if cfg.residual_multiplier != 1.0:
         branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    if cfg.residual_scaling:
+        s = lp[_RES_SCALE[name]]
+        return s["a_r"] * (x + s["b_r"]) + s["a_h"] * (branch + s["b_h"])
     return x + branch
 
 
@@ -1046,9 +1146,20 @@ def forward_packed(
     # the attention kernel, which the split checkpointing below cuts at
     def _pre(x, lp, rotary):
         h = _norm(cfg, lp["ln1"], x)
+        if cfg.cca is not None:
+            # one row that holds every document: a token at position 0
+            # of its own resets the convolutions and the value shift
+            q, k, v, _ = _cca_qkv_roped(
+                cfg, lp["attn"], h[None], cos[None], sin[None],
+                positions[None])
+            return q[0], k[0], v[0]
         return _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
 
-    def _post(x, ctx, lp):
+    # ``r``: a stateful router's vector of the layer before (None for
+    # every other model: the carry is then ``x`` alone, as ever)
+    r0 = _router_state0(cfg, x)
+
+    def _post(x, ctx, lp, r):
         layer_in = (
             _norm(cfg, lp["ln1"], x)
             if cfg.moe is not None and cfg.moe.router_on_layer_input
@@ -1056,8 +1167,10 @@ def forward_packed(
         )
         x = _add_branch(cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx))
         h = _norm(cfg, lp["ln2"], x)
-        m, aux, routing = _mlp(cfg, lp["mlp"], h, layer_in)
-        return _add_branch(cfg, lp, "mlp_out_ln", x, m), (aux, routing)
+        m, aux, routing, r = _mlp(
+            cfg, lp["mlp"], h, layer_in, router_state=r)
+        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        return (x if r0 is None else (x, r)), (aux, routing)
 
     policy = cfg.remat_policy if remat else "none"
     dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -1090,20 +1203,22 @@ def forward_packed(
                 policy=dots, prevent_cse=False,
             )
             post = jax.checkpoint(
-                lambda x, ctx, lp: _post(x, ctx, _cast(cfg, lp)),
+                lambda x, ctx, lp, r: _post(x, ctx, _cast(cfg, lp), r),
                 policy=dots, prevent_cse=False,
             )
 
-            def layer(x, lp):
+            def layer(carry, lp):
+                x, r = (carry, None) if r0 is None else carry
                 q, k, v = pre(x, lp)
-                return post(x, attend(q, k, v), lp)
+                return post(x, attend(q, k, v), lp, r)
 
             return layer
 
-        def layer(x, lp):
+        def layer(carry, lp):
+            x, r = (carry, None) if r0 is None else carry
             lp = _cast(cfg, lp)
             q, k, v = _pre(x, lp, rotary)
-            return _post(x, attend(q, k, v), lp)
+            return _post(x, attend(q, k, v), lp, r)
 
         if policy == "full":
             return jax.checkpoint(layer, prevent_cse=False)
@@ -1125,9 +1240,11 @@ def forward_packed(
     layers = [make_layer(kind) for kind in cfg.layer_kinds]
     layer = layers[-1]      # the block a multi-token-prediction module is
     x, (auxes, routing), _ = _run_stack(
-        cfg, layers, x, params, unroll=cfg.layer_scan_unroll or 1,
-        ssm_layer=ssm_layer,
+        cfg, layers, x if r0 is None else (x, r0), params,
+        unroll=cfg.layer_scan_unroll or 1, ssm_layer=ssm_layer,
     )
+    if r0 is not None:
+        x, _ = x
     stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     out = _head(cfg, params, x) if with_head else x
@@ -1233,8 +1350,10 @@ class KVCache:
     k: jnp.ndarray
     v: jnp.ndarray
     lens: jnp.ndarray
-    # the state-space layers' state of every row (``cfg.ssm``; else None)
-    ssm: Optional["SSMState"] = None
+    # what a row keeps beside its keys and values: the state-space
+    # layers' state (``cfg.ssm``: :class:`SSMState`), or the convolved
+    # latent's carry (``cfg.cca``: :class:`CCAState`); else None
+    ssm: Optional[Any] = None
 
     @classmethod
     def empty(cls, cfg: ModelConfig, batch: int, capacity: int) -> "KVCache":
@@ -1245,7 +1364,7 @@ class KVCache:
             k=jnp.zeros(shape, dt),
             v=jnp.zeros(shape, dt),
             lens=jnp.zeros((batch,), jnp.int32),
-            ssm=SSMState.empty(cfg, batch) if cfg.ssm is not None else None,
+            ssm=row_state_empty(cfg, batch),
         )
 
 
@@ -1274,6 +1393,47 @@ class SSMState:
             conv=jnp.zeros(conv, jnp.dtype(cfg.dtype)),
         )
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class CCAState:
+    """What attention inside a convolved latent (``cfg.cca``) keeps of a
+    ROW beside its keys and values: ``carry [L, B, W]``, a layer's last
+    inputs of the two convolutions and the shifted half of the last
+    token's value projection (``ModelConfig.cca_carry_dim``;
+    ``ops/cca.py``), flat, in the serving dtype. A few KB a layer whatever
+    the row's length. Like :class:`SSMState` it is allocated by row and
+    shared through the prefix cache only as a copy; unlike it, it is small
+    enough for the snapshot table to hold two entries a slot
+    (``gen/engine.py``)."""
+
+    carry: jnp.ndarray
+
+    @classmethod
+    def empty(cls, cfg: ModelConfig, batch: int) -> "CCAState":
+        return cls(carry=jnp.zeros(
+            (cfg.n_layers, batch, cfg.cca_carry_dim), jnp.dtype(cfg.dtype)))
+
+
+def row_state_empty(cfg: ModelConfig, batch: int):
+    """The per-row state of ``batch`` rows, of the model's kind
+    (:class:`SSMState`, :class:`CCAState`), or None for a model whose rows
+    keep keys and values only."""
+    if cfg.ssm is not None:
+        return SSMState.empty(cfg, batch)
+    if cfg.cca is not None:
+        return CCAState.empty(cfg, batch)
+    return None
+
+
+def row_state_bytes(cfg: ModelConfig) -> int:
+    """What one row's per-row state takes, all layers (0: the model keeps
+    none)."""
+    if cfg.ssm is not None:
+        return ssm_ops.state_bytes_per_slot(cfg)
+    if cfg.cca is not None:
+        return (cfg.n_layers * cfg.cca_carry_dim
+                * jnp.dtype(cfg.dtype).itemsize)
+    return 0
 
 
 def prefill(
@@ -1321,10 +1481,19 @@ def prefill(
                 idx[None, :, None] - idx[None, None, :] < window)
         return functools.partial(layer, window, rotary, kind_mask)
 
-    def layer(window, rotary, mask, x, lp):
+    r0 = _router_state0(cfg, x)
+
+    def layer(window, rotary, mask, carry, lp):
+        x, r = (carry, None) if r0 is None else carry
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
-        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)  # [B, S, H, D]
+        cc = None
+        if cfg.cca is not None:
+            q, k, v, cc = _cca_qkv_roped(
+                cfg, lp["attn"], h, cos, sin, positions, None, prompt_lens)
+        else:
+            q, k, v = _qkv_roped(
+                cfg, lp["attn"], h, cos, sin, rotary)  # [B, S, H, D]
         if use_flash:
             H, D = q.shape[-2:]
             ctx = attn_ops.packed_attention(
@@ -1351,10 +1520,10 @@ def prefill(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        x = _add_branch(
-            cfg, lp, "mlp_out_ln", x,
-            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
-        return x, (k, v)
+        m, _, _, r = _mlp(
+            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
+        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        return (x if r0 is None else (x, r)), (k, v, cc)
 
     def ssm_layer(x, lp):
         return _ssm_block(
@@ -1362,10 +1531,14 @@ def prefill(
             lambda p, h: ssm_ops.mixer_chunk(
                 cfg, p, h, positions, n_valid=prompt_lens))
 
-    x, (ks, vs), ssm = _run_stack(
-        cfg, [make_layer(kind) for kind in cfg.layer_kinds], x, params,
-        ssm_layer=ssm_layer,
+    x, (ks, vs, cc), ssm = _run_stack(
+        cfg, [make_layer(kind) for kind in cfg.layer_kinds],
+        x if r0 is None else (x, r0), params, ssm_layer=ssm_layer,
     )
+    if r0 is not None:
+        x, _ = x
+    if cc is not None:
+        ssm = (cc,)
     cap = cache.k.shape[2]
     pad = cap - S
     if pad < 0:
@@ -1377,7 +1550,7 @@ def prefill(
         k=jnp.where(keep, ks.astype(cache.k.dtype), cache.k),
         v=jnp.where(keep, vs.astype(cache.v.dtype), cache.v),
         lens=prompt_lens.astype(jnp.int32),
-        ssm=None if ssm is None else SSMState(*ssm),
+        ssm=None if ssm is None else type(cache.ssm)(*ssm),
     )
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     last = jnp.take_along_axis(
@@ -1404,13 +1577,21 @@ def decode_step(
     write_at = cache.lens  # [B]
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
 
-    def layer(kind, x, inputs):
+    r0 = _router_state0(cfg, x)
+
+    def layer(kind, carry, inputs):
+        x, r = (carry, None) if r0 is None else carry
         window, rotary = kind
-        lp, kc, vc = inputs
+        lp, kc, vc, *cc = inputs
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         # q: [B, Hq, D]; k/v: [B, Hkv, D]
-        q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
+        if cfg.cca is not None:
+            q, k, v, new = _cca_qkv_step(
+                cfg, lp["attn"], h, cos, sin, positions, cc[0], active)
+            cc = (new,)
+        else:
+            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
         # write new K/V at write_at (only for active slots)
         slot = jnp.arange(kc.shape[1])[None, :, None, None]  # [1, S, 1, 1]
         put = (slot == write_at[:, None, None, None]) & active[:, None, None, None]
@@ -1428,10 +1609,10 @@ def decode_step(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        x = _add_branch(
-            cfg, lp, "mlp_out_ln", x,
-            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h)[0])
-        return x, (kc, vc)
+        m, _, _, r = _mlp(
+            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
+        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        return (x if r0 is None else (x, r)), (kc, vc, *cc)
 
     def ssm_layer(x, inputs):
         lp, s, cv = inputs
@@ -1439,14 +1620,21 @@ def decode_step(
             cfg, _cast(cfg, lp), x,
             lambda p, h: ssm_ops.mixer_step(cfg, p, h, (s, cv), active))
 
-    x, (ks, vs), ssm = _run_stack(
+    x, (ks, vs, *cc), ssm = _run_stack(
         cfg, [functools.partial(layer, kind) for kind in cfg.layer_kinds],
-        x, params, xs=(cache.k, cache.v), ssm_layer=ssm_layer,
-        ssm_xs=() if cache.ssm is None else (cache.ssm.ssm, cache.ssm.conv),
+        x if r0 is None else (x, r0), params,
+        xs=(cache.k, cache.v) + (
+            (cache.ssm.carry,) if cfg.cca is not None else ()),
+        ssm_layer=ssm_layer,
+        ssm_xs=() if cfg.ssm is None else (cache.ssm.ssm, cache.ssm.conv),
     )
+    if r0 is not None:
+        x, _ = x
+    if cc:
+        ssm = tuple(cc)
     cache = KVCache(
         k=ks, v=vs, lens=new_lens,
-        ssm=None if ssm is None else SSMState(*ssm))
+        ssm=None if ssm is None else type(cache.ssm)(*ssm))
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     return _head(cfg, params, x), cache
 
@@ -1715,7 +1903,7 @@ def _extend_layers(
     n_new: jnp.ndarray,      # [B]
     skip_pool: bool = False,
     moe_grouped: bool = False,
-    ssm: Optional[SSMState] = None,
+    ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
 ):
     """The multi-token layer scan over the page pool (chunked prefill).
@@ -1729,7 +1917,11 @@ def _extend_layers(
     not written here) over its ``n_new[b]`` tokens; a token at position 0
     starts from nothing whatever the slot held. ``ssm_rows``: ``(ssm [Ls,
     B, G, K, N, 128], conv [Ls, B, (d_conv - 1) x C])`` after them, for the
-    caller to put back; None for a model without such layers."""
+    caller to put back; None for a model without such layers.
+
+    Attention inside a convolved latent (``cfg.cca``): the same, with
+    ``ssm`` the per-slot carry (:class:`CCAState`) and ``ssm_rows`` ``(carry
+    [L, B, W],)``."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     routed = None
@@ -1775,6 +1967,7 @@ def _extend_layers(
         x, li, *rest = carry                          # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
+        cc = None
         if cfg.mla is not None:
             # absorbed form, chunk and pool alike: multi-query attention
             # of [B, C, H, W] queries over the latents, whose head is the
@@ -1783,6 +1976,13 @@ def _extend_layers(
             k, v = latent[..., None, :], None
             ctx = _attend(q, k, k[..., : cfg.mla.kv_lora_rank], li, j)
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
+        elif cfg.cca is not None:
+            # the rows continue their slots' carry (a token at position 0
+            # starts from nothing whatever the slot held)
+            q, k, v, cc = _cca_qkv_roped(
+                cfg, lp["attn"], h, cos, sin, positions,
+                ssm.carry[li, slots], n_new)
+            ctx = _attend(q, k, v, li, j)
         else:
             # [B, C, H(kv), D]
             q, k, v = _pack_qkv(cfg, *_qkv_roped(
@@ -1791,19 +1991,26 @@ def _extend_layers(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        x = _add_branch(
-            cfg, lp, "mlp_out_ln", x,
-            _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
-                 _routed_at(cfg, routed, li, j))[0])
-        return (x, li + int(j == len(kinds) - 1), *rest), (k, v)
+        m, _, _, r = _mlp(
+            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
+            _routed_at(cfg, routed, li, j),
+            router_state=None if r0 is None else rest[0])
+        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        if r0 is not None:
+            rest = (r,)
+        return (x, li + int(j == len(kinds) - 1), *rest), (k, v, cc)
 
     zero = jnp.int32(0)
-    _, (ks, vs), ssm_rows = _run_stack(
+    # after ``x`` and ``li``: a stateful router's vector, or the running
+    # index of the state-space layers (no model has both)
+    r0 = _router_state0(cfg, x)
+    rest0 = () if r0 is None else (r0,)
+    _, (ks, vs, cc), ssm_rows = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero) if cfg.ssm is None else (x, zero, zero), params,
+        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero), params,
         ssm_layer=ssm_layer,
     )
-    return ks, vs, ssm_rows
+    return ks, vs, ssm_rows if cc is None else (cc,)
 
 
 def _kind_table(table, j: int):
@@ -1822,7 +2029,7 @@ def extend_paged_kv(
     n_new: jnp.ndarray,      # [B] valid tokens in this chunk (<= C)
     skip_pool: bool = False,
     moe_grouped: bool = False,
-    ssm: Optional[SSMState] = None,
+    ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
 ):
     """Chunked prefill, the computing half: attend the chunk causally over
@@ -1842,7 +2049,7 @@ def extend_paged_kv(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         moe_grouped=moe_grouped, ssm=ssm, slots=slots,
     )
-    if cfg.ssm is not None:
+    if ssm_rows is not None:
         return ks, vs, ssm_rows
     return ks, vs
 
@@ -1858,33 +2065,36 @@ def extend_paged(
     skip_pool: bool = False,
     use_pallas: Optional[bool] = None,
     mesh=None,
-    ssm: Optional[SSMState] = None,
+    ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
+    moe_grouped: bool = False,
 ):
     """Both halves of chunked prefill in one call: :func:`extend_paged_kv`,
     then the chunk's KV into the pages (:func:`_write_chunk_kv`, whose
     path ``use_pallas`` / ``mesh`` choose; the chunk's attention is
-    XLA's). With state-space layers: ``(cache, ssm)``, the rows' state
-    put back at ``slots``."""
+    XLA's). With per-slot state (state-space layers, a convolved
+    latent's carry): ``(cache, ssm)``, the rows' state put back at
+    ``slots``."""
     ks, vs, *rows = extend_paged_kv(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
-        ssm=ssm, slots=slots,
+        ssm=ssm, slots=slots, moe_grouped=moe_grouped,
     )
     cache = _write_chunk_kv(
         cache, ks, vs, table, start, n_new, use_pallas, mesh
     )
-    if cfg.ssm is None:
+    if not rows:
         return cache
     return cache, put_ssm_rows(ssm, slots, *rows)
 
 
-def put_ssm_rows(ssm: SSMState, slots, rows) -> SSMState:
-    """``rows`` (``(ssm, conv)`` over ``[Ls, n, ...]``) into the per-slot
-    state at ``slots [n]``; a slot index past the last is dropped (a
-    padding row)."""
-    return SSMState(*(
-        a.at[:, slots].set(v.astype(a.dtype), mode="drop")
-        for a, v in zip((ssm.ssm, ssm.conv), rows)))
+def put_ssm_rows(ssm, slots, rows):
+    """``rows`` (the arrays of the per-slot state, :class:`SSMState`'s
+    ``(ssm, conv)`` or :class:`CCAState`'s ``(carry,)``, over ``[L, n,
+    ...]``) into the per-slot state at ``slots [n]``; a slot index past
+    the last is dropped (a padding row)."""
+    return jax.tree.map(
+        lambda a, v: a.at[:, slots].set(v.astype(a.dtype), mode="drop"),
+        ssm, type(ssm)(*rows))
 
 
 def _length_order(lens: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -1908,7 +2118,7 @@ def decode_step_paged(
     return_hidden: bool = False,
     with_routing: bool = False,
     moe_grouped: bool = False,
-    ssm: Optional[SSMState] = None,
+    ssm: Optional[Any] = None,
     ssm_update=None,
 ) -> Tuple[jnp.ndarray, PagedKVCache, jnp.ndarray]:
     """One decode step over the page pool. Returns (fp32 logits ``[B, V]``,
@@ -1957,7 +2167,13 @@ def decode_step_paged(
     new state is appended to the result. ``ssm_update``: what stands in
     for ``ops/ssm.py:step_update`` on the state of ALL layers
     (``update(ssm_all, layer, x, dt, a, b, c, d, active=) -> (y,
-    ssm_all)``: the ``ssm_decode`` kernel)."""
+    ssm_all)``: the ``ssm_decode`` kernel).
+
+    Attention inside a convolved latent (``cfg.cca``): ``ssm`` is the
+    per-slot carry (:class:`CCAState`). Its rows go through the scan in
+    the step's length order with the layers' weights (each layer reads
+    and returns its own ``[B, W]``), rows that are not ``active`` keep
+    theirs, and the new carry is appended to the result in slot order."""
     from areal_tpu.ops import paged_attention as paged_ops
 
     routed = None
@@ -2006,6 +2222,9 @@ def decode_step_paged(
         # pool's leading axis that holds the layer's pages
         x, li, *rest = carry                          # pool NOT in the scan
         window, rotary = kinds[j]
+        cc = None
+        if cfg.cca is not None:
+            lp, cc = lp
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         kw = dict(
@@ -2026,6 +2245,11 @@ def decode_step_paged(
                 value_width=cfg.mla.kv_lora_rank, **kw,
             )
             ctx = _mla_absorbed_out(cfg, lp["attn"], ctx)
+        elif cfg.cca is not None:
+            q, k, v, cc = _cca_qkv_step(
+                cfg, lp["attn"], h, cos, sin, lens_o, cc, active_o)
+            ctx = paged_ops.paged_decode_attention(
+                q, k, v, cache.pages, li, table_o, lens_o, **kw)
         elif cfg.layer_pattern is None:
             q, k, v = _pack_qkv(
                 cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin))  # q [B, H, D]
@@ -2042,19 +2266,28 @@ def decode_step_paged(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, routing = _mlp(
+        m, _, routing, r = _mlp(
             cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
-            _routed_at(cfg, routed, li, j))
+            _routed_at(cfg, routed, li, j),
+            router_state=None if r0 is None else rest[0])
+        if r0 is not None:
+            rest = (r,)
         return (
             (_add_branch(cfg, lp, "mlp_out_ln", x, m),
              li + int(j == len(kinds) - 1), *rest),
-            (k, v, routing if with_routing else None),
+            (k, v, routing if with_routing else None, cc),
         )
 
     zero = jnp.int32(0)
-    (x, *rest), (ks, vs, routing), _ = _run_stack(
+    # after ``x`` and ``li``: a stateful router's vector, or the
+    # state-space layers' running index and state (no model has both)
+    r0 = _router_state0(cfg, x)
+    rest0 = () if r0 is None else (r0,)
+    active_o = active[order]
+    (x, *rest), (ks, vs, routing, cc), _ = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero) if cfg.ssm is None else (x, zero, zero, ssm), params,
+        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, ssm),
+        params, xs=() if cfg.cca is None else (ssm.carry[:, order],),
         ssm_layer=ssm_layer,
     )
     x, ks = x[inverse], ks[:, inverse]
@@ -2071,6 +2304,8 @@ def decode_step_paged(
         extra = ()
     if cfg.ssm is not None:
         extra += (rest[-1],)
+    if cc is not None:
+        extra += (CCAState(cc[:, inverse]),)
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     if return_hidden:
         return (x, cache, new_lens) + extra

@@ -68,6 +68,15 @@ and added to the routed sum. Where the family says so
 (``MoEConfig.router_on_layer_input``: ``smallthinker``) the router reads
 ANOTHER tensor than the experts: the layer's normed input, computed a
 whole attention earlier; the caller hands it in as ``router_input``.
+
+A router with STATE (``MoEConfig.router_dim``: ``zaya``) is an MLP
+(:func:`_route_mlp`): the residual projected down to ``router_dim``, plus
+the previous layer's such vector times a learned gain, an RMSNorm, three
+GELU layers, a softmax; the correction bias moves the choice and not the
+weight, which is not renormalised. The vector is handed in and back
+(``router_state``) and rides the callers' layer scans. Its last output
+(``MoEConfig.skip_expert``) is no expert: a row that chooses it reads none
+and passes through times its weight, and is ``num_experts`` in ``top_idx``.
 """
 
 from typing import Optional
@@ -75,6 +84,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from areal_tpu.ops import norms
 from areal_tpu.ops.activations import ACT2FN
 
 # the expert matmuls run under this scope: their HLO ``op_name`` carries it
@@ -84,6 +94,8 @@ from areal_tpu.ops.activations import ACT2FN
 EXPERTS_SCOPE = "moe_experts"
 SHARED_SCOPE = "moe_shared_expert"
 EARLY_ROUTER_SCOPE = "moe_router_early"
+ROUTER_MLP_SCOPE = "moe_router_mlp"
+SKIP_SCOPE = "moe_skip"
 
 
 def _route(cfg, router_w, x, bias=None):
@@ -111,6 +123,29 @@ def _route(cfg, router_w, x, bias=None):
     if moe.norm_topk_prob:
         top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
     return top_vals * moe.routed_scaling_factor, top_idx, probs, logits
+
+
+def _route_mlp(cfg, p, x, state):
+    """The stateful MLP router, fp32: :func:`_route`'s results and the
+    layer's router vector ``[T, router_dim]`` for the next layer.
+    ``state``: the previous layer's (zeros before the first)."""
+    moe, f32 = cfg.moe, jnp.float32
+    with jax.named_scope(ROUTER_MLP_SCOPE):
+        r = x.astype(f32) @ p["router_in"].astype(f32) + p[
+            "b_router_in"].astype(f32)
+        r = r + p["router_mix"].astype(f32) * state
+        h = norms.rms_norm(r, p["router_norm"], cfg.layer_norm_epsilon)
+        for w, b in (("router_w1", "b_router1"), ("router_w2", "b_router2")):
+            h = jax.nn.gelu(
+                h @ p[w].astype(f32) + p[b].astype(f32), approximate=False)
+        logits = h @ p["router"].astype(f32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, top_idx = jax.lax.top_k(
+            probs + p["b_router"].astype(f32), moe.top_k)
+        top_vals = jnp.take_along_axis(probs, top_idx, axis=-1)
+        if moe.norm_topk_prob:
+            top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    return top_vals * moe.routed_scaling_factor, top_idx, probs, logits, r
 
 
 def _aux_loss(cfg, chosen, probs, logits):
@@ -171,8 +206,11 @@ def moe_grouped_applies(
     )
 
 
-def moe_mlp(cfg, p, x, router_input=None, routed=None):
-    """x: [..., E] -> (out [..., E], aux_loss, top_idx [..., K]).
+def moe_mlp(cfg, p, x, router_input=None, routed=None, router_state=None):
+    """x: [..., E] -> (out [..., E], aux_loss, top_idx [..., K]), and with
+    a stateful router (``cfg.moe.router_dim``) a fourth: the layer's
+    router vector ``[..., router_dim]``, fp32. ``router_state``: the
+    previous layer's (required then; zeros before the first layer).
 
     ``router_input`` ``[..., E]``: what the router reads where that is not
     ``x`` (``cfg.moe.router_on_layer_input``; required then, refused
@@ -204,7 +242,12 @@ def moe_mlp(cfg, p, x, router_input=None, routed=None):
         raise ValueError(
             "moe_mlp: router_input goes with cfg.moe.router_on_layer_input"
         )
-    if router_input is None:
+    X = cfg.moe.num_experts
+    state = None
+    if cfg.moe.router_dim is not None:
+        top_vals, top_idx, probs, logits, state = _route_mlp(
+            cfg, p, xt, router_state.reshape(-1, cfg.moe.router_dim))
+    elif router_input is None:
         top_vals, top_idx, probs, logits = _route(
             cfg, p["router"], xt, p.get("b_router")
         )
@@ -215,7 +258,8 @@ def moe_mlp(cfg, p, x, router_input=None, routed=None):
                 router_input.reshape(-1, router_input.shape[-1]),
                 p.get("b_router"),
             )
-    onehot = jax.nn.one_hot(top_idx, cfg.moe.num_experts, dtype=jnp.float32)
+    # (a choice of the skip, index X, is a row of zeros: no expert's)
+    onehot = jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
     chosen = onehot.sum(axis=1)                                  # [T, X]
     if routed is not None:
         from areal_tpu.ops.pallas.moe_grouped import moe_grouped
@@ -226,6 +270,7 @@ def moe_mlp(cfg, p, x, router_input=None, routed=None):
                 xt, top_idx, top_vals, chosen.sum(axis=0),
                 stacks["w_gate"], stacks["w_up"], stacks["w_down"], index,
                 activation=cfg.activation_function,
+                with_skip=cfg.moe.skip_expert,
             )
     else:
         combine = (top_vals[:, :, None] * onehot).sum(axis=1)    # [T, X]
@@ -240,9 +285,19 @@ def moe_mlp(cfg, p, x, router_input=None, routed=None):
             out = out + (
                 act(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
             ) @ p["shared_down"]
+    if cfg.moe.skip_expert:
+        with jax.named_scope(SKIP_SCOPE):
+            skipped = jnp.where(top_idx == X, top_vals, 0.0).sum(axis=1)
+            out = out + skipped.astype(xt.dtype)[:, None] * xt
+            # the balance loss is over everything the router can choose
+            chosen = jax.nn.one_hot(
+                top_idx, X + 1, dtype=jnp.float32).sum(axis=1)
     aux = _aux_loss(cfg, chosen, probs, logits)
-    return (
+    res = (
         out.reshape(*lead, -1),
         aux,
         top_idx.reshape(*lead, cfg.moe.top_k),
     )
+    if state is not None:
+        res += (state.reshape(*lead, -1),)
+    return res

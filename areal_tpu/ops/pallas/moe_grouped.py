@@ -101,7 +101,7 @@ def _kernel(li_ref, expert_ref, n_active_ref, x_ref, wg_ref, wu_ref, wd_ref,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("activation", "tm", "interpret"))
+    jax.jit, static_argnames=("activation", "tm", "interpret", "with_skip"))
 def moe_grouped(
     x: jnp.ndarray,            # [T, E] rows
     top_idx: jnp.ndarray,      # [T, k] int32: the experts a row chose
@@ -115,6 +115,7 @@ def moe_grouped(
     activation: str,
     tm: int = None,
     interpret: bool = None,
+    with_skip: bool = False,
 ) -> jnp.ndarray:
     """``sum_j top_vals[t, j] * expert(top_idx[t, j])(x[t])`` -> ``[T, E]``
     in ``x``'s dtype; every product accumulates in f32, ``h`` is rounded
@@ -135,7 +136,11 @@ def moe_grouped(
     order = jnp.argsort(chosen, stable=True).astype(jnp.int32)
     shift = first_row - (jnp.cumsum(sizes) - sizes)
     dest = jnp.arange(N, dtype=jnp.int32) + shift[chosen[order]]
-    src = jnp.zeros((n_tiles * tm,), jnp.int32).at[dest].set(order // k)
+    if with_skip:
+        # sorted last; past the layout's end, where the scatter drops them
+        dest = jnp.where(chosen[order] < X, dest, n_tiles * tm)
+    src = jnp.zeros((n_tiles * tm,), jnp.int32).at[dest].set(
+        order // k, mode="drop" if with_skip else None)
 
     def w_spec(shape):
         return pl.BlockSpec(
@@ -182,6 +187,9 @@ def moe_grouped(
     )
     # padded row of every pair, back in (row, choice) order
     at = jnp.zeros((N,), jnp.int32).at[order].set(dest)
+    y = y[at]
+    if with_skip:
+        y = jnp.where((chosen < X)[:, None], y, 0.0)
     out = jnp.einsum(
-        "tke,tk->te", y[at].reshape(T, k, E), top_vals.astype(jnp.float32))
+        "tke,tk->te", y.reshape(T, k, E), top_vals.astype(jnp.float32))
     return out.astype(x.dtype)

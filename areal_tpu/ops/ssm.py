@@ -159,35 +159,59 @@ def _split_xbc(cfg: ModelConfig, xbc):
     return x, b, c
 
 
+def conv_history(x, state):
+    """``x [B, T, C]`` behind what its rows continue: ``state [B, (K - 1)
+    x C]``, a row's last ``K - 1`` inputs of a causal convolution of ``K``
+    taps, flat (:func:`state_shapes`). ``[B, K - 1 + T, C]``. With
+    :func:`conv_reads` and :func:`conv_next_state`, what the state-space
+    mixer's convolution (:func:`conv_chunk`) and the attention latent's
+    (``ops/cca.py``) share, many tokens a row or one."""
+    B, _, C = x.shape
+    return jnp.concatenate(
+        [state.astype(x.dtype).reshape(B, state.shape[-1] // C, C), x], axis=1)
+
+
+def conv_reads(full, positions):
+    """What each tap reads: for ``d = 0 .. K - 1`` the input ``d`` tokens
+    back ``[B, T, C]``, zero where that would reach behind position 0 of
+    the token's own document (``positions [B, T]``, restarting a
+    document). ``full``: :func:`conv_history`."""
+    T = positions.shape[1]
+    K = full.shape[1] - T + 1
+    for d in range(K):
+        tap = full[:, K - 1 - d : K - 1 - d + T]
+        ok = (positions >= d)[..., None]
+        yield jnp.where(ok, tap, 0)
+
+
+def conv_next_state(full, n_valid, state):
+    """The state after each row's first ``n_valid [B]`` tokens (0: as it
+    was), in ``state``'s shape and dtype."""
+    K = state.shape[-1] // full.shape[-1] + 1
+    new_state = jax.vmap(
+        lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, K - 1, axis=0)
+    )(full, n_valid)
+    return new_state.astype(state.dtype).reshape(state.shape)
+
+
 def conv_chunk(p, xbc, positions, conv_state, n_valid):
     """The causal depthwise convolution over ``xbc [B, T, C]`` whose rows
-    continue ``conv_state [B, (K - 1) x C]`` (the row's last ``K - 1``
-    inputs, flat: :func:`state_shapes`).
-    A tap that would reach behind position 0 of the token's own document
-    reads nothing (``positions [B, T]``, restarting a document). Returns
-    the activated output and the state after each row's first ``n_valid
-    [B]`` tokens."""
+    continue ``conv_state [B, (K - 1) x C]`` (:func:`conv_history`).
+    Returns the activated output and the state after each row's first
+    ``n_valid [B]`` tokens."""
     w = p["conv_w"]                                       # [K, C]
     K = w.shape[0]
-    T = xbc.shape[1]
-    full = jnp.concatenate([
-        conv_state.astype(xbc.dtype).reshape(xbc.shape[0], K - 1, -1), xbc
-    ], axis=1)
+    full = conv_history(xbc, conv_state)
     with jax.named_scope("ssm_conv"):
         out = 0.0
-        for d in range(K):
+        for d, tap in enumerate(conv_reads(full, positions)):
             # the tap ``d`` tokens back: weight K - 1 - d
-            tap = full[:, K - 1 - d : K - 1 - d + T]
-            ok = (positions >= d)[..., None]
-            out = out + jnp.where(ok, tap, 0).astype(jnp.float32) * w[
+            out = out + tap.astype(jnp.float32) * w[
                 K - 1 - d].astype(jnp.float32)
         if "conv_b" in p:
             out = out + p["conv_b"].astype(jnp.float32)
         out = jax.nn.silu(out).astype(xbc.dtype)
-    new_state = jax.vmap(
-        lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, K - 1, axis=0)
-    )(full, n_valid)
-    return out, new_state.astype(conv_state.dtype).reshape(conv_state.shape)
+    return out, conv_next_state(full, n_valid, conv_state)
 
 
 def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):

@@ -200,6 +200,9 @@ MOE_GROUPED_KERNEL = "moe_grouped"
 # layer, so a helper child drives a two-layer model of layer KINDS (one
 # state-space, one attention layer) through the engine (``child_statespace``)
 SSM_DECODE_KERNEL = "ssm_decode"
+# (another helper child, ``child_zaya``, drives a two-layer model whose
+# attention runs inside a convolved latent behind a top-1 expert layer: its
+# decode chunk holds the paged kernels above and ``moe_grouped``)
 
 
 def kernels_in(paths):
@@ -619,6 +622,26 @@ def phase_serve(sz, args):
                              KV_WRITE_KERNEL)),
             f"the two-kind model's decode chunk lacks a kernel: "
             f"{hybrid['kernels']}")
+    # ... and a model whose attention runs inside a convolved latent behind
+    # a top-1 expert layer with a skip (family ``zaya``): the per-slot carry
+    # beside the page pool, its snapshot in the prefix cache, the router's
+    # state through the layer scan
+    latent, _ = helper(d, "zaya", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(latent["logprobs"]["correct"]
+            and latent["state_snapshot_hits"] == 3
+            and latent["prefix_hit_tokens"][1:] == latent["prefix_hit_tokens"][1:2] * 3
+            and latent["prefix_hit_tokens"][1] > 0
+            and latent["router_agreement_given_earlier_choices"] >= 0.9,
+            f"attention in a convolved latent: served log-probs, the "
+            f"router or the snapshot path are off: {latent}")
+    require(args.rehearse or all(
+                any(k.startswith(want) for k in latent["kernels"])
+                for want in ("paged_decode", KV_WRITE_KERNEL)
+                + ((MOE_GROUPED_KERNEL,) if latent["moe_grouped"] else ())),
+            f"the zaya model's decode chunk lacks a kernel: "
+            f"{latent['kernels']}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -1451,7 +1474,102 @@ def child_statespace(arg):
     })
 
 
+def child_zaya(arg):
+    """A two-layer model of family ``zaya`` at ZAYA1-8B's widths (toy
+    widths under ``--rehearse``) through the generation engine, seeded as
+    the benchmark seeds it. A group of four shares a prompt (the first
+    prefills in chunks and files a snapshot of the convolved latent's
+    carry beside its pages, the rest are seeded from it), one request
+    generates alone; the served log-probs are held to the float32
+    reference given the program's routing (``rollout_cca_inproc._check``,
+    which also reports how often the two routers agree), and the decode
+    chunk's program is searched for the kernels it should hold."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine, GenRequest
+    from benchmark import sut, weights
+    from benchmark.drivers import rollout_cca_inproc as drv
+
+    with open(os.path.join(ROOT, "benchmark/configs/zaya1-8b-l16.json")) as f:
+        arch = json.load(f)
+    with open(os.path.join(
+            ROOT, "benchmark/traffic/grpo16_closed256_out8k_cca.json")) as f:
+        chk = json.load(f)["check"]
+    arch.update(num_hidden_layers=2, layer_types=["hybrid", "hybrid"])
+    page, prompt_len, new = 128, 300, 48
+    if arg["rehearse"]:
+        arch.update(hidden_size=64, num_attention_heads=4,
+                    num_key_value_heads=2, vocab_size=512,
+                    serving_dtype="float32")
+        arch = drv._rehearsal_arch(arch)
+        page, prompt_len, new = 16, 40, 12
+    cfg = sut.model_config(arch, {})
+    params = drv._cca_init(
+        weights.make_weights(
+            sut.weight_shapes(cfg, cfg.dtype), arg["seed"],
+            jnp.dtype(cfg.dtype)),
+        arg["seed"])
+    eng = GenerationEngine(
+        cfg, params, max_slots=8, max_seqlen=4 * page + 64,
+        max_new_tokens_cap=64, page_size=page, record_routing=True,
+        seed=arg["seed"] % (2**31 - 1))
+    rng = np.random.default_rng(arg["seed"])
+    shared = rng.integers(1, cfg.vocab_size, prompt_len).tolist()
+    alone = rng.integers(1, cfg.vocab_size, prompt_len // 3).tolist()
+    prompts = {**{f"g{i}": shared for i in range(4)}, "alone": alone}
+    for rid, p in prompts.items():
+        eng.submit(GenRequest(
+            rid=rid, input_ids=p, max_new_tokens=new, temperature=1.0))
+    outs = {o.rid: o for o in eng.run_until_done(decode_steps=8)}
+    (key,) = [k for k in eng._jit_chunk]
+    chunk = eng._chunk_fn(*key)
+    names = sorted(set(re.findall(
+        r'kernel_name = "([^"]+)"',
+        chunk.lower(
+            eng.params, eng.state,
+            jnp.asarray(eng._table_arg(slice(None), key[1])),
+            jnp.zeros((key[2],), jnp.int32)).as_text())))
+    samples = []
+    for rid, o in sorted(outs.items()):
+        p = prompts[rid]
+        forced = np.full((cfg.n_layers, len(p) + len(o.output_ids)), -1, np.int32)
+        forced[:, len(p) - 1 : -1] = np.asarray(o.output_routing)[:, :, 0].T
+        samples.append({"tokens": p + list(o.output_ids), "start": len(p),
+                        "logprobs": o.output_logprobs, "forced": forced})
+    stats, grouped = dict(eng.stats), eng._moe_grouped(eng.B)
+    eng.state = None
+    verdict = drv._check(params, arch, cfg.dtype, samples, chk)
+    emit({
+        "widths": [cfg.hidden_dim, cfg.n_q_heads, cfg.n_kv_heads,
+                   cfg.head_dim, cfg.cca_carry_dim, cfg.moe.num_experts,
+                   cfg.moe.router_dim],
+        "kernels": names, "moe_grouped": grouped,
+        "state_snapshots_taken": stats["state_snapshots_taken"],
+        "state_snapshot_hits": stats["state_snapshot_hits"],
+        "prefix_hit_tokens": [outs[f"g{i}"].prefix_hit_tokens
+                              for i in range(4)],
+        "state_slots": stats["state_slots"],
+        "moe_skip_rows": stats["moe_skip_rows"], "moe_rows": stats["moe_rows"],
+        **{k: verdict.get(k) for k in (
+            "router_agreement_free_running",
+            "router_agreement_given_earlier_choices")},
+        "stand_ins_refused": {
+            name: not verdict[name]["correct"] for name in drv._STAND_INS
+            if name in verdict},
+        "logprobs": {k: verdict.get(k) for k in (
+            "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
+            "mean_abs_diff_nats", "seq_mean_abs_diff_nats", "n_positions")},
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 CHILDREN = {
+    "zaya": child_zaya,
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,

@@ -868,6 +868,119 @@ def test_hybrid_engine_programs_compile(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
 
 
+def _zaya_program(one_chip, program: str, n_layers: int, n_pages: int):
+    """``(cfg, the jitted program, its arguments as shapes on the chip)``
+    of a ``zaya`` model at ZAYA1-8B's widths and ``n_layers`` layers under
+    the engine at the cell's 256 slots of 72 pages and a pool of
+    ``n_pages``, placeholder weights."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "zaya1-8b-l16.json")) as f:
+        arch = json.load(f)
+    arch.update(num_hidden_layers=n_layers, layer_types=["hybrid"] * n_layers)
+    cfg = sut.model_config(arch, {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B, M = 256, 72
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=B, max_seqlen=9216, max_new_tokens_cap=8192,
+        page_size=128, n_pages=80, record_routing=True, seed=0)
+    eng._decode_use_pallas = True
+    assert not eng.fused and eng._moe_grouped(B) and eng._stateful
+    carry = eng.state.ssm.carry
+    assert carry.shape == (n_layers, B, 2688) and carry.dtype == jnp.bfloat16
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    pages = eng.state.cache.pages
+    assert pages.shape[2:] == (2, 2, 128, 128)
+    # the pool at ``n_pages`` and the snapshot table as the engine sizes it
+    # there: two entries a slot
+    state = dataclasses.replace(
+        jax.tree.map(spec, eng.state),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (n_layers, n_pages) + pages.shape[2:], pages.dtype, one_chip)),
+        snaps=tfm.CCAState(_spec(
+            (n_layers, min(2 * B, n_pages), 2688), jnp.bfloat16, one_chip)))
+    params = jax.tree.map(spec, shapes)
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        args = (params, state, i32(B, M), i32(0))
+    else:
+        fn = eng._extend_fn(8, 64, skip_pool=False)
+        args = (params, state, i32(8, eng.admit_chunk), i32(8, 64), i32(8),
+                i32(8), i32(8))
+    return cfg, fn, args
+
+
+@pytest.mark.parametrize("program", ["jit_chunk", "jit_extend"])
+def test_zaya_engine_programs_compile(compiled_kernels, one_chip, program):
+    """A two-layer ``zaya`` model at ZAYA1-8B's widths (attention inside
+    the convolved latent, top-1 of 16 experts and a skip behind the MLP
+    router, the 262k tied head) through the engine at the cell's 256
+    slots, placeholder weights: ``jit_chunk`` (16 decode steps over the
+    full table of 72 pages, the per-slot carry through the layer scan)
+    holds ``paged_decode`` at the cell-1 geometry (8 q / 2 kv x 128),
+    ``kv_page_write`` and, at 256 rows of 16 experts, ``moe_grouped`` (the
+    rule's choice: over 0.6 of the ridge), and ends in the materialised
+    ``[256, 262272]`` logits (a tied head: no ``fused_sample``);
+    ``jit_extend`` (a wave of 8 x 128 tokens continuing 8 slots' carry)
+    holds ``moe_grouped`` and makes no array of the carry's size."""
+    B = 256
+    cfg, fn, args = _zaya_program(one_chip, program, 2, 3576)
+    text = fn.lower(*args).compile().as_text()
+    chunk = program == "jit_chunk"
+    assert bool(re.search(r"%paged_decode(\.\d+)? = ", text)) == chunk
+    assert bool(re.search(r"%kv_page_write(\.\d+)? = ", text)) == chunk
+    assert re.search(r"%moe_grouped(\.\d+)? = ", text)
+    assert not re.search(r"%fused_sample(\.\d+)? = ", text)
+    _assert_no_slice_of_the_routed_stack(text, cfg)
+    if chunk:
+        assert f"f32[{B},{cfg.vocab_size}]" in text
+    else:
+        made = [ln.strip()[:120] for ln in text.split("\n")
+                if "= bf16[2,256,2688]" in ln and " parameter(" not in ln
+                and " get-tuple-element(" not in ln]
+        assert not made, made
+
+
+@pytest.mark.parametrize("more_pages", [0, 48])
+def test_zaya_cell_decode_chunk_computes_its_head_once(
+        compiled_kernels, one_chip, more_pages):
+    """At the cell's sixteen layers and its pool (the traffic file's bytes)
+    the decode chunk fits beside what it is handed without recomputing
+    anything: where the compiler is short of memory it REMATERIALISES, and
+    at a pool of 7.65e9 with a snapshot an entry a page it computed the
+    262k-row tied head three times a step (PERF.md section 6, PR 45). The
+    same holds 48 pages (100 MB) further on: the cell does not sit on the
+    edge, where a few MB freed would read as a gain."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic",
+            "grpo16_closed256_out8k_cca.json")) as f:
+        pool_bytes = json.load(f)["engine"]["kv_pool_bytes"]
+    n_pages = pool_bytes // (16_384 * 128) + more_pages
+    _, fn, args = _zaya_program(one_chip, "jit_chunk", 16, n_pages)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert f"bf16[16,{n_pages},2,2,128,128]" in text
+    again = sorted(set(re.findall(r"%([\w.\-]*remat[\w.\-]*) = ", text)))
+    assert not again, again
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
 # ------------------------------------------------------------------ #
 # moe_grouped: the routed experts as one grouped matmul over the stack
 # ------------------------------------------------------------------ #
