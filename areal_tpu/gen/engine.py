@@ -607,8 +607,9 @@ class GenerationEngine:
             # for greedy, distribution-exact otherwise; top-p (and top-k >
             # TOPK_MAX) slots keep the sorted path via the warp-row bucket.
             # No flag: the rule reads what this engine can observe (one TPU
-            # device, an untied head in the serving dtype, a policy); the
-            # argument pins either side (tests, the CPU's streamed XLA pass).
+            # device, a head stored in the serving dtype, tied or not, a
+            # policy); the argument pins either side (tests, the CPU's
+            # streamed XLA pass).
             self.fused = (
                 fused_sample
                 if fused_sample is not None
@@ -2192,11 +2193,12 @@ class GenerationEngine:
                         (sp.top_k <= fused_ops.TOPK_MAX) & ~greedy_rows,
                         sp.top_k, jnp.int32(1 << 30),
                     )
+                head, vocab_rows = tfm.head_operand(cfg, params)
                 out = fused_ops.fused_sample(
-                    sub, head_out, tfm.head_weight(cfg, params),
-                    sp.temperature, greedy_rows,
+                    sub, head_out, head, sp.temperature, greedy_rows,
                     soft_cap=cfg.final_logits_soft_cap,
                     topk=topk_arg, mesh=self.mesh,
+                    logits_scale=cfg.logits_scaling, vocab_rows=vocab_rows,
                 )
                 tokens, lp = out["tokens"], out["logprobs"]
                 if warp_bucket > 0:

@@ -1065,17 +1065,26 @@ def _embed(cfg: ModelConfig, params: Params, input_ids, positions):
 
 
 def head_weight(cfg: ModelConfig, params: Params):
-    """The LM-head weight ``[E, V]`` in serving dtype (tied embeddings
-    transpose on the fly — a lazy view XLA fuses into the consumer, but a
-    ``V x E`` copy every step as a kernel's operand: the fused epilogue's
-    rule, ``ops/fused_sample.py:fused_sample_applies``, keeps tied heads
-    on the materialised path). The fused epilogue streams this over vocab
-    blocks instead of calling :func:`_head`; the soft cap, when
-    configured, must be applied by the consumer (``ops/fused_sample.py``
-    takes it as an argument)."""
+    """The LM-head weight ``[E, V]`` in serving dtype, for a matmul XLA
+    compiles (:func:`_head`: the materialised path, ``apply_head``). Tied
+    embeddings transpose on the fly — a lazy view XLA fuses into the
+    consumer, but a ``V x E`` copy every step as a kernel's operand: the
+    fused epilogue takes :func:`head_operand` instead."""
+    w, vocab_rows = head_operand(cfg, params)
+    return w.T if vocab_rows else w
+
+
+def head_operand(cfg: ModelConfig, params: Params):
+    """``(weight, vocab_rows)``: the LM head in serving dtype in the layout
+    the parameter tree stores it, for the fused epilogue
+    (``ops/fused_sample.py``), which streams either over vocabulary
+    blocks. Untied: ``params["head"]["weight"]``, ``[E, V]``, ``False``.
+    Tied: the embedding itself, ``[V, E]``, ``True`` (no transpose, no
+    copy). ``cfg.logits_scaling`` and the soft cap, when configured, are
+    the consumer's to apply (``fused_sample`` takes both as arguments)."""
     if cfg.tied_embedding:
-        return _cast(cfg, params["embed"]["weight"]).T
-    return _cast(cfg, params["head"]["weight"])
+        return _cast(cfg, params["embed"]["weight"]), True
+    return _cast(cfg, params["head"]["weight"]), False
 
 
 def _head(cfg: ModelConfig, params: Params, x):

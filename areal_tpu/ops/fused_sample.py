@@ -25,6 +25,13 @@ per-row state —
   TOPK_MAX``): the final sample is a cheap ``[R, TOPK_MAX]`` categorical
   over the masked buffer.
 
+The head is taken as the parameter tree stores it (``vocab_rows``,
+static): ``[E, V]`` for an untied head, ``[V, E]`` for a tied embedding,
+whose block is ``W[v0:v1, :]`` contracted over E — no transposed view, no
+copy (``models/transformer.py:head_operand``). ``logits_scale`` (static,
+``cfg.logits_scaling``) divides the block's float32 logits before the
+soft cap, as ``models/transformer.py:_head`` does.
+
 Top-p slots are NOT handled here — they keep the sorted reference path
 via the engine's warp-row bucket machinery (PR 9), so only those rows pay
 the ``[W, V]`` sort.
@@ -76,24 +83,24 @@ def fused_sample_applies(
     ``kv_write_kernel_applies`` and ``decode_kernel_applies`` say it of
     their kernels). The kernel serves: ONE TPU device (``pallas_call`` has
     no partitioning rule, so any mesh of several keeps the materialised
-    path, which GSPMD partitions); an UNTIED head stored in the serving
-    dtype (``head_weight`` of a tied embedding is a lazy transpose, and a
-    head kept in another dtype a lazy cast: as a ``pallas_call`` operand
-    either becomes a ``V x E`` copy every step) whose logits are not scaled
-    (``cfg.logits_scaling``: the kernel has no such argument); a policy, not a critic
-    (whose head is one column); a vocabulary of at least one lane tile.
-    Everything else runs exactly the programs it ran before the kernel
-    existed. ``params`` is the engine's tree as it serves it (arrays or
-    their shapes); ``platform`` defaults to the first device's."""
+    path, which GSPMD partitions); a head STORED in the serving dtype,
+    which is the embedding itself where the two are tied (the kernel
+    streams either layout as the tree holds it,
+    ``models/transformer.py:head_operand``; a head kept in another dtype is
+    a lazy cast, which as a ``pallas_call`` operand becomes a copy of the
+    whole weight every step); a policy, not a critic (whose head is one
+    column); a vocabulary of at least one lane tile. Everything else runs
+    exactly the programs it ran before the kernel existed. ``params`` is
+    the engine's tree as it serves it (arrays or their shapes);
+    ``platform`` defaults to the first device's."""
     if platform is None:
         platform = _platform()
+    head = params["embed" if cfg.tied_embedding else "head"]["weight"]
     return (
         platform == "tpu"
         and (mesh is None or mesh.size == 1)
-        and not cfg.tied_embedding
-        and cfg.logits_scaling == 1.0
         and not cfg.is_critic
-        and params["head"]["weight"].dtype == jnp.dtype(cfg.dtype)
+        and head.dtype == jnp.dtype(cfg.dtype)
         and cfg.vocab_size >= 128
     )
 
@@ -156,9 +163,10 @@ def _update_block(
 
 def _fused_sample_xla(
     rng, x, w, temperature, greedy, soft_cap, topk, block_size, kmax,
+    logits_scale, vocab_rows,
 ) -> Dict[str, jnp.ndarray]:
     R, E = x.shape
-    V = w.shape[1]
+    V = w.shape[0 if vocab_rows else 1]
     block = max(1, min(int(block_size), V))
     nbf, tail = divmod(V, block)
     t = jnp.maximum(temperature.astype(jnp.float32), 1e-6)
@@ -176,15 +184,23 @@ def _fused_sample_xla(
         carry["topv"] = jnp.full((R, kmax), _MASK, jnp.float32)
         carry["topi"] = jnp.zeros((R, kmax), jnp.int32)
 
+    v_axis = 0 if vocab_rows else 1
+
     def _logits(w_blk):
-        out = jnp.dot(x, w_blk, preferred_element_type=jnp.float32)
+        # a block of [E, V], or of [V, E] contracted over its E
+        out = jax.lax.dot_general(
+            x, w_blk, (((1,), (1 - v_axis,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if logits_scale != 1.0:
+            out = out / logits_scale
         if soft_cap is not None and soft_cap > 0:
             out = jnp.tanh(out / soft_cap) * soft_cap
         return out
 
     if nbf > 0:
         def body(c, j):
-            w_blk = jax.lax.dynamic_slice(w, (0, j * block), (E, block))
+            w_blk = jax.lax.dynamic_slice_in_dim(w, j * block, block, v_axis)
             c = _update_block(
                 c, _logits(w_blk), j * block, jax.random.fold_in(rng, j),
                 t, kmax,
@@ -193,7 +209,7 @@ def _fused_sample_xla(
 
         carry, _ = jax.lax.scan(body, carry, jnp.arange(nbf))
     if tail:
-        w_blk = jax.lax.slice(w, (0, nbf * block), (E, V))
+        w_blk = jax.lax.slice_in_dim(w, nbf * block, V, axis=v_axis)
         carry = _update_block(
             carry, _logits(w_blk), nbf * block,
             jax.random.fold_in(rng, nbf), t, kmax,
@@ -230,7 +246,8 @@ def _fused_sample_xla(
 def fused_sample(
     rng: jax.Array,
     x: jnp.ndarray,                # [R, E] final-norm hidden states
-    w: jnp.ndarray,                # [E, V] head weight (serving dtype)
+    w: jnp.ndarray,                # [E, V] head weight (serving dtype),
+                                   # or [V, E] with ``vocab_rows``
     temperature: jnp.ndarray,      # [R] f32 (0 => greedy slot)
     greedy: jnp.ndarray,           # [R] bool
     soft_cap: Optional[float] = None,
@@ -239,6 +256,8 @@ def fused_sample(
     use_pallas: Optional[bool] = None,
     mesh=None,
     interpret: Optional[bool] = None,
+    logits_scale: float = 1.0,
+    vocab_rows: bool = False,
 ) -> Dict[str, jnp.ndarray]:
     """Sample one token per row without materializing ``[R, V]`` logits.
 
@@ -256,10 +275,16 @@ def fused_sample(
     ``block_size`` left ``None``: the kernel sizes its vocabulary block
     from the shapes (``ops/pallas/fused_sample.py:block_columns``), the XLA
     path takes 2048 columns.
+
+    ``vocab_rows`` (STATIC) says ``w`` is ``[V, E]``: a tied embedding as
+    the parameter tree stores it (``models/transformer.py:head_operand``),
+    streamed in row blocks, never transposed. ``logits_scale`` (STATIC)
+    divides the float32 logits before the soft cap
+    (``cfg.logits_scaling``), as ``models/transformer.py:_head`` does.
     """
     R, E = x.shape
-    V = w.shape[1]
-    if w.shape[0] != E:
+    V = w.shape[0 if vocab_rows else 1]
+    if w.shape[1 if vocab_rows else 0] != E:
         raise ValueError(f"head weight {w.shape} does not match hidden {x.shape}")
     if use_pallas is None:
         use_pallas = (
@@ -286,9 +311,11 @@ def fused_sample(
         return _pk.fused_sample_pallas(
             rng, x, w, temperature, greedy, soft_cap=soft_cap,
             block_v=block_size, interpret=interpret,
+            logits_scale=logits_scale, vocab_rows=vocab_rows,
         )
     return _fused_sample_xla(
         rng, x, w, temperature, greedy, soft_cap, topk,
         2048 if block_size is None else block_size, TOPK_MAX,
+        logits_scale, vocab_rows,
     )
 

@@ -7,6 +7,19 @@ and the sampling state folds in online. The full ``[R, V]`` logits tensor
 never exists in HBM: HBM traffic is exactly one read of the head weight —
 the decode-epilogue roofline.
 
+The weight comes in the layout the parameter tree stores it, stated
+statically (``vocab_rows``): an untied head ``[E, V]`` in column blocks
+``[E, BV]``, or a tied embedding ``[V, E]`` in ROW blocks ``[BV, E]``
+(one contiguous run of HBM a grid step), contracted over its E by the
+same ``dot_general`` with the other dimension number: the MXU takes the
+block either way (on a v5e the two read the same time to 0.2 % at 80-256
+rows; logits kept TRANSPOSED, ``[BV, R]`` against a stationary ``x^T``,
+read 5.6 % less at 256 rows and the same at 80-128: PERF.md section 6,
+PR 46), and everything after the block's logits is one body.
+``logits_scale`` (static, ``cfg.logits_scaling``) divides the block's
+float32 logits before the soft cap, where
+``models/transformer.py:_head`` divides.
+
 The fold is written for the vector unit, which at 256 rows x 129k columns
 bounds the call together with the MXU once the weight streams at the
 memory's rate. A block's logits are taken in pieces of ``[ROW_GROUP,
@@ -90,9 +103,9 @@ def _interpret() -> bool:
 def block_columns(R: int, E: int, V: int, itemsize: int) -> int:
     """Vocabulary columns of one grid step, from the shapes: the most (in
     whole lane tiles, up to ``MAX_BLOCK_V``) whose double-buffered weight
-    block ``[E, BV]`` and f32 logits ``[R, BV]`` (with two temporaries of
-    that size) fit the VMEM budget. 3584 rows of bf16 leave 2048 columns
-    (14.7 MB a buffer)."""
+    block (``[E, BV]`` or ``[BV, E]``: the same bytes) and f32 logits
+    ``[R, BV]`` (with two temporaries of that size) fit the VMEM budget.
+    3584 rows of bf16 leave 2048 columns (14.7 MB a buffer)."""
     per_col = 2 * E * itemsize + 3 * R * 4
     fit = max(LANES, _VMEM_BUDGET // per_col // LANES * LANES)
     return min(MAX_BLOCK_V, fit, -(-V // LANES) * LANES)
@@ -116,7 +129,7 @@ def _kernel(
     tok_ref, lp_ref, argmax_ref, norm_ref,
     lg_scr, m_scr, l_scr, amv_scr, ami_scr, gp_scr, gw_scr, gi_scr,
     *, nb: int, block_v: int, vocab: int, soft_cap: Optional[float],
-    hw_prng: bool,
+    logits_scale: float, vocab_rows: bool, hw_prng: bool,
 ):
 
     j = pl.program_id(0)
@@ -137,9 +150,14 @@ def _kernel(
     def fold(tiles: int, partial: int):
         """One block's ``tiles`` whole lane tiles and, after them, the
         first ``partial`` columns of one more."""
-        logits = jnp.dot(
-            x_ref[...], w_ref[...], preferred_element_type=jnp.float32
+        # the weight block is [E, BV], or [BV, E] contracted over its E
+        logits = jax.lax.dot_general(
+            x_ref[...], w_ref[...],
+            (((1,), (1 if vocab_rows else 0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
+        if logits_scale != 1.0:
+            logits = logits / logits_scale
         if soft_cap is not None and soft_cap > 0:
             logits = jnp.tanh(logits / soft_cap) * soft_cap
         lg_scr[...] = logits
@@ -276,21 +294,26 @@ def _kernel(
 def fused_sample_pallas(
     rng: jax.Array,
     x: jnp.ndarray,               # [R, E]
-    w: jnp.ndarray,               # [E, V]
+    w: jnp.ndarray,               # [E, V], or [V, E] with ``vocab_rows``
     temperature: jnp.ndarray,     # [R] f32
     greedy: jnp.ndarray,          # [R] bool
     soft_cap: Optional[float] = None,
     block_v: Optional[int] = None,
     interpret: Optional[bool] = None,
+    logits_scale: float = 1.0,
+    vocab_rows: bool = False,
 ):
     """Kernel wrapper; same result dict as the XLA path of
     ``ops/fused_sample.py`` (minus top-k, which the dispatch never routes
     here). The PRNG seed derives from ``rng`` on device — no host
     round-trip rides the dispatch. ``block_v`` (columns of one grid step)
     follows from the shapes (:func:`block_columns`); a caller's value is
-    held to what fits."""
+    held to what fits. ``vocab_rows`` (STATIC) says ``w`` is ``[V, E]``, a
+    tied embedding as the parameter tree stores it; ``logits_scale``
+    (STATIC) divides the block's float32 logits before the soft cap, as
+    ``models/transformer.py:_head`` does."""
     R0, E = x.shape
-    V = w.shape[1]
+    V = w.shape[0 if vocab_rows else 1]
     R = -(-R0 // 8) * 8  # whole sublane tiles; the padding rows are cut off
     fit = block_columns(R, E, V, w.dtype.itemsize)
     if block_v is None:
@@ -324,6 +347,7 @@ def fused_sample_pallas(
     row_spec = pl.BlockSpec((R, LANES), lambda j, s: (0, 0))
     kernel = functools.partial(
         _kernel, nb=nb, block_v=block_v, vocab=V, soft_cap=soft_cap,
+        logits_scale=float(logits_scale), vocab_rows=vocab_rows,
         hw_prng=not interpret,
     )
     outs = pl.pallas_call(
@@ -333,7 +357,9 @@ def fused_sample_pallas(
             grid=(nb,),
             in_specs=[
                 pl.BlockSpec((R, E), lambda j, s: (0, 0)),
-                pl.BlockSpec((E, block_v), lambda j, s: (0, j)),
+                pl.BlockSpec((block_v, E), lambda j, s: (j, 0))
+                if vocab_rows
+                else pl.BlockSpec((E, block_v), lambda j, s: (0, j)),
             ] + [row_spec] * (len(operands) - 3),
             out_specs=[row_spec] * len(out_dtypes),
             scratch_shapes=[pltpu.VMEM((R, block_v), jnp.float32)] + [

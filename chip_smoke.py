@@ -551,7 +551,7 @@ def phase_serve(sz, args):
         require("hbm_peak_bytes_in_use" in metrics,
                 "gen server reported no memory_stats gauges")
         # the step ends in the fused head-and-sample kernel, by the
-        # engine's own rule (one TPU device, an untied head)
+        # engine's own rule (one TPU device, a head in the serving dtype)
         require(FUSED_SAMPLE_KERNEL in kernels
                 and metrics.get("fused_sample") is True,
                 f"decode chunk samples from materialised logits: {kernels}, "
@@ -605,8 +605,9 @@ def phase_serve(sz, args):
     # ... and a model of layer KINDS through the engine: one state-space
     # layer beside one attention layer (the served model above has one
     # kind), its log-probs against the token-by-token reference, its decode
-    # chunk searched for the state update's kernel and the paged kernels
-    # at a head of 64
+    # chunk searched for the state update's kernel, the paged kernels at a
+    # head of 64 and, its head being the embedding with the logits divided
+    # by 8, the fused epilogue over the ``[V, E]`` array as stored
     hybrid, _ = helper(d, "statespace", {
         "seed": args.seed, "rehearse": args.rehearse,
     })
@@ -619,9 +620,12 @@ def phase_serve(sz, args):
     require(args.rehearse or all(
                 any(k.startswith(want) for k in hybrid["kernels"])
                 for want in (SSM_DECODE_KERNEL, "paged_decode",
-                             KV_WRITE_KERNEL)),
-            f"the two-kind model's decode chunk lacks a kernel: "
-            f"{hybrid['kernels']}")
+                             KV_WRITE_KERNEL, FUSED_SAMPLE_KERNEL))
+            and hybrid["fused_rows"] == hybrid["state_slots"]
+            and hybrid["sampler_fallback_rows"] == 0,
+            f"the two-kind model's decode chunk lacks a kernel, or its tied "
+            f"head's rows did not end in the fused one: {hybrid['kernels']}, "
+            f"fused_rows {hybrid['fused_rows']} of {hybrid['state_slots']}")
     # ... and a model whose attention runs inside a convolved latent behind
     # a top-1 expert layer with a skip (family ``zaya``): the per-slot carry
     # beside the page pool, its snapshot in the prefix cache, the router's
@@ -638,7 +642,8 @@ def phase_serve(sz, args):
             f"router or the snapshot path are off: {latent}")
     require(args.rehearse or all(
                 any(k.startswith(want) for k in latent["kernels"])
-                for want in ("paged_decode", KV_WRITE_KERNEL)
+                for want in ("paged_decode", KV_WRITE_KERNEL,
+                             FUSED_SAMPLE_KERNEL)
                 + ((MOE_GROUPED_KERNEL,) if latent["moe_grouped"] else ())),
             f"the zaya model's decode chunk lacks a kernel: "
             f"{latent['kernels']}")
@@ -1400,7 +1405,10 @@ def child_statespace(arg):
     chunks and files a snapshot of the recurrent state, the rest are
     seeded from it), one request generates alone; the served log-probs
     are held to the token-by-token float32 reference, and the decode
-    chunk's program is searched for the kernels it should hold."""
+    chunk's program is searched for the kernels it should hold. The
+    model's head is granite's: the embedding, its logits divided by 8, so
+    on the chip the chunk ends in ``fused_sample`` over ``[V, E]`` and
+    the served log-probs are that kernel's."""
     from areal_tpu.base import compile_cache
 
     compile_cache.configure()
@@ -1467,6 +1475,9 @@ def child_statespace(arg):
         "prefix_hit_tokens": [outs[f"g{i}"].prefix_hit_tokens
                               for i in range(4)],
         "state_slots": stats["state_slots"],
+        "tied_head": cfg.tied_embedding, "logits_scaling": cfg.logits_scaling,
+        "fused_rows": stats["fused_rows"],
+        "sampler_fallback_rows": stats["sampler_fallback_rows"],
         "logprobs": {k: verdict.get(k) for k in (
             "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
             "mean_abs_diff_nats", "n_positions")},

@@ -35,6 +35,14 @@ CFG = ModelConfig(
     intermediate_dim=64, vocab_size=128, dtype="float32",
 )
 
+# granite's kind of head in small: the embedding is the head, its logits
+# divided by 8
+TIED_CFG = ModelConfig(
+    n_layers=2, n_q_heads=4, n_kv_heads=2, head_dim=8, hidden_dim=32,
+    intermediate_dim=64, vocab_size=128, dtype="float32",
+    tied_embedding=True, logits_scaling=8.0,
+)
+
 # chi-square threshold: df = 15 (16-token toy vocab), p ~ 1e-4
 CHI2_CRIT = 45.0
 N_DRAWS = 20000
@@ -57,11 +65,34 @@ def _prompts(rng, sizes=(5, 9, 3)):
     return [[int(x) for x in rng.integers(1, 128, size=n)] for n in sizes]
 
 
-def _head_problem(R=6, E=32, V=500, seed=0):
+def _head_problem(R=6, E=32, V=500, seed=0, vocab_rows=False,
+                  logits_scale=1.0, soft_cap=None):
+    """``(x, w, logits)``: ``w`` is ``[E, V]``, or the same numbers as
+    ``[V, E]`` (a tied embedding as stored); ``logits`` are the reference's,
+    materialised the way ``models/transformer.py:_head`` does."""
     kx, kw = jax.random.split(jax.random.key(seed))
     x = jax.random.normal(kx, (R, E), jnp.float32)
     w = jax.random.normal(kw, (E, V), jnp.float32) * 0.3
-    return x, w, (x @ w).astype(jnp.float32)
+    logits = (x @ w).astype(jnp.float32)
+    if logits_scale != 1.0:
+        logits = logits / logits_scale
+    if soft_cap is not None:
+        logits = soft_cap * jnp.tanh(logits / soft_cap)
+    return x, (jnp.asarray(w.T) if vocab_rows else w), logits
+
+
+# how the head reaches ``fused_sample``: the layout of the weight, the
+# scaling of its logits, a soft cap (the arguments of both)
+HEADS = [
+    pytest.param({}, id="ev"),
+    pytest.param({"vocab_rows": True}, id="ve"),
+    pytest.param({"logits_scale": 8.0}, id="ev_scaled"),
+    pytest.param(
+        {"vocab_rows": True, "logits_scale": 8.0, "soft_cap": 0.4},
+        id="ve_scaled_capped"),
+]
+LAYOUTS = [pytest.param({}, id="ev"),
+           pytest.param({"vocab_rows": True}, id="ve")]
 
 
 class TestOpParity:
@@ -73,8 +104,9 @@ class TestOpParity:
                   pytest.param(512, marks=pytest.mark.slow),
                   pytest.param(7, marks=pytest.mark.slow)],
     )
-    def test_greedy_exact_and_lp_formula(self, block):
-        x, w, logits = _head_problem()
+    @pytest.mark.parametrize("head", HEADS)
+    def test_greedy_exact_and_lp_formula(self, block, head):
+        x, w, logits = _head_problem(**head)
         temp = jnp.array([0.0, 1.0, 0.7, 0.0, 1.3, 1.0], jnp.float32)
         greedy = temp <= 0.0
         R = x.shape[0]
@@ -85,7 +117,8 @@ class TestOpParity:
         key = jax.random.key(3)
         ref_tok, ref_lp = sample_tokens(key, logits, sp, warp=False)
         out = fs.fused_sample(
-            key, x, w, temp, greedy, block_size=block, use_pallas=False
+            key, x, w, temp, greedy, block_size=block, use_pallas=False,
+            **head,
         )
         g = np.asarray(greedy)
         # greedy rows: token- and logprob-exact
@@ -129,17 +162,18 @@ class TestOpParity:
                 top_ids = np.argsort(-np.asarray(logits)[r])[:k]
                 assert tok[r] in top_ids
 
-    def test_pallas_interpret_matches_xla(self):
+    @pytest.mark.parametrize("head", HEADS)
+    def test_pallas_interpret_matches_xla(self, head):
         """The kernel (CPU interpret mode) agrees with the streamed XLA
         path on everything deterministic: greedy tokens, argmax, and the
         logprob formula for whatever token its own stream sampled."""
-        x, w, logits = _head_problem()
+        x, w, logits = _head_problem(**head)
         temp = jnp.array([0.0, 1.0, 0.7, 0.0, 1.3, 1.0], jnp.float32)
         greedy = temp <= 0.0
         R = x.shape[0]
         out = fs.fused_sample(
             jax.random.key(3), x, w, temp, greedy, block_size=128,
-            use_pallas=True,
+            use_pallas=True, **head,
         )
         g = np.asarray(greedy)
         ref = np.asarray(jnp.argmax(logits, -1))
@@ -175,20 +209,37 @@ class TestOpParity:
                 use_pallas=True, mesh=mesh,
             )
 
+    def test_weight_that_does_not_match_its_layout_raises(self):
+        x, w, _ = _head_problem()
+        R = x.shape[0]
+        with pytest.raises(ValueError, match="does not match"):
+            fs.fused_sample(
+                jax.random.key(0), x, w, jnp.ones((R,), jnp.float32),
+                jnp.zeros((R,), bool), use_pallas=False, vocab_rows=True,
+            )
+
 
 class TestFusedSampleApplies:
     """``fused_sample_applies``: the engine's default, from what it can
     observe (no flag)."""
 
     @staticmethod
-    def _params(dtype):
-        return {"head": {"weight": jax.ShapeDtypeStruct((32, 128), dtype)}}
+    def _params(head, embed=jnp.bfloat16, tied=False):
+        """The tree as the engine serves it: a tied model has no head."""
+        tree = {"embed": {"weight": jax.ShapeDtypeStruct((128, 32), embed)}}
+        if not tied:
+            tree["head"] = {"weight": jax.ShapeDtypeStruct((32, 128), head)}
+        return tree
 
     @pytest.mark.parametrize(
         "case,want",
         [("tpu_one_device", True), ("mesh_of_one", True), ("cpu", False),
-         ("mesh", False), ("tied_head", False), ("critic", False),
-         ("small_vocab", False), ("head_in_another_dtype", False)],
+         ("mesh", False), ("tied_head", True), ("critic", False),
+         ("small_vocab", False), ("head_in_another_dtype", False),
+         ("tied_embedding_in_another_dtype", False),
+         ("scaled_logits", True), ("tied_mesh", False),
+         # an untied head is what the kernel reads, whatever the embedding's
+         ("untied_embedding_in_another_dtype", True)],
     )
     def test_rule(self, case, want):
         import dataclasses
@@ -196,23 +247,29 @@ class TestFusedSampleApplies:
         from jax.sharding import Mesh
 
         cfg = dataclasses.replace(CFG, dtype="bfloat16")
-        head, mesh, platform = jnp.bfloat16, None, "tpu"
+        head = embed = jnp.bfloat16
+        mesh, platform = None, "tpu"
         if case == "cpu":
             platform = "cpu"
-        elif case == "mesh":
+        elif case in ("mesh", "tied_mesh"):
             mesh = Mesh(np.array(jax.devices()[:2]), ("model",))
         elif case == "mesh_of_one":
             mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
-        elif case == "tied_head":
-            cfg = dataclasses.replace(cfg, tied_embedding=True)
         elif case == "critic":
             cfg = dataclasses.replace(cfg, is_critic=True)
         elif case == "small_vocab":
             cfg = dataclasses.replace(cfg, vocab_size=64)
         elif case == "head_in_another_dtype":
             head = jnp.float32
+        elif case == "scaled_logits":
+            cfg = dataclasses.replace(cfg, logits_scaling=8.0)
+        elif case.endswith("embedding_in_another_dtype"):
+            embed = jnp.float32
+        tied = case.startswith("tied")
+        cfg = dataclasses.replace(cfg, tied_embedding=tied)
         assert fs.fused_sample_applies(
-            cfg, self._params(head), mesh=mesh, platform=platform) is want
+            cfg, self._params(head, embed, tied), mesh=mesh,
+            platform=platform) is want
 
     def test_platform_defaults_to_the_first_device(self):
         assert fs.fused_sample_applies(CFG, self._params(jnp.float32)) is False
@@ -223,20 +280,48 @@ class TestKernelForms:
     blocks with a masked tail."""
 
     @pytest.mark.parametrize(
-        "R,V,block",
-        [pytest.param(72, 700, 256, id="nine_groups_of_8"),
-         pytest.param(64, 1024, 512, id="two_groups_of_32_no_tail"),
-         pytest.param(5, 130, None, id="padded_rows_block_from_shapes")],
+        "R,V,block,head",
+        [pytest.param(72, 700, 256, {}, id="nine_groups_of_8"),
+         pytest.param(64, 1024, 512, {}, id="two_groups_of_32_no_tail"),
+         pytest.param(5, 130, None, {}, id="padded_rows_block_from_shapes"),
+         # [V, E] row blocks: the last block's rows past the vocabulary
+         # are whatever the pipeline read there
+         pytest.param(72, 700, 256, {"vocab_rows": True},
+                      id="ve_nine_groups_of_8"),
+         pytest.param(64, 1024, 512, {"vocab_rows": True},
+                      id="ve_two_groups_of_32_no_tail"),
+         pytest.param(5, 130, None, {"vocab_rows": True, "soft_cap": 0.4},
+                      id="ve_padded_rows_block_from_shapes_capped"),
+         # the two tied cells in small: 80 rows (five groups of 16), whole
+         # blocks and a tail of ONE lane tile, logits scaled by 8
+         pytest.param(80, 640, 256,
+                      {"vocab_rows": True, "logits_scale": 8.0},
+                      id="ve_80_rows_tail_of_one_lane_tile_scaled"),
+         pytest.param(80, 640, 256, {"logits_scale": 8.0, "soft_cap": 0.4},
+                      id="ev_80_rows_tail_of_one_lane_tile_scaled_capped")],
     )
-    def test_greedy_exact_and_lp_formula(self, R, V, block):
-        x, w, logits = _head_problem(R=R, E=32, V=V, seed=4)
+    def test_greedy_exact_and_lp_formula(self, R, V, block, head):
+        x, w, logits = _head_problem(R=R, E=32, V=V, seed=4, **head)
         temp = jnp.where(jnp.arange(R) % 3 == 0, 0.0, 0.8).astype(
             jnp.float32)
         greedy = temp <= 0.0
         out = fs.fused_sample(
             jax.random.key(9), x, w, temp, greedy, block_size=block,
-            use_pallas=True,
+            use_pallas=True, **head,
         )
+        # greedy rows: the reference sampler's tokens and log-probs
+        sp = SamplingParams(
+            temperature=temp, top_p=jnp.ones((R,), jnp.float32),
+            top_k=jnp.full((R,), 1 << 30, jnp.int32),
+        )
+        ref_tok, ref_lp = sample_tokens(
+            jax.random.key(9), logits, sp, warp=False)
+        np.testing.assert_array_equal(
+            np.asarray(out["tokens"])[np.asarray(greedy)],
+            np.asarray(ref_tok)[np.asarray(greedy)])
+        np.testing.assert_allclose(
+            np.asarray(out["logprobs"])[np.asarray(greedy)],
+            np.asarray(ref_lp)[np.asarray(greedy)], atol=1e-3)
         g = np.asarray(greedy)
         ref = np.asarray(jnp.argmax(logits, -1))
         tok = np.asarray(out["tokens"])
@@ -255,16 +340,17 @@ class TestKernelForms:
         # sampled rows are not all one token: the draws differ by row
         assert len(set(tok[~g].tolist())) > 1
 
-    def test_draws_do_not_depend_on_the_block(self):
+    @pytest.mark.parametrize("head", LAYOUTS)
+    def test_draws_do_not_depend_on_the_block(self, head):
         """The interpreted kernel draws from a hash of (seed, row, column):
         the same tokens whatever the block, a partial last block (500
         columns) included; the sums differ only in their order."""
-        x, w, logits = _head_problem(R=16, E=32, V=500, seed=2)
+        x, w, logits = _head_problem(R=16, E=32, V=500, seed=2, **head)
         R = x.shape[0]
         args = (jax.random.key(5), x, w, jnp.ones((R,), jnp.float32),
                 jnp.zeros((R,), bool))
         one, other = (
-            fs.fused_sample(*args, block_size=b, use_pallas=True)
+            fs.fused_sample(*args, block_size=b, use_pallas=True, **head)
             for b in (128, 256))
         assert set(one) == {"tokens", "logprobs", "argmax", "norm"}
         for k in ("tokens", "argmax"):
@@ -279,31 +365,55 @@ class TestKernelForms:
             np.asarray(one["logprobs"]),
             lp_all[np.arange(R), np.asarray(one["tokens"])], atol=1e-4)
 
+    def test_draws_do_not_depend_on_the_layout(self):
+        """One hash of (seed, row, column) whichever way the weight lies:
+        a tied head draws what the same numbers draw as an untied one."""
+        x, w, _ = _head_problem(R=16, E=32, V=500, seed=2)
+        R = x.shape[0]
+        args = (jnp.ones((R,), jnp.float32), jnp.zeros((R,), bool))
+        ev = fs.fused_sample(
+            jax.random.key(5), x, w, *args, block_size=128, use_pallas=True)
+        ve = fs.fused_sample(
+            jax.random.key(5), x, jnp.asarray(w.T), *args, block_size=128,
+            use_pallas=True, vocab_rows=True)
+        np.testing.assert_array_equal(
+            np.asarray(ev["tokens"]), np.asarray(ve["tokens"]))
+        np.testing.assert_allclose(
+            np.asarray(ev["logprobs"]), np.asarray(ve["logprobs"]),
+            atol=1e-5)
+
     def test_block_follows_from_the_shapes(self):
         from areal_tpu.ops.pallas import fused_sample as fsk
 
-        # the five rollout cells: 2048 columns; a toy vocabulary: one
-        # lane tile; a head too wide for 2048 columns of it: fewer
+        # the rollout cells (the two tied ones last: the same bytes a
+        # block whichever way the weight lies): 2048 columns; a toy
+        # vocabulary: one lane tile; a head too wide for 2048 columns of
+        # it: fewer
         for R, E, V in [(128, 1536, 151936), (64, 3584, 152064),
                         (64, 2048, 50304), (256, 2048, 129280),
-                        (112, 2560, 151936)]:
+                        (112, 2560, 151936), (80, 2048, 100352),
+                        (256, 2048, 262272)]:
             assert fsk.block_columns(R, E, V, 2) == 2048
         assert fsk.block_columns(8, 32, 100, 4) == 128
         wide = fsk.block_columns(64, 16384, 152064, 2)
         assert wide % 128 == 0 and 128 <= wide < 2048
 
-    def test_rows_are_independent_draws_of_the_marginal(self):
+    @pytest.mark.parametrize("head", [
+        pytest.param({}, id="ev"),
+        pytest.param({"vocab_rows": True, "logits_scale": 8.0},
+                     id="ve_scaled")])
+    def test_rows_are_independent_draws_of_the_marginal(self, head):
         """256 rows that are ONE row: every call is 256 draws of one
         distribution (the kernel's uniforms differ by row and column), so
         a few calls give the chi-square of ``chip_smoke.py``'s check of the
         chip's own uniform source."""
-        x1, w, logits = _head_problem(R=1, E=8, V=16, seed=1)
+        x1, w, logits = _head_problem(R=1, E=8, V=16, seed=1, **head)
         p = np.asarray(jax.nn.softmax(logits[0]))
         R = 256
         x = jnp.tile(x1, (R, 1))
         f = jax.jit(lambda k: fs.fused_sample(
             k, x, w, jnp.ones((R,)), jnp.zeros((R,), bool),
-            use_pallas=True,
+            use_pallas=True, **head,
         )["tokens"])
         counts = np.zeros(16)
         for k in jax.random.split(jax.random.key(11), 40):
@@ -329,12 +439,13 @@ class TestDistribution:
             (((counts[mask] - n * p[mask]) ** 2) / (n * p[mask])).sum()
         )
 
-    def test_temperature_marginal(self):
-        x, w, logits = _head_problem(R=1, E=8, V=16, seed=1)
+    @pytest.mark.parametrize("head", HEADS)
+    def test_temperature_marginal(self, head):
+        x, w, logits = _head_problem(R=1, E=8, V=16, seed=1, **head)
         p = np.asarray(jax.nn.softmax(logits[0]))
         f = jax.jit(lambda k: fs.fused_sample(
             k, x, w, jnp.ones((1,)), jnp.zeros((1,), bool),
-            block_size=7, use_pallas=False,
+            block_size=7, use_pallas=False, **head,
         )["tokens"][0])
         assert self._chi2(self._marginal(f), p) < CHI2_CRIT
 
@@ -356,12 +467,13 @@ class TestDistribution:
         assert self._chi2(counts, p) < CHI2_CRIT
 
     @pytest.mark.slow
-    def test_pallas_temperature_marginal(self):
-        x, w, logits = _head_problem(R=1, E=8, V=16, seed=1)
+    @pytest.mark.parametrize("head", LAYOUTS)
+    def test_pallas_temperature_marginal(self, head):
+        x, w, logits = _head_problem(R=1, E=8, V=16, seed=1, **head)
         p = np.asarray(jax.nn.softmax(logits[0]))
         f = jax.jit(lambda k: fs.fused_sample(
             k, x, w, jnp.ones((1,)), jnp.zeros((1,), bool),
-            block_size=128, use_pallas=True,
+            block_size=128, use_pallas=True, **head,
         )["tokens"][0])
         assert self._chi2(self._marginal(f), p) < CHI2_CRIT
 
@@ -413,6 +525,68 @@ class TestEngineFused:
                 outs[0][rid].output_logprobs, outs[1][rid].output_logprobs,
                 atol=1e-4,
             )
+
+    def test_tied_scaled_head_greedy_fused_matches_reference(self, rng):
+        """A tied, scaled head (granite's kind in small: the embedding IS
+        the head, the logits divided by 8) through the engine: the fused
+        epilogue reads the embedding as stored (``head_operand``) and is
+        token-exact against the materialised path, whose ``_head``
+        transposes and divides."""
+        prompts = _prompts(rng)
+        outs = []
+        for fused in (False, True):
+            eng = GenerationEngine(
+                TIED_CFG, tfm.init_params(TIED_CFG, jax.random.key(6)),
+                fused_sample=fused, max_slots=4, max_seqlen=128)
+            for i, p in enumerate(prompts):
+                eng.submit(GenRequest(
+                    rid=f"r{i}", input_ids=p, max_new_tokens=10 + i,
+                    greedy=True,
+                ))
+            outs.append({
+                o.rid: o for o in eng.run_until_done(decode_steps=3)
+            })
+            assert (eng.stats["fused_rows"] > 0) == fused
+            assert eng.stats["sampler_fallback_rows"] == 0
+        assert set(outs[0]) == set(outs[1])
+        for rid in outs[0]:
+            assert outs[0][rid].output_ids == outs[1][rid].output_ids, rid
+            np.testing.assert_allclose(
+                outs[0][rid].output_logprobs, outs[1][rid].output_logprobs,
+                atol=1e-4,
+            )
+
+    def test_tied_scaled_head_top_p_rows_take_the_fallback(self):
+        """A mixed batch on the tied, scaled head: the top-p row keeps the
+        sorted sampler over ITS logits row (``apply_head``, which handles
+        the tie and the scaling), the others the fused pass; the greedy
+        row is the materialised engine's."""
+
+        def run(fused):
+            eng = GenerationEngine(
+                TIED_CFG, tfm.init_params(TIED_CFG, jax.random.key(6)),
+                fused_sample=fused, max_slots=4, max_seqlen=128, seed=3)
+            eng.submit(GenRequest(
+                rid="g", input_ids=[5, 6, 7], max_new_tokens=8, greedy=True))
+            eng.submit(GenRequest(
+                rid="p", input_ids=[5, 6, 7], max_new_tokens=8,
+                temperature=1.0, top_p=0.9))
+            eng.submit(GenRequest(
+                rid="t", input_ids=[5, 6, 7], max_new_tokens=8,
+                temperature=0.8))
+            outs = {o.rid: o for o in eng.run_until_done(decode_steps=2)}
+            return outs, dict(eng.stats)
+
+        got, stats = run(True)
+        # one of three rows a step is the top-p one
+        assert stats["sampler_fallback_rows"] > 0
+        assert stats["fused_rows"] == 2 * stats["sampler_fallback_rows"]
+        ref, ref_stats = run(False)
+        assert ref_stats["fused_rows"] == 0
+        assert got["g"].output_ids == ref["g"].output_ids
+        for o in got.values():
+            assert len(o.output_ids) == 8
+            assert all(np.isfinite(o.output_logprobs))
 
     @pytest.mark.parametrize("fused", [True, False])
     def test_constructor_argument_overrides_the_rule(self, params, fused):

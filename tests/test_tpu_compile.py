@@ -331,6 +331,60 @@ def test_granite_admission_re_lays_no_state(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
+def test_granite_decode_chunk_ends_in_the_fused_kernel(
+        compiled_kernels, one_chip, monkeypatch):
+    """The granite cell's decode chunk in small (one state-space and one
+    attention layer at the model's widths, the cell's 80 slots and 16
+    steps): ``ssm_decode``, ``paged_decode`` and ``kv_page_write`` are in
+    it, and the step ends in ``fused_sample`` over the tied embedding as
+    stored with its logits divided by 8 inside the kernel: no ``[80,
+    100352]`` array in any dtype, no transposed copy of the embedding."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.ops.pallas import ssm_decode
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+        arch = json.load(f)
+    arch.update(num_hidden_layers=2, layer_types=["mamba", "attention"])
+    cfg = sut.model_config(arch, {})
+    assert cfg.tied_embedding and cfg.logits_scaling == 8.0
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B = 80
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=B, max_seqlen=5120, max_new_tokens_cap=4096,
+        page_size=128, n_pages=80, state_snapshots=2, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.fused       # the rule, on what the fixture describes
+    # the state update's rule asks the first device itself: here the CPU
+    assert ssm_decode.ssm_decode_applies(cfg, None, "tpu")
+    monkeypatch.setattr(eng, "_ssm_update", lambda: ssm_decode.ssm_decode)
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    pages = eng.state.cache.pages
+    state = dataclasses.replace(
+        jax.tree.map(spec, eng.state),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (pages.shape[0], 1600) + pages.shape[2:], pages.dtype, one_chip)))
+    text = eng._chunk_fn(16, eng.M, 0, fused=True, with_topk=False).lower(
+        jax.tree.map(spec, shapes), state,
+        _spec((B, eng.M), jnp.int32, one_chip),
+        _spec((0,), jnp.int32, one_chip),
+    ).compile().as_text()
+    for kernel in ("ssm_decode", "paged_decode", "kv_page_write"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    _assert_fused_epilogue(text, B, cfg.vocab_size)
+    assert f"bf16[{cfg.hidden_dim},{cfg.vocab_size}]" not in text
+
+
 # the rollout cells' decode epilogue: slots x hidden x vocabulary
 FUSED_SAMPLE_CELLS = {
     "cell1": (128, 1536, 151936),
@@ -341,35 +395,105 @@ FUSED_SAMPLE_CELLS = {
 }
 
 
+# the two cells whose head is the embedding: the kernel's operand is the
+# tree's [V, E] array itself; granite divides its logits by 8
+FUSED_SAMPLE_TIED_CELLS = {
+    "granite": (80, 2048, 100352, 8.0),
+    "zaya": (256, 2048, 262272, 1.0),
+}
+
+
 @pytest.mark.parametrize(
-    "R,E,V",
-    [pytest.param(*shape, id=cell)
+    "R,E,V,vocab_rows,logits_scale",
+    [pytest.param(*shape, False, 1.0, id=cell)
      for cell, shape in FUSED_SAMPLE_CELLS.items()]
-    + [pytest.param(64, 768, 32768, id="125m")],
+    + [pytest.param(64, 768, 32768, False, 1.0, id="125m")]
+    + [pytest.param(*shape[:3], True, shape[3], id=cell)
+       for cell, shape in FUSED_SAMPLE_TIED_CELLS.items()],
 )
-def test_fused_sample_compiles(compiled_kernels, one_chip, R, E, V):
+def test_fused_sample_compiles(
+        compiled_kernels, one_chip, R, E, V, vocab_rows, logits_scale):
     """The head-and-sample kernel as the decode chunk calls it at every
     rollout cell's ``(R, E, V)``:
     a block of 2048 columns everywhere (3584 rows of bf16 are 14.7 MB a
-    buffer), the chip's PRNG for the uniforms."""
+    buffer), the chip's PRNG for the uniforms. A tied head is the
+    embedding as stored, ``[V, E]``, streamed in row blocks (granite's
+    logits divided by 8 on the way): the program holds no ``[E, V]`` copy
+    of it."""
     from areal_tpu.ops.pallas import fused_sample as fsk
 
     assert fsk.block_columns(R, E, V, 2) == 2048
 
     def f(x, w, temperature, greedy):
         return fsk.fused_sample_pallas(
-            jax.random.key(0), x, w, temperature, greedy
+            jax.random.key(0), x, w, temperature, greedy,
+            vocab_rows=vocab_rows, logits_scale=logits_scale,
         )
 
     text = _compile(
         f,
         _spec((R, E), jnp.bfloat16, one_chip),
-        _spec((E, V), jnp.bfloat16, one_chip),
+        _spec((V, E) if vocab_rows else (E, V), jnp.bfloat16, one_chip),
         _spec((R,), jnp.float32, one_chip),
         _spec((R,), jnp.bool_, one_chip),
     ).as_text()
     assert "fused_sample" in text
     assert f"[{R},{V}]" not in text
+    if vocab_rows:
+        assert f"bf16[{E},{V}]" not in text
+
+
+# sha256[:16] of the kernel's Mosaic module (its MLIR without locations)
+# with an [E, V] head, taken on PR 45's tree: cell 1's shape and JoyAI's
+EV_KERNEL_MODULES = {
+    (128, 1536, 151936): "91d7126640d10999",
+    (256, 2048, 129280): "cad458fe11a9c090",
+}
+
+
+def ev_kernel_module_hash(R, E, V):
+    """The hash above, of this tree: the module ``pallas_call`` hands to
+    the chip's compiler for ``(R, E, V)``, read back out of the lowered
+    program and printed without source locations (so it moves only with
+    what the kernel computes, not with the lines it is written on)."""
+    import base64
+    import hashlib
+
+    from jaxlib.mlir import ir
+
+    from areal_tpu.ops.pallas import fused_sample as fsk
+
+    def f(x, w, temperature, greedy):
+        return fsk.fused_sample_pallas(
+            jax.random.key(0), x, w, temperature, greedy)
+
+    lowered = jax.jit(f).trace(
+        jax.ShapeDtypeStruct((R, E), jnp.bfloat16),
+        jax.ShapeDtypeStruct((E, V), jnp.bfloat16),
+        jax.ShapeDtypeStruct((R,), jnp.float32),
+        jax.ShapeDtypeStruct((R,), jnp.bool_),
+    ).lower(lowering_platforms=("tpu",))
+    # backend_config = "{\22custom_call_config\22: {\22body\22: \22<base64>\22
+    (body,) = re.findall(
+        r'\\22body\\22: \\22([^\\]+)\\22', lowered.as_text())
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True   # the serialised dialect
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        text = module.operation.get_asm(enable_debug_info=False)
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize(
+    "R,E,V", [pytest.param(*shape, id="x".join(map(str, shape)))
+              for shape in EV_KERNEL_MODULES])
+def test_ev_head_kernel_is_the_module_it_was(compiled_kernels, R, E, V):
+    """The six cells with an untied head run the kernel they ran before it
+    learnt the ``[V, E]`` layout and ``logits_scale``: with neither, the
+    module handed to the chip's compiler is PR 45's, operation for
+    operation. (A deliberate change to the kernel's body changes these:
+    take the new hashes then, and measure the six cells.)"""
+    assert ev_kernel_module_hash(R, E, V) == EV_KERNEL_MODULES[(R, E, V)]
 
 
 def _assert_fused_epilogue(text, B, V):
@@ -893,7 +1017,8 @@ def _zaya_program(one_chip, program: str, n_layers: int, n_pages: int):
         max_slots=B, max_seqlen=9216, max_new_tokens_cap=8192,
         page_size=128, n_pages=80, record_routing=True, seed=0)
     eng._decode_use_pallas = True
-    assert not eng.fused and eng._moe_grouped(B) and eng._stateful
+    # a tied head in the serving dtype on one TPU device: the rule's yes
+    assert eng.fused and eng._moe_grouped(B) and eng._stateful
     carry = eng.state.ssm.carry
     assert carry.shape == (n_layers, B, 2688) and carry.dtype == jnp.bfloat16
 
@@ -913,7 +1038,7 @@ def _zaya_program(one_chip, program: str, n_layers: int, n_pages: int):
             (n_layers, min(2 * B, n_pages), 2688), jnp.bfloat16, one_chip)))
     params = jax.tree.map(spec, shapes)
     if program == "jit_chunk":
-        fn = eng._chunk_fn(16, M, 0, fused=False, with_topk=False)
+        fn = eng._chunk_fn(16, M, 0, fused=eng.fused, with_topk=False)
         args = (params, state, i32(B, M), i32(0))
     else:
         fn = eng._extend_fn(8, 64, skip_pool=False)
@@ -931,9 +1056,10 @@ def test_zaya_engine_programs_compile(compiled_kernels, one_chip, program):
     full table of 72 pages, the per-slot carry through the layer scan)
     holds ``paged_decode`` at the cell-1 geometry (8 q / 2 kv x 128),
     ``kv_page_write`` and, at 256 rows of 16 experts, ``moe_grouped`` (the
-    rule's choice: over 0.6 of the ridge), and ends in the materialised
-    ``[256, 262272]`` logits (a tied head: no ``fused_sample``);
-    ``jit_extend`` (a wave of 8 x 128 tokens continuing 8 slots' carry)
+    rule's choice: over 0.6 of the ridge), and ends in ``fused_sample``
+    over the embedding as stored, with nothing of the logits' shape
+    ``[256, 262272]`` in it; ``jit_extend`` (a wave of 8 x 128 tokens
+    continuing 8 slots' carry, its first token's sampler where it was)
     holds ``moe_grouped`` and makes no array of the carry's size."""
     B = 256
     cfg, fn, args = _zaya_program(one_chip, program, 2, 3576)
@@ -942,11 +1068,13 @@ def test_zaya_engine_programs_compile(compiled_kernels, one_chip, program):
     assert bool(re.search(r"%paged_decode(\.\d+)? = ", text)) == chunk
     assert bool(re.search(r"%kv_page_write(\.\d+)? = ", text)) == chunk
     assert re.search(r"%moe_grouped(\.\d+)? = ", text)
-    assert not re.search(r"%fused_sample(\.\d+)? = ", text)
     _assert_no_slice_of_the_routed_stack(text, cfg)
     if chunk:
-        assert f"f32[{B},{cfg.vocab_size}]" in text
+        _assert_fused_epilogue(text, B, cfg.vocab_size)
+        # ... nor a transposed copy of the embedding for the kernel
+        assert f"bf16[{cfg.hidden_dim},{cfg.vocab_size}]" not in text
     else:
+        assert not re.search(r"%fused_sample(\.\d+)? = ", text)
         made = [ln.strip()[:120] for ln in text.split("\n")
                 if "= bf16[2,256,2688]" in ln and " parameter(" not in ln
                 and " get-tuple-element(" not in ln]
@@ -962,7 +1090,9 @@ def test_zaya_cell_decode_chunk_computes_its_head_once(
     at a pool of 7.65e9 with a snapshot an entry a page it computed the
     262k-row tied head three times a step (PERF.md section 6, PR 45). The
     same holds 48 pages (100 MB) further on: the cell does not sit on the
-    edge, where a few MB freed would read as a gain."""
+    edge, where a few MB freed would read as a gain. Since PR 46 the step
+    ends in ``fused_sample`` and the ``[256, 262272]`` logits, the array
+    that was computed three times, are in neither program."""
     import json
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -977,6 +1107,7 @@ def test_zaya_cell_decode_chunk_computes_its_head_once(
     assert f"bf16[16,{n_pages},2,2,128,128]" in text
     again = sorted(set(re.findall(r"%([\w.\-]*remat[\w.\-]*) = ", text)))
     assert not again, again
+    _assert_fused_epilogue(text, 256, 262272)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
 
