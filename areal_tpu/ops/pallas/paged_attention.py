@@ -12,18 +12,43 @@ reference inherits (SURVEY §2.1).
 Grid ``(B/SB, ceil(M/KP))``: SB slots x KP pages (``S = KP * page``
 positions) per step, ``(SB, KP)`` from :func:`block_plan`. What a call
 costs, measured alone on a TPU v5e at page 128, bf16, at 128 slots x
-12q/2kv x 128 unless said (PERF.md §6, PRs 25, 27 and 36):
+12q/2kv x 128 unless said (PERF.md §6, PRs 25, 27, 36 and 47):
 
-- the call and its grid: 0.025 ms with NO token resident at 32 grid steps,
-  0.029 at 80, 0.11 at 256 (64 slots, 16 kv heads, SB 1). A step its block
-  does not reach does the ``j == 0`` init, the last step's fold of the
-  current token and ONE test of the block's rows against the step's
-  positions (``_block_span``), and nothing else: no copy started, no page
-  zeroed, no wait, no body, no look at the next step. That is 0.1 us a
-  step, 0.2 us where reached steps lie between them (48 unreached steps
-  among 32 reached ones add 0.011 ms). :func:`kernel_steps` counts the
-  steps that are reached (a third of them on heavy-tailed rollout traffic,
-  once rows are sorted);
+- the launch: a call of this grid and these operands whose kernel does
+  NOTHING takes 0.0042 ms at 80 grid steps and 0.0047 at 256 by the device's
+  trace (PR 47; the table and the lengths reach SMEM before step 0). A
+  kernel timed by the host's clock over back-to-back calls reads 0.009-0.012
+  ms MORE a call than the trace does: the records' "0.025 / 0.029 / 0.11 ms"
+  for the empty call were such readings;
+- a change of block. With NO token resident a call is 0.018-0.019 ms at 16
+  blocks x 5 steps (128 slots, SB 8), 0.021 at 16 x 5 of 28q/4kv (SB 4), 0.097
+  at 64 x 4 (64 slots, 16 kv heads, SB 1), 0.101 at 36 x 5 (72 slots, 16 kv
+  heads, page 64, SB 2): 0.65 / 0.80 / 1.25 / 2.4 us a BLOCK, and nearly all
+  of it is the ``j == nblk - 1`` fold of the current token (``_done``: 0.3 us
+  a block at SB 8 and 6 query heads a kv head, 1.1-2.4 us where every head is
+  its own kv head and so its own tile) and the ``j == 0`` fills (0.08 us).
+  On a LOADED call they run under the page copies in flight: without both,
+  the call on the cells' own rows is 0.2-0.8 % shorter. Until PR 47 ``q``,
+  ``k_self``, ``v_self`` and the output were ``(SB, ...)`` blocks that the
+  pipeline copied at every change of block. Where those operands live in HBM
+  (this kernel timed alone) the copies IN made the call on the cells' rows
+  7-10 % longer (0.2838 -> 0.2545 ms at the 1.5B cell's geometry, 1.024 ->
+  0.954 at OLMoE's, 0.611 -> 0.560 at Ouro's, 0.727 -> 0.676 at 256 slots x
+  8q/2kv; resident inputs alone give all of it, a resident output nothing:
+  a block's small copies wait among the page copies in flight); they are now
+  WHOLE in VMEM for the call, one copy in and one out. Inside the engine's
+  ``jit_chunk`` XLA's memory-space assignment already kept all four in VMEM
+  (``S(1)`` on the custom call's operands and result), so there a block's
+  copies never left the chip and the cells' calls are the same to the
+  microsecond before and after (``%paged_decode.9`` 7.8107 | 7.8090 ms a
+  step): a kernel-alone table says what a cell will do only if its operands
+  sit where the cell's do;
+- an unreached step: the one test of the block's rows against the step's
+  positions (``_block_span``) and nothing else: no copy started, no page
+  zeroed, no wait, no body, no look at the next step; 0.06-0.085 us by the
+  trace (80 against 32 steps of 16 blocks: 0.0183 against 0.0147 ms).
+  :func:`kernel_steps` counts the steps that are reached (a third of them on
+  heavy-tailed rollout traffic, once rows are sorted);
 - page DMAs are issued per slot, only for pages the slot holds, so the
   bytes read from HBM are the resident KV and no more. Where EVERY step is
   reached the copies of step ``n + 1`` run under the dots of step ``n`` and
@@ -53,18 +78,24 @@ costs, measured alone on a TPU v5e at page 128, bf16, at 128 slots x
   1.5B rollout cell, 63 at SB 1;
 - the body (QK dot, softmax, PV dot, batched over ``[SB*Hkv, S, D]``)
   runs for the WHOLE block of SB rows at every page block up to
-  ``ceil(max_len / S)`` of its LONGEST row, and ``_zero`` stores a page of
-  zeros (``2*Hkv*page*D`` elements) for every page a shorter row does not
-  hold up to that same maximum (masked probabilities are 0, but ``0 * NaN
-  = NaN`` in the PV dot). :func:`kernel_positions` counts that:
-  ``SB * S * ceil(max_len / S)`` summed over blocks. Rows of mixed length
-  in one block are work over positions that hold no KV (2.4 x the
-  resident KV with rows in random order, 1.5 x sorted). At SB 8 such
+  ``ceil(max_len / S)`` of its LONGEST row, over every row's stripe of the
+  scratch whether a page was copied into it or not (masked probabilities
+  are 0, but ``0 * NaN = NaN`` in the PV dot, so what such a stripe holds as
+  VALUES must be finite). :func:`kernel_positions` counts that: ``SB * S *
+  ceil(max_len / S)`` summed over blocks. Rows of mixed length in one block
+  are work over positions that hold no KV (2.4 x the resident KV with rows
+  in random order, 1.5 x sorted). Until PR 47 ``_zero`` stored a page of
+  zeros, keys and values, for every page a shorter row does not hold, at
+  every reached step (635 stores of 128 KB a call on the 1.5B cell's rows:
+  1.2 % of the call, 0.6 % at SB 1, nothing at SB 2-4); now only the call's
+  first two issues store zeros, values only (``_issue``): after them every
+  stripe of both buffers holds a page of the pool or zeros. At SB 8 mixed
   blocks also lose what the chain wins: on rows in SLOT order it measures
-  6-9 % SLOWER alone than the chain over grid steps did (at SB 4 1 % and
-  at SB 1 12 % faster; sorted 9-27 % faster at the five cells'
-  geometries). Why is not established (the zero stores of a mixed block
-  are the suspect); it is one more reason for the caller's sort.
+  6-9 % SLOWER alone than the chain over grid steps did (at SB 4 1 % and at
+  SB 1 12 % faster; sorted 9-27 % faster at the five cells' geometries).
+  The zero stores were much of that: with them stored once the 1.5B cell's
+  rows SHUFFLED take 0.2897 ms for 0.3239 (SB 4: 0.2669 for 0.2863), against
+  0.2520 sorted; it is still one more reason for the caller's sort.
 
 THE CALLER ORDERS ROWS BY LENGTH (``decode_step_paged`` sorts the batch
 once per step, before its layer scan), so a block's rows are of
@@ -132,6 +163,22 @@ def _scratch_bytes(sb, kp, page, n_kv, head_dim, pool_dtype, streams=2) -> int:
     if dt == jnp.int8:
         b += 2 * streams * sb * kp * page * n_kv * 4
     return b
+
+
+def _resident_bytes(batch, n_q, n_kv, head_dim, dv, dtype, latent) -> int:
+    """VMEM that q, the current token's K (and V, but in a latent program)
+    and the output hold for the whole call, as the chip lays them out: the
+    head axis in whole sublane tiles (8 rows of 32 bits: 16 of bf16), the
+    width in whole 128-lane tiles. 2.1 MB at 128 slots x 12q/2kv x 128,
+    24 MB at the largest there is, 256 x 32 latent rows of 576."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item
+
+    def tiled(heads, width):
+        return batch * -(-heads // sub) * sub * -(-width // LANES) * LANES * item
+
+    return (tiled(n_q, head_dim) + tiled(n_q, dv)
+            + (1 if latent else 2) * tiled(n_kv, head_dim))
 
 
 def block_plan(
@@ -324,12 +371,14 @@ def _decode_kernel(
     #   lens_ref   [B] int32 scalar-prefetch (pool-resident, EXCL. self)
     #   first_ref  [B] int32 scalar-prefetch: first visible position
     #              (``windowed`` only: a window layer's program)
-    #   q_ref      [SB, Hq, D]
-    #   ks_ref     [SB, Hkv, D] the current tokens' K (not in the pool)
-    #   vs_ref     [SB, Hkv, D]
+    #   q_ref      [B, Hq, D]   WHOLE in VMEM for the call, as ``ks_ref``,
+    #              ``vs_ref`` and ``o_ref`` are: copied in once and out once;
+    #              block ``bb`` reads and writes its ``rows`` of them
+    #   ks_ref     [B, Hkv, D] the current tokens' K (not in the pool)
+    #   vs_ref     [B, Hkv, D]
     #   kv_hbm     [L, P, 2, Hkv, page, D] whole pool, ANY/HBM
     #   sc_hbm     [L, P, 2, Hkv, page] f32 scales, ANY/HBM   (quantized)
-    #   o_ref      [SB, Hq, D]
+    #   o_ref      [B, Hq, D]
     #   kv_scr     [2, SB, 2, Hkv, KP*page, D] DOUBLE-buffered page scratch
     #              — pages DMA straight into the compute layout while the
     #              previous grid step's buffer is being consumed
@@ -339,8 +388,9 @@ def _decode_kernel(
     #   acc_scr    [SB, HqP, Dp] f32
     #   sems       DMA semaphores [2, SB, KP]
     #   sc_sems    DMA semaphores [2, SB, KP]                 (quantized)
-    #   ord_scr    [1] int32 SMEM: which of the two buffers the NEXT reached
-    #              step's pages are in (the parity of its ordinal)
+    #   ord_scr    [2] int32 SMEM: which of the two buffers the NEXT reached
+    #              step's pages are in (the parity of its ordinal), and how
+    #              many reached steps' copies the call has started so far
     *refs, ord_scr = refs
     first_ref = None
     if windowed:
@@ -361,6 +411,7 @@ def _decode_kernel(
     n_str = 1 if latent else 2
     bb = pl.program_id(0)
     j = pl.program_id(1)
+    rows = pl.ds(bb * sb, sb)  # the block's, on the untiled leading dimension
     Hq = q_ref.shape[1]
     D = q_ref.shape[2]
     Dv = dv if latent else D  # width of a value
@@ -410,13 +461,23 @@ def _decode_kernel(
         jax.lax.fori_loop(0, sb, row, None, unroll=True)
 
     def _issue(bb_t, j_t, buf):
-        """Start every resident-page DMA (and zero un-DMA'd tail blocks the
-        body will read) for REACHED step ``j_t`` of block ``bb_t`` into
-        buffer ``buf``."""
+        """Start every resident-page DMA for REACHED step ``j_t`` of block
+        ``bb_t`` into buffer ``buf`` (and, the first time the call uses the
+        buffer, zero the VALUES of the stripes it starts no copy into)."""
         # the batched body reads EVERY slot's stripe whenever any slot of
-        # the block is active, so un-DMA'd pages of shorter slots must be
-        # zeroed up to the block the longest slot reaches (masked
-        # probabilities are 0, but 0 * NaN = NaN in the PV dot)
+        # the block is active, and masked probabilities are 0, but 0 * NaN =
+        # NaN in the PV dot: a stripe no page is copied into must hold
+        # finite VALUES. It does once the call has written it, with a page
+        # (the pool's are finite: the tail of every row's last page is read
+        # the same way) or with zeros; what VMEM held before the call is
+        # anything. So only the call's first two issues, one a buffer,
+        # store zeros (until PR 47 every issue did, keys and values: 635
+        # stores of 128 KB a call on the 1.5B cell's rows, -1.2 % of the
+        # call alone). Keys need none: a masked score is replaced, not
+        # multiplied.
+        issued = ord_scr[1]
+        fresh = issued < 2
+
         def visit(s, slot, i, at, tests):
             held, free = tests(j_t * kp + i)
 
@@ -442,17 +503,20 @@ def _decode_kernel(
                         sc_sems.at[buf, s, i],
                     ).start()
 
-            @pl.when(free)
+            @pl.when(free & fresh)
             def _zero():
-                kv_scr[buf, s, :, :, at, :] = (
-                    jnp.zeros((n_str, n_kv, page, D), kv_scr.dtype)
+                # the value stream: the second of a K/V pool, the one of a
+                # latent pool
+                kv_scr[buf, s, n_str - 1, :, at, :] = (
+                    jnp.zeros((n_kv, page, D), kv_scr.dtype)
                 )
                 if quantized:
-                    sc_scr[buf, s, :, :, at] = (
-                        jnp.zeros((2, n_kv, page), sc_scr.dtype)
+                    sc_scr[buf, s, 1, :, at] = (
+                        jnp.zeros((n_kv, page), sc_scr.dtype)
                     )
 
         _each_entry(bb_t, visit)
+        ord_scr[1] = issued + 1
 
     # Software pipeline over the REACHED steps of the (sequential) grid, in
     # grid order: a reached step's pages were started by the reached step
@@ -472,6 +536,7 @@ def _decode_kernel(
     @pl.when((bb == 0) & (j == 0))
     def _prologue():
         ord_scr[0] = 0
+        ord_scr[1] = 0
         b_t, j_t, found = _first_reached(lens_ref, first_ref, 0, **plan)
 
         @pl.when(found)
@@ -530,7 +595,7 @@ def _decode_kernel(
         ord_scr[0] = 1 - buf
         # (SB, Hkv) folds into ONE batch dim (Mosaic's tpu.matmul supports
         # a single batch dim); the reshape is layout-free
-        q = q_ref[...].reshape(sb * n_kv, n_rep, D)
+        q = q_ref[rows].reshape(sb * n_kv, n_rep, D)
         k = kv_scr[buf, :, 0].reshape(sb * n_kv, S, D)
         if latent:
             v = kv_scr[buf, :, 0, :, :, :Dv].reshape(sb * n_kv, S, Dv)
@@ -593,9 +658,9 @@ def _decode_kernel(
     def _done():
         # fold the current tokens' self-attention (always attended; their
         # KV is scattered into the pool by the caller AFTER the layer scan)
-        q = q_ref[...].reshape(sb, n_kv, n_rep, D)
-        ks = ks_ref[...]                                      # [SB,Hkv,D]
-        vs = ks[:, :, :Dv] if latent else vs_ref[...]
+        q = q_ref[rows].reshape(sb, n_kv, n_rep, D)
+        ks = ks_ref[rows]                                      # [SB,Hkv,D]
+        vs = ks[:, :, :Dv] if latent else vs_ref[rows]
         s_self = jnp.sum(
             q.astype(jnp.float32) * ks[:, :, None].astype(jnp.float32),
             axis=3,
@@ -614,7 +679,7 @@ def _decode_kernel(
             vs[:, :, None].astype(jnp.float32), (sb, n_kv, n_rep, Dv)
         ).reshape(sb, Hq, Dv)
         acc = acc_scr[:, :Hq, :Dv] * corr + p_self * v_rep
-        o_ref[...] = (acc / l).astype(o_ref.dtype)
+        o_ref[rows] = (acc / l).astype(o_ref.dtype)
 
 
 def decode(
@@ -702,11 +767,17 @@ def decode(
         quantized=quantized,
         dv=value_width,
     )
-    row = lambda b, j, *_: (b, 0, 0)
+    # q, the current token's K/V and the output are WHOLE in VMEM for the
+    # call: one copy in before the grid and one out after it. As blocks of
+    # ``sb`` rows the pipeline copied them at every change of block, which
+    # from HBM costs a loaded call 7-10 %; a caller whose program already
+    # keeps them in VMEM (the engine's ``jit_chunk``) pays neither (module
+    # docstring)
+    resident = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
     in_specs = [
-        pl.BlockSpec((sb, Hq, D), row),
-        pl.BlockSpec((sb, Hkv, D), row),
-        *([] if latent else [pl.BlockSpec((sb, Hkv, D), row)]),
+        resident,
+        resident,
+        *([] if latent else [resident]),
         pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     scratch_shapes = [
@@ -732,25 +803,28 @@ def decode(
         )
         scratch_shapes.append(pltpu.SemaphoreType.DMA((2, sb, kp)))
         operands.append(scales)
-    # the parity of the next reached step's ordinal, last of the scratch
-    scratch_shapes.append(pltpu.SMEM((1,), jnp.int32))
+    # the parity of the next reached step's ordinal and the count of issues,
+    # last of the scratch
+    scratch_shapes.append(pltpu.SMEM((2,), jnp.int32))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4 if windowed else 3,
             grid=(B // sb, nblk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((sb, Hq, Dv), row),
+            out_specs=resident,
             scratch_shapes=scratch_shapes,
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype),
         # the double-buffered page scratch alone can exceed the 16 MB
         # default scoped-vmem budget; size the limit from the actual
-        # scratch + generous op margin (v5e VMEM is 128 MB)
+        # scratch and the resident operands + generous op margin (v5e VMEM
+        # is 128 MB)
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_scratch_bytes(
                 sb, kp, page, Hkv, D, pages.dtype, streams
-            ) + 32 * 2**20,
+            ) + _resident_bytes(B, Hq, Hkv, D, Dv, q.dtype, latent)
+            + 32 * 2**20,
         ),
         interpret=_interpret(),
         # the kernel's name in the compiled program and the device trace

@@ -498,6 +498,28 @@ class TestPagedDecodeStepGate:
 
     PAGE = 8
 
+    def _inputs(self, rng, B, width, variant, kw):
+        """``(q, k_self, v_self, pool, table)`` of ``B`` rows with their own
+        pages, 2 query heads on 1 kv head of 16; the program's arguments
+        (an int8 pool's scales, a latent pool's value width) go into
+        ``kw``."""
+        Hq, Hkv, D, L, page = 2, 1, 16, 2, self.PAGE
+        P = B * width
+        latent = variant == "latent"
+        q = rng.normal(size=(B, Hq, D)).astype(np.float32)
+        k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        v_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        pool = rng.normal(
+            size=(L, P, 1 if latent else 2, Hkv, page, D)).astype(np.float32)
+        table = rng.permutation(P).reshape(B, width).astype(np.int32)
+        if variant == "int8":
+            pool = rng.integers(-127, 128, size=pool.shape).astype(np.int8)
+            kw["scales"] = jnp.asarray(rng.uniform(
+                0.001, 0.02, size=pool.shape[:-1]), jnp.float32)
+        if latent:
+            v_self, kw["value_width"] = None, 8
+        return q, k_self, v_self, pool, table
+
     @pytest.mark.parametrize(
         "lens,width,kp,sb,variant,chained",
         [
@@ -561,12 +583,11 @@ class TestPagedDecodeStepGate:
 
         rng = np.random.default_rng(len(lens) + width)
         lens = np.asarray(lens, np.int32)
-        B, Hq, Hkv, D, L, page = len(lens), 2, 1, 16, 2, self.PAGE
-        P = B * width
+        B, page = len(lens), self.PAGE
         latent = variant == "latent"
-        streams = 1 if latent else 2
         assert pl_paged.block_plan(
-            B, Hkv, D, page, width, jnp.float32, kp, sb, streams) == (sb, kp)
+            B, 1, 16, page, width, jnp.float32, kp, sb, 1 if latent else 2
+        ) == (sb, kp)
         kw, first = {}, None
         if variant == "window":
             kw["sliding_window"] = 6
@@ -575,18 +596,8 @@ class TestPagedDecodeStepGate:
         active, total = pl_paged.kernel_steps(lens, *plan)
         assert active < total          # the gate has steps to skip
         assert pl_paged.kernel_steps_chained(lens, *plan) == chained
-        q = rng.normal(size=(B, Hq, D)).astype(np.float32)
-        k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
-        v_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
-        pool = rng.normal(
-            size=(L, P, streams, Hkv, page, D)).astype(np.float32)
-        table = rng.permutation(P).reshape(B, width).astype(np.int32)
-        if variant == "int8":
-            pool = rng.integers(-127, 128, size=pool.shape).astype(np.int8)
-            kw["scales"] = jnp.asarray(rng.uniform(
-                0.001, 0.02, size=pool.shape[:-1]), jnp.float32)
-        if latent:
-            v_self, kw["value_width"] = None, 8
+        q, k_self, v_self, pool, table = self._inputs(
+            rng, B, width, variant, kw)
 
         def kernel(rows):
             return np.asarray(pl_paged.decode(
@@ -606,6 +617,44 @@ class TestPagedDecodeStepGate:
         order = np.argsort(lens, kind="stable")
         if not np.array_equal(order, np.arange(B)):
             np.testing.assert_array_equal(kernel(order), got[order])
+
+    @pytest.mark.parametrize("only", ["first", "last"])
+    @pytest.mark.parametrize("blocks", [1, 16, 64])
+    @pytest.mark.parametrize("variant", ["plain", "window", "latent", "int8"])
+    def test_block_rows_addressed_by_block(self, variant, blocks, only):
+        """q, the current token's K/V and the output are whole in VMEM for
+        the call and a block reads and writes ITS rows of them (``bb * sb``):
+        ONE block of the call reaches a step, the first or the last, every
+        other row's result is its own current token's value, and each row's
+        q and K/V are its own, so a block that took its neighbour's rows
+        shows in every program of the kernel."""
+        from areal_tpu.ops import paged_attention as xla_paged
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        sb = 1 if blocks == 64 else 2
+        B, width, kp = blocks * sb, 4, 2
+        rng = np.random.default_rng(blocks)
+        lens = np.zeros(B, np.int32)
+        at = 0 if only == "first" else B - sb
+        lens[at:at + sb] = [20, 5][:sb]
+        kw = {"sliding_window": 6} if variant == "window" else {}
+        assert pl_paged.kernel_steps(
+            lens, sb, kp * self.PAGE, width // kp) == (2, blocks * 2)
+        q, k_self, v_self, pool, table = self._inputs(
+            rng, B, width, variant, kw)
+        got = np.asarray(pl_paged.decode(
+            q, k_self, v_self, pool, jnp.int32(1), table, lens,
+            pages_per_step=kp, slots_per_step=sb, **kw))
+        want = np.asarray(xla_paged.paged_decode_attention(
+            q, k_self, v_self, pool, jnp.int32(1), table, lens,
+            use_pallas=False, **kw))
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        # a row with nothing resident attends to itself alone: its own value
+        # (one kv head: every query head's result is that head's value)
+        own = k_self[..., :8] if v_self is None else v_self
+        empty = lens == 0
+        np.testing.assert_allclose(
+            got[empty], np.broadcast_to(own, got.shape)[empty], atol=1e-6)
 
 
 class TestKernelPositions:

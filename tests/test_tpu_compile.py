@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
 
 # the 1.5B cell's widths (benchmark/configs/r1d-qwen-1p5b.json) and the
@@ -833,10 +834,24 @@ def _count_primitives(jaxpr, counts=None):
     return counts
 
 
+def _call_windows(jaxpr):
+    """The one ``pallas_call`` of ``jaxpr``: the block mappings of its
+    operands and results that Mosaic's pipeline copies (every one not left
+    in ``ANY`` memory for the kernel's own DMAs), and the VMEM limit it asks
+    the compiler for."""
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    piped = [
+        bm for bm in call.params["grid_mapping"].block_mappings
+        if bm.block_aval.memory_space is not pltpu.MemorySpace.ANY
+    ]
+    limit = call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    return piped, limit
+
+
 @pytest.mark.parametrize(
     "program",
     ["cell1-table32", "cell3-table32", "olmoe-table32", "window-table64",
-     "mla_decode-table64"],
+     "mla_decode-table64", "mla_decode-table72"],
 )
 def test_chained_decode_lowers_with_two_copies_of_issue(
         compiled_kernels, one_chip, program):
@@ -847,7 +862,16 @@ def test_chained_decode_lowers_with_two_copies_of_issue(
     page copies' start (the prologue's and the chain's) and ONE of the
     wait, each ONE entry of the table that the lowering unrolls over the
     step's ``SB * KP``: what is traced is traced by every chunk program at
-    every start (PERF.md §6, PRs 31, 35 and 36)."""
+    every start (PERF.md §6, PRs 31, 35 and 36).
+
+    Since PR 47 q, the current token's K/V and the output are WHOLE in VMEM
+    for the call: the pipeline has no window that moves with the grid, so it
+    copies each of them once a call and none at a change of block (every
+    piped operand is the whole array at index 0), and the VMEM limit the call
+    asks for covers them beside the page scratch. The largest there is, the
+    JoyAI cell's ``mla_decode`` at 256 rows x 32 heads of a 576-wide latent
+    (stored 640 wide: Mosaic takes the row in whole lane tiles) over its
+    table of 72 pages, lowers inside that limit."""
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
     kw, streams = {}, 2
@@ -860,7 +884,7 @@ def test_chained_decode_lowers_with_two_copies_of_issue(
             None,
             _spec((c["L"], c["P"], 1, 1, 128, width), jnp.bfloat16, one_chip),
             _spec((), jnp.int32, one_chip),
-            _spec((B, 64), jnp.int32, one_chip),
+            _spec((B, int(program.split("table")[1])), jnp.int32, one_chip),
             _spec((B,), jnp.int32, one_chip),
         ]
         kw = dict(softmax_scale=192 ** -0.5, value_width=c["DV"])
@@ -883,7 +907,15 @@ def test_chained_decode_lowers_with_two_copies_of_issue(
     def f(q, ks, vs, pages, layer, table, lens):
         return pl_paged.decode(q, ks, vs, pages, layer, table, lens, **kw)
 
-    counts = _count_primitives(jax.make_jaxpr(f)(*specs).jaxpr)
+    jaxpr = jax.make_jaxpr(f)(*specs).jaxpr
+    counts = _count_primitives(jaxpr)
+    piped, limit = _call_windows(jaxpr)
+    # q, k_self, (v_self,) and the output; the pool is the kernel's own
+    assert len(piped) == (3 if streams == 1 else 4)
+    for bm in piped:
+        assert bm.block_aval.memory_space is pltpu.MemorySpace.VMEM
+        assert bm.has_trivial_window(), bm.origin
+    assert limit <= 100 * 2**20           # of the v5e's 128 MiB
     assert sb * kp > 1
     assert counts["dma_start"] == 2
     assert counts["dma_wait"] == 1
