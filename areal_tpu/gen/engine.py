@@ -21,7 +21,11 @@ and SGLang's radix/paged KV memory. Redesigned for XLA:
   still ONE pool and ONE free list, pages of one byte size (a page holds
   ``page`` tokens of one position of the period in every period), and a
   slot has one table a position of the period. A model of one kind is the
-  same code with one position a period: no second allocator.
+  same code with one position a period: no second allocator. A stack plan
+  whose attention layers differ (``phi4flash``: eight window layers, one
+  full layer that seven cross-attention layers share) is the same code
+  with a kind a CACHE layer and one period; beside the pool it keeps the
+  state-space layers' per-slot state, as ``granitemoehybrid`` does.
 - PAGES ARE TAKEN AS A SLOT GROWS, in every kind (``GenerationEngine``'s
   docstring has the whole policy): admission takes the prompt's pages and
   RESERVES one look-ahead; every chunk takes the pages it writes from the
@@ -704,7 +708,16 @@ class GenerationEngine:
                 "slots_held": 0,
                 "preemptions": 0,
                 "preempted_tokens_recomputed": 0,
+                # prefilled positions x the layers admission ran over them /
+                # the layers it did not: a stack plan's segments behind
+                # its last layer that writes a cache or a state (gated
+                # memory units, cross attention) keep nothing of a
+                # position whose logits nobody reads (``tfm.
+                # _extend_layers``); 0 skipped for every other model
+                "admit_token_layers_run": 0,
+                "admit_token_layers_skipped": 0,
             }
+            self._admit_layers = tfm.admission_layers(cfg)
             if self._moe and cfg.moe.skip_expert:
                 # of the decode chunks' routing (active rows x expert
                 # layers x steps): rows in all, and rows that took the skip
@@ -933,7 +946,8 @@ class GenerationEngine:
 
     def recurrent_state(self, rid: str) -> Optional[Tuple[int, np.ndarray]]:
         """What the state-space layers hold of the running request ``rid``:
-        ``(n, ssm)`` with ``ssm [Ls, H, P, N]`` float32 the recurrent state
+        ``(n, ssm)`` with ``ssm [Ls, H, P, N]`` float32 (the selective
+        scan: one head of ``d_inner`` channels) the recurrent state
         after the prompt and all but the last of the ``n`` tokens generated
         so far (the last is fed at the next step), both from ONE state
         pytree. For a check from outside that the state is what the
@@ -1846,6 +1860,7 @@ class GenerationEngine:
                 st["state_snapshots_taken"], st["state_snapshot_hits"],
                 st["state_snapshot_bytes"], st["state_snapshot_evictions"],
                 st["preempted_tokens_recomputed"],
+                st["admit_token_layers_run"], st["admit_token_layers_skipped"],
             )
             self._admit_pending()
             attrs.update(
@@ -1871,6 +1886,11 @@ class GenerationEngine:
                 # where their routed experts ran
                 attrs["moe_grouped_rows"] = st["moe_grouped_rows"] - before[5]
                 attrs["moe_dense_rows"] = st["moe_dense_rows"] - before[6]
+            if st["admit_token_layers_skipped"]:
+                attrs["token_layers_run"] = (
+                    st["admit_token_layers_run"] - before[12])
+                attrs["token_layers_skipped"] = (
+                    st["admit_token_layers_skipped"] - before[13])
             if self._stateful:
                 # snapshots of the recurrent state filed by this wave,
                 # admissions it seeded from one, bytes copied in and out,
@@ -2047,7 +2067,21 @@ class GenerationEngine:
                     self.prefix.insert(
                         ids, self._registry_pages(slot, n_shared_full),
                         snapshot=snap_to)
+                    if any(c is not None and c < n_shared_full
+                           for c in self._window_claim):
+                        # a prompt longer than a window kind's claim: that
+                        # kind's later pages are taken as the chunks pass,
+                        # so the chain just filed has holes there. Filed
+                        # again behind the waves, the pages come home
+                        # (``PrefixRegistry.insert``), and the siblings of
+                        # LATER cycles find every page their window reads
+                        deferred_inserts.append(
+                            (ids, slot, n_shared_full, None))
             self.stats["prefill_tokens"] += len(row["tokens"])
+            self.stats["admit_token_layers_run"] += (
+                len(row["tokens"]) * self._admit_layers)
+            self.stats["admit_token_layers_skipped"] += len(row["tokens"]) * (
+                self.cfg.n_layers * self.cfg.n_passes - self._admit_layers)
             self.stats["admitted"] += 1
             if r.rid in self._carried and row["tokens"]:
                 # a preempted request back in a slot: these positions were

@@ -261,7 +261,7 @@ class PrefixRegistry:
         self._tick += 1
         pages: List[int] = []
         children = self._children
-        n_state = 0
+        snaps = []              # (pages up to a node with a snapshot, entry)
         for chunk in self._chunks(prompt_ids, n_full_pages):
             node = children.get(chunk)
             if node is None:
@@ -270,10 +270,20 @@ class PrefixRegistry:
             pages.append(node.page)
             children = node.children
             if node.snap is not None:
-                n_state, self.hit_snapshot = len(pages), node.snap
+                snaps.append((len(pages), node.snap))
+        windowed = len(self.windows) > 1
         if self.stateful:
+            # the hit ends where the state stands: the longest prefix that
+            # ends in a snapshot AND, with window kinds, still has every
+            # page its borrower reads (cut shorter, the state seeded from
+            # the snapshot would be ahead of the pages)
+            n_state = 0
+            for n, snap in reversed(snaps):
+                if not windowed or self._usable(pages, n) == n:
+                    n_state, self.hit_snapshot = n, snap
+                    break
             pages = pages[:n_state]
-        if len(self.windows) > 1:
+        elif windowed:
             pages = pages[: self._usable(pages, len(pages))]
         if not pages:
             return None

@@ -3,11 +3,12 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya, phi4flash) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
-from typing import Optional, Tuple
+import functools
+from typing import Any, Dict, List, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,16 +94,28 @@ class CCAConfig:
 
 @dataclasses.dataclass(frozen=True)
 class SSMConfig:
-    """State-space layers (the Mamba-2 recurrence as ``granitemoehybrid``
-    lays it out; ``ops/ssm.py`` has the equations). ``n_heads`` heads of
-    ``head_dim``, each with a recurrent state of ``[head_dim, d_state]``;
-    ``n_groups`` groups of heads share one ``B`` and ``C`` of ``d_state``;
-    a causal depthwise convolution of width ``d_conv`` over ``conv_dim``
-    channels. ``chunk_size`` is the program's own (any chunking computes
-    the same function). ``state_dtype``: what the recurrent state is kept
-    and accumulated in; only float32 is supported (a 16-bit state is
-    rounded once a token for thousands of tokens while the trainer's
-    chunked scan accumulates in float32: another configuration)."""
+    """State-space layers; ``ops/ssm.py`` has the equations of BOTH
+    recurrences, and the HF family chooses (a user never does):
+
+    - ``dt_rank`` None: the Mamba-2 recurrence as ``granitemoehybrid`` lays
+      it out. ``n_heads`` heads of ``head_dim``, each with a recurrent
+      state of ``[head_dim, d_state]`` that decays by ONE scalar a head and
+      token; ``n_groups`` groups of heads share one ``B`` and ``C`` of
+      ``d_state``; a causal depthwise convolution of width ``d_conv`` over
+      ``[x ; B ; C]``; a gated RMSNorm before the output projection.
+    - ``dt_rank`` a rank: Mamba-1's selective scan (``phi4flash``). ONE
+      head of ``head_dim = d_inner`` channels, each with a state of
+      ``d_state`` that decays by its own ``exp(dt[c] A[c, n])``; ``dt``
+      comes through a projection of ``dt_rank``; the convolution runs over
+      ``x`` alone, and ``B``, ``C`` are read from the convolved ``x``; no
+      norm before the output projection.
+
+    ``chunk_size`` is the program's own (any chunking computes the same
+    function; the selective scan runs token by token and does not read
+    it). ``state_dtype``: what the recurrent state is kept and accumulated
+    in; only float32 is supported (a 16-bit state is rounded once a token
+    for thousands of tokens while the trainer's scan accumulates in
+    float32: another configuration)."""
 
     n_heads: int
     head_dim: int
@@ -113,6 +126,12 @@ class SSMConfig:
     conv_bias: bool = True
     proj_bias: bool = False
     state_dtype: str = "float32"
+    dt_rank: Optional[int] = None
+
+    @property
+    def selective(self) -> bool:
+        """Mamba-1's selective scan (the class docstring)."""
+        return self.dt_rank is not None
 
     @property
     def d_inner(self) -> int:
@@ -120,13 +139,37 @@ class SSMConfig:
 
     @property
     def conv_dim(self) -> int:
-        """Channels the convolution runs over: ``[x ; B ; C]``."""
+        """Channels the convolution runs over: ``[x ; B ; C]``, or ``x``
+        alone under the selective scan."""
+        if self.selective:
+            return self.d_inner
         return self.d_inner + 2 * self.n_groups * self.d_state
 
     @property
     def in_dim(self) -> int:
-        """Width of the input projection: ``[z ; xBC ; dt]``."""
+        """Width of the input projection: ``[z ; xBC ; dt]``, or ``[x ;
+        z]``."""
+        if self.selective:
+            return 2 * self.d_inner
         return self.d_inner + self.conv_dim + self.n_heads
+
+
+MIXERS = ("ssm", "attn", "gmu", "cross")
+
+
+@dataclasses.dataclass(frozen=True)
+class StackPosition:
+    """One position of a segment's period, as ``ModelConfig.plan`` resolves
+    it: its ``mixer``; an attention layer's ``window`` (None: full) and
+    whether later cross-attention layers read the K/V it ``exports``; a
+    cross-attention layer's ``source``, the cache layer it reads (an
+    attention layer's own cache layer follows the order the layers run:
+    the forwards count it)."""
+
+    mixer: str
+    window: Optional[int] = None
+    exports: bool = False
+    source: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -180,18 +223,37 @@ class ModelConfig:
     # the period in every period and a slot has one table a position
     # (``models/transformer.PagedKVCache``, ``gen/engine.py``).
     layer_pattern: Optional[Tuple[Tuple[Optional[int], bool], ...]] = None
-    # State-space layers beside attention layers (``granitemoehybrid``):
-    # ``mixer_pattern`` is a PERIOD of mixer kinds, "ssm" or "attn"; layer
-    # ``l`` is kind ``mixer_pattern[l % len]``. The kinds differ in weight
-    # SHAPE, so the weight tree holds a stack a kind (``params["layers"]``
-    # the attention layers in the order they run, ``params["ssm_layers"]``
-    # the state-space layers) and one scan over the periods cuts each
-    # position's weights from the stack of its kind
-    # (``models/transformer._scan_mixers``). Only attention layers hold
-    # K/V: ``cache_layers`` counts them alone. A state-space layer's
-    # context is a per-SLOT state (``models/transformer.SSMState``).
+    # A stack whose layers differ in their MIXER (``granitemoehybrid``,
+    # ``phi4flash``): ``stack_plan`` is its SEGMENTS in the order they run,
+    # each ``(repeats, period)``, a period of positions repeated; a
+    # position names its mixer and, for "attn", its sliding window (None:
+    # full): ``"ssm"`` or ``("attn", 512)``. The mixers:
+    #   "ssm"   a state-space layer (``ssm``); its context is a per-SLOT
+    #           state (``models/transformer.SSMState``), and its scan
+    #           output BEFORE the gate is the MEMORY later "gmu" layers read
+    #   "attn"  self attention; the only kind that holds K/V
+    #           (``cache_layers`` counts these alone)
+    #   "gmu"   a gated memory unit: ``W_out (silu(W_in h) * M)`` with ``M``
+    #           the last state-space layer's memory at the same position
+    #   "cross" attention with the layer's own queries over the K/V of the
+    #           LAST "attn" layer of an earlier segment, which it shares
+    #           and never writes
+    # The kinds differ in weight SHAPE, so the weight tree holds a stack a
+    # kind, each in the order its layers run (``params["layers"]`` the
+    # "attn" layers, ``"ssm_layers"``, ``"gmu_layers"``, ``"cross_layers"``)
+    # and ONE scan a segment cuts each position's weights from the stack
+    # of its kind (``models/transformer._scan_plan``). The cache's layer
+    # kinds (``layer_kinds``) follow the "attn" layers' windows.
     ssm: Optional[SSMConfig] = None
-    mixer_pattern: Optional[Tuple[str, ...]] = None
+    stack_plan: Optional[Tuple[Tuple[int, Tuple[Any, ...]], ...]] = None
+    # Differential attention (``phi4flash``): heads in PAIRS; a pair's two
+    # softmaxes read the two halves of one kv row's key and both read its
+    # whole value, and the pair's context is their difference under a
+    # learned ``lambda``, normed (``models/transformer._diff_combine``).
+    # ``n_q_heads`` / ``n_kv_heads`` / ``head_dim`` are the published ones
+    # (40 / 20 / 64); the caches hold ``kv_heads_per_row`` = 2 kv heads a
+    # row.
+    diff_attn: bool = False
     attn_logits_soft_cap: Optional[float] = None
     softmax_scale: Optional[float] = None  # default head_dim ** -0.5
     # Latent attention in place of the q/k/v projections (None = those).
@@ -323,10 +385,19 @@ class ModelConfig:
 
     @property
     def layer_kinds(self) -> Tuple[Tuple[Optional[int], bool], ...]:
-        """``(window, rotary)`` of each position of the period: one entry
-        for a model whose layers are alike."""
+        """``(window, rotary)`` of each position of the CACHE's period: one
+        entry for a model whose layers are alike. Under a ``stack_plan``
+        the shortest period of its "attn" layers' windows (one kind where
+        they are alike; where they are not, as many kinds as the period is
+        long, each with a page table of its own)."""
         if self.layer_pattern is not None:
             return self.layer_pattern
+        if self.stack_plan is not None:
+            wins = [p.window for p in self.positions if p.mixer == "attn"]
+            period = next(
+                n for n in range(1, len(wins) + 1)
+                if len(wins) % n == 0 and wins == wins[:n] * (len(wins) // n))
+            return tuple((w, self.apply_rotary) for w in wins[:period])
         return ((self.sliding_window, self.apply_rotary),)
 
     @property
@@ -344,32 +415,85 @@ class ModelConfig:
 
     @property
     def kv_heads_per_row(self) -> int:
-        """kv heads laid side by side in ONE row of every cache (the page
-        pool, the dense cache; ``models/transformer._pack_qkv``): two where
-        state-space layers stand beside attention heads of at most half a
-        128-lane tile (the published 64: the paged kernels take a
-        full-lane head only), else one. Every older family keeps the
-        layout it had."""
+        """kv heads laid side by side in ONE row of the page pool
+        (``models/transformer._pack_qkv``): two where state-space layers
+        stand beside attention heads of at most half a 128-lane tile (the
+        published 64: the paged kernels take a full-lane head only), else
+        one. Every older family keeps the layout it had. The same packing
+        serves DIFFERENTIAL pairs (``diff_attn``): the two kv heads of a
+        row are then a pair's ``k1`` and ``k2``, a query keeps its values
+        in its own half, and the whole row of the value is what both
+        softmaxes of the pair read; such a model's dense cache and
+        trainer's forward pack the same way."""
         paired = (
-            self.ssm is not None and self.head_dim * 2 <= 128
-            and self.n_kv_heads % 2 == 0
+            (self.ssm is not None or self.diff_attn)
+            and self.head_dim * 2 <= 128 and self.n_kv_heads % 2 == 0
         )
         return 2 if paired else 1
+
+    @functools.cached_property
+    def plan(self) -> Optional[Tuple[Tuple[int, Tuple[StackPosition, ...]], ...]]:
+        """``stack_plan`` resolved: ``(repeats, positions)`` a segment (the
+        class :class:`StackPosition`); None for a model of one mixer."""
+        if self.stack_plan is None:
+            return None
+        segs: List = []
+        n_attn, last_attn = 0, None    # cache layers so far; (segment,
+        exported = False               # position, cache layer) of the newest
+        for reps, period in self.stack_plan:
+            mixers = [m for m, _ in period]
+            if "cross" in mixers and not exported:
+                # the one attention layer whose K/V the cross layers read
+                si, at, _ = last_attn
+                segs[si][1][at] = dataclasses.replace(
+                    segs[si][1][at], exports=True)
+                exported = True
+            source = None if last_attn is None else last_attn[2]
+            segs.append((reps, [
+                StackPosition(m, w, source=source if m == "cross" else None)
+                for m, w in period]))
+            if "attn" in mixers:
+                n_attn += reps * mixers.count("attn")
+                last_attn = (
+                    len(segs) - 1,
+                    len(mixers) - 1 - mixers[::-1].index("attn"), n_attn - 1)
+        return tuple((reps, tuple(out)) for reps, out in segs)
+
+    @property
+    def positions(self) -> Tuple[StackPosition, ...]:
+        """:attr:`plan`, a layer at a time, in the order the layers run."""
+        return tuple(
+            p for reps, period in self.plan for _ in range(reps)
+            for p in period)
 
     @property
     def mixers(self) -> Tuple[str, ...]:
         """The mixer kind of every layer, in the order the layers run."""
-        if self.mixer_pattern is None:
+        if self.stack_plan is None:
             return ("attn",) * self.n_layers
-        return self.mixer_pattern * (self.n_layers // len(self.mixer_pattern))
+        return tuple(
+            m for reps, period in self.stack_plan for _ in range(reps)
+            for m, _ in period)
+
+    @property
+    def layer_ids(self) -> Dict[str, List[int]]:
+        """Of each mixer kind, its layers' places in the model: entry ``i``
+        of a kind's weight stack is layer ``layer_ids[kind][i]``."""
+        return {
+            kind: [i for i, m in enumerate(self.mixers) if m == kind]
+            for kind in MIXERS}
+
+    def n_mixers(self, kind: str) -> int:
+        return sum(m == kind for m in self.mixers)
 
     @property
     def n_ssm_layers(self) -> int:
-        return sum(m == "ssm" for m in self.mixers)
+        return self.n_mixers("ssm")
 
     @property
     def n_attn_layers(self) -> int:
-        return self.n_layers - self.n_ssm_layers
+        """Layers of self attention: those that hold K/V."""
+        return self.n_mixers("attn")
 
     @property
     def n_periods(self) -> int:
@@ -438,38 +562,89 @@ class ModelConfig:
             )
         if self.n_passes < 1:
             raise ValueError("n_passes: the stack runs at least once")
-        if (self.ssm is None) != (self.mixer_pattern is None):
-            raise ValueError("ssm and mixer_pattern come together")
-        if self.mixer_pattern is not None:
-            object.__setattr__(
-                self, "mixer_pattern", tuple(self.mixer_pattern))
-            pat, s = self.mixer_pattern, self.ssm
+        if (self.ssm is None) != (self.stack_plan is None):
+            raise ValueError("ssm and stack_plan come together")
+        if self.stack_plan is not None:
+            def position(p):
+                mixer, window = (p, None) if isinstance(p, str) else p
+                return (mixer, None if window is None else int(window))
+
+            try:
+                plan = tuple(
+                    (int(reps), tuple(position(p) for p in period))
+                    for reps, period in self.stack_plan)
+            except (TypeError, ValueError):
+                plan = ()
+            object.__setattr__(self, "stack_plan", plan)
+            flat = [p for reps, period in plan for p in period * reps]
+            kinds = [m for m, _ in flat]
             if (
-                not pat or self.n_layers % len(pat)
-                or any(m not in ("ssm", "attn") for m in pat)
-                or "attn" not in pat or "ssm" not in pat
+                not plan or len(flat) != self.n_layers
+                or any(reps < 1 or not period for reps, period in plan)
+                or any(m not in MIXERS for m in kinds)
+                or any(w is not None and (m != "attn" or w < 1)
+                       for m, w in flat)
+                or "attn" not in kinds or "ssm" not in kinds
             ):
                 raise ValueError(
-                    "mixer_pattern: a period of 'ssm' and 'attn' (both "
-                    f"present) that divides n_layers, got {pat!r}"
+                    "stack_plan: segments (repeats, period) of 'ssm', "
+                    "('attn', window or None), 'gmu' and 'cross' positions "
+                    "(state-space and attention both present, a window on "
+                    f"'attn' alone) that make up n_layers, got "
+                    f"{self.stack_plan!r}"
                 )
+            for si, (reps, period) in enumerate(plan):
+                here = [m for m, _ in period]
+                before = [m for _, per in plan[:si] for m, _ in per]
+                if "gmu" in here and "ssm" not in before or (
+                    "cross" in here and "attn" not in before
+                ) or (
+                    ("gmu" in here or "cross" in here)
+                    and ("ssm" in here or "attn" in here)
+                ):
+                    raise ValueError(
+                        "stack_plan: a 'gmu' position reads the memory of a "
+                        "state-space layer, and a 'cross' position the K/V "
+                        "of an attention layer, of an EARLIER segment; a "
+                        "segment does not hold both a reader and what it "
+                        "reads"
+                    )
+            if "cross" in kinds:
+                first = next(
+                    i for i, (_, per) in enumerate(plan)
+                    if any(m == "cross" for m, _ in per))
+                src = [
+                    (reps, w) for reps, per in plan[:first]
+                    for m, w in per if m == "attn"][-1]
+                if src[0] != 1 or src[1] is not None:
+                    raise ValueError(
+                        "stack_plan: the attention layer whose K/V the "
+                        "'cross' positions share is ONE full layer (the "
+                        "last 'attn' position of a segment that runs once)"
+                    )
+            s = self.ssm
             if (
                 self.n_passes > 1 or self.exit_gate or self.mla is not None
                 or self.n_dense_layers or self.n_mtp_layers
                 or self.layer_pattern is not None
+                or self.sliding_window is not None
                 or self.abs_position_embedding or self.mlp_type == "moe"
                 or self.norm_branch_out or self.is_critic
             ):
                 raise ValueError(
-                    "state-space layers: a dense model of one pass whose "
-                    "attention layers are alike; with a looped stack, latent "
-                    "attention, a router, layer kinds, learned positions, "
-                    "branch norms or a value head it is not supported"
+                    "stack_plan: a dense model of one pass; with a looped "
+                    "stack, latent attention, a router, learned positions, "
+                    "branch norms or a value head it is not supported, and "
+                    "its attention layers' windows are named in the plan, "
+                    "not by layer_pattern or sliding_window"
                 )
-            if s.n_heads % s.n_groups:
+            if s.n_heads % s.n_groups or (s.selective and (
+                    s.n_heads != 1 or s.n_groups != 1 or s.proj_bias)):
                 raise ValueError(
                     f"ssm: n_groups={s.n_groups} does not divide "
-                    f"n_heads={s.n_heads}"
+                    f"n_heads={s.n_heads}, or a selective scan (dt_rank) "
+                    "with more than one head of d_inner channels, or with "
+                    "projection biases"
                 )
             if s.state_dtype != "float32":
                 raise ValueError(
@@ -477,6 +652,23 @@ class ModelConfig:
                     "state is float32 (a 16-bit state is another "
                     "configuration, not supported)"
                 )
+        if self.diff_attn and self.softmax_scale is None:
+            # the packed rows are twice a head wide: the scale is the
+            # published head's, said once here for every attention path
+            object.__setattr__(self, "softmax_scale", self.head_dim ** -0.5)
+        if self.diff_attn and (
+            self.kv_heads_per_row != 2 or self.n_q_heads % 2
+            or self.n_q_heads // 2 % (self.n_kv_heads // 2)
+            or self.mla is not None or self.cca is not None
+            or self.qk_layernorm or self.n_passes > 1
+            or self.attn_logits_soft_cap is not None
+        ):
+            raise ValueError(
+                "diff_attn: pairs of query heads over pairs of kv heads of "
+                "at most 64 (two to a 128-lane row); with latent or "
+                "convolved attention, q/k norms, a soft cap or a looped "
+                "stack it is not supported"
+            )
         if (self.n_passes > 1 or self.exit_gate) and (
             self.mla is not None or self.n_dense_layers or self.n_mtp_layers
             or self.layer_pattern is not None

@@ -2,7 +2,8 @@
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
-joyai_llm_flash, smallthinker, ouro and granitemoehybrid are added here) consumed by
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid and phi4flash are added
+here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -724,7 +725,7 @@ def _granite_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
             conv_bias=bool(hf.get("mamba_conv_bias", True)),
             proj_bias=bool(hf.get("mamba_proj_bias", False)),
         ),
-        mixer_pattern=tuple(mixers[:period]),
+        stack_plan=((L // period, tuple(mixers[:period])),),
     )
 
 
@@ -787,20 +788,13 @@ _GRANITE_MIXER = (
 )
 
 
-def _granite_layer_ids(cfg: ModelConfig) -> Dict[str, List[int]]:
-    return {
-        kind: [i for i, m in enumerate(cfg.mixers) if m == kind]
-        for kind in ("attn", "ssm")
-    }
-
-
 def _granite_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
     """A stack a kind of layer, each in the order its layers run. The
     published MLP holds gate and up in ONE matrix (``shared_mlp.
     input_linear``, gate first); the convolution's weight is ``[channels,
     1, taps]``, ours ``[taps, channels]``."""
     F = cfg.intermediate_dim
-    ids = _granite_layer_ids(cfg)
+    ids = cfg.layer_ids
 
     def get(i, name, transpose=False):
         m = np.asarray(sd[f"model.layers.{i}.{name}"])
@@ -871,7 +865,7 @@ def _granite_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
     }
     if not cfg.tied_embedding:
         sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
-    ids = _granite_layer_ids(cfg)
+    ids = cfg.layer_ids
     for kind, tree in (("attn", "layers"), ("ssm", "ssm_layers")):
         lp = params[tree]
         for at, i in enumerate(ids[kind]):
@@ -918,6 +912,271 @@ register_hf_family(
         config_to_hf=_granite_config_to_hf,
         params_from_hf=_granite_params_from_hf,
         params_to_hf=_granite_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# phi4flash (Phi-4-mini-flash-reasoning: a decoder-hybrid-decoder. Mamba-1
+# layers beside window layers, one full layer whose K/V the cross layers of
+# the second half share, gated memory units fed by the last Mamba layer,
+# differential attention throughout; no positions, LayerNorms with bias)
+# --------------------------------------------------------------------------- #
+
+# what the published config leaves to its class's defaults
+_PHI4FLASH_DEFAULTS = {
+    "mamba_d_state": 16, "mamba_d_conv": 4, "mamba_expand": 2,
+    "mamba_dt_rank": "auto", "mamba_conv_bias": True,
+    "mamba_proj_bias": False,
+}
+
+
+def _phi4flash_plan(L: int, every: int, window: Optional[int]):
+    """The published layout as a stack plan: a state-space layer every
+    ``every`` layers from layer 0 up to layer ``L / 2``; attention between
+    them, inside ``window`` below ``L / 2``, FULL at ``L / 2 + 1``; gated
+    memory units and cross attention from ``L / 2 + 2``."""
+    if every != 2 or L % 4 or L < 8:
+        raise ValueError(
+            "phi4flash: mb_per_layer must be 2 and num_hidden_layers a "
+            f"multiple of 4, at least 8 (got {every}, {L})"
+        )
+    return (
+        (L // 4, ("ssm", ("attn", window))),
+        (1, ("ssm", ("attn", None))),
+        (L // 4 - 1, ("gmu", "cross")),
+    )
+
+
+def _phi4flash_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    for key, want in (("mlp_bias", False), ("lm_head_bias", False),
+                      ("hidden_act", "silu")):
+        if hf.get(key, want) != want:
+            raise ValueError(f"phi4flash: {key}={hf[key]!r} is not supported")
+    if not hf.get("sliding_window"):
+        raise ValueError("phi4flash: sliding_window must be given")
+    opt = {**_PHI4FLASH_DEFAULTS, **{
+        k: hf[k] for k in _PHI4FLASH_DEFAULTS if k in hf}}
+    if opt["mamba_proj_bias"]:
+        raise ValueError("phi4flash: mamba_proj_bias is not supported")
+    E, Hq = hf["hidden_size"], hf["num_attention_heads"]
+    rank = opt["mamba_dt_rank"]
+    return ModelConfig(
+        n_layers=hf["num_hidden_layers"],
+        n_q_heads=Hq,
+        n_kv_heads=hf.get("num_key_value_heads") or Hq,
+        head_dim=E // Hq,
+        hidden_dim=E,
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 262144),
+        layer_norm_type="layer",
+        layer_norm_epsilon=hf.get("layer_norm_eps", 1e-5),
+        use_attention_bias=True,
+        use_attn_proj_bias=True,
+        apply_rotary=False,
+        activation_function="silu",
+        tied_embedding=bool(hf.get("tie_word_embeddings", True)),
+        embd_pdrop=float(hf.get("embd_pdrop", 0.0)),
+        resid_pdrop=float(hf.get("resid_pdrop", 0.0)),
+        diff_attn=True,
+        ssm=SSMConfig(
+            n_heads=1,
+            head_dim=opt["mamba_expand"] * E,
+            d_state=opt["mamba_d_state"],
+            d_conv=opt["mamba_d_conv"],
+            conv_bias=bool(opt["mamba_conv_bias"]),
+            dt_rank=-(-E // 16) if rank == "auto" else int(rank),
+        ),
+        stack_plan=_phi4flash_plan(
+            hf["num_hidden_layers"], hf.get("mb_per_layer", 2),
+            int(hf["sliding_window"])),
+    )
+
+
+def _phi4flash_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    s = cfg.ssm
+    window = next(w for w, _ in cfg.layer_kinds if w is not None)
+    return {
+        "model_type": "phi4flash",
+        "architectures": ["Phi4FlashForCausalLM"],
+        "embd_pdrop": cfg.embd_pdrop,
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "layer_norm_eps": cfg.layer_norm_epsilon,
+        "max_position_embeddings": cfg.n_positions,
+        "mb_per_layer": 2,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "resid_pdrop": cfg.resid_pdrop,
+        "sliding_window": window,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "mlp_bias": False,
+        "lm_head_bias": False,
+        "vocab_size": cfg.vocab_size,
+        "mamba_d_state": s.d_state,
+        "mamba_d_conv": s.d_conv,
+        "mamba_expand": s.d_inner // cfg.hidden_dim,
+        "mamba_dt_rank": s.dt_rank,
+        "mamba_conv_bias": s.conv_bias,
+        "mamba_proj_bias": False,
+    }
+
+
+# (ours under ``attn``, the published name under ``model.layers.{i}.attn.``)
+_PHI4FLASH_DIFF = (
+    ("subln", "subln.weight"), ("lam_q1", "lambda_q1"),
+    ("lam_k1", "lambda_k1"), ("lam_q2", "lambda_q2"), ("lam_k2", "lambda_k2"),
+)
+# (ours under ``ssm``, the published name, transposed)
+_PHI4FLASH_MIXER = (
+    ("w_xproj", "x_proj.weight", True), ("w_dt", "dt_proj.weight", True),
+    ("dt_bias", "dt_proj.bias", False), ("A_log", "A_log", True),
+    ("D", "D", False), ("w_out", "out_proj.weight", True),
+    ("conv_b", "conv1d.bias", False),
+)
+
+
+def _phi4flash_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    """A stack a kind of layer, each in the order its layers run. The
+    published ``Wqkv`` is ``[q ; k ; v]`` in one matrix (a cross layer's
+    holds ``q`` alone), ``mlp.fc1`` ``[gate ; up]``, the Mamba ``in_proj``
+    ``[x ; z]``; ``A_log`` is ``[channels, state]``, ours ``[state,
+    channels]``; the convolution's weight ``[channels, 1, taps]``, ours
+    ``[taps, channels]``."""
+    F, ids = cfg.intermediate_dim, cfg.layer_ids
+    nq = cfg.n_q_heads * cfg.head_dim
+    nkv = cfg.n_kv_heads * cfg.head_dim
+    C = cfg.ssm.d_inner
+
+    def stack(kind, name, transpose=False, fn=None):
+        out = []
+        for i in ids[kind]:
+            m = np.asarray(sd[f"model.layers.{i}.{name}"])
+            m = m.T if transpose else m
+            out.append(fn(m) if fn else m)
+        return np.stack(out)
+
+    def block(kind, name, mixer):
+        return {
+            "ln1": {"weight": stack(kind, "input_layernorm.weight"),
+                    "bias": stack(kind, "input_layernorm.bias")},
+            "ln2": {"weight": stack(kind, "post_attention_layernorm.weight"),
+                    "bias": stack(kind, "post_attention_layernorm.bias")},
+            "mlp": {
+                "w_gate": stack(kind, "mlp.fc1.weight", True,
+                                lambda m: m[:, :F]),
+                "w_up": stack(kind, "mlp.fc1.weight", True,
+                              lambda m: m[:, F:]),
+                "w_down": stack(kind, "mlp.fc2.weight", True),
+            },
+            name: mixer,
+        }
+
+    def attention(kind, parts):
+        a = {"wo": stack(kind, "attn.out_proj.weight", True),
+             "bo": stack(kind, "attn.out_proj.bias")}
+        for w, b, lo, hi in parts:
+            a[w] = stack(kind, "attn.Wqkv.weight", True,
+                         lambda m, lo=lo, hi=hi: m[:, lo:hi])
+            a[b] = stack(kind, "attn.Wqkv.bias",
+                         fn=lambda m, lo=lo, hi=hi: m[lo:hi])
+        for ours, theirs in _PHI4FLASH_DIFF:
+            a[ours] = stack(kind, "attn." + theirs)
+        return a
+
+    mixer = {
+        ours: stack("ssm", "attn." + theirs, t)
+        for ours, theirs, t in _PHI4FLASH_MIXER
+        if ours != "conv_b" or cfg.ssm.conv_bias
+    }
+    mixer["conv_w"] = stack(
+        "ssm", "attn.conv1d.weight", fn=lambda m: m[:, 0, :].T)
+    mixer["w_x"] = stack("ssm", "attn.in_proj.weight", True, lambda m: m[:, :C])
+    mixer["w_z"] = stack("ssm", "attn.in_proj.weight", True, lambda m: m[:, C:])
+    params: Dict[str, Any] = {
+        "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
+        "layers": block("attn", "attn", attention("attn", (
+            ("wq", "bq", 0, nq), ("wk", "bk", nq, nq + nkv),
+            ("wv", "bv", nq + nkv, nq + 2 * nkv)))),
+        "ssm_layers": block("ssm", "ssm", mixer),
+        "gmu_layers": block("gmu", "gmu", {
+            "w_in": stack("gmu", "attn.in_proj.weight", True),
+            "w_out": stack("gmu", "attn.out_proj.weight", True)}),
+        "cross_layers": block(
+            "cross", "attn", attention("cross", (("wq", "bq", 0, nq),))),
+        "final_ln": {
+            "weight": np.asarray(sd["model.final_layernorm.weight"]),
+            "bias": np.asarray(sd["model.final_layernorm.bias"])},
+    }
+    if not cfg.tied_embedding:
+        params["head"] = {"weight": np.asarray(sd["lm_head.weight"]).T}
+    return params
+
+
+def _phi4flash_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    sd: HFState = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
+        "model.final_layernorm.weight": np.asarray(params["final_ln"]["weight"]),
+        "model.final_layernorm.bias": np.asarray(params["final_ln"]["bias"]),
+    }
+    if not cfg.tied_embedding:
+        sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
+    trees = {"attn": "layers", "ssm": "ssm_layers", "gmu": "gmu_layers",
+             "cross": "cross_layers"}
+    for kind, layer_ids in cfg.layer_ids.items():
+        lp = params[trees[kind]]
+        for at, i in enumerate(layer_ids):
+            p = f"model.layers.{i}."
+
+            def put(name, value, transpose=False):
+                m = np.asarray(value[at])
+                sd[p + name] = m.T if transpose else m
+
+            for ours, theirs in (("ln1", "input_layernorm"),
+                                 ("ln2", "post_attention_layernorm")):
+                put(theirs + ".weight", lp[ours]["weight"])
+                put(theirs + ".bias", lp[ours]["bias"])
+            m = lp["mlp"]
+            sd[p + "mlp.fc1.weight"] = np.concatenate(
+                [np.asarray(m["w_gate"][at]).T, np.asarray(m["w_up"][at]).T])
+            put("mlp.fc2.weight", m["w_down"], True)
+            if kind in ("attn", "cross"):
+                a = lp["attn"]
+                names = ("q", "k", "v") if kind == "attn" else ("q",)
+                sd[p + "attn.Wqkv.weight"] = np.concatenate(
+                    [np.asarray(a["w" + n][at]).T for n in names])
+                sd[p + "attn.Wqkv.bias"] = np.concatenate(
+                    [np.asarray(a["b" + n][at]) for n in names])
+                put("attn.out_proj.weight", a["wo"], True)
+                put("attn.out_proj.bias", a["bo"])
+                for ours, theirs in _PHI4FLASH_DIFF:
+                    put("attn." + theirs, a[ours])
+            elif kind == "gmu":
+                put("attn.in_proj.weight", lp["gmu"]["w_in"], True)
+                put("attn.out_proj.weight", lp["gmu"]["w_out"], True)
+            else:
+                x = lp["ssm"]
+                for ours, theirs, t in _PHI4FLASH_MIXER:
+                    if ours in x:
+                        put("attn." + theirs, x[ours], t)
+                sd[p + "attn.in_proj.weight"] = np.concatenate(
+                    [np.asarray(x["w_x"][at]).T, np.asarray(x["w_z"][at]).T])
+                sd[p + "attn.conv1d.weight"] = np.ascontiguousarray(
+                    np.asarray(x["conv_w"][at]).T[:, None, :])
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="phi4flash",
+        hf_model_type="phi4flash",
+        config_from_hf=_phi4flash_config_from_hf,
+        config_to_hf=_phi4flash_config_to_hf,
+        params_from_hf=_phi4flash_params_from_hf,
+        params_to_hf=_phi4flash_params_to_hf,
     )
 )
 

@@ -95,6 +95,15 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         full = cfg.qk_norm_full
         attn["q_norm"] = jnp.ones((L, Hq * D if full else D), dtype)
         attn["k_norm"] = jnp.ones((L, Hkv * D if full else D), dtype)
+
+    def diff_params(n):
+        # a differential pair's norm gain over the value row and the four
+        # vectors of its ``lambda`` (:func:`_diff_combine`), normal(0, 0.1)
+        # as published
+        return {
+            "subln": jnp.ones((n, 2 * D), dtype),
+            **{name: w((n, D)) * (0.1 / std) for name in _LAMBDAS},
+        }
     if cfg.cca is not None:
         # the two convolutions over [q ; k] (``ops/cca.py``): taps first,
         # the newest last; the second is one D x D block a head, input-
@@ -177,7 +186,43 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         # carried, never read by a forward (``ModelConfig.exit_gate``)
         params["exit_gate"] = {
             "weight": w((E, 1)), "bias": jnp.zeros((1,), dtype)}
-    if cfg.ssm is not None:
+    def block(n, name, mixer):
+        # a layer of another mixer: its own norms and the dense MLP
+        return {
+            "ln1": ln(has_ln_bias, n),
+            name: mixer,
+            "ln2": ln(has_ln_bias, n),
+            "mlp": {
+                "w_gate": w((n, E, F)),
+                "w_up": w((n, E, F)),
+                "w_down": w((n, F, E)),
+            },
+        }
+
+    if cfg.ssm is not None and cfg.ssm.selective:
+        # Mamba-1 (``ops/ssm.py``): ``A_log = log(1..N)`` a channel (the
+        # published S4D-real initialisation), kept ``[N, C]``
+        s, Ls = cfg.ssm, cfg.n_ssm_layers
+        C_, N_ = s.d_inner, s.d_state
+        dt0 = jnp.exp(jax.random.uniform(
+            next(rngs), (Ls, C_), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        mixer = {
+            "w_x": w((Ls, E, C_)),
+            "w_z": w((Ls, E, C_)),
+            "conv_w": w((Ls, s.d_conv, C_)),
+            "w_xproj": w((Ls, C_, s.dt_rank + 2 * N_)),
+            "w_dt": w((Ls, s.dt_rank, C_)) * (s.dt_rank ** -0.5 / std),
+            "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+            "A_log": jnp.broadcast_to(
+                jnp.log(jnp.arange(1, N_ + 1, dtype=jnp.float32))[:, None],
+                (Ls, N_, C_)).astype(dtype),
+            "D": jnp.ones((Ls, C_), dtype),
+            "w_out": w((Ls, C_, E)),
+        }
+        if s.conv_bias:
+            mixer["conv_b"] = jnp.zeros((Ls, C_), dtype)
+        params["ssm_layers"] = block(Ls, "ssm", mixer)
+    elif cfg.ssm is not None:
         # the state-space layers, a stack of their own (another SHAPE than
         # an attention layer); ``A``, ``dt`` and ``D`` start in the
         # published initialisation's ranges, not at normal(0.02), which
@@ -215,6 +260,25 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
                 "w_down": w((Ls, F, E)),
             },
         }
+    if cfg.diff_attn:
+        attn.update(diff_params(L))
+    n_gmu, n_cross = cfg.n_mixers("gmu"), cfg.n_mixers("cross")
+    if n_gmu:
+        params["gmu_layers"] = block(n_gmu, "gmu", {
+            "w_in": w((n_gmu, E, cfg.ssm.d_inner)),
+            "w_out": w((n_gmu, cfg.ssm.d_inner, E)),
+        })
+    if n_cross:
+        # queries and the output projection only: keys and values are
+        # another layer's
+        cross = {"wq": w((n_cross, E, Hq * D)), "wo": w((n_cross, Hq * D, E))}
+        if cfg.use_attention_bias:
+            cross["bq"] = jnp.zeros((n_cross, Hq * D), dtype)
+        if cfg.use_attn_proj_bias:
+            cross["bo"] = jnp.zeros((n_cross, E), dtype)
+        if cfg.diff_attn:
+            cross.update(diff_params(n_cross))
+        params["cross_layers"] = block(n_cross, "attn", cross)
     if cfg.abs_position_embedding:
         params["pos_embed"] = {"weight": w((cfg.n_positions, E))}
     if cfg.is_critic:
@@ -414,7 +478,28 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                 k: ("layer", "embed") for k in ("a_r", "b_r", "a_h", "b_h")}
     if cfg.exit_gate:
         axes["exit_gate"] = {"weight": ("embed", None), "bias": (None,)}
-    if cfg.ssm is not None:
+    dense_mlp = {
+        "w_gate": ("layer", "embed", "mlp"),
+        "w_up": ("layer", "embed", "mlp"),
+        "w_down": ("layer", "mlp", "embed"),
+    }
+    if cfg.diff_attn:
+        diff_axes = {k: ("layer", None) for k in ("subln", *_LAMBDAS)}
+        attn.update(diff_axes)
+    if cfg.ssm is not None and cfg.ssm.selective:
+        # (no tensor-parallel split: as below)
+        mixer = {
+            "w_x": ("layer", "embed", None), "w_z": ("layer", "embed", None),
+            "conv_w": ("layer", None, None),
+            "w_xproj": ("layer", None, None), "w_dt": ("layer", None, None),
+            "dt_bias": ("layer", None), "A_log": ("layer", None, None),
+            "D": ("layer", None), "w_out": ("layer", None, "embed"),
+        }
+        if cfg.ssm.conv_bias:
+            mixer["conv_b"] = ("layer", None)
+        axes["ssm_layers"] = {
+            "ln1": ln(), "ssm": mixer, "ln2": ln(), "mlp": dense_mlp}
+    elif cfg.ssm is not None:
         # no tensor-parallel split of the mixer (its heads, the
         # convolution's channels and the state would all have to follow
         # one; the engine refuses a mesh for this family)
@@ -442,6 +527,23 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                 "w_down": ("layer", "mlp", "embed"),
             },
         }
+    if cfg.n_mixers("gmu"):
+        axes["gmu_layers"] = {
+            "ln1": ln(), "ln2": ln(), "mlp": dense_mlp,
+            "gmu": {"w_in": ("layer", "embed", None),
+                    "w_out": ("layer", None, "embed")},
+        }
+    if cfg.n_mixers("cross"):
+        cross = {"wq": ("layer", "embed", "heads"),
+                 "wo": ("layer", "heads", "embed")}
+        if cfg.use_attention_bias:
+            cross["bq"] = ("layer", "heads")
+        if cfg.use_attn_proj_bias:
+            cross["bo"] = ("layer", "embed")
+        if cfg.diff_attn:
+            cross.update(diff_axes)
+        axes["cross_layers"] = {
+            "ln1": ln(), "attn": cross, "ln2": ln(), "mlp": dense_mlp}
     if cfg.abs_position_embedding:
         axes["pos_embed"] = {"weight": (None, "embed")}
     if cfg.is_critic:
@@ -465,8 +567,12 @@ def _norm(cfg: ModelConfig, p, x):
 
 
 def _cast(cfg: ModelConfig, p):
+    """The weights in the serving dtype (an integer leaf, a layer's place
+    in the model that :func:`_scan_plan` hands it, stays as it is)."""
     dt = jnp.dtype(cfg.dtype)
-    return jax.tree.map(lambda x: x.astype(dt), p)
+    return jax.tree.map(
+        lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        p)
 
 
 def _qkv(cfg: ModelConfig, p, x):
@@ -728,6 +834,13 @@ def _mlp(cfg: ModelConfig, p, x, layer_in=None, routed=None,
     return res if len(res) == 4 else (*res, None)
 
 
+def _attn_params(lp):
+    """A layer's attention weights, with the layer's place in the model
+    where :func:`_scan_plan` handed it (differential attention)."""
+    p = lp["attn"]
+    return {**p, "index": lp["index"]} if "index" in lp else p
+
+
 def _attn_out(p, ctx):
     """ctx: [..., H, D] -> [..., E]. Where a value head is narrower than a
     key head (latent attention in its expanded form pads ``v`` with zeros
@@ -920,96 +1033,215 @@ def _scan_passes(cfg: ModelConfig, layer, carry, params: Params, xs=(),
     )
 
 
-def _scan_mixers(cfg: ModelConfig, attn_layer, ssm_layer, carry,
-                 params: Params, attn_xs=(), ssm_xs=(), unroll=1):
-    """:func:`_scan_periods` of a model whose layers differ in weight
-    SHAPE (``cfg.mixer_pattern``: state-space and attention layers): a
-    stack a kind, ``params["layers"]`` the attention layers and
-    ``params["ssm_layers"]`` the state-space layers, each in the order its
-    layers run. ONE scan runs over the periods; its body runs the
-    period's positions in order and cuts position ``j``'s weights (and
-    its slice of ``attn_xs`` / ``ssm_xs``, arrays over the layers of that
-    kind) from the stack of its kind by its index IN that stack, one
-    layer at a time, as a plain scan over one stack does (no copy of a
-    period's weights: :func:`_scan_periods` says what that cost). The
-    period is taken as its RUNS of one kind (five state-space layers, the
-    attention layer, four more), each a scan unrolled in full: the layer
-    is traced once a run, not once a position (three traces for ten: 49 s
-    of 67 s of tracing and lowering at the start of a 40-layer model's
-    engine; PERF.md section 6, PR 41), and the compiler still gets the
-    period as straight-line code.
-    ``attn_layer`` is one function or a list of one. Returns ``(carry,
-    (ys_attn, ys_ssm))``, each stacked over the layers of its kind."""
-    if isinstance(attn_layer, (list, tuple)):
-        (attn_layer,) = attn_layer
-    pat = cfg.mixer_pattern
-    per = {"attn": pat.count("attn"), "ssm": pat.count("ssm")}
-    fns = {"attn": attn_layer, "ssm": ssm_layer}
-    stacks = {
-        "attn": (params["layers"], *attn_xs) if attn_xs else params["layers"],
-        "ssm": (
-            (params["ssm_layers"], *ssm_xs) if ssm_xs
-            else params["ssm_layers"]),
-    }
+# the weight stack of each mixer kind (``ModelConfig.stack_plan``)
+_STACKS = {"attn": "layers", "ssm": "ssm_layers", "gmu": "gmu_layers",
+           "cross": "cross_layers"}
 
-    # the period as runs of one kind: ("ssm", 5), ("attn", 1), ("ssm", 4)
-    runs = [(k, len(list(g))) for k, g in itertools.groupby(pat)]
 
-    def body(carry, period):
-        ys = {"attn": [], "ssm": []}
-        done = {"attn": 0, "ssm": 0}
-        for kind, n in runs:
-            first = period * per[kind] + done[kind]
-            done[kind] += n
+def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
+               unroll=1, writers_only: bool = False):
+    """:func:`_scan_periods` of a model whose layers differ in weight SHAPE
+    (``cfg.stack_plan``: state-space, attention, gated-memory and
+    cross-attention layers): a stack a kind (``_STACKS``), each in the
+    order its layers run, and ONE scan a SEGMENT of the plan over its
+    repeats (a segment that runs once is its body, no scan). The body runs
+    the period's positions in order and cuts each position's weights (and
+    its slice of ``xs[kind]``, arrays over the layers of that kind) from
+    the stack of its kind by its index IN that stack, one layer at a time,
+    as a plain scan over one stack does (no copy of a period's weights:
+    :func:`_scan_periods` says what that cost). The period is taken as its
+    RUNS of alike positions (five state-space layers, the attention layer,
+    four more), each a scan unrolled in full: the layer is traced once a
+    run, not once a position (three traces for ten: 49 s of 67 s of
+    tracing and lowering at the start of a 40-layer model's engine;
+    PERF.md section 6, PR 41), and the compiler still gets the period as
+    straight-line code.
 
-            def layer(carry, k, kind=kind, first=first):
-                inp = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, first + k, 0, keepdims=False),
-                    stacks[kind],
-                )
-                return fns[kind](carry, inp)
+    ``fns[kind](position)`` is the layer function ``(carry, inp) -> (carry,
+    y)`` of a :class:`StackPosition` of that kind. What later layers read
+    of earlier ones (the memory, the shared K/V, a running cache layer)
+    rides ``carry``, which crosses the segments. Under differential
+    attention a layer's slice also holds ``"index"``, its place in the
+    model (what ``lambda``'s constant follows). ``writers_only``: stop
+    after the last segment that writes a cache or a state (admission keeps
+    nothing of what the readers behind it compute). Returns ``(carry,
+    ys)``, ``ys[kind]`` stacked over the layers of that kind that ran."""
+    xs = xs or {}
+    ids = cfg.layer_ids
+    stacks = {}
+    for kind, tree in _STACKS.items():
+        if not ids[kind]:
+            continue
+        stack = params[tree]
+        if cfg.diff_attn:
+            stack = {**stack, "index": jnp.asarray(ids[kind], jnp.int32)}
+        stacks[kind] = (stack, *xs[kind]) if xs.get(kind) else stack
+    plan = cfg.plan
+    if writers_only:
+        last = max(
+            i for i, (_, period) in enumerate(plan)
+            if any(pos.mixer in ("ssm", "attn") for pos in period))
+        plan = plan[: last + 1]
+    base = dict.fromkeys(_STACKS, 0)    # layers of each kind so far
+    outs = {kind: [] for kind in _STACKS}
+    for reps, period in plan:
+        per = {k: sum(pos.mixer == k for pos in period) for k in _STACKS}
+        # ("ssm", 5), ("attn", 1), ("ssm", 4)
+        runs = [(pos, len(list(g))) for pos, g in itertools.groupby(period)]
 
-            carry, y = jax.lax.scan(
-                layer, carry, jnp.arange(n, dtype=jnp.int32), unroll=n)
-            ys[kind].append(y)
-        return carry, tuple(
-            jax.tree.map(lambda *a: jnp.concatenate(a), *ys[kind])
-            for kind in ("attn", "ssm"))
+        def body(carry, rep, per=per, runs=runs, base=dict(base)):
+            ys = {k: [] for k in per if per[k]}
+            done = dict.fromkeys(per, 0)
+            for pos, n in runs:
+                kind = pos.mixer
+                first = rep * per[kind] + (base[kind] + done[kind])
+                done[kind] += n
+                fn = fns[kind](pos)
 
-    carry, ys = jax.lax.scan(
-        body, carry,
-        jnp.arange(cfg.n_layers // len(pat), dtype=jnp.int32), unroll=unroll,
-    )
-    return carry, jax.tree.map(
-        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys
-    )
+                def layer(carry, k, kind=kind, first=first, fn=fn):
+                    inp = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, first + k, 0, keepdims=False),
+                        stacks[kind],
+                    )
+                    return fn(carry, inp)
+
+                carry, y = jax.lax.scan(
+                    layer, carry, jnp.arange(n, dtype=jnp.int32), unroll=n)
+                ys[kind].append(y)
+            return carry, {
+                kind: jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+                for kind, parts in ys.items()}
+
+        if reps == 1 and len(plan) > 1:
+            carry, ys = body(carry, 0)
+        else:
+            carry, ys = jax.lax.scan(
+                body, carry, jnp.arange(reps, dtype=jnp.int32), unroll=unroll)
+            ys = jax.tree.map(
+                lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), ys)
+        for kind, y in ys.items():
+            outs[kind].append(y)
+            base[kind] += reps * per[kind]
+    return carry, {
+        kind: jax.tree.map(lambda *a: jnp.concatenate(a), *parts)
+        if len(parts) > 1 else parts[0]
+        for kind, parts in outs.items() if parts}
 
 
 def _run_stack(cfg: ModelConfig, layer, carry, params: Params, xs=(),
-               unroll=1, ssm_layer=None, ssm_xs=()):
+               unroll=1, fns=None, plan_xs=None, writers_only=False):
     """What every forward runs its layers through: :func:`_scan_passes`,
-    or :func:`_scan_mixers` for a model with state-space layers. Returns
-    ``(carry, ys, ys_ssm)``: the attention layers' stacked results and the
-    state-space layers' (None for a model without them)."""
-    if cfg.ssm is None:
+    or :func:`_scan_plan` for a model with a stack plan (``fns``,
+    ``plan_xs``, ``writers_only``: its arguments). Returns ``(carry, ys,
+    ys_ssm)``: the attention layers' stacked results and the state-space
+    layers' (None for a model without them)."""
+    if cfg.plan is None:
         carry, ys = _scan_passes(cfg, layer, carry, params, xs, unroll)
         return carry, ys, None
-    carry, (ys, ys_ssm) = _scan_mixers(
-        cfg, layer, ssm_layer, carry, params, xs, ssm_xs, unroll)
-    return carry, ys, ys_ssm
+    carry, ys = _scan_plan(
+        cfg, fns, carry, params, plan_xs, unroll, writers_only)
+    return carry, ys["attn"], ys["ssm"]
 
 
 def _ssm_block(cfg: ModelConfig, lp, x, mixer):
     """One state-space layer: ``x + mixer(norm(x))``, then the MLP as in
-    an attention layer. ``mixer(p, h)`` returns ``(out, state)``."""
+    an attention layer. ``mixer(p, h)`` returns ``(out, state)``, and where
+    the model has gated memory units a third, the memory (``ops/ssm.py``).
+    Returns ``(x, state, memory or None)``."""
     h = _norm(cfg, lp["ln1"], x)
-    out, st = mixer(lp["ssm"], h)
+    out, st, *mem = mixer(lp["ssm"], h)
     x = _add_branch(cfg, lp, "attn_out_ln", x, out.astype(x.dtype))
     x = _add_branch(
         cfg, lp, "mlp_out_ln", x,
         _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
-    return x, st
+    return x, st, (mem[0] if mem else None)
+
+
+def _gmu_block(cfg: ModelConfig, lp, x, memory):
+    """One gated memory unit: ``x + W_out (silu(W_in norm(x)) * M)``, ``M``
+    the last state-space layer's scan output at the same positions (float32
+    ``[..., d_inner]``), then the MLP."""
+    lp = _cast(cfg, lp)
+    h = _norm(cfg, lp["ln1"], x)
+    with jax.named_scope("gmu"):
+        gate = jax.nn.silu((h @ lp["gmu"]["w_in"]).astype(jnp.float32))
+        out = (gate * memory).astype(x.dtype) @ lp["gmu"]["w_out"]
+    x = _add_branch(cfg, lp, "attn_out_ln", x, out)
+    return _add_branch(
+        cfg, lp, "mlp_out_ln", x,
+        _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
+
+
+def _plan_fns(attn, ssm_layer, gmu_layer):
+    """:func:`_scan_plan`'s ``fns`` of a forward: ``attn(position)`` makes
+    the layer of an "attn" or a "cross" position; the state-space layer and
+    the gated memory unit are one function each wherever they stand."""
+    return {"attn": attn, "cross": attn,
+            "ssm": lambda pos: ssm_layer, "gmu": lambda pos: gmu_layer}
+
+
+def admission_layers(cfg: ModelConfig) -> int:
+    """Layers that admission's chunks run over a prefilled position
+    (:func:`_extend_layers`): all of them, passes counted, or under a
+    stack plan those up to the last that writes a cache or a state."""
+    if cfg.plan is None:
+        return cfg.n_layers * cfg.n_passes
+    mixers = cfg.mixers
+    return max(i for i, m in enumerate(mixers) if m in ("ssm", "attn")) + 1
+
+
+def _readers(cfg: ModelConfig) -> bool:
+    """The plan has layers that read what earlier layers computed (gated
+    memory units, cross attention): every forward's carry then holds the
+    memory and the shared K/V (:func:`_shared0`)."""
+    return any(m in ("gmu", "cross") for m in cfg.mixers)
+
+
+def _shared0(cfg: ModelConfig, lead, dtype):
+    """What the readers read before any layer has written it: ``(memory
+    [*lead, d_inner] float32, k, v [*lead, rows, width])``, zeros; ``()``
+    for a model without readers."""
+    if not _readers(cfg):
+        return ()
+    _, heads, width = kv_page_geometry(cfg)
+    kv = jnp.zeros((*lead, heads, width), dtype)
+    return (jnp.zeros((*lead, cfg.ssm.d_inner), jnp.float32), kv, kv)
+
+
+def _pool_of(cfg: ModelConfig, table, li, j: int = 0, pos=None):
+    """Where a layer's pages are: ``(index on the pool's leading axis, page
+    table [B, M])``. Without a plan ``li`` is the running period and ``j``
+    the layer's (static) position in it. Under a plan ``li`` is the
+    running CACHE LAYER (the "attn" layers in the order they run): cache
+    layer ``c`` is position ``c % period`` of period ``c // period``, a
+    cross layer reads its ``source``'s."""
+    if pos is None:
+        return li, _kind_table(table, j)
+    p = cfg.period
+    if pos.mixer == "cross":
+        return pos.source // p, _kind_table(table, pos.source % p)
+    if p == 1:
+        return li, table
+    return li // p, jax.lax.dynamic_index_in_dim(table, li % p, 0, False)
+
+
+def _plan_scope(cfg: ModelConfig, pos):
+    """``jax.named_scope`` name of a plan position's attention where the
+    plan's attention layers differ (None: no scope, as ever)."""
+    if pos is None or (len(cfg.layer_kinds) == 1 and not _readers(cfg)):
+        return None
+    return "attn_cross" if pos.mixer == "cross" else _attn_scope(pos.window)
+
+
+def _q_only(cfg: ModelConfig, p, x, cos, sin):
+    """A cross-attention layer's queries ``[..., Hq, D]``: keys and values
+    are another layer's."""
+    q = x @ p["wq"]
+    if "bq" in p:
+        q = q + p["bq"]
+    q = q.reshape(*x.shape[:-1], cfg.n_q_heads, cfg.head_dim)
+    return apply_rotary(q, cos, sin) if cfg.apply_rotary else q
 
 
 # ``cfg.kv_heads_per_row`` kv heads side by side in ONE row of a cache: the
@@ -1021,12 +1253,21 @@ def _ssm_block(cfg: ModelConfig, lp, x, mixer):
 
 
 def _row_part(cfg: ModelConfig, dtype):
-    """``[Hq, r]`` one-hot: which part of its kv row a query head reads."""
-    part = (jnp.arange(cfg.n_q_heads) // cfg.n_rep) % cfg.kv_heads_per_row
+    """``[Hq, r]`` one-hot: which part of its kv row a query head reads.
+    Differential pairs: head ``h`` is half ``h % 2`` of pair ``h // 2``
+    (``q1`` the even heads, ``q2`` the odd), pair ``i`` reads kv pair ``i
+    // (pairs a kv pair)``, and a kv pair is one row ``[k1 ; k2]``: so head
+    ``h`` reads half ``h % 2`` of row ``h // (Hq / rows)``, the row the
+    kernels' grouping gives it anyway."""
+    if cfg.diff_attn:
+        part = jnp.arange(cfg.n_q_heads) % 2
+    else:
+        part = (jnp.arange(cfg.n_q_heads) // cfg.n_rep) % cfg.kv_heads_per_row
     return jax.nn.one_hot(part, cfg.kv_heads_per_row, dtype=dtype)
 
 
 def _pack_qkv(cfg: ModelConfig, q, k, v):
+    """``k``, ``v`` None: a cross layer's queries alone."""
     r = cfg.kv_heads_per_row
     if r == 1:
         return q, k, v
@@ -1034,18 +1275,63 @@ def _pack_qkv(cfg: ModelConfig, q, k, v):
     q = (q[..., None, :] * sel[:, :, None]).reshape(*q.shape[:-1], -1)
 
     def rows(a):
-        return a.reshape(*a.shape[:-2], a.shape[-2] // r, -1)
+        return None if a is None else a.reshape(
+            *a.shape[:-2], a.shape[-2] // r, -1)
 
     return q, rows(k), rows(v)
 
 
-def _unpack_ctx(cfg: ModelConfig, ctx):
+def _unpack_ctx(cfg: ModelConfig, ctx, p=None):
+    """The packed context back as the model's heads: of each head the part
+    of the value row that is its own kv head's; under differential
+    attention the pairs' combination (``p``: the layer's attention weights
+    and its ``"index"``), which keeps the whole row."""
     r = cfg.kv_heads_per_row
     if r == 1:
         return ctx
+    if cfg.diff_attn:
+        return _diff_combine(cfg, p, ctx)
     sel = _row_part(cfg, ctx.dtype)
     ctx = ctx.reshape(*ctx.shape[:-1], r, -1)
     return (ctx * sel[:, :, None]).sum(axis=-2)
+
+
+_LAMBDAS = ("lam_q1", "lam_k1", "lam_q2", "lam_k2")
+
+
+def diff_lambda_init(index):
+    """``lambda``'s constant of layer ``index`` (0-based), as published."""
+    return 0.8 - 0.6 * jnp.exp(-0.3 * jnp.asarray(index, jnp.float32))
+
+
+def _diff_combine(cfg: ModelConfig, p, ctx):
+    """Differential attention's second half. ``ctx [..., Hq, 2 D]``: head
+    ``2 i`` is ``a1 = softmax(q1 k1^T) [v1 ; v2]`` of pair ``i``, head ``2 i
+    + 1`` is ``a2 = softmax(q2 k2^T) [v1 ; v2]`` (what the packing of
+    :func:`_pack_qkv` computes). Returns ``(1 - lam0) rms(a1 - lam a2) w``,
+    ``[..., Hq / 2, 2 D]``, with ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    lam0`` and ``lam0`` :func:`diff_lambda_init` of the layer."""
+    f32 = jnp.float32
+    lam0 = diff_lambda_init(p["index"])
+    lq1, lk1, lq2, lk2 = (p[name].astype(f32) for name in _LAMBDAS)
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+    with jax.named_scope("diff_combine"):
+        a = ctx.astype(f32)
+        d = a[..., 0::2, :] - lam * a[..., 1::2, :]
+        out = norms.rms_norm(d, p["subln"], cfg.layer_norm_epsilon)
+        return ((1.0 - lam0) * out).astype(ctx.dtype)
+
+
+def _dense_qkv(cfg: ModelConfig, q, k, v):
+    """What the forwards WITHOUT a page pool (the trainer's, the dense
+    cache's) attend with: the model's heads as they are, or under
+    differential attention the packed rows (:func:`_pack_qkv`), which is
+    how a pair's softmax comes to read the whole value row."""
+    return _pack_qkv(cfg, q, k, v) if cfg.diff_attn else (q, k, v)
+
+
+def _dense_ctx(cfg: ModelConfig, p, ctx):
+    return _diff_combine(cfg, p, ctx) if cfg.diff_attn else ctx
 
 
 # --------------------------------------------------------------------------- #
@@ -1153,7 +1439,7 @@ def forward_packed(
     # a router that reads the layer's input needs it after attention: the
     # norm is recomputed there (one RMSNorm) rather than carried across
     # the attention kernel, which the split checkpointing below cuts at
-    def _pre(x, lp, rotary):
+    def _pre(x, lp, rotary, cross=False):
         h = _norm(cfg, lp["ln1"], x)
         if cfg.cca is not None:
             # one row that holds every document: a token at position 0
@@ -1162,11 +1448,17 @@ def forward_packed(
                 cfg, lp["attn"], h[None], cos[None], sin[None],
                 positions[None])
             return q[0], k[0], v[0]
-        return _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
+        if cross:
+            return _pack_qkv(
+                cfg, _q_only(cfg, lp["attn"], h, cos, sin), None, None)
+        return _dense_qkv(
+            cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin, rotary))
 
     # ``r``: a stateful router's vector of the layer before (None for
     # every other model: the carry is then ``x`` alone, as ever)
     r0 = _router_state0(cfg, x)
+    # ... and where the plan has readers, the memory and the shared K/V
+    shared0 = _shared0(cfg, x.shape[:1], x.dtype)
 
     def _post(x, ctx, lp, r):
         layer_in = (
@@ -1174,6 +1466,7 @@ def forward_packed(
             if cfg.moe is not None and cfg.moe.router_on_layer_input
             else None
         )
+        ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx))
         h = _norm(cfg, lp["ln2"], x)
         m, aux, routing, r = _mlp(
@@ -1187,16 +1480,20 @@ def forward_packed(
     if policy not in ("dots_attn", "full", "dots", "none"):
         raise ValueError(f"unknown remat_policy {policy!r}")
 
-    def make_layer(kind):
+    def make_layer(kind, pos=None):
         window, rotary = kind
+        scope = (
+            _plan_scope(cfg, pos) if cfg.layer_pattern is None
+            else _attn_scope(window))
+        cross = pos is not None and pos.mixer == "cross"
 
         def attend(q, k, v):
-            if cfg.layer_pattern is None:
+            if scope is None:
                 return _attend(q, k, v, window)
-            with jax.named_scope(_attn_scope(window)):
+            with jax.named_scope(scope):
                 return _attend(q, k, v, window)
 
-        if policy == "dots_attn":
+        if policy == "dots_attn" and not shared0:
             # Split checkpointing that leaves the attention kernel OUTSIDE
             # the remat region: jax.checkpoint cannot save a custom_vjp's
             # residuals, so a whole-layer checkpoint re-runs the full flash
@@ -1229,31 +1526,62 @@ def forward_packed(
             q, k, v = _pre(x, lp, rotary)
             return _post(x, attend(q, k, v), lp, r)
 
+        def reader_layer(carry, lp):
+            # a plan with readers: the carry is ``(x, memory, k, v)``; a
+            # cross layer attends the shared K/V, the layer that exports
+            # them leaves its own there
+            x, mem, ks, vs = carry
+            lp = _cast(cfg, lp)
+            q, k, v = _pre(x, lp, rotary, cross)
+            if cross:
+                k, v = ks, vs
+            elif pos.exports:
+                ks, vs = k, v
+            x, y = _post(x, attend(q, k, v), lp, None)
+            return (x, mem, ks, vs), y
+
+        if shared0:
+            layer = reader_layer
         if policy == "full":
             return jax.checkpoint(layer, prevent_cse=False)
-        if policy == "dots":
+        if policy == "dots" or (policy == "dots_attn" and shared0):
             return jax.checkpoint(layer, policy=dots, prevent_cse=False)
         return layer
 
-    def ssm_layer(x, lp):
+    def ssm_layer(carry, lp):
         # one row that holds every document: a token at position 0 of its
         # own resets the state and the convolution
+        x, *shared = carry if shared0 else (carry,)
         lp = _cast(cfg, lp)
-        x, _ = _ssm_block(
+        x, _, mem = _ssm_block(
             cfg, lp, x[None],
-            lambda p, h: ssm_ops.mixer_chunk(cfg, p, h, positions[None]))
+            lambda p, h: ssm_ops.mixer_chunk(
+                cfg, p, h, positions[None], memory=bool(shared0)))
+        if shared0:
+            return (x[0], mem[0], *shared[1:]), None
         return x[0], None
+
+    def gmu_layer(carry, lp):
+        x, mem, *kv = carry
+        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
 
     if policy != "none":
         ssm_layer = jax.checkpoint(ssm_layer, prevent_cse=False)
+        gmu_layer = jax.checkpoint(gmu_layer, prevent_cse=False)
     layers = [make_layer(kind) for kind in cfg.layer_kinds]
     layer = layers[-1]      # the block a multi-token-prediction module is
+
+    def attn_fn(pos):
+        return make_layer((pos.window, cfg.apply_rotary), pos)
+
     x, (auxes, routing), _ = _run_stack(
-        cfg, layers, x if r0 is None else (x, r0), params,
-        unroll=cfg.layer_scan_unroll or 1, ssm_layer=ssm_layer,
+        cfg, layers, (x, *shared0) if shared0 else (
+            x if r0 is None else (x, r0)), params,
+        unroll=cfg.layer_scan_unroll or 1,
+        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
     )
-    if r0 is not None:
-        x, _ = x
+    if r0 is not None or shared0:
+        x, *_ = x
     stack_out = x
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)
     out = _head(cfg, params, x) if with_head else x
@@ -1354,7 +1682,9 @@ class KVCache:
     """Per-layer KV cache: ``k, v: [L, B, S, Hkv, D]``; ``lens: [B]`` counts
     valid entries per slot (0 = free slot). ``L`` is ``cfg.cache_layers``:
     a looped stack (``cfg.n_passes``) holds a token once a PASS, pass ``t``
-    of layer ``l`` at ``t * n_layers + l``."""
+    of layer ``l`` at ``t * n_layers + l``; a stack plan holds its
+    self-attention layers alone. Under differential attention a row is a
+    PAIR of kv heads, as in the page pool (``cfg.kv_heads_per_row``)."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -1366,8 +1696,11 @@ class KVCache:
 
     @classmethod
     def empty(cls, cfg: ModelConfig, batch: int, capacity: int) -> "KVCache":
-        shape = (
-            cfg.cache_layers, batch, capacity, cfg.n_kv_heads, cfg.head_dim)
+        heads, width = cfg.n_kv_heads, cfg.head_dim
+        if cfg.diff_attn:
+            # a pair of kv heads a row, as in the page pool
+            _, heads, width = kv_page_geometry(cfg)
+        shape = (cfg.cache_layers, batch, capacity, heads, width)
         dt = jnp.dtype(cfg.dtype)
         return cls(
             k=jnp.zeros(shape, dt),
@@ -1482,27 +1815,38 @@ def prefill(
         mask = (idx[None, :, None] >= idx[None, None, :]) & valid[:, None, :]
     scale = cfg.softmax_scale or cfg.head_dim**-0.5
 
-    def make_layer(kind):
+    def make_layer(kind, pos=None):
         window, rotary = kind
         kind_mask = mask
         if mask is not None and window is not None:
             kind_mask = mask & (
                 idx[None, :, None] - idx[None, None, :] < window)
-        return functools.partial(layer, window, rotary, kind_mask)
+        return functools.partial(layer, window, rotary, kind_mask, pos)
 
     r0 = _router_state0(cfg, x)
+    # a plan with readers: the carry is ``(x, memory, k, v)``
+    shared0 = _shared0(cfg, (B, S), x.dtype)
 
-    def layer(window, rotary, mask, carry, lp):
+    def layer(window, rotary, mask, pos, carry, lp):
         x, r = (carry, None) if r0 is None else carry
+        if shared0:
+            x, mem, ks, vs = carry
+        cross = pos is not None and pos.mixer == "cross"
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         cc = None
         if cfg.cca is not None:
             q, k, v, cc = _cca_qkv_roped(
                 cfg, lp["attn"], h, cos, sin, positions, None, prompt_lens)
+        elif cross:
+            q, _, _ = _pack_qkv(
+                cfg, _q_only(cfg, lp["attn"], h, cos, sin), None, None)
+            k, v = ks, vs
         else:
-            q, k, v = _qkv_roped(
-                cfg, lp["attn"], h, cos, sin, rotary)  # [B, S, H, D]
+            q, k, v = _dense_qkv(cfg, *_qkv_roped(
+                cfg, lp["attn"], h, cos, sin, rotary))  # [B, S, H, D]
+            if shared0 and pos.exports:
+                ks, vs = k, v
         if use_flash:
             H, D = q.shape[-2:]
             ctx = attn_ops.packed_attention(
@@ -1517,8 +1861,9 @@ def prefill(
                 max_seqlen=S,
             ).reshape(B, S, H, D)
         else:
-            kk = jnp.repeat(k, cfg.n_rep, axis=2)
-            vv = jnp.repeat(v, cfg.n_rep, axis=2)
+            n_rep = q.shape[2] // k.shape[2]
+            kk = jnp.repeat(k, n_rep, axis=2)
+            vv = jnp.repeat(v, n_rep, axis=2)
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk, preferred_element_type=jnp.float32) * scale
             if cfg.attn_logits_soft_cap is not None:
                 c = cfg.attn_logits_soft_cap
@@ -1526,26 +1871,40 @@ def prefill(
             scores = jnp.where(mask[:, None], scores, attn_ops._NEG_INF)
             probs = jax.nn.softmax(scores, axis=-1).astype(vv.dtype)
             ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, vv)
+        ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
         m, _, _, r = _mlp(
             cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
         x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        if shared0:
+            return (x, mem, ks, vs), (None if cross else (k, v, cc))
         return (x if r0 is None else (x, r)), (k, v, cc)
 
-    def ssm_layer(x, lp):
-        return _ssm_block(
+    def ssm_layer(carry, lp):
+        x, *shared = carry if shared0 else (carry,)
+        x, st, mem = _ssm_block(
             cfg, _cast(cfg, lp), x,
             lambda p, h: ssm_ops.mixer_chunk(
-                cfg, p, h, positions, n_valid=prompt_lens))
+                cfg, p, h, positions, n_valid=prompt_lens,
+                memory=bool(shared0)))
+        return ((x, mem, *shared[1:]) if shared0 else x), st
+
+    def gmu_layer(carry, lp):
+        x, mem, *kv = carry
+        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
+
+    def attn_fn(pos):
+        return make_layer((pos.window, cfg.apply_rotary), pos)
 
     x, (ks, vs, cc), ssm = _run_stack(
         cfg, [make_layer(kind) for kind in cfg.layer_kinds],
-        x if r0 is None else (x, r0), params, ssm_layer=ssm_layer,
+        (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
+        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
     )
-    if r0 is not None:
-        x, _ = x
+    if r0 is not None or shared0:
+        x, *_ = x
     if cc is not None:
         ssm = (cc,)
     cap = cache.k.shape[2]
@@ -1587,25 +1946,42 @@ def decode_step(
     new_lens = jnp.where(active, cache.lens + 1, cache.lens)
 
     r0 = _router_state0(cfg, x)
+    # a plan with readers: the carry is ``(x, memory, k cache, v cache)``,
+    # the last two the exporting layer's whole rows ``[B, S, rows, width]``
+    shared0 = _shared0(cfg, (B,), x.dtype)
+    if shared0:
+        shared0 = (shared0[0], cache.k[0], cache.v[0])
 
-    def layer(kind, carry, inputs):
+    def layer(kind, pos, carry, inputs):
         x, r = (carry, None) if r0 is None else carry
+        if shared0:
+            x, mem, ks, vs = carry
+        cross = pos is not None and pos.mixer == "cross"
         window, rotary = kind
-        lp, kc, vc, *cc = inputs
+        lp, *kv = (inputs,) if cross else inputs
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         # q: [B, Hq, D]; k/v: [B, Hkv, D]
-        if cfg.cca is not None:
-            q, k, v, new = _cca_qkv_step(
-                cfg, lp["attn"], h, cos, sin, positions, cc[0], active)
-            cc = (new,)
+        if cross:
+            q, _, _ = _pack_qkv(
+                cfg, _q_only(cfg, lp["attn"], h, cos, sin), None, None)
+            kc, vc, cc = ks, vs, ()
         else:
-            q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
-        # write new K/V at write_at (only for active slots)
-        slot = jnp.arange(kc.shape[1])[None, :, None, None]  # [1, S, 1, 1]
-        put = (slot == write_at[:, None, None, None]) & active[:, None, None, None]
-        kc = jnp.where(put, k[:, None].astype(kc.dtype), kc)
-        vc = jnp.where(put, v[:, None].astype(vc.dtype), vc)
+            kc, vc, *cc = kv
+            if cfg.cca is not None:
+                q, k, v, new = _cca_qkv_step(
+                    cfg, lp["attn"], h, cos, sin, positions, cc[0], active)
+                cc = (new,)
+            else:
+                q, k, v = _dense_qkv(cfg, *_qkv_roped(
+                    cfg, lp["attn"], h, cos, sin, rotary))
+            # write new K/V at write_at (only for active slots)
+            slot = jnp.arange(kc.shape[1])[None, :, None, None]  # [1, S, 1, 1]
+            put = (slot == write_at[:, None, None, None]) & active[:, None, None, None]
+            kc = jnp.where(put, k[:, None].astype(kc.dtype), kc)
+            vc = jnp.where(put, v[:, None].astype(vc.dtype), vc)
+            if shared0 and pos.exports:
+                ks, vs = kc, vc
         ctx = attn_ops.decode_attention(
             q,
             kc,
@@ -1615,30 +1991,46 @@ def decode_step(
             soft_cap=cfg.attn_logits_soft_cap,
             sliding_window=window,
         )
+        ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
         m, _, _, r = _mlp(
             cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
         x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        if shared0:
+            return (x, mem, ks, vs), (None if cross else (kc, vc))
         return (x if r0 is None else (x, r)), (kc, vc, *cc)
 
-    def ssm_layer(x, inputs):
+    def ssm_layer(carry, inputs):
+        x, *shared = carry if shared0 else (carry,)
         lp, s, cv = inputs
-        return _ssm_block(
+        x, st, mem = _ssm_block(
             cfg, _cast(cfg, lp), x,
-            lambda p, h: ssm_ops.mixer_step(cfg, p, h, (s, cv), active))
+            lambda p, h: ssm_ops.mixer_step(
+                cfg, p, h, (s, cv), active, memory=bool(shared0)))
+        return ((x, mem, *shared[1:]) if shared0 else x), st
+
+    def gmu_layer(carry, lp):
+        x, mem, *kv = carry
+        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
+
+    def attn_fn(pos):
+        return functools.partial(layer, (pos.window, cfg.apply_rotary), pos)
 
     x, (ks, vs, *cc), ssm = _run_stack(
-        cfg, [functools.partial(layer, kind) for kind in cfg.layer_kinds],
-        x if r0 is None else (x, r0), params,
+        cfg,
+        [functools.partial(layer, kind, None) for kind in cfg.layer_kinds],
+        (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
         xs=(cache.k, cache.v) + (
             (cache.ssm.carry,) if cfg.cca is not None else ()),
-        ssm_layer=ssm_layer,
-        ssm_xs=() if cfg.ssm is None else (cache.ssm.ssm, cache.ssm.conv),
+        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        plan_xs=None if cfg.ssm is None else {
+            "attn": (cache.k, cache.v),
+            "ssm": (cache.ssm.ssm, cache.ssm.conv)},
     )
-    if r0 is not None:
-        x, _ = x
+    if r0 is not None or shared0:
+        x, *_ = x
     if cc:
         ssm = tuple(cc)
     cache = KVCache(
@@ -1715,6 +2107,20 @@ class PagedKVCache:
     list while the request still runs (``gen/engine.py``); nothing here
     reads a table entry before a row's first visible position. A model of
     one kind is the same layout with ``p = 1``.
+
+    A STACK PLAN (``cfg.stack_plan``): the same layout over the plan's
+    CACHE layers, its self-attention layers in the order they run (state-
+    space layers, gated memory units and cross-attention layers hold
+    none). Their kinds are the shortest period of their windows
+    (``cfg.layer_kinds``): where they are alike, one table and a page the
+    same positions in every cache layer (``granitemoehybrid``); where
+    eight window layers stand before ONE full layer (``phi4flash``) the
+    period is all nine, one period deep (``pages [1, P, 2, rows, page,
+    width]``, a page ``page`` positions of one cache layer, a table a cache
+    layer ``[9, B, M]``), so the window layers give their pages back behind
+    the window while the full layer's stay. A cross-attention layer reads
+    its source layer's pages through that layer's table with its own
+    queries and writes nothing (:func:`_pool_of`).
 
     A LOOPED stack (``cfg.n_passes``: the layers run several times over
     one set of weights): a token's key and value of a layer differ from
@@ -1914,9 +2320,16 @@ def _extend_layers(
     moe_grouped: bool = False,
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
+    skip_readers: bool = True,
 ):
     """The multi-token layer scan over the page pool (chunked prefill).
     Returns ``(ks, vs, ssm_rows)``; the caller writes the KV.
+    ``skip_readers`` (STATIC; a stack plan with gated memory units and
+    cross attention): the segments behind the last layer that writes a
+    cache or a state are NOT run. Admission keeps nothing of them (no
+    logits are computed here: the last prompt token is fed to the first
+    decode step), which is the decoder-hybrid-decoder's saving at prefill,
+    whole. False runs them all the same (the tests compare the two).
     ``moe_grouped`` (STATIC): the routed experts run as the
     grouped-matmul kernel over the whole stack (``ops/moe.py``; the caller
     asks ``moe_grouped_applies``).
@@ -1943,40 +2356,58 @@ def _extend_layers(
     cos, sin = _cos_sin(cfg, positions)
     kinds = cfg.layer_kinds
 
-    def _attend(q, k, v, li, j):
+    def _attend(q, k, v, li, j, pos=None):
         kw = dict(
             softmax_scale=_attn_scale(cfg),
             soft_cap=cfg.attn_logits_soft_cap,
-            sliding_window=kinds[j][0],
+            sliding_window=kinds[j][0] if pos is None else pos.window,
             scales=cache.scales,
         )
-        return paged_ops.paged_extend_attention(
-            q, k, v, cache.pages, li, _kind_table(table, j), start, n_new,
-            skip_pool=skip_pool, **kw,
-        )
+        li, tbl = _pool_of(cfg, table, li, j, pos)
+        scope = _plan_scope(cfg, pos)
+        if scope is None:
+            return paged_ops.paged_extend_attention(
+                q, k, v, cache.pages, li, tbl, start, n_new,
+                skip_pool=skip_pool, **kw,
+            )
+        with jax.named_scope(scope):
+            return paged_ops.paged_extend_attention(
+                q, k, v, cache.pages, li, tbl, start, n_new,
+                skip_pool=skip_pool, **kw,
+            )
+
+    shared0 = _shared0(cfg, (B, C), x.dtype)
 
     def ssm_layer(carry, lp):
-        x, li, si = carry
+        x, li, si, *shared = carry
         # the barrier keeps the rows' gather a result of its own: the
         # gather of ONE row is a slice to the chip's compiler, and the
         # layout the scan's matmuls ask of that slice it then gave to the
         # whole state, a copy of all 36 layers of it (PERF.md §6 PR 42)
         rows = jax.lax.optimization_barrier(ssm.ssm[si, slots])
-        x, st = _ssm_block(
+        x, st, mem = _ssm_block(
             cfg, _cast(cfg, lp), x,
             lambda p, h: ssm_ops.mixer_chunk(
                 cfg, p, h, positions, (rows, ssm.conv[si, slots]),
-                n_valid=n_new))
-        return (x, li, si + 1), st
+                n_valid=n_new, memory=bool(shared0)))
+        if shared0:
+            shared = (mem, *shared[1:])
+        return (x, li, si + 1, *shared), st
 
-    def layer(j, carry, lp):
+    def gmu_layer(carry, lp):
+        x, li, si, mem, *kv = carry
+        return (_gmu_block(cfg, lp, x, mem), li, si, mem, *kv), None
+
+    def layer(j, carry, lp, pos=None):
         # ``li``: which slice of the pool's leading axis the layer's pages
         # are in: its layer, or (layer kinds) its period (``rest``: the
-        # running index of the state-space layers, which have their own)
+        # running index of the state-space layers, which have their own;
+        # under a plan ``li`` is the running cache layer, :func:`_pool_of`)
         x, li, *rest = carry                          # pool NOT in the scan
         lp = _cast(cfg, lp)
         h = _norm(cfg, lp["ln1"], x)
         cc = None
+        cross = pos is not None and pos.mixer == "cross"
         if cfg.mla is not None:
             # absorbed form, chunk and pool alike: multi-query attention
             # of [B, C, H, W] queries over the latents, whose head is the
@@ -1992,11 +2423,23 @@ def _extend_layers(
                 cfg, lp["attn"], h, cos, sin, positions,
                 ssm.carry[li, slots], n_new)
             ctx = _attend(q, k, v, li, j)
+        elif cross:
+            # the layer's own queries over the shared K/V: the pool's
+            # pages of the source layer and, of this chunk, what that
+            # layer left in the carry
+            q, _, _ = _pack_qkv(
+                cfg, _q_only(cfg, lp["attn"], h, cos, sin), None, None)
+            ctx = _unpack_ctx(
+                cfg, _attend(q, *rest[-2:], li, j, pos), _attn_params(lp))
         else:
             # [B, C, H(kv), D]
             q, k, v = _pack_qkv(cfg, *_qkv_roped(
-                cfg, lp["attn"], h, cos, sin, kinds[j][1]))
-            ctx = _unpack_ctx(cfg, _attend(q, k, v, li, j))
+                cfg, lp["attn"], h, cos, sin,
+                kinds[j][1] if pos is None else cfg.apply_rotary))
+            ctx = _unpack_ctx(
+                cfg, _attend(q, k, v, li, j, pos), _attn_params(lp))
+            if pos is not None and pos.exports:
+                rest = (*rest[:-2], k, v)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
@@ -2007,17 +2450,27 @@ def _extend_layers(
         x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
         if r0 is not None:
             rest = (r,)
-        return (x, li + int(j == len(kinds) - 1), *rest), (k, v, cc)
+        if cross:
+            return (x, li, *rest), None
+        step = int(j == len(kinds) - 1) if pos is None else 1
+        return (x, li + step, *rest), (k, v, cc)
 
     zero = jnp.int32(0)
     # after ``x`` and ``li``: a stateful router's vector, or the running
-    # index of the state-space layers (no model has both)
+    # index of the state-space layers (no model has both), and behind
+    # that what a plan's readers read (:func:`_shared0`)
     r0 = _router_state0(cfg, x)
     rest0 = () if r0 is None else (r0,)
+
+    def attn_fn(pos):
+        return functools.partial(layer, 0, pos=pos)
+
     _, (ks, vs, cc), ssm_rows = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero), params,
-        ssm_layer=ssm_layer,
+        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, *shared0),
+        params,
+        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        writers_only=skip_readers,
     )
     return ks, vs, ssm_rows if cc is None else (cc,)
 
@@ -2040,6 +2493,7 @@ def extend_paged_kv(
     moe_grouped: bool = False,
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
+    skip_readers: bool = True,
 ):
     """Chunked prefill, the computing half: attend the chunk causally over
     everything resident (pool part + intra-chunk part, merged inside the
@@ -2057,6 +2511,7 @@ def extend_paged_kv(
     ks, vs, ssm_rows = _extend_layers(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         moe_grouped=moe_grouped, ssm=ssm, slots=slots,
+        skip_readers=skip_readers,
     )
     if ssm_rows is not None:
         return ks, vs, ssm_rows
@@ -2077,6 +2532,7 @@ def extend_paged(
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
     moe_grouped: bool = False,
+    skip_readers: bool = True,
 ):
     """Both halves of chunked prefill in one call: :func:`extend_paged_kv`,
     then the chunk's KV into the pages (:func:`_write_chunk_kv`, whose
@@ -2087,6 +2543,7 @@ def extend_paged(
     ks, vs, *rows = extend_paged_kv(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         ssm=ssm, slots=slots, moe_grouped=moe_grouped,
+        skip_readers=skip_readers,
     )
     cache = _write_chunk_kv(
         cache, ks, vs, table, start, n_new, use_pallas, mesh
@@ -2201,7 +2658,7 @@ def decode_step_paged(
     kinds = cfg.layer_kinds
 
     def ssm_layer(carry, lp):
-        x, li, si, st = carry
+        x, li, si, st, *shared = carry
         conv_l = jax.lax.dynamic_index_in_dim(st.conv, si, 0, keepdims=False)
         if ssm_update is None:
             ssm_l = jax.lax.dynamic_index_in_dim(
@@ -2215,23 +2672,33 @@ def decode_step_paged(
             def update(whole, *args, **kw):
                 return ssm_update(whole, si, *args, **kw)
 
-        x, (ssm_l, conv_l) = _ssm_block(
+        x, (ssm_l, conv_l), mem = _ssm_block(
             cfg, _cast(cfg, lp), x,
             lambda p, h: ssm_ops.mixer_step(
-                cfg, p, h, (ssm_l, conv_l), active, update=update))
+                cfg, p, h, (ssm_l, conv_l), active, update=update,
+                memory=bool(shared0)))
         if ssm_update is None:
             ssm_l = jax.lax.dynamic_update_index_in_dim(st.ssm, ssm_l, si, 0)
         st = SSMState(
             ssm=ssm_l,
             conv=jax.lax.dynamic_update_index_in_dim(st.conv, conv_l, si, 0))
-        return (x, li, si + 1, st), (None, None, None)
+        if shared0:
+            shared = (mem, *shared[1:])
+        return (x, li, si + 1, st, *shared), (None, None, None)
 
-    def layer(j, carry, lp):
+    def gmu_layer(carry, lp):
+        x, li, si, st, mem, *kv = carry
+        return (_gmu_block(cfg, lp, x, mem), li, si, st, mem, *kv), None
+
+    def layer(j, carry, lp, pos=None):
         # ``li``: the layer, or (layer kinds) the period: the slice of the
-        # pool's leading axis that holds the layer's pages
+        # pool's leading axis that holds the layer's pages (under a plan
+        # the running cache layer, :func:`_pool_of`)
         x, li, *rest = carry                          # pool NOT in the scan
-        window, rotary = kinds[j]
+        window, rotary = kinds[j] if pos is None else (
+            pos.window, cfg.apply_rotary)
         cc = None
+        cross = pos is not None and pos.mixer == "cross"
         if cfg.cca is not None:
             lp, cc = lp
         lp = _cast(cfg, lp)
@@ -2259,12 +2726,31 @@ def decode_step_paged(
                 cfg, lp["attn"], h, cos, sin, lens_o, cc, active_o)
             ctx = paged_ops.paged_decode_attention(
                 q, k, v, cache.pages, li, table_o, lens_o, **kw)
+        elif _plan_scope(cfg, pos) is not None:
+            # a plan whose attention layers differ: a window, a full or a
+            # cross layer, each through its own cache layer's table. A
+            # cross layer brings its queries alone: the pages are its
+            # source's, this token's K/V what that layer left in the carry
+            if cross:
+                q, _, _ = _pack_qkv(
+                    cfg, _q_only(cfg, lp["attn"], h, cos, sin), None, None)
+                k, v = rest[-2:]
+            else:
+                q, k, v = _pack_qkv(
+                    cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin, rotary))
+            pool_i, tbl = _pool_of(cfg, table_o, li, j, pos)
+            with jax.named_scope(_plan_scope(cfg, pos)):
+                ctx = paged_ops.paged_decode_attention(
+                    q, k, v, cache.pages, pool_i, tbl, lens_o, **kw)
+            ctx = _unpack_ctx(cfg, ctx, _attn_params(lp))
+            if pos.exports:
+                rest = (*rest[:-2], k, v)
         elif cfg.layer_pattern is None:
             q, k, v = _pack_qkv(
                 cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin))  # q [B, H, D]
             ctx = _unpack_ctx(cfg, paged_ops.paged_decode_attention(
                 q, k, v, cache.pages, li, table_o, lens_o, **kw
-            ))
+            ), _attn_params(lp))
         else:
             q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
             with jax.named_scope(_attn_scope(window)):
@@ -2281,23 +2767,33 @@ def decode_step_paged(
             router_state=None if r0 is None else rest[0])
         if r0 is not None:
             rest = (r,)
+        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        if cross:
+            return (x, li, *rest), None
+        step = int(j == len(kinds) - 1) if pos is None else 1
         return (
-            (_add_branch(cfg, lp, "mlp_out_ln", x, m),
-             li + int(j == len(kinds) - 1), *rest),
+            (x, li + step, *rest),
             (k, v, routing if with_routing else None, cc),
         )
 
     zero = jnp.int32(0)
     # after ``x`` and ``li``: a stateful router's vector, or the
-    # state-space layers' running index and state (no model has both)
+    # state-space layers' running index and state (no model has both), and
+    # behind those what a plan's readers read (:func:`_shared0`)
     r0 = _router_state0(cfg, x)
     rest0 = () if r0 is None else (r0,)
+    shared0 = _shared0(cfg, x.shape[:1], x.dtype)
     active_o = active[order]
+
+    def attn_fn(pos):
+        return functools.partial(layer, 0, pos=pos)
+
     (x, *rest), (ks, vs, routing, cc), _ = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, ssm),
+        (x, zero, *rest0) if cfg.ssm is None else (
+            x, zero, zero, ssm, *shared0),
         params, xs=() if cfg.cca is None else (ssm.carry[:, order],),
-        ssm_layer=ssm_layer,
+        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
     )
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(
@@ -2312,7 +2808,7 @@ def decode_step_paged(
     else:
         extra = ()
     if cfg.ssm is not None:
-        extra += (rest[-1],)
+        extra += (rest[2],)
     if cc is not None:
         extra += (CCAState(cc[:, inverse]),)
     x = _norm(cfg, _cast(cfg, params["final_ln"]), x)

@@ -90,6 +90,7 @@ def ssm_decode_applies(cfg, mesh=None, platform=None) -> bool:
         platform == "tpu"
         and (mesh is None or mesh.size == 1)
         and s.state_dtype == "float32"
+        and not s.selective     # a decay a (channel, state) pair: XLA's
         and lanes % ssm_ops.LANES == 0
         and s.d_state % ssm_ops.LANES == 0
         and 2 * PHASE_ROWS * 4 * s.d_state * lanes <= RING_BYTES

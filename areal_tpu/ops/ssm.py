@@ -1,5 +1,7 @@
-"""The state-space mixer (the published Mamba-2 recurrence, as the
-``granitemoehybrid`` family lays it out) in plain ``jax.numpy``.
+"""The state-space mixers in plain ``jax.numpy``: the published Mamba-2
+recurrence as the ``granitemoehybrid`` family lays it out, and Mamba-1's
+selective scan as ``phi4flash`` does (``cfg.ssm.selective``; its equations
+and what differs stand above :func:`s6_scan`). First Mamba-2.
 
 One layer, for a head with a state ``S`` of ``head_dim x d_state``::
 
@@ -284,9 +286,131 @@ def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):
     return (y[:, :T] if pad else y), final
 
 
+# --------------------------------------------------------------------------- #
+# Mamba-1's selective scan (``cfg.ssm.selective``)
+# --------------------------------------------------------------------------- #
+#
+#     [x ; z] = W_in h                      two matrices, ``w_x`` and ``w_z``
+#     x    = silu(conv(x) + b)              causal depthwise over x ALONE
+#     [dt_low ; B ; C] = W_xproj x          read from the CONVOLVED x
+#     dt   = softplus(W_dt dt_low + dt_bias)    a channel
+#     S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+#     y_t[c]    = sum_n S_t[c, n] C_t[n] + D[c] x_t[c]
+#     out  = W_out (y * silu(z))            no norm
+#
+# What differs from Mamba-2 is the DECAY: ``A = -exp(A_log)`` has the
+# state's own shape, one number a (channel, state) pair, so the decay
+# between two tokens of a chunk is no scalar a head that a masked matmul
+# could factor out (``scan_chunked``): the recurrence is computed as it is
+# written, token by token, and is elementwise in the stored layout (the
+# channels on the lanes, ``N`` on the sublanes: ``A`` is laid out the same
+# way once a call). ``A_log`` is kept ``[N, C]``, channels minor as in the
+# state. Both forms below take and return the state as Mamba-2's do; ``y``
+# before the gate is the MEMORY a later gated memory unit reads.
+#
+# What a chunk costs: ``T`` sequential steps over ``[B, C, N]`` (admission's
+# 128 tokens x 8 rows at 5,120 channels x 16: 655 k multiply-adds and
+# exponentials a step, the state read and written once a step, 5.2 MB: 128
+# steps a layer), where Mamba-2's form is two matmuls a chunk. An
+# associative scan over the chunk would hold ``T x C x N`` floats a row
+# (42 MB a row a layer) for a ``log T`` depth; not taken.
+
+
+def _s6_a(p):
+    """``A = -exp(A_log)`` in the state's layout ``[K, N, lanes]``."""
+    a = -jnp.exp(p["A_log"].astype(jnp.float32))            # [N, C]
+    return jnp.moveaxis(_tiles(a), 0, 1)
+
+
+def s6_step(ssm, x, dt, a, b, c, d_skip):
+    """One token of the selective scan, every row: ``ssm [B, 1, K, N,
+    lanes]``, ``x, dt [B, C]`` (``dt`` 0: the row's state stays), ``a [K, N,
+    lanes]``, ``b, c [B, N]``, ``d_skip [C]``; float32. Returns ``(y [B, C],
+    ssm)``."""
+    dt_t = _tiles(dt)[:, :, None, :]                        # [B, K, 1, lanes]
+    dtx = _tiles(dt * x)[:, :, None, :]
+    s = ssm[:, 0] * jnp.exp(dt_t * a) + b[:, None, :, None] * dtx
+    y = jnp.sum(s * c[:, None, :, None], axis=2)            # [B, K, lanes]
+    return y.reshape(x.shape) + d_skip * x, s[:, None]
+
+
+def s6_scan(x, dt, a, b, c, d_skip, reset, init):
+    """The selective scan over ``T`` tokens a row, token by token: ``x, dt
+    [B, T, C]`` (``dt`` 0 where a token must leave the state alone:
+    padding), ``b, c [B, T, N]``, ``reset [B, T]`` (the state is dropped
+    before this token), ``init [B, 1, K, N, lanes]``; float32. Returns ``(y
+    [B, T, C], state after the last token)``."""
+
+    def token(s, inp):
+        x_t, dt_t, b_t, c_t, r_t = inp
+        s = jnp.where(r_t[:, None, None, None, None], 0.0, s)
+        y, s = s6_step(s, x_t, dt_t, a, b_t, c_t, d_skip)
+        return s, y
+
+    with jax.named_scope("ssm_s6"):
+        final, y = jax.lax.scan(
+            token, init,
+            tuple(jnp.moveaxis(v, 1, 0) for v in (x, dt, b, c, reset)))
+    return jnp.moveaxis(y, 0, 1), final
+
+
+def _s6_inputs(cfg: ModelConfig, p, h, positions, conv0, n_valid):
+    """``h [B, T, E]`` to what the scan reads: ``(z, x, dt, b, c, conv
+    state)``, ``x`` convolved and activated, ``dt`` after its projection and
+    softplus, all but ``z`` float32."""
+    s = cfg.ssm
+    with jax.named_scope("ssm_in_proj"):
+        x, z = h @ p["w_x"], h @ p["w_z"]
+    x, conv1 = conv_chunk(p, x, positions, conv0, n_valid)
+    with jax.named_scope("ssm_x_proj"):
+        dbc = x @ p["w_xproj"]
+        dt = dbc[..., : s.dt_rank] @ p["w_dt"]
+    dt = jax.nn.softplus(
+        dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    dbc = dbc.astype(jnp.float32)
+    b = dbc[..., s.dt_rank : s.dt_rank + s.d_state]
+    c = dbc[..., s.dt_rank + s.d_state :]
+    return z, x.astype(jnp.float32), dt, b, c, conv1
+
+
+def _s6_out(p, y, z):
+    with jax.named_scope("ssm_gate"):
+        g = y * jax.nn.silu(z.astype(jnp.float32))
+    return g.astype(z.dtype) @ p["w_out"]
+
+
+def _s6_chunk(cfg: ModelConfig, p, h, positions, state, n_valid):
+    """:func:`mixer_chunk` under the selective scan: ``(out, (ssm, conv),
+    y)`` with ``y [B, T, d_inner]`` the scan's output before the gate."""
+    T = h.shape[1]
+    ssm0, conv0 = state
+    z, x, dt, b, c, conv1 = _s6_inputs(cfg, p, h, positions, conv0, n_valid)
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    y, ssm1 = s6_scan(
+        x, dt, _s6_a(p), b, c, p["D"].astype(jnp.float32),
+        (positions == 0) & valid, ssm0.astype(jnp.float32))
+    return _s6_out(p, y, z), (ssm1.astype(ssm0.dtype), conv1), y
+
+
+def _s6_token(cfg: ModelConfig, p, h, state, active):
+    """:func:`mixer_step` under the selective scan (``y [B, d_inner]``)."""
+    Bt = h.shape[0]
+    ssm0, conv0 = state
+    z, x, dt, b, c, conv1 = _s6_inputs(
+        cfg, p, h[:, None], jnp.full((Bt, 1), cfg.ssm.d_conv, jnp.int32),
+        conv0, active.astype(jnp.int32))
+    dt = jnp.where(active[:, None], dt[:, 0], 0.0)
+    with jax.named_scope("ssm_s6"):
+        y, ssm1 = s6_step(
+            ssm0, x[:, 0], dt, _s6_a(p), b[:, 0], c[:, 0],
+            p["D"].astype(jnp.float32))
+    return _s6_out(p, y, z[:, 0]), (ssm1, conv1), y
+
+
 def mixer_chunk(
     cfg: ModelConfig, p, h, positions, state: Optional[Tuple] = None,
-    n_valid=None, chunk: Optional[int] = None,
+    n_valid=None, chunk: Optional[int] = None, memory: bool = False,
 ):
     """The state-space mixer over ``h [B, T, E]`` (normed layer input).
     ``positions [B, T]``: each token's place in its own document: a token
@@ -296,7 +420,9 @@ def mixer_chunk(
     :func:`state_shapes`).
     ``n_valid [B]``: tokens of each row
     that count (the rest is padding BEHIND them, which leaves the state
-    as it is). Returns ``(out [B, T, E], (ssm, conv))``."""
+    as it is). Returns ``(out [B, T, E], (ssm, conv))``, and with
+    ``memory`` a third, the scan's output ``y [B, T, d_inner]`` before the
+    gate (what a gated memory unit reads)."""
     s = cfg.ssm
     Bt, T = h.shape[:2]
     G, R = s.n_groups, s.n_heads // s.n_groups
@@ -308,6 +434,9 @@ def mixer_chunk(
         )
     if n_valid is None:
         n_valid = jnp.full((Bt,), T, jnp.int32)
+    if s.selective:
+        out = _s6_chunk(cfg, p, h, positions, state, n_valid)
+        return out if memory else out[:2]
     ssm0, conv0 = state
     z, xbc, dt = _split_in(cfg, p, h)
     xbc, conv1 = conv_chunk(p, xbc, positions, conv0, n_valid)
@@ -320,8 +449,9 @@ def mixer_chunk(
         ssm0.astype(jnp.float32), chunk or s.chunk_size,
     )
     y = y + p["D"].astype(jnp.float32).reshape(G, R)[..., None] * x
-    out = _gated_out(cfg, p, y.reshape(Bt, T, s.d_inner), z)
-    return out, (ssm1.astype(ssm0.dtype), conv1)
+    y = y.reshape(Bt, T, s.d_inner)
+    out = _gated_out(cfg, p, y, z), (ssm1.astype(ssm0.dtype), conv1)
+    return (*out, y) if memory else out
 
 
 def step_rows(x, dt, a):
@@ -355,7 +485,8 @@ def step_update(ssm, x, dt, a, b, c, d_skip):
     return step_out(y.reshape(*y.shape[:2], -1), x, d_skip), ssm
 
 
-def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
+def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None,
+               memory: bool = False):
     """The mixer over ONE token a row: ``h [B, E]``, ``state`` as
     :func:`mixer_chunk` takes it. Rows where ``active [B]`` is false leave
     their state as it was (their output is garbage nobody reads).
@@ -363,13 +494,17 @@ def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
     kernel, which works on the state of all layers in place): it is handed
     the state of ALL layers in place of this layer's, and ``active`` by
     name; what it returns as the state is returned as is. Returns ``(out
-    [B, E], (ssm, conv))``."""
+    [B, E], (ssm, conv))``, and with ``memory`` the scan's output ``y [B,
+    d_inner]`` before the gate."""
     s = cfg.ssm
     Bt = h.shape[0]
     G, R = s.n_groups, s.n_heads // s.n_groups
     ssm0, conv0 = state
     if active is None:
         active = jnp.ones((Bt,), bool)
+    if s.selective:
+        out = _s6_token(cfg, p, h, state, active)
+        return out if memory else out[:2]
     z, xbc, dt = _split_in(cfg, p, h)
     xbc, conv1 = conv_chunk(
         p, xbc[:, None], jnp.full((Bt, 1), s.d_conv, jnp.int32), conv0,
@@ -382,5 +517,6 @@ def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None):
     y, ssm1 = (
         step_update(ssm0, *args) if update is None
         else update(ssm0, *args, active=active))
-    out = _gated_out(cfg, p, y.reshape(Bt, s.d_inner), z)
-    return out, (ssm1, conv1)
+    y = y.reshape(Bt, s.d_inner)
+    out = _gated_out(cfg, p, y, z), (ssm1, conv1)
+    return (*out, y) if memory else out

@@ -626,6 +626,29 @@ def phase_serve(sz, args):
             f"the two-kind model's decode chunk lacks a kernel, or its tied "
             f"head's rows did not end in the fused one: {hybrid['kernels']}, "
             f"fused_rows {hybrid['fused_rows']} of {hybrid['state_slots']}")
+    # ... and a model of THREE SEGMENTS through the engine (family
+    # ``phi4flash``, 8 layers at the published widths): Mamba-1 layers beside
+    # window layers, one full layer whose K/V a cross layer shares, a gated
+    # memory unit; prompts past the window, so both programs of the paged
+    # kernel run and window pages go back while the full layer's stay
+    yoco, _ = helper(d, "yoco", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(yoco["logprobs"]["correct"]
+            and yoco["state_snapshot_hits"] == 3
+            and yoco["prefix_hit_tokens"][1:] == yoco["prefix_hit_tokens"][1:2] * 3
+            and yoco["prefix_hit_tokens"][1] > 0
+            and yoco["window_pages_released"] > 0
+            and yoco["admit_token_layers_skipped"] > 0,
+            f"a decoder-hybrid-decoder: served log-probs, the snapshot path "
+            f"or the window's release are off: {yoco}")
+    require(args.rehearse or all(
+                any(k == want or k.startswith(want + ".")
+                    for k in yoco["kernels"])
+                for want in ("paged_decode", "paged_decode_window",
+                             KV_WRITE_KERNEL, FUSED_SAMPLE_KERNEL)),
+            f"the three-segment model's decode chunk lacks a kernel: "
+            f"{yoco['kernels']}")
     # ... and a model whose attention runs inside a convolved latent behind
     # a top-1 expert layer with a skip (family ``zaya``): the per-slot carry
     # beside the page pool, its snapshot in the prefix cache, the router's
@@ -1485,6 +1508,95 @@ def child_statespace(arg):
     })
 
 
+def child_yoco(arg):
+    """A model of THREE SEGMENTS through the generation engine: family
+    ``phi4flash`` cut to 8 layers at Phi-4-mini-flash's widths (2 x (Mamba-1,
+    window 512), (Mamba-1, FULL), (gated memory unit, cross attention);
+    toy widths under ``--rehearse``), seeded as the benchmark seeds it. A
+    group of four shares a prompt past the window (the first prefills in
+    chunks, its window layers' pages going back behind the window, and
+    files a snapshot of the Mamba state; the rest are seeded from it), one
+    request generates alone; the served log-probs are held to the
+    token-by-token float32 reference, and the decode chunk's program is
+    searched for the kernels it should hold: BOTH programs of the paged
+    kernel (the window layers', the full layer's and the cross layer's),
+    ``kv_page_write`` over three cache layers, ``fused_sample`` over the
+    200k-row tied embedding."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine, GenRequest
+    from benchmark import correct, sut, weights
+    from benchmark.drivers import rollout_yoco_inproc as drv
+
+    with open(os.path.join(
+            ROOT, "benchmark/configs/phi4-mini-flash.json")) as f:
+        arch = json.load(f)
+    arch.update(num_hidden_layers=8)
+    page, prompt_len, new = 128, 700, 48
+    if arg["rehearse"]:
+        arch.update(hidden_size=64, intermediate_size=128,
+                    num_attention_heads=4, num_key_value_heads=2,
+                    vocab_size=512, serving_dtype="float32")
+        arch = drv._rehearsal_arch(arch)
+        page, prompt_len, new = 16, 56, 12
+    cfg = sut.model_config(arch, {})
+    params = drv._seeded_init(
+        weights.make_weights(
+            sut.weight_shapes(cfg, cfg.dtype), arg["seed"],
+            jnp.dtype(cfg.dtype)),
+        arg["seed"])
+    eng = GenerationEngine(
+        cfg, params, max_slots=8, max_seqlen=8 * page,
+        max_new_tokens_cap=64, page_size=page, state_snapshots=2,
+        seed=arg["seed"] % (2**31 - 1))
+    rng = np.random.default_rng(arg["seed"])
+    shared = rng.integers(1, cfg.vocab_size, prompt_len).tolist()
+    alone = rng.integers(1, cfg.vocab_size, prompt_len // 3).tolist()
+    prompts = {**{f"g{i}": shared for i in range(4)}, "alone": alone}
+    for rid, p in prompts.items():
+        eng.submit(GenRequest(
+            rid=rid, input_ids=p, max_new_tokens=new, temperature=1.0))
+    outs = {o.rid: o for o in eng.run_until_done(decode_steps=8)}
+    (key,) = [k for k in eng._jit_chunk]
+    chunk = eng._chunk_fn(*key)
+    names = sorted(set(re.findall(
+        r'kernel_name = "([^"]+)"',
+        chunk.lower(
+            eng.params, eng.state,
+            jnp.asarray(eng._table_arg(slice(None), key[1])),
+            jnp.zeros((key[2],), jnp.int32)).as_text())))
+    samples = [{
+        "tokens": prompts[rid] + list(o.output_ids),
+        "start": len(prompts[rid]), "logprobs": o.output_logprobs,
+    } for rid, o in sorted(outs.items())]
+    stats = dict(eng.stats)
+    eng.state = None
+    verdict = correct.check_logprobs(params, arch, cfg.dtype, samples)
+    emit({
+        "mixers": list(cfg.mixers), "cache_layers": cfg.cache_layers,
+        "widths": [cfg.hidden_dim, cfg.ssm.d_inner, cfg.ssm.d_state,
+                   cfg.n_q_heads, cfg.n_kv_heads, cfg.head_dim],
+        "kernels": names,
+        "state_snapshots_taken": stats["state_snapshots_taken"],
+        "state_snapshot_hits": stats["state_snapshot_hits"],
+        "prefix_hit_tokens": [outs[f"g{i}"].prefix_hit_tokens
+                              for i in range(4)],
+        "window_pages_released": stats["window_pages_released"],
+        "admit_token_layers_run": stats["admit_token_layers_run"],
+        "admit_token_layers_skipped": stats["admit_token_layers_skipped"],
+        "fused_rows": stats["fused_rows"],
+        "logprobs": {k: verdict.get(k) for k in (
+            "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
+            "mean_abs_diff_nats", "n_positions")},
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 def child_zaya(arg):
     """A two-layer model of family ``zaya`` at ZAYA1-8B's widths (toy
     widths under ``--rehearse``) through the generation engine, seeded as
@@ -1585,6 +1697,7 @@ CHILDREN = {
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,
     "moegrouped": child_moegrouped, "statespace": child_statespace,
+    "yoco": child_yoco,
 }
 
 

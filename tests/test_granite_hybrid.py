@@ -132,7 +132,8 @@ def test_family_reads_and_writes_the_published_config_key_for_key():
     back = FAMILY.config_to_hf(cfg)
     for key, value in row["config"].items():
         assert back[key] == value, key
-    assert cfg.mixer_pattern == ("ssm",) * 5 + ("attn",) + ("ssm",) * 4
+    assert cfg.stack_plan == ((4, tuple(
+        (m, None) for m in ("ssm",) * 5 + ("attn",) + ("ssm",) * 4)),)
     assert (cfg.n_ssm_layers, cfg.n_attn_layers, cfg.cache_layers) == (36, 4, 4)
     assert cfg.kv_heads_per_row == 2 and cfg.softmax_scale == 0.015625
     shapes = jax.eval_shape(
@@ -175,7 +176,7 @@ def test_family_refuses_what_it_does_not_implement(key, value):
     {"n_passes": 2}, {"mlp_type": "moe"}, {"norm_branch_out": True},
     {"layer_pattern": ((None, False), (8, False))},
     {"ssm": dataclasses.replace(CFG.ssm, state_dtype="bfloat16")},
-    {"mixer_pattern": None},
+    {"stack_plan": None},
 ])
 def test_config_refuses_state_space_beside_what_no_test_covers(over):
     with pytest.raises(ValueError):
@@ -521,7 +522,7 @@ WIDE = ModelConfig(
     intermediate_dim=64, vocab_size=64, dtype="float32",
     apply_rotary=False, softmax_scale=0.015625,
     ssm=SSMConfig(n_heads=2, head_dim=8, d_state=16),
-    mixer_pattern=("attn", "ssm"))
+    stack_plan=((2, ("attn", "ssm")),))
 
 
 def _dense_attention(q, k, v, scale):

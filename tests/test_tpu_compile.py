@@ -1328,3 +1328,115 @@ def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
     before the kernel existed: the routed stacks leave the scanned tree
     only in a program that runs the kernel."""
     assert forward_hashes(config) == FORWARD_HASHES[config]
+
+
+# ------------------------------------------------------------------ #
+# Phi-4-mini-flash (benchmark/configs/phi4-mini-flash): the decode chunk
+# of the WHOLE model at the cell's slots and pool
+# ------------------------------------------------------------------ #
+
+
+def _phi4flash_program(one_chip, program: str, n_pages: int):
+    """``(cfg, the jitted program, its arguments as shapes on the chip)``
+    of the published model under the engine at the cell's 128 slots of 128
+    pages in each of nine tables and a pool of ``n_pages``, placeholder
+    weights."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "phi4-mini-flash.json")) as f:
+        arch = json.load(f)
+    cfg = sut.model_config(arch, {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B, M = 128, 128
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=4, max_seqlen=16384, max_new_tokens_cap=15360,
+        page_size=128, n_pages=80, state_snapshots=8, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.fused and eng._stateful and eng._windowed
+    assert eng._ssm_update() is None            # the selective scan is XLA's
+    eng.B = B
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    def rows(a):
+        # the engine above holds 4 slots (a CPU's worth): the cell's 128
+        return _spec(
+            tuple(B if d == 4 else d for d in a.shape), a.dtype, one_chip)
+
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    pages = eng.state.cache.pages
+    assert pages.shape[2:] == (2, 10, 128, 128) and pages.shape[0] == 1
+    st = eng.state
+    state = dataclasses.replace(
+        jax.tree.map(rows, dataclasses.replace(st, snaps=None, cache=None)),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (1, n_pages) + pages.shape[2:], pages.dtype, one_chip)),
+        snaps=jax.tree.map(spec, st.snaps),
+        rng=spec(st.rng))
+    params = jax.tree.map(spec, shapes)
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=eng.fused, with_topk=False)
+        args = (params, state, i32(9, B, M), i32(0))
+    else:
+        fn = eng._extend_fn(8, 64, skip_pool=False)
+        args = (params, state, i32(8, eng.admit_chunk), i32(9, 8, 64), i32(8),
+                i32(8), i32(8))
+    return cfg, fn, args
+
+
+@pytest.mark.parametrize("more_pages", [0, 160])
+def test_phi4flash_cell_decode_chunk_computes_its_head_once(
+        compiled_kernels, one_chip, more_pages):
+    """The WHOLE published model's decode chunk at the cell's 128 slots
+    and its pool (the traffic file's bytes), and 160 pages (105 MB)
+    further on: both programs of the paged kernel and the write kernel
+    over NINE cache layers are in it, the step ends in ``fused_sample``
+    over the 200k-row embedding as stored (no ``[128, 200064]`` array, no
+    transposed copy), nothing is rematerialised, and arguments and
+    temporaries fit the chip."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic",
+            "grpo16_closed128_out15k_yoco.json")) as f:
+        pool_bytes = json.load(f)["engine"]["kv_pool_bytes"]
+    n_pages = pool_bytes // (5_120 * 128) + more_pages
+    cfg, fn, args = _phi4flash_program(one_chip, "jit_chunk", n_pages)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert f"bf16[1,{n_pages},2,10,128,128]" in text
+    for kernel in ("paged_decode", "paged_decode_window", "kv_page_write"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    assert not re.search(r"%ssm_decode(\.\d+)? = ", text)
+    again = sorted(set(re.findall(r"%([\w.\-]*remat[\w.\-]*) = ", text)))
+    assert not again, again
+    _assert_fused_epilogue(text, 128, cfg.vocab_size)
+    assert f"bf16[{cfg.hidden_dim},{cfg.vocab_size}]" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+def test_phi4flash_admission_runs_no_cross_decoder(compiled_kernels, one_chip):
+    """A wave of 8 x 128 tokens continuing 8 slots' state: the program
+    holds the matmuls of layers 0-17 and none over the gated memory units'
+    or the cross layers' stacks (admission keeps nothing of them), and
+    makes no array of the whole recurrent state's size."""
+    cfg, fn, args = _phi4flash_program(one_chip, "jit_extend", 9600)
+    text = fn.lower(*args).compile().as_text()
+    assert "bf16[7,2560,5120]" not in text      # gmu_layers' w_in
+    assert "bf16[7,2560,2560]" not in text      # cross_layers' wq / wo
+    assert "bf16[9,2560,5120]" in text          # ssm_layers' w_x / w_z
+    made = [ln.strip()[:120] for ln in text.split("\n")
+            if "= f32[9,128,1,40,16,128]" in ln and " parameter(" not in ln
+            and " get-tuple-element(" not in ln]
+    assert not made, made
