@@ -708,16 +708,7 @@ class GenerationEngine:
                 "slots_held": 0,
                 "preemptions": 0,
                 "preempted_tokens_recomputed": 0,
-                # prefilled positions x the layers admission ran over them /
-                # the layers it did not: a stack plan's segments behind
-                # its last layer that writes a cache or a state (gated
-                # memory units, cross attention) keep nothing of a
-                # position whose logits nobody reads (``tfm.
-                # _extend_layers``); 0 skipped for every other model
-                "admit_token_layers_run": 0,
-                "admit_token_layers_skipped": 0,
             }
-            self._admit_layers = tfm.admission_layers(cfg)
             if self._moe and cfg.moe.skip_expert:
                 # of the decode chunks' routing (active rows x expert
                 # layers x steps): rows in all, and rows that took the skip
@@ -1860,7 +1851,6 @@ class GenerationEngine:
                 st["state_snapshots_taken"], st["state_snapshot_hits"],
                 st["state_snapshot_bytes"], st["state_snapshot_evictions"],
                 st["preempted_tokens_recomputed"],
-                st["admit_token_layers_run"], st["admit_token_layers_skipped"],
             )
             self._admit_pending()
             attrs.update(
@@ -1886,11 +1876,6 @@ class GenerationEngine:
                 # where their routed experts ran
                 attrs["moe_grouped_rows"] = st["moe_grouped_rows"] - before[5]
                 attrs["moe_dense_rows"] = st["moe_dense_rows"] - before[6]
-            if st["admit_token_layers_skipped"]:
-                attrs["token_layers_run"] = (
-                    st["admit_token_layers_run"] - before[12])
-                attrs["token_layers_skipped"] = (
-                    st["admit_token_layers_skipped"] - before[13])
             if self._stateful:
                 # snapshots of the recurrent state filed by this wave,
                 # admissions it seeded from one, bytes copied in and out,
@@ -2078,10 +2063,6 @@ class GenerationEngine:
                         deferred_inserts.append(
                             (ids, slot, n_shared_full, None))
             self.stats["prefill_tokens"] += len(row["tokens"])
-            self.stats["admit_token_layers_run"] += (
-                len(row["tokens"]) * self._admit_layers)
-            self.stats["admit_token_layers_skipped"] += len(row["tokens"]) * (
-                self.cfg.n_layers * self.cfg.n_passes - self._admit_layers)
             self.stats["admitted"] += 1
             if r.rid in self._carried and row["tokens"]:
                 # a preempted request back in a slot: these positions were

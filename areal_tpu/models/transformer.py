@@ -1173,22 +1173,22 @@ def _gmu_block(cfg: ModelConfig, lp, x, memory):
         _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
 
 
-def _plan_fns(attn, ssm_layer, gmu_layer):
+def _plan_fns(cfg: ModelConfig, attn, ssm_layer, remat: bool = False):
     """:func:`_scan_plan`'s ``fns`` of a forward: ``attn(position)`` makes
     the layer of an "attn" or a "cross" position; the state-space layer and
-    the gated memory unit are one function each wherever they stand."""
+    the gated memory unit are one function each wherever they stand. The
+    unit is the same in every forward: what the readers read (memory, k,
+    v: :func:`_shared0`) ENDS the carry, whatever else it holds. ``remat``:
+    the unit under ``jax.checkpoint`` (the trainer's forward)."""
+
+    def gmu_layer(carry, lp):
+        x, *rest = carry
+        return (_gmu_block(cfg, lp, x, rest[-3]), *rest), None
+
+    if remat:
+        gmu_layer = jax.checkpoint(gmu_layer, prevent_cse=False)
     return {"attn": attn, "cross": attn,
             "ssm": lambda pos: ssm_layer, "gmu": lambda pos: gmu_layer}
-
-
-def admission_layers(cfg: ModelConfig) -> int:
-    """Layers that admission's chunks run over a prefilled position
-    (:func:`_extend_layers`): all of them, passes counted, or under a
-    stack plan those up to the last that writes a cache or a state."""
-    if cfg.plan is None:
-        return cfg.n_layers * cfg.n_passes
-    mixers = cfg.mixers
-    return max(i for i, m in enumerate(mixers) if m in ("ssm", "attn")) + 1
 
 
 def _readers(cfg: ModelConfig) -> bool:
@@ -1561,13 +1561,8 @@ def forward_packed(
             return (x[0], mem[0], *shared[1:]), None
         return x[0], None
 
-    def gmu_layer(carry, lp):
-        x, mem, *kv = carry
-        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
-
     if policy != "none":
         ssm_layer = jax.checkpoint(ssm_layer, prevent_cse=False)
-        gmu_layer = jax.checkpoint(gmu_layer, prevent_cse=False)
     layers = [make_layer(kind) for kind in cfg.layer_kinds]
     layer = layers[-1]      # the block a multi-token-prediction module is
 
@@ -1578,7 +1573,7 @@ def forward_packed(
         cfg, layers, (x, *shared0) if shared0 else (
             x if r0 is None else (x, r0)), params,
         unroll=cfg.layer_scan_unroll or 1,
-        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer, remat=policy != "none"),
     )
     if r0 is not None or shared0:
         x, *_ = x
@@ -1891,17 +1886,13 @@ def prefill(
                 memory=bool(shared0)))
         return ((x, mem, *shared[1:]) if shared0 else x), st
 
-    def gmu_layer(carry, lp):
-        x, mem, *kv = carry
-        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
-
     def attn_fn(pos):
         return make_layer((pos.window, cfg.apply_rotary), pos)
 
     x, (ks, vs, cc), ssm = _run_stack(
         cfg, [make_layer(kind) for kind in cfg.layer_kinds],
         (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
-        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer),
     )
     if r0 is not None or shared0:
         x, *_ = x
@@ -2011,10 +2002,6 @@ def decode_step(
                 cfg, p, h, (s, cv), active, memory=bool(shared0)))
         return ((x, mem, *shared[1:]) if shared0 else x), st
 
-    def gmu_layer(carry, lp):
-        x, mem, *kv = carry
-        return (_gmu_block(cfg, lp, x, mem), mem, *kv), None
-
     def attn_fn(pos):
         return functools.partial(layer, (pos.window, cfg.apply_rotary), pos)
 
@@ -2024,7 +2011,7 @@ def decode_step(
         (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
         xs=(cache.k, cache.v) + (
             (cache.ssm.carry,) if cfg.cca is not None else ()),
-        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer),
         plan_xs=None if cfg.ssm is None else {
             "attn": (cache.k, cache.v),
             "ssm": (cache.ssm.ssm, cache.ssm.conv)},
@@ -2203,8 +2190,18 @@ def _write_chunk_kv(
                 return None
             return a.reshape(a.shape[0] // p, p, *a.shape[1:])[:, j]
 
+        # the kinds are alike in shape, and what a kind costs a START is
+        # tracing and lowering the kernel (0.8 s on a chip's host): under
+        # ``jit`` that is paid for the first kind and found again for the
+        # others (nine cache layers: 7.2 s of tracing a write program and
+        # 6 of a decode chunk's 9.4; PERF.md section 6, PR 48); the
+        # compiler inlines the calls: the same kernel calls, and the
+        # index arithmetic the kinds share computed once
+        write_kind = _write_kind_jit if paged_ops.kv_write_kernel_applies(
+            use_pallas, cache.pages, cache.quantized, mesh
+        ) else _write_chunk_kv
         for j in range(p):
-            cache = _write_chunk_kv(
+            cache = write_kind(
                 cache, of_kind(ks, j), of_kind(vs, j), table[j], start,
                 count, use_pallas, mesh,
             )
@@ -2220,6 +2217,9 @@ def _write_chunk_kv(
     return PagedKVCache(
         pages=kv_page_write.write(pages, fresh, table, start, count)
     )
+
+
+_write_kind_jit = jax.jit(_write_chunk_kv, static_argnums=(6, 7))
 
 
 def _scatter_chunk_kv(cache: PagedKVCache, ks, vs, table, start, count):
@@ -2320,16 +2320,14 @@ def _extend_layers(
     moe_grouped: bool = False,
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
-    skip_readers: bool = True,
 ):
     """The multi-token layer scan over the page pool (chunked prefill).
-    Returns ``(ks, vs, ssm_rows)``; the caller writes the KV.
-    ``skip_readers`` (STATIC; a stack plan with gated memory units and
-    cross attention): the segments behind the last layer that writes a
-    cache or a state are NOT run. Admission keeps nothing of them (no
-    logits are computed here: the last prompt token is fed to the first
-    decode step), which is the decoder-hybrid-decoder's saving at prefill,
-    whole. False runs them all the same (the tests compare the two).
+    Returns ``(ks, vs, ssm_rows)``; the caller writes the KV. Under a
+    stack plan with gated memory units and cross attention the segments
+    behind the last layer that writes a cache or a state are NOT run:
+    admission keeps nothing of them (no logits are computed here: the last
+    prompt token is fed to the first decode step), which is the
+    decoder-hybrid-decoder's saving at prefill, whole.
     ``moe_grouped`` (STATIC): the routed experts run as the
     grouped-matmul kernel over the whole stack (``ops/moe.py``; the caller
     asks ``moe_grouped_applies``).
@@ -2393,10 +2391,6 @@ def _extend_layers(
         if shared0:
             shared = (mem, *shared[1:])
         return (x, li, si + 1, *shared), st
-
-    def gmu_layer(carry, lp):
-        x, li, si, mem, *kv = carry
-        return (_gmu_block(cfg, lp, x, mem), li, si, mem, *kv), None
 
     def layer(j, carry, lp, pos=None):
         # ``li``: which slice of the pool's leading axis the layer's pages
@@ -2469,8 +2463,8 @@ def _extend_layers(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, *shared0),
         params,
-        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
-        writers_only=skip_readers,
+        fns=_plan_fns(cfg, attn_fn, ssm_layer),
+        writers_only=True,
     )
     return ks, vs, ssm_rows if cc is None else (cc,)
 
@@ -2493,7 +2487,6 @@ def extend_paged_kv(
     moe_grouped: bool = False,
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
-    skip_readers: bool = True,
 ):
     """Chunked prefill, the computing half: attend the chunk causally over
     everything resident (pool part + intra-chunk part, merged inside the
@@ -2511,7 +2504,6 @@ def extend_paged_kv(
     ks, vs, ssm_rows = _extend_layers(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         moe_grouped=moe_grouped, ssm=ssm, slots=slots,
-        skip_readers=skip_readers,
     )
     if ssm_rows is not None:
         return ks, vs, ssm_rows
@@ -2532,7 +2524,6 @@ def extend_paged(
     ssm: Optional[Any] = None,
     slots: Optional[jnp.ndarray] = None,
     moe_grouped: bool = False,
-    skip_readers: bool = True,
 ):
     """Both halves of chunked prefill in one call: :func:`extend_paged_kv`,
     then the chunk's KV into the pages (:func:`_write_chunk_kv`, whose
@@ -2543,7 +2534,6 @@ def extend_paged(
     ks, vs, *rows = extend_paged_kv(
         params, cfg, cache, tokens, table, start, n_new, skip_pool=skip_pool,
         ssm=ssm, slots=slots, moe_grouped=moe_grouped,
-        skip_readers=skip_readers,
     )
     cache = _write_chunk_kv(
         cache, ks, vs, table, start, n_new, use_pallas, mesh
@@ -2686,10 +2676,6 @@ def decode_step_paged(
             shared = (mem, *shared[1:])
         return (x, li, si + 1, st, *shared), (None, None, None)
 
-    def gmu_layer(carry, lp):
-        x, li, si, st, mem, *kv = carry
-        return (_gmu_block(cfg, lp, x, mem), li, si, st, mem, *kv), None
-
     def layer(j, carry, lp, pos=None):
         # ``li``: the layer, or (layer kinds) the period: the slice of the
         # pool's leading axis that holds the layer's pages (under a plan
@@ -2793,7 +2779,7 @@ def decode_step_paged(
         (x, zero, *rest0) if cfg.ssm is None else (
             x, zero, zero, ssm, *shared0),
         params, xs=() if cfg.cca is None else (ssm.carry[:, order],),
-        fns=_plan_fns(attn_fn, ssm_layer, gmu_layer),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer),
     )
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(

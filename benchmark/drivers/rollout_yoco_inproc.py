@@ -23,10 +23,10 @@ differs:
   and the pair's norm gain 0.02. ``_seeded_init`` overwrites ``A_log``
   (``log(1..N)`` a channel, the published initialisation), ``dt_bias``,
   ``D``, the convolution, the four ``lambda`` vectors (normal(0, 0.1) x 4)
-  and the pair norm's gain (1 + normal(0, 0.1)) from ``--seed``, channel 0
-  of the first Mamba layer at the slow end, in the ONE tree that the
-  program and the reference both read (the configuration file's
-  ``assumed.seeded_weights``);
+  and the pair norm's gain (1 + normal(0, 0.1)) from ``--seed``, the first
+  ``_STATE_TILE`` channels of the first Mamba layer at the slow end, in
+  the ONE tree that the program and the reference both read (the
+  configuration file's ``assumed.seeded_weights``);
 - what is checked: ``check.n_requests`` requests of at most
   ``check.max_tokens``, prefix hits (seeded from a snapshot) first, and
   ``check.n_long`` COMPLETED requests of ``long_min_tokens`` to
@@ -46,18 +46,26 @@ differs:
   control named in ``check.controls_reported`` is reported and not judged
   (PERF.md says why);
 - the recurrent STATE of one running request is compared with the
-  reference's (``_state_check``), 128 channels at a time, in the FIRST
-  Mamba layer, under ``check.state_rel_diff_limit``; the reference with
+  reference's (``_state_check``), ``_STATE_TILE`` channels at a time, in the
+  FIRST Mamba layer, under ``check.state_rel_diff_limit``; the reference with
   its state rounded to ``check.control_state_dtype`` has to come out over
   that limit;
 - the last act of set-up is ``gc.collect()`` + ``gc.freeze()``, as in
   ``rollout_state_inproc``;
+- what a COLD run costs outside the window (the driver's check stops a
+  run at 360 s, and its first run of a cell finds no compiled program):
+  the weights are drawn a top-level stack a program, side by side
+  (``_make_weights``), the engine takes the traffic file's
+  ``engine.admit_buckets`` (two buckets: half the admission programs of
+  the default four), and the reference runs every checked sequence at ONE
+  padded length (``check.long_max_tokens``) in programs built side by
+  side before the first comparison (``build_ahead``);
 - under ``--rehearse`` the generic tiny preset leaves two layers:
   ``_rehearsal_arch`` sets a depth that keeps the three segments and small
   state-space sizes.
 
-The next ``benchmark`` issue should fold the SIX rollout drivers into one
-(PERF.md, section 7).
+This is the SEVENTH rollout driver: the next ``benchmark`` issue should
+fold them into one (ROADMAP B0(a); PERF.md, section 7).
 
 Tokens are counted exactly: what the requests completed in the window
 generated, plus what the requests still running at its end had generated,
@@ -82,6 +90,14 @@ from benchmark.drivers.rollout_state_inproc import _probe_state
 from benchmark.stats import percentile
 
 
+# channels a tile of the state's comparison; the first tile of the first
+# Mamba layer is seeded WHOLE at the slow end: one slow channel among 127
+# others moves its tile's norm by the chance of one random walk (the
+# reference rounded to bfloat16 read 0.022-0.154 of a tile's norm over 24
+# runs, once under the limit of 0.03), a tile of them reads its mean
+_STATE_TILE = 128
+
+
 def _rehearsal_arch(arch: dict) -> dict:
     """A depth that keeps the three segments, a window a prompt passes, and
     small state-space sizes."""
@@ -90,12 +106,16 @@ def _rehearsal_arch(arch: dict) -> dict:
         mamba_dt_rank=4, max_position_embeddings=512)
 
 
-def _seeded_init(params, seed: int):
-    """What normal(0, 0.02) would make degenerate (module docstring), from
-    ``seed``."""
-    key = jax.random.fold_in(weights.fold_seed(seed), 0x5A3)
+_SEEDED = {"ssm": ("A_log", "dt_bias", "D", "conv_w", "conv_b"),
+           "attn": ("lam_q1", "lam_k1", "lam_q2", "lam_k2", "subln")}
+
+
+@jax.jit
+def _seeded_leaves(key, mixer, attns):
+    """``_seeded_init``'s leaves (``_SEEDED``) from ``key``, given the ones
+    they replace (for their shapes and dtypes). ONE program: leaf by leaf
+    these were forty small ones, 14 s of a cold start."""
     ks = iter(jax.random.split(key, 16))
-    mixer = dict(params["ssm_layers"]["ssm"])
 
     def like(ref, x):
         return x.astype(ref.dtype)
@@ -105,40 +125,89 @@ def _seeded_init(params, seed: int):
         next(ks), (Ls, C), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
     a = jnp.broadcast_to(
         jnp.arange(1, N + 1, dtype=jnp.float32)[None, :, None], (Ls, N, C))
-    # channel 0 of the first layer at the slow end: the channel a state
-    # kept in 16 bits loses most of, in the layer ``_state_check`` compares
-    dt, a = dt.at[0, 0].set(1e-3), a.at[0, :, 0].set(1.0)
-    mixer["A_log"] = like(mixer["A_log"], jnp.log(a))
-    mixer["dt_bias"] = like(mixer["dt_bias"], dt + jnp.log(-jnp.expm1(-dt)))
-    mixer["D"] = like(mixer["D"], 1.0 + 0.1 * jax.random.normal(
-        next(ks), (Ls, C), jnp.float32))
+    # the first tile of the first layer at the slow end: the channels a
+    # state kept in 16 bits loses most of, in the layer ``_state_check``
+    # compares
+    dt = dt.at[0, :_STATE_TILE].set(1e-3)
+    a = a.at[0, :, :_STATE_TILE].set(1.0)
+    out = {
+        "A_log": like(mixer["A_log"], jnp.log(a)),
+        "dt_bias": like(mixer["dt_bias"], dt + jnp.log(-jnp.expm1(-dt))),
+        "D": like(mixer["D"], 1.0 + 0.1 * jax.random.normal(
+            next(ks), (Ls, C), jnp.float32)),
+    }
     for name in ("conv_w", "conv_b"):
         if name in mixer:
-            mixer[name] = like(mixer[name], jax.random.uniform(
+            out[name] = like(mixer[name], jax.random.uniform(
                 next(ks), mixer[name].shape, jnp.float32, -0.5, 0.5))
-    out = {**params, "ssm_layers": {**params["ssm_layers"], "ssm": mixer}}
-    for tree in ("layers", "cross_layers"):
-        attn = dict(params[tree]["attn"])
-        for name in ("lam_q1", "lam_k1", "lam_q2", "lam_k2"):
-            attn[name] = like(attn[name], 0.4 * jax.random.normal(
+    pairs = {}
+    for tree in ("layers", "cross_layers"):     # (a jit hands a dict sorted)
+        attn = attns[tree]
+        pairs[tree] = {
+            name: like(attn[name], 0.4 * jax.random.normal(
                 next(ks), attn[name].shape, jnp.float32))
-        attn["subln"] = like(attn["subln"], 1.0 + 0.1 * jax.random.normal(
-            next(ks), attn["subln"].shape, jnp.float32))
-        out[tree] = {**params[tree], "attn": attn}
+            for name in _SEEDED["attn"][:-1]}
+        pairs[tree]["subln"] = like(
+            attn["subln"], 1.0 + 0.1 * jax.random.normal(
+                next(ks), attn["subln"].shape, jnp.float32))
+    return out, pairs
+
+
+def _seeded_init(params, seed: int):
+    """What normal(0, 0.02) would make degenerate (module docstring), from
+    ``seed``."""
+    def some(tree, names):
+        return {k: tree[k] for k in names if k in tree}
+
+    mixer = params["ssm_layers"]["ssm"]
+    attns = {tree: params[tree]["attn"] for tree in ("layers", "cross_layers")}
+    new_mixer, pairs = _seeded_leaves(
+        jax.random.fold_in(weights.fold_seed(seed), 0x5A3),
+        some(mixer, _SEEDED["ssm"]),
+        {t: some(a, _SEEDED["attn"]) for t, a in attns.items()})
+    out = {**params, "ssm_layers": {
+        **params["ssm_layers"], "ssm": {**mixer, **new_mixer}}}
+    for tree, new in pairs.items():
+        out[tree] = {**params[tree], "attn": {**attns[tree], **new}}
     return out
 
 
-def _memoised(ref):
-    """The reference's ``next_token_logprobs`` with its results kept: the
+def _make_weights(shapes, seed: int, dtype):
+    """``weights.make_weights`` a top-level stack of the tree, all at once
+    on threads: the one program over this model's 65 leaves takes the
+    chip's compiler 30-46 s of a cold start, a stack's takes its share of
+    that and they are built side by side. Each stack draws from a key of
+    its own (``seed`` with the stack's number above the bits ``--seed``
+    uses)."""
+    import concurrent.futures
+
+    names = sorted(shapes)
+    with concurrent.futures.ThreadPoolExecutor(len(names)) as pool:
+        # (a stack under its own name: what a leaf is drawn as follows
+        # from the names on its path, ``final_ln``'s too)
+        made = [pool.submit(weights.make_weights, {name: shapes[name]},
+                            seed + ((k + 1) << 40), dtype)
+                for k, name in enumerate(names)]
+        return {name: m.result()[name] for name, m in zip(names, made)}
+
+
+def _memoised(ref, length: int):
+    """The reference's ``next_token_logprobs`` with its results kept (the
     verdicts and the controls each ask for the float32 and the
-    served-dtype pass of every sample."""
+    served-dtype pass of every sample) and every sequence padded to
+    ``length``, whatever the caller's own padding: the reference's
+    programs are then the ones ``build_ahead`` built, on every seed (a
+    length of its own a group of samples was fifteen programs more a
+    group, 170-290 s of a run whose samples fell otherwise than the last
+    run's: PERF.md section 6)."""
     plain, kept = ref.next_token_logprobs, {}
 
     def cached(params, arch, tokens, dtype, pad_to):
-        key = (tuple(tokens), str(dtype), pad_to, tuple(sorted(
+        assert len(tokens) <= length, (len(tokens), length)
+        key = (tuple(tokens), str(dtype), tuple(sorted(
             (k, str(v)) for k, v in arch.items() if k.startswith("control_"))))
         if key not in kept:
-            kept[key] = plain(params, arch, tokens, dtype, pad_to)
+            kept[key] = plain(params, arch, tokens, dtype, length)
         return kept[key]
 
     return plain, cached
@@ -165,7 +234,12 @@ def _check(params, arch: dict, served_dtype: str, short, long_, chk: dict,
     """The verdict on both groups and the four controls (module
     docstring)."""
     ref = correct.reference_module(arch["reference"])
-    plain, ref.next_token_logprobs = _memoised(ref)
+    # ONE padded length for every sequence the check reads: the longest a
+    # checked request (or the state's probe) may have
+    ref.build_ahead(
+        params, arch, ("float32", served_dtype, chk["control_dtype"]),
+        chk["long_max_tokens"], state_dtype=chk["control_state_dtype"])
+    plain, ref.next_token_logprobs = _memoised(ref, chk["long_max_tokens"])
     try:
         check = _judge(params, arch, served_dtype, short, chk)
         check["n_long_sequences"] = len(long_)
@@ -226,7 +300,7 @@ def _check(params, arch: dict, served_dtype: str, short, long_, chk: dict,
 
 def _state_check(params, arch: dict, probe, chk: dict) -> Dict:
     """The program's recurrent state after ``probe``'s tokens against the
-    float32 reference's, 128 channels at a time (the norm of the
+    float32 reference's, ``_STATE_TILE`` channels at a time (the norm of the
     difference over the norm of the reference's tile), in the FIRST Mamba
     layer: its inputs are one matmul from the embedding, where every later
     layer's carry the residual stream's rounding in the serving dtype.
@@ -235,7 +309,7 @@ def _state_check(params, arch: dict, probe, chk: dict) -> Dict:
     over the limit."""
     ref = correct.reference_module(arch["reference"])
     tokens, got = probe
-    pad = -(-len(tokens) // 256) * 256
+    pad = chk["long_max_tokens"]      # the length ``_check`` built ahead
 
     def first_layer(arch):
         return ref.recurrent_state(
@@ -244,7 +318,7 @@ def _state_check(params, arch: dict, probe, chk: dict) -> Dict:
     want = first_layer(arch)                              # [C, N]
     rounded = first_layer(
         dict(arch, control_state_dtype=chk["control_state_dtype"]))
-    tile = min(128, want.shape[0])
+    tile = min(_STATE_TILE, want.shape[0])
 
     def tiles(a):
         return a.reshape(-1, tile, a.shape[-1])
@@ -271,7 +345,7 @@ def run(bench) -> Dict:
     eng_opts = mix["engine"]
     cfg = sut.model_config(arch, mix.get("model_overrides", {}))
     params = _seeded_init(
-        weights.make_weights(
+        _make_weights(
             sut.weight_shapes(cfg, cfg.dtype), bench.seed,
             jnp.dtype(cfg.dtype)),
         bench.seed)
@@ -291,6 +365,7 @@ def run(bench) -> Dict:
         max_new_tokens_cap=out_hi, page_size=page, n_pages=n_pages,
         enable_prefix_cache=eng_opts["enable_prefix_cache"],
         state_snapshots=eng_opts["state_snapshots"],
+        admit_buckets=eng_opts["admit_buckets"],
         seed=bench.seed % (2**31 - 1),
     )
     decode_steps = eng_opts["decode_steps"]
@@ -411,10 +486,6 @@ def run(bench) -> Dict:
         state_snapshot_hits=grew("state_snapshot_hits"),
         window_pages_released=grew("window_pages_released"),
     )
-    if "admit_token_layers_run" in stats1:
-        bench.counters.update(
-            admit_token_layers_run=grew("admit_token_layers_run"),
-            admit_token_layers_skipped=grew("admit_token_layers_skipped"))
     bench.facts["chunk_resident_tokens"] = resident   # one per engine.step span
     end_to_end = {
         "rollout_tokens_per_s": tokens / window,
@@ -515,8 +586,6 @@ def run(bench) -> Dict:
             "state_snapshot_bytes": grew("state_snapshot_bytes"),
             "state_snapshot_evictions": grew("state_snapshot_evictions"),
             "window_pages_released": grew("window_pages_released"),
-            "admit_token_layers_run": grew("admit_token_layers_run"),
-            "admit_token_layers_skipped": grew("admit_token_layers_skipped"),
             "slots_held": grew("slots_held"),
             "preemptions": grew("preemptions"),
             "kv_write_tiles": grew("kv_write_tiles"),

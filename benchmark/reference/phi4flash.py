@@ -60,9 +60,13 @@ that forgot the window, or read pages it had given back),
 place of the difference).
 
 Departures from a textbook forward, all to fit beside a model that fills
-the chip: one layer at a time is cast from the stored dtype to the compute
-dtype, attention runs in blocks of queries, and the head is applied in
-vocabulary blocks with a running log-sum-exp. None changes the
+the chip: one layer at a time is cut from its stack and cast from the
+stored dtype to the compute dtype (inside the compiled layer), attention
+runs in blocks of queries, and the head is applied in vocabulary blocks
+with a running log-sum-exp. And two to fit a check's time: a kind of layer
+is ONE compiled program a dtype and a length (the layer's index, its
+window, its ``lambda`` constant and the controls' switches are arguments),
+and ``build_ahead`` builds those programs side by side. None changes the
 mathematics. In float32 it runs under
 ``jax.default_matmul_precision("highest")``.
 """
@@ -117,6 +121,7 @@ STATE_DTYPE = jnp.float32
 
 _VOCAB_BLOCK = 16384
 _QUERY_BLOCK = 512
+_BUILD_THREADS = 8      # ``build_ahead``: programs built side by side
 
 
 def sizes(arch: dict) -> dict:
@@ -166,6 +171,13 @@ def _mlp(x, lp, eps):
     return x + (jax.nn.silu(h @ m["w_gate"]) * (h @ m["w_up"])) @ m["w_down"]
 
 
+def _layer_of(stack, i, dtype):
+    """Layer ``i`` of a stack of layers, in the compute dtype (cut inside
+    the compiled layer: one layer at a time is cast, and no copy of a
+    layer is made outside it)."""
+    return jax.tree.map(lambda a: a[i].astype(dtype), stack)
+
+
 def lambda_init(l: int) -> float:
     """``lam0`` of layer ``l`` (0-based)."""
     c0, c1, c2 = LAMBDA_INIT
@@ -174,9 +186,10 @@ def lambda_init(l: int) -> float:
 
 def _diff_attention(q, k, v, a, valid, window, lam0, *, eps, dtype, lam_zero):
     """q ``[T, Hq, D]`` over k, v ``[T, Hkv, D]``: the differential form,
-    causal (and inside ``window``), in blocks of queries. ``lam0``: the
-    layer's constant (an argument, not a static: one compiled layer serves
-    every depth)."""
+    causal (and inside ``window``), in blocks of queries. ``lam0`` (the
+    layer's constant), ``window`` (None: none) and ``lam_zero`` are
+    arguments, not statics: one compiled layer serves every depth, window
+    and full layers, and the control without the difference."""
     T, _, D = q.shape
     q1, q2 = q[:, 0::2], q[:, 1::2]
     k1, k2 = k[:, 0::2], k[:, 1::2]
@@ -205,21 +218,20 @@ def _diff_attention(q, k, v, a, valid, window, lam0, *, eps, dtype, lam_zero):
     lam = (jnp.exp(jnp.sum(a["lam_q1"].astype(f32) * a["lam_k1"].astype(f32)))
            - jnp.exp(jnp.sum(a["lam_q2"].astype(f32) * a["lam_k2"].astype(f32)))
            + lam0)
-    if lam_zero:
-        lam = 0.0
+    lam = jnp.where(lam_zero, 0.0, lam)
     d = a1 - lam * a2
     d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + eps)
     ctx = (1.0 - lam0) * d * a["subln"].astype(f32)
     return ctx.astype(dtype).reshape(T, -1)
 
 
-@functools.partial(
-    jax.jit, static_argnames=(
-        "n_q", "n_kv", "eps", "dtype", "window", "lam_zero"))
-def _attn_layer(x, lp, valid, lam0, *, n_q, n_kv, eps, dtype, window,
-                lam_zero):
-    """Self attention: the layer's output and its K/V."""
-    lp = jax.tree.map(lambda a: a.astype(dtype), lp)
+@functools.partial(jax.jit, static_argnames=("n_q", "n_kv", "eps", "dtype"))
+def _attn_layer(x, stack, i, valid, lam0, window, lam_zero, *, n_q, n_kv,
+                eps, dtype):
+    """Self attention, layer ``i`` of its stack: the layer's output and its
+    K/V. ``window``: a number of positions (a full layer's is the
+    sequence's length)."""
+    lp = _layer_of(stack, i, dtype)
     T = x.shape[0]
     a = lp["attn"]
     h = _ln(x, lp["ln1"], eps)
@@ -232,10 +244,9 @@ def _attn_layer(x, lp, valid, lam0, *, n_q, n_kv, eps, dtype, window,
     return _mlp(x + ctx @ a["wo"] + a["bo"], lp, eps), (k, v)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_q", "eps", "dtype", "lam_zero"))
-def _cross_layer(x, lp, kv, valid, lam0, *, n_q, eps, dtype, lam_zero):
-    lp = jax.tree.map(lambda a: a.astype(dtype), lp)
+@functools.partial(jax.jit, static_argnames=("n_q", "eps", "dtype"))
+def _cross_layer(x, stack, i, kv, valid, lam0, lam_zero, *, n_q, eps, dtype):
+    lp = _layer_of(stack, i, dtype)
     T = x.shape[0]
     a = lp["attn"]
     h = _ln(x, lp["ln1"], eps)
@@ -246,8 +257,8 @@ def _cross_layer(x, lp, kv, valid, lam0, *, n_q, eps, dtype, lam_zero):
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "dtype"))
-def _gmu_layer(x, lp, memory, *, eps, dtype):
-    lp = jax.tree.map(lambda a: a.astype(dtype), lp)
+def _gmu_layer(x, stack, i, memory, *, eps, dtype):
+    lp = _layer_of(stack, i, dtype)
     g = lp["gmu"]
     h = _ln(x, lp["ln1"], eps)
     gate = jax.nn.silu((h @ g["w_in"]).astype(jnp.float32))
@@ -256,12 +267,12 @@ def _gmu_layer(x, lp, memory, *, eps, dtype):
 
 @functools.partial(
     jax.jit, static_argnames=("dt_rank", "d_state", "eps", "dtype", "round_to"))
-def _ssm_layer(x, lp, zero_at, keep_at, *, dt_rank, d_state, eps, dtype,
-               round_to):
+def _ssm_layer(x, stack, i, zero_at, keep_at, *, dt_rank, d_state, eps,
+               dtype, round_to):
     """The layer's output, its scan output before the gate (the memory),
     and its recurrent state ``[d_inner, d_state]`` after token ``keep_at``
     (zeros where no token is)."""
-    lp = jax.tree.map(lambda a: a.astype(dtype), lp)
+    lp = _layer_of(stack, i, dtype)
     m = lp["ssm"]
     T = x.shape[0]
     h = _ln(x, lp["ln1"], eps)
@@ -353,29 +364,29 @@ def _forward(params, arch: dict, ids, valid, dt, keep_at=-1, n_states=None):
     at = dict.fromkeys(stacks, 0)
     memory = shared_kv = None
     for l, (kind, window) in enumerate(layer_kinds(arch)):
-        lp = jax.tree.map(lambda a: a[at[kind]], params[stacks[kind]])
+        lp = params[stacks[kind]], jnp.int32(at[kind])
         at[kind] += 1
         if kind == "mamba":
             x, memory, kept = _ssm_layer(
-                x, lp, zero_at, keep_at, dt_rank=sz["dt_rank"],
+                x, *lp, zero_at, keep_at, dt_rank=sz["dt_rank"],
                 d_state=sz["d_state"], eps=eps, dtype=dt,
                 round_to=None if round_to is None else jnp.dtype(round_to))
             states.append(kept)
             if len(states) == n_states:
                 return None, states
         elif kind == "attention":
-            if arch.get("control_no_window"):
-                window = None
+            if window is None or arch.get("control_no_window"):
+                window = ids.shape[0]           # every position
             x, shared_kv = _attn_layer(
-                x, lp, valid, jnp.float32(lambda_init(l)), n_q=n_q,
-                n_kv=arch["num_key_value_heads"], eps=eps, dtype=dt,
-                window=window, lam_zero=lam_zero)
+                x, *lp, valid, jnp.float32(lambda_init(l)), jnp.int32(window),
+                jnp.bool_(lam_zero), n_q=n_q,
+                n_kv=arch["num_key_value_heads"], eps=eps, dtype=dt)
         elif kind == "gmu":
-            x = _gmu_layer(x, lp, memory, eps=eps, dtype=dt)
+            x = _gmu_layer(x, *lp, memory, eps=eps, dtype=dt)
         else:
             x = _cross_layer(
-                x, lp, shared_kv, valid, jnp.float32(lambda_init(l)),
-                n_q=n_q, eps=eps, dtype=dt, lam_zero=lam_zero)
+                x, *lp, shared_kv, valid, jnp.float32(lambda_init(l)),
+                jnp.bool_(lam_zero), n_q=n_q, eps=eps, dtype=dt)
     return _head_logprobs(
         x, params["final_ln"], params["embed"]["weight"], labels,
         eps=eps, dtype=dt), states
@@ -414,6 +425,70 @@ def recurrent_state(params, arch: dict, tokens, dtype: str, pad_to: int,
         _, states = _forward(
             params, arch, ids, valid, dt, keep_at=n - 1, n_states=n_layers)
     return np.stack(jax.device_get(states))
+
+
+def build_ahead(params, arch: dict, dtypes, pad_to: int, state_dtype=None):
+    """Build every program that the forwards in ``dtypes`` at ``pad_to``
+    are made of (each kind of layer and the head; with ``state_dtype``
+    also the float32 state-space layer that rounds its state to it), all
+    at once on ``_BUILD_THREADS`` threads, each by one run on a sequence of
+    token 0. A forward meets its programs one after another, and the chip's
+    compiler takes 10-20 s over a float32 layer at the highest precision
+    (4-6 s in 16 bits): fifteen programs are minutes in a row and one
+    program's time side by side. Nothing here computes a result."""
+    import concurrent.futures
+
+    _, ids, valid = _padded([0] * pad_to, pad_to)
+    eps, sz = float(arch["layer_norm_eps"]), sizes(arch)
+    n_q, n_kv = arch["num_attention_heads"], arch["num_key_value_heads"]
+    i0, none = jnp.int32(0), jnp.int32(-1)
+    lam0, lam_zero = jnp.float32(0), jnp.bool_(False)
+    memory = jnp.zeros((pad_to, sz["d_inner"]), jnp.float32)
+
+    def calls(dt, round_to=None):
+        x = params["embed"]["weight"][ids].astype(dt)
+        kv = jnp.zeros((pad_to, n_kv, sz["head_dim"]), dt)
+        kw = {"eps": eps, "dtype": dt}
+
+        def ssm():
+            return _ssm_layer(
+                x, params["ssm_layers"], i0, none, none, round_to=round_to,
+                dt_rank=sz["dt_rank"], d_state=sz["d_state"], **kw)
+
+        def attn():
+            return _attn_layer(
+                x, params["layers"], i0, valid, lam0, jnp.int32(pad_to),
+                lam_zero, n_q=n_q, n_kv=n_kv, **kw)
+
+        def gmu():
+            return _gmu_layer(x, params["gmu_layers"], i0, memory, **kw)
+
+        def cross():
+            return _cross_layer(
+                x, params["cross_layers"], i0, (kv, kv), valid, lam0,
+                lam_zero, n_q=n_q, **kw)
+
+        def head():
+            return _head_logprobs(
+                x, params["final_ln"], params["embed"]["weight"], ids, **kw)
+
+        return [ssm] if round_to is not None else [
+            ssm, attn, gmu, cross, head]
+
+    def build(dt, call):
+        # (the precision is a thread's own setting, and part of what a
+        # compiled program is kept under)
+        precision = "highest" if dt == jnp.float32 else "default"
+        with jax.default_matmul_precision(precision):
+            jax.block_until_ready(call())
+
+    todo = [(jnp.dtype(d), c) for d in dtypes for c in calls(jnp.dtype(d))]
+    if state_dtype is not None:
+        f32 = jnp.dtype("float32")
+        todo += [(f32, c) for c in calls(f32, jnp.dtype(state_dtype))]
+    with concurrent.futures.ThreadPoolExecutor(_BUILD_THREADS) as pool:
+        for done in [pool.submit(build, *t) for t in todo]:
+            done.result()
 
 
 def sequence_logprobs(params, arch: dict, ids, dtype: str = "float32"):

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 
 from areal_tpu.models import hf, transformer as tfm
@@ -247,21 +248,15 @@ def test_dense_cache_prefill_and_decode_are_the_reference(params):
     np.testing.assert_allclose(np.asarray(got), want[n0 - 1:], atol=TOL)
 
 
-_PAGED = {}
-
-
-def _paged_run(params, skip_readers):
+def test_paged_admission_in_chunks_then_decode_is_the_reference(params):
     """Admission in chunks of a page over a table a cache layer, then one
-    token a step, held to the reference; returns the pool and the state
-    (computed once a variant: three tests read it)."""
-    if skip_readers in _PAGED:
-        return _PAGED[skip_readers]
+    token a step, held to the reference."""
     ids = _ids(5, 46)
     want = _want(params, ids)
     page, n0, M = 8, 21, 8
     extend = jax.jit(lambda p, c, st, toks, table, start, n: tfm.extend_paged(
         p, CFG, c, toks, table, start, n, ssm=st, slots=jnp.asarray([0]),
-        use_pallas=False, skip_readers=skip_readers))
+        use_pallas=False))
     step = jax.jit(lambda p, c, st, tok, table, lens: tfm.decode_step_paged(
         p, CFG, c, tok, table, lens, jnp.asarray([True]), use_pallas=False,
         ssm=st))
@@ -284,32 +279,53 @@ def _paged_run(params, skip_readers):
                 params, cache, st, jnp.asarray(ids[t : t + 1]), table, lens)
             got.append(jax.nn.log_softmax(logits)[0, ids[t + 1]])
     np.testing.assert_allclose(np.asarray(got), want[n0:], atol=TOL)
-    _PAGED[skip_readers] = np.asarray(cache.pages), np.asarray(st.ssm)
-    return _PAGED[skip_readers]
 
 
-@pytest.mark.parametrize("skip_readers", [True, False])
-def test_paged_admission_in_chunks_then_decode_is_the_reference(
-        params, skip_readers):
-    _paged_run(params, skip_readers)
+def _weights_read(fn, params):
+    """The leaves of ``params`` (their paths as strings) that any equation
+    of ``fn(params)``'s jaxpr takes: what the traced program reads."""
+    closed = jax.make_jaxpr(fn)(params)
+    read = {v for eqn in closed.jaxpr.eqns for v in eqn.invars
+            if isinstance(v, jax.extend.core.Var)}
+    paths = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]]
+    assert len(paths) == len(closed.jaxpr.invars)
+    return {p for p, v in zip(paths, closed.jaxpr.invars) if v in read}
 
 
-def test_admission_skips_the_cross_decoder_and_leaves_the_same_bits(params):
-    """With the cross-decoder skipped at admission (it keeps nothing of
-    it) and run all the same, the pool and the state are EQUAL."""
-    for x, y in zip(_paged_run(params, True), _paged_run(params, False)):
-        np.testing.assert_array_equal(x, y)
-    # ... and the program that skips it holds none of its weights' matmuls
-    def n_dots(skip):
-        toks = jnp.zeros((1, 8), jnp.int32)
-        table = jnp.zeros((CFG.period, 1, 8), jnp.int32)
-        jaxpr = jax.make_jaxpr(lambda p: tfm.extend_paged_kv(
-            p, CFG, tfm.PagedKVCache.empty(CFG, 16, 8), toks, table,
-            jnp.zeros((1,), jnp.int32), jnp.full((1,), 8, jnp.int32),
-            ssm=tfm.SSMState.empty(CFG, 1), slots=jnp.zeros((1,), jnp.int32),
-            skip_readers=skip))(params)
-        return str(jaxpr).count("dot_general")
-    assert n_dots(True) < n_dots(False)
+@pytest.mark.parametrize("stack", ["gmu_layers", "cross_layers"])
+def test_admission_reads_no_weight_of_the_cross_decoder(params, stack):
+    """Admission stops behind the last layer that writes a cache or a
+    state (the test above holds what it wrote to the reference): its
+    program takes no weight of a gated memory unit or a cross-attention
+    layer, and every weight of the layers before them; the decode step
+    takes them all."""
+    toks = jnp.zeros((1, 8), jnp.int32)
+    table = jnp.zeros((CFG.period, 1, 8), jnp.int32)
+    zero = jnp.zeros((1,), jnp.int32)
+
+    def admit(p):
+        return tfm.extend_paged_kv(
+            p, CFG, tfm.PagedKVCache.empty(CFG, 16, 8), toks, table, zero,
+            jnp.full((1,), 8, jnp.int32), ssm=tfm.SSMState.empty(CFG, 1),
+            slots=zero)
+
+    def decode(p):
+        return tfm.decode_step_paged(
+            p, CFG, tfm.PagedKVCache.empty(CFG, 16, 8), zero, table,
+            jnp.full((1,), 8, jnp.int32), jnp.asarray([True]),
+            use_pallas=False, ssm=tfm.SSMState.empty(CFG, 1))
+
+    def of(paths, tree):
+        return {p for p in paths if p.startswith(f"['{tree}']")}
+
+    every = {jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]}
+    admitted, decoded = _weights_read(admit, params), _weights_read(decode, params)
+    assert of(every, stack) and not of(admitted, stack)
+    assert of(decoded, stack) == of(every, stack)
+    for writers in ("ssm_layers", "layers"):
+        assert of(admitted, writers) == of(every, writers)
 
 
 # ---- the Mamba-1 recurrence ------------------------------------------- #
@@ -461,6 +477,58 @@ def test_reference_controls_compute_another_function(params, control):
     assert moved.max() > 1e-4
     if "control_zero_state_at" in control:
         assert moved[:15].max() == 0 and moved[16:].max() > 1e-4
+
+
+def test_the_cell_s_weights_a_stack_at_once_are_drawn_as_the_tree_s():
+    """``rollout_yoco_inproc._make_weights`` (a program a top-level stack,
+    built side by side) draws every leaf as ``weights.make_weights`` draws
+    it in the whole tree: a norm's gain about 1 (the FINAL norm's too,
+    whose path is its stack's name and nothing else), the rest about 0;
+    two stacks draw from keys of their own."""
+    from benchmark import sut, weights
+    from benchmark.drivers import rollout_yoco_inproc as drv
+
+    shapes = sut.weight_shapes(CFG, CFG.dtype)
+    made = drv._make_weights(shapes, 4_800_000_123, jnp.float32)
+    whole = weights.make_weights(shapes, 4_800_000_123, jnp.float32)
+    assert jax.tree.structure(made) == jax.tree.structure(whole)
+    for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(made)[0],
+            jax.tree.leaves(whole)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        gain = weights._kind(path) == "gain"
+        assert abs(float(a.mean()) - gain) < 0.1, jax.tree_util.keystr(path)
+        assert abs(float(b.mean()) - gain) < 0.1
+    ln1 = [made[t]["ln1"]["weight"] for t in ("layers", "cross_layers")]
+    assert np.abs(np.asarray(ln1[0][0] - ln1[1][0])).max() > 1e-3
+
+
+def test_reference_builds_its_programs_ahead_and_meets_them_again(params):
+    """``build_ahead`` at a length and in the dtypes of a check builds
+    every program the forwards there are made of (a kind of layer each,
+    the head, the state-space layer that rounds its state): the forwards
+    after it, the controls too (window, full and ``lambda`` = 0 are
+    arguments of ONE attention program), build none, and read what they
+    read without it."""
+    from areal_tpu.base import jitcache
+
+    ids, pad = list(_ids(21, 30)), 48
+    before = _want(params, ids)
+    layers = (ref._ssm_layer, ref._attn_layer, ref._gmu_layer,
+              ref._cross_layer, ref._head_logprobs)
+    ref.build_ahead(params, ARCH, ("float32", "bfloat16"), pad,
+                    state_dtype="bfloat16")
+    built = jitcache.total_cache_size(layers)
+    for arch in (ARCH, dict(ARCH, control_no_window=True),
+                 dict(ARCH, control_lambda_zero=True),
+                 dict(ARCH, control_zero_state_at=16)):
+        for dtype in ("float32", "bfloat16"):
+            got = ref.next_token_logprobs(params, arch, ids, dtype, pad)[0]
+            if arch is ARCH and dtype == "float32":
+                np.testing.assert_allclose(got, before, atol=1e-6)
+    ref.recurrent_state(params, dict(ARCH, control_state_dtype="bfloat16"),
+                        ids, "float32", pad, n_layers=1)
+    assert jitcache.total_cache_size(layers) == built
 
 
 def test_reference_state_is_the_dense_cache_s(params):

@@ -72,12 +72,62 @@ def test_admission_in_chunks_then_paged_decode_is_the_reference(
     outs = _run(eng, prompts, max_new=24)
     for rid, o in outs.items():
         _assert_reference(params, prompts[int(rid)], o)
-    # admission ran the 6 layers that write a cache or a state and skipped
-    # the cross-decoder's 2 at every prefilled position
-    n = eng.stats["prefill_tokens"]
-    assert n == sum(len(p) - 1 for p in prompts)
-    assert eng.stats["admit_token_layers_run"] == 6 * n
-    assert eng.stats["admit_token_layers_skipped"] == 2 * n
+    assert eng.stats["prefill_tokens"] == sum(len(p) - 1 for p in prompts)
+
+
+@pytest.mark.parametrize("n_prompts", [1, 3, 4])
+def test_waves_without_a_bucket_of_one_are_the_reference(params, n_prompts):
+    """The cell's admission buckets (``grpo16_closed128_out15k_yoco``: 2
+    and 8; here 2 and 4): a wave of one is a row and a padding row, a wave
+    of three a row past them, and each is the reference's, state and
+    pages; half the admission programs of (1, 2, 4, 8) are built at a
+    start."""
+    prompts = [_prompt(20 + i, n) for i, n in enumerate((19, 3, 30, 12))]
+    prompts = prompts[:n_prompts]
+    eng = _engine(params, admit_buckets=(2, 4))
+    outs = _run(eng, prompts, max_new=12)
+    for rid, o in outs.items():
+        _assert_reference(params, prompts[int(rid)], o)
+    assert {k[0] for k in eng._jit_extend} <= {2, 4}
+    # ... and a second wave over the first's snapshot and pages
+    again = _run(eng, prompts[:1] * 2, max_new=6)
+    for o in again.values():
+        _assert_reference(params, prompts[0], o)
+
+
+def test_the_write_kernel_is_traced_once_for_the_cache_kinds(params):
+    """A table a cache kind (three here, nine in the published model): the
+    write of a decode step traces ``kv_page_write`` for the FIRST kind and
+    finds the trace again for the others (what a start pays for a kind is
+    tracing and lowering the kernel), and leaves the pool the scatter
+    leaves."""
+    from areal_tpu.ops.pallas import kv_page_write
+
+    K, B, M = CFG.period, 2, 4
+    cache = tfm.PagedKVCache.empty(CFG, 16, PAGE)
+    L = cache.pages.shape[0] * K
+    shape = (L, B, 1) + cache.pages.shape[3:4] + cache.pages.shape[5:]
+    ks = jax.random.normal(jax.random.key(1), shape, cache.pages.dtype)
+    vs = jax.random.normal(jax.random.key(2), shape, cache.pages.dtype)
+    table = jnp.arange(K * B * M, dtype=jnp.int32).reshape(K, B, M) % 16
+    start, count = jnp.asarray([3, 9]), jnp.asarray([1, 1])
+    traced = []
+    plain = kv_page_write._write_kernel
+
+    def counting(*a, **kw):
+        traced.append(1)
+        return plain(*a, **kw)
+
+    kv_page_write._write_kernel = counting
+    try:
+        got = jax.jit(lambda c: tfm._write_chunk_kv(
+            c, ks, vs, table, start, count, use_pallas=True))(cache)
+    finally:
+        kv_page_write._write_kernel = plain
+    want = tfm._write_chunk_kv(
+        cache, ks, vs, table, start, count, use_pallas=False)
+    assert K == 3 and len(traced) == 1
+    np.testing.assert_array_equal(got.pages, want.pages)
 
 
 def test_group_through_a_snapshot_equals_the_prefix_cache_off(params):
