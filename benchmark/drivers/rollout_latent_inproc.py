@@ -49,6 +49,7 @@ import numpy as np
 
 from benchmark import correct, mla_flops, sut, traffic_gen, weights
 from benchmark.drivers.rollout_inproc import _warm_admission
+from benchmark.resident import ChunkResident
 from benchmark.stats import percentile
 
 
@@ -200,6 +201,8 @@ def run(bench) -> Dict:
     live: Dict[str, Dict] = {}      # rid -> request record
     done: List[Dict] = []
     chunk_resident: List[int] = []  # resident tokens at each chunk's start
+    chunk_distinct: List[int] = []  # the same, a shared prompt page once
+    resident_count = ChunkResident(page, decode_steps)
 
     def submit(req: traffic_gen.Request):
         engine.submit(GenRequest(
@@ -217,17 +220,12 @@ def run(bench) -> Dict:
         bench.samples["n_running"].append(engine.n_running())
         # a request that found no pages waits out this whole chunk
         bench.samples["n_pending"].append(engine.n_pending())
-        # resident context the decode kernel had to read in this chunk:
-        # each running request's prompt + what it had generated, midway
-        # (the newest submissions still pending hold no slot yet)
-        res = 0
-        for rec in list(live.values())[: len(live) - engine.n_pending()]:
-            r = rec["req"]
-            res += len(r.prompt) - 1 + min(
-                r.max_new_tokens,
-                rec["chunks"] * decode_steps + decode_steps // 2)
-            rec["chunks"] += 1
-        chunk_resident.append(res)
+        # resident context of this chunk, once a slot and once a distinct
+        # page (the newest submissions still pending hold no slot yet)
+        per_slot, distinct = resident_count.count(
+            list(live.values())[: len(live) - engine.n_pending()])
+        chunk_resident.append(per_slot)
+        chunk_distinct.append(distinct)
         with bench.span("resolve"):
             for o in outs:
                 rec = live.pop(o.rid)
@@ -286,6 +284,7 @@ def run(bench) -> Dict:
         prefill_tokens=stats1["prefill_tokens"] - stats0["prefill_tokens"],
     )
     bench.facts["chunk_resident_tokens"] = resident   # one per engine.step span
+    bench.facts["chunk_distinct_tokens"] = chunk_distinct[n_chunks0:]
     end_to_end = {
         "rollout_tokens_per_s": tokens / window,
         "rollout_norm_latency_p90_ms": (

@@ -85,12 +85,20 @@ def page_bytes(arch: dict, page: int, itemsize: int = 2) -> int:
 
 def resident_bytes(
     arch: dict, resident_tokens: int, window_resident_tokens: int,
-    itemsize: int = 2,
-) -> int:
+    itemsize: int = 2, distinct_ratio: float = 1.0,
+) -> float:
     """Bytes one decode step must read: the full layers over every
-    resident token, the window layers over ``sum(min(len, window))``."""
+    resident token, the window layers over ``sum(min(len, window))``.
+
+    ``distinct_ratio`` (``benchmark/resident.py``: distinct over per-slot
+    resident tokens) scales the FULL layers' part only: rows of a group
+    share their prompt's pages there for as long as they run. The window
+    layers' part stays per slot: a row's window leaves its prompt behind
+    after ``sliding_window_size`` generated tokens, and until then what it
+    shares is a part of ``min(len, window)`` that this count does not
+    follow. Left at 1, it is what the cache HOLDS a step's worth of."""
     by_kind = kv_bytes_per_token_by_kind(arch, itemsize)
-    return (by_kind["full"] * resident_tokens
+    return (by_kind["full"] * resident_tokens * distinct_ratio
             + by_kind["window"] * window_resident_tokens)
 
 

@@ -14,11 +14,20 @@ It cannot pass 100 %: lengths only grow inside a chunk and a slot that
 finishes keeps its length until it is refilled, so the bytes are a lower
 bound of what the kernel read, and every call that read them is in the
 time. A program whose chunks carry no ``cache_layers``, or whose trace has
-no such kernel, reads nothing."""
+no such kernel, reads nothing.
+
+The numerator counts a DISTINCT page once: the per-slot count above times
+``distinct / per_slot`` of the traced chunks, which is the traffic's and
+not the program's (``benchmark/resident.py``: rows of a GRPO group that
+run together hold the same whole prompt pages; today's kernel reads them
+once a row, so the reading stands under the per-slot one by about
+``gen.kv_shared_share``). A kernel added later must carry a name the
+pattern matches (``paged_decode*``), or its time is not counted.
+"""
 
 import jax.numpy as jnp
 
-from benchmark import loop_flops, program_spans, trace_reduce
+from benchmark import loop_flops, program_spans, resident, trace_reduce
 
 UNIT = "%"
 LAYER = "decode kernels"
@@ -41,7 +50,8 @@ def read(bench):
         if attrs.get("cache_layers") != loop_flops.cache_layers(bench.arch):
             continue
         tokens_read += attrs.get("steps", 0) * attrs.get("resident_tokens", 0)
-    if seconds <= 0 or tokens_read <= 0:
+    ratio = resident.traced_ratio(bench)
+    if seconds <= 0 or tokens_read <= 0 or ratio is None:
         return None
-    return 100.0 * tokens_read * per_token / (
+    return 100.0 * tokens_read * ratio * per_token / (
         bench.peaks["hbm_bytes_per_s"]) / seconds

@@ -14,11 +14,20 @@ times the chunk's steps: lengths only grow inside a chunk and a slot that
 finishes keeps its length until it is refilled, so this is a lower bound
 of what the kernel read and the share cannot pass 100 %. A program whose
 chunks carry no such count, or whose trace has no ``%mla_decode``, reads
-nothing."""
+nothing.
+
+The numerator counts a DISTINCT page once: the per-slot count above times
+``distinct / per_slot`` of the traced chunks, which is the traffic's and
+not the program's (``benchmark/resident.py``: rows of a GRPO group that
+run together hold the same whole prompt pages; today's kernel reads them
+once a row, so the reading stands under the per-slot one by about
+``gen.kv_shared_share``). A kernel added later must carry a name the
+pattern matches (``mla_decode*``), or its time is not counted.
+"""
 
 import jax.numpy as jnp
 
-from benchmark import mla_flops, program_spans, trace_reduce
+from benchmark import mla_flops, program_spans, resident, trace_reduce
 
 UNIT = "%"
 LAYER = "decode kernels"
@@ -37,9 +46,10 @@ def read(bench):
             bench, "gen_engine/chunk", traced_only=True):
         attrs = c.get("attrs", {})
         tokens_read += attrs.get("resident_tokens", 0) * attrs.get("steps", 0)
-    if seconds <= 0 or tokens_read <= 0:
+    ratio = resident.traced_ratio(bench)
+    if seconds <= 0 or tokens_read <= 0 or ratio is None:
         return None
     itemsize = jnp.dtype(bench.arch["serving_dtype"]).itemsize
-    least = tokens_read * mla_flops.latent_bytes_per_token(
+    least = tokens_read * ratio * mla_flops.latent_bytes_per_token(
         bench.arch, itemsize) / bench.peaks["hbm_bytes_per_s"]
     return 100.0 * least / seconds

@@ -8,7 +8,9 @@
    under ``testdata/``, against the numbers stored beside it.
 3. The traffic generator: the same seed gives the same work, another seed
    the same sizes in another order.
-4. The FLOP and byte arithmetic against hand-worked values.
+4. The FLOP and byte arithmetic against hand-worked values, and the
+   resident count of a decode chunk (``resident.py``) on a hand-made
+   population (its cases in full: ``python -m pytest benchmark/tests``).
 5. Every cell with ``--rehearse`` (tiny preset, control flow only), unless
    ``--no-cells`` is given.
 
@@ -20,7 +22,7 @@ import os
 import subprocess
 import sys
 
-from benchmark import flops, trace_reduce, traffic_gen
+from benchmark import flops, resident, trace_reduce, traffic_gen
 from benchmark.run import HERE, REHEARSAL_EXIT, ROOT, applies, load_json, load_reader
 
 FAILED = []
@@ -150,6 +152,20 @@ def check_flops():
           "train >= 3x forward")
 
 
+def check_resident():
+    def group(n, plen, tok):
+        return [{"req": traffic_gen.Request("r", [tok] * plen, 4096), "chunks": 0}
+                for _ in range(n)]
+
+    rows = group(16, 641, 1) + group(3, 300, 2) + group(1, 900, 3)
+    per_slot, distinct = resident.ChunkResident(128, 16).count(rows)
+    check(per_slot == 16 * 648 + 3 * 307 + 907,
+          "resident: per slot, prompt - 1 + half a chunk a row")
+    check(per_slot - distinct == (15 * 5 + 2 * 2) * 128,
+          "resident: a group's whole prompt pages count once, a lone row's all")
+    check(all(r["chunks"] == 1 for r in rows), "resident: the count advances the rows")
+
+
 def check_cells(bench_json):
     for cell in bench_json["workloads"]:
         p = subprocess.run(
@@ -171,6 +187,7 @@ def main(argv):
     check_trace_reduce()
     check_traffic()
     check_flops()
+    check_resident()
     if "--no-cells" not in argv:
         check_cells(bench_json)
     print(f"{len(FAILED)} failed")

@@ -93,13 +93,21 @@ def shared_kv_readers(arch: dict) -> int:
 
 
 def resident_bytes(arch: dict, resident_tokens: int,
-                   window_resident_tokens: int, itemsize: int = 2) -> int:
+                   window_resident_tokens: int, itemsize: int = 2,
+                   distinct_ratio: float = 1.0) -> float:
     """Bytes one decode step must read of the cache: the full layer's
     resident positions once a READER, each window layer's ``sum(min(len,
-    window))``."""
+    window))``.
+
+    ``distinct_ratio`` (``benchmark/resident.py``: distinct over per-slot
+    resident tokens) scales the FULL layer's part only, for each of its
+    readers: rows of a group share their prompt's pages there for as long
+    as they run. The window layers' part stays per slot: a row's window
+    leaves its prompt behind after ``sliding_window`` generated tokens."""
     one = token_layer_bytes(arch, itemsize)
-    return one * (shared_kv_readers(arch) * resident_tokens
-                  + layers_of(arch)["window"] * window_resident_tokens)
+    return one * (
+        shared_kv_readers(arch) * resident_tokens * distinct_ratio
+        + layers_of(arch)["window"] * window_resident_tokens)
 
 
 _OPERAND = re.compile(r"<- f32\[([\d,]+)\]$")
