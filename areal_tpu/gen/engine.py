@@ -654,6 +654,15 @@ class GenerationEngine:
                 "kernel_steps_active": 0,
                 "kernel_steps": 0,
                 "kernel_steps_chained": 0,
+                # ... and, of the full layers' call: the pages the rows'
+                # tables hold under their lengths / the page copies the
+                # kernel's programs start (fewer where rows name the same
+                # pages: those go through the prefix program once a block)
+                # / its blocks in use / the rows seated in them
+                "kv_pages_named": 0,
+                "kv_pages_read": 0,
+                "kv_shared_groups": 0,
+                "kv_shared_rows": 0,
                 # pool tiles the ``kv_page_write`` kernel writes: of a decode
                 # chunk as dispatched, of an admission wave's prefill; stays 0
                 # where the XLA scatter writes the pool (``_kv_write_rows``)
@@ -2568,7 +2577,7 @@ class GenerationEngine:
                 # every row of the batch routes, free slots too
                 chunk_attrs.update(self._count_moe_rows(
                     self.B, decode_steps * cfg.n_passes))
-            counts = self._kernel_counts(W)
+            counts = self._kernel_counts(W, running)
             if counts is not None:
                 self.stats["resident_tokens"] += resident
             if self._kv_write_rows():
@@ -2603,10 +2612,13 @@ class GenerationEngine:
 
         return kv_page_write.tile_rows(cache.pages.dtype)
 
-    def _kernel_counts(self, W: int) -> Optional[Dict[str, int]]:
+    def _kernel_counts(
+        self, W: int, running: List[int],
+    ) -> Optional[Dict[str, int]]:
         """What the paged-decode kernel does at the first step of a chunk
-        of table width ``W``, from the kernel's own block plan over
-        the host's lengths, sorted as ``decode_step_paged`` sorts its rows.
+        of table width ``W`` that the slots ``running`` run, from the
+        kernel's own block plan over the host's lengths, sorted as
+        ``decode_step_paged`` sorts its rows.
         ``kernel_positions``: KV positions its body runs over (over
         ``resident_tokens``, how many times the resident KV it computes);
         ``kernel_steps_active`` of ``kernel_steps`` grid steps reach a page
@@ -2615,8 +2627,17 @@ class GenerationEngine:
         block after the call's first, their copies started by the last
         reached step of an earlier block. Free slots
         count as empty (on the device a finished slot keeps its length
-        until it is refilled). ``None`` where the chunk runs no such
-        kernel: the XLA gather path."""
+        until it is refilled). Where the step reads the pages several rows
+        name once (``ops/paged_attention.py:shared_prefix_applies``) the
+        full layers' call is two programs and the counts are of both
+        (``ops/pallas/paged_attention.py:shared_counts``, from the table
+        the chunk is dispatched with; only ``running`` rows, the ones active
+        on the device, sit in a group): ``kv_pages_named`` pages under the
+        rows' lengths, ``kv_pages_read`` page copies started,
+        ``kv_shared_groups`` blocks of the prefix program in use with
+        ``kv_shared_rows`` rows seated; ``1 - read / named`` is the
+        program's side of the benchmark's ``gen.kv_shared_share``. ``None``
+        where the chunk runs no such kernel: the XLA gather path."""
         cfg = self.cfg
         tp = self.mesh.shape["model"] if self.mesh is not None else 1
         pool_dtype = self.state.cache.pages.dtype
@@ -2641,7 +2662,32 @@ class GenerationEngine:
         lens, span = np.sort(self._lens_host), kp * self.page
         by_kind = []
         nblk = -(-W // kp)
-        for w in self._windows:
+        # the full layers' call where rows of the chunk name the same pages
+        # (the rule ``decode_step_paged`` applies): the prefix program and
+        # the rows' own pages, from the table the chunk is dispatched with
+        shares = paged_ops.shared_prefix_applies(
+            self._decode_use_pallas, width, heads, self.page, pool_dtype,
+            full_kinds=sum(w is None for w in self._windows),
+            quantized=self.state.cache.quantized, latent=cfg.mla is not None,
+            slot_order=cfg.ssm is not None, mesh=self.mesh,
+        )
+        pages = int((-(-lens // self.page)).sum())
+        shared = {"kv_pages_named": pages, "kv_pages_read": pages,
+                  "kv_shared_groups": 0, "kv_shared_rows": 0}
+        table = self._table_arg(slice(None), W) if shares else None
+        active = np.zeros((self.B,), bool)
+        active[running] = True
+        for j, w in enumerate(self._windows):
+            if w is None and shares:
+                of = pl_paged.shared_counts(
+                    table if table.ndim == 2 else table[j], self._lens_host,
+                    active, self.page, sb, kp, nblk,
+                    by_own=not self._windowed)
+                shared = {k: of[k] for k in shared}
+                by_kind.append((
+                    of["kernel_positions"], of["kernel_steps_active"],
+                    of["kernel_steps"], of["kernel_steps_chained"]))
+                continue
             first = None if w is None else pl_paged.first_visible(lens, w)
             by_kind.append((
                 pl_paged.kernel_positions(lens, sb, span, first),
@@ -2657,6 +2703,7 @@ class GenerationEngine:
             "kernel_steps_active": active,
             "kernel_steps": total,
             "kernel_steps_chained": chained,
+            **shared,
         }
         if self._windowed:
             for kind in ("full", "window"):

@@ -2636,8 +2636,30 @@ def decode_step_paged(
     if moe_grouped:
         params, routed = _hold_routed(params)
     new_lens = jnp.where(active, lens + 1, lens)
-    # the scan's rows, by length (``_o``); slot order again after it
-    if cfg.ssm is None:
+    kinds = cfg.layer_kinds
+    # the scan's rows, by length (``_o``); slot order again after it. Where
+    # the kernel runs and the step orders its own rows, what the full
+    # layers' table shows of rows that name the same pages is observed
+    # here, once: those pages go through the prefix pass, ``own_o`` is what
+    # is left of each row, and the rows go by THAT length (by length under
+    # window layers, which walk the whole row)
+    full = [j for j, (window, _) in enumerate(kinds) if window is None]
+    prefix = own_o = None
+    n_kv, page, width = cache.pages.shape[3:]
+    if paged_ops.shared_prefix_applies(
+        use_pallas, width, n_kv, page, cache.pages.dtype,
+        full_kinds=len(full), quantized=cache.scales is not None,
+        latent=cfg.mla is not None, slot_order=cfg.ssm is not None, mesh=mesh,
+    ):
+        shared_table = _kind_table(table, full[0])
+        plan, *own = paged_ops.shared_prefix_step(
+            shared_table, lens, active, page)
+        order, inverse = _length_order(
+            own[1] if len(full) == len(kinds) else lens)
+        prefix = paged_ops.prefix_pass(
+            plan, shared_table, page, order, inverse)
+        own_o = [a[order] for a in own]
+    elif cfg.ssm is None:
         order, inverse = _length_order(lens)
     else:
         order = inverse = jnp.arange(lens.shape[0])
@@ -2645,7 +2667,15 @@ def decode_step_paged(
     lens_o = lens[order]
     x = _embed(cfg, params, tokens[order], lens_o)    # [B, E]
     cos, sin = _cos_sin(cfg, lens_o)
-    kinds = cfg.layer_kinds
+
+    def attend(q, k, v, li, tbl, **kw):
+        if prefix is not None and kw["sliding_window"] is None:
+            tbl, n = own_o
+            kw["shared"] = prefix
+        else:
+            n = lens_o
+        return paged_ops.paged_decode_attention(
+            q, k, v, cache.pages, li, tbl, n, **kw)
 
     def ssm_layer(carry, lp):
         x, li, si, st, *shared = carry
@@ -2710,8 +2740,7 @@ def decode_step_paged(
         elif cfg.cca is not None:
             q, k, v, cc = _cca_qkv_step(
                 cfg, lp["attn"], h, cos, sin, lens_o, cc, active_o)
-            ctx = paged_ops.paged_decode_attention(
-                q, k, v, cache.pages, li, table_o, lens_o, **kw)
+            ctx = attend(q, k, v, li, table_o, **kw)
         elif _plan_scope(cfg, pos) is not None:
             # a plan whose attention layers differ: a window, a full or a
             # cross layer, each through its own cache layer's table. A
@@ -2734,16 +2763,12 @@ def decode_step_paged(
         elif cfg.layer_pattern is None:
             q, k, v = _pack_qkv(
                 cfg, *_qkv_roped(cfg, lp["attn"], h, cos, sin))  # q [B, H, D]
-            ctx = _unpack_ctx(cfg, paged_ops.paged_decode_attention(
-                q, k, v, cache.pages, li, table_o, lens_o, **kw
-            ), _attn_params(lp))
+            ctx = _unpack_ctx(
+                cfg, attend(q, k, v, li, table_o, **kw), _attn_params(lp))
         else:
             q, k, v = _qkv_roped(cfg, lp["attn"], h, cos, sin, rotary)
             with jax.named_scope(_attn_scope(window)):
-                ctx = paged_ops.paged_decode_attention(
-                    q, k, v, cache.pages, li, _kind_table(table_o, j),
-                    lens_o, **kw
-                )
+                ctx = attend(q, k, v, li, _kind_table(table_o, j), **kw)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))

@@ -27,7 +27,7 @@ Two implementations:
   via kernel-issued DMAs on TPU — no materialized gather.
 """
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -149,6 +149,85 @@ def latent_kernel_applies(
     )
 
 
+def shared_prefix_applies(
+    use_pallas: Optional[bool], head_dim: int, n_kv_heads: int, page: int,
+    pool_dtype, *, full_kinds: int = 1, quantized: bool = False,
+    latent: bool = False, slot_order: bool = False, mesh=None,
+) -> bool:
+    """Whether a decode step reads a page that several of its rows name
+    ONCE (:func:`shared_prefix_step`), from what the caller can observe:
+    where the paged kernel runs (:func:`decode_kernel_applies`) over a K/V
+    pool in the serving dtype, on one device, in a step that orders its
+    rows itself, over a cache with ONE kind of full-attention layer in its
+    period (``full_kinds``: each kind has a page table of its own, and the
+    step observes one). Today's call keeps: a period with no full kind or
+    with several, an int8 pool (a second array with another tile), a latent
+    pool (``mla_decode``: another program), a model whose per-slot state
+    holds the step to slot order (``slot_order``), a mesh of more than one
+    device, and the XLA gather path, which is the plain reference and stays
+    what it is."""
+    if full_kinds != 1 or quantized or latent or slot_order or (
+        mesh is not None and mesh.size > 1
+    ):
+        return False
+    return decode_kernel_applies(
+        use_pallas, head_dim, n_kv_heads, page, pool_dtype)
+
+
+class PrefixPass(NamedTuple):
+    """What a layer's prefix pass takes of the step's
+    :func:`shared_prefix_step`: ``rows [G, R]`` the row in each seat (in
+    the step's order; ``B``: empty), ``seat [B]`` each row's seat, ``table
+    [G, M]`` and ``lens [G]`` the pages and positions a block's seats
+    share."""
+
+    rows: jnp.ndarray
+    seat: jnp.ndarray
+    table: jnp.ndarray
+    lens: jnp.ndarray
+
+
+def shared_prefix_step(
+    table: jnp.ndarray, lens: jnp.ndarray, active: jnp.ndarray, page: int,
+):
+    """What a decode step observes of its page table, once, before the
+    layer scan: ``(plan, own_table, own_lens)``, rows in slot order. Rows
+    that are ``active`` and whose tables agree on their leading whole pages
+    form groups
+    (``ops/pallas/paged_attention.py:shared_prefix``, the ``plan``); the
+    group's pages are read by the prefix pass (:func:`prefix_pass`), and
+    ``own_table`` / ``own_lens`` are what is left of each row: its table
+    from its first private page on and the positions behind the shared
+    ones. The step orders its rows by ``own_lens``, so that the kernel's
+    blocks hold rows of like work. A table that shares nothing gives
+    ``own_* == table, lens`` and a prefix pass whose blocks reach no
+    step."""
+    from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+    B, M = table.shape
+    plan = pl_paged.shared_prefix(
+        table, lens, active, page, *pl_paged.prefix_plan(B), xp=jnp)
+    own_table = jnp.take_along_axis(
+        table,
+        jnp.minimum(plan.pages[:, None] + jnp.arange(M)[None, :], M - 1),
+        axis=1)
+    return plan, own_table, lens - plan.pages * page
+
+
+def prefix_pass(plan, table, page: int, order, inverse) -> PrefixPass:
+    """The layers' :class:`PrefixPass` of a step's ``plan`` and ``table``
+    (slot order, :func:`shared_prefix_step`) for rows that run in
+    ``order`` (``inverse`` puts them back)."""
+    B = table.shape[0]
+    place = jnp.concatenate([inverse, jnp.full((1,), B, inverse.dtype)])
+    return PrefixPass(
+        rows=place[plan.rows],
+        seat=plan.seat[order],
+        table=table[jnp.minimum(plan.rows[:, 0], B - 1)],
+        lens=(plan.n * page).astype(jnp.int32),
+    )
+
+
 def kv_write_kernel_applies(
     use_pallas: Optional[bool], pages, quantized: bool = False, mesh=None,
 ) -> bool:
@@ -191,6 +270,7 @@ def paged_decode_attention(
     mesh=None,
     scales: Optional[jnp.ndarray] = None,  # [L, P, 2, Hkv, page] int8 pools
     value_width: Optional[int] = None,
+    shared: Optional[PrefixPass] = None,
 ) -> jnp.ndarray:
     """Single-token attention against paged KV plus the token itself.
     The pool holds positions ``[0, lens)``; the query sits at position
@@ -224,7 +304,14 @@ def paged_decode_attention(
     ``v_self`` is not read, a position's value is the first
     ``value_width`` of its key, and the result is ``[B, H, value_width]``.
     The Pallas kernel is the same one, named ``mla_decode`` (one stream
-    DMA'd once, 32 query rows on it); it is not sharded over a mesh."""
+    DMA'd once, 32 query rows on it); it is not sharded over a mesh.
+
+    ``shared`` (where :func:`shared_prefix_applies`; from
+    :func:`shared_prefix_step`, with its ``own_table`` and ``own_lens`` as
+    ``table`` and ``lens``): the pages several rows name go through the
+    prefix program once a block, the rows' queries folded into one dot, and
+    the kernel over the rows' own pages goes on from the state it leaves:
+    one softmax state over both, the current token folded last."""
     B, H, D = q.shape
     Hkv = pages.shape[3]
     n_rep = H // Hkv
@@ -259,6 +346,15 @@ def paged_decode_attention(
             "divides n_kv_heads, or pass use_pallas=False for the XLA "
             "gather path."
         )
+    if shared is not None:
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        kw = dict(softmax_scale=softmax_scale, soft_cap=soft_cap)
+        acc, ml = pl_paged.decode_prefix(
+            q, pages, layer, shared.table, shared.lens, shared.rows, **kw)
+        return pl_paged.decode(
+            q, k_self, v_self, pages, layer, table, lens,
+            carry=(acc, ml, shared.seat), **kw)
     if decode_kernel_applies(
         use_pallas, D, Hkv, pages.shape[4], pages.dtype, tp
     ):

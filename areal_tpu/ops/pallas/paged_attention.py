@@ -123,10 +123,62 @@ every row of its block costs the one test, as a step past the longest row
 does (``_reached_spans`` gives both ends; the chain enters a block at its
 FIRST reached step, not at step 0). The full-attention program has none
 of this and is what it was.
+
+SHARED PAGES (PR 50). ACTIVE rows of one call whose tables agree on their
+leading whole pages (a GRPO group on one prompt, through the prefix cache;
+a re-admitted request; a later turn: only the table is read) form a GROUP
+(:func:`shared_prefix`, which ``decode_step_paged`` applies once a step,
+outside its layer scan, and :func:`shared_counts` applies on the host for
+the engine's census: one function, numpy or ``jax.numpy``). A
+full-attention layer is then TWO programs over ONE float32 softmax state,
+in the order the one program had (prefix positions, own positions, the
+current token last):
+
+- ``paged_decode_prefix`` (:func:`decode_prefix`): grid over group BLOCKS of
+  16 seats (:func:`prefix_plan`; a group of more takes several). A block
+  copies each page its seats share ONCE, and multiplies it once against all
+  its seats' queries, gathered from the whole-in-VMEM ``q`` by row and folded
+  with the ``n_rep`` query heads of a kv head into the row dimension of one
+  dot (``[Hkv, 16 * n_rep, D] x [Hkv, S, D]``: 96 rows on one copy of a K
+  tile at 12q/2kv where the program over rows has 6 and loads the tile 16
+  times; 16 rows where it has 1 at 16 kv heads). No mask but the shared
+  length. It leaves ``m``, ``l``, ``acc`` BY SEAT.
+- ``paged_decode`` with ``carry`` (:func:`decode`): each row's OWN pages,
+  through a table shifted past the shared ones and the own length (both
+  made once a step by the caller, which also orders the rows by OWN length,
+  so the body shrinks with the bytes); ``_init`` takes the row's seat's
+  state where it filled constants. Nothing else of the program changes.
+
+What the two cost in the rollout cells' ``jit_chunk`` (operands in VMEM;
+traced pairs on one seed a cell, the last ~4 s of a 40 s window, shared
+pages 33-37 % of the per-slot bytes there; PERF.md §6, PR 50), ms a layer's
+call, the one program -> own + prefix: 128 slots x 12q/2kv, page 128:
+0.2761 -> 0.1750 + 0.0352 (-24 %); 64 slots x 28q/4kv (SB 4): 0.2532 ->
+0.1620 + 0.0282 (-25 %); 64 slots x 16q/16kv, page 128 (SB 1): 0.9226 ->
+0.5823 + 0.0646 (-30 %); 72 slots x 16q/16kv, page 64 (SB 2): 0.5242 ->
+0.3857 + 0.0365 (-19 %). ALONE (operands in HBM, 200 calls a scan, the
+sandbox's scheduler run of each cell's traffic for a table; the form with
+one reshaped store a seat, whose prefix program is 0.006 ms longer at 2 kv
+heads than the committed one) the first, third and fourth read 0.2801 ->
+0.2306 (own 0.1851, prefix 0.0507, each alone), 0.8872 -> 0.6876 (0.6467,
+0.0525) and 0.5389 -> 0.3978 (0.3729, 0.0386): the table understates a
+cell, whose small operands sit in VMEM (PR 47's
+lesson). Where nothing is shared (the same lengths, every page a row's
+own) the prefix program's blocks reach no step, 0.0055-0.0076 ms alone for
+its launch and its 16-32 tests, and the two programs are +0.2 to +0.5 % of
+the one (0.2811 -> 0.2826, 0.8875 -> 0.8893, 0.5389 -> 0.5414). The prefix
+program's seat loops (gather the queries, leave the state) are traced ONCE
+with a store a kv head: as Python loops they run 0.006 ms a call faster at
+2 kv heads (0.0392 for 0.0454 alone) and are 512 stores to trace and lower
+in every chunk program at 16; one reshaped store a seat is slower (0.0512).
+The own-pages program keeps its ROWS a loop where the other programs unroll
+them (``_each_entry``): alone the call is the same (0.1913 | 0.1919 ms) and
+a chunk program lowers 2-3 s sooner on the chip's host, which more than
+pays for tracing the prefix program beside it.
 """
 
 import functools
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +188,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
 LANES = 128
+# a softmax state between two programs: ``m`` and ``l`` of a query row share
+# one ``[..., LANES]`` vector, ``m`` in the lanes under this one
+ML_SPLIT = LANES // 2
+# pages of one grid step (the table's width where that is less): the own
+# program's default (:func:`block_plan`) and the prefix program's
+PAGES_PER_STEP = 8
 
 
 def _interpret() -> bool:
@@ -181,6 +239,14 @@ def _resident_bytes(batch, n_q, n_kv, head_dim, dv, dtype, latent) -> int:
             + (1 if latent else 2) * tiled(n_kv, head_dim))
 
 
+def _state_bytes(batch, n_q, head_dim) -> int:
+    """VMEM a float32 softmax state of ``batch`` rows holds for a call
+    (``acc`` and the ``m`` / ``l`` vector of every query row), tiled as
+    :func:`_resident_bytes` tiles."""
+    heads = -(-n_q // 8) * 8
+    return batch * heads * (-(-head_dim // LANES) * LANES + LANES) * 4
+
+
 def block_plan(
     batch: int,
     n_kv_heads: int,
@@ -188,7 +254,7 @@ def block_plan(
     page: int,
     table_width: int,
     pool_dtype,
-    pages_per_step: int = 8,
+    pages_per_step: int = PAGES_PER_STEP,
     slots_per_step: int = 8,
     streams: int = 2,
 ) -> Tuple[int, int]:
@@ -208,6 +274,85 @@ def block_plan(
     ) > 16 * 1024 * 1024:
         sb //= 2
     return sb, kp
+
+
+class SharedPrefix(NamedTuple):
+    """What a call's page table shows of rows that name the same pages
+    (:func:`shared_prefix`). A GROUP is the rows whose tables agree on their
+    leading ``n`` entries, all wholly under every member's length, with
+    ``n`` the longest such run each member has with any row; it sits in
+    BLOCKS of ``seats`` rows (a group of more takes several)."""
+
+    pages: Any   # [B] leading pages a row reads through its block (0: none)
+    # [B] the row's seat, block * seats + place (blocks * seats: none)
+    seat: Any
+    rows: Any    # [blocks, seats] the row in each seat (B: empty)
+    n: Any       # [blocks] the block's shared pages (0: a block not in use)
+
+
+def prefix_plan(batch: int) -> Tuple[int, int]:
+    """``(seats, blocks)`` of the prefix pass over a call of ``batch`` rows:
+    16 rows a block, the GRPO group every rollout cell sends (with ``n_rep``
+    query heads a kv head that is ``16 * n_rep`` rows of the MXU on one copy
+    of a K tile), and a block for every four rows (a call of groups thinned
+    to 6-8 running members fills two thirds of them; rows of a group past
+    the last block read their pages themselves)."""
+    return min(16, batch), max(batch // 4, 1)
+
+
+def shared_prefix(
+    table, lens, active, page: int, seats: int, blocks: int, xp=np,
+):
+    """:class:`SharedPrefix` of a call's ``table [B, M]``, ``lens [B]`` and
+    ``active [B]`` (``xp``: numpy on the host, ``jax.numpy`` in the step;
+    ONE arithmetic, so the engine's census counts what the kernel does).
+    Only the table is read: two rows share a page where their tables name it
+    at the same place behind the same leading entries, whoever put it there
+    (a prefix hit, a re-admitted request, a later turn). Entries past a
+    row's whole pages are never compared as shared (the page a row is still
+    writing is its own; what lies past its length is stale), and a row that
+    is not ``active`` shares nothing: its result is thrown away, and a freed
+    slot's zeroed table under the length the device still has would
+    otherwise sit in a block beside every other freed slot. ``B x B``
+    compares and no sort, once a step, outside the layer scan."""
+    B, M = table.shape
+    ids = xp.arange(B)
+    whole = xp.where(active, lens // page, 0)
+    # lcp[b, c]: the leading entries rows b and c agree on, whole pages of
+    # both (behind its entries a row carries its index: two rows differ
+    # there at the latest, and a row and itself agree on nothing)
+    keyed = xp.concatenate([table, ids[:, None].astype(table.dtype)], axis=1)
+    differ = (keyed[:, None, :] != keyed[None, :, :]).argmax(axis=2)
+    lcp = xp.minimum(differ, xp.minimum(whole[:, None], whole[None, :]))
+    n = lcp.max(axis=1)
+    # rows of one n that agree on those n entries (an equivalence: agreement
+    # on a leading run is transitive); a row whose partners all share more
+    # with others is alone and reads its pages itself
+    mate = (lcp >= n[:, None]) & (n[None, :] == n[:, None]) & (n[:, None] > 0)
+    shared = mate.any(axis=1)
+    mate = mate | ((ids[:, None] == ids[None, :]) & shared[:, None])
+    head = xp.argmax(mate, axis=1)
+    place = (mate & (ids[None, :] < ids[:, None])).sum(axis=1)
+    size = mate.sum(axis=1)
+    # groups take their blocks in the order of their heads
+    is_head = shared & (head == ids)
+    blocks_of = xp.where(is_head, -(-size // seats), 0)
+    before = (xp.where(ids[None, :] < head[:, None], blocks_of[None, :], 0)
+              ).sum(axis=1)
+    block = before + place // seats
+    seated = shared & (block < blocks)
+    none = blocks * seats
+    seat = xp.where(seated, block * seats + place % seats, none)
+    at = seat[None, :] == xp.arange(none)[:, None]            # [seats, B]
+    rows = xp.where(at.any(axis=1), xp.argmax(at, axis=1), B)
+    rows = rows.reshape(blocks, seats)
+    first = rows[:, 0]
+    return SharedPrefix(
+        pages=xp.where(seated, n, 0),
+        seat=seat,
+        rows=rows,
+        n=xp.where(first < B, n[xp.minimum(first, B - 1)], 0),
+    )
 
 
 def first_visible(lens, sliding_window: Optional[int]):
@@ -299,6 +444,68 @@ def kernel_steps_chained(
     return max(int((lo < hi).sum()) - 1, 0)
 
 
+def _columns_compared(table, whole) -> int:
+    """Leading columns of ``table`` that :func:`shared_prefix` has to be
+    handed for rows of ``whole`` pages (0: not active) to get the plan of
+    the whole table: as far as the longest row's whole pages, or ``k`` of
+    them where no two rows that reach past ``k`` pages agree on all ``k``
+    (then no run is longer than ``k``; a GRPO group's prompt is a few
+    pages of a table of up to 128). The dense compare is most of what the
+    engine's census costs the host a chunk."""
+    width = max(int(whole.max()), 1)
+    k = 8
+    while k < width:
+        reach = table[whole > k, :k]
+        if len({row.tobytes() for row in reach}) == len(reach):
+            return k
+        k *= 2
+    return width
+
+
+def shared_counts(
+    table, lens, active, page: int, sb: int, kp: int, nblk: int,
+    by_own: bool = True,
+) -> dict:
+    """What the two programs of a full-attention call do over ``table [B,
+    M]``, ``lens [B]`` and ``active [B]`` (host arrays, slot order; ``sb``,
+    ``kp`` the own program's :func:`block_plan`, ``nblk`` its page blocks a
+    row; ``by_own`` false where the step keeps its rows by length), as the
+    engine's census names it. ``kernel_positions``: row-positions the
+    bodies run over, the prefix program's seated rows times the padded
+    positions of their block and the own program's
+    :func:`kernel_positions` over the own lengths; ``kernel_steps*`` as
+    :func:`kernel_steps` and :func:`kernel_steps_chained`, both programs;
+    ``kv_pages_named``: pages the rows' tables hold under their lengths;
+    ``kv_pages_read``: page copies the two programs start;
+    ``kv_shared_groups`` blocks in use and ``kv_shared_rows`` rows seated in
+    them."""
+    B = len(lens)
+    seats, blocks = prefix_plan(B)
+    lens, active = np.asarray(lens, np.int64), np.asarray(active)
+    table = np.asarray(table)
+    plan = shared_prefix(
+        table[:, :_columns_compared(table, np.where(active, lens // page, 0))],
+        lens, active, page, seats, blocks)
+    own, span = lens - plan.pages * page, kp * page
+    own = own[np.argsort(own if by_own else lens, kind="stable")]
+    shared_lens = plan.n * page
+    seated = (plan.rows < B).sum(axis=1)
+    prefix_steps = -(-shared_lens // span)
+    active, total = kernel_steps(own, sb, span, nblk)
+    return {
+        "kernel_positions": kernel_positions(own, sb, span)
+        + int((seated * span * prefix_steps).sum()),
+        "kernel_steps_active": active + int(prefix_steps.sum()),
+        "kernel_steps": total + blocks * nblk,
+        "kernel_steps_chained": kernel_steps_chained(own, sb, span, nblk)
+        + kernel_steps_chained(shared_lens, 1, span, nblk),
+        "kv_pages_named": int((-(-lens // page)).sum()),
+        "kv_pages_read": int((-(-own // page)).sum() + plan.n.sum()),
+        "kv_shared_groups": int((plan.n > 0).sum()),
+        "kv_shared_rows": int(seated.sum()),
+    }
+
+
 def _block_span(lens_ref, first_ref, b, *, sb: int, S: int, nblk: int):
     """``(lo, hi)`` of block ``b`` on the scalar core (:func:`_reached_spans`
     held to ``nblk``; ``first_ref`` is ``None`` but in a window program):
@@ -357,7 +564,15 @@ def _decode_kernel(
     windowed: bool,
     quantized: bool,
     dv: Optional[int] = None,
+    carried: bool = False,
 ):
+    # ``carried``: the program over the rows' OWN pages, behind the pages
+    # the prefix program (``_prefix_kernel``) read for their group. It
+    # starts from the state that program left BY SEAT: ``seat_ref [B]`` (a
+    # fourth scalar operand) each row's seat, and ``acc0_ref [seats, Hq,
+    # D]``, ``ml0_ref [seats, Hq, LANES]`` (``m`` in the lanes under
+    # ``ML_SPLIT``, ``l`` in the others) after ``vs_ref``; a row without a
+    # seat starts from the constants ``_init`` fills otherwise.
     # ``dv`` set: a LATENT pool ``[L, P, 1, 1, page, D]`` (absorbed MLA,
     # ``models/transformer.py``). Its one stream is key and value at once
     # for every query head: ``n_kv`` is 1, the value of a position is the
@@ -403,6 +618,11 @@ def _decode_kernel(
         (layer_ref, table_ref, lens_ref, q_ref, ks_ref, kv_hbm,
          o_ref, kv_scr, m_scr, l_scr, acc_scr, sems) = refs
         vs_ref = sc_hbm = sc_scr = sc_sems = None
+    elif carried:
+        (layer_ref, table_ref, lens_ref, seat_ref, q_ref, ks_ref, vs_ref,
+         acc0_ref, ml0_ref, kv_hbm, o_ref, kv_scr, m_scr, l_scr, acc_scr,
+         sems) = refs
+        sc_hbm = sc_scr = sc_sems = None
     else:
         (layer_ref, table_ref, lens_ref, q_ref, ks_ref, vs_ref, kv_hbm,
          o_ref, kv_scr, m_scr, l_scr, acc_scr, sems) = refs
@@ -418,11 +638,35 @@ def _decode_kernel(
     S = kp * page             # positions of one grid step
     layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
+    def _fresh():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    if carried:
+        n_seats = acc0_ref.shape[0]
+
+        @pl.when(j == 0)
+        def _init():
+            # what the prefix pass left for each row's seat: the row's own
+            # pages go on from it (a row without a seat from the constants)
+            def row(s, _):
+                seat = seat_ref[bb * sb + s]
+                taken = seat < n_seats
+                at = jnp.minimum(seat, n_seats - 1)
+                ml = ml0_ref[at]                              # [Hq,LANES]
+                m_scr[s, :Hq] = jnp.where(
+                    taken, jnp.broadcast_to(ml[:, :1], (Hq, LANES)), NEG_INF)
+                l_scr[s, :Hq] = jnp.where(
+                    taken,
+                    jnp.broadcast_to(
+                        ml[:, ML_SPLIT:ML_SPLIT + 1], (Hq, LANES)),
+                    0.0)
+                acc_scr[s, :Hq, :Dv] = jnp.where(taken, acc0_ref[at], 0.0)
+
+            jax.lax.fori_loop(0, sb, row, None)
+    else:
+        pl.when(j == 0)(_fresh)
 
     def _page_tests(slot):
         """``tests(pg) -> (held, not held)`` for the pages of the slot's
@@ -458,7 +702,10 @@ def _decode_kernel(
 
             jax.lax.fori_loop(0, kp, entry, None, unroll=True)
 
-        jax.lax.fori_loop(0, sb, row, None, unroll=True)
+        # (the program over own pages keeps its rows a loop: a third of what
+        # the unrolled rows cost every chunk program to lower, which pays
+        # for the prefix program beside it; PERF.md §6, PR 50)
+        jax.lax.fori_loop(0, sb, row, None, unroll=not carried)
 
     def _issue(bb_t, j_t, buf):
         """Start every resident-page DMA for REACHED step ``j_t`` of block
@@ -682,6 +929,256 @@ def _decode_kernel(
         o_ref[rows] = (acc / l).astype(o_ref.dtype)
 
 
+def _prefix_kernel(
+    layer_ref,   # [1] int32 scalar-prefetch: which layer of the pool
+    table_ref,   # [G, M] int32: the pages the seats of each block share
+    lens_ref,    # [G] int32: their positions, whole pages (0: not in use)
+    rows_ref,    # [G, R] int32: the row of ``q_ref`` in each seat
+    q_ref,       # [B, Hq, D] WHOLE in VMEM
+    kv_hbm,      # [L, P, 2, Hkv, page, D] whole pool, ANY/HBM
+    acc_out,     # [G * R, Hq, D] f32: the state BY SEAT
+    ml_out,      # [G * R, Hq, LANES] f32: m under ML_SPLIT, l in the rest
+    kv_scr,      # [2, 2, Hkv, KP*page, D] ONE stripe, double-buffered
+    m_scr,       # [Hkv, R * heads, LANES] f32
+    l_scr,
+    acc_scr,     # [Hkv, R * heads, D] f32
+    sems,        # DMA semaphores [2, KP]
+    qf_scr,      # [Hkv, R * heads, D] f32: the block's folded queries
+    ord_scr,     # [3] int32 SMEM: the buffer of the next step, the issues
+                 # so far, the block whose first step is already started
+    *,
+    scale: float,
+    page: int,
+    kp: int,
+    n_blocks: int,
+    n_kv: int,
+    heads: int,
+    seats: int,
+    soft_cap: Optional[float],
+):
+    """The PREFIX program, ``paged_decode_prefix``: grid ``(G,)``, a step a
+    GROUP BLOCK (:class:`SharedPrefix`). A block copies each page its seats
+    share ONCE, ``kp`` pages a step of an inner loop that runs as far as the
+    block's pages go (a block not in use costs the one test), and multiplies
+    it once against all its seats' queries: they are gathered from ``q_ref``
+    by row and folded with the ``heads`` query heads of a kv head into the
+    row dimension of ONE dot on that head's K tile (``seats * heads`` rows
+    where the program over rows has ``heads``). No mask but the shared
+    length (every seat sees all of it), no current token. It leaves each
+    seat's float32 ``m``, ``l`` and ``acc`` for :func:`decode`'s ``carry``.
+    The copies of a step run under the dots of the step before, across
+    blocks too: a block's last step starts the first of the next block in
+    use (blocks in use come first, :func:`shared_prefix`; a block that
+    finds its first step not started starts it itself)."""
+    g = pl.program_id(0)
+    D = q_ref.shape[2]
+    S = kp * page
+    n_rep = seats * heads     # rows of the fold a kv head
+    layer = layer_ref[0]
+    steps = pl.cdiv(lens_ref[g], S)
+
+    def _issue(b, j, buf):
+        """Start the copies of step ``j`` of block ``b`` into ``buf`` (the
+        call's first two issues also zero the VALUES of the stripes they
+        start no copy into: see ``_decode_kernel._issue``)."""
+        n_pages = lens_ref[b] // page
+        issued = ord_scr[1]
+
+        def entry(i, _):
+            at = pl.ds(pl.multiple_of(i * page, page), page)
+            held = j * kp + i < n_pages
+
+            @pl.when(held)
+            def _start():
+                pltpu.make_async_copy(
+                    kv_hbm.at[layer, table_ref[b, j * kp + i]],
+                    kv_scr.at[buf, :, :, at, :],
+                    sems.at[buf, i],
+                ).start()
+
+            @pl.when(jnp.logical_not(held) & (issued < 2))
+            def _zero():
+                kv_scr[buf, 1, :, at, :] = jnp.zeros(
+                    (n_kv, page, D), kv_scr.dtype)
+
+        jax.lax.fori_loop(0, kp, entry, None, unroll=True)
+        ord_scr[1] = issued + 1
+
+    @pl.when(g == 0)
+    def _prologue():
+        ord_scr[0] = 0
+        ord_scr[1] = 0
+        ord_scr[2] = -1
+
+    @pl.when(steps > 0)
+    def _block():
+        @pl.when(ord_scr[2] != g)
+        def _first():
+            _issue(g, 0, ord_scr[0])
+
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        # the seats' queries, folded: seat ``r``'s ``heads`` rows of every
+        # kv head. Through float32 (the rows start anywhere in a tile), and
+        # ONE traced visit for all seats, as ``_each_entry``'s: as Python
+        # loops over seats x kv heads this and ``seat_out`` were 512 stores
+        # to trace and lower in every chunk program at 16 kv heads (the
+        # loops unrolled run 0.006 ms a call faster at 2 kv heads and cost
+        # seconds of every start; one reshaped store a seat, ``[Hq, D]`` to
+        # ``[Hkv, heads, D]``, is slower than a store a kv head: PERF.md
+        # §6, PR 50)
+        def seat_in(r, _):
+            row = jnp.minimum(rows_ref[g, r], q_ref.shape[0] - 1)
+            q_row = q_ref[row].astype(jnp.float32)            # [Hq_row, D]
+            for h in range(n_kv):
+                qf_scr[h, pl.ds(r * heads, heads), :] = (
+                    q_row[h * heads:(h + 1) * heads])
+
+        jax.lax.fori_loop(0, seats, seat_in, None)
+        q = qf_scr[...].astype(q_ref.dtype)                   # [Hkv,n_rep,D]
+        nxt = jnp.minimum(g + 1, n_blocks - 1)
+        next_in_use = (g + 1 < n_blocks) & (lens_ref[nxt] > 0)
+        n_len = lens_ref[g]
+
+        def step(j, _):
+            buf = ord_scr[0]
+            last = j + 1 >= steps
+
+            @pl.when(jnp.logical_not(last) | next_in_use)
+            def _prefetch():
+                _issue(jnp.where(last, nxt, g), jnp.where(last, 0, j + 1),
+                       1 - buf)
+
+            @pl.when(last & next_in_use)
+            def _handed():
+                ord_scr[2] = nxt
+
+            def wait(i, _):
+                @pl.when(j * kp + i < n_len // page)
+                def _wait():
+                    pltpu.make_async_copy(
+                        kv_hbm.at[layer, table_ref[g, j * kp + i]],
+                        kv_scr.at[
+                            buf, :, :,
+                            pl.ds(pl.multiple_of(i * page, page), page), :],
+                        sems.at[buf, i],
+                    ).wait()
+
+            jax.lax.fori_loop(0, kp, wait, None, unroll=True)
+            ord_scr[0] = 1 - buf
+            k = kv_scr[buf, 0]                                # [Hkv,S,D]
+            v = kv_scr[buf, 1]
+            sc = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ) * scale                                         # [Hkv,n_rep,S]
+            if soft_cap is not None:
+                sc = soft_cap * jnp.tanh(sc / soft_cap)
+            kpos = j * S + jax.lax.broadcasted_iota(
+                jnp.int32, (n_kv, n_rep, S), 2)
+            mask = kpos < n_len
+            sc = jnp.where(mask, sc, NEG_INF)
+            m_prev = m_scr[:, :, 0:1]                         # [Hkv,n_rep,1]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2, keepdims=True))
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)     # [Hkv,n_rep,S]
+            corr = jnp.exp(
+                jnp.where(m_prev > NEG_INF / 2, m_prev - m_new, 0.0))
+            l_new = corr * l_scr[:, :, 0:1] + jnp.sum(
+                p, axis=2, keepdims=True)
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )                                                 # [Hkv,n_rep,D]
+            acc_scr[...] = acc_scr[...] * corr + pv
+            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+        jax.lax.fori_loop(0, steps, step, None)
+        # the state by SEAT: seat ``r``'s ``heads`` rows of every kv head
+        # are its query heads in order
+        lane = jax.lax.broadcasted_iota(jnp.int32, m_scr.shape, 2)
+        m_scr[...] = jnp.where(lane < ML_SPLIT, m_scr[...], l_scr[...])
+
+        def seat_out(r, _):
+            piece = pl.ds(r * heads, heads)
+            for h in range(n_kv):
+                to = pl.ds(h * heads, heads)
+                acc_out[g * seats + r, to, :] = acc_scr[h, piece, :]
+                ml_out[g * seats + r, to, :] = m_scr[h, piece, :]
+
+        jax.lax.fori_loop(0, seats, seat_out, None)
+
+
+def decode_prefix(
+    q: jnp.ndarray,          # [B, Hq, D] the step's queries
+    pages: jnp.ndarray,      # [L, P, 2, Hkv, page, D] the WHOLE pool
+    layer: jnp.ndarray,      # scalar i32 layer index
+    table: jnp.ndarray,      # [G, M] i32: each block's shared pages
+    lens: jnp.ndarray,       # [G] i32: their positions (whole pages)
+    rows: jnp.ndarray,       # [G, R] i32: the row of ``q`` in each seat
+    *,
+    softmax_scale: Optional[float] = None,
+    soft_cap: Optional[float] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The prefix pass of a full-attention layer (module docstring, "SHARED
+    PAGES"; :func:`_prefix_kernel`): the pages each block's seats share are
+    copied once a block and multiplied once against all its seats' queries.
+    Returns the float32 softmax state by seat, ``(acc [G * R, Hq, D], ml [G
+    * R, Hq, LANES])``, for :func:`decode`'s ``carry``; the seats of a
+    block not in use are not written (no row's ``seat`` names them). A K/V
+    pool in the serving dtype."""
+    B, Hq, D = q.shape
+    L, P, streams, Hkv, page, _ = pages.shape
+    G, M = table.shape
+    seats = rows.shape[1]
+    heads = Hq // Hkv
+    if streams != 2 or jnp.dtype(pages.dtype) == jnp.int8:
+        raise ValueError(
+            f"the prefix program reads a K/V pool in the serving dtype; "
+            f"got {pages.shape} {pages.dtype}")
+    if softmax_scale is None:
+        softmax_scale = D ** -0.5
+    kp = min(PAGES_PER_STEP, M)
+    folded = (Hkv, seats * heads)
+    resident = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
+    return pl.pallas_call(
+        functools.partial(
+            _prefix_kernel, scale=softmax_scale, page=page, kp=kp,
+            n_blocks=G, n_kv=Hkv, heads=heads, seats=seats,
+            soft_cap=soft_cap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(G,),
+            in_specs=[
+                resident, pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+            out_specs=[resident, resident],
+            scratch_shapes=[
+                pltpu.VMEM((2, 2, Hkv, kp * page, D), pages.dtype),
+                pltpu.VMEM((*folded, LANES), jnp.float32),
+                pltpu.VMEM((*folded, LANES), jnp.float32),
+                pltpu.VMEM((*folded, D), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, kp)),
+                pltpu.VMEM((*folded, D), jnp.float32),
+                pltpu.SMEM((3,), jnp.int32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((G * seats, Hq, D), jnp.float32),
+            jax.ShapeDtypeStruct((G * seats, Hq, LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_scratch_bytes(
+                1, kp, page, Hkv, D, pages.dtype)
+            + _resident_bytes(B, Hq, Hkv, D, D, q.dtype, True)
+            + _state_bytes(G * seats, Hq, D) + 32 * 2**20,
+        ),
+        interpret=_interpret(),
+        # a name the benchmark's rooflines find (``^paged_decode``)
+        name="paged_decode_prefix",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), table, lens, rows, q, pages)
+
+
 def decode(
     q: jnp.ndarray,          # [B, Hq, D]
     k_self: jnp.ndarray,     # [B, Hkv, D] current token's K (not in pool)
@@ -694,10 +1191,11 @@ def decode(
     softmax_scale: Optional[float] = None,
     soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
-    pages_per_step: int = 8,
+    pages_per_step: int = PAGES_PER_STEP,
     slots_per_step: int = 8,
     scales: Optional[jnp.ndarray] = None,  # [L, P, 2, Hkv, page] f32
     value_width: Optional[int] = None,
+    carry: Optional[Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]] = None,
 ) -> jnp.ndarray:
     """The pool rides in whole (ANY memory space); the kernel issues its own
     per-page DMAs keyed by the scalar-prefetched layer index and page table
@@ -719,7 +1217,13 @@ def decode(
     ``sliding_window``: the query (at position ``lens``) sees itself and
     ``sliding_window - 1`` positions before it; the kernel is then the
     ``_window`` program of its name and reads each row's pages from its
-    first visible position on (module docstring)."""
+    first visible position on (module docstring).
+
+    ``carry``: the state :func:`decode_prefix` left by seat and each
+    row's seat, ``(acc, ml, seat [B])`` (module docstring, "SHARED PAGES"),
+    which the rows' own pages go on from: ``table`` and ``lens`` are then
+    each row's pages and positions BEHIND the ones its group shares. A K/V
+    pool in the serving dtype under full attention."""
     B, Hq, D = q.shape
     L, P, streams, Hkv, page, _ = pages.shape
     M = table.shape[1]
@@ -730,6 +1234,12 @@ def decode(
         raise ValueError(
             f"a latent pool is [L, P, 1, 1, page, D] in the serving dtype; "
             f"got {pages.shape}, scales={quantized}"
+        )
+    carried = carry is not None
+    if carried and (quantized or latent or sliding_window is not None):
+        raise ValueError(
+            "a carried state goes on over a K/V pool in the serving dtype "
+            "under full attention"
         )
     Dv = value_width if latent else D
     page_mult = page_multiple(pages.dtype)
@@ -766,6 +1276,7 @@ def decode(
         windowed=windowed,
         quantized=quantized,
         dv=value_width,
+        carried=carried,
     )
     # q, the current token's K/V and the output are WHOLE in VMEM for the
     # call: one copy in before the grid and one out after it. As blocks of
@@ -774,10 +1285,13 @@ def decode(
     # keeps them in VMEM (the engine's ``jit_chunk``) pays neither (module
     # docstring)
     resident = pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
+    # (a carried state is two more)
+    small = (
+        [q, k_self] if latent
+        else [q, k_self, v_self, *(carry[:2] if carried else ())]
+    )
     in_specs = [
-        resident,
-        resident,
-        *([] if latent else [resident]),
+        *([resident] * len(small)),
         pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
     ]
     scratch_shapes = [
@@ -791,8 +1305,8 @@ def decode(
     operands = [
         jnp.asarray(layer, jnp.int32).reshape(1), table, lens,
         *([first_visible(lens, sliding_window).astype(jnp.int32)]
-          if windowed else []),
-        q, k_self, *([] if latent else [v_self]), pages,
+          if windowed else [carry[2]] if carried else []),
+        *small, pages,
     ]
     if quantized:
         # scales ride whole in ANY/HBM like the pool; their scratch and
@@ -809,7 +1323,7 @@ def decode(
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4 if windowed else 3,
+            num_scalar_prefetch=4 if windowed or carried else 3,
             grid=(B // sb, nblk),
             in_specs=in_specs,
             out_specs=resident,
@@ -824,6 +1338,7 @@ def decode(
             vmem_limit_bytes=_scratch_bytes(
                 sb, kp, page, Hkv, D, pages.dtype, streams
             ) + _resident_bytes(B, Hq, Hkv, D, Dv, q.dtype, latent)
+            + (_state_bytes(carry[0].shape[0], Hq, D) if carried else 0)
             + 32 * 2**20,
         ),
         interpret=_interpret(),

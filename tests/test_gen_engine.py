@@ -584,8 +584,12 @@ class TestEngineSpans:
         # short rows and the one with the free slots reach only the first
         widths = [c["gen_engine/dispatch"]["attrs"]["table_width"]
                   for _, c in chunks]
+        # (and as many blocks again of the prefix program, a block for
+        # every four rows, none of which reaches a step: no row shares)
         assert [a["kernel_steps"] for a in attrs] == [
-            3 * -(-w // 8) for w in widths]
+            (3 + 3) * -(-w // 8) for w in widths]
+        assert all(a["kv_pages_read"] == a["kv_pages_named"] > 0
+                   and a["kv_shared_rows"] == 0 for a in attrs)
         assert all(a["kernel_steps_active"] < a["kernel_steps"]
                    for a in attrs)
         # sorted, the three free slots share block 0 with a short row: all

@@ -1061,3 +1061,127 @@ def test_admission_writes_through_one_program(params, use_pallas):
         len(eng._jit_extend) + len(eng._jit_kv_write)
         + len(eng._jit_commit) + len(eng._jit_chunk)
     )
+
+
+# --------------------------------------------------------------------- #
+# A GRPO group's prompt pages are read once a step (the prefix program)
+# --------------------------------------------------------------------- #
+
+SHARED_COUNTERS = ("kv_pages_named", "kv_pages_read", "kv_shared_groups",
+                   "kv_shared_rows")
+
+
+def _group_run(params, *, prefix_cache, lengths, n_pages=None, horizon=None,
+               steps=3, loner=True):
+    """A group on one prompt of two whole pages and a tail (and, ``loner``,
+    one request of its own), each member asking for its entry of
+    ``lengths``, through an engine that runs the paged kernel (interpret
+    mode). Returns outputs by rid, the engine, its decode-chunk spans and
+    the rids the dry rule preempted."""
+    from areal_tpu.base import tracing
+
+    rng = np.random.default_rng(11)
+    prompt = [int(x) for x in rng.integers(1, 128, 21)]
+    eng = GenerationEngine(
+        CFG, params, max_slots=8, max_seqlen=96, max_new_tokens_cap=64,
+        page_size=8, n_pages=n_pages, seed=0,
+        enable_prefix_cache=prefix_cache,
+    )
+    eng._decode_use_pallas = True
+    if horizon is not None:
+        eng.ADMIT_HORIZON = horizon
+    mark = time.perf_counter()
+    for i, g in enumerate(lengths):
+        eng.submit(GenRequest(
+            rid=f"g{i}", input_ids=prompt, max_new_tokens=g, greedy=True))
+    if loner:
+        eng.submit(GenRequest(
+            rid="alone", input_ids=[int(x) for x in rng.integers(1, 128, 13)],
+            max_new_tokens=7, greedy=True))
+    outs, preempted, n = {}, set(), 0
+    while eng.n_pending() or eng.n_running():
+        for o in eng.step(steps):
+            outs[o.rid] = o
+        preempted |= set(eng._carried)
+        n += 1
+        assert n < 400
+    chunks = [
+        r["attrs"] for r in tracing.spans_since(mark)
+        if r["name"] == "gen_engine/chunk" and r["attrs"].get("slots")
+    ]
+    return outs, eng, chunks, preempted
+
+
+SHARED_GROUPS = {
+    # five members that all run to one length beside a request of its own
+    "group": dict(lengths=[9] * 5),
+    # a member ends inside a chunk of three steps; the others go on
+    "member_finishes_inside_a_chunk": dict(lengths=[4, 10, 10, 11]),
+    # the group thins to one row, which then shares with nobody
+    "thinned_to_one": dict(lengths=[2, 3, 14], loner=False),
+    # a dry pool: members are held out and one is preempted (PR 43's dry
+    # rule), re-admitted over the prompt's filed pages when the short member
+    # has gone, and runs on beside another to the end
+    "preempted_and_readmitted": dict(
+        lengths=[16, 56, 60, 64], n_pages=21, horizon=0, loner=False),
+}
+
+
+@pytest.mark.parametrize("case", list(SHARED_GROUPS))
+def test_shared_group_decodes_as_without_the_cache(params, case):
+    """With the prefix cache the members' tables name the prompt's whole
+    pages; the decode chunks read them once a group (``kv_pages_read <
+    kv_pages_named``) and every member's tokens and log-probs are what the
+    same requests give on an engine without the cache, where every row
+    holds and reads its own copy."""
+    opt = SHARED_GROUPS[case]
+    outs, eng, chunks, preempted = _group_run(
+        params, prefix_cache=True, **opt)
+    want, cold, cold_chunks, _ = _group_run(
+        params, prefix_cache=False, **{**opt, "n_pages": None})
+    assert set(outs) == set(want)
+    for rid, o in outs.items():
+        assert o.output_ids == want[rid].output_ids, rid
+        np.testing.assert_allclose(
+            o.output_logprobs, want[rid].output_logprobs, atol=1e-4,
+            err_msg=rid)
+    assert all(all(k in a for k in SHARED_COUNTERS) for a in chunks)
+    sharing = [a for a in chunks if a["kv_shared_rows"]]
+    assert sharing
+    for a in sharing:
+        # the whole pages of the prompt that the members' tables name (two
+        # where a member found both filed), copied once a group and not
+        # once a member
+        assert a["kv_shared_groups"] == 1 and a["kv_shared_rows"] >= 2
+        assert a["kv_pages_read"] < a["kv_pages_named"]
+    first = chunks[0]
+    if "n_pages" not in opt:
+        assert first["kv_shared_rows"] == len(opt["lengths"])
+    assert first["kv_pages_named"] - first["kv_pages_read"] == 2 * (
+        first["kv_shared_rows"] - 1)
+    # without the cache no running row's table names another's page
+    # in ANY chunk: a slot freed inside the run keeps its length on the
+    # device over a row of zeros, and two of those are not a group
+    assert all(a["kv_pages_read"] == a["kv_pages_named"] > 0
+               and a["kv_shared_rows"] == a["kv_shared_groups"] == 0
+               for a in cold_chunks)
+    for k in SHARED_COUNTERS:
+        assert eng.stats[k] == sum(a[k] for a in chunks)
+    if case == "thinned_to_one":
+        assert chunks[-1]["slots"] == 1
+    if case == "preempted_and_readmitted":
+        assert preempted and eng.stats["preemptions"] >= 1
+        # the rows that RUN a chunk are its group, the member that came
+        # back among them (two run the last chunk); a member held out of a
+        # chunk is not active on the device and sits in no block
+        assert any(a["slots_held"] for a in chunks)
+        assert [a["kv_shared_rows"] for a in chunks] == [
+            a["slots"] if a["slots"] > 1 else 0 for a in chunks]
+        assert chunks[-1]["slots"] == 2
+    if case == "group":
+        from areal_tpu.gen.server import GenerationHTTPServer
+
+        m = GenerationHTTPServer(eng)._metrics_dict()
+        for k in SHARED_COUNTERS:
+            assert m[f"engine_{k}"] == eng.stats[k]
+        assert 0 < m["engine_kv_pages_read"] < m["engine_kv_pages_named"]

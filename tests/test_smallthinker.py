@@ -710,6 +710,48 @@ def test_one_kind_with_a_window_is_the_same_code(rng):
     assert eng.pool.n_free == 64 and eng.pool.reserved == 0
 
 
+@pytest.mark.parametrize("layout,shares", [
+    ([0, 1, 1, 1] * 2, True), ([0, 0, 1, 1] * 2, False),
+    ([0, 1, 0, 1] * 2, True),
+], ids=["one_full_kind", "two_full_kinds", "one_full_kind_period_2"])
+def test_a_group_s_shared_pages_with_layer_kinds(rng, layout, shares):
+    """A group on one prompt through the kernels (interpret mode), prefix
+    cache on: the members' full-kind tables name the prompt's whole pages.
+    With ONE full kind in the period those go through the prefix program
+    (``shared_prefix_applies``); with two, each with a table and pages of
+    its own, the step observes neither and every layer keeps the call it
+    had. Either way the served log-probs are the reference's."""
+    arch = dict(ARCH, sliding_window_layout=layout, rope_layout=layout)
+    cfg = _cfg(arch)
+    assert sum(w is None for w, _ in cfg.layer_kinds) == 1 + (not shares)
+    p = _weights(cfg, 5)
+    eng = _engine(p, cfg, n_pages=96)
+    eng._decode_use_pallas = True
+    prompt = _toks(rng, 14)
+    prompts = {f"g{i}": prompt for i in range(3)}
+    prompts["alone"] = _toks(rng, 6)
+    tracing.drain()
+    # the first member fills the registry, its siblings hit it
+    eng.submit(GenRequest(rid="g0", input_ids=prompt, max_new_tokens=12,
+                          temperature=1.0))
+    outs = {o.rid: o for o in eng.run_until_done(1)}
+    for rid, ids in list(prompts.items())[1:]:
+        eng.submit(GenRequest(rid=rid, input_ids=ids, max_new_tokens=12,
+                              temperature=1.0))
+    outs.update((o.rid, o) for o in eng.run_until_done(3))
+    for rid, ids in prompts.items():
+        want = _ref_logprobs(p, ids + outs[rid].output_ids, arch)[len(ids) - 1:]
+        np.testing.assert_allclose(
+            np.asarray(outs[rid].output_logprobs), want, atol=TOL_NATS,
+            err_msg=rid)
+    chunks = _chunk_attrs()
+    assert any(c["kv_shared_rows"] for c in chunks) == shares
+    assert all((c["kv_pages_read"] < c["kv_pages_named"])
+               == bool(c["kv_shared_rows"]) for c in chunks)
+    assert all(c["kernel_positions_window"] <= c["kernel_positions_full"]
+               for c in chunks)
+
+
 # ------------------------------------------------------------------ #
 # (iv) the kernels and the model's paged entry points, straight
 # ------------------------------------------------------------------ #

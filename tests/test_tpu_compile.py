@@ -108,6 +108,18 @@ def _compile(fn, *specs):
     return compiled
 
 
+def _custom_call_names(text: str) -> set:
+    """The names of a compiled program's Mosaic calls (``%name.3 = ...
+    custom-call(...), custom_call_target="tpu_custom_call"``), without the
+    number XLA gives the second of a name."""
+    return {
+        re.sub(r"\.\d+$", "", m.group(1))
+        for m in re.finditer(
+            r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            text)
+    }
+
+
 def _flash_specs(layout, T, one_chip):
     hq, hkv, d = layout["hq"], layout["hkv"], layout["d"]
     return (
@@ -217,6 +229,44 @@ def test_paged_decode_compiles(compiled_kernels, one_chip, page, int8, shape):
         )
 
     _compile(f, *_paged_specs(one_chip, page=page, int8=int8, **shape))
+
+
+@pytest.mark.parametrize(
+    "shape",
+    [
+        pytest.param(dict(page=128, B=128, M=40, P=2588),
+                     id="cell1-128x12q2kv-table40"),
+        pytest.param(dict(page=128, B=64, M=32, P=715, L=8, layout=OLMOE),
+                     id="olmoe-64x16q16kv-table32"),
+        pytest.param(dict(page=64, B=72, M=64, P=600, L=32, layout=OLMOE),
+                     id="ouro-72x16q16kv-page64"),
+        pytest.param(dict(page=128, B=64, M=40, P=1311, L=16, layout=QWEN_7B),
+                     id="cell3-64x28q4kv-table40"),
+    ],
+)
+def test_shared_prefix_programs_compile(compiled_kernels, one_chip, shape):
+    """What a full-attention layer runs where rows of the call name the
+    same pages (``ops/paged_attention.py:shared_prefix_step``): the plan
+    from the table, the ``paged_decode_prefix`` program over the blocks'
+    folded queries, and ``paged_decode`` over the rows' own pages from the
+    state it leaves, at the rollout cells' geometries. Every Mosaic call of
+    it carries a name the benchmark's rooflines find (``^paged_decode``)."""
+    from areal_tpu.ops import paged_attention as paged_ops
+
+    def f(q, ks, vs, pages, layer, table, lens):
+        page = pages.shape[4]
+        plan, own_table, own_lens = paged_ops.shared_prefix_step(
+            table, lens, lens > 0, page)
+        order = jnp.argsort(own_lens)
+        inverse = jnp.argsort(order)
+        prefix = paged_ops.prefix_pass(plan, table, page, order, inverse)
+        return paged_ops.paged_decode_attention(
+            q[order], ks[order], vs[order], pages, layer, own_table[order],
+            own_lens[order], shared=prefix, use_pallas=True)[inverse]
+
+    text = _compile(
+        f, *_paged_specs(one_chip, int8=False, **shape)).as_text()
+    assert _custom_call_names(text) == {"paged_decode", "paged_decode_prefix"}
 
 
 def test_paged_decode_compiles_at_16_kv_heads(compiled_kernels, one_chip):
@@ -661,6 +711,14 @@ def test_kv_cells_chunk_writes_the_pool_in_place(
     ).compile()
     text = compiled.as_text()
     assert "paged_decode" in text and "kv_page_write" in text
+    # every attention call carries a name the benchmark's rooflines find
+    # (``^paged_decode|^mla_decode``, benchmark/resident.py): the prefix
+    # program over the pages rows share and the program over their own
+    names = _custom_call_names(text)
+    assert {"paged_decode", "paged_decode_prefix"} <= names
+    assert all(
+        re.match(r"paged_decode|mla_decode", n)
+        for n in names - {"kv_page_write", "fused_sample"}), names
     assert eng.fused       # the rule, on what the fixture describes
     # OLMoE's 64 rows a step are under the ridge; the others have no router
     assert not re.search(r"%moe_grouped(\.\d+)? = ", text)
@@ -1328,6 +1386,52 @@ def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
     before the kernel existed: the routed stacks leave the scanned tree
     only in a program that runs the kernel."""
     assert forward_hashes(config) == FORWARD_HASHES[config]
+
+
+# sha256 (16 hex digits) of the StableHLO of decode_step_paged WITH the
+# paged kernel (``use_pallas=True``; interpret mode here) for the two cells
+# whose per-slot state holds the step to slot order, taken at PR 49's
+# commit: what a step observes of rows that name the same pages, and the
+# prefix program, are no part of a program that keeps slot order.
+KERNEL_DECODE_HASHES = {
+    "granite-4.0-h-micro": "5f8d9a2459ff4965",
+    "phi4-mini-flash": "252a10f65ca8b879",
+}
+
+
+def kernel_decode_hash(config: str) -> str:
+    import hashlib
+
+    from areal_tpu.models import transformer as tfm
+
+    cfg, shapes = _benchmark_config(config)
+    B, M = 8, 4
+    cache = jax.eval_shape(lambda: tfm.PagedKVCache.empty(cfg, 12, 16))
+    state = jax.eval_shape(lambda: tfm.row_state_empty(cfg, B))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    kinds = len(cfg.layer_kinds)
+    table = i32(B, M) if kinds == 1 else i32(kinds, B, M)
+
+    def decode(p, c, t, tb, ln, a, st):
+        return tfm.decode_step_paged(
+            p, cfg, c, t, tb, ln, a, use_pallas=True, ssm=st)
+
+    text = jax.jit(decode).lower(
+        shapes, cache, i32(B), table, i32(B),
+        jax.ShapeDtypeStruct((B,), jnp.bool_), state).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("config", list(KERNEL_DECODE_HASHES))
+def test_slot_order_steps_with_the_kernel_are_the_programs_they_were(config):
+    """granite-4.0-h-micro's and Phi-4-mini-flash's decode steps keep slot
+    order (``shared_prefix_applies``: ``slot_order``), so with the kernel
+    they lower to what they lowered to before a step looked at its table
+    for rows that name the same pages."""
+    assert kernel_decode_hash(config) == KERNEL_DECODE_HASHES[config]
 
 
 # ------------------------------------------------------------------ #
