@@ -481,9 +481,6 @@ GW_BROWNOUT_TRANSITIONS = "gw/brownout_transitions"  # ladder level changes
 TRACE_SPANS = "trace/spans"                 # spans recorded into the ring
 TRACE_SPAN_ERRORS = "trace/span_errors"     # spans that exited via exception
 TRACE_DROPPED = "trace/dropped"             # ring overwrote an unflushed span
-TRACE_FLUSHES = "trace/flushes"             # ring drains to the fileroot
-TRACE_FLUSHED_SPANS = "trace/flushed_spans" # spans written by those drains
-TRACE_FLIGHT_DUMPS = "trace/flight_dumps"   # flight-recorder dumps written
 
 # --------------------------------------------------------------------- #
 # Compile namespace (``compile/``, docs/observability.md "What a start

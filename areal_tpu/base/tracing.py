@@ -525,8 +525,6 @@ def flush(worker_name: str, root: Optional[str] = None) -> int:
         with open(path, "a") as f:
             for s in spans:
                 f.write(json.dumps({"worker": worker_name, **s}) + "\n")
-    metrics_mod.counters.add(metrics_mod.TRACE_FLUSHES)
-    metrics_mod.counters.add(metrics_mod.TRACE_FLUSHED_SPANS, len(spans))
     return len(spans)
 
 

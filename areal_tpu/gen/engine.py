@@ -574,6 +574,9 @@ class GenerationEngine:
             self._held_out: set = set()
             self._admit_seq = 0
             self._room: Optional[int] = None   # ``kv_pool_demand_occupancy``
+            # admission's device programs dispatched so far (the
+            # ``gen_engine/admit/prefill`` span's ``programs``)
+            self._admit_programs = 0
             self._carried: Dict[str, dict] = {}
             # window kinds' pages that more than one slot holds, counted once
             # for every holder past the first: each is a page promised
@@ -1747,6 +1750,7 @@ class GenerationEngine:
                     self.state, fresh, jnp.asarray(tables),
                     jnp.asarray(start), jnp.asarray(n_new), *state_args,
                 )
+                self._admit_programs += 2
 
     # ------------------------------------------------------------------ #
     # A slot's recurrent state (models with state-space layers)
@@ -1807,6 +1811,7 @@ class GenerationEngine:
             src[: len(group)] = [s_ for _, s_ in group]
             self.state = self._state_copy_fn(n, into_slots)(
                 self.state, jnp.asarray(dst), jnp.asarray(src))
+            self._admit_programs += 1
             self.stats["state_snapshot_bytes"] += per * sum(
                 1 for _, s_ in group if s_ >= 0 or not into_slots)
 
@@ -2092,20 +2097,30 @@ class GenerationEngine:
                 self._pending[:0] = still_pending
         if not admitted:
             return
-        # wave 1: unique prompts compute their KV; wave 2: prefix borrowers
-        # extend only their tails (their shared pages were written by wave 1
-        # or by earlier admissions)
-        if self._stateful:
-            self._run_state_waves(misses, hits)
-        else:
-            self._run_extends(misses)
-            self._run_extends(hits)
+        # everything of the wave that dispatches device programs, under one
+        # span: the prefill waves, then the commit of the slots' state
+        with tracing.span("gen_engine/admit/prefill") as attrs:
+            before = self._admit_programs
+            # wave 1: unique prompts compute their KV; wave 2: prefix
+            # borrowers extend only their tails (their shared pages were
+            # written by wave 1 or by earlier admissions)
+            if self._stateful:
+                self._run_state_waves(misses, hits)
+            else:
+                self._run_extends(misses)
+                self._run_extends(hits)
+            self._commit_admitted(admitted)
+            attrs["programs"] = self._admit_programs - before
         for ins_ids, slot, n_full, snap in deferred_inserts:
             self.prefix.insert(
                 ins_ids, self._registry_pages(slot, n_full), snapshot=snap)
         self.stats["state_snapshot_evictions"] = (
             self.prefix.snapshot_evictions)
-        # commit slot state in row buckets
+
+    def _commit_admitted(
+        self, admitted: List[Tuple[GenRequest, int, dict]]
+    ) -> None:
+        """Commit the admitted requests' slot state in row buckets."""
         i = 0
         while i < len(admitted):
             n = self._row_bucket(len(admitted) - i)
@@ -2155,6 +2170,7 @@ class GenerationEngine:
                 jnp.asarray(top_k), jnp.asarray(min_gen), jnp.asarray(max_gen),
                 jnp.asarray(stop_ids),
             )
+            self._admit_programs += 1
 
     # ------------------------------------------------------------------ #
     # Decode
@@ -2460,9 +2476,10 @@ class GenerationEngine:
                 self._jit_routing_rows(st.out_routing, jnp.asarray(
                     (slots[i : i + n] + [slots[i]] * n)[:n], jnp.int32))
                 for i in range(0, len(slots), n))
-        n_gen, out_tokens, out_logprobs, active, max_gen, *routing = (
-            jax.device_get(want)
-        )
+        with tracing.span("gen_engine/harvest/pull") as attrs:
+            got = jax.device_get(want)
+            attrs["bytes"] = sum(a.nbytes for a in got)
+        n_gen, out_tokens, out_logprobs, active, max_gen, *routing = got
         return {
             "n_gen": n_gen, "out_tokens": out_tokens,
             "out_logprobs": out_logprobs, "active": active,
@@ -2520,21 +2537,44 @@ class GenerationEngine:
         )
 
     def _dispatch(self, decode_steps: int, ahead: int,
-                  chunk_attrs: dict) -> Tuple[Optional[tuple], List[int]]:
+                  chunk_attrs: dict) -> Tuple[Optional[tuple], List[int], int]:
         """Seat the slots (``_seat``: who runs, with the pages the chunk
         writes), pick the chunk program for them and dispatch it, under
-        its span. ``ahead``: tokens already dispatched but not yet in
-        ``_lens_host`` (pipelined mode). Returns the flag handles (``None``:
-        no slot to run) and the slots that run the chunk."""
+        its span: only what the chunk program needs, because the device
+        has nothing to run until ``_dispatch_chunk`` returns (what only
+        counts is :meth:`_census`, behind the enqueue). ``ahead``: tokens
+        already dispatched but not yet in ``_lens_host`` (pipelined mode).
+        Returns the flag handles (``None``: no slot to run), the slots that
+        run the chunk and its table width."""
         with tracing.span("gen_engine/dispatch") as attrs:
             # (the host's lengths may lag one chunk behind: a lower bound,
             # so no live page of a window kind goes)
-            running = self._seat(ahead + decode_steps, chunk_attrs)
+            with tracing.span("gen_engine/dispatch/seat"):
+                running = self._seat(ahead + decode_steps, chunk_attrs)
             if not running:
-                return None, running
+                return None, running, 0
             with_topk, wb, warp_idx = self._warp_operand(
                 decode_steps, running, chunk_attrs
             )
+            # width-limit the chunk to the pages this chunk can touch
+            W = self._table_width(
+                int(self._lens_host[running].max()) + ahead + decode_steps)
+            attrs["table_width"] = W
+            with tracing.span("gen_engine/dispatch/enqueue", table_width=W):
+                chunk = self._chunk_fn(
+                    decode_steps, W, wb, fused=self.fused, with_topk=with_topk)
+                flags = self._dispatch_chunk(chunk, W, warp_idx)
+            return flags, running, W
+
+    def _census(self, decode_steps: int, W: int, running: List[int],
+                chunk_attrs: dict) -> None:
+        """Every count of the chunk just dispatched, under its span: onto
+        the chunk span's attributes and ``self.stats``. None of it is an
+        input of the chunk program, so it runs BEHIND the enqueue, while
+        the device computes the chunk; it reads the host's lengths, tables
+        and pool as ``_dispatch`` left them (nothing moves them before the
+        chunk's flags are resolved)."""
+        with tracing.span("gen_engine/census"):
             lens = self._lens_host[running]
             if self._windowed:
                 w = max(w for w in self._windows if w is not None)
@@ -2544,9 +2584,6 @@ class GenerationEngine:
                     "window_resident_tokens"]
                 for kind, n in self.cache_bytes_per_token_by_kind().items():
                     chunk_attrs["cache_bytes_per_token_" + kind] = n
-            # width-limit the chunk to the pages this chunk can touch
-            W = self._table_width(int(lens.max()) + ahead + decode_steps)
-            attrs["table_width"] = W
             chunk_attrs["slots"] = len(running)
             # KV positions the decode kernel reads at the chunk's first
             # step: exact on the host (prompt - 1 + generated per slot; in
@@ -2592,9 +2629,6 @@ class GenerationEngine:
                 for name, n in counts.items():
                     self.stats[name] = self.stats.get(name, 0) + n
             self._observe_occupancy()
-            chunk = self._chunk_fn(
-                decode_steps, W, wb, fused=self.fused, with_topk=with_topk)
-            return self._dispatch_chunk(chunk, W, warp_idx), running
 
     def _kv_write_rows(self) -> int:
         """Rows of the pool tile that the ``kv_page_write`` kernel copies,
@@ -2775,10 +2809,11 @@ class GenerationEngine:
                 if self._pipeline:
                     return self._step_pipelined(decode_steps, span_attrs)
                 self._admit()
-                flags, running = self._dispatch(
+                flags, running, W = self._dispatch(
                     decode_steps, 0, span_attrs)
                 if flags is None:
                     return []
+                self._census(decode_steps, W, running, span_attrs)
                 # one host sync per chunk; the flag copy was enqueued at
                 # dispatch, so the resolve costs no extra round trip
                 flags = self._resolve_flags(flags)
@@ -2804,9 +2839,11 @@ class GenerationEngine:
             outs = self._settle(span_attrs)
         # _lens_host can be one in-flight chunk stale for continuing
         # slots: widen the bound by the TOKENS already dispatched
-        new_flags, running = self._dispatch(
+        new_flags, running, W = self._dispatch(
             decode_steps, self._steps_ahead, span_attrs
         )
+        if running:
+            self._census(decode_steps, W, running, span_attrs)
         new_running = tuple((b, int(self._slot_epoch[b])) for b in running)
         prev_flags = self._prev_flags
         if prev_flags is None:

@@ -456,7 +456,6 @@ class FlightRecorder:
                 json.dump(payload, f)
             os.replace(tmp, path)  # atomic: a watcher never reads torn JSON
             self.dumps += 1
-            metrics_mod.counters.add(metrics_mod.TRACE_FLIGHT_DUMPS)
             logger.error(
                 "flight recorder: dumped %s (%d span(s), %d log line(s))",
                 path, len(payload["spans"]), len(payload["log_tail"]),

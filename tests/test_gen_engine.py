@@ -429,14 +429,19 @@ class TestEngineSpans:
                 rid=f"r{i}", input_ids=p, max_new_tokens=6, greedy=True))
         outs = eng.run_until_done(decode_steps=4)
         assert len(outs) == 3
-        chunks = _chunks_with_children(tracing.drain())
+        spans = tracing.drain()
+        chunks = _chunks_with_children(spans)
         assert len(chunks) >= 2
         first, kids = chunks[0]
         assert {"gen_engine/admit", "gen_engine/dispatch"} <= set(kids)
-        # the first wave at this shape built its programs under the span
+        # the first wave at this shape built its programs under the span's
+        # leaf, the stretch that dispatches them (the innermost span pays)
         admit = kids["gen_engine/admit"]
-        assert admit["attrs"].pop("compiled") >= 1
-        assert 0 < admit["attrs"].pop("compile_s") <= admit["dur_s"]
+        (prefill,) = [s for s in spans if s["parent_id"] == admit["span_id"]]
+        assert prefill["name"] == "gen_engine/admit/prefill"
+        assert prefill["attrs"].pop("compiled") >= 1
+        assert 0 < prefill["attrs"].pop("compile_s") <= prefill["dur_s"]
+        assert prefill["attrs"] == {"programs": 3}  # extend, write, commit
         assert admit["attrs"] == {
             "admitted": 3, "prefill_tokens": sum(len(p) - 1 for p in prompts),
             "prefix_hit_tokens": 0, "pending_left": 0,
@@ -668,8 +673,10 @@ class TestEngineSpans:
                     if s["name"].startswith("gen_engine/start")]
 
     def test_first_step_at_a_shape_says_what_it_built(self, params, rng):
-        """``compiled`` on ``gen_engine/admit`` / ``gen_engine/dispatch``:
-        the first step at a shape builds its programs under them, each a
+        """``compiled`` on ``gen_engine/admit/prefill`` /
+        ``gen_engine/dispatch/enqueue`` (the leaves of ``gen_engine/admit``
+        / ``gen_engine/dispatch`` that dispatch device programs): the first
+        step at a shape builds its programs under them, each a
         ``compile/program`` child naming the program; the second builds
         nothing and carries no stamp."""
         from areal_tpu.base import tracing
@@ -684,14 +691,14 @@ class TestEngineSpans:
             tracing.drain()
             eng.step(4)
             steps.append(tracing.drain())
-        names = ("gen_engine/admit", "gen_engine/dispatch")
+        names = ("gen_engine/admit/prefill", "gen_engine/dispatch/enqueue")
         first = {s["name"]: s for s in steps[0] if s["name"] in names}
-        assert first["gen_engine/admit"]["attrs"]["compiled"] >= 1
-        assert first["gen_engine/dispatch"]["attrs"]["compiled"] >= 1
+        assert first["gen_engine/admit/prefill"]["attrs"]["compiled"] >= 1
+        assert first["gen_engine/dispatch/enqueue"]["attrs"]["compiled"] >= 1
         programs = {
             s["attrs"]["fun_name"] for s in steps[0]
             if s["name"] == "compile/program"
-            and s["parent_id"] == first["gen_engine/dispatch"]["span_id"]}
+            and s["parent_id"] == first["gen_engine/dispatch/enqueue"]["span_id"]}
         assert "jit(chunk)" in programs
         assert not [s for s in steps[1] if s["name"] == "compile/program"]
         assert all("compiled" not in s.get("attrs", {}) for s in steps[1])
@@ -699,7 +706,8 @@ class TestEngineSpans:
     def test_a_table_width_nobody_warmed_is_one_record(self, params, rng):
         """Serving builds a program when a slot grows into the next table
         width: ONE ``compile/program`` record, child of the
-        ``gen_engine/dispatch`` that paid for it, naming the program."""
+        ``gen_engine/dispatch/enqueue`` that paid for it, naming the
+        program."""
         from areal_tpu.base import tracing
 
         eng = GenerationEngine(
@@ -713,8 +721,13 @@ class TestEngineSpans:
         while not eng.step(4):
             pass
         spans = tracing.drain()
-        dispatches = [s for s in spans if s["name"] == "gen_engine/dispatch"]
+        dispatches = [
+            s for s in spans if s["name"] == "gen_engine/dispatch/enqueue"]
         assert {d["attrs"]["table_width"] for d in dispatches} == {32, 64}
+        by_id = {s["span_id"]: s for s in spans}
+        assert all(      # the leaf's width is its ``gen_engine/dispatch``'s
+            by_id[d["parent_id"]]["attrs"]["table_width"]
+            == d["attrs"]["table_width"] for d in dispatches)
         (built,) = [s for s in spans if s["name"] == "compile/program"]
         assert built["attrs"]["fun_name"] == "jit(chunk)"
         (paid,) = [d for d in dispatches if d["span_id"] == built["parent_id"]]

@@ -711,14 +711,28 @@ def test_rehearsal_of_the_cell_and_selfcheck():
     last line counts-only, the check and both controls inside), and the
     yardstick's own checks with the new entries."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
-    p = subprocess.run(
-        [sys.executable, "-m", "benchmark.run", "--workload",
-         "ouro-l8.rollout_out2k", "--seed", str(2**31 + 37), "--seconds", "3",
-         "--trace", "1", "--rehearse"],
-        cwd=ROOT, capture_output=True, text=True, timeout=900, env=env)
-    assert p.returncode == 3, p.stdout[-2000:] + p.stderr[-3000:]
-    lines = p.stdout.strip().splitlines()
-    last, info = json.loads(lines[-1]), json.loads(lines[-2])["info"]
+
+    def rehearse(seconds):
+        p = subprocess.run(
+            [sys.executable, "-m", "benchmark.run", "--workload",
+             "ouro-l8.rollout_out2k", "--seed", str(2**31 + 37), "--seconds",
+             str(seconds), "--trace", "1", "--rehearse"],
+            cwd=ROOT, capture_output=True, text=True, timeout=900, env=env)
+        assert p.returncode == 3, p.stdout[-2000:] + p.stderr[-3000:]
+        lines = p.stdout.strip().splitlines()
+        return json.loads(lines[-1]), json.loads(lines[-2])["info"]
+
+    last, info = rehearse(3)
+    got = info["submitted_and_completed_in_window"]
+    if got < 20:
+        # the driver's verdict counts REQUESTS, and refuses a window in
+        # which fewer than 20 were submitted and completed: a window is
+        # seconds, and on a CPU that five other workers share 3 of them
+        # can be too few (alone: 120 requests in 72 chunks). Sized by what
+        # the first window got, with room, the second holds them
+        assert not last["correct"] and info["check"]["reason"].startswith(
+            f"only {got} requests ran inside the window"), info["check"]
+        last, info = rehearse(min(120, 3 * 40 // max(got, 1) + 3))
     assert last["rehearsal"] and last["correct"] and last["failed"] == 0
     check = info["check"]
     assert check["control"]["correct"] is False
