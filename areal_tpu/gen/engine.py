@@ -2689,13 +2689,8 @@ class GenerationEngine:
             return None
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
-        sb, kp = pl_paged.block_plan(
-            self.B, heads // tp, width, self.page, W, pool_dtype,
-            streams=streams,
-        )
-        lens, span = np.sort(self._lens_host), kp * self.page
+        lens = np.sort(self._lens_host)
         by_kind = []
-        nblk = -(-W // kp)
         # the full layers' call where rows of the chunk name the same pages
         # (the rule ``decode_step_paged`` applies): the prefix program and
         # the rows' own pages, from the table the chunk is dispatched with
@@ -2712,6 +2707,13 @@ class GenerationEngine:
         active = np.zeros((self.B,), bool)
         active[running] = True
         for j, w in enumerate(self._windows):
+            # (a kind's program has its own plan: a full-attention step is
+            # fewer pages than a window step)
+            sb, kp = pl_paged.block_plan(
+                self.B, heads // tp, width, self.page, W, pool_dtype,
+                streams=streams, windowed=w is not None,
+            )
+            span, nblk = kp * self.page, -(-W // kp)
             if w is None and shares:
                 of = pl_paged.shared_counts(
                     table if table.ndim == 2 else table[j], self._lens_host,

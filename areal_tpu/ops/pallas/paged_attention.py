@@ -10,9 +10,11 @@ counterpart of vLLM/SGLang's paged-attention CUDA kernels, which the
 reference inherits (SURVEY §2.1).
 
 Grid ``(B/SB, ceil(M/KP))``: SB slots x KP pages (``S = KP * page``
-positions) per step, ``(SB, KP)`` from :func:`block_plan`. What a call
-costs, measured alone on a TPU v5e at page 128, bf16, at 128 slots x
-12q/2kv x 128 unless said (PERF.md §6, PRs 25, 27, 36 and 47):
+positions) per step, ``(SB, KP)`` from :func:`block_plan`: 4 pages a step
+in the full-attention program since PR 52, 8 in the window, latent and int8
+programs, ``SB`` as 8 pages give it. What a call costs, measured alone on a
+TPU v5e at page 128, bf16, at 128 slots x 12q/2kv x 128 and 8 pages a step
+unless said (PERF.md §6, PRs 25, 27, 36 and 47):
 
 - the launch: a call of this grid and these operands whose kernel does
   NOTHING takes 0.0042 ms at 80 grid steps and 0.0047 at 256 by the device's
@@ -96,6 +98,26 @@ costs, measured alone on a TPU v5e at page 128, bf16, at 128 slots x
   The zero stores were much of that: with them stored once the 1.5B cell's
   rows SHUFFLED take 0.2897 ms for 0.3239 (SB 4: 0.2669 for 0.2863), against
   0.2520 sorted; it is still one more reason for the caller's sort.
+  Since PR 50 took a group's prompt pages out of this program a row's OWN
+  pages are 1-8 in the rollout cells, most blocks reach ONE step of 8 pages
+  and padded it: 1.9 x the own positions at the 1.5B cell's rows, 1.5 x at 4
+  pages a step (196,608 | 155,648 row-positions over 104,634 own). The
+  full-attention program therefore steps 4 pages (:func:`block_plan`, PR
+  52): the call over the cells' own pages, ALONE (operands in HBM, 200 calls
+  a scan, host clock; 8 -> 4 pages a step, ``SB`` held): 0.1892 -> 0.1760 ms
+  at 128 x 12q/2kv (-7.0 %), 0.1684 -> 0.1576 at 64 x 28q/4kv (-6.4 %),
+  0.3735 -> 0.3596 at 72 x 16q/16kv, page 64 (-3.7 %), 0.6053 -> 0.5911 at
+  256 x 8q/2kv (-2.4 %), 0.6460 -> 0.6331 at 64 x 16q/16kv (-2.0 %); rows of
+  1,500-4,500 positions in slot order, 128 x 40q/10kv: 2.6216 -> 2.6079;
+  rows of 2,000-6,000, 112 x 28q/4kv: 1.2445 -> 1.2467. The OTHER form, the
+  copies left at 8 pages and the body as passes over tiles of 4 as far as the
+  block's longest row reaches (one ``fori_loop`` over one softmax state),
+  measured half of that where ``SB`` is 4-8 (0.1824 and 0.1626: a step of 8
+  still waits for all its copies before its first dot) and the same at ``SB``
+  1-2, and +0.5 % on the long rows; tiles of 2 pages less again. The window
+  program: 4 pages a step +9.8 % alone at window 512 (0.6437 -> 0.7066, twice
+  the steps for the same 4-5 pages a row), tiles of 4 in steps of 8 -2.9 %
+  (0.6248): not taken, one geometry and 12 % of the one cell that has it.
 
 THE CALLER ORDERS ROWS BY LENGTH (``decode_step_paged`` sorts the batch
 once per step, before its layer scan), so a block's rows are of
@@ -175,6 +197,16 @@ The own-pages program keeps its ROWS a loop where the other programs unroll
 them (``_each_entry``): alone the call is the same (0.1913 | 0.1919 ms) and
 a chunk program lowers 2-3 s sooner on the chip's host, which more than
 pays for tracing the prefix program beside it.
+
+The prefix program KEEPS 8 pages a step (PR 52, alone, 8 pages | 4 pages a
+step | 8 with the body in tiles of 4): 0.0425 | 0.0508 | 0.0524 ms at 128 x
+12q/2kv, 0.0199 | 0.0229 | 0.0231 at 64 x 28q/4kv, 0.0387 | 0.0387 | 0.0415 at
+72 x 16q/16kv page 64, 0.0312 | 0.0310 | 0.0319 at 256 x 8q/2kv, 0.0525 |
+0.0500 | 0.0514 at 64 x 16q/16kv. A pass of its body updates the state of
+``16 * n_rep`` folded rows a kv head (``m``, ``l`` and ``acc``: 0.7 MB at
+28q/4kv), which costs more than the dots over the 512 positions a shorter
+pass leaves out; a prompt of 2-7 whole pages padded to one step of 8 is the
+cheaper form wherever a kv head has more than one query head.
 """
 
 import functools
@@ -191,9 +223,13 @@ LANES = 128
 # a softmax state between two programs: ``m`` and ``l`` of a query row share
 # one ``[..., LANES]`` vector, ``m`` in the lanes under this one
 ML_SPLIT = LANES // 2
-# pages of one grid step (the table's width where that is less): the own
-# program's default (:func:`block_plan`) and the prefix program's
+# pages of one grid step (the table's width where that is less): the
+# prefix program's, and the window, latent and int8 programs' default
+# (:func:`block_plan`)
 PAGES_PER_STEP = 8
+# the full-attention program's over a K/V pool in the serving dtype, with or
+# without ``carry`` (:func:`block_plan` has the reason)
+FULL_PAGES_PER_STEP = 4
 
 
 def _interpret() -> bool:
@@ -254,9 +290,10 @@ def block_plan(
     page: int,
     table_width: int,
     pool_dtype,
-    pages_per_step: int = PAGES_PER_STEP,
+    pages_per_step: Optional[int] = None,
     slots_per_step: int = 8,
     streams: int = 2,
+    windowed: bool = False,
 ) -> Tuple[int, int]:
     """``(sb, kp)``: the slots and pages of one grid step that
     :func:`decode` runs a batch with. ``kp`` is the table width capped at
@@ -264,13 +301,33 @@ def block_plan(
     divides the batch and the KV scratch is NOT OVER 16 MiB (a scratch of
     exactly 16 MiB stays: 12q/2kv x 128 at page 128 runs 8 slots a step,
     28q/4kv x 128 runs 4; one latent stream of 576 runs 4). Pure, so the engine counts
-    :func:`kernel_positions` with the plan the kernel uses."""
-    kp = min(pages_per_step, table_width)
+    :func:`kernel_positions` with the plan the kernel uses.
+
+    ``pages_per_step`` left to the plan: ``FULL_PAGES_PER_STEP`` (4) in the
+    full-attention program over a K/V pool in the serving dtype (two
+    ``streams``, not int8, not ``windowed``), ``PAGES_PER_STEP`` (8) in the
+    window, latent and int8 programs, and ``sb`` as 8 pages a step give it
+    either way (the scratch halves; 8 slots of 4 pages at 28q/4kv measured
+    +3.3 % alone, PR 47). A step's body runs over ALL ``sb x kp`` pages'
+    positions, so a block whose longest row ends in the first four pages of
+    an 8-page step multiplied its rows' queries against 512 positions that
+    hold nothing; at 4 it does not, and the copies of the step's second
+    half run under the body of its first: 2-7 % of the call on the rollout
+    cells' own pages, nothing on long rows, and a LOSS in the window program
+    (the module docstring has the table; the latent and int8 programs were
+    not measured)."""
+    if pages_per_step is None:
+        full = streams == 2 and jnp.dtype(pool_dtype) != jnp.int8 and (
+            not windowed)
+        kp = min(FULL_PAGES_PER_STEP if full else PAGES_PER_STEP, table_width)
+        fit = min(PAGES_PER_STEP, table_width)
+    else:
+        kp = fit = min(pages_per_step, table_width)
     sb = slots_per_step
     while batch % sb:
         sb //= 2
     while sb > 1 and _scratch_bytes(
-        sb, kp, page, n_kv_heads, head_dim, pool_dtype, streams
+        sb, fit, page, n_kv_heads, head_dim, pool_dtype, streams
     ) > 16 * 1024 * 1024:
         sb //= 2
     return sb, kp
@@ -472,8 +529,9 @@ def shared_counts(
     row; ``by_own`` false where the step keeps its rows by length), as the
     engine's census names it. ``kernel_positions``: row-positions the
     bodies run over, the prefix program's seated rows times the padded
-    positions of their block and the own program's
-    :func:`kernel_positions` over the own lengths; ``kernel_steps*`` as
+    positions of their block (in ITS steps, ``PAGES_PER_STEP`` pages) and
+    the own program's :func:`kernel_positions` over the own lengths (in
+    steps of ``kp``); ``kernel_steps*`` as
     :func:`kernel_steps` and :func:`kernel_steps_chained`, both programs;
     ``kv_pages_named``: pages the rows' tables hold under their lengths;
     ``kv_pages_read``: page copies the two programs start;
@@ -490,15 +548,18 @@ def shared_counts(
     own = own[np.argsort(own if by_own else lens, kind="stable")]
     shared_lens = plan.n * page
     seated = (plan.rows < B).sum(axis=1)
-    prefix_steps = -(-shared_lens // span)
+    # (the prefix program's steps are its own: ``decode_prefix``)
+    prefix_kp = min(PAGES_PER_STEP, table.shape[1])
+    prefix_span, prefix_nblk = prefix_kp * page, -(-table.shape[1] // prefix_kp)
+    prefix_steps = -(-shared_lens // prefix_span)
     active, total = kernel_steps(own, sb, span, nblk)
     return {
         "kernel_positions": kernel_positions(own, sb, span)
-        + int((seated * span * prefix_steps).sum()),
+        + int((seated * prefix_span * prefix_steps).sum()),
         "kernel_steps_active": active + int(prefix_steps.sum()),
-        "kernel_steps": total + blocks * nblk,
+        "kernel_steps": total + blocks * prefix_nblk,
         "kernel_steps_chained": kernel_steps_chained(own, sb, span, nblk)
-        + kernel_steps_chained(shared_lens, 1, span, nblk),
+        + kernel_steps_chained(shared_lens, 1, prefix_span, prefix_nblk),
         "kv_pages_named": int((-(-lens // page)).sum()),
         "kv_pages_read": int((-(-own // page)).sum() + plan.n.sum()),
         "kv_shared_groups": int((plan.n > 0).sum()),
@@ -1191,7 +1252,7 @@ def decode(
     softmax_scale: Optional[float] = None,
     soft_cap: Optional[float] = None,
     sliding_window: Optional[int] = None,
-    pages_per_step: int = PAGES_PER_STEP,
+    pages_per_step: Optional[int] = None,
     slots_per_step: int = 8,
     scales: Optional[jnp.ndarray] = None,  # [L, P, 2, Hkv, page] f32
     value_width: Optional[int] = None,
@@ -1258,7 +1319,7 @@ def decode(
     windowed = sliding_window is not None
     sb, kp = block_plan(
         B, Hkv, D, page, M, pages.dtype, pages_per_step, slots_per_step,
-        streams,
+        streams, windowed,
     )
     nblk = -(-M // kp)
 

@@ -550,6 +550,7 @@ class TestEngineSpans:
         (forced on here, interpret mode; on the CPU's own XLA gather path
         there is nothing to count), and the tokens are those of the gather path either way."""
         from areal_tpu.base import tracing
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
 
         eng = GenerationEngine(
             CFG, params, max_slots=12, max_seqlen=256, page_size=8)
@@ -572,7 +573,11 @@ class TestEngineSpans:
             assert all(eng.stats[n] == 0 for n in names)
             return
         assert len(attrs) == 2
-        sb, span = 4, 8 * 8       # 12 slots: blocks of 4; 8 pages of 8
+        # 12 slots: blocks of 4; the full-attention program's step is 4
+        # pages of 8 (``block_plan``)
+        sb, span = 4, 4 * 8
+        assert pl_paged.block_plan(
+            12, CFG.n_kv_heads, CFG.head_dim, 8, 32, jnp.float32) == (4, 4)
         want = []
         for k in range(2):
             lens = np.sort([0] * 3 + [n - 1 + 4 * k for n in plens])
@@ -583,16 +588,19 @@ class TestEngineSpans:
         assert [a["kernel_positions"] for a in attrs] == [
             sb * span * w for w in want]
         # slot order (5, 150, 9, 70 | 3, 130, 64, 20 | 200, -, -, -) would
-        # take every block as far as a long row: 3 + 3 + 4 page blocks
-        assert want[0] == 1 + 1 + 4
+        # take every block as far as a long row: 5 + 5 + 7 page blocks;
+        # and steps of 8 pages would end the last block at 4 x 64
+        # positions for its 199, not at 7 x 32
+        assert want[0] == 1 + 2 + 7
         # 3 blocks x the page blocks of the chunk's table: the block of
         # short rows and the one with the free slots reach only the first
         widths = [c["gen_engine/dispatch"]["attrs"]["table_width"]
                   for _, c in chunks]
         # (and as many blocks again of the prefix program, a block for
-        # every four rows, none of which reaches a step: no row shares)
+        # every four rows and 8 pages a step, none of which reaches a
+        # step: no row shares)
         assert [a["kernel_steps"] for a in attrs] == [
-            (3 + 3) * -(-w // 8) for w in widths]
+            3 * -(-w // 4) + 3 * -(-w // 8) for w in widths]
         assert all(a["kv_pages_read"] == a["kv_pages_named"] > 0
                    and a["kv_shared_rows"] == 0 for a in attrs)
         assert all(a["kernel_steps_active"] < a["kernel_steps"]

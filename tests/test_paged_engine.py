@@ -317,19 +317,30 @@ class TestPallasPagedDecode:
     # interpret mode is slow on CPU: tier-1 keeps the (1,1)-grid default
     # and the (2,2) multi-step pipeline; the (1,2) cross-bb prefetch case
     # rides the slow sweep (runs unmarked + compiled on chip)
+    # ``ends``: a table of 16 pages under the plan's own pages a step
+    # (``block_plan``: 4 in the full-attention program, 8 in a window
+    # program): the longest row of each block of 2 ends in the FIRST half of
+    # what was a step of 8 pages (the full program's body stops there) or in
+    # the SECOND; rows 2 and 3 share their leading pages, so the same table
+    # also goes through the prefix program (3 shared pages or 5, of its step
+    # of 8) and the own-pages program with ``carry``
     @pytest.mark.parametrize(
-        "kp_sb",
-        [(8, 8), (2, 2), pytest.param((1, 2), marks=pytest.mark.slow)],
+        "kp_sb,ends",
+        [((8, 8), None), ((2, 2), None),
+         pytest.param((1, 2), None, marks=pytest.mark.slow),
+         ((None, 2), "first"), ((None, 2), "second")],
     )
     @pytest.mark.parametrize(
         "soft_cap,window", [(None, None), (5.0, None), (None, 6)]
     )
-    def test_parity_vs_xla_and_dense(self, soft_cap, window, kp_sb):
+    def test_parity_vs_xla_and_dense(self, soft_cap, window, kp_sb, ends):
         from areal_tpu.ops import paged_attention as xla_paged
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
         rng = np.random.default_rng(0)
         B, Hq, Hkv, D, page, M, P, L = 4, 4, 2, 16, 8, 4, 20, 3
+        if ends is not None:
+            M, P = 16, 70
         layer = 1
         q = rng.normal(size=(B, Hq, D)).astype(np.float32)
         k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
@@ -340,6 +351,17 @@ class TestPallasPagedDecode:
         v_pages = np.swapaxes(pool[:, :, 1], 2, 3)
         table = rng.permutation(P)[: B * M].reshape(B, M).astype(np.int32)
         lens = np.asarray([1, 9, 32, 0], np.int32)  # partial/full/empty pool
+        if ends is not None:
+            # 8 pages are 64 positions, the full program's step 32
+            lens = np.asarray(
+                {"first": [20, 9, 84, 70], "second": [40, 9, 114, 100]}[ends],
+                np.int32)
+            n_shared = {"first": 3, "second": 5}[ends]
+            table[3, :n_shared] = table[2, :n_shared]
+            assert pl_paged.block_plan(
+                B, Hkv, D, page, M, pool.dtype, None, 2,
+                windowed=window is not None,
+            ) == (2, 4 if window is None else 8)
 
         got = pl_paged.decode(
             q, k_self, v_self, pool, jnp.int32(layer), table,
@@ -353,6 +375,22 @@ class TestPallasPagedDecode:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), atol=2e-5
         )
+        if ends is not None and window is None:
+            # the two programs of a step that reads shared pages once
+            plan, own_table, own_lens = xla_paged.shared_prefix_step(
+                jnp.asarray(table), jnp.asarray(lens), lens > 0, page)
+            assert list(np.asarray(plan.pages)) == [0, 0, n_shared, n_shared]
+            order = jnp.argsort(own_lens)
+            inverse = jnp.argsort(order)
+            both = xla_paged.paged_decode_attention(
+                q[order], k_self[order], v_self[order], pool,
+                jnp.int32(layer), own_table[order], own_lens[order],
+                soft_cap=soft_cap, use_pallas=True,
+                shared=xla_paged.prefix_pass(
+                    plan, jnp.asarray(table), page, order, inverse),
+            )[inverse]
+            np.testing.assert_allclose(
+                np.asarray(both), np.asarray(want), atol=2e-5)
 
         # dense reference: gather pool positions [0, len) + self at the end
         scale = D ** -0.5
@@ -381,6 +419,51 @@ class TestPallasPagedDecode:
                 np.testing.assert_allclose(
                     np.asarray(got)[b, h], ref, atol=2e-5, err_msg=f"b{b}h{h}"
                 )
+
+    def test_rows_longer_than_a_step_agree_at_both_granules(self):
+        """The long-row cells' guard: a call whose rows share nothing and
+        all reach past 8 pages gives the same result (the order in which
+        partial sums meet in the float32 state is all that differs) at 4
+        pages a grid step, the full-attention program's plan, and at 8, and
+        computes over the same positions but for a block's last 8 pages, at
+        most 4 pages a block less (none where the block's longest row ends
+        in their second half)."""
+        from areal_tpu.ops import paged_attention as xla_paged
+        from areal_tpu.ops.pallas import paged_attention as pl_paged
+
+        rng = np.random.default_rng(4)
+        B, Hq, Hkv, D, page, M, L, sb = 8, 4, 2, 16, 8, 32, 2, 2
+        step, half = 8 * page, 4 * page
+        lens = np.asarray([70, 120, 150, 100, 255, 130, 200, 180], np.int32)
+        assert lens.min() > step
+        q = rng.normal(size=(B, Hq, D)).astype(np.float32)
+        k_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        v_self = rng.normal(size=(B, Hkv, D)).astype(np.float32)
+        pool = rng.normal(size=(L, B * M, 2, Hkv, page, D)).astype(np.float32)
+        table = rng.permutation(B * M).reshape(B, M).astype(np.int32)
+        seats, blocks = pl_paged.prefix_plan(B)
+        assert not pl_paged.shared_prefix(
+            table, lens, lens > 0, page, seats, blocks).pages.any()
+        assert pl_paged.block_plan(
+            B, Hkv, D, page, M, pool.dtype, None, sb) == (sb, 4)
+        got = {
+            kp: np.asarray(pl_paged.decode(
+                q, k_self, v_self, pool, jnp.int32(1), table, lens,
+                slots_per_step=sb, pages_per_step=kp))
+            for kp in (4, 8)
+        }
+        want = np.asarray(xla_paged.paged_decode_attention(
+            q, k_self, v_self, pool, jnp.int32(1), table, lens,
+            use_pallas=False))
+        np.testing.assert_allclose(got[4], got[8], atol=2e-5)
+        np.testing.assert_allclose(got[4], want, atol=2e-5)
+        # blocks (70, 120) and (255, 130) end in a second half, (150, 100)
+        # and (200, 180) in a first
+        was = pl_paged.kernel_positions(lens, sb, step)
+        assert pl_paged.kernel_positions(lens, sb, half) == was - 2 * sb * half
+        second = np.asarray([70, 120, 250, 100, 255, 130, 200, 230])
+        assert pl_paged.kernel_positions(second, sb, half) == (
+            pl_paged.kernel_positions(second, sb, step))
 
 
 def _heavy_tailed_lens(rng, n, cap):
@@ -666,15 +749,17 @@ class TestKernelPositions:
         [
             # R1-Distill-Qwen-1.5B, 12q/2kv x 128: the scratch is exactly
             # 16 MiB at 8 slots, and exactly 16 MiB is not over it
-            (128, 2, "bfloat16", (8, 8)),
+            # (of 8 pages a step: the slots are held to what 8 pages give,
+            # the full-attention program's step is 4)
+            (128, 2, "bfloat16", (8, 4)),
             # 7B widths, 28q/4kv x 128: 32 MiB at 8 slots, halved
-            (64, 4, "bfloat16", (4, 8)),
+            (64, 4, "bfloat16", (4, 4)),
             # an int8 pool: half the page bytes, but at 4 kv heads its
             # scale stripes take 8 slots over 16 MiB again
             (128, 2, "int8", (8, 8)),
             (64, 4, "int8", (4, 8)),
             # a batch that 8 does not divide; a table narrower than 8 pages
-            (4, 2, "bfloat16", (4, 8)),
+            (4, 2, "bfloat16", (4, 4)),
         ],
     )
     def test_block_plan(self, batch, n_kv, dtype, want):
@@ -682,8 +767,23 @@ class TestKernelPositions:
 
         assert pl_paged.block_plan(batch, n_kv, 128, 128, 32, dtype) == want
         assert pl_paged.block_plan(batch, n_kv, 128, 128, 4, dtype)[1] == 4
+        assert pl_paged.block_plan(batch, n_kv, 128, 128, 2, dtype)[1] == 2
+        # a window program keeps 8 pages a step and an explicit
+        # ``pages_per_step`` is taken as given, with the same slots; so does
+        # a latent program (one stream)
+        for kw in (dict(windowed=True), dict(pages_per_step=8)):
+            assert pl_paged.block_plan(
+                batch, n_kv, 128, 128, 32, dtype, **kw) == (want[0], 8)
+        assert pl_paged.block_plan(
+            batch, 1, 640, 128, 32, "bfloat16", streams=1)[1] == 8
 
-    @pytest.mark.parametrize("sb,span", [(8, 1024), (4, 1024), (2, 64)])
+    # (slots, positions of a grid step: 8 pages of 128, the full-attention
+    # program's 4, and 4 pages of 64)
+    @pytest.mark.parametrize(
+        "sb,span",
+        [(8, 1024), (4, 1024), (2, 64), (8, 512), (4, 512), (1, 512),
+         (2, 256)],
+    )
     def test_matches_brute_force(self, sb, span):
         from areal_tpu.ops.pallas import paged_attention as pl_paged
 
@@ -697,6 +797,11 @@ class TestKernelPositions:
                     if j * span < rows[b0:b0 + sb].max():
                         brute += sb * span
             assert pl_paged.kernel_positions(rows, sb, span) == brute
+            # half the pages a step: never more positions, and at most one
+            # step of the smaller kind a block fewer
+            if span == 512:
+                was = pl_paged.kernel_positions(rows, sb, 2 * span)
+                assert 0 <= was - brute <= len(rows) // sb * sb * span
 
     @pytest.mark.parametrize(
         "sb,span,nblk", [(8, 1024, 5), (4, 1024, 5), (1, 1024, 4), (2, 64, 79)]

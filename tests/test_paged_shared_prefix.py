@@ -225,8 +225,11 @@ def _brute_force_shared(table, lens, page):
     return out
 
 
+# (slots, pages of a grid step) of the own-pages program; the prefix
+# program's step is its own, 8 pages (the table's width here)
+@pytest.mark.parametrize("sb,kp", [(1, 2), (2, 4)])
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_plan_and_census_match_brute_force(seed):
+def test_plan_and_census_match_brute_force(seed, sb, kp):
     """The host twins: what the plan seats and what the two programs read
     and compute, against counts made row by row."""
     rng = np.random.default_rng(seed)
@@ -262,7 +265,6 @@ def test_plan_and_census_match_brute_force(seed):
     for a, b in zip(plan, dev):
         assert np.array_equal(a, np.asarray(b))
 
-    sb, kp = 1, 2
     counts = pl_paged.shared_counts(
         table, lens, active, page, sb, kp, -(-M // kp))
     named = int((-(-lens // page)).sum())
@@ -273,11 +275,18 @@ def test_plan_and_census_match_brute_force(seed):
     assert counts["kv_shared_rows"] == int(seated.sum())
     assert counts["kv_shared_groups"] == int((plan.n > 0).sum())
     span = kp * page
+    prefix_span = min(pl_paged.PAGES_PER_STEP, M) * page
+    prefix_steps = [-(-int(plan.n[g]) * page // prefix_span)
+                    for g in range(blocks)]
     prefix_positions = sum(
-        int((plan.rows[g] < len(lens)).sum()) * span * -(-int(plan.n[g]) * page // span)
+        int((plan.rows[g] < len(lens)).sum()) * prefix_span * prefix_steps[g]
         for g in range(blocks))
     assert counts["kernel_positions"] == prefix_positions + (
         pl_paged.kernel_positions(np.sort(own), sb, span))
+    own_steps, own_total = pl_paged.kernel_steps(
+        np.sort(own), sb, span, -(-M // kp))
+    assert counts["kernel_steps_active"] == own_steps + sum(prefix_steps)
+    assert counts["kernel_steps"] == own_total + blocks * -(-M // 8)
 
 
 @pytest.mark.parametrize("run", [3, 8, 9, 21, 40])

@@ -276,7 +276,11 @@ def test_paged_decode_compiles_at_16_kv_heads(compiled_kernels, one_chip):
     exactly the 16 MiB the plan allows), and Mosaic takes it."""
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
-    assert pl_paged.block_plan(64, 16, 128, 128, 32, jnp.bfloat16) == (1, 8)
+    # (one slot is what 8 pages a step leave room for; the full-attention
+    # program's step is 4)
+    assert pl_paged.block_plan(64, 16, 128, 128, 32, jnp.bfloat16) == (1, 4)
+    assert pl_paged.block_plan(
+        64, 16, 128, 128, 32, jnp.bfloat16, windowed=True) == (1, 8)
     _compile(
         pl_paged.decode,
         *_paged_specs(one_chip, page=128, int8=False, L=8, P=715, M=32,
@@ -960,7 +964,8 @@ def test_chained_decode_lowers_with_two_copies_of_issue(
         specs = _paged_specs(one_chip, page=128, int8=False, **shape)
         B, hkv, width = specs[0].shape[0], specs[1].shape[1], 128
     sb, kp = pl_paged.block_plan(
-        B, hkv, width, 128, specs[5].shape[1], jnp.bfloat16, streams=streams)
+        B, hkv, width, 128, specs[5].shape[1], jnp.bfloat16, streams=streams,
+        windowed=program.startswith("window"))
 
     def f(q, ks, vs, pages, layer, table, lens):
         return pl_paged.decode(q, ks, vs, pages, layer, table, lens, **kw)
@@ -1393,6 +1398,10 @@ def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
 # whose per-slot state holds the step to slot order, taken at PR 49's
 # commit: what a step observes of rows that name the same pages, and the
 # prefix program, are no part of a program that keeps slot order.
+# (traced at a table of 4 pages, which is one grid step at 8 pages a step
+# and at the full-attention program's 4 since PR 52: the hashes stayed. At
+# the cells' tables of 40-128 pages these models' full layers run the new
+# plan like every other model's; their window layers run the old one.)
 KERNEL_DECODE_HASHES = {
     "granite-4.0-h-micro": "5f8d9a2459ff4965",
     "phi4-mini-flash": "252a10f65ca8b879",
