@@ -122,8 +122,11 @@ class GenState:
     sp: SamplingParams
     rng: jax.Array
     # MoE routing record (``record_routing``; None otherwise): the experts
-    # the decode step that produced out_tokens[b, i] chose in every layer
-    out_routing: Optional[jnp.ndarray] = None   # [B, G, L, top_k] i32
+    # the decode step that produced out_tokens[b, i] chose in every layer,
+    # a token's ``[L, top_k]`` FLAT: the chip tiles an array's two minor
+    # axes to (8, 128), and ``[.., 5, 22]`` then takes 9.3 x its bytes
+    # (3.0 GB of temporaries in the decode chunk of 192 slots x 4,096)
+    out_routing: Optional[jnp.ndarray] = None   # [B, G, L x top_k] i32
     # a model with state-space layers (``cfg.ssm``; None otherwise): what
     # those layers keep of each SLOT in place of keys and values, ``ssm
     # [Ls, B, G, K, N, 128]`` float32 and ``conv [Ls, B, (d_conv - 1) x C]``. Allocated
@@ -500,7 +503,7 @@ class GenerationEngine:
                     rng=jax.random.key(seed),
                     out_routing=(
                         jnp.zeros(
-                            (self.B, self.G, cfg.n_moe_layers, cfg.moe.top_k),
+                            (self.B, self.G, cfg.n_moe_layers * cfg.moe.top_k),
                             jnp.int32,
                         )
                         if record_routing
@@ -721,10 +724,20 @@ class GenerationEngine:
                 "preemptions": 0,
                 "preempted_tokens_recomputed": 0,
             }
+            # what a chunk's routing census carries behind its first three
+            # (``_fold_chunk_aux``), of the decode chunks' routing (active
+            # rows x expert layers x steps): with a skip output, rows in
+            # all and rows that took the skip; on an expert-parallel
+            # rank's SHARE of the experts, (row, expert) pairs chosen, the
+            # pairs that landed on experts held here, and the held experts
+            # with a row (of ``n_held`` a layer-step)
+            self._census_extra = ()
             if self._moe and cfg.moe.skip_expert:
-                # of the decode chunks' routing (active rows x expert
-                # layers x steps): rows in all, and rows that took the skip
-                self.stats.update(moe_rows=0, moe_skip_rows=0)
+                self._census_extra = ("moe_rows", "moe_skip_rows")
+            elif self._moe and not cfg.moe.holds_all:
+                self._census_extra = (
+                    "moe_pairs", "moe_pairs_held", "moe_held_experts_hit")
+            self.stats.update(dict.fromkeys(self._census_extra, 0))
             start.update(n_pages=self.n_pages, pool_bytes=self.kv_pool_bytes())
 
     # ------------------------------------------------------------------ #
@@ -2302,10 +2315,22 @@ class GenerationEngine:
                         & state.active[None]).sum()
                     census = jnp.concatenate(
                         [census, jnp.stack([n_rows, skipped])])
+                elif not cfg.moe.holds_all:
+                    # the rank's share: pairs of the rows that run, those
+                    # on held experts, and the held experts any row hit
+                    n_held, first = cfg.moe.held
+                    held = (routing >= first) & (routing < first + n_held)
+                    n_pairs = (
+                        state.active.sum() * routing.shape[0]
+                        * routing.shape[2])
+                    census = jnp.concatenate([census, jnp.stack([
+                        n_pairs, (state.active[None, :, None] & held).sum(),
+                        (load[:, first : first + n_held] > 0).sum()])])
                 if out_routing is not None:
-                    keep = state.active[:, None, None]
                     out_routing = out_routing.at[rows, idx].set(jnp.where(
-                        keep, routing.transpose(1, 0, 2),
+                        state.active[:, None],
+                        routing.transpose(1, 0, 2).reshape(
+                            routing.shape[1], -1),
                         out_routing[rows, idx],
                     ))
             return dataclasses.replace(
@@ -2360,11 +2385,11 @@ class GenerationEngine:
 
     def _fold_chunk_aux(self, aux: tuple, chunk_attrs: dict):
         """What a resolved chunk carries after its four harvest flags: an
-        MoE model's routing census (one ``[3]`` vector; with a skip output
-        two more: rows routed, rows that took the skip), else nothing."""
+        MoE model's routing census (one ``[3]`` vector, and behind it what
+        ``_census_extra`` names), else nothing."""
         if aux:
             hit, slots, load_max, *skip = (int(v) for v in aux[0])
-            for name, v in zip(("moe_rows", "moe_skip_rows"), skip):
+            for name, v in zip(self._census_extra, skip):
                 chunk_attrs[name] = v
                 self.stats[name] += v
             chunk_attrs["moe_experts_hit"] = hit
@@ -2484,8 +2509,10 @@ class GenerationEngine:
             "n_gen": n_gen, "out_tokens": out_tokens,
             "out_logprobs": out_logprobs, "active": active,
             "max_gen": max_gen,
-            "out_routing": dict(
-                zip(slots, (row for rows in routing for row in rows))),
+            # a token's record back to ``[L, top_k]``
+            "out_routing": dict(zip(slots, (
+                row.reshape(len(row), -1, self.cfg.moe.top_k)
+                for rows in routing for row in rows))),
         }
 
     def _harvest(self, b: int, reason: str, host_state: dict) -> GenOutput:

@@ -3,7 +3,8 @@
 TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:340``)
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
-joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya, phi4flash) via feature switches, exactly like the reference's single in-house architecture.
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya, phi4flash,
+nemotron_h) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -48,6 +49,44 @@ class MoEConfig:
     # takes NO expert and passes through, scaled by its router weight.
     # ``top_idx == num_experts`` marks it wherever routing is reported.
     skip_expert: bool = False
+    # Experts of TWO matrices, ``W_down act(W_up u)``, no gate matrix
+    # (``nemotron_h``: squared ReLU); the shared expert likewise.
+    gated: bool = True
+    # Width of the shared expert where it is not ``n_shared_experts *
+    # expert_dim`` (None: that).
+    shared_dim: Optional[int] = None
+    # LatentMoE: the routed experts live in a latent of this width behind
+    # ONE down- and ONE up-projection a layer (``latent_down [E, latent]``,
+    # ``latent_up [latent, E]``, no norm, bias or activation): ``W_up sum_j
+    # w_j expert_j(W_dn h)``. The router and the shared expert read the
+    # hidden-width ``h``. None: the experts read ``h`` itself.
+    latent_dim: Optional[int] = None
+    # An expert-parallel rank's SHARE: of the ``num_experts`` the router
+    # scores, this weight tree holds ``n_held`` (None: all), experts
+    # ``held_offset .. held_offset + n_held - 1``. The router keeps its
+    # width and its ``top_k``, the combine weights are normalised over
+    # everything a row chose, and the routed sum runs over the chosen
+    # experts HELD here alone: a partial result, as the rank computes it
+    # before the exchange (``ops/moe.py``). Nothing stands in for the
+    # absent ranks.
+    n_held: Optional[int] = None
+    held_offset: int = 0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """``(count, offset)`` of the experts the weight tree holds."""
+        n = self.num_experts if self.n_held is None else self.n_held
+        return n, self.held_offset
+
+    def shared_width(self, expert_dim: int) -> int:
+        """Width of the shared expert (0: none)."""
+        if not self.n_shared_experts:
+            return 0
+        return self.shared_dim or self.n_shared_experts * expert_dim
+
+    @property
+    def holds_all(self) -> bool:
+        return self.held == (self.num_experts, 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +141,9 @@ class SSMConfig:
       state of ``[head_dim, d_state]`` that decays by ONE scalar a head and
       token; ``n_groups`` groups of heads share one ``B`` and ``C`` of
       ``d_state``; a causal depthwise convolution of width ``d_conv`` over
-      ``[x ; B ; C]``; a gated RMSNorm before the output projection.
+      ``[x ; B ; C]``; a gated RMSNorm before the output projection, over
+      all of ``d_inner`` or (``norm_per_group``: ``nemotron_h``) over each
+      group's ``d_inner / n_groups`` channels by itself.
     - ``dt_rank`` a rank: Mamba-1's selective scan (``phi4flash``). ONE
       head of ``head_dim = d_inner`` channels, each with a state of
       ``d_state`` that decays by its own ``exp(dt[c] A[c, n])``; ``dt``
@@ -127,6 +168,7 @@ class SSMConfig:
     proj_bias: bool = False
     state_dtype: str = "float32"
     dt_rank: Optional[int] = None
+    norm_per_group: bool = False
 
     @property
     def selective(self) -> bool:
@@ -154,7 +196,7 @@ class SSMConfig:
         return self.d_inner + self.conv_dim + self.n_heads
 
 
-MIXERS = ("ssm", "attn", "gmu", "cross")
+MIXERS = ("ssm", "attn", "gmu", "cross", "moe")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,7 +206,8 @@ class StackPosition:
     whether later cross-attention layers read the K/V it ``exports``; a
     cross-attention layer's ``source``, the cache layer it reads (an
     attention layer's own cache layer follows the order the layers run:
-    the forwards count it)."""
+    the forwards count it). ``"moe"`` is no mixer: a block that is an
+    expert layer ALONE (``ModelConfig.one_branch``)."""
 
     mixer: str
     window: Optional[int] = None
@@ -244,8 +287,18 @@ class ModelConfig:
     # and ONE scan a segment cuts each position's weights from the stack
     # of its kind (``models/transformer._scan_plan``). The cache's layer
     # kinds (``layer_kinds``) follow the "attn" layers' windows.
+    #
+    # ``one_branch`` (``nemotron_h``; the HF family sets it): every block
+    # is ``x + f(norm(x))`` with ONE ``f`` and one norm, where every other
+    # plan's block is a mixer and then a feed-forward part with a norm
+    # each. A position's name then says which branch the block has: "ssm"
+    # and "attn" the mixer alone, and
+    #   "moe"   the expert layer alone (``moe``, ``mlp_type`` "moe"; the
+    #           stack ``params["moe_layers"]``, ``ln1`` and ``mlp``).
+    # Only such a plan holds a router.
     ssm: Optional[SSMConfig] = None
     stack_plan: Optional[Tuple[Tuple[int, Tuple[Any, ...]], ...]] = None
+    one_branch: bool = False
     # Differential attention (``phi4flash``): heads in PAIRS; a pair's two
     # softmaxes read the two halves of one kv row's key and both read its
     # whole value, and the pair's context is their difference under a
@@ -477,11 +530,13 @@ class ModelConfig:
 
     @property
     def layer_ids(self) -> Dict[str, List[int]]:
-        """Of each mixer kind, its layers' places in the model: entry ``i``
-        of a kind's weight stack is layer ``layer_ids[kind][i]``."""
+        """Of each kind of block the model has, its layers' places in the
+        model: entry ``i`` of a kind's weight stack is layer
+        ``layer_ids[kind][i]``."""
+        mixers = self.mixers
         return {
-            kind: [i for i, m in enumerate(self.mixers) if m == kind]
-            for kind in MIXERS}
+            kind: [i for i, m in enumerate(mixers) if m == kind]
+            for kind in MIXERS if kind in mixers}
 
     def n_mixers(self, kind: str) -> int:
         return sum(m == kind for m in self.mixers)
@@ -535,6 +590,8 @@ class ModelConfig:
     @property
     def n_moe_layers(self) -> int:
         """Layers with a router (0 for a dense model)."""
+        if self.stack_plan is not None:
+            return self.n_mixers("moe")
         return self.n_layers - self.n_dense_layers if self.mlp_type == "moe" else 0
 
     @property
@@ -588,10 +645,19 @@ class ModelConfig:
             ):
                 raise ValueError(
                     "stack_plan: segments (repeats, period) of 'ssm', "
-                    "('attn', window or None), 'gmu' and 'cross' positions "
-                    "(state-space and attention both present, a window on "
-                    f"'attn' alone) that make up n_layers, got "
+                    "('attn', window or None), 'gmu', 'cross' and 'moe' "
+                    "positions (state-space and attention both present, a "
+                    f"window on 'attn' alone) that make up n_layers, got "
                     f"{self.stack_plan!r}"
+                )
+            if ("moe" in kinds) != (self.mlp_type == "moe") or (
+                "moe" in kinds and not self.one_branch
+            ) or (self.one_branch and ("gmu" in kinds or "cross" in kinds)):
+                raise ValueError(
+                    "stack_plan: a plan holds a router in 'moe' positions "
+                    "(mlp_type 'moe', blocks of one branch: one_branch) and "
+                    "nowhere else; blocks of one branch are 'ssm', 'attn' "
+                    "and 'moe'"
                 )
             for si, (reps, period) in enumerate(plan):
                 here = [m for m, _ in period]
@@ -628,12 +694,12 @@ class ModelConfig:
                 or self.n_dense_layers or self.n_mtp_layers
                 or self.layer_pattern is not None
                 or self.sliding_window is not None
-                or self.abs_position_embedding or self.mlp_type == "moe"
+                or self.abs_position_embedding
                 or self.norm_branch_out or self.is_critic
             ):
                 raise ValueError(
-                    "stack_plan: a dense model of one pass; with a looped "
-                    "stack, latent attention, a router, learned positions, "
+                    "stack_plan: a model of one pass; with a looped "
+                    "stack, latent attention, learned positions, "
                     "branch norms or a value head it is not supported, and "
                     "its attention layers' windows are named in the plan, "
                     "not by layer_pattern or sliding_window"
@@ -651,6 +717,20 @@ class ModelConfig:
                     f"ssm: state_dtype {s.state_dtype!r}: the recurrent "
                     "state is float32 (a 16-bit state is another "
                     "configuration, not supported)"
+                )
+        if self.one_branch and self.stack_plan is None:
+            raise ValueError("one_branch: blocks of one branch need a stack_plan")
+        if self.moe is not None:
+            m = self.moe
+            n, off = m.held
+            if n < 1 or off < 0 or off + n > m.num_experts or (
+                not m.holds_all and (m.skip_expert or m.router_dim is not None)
+            ) or (m.latent_dim is not None and m.latent_dim < 1):
+                raise ValueError(
+                    f"moe: the experts held ({n} from {off}) lie among the "
+                    f"{m.num_experts} the router scores; a share of them "
+                    "with a skip output or a stateful router is not "
+                    "supported"
                 )
         if self.diff_attn and self.softmax_scale is None:
             # the packed rows are twice a head wide: the scale is the

@@ -2,8 +2,8 @@
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
-joyai_llm_flash, smallthinker, ouro, granitemoehybrid and phi4flash are added
-here) consumed by
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid, phi4flash and
+nemotron_h are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -912,6 +912,351 @@ register_hf_family(
         config_to_hf=_granite_config_to_hf,
         params_from_hf=_granite_params_from_hf,
         params_to_hf=_granite_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# nemotron_h (Nemotron-3: blocks of ONE branch, a Mamba-2 mixer, an attention
+# without positions or an expert layer; experts of two matrices with squared
+# ReLU in a latent; an expert-parallel rank's share of the experts)
+# --------------------------------------------------------------------------- #
+
+_NEMOTRON_BLOCKS = {"M": "ssm", "*": "attn", "E": "moe"}
+
+
+def _runs_plan(kinds: List[str]):
+    """``kinds`` (a block a layer) as a stack plan: greedily, the period
+    whose repeats cover most from here on (at least two repeats), else the
+    block alone, joined to the run of single blocks before it."""
+    plan: List[Tuple[int, Tuple[str, ...]]] = []
+    i, n = 0, len(kinds)
+    while i < n:
+        best = (0, 1, 1)                # (covered, period, repeats)
+        for p in range(1, (n - i) // 2 + 1):
+            r = 1
+            while kinds[i + r * p : i + (r + 1) * p] == kinds[i : i + p]:
+                r += 1
+            if r >= 2 and r * p > best[0]:
+                best = (r * p, p, r)
+        _, p, r = best
+        if r == 1 and plan and plan[-1][0] == 1:
+            plan[-1] = (1, plan[-1][1] + (kinds[i],))
+        else:
+            plan.append((r, tuple(kinds[i : i + p])))
+        i += r * p
+    return tuple(plan)
+
+
+def _nemotron_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read; what the program does not
+    compute is refused by name: a block other than ``M`` / ``*`` / ``E`` in
+    ``hybrid_override_pattern`` (``-``, a dense feed-forward block), a
+    group-limited router (``n_group`` / ``topk_group`` other than 1),
+    multi-token-prediction layers, projection or attention biases, another
+    activation than silu in the mixer, ``expand x hidden_size`` other than
+    the mixer's heads. ``rope_theta`` and ``partial_rotary_factor`` shape
+    nothing (the family applies no positional encoding) and are written
+    back as published; so are the initialisation's keys (``time_step_*``,
+    ``rescale_prenorm_residual``) and ``mtp_hybrid_override_pattern``.
+
+    An expert-parallel rank's share is three keys that no published config
+    has: ``n_routed_experts`` counts the experts HELD, of the
+    ``n_routed_experts x expert_parallel_size`` the router scores, from
+    ``expert_parallel_rank x n_routed_experts`` on (``MoEConfig.n_held``)."""
+    pattern = hf["hybrid_override_pattern"]
+    L = hf["num_hidden_layers"]
+    if len(pattern) != L or any(c not in _NEMOTRON_BLOCKS for c in pattern):
+        raise ValueError(
+            "nemotron_h: hybrid_override_pattern names num_hidden_layers "
+            "blocks, each 'M' (Mamba-2), '*' (attention) or 'E' (experts); "
+            f"'-' (a dense feed-forward block) is not supported; got "
+            f"{pattern!r} for {L} layers")
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError(
+            f"nemotron_h: n_group={hf.get('n_group')} / topk_group="
+            f"{hf.get('topk_group')}: a group-limited router is not "
+            "supported (only 1 / 1)")
+    if int(hf.get("num_nextn_predict_layers", 0) or 0) > 0:
+        raise ValueError(
+            "nemotron_h: num_nextn_predict_layers > 0: the multi-token-"
+            "prediction module is not loaded (no generation path drafts "
+            "from one); set it to 0")
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias", "use_bias"):
+        if hf.get(key, False):
+            raise ValueError(f"nemotron_h: {key} is not supported")
+    if hf.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"nemotron_h: mamba_hidden_act {hf['mamba_hidden_act']!r} is "
+            "not supported (only 'silu')")
+    n_heads, d_head = hf["mamba_num_heads"], hf["mamba_head_dim"]
+    if hf.get("expand", 2) * hf["hidden_size"] != n_heads * d_head:
+        raise ValueError(
+            "nemotron_h: expand x hidden_size must equal mamba_num_heads x "
+            "mamba_head_dim")
+    held = hf["n_routed_experts"]
+    ranks = int(hf.get("expert_parallel_size", 1))
+    rank = int(hf.get("expert_parallel_rank", 0))
+    if not 0 <= rank < ranks:
+        raise ValueError(
+            f"nemotron_h: expert_parallel_rank {rank} of {ranks} ranks")
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        hidden_dim=hf["hidden_size"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 262144),
+        layer_norm_epsilon=hf.get("layer_norm_epsilon", 1e-5),
+        apply_rotary=False,
+        rotary_base=float(hf.get("rope_theta", 10000)),
+        activation_function=hf.get("mlp_hidden_act", "relu2"),
+        mlp_type="moe",
+        tied_embedding=bool(hf.get("tie_word_embeddings", False)),
+        moe=MoEConfig(
+            num_experts=held * ranks,
+            top_k=hf["num_experts_per_tok"],
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            expert_dim=hf["moe_intermediate_size"],
+            n_shared_experts=hf.get("n_shared_experts", 0),
+            shared_dim=hf.get("moe_shared_expert_intermediate_size"),
+            scoring="sigmoid",
+            selection_bias=True,
+            gated=False,
+            latent_dim=hf.get("moe_latent_size"),
+            n_held=None if ranks == 1 else held,
+            held_offset=rank * held,
+        ),
+        ssm=SSMConfig(
+            n_heads=n_heads,
+            head_dim=d_head,
+            d_state=hf["ssm_state_size"],
+            n_groups=hf.get("n_groups", 1),
+            d_conv=hf.get("conv_kernel", 4),
+            chunk_size=hf.get("chunk_size", 128),
+            conv_bias=bool(hf.get("use_conv_bias", True)),
+            norm_per_group=True,
+        ),
+        stack_plan=_runs_plan([_NEMOTRON_BLOCKS[c] for c in pattern]),
+        one_branch=True,
+    )
+
+
+def _nemotron_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys; the initialisation's as the family publishes
+    them."""
+    s, m = cfg.ssm, cfg.moe
+    back = {v: k for k, v in _NEMOTRON_BLOCKS.items()}
+    held, first = m.held
+    out = {
+        "model_type": "nemotron_h",
+        "architectures": ["NemotronHForCausalLM"],
+        "attention_bias": False,
+        "chunk_size": s.chunk_size,
+        "conv_kernel": s.d_conv,
+        "expand": s.d_inner // cfg.hidden_dim,
+        "head_dim": cfg.head_dim,
+        "hidden_size": cfg.hidden_dim,
+        "hybrid_override_pattern": "".join(back[k] for k in cfg.mixers),
+        "intermediate_size": cfg.intermediate_dim,
+        "layer_norm_epsilon": cfg.layer_norm_epsilon,
+        "mamba_head_dim": s.head_dim,
+        "mamba_hidden_act": "silu",
+        "mamba_num_heads": s.n_heads,
+        "mamba_proj_bias": False,
+        "max_position_embeddings": cfg.n_positions,
+        "mlp_bias": False,
+        "mlp_hidden_act": cfg.activation_function,
+        "moe_intermediate_size": cfg.expert_dim,
+        "moe_latent_size": m.latent_dim,
+        "moe_shared_expert_intermediate_size": m.shared_width(cfg.expert_dim),
+        "moe_shared_expert_overlap": False,
+        "mtp_hybrid_override_pattern": "*E",
+        "n_group": 1,
+        "n_groups": s.n_groups,
+        "n_routed_experts": held,
+        "n_shared_experts": m.n_shared_experts,
+        "norm_eps": cfg.layer_norm_epsilon,
+        "norm_topk_prob": m.norm_topk_prob,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_experts_per_tok": m.top_k,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "num_logits_to_keep": 1,
+        "num_nextn_predict_layers": 0,
+        "partial_rotary_factor": 1,
+        "rescale_prenorm_residual": True,
+        "residual_in_fp32": False,
+        "rope_theta": int(cfg.rotary_base),
+        "routed_scaling_factor": (
+            int(m.routed_scaling_factor)
+            if float(m.routed_scaling_factor).is_integer()
+            else m.routed_scaling_factor),
+        "sliding_window": None,
+        "ssm_state_size": s.d_state,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "time_step_floor": 0.0001,
+        "time_step_max": 0.1,
+        "time_step_min": 0.001,
+        "topk_group": 1,
+        "use_bias": False,
+        "use_conv_bias": s.conv_bias,
+        "use_mamba_kernels": True,
+        "vocab_size": cfg.vocab_size,
+    }
+    if not m.holds_all:
+        out["expert_parallel_size"] = m.num_experts // held
+        out["expert_parallel_rank"] = first // held
+    return out
+
+
+# (ours, the published name under ``backbone.layers.{i}.mixer.``, transposed)
+_NEMOTRON_MIXER = (
+    ("conv_b", "conv1d.bias", False),
+    ("dt_bias", "dt_bias", False),
+    ("A_log", "A_log", False),
+    ("D", "D", False),
+    ("gate_norm", "norm.weight", False),
+    ("w_out", "out_proj.weight", True),
+)
+_NEMOTRON_ATTN = (("wq", "q"), ("wk", "k"), ("wv", "v"), ("wo", "o"))
+_NEMOTRON_MOE = (
+    ("router", "gate.weight", True),
+    ("b_router", "gate.e_score_correction_bias", False),
+    ("latent_down", "fc1_latent_proj.weight", True),
+    ("latent_up", "fc2_latent_proj.weight", True),
+    ("shared_up", "shared_experts.up_proj.weight", True),
+    ("shared_down", "shared_experts.down_proj.weight", True),
+)
+_NEMOTRON_EXPERT = (("w_up", "up_proj"), ("w_down", "down_proj"))
+
+
+def _nemotron_in_proj(s: SSMConfig):
+    """The published ``in_proj`` is ``[z ; x ; B ; C ; dt]`` in one matrix;
+    ours keeps ``[z], [x ; B ; C], [dt]`` apart (``ops/ssm.py:_split_in``)."""
+    return (("w_z", 0, s.d_inner),
+            ("w_xbc", s.d_inner, s.d_inner + s.conv_dim),
+            ("w_dt", s.d_inner + s.conv_dim, s.in_dim))
+
+
+def _nemotron_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    """A stack a kind of block, each in the order its blocks run. Of the
+    checkpoint's experts (all that the router scores, or the rank's own)
+    the tree takes those HELD: ``experts.{held_offset + j}`` where the
+    checkpoint has it, else ``experts.{j}``."""
+    ids = cfg.layer_ids
+
+    def get(i, name, transpose=False):
+        m = np.asarray(sd[f"backbone.layers.{i}.{name}"])
+        return m.T if transpose else m
+
+    def stack(kind, name, transpose=False, fn=None):
+        return np.stack([
+            (fn or (lambda m: m))(get(i, name, transpose)) for i in ids[kind]
+        ])
+
+    def norm(kind):
+        return {"weight": stack(kind, "norm.weight")}
+
+    attn = {ours: stack("attn", f"mixer.{theirs}_proj.weight", True)
+            for ours, theirs in _NEMOTRON_ATTN}
+    mixer = {ours: stack("ssm", "mixer." + theirs, t)
+             for ours, theirs, t in _NEMOTRON_MIXER
+             if ours != "conv_b" or cfg.ssm.conv_bias}
+    mixer["conv_w"] = stack(
+        "ssm", "mixer.conv1d.weight", fn=lambda m: m[:, 0, :].T)
+    for name, lo, hi in _nemotron_in_proj(cfg.ssm):
+        mixer[name] = stack(
+            "ssm", "mixer.in_proj.weight", True,
+            lambda m, lo=lo, hi=hi: m[:, lo:hi])
+    moe = cfg.moe
+    n_held, first = moe.held
+    absent = set()
+    if moe.latent_dim is None:
+        absent |= {"latent_down", "latent_up"}
+    if not moe.n_shared_experts:
+        absent |= {"shared_up", "shared_down"}
+    mlp = {ours: stack("moe", "mixer." + theirs, t)
+           for ours, theirs, t in _NEMOTRON_MOE if ours not in absent}
+    for ours, theirs in _NEMOTRON_EXPERT:
+        def expert(i, j):
+            whole = f"backbone.layers.{i}.mixer.experts.{first + j}.{theirs}.weight"
+            own = f"backbone.layers.{i}.mixer.experts.{j}.{theirs}.weight"
+            return np.asarray(sd[whole if whole in sd else own]).T
+
+        mlp[ours] = np.stack([
+            np.stack([expert(i, j) for j in range(n_held)])
+            for i in ids["moe"]])
+    params: Dict[str, Any] = {
+        "embed": {"weight": np.asarray(sd["backbone.embeddings.weight"])},
+        "layers": {"ln1": norm("attn"), "attn": attn},
+        "ssm_layers": {"ln1": norm("ssm"), "ssm": mixer},
+        "moe_layers": {"ln1": norm("moe"), "mlp": mlp},
+        "final_ln": {"weight": np.asarray(sd["backbone.norm_f.weight"])},
+    }
+    if not cfg.tied_embedding:
+        params["head"] = {"weight": np.asarray(sd["lm_head.weight"]).T}
+    return params
+
+
+def _nemotron_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    """The experts are written under their place among ALL the router
+    scores (``experts.{held_offset + j}``)."""
+    sd: HFState = {
+        "backbone.embeddings.weight": np.asarray(params["embed"]["weight"]),
+        "backbone.norm_f.weight": np.asarray(params["final_ln"]["weight"]),
+    }
+    if not cfg.tied_embedding:
+        sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
+    ids = cfg.layer_ids
+    first = cfg.moe.held[1]
+
+    def put(p, table, tree, at):
+        for ours, theirs, t in table:
+            if ours in tree:
+                w = np.asarray(tree[ours][at])
+                sd[p + theirs] = w.T if t else w
+
+    for kind, name in (("attn", "layers"), ("ssm", "ssm_layers"),
+                       ("moe", "moe_layers")):
+        lp = params[name]
+        for at, i in enumerate(ids[kind]):
+            p = f"backbone.layers.{i}."
+            sd[p + "norm.weight"] = np.asarray(lp["ln1"]["weight"][at])
+            p += "mixer."
+            if kind == "attn":
+                for ours, theirs in _NEMOTRON_ATTN:
+                    sd[p + f"{theirs}_proj.weight"] = np.asarray(
+                        lp["attn"][ours][at]).T
+            elif kind == "ssm":
+                x = lp["ssm"]
+                put(p, _NEMOTRON_MIXER, x, at)
+                sd[p + "in_proj.weight"] = np.concatenate([
+                    np.asarray(x[n][at]).T
+                    for n, _, _ in _nemotron_in_proj(cfg.ssm)])
+                sd[p + "conv1d.weight"] = np.ascontiguousarray(
+                    np.asarray(x["conv_w"][at]).T[:, None, :])
+            else:
+                m = lp["mlp"]
+                put(p, _NEMOTRON_MOE, m, at)
+                for ours, theirs in _NEMOTRON_EXPERT:
+                    for j in range(m[ours].shape[1]):
+                        sd[p + f"experts.{first + j}.{theirs}.weight"] = (
+                            np.asarray(m[ours][at, j]).T)
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="nemotron_h",
+        hf_model_type="nemotron_h",
+        config_from_hf=_nemotron_config_from_hf,
+        config_to_hf=_nemotron_config_to_hf,
+        params_from_hf=_nemotron_params_from_hf,
+        params_to_hf=_nemotron_params_to_hf,
     )
 )
 

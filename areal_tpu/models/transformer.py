@@ -117,6 +117,43 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         attn["conv1_b"] = jnp.zeros((L, C), dtype)
         attn["k_temp"] = jnp.ones((L, Hkv), dtype)
 
+    def moe_mlp(n):
+        # router over ALL the experts, matrices of those HELD here, in
+        # the width they read (``MoEConfig``)
+        moe = cfg.moe
+        X, F = moe.num_experts, cfg.expert_dim
+        Xh, W = moe.held[0], moe.latent_dim or E
+        mlp = {"router": w((n, E, X))}
+        if moe.gated:
+            mlp["w_gate"] = w((n, Xh, W, F))
+        mlp["w_up"] = w((n, Xh, W, F))
+        mlp["w_down"] = w((n, Xh, F, W))
+        if moe.selection_bias:
+            mlp["b_router"] = jnp.zeros((n, X), dtype)
+        if moe.router_dim is not None:
+            # the stateful MLP router (``ops/moe.py:_route_mlp``); its
+            # last layer keeps the name ``router``, one output more where
+            # the family has a skip
+            R, n_out = moe.router_dim, X + int(moe.skip_expert)
+            mlp.update(
+                router_in=w((n, E, R)), b_router_in=jnp.zeros((n, R), dtype),
+                router_mix=jnp.ones((n, R), dtype),
+                router_norm=jnp.ones((n, R), dtype),
+                router_w1=w((n, R, R)), b_router1=jnp.zeros((n, R), dtype),
+                router_w2=w((n, R, R)), b_router2=jnp.zeros((n, R), dtype),
+                router=w((n, R, n_out)), b_router=jnp.zeros((n, n_out), dtype),
+            )
+        if moe.latent_dim is not None:
+            mlp["latent_down"] = w((n, E, W))
+            mlp["latent_up"] = w((n, W, E))
+        Fs = moe.shared_width(F)
+        if Fs:
+            if moe.gated:
+                mlp["shared_gate"] = w((n, E, Fs))
+            mlp["shared_up"] = w((n, E, Fs))
+            mlp["shared_down"] = w((n, Fs, E))
+        return mlp
+
     if cfg.mlp_type == "gated":
         mlp: Dict[str, Any] = {
             "w_gate": w((L, E, F)),
@@ -129,33 +166,7 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             mlp["b_fc"] = jnp.zeros((L, F), dtype)
             mlp["b_proj"] = jnp.zeros((L, E), dtype)
     elif cfg.mlp_type == "moe":
-        X, F = cfg.moe.num_experts, cfg.expert_dim
-        mlp = {
-            "router": w((L, E, X)),
-            "w_gate": w((L, X, E, F)),
-            "w_up": w((L, X, E, F)),
-            "w_down": w((L, X, F, E)),
-        }
-        if cfg.moe.selection_bias:
-            mlp["b_router"] = jnp.zeros((L, X), dtype)
-        if cfg.moe.router_dim is not None:
-            # the stateful MLP router (``ops/moe.py:_route_mlp``); its
-            # last layer keeps the name ``router``, one output more where
-            # the family has a skip
-            R, n_out = cfg.moe.router_dim, X + int(cfg.moe.skip_expert)
-            mlp.update(
-                router_in=w((L, E, R)), b_router_in=jnp.zeros((L, R), dtype),
-                router_mix=jnp.ones((L, R), dtype),
-                router_norm=jnp.ones((L, R), dtype),
-                router_w1=w((L, R, R)), b_router1=jnp.zeros((L, R), dtype),
-                router_w2=w((L, R, R)), b_router2=jnp.zeros((L, R), dtype),
-                router=w((L, R, n_out)), b_router=jnp.zeros((L, n_out), dtype),
-            )
-        if cfg.moe.n_shared_experts:
-            Fs = cfg.moe.n_shared_experts * F
-            mlp["shared_gate"] = w((L, E, Fs))
-            mlp["shared_up"] = w((L, E, Fs))
-            mlp["shared_down"] = w((L, Fs, E))
+        mlp = None if cfg.one_branch else moe_mlp(L)
     else:
         raise ValueError(cfg.mlp_type)
 
@@ -172,6 +183,13 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
             **({"bias": jnp.zeros((E,), dtype)} if has_ln_bias else {}),
         },
     }
+    if cfg.one_branch:
+        # blocks of ONE branch: an attention block is its mixer and one
+        # norm, the expert blocks are a stack of their own
+        del params["layers"]["ln2"], params["layers"]["mlp"]
+        n_moe = cfg.n_mixers("moe")
+        params["moe_layers"] = {
+            "ln1": ln(has_ln_bias, n_moe), "mlp": moe_mlp(n_moe)}
     if cfg.norm_branch_out:
         params["layers"]["attn_out_ln"] = ln(has_ln_bias)
         params["layers"]["mlp_out_ln"] = ln(has_ln_bias)
@@ -250,16 +268,9 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         if s.proj_bias:
             mixer["b_in"] = jnp.zeros((Ls, s.in_dim), dtype)
             mixer["b_out"] = jnp.zeros((Ls, E), dtype)
-        params["ssm_layers"] = {
-            "ln1": ln(has_ln_bias, Ls),
-            "ssm": mixer,
-            "ln2": ln(has_ln_bias, Ls),
-            "mlp": {
-                "w_gate": w((Ls, E, F)),
-                "w_up": w((Ls, E, F)),
-                "w_down": w((Ls, F, E)),
-            },
-        }
+        params["ssm_layers"] = (
+            {"ln1": ln(has_ln_bias, Ls), "ssm": mixer} if cfg.one_branch
+            else block(Ls, "ssm", mixer))
     if cfg.diff_attn:
         attn.update(diff_params(L))
     n_gmu, n_cross = cfg.n_mixers("gmu"), cfg.n_mixers("cross")
@@ -440,15 +451,17 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         # the per-expert F dim must stay unsharded (one mesh axis can map to
         # at most one dim of a param). How each dispatch behaves under a
         # sharded expert dim is in ``ops/moe.py``.
+        moe = cfg.moe
         mlp = {
             "router": ("layer", "embed", None),
-            "w_gate": ("layer", "expert", "embed", None),
             "w_up": ("layer", "expert", "embed", None),
             "w_down": ("layer", "expert", None, "embed"),
         }
-        if cfg.moe.selection_bias:
+        if moe.gated:
+            mlp["w_gate"] = ("layer", "expert", "embed", None)
+        if moe.selection_bias:
             mlp["b_router"] = ("layer", None)
-        if cfg.moe.router_dim is not None:
+        if moe.router_dim is not None:
             mlp.update(
                 router_in=("layer", "embed", None), router=("layer", None, None),
                 router_w1=("layer", None, None), router_w2=("layer", None, None),
@@ -456,8 +469,16 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
                     "b_router_in", "router_mix", "router_norm", "b_router1",
                     "b_router2", "b_router")},
             )
-        if cfg.moe.n_shared_experts:
-            mlp["shared_gate"] = ("layer", "embed", "mlp")
+        if moe.latent_dim is not None:
+            # the latent is whole on every shard: the experts' "embed"
+            # axis is the latent's, which the projections do not split
+            mlp["latent_down"] = ("layer", "embed", None)
+            mlp["latent_up"] = ("layer", None, "embed")
+            mlp["w_up"] = ("layer", "expert", None, None)
+            mlp["w_down"] = ("layer", "expert", None, None)
+        if moe.n_shared_experts:
+            if moe.gated:
+                mlp["shared_gate"] = ("layer", "embed", "mlp")
             mlp["shared_up"] = ("layer", "embed", "mlp")
             mlp["shared_down"] = ("layer", "mlp", "embed")
 
@@ -469,6 +490,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
             **({"bias": ("embed",)} if has_ln_bias else {}),
         },
     }
+    if cfg.one_branch:
+        del axes["layers"]["ln2"], axes["layers"]["mlp"]
+        axes["moe_layers"] = {"ln1": ln(), "mlp": mlp}
     if cfg.norm_branch_out:
         axes["layers"]["attn_out_ln"] = ln()
         axes["layers"]["mlp_out_ln"] = ln()
@@ -519,14 +543,9 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         if cfg.ssm.proj_bias:
             mixer["b_in"] = ("layer", None)
             mixer["b_out"] = ("layer", "embed")
-        axes["ssm_layers"] = {
-            "ln1": ln(), "ssm": mixer, "ln2": ln(),
-            "mlp": {
-                "w_gate": ("layer", "embed", "mlp"),
-                "w_up": ("layer", "embed", "mlp"),
-                "w_down": ("layer", "mlp", "embed"),
-            },
-        }
+        axes["ssm_layers"] = (
+            {"ln1": ln(), "ssm": mixer} if cfg.one_branch
+            else {"ln1": ln(), "ssm": mixer, "ln2": ln(), "mlp": dense_mlp})
     if cfg.n_mixers("gmu"):
         axes["gmu_layers"] = {
             "ln1": ln(), "ln2": ln(), "mlp": dense_mlp,
@@ -877,6 +896,34 @@ def _add_branch(cfg: ModelConfig, lp, name: str, x, branch):
     return x + branch
 
 
+def _ffn(cfg: ModelConfig, lp, x, layer_in=None, routed=None,
+         router_state=None):
+    """The block's feed-forward part onto the residual, behind its mixer:
+    ``x + mlp(norm(x))``. Returns ``(x, aux, routing, router_state)``
+    (:func:`_mlp`'s). A block of ONE branch (``cfg.one_branch``) has none
+    behind its mixer: ``x`` as it came."""
+    if cfg.one_branch:
+        return x, jnp.float32(0.0), None, None
+    m, aux, routing, r = _mlp(
+        cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), layer_in, routed,
+        router_state=router_state)
+    return _add_branch(cfg, lp, "mlp_out_ln", x, m), aux, routing, r
+
+
+def _moe_block(cfg: ModelConfig, lp, x, routed=None):
+    """A block that is an expert layer ALONE (a "moe" position of a plan
+    of one-branch blocks): ``x + experts(norm(x))``. ``routed``: the held
+    stacks, where they did not come with ``lp`` (:func:`_hold_routed`);
+    the block's index in them is ``lp["index"]``
+    (:func:`_scan_plan`). Returns ``(x, aux, routing)``."""
+    index = lp["index"]
+    lp = _cast(cfg, {k: v for k, v in lp.items() if k != "index"})
+    m, aux, routing, _ = _mlp(
+        cfg, lp["mlp"], _norm(cfg, lp["ln1"], x),
+        routed=None if routed is None else (routed, index))
+    return _add_branch(cfg, lp, "mlp_out_ln", x, m), aux, routing
+
+
 def _layer_stacks(params: Params):
     """The model's runs of identical layers, in the order they run: the
     leading dense layers of an expert model, if it has them, then
@@ -898,13 +945,16 @@ def _hold_routed(params: Params) -> Tuple[Params, Params]:
     grouped-matmul kernel indexes them itself. (A slice of them handed to
     a custom call is a COPY of ``X x E x F`` for each, every layer-step;
     into an einsum XLA fuses it, which is why only a forward that runs the
-    kernel asks for this.)"""
-    layers = params["layers"]
+    kernel asks for this.) Under a plan of one-branch blocks the expert
+    stack is ``moe_layers`` and a block's index in it comes with its slice
+    (:func:`_scan_plan`); experts of two matrices have no ``w_gate``."""
+    tree = "moe_layers" if "moe_layers" in params else "layers"
+    layers = params[tree]
     mlp = layers["mlp"]
     rest = {k: v for k, v in mlp.items() if k not in _ROUTED}
     return (
-        {**params, "layers": {**layers, "mlp": rest}},
-        {k: mlp[k] for k in _ROUTED},
+        {**params, tree: {**layers, "mlp": rest}},
+        {k: mlp[k] for k in _ROUTED if k in mlp},
     )
 
 
@@ -912,8 +962,9 @@ def _routed_at(cfg: ModelConfig, routed: Optional[Params], li, j: int):
     """:func:`_mlp`'s ``routed`` for the layer at position ``j`` of the
     period, from the running ``li`` of the engine's forwards (the layer's
     slice of the pool's leading axis: its cache layer, or its period):
-    the held stacks and the layer's index in the expert stack."""
-    if routed is None:
+    the held stacks and the layer's index in the expert stack. (None
+    for a block of one branch: an attention block holds no experts.)"""
+    if routed is None or cfg.one_branch:
         return None
     layer = (li * len(cfg.layer_kinds) + j) % cfg.n_layers
     return routed, layer - cfg.n_dense_layers
@@ -1035,7 +1086,7 @@ def _scan_passes(cfg: ModelConfig, layer, carry, params: Params, xs=(),
 
 # the weight stack of each mixer kind (``ModelConfig.stack_plan``)
 _STACKS = {"attn": "layers", "ssm": "ssm_layers", "gmu": "gmu_layers",
-           "cross": "cross_layers"}
+           "cross": "cross_layers", "moe": "moe_layers"}
 
 
 def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
@@ -1062,7 +1113,9 @@ def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
     of earlier ones (the memory, the shared K/V, a running cache layer)
     rides ``carry``, which crosses the segments. Under differential
     attention a layer's slice also holds ``"index"``, its place in the
-    model (what ``lambda``'s constant follows). ``writers_only``: stop
+    model (what ``lambda``'s constant follows); an expert block's holds
+    ``"index"``, its place in ITS stack (where the grouped-matmul kernel
+    finds its experts in the held stacks). ``writers_only``: stop
     after the last segment that writes a cache or a state (admission keeps
     nothing of what the readers behind it compute). Returns ``(carry,
     ys)``, ``ys[kind]`` stacked over the layers of that kind that ran."""
@@ -1070,11 +1123,13 @@ def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
     ids = cfg.layer_ids
     stacks = {}
     for kind, tree in _STACKS.items():
-        if not ids[kind]:
+        if kind not in ids:
             continue
         stack = params[tree]
         if cfg.diff_attn:
             stack = {**stack, "index": jnp.asarray(ids[kind], jnp.int32)}
+        if kind == "moe":
+            stack = {**stack, "index": jnp.arange(len(ids[kind]), dtype=jnp.int32)}
         stacks[kind] = (stack, *xs[kind]) if xs.get(kind) else stack
     plan = cfg.plan
     if writers_only:
@@ -1134,28 +1189,26 @@ def _run_stack(cfg: ModelConfig, layer, carry, params: Params, xs=(),
     """What every forward runs its layers through: :func:`_scan_passes`,
     or :func:`_scan_plan` for a model with a stack plan (``fns``,
     ``plan_xs``, ``writers_only``: its arguments). Returns ``(carry, ys,
-    ys_ssm)``: the attention layers' stacked results and the state-space
-    layers' (None for a model without them)."""
+    ys_ssm, ys_moe)``: the attention layers' stacked results, the
+    state-space layers' and the expert blocks' (None for a model without
+    them)."""
     if cfg.plan is None:
         carry, ys = _scan_passes(cfg, layer, carry, params, xs, unroll)
-        return carry, ys, None
+        return carry, ys, None, None
     carry, ys = _scan_plan(
         cfg, fns, carry, params, plan_xs, unroll, writers_only)
-    return carry, ys["attn"], ys["ssm"]
+    return carry, ys["attn"], ys["ssm"], ys.get("moe")
 
 
 def _ssm_block(cfg: ModelConfig, lp, x, mixer):
     """One state-space layer: ``x + mixer(norm(x))``, then the MLP as in
-    an attention layer. ``mixer(p, h)`` returns ``(out, state)``, and where
+    an attention layer (:func:`_ffn`). ``mixer(p, h)`` returns ``(out, state)``, and where
     the model has gated memory units a third, the memory (``ops/ssm.py``).
     Returns ``(x, state, memory or None)``."""
     h = _norm(cfg, lp["ln1"], x)
     out, st, *mem = mixer(lp["ssm"], h)
     x = _add_branch(cfg, lp, "attn_out_ln", x, out.astype(x.dtype))
-    x = _add_branch(
-        cfg, lp, "mlp_out_ln", x,
-        _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
-    return x, st, (mem[0] if mem else None)
+    return _ffn(cfg, lp, x)[0], st, (mem[0] if mem else None)
 
 
 def _gmu_block(cfg: ModelConfig, lp, x, memory):
@@ -1168,18 +1221,26 @@ def _gmu_block(cfg: ModelConfig, lp, x, memory):
         gate = jax.nn.silu((h @ lp["gmu"]["w_in"]).astype(jnp.float32))
         out = (gate * memory).astype(x.dtype) @ lp["gmu"]["w_out"]
     x = _add_branch(cfg, lp, "attn_out_ln", x, out)
-    return _add_branch(
-        cfg, lp, "mlp_out_ln", x,
-        _mlp(cfg, lp["mlp"], _norm(cfg, lp["ln2"], x))[0])
+    return _ffn(cfg, lp, x)[0]
 
 
-def _plan_fns(cfg: ModelConfig, attn, ssm_layer, remat: bool = False):
+def _plan_fns(cfg: ModelConfig, attn, ssm_layer, remat: bool = False,
+              routed=None, moe_ys=None):
     """:func:`_scan_plan`'s ``fns`` of a forward: ``attn(position)`` makes
     the layer of an "attn" or a "cross" position; the state-space layer and
     the gated memory unit are one function each wherever they stand. The
     unit is the same in every forward: what the readers read (memory, k,
-    v: :func:`_shared0`) ENDS the carry, whatever else it holds. ``remat``:
-    the unit under ``jax.checkpoint`` (the trainer's forward)."""
+    v: :func:`_shared0`) ENDS the carry, whatever else it holds. So is the
+    expert block (:func:`_moe_block`; ``routed``: its argument): ``x``
+    STARTS every carry, and ``moe_ys(aux, routing)`` is what the forward
+    keeps of it (None: nothing). ``remat``: both under ``jax.checkpoint``
+    (the trainer's forward)."""
+
+    def moe_layer(carry, lp):
+        x, *rest = carry if isinstance(carry, tuple) else (carry,)
+        x, aux, routing = _moe_block(cfg, lp, x, routed)
+        return ((x, *rest) if isinstance(carry, tuple) else x), (
+            None if moe_ys is None else moe_ys(aux, routing))
 
     def gmu_layer(carry, lp):
         x, *rest = carry
@@ -1187,7 +1248,8 @@ def _plan_fns(cfg: ModelConfig, attn, ssm_layer, remat: bool = False):
 
     if remat:
         gmu_layer = jax.checkpoint(gmu_layer, prevent_cse=False)
-    return {"attn": attn, "cross": attn,
+        moe_layer = jax.checkpoint(moe_layer, prevent_cse=False)
+    return {"attn": attn, "cross": attn, "moe": lambda pos: moe_layer,
             "ssm": lambda pos: ssm_layer, "gmu": lambda pos: gmu_layer}
 
 
@@ -1468,10 +1530,7 @@ def forward_packed(
         )
         ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx))
-        h = _norm(cfg, lp["ln2"], x)
-        m, aux, routing, r = _mlp(
-            cfg, lp["mlp"], h, layer_in, router_state=r)
-        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        x, aux, routing, r = _ffn(cfg, lp, x, layer_in, router_state=r)
         return (x if r0 is None else (x, r)), (aux, routing)
 
     policy = cfg.remat_policy if remat else "none"
@@ -1569,12 +1628,15 @@ def forward_packed(
     def attn_fn(pos):
         return make_layer((pos.window, cfg.apply_rotary), pos)
 
-    x, (auxes, routing), _ = _run_stack(
+    x, (auxes, routing), _, moe_ys = _run_stack(
         cfg, layers, (x, *shared0) if shared0 else (
             x if r0 is None else (x, r0)), params,
         unroll=cfg.layer_scan_unroll or 1,
-        fns=_plan_fns(cfg, attn_fn, ssm_layer, remat=policy != "none"),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer, remat=policy != "none",
+                      moe_ys=lambda aux, routing: (aux, routing)),
     )
+    if moe_ys is not None:
+        auxes, routing = moe_ys     # the expert blocks': the plan's router
     if r0 is not None or shared0:
         x, *_ = x
     stack_out = x
@@ -1870,9 +1932,7 @@ def prefill(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, _, r = _mlp(
-            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
-        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        x, _, _, r = _ffn(cfg, lp, x, h, router_state=r)
         if shared0:
             return (x, mem, ks, vs), (None if cross else (k, v, cc))
         return (x if r0 is None else (x, r)), (k, v, cc)
@@ -1889,7 +1949,7 @@ def prefill(
     def attn_fn(pos):
         return make_layer((pos.window, cfg.apply_rotary), pos)
 
-    x, (ks, vs, cc), ssm = _run_stack(
+    x, (ks, vs, cc), ssm, _ = _run_stack(
         cfg, [make_layer(kind) for kind in cfg.layer_kinds],
         (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
         fns=_plan_fns(cfg, attn_fn, ssm_layer),
@@ -1986,9 +2046,7 @@ def decode_step(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, _, r = _mlp(
-            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h, router_state=r)
-        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
+        x, _, _, r = _ffn(cfg, lp, x, h, router_state=r)
         if shared0:
             return (x, mem, ks, vs), (None if cross else (kc, vc))
         return (x if r0 is None else (x, r)), (kc, vc, *cc)
@@ -2005,7 +2063,7 @@ def decode_step(
     def attn_fn(pos):
         return functools.partial(layer, (pos.window, cfg.apply_rotary), pos)
 
-    x, (ks, vs, *cc), ssm = _run_stack(
+    x, (ks, vs, *cc), ssm, _ = _run_stack(
         cfg,
         [functools.partial(layer, kind, None) for kind in cfg.layer_kinds],
         (x, *shared0) if shared0 else (x if r0 is None else (x, r0)), params,
@@ -2437,11 +2495,9 @@ def _extend_layers(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, _, r = _mlp(
-            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
-            _routed_at(cfg, routed, li, j),
+        x, _, _, r = _ffn(
+            cfg, lp, x, h, _routed_at(cfg, routed, li, j),
             router_state=None if r0 is None else rest[0])
-        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
         if r0 is not None:
             rest = (r,)
         if cross:
@@ -2459,11 +2515,11 @@ def _extend_layers(
     def attn_fn(pos):
         return functools.partial(layer, 0, pos=pos)
 
-    _, (ks, vs, cc), ssm_rows = _run_stack(
+    _, (ks, vs, cc), ssm_rows, _ = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, *shared0),
         params,
-        fns=_plan_fns(cfg, attn_fn, ssm_layer),
+        fns=_plan_fns(cfg, attn_fn, ssm_layer, routed=routed),
         writers_only=True,
     )
     return ks, vs, ssm_rows if cc is None else (cc,)
@@ -2772,13 +2828,11 @@ def decode_step_paged(
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
             _attn_out(lp["attn"], ctx.astype(x.dtype)))
-        m, _, routing, r = _mlp(
-            cfg, lp["mlp"], _norm(cfg, lp["ln2"], x), h,
-            _routed_at(cfg, routed, li, j),
+        x, _, routing, r = _ffn(
+            cfg, lp, x, h, _routed_at(cfg, routed, li, j),
             router_state=None if r0 is None else rest[0])
         if r0 is not None:
             rest = (r,)
-        x = _add_branch(cfg, lp, "mlp_out_ln", x, m)
         if cross:
             return (x, li, *rest), None
         step = int(j == len(kinds) - 1) if pos is None else 1
@@ -2799,13 +2853,17 @@ def decode_step_paged(
     def attn_fn(pos):
         return functools.partial(layer, 0, pos=pos)
 
-    (x, *rest), (ks, vs, routing, cc), _ = _run_stack(
+    (x, *rest), (ks, vs, routing, cc), _, moe_routing = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
         (x, zero, *rest0) if cfg.ssm is None else (
             x, zero, zero, ssm, *shared0),
         params, xs=() if cfg.cca is None else (ssm.carry[:, order],),
-        fns=_plan_fns(cfg, attn_fn, ssm_layer),
+        fns=_plan_fns(
+            cfg, attn_fn, ssm_layer, routed=routed,
+            moe_ys=(lambda aux, routing: routing) if with_routing else None),
     )
+    if moe_routing is not None:
+        routing = moe_routing       # the expert blocks': the plan's router
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(
         cache, ks[:, :, None],

@@ -10,5 +10,7 @@ ACT2FN = {
     "gelu_new": lambda x: jax.nn.gelu(x, approximate=True),
     "gelu_pytorch_tanh": lambda x: jax.nn.gelu(x, approximate=True),
     "relu": jax.nn.relu,
+    # squared ReLU (``nemotron_h``'s experts)
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
     "tanh": jnp.tanh,
 }

@@ -48,15 +48,28 @@ weights at 819 GB/s):
     64 of 2560 x 768, 6 a row     112   100 %   0.92    1.04    1.16 (32)
     64 of 2560 x 768, 6 a row    1024   100 %   0.92    4.05    2.08 (128)
 
+    128 held of 512, 1024 x 2688, 22 a row   8  30 %   0.51    1.67    0.31 (16)
+    (two matrices in a latent: PR 53)       64  94 %   1.62    1.70    1.62 (16)
+                                           128 100 %   1.72    1.70    1.83 (16)
+                                           192 100 %   1.72    1.76    1.99 (32)
+                                           384 100 %   1.72    2.92    2.32 (64)
+                                          1024 100 %   1.72    7.51    3.68 (128)
+
 ``grouped`` includes the sort, the gather and the weighted sum around the
-call. The dense dispatch sits on its bytes up to about half the ridge and
+call (PR 53's rows: both with the router and the latent projections, 0.24-
+0.47 ms timed alone, taken off). The dense dispatch sits on its bytes up to about half the ridge and
 climbs with the rows from there; the kernel climbs a quarter as fast (the
 padded rows it moves), so the two cross at 0.58 of the ridge at 256
 experts (139 rows) and at 0.67 at 64 (161), and below the crossing the
 einsums are ahead by 1-11 %. Where few rows leave most experts unread the
 kernel reads the hit ones only (8 rows of 256 experts: 4.1 x). That is
 the rule of :func:`moe_grouped_applies`: rows at or over ``GROUPED_FROM``
-of the ridge, or at most ``GROUPED_FROM`` of the experts hit.
+of the ridge, or at most ``GROUPED_FROM`` of the experts hit. Experts of
+TWO matrices (no gate: one product less between the matmuls, which XLA
+folds into them) sit on their bytes up to the ridge itself: 1.76 ms for
+1.72 of bytes at 192 rows, 7.15e-3 ms a row from there, which is the MXU's
+time alone, so they cross the kernel at 1.15 of the ridge (~280 rows:
+``GROUPED_FROM_UNGATED``).
 
 Router runs in fp32 (matches the reference's fp32 router,
 ``moe/router.py``). Two kinds of score (``MoEConfig.scoring``): a softmax
@@ -68,6 +81,21 @@ and added to the routed sum. Where the family says so
 (``MoEConfig.router_on_layer_input``: ``smallthinker``) the router reads
 ANOTHER tensor than the experts: the layer's normed input, computed a
 whole attention earlier; the caller hands it in as ``router_input``.
+
+Three switches of ``nemotron_h`` (LatentMoE), each the HF family's:
+experts of TWO matrices, ``W_down act(W_up u)`` (``MoEConfig.gated``
+False; squared ReLU, the shared expert likewise); experts that live in a
+LATENT (``latent_dim``): one down-projection ``u = W_dn h`` before the
+routed experts and one up-projection behind their weighted sum, the router
+and the shared expert reading ``h``; and an expert-parallel rank's SHARE
+(``n_held``, ``held_offset``): the router scores all ``num_experts`` and
+keeps ``top_k`` of them, the combine weights are normalised over all the
+row chose, and the routed sum runs over the chosen experts whose matrices
+are HERE: ``w_up [Xh, ...]``. The dense dispatch contracts over the held
+slice of the combine weights; the grouped one sorts the pairs on held
+experts and drops the rest (they get the index past the last held expert,
+as a skipped row does). What the other ranks would add is absent, here as
+in the plain reference, and nothing stands in for them or their exchange.
 
 A router with STATE (``MoEConfig.router_dim``: ``zaya``) is an MLP
 (:func:`_route_mlp`): the residual projected down to ``router_dim``, plus
@@ -96,6 +124,8 @@ SHARED_SCOPE = "moe_shared_expert"
 EARLY_ROUTER_SCOPE = "moe_router_early"
 ROUTER_MLP_SCOPE = "moe_router_mlp"
 SKIP_SCOPE = "moe_skip"
+LATENT_DOWN_SCOPE = "moe_latent_down"
+LATENT_UP_SCOPE = "moe_latent_up"
 
 
 def _route(cfg, router_w, x, bias=None):
@@ -166,6 +196,9 @@ RIDGE_ROWS = 240
 # cross at 0.58 of it at 256 experts and 0.67 at 64: the module's table),
 # and the programs that hit no more than this share of the experts
 GROUPED_FROM = 0.6
+# ... and experts of two matrices up to this share of it (the table's last
+# block: dense 1.76 against 1.99 at 192 rows, 2.92 against 2.32 at 384)
+GROUPED_FROM_UNGATED = 1.15
 
 
 def _platform() -> str:
@@ -184,7 +217,8 @@ def moe_grouped_applies(
     partitioning rule: under a mesh of several the einsums contract over
     the sharded expert axis); routed stacks stored in the serving dtype
     (a lazy cast of an operand of a custom call is a copy of the stack);
-    and ``rows`` at or over ``GROUPED_FROM`` of the chip's ridge. The
+    and ``rows`` at or over ``GROUPED_FROM`` of the chip's ridge
+    (``GROUPED_FROM_UNGATED`` for experts of two matrices). The
     dense dispatch's MXU time is ``rows / RIDGE_ROWS`` of its weights' HBM
     time whatever ``X`` and ``k`` are, and does not hide under it; the
     kernel's time is the HBM time of the experts HIT, ``1 - (1 - k / X) **
@@ -198,12 +232,21 @@ def moe_grouped_applies(
         platform = _platform()
     moe = cfg.moe
     hit = 1.0 - (1.0 - moe.top_k / moe.num_experts) ** rows
+    rows_from = GROUPED_FROM if moe.gated else GROUPED_FROM_UNGATED
     return (
         platform == "tpu"
         and (mesh is None or mesh.size == 1)
-        and params["layers"]["mlp"]["w_gate"].dtype == jnp.dtype(cfg.dtype)
-        and (rows >= GROUPED_FROM * RIDGE_ROWS or hit <= GROUPED_FROM)
+        and _routed_dtype(cfg, params) == jnp.dtype(cfg.dtype)
+        and (rows >= rows_from * RIDGE_ROWS or hit <= GROUPED_FROM)
     )
+
+
+def _routed_dtype(cfg, params):
+    """What the routed experts' stacks are stored in: ``layers``', or under
+    a stack plan the expert blocks' own (``moe_layers``); experts of two
+    matrices have no ``w_gate``."""
+    mlp = params["layers" if cfg.stack_plan is None else "moe_layers"]["mlp"]
+    return mlp["w_gate" if "w_gate" in mlp else "w_up"].dtype
 
 
 def moe_mlp(cfg, p, x, router_input=None, routed=None, router_state=None):
@@ -261,30 +304,55 @@ def moe_mlp(cfg, p, x, router_input=None, routed=None, router_state=None):
     # (a choice of the skip, index X, is a row of zeros: no expert's)
     onehot = jax.nn.one_hot(top_idx, X, dtype=jnp.float32)
     chosen = onehot.sum(axis=1)                                  # [T, X]
+    moe = cfg.moe
+    n_held, first = moe.held
+    u = xt
+    if moe.latent_dim is not None:
+        with jax.named_scope(LATENT_DOWN_SCOPE):
+            u = xt @ p["latent_down"]
     if routed is not None:
         from areal_tpu.ops.pallas.moe_grouped import moe_grouped
 
         stacks, index = routed
+        local, sizes = top_idx, chosen.sum(axis=0)
+        if not moe.holds_all:
+            # a pair on an expert of another rank: the index past the last
+            # held one, sorted last and dropped
+            local = jnp.where(
+                (top_idx >= first) & (top_idx < first + n_held),
+                top_idx - first, n_held)
+            sizes = sizes[first : first + n_held]
         with jax.named_scope(EXPERTS_SCOPE):
             out = moe_grouped(
-                xt, top_idx, top_vals, chosen.sum(axis=0),
-                stacks["w_gate"], stacks["w_up"], stacks["w_down"], index,
+                u, local, top_vals, sizes,
+                stacks.get("w_gate"), stacks["w_up"], stacks["w_down"], index,
                 activation=cfg.activation_function,
-                with_skip=cfg.moe.skip_expert,
+                with_skip=moe.skip_expert or not moe.holds_all,
+                n_routed=X,
             )
     else:
         combine = (top_vals[:, :, None] * onehot).sum(axis=1)    # [T, X]
+        if not moe.holds_all:
+            combine = combine[:, first : first + n_held]
         with jax.named_scope(EXPERTS_SCOPE):
-            h = act(jnp.einsum("te,xef->txf", xt, p["w_gate"])) * jnp.einsum(
-                "te,xef->txf", xt, p["w_up"]
-            )
+            if moe.gated:
+                h = act(jnp.einsum("te,xef->txf", u, p["w_gate"])) * jnp.einsum(
+                    "te,xef->txf", u, p["w_up"]
+                )
+            else:
+                h = act(jnp.einsum("te,xef->txf", u, p["w_up"]))
             h = h * combine.astype(h.dtype)[:, :, None]
             out = jnp.einsum("txf,xfe->te", h, p["w_down"])
-    if "shared_gate" in p:
+    if moe.latent_dim is not None:
+        with jax.named_scope(LATENT_UP_SCOPE):
+            out = out @ p["latent_up"]
+    if "shared_up" in p:
         with jax.named_scope(SHARED_SCOPE):
-            out = out + (
-                act(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
-            ) @ p["shared_down"]
+            if moe.gated:
+                hs = act(xt @ p["shared_gate"]) * (xt @ p["shared_up"])
+            else:
+                hs = act(xt @ p["shared_up"])
+            out = out + hs @ p["shared_down"]
     if cfg.moe.skip_expert:
         with jax.named_scope(SKIP_SCOPE):
             skipped = jnp.where(top_idx == X, top_vals, 0.0).sum(axis=1)

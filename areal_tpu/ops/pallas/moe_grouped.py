@@ -28,6 +28,13 @@ computes on row 0 and is read by nobody.
 The sort, the gather of the padded sorted rows and the weighted sum of a
 row's ``k`` copies (f32, in row order) are XLA's, around the call.
 
+Two variants, chosen by the operands (``ops/moe.py`` says whose they are):
+experts of TWO matrices (``w_gate`` None: ``h = act(x W_up)``), and a
+SHARE of the experts the router chose among (``n_routed`` over the stack's
+``X``): a pair on an expert that is not held comes with the index ``X``,
+is sorted last and dropped (``with_skip``), and the row tile is sized by
+the pairs expected here, ``T k X / n_routed``.
+
 No gradient and no partitioning rule: the generation engine's forwards run
 this on ONE device (``ops/moe.py:moe_grouped_applies``), the trainer keeps
 the einsums.
@@ -86,28 +93,33 @@ def tile_plan(group_sizes: jnp.ndarray, n_tiles: int, tm: int):
     return expert, (tile_end - tiles) * tm, n_active.reshape(1)
 
 
-def _kernel(li_ref, expert_ref, n_active_ref, x_ref, wg_ref, wu_ref, wd_ref,
-            o_ref, *, act):
+def _kernel(li_ref, expert_ref, n_active_ref, x_ref, *refs, act):
     del li_ref, expert_ref  # read by the index maps
+    *wg_ref, wu_ref, wd_ref, o_ref = refs     # no gate: two matrices
 
     @pl.when(pl.program_id(0) < n_active_ref[0])
     def _():
         x = x_ref[...]                                          # [tm, E]
-        gate = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        up = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = (act(gate) * up).astype(x.dtype)                    # [tm, F]
+        h = act(jnp.dot(
+            x, (wg_ref[0] if wg_ref else wu_ref)[...],
+            preferred_element_type=jnp.float32))
+        if wg_ref:
+            h = h * jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
         o_ref[...] = jnp.dot(
-            h, wd_ref[...], preferred_element_type=jnp.float32)
+            h.astype(x.dtype), wd_ref[...],                     # [tm, F]
+            preferred_element_type=jnp.float32)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("activation", "tm", "interpret", "with_skip"))
+    jax.jit, static_argnames=(
+        "activation", "tm", "interpret", "with_skip", "n_routed"))
 def moe_grouped(
     x: jnp.ndarray,            # [T, E] rows
     top_idx: jnp.ndarray,      # [T, k] int32: the experts a row chose
     top_vals: jnp.ndarray,     # [T, k] f32: their combine weights
     group_sizes: jnp.ndarray,  # [X] pairs an expert serves
-    w_gate: jnp.ndarray,       # [L, X, E, F] the STACK, never a slice
+    w_gate: jnp.ndarray,       # [L, X, E, F] the STACK, never a slice;
+                               # None: experts of two matrices
     w_up: jnp.ndarray,         # [L, X, E, F]
     w_down: jnp.ndarray,       # [L, X, F, E]
     layer: jnp.ndarray,        # scalar int32: which layer of the stack
@@ -116,6 +128,7 @@ def moe_grouped(
     tm: int = None,
     interpret: bool = None,
     with_skip: bool = False,
+    n_routed: int = None,
 ) -> jnp.ndarray:
     """``sum_j top_vals[t, j] * expert(top_idx[t, j])(x[t])`` -> ``[T, E]``
     in ``x``'s dtype; every product accumulates in f32, ``h`` is rounded
@@ -123,9 +136,11 @@ def moe_grouped(
     it), and the ``k`` copies of a row are weighted and summed in f32."""
     T, E = x.shape
     k = top_idx.shape[1]
-    _, X, _, F = w_gate.shape
+    _, X, _, F = w_up.shape
     N = T * k
-    tm = tm or row_tile(N, X)
+    tm = tm or row_tile(N, n_routed or X)
+    weights = (w_up, w_down) if w_gate is None else (w_gate, w_up, w_down)
+    n_w = len(weights)
     n_tiles = N // tm + min(X, N)
     sizes = group_sizes.astype(jnp.int32)
     expert, first_row, n_active = tile_plan(sizes, n_tiles, tm)
@@ -149,13 +164,13 @@ def moe_grouped(
     rows_spec = pl.BlockSpec(
         (tm, E),
         lambda t, li, ex, na: (jnp.minimum(t, jnp.maximum(na[0] - 1, 0)), 0))
-    itemsize = w_gate.dtype.itemsize
+    itemsize = w_up.dtype.itemsize
     y = pl.pallas_call(
         functools.partial(_kernel, act=ACT2FN[activation]),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_tiles,),
-            in_specs=[rows_spec, w_spec((E, F)), w_spec((E, F)),
+            in_specs=[rows_spec, *[w_spec((E, F))] * (n_w - 1),
                       w_spec((F, E))],
             out_specs=rows_spec,
         ),
@@ -163,19 +178,19 @@ def moe_grouped(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=(
-                # an expert's three matrices, double-buffered; the tile's
-                # rows in and out, twice; gate, up, h and y with temporaries
-                2 * 3 * E * F * itemsize
+                # an expert's matrices, double-buffered; the tile's rows
+                # in and out, twice; gate, up, h and y with temporaries
+                2 * n_w * E * F * itemsize
                 + 2 * tm * E * (x.dtype.itemsize + 4)
                 + 4 * tm * (F + E) * 4
                 + 16 * 2 ** 20
             ),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=6 * n_tiles * tm * E * F,
+            flops=2 * n_w * n_tiles * tm * E * F,
             transcendentals=n_tiles * tm * F,
             bytes_accessed=(
-                3 * min(X, N) * E * F * itemsize
+                n_w * min(X, N) * E * F * itemsize
                 + n_tiles * tm * E * (x.dtype.itemsize + 4)
             ),
         ),
@@ -183,7 +198,7 @@ def moe_grouped(
         name="moe_grouped",
     )(
         jnp.reshape(layer, (1,)).astype(jnp.int32), expert, n_active,
-        x[src], w_gate, w_up, w_down,
+        x[src], *weights,
     )
     # padded row of every pair, back in (row, choice) order
     at = jnp.zeros((N,), jnp.int32).at[order].set(dest)

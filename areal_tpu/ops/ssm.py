@@ -13,6 +13,10 @@ One layer, for a head with a state ``S`` of ``head_dim x d_state``::
     y_t  = S_t C_t + D x_t
     out  = W_out (rms(y * silu(z)) * w)
 
+The gated norm spans all of ``d_inner`` (``granitemoehybrid``, one group),
+or each group's ``d_inner / n_groups`` channels by itself
+(``cfg.ssm.norm_per_group``: ``nemotron_h``, eight groups of 1,024).
+
 Two forms of one function:
 
 - :func:`mixer_chunk`: many tokens a row, the CHUNKED scan. Inside a chunk
@@ -137,10 +141,17 @@ def _dt_a(p, dt):
 
 
 def _gated_out(cfg: ModelConfig, p, y, z):
-    """``W_out (rms(y * silu(z)) * w)``; the norm spans ``d_inner``."""
-    with jax.named_scope("ssm_gated_norm"):
+    """``W_out (rms(y * silu(z)) * w)``; the norm spans ``d_inner``, or
+    each group's channels alone (``cfg.ssm.norm_per_group``)."""
+    s = cfg.ssm
+    grouped = s.norm_per_group and s.n_groups > 1
+    with jax.named_scope("ssm_grouped_norm" if grouped else "ssm_gated_norm"):
         g = y * jax.nn.silu(z.astype(jnp.float32))
-        g = norms.rms_norm(g, p["gate_norm"], cfg.layer_norm_epsilon)
+        w = p["gate_norm"]
+        if grouped:     # a group's channels on an axis of their own
+            g = g.reshape(*g.shape[:-1], s.n_groups, -1)
+            w = w.reshape(s.n_groups, -1)
+        g = norms.rms_norm(g, w, cfg.layer_norm_epsilon).reshape(z.shape)
     out = g.astype(z.dtype) @ p["w_out"]
     if "b_out" in p:
         out = out + p["b_out"]

@@ -1553,3 +1553,138 @@ def test_phi4flash_admission_runs_no_cross_decoder(compiled_kernels, one_chip):
             if "= f32[9,128,1,40,16,128]" in ln and " parameter(" not in ln
             and " get-tuple-element(" not in ln]
     assert not made, made
+
+
+# ------------------------------------------------------------------ #
+# nemotron_h cut to one period, an expert-parallel rank's share
+# (benchmark/configs/nemotron3-super-l11-ep4): the engine's decode chunk
+# ------------------------------------------------------------------ #
+
+
+def _nemotron_program(one_chip, n_pages: int, program: str = "jit_chunk"):
+    """``(cfg, the jitted program, its arguments as shapes on the chip)``
+    of the configuration under the engine at the cell's 192 slots of 40
+    pages and a pool of ``n_pages``, placeholder weights: the decode chunk,
+    or (``jit_extend``) a wave of 8 x 128 tokens continuing 8 slots."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.ops.pallas import ssm_decode
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs",
+            "nemotron3-super-l11-ep4.json")) as f:
+        cfg = sut.model_config(json.load(f), {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B, M, few = 192, 40, 6
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=few, max_seqlen=5120, max_new_tokens_cap=4096,
+        page_size=128, n_pages=80, state_snapshots=8, admit_buckets=(2, 8),
+        record_routing=True, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.fused and eng._stateful and eng.M == M
+    # the state update's rule asks the first device itself: here the CPU
+    assert ssm_decode.ssm_decode_applies(cfg, None, "tpu")
+    eng._ssm_update = lambda: ssm_decode.ssm_decode
+    eng.B = B
+    # two-matrix experts: the einsums up to 1.15 of the ridge, the kernel
+    # from there (a full admission wave)
+    assert not eng._moe_grouped(B) and eng._moe_grouped(8 * eng.admit_chunk)
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    def rows(a):
+        # the engine above holds 6 slots (a CPU's worth): the cell's 192
+        return _spec(
+            tuple(B if d == few else d for d in a.shape), a.dtype, one_chip)
+
+    pages = eng.state.cache.pages
+    assert pages.shape[2:] == (2, 2, 128, 128) and pages.shape[0] == 1
+    st = eng.state
+    state = dataclasses.replace(
+        jax.tree.map(rows, dataclasses.replace(st, snaps=None, cache=None)),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (1, n_pages) + pages.shape[2:], pages.dtype, one_chip)),
+        snaps=jax.tree.map(spec, st.snaps),
+        rng=spec(st.rng))
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    params = jax.tree.map(spec, shapes)
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=eng.fused, with_topk=False)
+        return cfg, fn, (params, state, i32(B, M), i32(0))
+    fn = eng._extend_fn(8, M, skip_pool=False)
+    return cfg, fn, (params, state, i32(8, eng.admit_chunk), i32(8, M),
+                     i32(8), i32(8), i32(8))
+
+
+def test_nemotron_cell_decode_chunk_copies_no_stack_and_no_state(
+        compiled_kernels, one_chip):
+    """The cell's decode chunk (11 one-branch blocks at the published
+    widths, 128 of 512 experts, 192 slots, 16 steps) at the traffic file's
+    pool: ``ssm_decode`` over the state
+    of eight groups, ``paged_decode``, ``kv_page_write`` and
+    ``fused_sample`` are in it; the held experts' two stacks are read in
+    place by the einsums (192 rows of two-matrix experts are under the
+    kernel's crossing; the admission wave of 1,024 rows holds
+    ``moe_grouped``), so the temporaries are under ONE routed layer's 0.7
+    GB; nothing results in an array of the state's size (the kernel
+    updates the donated state in place: the state is aliased to the
+    result); arguments and temporaries fit the chip with 1.5e9 B to spare (800
+    pages further on, by hand, as well: the cell sits on no memory edge;
+    three small ops carry ``remat`` in their names there, here and 4,000
+    pages under: the compiler's placement of the kernel's gathers in fast
+    memory, not a shortage)."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic", "grpo16_closed192_ep4.json")) as f:
+        pool_bytes = json.load(f)["engine"]["kv_pool_bytes"]
+    n_pages = pool_bytes // (1_024 * 128)
+    cfg, fn, args = _nemotron_program(one_chip, n_pages)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert f"bf16[1,{n_pages},2,2,128,128]" in text
+    for kernel in ("ssm_decode", "paged_decode", "kv_page_write"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    # 192 rows of two-matrix experts run on the einsums (the rule's table,
+    # ``ops/moe.py``), which read their layer's slice of the stacks in place
+    assert not re.search(r"%moe_grouped(\.\d+)? = ", text)
+    _assert_fused_epilogue(text, 192, cfg.vocab_size)
+    made = [ln.strip()[:120] for ln in text.split("\n")
+            if "= f32[5,192,8,8,128,128]" in ln and " parameter(" not in ln
+            and " get-tuple-element(" not in ln and "custom-call" not in ln
+            and " while(" not in ln and " bitcast(" not in ln]
+    assert not made, made
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * 5 * 192 * 8 * 8 * 128 * 128
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+def test_nemotron_admission_wave_holds_the_grouped_kernel(
+        compiled_kernels, one_chip):
+    """A wave of 8 x 128 tokens continuing 8 slots' state at the cell's
+    sizes: its 1,024 rows are over the two-matrix experts' crossing, so the
+    held experts run in ``moe_grouped``, which is handed the two stacks
+    whole (nothing results in one layer's ``[128, 1024, 2688]``), and the
+    program makes no array of the whole recurrent state's size."""
+    cfg, fn, args = _nemotron_program(one_chip, 7781, "jit_extend")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _custom_call_names(text) == {"moe_grouped"}
+    n_held, lat, width = cfg.moe.held[0], cfg.moe.latent_dim, cfg.expert_dim
+    for shape in (f"bf16[{n_held},{lat},{width}]",
+                  f"bf16[{n_held},{width},{lat}]"):
+        assert not re.search(r"= (\()?" + re.escape(shape) + r"\{", text), shape
+    made = [ln.strip()[:120] for ln in text.split("\n")
+            if "= f32[5,192,8,8,128,128]" in ln and " parameter(" not in ln
+            and " get-tuple-element(" not in ln]
+    assert not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
