@@ -28,7 +28,9 @@ Two forms of one function:
   it is read, so packed documents do not see each other. Autodiff through
   it is the trainer's backward pass. Any chunk length gives the same
   function.
-- :func:`mixer_step`: one token a row, the decode step's update.
+- :func:`mixer_step`: one token a row, the decode step's update; its
+  convolution is :func:`conv_step`, the many-token form's
+  :func:`conv_chunk`.
 
 The recurrent state is kept TRANSPOSED and in lane tiles, ``[G, K, N,
 128]`` a row a layer (``G`` groups of ``R`` heads of ``P`` channels, a state
@@ -95,7 +97,10 @@ def state_shapes(cfg: ModelConfig, batch: int):
     The convolution's last inputs are kept FLAT: with the 3 taps as an
     axis of their own the chip's compiler, gathering a few rows, re-laid
     the whole array out with that axis on the lanes (3 padded to 128:
-    2.99 GB at the published sizes; PERF.md §6 PR 41)."""
+    2.99 GB at the published sizes; PERF.md §6 PR 41). The decode step's
+    reader, :func:`conv_step`, depends on it: a tap is a static slice of
+    whole lane tiles along the minor axis (``channels`` is 34, 40 or 80
+    tiles at the published sizes)."""
     s = cfg.ssm
     k, lanes = _lane_tiles(s.n_heads // s.n_groups * s.head_dim)
     return (
@@ -177,8 +182,9 @@ def conv_history(x, state):
     x C]``, a row's last ``K - 1`` inputs of a causal convolution of ``K``
     taps, flat (:func:`state_shapes`). ``[B, K - 1 + T, C]``. With
     :func:`conv_reads` and :func:`conv_next_state`, what the state-space
-    mixer's convolution (:func:`conv_chunk`) and the attention latent's
-    (``ops/cca.py``) share, many tokens a row or one."""
+    mixer's convolution over many tokens a row (:func:`conv_chunk`; its
+    decode step is :func:`conv_step`, which builds no such array) and the
+    attention latent's (``ops/cca.py``) share."""
     B, _, C = x.shape
     return jnp.concatenate(
         [state.astype(x.dtype).reshape(B, state.shape[-1] // C, C), x], axis=1)
@@ -198,8 +204,9 @@ def conv_reads(full, positions):
 
 
 def conv_next_state(full, n_valid, state):
-    """The state after each row's first ``n_valid [B]`` tokens (0: as it
-    was), in ``state``'s shape and dtype."""
+    """The state after each row's first ``n_valid [B]`` of many tokens (0:
+    as it was), in ``state``'s shape and dtype: a slice of ``full`` at a
+    start a row."""
     K = state.shape[-1] // full.shape[-1] + 1
     new_state = jax.vmap(
         lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, K - 1, axis=0)
@@ -207,24 +214,55 @@ def conv_next_state(full, n_valid, state):
     return new_state.astype(state.dtype).reshape(state.shape)
 
 
-def conv_chunk(p, xbc, positions, conv_state, n_valid):
-    """The causal depthwise convolution over ``xbc [B, T, C]`` whose rows
-    continue ``conv_state [B, (K - 1) x C]`` (:func:`conv_history`).
-    Returns the activated output and the state after each row's first
-    ``n_valid [B]`` tokens."""
+def _conv_out(p, taps, dtype):
+    """``silu(sum_d tap_d * w[K - 1 - d] + b)`` in float32, as ``dtype``:
+    ``taps`` from the token itself (``d = 0``) back, added in that order."""
     w = p["conv_w"]                                       # [K, C]
     K = w.shape[0]
-    full = conv_history(xbc, conv_state)
     with jax.named_scope("ssm_conv"):
         out = 0.0
-        for d, tap in enumerate(conv_reads(full, positions)):
+        for d, tap in enumerate(taps):
             # the tap ``d`` tokens back: weight K - 1 - d
             out = out + tap.astype(jnp.float32) * w[
                 K - 1 - d].astype(jnp.float32)
         if "conv_b" in p:
             out = out + p["conv_b"].astype(jnp.float32)
-        out = jax.nn.silu(out).astype(xbc.dtype)
+        return jax.nn.silu(out).astype(dtype)
+
+
+def conv_chunk(p, xbc, positions, conv_state, n_valid):
+    """The causal depthwise convolution over ``xbc [B, T, C]``, many tokens
+    a row, whose rows continue ``conv_state [B, (K - 1) x C]``
+    (:func:`conv_history`). Returns the activated output and the state
+    after each row's first ``n_valid [B]`` tokens."""
+    full = conv_history(xbc, conv_state)
+    out = _conv_out(p, conv_reads(full, positions), xbc.dtype)
     return out, conv_next_state(full, n_valid, conv_state)
+
+
+def conv_step(p, x, state, active):
+    """The convolution over ONE token a row, the decode step's: ``x [B,
+    C]``, ``state [B, (K - 1) x C]``, ``active [B]`` (false: the row's
+    state stays). Bit for bit :func:`conv_chunk` at ``T = 1`` behind ``K -
+    1`` or more tokens of the document (no tap is masked), in another
+    form: the taps are static slices of the FLAT state along its minor
+    axis and the next state is one elementwise pass, which the chip's
+    compiler fuses into the in-place update of the stacked state. Built
+    as ``[B, K, C]`` with a start a row, the same values cost two re-laid
+    copies, a padded ``[B, 4, C]`` and a gather a layer a token (PERF.md
+    §6 PR 54)."""
+    C = x.shape[-1]
+    K = state.shape[-1] // C + 1
+    taps = [x] + [
+        state[:, j * C : (j + 1) * C].astype(x.dtype)
+        for j in reversed(range(K - 1))]
+    # each piece chosen a row BEFORE the two are laid end to end: one select
+    # over the whole shifted row measured a fifth slower on the chip
+    moves = active[:, None]
+    state = jnp.concatenate([
+        jnp.where(moves, state[:, C:], state[:, :-C]),
+        jnp.where(moves, x.astype(state.dtype), state[:, -C:])], axis=-1)
+    return _conv_out(p, taps, x.dtype), state
 
 
 def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):
@@ -365,14 +403,17 @@ def s6_scan(x, dt, a, b, c, d_skip, reset, init):
     return jnp.moveaxis(y, 0, 1), final
 
 
-def _s6_inputs(cfg: ModelConfig, p, h, positions, conv0, n_valid):
-    """``h [B, T, E]`` to what the scan reads: ``(z, x, dt, b, c, conv
-    state)``, ``x`` convolved and activated, ``dt`` after its projection and
-    softplus, all but ``z`` float32."""
-    s = cfg.ssm
+def _s6_in(p, h):
+    """``h [..., E]`` -> ``(x, z)``, each ``[..., d_inner]``."""
     with jax.named_scope("ssm_in_proj"):
-        x, z = h @ p["w_x"], h @ p["w_z"]
-    x, conv1 = conv_chunk(p, x, positions, conv0, n_valid)
+        return h @ p["w_x"], h @ p["w_z"]
+
+
+def _s6_inputs(cfg: ModelConfig, p, x):
+    """``x [..., d_inner]``, convolved and activated, to what the scan
+    reads: ``(x, dt, b, c)``, ``dt`` after its projection and softplus, all
+    float32."""
+    s = cfg.ssm
     with jax.named_scope("ssm_x_proj"):
         dbc = x @ p["w_xproj"]
         dt = dbc[..., : s.dt_rank] @ p["w_dt"]
@@ -381,7 +422,7 @@ def _s6_inputs(cfg: ModelConfig, p, h, positions, conv0, n_valid):
     dbc = dbc.astype(jnp.float32)
     b = dbc[..., s.dt_rank : s.dt_rank + s.d_state]
     c = dbc[..., s.dt_rank + s.d_state :]
-    return z, x.astype(jnp.float32), dt, b, c, conv1
+    return x.astype(jnp.float32), dt, b, c
 
 
 def _s6_out(p, y, z):
@@ -395,7 +436,9 @@ def _s6_chunk(cfg: ModelConfig, p, h, positions, state, n_valid):
     y)`` with ``y [B, T, d_inner]`` the scan's output before the gate."""
     T = h.shape[1]
     ssm0, conv0 = state
-    z, x, dt, b, c, conv1 = _s6_inputs(cfg, p, h, positions, conv0, n_valid)
+    x, z = _s6_in(p, h)
+    x, conv1 = conv_chunk(p, x, positions, conv0, n_valid)
+    x, dt, b, c = _s6_inputs(cfg, p, x)
     valid = jnp.arange(T)[None, :] < n_valid[:, None]
     dt = jnp.where(valid[..., None], dt, 0.0)
     y, ssm1 = s6_scan(
@@ -406,17 +449,15 @@ def _s6_chunk(cfg: ModelConfig, p, h, positions, state, n_valid):
 
 def _s6_token(cfg: ModelConfig, p, h, state, active):
     """:func:`mixer_step` under the selective scan (``y [B, d_inner]``)."""
-    Bt = h.shape[0]
     ssm0, conv0 = state
-    z, x, dt, b, c, conv1 = _s6_inputs(
-        cfg, p, h[:, None], jnp.full((Bt, 1), cfg.ssm.d_conv, jnp.int32),
-        conv0, active.astype(jnp.int32))
-    dt = jnp.where(active[:, None], dt[:, 0], 0.0)
+    x, z = _s6_in(p, h)
+    x, conv1 = conv_step(p, x, conv0, active)
+    x, dt, b, c = _s6_inputs(cfg, p, x)
+    dt = jnp.where(active[:, None], dt, 0.0)
     with jax.named_scope("ssm_s6"):
         y, ssm1 = s6_step(
-            ssm0, x[:, 0], dt, _s6_a(p), b[:, 0], c[:, 0],
-            p["D"].astype(jnp.float32))
-    return _s6_out(p, y, z[:, 0]), (ssm1, conv1), y
+            ssm0, x, dt, _s6_a(p), b, c, p["D"].astype(jnp.float32))
+    return _s6_out(p, y, z), (ssm1, conv1), y
 
 
 def mixer_chunk(
@@ -517,10 +558,8 @@ def mixer_step(cfg: ModelConfig, p, h, state, active=None, update=None,
         out = _s6_token(cfg, p, h, state, active)
         return out if memory else out[:2]
     z, xbc, dt = _split_in(cfg, p, h)
-    xbc, conv1 = conv_chunk(
-        p, xbc[:, None], jnp.full((Bt, 1), s.d_conv, jnp.int32), conv0,
-        active.astype(jnp.int32))
-    x, b, c = _split_xbc(cfg, xbc[:, 0])
+    xbc, conv1 = conv_step(p, xbc, conv0, active)
+    x, b, c = _split_xbc(cfg, xbc)
     dt, a = _dt_a(p, dt)
     dt = jnp.where(active[:, None], dt, 0.0).reshape(Bt, G, R)
     args = (x, dt, a.reshape(G, R), b, c,

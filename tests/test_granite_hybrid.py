@@ -456,6 +456,47 @@ def test_ssm_decode_kernel_equals_the_plain_update(G, R, P, N, rows):
     np.testing.assert_array_equal(got[2], whole[2])
 
 
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows", ["active", "inactive", "mixed"])
+@pytest.mark.parametrize("C", [256, 96], ids=["lane-tiles", "ragged"])
+@pytest.mark.parametrize("bias", [True, False], ids=["bias", "no-bias"])
+@pytest.mark.parametrize("K", [2, 4])
+def test_conv_step_is_conv_chunk_at_one_token_bit_for_bit(
+        K, bias, C, rows, dtype):
+    """The decode step's convolution (``conv_step``: static slices of the
+    flat state, one elementwise pass for the next state) against the
+    many-token form at ``T = 1`` behind ``d_conv`` tokens of the document,
+    which is what the decode step ran before PR 54: the output and the
+    state bit for bit, and a row that is not active keeps its state."""
+    B = 6
+    active = jnp.asarray({
+        "active": [True] * B, "inactive": [False] * B,
+        "mixed": [False, True, True, False, True, False]}[rows])
+    ks = jax.random.split(jax.random.key(54), 4)
+    p = {"conv_w": jax.random.normal(ks[0], (K, C)).astype(dtype)}
+    if bias:
+        p["conv_b"] = jax.random.normal(ks[1], (C,)).astype(dtype)
+    x = jax.random.normal(ks[2], (B, C)).astype(dtype)
+    state = jax.random.normal(ks[3], (B, (K - 1) * C)).astype(dtype)
+    want, want_state = ssm_ops.conv_chunk(
+        p, x[:, None], jnp.full((B, 1), K, jnp.int32), state,
+        active.astype(jnp.int32))
+    out, got_state = ssm_ops.conv_step(p, x, state, active)
+    assert out.dtype == want.dtype and out.shape == (B, C)
+    assert got_state.dtype == state.dtype and got_state.shape == state.shape
+    np.testing.assert_array_equal(_bits(out), _bits(want[:, 0]))
+    np.testing.assert_array_equal(_bits(got_state), _bits(want_state))
+    keep = ~np.asarray(active)
+    np.testing.assert_array_equal(
+        _bits(got_state)[keep], _bits(state)[keep])
+
+
 @pytest.mark.parametrize("over,platform,mesh_size,want", [
     ({}, "tpu", 1, True),
     ({"n_groups": 2}, "tpu", 1, True),        # 32 heads x 64 a group

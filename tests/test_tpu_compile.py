@@ -386,6 +386,26 @@ def test_granite_admission_re_lays_no_state(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
+def _assert_one_token_convolution(text, layers, B, C):
+    """A compiled decode chunk runs the state-space mixer's convolution
+    over the stacked FLAT state ``bf16[layers, B, 3 x C]``
+    (``ops/ssm.py:conv_step``): no line results in a layer's state with
+    its 3 taps as an axis of their own (``[B, 3, C]``, which the chip's
+    compiler re-lays out with the 3 on the sublanes and pads to ``[B, 4,
+    C]``; in any layout), and no ``gather`` reads the convolution's state
+    (the next state as a slice at a start a row). Before PR 54 both stood
+    in the step of every state-space layer."""
+    assert f"bf16[{layers},{B},{3 * C}]" in text
+    lines = text.split("\n")
+    axis = re.compile(rf"= bf16\[{B},[34],{C}\]")
+    made = [ln.strip()[:120] for ln in lines if axis.search(ln)]
+    assert not made, made
+    state = re.compile(rf"bf16\[(\d+,)?{B},({3 * C}|[34],{C})\]")
+    gathers = [ln.strip()[:160] for ln in lines
+               if " gather(" in ln and state.search(ln)]
+    assert not gathers, gathers
+
+
 def test_granite_decode_chunk_ends_in_the_fused_kernel(
         compiled_kernels, one_chip, monkeypatch):
     """The granite cell's decode chunk in small (one state-space and one
@@ -438,6 +458,7 @@ def test_granite_decode_chunk_ends_in_the_fused_kernel(
         assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
     _assert_fused_epilogue(text, B, cfg.vocab_size)
     assert f"bf16[{cfg.hidden_dim},{cfg.vocab_size}]" not in text
+    _assert_one_token_convolution(text, 1, B, cfg.ssm.conv_dim)
 
 
 # the rollout cells' decode epilogue: slots x hidden x vocabulary
@@ -1402,9 +1423,14 @@ def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
 # and at the full-attention program's 4 since PR 52: the hashes stayed. At
 # the cells' tables of 40-128 pages these models' full layers run the new
 # plan like every other model's; their window layers run the old one.)
+# RE-PINNED by PR 54, on purpose and here only: the step's convolution is
+# ``ops/ssm.py:conv_step`` (static slices of the flat state, no ``[B, K,
+# C]`` array, no gather) where it was the many-token form at ``T = 1``
+# (PR 49's: 5f8d9a2459ff4965 and 252a10f65ca8b879); nothing else of the
+# two programs changed.
 KERNEL_DECODE_HASHES = {
-    "granite-4.0-h-micro": "5f8d9a2459ff4965",
-    "phi4-mini-flash": "252a10f65ca8b879",
+    "granite-4.0-h-micro": "e7e09b7d01ced581",
+    "phi4-mini-flash": "604ef403ddac6f68",
 }
 
 
@@ -1535,6 +1561,8 @@ def test_phi4flash_cell_decode_chunk_computes_its_head_once(
     assert not again, again
     _assert_fused_epilogue(text, 128, cfg.vocab_size)
     assert f"bf16[{cfg.hidden_dim},{cfg.vocab_size}]" not in text
+    # nine selective-scan mixers' convolution, 128 x 5,120 channels
+    _assert_one_token_convolution(text, 9, 128, cfg.ssm.conv_dim)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
 
@@ -1662,6 +1690,7 @@ def test_nemotron_cell_decode_chunk_copies_no_stack_and_no_state(
             and " get-tuple-element(" not in ln and "custom-call" not in ln
             and " while(" not in ln and " bitcast(" not in ln]
     assert not made, made
+    _assert_one_token_convolution(text, 5, 192, cfg.ssm.conv_dim)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 4 * 5 * 192 * 8 * 8 * 128 * 128
     assert mem.temp_size_in_bytes < 0.6e9
