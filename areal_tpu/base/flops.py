@@ -65,6 +65,8 @@ def param_count(cfg: ModelConfig, activated: bool = False) -> int:
         )
     else:
         attn = E * (H * D) + 2 * E * (cfg.n_kv_heads * D) + (H * D) * E
+        if cfg.attn_gate:
+            attn += E * (H * D)     # the gate's projection, as wide as q's
     if cfg.mlp_type == "gated":
         mlp = 3 * E * F
     elif cfg.mlp_type == "moe":

@@ -4,7 +4,7 @@ TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:34
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
 joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya, phi4flash,
-nemotron_h) via feature switches, exactly like the reference's single in-house architecture.
+nemotron_h, afmoe) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -256,6 +256,11 @@ class ModelConfig:
     # ``[L, D]`` (qwen3). "full": the whole projected vector BEFORE the
     # split, gains ``[L, Hq*D]`` / ``[L, Hkv*D]`` (olmoe).
     qk_norm_over: str = "head"
+    # Gated attention (``afmoe``): ``W_o (ctx * sigmoid(W_g a))``, a fifth
+    # projection ``layers.attn.wg [L, E, Hq * D]`` of the SAME normed input
+    # the q/k/v projections read, channel by channel on the heads' context
+    # before the output projection. The HF family sets it, a user never does.
+    attn_gate: bool = False
     sliding_window: Optional[int] = None
     # A PERIOD of layer kinds, where the layers of one stack differ in what
     # is static about their attention (``smallthinker``): layer ``l`` is
@@ -767,13 +772,34 @@ class ModelConfig:
             if (
                 not self.layer_pattern
                 or self.n_layers % len(self.layer_pattern)
-                or self.n_dense_layers or self.n_mtp_layers
+                or self.n_mtp_layers
                 or self.mla is not None or self.abs_position_embedding
             ):
                 raise ValueError(
-                    "layer_pattern: a period that divides n_layers, in a "
-                    "model of one stack with rotary or no positions"
+                    "layer_pattern: a period that divides n_layers (counted "
+                    "over the model's layers, across leading dense layers), "
+                    "with rotary or no positions; with latent attention or "
+                    "prediction modules it is not supported"
                 )
+        if self.attn_gate and (
+            self.mla is not None or self.cca is not None
+            or self.ssm is not None or self.diff_attn
+        ):
+            raise ValueError(
+                "attn_gate: a gate on the context of plain q/k/v attention; "
+                "with latent, convolved or differential attention or a "
+                "stack plan it is not supported"
+            )
+        if self.n_dense_layers and self.mla is None and (
+            self.moe.router_on_layer_input or self.residual_scaling
+            or self.cca is not None or self.use_attn_proj_bias
+        ):
+            raise ValueError(
+                "n_dense_layers: leading dense layers without latent "
+                "attention, with a router on the layer's input, learned "
+                "residual scaling, convolved attention or an output bias, "
+                "are not supported (no published model has both)"
+            )
         if self.cca is not None:
             if (
                 self.mla is not None or self.ssm is not None

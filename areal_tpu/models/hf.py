@@ -2,8 +2,8 @@
 
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
-joyai_llm_flash, smallthinker, ouro, granitemoehybrid, phi4flash and
-nemotron_h are added here) consumed by
+joyai_llm_flash, smallthinker, ouro, granitemoehybrid, phi4flash,
+nemotron_h and afmoe are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -1671,15 +1671,22 @@ _JOYAI_MLP = {
     "shared_down": "mlp.shared_experts.down_proj.weight",
 }
 _JOYAI_EXPERT = {"w_gate": "gate_proj", "w_up": "up_proj", "w_down": "down_proj"}
+_JOYAI_NORMS = {"ln1": "input_layernorm", "ln2": "post_attention_layernorm"}
+# a family's three tables: (norms, attention leaves, MLP leaves)
+_JOYAI_NAMES = (_JOYAI_NORMS, _JOYAI_ATTN, _JOYAI_MLP)
 
 
 def _is_vector(leaf: str) -> bool:
     return leaf.endswith("_norm") or leaf.startswith("b")
 
 
-def _joyai_stack_from_hf(sd: HFState, ids: List[int], moe: Optional[MoEConfig]):
+def _joyai_stack_from_hf(sd: HFState, ids: List[int], moe: Optional[MoEConfig],
+                         names=_JOYAI_NAMES):
     """The layers ``ids`` of the checkpoint as one stack; ``moe`` None for
-    dense layers."""
+    dense layers. ``names``: the family's tables (``afmoe`` has the same
+    two stacks under other names, with two more norms and a gate)."""
+    norms, attn_names, mlp_names = names
+
     def stack(name, leaf):
         return np.stack([
             np.asarray(sd[f"model.layers.{i}.{name}"]).T if not _is_vector(leaf)
@@ -1692,13 +1699,13 @@ def _joyai_stack_from_hf(sd: HFState, ids: List[int], moe: Optional[MoEConfig]):
             [np.asarray(sd[f"model.layers.{i}.{name}.weight"]) for i in ids]
         )}
 
-    attn = {leaf: stack(name, leaf) for leaf, name in _JOYAI_ATTN.items()}
+    attn = {leaf: stack(name, leaf) for leaf, name in attn_names.items()}
     if moe is None:
-        mlp = {leaf: stack(_JOYAI_MLP[leaf], leaf)
+        mlp = {leaf: stack(mlp_names[leaf], leaf)
                for leaf in ("w_gate", "w_up", "w_down")}
     else:
-        mlp = {"router": stack(_JOYAI_MLP["router"], "router"),
-               "b_router": stack(_JOYAI_MLP["b_router"], "b_router")}
+        mlp = {"router": stack(mlp_names["router"], "router"),
+               "b_router": stack(mlp_names["b_router"], "b_router")}
         for leaf, name in _JOYAI_EXPERT.items():
             mlp[leaf] = np.stack([
                 np.stack([
@@ -1711,18 +1718,18 @@ def _joyai_stack_from_hf(sd: HFState, ids: List[int], moe: Optional[MoEConfig]):
             ])
         if moe.n_shared_experts:
             for leaf in ("shared_gate", "shared_up", "shared_down"):
-                mlp[leaf] = stack(_JOYAI_MLP[leaf], leaf)
-    return {"ln1": gain("input_layernorm"), "attn": attn,
-            "ln2": gain("post_attention_layernorm"), "mlp": mlp}
+                mlp[leaf] = stack(mlp_names[leaf], leaf)
+    return {**{ours: gain(theirs) for ours, theirs in norms.items()},
+            "attn": attn, "mlp": mlp}
 
 
-def _joyai_stack_to_hf(sd: HFState, stack, ids: List[int]):
+def _joyai_stack_to_hf(sd: HFState, stack, ids: List[int], names=_JOYAI_NAMES):
+    norms, attn_names, mlp_names = names
     for n, i in enumerate(ids):
         p = f"model.layers.{i}."
-        sd[p + "input_layernorm.weight"] = np.asarray(stack["ln1"]["weight"][n])
-        sd[p + "post_attention_layernorm.weight"] = np.asarray(
-            stack["ln2"]["weight"][n])
-        for leaf, name in _JOYAI_ATTN.items():
+        for ours, theirs in norms.items():
+            sd[p + theirs + ".weight"] = np.asarray(stack[ours]["weight"][n])
+        for leaf, name in attn_names.items():
             a = np.asarray(stack["attn"][leaf][n])
             sd[p + name] = a if _is_vector(leaf) else a.T
         mlp = stack["mlp"]
@@ -1733,17 +1740,20 @@ def _joyai_stack_to_hf(sd: HFState, stack, ids: List[int]):
                         np.asarray(a[n, j]).T)
             else:
                 a = np.asarray(a[n])
-                sd[p + _JOYAI_MLP[leaf]] = a if _is_vector(leaf) else a.T
+                sd[p + mlp_names[leaf]] = a if _is_vector(leaf) else a.T
 
 
-def _joyai_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+def _joyai_params_from_hf(sd: HFState, cfg: ModelConfig,
+                          names=_JOYAI_NAMES) -> Dict[str, Any]:
     L, nd = cfg.n_layers, cfg.n_dense_layers
     params: Dict[str, Any] = {
         "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
     }
     if nd:
-        params["dense_layers"] = _joyai_stack_from_hf(sd, list(range(nd)), None)
-    params["layers"] = _joyai_stack_from_hf(sd, list(range(nd, L)), cfg.moe)
+        params["dense_layers"] = _joyai_stack_from_hf(
+            sd, list(range(nd)), None, names)
+    params["layers"] = _joyai_stack_from_hf(
+        sd, list(range(nd, L)), cfg.moe, names)
     params["final_ln"] = {"weight": np.asarray(sd["model.norm.weight"])}
     if cfg.n_mtp_layers:
         # the modules follow the stack as layers L, L+1, ...; their copies
@@ -1765,15 +1775,16 @@ def _joyai_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
     return params
 
 
-def _joyai_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+def _joyai_params_to_hf(params: Dict[str, Any], cfg: ModelConfig,
+                        names=_JOYAI_NAMES) -> HFState:
     L, nd = cfg.n_layers, cfg.n_dense_layers
     sd: HFState = {
         "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
         "model.norm.weight": np.asarray(params["final_ln"]["weight"]),
     }
     if nd:
-        _joyai_stack_to_hf(sd, params["dense_layers"], list(range(nd)))
-    _joyai_stack_to_hf(sd, params["layers"], list(range(nd, L)))
+        _joyai_stack_to_hf(sd, params["dense_layers"], list(range(nd)), names)
+    _joyai_stack_to_hf(sd, params["layers"], list(range(nd, L)), names)
     if not cfg.is_critic and not cfg.tied_embedding:
         sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
     if cfg.n_mtp_layers:
@@ -1800,6 +1811,204 @@ register_hf_family(
         config_to_hf=_joyai_config_to_hf,
         params_from_hf=_joyai_params_from_hf,
         params_to_hf=_joyai_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# afmoe (Arcee's Trinity: gated attention; window layers with rotary and
+# full layers without positions in a period counted ACROSS two leading dense
+# layers and the expert layers; four norms a layer; a sigmoid router with a
+# choice-only bias, a shared expert; muP's scaled embedding)
+# --------------------------------------------------------------------------- #
+
+_AFMOE_KINDS = ("sliding_attention", "full_attention")
+
+
+def _afmoe_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read. Layer ``l`` is a window
+    layer of ``sliding_window`` positions WITH rotary where ``layer_types[l]``
+    is ``sliding_attention`` and a full layer with NO positional encoding
+    where ``full_attention``; the first ``num_dense_layers`` layers are
+    SwiGLUs of ``intermediate_size``, the rest route ``num_experts_per_tok``
+    of ``num_experts`` experts of ``moe_intermediate_size`` by the sigmoid
+    of the router's logits plus ``expert_bias`` (the CHOICE only), weighted
+    by the scores alone, renormalised (``route_norm``) and times
+    ``route_scale``, beside ``num_shared_experts`` shared ones;
+    ``mup_enabled`` multiplies the embedding by ``sqrt(hidden_size)``.
+    ``load_balance_coeff`` is carried (``moe.aux_loss_coeff``): the
+    published model moves ``expert_bias`` by it and has no loss term; this
+    trainer never updates the bias and adds its switch-style balance loss
+    times the coefficient where a caller asks for the auxiliary loss.
+    ``use_grouped_mm`` (which kernel the published code runs the experts
+    through) shapes nothing here. What the family does not do is refused,
+    never guessed: a group-limited router (``n_group``, ``topk_group``,
+    ``num_expert_groups``, ``num_limited_groups`` other than 1), a
+    ``score_func`` other than ``sigmoid``, a non-null ``rope_scaling``, a
+    ``layer_types`` entry of another name or too few of them, a
+    ``layer_types`` that ``global_attn_every_n_layers`` does not reproduce,
+    window layers without ``sliding_window``, attention bias, an
+    activation other than ``silu``, ``num_dense_layers`` that leaves no
+    expert layer. ``tie_word_embeddings`` is read as given."""
+    def must(key, ok, default=None):
+        v = hf.get(key, default)
+        if v not in ok:
+            raise ValueError(
+                f"afmoe: {key}={v!r} is not supported (implemented: {ok})")
+
+    for key in ("n_group", "topk_group", "num_expert_groups",
+                "num_limited_groups"):
+        must(key, (1,), 1)
+    must("score_func", ("sigmoid",), "sigmoid")
+    must("rope_scaling", (None,))
+    must("hidden_act", ("silu",), "silu")
+    must("attention_bias", (False,), False)
+    L = hf["num_hidden_layers"]
+    every = hf.get("global_attn_every_n_layers", 4)
+    layer_types = hf.get("layer_types")
+    if layer_types is None:
+        layer_types = [
+            _AFMOE_KINDS[(l + 1) % every == 0] for l in range(L)]
+    layer_types = list(layer_types)
+    if len(layer_types) < L:
+        raise ValueError(
+            f"afmoe: layer_types has {len(layer_types)} entries for {L} layers")
+    layer_types = layer_types[:L]
+    for t in layer_types:
+        if t not in _AFMOE_KINDS:
+            raise ValueError(f"afmoe: layer_types entry {t!r} is not supported")
+    if layer_types != [_AFMOE_KINDS[(l + 1) % every == 0] for l in range(L)]:
+        raise ValueError(
+            "afmoe: layer_types is not a full layer every "
+            f"global_attn_every_n_layers={every}")
+    window = hf.get("sliding_window")
+    if "sliding_attention" in layer_types and not window:
+        raise ValueError("afmoe: window layers need sliding_window")
+    per_layer = [
+        (window, True) if t == "sliding_attention" else (None, False)
+        for t in layer_types]
+    period = _layout_period(per_layer)
+    n_dense = hf.get("num_dense_layers", 0)
+    if not 0 <= n_dense < L:
+        raise ValueError("afmoe: num_dense_layers must leave an expert layer")
+    n_q = hf["num_attention_heads"]
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=n_q,
+        n_kv_heads=hf.get("num_key_value_heads") or n_q,
+        head_dim=hf.get("head_dim") or hf["hidden_size"] // n_q,
+        hidden_dim=hf["hidden_size"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 131072),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5),
+        norm_branch_out=True,
+        qk_layernorm=True,
+        attn_gate=True,
+        rotary_base=hf.get("rope_theta", 10000.0),
+        layer_pattern=tuple(per_layer[:period]),
+        mlp_type="moe",
+        n_dense_layers=n_dense,
+        moe=MoEConfig(
+            num_experts=hf["num_experts"],
+            top_k=hf["num_experts_per_tok"],
+            routed_scaling_factor=hf.get("route_scale", 1.0),
+            aux_loss_coeff=hf.get("load_balance_coeff", 0.0),
+            norm_topk_prob=bool(hf.get("route_norm", True)),
+            expert_dim=hf["moe_intermediate_size"],
+            n_shared_experts=hf.get("num_shared_experts") or 0,
+            scoring="sigmoid",
+            selection_bias=True,
+        ),
+        tied_embedding=bool(hf.get("tie_word_embeddings", False)),
+        normalize_embed=bool(hf.get("mup_enabled", False)),
+    )
+
+
+def _afmoe_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    """The published keys, key for key."""
+    kinds = [cfg.layer_kinds[l % cfg.period] for l in range(cfg.n_layers)]
+    windows = {w for w, _ in kinds if w is not None}
+    if len(windows) > 1 or any((w is None) == r for w, r in kinds):
+        raise ValueError(
+            "afmoe: window layers of one sliding_window with rotary, full "
+            "layers without positions")
+    full = [l for l, (w, _) in enumerate(kinds) if w is None]
+    moe = cfg.moe
+    return {
+        "model_type": "afmoe",
+        "architectures": ["AfmoeForCausalLM"],
+        "global_attn_every_n_layers": full[0] + 1 if full else cfg.n_layers + 1,
+        "head_dim": cfg.head_dim,
+        "hidden_act": cfg.activation_function,
+        "hidden_size": cfg.hidden_dim,
+        "intermediate_size": cfg.intermediate_dim,
+        "layer_types": [_AFMOE_KINDS[w is None] for w, _ in kinds],
+        "load_balance_coeff": moe.aux_loss_coeff,
+        "max_position_embeddings": cfg.n_positions,
+        "moe_intermediate_size": cfg.expert_dim,
+        "mup_enabled": cfg.normalize_embed,
+        "n_group": 1,
+        "num_attention_heads": cfg.n_q_heads,
+        "num_dense_layers": cfg.n_dense_layers,
+        "num_expert_groups": 1,
+        "num_experts": moe.num_experts,
+        "num_experts_per_tok": moe.top_k,
+        "num_hidden_layers": cfg.n_layers,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "num_limited_groups": 1,
+        "num_shared_experts": moe.n_shared_experts,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_scaling": None,
+        "rope_theta": cfg.rotary_base,
+        "route_norm": moe.norm_topk_prob,
+        "route_scale": moe.routed_scaling_factor,
+        "score_func": "sigmoid",
+        "sliding_window": windows.pop() if windows else None,
+        "tie_word_embeddings": cfg.tied_embedding,
+        "topk_group": 1,
+        "use_grouped_mm": True,
+        "vocab_size": cfg.vocab_size,
+    }
+
+
+# our leaf -> the checkpoint's name under ``model.layers.{i}.`` (matrices
+# are transposed on the way, gains and the bias are not)
+_AFMOE_NORMS = {
+    "ln1": "input_layernorm", "attn_out_ln": "post_attention_layernorm",
+    "ln2": "pre_mlp_layernorm", "mlp_out_ln": "post_mlp_layernorm",
+}
+_AFMOE_ATTN = {
+    "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+    "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+    "wg": "self_attn.gate_proj.weight",
+    "q_norm": "self_attn.q_norm.weight", "k_norm": "self_attn.k_norm.weight",
+}
+_AFMOE_MLP = {
+    "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+    "w_down": "mlp.down_proj.weight",
+    "router": "mlp.router.gate.weight", "b_router": "mlp.expert_bias",
+    "shared_gate": "mlp.shared_experts.gate_proj.weight",
+    "shared_up": "mlp.shared_experts.up_proj.weight",
+    "shared_down": "mlp.shared_experts.down_proj.weight",
+}
+
+
+_AFMOE_NAMES = (_AFMOE_NORMS, _AFMOE_ATTN, _AFMOE_MLP)
+
+
+register_hf_family(
+    HFFamily(
+        name="afmoe",
+        hf_model_type="afmoe",
+        config_from_hf=_afmoe_config_from_hf,
+        config_to_hf=_afmoe_config_to_hf,
+        # two stacks as ``joyai_llm_flash``'s, under this family's names
+        # (no prediction module: ``n_mtp_layers`` is 0)
+        params_from_hf=lambda sd, cfg: _joyai_params_from_hf(
+            sd, cfg, _AFMOE_NAMES),
+        params_to_hf=lambda params, cfg: _joyai_params_to_hf(
+            params, cfg, _AFMOE_NAMES),
     )
 )
 

@@ -59,7 +59,8 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         cfg.n_kv_heads,
         cfg.intermediate_dim,
         cfg.vocab_size,
-        cfg.n_attn_layers,
+        # (leading dense layers are a stack of their own, built last)
+        cfg.n_attn_layers - cfg.n_dense_layers,
     )
     std = 0.02
     rngs = iter(_split(rng, 64))
@@ -78,23 +79,29 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
     has_ln_bias = cfg.layer_norm_type == "layer"
     if cfg.mla is not None:
         return _init_latent(cfg, rng, dtype)
-    attn: Dict[str, Any] = {
-        "wq": w((L, E, Hq * D)),
-        "wk": w((L, E, Hkv * D)),
-        "wv": w((L, E, Hkv * D)),
-        "wo": w((L, Hq * D, E)),
-    }
-    if cfg.use_attention_bias:
-        attn["bq"] = jnp.zeros((L, Hq * D), dtype)
-        attn["bk"] = jnp.zeros((L, Hkv * D), dtype)
-        attn["bv"] = jnp.zeros((L, Hkv * D), dtype)
-    if cfg.use_attn_proj_bias:
-        attn["bo"] = jnp.zeros((L, E), dtype)
-    if cfg.qk_layernorm:
-        # per head [L, D] (qwen3) or over the whole projection (olmoe)
-        full = cfg.qk_norm_full
-        attn["q_norm"] = jnp.ones((L, Hq * D if full else D), dtype)
-        attn["k_norm"] = jnp.ones((L, Hkv * D if full else D), dtype)
+    def attn_params(n):
+        attn: Dict[str, Any] = {
+            "wq": w((n, E, Hq * D)),
+            "wk": w((n, E, Hkv * D)),
+            "wv": w((n, E, Hkv * D)),
+            "wo": w((n, Hq * D, E)),
+        }
+        if cfg.use_attention_bias:
+            attn["bq"] = jnp.zeros((n, Hq * D), dtype)
+            attn["bk"] = jnp.zeros((n, Hkv * D), dtype)
+            attn["bv"] = jnp.zeros((n, Hkv * D), dtype)
+        if cfg.use_attn_proj_bias:
+            attn["bo"] = jnp.zeros((n, E), dtype)
+        if cfg.qk_layernorm:
+            # per head [L, D] (qwen3) or over the whole projection (olmoe)
+            full = cfg.qk_norm_full
+            attn["q_norm"] = jnp.ones((n, Hq * D if full else D), dtype)
+            attn["k_norm"] = jnp.ones((n, Hkv * D if full else D), dtype)
+        if cfg.attn_gate:
+            attn["wg"] = w((n, E, Hq * D))
+        return attn
+
+    attn = attn_params(L)
 
     def diff_params(n):
         # a differential pair's norm gain over the value row and the four
@@ -296,6 +303,15 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         params["head"] = {"weight": w((E, 1))}
     elif not cfg.tied_embedding:
         params["head"] = {"weight": w((E, V))}
+    if cfg.n_dense_layers:
+        # an expert model's leading dense layers: attention as the expert
+        # layers', a SwiGLU of ``intermediate_dim`` where those route
+        nd = cfg.n_dense_layers
+        dense = block(nd, "attn", attn_params(nd))
+        if cfg.norm_branch_out:
+            dense["attn_out_ln"] = ln(has_ln_bias, nd)
+            dense["mlp_out_ln"] = ln(has_ln_bias, nd)
+        params = {"embed": params.pop("embed"), "dense_layers": dense, **params}
     return params
 
 
@@ -426,6 +442,8 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         width = "heads" if cfg.qk_norm_full else None
         attn["q_norm"] = ("layer", width)
         attn["k_norm"] = ("layer", width)
+    if cfg.attn_gate:
+        attn["wg"] = ("layer", "embed", "heads")
     if cfg.cca is not None:
         # no tensor-parallel split of the convolved latent (the engine
         # refuses a mesh for this family); the trainer replicates these
@@ -569,6 +587,10 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         axes["head"] = {"weight": ("embed", None)}
     elif not cfg.tied_embedding:
         axes["head"] = {"weight": ("embed", "vocab")}
+    if cfg.n_dense_layers:
+        axes["dense_layers"] = {
+            **{k: v for k, v in axes["layers"].items() if k != "mlp"},
+            "mlp": dense_mlp}
     return axes
 
 
@@ -860,15 +882,23 @@ def _attn_params(lp):
     return {**p, "index": lp["index"]} if "index" in lp else p
 
 
-def _attn_out(p, ctx):
+def _attn_out(p, ctx, h=None):
     """ctx: [..., H, D] -> [..., E]. Where a value head is narrower than a
     key head (latent attention in its expanded form pads ``v`` with zeros
     up to the key's width for the one-width kernels), the padding's share
-    of ``ctx`` is dropped here."""
+    of ``ctx`` is dropped here. ``h``: the normed input the layer's q/k/v
+    projections read; a gated layer (``p["wg"]``, ``cfg.attn_gate``)
+    projects its gate from it, ``W_o (ctx * sigmoid(W_g h))``, the sigmoid
+    in float32."""
     dv = p["wo"].shape[-2] // ctx.shape[-2]
     if dv != ctx.shape[-1]:
         ctx = ctx[..., :dv]
-    y = ctx.reshape(*ctx.shape[:-2], -1) @ p["wo"]
+    ctx = ctx.reshape(*ctx.shape[:-2], -1)
+    if "wg" in p:
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid((h @ p["wg"]).astype(jnp.float32))
+            ctx = ctx * gate.astype(ctx.dtype)
+    y = ctx @ p["wo"]
     if "bo" in p:
         y = y + p["bo"]
     return y
@@ -970,7 +1000,7 @@ def _routed_at(cfg: ModelConfig, routed: Optional[Params], li, j: int):
     return routed, layer - cfg.n_dense_layers
 
 
-def _scan_periods(layers, carry, stack, xs=(), unroll=1):
+def _scan_periods(layers, carry, stack, xs=(), unroll=1, at=0, xs_at=0):
     """:func:`_scan_layers` of a stack whose layers come in a PERIOD of
     kinds (``cfg.layer_pattern``): ``layers[j]`` is the layer function of
     position ``j``, with what is static about its kind (window, rotary,
@@ -984,19 +1014,25 @@ def _scan_periods(layers, carry, stack, xs=(), unroll=1):
     it. (Handing the scan the stack viewed as ``[L / p, p, ...]`` makes
     the compiler materialise a whole period's slice, whose positions have
     several readers: 3 x 0.96 GB of copies a period at the 21B's widths,
-    and 1.9 GB over the chip's memory in the decode chunk.)"""
+    and 1.9 GB over the chip's memory in the decode chunk.)
+
+    ``at``, ``xs_at`` (:func:`_scan_across`): the whole periods start at
+    entry ``at`` of the stack and entry ``xs_at`` of ``xs``."""
     p = len(layers)
-    n_periods = jax.tree.leaves(stack)[0].shape[0] // p
+    n_periods = (jax.tree.leaves(stack)[0].shape[0] - at) // p
+
+    def cut(tree, period, j):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(
+                a, period * p + j, 0, keepdims=False),
+            tree)
 
     def body(carry, period):
         ys = []
         for j, layer in enumerate(layers):
-            inp = jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, period * p + j, 0, keepdims=False),
-                (stack, *xs) if xs else stack,
-            )
-            carry, y = layer(carry, inp)
+            lp = cut(stack, period, j + at)
+            carry, y = layer(
+                carry, (lp, *cut(tuple(xs), period, j + xs_at)) if xs else lp)
             ys.append(y)
         return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
 
@@ -1006,6 +1042,45 @@ def _scan_periods(layers, carry, stack, xs=(), unroll=1):
     return carry, jax.tree.map(
         lambda a: a.reshape(a.shape[0] * p, *a.shape[2:]), ys
     )
+
+
+def _scan_across(layers, carry, params: Params, xs=(), unroll=1):
+    """:func:`_scan_periods` of a model whose period of layer kinds is
+    counted over the MODEL's layers and whose layers are TWO stacks of
+    different shape (``afmoe``: ``n_dense_layers`` dense layers, then the
+    expert layers), so that the expert stack starts wherever in the period
+    the dense run ends. The dense layers and as many expert layers as
+    complete their period run one by one, each as the kind of its place in
+    the model, its weights cut from its own stack at a static index; the
+    rest of the expert stack is whole periods and ONE scan over them.
+    ``xs`` and the stacked results are over all the model's layers; a
+    result that the dense layers give as ``None`` (their routing) has the
+    expert layers' alone, as in :func:`_scan_layers`. The running period
+    ``li`` of the engine's forwards counts on through both parts, so a
+    layer's pages, table and index in the expert stack
+    (:func:`_routed_at`) are those of its place in the model."""
+    p = len(layers)
+    dense, experts = params["dense_layers"], params["layers"]
+    nd = jax.tree.leaves(dense)[0].shape[0]
+    head = (-nd) % p            # expert layers that complete the period
+    outs = []
+    for l in range(nd + head):
+        stack, i = (dense, l) if l < nd else (experts, l - nd)
+        lp = jax.tree.map(lambda a: a[i], stack)
+        xs_l = tuple(x[l] for x in xs)
+        carry, y = layers[l % p](carry, (lp, *xs_l) if xs else lp)
+        outs.append(y)
+    rest = None
+    if jax.tree.leaves(experts)[0].shape[0] > head:
+        carry, rest = _scan_periods(
+            layers, carry, experts, xs, unroll, at=head, xs_at=nd + head)
+    joined = []
+    for m, parts in enumerate(zip(*outs)):
+        parts = [y[None] for y in parts if y is not None]
+        if rest is not None and rest[m] is not None:
+            parts.append(rest[m])
+        joined.append(jnp.concatenate(parts, axis=0) if parts else None)
+    return carry, tuple(joined)
 
 
 def _scan_layers(layer, carry, params: Params, xs=(), unroll=1):
@@ -1019,6 +1094,8 @@ def _scan_layers(layer, carry, params: Params, xs=(), unroll=1):
     kinds (:func:`_scan_periods`; a list of one is that one)."""
     if isinstance(layer, (list, tuple)):
         if len(layer) > 1:
+            if "dense_layers" in params:
+                return _scan_across(layer, carry, params, xs, unroll)
             return _scan_periods(layer, carry, params["layers"], xs, unroll)
         (layer,) = layer
     stacks = _layer_stacks(params)
@@ -1529,7 +1606,10 @@ def forward_packed(
             else None
         )
         ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
-        x = _add_branch(cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx))
+        # (the gate's input likewise: one RMSNorm again, not carried)
+        gate_in = _norm(cfg, lp["ln1"], x) if cfg.attn_gate else None
+        x = _add_branch(
+            cfg, lp, "attn_out_ln", x, _attn_out(lp["attn"], ctx, gate_in))
         x, aux, routing, r = _ffn(cfg, lp, x, layer_in, router_state=r)
         return (x if r0 is None else (x, r)), (aux, routing)
 
@@ -1931,7 +2011,7 @@ def prefill(
         ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
-            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+            _attn_out(lp["attn"], ctx.astype(x.dtype), h))
         x, _, _, r = _ffn(cfg, lp, x, h, router_state=r)
         if shared0:
             return (x, mem, ks, vs), (None if cross else (k, v, cc))
@@ -2045,7 +2125,7 @@ def decode_step(
         ctx = _dense_ctx(cfg, _attn_params(lp), ctx)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
-            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+            _attn_out(lp["attn"], ctx.astype(x.dtype), h))
         x, _, _, r = _ffn(cfg, lp, x, h, router_state=r)
         if shared0:
             return (x, mem, ks, vs), (None if cross else (kc, vc))
@@ -2494,7 +2574,7 @@ def _extend_layers(
                 rest = (*rest[:-2], k, v)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
-            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+            _attn_out(lp["attn"], ctx.astype(x.dtype), h))
         x, _, _, r = _ffn(
             cfg, lp, x, h, _routed_at(cfg, routed, li, j),
             router_state=None if r0 is None else rest[0])
@@ -2827,7 +2907,7 @@ def decode_step_paged(
                 ctx = attend(q, k, v, li, _kind_table(table_o, j), **kw)
         x = _add_branch(
             cfg, lp, "attn_out_ln", x,
-            _attn_out(lp["attn"], ctx.astype(x.dtype)))
+            _attn_out(lp["attn"], ctx.astype(x.dtype), h))
         x, _, routing, r = _ffn(
             cfg, lp, x, h, _routed_at(cfg, routed, li, j),
             router_state=None if r0 is None else rest[0])

@@ -55,9 +55,23 @@ weights at 819 GB/s):
                                            384 100 %   1.72    2.92    2.32 (64)
                                           1024 100 %   1.72    7.51    3.68 (128)
 
+    128 of 2048 x 1024, 8 a row, 1 shared      8  40 %   0.78    2.28    0.93
+    (a sigmoid router; both with the router   32  86 %   1.69    2.29    1.93
+    and the shared expert, 0.04-0.09 ms:      80  99 %   1.94    2.25    2.24
+    PR 55)                                   128 100 %   1.96    2.26    2.30
+                                             144 100 %   1.96    2.26    2.34
+                                             192 100 %   1.97    2.27    2.36
+                                             256 100 %   1.97    2.62    2.41
+                                             512 100 %   1.97    4.46    2.81
+                                            1024 100 %   1.97    8.80    3.49
+
 ``grouped`` includes the sort, the gather and the weighted sum around the
 call (PR 53's rows: both with the router and the latent projections, 0.24-
-0.47 ms timed alone, taken off). The dense dispatch sits on its bytes up to about half the ridge and
+0.47 ms timed alone, taken off). At 128 experts of 2048 x 1024 the two
+cross later, at ~220 rows (0.9 of the ridge): between the rule's 144 and
+there the einsums are 3-4 % ahead; no program of the benchmark has such
+rows at that shape and the rule was left as it is (PERF.md section 7,
+open after PR 55). The dense dispatch sits on its bytes up to about half the ridge and
 climbs with the rows from there; the kernel climbs a quarter as fast (the
 padded rows it moves), so the two cross at 0.58 of the ridge at 256
 experts (139 rows) and at 0.67 at 64 (161), and below the crossing the

@@ -669,6 +669,28 @@ def phase_serve(sz, args):
                 + ((MOE_GROUPED_KERNEL,) if latent["moe_grouped"] else ())),
             f"the zaya model's decode chunk lacks a kernel: "
             f"{latent['kernels']}")
+    # ... and a model with WINDOW kinds through the engine (family
+    # ``afmoe``, one dense + three expert layers at Trinity-Mini's widths,
+    # kinds S, S, S, F, a gate on attention's output): a period that
+    # crosses the dense and the expert stack, prompts past the window so
+    # both programs of the paged kernel run and window pages go back
+    trinity, _ = helper(d, "trinity", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(trinity["logprobs"]["correct"]
+            and not trinity["control_full_attention_correct"]
+            and trinity["prefix_hit_tokens"][1:] == trinity["prefix_hit_tokens"][1:2] * 3
+            and trinity["prefix_hit_tokens"][1] > 0
+            and trinity["window_pages_released"] > 0,
+            f"window and full layers across two stacks: served log-probs, "
+            f"the prefix hits or the window's release are off: {trinity}")
+    require(args.rehearse or all(
+                any(k == want or k.startswith(want + ".")
+                    for k in trinity["kernels"])
+                for want in ("paged_decode", "paged_decode_window",
+                             KV_WRITE_KERNEL)),
+            f"the afmoe model's decode chunk lacks a kernel: "
+            f"{trinity['kernels']}")
     # the server has given the chip back: dense recompute in its own child
     ref, ref_secs = helper(d, "recompute", {
         "ckpt": ckpt,
@@ -728,6 +750,7 @@ def phase_serve(sz, args):
         "fused_sample_vs_head": fused,
         "moe_grouped_vs_einsums": grouped,
         "state_space_model": hybrid,
+        "window_kinds_across_two_stacks": trinity,
         "peak_hbm_gib": round(
             metrics.get("hbm_peak_bytes_in_use", 0) / 2**30, 2),
         "checkpoint_gib": round(made["bytes"] / 2**30, 2),
@@ -1688,8 +1711,111 @@ def child_zaya(arg):
     })
 
 
+def child_trinity(arg):
+    """A four-layer model of family ``afmoe`` at Trinity-Mini's widths (toy
+    widths under ``--rehearse``) through the generation engine, seeded as
+    the benchmark seeds it: ONE dense + three expert layers, kinds S, S,
+    S, F, so the period crosses the stacks' boundary, a gate on attention's
+    output, four norms a layer. A group of four shares a prompt longer than
+    the window (the first prefills in chunks past it, the rest hit its
+    pages), one request generates alone; the served log-probs are held to
+    the float32 reference given the program's routing
+    (``rollout_afmoe_inproc._verdict``), the reference with every layer
+    FULL has to be refused, and the decode chunk's program is searched for
+    the kernels it should hold (``paged_decode_window``, ``paged_decode``,
+    ``kv_page_write``)."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.gen.engine import GenerationEngine, GenRequest
+    from benchmark import correct, sut, weights
+    from benchmark.drivers import rollout_afmoe_inproc as drv
+
+    with open(os.path.join(
+            ROOT, "benchmark/configs/trinity-mini-l8.json")) as f:
+        arch = json.load(f)
+    with open(os.path.join(
+            ROOT, "benchmark/traffic/grpo16_closed80_out15k_w2k.json")) as f:
+        chk = json.load(f)["check"]
+    arch.update(num_hidden_layers=4, layer_types=arch["layer_types"][:4],
+                num_dense_layers=1, sliding_window=256, num_experts=16)
+    page, prompt_len, new = 128, 600, 48
+    chk = dict(chk, long_max_tokens=prompt_len + new)
+    if arg["rehearse"]:
+        arch.update(hidden_size=64, intermediate_size=96, head_dim=16,
+                    num_attention_heads=4, num_key_value_heads=2,
+                    moe_intermediate_size=32, num_experts=4,
+                    num_experts_per_tok=2, vocab_size=512, sliding_window=16,
+                    serving_dtype="float32")
+        page, prompt_len, new = 8, 40, 12
+        chk = dict(chk, seq_mean_abs_diff_limit_nats=1e-3, long_max_tokens=64)
+    cfg = sut.model_config(arch, {})
+    params = weights.make_weights(
+        sut.weight_shapes(cfg, cfg.dtype), arg["seed"], jnp.dtype(cfg.dtype))
+    eng = GenerationEngine(
+        cfg, params, max_slots=8, max_seqlen=6 * page + 64,
+        max_new_tokens_cap=64, page_size=page, record_routing=True,
+        seed=arg["seed"] % (2**31 - 1))
+    rng = np.random.default_rng(arg["seed"])
+    shared = rng.integers(1, cfg.vocab_size, prompt_len).tolist()
+    alone = rng.integers(1, cfg.vocab_size, prompt_len // 3).tolist()
+    prompts = {**{f"g{i}": shared for i in range(4)}, "alone": alone}
+    # the first member fills the registry, its siblings hit it
+    eng.submit(GenRequest(rid="g0", input_ids=shared, max_new_tokens=new,
+                          temperature=1.0))
+    outs = {o.rid: o for o in eng.run_until_done(decode_steps=8)}
+    for rid, p in list(prompts.items())[1:]:
+        eng.submit(GenRequest(
+            rid=rid, input_ids=p, max_new_tokens=new, temperature=1.0))
+    outs.update((o.rid, o) for o in eng.run_until_done(decode_steps=8))
+    key = sorted(eng._jit_chunk)[-1]
+    chunk = eng._chunk_fn(*key)
+    names = sorted(set(re.findall(
+        r'kernel_name = "([^"]+)"',
+        chunk.lower(
+            eng.params, eng.state,
+            jnp.asarray(eng._table_arg(slice(None), key[1])),
+            jnp.zeros((key[2],), jnp.int32)).as_text())))
+    samples = []
+    for rid, o in sorted(outs.items()):
+        p = prompts[rid]
+        toks = p + list(o.output_ids)
+        forced = np.full(
+            (cfg.n_moe_layers, len(toks), cfg.moe.top_k), -1, np.int32)
+        forced[:, len(p) - 1: -1] = np.asarray(
+            o.output_routing, np.int32).transpose(1, 0, 2)
+        samples.append({"tokens": toks, "start": len(p), "forced": forced,
+                        "logprobs": o.output_logprobs})
+    stats = dict(eng.stats)
+    eng.state = None
+    ref = correct.reference_module(arch["reference"])
+    verdict = drv._verdict(ref, params, arch, cfg.dtype, samples, chk)
+    full = drv._stand_in(
+        ref, params, arch, cfg.dtype, samples[:2], chk, "float32", window=None)
+    emit({
+        "widths": [cfg.hidden_dim, cfg.n_q_heads, cfg.n_kv_heads,
+                   cfg.head_dim, cfg.moe.num_experts, cfg.n_dense_layers],
+        "layer_kinds": [list(k) for k in cfg.layer_kinds],
+        "kernels": names,
+        "prefix_hit_tokens": [outs[f"g{i}"].prefix_hit_tokens
+                              for i in range(4)],
+        "window_pages_released": stats["window_pages_released"],
+        "router_agreement_given_earlier_choices": verdict.get(
+            "router_agreement_given_earlier_choices"),
+        "control_full_attention_correct": full["correct"],
+        "logprobs": {k: verdict.get(k) for k in (
+            "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
+            "mean_abs_diff_nats", "seq_mean_abs_diff_nats", "n_positions")},
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 CHILDREN = {
-    "zaya": child_zaya,
+    "zaya": child_zaya, "trinity": child_trinity,
     "device": child_device, "ckpt": child_ckpt, "recompute": child_recompute,
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,

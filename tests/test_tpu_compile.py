@@ -1357,6 +1357,10 @@ FORWARD_HASHES = {
     "smallthinker-21b-l8": (
         "a0e19bf38715ae01", "a91439fc8081a034", "c881c069dfe706c9",
         "a7f3ebf91e10838f"),
+    # new in PR 55 (taken at its commit): a period across two stacks
+    "trinity-mini-l8": (
+        "d86f8ec23d9f8fa8", "cf687d0d46bc2fd9", "70100e2a817c0dd7",
+        "c5bcb4d983e0a773"),
 }
 
 
@@ -1431,6 +1435,11 @@ def test_forwards_without_the_grouped_kernel_are_the_programs_they_were(
 KERNEL_DECODE_HASHES = {
     "granite-4.0-h-micro": "e7e09b7d01ced581",
     "phi4-mini-flash": "604ef403ddac6f68",
+    # new in PR 55 (taken at its commit): NOT a slot-order step (the rows go
+    # by length and the one full kind's shared pages through the prefix
+    # program); pinned so that a later change to the period across two
+    # stacks with the kernel shows
+    "trinity-mini-l8": "5cefdff0dd4b5893",
 }
 
 
@@ -1465,7 +1474,9 @@ def test_slot_order_steps_with_the_kernel_are_the_programs_they_were(config):
     """granite-4.0-h-micro's and Phi-4-mini-flash's decode steps keep slot
     order (``shared_prefix_applies``: ``slot_order``), so with the kernel
     they lower to what they lowered to before a step looked at its table
-    for rows that name the same pages."""
+    for rows that name the same pages. (``trinity-mini-l8``, PR 55: the
+    kernel step of a period across two stacks, pinned at its first
+    commit.)"""
     assert kernel_decode_hash(config) == KERNEL_DECODE_HASHES[config]
 
 
@@ -1717,3 +1728,125 @@ def test_nemotron_admission_wave_holds_the_grouped_kernel(
             and " get-tuple-element(" not in ln]
     assert not made, made
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# ------------------------------------------------------------------ #
+# Trinity-Mini cut to eight layers (benchmark/configs/trinity-mini-l8):
+# the decode chunk of the cell at its slots and pool, a period of layer
+# kinds that runs ACROSS the dense and the expert stack
+# ------------------------------------------------------------------ #
+
+# the cell trinity-mini-l8.rollout_out15k: 64 slots, a table of 128 pages of
+# 128 in each of the four kinds
+AFMOE_CELL = dict(B=64, M=128)
+
+
+def _afmoe_program(one_chip, n_pages: int, program: str = "jit_chunk"):
+    """``(cfg, the jitted program, its arguments as shapes on the chip)``
+    of the configuration under the engine at the cell's 64 slots of 128
+    pages in each of the four tables and a pool of ``n_pages``, placeholder
+    weights: the decode chunk, or (``jit_extend``) a wave of 8 x 128 tokens
+    against the pool."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from benchmark import sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "trinity-mini-l8.json")) as f:
+        cfg = sut.model_config(json.load(f), {})
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B, M = AFMOE_CELL["B"], AFMOE_CELL["M"]
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=B, max_seqlen=16384, max_new_tokens_cap=15360,
+        page_size=128, n_pages=80, admit_buckets=(2, 8),
+        record_routing=True, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.fused and eng.M == M
+    # a decode step's rows are under the ridge (the einsums); a full
+    # admission wave is over it (the kernel)
+    assert not eng._moe_grouped(B) and eng._moe_grouped(8 * eng.admit_chunk)
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    pages = eng.state.cache.pages
+    assert pages.shape[0] == 2 and pages.shape[2:] == (2, 4, 128, 128)
+    state = dataclasses.replace(
+        jax.tree.map(spec, eng.state),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (2, n_pages) + pages.shape[2:], pages.dtype, one_chip)))
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    params = jax.tree.map(spec, shapes)
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=eng.fused, with_topk=False)
+        return cfg, fn, (params, state, i32(4, B, M), i32(0))
+    fn = eng._extend_fn(8, 64, skip_pool=False)
+    return cfg, fn, (params, state, i32(8, eng.admit_chunk), i32(4, 8, 64),
+                     i32(8), i32(8))
+
+
+def _afmoe_pool_pages() -> int:
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic",
+            "grpo16_closed80_out15k_w2k.json")) as f:
+        pool_bytes = json.load(f)["engine"]["kv_pool_bytes"]
+    return pool_bytes // (2 * 128 * 2048)
+
+
+@pytest.mark.parametrize("more_pages", [0, 572])
+def test_trinity_cell_decode_chunk_computes_its_head_once(
+        compiled_kernels, one_chip, more_pages):
+    """The cell's decode chunk (2 dense + 6 expert layers at the published
+    widths, 128 experts, 64 slots, 16 steps, a table of 128 pages in each
+    of the four kinds) at the traffic file's pool and 0.3e9 B (572 pages)
+    past it: the dense layers and the two expert layers that complete the
+    first period run one by one and the second period in the scan, each
+    layer through the program of its kind (``paged_decode_window`` and
+    ``paged_decode``), ``kv_page_write`` writes the pool in place, the step
+    ends in ``fused_sample`` over the 200,192-row untied head with no
+    ``[B, 200192]`` array in the program, a step's rows keep the einsums
+    (no ``moe_grouped``). At the cell's pool NOTHING is rematerialised; 0.3e9
+    past it the compiler is short enough to recompute 16 MB slices of the
+    attention projections (from 3.6e9 on: PERF.md section 7), but never
+    anything of the vocabulary's size: the head is computed once at both
+    (PR 45's lesson)."""
+    n_pages = _afmoe_pool_pages() + more_pages
+    cfg, fn, args = _afmoe_program(one_chip, n_pages)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert f"bf16[2,{n_pages},2,4,128,128]" in text
+    for kernel in ("paged_decode_window", "paged_decode", "kv_page_write"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    assert not re.search(r"%moe_grouped(\.\d+)? = ", text)
+    _assert_fused_epilogue(text, AFMOE_CELL["B"], cfg.vocab_size)
+    again = [ln.strip()[:160] for ln in text.split("\n")
+             if re.search(r"%[\w.\-]*remat[\w.\-]* = ", ln)]
+    if not more_pages:
+        assert not again, again
+    assert not [ln for ln in again if str(cfg.vocab_size) in ln], again
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+def test_trinity_admission_wave_holds_the_grouped_kernel(
+        compiled_kernels, one_chip):
+    """A wave of 8 x 128 tokens against the pool at the cell's sizes: its
+    1,024 rows are over the kernel's crossing, so the routed experts of
+    ALL six expert layers (the two that complete the first period among
+    them) run in ``moe_grouped``, handed the stack whole with the layer's
+    index in it: nothing results in one layer's ``[128, 2048, 1024]``."""
+    cfg, fn, args = _afmoe_program(one_chip, _afmoe_pool_pages(), "jit_extend")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _custom_call_names(text) == {"moe_grouped"}
+    _assert_no_slice_of_the_routed_stack(text, cfg)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
