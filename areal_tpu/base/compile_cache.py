@@ -14,6 +14,9 @@ the cache key, and a cache that moves never hits.
 A run held to the CPU (``JAX_PLATFORMS=cpu``: the tests, CPU-designated
 workers) caches nothing unless the variable asks for it: its programs are
 tiny, and XLA:CPU ties a cached executable to the host's CPU features.
+The test harness asks (``tests/conftest.py``): one fresh directory a test
+run, inherited by the workers and every subprocess world, removed when the
+session ends, so a program that many cases build is compiled once a run.
 
 Importing this module does not import JAX, and :func:`configure` never
 initialises a backend — the launcher's JAX-free parent calls it too.

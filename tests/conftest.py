@@ -3,10 +3,19 @@
 Mirrors the reference's CPU-only test strategy (``realhf/base/testing.py``):
 the whole stack must be testable without TPU hardware. An 8-device host
 platform replaces the reference's 8-process gloo trick (SURVEY.md §4).
+
+The harness asks for a compile cache (``areal_tpu/base/compile_cache.py``:
+a CPU run caches nothing unless ``JAX_COMPILATION_CACHE_DIR`` asks): one
+fresh directory a test run, made here, inherited by the xdist workers and
+by every subprocess world a test launches, removed when the session ends,
+so a program that many cases build is compiled once a run. A test whose
+SUBJECT is compiling or caching takes ``no_persistent_cache``.
 """
 
 import os
+import shutil
 import sys
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"  # tests always run on the CPU
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -15,6 +24,18 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("AREAL_FILEROOT", "/tmp/areal_tpu_test")
+# The run's compile cache. The process that is no xdist worker makes the
+# directory (fresh a run: what a case costs must not depend on what an
+# earlier run left) unless the caller named one, and its children inherit
+# the variable. At test sizes a CPU program compiles in well under JAX's
+# one-second threshold for storing an entry, hence the two floors.
+_RUN_CACHE = None
+if ("PYTEST_XDIST_WORKER" not in os.environ
+        and "JAX_COMPILATION_CACHE_DIR" not in os.environ):
+    _RUN_CACHE = tempfile.mkdtemp(prefix="areal_tpu_xla_cache_")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _RUN_CACHE
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES", "-1")
 # Data-plane pipelining (docs/pipelined_data_plane.md) defaults OFF under
 # the CPU harness: with JAX_PLATFORMS=cpu the "device" IS the host, so
 # dispatch-ahead depth and the background packer thread only oversubscribe
@@ -42,10 +63,39 @@ jax.config.update("jax_platforms", "cpu")
 import asyncio
 import contextlib
 import inspect
-import tempfile
 
 import numpy as np
 import pytest
+
+
+def pytest_unconfigure(config):
+    if _RUN_CACHE is not None:
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
+
+
+@contextlib.contextmanager
+def persistent_cache_off():
+    """This process compiles every program itself while the block runs:
+    for a test that counts compiles, reads ``cache_hit``, or compiles for
+    a described device whose executables must not land in the run's
+    directory. (A subprocess the test launches is not covered: hand it an
+    ``env`` without ``JAX_COMPILATION_CACHE_DIR``, or one of its own.)"""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+
+
+@pytest.fixture
+def no_persistent_cache():
+    with persistent_cache_off():
+        yield
 
 
 @contextlib.contextmanager

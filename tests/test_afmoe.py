@@ -567,31 +567,42 @@ HP = PPOHyperparameters(
 
 @pytest.fixture(scope="module")
 def reference_gradient(params, ppo_case):
-    """``jax.grad`` of the PPO actor loss built on the REFERENCE's
-    log-probs (advantages as ``train_step`` computes them from the
-    rewards: taken from a first step's sample)."""
-    seqs, prompt_lens, sample = ppo_case
-    sample = SequenceSample.from_default(
-        ids=list(sample.ids), seqlens=[len(s) for s in seqs],
-        data=dict(sample.data))
-    eng = _train_engine(params)
-    eng.setup_optimizer(10)
-    PPOActorInterface(hp=HP).train_step(eng, sample, MicroBatchSpec())
-    adv = np.asarray(sample.data["advantages"], np.float32)
-    old = np.asarray(sample.data["packed_logprobs"], np.float32)
-    mask = np.concatenate([
-        np.r_[np.arange(1, n) >= pl, False]
-        for n, pl in zip(map(len, seqs), prompt_lens)])
+    """``of(sample)``: ``jax.grad`` of the PPO actor loss built on the
+    REFERENCE's log-probs, with the advantages ``train_step`` left in
+    ``sample`` (it computes them from the rewards, whatever the policy);
+    computed for the first policy that asks and kept for the others."""
+    seqs, prompt_lens, _ = ppo_case
+    kept = []
 
-    def reference_loss(p):
-        lp = jnp.concatenate([
-            jnp.concatenate([ref.sequence_logprobs(p, ARCH, s), jnp.zeros(1)])
-            for s in seqs])
-        return ppo_ops.actor_loss_fn(
-            lp, jnp.asarray(old), jnp.asarray(adv), HP.eps_clip,
-            jnp.asarray(mask))[0]
+    def of(sample):
+        if kept:
+            return kept[0]
+        adv = np.asarray(sample.data["advantages"], np.float32)
+        old = np.asarray(sample.data["packed_logprobs"], np.float32)
+        mask = np.concatenate([
+            np.r_[np.arange(1, n) >= pl, False]
+            for n, pl in zip(map(len, seqs), prompt_lens)])
 
-    return jax.grad(reference_loss)(params)
+        def reference_loss(p):
+            lp = jnp.concatenate([
+                jnp.concatenate(
+                    [ref.sequence_logprobs(p, ARCH, s), jnp.zeros(1)])
+                for s in seqs])
+            return ppo_ops.actor_loss_fn(
+                lp, jnp.asarray(old), jnp.asarray(adv), HP.eps_clip,
+                jnp.asarray(mask))[0]
+
+        # ONE program: eagerly its backward is ~140 one-op programs
+        kept.append(jax.jit(jax.grad(reference_loss))(params))
+        return kept[0]
+
+    return of
+
+
+def _policy_cfg(policy):
+    return dataclasses.replace(
+        CFG, remat_policy=policy,
+        moe=dataclasses.replace(CFG.moe, aux_loss_coeff=0.0))
 
 
 @pytest.mark.parametrize("policy", ["full", "dots", "dots_attn"])
@@ -610,10 +621,7 @@ def test_trainer_gradients_match_reference(
     import optax
 
     seqs, _, sample = ppo_case
-    cfg = dataclasses.replace(
-        CFG, remat_policy=policy,
-        moe=dataclasses.replace(CFG.moe, aux_loss_coeff=0.0))
-    eng = _train_engine(params, cfg)
+    eng = _train_engine(params, _policy_cfg(policy))
     eng.setup_optimizer(10)
     eng.tx = optax.sgd(1.0)
     eng.opt_state = eng.tx.init(eng.params)
@@ -623,7 +631,7 @@ def test_trainer_gradients_match_reference(
         data=dict(sample.data))
     PPOActorInterface(hp=HP).train_step(eng, sample, MicroBatchSpec())
     g_prog = jax.tree.map(lambda a, b: a - np.asarray(b), before, eng.params)
-    g_ref = reference_gradient
+    g_ref = reference_gradient(sample)
     for (path, a), b in zip(
             jax.tree_util.tree_leaves_with_path(g_prog), jax.tree.leaves(g_ref)):
         b = np.asarray(b)

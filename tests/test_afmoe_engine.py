@@ -215,11 +215,11 @@ def test_a_group_s_shared_pages_go_through_the_prefix_program(params, rng):
     # the first member fills the registry, its siblings hit it
     eng.submit(GenRequest(rid="g0", input_ids=prompt, max_new_tokens=6,
                           temperature=1.0))
-    outs = {o.rid: o for o in eng.run_until_done(2)}
+    outs = {o.rid: o for o in eng.run_until_done(4)}
     for rid, ids in list(prompts.items())[1:]:
         eng.submit(GenRequest(rid=rid, input_ids=ids, max_new_tokens=6,
                               temperature=1.0))
-    outs.update((o.rid, o) for o in eng.run_until_done(2))
+    outs.update((o.rid, o) for o in eng.run_until_done(4))
     _check_outputs(params, prompts, outs, 6)
     chunks = _chunk_attrs()
     assert any(c["kv_shared_rows"] for c in chunks)

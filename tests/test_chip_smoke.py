@@ -142,10 +142,13 @@ def test_child_env_is_restored_after_spawn(monkeypatch):
     assert os.environ["JAX_PLATFORMS"] == "cpu"
 
 
-def test_compile_cache_has_one_place(monkeypatch, tmp_path):
+def test_compile_cache_has_one_place(
+        monkeypatch, tmp_path, no_persistent_cache):
     """Set from outside, the variable stands and nothing else is touched;
     unset on an accelerator run, the one fixed path in the checkout is
-    exported so children and a later ``import jax`` agree on it."""
+    exported so children and a later ``import jax`` agree on it. (Outside
+    the test run's own cache, whose variable it sets and unsets; the
+    monkeypatch puts the run's directory back.)"""
     from areal_tpu.base import compile_cache, constants
 
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))

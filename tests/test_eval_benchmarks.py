@@ -7,12 +7,14 @@ pool with per-item deadlines (``/root/reference/evaluation/
 eval_and_aggregate.py``, ``evaluate.py:44-60``)."""
 
 import json
+import logging
 import os
 import time
 
 import pytest
 
 from areal_tpu.evaluation import benchmarks as bm
+from areal_tpu.evaluation import grading
 from areal_tpu.evaluation.grading import PoolGrader
 from areal_tpu.evaluation.mcq import extract_choice, grade_choice
 
@@ -92,21 +94,29 @@ def test_pool_grader_math_and_gpqa():
 
 
 def _hang_grader(task, answer, gold):
-    if answer == "hang":
+    if answer.startswith("hang"):
         time.sleep(60)
+        open(answer.split(":", 1)[1], "w").close()    # the wedge let go
     return 1.0
 
 
-def test_pool_grader_kills_wedged_worker():
+def test_pool_grader_kills_wedged_worker(tmp_path, caplog):
     pool = PoolGrader(n_workers=2, timeout_s=1.0, grade_one=_hang_grader)
+    let_go = tmp_path / "let_go"
     try:
-        t0 = time.monotonic()
-        scores = pool.grade([
-            ("math", "ok", ["1"]),
-            ("math", "hang", ["1"]),
-            ("math", "ok", ["1"]),
-        ])
-        assert time.monotonic() - t0 < 20
+        with caplog.at_level(logging.WARNING, logger=grading.logger.name):
+            scores = pool.grade([
+                ("math", "ok", ["1"]),
+                ("math", f"hang:{let_go}", ["1"]),
+                ("math", "ok", ["1"]),
+            ])
+        # the scores came back BEFORE the wedged grader let go, by the
+        # item's deadline and not by the worker's end: an order of events
+        # (no bound on this test's own seconds, which a loaded machine
+        # stretches), and the worker was killed, so it never does
+        assert not let_go.exists()
+        assert [r.getMessage() for r in caplog.records] == [
+            "grading item 1 timed out after 1.0s"]
         # timeout scores as a WRONG math answer (-1.0), matching the
         # in-process convention so reward_mean stays comparable
         assert scores == [1.0, -1.0, 1.0]
@@ -279,19 +289,22 @@ def _crash_grader(task, answer, gold):
     return 1.0
 
 
-def test_pool_grader_detects_dead_worker_fast():
+def test_pool_grader_detects_dead_worker_fast(caplog):
     """Review finding r5: a CRASHED worker (not a wedge) must be detected
     by liveness, not by waiting out the deadline + spawn allowance."""
     pool = PoolGrader(n_workers=1, timeout_s=30.0, grade_one=_crash_grader)
     try:
-        t0 = time.monotonic()
-        scores = pool.grade([
-            ("math", "ok", ["1"]),
-            ("math", "die", ["1"]),
-            ("math", "ok", ["1"]),
-        ])
-        # far below timeout_s (30) + SPAWN_ALLOWANCE (120)
-        assert time.monotonic() - t0 < 25
+        with caplog.at_level(logging.WARNING, logger=grading.logger.name):
+            scores = pool.grade([
+                ("math", "ok", ["1"]),
+                ("math", "die", ["1"]),
+                ("math", "ok", ["1"]),
+            ])
+        # the branch that scored the item is the liveness one, which does
+        # not wait for timeout_s (30) + SPAWN_ALLOWANCE (120): an event,
+        # not a bound on this test's own seconds
+        assert [r.getMessage() for r in caplog.records] == [
+            "grading item 1 worker died"]
         assert scores == [1.0, -1.0, 1.0]
         assert pool.timeout_cnt == 1
     finally:

@@ -290,9 +290,10 @@ def test_gradients_match_reference(params, policy):
     against the gradient of the token-by-token reference."""
     cfg = dataclasses.replace(CFG, remat_policy=policy)
     ids = _toks(8, 33)
-    got = jax.grad(lambda p: _program_loss(cfg, p, ids))(params)
-    want = jax.grad(
-        lambda p: -jnp.mean(ref.sequence_logprobs(p, ARCH, ids)))(params)
+    # each side ONE program (eagerly ~250 one-op programs a side)
+    got = jax.jit(jax.grad(lambda p: _program_loss(cfg, p, ids)))(params)
+    want = jax.jit(jax.grad(
+        lambda p: -jnp.mean(ref.sequence_logprobs(p, ARCH, ids))))(params)
     for (path, g), w in zip(
             jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
         scale = float(jnp.abs(w).max()) + 1e-6

@@ -403,7 +403,7 @@ def test_trainer_gradients_match_reference(params, ppo_case):
             lp, jnp.asarray(old), jnp.asarray(adv), hp.eps_clip,
             jnp.asarray(mask))[0]
 
-    g_ref = jax.grad(reference_loss)(params)
+    g_ref = jax.jit(jax.grad(reference_loss))(params)    # ONE program
     for (path, a), b in zip(
             jax.tree_util.tree_leaves_with_path(g_prog), jax.tree.leaves(g_ref)):
         b = np.asarray(b)

@@ -340,10 +340,11 @@ def test_loss_and_gradients_match_reference(params, rng, policy):
     plain reference's loss."""
     ids = _toks(rng, 24)
     cfg = dataclasses.replace(CFG, remat_policy=policy)
-    loss, grads = jax.value_and_grad(
-        lambda p: _program_loss(cfg, p, ids))(params)
-    want, g_ref = jax.value_and_grad(
-        lambda p: ref.loss(p, ARCH, ids))(params)
+    # each side ONE program, not an op at a time
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: _program_loss(cfg, p, ids)))(params)
+    want, g_ref = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, ARCH, ids)))(params)
     np.testing.assert_allclose(float(loss), float(want), atol=1e-5)
     _assert_grads_close(grads, g_ref)
 
@@ -353,11 +354,11 @@ def test_shared_weight_gradient_is_the_sum_of_its_uses(params, rng):
     reference: its T per-pass gradients sum to the program's gradient of
     the shared stack, and no single pass's does."""
     ids = _toks(rng, 24)
-    grads = jax.grad(lambda p: _program_loss(CFG, p, ids))(params)
+    grads = jax.jit(jax.grad(lambda p: _program_loss(CFG, p, ids)))(params)
     untied = dict(params, layers=jax.tree.map(
         lambda a: jnp.broadcast_to(a, (T, *a.shape)), params["layers"]))
-    g_untied = jax.grad(
-        lambda p: ref.loss(p, ARCH, ids, untied=True))(untied)
+    g_untied = jax.jit(jax.grad(
+        lambda p: ref.loss(p, ARCH, ids, untied=True)))(untied)
     summed = dict(g_untied, layers=jax.tree.map(
         lambda a: a.sum(axis=0), g_untied["layers"]))
     _assert_grads_close(grads, summed)
@@ -447,23 +448,30 @@ def test_paged_extend_and_decode_match_reference(
     table = jnp.asarray(np.arange(1, 21, dtype=np.int32).reshape(2, 10))
     n0 = [20, 11]
     toks = np.zeros((2, 12), np.int32)
+    # one program a chunk and ONE for the nine steps, as the engine builds
+    # them: eagerly every op of every (pass, layer) is a program of its own
+    extend = jax.jit(
+        lambda skip_pool, *a: tfm.extend_paged(
+            params, CFG, *a, skip_pool=skip_pool, use_pallas=use_pallas),
+        static_argnums=0)
+    decode = jax.jit(lambda *a: tfm.decode_step_paged(
+        params, CFG, *a, use_pallas=use_pallas))
     with jax.default_matmul_precision("highest"):
         for c in range(2):
             start = np.asarray([12 * c, 12 * c])
             n_new = np.clip(np.asarray(n0) - start, 0, 12)
             for b in range(2):
                 toks[b, : n_new[b]] = seq[start[b]: start[b] + n_new[b]]
-            cache = tfm.extend_paged(
-                params, CFG, cache, jnp.asarray(toks), table,
-                jnp.asarray(start), jnp.asarray(n_new),
-                skip_pool=c == 0, use_pallas=use_pallas)
+            cache = extend(
+                c == 0, cache, jnp.asarray(toks), table,
+                jnp.asarray(start), jnp.asarray(n_new))
         lens = jnp.asarray(n0)
         got = [[], []]
         for step in range(9):
             cur = [seq[n0[0] + step], seq[n0[1] + step]]
-            logits, cache, lens = tfm.decode_step_paged(
-                params, CFG, cache, jnp.asarray(cur), table, lens,
-                jnp.asarray([True, True]), use_pallas=use_pallas)
+            logits, cache, lens = decode(
+                cache, jnp.asarray(cur), table, lens,
+                jnp.asarray([True, True]))
             for b in range(2):
                 got[b].append(float(jax.nn.log_softmax(logits[b])[
                     seq[n0[b] + step + 1]]))
@@ -706,7 +714,7 @@ def test_benchmark_check_and_its_two_controls(params, rng, case):
             100 * max(got["seq_mean_abs_diff_nats"] + [1e-6]))
 
 
-def test_rehearsal_of_the_cell_and_selfcheck():
+def test_rehearsal_of_the_cell_and_selfcheck(capsys):
     """``--rehearse`` of the new cell end to end on the CPU (exits 3, its
     last line counts-only, the check and both controls inside), and the
     yardstick's own checks with the new entries."""
@@ -739,10 +747,18 @@ def test_rehearsal_of_the_cell_and_selfcheck():
     assert check["control_fewer_passes"]["correct"] is False
     assert info["cache_layers"] == 4 * 2        # the file's passes, 2 layers
     assert info["layer_passes"] == info["loop_passes"] * 2 > 0
-    s = subprocess.run(
-        [sys.executable, "-m", "benchmark.selfcheck", "--no-cells"],
-        cwd=ROOT, capture_output=True, text=True, timeout=600, env=env)
-    assert s.returncode == 0, s.stdout[-3000:] + s.stderr[-3000:]
+    # the part of the yardstick's own checks that this cell's entries can
+    # move (check 1: BENCHMARK.json against the files it names), in
+    # process; the whole of ``python -m benchmark.selfcheck --no-cells``,
+    # 20 s of it the traffic generator's, is ``test_start_metrics.py``'s
+    from benchmark import selfcheck
+    from benchmark.run import load_json
+
+    capsys.readouterr()
+    failed_before = list(selfcheck.FAILED)
+    selfcheck.check_files(load_json(ROOT, "BENCHMARK.json"))
+    said = capsys.readouterr().out
+    assert selfcheck.FAILED == failed_before, said
     for name in ("kernel.looped_decode_roofline", "loop.weight_stream_roofline",
                  "loop.weight_stream_share"):
-        assert f"ok   reader {name}: unit, layer, moves, source agree" in s.stdout
+        assert f"ok   reader {name}: unit, layer, moves, source agree" in said

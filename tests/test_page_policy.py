@@ -8,6 +8,8 @@ makes admission look no further than the newcomer's first chunk, which is
 what drives the dry rule hard in a small test; the default horizon is
 there to keep it rare."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -104,14 +106,18 @@ def _closed_loop(kind, params, n_pages, horizon=None, clients=4, steps=4):
 
 @pytest.fixture(scope="module")
 def roomy_runs(weights):
-    """Every stack through a pool that seats whole outputs."""
-    return {kind: _closed_loop(kind, weights[kind], None) for kind in STACKS}
+    """``roomy_runs(kind)``: the stack's run through a pool that seats
+    whole outputs, made when a case first asks for its kind (a case pays
+    for the run it compares with, not for the four stacks')."""
+    return functools.cache(
+        lambda kind: _closed_loop(kind, weights[kind], None))
 
 
 @pytest.fixture(scope="module")
 def roomy(roomy_runs):
-    """... its outputs by rid: the reference for every other run."""
-    return {kind: run[0] for kind, run in roomy_runs.items()}
+    """... ``roomy(kind)``: its outputs by rid, the reference for every
+    other run."""
+    return lambda kind: roomy_runs(kind)[0]
 
 
 def _assert_same(outs, want):
@@ -134,7 +140,7 @@ def test_dry_pool_holds_and_preempts_and_nobody_sees_it(kind, weights, roomy):
     its ``max_new_tokens``, the roomy engine's tokens and log-probs."""
     outs, eng, held, preempted = _closed_loop(
         kind, weights[kind], STACKS[kind][2], horizon=0)
-    _assert_same(outs, roomy[kind])
+    _assert_same(outs, roomy(kind))
     st = eng.stats
     assert st["preemptions"] >= 1 and st["slots_held"] >= 1
     assert held and preempted
@@ -148,7 +154,7 @@ def test_default_horizon_keeps_the_dry_rule_out(kind, weights, roomy):
     nobody preempted, the outputs are the roomy engine's."""
     outs, eng, held, preempted = _closed_loop(
         kind, weights[kind], STACKS[kind][2])
-    _assert_same(outs, roomy[kind])
+    _assert_same(outs, roomy(kind))
     assert not held and not preempted
     assert (eng.stats["slots_held"], eng.stats["preemptions"],
             eng.stats["preempted_tokens_recomputed"]) == (0, 0, 0)
@@ -157,7 +163,7 @@ def test_default_horizon_keeps_the_dry_rule_out(kind, weights, roomy):
 
 @pytest.mark.parametrize("kind", ["full", "state", "looped"])
 def test_roomy_pool_counts_nothing_but_growth(kind, roomy_runs):
-    stats = roomy_runs[kind][1].stats
+    stats = roomy_runs(kind)[1].stats
     assert [stats[k] for k in COUNTERS[1:]] == [0, 0, 0]
     # every page past a prompt's own was taken by a running slot
     page = STACKS[kind][1]
@@ -406,8 +412,8 @@ def test_pipelined_chunks_settle_before_the_dry_rule_acts(
         assert eng.pool.n_unpromised >= 0
     assert eng.stats["preemptions"] + eng.stats["slots_held"] >= 1
     for rid, _, g in reqs:
-        assert outs[rid].output_ids == roomy["full"][rid].output_ids
+        assert outs[rid].output_ids == roomy("full")[rid].output_ids
         np.testing.assert_allclose(
-            outs[rid].output_logprobs, roomy["full"][rid].output_logprobs,
+            outs[rid].output_logprobs, roomy("full")[rid].output_logprobs,
             atol=TOL)
     _drained(eng)

@@ -219,9 +219,10 @@ def test_packed_forward_under_remat_has_the_reference_s_gradient(
         return -lp[jnp.arange(20), ids[1:]].mean()
 
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(loss)(params)
-        want = jax.grad(lambda p: -ref.sequence_logprobs(
-            p, ARCH, ids).mean())(params)
+        # each side ONE program (eagerly ~130 one-op programs a side)
+        got = jax.jit(jax.grad(loss))(params)
+        want = jax.jit(jax.grad(lambda p: -ref.sequence_logprobs(
+            p, ARCH, ids).mean()))(params)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-4)
 

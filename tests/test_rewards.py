@@ -127,13 +127,28 @@ def test_answers_equal_latex2sympy_grammar(a, b, eq):
     assert math_verify.answers_equal(a, b) == eq, (a, b)
 
 
-def test_degenerate_power_is_fast():
-    """Model-controlled giant exponents must not stall the reward worker."""
-    import time
+def test_degenerate_power_is_fast(monkeypatch):
+    """Model-controlled giant exponents must not stall the reward worker:
+    the expression is refused BEFORE sympy is handed it (what makes it
+    fast, as an event; this test's own seconds say nothing on a machine
+    that six workers share)."""
+    import sympy
+    from sympy.parsing import sympy_parser
 
-    t0 = time.time()
+    handed = []
+    for mod, name in ((sympy, "sympify"), (sympy_parser, "parse_expr")):
+        real = getattr(mod, name)
+
+        def recording(expr, *args, _real=real, **kw):
+            handed.append(str(expr))
+            return _real(expr, *args, **kw)
+
+        monkeypatch.setattr(mod, name, recording)
     assert not math_verify.answers_equal(r"2^{999999999}", "5")
-    assert time.time() - t0 < 2.0
+    assert not any("999999999" in expr for expr in handed)
+    # ... and the recorder sees what IS handed over
+    assert math_verify.answers_equal(r"2^{3}", "8")
+    assert any("2**" in expr for expr in handed)
 
 
 # --------------------------------------------------------------------------- #

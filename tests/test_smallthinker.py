@@ -742,11 +742,11 @@ def test_a_group_s_shared_pages_with_layer_kinds(rng, layout, shares):
     # the first member fills the registry, its siblings hit it
     eng.submit(GenRequest(rid="g0", input_ids=prompt, max_new_tokens=12,
                           temperature=1.0))
-    outs = {o.rid: o for o in eng.run_until_done(1)}
+    outs = {o.rid: o for o in eng.run_until_done(4)}
     for rid, ids in list(prompts.items())[1:]:
         eng.submit(GenRequest(rid=rid, input_ids=ids, max_new_tokens=12,
                               temperature=1.0))
-    outs.update((o.rid, o) for o in eng.run_until_done(3))
+    outs.update((o.rid, o) for o in eng.run_until_done(4))
     for rid, ids in prompts.items():
         want = _ref_logprobs(p, ids + outs[rid].output_ids, arch)[len(ids) - 1:]
         np.testing.assert_allclose(
@@ -946,7 +946,7 @@ def test_trainer_gradients_match_reference(params, ppo_case):
             lp, jnp.asarray(old), jnp.asarray(adv), hp.eps_clip,
             jnp.asarray(mask))[0]
 
-    g_ref = jax.grad(reference_loss)(params)
+    g_ref = jax.jit(jax.grad(reference_loss))(params)
     for (path, a), b in zip(
             jax.tree_util.tree_leaves_with_path(g_prog), jax.tree.leaves(g_ref)):
         b = np.asarray(b)

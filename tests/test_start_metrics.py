@@ -55,10 +55,11 @@ def test_reader_reads_the_starts_totals(counters, name, want):
 
 @pytest.mark.parametrize("name", NAMES)
 def test_reader_leaves_out_what_was_built_after_the_window_opened(
-        counters, name):
+        counters, name, no_persistent_cache):
     """The counters run on past the start (the check against the reference
     builds programs after the window); the ring's records since ``t_open``
-    say by how much. A run that caches nothing has no hit share."""
+    say by how much. A run that caches nothing has no hit share (outside
+    the test run's own compile cache: ``no_persistent_cache``)."""
     tracing.listen_for_compiles()
     _build_a_program(2.0)
     at_open = counters.snapshot()

@@ -65,14 +65,12 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def no_persistent_cache():
-    from jax.experimental.compilation_cache import compilation_cache as cc
+    """The harness's fixture at module scope: an executable for the
+    described v5e is never written to, or read from, the run's cache."""
+    from conftest import persistent_cache_off
 
-    prev = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", prev)
-    cc.reset_cache()
+    with persistent_cache_off():
+        yield
 
 
 @pytest.fixture

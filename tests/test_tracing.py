@@ -245,6 +245,12 @@ class TestCompileListener:
     ``compile/*`` counters and one ``compile/program`` record under the
     span that paid for it (docs/observability.md "What a start cost")."""
 
+    @pytest.fixture(autouse=True)
+    def _builds_are_builds(self, no_persistent_cache):
+        """These cases' subject is what a BUILD reports (three stages with
+        seconds each, ``cache_hit`` None where no cache was asked): outside
+        the run's compile cache."""
+
     @staticmethod
     def _fresh(scale):
         import jax

@@ -47,11 +47,13 @@ class TestStatusWatch:
         assert not w.alive()      # launcher never appeared / died silently
 
     def test_heartbeat_publishes(self):
+        t_start = time.time()
         hb = Heartbeat(EXP, TRIAL, "unit_worker", interval=0.05).start()
         time.sleep(0.15)
         hb.stop()
         t = last_heartbeat(EXP, TRIAL, "unit_worker")
-        assert t is not None and abs(time.time() - t) < 5
+        # this heartbeat's stamp, by order: no older one, none from ahead
+        assert t is not None and t_start <= t <= time.time()
 
 
 class TestHangWatchdog:

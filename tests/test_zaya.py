@@ -347,9 +347,10 @@ def test_loss_and_gradients_match_reference(params, rng, policy):
         lp = jax.nn.log_softmax(logits, axis=-1)
         return -jnp.mean(lp[jnp.arange(23), ids[1:]])
 
-    got_l, got_g = jax.value_and_grad(loss)(params)
-    want_l, want_g = jax.value_and_grad(
-        lambda p: ref.loss(p, ARCH, seq))(params)
+    # each side ONE program (eagerly ~180 one-op programs a side)
+    got_l, got_g = jax.jit(jax.value_and_grad(loss))(params)
+    want_l, want_g = jax.jit(jax.value_and_grad(
+        lambda p: ref.loss(p, ARCH, seq)))(params)
     assert abs(float(got_l) - float(want_l)) < 1e-5
     flat_w = jax.tree_util.tree_leaves_with_path(want_g)
     for (path, w), g in zip(flat_w, jax.tree.leaves(got_g)):
