@@ -391,18 +391,22 @@ class ModelConfig:
     use_flash_attention: Optional[bool] = None
 
     # STATIC upper bound on any packed segment's length (e.g. max prompt +
-    # max new tokens). When set, the flash kernels iterate a statically
-    # narrowed block band instead of the full causal rectangle — a multi-x
-    # attention win when packing many short sequences. The train engine
-    # rejects batches that violate the bound.
+    # max new tokens). When set, the flash kernels' grid is as long as the
+    # band it leaves and not as the causal triangle — fewer empty steps
+    # when packing many short sequences. The train engine rejects batches
+    # that violate the bound.
     attn_max_seqlen: Optional[int] = None
 
-    # Flash-attention block size override (None = auto: 1024 at T >= 8192,
-    # else 512). Bigger score tiles amortize the kernels' VPU mask/softmax
-    # passes at very long context; may need more VMEM.
+    # Flash-attention block size override (None = the kernels' rule,
+    # ``ops/pallas/flash_attention.flash_blocks``, which reads the call's
+    # shapes: 1024 x 1024 at T >= 8192; under it 256 x 1024 forward and
+    # 256 x 256 backward, 512 x 512 under a window or ``attn_max_seqlen``).
+    # Bigger score tiles amortize the kernels' per-step work at very long
+    # context; may need more VMEM.
     flash_block_size: Optional[int] = None
-    # Separate K-block size (None = same as flash_block_size). Rectangular
-    # tiles trade VPU-pass shape against MXU dot shapes at long context.
+    # Separate K-block size (None = the rule's, or ``flash_block_size``
+    # where that is set). Rectangular tiles trade the steps' fixed work
+    # against the band's cover.
     flash_block_size_k: Optional[int] = None
 
     # Cross-entropy in token blocks of this size (None = dense): the LM

@@ -91,11 +91,14 @@ def packed_attention(
     Args:
       q: ``[T, H, D]``; k, v: ``[T, Hkv, D]`` (``H % Hkv == 0``).
       segment_ids: ``[T]`` int32, 0 marks padding tokens.
-      flash_block_size: None = auto — 1024 at long context (T >= 8192), where
-        bigger score tiles roughly double measured kernel throughput; 512
-        otherwise (short packed segments straddle fewer block boundaries).
-      max_seqlen: STATIC upper bound on any segment length; narrows the
-        flash kernels' block band (see ``packed_flash_attention``).
+      flash_block_size, flash_block_size_k: None = the kernels' own rule
+        (``ops/pallas/flash_attention.flash_blocks``), which reads the
+        call's shapes: 1024 x 1024 and the interior body at T >= 8192;
+        under it 256 x 1024 for the forward and 256 x 256 for the fused
+        backward (512 x 512 with a window or a ``max_seqlen``). A value
+        overrides both directions' block.
+      max_seqlen: STATIC upper bound on any segment length; shortens the
+        flash kernels' pair list (see ``packed_flash_attention``).
     Returns ``[T, H, D]``.
     """
     if softmax_scale is None:
@@ -113,24 +116,13 @@ def packed_attention(
     if use_flash:
         from areal_tpu.ops.pallas import flash_attention as _fa
 
-        T = q.shape[0]
-        bs = flash_block_size or (
-            1024 if T >= 8192 and T % 1024 == 0 else 512
-        )
-        while T % bs:
-            # an override that does not divide T would silently truncate
-            # the kernel grid; fall back to the largest dividing block
-            bs //= 2
-        bsk = flash_block_size_k or bs
-        while T % bsk:
-            bsk //= 2
         flash = functools.partial(
             _fa.packed_flash_attention,
             softmax_scale=softmax_scale,
             soft_cap=soft_cap,
             sliding_window=sliding_window,
-            block_size=bs,
-            block_size_k=bsk,
+            block_size=flash_block_size,
+            block_size_k=flash_block_size_k,
             max_seqlen=max_seqlen,
         )
         if _FLASH_MESH is not None:

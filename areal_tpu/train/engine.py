@@ -783,6 +783,34 @@ class TrainEngine:
             packed.append(batching.empty_like(packed[0]))
         return mbs, packed, weights
 
+    def _flash_pair_counts(
+        self, packed: List[batching.PackedBatch]
+    ) -> Dict[str, float]:
+        """How tightly the flash kernels' pair lists cover this step's
+        packed rows (``flash_pairs``, ``flash_interior_pairs``,
+        ``flash_fill`` on the ``train_pipe/pack`` record): the host knows
+        the rows' segment layout here, and the blocks come from the rule
+        the kernels' wrapper asks (`flash_attention.flash_blocks`), for the
+        model's heads a kv head and its first layer kind's window.
+        Nothing where the span plane is off or the model runs no flash
+        kernel."""
+        cfg = self.cfg
+        if not (tracing.spans_enabled() and cfg.flash_enabled()):
+            return {}
+        from areal_tpu.ops.pallas import flash_attention
+
+        window = cfg.layer_kinds[0][0]
+        block_q, block_k, specialize = flash_attention.flash_blocks(
+            packed[0].capacity, cfg.n_q_heads // cfg.n_kv_heads,
+            sliding_window=window,
+            max_seqlen=cfg.attn_max_seqlen, block_q=cfg.flash_block_size,
+            block_k=cfg.flash_block_size_k,
+        )
+        return flash_attention.pair_counts(
+            np.stack([pb.arrays["segment_ids"] for pb in packed]),
+            block_q, block_k, specialize, window, cfg.attn_max_seqlen,
+        )
+
     # ------------------------------------------------------------------ #
     # PipelinableEngine API (≈ model_api.py:514)
     # ------------------------------------------------------------------ #
@@ -806,10 +834,11 @@ class TrainEngine:
         # already GLOBAL over all hosts' rows — so weight by the global
         # action-token count of each micro-batch (gathered in the same
         # round as the capacity agreement).
-        with tracing.span("train_pipe/pack"):
+        with tracing.span("train_pipe/pack") as attrs:
             _, packed, weights = self._make_micro_batches(
                 sample, mb_spec, weight_fn=loss_weight_fn
             )
+            attrs.update(self._flash_pair_counts(packed))
         weights = np.asarray(weights, np.float32)
         total_w = weights.sum() or 1.0
         weights = weights / total_w

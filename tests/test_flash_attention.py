@@ -144,13 +144,13 @@ def test_flash_bwd_matches_xla_multiblock(rng, kwargs):
 
 @slow
 def test_flash_specialized_path_matches_xla(rng, monkeypatch):
-    """Force the interior/boundary dual-body kernels (normally gated on
-    T >= SPECIALIZE_MIN_T) at a test-sized T: fwd and bwd must match XLA,
+    """Force the interior/boundary dual-body kernels (which the block rule
+    turns on from the call's shapes) at a test-sized T: fwd and bwd must match XLA,
     including blocks that are fully interior (one long segment spanning
     many blocks) and boundary blocks (segment edges, padding)."""
     from areal_tpu.ops.pallas import flash_attention as fa
 
-    monkeypatch.setattr(fa, "SPECIALIZE_MIN_T", 0)
+    monkeypatch.setattr(fa, "flash_blocks", lambda *a, **kw: (64, 64, True))
     T, H, Hkv, D = 512, 4, 2, 16
     # one long segment (interior blocks at block_size=64) + short ones + pad
     q, k, v, seg = _mk(rng, T, H, Hkv, D, [320, 64, 100])
@@ -334,6 +334,191 @@ def test_flash_gradients_match_pipelined(rng, monkeypatch, gqa, banded):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4
         )
+
+
+def _rule_geometries():
+    """Every (block_q, block_k, specialised) the block rule returns over
+    the shapes a trainer can hand it."""
+    import itertools
+
+    from areal_tpu.ops.pallas.flash_attention import flash_blocks
+
+    return sorted({
+        flash_blocks(T, n_rep, sliding_window=w, max_seqlen=m, backward=back)
+        for T, n_rep, w, m, back in itertools.product(
+            (1024, 2048, 4096, 8192, 32768), (1, 2, 3, 6, 8), (None, 1024),
+            (None, 512, 4096), (False, True))
+    })
+
+
+# rows of 1,024 shaped like the train cell's: sequences and a pad tail, one
+# sequence that fills the row, a row that is padding past its first block
+RULE_ROWS = {
+    "packed": [400, 300, 200], "full": [1024], "head": [100],
+}
+
+
+def _rule_cases():
+    for bq, bk, spec in _rule_geometries():
+        for row, n_rep, window in (
+            ("packed", 6, None), ("full", 1, None), ("head", 6, None),
+            ("packed", 1, 200), ("full", 6, None), ("packed", 1, None),
+        ):
+            yield pytest.param(
+                bq, bk, spec, row, n_rep, window,
+                id=f"{bq}x{bk}{'s' if spec else 'm'}-{row}-rep{n_rep}"
+                   f"{'-w' if window else ''}")
+
+
+@pytest.mark.parametrize("bq,bk,spec,row,n_rep,window", list(_rule_cases()))
+def test_flash_rule_geometries_match_xla(rng, bq, bk, spec, row, n_rep,
+                                         window):
+    """Forward and dq / dk / dv at every geometry the block rule can return
+    against the dense path: the pair list at its blocks, the masked and the
+    interior body, folded heads and not, a window."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    T, Hkv, D = 1024, 1, 16
+    q, k, v, seg = _mk(rng, T, Hkv * n_rep, Hkv, D, RULE_ROWS[row])
+    scale = D**-0.5
+    live = (seg > 0)[:, None, None]
+
+    def loss(attn):
+        def f(q, k, v):
+            o = jnp.where(live, attn(q, k, v), 0.0)
+            return jnp.sum(o * o), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    blocks = (bq, bk, spec)
+    (_, got), g1 = loss(lambda q, k, v: fa._flash_thd(
+        q, k, v, seg, scale, None, window, blocks, blocks, None))(q, k, v)
+    (_, ref), g2 = loss(lambda q, k, v: _attention_xla(
+        q, k, v, seg, scale, sliding_window=window))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
+def _segments(T, lens):
+    seg = np.zeros(T, np.int32)
+    off = 0
+    for i, n in enumerate(lens):
+        seg[off:off + n] = i + 1
+        off += n
+    return seg
+
+
+# (lens in a row of 256, block_q, block_k, window, max_seqlen)
+PAIR_LIST_CASES = [
+    pytest.param([100, 60, 50], 32, 32, None, None, id="three-and-a-pad-tail"),
+    pytest.param([256], 32, 32, None, None, id="one-fills-the-row"),
+    pytest.param([20], 32, 32, None, None, id="all-pad-past-block-0"),
+    pytest.param([], 64, 32, None, None, id="an-empty-row"),
+    pytest.param([100, 60, 50], 16, 64, None, None, id="wide-k-blocks"),
+    pytest.param([100, 60, 50], 64, 16, None, None, id="tall-q-blocks"),
+    pytest.param([120, 100], 32, 32, 40, None, id="window"),
+    pytest.param([90, 70, 80], 32, 32, None, 96, id="max-seqlen"),
+    pytest.param([120, 100], 32, 16, 70, 128, id="window-and-max-seqlen"),
+]
+
+
+@pytest.mark.parametrize("xp", [jnp, np], ids=["program", "host-count"])
+@pytest.mark.parametrize("lens,bq,bk,window,max_seqlen", PAIR_LIST_CASES)
+def test_pair_list_is_the_masks_block_pairs(lens, bq, bk, window, max_seqlen,
+                                            xp):
+    """The list the kernels walk against a brute-force enumeration of the
+    token mask: every (q block, k block) pair with an unmasked element is
+    in it exactly once and no other pair runs a body, in q-block order;
+    a pair that skips the mask is unmasked throughout; each q block's
+    sweep has one FIRST and one LAST step (an all-pad block's one step
+    runs no body); the tail repeats the last indices without a flag. The
+    host's count (`pair_counts`) runs the same code over numpy."""
+    from areal_tpu.ops.pallas import flash_attention as fa
+
+    T = 256
+    seg = _segments(T, lens)
+    idx = np.arange(T)
+    mask = (seg[:, None] == seg[None, :]) & (seg[:, None] > 0) & (
+        idx[:, None] >= idx[None, :])
+    if window is not None:
+        mask &= idx[:, None] - idx[None, :] < window
+    blocks = mask.reshape(T // bq, bq, T // bk, bk)
+    some, every = blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
+
+    iq, ik, flags = (np.asarray(t) for t in fa._pair_list(
+        xp.asarray(seg), bq, bk, window, max_seqlen, T, xp=xp))
+    assert len(iq) == fa._pair_steps(bq, bk, window, max_seqlen, T)
+    active = flags & fa.ACTIVE != 0
+    ran = np.zeros_like(some, dtype=np.int64)
+    np.add.at(ran, (iq[active], ik[active]), 1)
+    np.testing.assert_array_equal(ran, some.astype(np.int64))
+    interior = active & (flags & fa.MASKED == 0)
+    assert every[iq[interior], ik[interior]].all()
+
+    n_live = int((flags != 0).sum())
+    assert (flags[:n_live] != 0).all() and n_live >= T // bq
+    assert (np.diff(iq[:n_live]) >= 0).all()
+    for b in range(T // bq):
+        sweep = flags[:n_live][iq[:n_live] == b]
+        assert sweep[0] & fa.FIRST and sweep[-1] & fa.LAST
+        assert (sweep & fa.FIRST != 0).sum() == 1
+        assert (sweep & fa.LAST != 0).sum() == 1
+        assert (np.diff(ik[:n_live][iq[:n_live] == b]) == 1).all()
+    assert (iq[n_live:] == iq[n_live - 1]).all()
+    assert (ik[n_live:] == ik[n_live - 1]).all()
+    assert ((0 <= ik) & (ik < T // bk)).all()
+
+    counts = fa.pair_counts(seg, bq, bk, True, window, max_seqlen)
+    assert counts["flash_pairs"] == some.sum()
+    assert counts["flash_interior_pairs"] == interior.sum()
+    if lens:
+        np.testing.assert_allclose(
+            counts["flash_fill"],
+            sum(n * n for n in lens) / 2 / (some.sum() * bq * bk))
+
+
+def test_pack_record_carries_the_pair_counts():
+    """The host half of a train step says how tightly the flash kernels
+    will cover its packed rows: ``flash_pairs``, ``flash_interior_pairs``
+    and ``flash_fill`` on the ``train_pipe/pack`` record, at the blocks the
+    kernels' wrapper gets from the same rule; nothing for a model that
+    runs no flash kernel."""
+    from areal_tpu.api.data import MicroBatchSpec, SequenceSample
+    from areal_tpu.base import tracing
+    from areal_tpu.models.config import ModelConfig
+    from areal_tpu.ops.pallas import flash_attention as fa
+    from areal_tpu.parallel.mesh import ParallelConfig
+    from areal_tpu.train.engine import OptimizerConfig, TrainEngine
+
+    lens = [700, 200, 60]
+    sample = SequenceSample.from_default(
+        ids=list(range(len(lens))), seqlens=lens,
+        data={"packed_input_ids": np.zeros(sum(lens), np.int64)},
+    )
+
+    def pack_attrs(use_flash):
+        cfg = ModelConfig(
+            n_layers=1, n_q_heads=4, n_kv_heads=2, head_dim=8, hidden_dim=16,
+            intermediate_dim=32, vocab_size=64, dtype="float32",
+            use_flash_attention=use_flash,
+        )
+        eng = TrainEngine(cfg, ParallelConfig(), OptimizerConfig(lr=1e-3))
+        eng.init_random(0)
+        eng.prepare_train_batch(
+            sample, MicroBatchSpec(n_mbs=1, max_tokens_per_mb=1024))
+        rec = [r for r in tracing.recent_spans()
+               if r["name"] == "train_pipe/pack"][-1]
+        return rec.get("attrs", {})
+
+    assert "flash_pairs" not in pack_attrs(False)
+    got = pack_attrs(True)
+    bq, bk, spec = fa.flash_blocks(1024, 2)
+    seg = _segments(1024, lens)
+    want = fa.pair_counts(seg, bq, bk, spec)
+    assert want["flash_pairs"] > 0
+    assert {k: got[k] for k in want} == want
 
 
 def test_flash_under_a_mesh_runs_as_shard_map():

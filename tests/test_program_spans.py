@@ -82,14 +82,17 @@ def _fused_sample():
 
 
 KERNELS = [
+    # one forward and one fused backward since PR 58: with a static
+    # ``max_seqlen`` or without, both walk the pair list
     pytest.param(lambda: (_flash(128, False), _flash_args()),
                  {"flash_fwd"}, id="flash_fwd"),
     pytest.param(lambda: (_flash(None, False), _flash_args()),
-                 {"flash_fwd_tri"}, id="flash_fwd_tri"),
+                 {"flash_fwd"}, id="flash_fwd_no_max_seqlen"),
     pytest.param(lambda: (_flash(128, True), _flash_args()),
                  {"flash_fwd", "flash_bwd_fused"}, id="flash_bwd_fused"),
     pytest.param(lambda: (_flash(None, True), _flash_args()),
-                 {"flash_fwd_tri", "flash_bwd_fused_tri"}, id="flash_bwd_fused_tri"),
+                 {"flash_fwd", "flash_bwd_fused"},
+                 id="flash_bwd_fused_no_max_seqlen"),
     pytest.param(lambda: (_flash(128, True), _flash_args()),
                  {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}, id="flash_bwd_split"),
     pytest.param(lambda: _paged(False), {"paged_decode"}, id="paged_decode"),

@@ -128,7 +128,9 @@ def _flash_specs(layout, T, one_chip):
     )
 
 
-def _flash(layout, max_seqlen, block=512):
+def _flash(layout, max_seqlen, block=None):
+    """The kernels at the blocks their own rule takes from the shapes
+    (``block=None``: what a model with no override compiles)."""
     from areal_tpu.ops.pallas.flash_attention import packed_flash_attention
 
     return functools.partial(
@@ -139,6 +141,7 @@ def _flash(layout, max_seqlen, block=512):
 
 FLASH_CASES = [
     pytest.param(QWEN_1P5B, 512, id="1p5b-band512"),
+    # the train cell's calls: no static bound, the rule's short-row blocks
     pytest.param(QWEN_1P5B, None, id="1p5b-triangle"),
     pytest.param(PRESET_125M, 512, id="125m-band512"),
     pytest.param(PRESET_125M, None, id="125m-triangle"),
@@ -165,8 +168,8 @@ def test_flash_bwd_compiles(compiled_kernels, one_chip, layout, max_seqlen):
 
 @pytest.mark.slow  # ~30 s alone; the tier-1 cases keep the file near a minute
 def test_flash_long_context_block_compiles(compiled_kernels, one_chip):
-    """Block 1024 is the default at T >= 8192 (ops/attention.py)."""
-    attn = _flash(QWEN_1P5B, None, block=1024)
+    """Block 1024 is the rule's choice at T >= 8192 (pinned below)."""
+    attn = _flash(QWEN_1P5B, None)
 
     def loss(q, k, v, seg):
         return jnp.sum(attn(q, k, v, seg).astype(jnp.float32))
@@ -175,6 +178,125 @@ def test_flash_long_context_block_compiles(compiled_kernels, one_chip):
         jax.grad(loss, argnums=(0, 1, 2)),
         *_flash_specs(QWEN_1P5B, 8192, one_chip),
     )
+
+
+@pytest.mark.parametrize("T,layout", [
+    pytest.param(8192, QWEN_1P5B, id="1p5b-8k"),
+    pytest.param(32768, QWEN_1P5B, id="1p5b-32k"),
+    pytest.param(8192, PRESET_125M, id="125m-8k"),
+    pytest.param(16384, OLMOE, id="olmoe-16k"),
+])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_rule_keeps_long_rows_blocks(T, layout, backward):
+    """A row of 8,192 tokens or more takes 1,024 x 1,024 and the
+    specialised (masked / interior) bodies in both directions, as it did
+    before the rule read shapes (PR 58)."""
+    from areal_tpu.ops.pallas.flash_attention import flash_blocks
+
+    assert flash_blocks(
+        T, layout["hq"] // layout["hkv"], backward=backward,
+    ) == (1024, 1024, True)
+
+
+@pytest.mark.parametrize("bound", [
+    pytest.param(dict(max_seqlen=512), id="max_seqlen"),
+    pytest.param(dict(sliding_window=1024), id="window"),
+    pytest.param(dict(max_seqlen=2048, sliding_window=512), id="both"),
+])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_rule_keeps_bounded_rows_blocks(bound, backward):
+    """A short row whose band a static ``max_seqlen`` or a window bounds
+    keeps 512 x 512 and the one masked body: no other size was measured
+    for it (PERF.md section 6, PR 58)."""
+    from areal_tpu.ops.pallas.flash_attention import flash_blocks
+
+    assert flash_blocks(
+        T_TRAIN, 6, backward=backward, **bound
+    ) == (512, 512, False)
+
+
+def test_flash_rule_short_rows_and_overrides():
+    """The train cell's calls (4,096 tokens, no bound): the forward's and
+    the fused backward's own blocks, the one masked body; a model's
+    override goes to both directions and is halved until it divides the
+    row."""
+    from areal_tpu.ops.pallas.flash_attention import flash_blocks
+
+    assert flash_blocks(T_TRAIN, 6) == (256, 1024, False)
+    assert flash_blocks(T_TRAIN, 6, backward=True) == (256, 256, False)
+    # fewer than three heads a kv head: q blocks of 512, so a tile keeps
+    # its rows
+    assert flash_blocks(T_TRAIN, 1) == (512, 1024, False)
+    assert flash_blocks(T_TRAIN, 1, backward=True) == (512, 512, False)
+    for backward in (False, True):
+        assert flash_blocks(
+            T_TRAIN, 6, block_q=512, backward=backward) == (512, 512, False)
+        assert flash_blocks(
+            T_TRAIN, 6, block_q=512, block_k=128, backward=backward
+        ) == (512, 128, False)
+        assert flash_blocks(
+            3072, 6, block_q=2048, backward=backward) == (1024, 1024, False)
+    assert flash_blocks(768, 6) == (256, 256, False)
+
+
+@pytest.mark.parametrize("over", [
+    pytest.param({}, id="rule"),
+    pytest.param(dict(attn_max_seqlen=512), id="max_seqlen"),
+    pytest.param(dict(flash_block_size=512, flash_block_size_k=256),
+                 id="override"),
+    pytest.param(dict(sliding_window=1024), id="window"),
+])
+def test_flash_counter_and_wrapper_ask_for_the_same_blocks(monkeypatch, over):
+    """The trainer's count on the ``train_pipe/pack`` record and the
+    kernels' wrapper get their blocks from ONE function, with the same
+    arguments for the same model: the count is of the list the forward
+    kernel will walk."""
+    from areal_tpu.models.config import ModelConfig
+    from areal_tpu.ops import attention as attn_ops
+    from areal_tpu.ops.pallas import flash_attention as fa
+    from areal_tpu.train import batching
+    from areal_tpu.train.engine import TrainEngine
+
+    cfg = ModelConfig(
+        n_layers=1, n_q_heads=12, n_kv_heads=2, head_dim=128,
+        hidden_dim=1536, intermediate_dim=64, vocab_size=64,
+        dtype="bfloat16", use_flash_attention=True, **over)
+    asked = []
+    rule = fa.flash_blocks
+
+    def spy(*args, **kwargs):
+        out = rule(*args, **kwargs)  # the forward's call is the one counted
+        if not kwargs.pop("backward", False):
+            asked.append((args, kwargs, out))
+        return out
+
+    monkeypatch.setattr(fa, "flash_blocks", spy)
+    monkeypatch.setattr(fa, "_flash_thd", lambda q, *a: q)
+    T = T_TRAIN
+    jax.eval_shape(
+        lambda q, k, v, seg: attn_ops.packed_attention(
+            q, k, v, seg, sliding_window=cfg.sliding_window, use_flash=True,
+            flash_block_size=cfg.flash_block_size,
+            flash_block_size_k=cfg.flash_block_size_k,
+            max_seqlen=cfg.attn_max_seqlen),
+        jax.ShapeDtypeStruct((T, 12, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((T, 2, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((T, 2, 128), jnp.bfloat16),
+        jax.ShapeDtypeStruct((T,), jnp.int32),
+    )
+    from areal_tpu.api.data import SequenceSample
+
+    packed = batching.pack_sequences(
+        SequenceSample.from_default(
+            ids=[0, 1], seqlens=[3000, 700],
+            data={"packed_input_ids": np.zeros(3700, np.int64)}),
+        1, capacity=T)
+    eng = TrainEngine.__new__(TrainEngine)
+    eng.cfg = cfg
+    counts = eng._flash_pair_counts([packed])
+    wrapper, counter = asked
+    assert wrapper == counter
+    assert counts["flash_pairs"] > 0
 
 
 def _paged_specs(one_chip, *, page, int8, L=28, B=64, P=256, M=16,
