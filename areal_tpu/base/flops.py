@@ -79,6 +79,14 @@ def param_count(cfg: ModelConfig, activated: bool = False) -> int:
     layers = cfg.n_dense_layers * (attn + 3 * E * F) + (
         L - cfg.n_dense_layers
     ) * (attn + mlp)
+    if cfg.kda is not None:
+        # delta-rule layers in the attention layers' place (``ops/kda.py``):
+        # q, k, v and the output projection at the mixer's width, the
+        # decay's and the gate's rank pairs, beta a head (the convolution's
+        # taps, the norms and the decay's vectors are no matmul)
+        d = cfg.kda
+        mixer = 4 * E * d.d_inner + 2 * d.head_dim * (E + d.d_inner) + E * d.n_heads
+        layers += cfg.n_kda_layers * (mixer - attn)
     head = E if cfg.is_critic else (0 if cfg.tied_embedding else E * V)
     return V * E + layers + head
 
@@ -120,13 +128,26 @@ def _attention_forward_flops(
     return sum(2 * 2 * (l * l / 2) * D * H for l in seqlens) * cfg.cache_layers
 
 
+def _delta_rule_forward_flops(cfg: ModelConfig, n_tokens: int) -> float:
+    """The delta-rule recurrence a token (``ops/kda.py:step_update``): over
+    each head's ``Dk x Dv`` state one decay and three multiply-adds (``S^T
+    k``, the rank-one write, ``S^T q``), 7 FLOP an entry, whatever the
+    chunking (the chunked form's pair terms are ``chunk / D`` of that
+    again and are left out, as the flash kernels' recompute is)."""
+    if cfg.kda is None:
+        return 0.0
+    d = cfg.kda
+    return 7.0 * d.n_heads * d.head_dim**2 * cfg.n_kda_layers * n_tokens
+
+
 def forward_flops(
     cfg: ModelConfig,
     n_tokens: int,
     seqlens: Optional[Sequence[int]] = None,
 ) -> float:
     fwd = 2 * _matmul_uses(cfg) * n_tokens
-    return fwd + _attention_forward_flops(cfg, seqlens)
+    return fwd + _attention_forward_flops(cfg, seqlens) + (
+        _delta_rule_forward_flops(cfg, n_tokens))
 
 
 def train_flops(
@@ -138,4 +159,5 @@ def train_flops(
     (backward ≈ 2x forward for matmuls; attention backward ≈ 2.5x its
     forward). ``seqlens`` sharpens the attention term."""
     fwd = 2 * _matmul_uses(cfg) * n_tokens
-    return 3 * fwd + 3.5 * _attention_forward_flops(cfg, seqlens)
+    return 3 * fwd + 3.5 * _attention_forward_flops(cfg, seqlens) + (
+        3 * _delta_rule_forward_flops(cfg, n_tokens))

@@ -135,7 +135,9 @@ class GenState:
     # admission's chunks, updated in place by every decode step.
     # A model whose attention runs inside a convolved latent (``cfg.cca``)
     # keeps its carry here the same way, BESIDE keys and values in every
-    # layer (``tfm.CCAState``: ``carry [L, B, W]``, a few KB a layer).
+    # layer (``tfm.CCAState``: ``carry [L, B, W]``, a few KB a layer); a
+    # model with delta-rule layers (``cfg.kda``) a matrix a head of each
+    # (``tfm.DeltaState``: ``s [Lk, B, H, Dk, Dv]`` float32 and ``conv``).
     ssm: Optional[Any] = None
     # the prefix cache's SNAPSHOTS of that state, ``[Ls, n_snapshots,
     # ...]``: entry ``i`` is the state after exactly the tokens of the
@@ -199,6 +201,13 @@ class GenOutput:
 # rows of the routing record (``record_routing``) that one pull of the
 # harvest takes
 _ROUTING_PULL = 8
+# a per-slot state of at least this many bytes is a matrix a head (a
+# recurrent or a delta-rule state: 13-76 MB a slot at the published sizes)
+# and gets the few snapshots that memory allows; a smaller one is a
+# convolution's carry (86 KB) and gets two a slot. Nothing lies between:
+# the widest carry is a twelfth of the threshold, the smallest matrix state
+# thirteen times it
+_SNAPSHOT_MATRIX_STATE_BYTES = 2**20
 
 
 def _page_ids(page, kind: Optional[int] = None):
@@ -360,16 +369,16 @@ class GenerationEngine:
                         "latent attention: a latent page has no head axis to "
                         "shard; tensor-parallel serving is not supported"
                     )
-            # per-slot state beside the page pool: the state-space layers'
-            # recurrent state, or the convolved latent's carry (the
-            # ``state_*`` counters are of either)
-            self._stateful = cfg.ssm is not None or cfg.cca is not None
+            # per-slot state beside the page pool: the state-space or the
+            # delta-rule layers' recurrent state, or the convolved latent's
+            # carry (the ``state_*`` counters are of whichever the model
+            # has: ``tfm.row_state_*`` say what, how large, in how many
+            # layers)
+            kind = tfm.row_state_kind(cfg)
+            self._stateful = kind is not None
             if self._stateful:
                 # what has no test beside per-slot state is refused, not
                 # approximated
-                kind = (
-                    "state-space layers" if cfg.ssm is not None
-                    else "attention in a convolved latent")
                 if self.kv_quantized:
                     raise NotImplementedError(
                         f"{kind}: an int8 page pool beside per-slot state "
@@ -453,14 +462,17 @@ class GenerationEngine:
             )
             self.pool = PagePool(self.n_pages, page_size)
             # snapshots of the per-slot state in the prefix cache: 8 of a
-            # recurrent state (76 MB each at the published sizes); of the
-            # convolved latent's carry (86 KB) two a slot: an admission
+            # recurrent state (76 MB each at the published sizes, 13 MB of
+            # a delta-rule model's); of a state under a megabyte (the
+            # convolved latent's carry: 86 KB) two a slot: an admission
             # files at most one, so the runs the running slots filed keep
             # theirs and as many runs again whose tenants have left (the
             # least recently used goes first; a run is a page at least)
             if state_snapshots is None:
+                matrix = (
+                    tfm.row_state_bytes(cfg) >= _SNAPSHOT_MATRIX_STATE_BYTES)
                 state_snapshots = (
-                    8 if cfg.cca is None else min(2 * self.B, self.n_pages))
+                    8 if matrix else min(2 * self.B, self.n_pages))
             self.n_snapshots = (
                 max(int(state_snapshots), 1)
                 if self._stateful and enable_prefix_cache else 0)
@@ -961,9 +973,10 @@ class GenerationEngine:
             return out
 
     def recurrent_state(self, rid: str) -> Optional[Tuple[int, np.ndarray]]:
-        """What the state-space layers hold of the running request ``rid``:
-        ``(n, ssm)`` with ``ssm [Ls, H, P, N]`` float32 (the selective
-        scan: one head of ``d_inner`` channels) the recurrent state
+        """What the state-space (or delta-rule) layers hold of the running
+        request ``rid``: ``(n, ssm)`` with ``ssm [Ls, H, P, N]`` float32 (the
+        selective scan: one head of ``d_inner`` channels; delta-rule layers:
+        ``[Lk, H, Dk, Dv]``) the recurrent state
         after the prompt and all but the last of the ``n`` tokens generated
         so far (the last is fed at the next step), both from ONE state
         pytree. For a check from outside that the state is what the
@@ -975,15 +988,30 @@ class GenerationEngine:
         ``partial_outputs``'s does."""
         with self._lock:
             st = self.state
-            if st.ssm is None:
+            if self.cfg.recurrent is None:
                 return None
             for b, s in enumerate(self._slots):
                 if s is not None and s.rid == rid:
-                    n, ssm = jax.device_get((st.n_gen[b], st.ssm.ssm[:, b]))
-                    c = self.cfg.ssm
-                    ssm = np.asarray(ssm).transpose(0, 1, 2, 4, 3)
-                    return int(n), ssm.reshape(
-                        len(ssm), c.n_heads, c.head_dim, c.d_state)
+                    n, ssm = jax.device_get(
+                        (st.n_gen[b], jax.tree.leaves(st.ssm)[0][:, b]))
+                    return int(n), tfm.recurrent_heads(
+                        self.cfg, np.asarray(ssm))
+            return None
+
+    def partial_routing(self, rid: str) -> Optional[np.ndarray]:
+        """The experts the decode steps chose for the tokens the running
+        request ``rid`` has generated so far, int32 ``[n, L, top_k]``, on
+        a ``record_routing`` engine (``None`` otherwise, or for a request
+        that holds no slot). For a check from outside that reads a running
+        request's state (:meth:`recurrent_state`) GIVEN the program's
+        routing; one pull, which blocks on any in-flight chunk."""
+        with self._lock:
+            if self.state.out_routing is None:
+                return None
+            for b, s in enumerate(self._slots):
+                if s is not None and s.rid == rid:
+                    host = self._pull_outputs([b])
+                    return host["out_routing"][b][: int(host["n_gen"][b])]
             return None
 
     def cancel(self, rid: str) -> bool:
@@ -1543,11 +1571,14 @@ class GenerationEngine:
         one group of heads), which reads and writes the state once; else
         ``None``, which is ``ops/ssm.py:step_update`` (XLA: two fusions a
         layer that both read the state)."""
-        from areal_tpu.ops.pallas import ssm_decode
+        from areal_tpu.ops.pallas import kda_decode, ssm_decode
 
         if self.cfg.ssm is not None and ssm_decode.ssm_decode_applies(
                 self.cfg, self.mesh):
             return ssm_decode.ssm_decode
+        # the delta-rule layers' likewise (``ops/kda.py:step_update``)
+        if kda_decode.kda_decode_applies(self.cfg, self.mesh):
+            return kda_decode.kda_decode
         return None
 
     def _moe_grouped(self, rows: int) -> bool:
@@ -1780,6 +1811,7 @@ class GenerationEngine:
         if key in self._jit_state:
             return self._jit_state[key]
 
+        @jax.named_scope("state_snapshot_copy")
         def copy(state: GenState, dst, src):
             if into_slots:
                 if state.snaps is None:
@@ -2633,7 +2665,7 @@ class GenerationEngine:
                 chunk_attrs.update(
                     state_slots=len(running) * decode_steps,
                     state_bytes_per_slot=tfm.row_state_bytes(cfg),
-                    state_layers=cfg.n_ssm_layers or cfg.n_layers)
+                    state_layers=tfm.row_state_layers(cfg))
                 self.stats["state_slots"] += chunk_attrs["state_slots"]
             self.stats["loop_passes"] += decode_steps * cfg.n_passes
             self.stats["layer_passes"] += layer_passes
@@ -2725,7 +2757,7 @@ class GenerationEngine:
             self._decode_use_pallas, width, heads, self.page, pool_dtype,
             full_kinds=sum(w is None for w in self._windows),
             quantized=self.state.cache.quantized, latent=cfg.mla is not None,
-            slot_order=cfg.ssm is not None, mesh=self.mesh,
+            slot_order=cfg.recurrent is not None, mesh=self.mesh,
         )
         pages = int((-(-lens // self.page)).sum())
         shared = {"kv_pages_named": pages, "kv_pages_read": pages,

@@ -4,7 +4,7 @@ TPU-native counterpart of ``ReaLModelConfig`` (``realhf/api/core/model_api.py:34
 and ``ReaLMoEConfig`` (``:294``). One dataclass covers every supported HF
 family (llama, qwen2, qwen3, mistral, gemma, gpt2, mixtral, olmoe,
 joyai_llm_flash, smallthinker, ouro, granitemoehybrid, zaya, phi4flash,
-nemotron_h, afmoe) via feature switches, exactly like the reference's single in-house architecture.
+nemotron_h, afmoe, solar_open2) via feature switches, exactly like the reference's single in-house architecture.
 """
 
 import dataclasses
@@ -196,7 +196,39 @@ class SSMConfig:
         return self.d_inner + self.conv_dim + self.n_heads
 
 
-MIXERS = ("ssm", "attn", "gmu", "cross", "moe")
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Gated delta-rule linear attention (family ``solar_open2``; Kimi Delta
+    Attention's layout; ``ops/kda.py`` has the equations). ``n_heads``
+    heads, each with a recurrent state ``S`` of ``head_dim x head_dim`` (a
+    key and a value head are one width, one k/v head a q head) that decays
+    by its own factor a key CHANNEL and token and is corrected by a delta
+    rule; q, k and v each pass a causal depthwise convolution of ``d_conv``
+    taps; the decay's and the output gate's projections go through a rank
+    of ``head_dim`` (the published ``kda_use_full_proj: false``).
+    ``neg_eigval``: the delta rule's ``beta`` is ``2 x sigmoid``
+    (eigenvalues of ``I - beta k k^T`` in [-1, 1]) where it is ``sigmoid``
+    otherwise. ``chunk_size`` is the program's own (any chunking computes
+    the same function; the tests lower it so that a tiny model's prompt
+    crosses chunks). The state is float32 (``ops/kda.py:STATE_DTYPE``)."""
+
+    n_heads: int
+    head_dim: int
+    d_conv: int = 4
+    neg_eigval: bool = False
+    chunk_size: int = 64
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolutions run over: ``[q ; k ; v]``."""
+        return 3 * self.d_inner
+
+
+MIXERS = ("ssm", "attn", "gmu", "cross", "moe", "kda")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -286,9 +318,13 @@ class ModelConfig:
     #   "cross" attention with the layer's own queries over the K/V of the
     #           LAST "attn" layer of an earlier segment, which it shares
     #           and never writes
+    #   "kda"   a gated delta-rule linear-attention layer (``kda``); its
+    #           context is a per-SLOT state too (``DeltaState``). A plan has
+    #           "ssm" or "kda" layers, not both
     # The kinds differ in weight SHAPE, so the weight tree holds a stack a
     # kind, each in the order its layers run (``params["layers"]`` the
-    # "attn" layers, ``"ssm_layers"``, ``"gmu_layers"``, ``"cross_layers"``)
+    # "attn" layers, ``"ssm_layers"``, ``"gmu_layers"``, ``"cross_layers"``,
+    # ``"kda_layers"``)
     # and ONE scan a segment cuts each position's weights from the stack
     # of its kind (``models/transformer._scan_plan``). The cache's layer
     # kinds (``layer_kinds``) follow the "attn" layers' windows.
@@ -300,8 +336,12 @@ class ModelConfig:
     # and "attn" the mixer alone, and
     #   "moe"   the expert layer alone (``moe``, ``mlp_type`` "moe"; the
     #           stack ``params["moe_layers"]``, ``ln1`` and ``mlp``).
-    # Only such a plan holds a router.
+    # Such a plan holds its router THERE. A plan of two-branch blocks over
+    # "attn" and "kda" layers may hold one in EVERY block's second branch
+    # (``mlp_type`` "moe": ``solar_open2``; both stacks then carry ``ln2``
+    # and the expert ``mlp``), share included (``MoEConfig.n_held``).
     ssm: Optional[SSMConfig] = None
+    kda: Optional[KDAConfig] = None
     stack_plan: Optional[Tuple[Tuple[int, Tuple[Any, ...]], ...]] = None
     one_branch: bool = False
     # Differential attention (``phi4flash``): heads in PAIRS; a pair's two
@@ -555,6 +595,18 @@ class ModelConfig:
         return self.n_mixers("ssm")
 
     @property
+    def n_kda_layers(self) -> int:
+        return self.n_mixers("kda")
+
+    @property
+    def recurrent(self) -> Optional[str]:
+        """The plan's mixer kind that keeps a per-slot recurrent state,
+        "ssm" or "kda" (None: the model has neither)."""
+        if self.ssm is not None:
+            return "ssm"
+        return "kda" if self.kda is not None else None
+
+    @property
     def n_attn_layers(self) -> int:
         """Layers of self attention: those that hold K/V."""
         return self.n_mixers("attn")
@@ -599,7 +651,7 @@ class ModelConfig:
     @property
     def n_moe_layers(self) -> int:
         """Layers with a router (0 for a dense model)."""
-        if self.stack_plan is not None:
+        if self.one_branch:
             return self.n_mixers("moe")
         return self.n_layers - self.n_dense_layers if self.mlp_type == "moe" else 0
 
@@ -628,8 +680,12 @@ class ModelConfig:
             )
         if self.n_passes < 1:
             raise ValueError("n_passes: the stack runs at least once")
-        if (self.ssm is None) != (self.stack_plan is None):
-            raise ValueError("ssm and stack_plan come together")
+        if (self.recurrent is None) != (self.stack_plan is None) or (
+            self.ssm is not None and self.kda is not None
+        ):
+            raise ValueError(
+                "stack_plan comes with ONE recurrent mixer's settings, ssm "
+                "or kda, and they with it")
         if self.stack_plan is not None:
             def position(p):
                 mixer, window = (p, None) if isinstance(p, str) else p
@@ -650,23 +706,42 @@ class ModelConfig:
                 or any(m not in MIXERS for m in kinds)
                 or any(w is not None and (m != "attn" or w < 1)
                        for m, w in flat)
-                or "attn" not in kinds or "ssm" not in kinds
+                or "attn" not in kinds or self.recurrent not in kinds
+                or ("kda" if self.ssm is not None else "ssm") in kinds
             ):
                 raise ValueError(
-                    "stack_plan: segments (repeats, period) of 'ssm', "
-                    "('attn', window or None), 'gmu', 'cross' and 'moe' "
-                    "positions (state-space and attention both present, a "
-                    f"window on 'attn' alone) that make up n_layers, got "
-                    f"{self.stack_plan!r}"
+                    "stack_plan: segments (repeats, period) of 'ssm' or "
+                    "'kda' (the kind whose settings the model has: ssm, "
+                    "kda), ('attn', window or None), 'gmu', 'cross' and "
+                    "'moe' positions (the recurrent kind and attention both "
+                    "present, a window on 'attn' alone) that make up "
+                    f"n_layers, got {self.stack_plan!r}"
                 )
-            if ("moe" in kinds) != (self.mlp_type == "moe") or (
-                "moe" in kinds and not self.one_branch
-            ) or (self.one_branch and ("gmu" in kinds or "cross" in kinds)):
+            delta = self.kda is not None
+            if delta and (
+                self.one_branch or set(kinds) != {"attn", "kda"}
+                or self.mlp_type not in ("gated", "moe")
+            ):
                 raise ValueError(
-                    "stack_plan: a plan holds a router in 'moe' positions "
-                    "(mlp_type 'moe', blocks of one branch: one_branch) and "
-                    "nowhere else; blocks of one branch are 'ssm', 'attn' "
-                    "and 'moe'"
+                    "stack_plan: 'kda' layers stand beside 'attn' layers "
+                    "alone, in blocks of TWO branches (a mixer, then a "
+                    "gated MLP or, mlp_type 'moe', an expert layer in "
+                    "every block, a share of the experts included); with "
+                    "'gmu', 'cross' or 'moe' positions or one_branch they "
+                    "are not supported"
+                )
+            if not delta and (
+                ("moe" in kinds) != (self.mlp_type == "moe") or (
+                    "moe" in kinds and not self.one_branch
+                ) or (self.one_branch and ("gmu" in kinds or "cross" in kinds))
+            ):
+                raise ValueError(
+                    "stack_plan: a plan over 'ssm' layers holds a router in "
+                    "'moe' positions (mlp_type 'moe', blocks of one branch: "
+                    "one_branch) and nowhere else, and blocks of one branch "
+                    "are 'ssm', 'attn' and 'moe'; a router in every block's "
+                    "second branch is for a plan over 'attn' and 'kda' "
+                    "layers"
                 )
             for si, (reps, period) in enumerate(plan):
                 here = [m for m, _ in period]
@@ -697,7 +772,7 @@ class ModelConfig:
                         "'cross' positions share is ONE full layer (the "
                         "last 'attn' position of a segment that runs once)"
                     )
-            s = self.ssm
+            s, d = self.ssm, self.kda
             if (
                 self.n_passes > 1 or self.exit_gate or self.mla is not None
                 or self.n_dense_layers or self.n_mtp_layers
@@ -713,15 +788,31 @@ class ModelConfig:
                     "its attention layers' windows are named in the plan, "
                     "not by layer_pattern or sliding_window"
                 )
-            if s.n_heads % s.n_groups or (s.selective and (
-                    s.n_heads != 1 or s.n_groups != 1 or s.proj_bias)):
+            if d is not None and (
+                d.d_conv < 2
+                or min(d.n_heads, d.head_dim, d.chunk_size) < 1
+                or (self.moe is not None and (
+                    self.moe.router_on_layer_input
+                    or self.moe.router_dim is not None
+                    or self.moe.skip_expert))
+                or self.residual_scaling or self.diff_attn
+            ):
+                raise ValueError(
+                    "kda: heads, a head width and a chunk of at least one "
+                    "and a convolution of at least two taps; with a "
+                    "router on the layer's input, a stateful router, a skip "
+                    "output, learned residual scaling or differential "
+                    "attention it is not supported"
+                )
+            if s is not None and (s.n_heads % s.n_groups or (s.selective and (
+                    s.n_heads != 1 or s.n_groups != 1 or s.proj_bias))):
                 raise ValueError(
                     f"ssm: n_groups={s.n_groups} does not divide "
                     f"n_heads={s.n_heads}, or a selective scan (dt_rank) "
                     "with more than one head of d_inner channels, or with "
                     "projection biases"
                 )
-            if s.state_dtype != "float32":
+            if s is not None and s.state_dtype != "float32":
                 raise ValueError(
                     f"ssm: state_dtype {s.state_dtype!r}: the recurrent "
                     "state is float32 (a 16-bit state is another "
@@ -790,9 +881,10 @@ class ModelConfig:
             or self.ssm is not None or self.diff_attn
         ):
             raise ValueError(
-                "attn_gate: a gate on the context of plain q/k/v attention; "
-                "with latent, convolved or differential attention or a "
-                "stack plan it is not supported"
+                "attn_gate: a gate on the context of plain q/k/v attention "
+                "(also beside 'kda' layers in a stack plan); with latent, "
+                "convolved or differential attention or a plan over 'ssm' "
+                "layers it is not supported"
             )
         if self.n_dense_layers and self.mla is None and (
             self.moe.router_on_layer_input or self.residual_scaling

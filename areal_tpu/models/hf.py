@@ -3,7 +3,7 @@
 TPU-native counterpart of the reference's ``realhf/api/from_hf/*`` registry
 (llama/qwen2/qwen3/gpt2/gemma/mistral/mixtral, ~1390 LoC; olmoe,
 joyai_llm_flash, smallthinker, ouro, granitemoehybrid, phi4flash,
-nemotron_h and afmoe are added here) consumed by
+nemotron_h, afmoe and solar_open2 are added here) consumed by
 ``ReaLModel.from_/to_{family}`` (``realhf/impl/model/nn/real_llm_api.py:898``).
 
 Design: converters are pure functions over ``Dict[str, np.ndarray]`` (flat HF
@@ -24,7 +24,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from areal_tpu.models.config import (
-    CCAConfig, MLAConfig, ModelConfig, MoEConfig, SSMConfig,
+    CCAConfig, KDAConfig, MLAConfig, ModelConfig, MoEConfig, SSMConfig,
 )
 
 HFState = Dict[str, np.ndarray]
@@ -1257,6 +1257,294 @@ register_hf_family(
         config_to_hf=_nemotron_config_to_hf,
         params_from_hf=_nemotron_params_from_hf,
         params_to_hf=_nemotron_params_to_hf,
+    )
+)
+
+
+# --------------------------------------------------------------------------- #
+# solar_open2 (Upstage's Solar Open 2: gated delta-rule linear attention,
+# Kimi Delta Attention's layout, 3:1 with gated softmax attention WITHOUT
+# positions; two-branch blocks whose second branch is an expert layer in
+# every block: sigmoid router with a selection bias, one shared expert)
+# --------------------------------------------------------------------------- #
+
+
+def _solar2_config_from_hf(hf: Dict[str, Any]) -> ModelConfig:
+    """Every key of the published config is read; what the program does not
+    compute is refused by the key's name: ``kda_use_full_proj`` (the
+    decay's and the gate's projections go through a rank of one head's
+    width), a non-null ``linear_attn_config.num_kv_heads`` (one k/v head a
+    q head), ``use_rope`` (the attention layers apply no positional
+    encoding: ``rope_theta`` and ``partial_rotary_factor`` shape nothing
+    and are written back as published), ``first_k_dense_replace`` > 0 (every
+    block's second branch is the expert layer; ``intermediate_size`` then
+    shapes nothing), a ``gqa_layers`` that ``gqa_interval`` does not
+    reproduce (layer ``l`` is softmax attention where ``l % (gqa_interval
+    + 1) == 0``).
+
+    An expert-parallel rank's share is read the way ``nemotron_h`` reads
+    it: ``n_routed_experts`` counts the experts HELD, of ``n_routed_experts
+    x expert_parallel_size`` that the router scores, from
+    ``expert_parallel_rank x n_routed_experts`` on."""
+    lin = hf["linear_attn_config"]
+    L = hf["num_hidden_layers"]
+    if hf.get("kda_use_full_proj", False):
+        raise ValueError(
+            "solar_open2: kda_use_full_proj true (full-width projections of "
+            "the decay and the output gate) is not supported")
+    if lin.get("num_kv_heads") is not None:
+        raise ValueError(
+            "solar_open2: linear_attn_config.num_kv_heads="
+            f"{lin['num_kv_heads']!r}: grouped k/v heads in the linear "
+            "layers are not supported (only null: one a q head)")
+    if hf.get("use_rope", False):
+        raise ValueError(
+            "solar_open2: use_rope true is not supported (the attention "
+            "layers apply no positional encoding)")
+    if int(hf.get("first_k_dense_replace", 0) or 0) > 0:
+        raise ValueError(
+            "solar_open2: first_k_dense_replace > 0 (leading dense layers) "
+            "is not supported")
+    every = int(hf["gqa_interval"]) + 1
+    gqa = [l for l in range(L) if l % every == 0]
+    if list(hf.get("gqa_layers", gqa)) != gqa or L % every:
+        raise ValueError(
+            f"solar_open2: gqa_layers={hf.get('gqa_layers')!r} is not what "
+            f"gqa_interval={hf['gqa_interval']} gives over {L} layers "
+            f"({gqa}: one attention layer, then gqa_interval linear layers, "
+            "in whole periods)")
+    held = hf["n_routed_experts"]
+    ranks = int(hf.get("expert_parallel_size", 1))
+    rank = int(hf.get("expert_parallel_rank", 0))
+    if not 0 <= rank < ranks:
+        raise ValueError(
+            f"solar_open2: expert_parallel_rank {rank} of {ranks} ranks")
+    return ModelConfig(
+        n_layers=L,
+        n_q_heads=hf["num_attention_heads"],
+        n_kv_heads=hf["num_key_value_heads"],
+        head_dim=hf["head_dim"],
+        hidden_dim=hf["hidden_size"],
+        intermediate_dim=hf["intermediate_size"],
+        vocab_size=hf["vocab_size"],
+        n_positions=hf.get("max_position_embeddings", 1048576),
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5),
+        apply_rotary=False,
+        rotary_base=float(hf.get("rope_theta", 10000)),
+        attn_gate=bool(hf.get("use_gqa_gate", False)),
+        mlp_type="moe",
+        tied_embedding=bool(hf.get("tie_word_embeddings", False)),
+        moe=MoEConfig(
+            num_experts=held * ranks,
+            top_k=hf["num_experts_per_tok"],
+            routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+            norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+            expert_dim=hf["moe_intermediate_size"],
+            n_shared_experts=hf.get("n_shared_experts", 0),
+            scoring="sigmoid",
+            selection_bias=True,
+            n_held=None if ranks == 1 else held,
+            held_offset=rank * held,
+        ),
+        kda=KDAConfig(
+            n_heads=lin["num_heads"],
+            head_dim=lin["head_dim"],
+            d_conv=lin.get("short_conv_kernel_size", 4),
+            neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),
+        ),
+        stack_plan=((L // every, (("attn", None),) + ("kda",) * (every - 1)),),
+    )
+
+
+def _solar2_config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:
+    d, m = cfg.kda, cfg.moe
+    held, first = m.held
+    every = cfg.n_layers // cfg.n_attn_layers
+    whole = lambda x: int(x) if float(x).is_integer() else x
+    out = {
+        "model_type": "solar_open2",
+        "architectures": ["SolarOpen2ForCausalLM"],
+        "partial_rotary_factor": 1,
+        "linear_attn_config": {
+            "short_conv_kernel_size": d.d_conv, "head_dim": d.head_dim,
+            "num_heads": d.n_heads, "num_kv_heads": None},
+        "hidden_size": cfg.hidden_dim,
+        "num_hidden_layers": cfg.n_layers,
+        "num_attention_heads": cfg.n_q_heads,
+        "head_dim": cfg.head_dim,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "vocab_size": cfg.vocab_size,
+        "intermediate_size": cfg.intermediate_dim,
+        "moe_intermediate_size": cfg.expert_dim,
+        "rms_norm_eps": cfg.layer_norm_epsilon,
+        "rope_theta": whole(cfg.rotary_base),
+        "tie_word_embeddings": cfg.tied_embedding,
+        "max_position_embeddings": cfg.n_positions,
+        "first_k_dense_replace": 0,
+        "use_rope": False,
+        "gqa_interval": every - 1,
+        "gqa_layers": cfg.layer_ids["attn"],
+        "use_gqa_gate": cfg.attn_gate,
+        "kda_use_full_proj": False,
+        "kda_allow_neg_eigval": d.neg_eigval,
+        "n_routed_experts": held,
+        "n_shared_experts": m.n_shared_experts,
+        "norm_topk_prob": m.norm_topk_prob,
+        "routed_scaling_factor": whole(m.routed_scaling_factor),
+        "num_experts_per_tok": m.top_k,
+    }
+    if not m.holds_all:
+        out["expert_parallel_size"] = m.num_experts // held
+        out["expert_parallel_rank"] = first // held
+    return out
+
+
+# (ours, the name under ``model.layers.{i}.``, transposed); ASSUMED from
+# Kimi Linear's published modelling code (the family's own is not public
+# where this was written: ``benchmark/configs/solar-open2-l4-ep8.json``)
+_SOLAR2_NORMS = (("ln1", "input_layernorm"), ("ln2", "post_attention_layernorm"))
+_SOLAR2_ATTN = (
+    ("wq", "self_attn.q_proj.weight", True),
+    ("wk", "self_attn.k_proj.weight", True),
+    ("wv", "self_attn.v_proj.weight", True),
+    ("wo", "self_attn.o_proj.weight", True),
+    ("wg", "self_attn.g_proj.weight", True),
+)
+_SOLAR2_KDA = (
+    ("w_fa", "self_attn.f_a_proj.weight", True),
+    ("w_fb", "self_attn.f_b_proj.weight", True),
+    ("dt_bias", "self_attn.dt_bias", False),
+    ("A_log", "self_attn.A_log", False),
+    ("w_beta", "self_attn.b_proj.weight", True),
+    ("w_ga", "self_attn.g_a_proj.weight", True),
+    ("w_gb", "self_attn.g_b_proj.weight", True),
+    ("o_norm", "self_attn.o_norm.weight", False),
+    ("wo", "self_attn.o_proj.weight", True),
+)
+_SOLAR2_QKV = ("q", "k", "v")
+_SOLAR2_MOE = (
+    ("router", "block_sparse_moe.gate.weight", True),
+    ("b_router", "block_sparse_moe.gate.e_score_correction_bias", False),
+    ("shared_gate", "block_sparse_moe.shared_experts.gate_proj.weight", True),
+    ("shared_up", "block_sparse_moe.shared_experts.up_proj.weight", True),
+    ("shared_down", "block_sparse_moe.shared_experts.down_proj.weight", True),
+)
+
+
+def _solar2_params_from_hf(sd: HFState, cfg: ModelConfig) -> Dict[str, Any]:
+    """A stack a kind of layer, each in the order its layers run, both
+    with the norms and the expert layer. The linear layers' three
+    projections and three convolutions are laid side by side (``w_qkv``,
+    ``conv_w``: ``ops/kda.py``). Of the checkpoint's experts the tree takes
+    those HELD, as ``nemotron_h``'s loader does."""
+    ids = cfg.layer_ids
+    n_held, first = cfg.moe.held
+
+    def get(i, name, transpose=False):
+        m = np.asarray(sd[f"model.layers.{i}.{name}"])
+        return m.T if transpose else m
+
+    def stack(kind, table):
+        return {ours: np.stack([get(i, theirs, t) for i in ids[kind]])
+                for ours, theirs, t in table
+                if ours not in ("wg",) or cfg.attn_gate}
+
+    def common(kind):
+        mlp = stack(kind, [
+            row for row in _SOLAR2_MOE
+            if not row[0].startswith("shared") or cfg.moe.n_shared_experts])
+        for ours, theirs in _JOYAI_EXPERT.items():
+            def expert(i, j):
+                p = f"model.layers.{i}.block_sparse_moe.experts."
+                whole = f"{p}{first + j}.{theirs}.weight"
+                return np.asarray(
+                    sd[whole if whole in sd else f"{p}{j}.{theirs}.weight"]).T
+
+            mlp[ours] = np.stack([
+                np.stack([expert(i, j) for j in range(n_held)])
+                for i in ids[kind]])
+        return {
+            **{ours: {"weight": np.stack(
+                [get(i, theirs + ".weight") for i in ids[kind]])}
+               for ours, theirs in _SOLAR2_NORMS},
+            "mlp": mlp}
+
+    kda = stack("kda", _SOLAR2_KDA)
+    kda["w_qkv"] = np.stack([
+        np.concatenate(
+            [get(i, f"self_attn.{x}_proj.weight", True) for x in _SOLAR2_QKV],
+            axis=1)
+        for i in ids["kda"]])
+    kda["conv_w"] = np.stack([
+        np.concatenate(
+            [get(i, f"self_attn.{x}_conv1d.weight")[:, 0, :].T
+             for x in _SOLAR2_QKV], axis=1)
+        for i in ids["kda"]])
+    params: Dict[str, Any] = {
+        "embed": {"weight": np.asarray(sd["model.embed_tokens.weight"])},
+        "layers": {**common("attn"), "attn": stack("attn", _SOLAR2_ATTN)},
+        "kda_layers": {**common("kda"), "kda": kda},
+        "final_ln": {"weight": np.asarray(sd["model.norm.weight"])},
+    }
+    if not cfg.tied_embedding:
+        params["head"] = {"weight": np.asarray(sd["lm_head.weight"]).T}
+    return params
+
+
+def _solar2_params_to_hf(params: Dict[str, Any], cfg: ModelConfig) -> HFState:
+    """The experts are written under their place among ALL the router
+    scores (``experts.{held_offset + j}``)."""
+    sd: HFState = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["weight"]),
+        "model.norm.weight": np.asarray(params["final_ln"]["weight"]),
+    }
+    if not cfg.tied_embedding:
+        sd["lm_head.weight"] = np.asarray(params["head"]["weight"]).T
+    first = cfg.moe.held[1]
+    C = cfg.kda.d_inner
+
+    def put(p, table, tree, at):
+        for ours, theirs, t in table:
+            if ours in tree:
+                w = np.asarray(tree[ours][at])
+                sd[p + theirs] = w.T if t else w
+
+    for kind, name in (("attn", "layers"), ("kda", "kda_layers")):
+        lp = params[name]
+        for at, i in enumerate(cfg.layer_ids[kind]):
+            p = f"model.layers.{i}."
+            for ours, theirs in _SOLAR2_NORMS:
+                sd[p + theirs + ".weight"] = np.asarray(lp[ours]["weight"][at])
+            if kind == "attn":
+                put(p, _SOLAR2_ATTN, lp["attn"], at)
+            else:
+                x = lp["kda"]
+                put(p, _SOLAR2_KDA, x, at)
+                for n, which in enumerate(_SOLAR2_QKV):
+                    cut = slice(n * C, (n + 1) * C)
+                    sd[p + f"self_attn.{which}_proj.weight"] = np.asarray(
+                        x["w_qkv"][at])[:, cut].T
+                    sd[p + f"self_attn.{which}_conv1d.weight"] = (
+                        np.ascontiguousarray(
+                            np.asarray(x["conv_w"][at])[:, cut].T[:, None, :]))
+            m = lp["mlp"]
+            put(p, _SOLAR2_MOE, m, at)
+            for ours, theirs in _JOYAI_EXPERT.items():
+                for j in range(m[ours].shape[1]):
+                    sd[p + "block_sparse_moe.experts."
+                       f"{first + j}.{theirs}.weight"] = np.asarray(
+                           m[ours][at, j]).T
+    return sd
+
+
+register_hf_family(
+    HFFamily(
+        name="solar_open2",
+        hf_model_type="solar_open2",
+        config_from_hf=_solar2_config_from_hf,
+        config_to_hf=_solar2_config_to_hf,
+        params_from_hf=_solar2_params_from_hf,
+        params_to_hf=_solar2_params_to_hf,
     )
 )
 

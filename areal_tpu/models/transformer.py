@@ -29,10 +29,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import attention as attn_ops
 from areal_tpu.ops import cca as cca_ops
+from areal_tpu.ops import kda as kda_ops
 from areal_tpu.ops import norms
 from areal_tpu.ops import ssm as ssm_ops
 from areal_tpu.ops.activations import ACT2FN
@@ -211,13 +213,14 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         # carried, never read by a forward (``ModelConfig.exit_gate``)
         params["exit_gate"] = {
             "weight": w((E, 1)), "bias": jnp.zeros((1,), dtype)}
-    def block(n, name, mixer):
-        # a layer of another mixer: its own norms and the dense MLP
+    def block(n, name, mixer, experts=False):
+        # a layer of another mixer: its own norms and the dense MLP (or,
+        # ``experts``, the model's expert layer)
         return {
             "ln1": ln(has_ln_bias, n),
             name: mixer,
             "ln2": ln(has_ln_bias, n),
-            "mlp": {
+            "mlp": moe_mlp(n) if experts else {
                 "w_gate": w((n, E, F)),
                 "w_up": w((n, E, F)),
                 "w_down": w((n, F, E)),
@@ -278,6 +281,32 @@ def init_params(cfg: ModelConfig, rng: jax.Array, dtype=jnp.float32) -> Params:
         params["ssm_layers"] = (
             {"ln1": ln(has_ln_bias, Ls), "ssm": mixer} if cfg.one_branch
             else block(Ls, "ssm", mixer))
+    if cfg.kda is not None:
+        # the delta-rule layers (``ops/kda.py``), a stack of their own;
+        # ``A_log`` and ``dt_bias`` in the published initialisation's
+        # ranges, as the state-space layers' above
+        d, Lk = cfg.kda, cfg.n_kda_layers
+        C_, R_ = d.d_inner, d.head_dim
+        dt0 = jnp.exp(jax.random.uniform(
+            next(rngs), (Lk, C_), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        params["kda_layers"] = block(Lk, "kda", {
+            # q, k and v side by side, and ONE convolution over the three
+            "w_qkv": w((Lk, E, d.conv_dim)),
+            "conv_w": w((Lk, d.d_conv, d.conv_dim)),
+            # the decay a key channel, through a rank (f), and beta a head
+            "w_fa": w((Lk, E, R_)),
+            "w_fb": w((Lk, R_, C_)),
+            "dt_bias": (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype),
+            "A_log": jnp.log(jax.random.uniform(
+                next(rngs), (Lk, d.n_heads), jnp.float32, 1.0, 16.0)
+            ).astype(dtype),
+            "w_beta": w((Lk, E, d.n_heads)),
+            # the output gate, through a rank (g), and the head norm
+            "w_ga": w((Lk, E, R_)),
+            "w_gb": w((Lk, R_, C_)),
+            "o_norm": jnp.ones((Lk, d.head_dim), dtype),
+            "wo": w((Lk, C_, E)),
+        }, experts=cfg.mlp_type == "moe")
     if cfg.diff_attn:
         attn.update(diff_params(L))
     n_gmu, n_cross = cfg.n_mixers("gmu"), cfg.n_mixers("cross")
@@ -564,6 +593,21 @@ def param_logical_axes(cfg: ModelConfig) -> Params:
         axes["ssm_layers"] = (
             {"ln1": ln(), "ssm": mixer} if cfg.one_branch
             else {"ln1": ln(), "ssm": mixer, "ln2": ln(), "mlp": dense_mlp})
+    if cfg.kda is not None:
+        # no tensor-parallel split of the mixer either (its heads, the
+        # convolution's channels and the state would all have to follow
+        # one; the engine refuses a mesh for this family)
+        mixer = {
+            "w_qkv": ("layer", "embed", None), "conv_w": ("layer", None, None),
+            "w_fa": ("layer", "embed", None), "w_fb": ("layer", None, None),
+            "dt_bias": ("layer", None), "A_log": ("layer", None),
+            "w_beta": ("layer", "embed", None),
+            "w_ga": ("layer", "embed", None), "w_gb": ("layer", None, None),
+            "o_norm": ("layer", None), "wo": ("layer", None, "embed"),
+        }
+        axes["kda_layers"] = {
+            "ln1": ln(), "kda": mixer, "ln2": ln(),
+            "mlp": mlp if cfg.mlp_type == "moe" else dense_mlp}
     if cfg.n_mixers("gmu"):
         axes["gmu_layers"] = {
             "ln1": ln(), "ln2": ln(), "mlp": dense_mlp,
@@ -977,15 +1021,24 @@ def _hold_routed(params: Params) -> Tuple[Params, Params]:
     into an einsum XLA fuses it, which is why only a forward that runs the
     kernel asks for this.) Under a plan of one-branch blocks the expert
     stack is ``moe_layers`` and a block's index in it comes with its slice
-    (:func:`_scan_plan`); experts of two matrices have no ``w_gate``."""
+    (:func:`_scan_plan`); experts of two matrices have no ``w_gate``. A
+    model whose delta-rule layers hold experts too (``kda_layers``, a
+    second expert stack beside ``layers``') has THEIR stacks under
+    ``stacks["kda"]``, and such a layer's index in them is its index among
+    the delta-rule layers."""
+    def take(tree):
+        layers = params[tree]
+        mlp = layers["mlp"]
+        rest = {k: v for k, v in mlp.items() if k not in _ROUTED}
+        return ({**layers, "mlp": rest},
+                {k: mlp[k] for k in _ROUTED if k in mlp})
+
     tree = "moe_layers" if "moe_layers" in params else "layers"
-    layers = params[tree]
-    mlp = layers["mlp"]
-    rest = {k: v for k, v in mlp.items() if k not in _ROUTED}
-    return (
-        {**params, tree: {**layers, "mlp": rest}},
-        {k: mlp[k] for k in _ROUTED if k in mlp},
-    )
+    held, stacks = take(tree)
+    out = {**params, tree: held}
+    if "router" in params.get("kda_layers", {}).get("mlp", {}):
+        out["kda_layers"], stacks["kda"] = take("kda_layers")
+    return out, stacks
 
 
 def _routed_at(cfg: ModelConfig, routed: Optional[Params], li, j: int):
@@ -1163,7 +1216,7 @@ def _scan_passes(cfg: ModelConfig, layer, carry, params: Params, xs=(),
 
 # the weight stack of each mixer kind (``ModelConfig.stack_plan``)
 _STACKS = {"attn": "layers", "ssm": "ssm_layers", "gmu": "gmu_layers",
-           "cross": "cross_layers", "moe": "moe_layers"}
+           "cross": "cross_layers", "moe": "moe_layers", "kda": "kda_layers"}
 
 
 def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
@@ -1212,7 +1265,7 @@ def _scan_plan(cfg: ModelConfig, fns, carry, params: Params, xs=None,
     if writers_only:
         last = max(
             i for i, (_, period) in enumerate(plan)
-            if any(pos.mixer in ("ssm", "attn") for pos in period))
+            if any(pos.mixer in ("ssm", "kda", "attn") for pos in period))
         plan = plan[: last + 1]
     base = dict.fromkeys(_STACKS, 0)    # layers of each kind so far
     outs = {kind: [] for kind in _STACKS}
@@ -1267,25 +1320,59 @@ def _run_stack(cfg: ModelConfig, layer, carry, params: Params, xs=(),
     or :func:`_scan_plan` for a model with a stack plan (``fns``,
     ``plan_xs``, ``writers_only``: its arguments). Returns ``(carry, ys,
     ys_ssm, ys_moe)``: the attention layers' stacked results, the
-    state-space layers' and the expert blocks' (None for a model without
-    them)."""
+    recurrent layers' (state-space or delta-rule) and the expert blocks'
+    (None for a model without them)."""
     if cfg.plan is None:
         carry, ys = _scan_passes(cfg, layer, carry, params, xs, unroll)
         return carry, ys, None, None
     carry, ys = _scan_plan(
         cfg, fns, carry, params, plan_xs, unroll, writers_only)
-    return carry, ys["attn"], ys["ssm"], ys.get("moe")
+    return carry, ys["attn"], ys[cfg.recurrent], ys.get("moe")
 
 
-def _ssm_block(cfg: ModelConfig, lp, x, mixer):
-    """One state-space layer: ``x + mixer(norm(x))``, then the MLP as in
-    an attention layer (:func:`_ffn`). ``mixer(p, h)`` returns ``(out, state)``, and where
-    the model has gated memory units a third, the memory (``ops/ssm.py``).
-    Returns ``(x, state, memory or None)``."""
+def _ssm_block(cfg: ModelConfig, lp, x, mixer, routed=None):
+    """One layer of the model's recurrent kind (``cfg.recurrent``:
+    state-space, or delta-rule): ``x + mixer(norm(x))``, then the
+    feed-forward part as in an attention layer (:func:`_ffn`; ``routed``:
+    its argument). ``mixer(p, h)`` returns ``(out, state)``, and where the
+    model has gated memory units a third, the memory (``ops/ssm.py``).
+    Returns ``(x, state, memory or None, (aux, routing))``."""
     h = _norm(cfg, lp["ln1"], x)
-    out, st, *mem = mixer(lp["ssm"], h)
+    out, st, *mem = mixer(lp[cfg.recurrent], h)
     x = _add_branch(cfg, lp, "attn_out_ln", x, out.astype(x.dtype))
-    return _ffn(cfg, lp, x)[0], st, (mem[0] if mem else None)
+    x, aux, routing, _ = _ffn(cfg, lp, x, h, routed)
+    return x, st, (mem[0] if mem else None), (aux, routing)
+
+
+def _rec_chunk(cfg: ModelConfig, positions, state=None, n_valid=None,
+               memory: bool = False):
+    """``mixer(p, h)`` of the model's recurrent kind over many tokens a
+    row (``ops/ssm.py`` / ``ops/kda.py``: ``mixer_chunk``)."""
+    if cfg.kda is not None:
+        return lambda p, h: kda_ops.mixer_chunk(
+            cfg, p, h, positions, state, n_valid=n_valid)
+    return lambda p, h: ssm_ops.mixer_chunk(
+        cfg, p, h, positions, state, n_valid=n_valid, memory=memory)
+
+
+def _rec_step(cfg: ModelConfig, state, active, update=None,
+              memory: bool = False):
+    """``mixer(p, h)`` of the model's recurrent kind over ONE token a row
+    (``mixer_step``)."""
+    if cfg.kda is not None:
+        return lambda p, h: kda_ops.mixer_step(
+            cfg, p, h, state, active, update=update)
+    return lambda p, h: ssm_ops.mixer_step(
+        cfg, p, h, state, active, update=update, memory=memory)
+
+
+def _in_layer_order(cfg: ModelConfig, attn_part, rec_part):
+    """What the attention layers and the recurrent layers each stacked
+    over THEIR layers (a router's choices, its loss), as one array over
+    the model's layers in the order they run."""
+    ids = cfg.layer_ids
+    order = np.argsort(ids["attn"] + ids[cfg.recurrent])
+    return jnp.concatenate([attn_part, rec_part])[order]
 
 
 def _gmu_block(cfg: ModelConfig, lp, x, memory):
@@ -1327,7 +1414,8 @@ def _plan_fns(cfg: ModelConfig, attn, ssm_layer, remat: bool = False,
         gmu_layer = jax.checkpoint(gmu_layer, prevent_cse=False)
         moe_layer = jax.checkpoint(moe_layer, prevent_cse=False)
     return {"attn": attn, "cross": attn, "moe": lambda pos: moe_layer,
-            "ssm": lambda pos: ssm_layer, "gmu": lambda pos: gmu_layer}
+            "ssm": lambda pos: ssm_layer, "kda": lambda pos: ssm_layer,
+            "gmu": lambda pos: gmu_layer}
 
 
 def _readers(cfg: ModelConfig) -> bool:
@@ -1692,13 +1780,13 @@ def forward_packed(
         # own resets the state and the convolution
         x, *shared = carry if shared0 else (carry,)
         lp = _cast(cfg, lp)
-        x, _, mem = _ssm_block(
+        x, _, mem, (aux, chosen) = _ssm_block(
             cfg, lp, x[None],
-            lambda p, h: ssm_ops.mixer_chunk(
-                cfg, p, h, positions[None], memory=bool(shared0)))
+            _rec_chunk(cfg, positions[None], memory=bool(shared0)))
         if shared0:
             return (x[0], mem[0], *shared[1:]), None
-        return x[0], None
+        # (a delta-rule layer holds a router where the model has one)
+        return x[0], None if chosen is None else (aux, chosen[0])
 
     if policy != "none":
         ssm_layer = jax.checkpoint(ssm_layer, prevent_cse=False)
@@ -1708,7 +1796,7 @@ def forward_packed(
     def attn_fn(pos):
         return make_layer((pos.window, cfg.apply_rotary), pos)
 
-    x, (auxes, routing), _, moe_ys = _run_stack(
+    x, (auxes, routing), rec_ys, moe_ys = _run_stack(
         cfg, layers, (x, *shared0) if shared0 else (
             x if r0 is None else (x, r0)), params,
         unroll=cfg.layer_scan_unroll or 1,
@@ -1717,6 +1805,9 @@ def forward_packed(
     )
     if moe_ys is not None:
         auxes, routing = moe_ys     # the expert blocks': the plan's router
+    elif rec_ys is not None:        # a router in the recurrent layers too
+        auxes = jnp.concatenate([auxes, rec_ys[0]])
+        routing = _in_layer_order(cfg, routing, rec_ys[1])
     if r0 is not None or shared0:
         x, *_ = x
     stack_out = x
@@ -1893,12 +1984,36 @@ class CCAState:
             (cfg.n_layers, batch, cfg.cca_carry_dim), jnp.dtype(cfg.dtype)))
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class DeltaState:
+    """What the delta-rule layers (``cfg.kda``) keep of a ROW in place of
+    keys and values: ``s [Lk, B, H, Dk, Dv]`` a matrix a head of every such
+    layer, float32 (``ops/kda.py:STATE_DTYPE``), and ``conv [Lk, B, (d_conv -
+    1) x 3 H D]`` the convolutions' last inputs in the serving dtype, flat
+    (``ops/kda.py``). Allocated by row and shared through the prefix cache
+    only as a copy, as :class:`SSMState` is."""
+
+    s: jnp.ndarray
+    conv: jnp.ndarray
+
+    @classmethod
+    def empty(cls, cfg: ModelConfig, batch: int) -> "DeltaState":
+        s, conv = kda_ops.state_shapes(cfg, batch)
+        return cls(
+            s=jnp.zeros(s, kda_ops.STATE_DTYPE),
+            conv=jnp.zeros(conv, jnp.dtype(cfg.dtype)),
+        )
+
+
 def row_state_empty(cfg: ModelConfig, batch: int):
     """The per-row state of ``batch`` rows, of the model's kind
-    (:class:`SSMState`, :class:`CCAState`), or None for a model whose rows
-    keep keys and values only."""
+    (:class:`SSMState`, :class:`DeltaState`, :class:`CCAState`), or None
+    for a model whose rows keep keys and values only."""
     if cfg.ssm is not None:
         return SSMState.empty(cfg, batch)
+    if cfg.kda is not None:
+        return DeltaState.empty(cfg, batch)
     if cfg.cca is not None:
         return CCAState.empty(cfg, batch)
     return None
@@ -1909,10 +2024,41 @@ def row_state_bytes(cfg: ModelConfig) -> int:
     none)."""
     if cfg.ssm is not None:
         return ssm_ops.state_bytes_per_slot(cfg)
+    if cfg.kda is not None:
+        return kda_ops.state_bytes_per_slot(cfg)
     if cfg.cca is not None:
         return (cfg.n_layers * cfg.cca_carry_dim
                 * jnp.dtype(cfg.dtype).itemsize)
     return 0
+
+
+def row_state_layers(cfg: ModelConfig) -> int:
+    """The layers that keep per-row state (0: the model keeps none)."""
+    if cfg.recurrent is not None:
+        return cfg.n_mixers(cfg.recurrent)
+    return cfg.n_layers if cfg.cca is not None else 0
+
+
+def row_state_kind(cfg: ModelConfig) -> Optional[str]:
+    """What the per-row state is, for a message (None: the model keeps
+    none)."""
+    if cfg.ssm is not None:
+        return "state-space layers"
+    if cfg.kda is not None:
+        return "delta-rule layers"
+    return "attention in a convolved latent" if cfg.cca is not None else None
+
+
+def recurrent_heads(cfg: ModelConfig, state):
+    """One row's recurrent state ``[layers, ...]`` (the first array of
+    :class:`SSMState` or :class:`DeltaState`, cut at a row) head by head
+    as the equations write it: ``[Ls, H, P, N]`` turned from the layout
+    the slots keep (``ops/ssm.py``), or ``[Lk, H, Dk, Dv]`` as it is."""
+    if cfg.kda is not None:
+        return state
+    c = cfg.ssm
+    state = state.transpose(0, 1, 2, 4, 3)
+    return state.reshape(len(state), c.n_heads, c.head_dim, c.d_state)
 
 
 def prefill(
@@ -2019,11 +2165,10 @@ def prefill(
 
     def ssm_layer(carry, lp):
         x, *shared = carry if shared0 else (carry,)
-        x, st, mem = _ssm_block(
+        x, st, mem, _ = _ssm_block(
             cfg, _cast(cfg, lp), x,
-            lambda p, h: ssm_ops.mixer_chunk(
-                cfg, p, h, positions, n_valid=prompt_lens,
-                memory=bool(shared0)))
+            _rec_chunk(cfg, positions, n_valid=prompt_lens,
+                       memory=bool(shared0)))
         return ((x, mem, *shared[1:]) if shared0 else x), st
 
     def attn_fn(pos):
@@ -2134,10 +2279,9 @@ def decode_step(
     def ssm_layer(carry, inputs):
         x, *shared = carry if shared0 else (carry,)
         lp, s, cv = inputs
-        x, st, mem = _ssm_block(
+        x, st, mem, _ = _ssm_block(
             cfg, _cast(cfg, lp), x,
-            lambda p, h: ssm_ops.mixer_step(
-                cfg, p, h, (s, cv), active, memory=bool(shared0)))
+            _rec_step(cfg, (s, cv), active, memory=bool(shared0)))
         return ((x, mem, *shared[1:]) if shared0 else x), st
 
     def attn_fn(pos):
@@ -2150,9 +2294,9 @@ def decode_step(
         xs=(cache.k, cache.v) + (
             (cache.ssm.carry,) if cfg.cca is not None else ()),
         fns=_plan_fns(cfg, attn_fn, ssm_layer),
-        plan_xs=None if cfg.ssm is None else {
+        plan_xs=None if cfg.recurrent is None else {
             "attn": (cache.k, cache.v),
-            "ssm": (cache.ssm.ssm, cache.ssm.conv)},
+            cfg.recurrent: tuple(jax.tree.leaves(cache.ssm))},
     )
     if r0 is not None or shared0:
         x, *_ = x
@@ -2520,12 +2664,15 @@ def _extend_layers(
         # gather of ONE row is a slice to the chip's compiler, and the
         # layout the scan's matmuls ask of that slice it then gave to the
         # whole state, a copy of all 36 layers of it (PERF.md §6 PR 42)
-        rows = jax.lax.optimization_barrier(ssm.ssm[si, slots])
-        x, st, mem = _ssm_block(
+        state_all, conv_all = jax.tree.leaves(ssm)
+        rows = jax.lax.optimization_barrier(state_all[si, slots])
+        x, st, mem, _ = _ssm_block(
             cfg, _cast(cfg, lp), x,
-            lambda p, h: ssm_ops.mixer_chunk(
-                cfg, p, h, positions, (rows, ssm.conv[si, slots]),
-                n_valid=n_new, memory=bool(shared0)))
+            _rec_chunk(cfg, positions, (rows, conv_all[si, slots]),
+                       n_valid=n_new, memory=bool(shared0)),
+            # (a delta-rule layer's experts: its index in THEIR stacks)
+            routed=None if routed is None or cfg.kda is None else (
+                routed["kda"], si))
         if shared0:
             shared = (mem, *shared[1:])
         return (x, li, si + 1, *shared), st
@@ -2597,7 +2744,8 @@ def _extend_layers(
 
     _, (ks, vs, cc), ssm_rows, _ = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero, *rest0) if cfg.ssm is None else (x, zero, zero, *shared0),
+        (x, zero, *rest0) if cfg.recurrent is None else (
+            x, zero, zero, *shared0),
         params,
         fns=_plan_fns(cfg, attn_fn, ssm_layer, routed=routed),
         writers_only=True,
@@ -2785,7 +2933,8 @@ def decode_step_paged(
     if paged_ops.shared_prefix_applies(
         use_pallas, width, n_kv, page, cache.pages.dtype,
         full_kinds=len(full), quantized=cache.scales is not None,
-        latent=cfg.mla is not None, slot_order=cfg.ssm is not None, mesh=mesh,
+        latent=cfg.mla is not None, slot_order=cfg.recurrent is not None,
+        mesh=mesh,
     ):
         shared_table = _kind_table(table, full[0])
         plan, *own = paged_ops.shared_prefix_step(
@@ -2795,7 +2944,7 @@ def decode_step_paged(
         prefix = paged_ops.prefix_pass(
             plan, shared_table, page, order, inverse)
         own_o = [a[order] for a in own]
-    elif cfg.ssm is None:
+    elif cfg.recurrent is None:
         order, inverse = _length_order(lens)
     else:
         order = inverse = jnp.arange(lens.shape[0])
@@ -2815,32 +2964,35 @@ def decode_step_paged(
 
     def ssm_layer(carry, lp):
         x, li, si, st, *shared = carry
-        conv_l = jax.lax.dynamic_index_in_dim(st.conv, si, 0, keepdims=False)
+        state_all, conv_all = jax.tree.leaves(st)
+        conv_l = jax.lax.dynamic_index_in_dim(conv_all, si, 0, keepdims=False)
         if ssm_update is None:
             ssm_l = jax.lax.dynamic_index_in_dim(
-                st.ssm, si, 0, keepdims=False)
+                state_all, si, 0, keepdims=False)
             update = None
         else:
             # the kernel takes the state of all layers and the layer's
             # index, and gives the whole back, updated in place
-            ssm_l = st.ssm
+            ssm_l = state_all
 
             def update(whole, *args, **kw):
                 return ssm_update(whole, si, *args, **kw)
 
-        x, (ssm_l, conv_l), mem = _ssm_block(
+        x, (ssm_l, conv_l), mem, (_, chosen) = _ssm_block(
             cfg, _cast(cfg, lp), x,
-            lambda p, h: ssm_ops.mixer_step(
-                cfg, p, h, (ssm_l, conv_l), active, update=update,
-                memory=bool(shared0)))
+            _rec_step(cfg, (ssm_l, conv_l), active, update=update,
+                      memory=bool(shared0)),
+            routed=None if routed is None or cfg.kda is None else (
+                routed["kda"], si))
         if ssm_update is None:
-            ssm_l = jax.lax.dynamic_update_index_in_dim(st.ssm, ssm_l, si, 0)
-        st = SSMState(
-            ssm=ssm_l,
-            conv=jax.lax.dynamic_update_index_in_dim(st.conv, conv_l, si, 0))
+            ssm_l = jax.lax.dynamic_update_index_in_dim(
+                state_all, ssm_l, si, 0)
+        st = type(st)(
+            ssm_l, jax.lax.dynamic_update_index_in_dim(conv_all, conv_l, si, 0))
         if shared0:
             shared = (mem, *shared[1:])
-        return (x, li, si + 1, st, *shared), (None, None, None)
+        return (x, li, si + 1, st, *shared), (
+            None, None, chosen if with_routing else None)
 
     def layer(j, carry, lp, pos=None):
         # ``li``: the layer, or (layer kinds) the period: the slice of the
@@ -2933,9 +3085,9 @@ def decode_step_paged(
     def attn_fn(pos):
         return functools.partial(layer, 0, pos=pos)
 
-    (x, *rest), (ks, vs, routing, cc), _, moe_routing = _run_stack(
+    (x, *rest), (ks, vs, routing, cc), rec_ys, moe_routing = _run_stack(
         cfg, [functools.partial(layer, j) for j in range(len(kinds))],
-        (x, zero, *rest0) if cfg.ssm is None else (
+        (x, zero, *rest0) if cfg.recurrent is None else (
             x, zero, zero, ssm, *shared0),
         params, xs=() if cfg.cca is None else (ssm.carry[:, order],),
         fns=_plan_fns(
@@ -2944,6 +3096,8 @@ def decode_step_paged(
     )
     if moe_routing is not None:
         routing = moe_routing       # the expert blocks': the plan's router
+    elif rec_ys is not None and rec_ys[2] is not None:
+        routing = _in_layer_order(cfg, routing, rec_ys[2])
     x, ks = x[inverse], ks[:, inverse]
     cache = _write_chunk_kv(
         cache, ks[:, :, None],
@@ -2956,7 +3110,7 @@ def decode_step_paged(
         extra = (routing[:, inverse],)
     else:
         extra = ()
-    if cfg.ssm is not None:
+    if cfg.recurrent is not None:
         extra += (rest[2],)
     if cc is not None:
         extra += (CCAState(cc[:, inverse]),)

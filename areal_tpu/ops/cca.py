@@ -22,7 +22,7 @@ CARRY ``[B, W]`` (``ModelConfig.cca_carry_dim``: the first convolution's
 last ``time0 - 1`` inputs, the second's last ``time1 - 1``, the shifted
 half of the last token's value projection; flat, in the serving dtype)
 and hands back the carry after its first ``n_valid`` tokens. The look-back
-itself is ``ops/ssm.py:conv_history`` and its two companions, the
+itself is ``ops/conv.py:conv_history`` and its two companions, the
 state-space mixer's.
 """
 
@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from areal_tpu.models.config import ModelConfig
-from areal_tpu.ops.ssm import conv_history, conv_next_state, conv_reads
+from areal_tpu.ops.conv import conv_history, conv_next_state, conv_reads
 
 
 def _unit(x):

@@ -272,9 +272,9 @@ def moe_grouped_applies(
 
 def _routed_dtype(cfg, params):
     """What the routed experts' stacks are stored in: ``layers``', or under
-    a stack plan the expert blocks' own (``moe_layers``); experts of two
-    matrices have no ``w_gate``."""
-    mlp = params["layers" if cfg.stack_plan is None else "moe_layers"]["mlp"]
+    a plan of one-branch blocks the expert blocks' own (``moe_layers``);
+    experts of two matrices have no ``w_gate``."""
+    mlp = params["moe_layers" if cfg.one_branch else "layers"]["mlp"]
     return mlp["w_gate" if "w_gate" in mlp else "w_up"].dtype
 
 

@@ -29,8 +29,8 @@ Two forms of one function:
   it is the trainer's backward pass. Any chunk length gives the same
   function.
 - :func:`mixer_step`: one token a row, the decode step's update; its
-  convolution is :func:`conv_step`, the many-token form's
-  :func:`conv_chunk`.
+  convolution is ``conv_step``, the many-token form's ``conv_chunk``
+  (``ops/conv.py``).
 
 The recurrent state is kept TRANSPOSED and in lane tiles, ``[G, K, N,
 128]`` a row a layer (``G`` groups of ``R`` heads of ``P`` channels, a state
@@ -71,6 +71,8 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.ops import norms
+# the mixers' convolution, shared with ``ops/kda.py`` and ``ops/cca.py``
+from areal_tpu.ops.conv import conv_chunk, conv_step  # noqa: F401
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -98,8 +100,8 @@ def state_shapes(cfg: ModelConfig, batch: int):
     axis of their own the chip's compiler, gathering a few rows, re-laid
     the whole array out with that axis on the lanes (3 padded to 128:
     2.99 GB at the published sizes; PERF.md §6 PR 41). The decode step's
-    reader, :func:`conv_step`, depends on it: a tap is a static slice of
-    whole lane tiles along the minor axis (``channels`` is 34, 40 or 80
+    reader, ``ops/conv.py:conv_step``, depends on it: a tap is a static slice
+    of whole lane tiles along the minor axis (``channels`` is 34, 40 or 80
     tiles at the published sizes)."""
     s = cfg.ssm
     k, lanes = _lane_tiles(s.n_heads // s.n_groups * s.head_dim)
@@ -175,94 +177,6 @@ def _split_xbc(cfg: ModelConfig, xbc):
         *lead, G, s.d_state)
     c = xbc[..., s.d_inner + G * s.d_state :].reshape(*lead, G, s.d_state)
     return x, b, c
-
-
-def conv_history(x, state):
-    """``x [B, T, C]`` behind what its rows continue: ``state [B, (K - 1)
-    x C]``, a row's last ``K - 1`` inputs of a causal convolution of ``K``
-    taps, flat (:func:`state_shapes`). ``[B, K - 1 + T, C]``. With
-    :func:`conv_reads` and :func:`conv_next_state`, what the state-space
-    mixer's convolution over many tokens a row (:func:`conv_chunk`; its
-    decode step is :func:`conv_step`, which builds no such array) and the
-    attention latent's (``ops/cca.py``) share."""
-    B, _, C = x.shape
-    return jnp.concatenate(
-        [state.astype(x.dtype).reshape(B, state.shape[-1] // C, C), x], axis=1)
-
-
-def conv_reads(full, positions):
-    """What each tap reads: for ``d = 0 .. K - 1`` the input ``d`` tokens
-    back ``[B, T, C]``, zero where that would reach behind position 0 of
-    the token's own document (``positions [B, T]``, restarting a
-    document). ``full``: :func:`conv_history`."""
-    T = positions.shape[1]
-    K = full.shape[1] - T + 1
-    for d in range(K):
-        tap = full[:, K - 1 - d : K - 1 - d + T]
-        ok = (positions >= d)[..., None]
-        yield jnp.where(ok, tap, 0)
-
-
-def conv_next_state(full, n_valid, state):
-    """The state after each row's first ``n_valid [B]`` of many tokens (0:
-    as it was), in ``state``'s shape and dtype: a slice of ``full`` at a
-    start a row."""
-    K = state.shape[-1] // full.shape[-1] + 1
-    new_state = jax.vmap(
-        lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, K - 1, axis=0)
-    )(full, n_valid)
-    return new_state.astype(state.dtype).reshape(state.shape)
-
-
-def _conv_out(p, taps, dtype):
-    """``silu(sum_d tap_d * w[K - 1 - d] + b)`` in float32, as ``dtype``:
-    ``taps`` from the token itself (``d = 0``) back, added in that order."""
-    w = p["conv_w"]                                       # [K, C]
-    K = w.shape[0]
-    with jax.named_scope("ssm_conv"):
-        out = 0.0
-        for d, tap in enumerate(taps):
-            # the tap ``d`` tokens back: weight K - 1 - d
-            out = out + tap.astype(jnp.float32) * w[
-                K - 1 - d].astype(jnp.float32)
-        if "conv_b" in p:
-            out = out + p["conv_b"].astype(jnp.float32)
-        return jax.nn.silu(out).astype(dtype)
-
-
-def conv_chunk(p, xbc, positions, conv_state, n_valid):
-    """The causal depthwise convolution over ``xbc [B, T, C]``, many tokens
-    a row, whose rows continue ``conv_state [B, (K - 1) x C]``
-    (:func:`conv_history`). Returns the activated output and the state
-    after each row's first ``n_valid [B]`` tokens."""
-    full = conv_history(xbc, conv_state)
-    out = _conv_out(p, conv_reads(full, positions), xbc.dtype)
-    return out, conv_next_state(full, n_valid, conv_state)
-
-
-def conv_step(p, x, state, active):
-    """The convolution over ONE token a row, the decode step's: ``x [B,
-    C]``, ``state [B, (K - 1) x C]``, ``active [B]`` (false: the row's
-    state stays). Bit for bit :func:`conv_chunk` at ``T = 1`` behind ``K -
-    1`` or more tokens of the document (no tap is masked), in another
-    form: the taps are static slices of the FLAT state along its minor
-    axis and the next state is one elementwise pass, which the chip's
-    compiler fuses into the in-place update of the stacked state. Built
-    as ``[B, K, C]`` with a start a row, the same values cost two re-laid
-    copies, a padded ``[B, 4, C]`` and a gather a layer a token (PERF.md
-    §6 PR 54)."""
-    C = x.shape[-1]
-    K = state.shape[-1] // C + 1
-    taps = [x] + [
-        state[:, j * C : (j + 1) * C].astype(x.dtype)
-        for j in reversed(range(K - 1))]
-    # each piece chosen a row BEFORE the two are laid end to end: one select
-    # over the whole shifted row measured a fifth slower on the chip
-    moves = active[:, None]
-    state = jnp.concatenate([
-        jnp.where(moves, state[:, C:], state[:, :-C]),
-        jnp.where(moves, x.astype(state.dtype), state[:, -C:])], axis=-1)
-    return _conv_out(p, taps, x.dtype), state
 
 
 def scan_chunked(x, dt, a_head, b, c, reset, init, chunk: int):

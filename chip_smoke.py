@@ -195,6 +195,10 @@ FUSED_SAMPLE_KERNEL = "fused_sample"
 # (ops/pallas/moe_grouped.py); the smoke's model has no router, so the
 # kernel runs alone, beside the einsums (``child_moegrouped``)
 MOE_GROUPED_KERNEL = "moe_grouped"
+# the decode step's delta-rule update of the per-slot matrix state
+# (ops/pallas/kda_decode.py); no model of the smoke has such a layer, so the
+# kernel runs alone, beside ``ops/kda.py:step_update`` (``child_kdadecode``)
+KDA_DECODE_KERNEL = "kda_decode"
 # the decode step's update of the per-slot recurrent state
 # (ops/pallas/ssm_decode.py); the smoke's own model has no state-space
 # layer, so a helper child drives a two-layer model of layer KINDS (one
@@ -602,6 +606,16 @@ def phase_serve(sz, args):
     require(grouped["max_abs_diff_vs_einsums"] <= grouped["tolerance"]
             and (args.rehearse or grouped["kernel"] == MOE_GROUPED_KERNEL),
             f"moe_grouped disagrees with the einsums: {grouped}")
+    # ... and the delta-rule update alone: the kernel over one layer of a
+    # stacked state, in place, against the plain step on that layer's slice
+    delta, _ = helper(d, "kdadecode", {
+        "seed": args.seed, "rehearse": args.rehearse,
+    })
+    require(max(delta["max_abs_diff_out"], delta["max_abs_diff_state"])
+            <= delta["tolerance"] and delta["other_layers_untouched"]
+            and delta["inactive_row_untouched"]
+            and (args.rehearse or delta["kernel"] == KDA_DECODE_KERNEL),
+            f"kda_decode disagrees with the plain step: {delta}")
     # ... and a model of layer KINDS through the engine: one state-space
     # layer beside one attention layer (the served model above has one
     # kind), its log-probs against the token-by-token reference, its decode
@@ -1441,6 +1455,66 @@ def child_moegrouped(arg):
     })
 
 
+def child_kdadecode(arg):
+    """The delta-rule decode kernel alone (compiled on the chip, interpreted
+    off it) beside ``ops/kda.py:step_update``, at Solar-Open2's linear
+    layers (64 heads of 128 x 128 float32; 8 under ``--rehearse``): the
+    kernel is handed a 3-layer stacked state, donated, and the index of one
+    layer; the plain step that layer's slice. One row comes as an inactive
+    row does (decay 1, beta 0) and must keep its state bit for bit."""
+    from areal_tpu.base import compile_cache
+
+    compile_cache.configure()
+    import jax
+    import jax.numpy as jnp
+
+    from areal_tpu.ops import kda as kda_ops
+    from areal_tpu.ops.pallas import kda_decode as kd
+
+    L, B, H, D = (3, 3, 8, 128) if arg["rehearse"] else (3, 32, 64, 128)
+    layer = 1
+    ks = jax.random.split(jax.random.key(arg["seed"]), 6)
+    s_all = jax.random.normal(ks[0], (L, B, H, D, D), jnp.float32)
+    q = kda_ops._l2norm(jax.random.normal(ks[1], (B, H, D))) * D ** -0.5
+    k = kda_ops._l2norm(jax.random.normal(ks[2], (B, H, D)))
+    v = jax.random.normal(ks[3], (B, H, D))
+    a = jnp.exp(-jax.nn.softplus(jax.random.normal(ks[4], (B, H, D))))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[5], (B, H)))
+    a, beta = a.at[1].set(1.0), beta.at[1].set(0.0)     # an inactive row
+    want_o, want_s = jax.jit(kda_ops.step_update)(
+        s_all[layer], q, k, v, a, beta)
+    before = jax.device_get(s_all)
+    kernel = jax.jit(kd.kda_decode, donate_argnums=0)
+    args = (jnp.int32(layer), q, k, v, a, beta)
+    names = re.findall(
+        r'kernel_name = "([^"]+)"', kernel.lower(s_all, *args).as_text())
+    got_o, got_s = kernel(s_all, *args)
+    got_s.block_until_ready()
+    t0 = time.time()
+    for _ in range(10):
+        got2_o, got_s2 = kernel(got_s, *args)
+        got_s = got_s2
+    got_s.block_until_ready()
+    ms = round((time.time() - t0) * 100, 3)
+    # (the timing loop ran the update ten more times: compare the first)
+    first_o, first_s = kernel(jnp.asarray(before), *args)
+    after = jax.device_get(first_s)
+    emit({
+        "layers": L, "rows": B, "heads": H, "head_dim": D, "layer": layer,
+        "max_abs_diff_out": float(jnp.abs(first_o - want_o).max()),
+        "max_abs_diff_state": float(abs(after[layer] - want_s).max()),
+        "tolerance": 1e-4,
+        "other_layers_untouched": bool(
+            (after[0] == before[0]).all() and (after[2] == before[2]).all()),
+        "inactive_row_untouched": bool(
+            (after[layer, 1] == before[layer, 1]).all()),
+        "kernel": names[0] if names else None,
+        "ms_a_call": ms,
+        "state_bytes_a_call": 2 * 4 * B * H * D * D,
+        "compiled": jax.devices()[0].platform == "tpu",
+    })
+
+
 def child_statespace(arg):
     """A model of two layer KINDS through the generation engine: one
     state-space layer and one attention layer at granite-4.0-h-micro's
@@ -1819,7 +1893,7 @@ CHILDREN = {
     "tokenizer": child_tokenizer, "kvwrite": child_kvwrite,
     "pageddecode": child_pageddecode, "fusedsample": child_fusedsample,
     "moegrouped": child_moegrouped, "statespace": child_statespace,
-    "yoco": child_yoco,
+    "yoco": child_yoco, "kdadecode": child_kdadecode,
 }
 
 

@@ -78,11 +78,12 @@ def compiled_kernels(monkeypatch, no_persistent_cache):
     """Steer the three kernel modules off interpret mode (on the CPU their
     `_interpret()` says True) for the duration of one test."""
     from areal_tpu.ops.pallas import flash_attention, fused_sample
-    from areal_tpu.ops.pallas import kv_page_write, moe_grouped, ssm_decode
+    from areal_tpu.ops.pallas import kda_decode, kv_page_write
+    from areal_tpu.ops.pallas import moe_grouped, ssm_decode
     from areal_tpu.ops.pallas import paged_attention as pl_paged
 
     for mod in (flash_attention, fused_sample, pl_paged, kv_page_write,
-                moe_grouped, ssm_decode):
+                moe_grouped, ssm_decode, kda_decode):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     # ... and the fused epilogue's dispatch (and the engine's rule) off the
     # CPU they would see: a chunk built with ``fused=True`` ends in the
@@ -1993,3 +1994,152 @@ def test_trinity_admission_wave_holds_the_grouped_kernel(
     _assert_no_slice_of_the_routed_stack(text, cfg)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.0e9
+
+
+# ------------------------------------------------------------------ #
+# Solar-Open2 cut to one period (benchmark/configs/solar-open2-l4-ep8):
+# the decode chunk and an admission wave of the cell at its slots and pool,
+# delta-rule layers beside one attention layer, experts in every block
+# ------------------------------------------------------------------ #
+
+# the cell solar-open2-l4.rollout_out8k: 256 slots, a table of 72 pages
+SOLAR2_CELL = dict(B=256, M=72)
+
+
+def _solar2_traffic():
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "traffic",
+            "grpo16_closed256_out8k_kda.json")) as f:
+        return json.load(f)
+
+
+def _solar2_program(one_chip, program: str = "jit_chunk"):
+    """``(cfg, the jitted program, its arguments as shapes on the chip)``
+    of the configuration under the engine at the cell's 256 slots of 72
+    pages and the traffic file's pool, placeholder weights: the decode
+    chunk, or (``jit_extend``) a wave of 8 x 128 tokens continuing 8
+    slots."""
+    import dataclasses
+    import json
+
+    from areal_tpu.gen.engine import GenerationEngine
+    from areal_tpu.models import transformer as tfm
+    from areal_tpu.ops.pallas import kda_decode
+    from benchmark import kda_flops, sut
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark", "configs", "solar-open2-l4-ep8.json")) as f:
+        arch = json.load(f)
+    cfg = sut.model_config(arch, {})
+    mix = _solar2_traffic()
+    n_pages = mix["engine"]["kv_pool_bytes"] // (
+        kda_flops.kv_bytes_per_token(arch) * 128)
+    shapes = sut.weight_shapes(cfg, cfg.dtype)
+    B, M, few = SOLAR2_CELL["B"], SOLAR2_CELL["M"], 6
+    eng = GenerationEngine(
+        cfg, jax.tree.map(lambda s: np.zeros((1,), s.dtype), shapes),
+        max_slots=few, max_seqlen=9216, max_new_tokens_cap=8192,
+        page_size=128, n_pages=80,
+        state_snapshots=mix["engine"]["state_snapshots"],
+        admit_buckets=(2, 8), record_routing=True, seed=0)
+    eng._decode_use_pallas = True
+    assert eng.fused and eng._stateful and eng.M == M
+    # the state update's rule asks the first device itself: here the CPU
+    assert kda_decode.kda_decode_applies(cfg, None, "tpu")
+    eng._ssm_update = lambda: kda_decode.kda_decode
+    eng.B = B
+    # gated experts: a step's 256 rows and a wave's 1,024 are both over
+    # the kernel's crossing
+    assert eng._moe_grouped(B) and eng._moe_grouped(8 * eng.admit_chunk)
+
+    def spec(a):
+        return _spec(a.shape, a.dtype, one_chip)
+
+    def rows(a):
+        # the engine above holds 6 slots (a CPU's worth): the cell's 256
+        return _spec(
+            tuple(B if d == few else d for d in a.shape), a.dtype, one_chip)
+
+    pages = eng.state.cache.pages
+    assert pages.shape[2:] == (2, 8, 128, 128) and pages.shape[0] == 1
+    st = eng.state
+    state = dataclasses.replace(
+        jax.tree.map(rows, dataclasses.replace(st, snaps=None, cache=None)),
+        cache=tfm.PagedKVCache(pages=_spec(
+            (1, n_pages) + pages.shape[2:], pages.dtype, one_chip)),
+        snaps=jax.tree.map(spec, st.snaps),
+        rng=spec(st.rng))
+    i32 = lambda *shape: _spec(shape, jnp.int32, one_chip)
+    params = jax.tree.map(spec, shapes)
+    if program == "jit_chunk":
+        fn = eng._chunk_fn(16, M, 0, fused=eng.fused, with_topk=False)
+        return cfg, fn, (params, state, i32(B, M), i32(0))
+    fn = eng._extend_fn(8, M, skip_pool=False)
+    return cfg, fn, (params, state, i32(8, eng.admit_chunk), i32(8, M),
+                     i32(8), i32(8), i32(8))
+
+
+def test_solar2_cell_decode_chunk_updates_the_state_in_place(
+        compiled_kernels, one_chip):
+    """The cell's decode chunk (one attention and three delta-rule layers
+    at the published widths, 40 of 320 experts in every block, 256 slots,
+    16 steps) at the traffic file's pool: ``kda_decode`` over the stacked
+    state, ``paged_decode``, ``kv_page_write`` and ``moe_grouped`` (handed
+    both expert stacks whole) are in it and the step ends in
+    ``fused_sample`` over the 24,576-row head, computed once; NOTHING
+    results in an array of the stacked state's size or of one layer's
+    ``[256, 64, 128, 128]`` (the kernel updates the donated state in
+    place: the state is aliased to the result); arguments and temporaries
+    fit the chip."""
+    cfg, fn, args = _solar2_program(one_chip)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("kda_decode", "paged_decode", "kv_page_write",
+                   "moe_grouped"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    # (the vocabulary slice is as wide as [q ; k ; v], 3 x 8192, so ``[256,
+    # 24576]`` is also the mixers' projection and its convolution, and the
+    # other cells' "no array of the logits' shape" cannot be asked here:
+    # the head is the ONE ``fused_sample`` call, and no op takes a softmax
+    # over such an array)
+    assert cfg.vocab_size == cfg.kda.conv_dim
+    assert len(re.findall(r"%fused_sample(?:\.\d+)? = ", text)) == 1
+    assert not [ln[:160] for ln in text.split("\n")
+                if "[256,24576]" in ln and "exponential(" in ln
+                and "kda_conv" not in ln]
+    for shape in ("f32[3,256,64,128,128]", "f32[256,64,128,128]"):
+        made = [ln.strip()[:120] for ln in text.split("\n")
+                if f"= {shape}" in ln and " parameter(" not in ln
+                and " get-tuple-element(" not in ln
+                and "custom-call" not in ln and " while(" not in ln
+                and " bitcast(" not in ln]
+        assert not made, made
+    held, E, F = cfg.moe.held[0], cfg.hidden_dim, cfg.expert_dim
+    for shape in (f"bf16[{held},{E},{F}]", f"bf16[{held},{F},{E}]"):
+        assert not re.search(r"= (\()?" + re.escape(shape) + r"\{", text), shape
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * 3 * 256 * 64 * 128 * 128
+    assert mem.temp_size_in_bytes < 0.8e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+def test_solar2_admission_wave_runs_the_chunked_form(
+        compiled_kernels, one_chip):
+    """A wave of 8 x 128 tokens continuing 8 slots' state at the cell's
+    sizes: the held experts of both stacks run in ``moe_grouped`` (the only
+    Mosaic call: the delta rule's chunked form is XLA's), the pair terms of
+    a chunk stay inside their fusions (no ``[.., 64, 64, 128]`` array a
+    head), and the program makes no array of the whole state's size."""
+    cfg, fn, args = _solar2_program(one_chip, "jit_extend")
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _custom_call_names(text) == {"moe_grouped"}
+    made = [ln.strip()[:120] for ln in text.split("\n")
+            if "= f32[3,256,64,128,128]" in ln and " parameter(" not in ln
+            and " get-tuple-element(" not in ln]
+    assert not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
