@@ -71,6 +71,7 @@ Thread-safety: ``submit`` arrives on the server's asyncio thread while
 
 import dataclasses
 import logging
+import math
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -198,9 +199,16 @@ class GenOutput:
     prefix_hit_tokens: int = 0
 
 
-# rows of the routing record (``record_routing``) that one pull of the
-# harvest takes
-_ROUTING_PULL = 8
+# what the harvest's gather (``GenerationEngine._pull_outputs``) takes of a
+# slot's output buffers: BLOCKS of this many positions (of the largest
+# divisor of the cap that 128 has, so a cap that is no multiple of 128
+# still splits evenly), one program a COUNT of blocks, the counts powers of
+# two from the first to the second (fewer pad to the first; more take the
+# largest program several times before the one pull). At 1 KB a block of
+# tokens and log-probs (up to 56 KB with a routing record) the first costs
+# nothing to pad to; the second is four rows at a cap of 15,360
+_PULL_BLOCK = 128
+_PULL_COUNTS = (8, 512)
 # a per-slot state of at least this many bytes is a matrix a head (a
 # recurrent or a delta-rule state: 13-76 MB a slot at the published sizes)
 # and gets the few snapshots that memory allows; a smaller one is a
@@ -303,6 +311,20 @@ class GenerationEngine:
       new, no output is shorter, ``partial_outputs`` and ``pause`` hand out
       a held or preempted request's tokens like any other's. A request
       that could not run even alone in the pool is refused at ``submit``.
+
+    WHAT A CHUNK BOUNDARY MOVES TO THE HOST, two pulls a step: the chunk's
+    FLAGS (``active``, ``n_gen``, ``max_gen``, ``lens`` of every slot and
+    the chunk's counts, a few KB, their copy started at dispatch), and,
+    where a slot finished, what the finished slots WROTE: their tokens,
+    log-probs and (``record_routing``) routing in blocks of
+    ``_pull_block`` positions, ``ceil(n_gen / block)`` a slot by the
+    flags' ``n_gen``, gathered on the device by one program built with
+    the engine and pulled under ``gen_engine/harvest/pull`` (its ``bytes``,
+    ``rows``, ``blocks``). Never the ``[slots, max_new_tokens_cap]``
+    buffers themselves. ``pause``, ``partial_outputs``,
+    ``partial_routing`` and a preemption read through the same
+    ``_pull_outputs``; they hold no fresh flags, so they pull
+    ``n_gen`` / ``active`` / ``max_gen`` (``[slots]`` each) first.
 
     Counters (``stats``, the chunk and admit spans, ``metrics``):
     ``pages_taken_growing``, ``slots_held`` (slot-chunks held out),
@@ -556,12 +578,16 @@ class GenerationEngine:
                 # no-op) so that holding a slot compiles nothing later
                 self._jit_activity = self._activity_fn()
                 self._set_activity(build=True)
-                if record_routing:
-                    # likewise the harvest's pull of the routing record
-                    self._jit_routing_rows = jax.jit(lambda rec, idx: rec[idx])
-                    self._jit_routing_rows(
-                        self.state.out_routing,
-                        jnp.zeros((_ROUTING_PULL,), jnp.int32))
+                # likewise the harvest's gather, every count it comes in
+                self._pull_block = math.gcd(self.G, _PULL_BLOCK)
+                self._pull_counts = [_PULL_COUNTS[0]]
+                while self._pull_counts[-1] < min(
+                        _PULL_COUNTS[1], self.B * self.G // self._pull_block):
+                    self._pull_counts.append(2 * self._pull_counts[-1])
+                self._jit_pull = self._pull_fn()
+                for n in self._pull_counts:
+                    self._jit_pull(
+                        self._out_buffers(), np.zeros((2, n), np.int32))
             self.accepting = True  # False = decode only, no new admissions
             self.paused = False
             self._slots: List[Optional[_SlotInfo]] = [None] * self.B
@@ -798,8 +824,8 @@ class GenerationEngine:
     def n_compiles(self) -> int:
         """Total jitted specializations (stability tested: bounded by the
         admit buckets + decode chunk sizes, NOT by prompt lengths;
-        the dry rule's one program, built with the engine, is not among
-        them)."""
+        the dry rule's one program and the harvest's gather, built with
+        the engine, are not among them)."""
         return (
             len(self._jit_extend) + len(self._jit_kv_write)
             + len(self._jit_commit) + len(self._jit_state)
@@ -816,7 +842,7 @@ class GenerationEngine:
             j
             for d in (self._jit_extend, self._jit_kv_write, self._jit_commit,
                       self._jit_state, self._jit_chunk,
-                      {(): self._jit_activity})
+                      {(): self._jit_activity}, {(): self._jit_pull})
             for j in d.values()
         )
 
@@ -834,7 +860,8 @@ class GenerationEngine:
                             ("commit", self._jit_commit),
                             ("state", self._jit_state),
                             ("chunk", self._jit_chunk),
-                            ("activity", {(): self._jit_activity}))
+                            ("activity", {(): self._jit_activity}),
+                            ("pull", {(): self._jit_pull}))
             for key, fn in d.items()
         }
 
@@ -943,8 +970,9 @@ class GenerationEngine:
         """Accumulated (tokens, logprobs) so far for running slots — the
         per-chunk harvest the streaming endpoint emits between finishes.
 
-        ONE device pull serves every requested slot (same batching rule as
-        ``_harvest``). Callers off the event loop only: the pull blocks on
+        ONE ``_pull_outputs`` serves every requested slot (same batching
+        rule as ``_harvest``): their ``n_gen`` first, then the blocks they
+        wrote. Callers off the event loop only: the pull blocks on
         any in-flight chunk. A request the dry rule preempted reads as it
         would have: what it generated before is kept on the host, whether
         it waits for a slot or runs in one again."""
@@ -962,13 +990,12 @@ class GenerationEngine:
             }
             if not sel:
                 return out
-            host = self._pull_outputs()
+            host = self._pull_outputs([b for b, _ in sel])
             for b, rid in sel:
-                n = int(host["n_gen"][b])
                 toks, lps = out.get(rid, ([], []))
                 out[rid] = (
-                    toks + host["out_tokens"][b, :n].tolist(),
-                    lps + host["out_logprobs"][b, :n].tolist(),
+                    toks + host["out_tokens"][b].tolist(),
+                    lps + host["out_logprobs"][b].tolist(),
                 )
             return out
 
@@ -1004,14 +1031,14 @@ class GenerationEngine:
         a ``record_routing`` engine (``None`` otherwise, or for a request
         that holds no slot). For a check from outside that reads a running
         request's state (:meth:`recurrent_state`) GIVEN the program's
-        routing; one pull, which blocks on any in-flight chunk."""
+        routing; one ``_pull_outputs``, which blocks on any in-flight
+        chunk."""
         with self._lock:
             if self.state.out_routing is None:
                 return None
             for b, s in enumerate(self._slots):
                 if s is not None and s.rid == rid:
-                    host = self._pull_outputs([b])
-                    return host["out_routing"][b][: int(host["n_gen"][b])]
+                    return self._pull_outputs([b])["out_routing"][b]
             return None
 
     def cancel(self, rid: str) -> bool:
@@ -1058,8 +1085,8 @@ class GenerationEngine:
             self._steps_ahead = 0
             outs = []
             if any(s is not None for s in self._slots):
-                # ONE device pull for every slot (a per-slot fetch is one
-                # blocking device->host sync each)
+                # ONE pull for every occupied slot, of what each wrote (a
+                # per-slot fetch is one blocking device->host sync each)
                 host_state = self._pull_outputs(
                     [b for b, s in enumerate(self._slots) if s is not None]
                 )
@@ -1362,7 +1389,7 @@ class GenerationEngine:
         n = int(host["n_gen"][b])
         with self._pending_lock:
             req = self._req_meta[info.rid]
-        toks = host["out_tokens"][b, :n].tolist()
+        toks = host["out_tokens"][b].tolist()
         ids = list(req.input_ids) + toks
         c = self._carried.setdefault(info.rid, {
             "tokens": [], "logprobs": [], "routing": None,
@@ -1370,12 +1397,12 @@ class GenerationEngine:
             "prefix_hit_tokens": info.prefix_hit_tokens,
         })
         c["tokens"] += toks
-        c["logprobs"] += host["out_logprobs"][b, :n].tolist()
+        c["logprobs"] += host["out_logprobs"][b].tolist()
         c["t_first"] = c["t_first"] or info.t_first
         routing = host["out_routing"].get(b)
         if routing is not None:
-            c["routing"] = routing[:n] if c["routing"] is None else (
-                np.concatenate([c["routing"], routing[:n]]))
+            c["routing"] = routing if c["routing"] is None else (
+                np.concatenate([c["routing"], routing]))
         n_full = (len(ids) - 1) // self.page      # pages wholly written
         if self.enable_prefix_cache and not self._stateful and n_full:
             self.prefix.insert(ids, self._registry_pages(b, n_full))
@@ -2516,36 +2543,100 @@ class GenerationEngine:
             # arealint: ok(resolving the dispatch-ahead flag copy, not a pull)
             return tuple(np.asarray(f) for f in flags)
 
-    def _pull_outputs(self, slots: Sequence[int] = ()) -> dict:
-        """ONE device pull of every slot's accumulated outputs + flags
-        (and, on a ``record_routing`` engine, of the routing record of
-        ``slots``: the rows about to be harvested, not all ``B``)."""
+    def _pull_fn(self):
+        """``(buffers, at [2, n])`` -> of each output buffer the ``n``
+        blocks of ``_pull_block`` positions that ``at`` names, a block
+        ``(slot, first position)``: ``[n, block]`` of tokens and log-probs,
+        ``[n, block, L x top_k]`` of a routing record. A LOOP of slices,
+        not one gather: the chip's gather wants its operand in another
+        layout and first copies the whole buffer to get it (0.35-0.5 GB of
+        temporaries for a routing record of 192 x 4,096 x 110, which is
+        what the row gather before this one cost a harvest); a slice reads
+        the buffer where it lies."""
+        block = self._pull_block
+
+        def pull(buffers, at):
+            def one(at):
+                return tuple(
+                    jax.lax.dynamic_slice(
+                        buf, (at[0], at[1]) + (0,) * (buf.ndim - 2),
+                        (1, block) + buf.shape[2:])[0]
+                    for buf in buffers)
+
+            return jax.lax.map(one, at.T)
+
+        return jax.jit(pull)
+
+    def _out_buffers(self) -> tuple:
         st = self.state
-        want = (st.n_gen, st.out_tokens, st.out_logprobs, st.active,
-                st.max_gen)
-        if st.out_routing is not None and len(slots):
-            # in groups of ``_ROUTING_PULL`` rows, the last padded: ONE
-            # program whatever number of slots ends in a chunk (an index
-            # array of the slots' own number compiled one for each)
-            n = _ROUTING_PULL
-            slots = list(slots)
-            want += tuple(
-                self._jit_routing_rows(st.out_routing, jnp.asarray(
-                    (slots[i : i + n] + [slots[i]] * n)[:n], jnp.int32))
-                for i in range(0, len(slots), n))
+        routing = () if st.out_routing is None else (st.out_routing,)
+        return (st.out_tokens, st.out_logprobs) + routing
+
+    def _pull_outputs(
+        self, slots: Sequence[int], flags: Optional[tuple] = None,
+    ) -> dict:
+        """What ``slots`` have generated, to the host: of each its tokens
+        and log-probs (on a ``record_routing`` engine its routing record
+        too, a token's back to ``[L, top_k]``) cut to its ``n_gen``, under
+        ``out_tokens`` / ``out_logprobs`` / ``out_routing`` BY SLOT, beside
+        every slot's ``n_gen`` / ``active`` / ``max_gen``. ONE pull of what
+        those slots WROTE, not of every slot's buffers at their whole cap
+        (4-40 MB a chunk boundary at 64-256 slots and caps of
+        4,096-15,360, to read the few rows that finished; PERF.md section
+        6, PR 60): the buffers are read in blocks of ``_pull_block``
+        positions, ``ceil(n_gen / block)`` a slot, so a long row does not
+        widen the short ones beside it; the list of blocks is padded to one
+        of ``_pull_counts`` (every program built with the engine: nothing
+        compiles here), gathered on the device and pulled. ``flags``:
+        ``(n_gen, active, max_gen)`` where the caller holds them fresh on
+        the host (the harvest: its chunk's resolved flags); a caller that
+        does not (a chunk may be in flight) leaves them out and pays a
+        second small pull for them first."""
+        st, block = self.state, self._pull_block
+        slots, buffers = list(slots), self._out_buffers()
         with tracing.span("gen_engine/harvest/pull") as attrs:
-            got = jax.device_get(want)
-            attrs["bytes"] = sum(a.nbytes for a in got)
-        n_gen, out_tokens, out_logprobs, active, max_gen, *routing = got
-        return {
-            "n_gen": n_gen, "out_tokens": out_tokens,
-            "out_logprobs": out_logprobs, "active": active,
-            "max_gen": max_gen,
-            # a token's record back to ``[L, top_k]``
-            "out_routing": dict(zip(slots, (
-                row.reshape(len(row), -1, self.cfg.moe.top_k)
-                for rows in routing for row in rows))),
-        }
+            nbytes = 0
+            if flags is None:
+                flags = jax.device_get((st.n_gen, st.active, st.max_gen))
+                nbytes = sum(a.nbytes for a in flags)
+            n_gen, active, max_gen = flags
+            counts = [-(-int(n_gen[b]) // block) for b in slots]
+            at = np.stack([
+                np.repeat(slots, counts),
+                block * np.concatenate(
+                    [np.arange(k) for k in counts] + [np.arange(0)]),
+            ]).astype(np.int32)
+            # the largest program as often as it takes, then the smallest
+            # that holds the rest; what pads it (block 0 of slot 0) lies
+            # behind every block that was asked for
+            most, parts = self._pull_counts[-1], []
+            for i in range(0, at.shape[1], most):
+                part = at[:, i : i + most]
+                n = next(c for c in self._pull_counts if c >= part.shape[1])
+                parts.append(self._jit_pull(
+                    buffers, np.pad(part, ((0, 0), (0, n - part.shape[1])))))
+            got = jax.device_get(parts)
+            attrs.update(
+                bytes=nbytes + sum(a.nbytes for part in got for a in part),
+                rows=len(slots), blocks=sum(len(part[0]) for part in got))
+        out = {"n_gen": n_gen, "active": active, "max_gen": max_gen,
+               "out_routing": {}}
+        firsts = block * (np.cumsum(counts) - counts)
+        for key, buf, *blocks in zip(
+                ("out_tokens", "out_logprobs", "out_routing"), buffers, *got):
+            # (one program's blocks as they came: no copy on the hot path)
+            flat = (
+                blocks[0] if len(blocks) == 1
+                else np.concatenate(blocks) if blocks
+                else np.zeros((0, block) + buf.shape[2:], buf.dtype)
+            ).reshape((-1,) + buf.shape[2:])
+            if key == "out_routing":
+                flat = flat.reshape(len(flat), -1, self.cfg.moe.top_k)
+            out[key] = {
+                b: flat[first : first + int(n_gen[b])]
+                for b, first in zip(slots, firsts)
+            }
+        return out
 
     def _harvest(self, b: int, reason: str, host_state: dict) -> GenOutput:
         """Release slot ``b`` and build its output from a host snapshot.
@@ -2559,10 +2650,9 @@ class GenerationEngine:
         ``step()``'s path the decode chunk already set
         ``active[b]=False`` on device, so no scatter is needed at all."""
         n = int(host_state["n_gen"][b])
-        toks = host_state["out_tokens"][b, :n].tolist()
-        lps = host_state["out_logprobs"][b, :n].tolist()
+        toks = host_state["out_tokens"][b].tolist()
+        lps = host_state["out_logprobs"][b].tolist()
         routing = host_state["out_routing"].get(b)
-        routing = None if routing is None else routing[:n]
         info = self._free_slot(b)
         with self._pending_lock:
             self._req_meta.pop(info.rid, None)
@@ -2816,10 +2906,12 @@ class GenerationEngine:
             if info is not None and info.t_first is None:
                 info.t_first = now
 
-    def _harvest_finished(self, finished: List[int], n_gen, max_gen,
+    def _harvest_finished(self, finished: List[int], flags: tuple,
                           chunk_attrs: dict) -> List[GenOutput]:
-        """One output pull for every finished slot, then their release,
-        under its span."""
+        """One output pull for every finished slot (of what each wrote:
+        ``flags``, the chunk's resolved ``(n_gen, active, max_gen)``, say
+        how much), then their release, under its span."""
+        n_gen, _, max_gen = flags
         chunk_attrs["finished"] = len(finished)
         if not finished:
             return []
@@ -2828,7 +2920,7 @@ class GenerationEngine:
         ) as attrs:
             # the chunk already deactivated them on device, so no scatter
             # back
-            host_state = self._pull_outputs(finished)
+            host_state = self._pull_outputs(finished, flags)
             outs = [
                 self._harvest(
                     b, _finish_reason(n_gen[b], max_gen[b]),
@@ -2855,8 +2947,12 @@ class GenerationEngine:
         an attached chip not measured) — overlaps the NEXT chunk's
         compute: chunk k+1 is dispatched first, then chunk k's (already resolved, undonated)
         flag outputs are pulled and its finishes harvested, one chunk
-        late. Output pulls for finished slots still ride the current
-        state, so a harvest-bearing step waits like the unpipelined path.
+        late. The harvest pulls what the finished slots WROTE
+        (``_pull_outputs``: blocks of their tokens, log-probs and routing
+        gathered on the device, sized by the flags' ``n_gen``; not every
+        slot's buffers), but the gather's operand is the CURRENT state,
+        the one the in-flight chunk returns, so a harvest-bearing step
+        still waits that chunk out like the unpipelined path.
         """
         with self._lock:
             if self.paused:
@@ -2885,7 +2981,7 @@ class GenerationEngine:
                 self._mark_first(running)
                 finished = [b for b in running if not active[b]]
                 return self._harvest_finished(
-                    finished, n_gen, max_gen, span_attrs
+                    finished, (n_gen, active, max_gen), span_attrs
                 )
 
     def _step_pipelined(
@@ -2951,11 +3047,12 @@ class GenerationEngine:
             self._lens_host[b] = lens[b]
         self._mark_first(same)
         finished = [b for b in same if not active[b]]
-        # output pull rides the CURRENT state: waits out the in-flight
-        # chunk (same cost the unpipelined path pays every chunk). The
-        # finished slots were inactive through chunk k+1, so their
-        # outputs are final.
-        return self._harvest_finished(finished, n_gen, max_gen, span_attrs)
+        # the output gather reads the CURRENT state: it waits out the
+        # in-flight chunk (same cost the unpipelined path pays every
+        # chunk). The finished slots were inactive through chunk k+1, so
+        # their outputs, and chunk k's ``n_gen`` of them, are final.
+        return self._harvest_finished(
+            finished, (n_gen, active, max_gen), span_attrs)
 
     @property
     def has_inflight(self) -> bool:

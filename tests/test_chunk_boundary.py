@@ -157,8 +157,16 @@ def test_no_hole_in_the_serial_section(models, model, pipelined, monkeypatch):
     assert all(
         r["attrs"]["table_width"] == by_id[r["parent_id"]]["attrs"]["table_width"]
         for r in enqueues)
-    pulls = [r for r in ring if r["name"] == "gen_engine/harvest/pull"]
-    assert pulls and all(r["attrs"]["bytes"] > 0 for r in pulls)
+    # a pull moves what its rows WROTE: ``rows`` harvested slots, ``blocks``
+    # of the engine's block of positions gathered (padding included), and
+    # ``bytes`` no more than those blocks hold (8 B a position here)
+    pulls = [r["attrs"] for r in ring if r["name"] == "gen_engine/harvest/pull"]
+    assert pulls and all(p["bytes"] > 0 for p in pulls)
+    assert all(1 <= p["rows"] <= eng.B for p in pulls)
+    assert all(p["blocks"] >= eng._pull_counts[0] for p in pulls)
+    assert all(
+        p["bytes"] == p["blocks"] * eng._pull_block * 8 for p in pulls)
+    assert sum(p["rows"] for p in pulls) == 6
     prefills = [r for r in ring if r["name"] == "gen_engine/admit/prefill"]
     assert prefills and all(r["attrs"]["programs"] >= 2 for r in prefills)
 

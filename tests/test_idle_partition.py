@@ -114,7 +114,7 @@ NEW_READERS = [
     "gen.idle_harvest_share", "gen.idle_admit_plan_share",
     "gen.idle_admit_prefill_share", "gen.idle_dispatch_share",
     "gen.idle_outside_step_share", "gen.kv_read_once_share",
-    "gen.step_longest_ms",
+    "gen.step_longest_ms", "gen.harvest_pull_kb",
 ]
 
 
@@ -184,3 +184,21 @@ def test_kv_read_once_share_and_step_longest_read_the_chunk_spans():
     spans = program_spans.window_spans(bench, "gen_engine/chunk")
     assert longest == pytest.approx(1e3 * max(s["dur_s"] for s in spans))
     assert longest >= 20.0
+
+
+def test_harvest_pull_kb_is_the_mean_of_the_pull_spans_bytes():
+    """ISSUE 60: mean KB of the window's ``gen_engine/harvest/pull`` spans;
+    a span without ``bytes`` (a program from before PR 51) is no reading."""
+    def record():
+        for nbytes in (8192, 1024 * 57, 20_709_376):
+            with tracing.span("gen_engine/harvest"):
+                with tracing.span("gen_engine/harvest/pull") as attrs:
+                    attrs.update(bytes=nbytes, rows=1, blocks=8)
+        with tracing.span("gen_engine/harvest/pull"):
+            pass
+
+    reader = load_reader("gen.harvest_pull_kb")
+    assert reader.read(_ring_bench(record)) == pytest.approx(
+        (8192 + 1024 * 57 + 20_709_376) / 3 / 1e3)
+    assert reader.read(_ring_bench(lambda: None)) is None
+
