@@ -1,4 +1,14 @@
-"""The persistent XLA compile cache: ONE site configures it.
+"""The persistent XLA compile cache and the program store: ONE site
+configures both.
+
+A WARM start is one that finds its programs BUILT: the engines' programs
+come out of the program store (``base/program_store.py``, in
+``<cache>/programs``), loaded under a key digested from what each was built
+from, with no Python trace and no lowering; what the store does not hold
+(the first start of a tree, a changed configuration or shape, an evicted
+entry) is traced and lowered as before and finds its EXECUTABLE in JAX's
+cache if it was ever compiled here, so a cold start of the store is what
+a warm start was before it existed, and a start with neither compiles.
 
 Every process entry calls :func:`configure` before its first compile
 (``apps/launcher._setup_worker_env``, ``gateway/__main__``,
@@ -23,19 +33,28 @@ initialises a backend — the launcher's JAX-free parent calls it too.
 Where JAX is already imported it also starts the process's compile
 listener (``tracing.listen_for_compiles``), so what the cache hit and
 missed is counted from the first program on; where it is not, the
-engines' constructors do.
+engines' constructors do. It opens the program store inside the
+directory it returns, or closes it where it returns None: a process that
+never calls it (the test session) has no store.
 """
 
 import os
 import sys
 from typing import Optional
 
-from areal_tpu.base import constants, tracing
+from areal_tpu.base import constants, program_store, tracing
 
 
 def configure() -> Optional[str]:
     """Returns the directory the cache lives in, or None when this run
-    caches nothing (held to the CPU, variable unset)."""
+    caches nothing (held to the CPU, variable unset). The program store
+    opens inside it, or is closed with it."""
+    path = _configure()
+    program_store.open_in(path)
+    return path
+
+
+def _configure() -> Optional[str]:
     tracing.listen_for_compiles()   # nothing where JAX is not imported
     if constants.env_str(constants.COMPILE_CACHE_ENV) is not None:
         return constants.compile_cache_dir()    # JAX reads it itself

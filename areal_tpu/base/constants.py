@@ -305,6 +305,39 @@ def compile_cache_dir() -> str:
     return env_str(COMPILE_CACHE_ENV) or _DEFAULT_COMPILE_CACHE_DIR
 
 
+# What of the environment may shape a PROGRAM (``base/program_store.py``
+# keys a built program by it): the compiler's own flags and EVERY variable
+# of this package's, so that a flag a later change reads inside a traced
+# function is keyed before anyone thinks of it, but those that say where
+# files live, who talks to whom and what is logged, traced or watched (a
+# restarted worker gets new ones and must still find its programs).
+_PROGRAM_ENV = ("XLA_FLAGS", "LIBTPU_INIT_ARGS")
+_NOT_PROGRAM_ENV = (      # prefixes of ``AREAL_*`` names
+    "AREAL_FILEROOT", "AREAL_JOBDIR", "AREAL_NAME_RESOLVE_", "AREAL_LOG_LEVEL",
+    "AREAL_COORDINATOR", "AREAL_NUM_PROCESSES", "AREAL_PROCESS_ID",
+    "AREAL_GATEWAY_", "AREAL_GW_", "AREAL_TRACE_", TRACE_ENV,
+    "AREAL_TELEMETRY_", "AREAL_WATCHDOG_", "AREAL_HBM_",
+)
+
+
+def dumps_ir() -> bool:
+    """``JAX_DUMP_IR_TO`` (JAX's own variable): the process writes the
+    lowered program of every jit to a directory, ``chip_smoke.py``'s check
+    of the kernels from outside. Such a process wants every program
+    LOWERED: the program store leaves it on ``jax.jit``'s own path."""
+    return bool(env_str("JAX_DUMP_IR_TO"))
+
+
+def program_env() -> tuple:
+    """``(name, value)`` of every variable set that may shape a program
+    (JAX's own settings are keyed from ``jax.config``, not from here)."""
+    return tuple(sorted(
+        (k, v) for k, v in os.environ.items()
+        if k in _PROGRAM_ENV or (
+            k.startswith("AREAL_") and not k.startswith(_NOT_PROGRAM_ENV))
+    ))
+
+
 def native_disabled() -> bool:
     """``AREAL_DISABLE_NATIVE``: skip building/loading the C packer
     extension (pure-python fallback)."""

@@ -497,6 +497,12 @@ COMPILE_CACHE_HITS = "compile/cache_hits"     # loaded from the persistent cache
 COMPILE_CACHE_MISSES = "compile/cache_misses" # compiled, then written to it
 COMPILE_CACHE_LOAD_S = "compile/cache_load_s" # reading + deserialising hits
 COMPILE_CACHE_SAVED_S = "compile/cache_saved_s"  # compile time the hits saved
+# The program store (``base/program_store.py``): how often a start found a
+# program BUILT (a hit is also one of ``compile/programs`` and of
+# ``compile/cache_hits``, its load in ``compile/backend_s``) and how often
+# it built one as before and wrote it.
+COMPILE_STORE_HITS = "compile/store_hits"
+COMPILE_STORE_MISSES = "compile/store_misses"
 
 
 # Fraction edges for the pool-occupancy histogram: occupancy lives in

@@ -34,7 +34,9 @@ tree. The ring additionally feeds the crash flight recorder
 ``jax.monitoring`` listeners a process turns every executable JAX builds
 or loads from its cache into the ``compile/*`` counters and, with the
 span plane on, one ``compile/program`` record under the span that paid
-for it (docs/observability.md "What a start cost").
+for it (docs/observability.md "What a start cost"); a program the
+program store handed over built (``base/program_store.py``) is booked
+the same way by :func:`program_loaded`.
 
 Context flows through :mod:`contextvars`, so one event loop serving many
 concurrent requests keeps each request's trace identity isolated without
@@ -448,6 +450,41 @@ def _program_built(fun_name: str, backend_s: float) -> None:
     # "jit(chunk)" was traced as "chunk"
     trace_s = traces.get(fun_name[fun_name.find("(") + 1:-1], 0.0)
     cache_hit = built.pop("cache_hit", None)    # None: no cache consulted
+    more = {}
+    if built.get("store_miss") == fun_name:     # built to be stored
+        built["store_miss"] = bool(cache_hit)
+        more["stored"] = False
+    _book_program(fun_name, trace_s, lower_s, backend_s, cache_hit, **more)
+
+
+def program_missed(fun_name: str) -> None:
+    """The program store is about to build ``fun_name`` on this thread as
+    JAX always did (a miss): its record says ``stored: false``."""
+    metrics_mod.counters.add(metrics_mod.COMPILE_STORE_MISSES)
+    _building.store_miss = fun_name
+
+
+def missed_from_cache() -> bool:
+    """After the build :func:`program_missed` announced: whether JAX's
+    persistent cache handed the executable over (it was not compiled
+    here)."""
+    return _building.__dict__.pop("store_miss", None) is True
+
+
+def program_loaded(fun_name: str, load_s: float) -> None:
+    """One BUILT program was taken from the program store
+    (``base/program_store.py``): booked as a program built from a cache
+    (one of ``compile/programs`` and of ``compile/cache_hits``, its load
+    the backend's seconds, no trace and no lowering), its record
+    ``stored``."""
+    metrics_mod.counters.add(metrics_mod.COMPILE_STORE_HITS)
+    metrics_mod.counters.add(metrics_mod.COMPILE_CACHE_HITS)
+    _book_program(fun_name, 0.0, 0.0, load_s, True, stored=True)
+
+
+def _book_program(fun_name: str, trace_s: float, lower_s: float,
+                  backend_s: float, cache_hit: Optional[bool],
+                  **more) -> None:
     add = metrics_mod.counters.add
     add(metrics_mod.COMPILE_PROGRAMS)
     add(metrics_mod.COMPILE_TRACE_S, trace_s)
@@ -458,7 +495,7 @@ def _program_built(fun_name: str, backend_s: float) -> None:
     dur = trace_s + lower_s + backend_s
     rec = _new_record(COMPILE_RECORD, time.perf_counter() - dur, {
         "fun_name": fun_name, "trace_s": trace_s, "lower_s": lower_s,
-        "backend_s": backend_s, "cache_hit": cache_hit,
+        "backend_s": backend_s, "cache_hit": cache_hit, **more,
     })
     if rec["parent_id"] is not None:
         with _live_lock:

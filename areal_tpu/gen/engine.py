@@ -81,7 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from areal_tpu.base import constants, tracing
+from areal_tpu.base import constants, program_store, tracing
 from areal_tpu.base import metrics as metrics_mod
 from areal_tpu.gen.pages import PagePool, PrefixRegistry
 from areal_tpu.gen.pages import _ids as _held_ids
@@ -1432,8 +1432,8 @@ class GenerationEngine:
                 lens=jnp.where(drop, 0, state.lens),
             )
 
-        return jax.jit(
-            activity, donate_argnums=(0,),
+        return program_store.stored_jit(
+            activity, name="gen/activity", donate_argnums=(0,),
             **self._jit_sharding(3, with_params=False),
         )
 
@@ -1587,7 +1587,9 @@ class GenerationEngine:
 
         sharding_kw = self._jit_sharding(5 if self._stateful else 4)
         sharding_kw.pop("out_shardings", None)
-        jitted = jax.jit(extend, **sharding_kw)
+        jitted = program_store.stored_jit(
+            extend, name="gen/extend", key=key,
+            built_from=self._built_from(), **sharding_kw)
         self._jit_extend[key] = jitted
         return jitted
 
@@ -1672,9 +1674,24 @@ class GenerationEngine:
                 + ((None, self._repl) if self._stateful else ()),
                 out_shardings=self._state_sh,
             )
-        jitted = jax.jit(kv_write, donate_argnums=(0,), **sharding_kw)
+        jitted = program_store.stored_jit(
+            kv_write, name="gen/kv_write", key=n_rows,
+            built_from=self._built_from(), donate_argnums=(0,), **sharding_kw)
         self._jit_kv_write[n_rows] = jitted
         return jitted
+
+    def _built_from(self) -> tuple:
+        """What the model's programs (extend, kv_write, chunk) read of this
+        engine that their arguments and static keys do not show: the key
+        of a stored program (``base/program_store.py``) holds it. The
+        kernel choices (``_ssm_update``, ``_moe_grouped``,
+        ``_kv_write_rows``) are functions of these, of the arguments and
+        of the source."""
+        return (
+            self.cfg, self.B, self.page, self.admit_chunk,
+            tuple(self.admit_buckets), self._decode_use_pallas, self._moe,
+            self._stateful, self.fused, self.kv_dtype, self.mesh,
+        )
 
     def _jit_sharding(self, n_host_args: int, with_params: bool = True):
         """in/out sharding kwargs for the engine's jitted programs (empty
@@ -1710,8 +1727,8 @@ class GenerationEngine:
                 ),
             )
 
-        jitted = jax.jit(
-            commit, donate_argnums=(0,),
+        jitted = program_store.stored_jit(
+            commit, name="gen/commit", key=n_rows, donate_argnums=(0,),
             **self._jit_sharding(9, with_params=False),
         )
         self._jit_commit[n_rows] = jitted
@@ -1861,8 +1878,8 @@ class GenerationEngine:
                     state.snaps, dst,
                     [a[:, src] for a in jax.tree.leaves(state.ssm)]))
 
-        jitted = jax.jit(
-            copy, donate_argnums=(0,),
+        jitted = program_store.stored_jit(
+            copy, name="gen/state_copy", key=key, donate_argnums=(0,),
             **self._jit_sharding(2, with_params=False),
         )
         self._jit_state[key] = jitted
@@ -2438,7 +2455,9 @@ class GenerationEngine:
                 sharding_kw["out_shardings"],
                 (self._repl,) * (5 if self._moe else 4),
             )
-        jitted = jax.jit(chunk, donate_argnums=(1,), **sharding_kw)
+        jitted = program_store.stored_jit(
+            chunk, name="gen/chunk", key=key,
+            built_from=self._built_from(), donate_argnums=(1,), **sharding_kw)
         self._jit_chunk[key] = jitted
         return jitted
 
@@ -2565,7 +2584,7 @@ class GenerationEngine:
 
             return jax.lax.map(one, at.T)
 
-        return jax.jit(pull)
+        return program_store.stored_jit(pull, name="gen/pull", key=block)
 
     def _out_buffers(self) -> tuple:
         st = self.state

@@ -22,7 +22,7 @@ import numpy as np
 
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model import ModelInterface, PPOHyperparameters
-from areal_tpu.base import tracing
+from areal_tpu.base import program_store, tracing
 from areal_tpu.ops import ppo as ppo_ops
 from areal_tpu.parallel import multihost
 from areal_tpu.train import batching
@@ -93,7 +93,8 @@ class PPOActorInterface(ModelInterface):
         # Built once so the engine's jit cache hits across train_step calls.
         self._actor_loss_fn = self._build_actor_loss()
         # Likewise the advantage pre-pass: its cache lives with the interface.
-        self._prepass = jax.jit(self._build_prepass())
+        self._prepass = program_store.stored_jit(
+            self._build_prepass(), name="ppo/prepass", built_from=self.hp)
         # what it reads of a packed batch; no other array is sent to the device
         self._prepass_keys = {
             "segment_ids", "prompt_mask", "packed_logprobs", "rewards",

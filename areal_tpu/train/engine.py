@@ -17,7 +17,6 @@ Losses/outputs are supplied by interfaces as pure functions
 
 import collections
 import dataclasses
-import functools
 import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -29,7 +28,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from areal_tpu.api.data import MicroBatchSpec, SequenceSample
-from areal_tpu.base import constants, faults, recover, tracing
+from areal_tpu.base import constants, faults, program_store, recover, tracing
 from areal_tpu.base import metrics as metrics_mod
 from areal_tpu.models.config import ModelConfig
 from areal_tpu.models import transformer as tfm
@@ -499,12 +498,6 @@ class TrainEngine:
         if key in self._jit_cache:
             return self._jit_cache[key][1]
         cfg = self.cfg
-        from areal_tpu.ops import attention as attn_ops
-
-        # a Mosaic kernel under a multi-device mesh must be wrapped in
-        # shard_map (GSPMD cannot partition it): trace with the mesh known
-        on_mesh = functools.partial(attn_ops.trace_on_mesh, self.mesh)
-
         if kind == "train_step":
             # ONE dispatch per optimizer step: micro-batch grad accumulation
             # via lax.scan over stacked [n_mbs, D, T] buffers, the optax
@@ -606,8 +599,8 @@ class TrainEngine:
             # deleted in PR 21: not measured), and stats never feed back
             # as inputs, so they cannot cause recompiles.
             opt_sh = jax.tree.map(lambda x: x.sharding, self.opt_state)
-            jitted = jax.jit(
-                on_mesh(train_step),
+            jitted = self._stored_jit(
+                train_step, kind, fn, guard,
                 donate_argnums=(0, 1),
                 out_shardings=(self._param_shardings, opt_sh, None),
             )
@@ -616,17 +609,40 @@ class TrainEngine:
             def fwd(params, arrays):
                 return fn(params, cfg, arrays)
 
-            jitted = jax.jit(on_mesh(fwd))
+            jitted = self._stored_jit(fwd, kind, fn)
         elif kind == "eval":
 
             def ev(params, arrays):
                 return fn(params, cfg, arrays)
 
-            jitted = jax.jit(on_mesh(ev))
+            jitted = self._stored_jit(ev, kind, fn)
         else:
             raise ValueError(kind)
         self._jit_cache[key] = (fn, jitted)
         return jitted
+
+    def _stored_jit(self, step, kind: str, fn, *more, **jit_kw):
+        """``jax.jit`` of one of this engine's programs, through the
+        program store (``base/program_store.py``). Its key holds what the
+        program reads beside its arguments: the model, the caller's
+        function and the optimizer (each by what it is and what its
+        closures hold: the hyper-parameters, the schedule), the mesh and
+        the process's context-parallel ring. A
+        Mosaic kernel under a multi-device mesh must be wrapped in
+        ``shard_map`` (GSPMD cannot partition it): traced with the mesh
+        known."""
+        from areal_tpu.ops import attention as attn_ops
+
+        return program_store.stored_jit(
+            attn_ops.trace_on_mesh(self.mesh, step),
+            name="train/" + kind,
+            built_from=(
+                self.cfg, fn, more, self.tx, self.parallel,
+                str(self.param_dtype), self.mesh,
+                attn_ops.get_context_parallel(),
+            ),
+            **jit_kw,
+        )
 
     def n_jit_entries(self) -> int:
         """Total jax-level specializations across this engine's jitted

@@ -23,7 +23,11 @@ Contract (the builder's instructions, "The chip check"):
   own ``JAX_DUMP_IR_TO`` (the lowered program of every jit, written whether
   or not the compile cache hits) and ``JAX_LOG_COMPILES`` (compile seconds).
   A train step or decode chunk whose program has no ``tpu_custom_call``
-  carrying the kernel's name ran the XLA path or the interpreter, and fails.
+  carrying the kernel's name ran the XLA path or the interpreter, and fails;
+- a child that dumps its programs builds every one (the program store,
+  ``areal_tpu/base/program_store.py``, leaves it on ``jax.jit``'s own
+  path), so the serve phase runs one engine twice more WITHOUT the dump: the
+  second start must load its programs built and serve what the first did.
 
 ``--rehearse`` runs the same control flow at a toy size on whatever device
 JAX finds (the CPU in the sandbox), skips the device and kernel checks, and
@@ -35,6 +39,7 @@ import argparse
 import concurrent.futures
 import functools
 import glob
+import hashlib
 import json
 import math
 import os
@@ -141,18 +146,20 @@ def last_json_line(path):
     return json.loads(lines[-1])
 
 
-def helper(phase_dir, name, payload, timeout=900):
-    """Run one of this file's own `--child` bodies in a fresh process."""
+def helper(phase_dir, name, payload, timeout=900, run=None, extra_env=None):
+    """Run one of this file's own `--child` bodies in a fresh process
+    (``run``: this run's name, where a body runs more than once)."""
     os.makedirs(phase_dir, exist_ok=True)
-    arg = os.path.join(phase_dir, f"{name}.in.json")
+    run = run or name
+    arg = os.path.join(phase_dir, f"{run}.in.json")
     with open(arg, "w") as f:
         json.dump(payload, f)
     rc, out_p, err_p, secs = run_child(
-        phase_dir, name,
+        phase_dir, run,
         [sys.executable, os.path.abspath(__file__), "--child", name, arg],
-        timeout,
+        timeout, extra_env,
     )
-    require(rc == 0, f"{name} child rc={rc}: {tail(err_p)}")
+    require(rc == 0, f"{run} child rc={rc}: {tail(err_p)}")
     return last_json_line(out_p), secs
 
 
@@ -467,6 +474,34 @@ def stop(proc, grace=60):
             proc.wait(30)
 
 
+def warm_start_pair(d, args):
+    """A WARM START of the two-kind engine (child ``statespace``). A child
+    that dumps its programs stays on ``jax.jit``'s own path
+    (``base/program_store.py``), so the body runs twice WITHOUT the dump:
+    the first builds its programs and stores them, the second must find
+    every one BUILT, load it, and serve what the first served, token for
+    token."""
+    payload = {"seed": args.seed, "rehearse": args.rehearse}
+    no_dump = {"JAX_DUMP_IR_TO": ""}
+    built, _ = helper(d, "statespace", payload, run="statespace_built",
+                      extra_env=no_dump)
+    loaded, secs = helper(d, "statespace", payload, run="statespace_loaded",
+                          extra_env=no_dump)
+    require(loaded["logprobs"]["correct"]
+            and loaded["served_digest"] == built["served_digest"]
+            # (a rehearsal held to the CPU configures no cache, so no store)
+            and (args.rehearse or (sum(built["store_hits_misses"]) > 0
+                                   and loaded["store_hits_misses"][0] > 0
+                                   and loaded["store_hits_misses"][1] == 0)),
+            f"a warm start: the programs the store handed over are not "
+            f"the ones built, or it handed none: built {built}, "
+            f"loaded {loaded}")
+    emit({"phase": "serve", "step": "warm_start", "warm_start": {
+        "built_hits_misses": built["store_hits_misses"],
+        "loaded_hits_misses": loaded["store_hits_misses"],
+        "kernels": loaded["kernels"], "seconds": round(secs, 1)}})
+
+
 def phase_serve(sz, args):
     d = os.path.join(WORK, "serve")
     os.makedirs(d, exist_ok=True)
@@ -640,6 +675,7 @@ def phase_serve(sz, args):
             f"the two-kind model's decode chunk lacks a kernel, or its tied "
             f"head's rows did not end in the fused one: {hybrid['kernels']}, "
             f"fused_rows {hybrid['fused_rows']} of {hybrid['state_slots']}")
+    warm_start_pair(d, args)
     # ... and a model of THREE SEGMENTS through the engine (family
     # ``phi4flash``, 8 layers at the published widths): Mamba-1 layers beside
     # window layers, one full layer whose K/V a cross layer shares, a gated
@@ -1527,7 +1563,7 @@ def child_statespace(arg):
     model's head is granite's: the embedding, its logits divided by 8, so
     on the chip the chunk ends in ``fused_sample`` over ``[V, E]`` and
     the served log-probs are that kernel's."""
-    from areal_tpu.base import compile_cache
+    from areal_tpu.base import compile_cache, metrics
 
     compile_cache.configure()
     import jax
@@ -1600,6 +1636,13 @@ def child_statespace(arg):
             "correct", "reason", "max_abs_diff_nats", "tolerance_nats",
             "mean_abs_diff_nats", "n_positions")},
         "compiled": jax.devices()[0].platform == "tpu",
+        # what the program store did for this start, and what was served
+        "store_hits_misses": [
+            int(metrics.counters.get(f"compile/store_{k}"))
+            for k in ("hits", "misses")],
+        "served_digest": hashlib.sha256(json.dumps(
+            [[s["tokens"], s["logprobs"]] for s in samples]
+        ).encode()).hexdigest(),
     })
 
 
